@@ -4,7 +4,7 @@
 
 PY ?= python
 
-.PHONY: all native test test-all bench dryrun lint check-plan audit-comm chaos serving-chaos fleet-chaos data-smoke warmup clean
+.PHONY: all native test test-all bench dryrun chip-smoke lint check-plan audit-comm chaos serving-chaos fleet-chaos data-smoke warmup clean
 
 all: native
 
@@ -98,9 +98,18 @@ warmup:
 bench:
 	$(PY) bench.py
 
-# multi-chip sharding validation on a virtual 8-device CPU mesh
+# CPU SIMULATION of a multi-chip run: sharding/schedule validation in a child
+# process on 8 virtual CPU devices — never touches an accelerator
 dryrun:
 	$(PY) -c "import __graft_entry__ as g; g.dryrun_multichip(8)"
+
+# the quickest proof the trainer starts on the chip: kernel parity + a few
+# train steps at llama-7b widths on ONE TPU chip (fails without a TPU; run it
+# on the chip machine through the chip tool; CHIPS=4 runs the multi-chip
+# plans instead, on a four-chip host)
+CHIPS ?= 1
+chip-smoke:
+	$(PY) chip_smoke.py --chips $(CHIPS)
 
 clean:
 	rm -rf build .jax_cache .pytest_cache

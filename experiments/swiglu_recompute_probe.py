@@ -1,5 +1,6 @@
+import os
 import sys
-sys.path.insert(0, "/root/repo")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax, jax.numpy as jnp
 from galvatron_tpu.models import modeling
 from galvatron_tpu.core.strategy import HybridParallelConfig, LayerStrategy

@@ -24,7 +24,7 @@ import tempfile
 import jax
 import jax.numpy as jnp
 
-sys.path.insert(0, "/root/repo")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from galvatron_tpu.models import modeling
 

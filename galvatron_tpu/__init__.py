@@ -32,6 +32,3 @@ programming search core (csrc/dp_core.cpp equivalent).
 """
 
 __version__ = "0.1.0"
-
-# jax-version compatibility lives in galvatron_tpu.compat (imported by the
-# call sites) — the third-party jax namespace is never mutated here.

@@ -8,9 +8,9 @@ module makes compilation a first-class, keyed artifact:
 - :func:`enable_persistent_cache` — the ONE shared wiring of JAX's
   persistent compilation cache (previously hand-wired three divergent ways:
   tests/conftest.py, a CI env block, and nothing at all for the trainer).
-  Idempotent, and by default it respects a cache dir that is already
-  configured (conftest, ``JAX_COMPILATION_CACHE_DIR``) instead of silently
-  redirecting the process-wide cache mid-run.
+  Idempotent; ``JAX_COMPILATION_CACHE_DIR`` places the cache from outside
+  and wins over every flag, and without it the cache lives at the fixed
+  ``<repo>/.jax_cache``.
 - :func:`program_key` — the content key of one compiled program:
   ``(program name, plan_hash, effective model-shape dict, topology
   fingerprint, jax/jaxlib version, relevant XLA flags, donate/sharding
@@ -28,6 +28,7 @@ module makes compilation a first-class, keyed artifact:
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import json
 import os
@@ -48,30 +49,34 @@ _DISABLED_VALUES = ("0", "off", "none", "disabled")
 # ---------------------------------------------------------------------------
 
 
+#: where JAX's persistent cache lives when nothing outside places it: one
+#: fixed path inside the checkout (the path is part of the cache key, so a
+#: directory that moves never hits)
+REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+
+
 def enable_persistent_cache(
     cache_dir: Optional[str],
     *,
     min_entry_bytes: Optional[int] = None,
     min_compile_time_s: Optional[float] = None,
-    override: bool = False,
 ) -> Optional[str]:
-    """Point JAX's persistent compilation cache at ``cache_dir``.
+    """Point JAX's persistent compilation cache at ``cache_dir`` (a
+    :func:`resolve_compile_cache_dir` result) and return the EFFECTIVE dir.
 
-    Returns the EFFECTIVE cache dir: when one is already configured (a
-    conftest, an operator's ``JAX_COMPILATION_CACHE_DIR``) and ``override``
-    is False, the existing dir is kept and returned — a derived default must
-    never silently redirect a process-wide cache that someone wired on
-    purpose.  ``override=True`` (an explicit ``--compile_cache_dir``)
-    redirects, dropping jax's in-process cache handle so the new dir takes
-    effect even after compiles already happened.
+    ``JAX_COMPILATION_CACHE_DIR`` places the cache from outside: where it is
+    set, that directory is kept whatever ``cache_dir`` says — nothing in
+    code redirects a cache an operator placed.  ``None`` wires nothing and
+    returns what is configured.
 
     Thresholds: a redirect wires ``min_entry_bytes``/``min_compile_time_s``
     (0 / 0.0 when unspecified, so every compile persists); when the dir is
-    already wired exactly here, only EXPLICITLY passed thresholds land — a
-    bare re-enable of the live dir (trainer consult, elastic prewarm) must
-    not silently drop a conftest's write-churn floor mid-suite, but a caller
-    that asks for a floor gets it even when the dir came from the
-    environment."""
+    already the live one, only EXPLICITLY passed thresholds land — a bare
+    re-enable (trainer start, elastic prewarm) must not silently drop a
+    conftest's write-churn floor mid-suite."""
     import jax
 
     current = getattr(jax.config, "jax_compilation_cache_dir", None)
@@ -88,53 +93,55 @@ def enable_persistent_cache(
 
     if cache_dir is None:
         return current
-    cache_dir = os.path.abspath(cache_dir)
+    cache_dir = os.path.abspath(
+        os.environ.get("JAX_COMPILATION_CACHE_DIR") or cache_dir
+    )
     if current and os.path.abspath(current) == cache_dir:
         _apply_thresholds()
-        return current
-    if current and not override:
         return current
     os.makedirs(cache_dir, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", cache_dir)
     _apply_thresholds(0, 0.0)
     # jax latches its cache state (including "no cache configured") on the
-    # FIRST compile of the process; a config update after that is silently
-    # ignored until the module handle is dropped. Private API, so
-    # best-effort by contract — entries on disk are untouched.
-    try:
-        from jax._src import compilation_cache as _cc
+    # FIRST compile of the process; a config update after that is ignored
+    # until the module handle is dropped
+    from jax.experimental.compilation_cache import compilation_cache as _cc
 
-        _cc.reset_cache()
-    except Exception:  # noqa: BLE001 — older/newer jax: keep the config update
-        pass
+    _cc.reset_cache()
     return cache_dir
 
 
-def resolve_compile_cache_dir(ns) -> Optional[str]:
-    """The run's compile-cache dir, by precedence: explicit
-    ``--compile_cache_dir`` (``0``/``off``/``none`` disables) > the
-    ``JAX_COMPILATION_CACHE_DIR`` env > a dir already configured on
-    ``jax.config`` > the ``.jax_cache`` sibling of ``--save`` > None."""
+@contextlib.contextmanager
+def persistent_cache_off():
+    """Compile with JAX's persistent cache out of the way, then put it back.
+    For compiles whose executable must be the compiler's own: one for a
+    described (not attached) device is written to the cache but cannot be
+    read back, and one read back from the cache need not carry its text."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as _cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    _cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        _cc.reset_cache()
+
+
+def resolve_compile_cache_dir(ns=None) -> Optional[str]:
+    """Where this run's compile cache lives: ``JAX_COMPILATION_CACHE_DIR``
+    when set (whatever the flags say) > an explicit ``--compile_cache_dir``
+    > ``<repo>/.jax_cache``.  ``--compile_cache_dir 0``/``off``/``none``
+    returns None: this run wires no cache and runs no AOT consult."""
     v = getattr(ns, "compile_cache_dir", None)
-    if v:
-        if str(v).lower() in _DISABLED_VALUES:
-            return None
-        return os.path.abspath(v)
+    if v and str(v).lower() in _DISABLED_VALUES:
+        return None
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return os.path.abspath(env)
-    try:
-        import jax
-
-        current = getattr(jax.config, "jax_compilation_cache_dir", None)
-    except Exception:  # pragma: no cover — jax is always importable here
-        current = None
-    if current:
-        return os.path.abspath(current)
-    save = getattr(ns, "save", None)
-    if save:
-        return os.path.join(os.path.dirname(os.path.abspath(save)), ".jax_cache")
-    return None
+    return os.path.abspath(v) if v else REPO_CACHE_DIR
 
 
 # ---------------------------------------------------------------------------

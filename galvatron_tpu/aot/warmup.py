@@ -10,9 +10,8 @@ is a disk deserialize instead of an XLA compile, which is what turns a
 trainer start, an elastic restart, or a serving cold-start into a cache
 lookup.
 
-Failure isolation is the contract: one program failing to compile (this
-container's protobuf pipeline-compile crash class, a backend without some
-feature) degrades to a per-program ``status: failed`` report and a printed
+Failure isolation is the contract: one program failing to compile (a kernel
+the backend's compiler refuses, a backend without some feature) degrades to a per-program ``status: failed`` report and a printed
 warning — it must never abort the sweep, because the other programs' warmth
 is exactly as valuable without it.
 
@@ -34,11 +33,12 @@ from galvatron_tpu.aot import registry as aot_registry
 
 
 def force_cpu_world(n_devices: int) -> None:
-    """Simulate an ``n_devices``-wide CPU platform (``cli warmup
-    --force_world``; the elastic child's sim-world bootstrap delegates
-    here): programmatic XLA_FLAGS append + platform pin — env vars alone
-    are overridden where a sitecustomize pre-imports jax.  Must run before
-    the first backend touch; permanently redirects this process to CPU."""
+    """Simulate an ``n_devices``-wide CPU platform — the ONE copy of the
+    recipe (``cli warmup --force_world``, the elastic child's sim-world
+    bootstrap, the multi-chip dry run's child and tests/conftest.py all call
+    it): XLA_FLAGS append + platform pin, which wins over ``JAX_PLATFORMS``
+    in the environment.  Must run before the first backend touch;
+    permanently redirects this process to CPU."""
     import jax
 
     flag = f"--xla_force_host_platform_device_count={int(n_devices)}"
@@ -166,7 +166,7 @@ def compile_program(
             compiled = lowered.compile()
             report["compile_ms"] = round((time.perf_counter() - t1) * 1000.0, 1)
     except Exception as e:  # noqa: BLE001 — per-program isolation IS the contract
-        # e.g. this container's protobuf pipeline-compile crash: warn, move on
+        # e.g. a program the backend's compiler refuses: warn, move on
         report["status"] = "failed"
         report["error"] = f"{type(e).__name__}: {str(e)[:300]}"
         if report["compile_ms"] is None:

@@ -531,25 +531,26 @@ def _serve_warmup(ns, engine, service, listening) -> None:
     (the first request pays it) but never blocks readiness forever."""
     listening.wait(timeout=60.0)
     try:
-        if getattr(ns, "compile_cache_dir", None):
-            # resolved like the trainer flag: '0'/'off'/'none' disables
-            from galvatron_tpu.aot import warmup as aot_warmup
-            from galvatron_tpu.aot.cache import (
-                ArtifactStore,
-                enable_persistent_cache,
-                resolve_compile_cache_dir,
-            )
+        # the persistent cache is always placed like the trainer's
+        # (aot/cache.resolve_compile_cache_dir); the flag arms the AOT warm
+        # start of the pinned programs, and its '0'/'off'/'none' disables
+        from galvatron_tpu.aot import warmup as aot_warmup
+        from galvatron_tpu.aot.cache import (
+            ArtifactStore,
+            enable_persistent_cache,
+            resolve_compile_cache_dir,
+        )
 
-            serve_cache_dir = resolve_compile_cache_dir(ns)
-            if serve_cache_dir:
-                eff = enable_persistent_cache(serve_cache_dir, override=True)
-                reports = engine.warm_start(ArtifactStore(eff))
-                s = aot_warmup.summarize(reports)
-                print(
-                    f"serving warm-start: {s['compiled']}/{s['programs']} "
-                    f"programs ({s['hits']} cache hits, "
-                    f"{s['total_compile_ms']:.0f} ms)", flush=True,
-                )
+        eff = enable_persistent_cache(resolve_compile_cache_dir(ns))
+        print(f"compile cache: {eff or 'disabled'}", flush=True)
+        if getattr(ns, "compile_cache_dir", None) and eff:
+            reports = engine.warm_start(ArtifactStore(eff))
+            s = aot_warmup.summarize(reports)
+            print(
+                f"serving warm-start: {s['compiled']}/{s['programs']} "
+                f"programs ({s['hits']} cache hits, "
+                f"{s['total_compile_ms']:.0f} ms)", flush=True,
+            )
         # the first scheduler iteration: an AOT lower/compile populates the
         # persistent cache but not the jit call cache — one real request
         # proves the engine serves before /readyz says so
@@ -568,7 +569,7 @@ def _warmup_mode(ns) -> int:
 
     Per-plan and per-program failure isolation: a plan that fails static
     validation is skipped with its diagnostics, a program that fails to
-    compile (this container's protobuf pipeline-compile class) degrades to
+    compile (a kernel or plan the backend's compiler refuses) degrades to
     a warning — the sweep itself never aborts.  rc 0 when at least one
     program compiled (or reported a hit), else 1."""
     from galvatron_tpu.aot import warmup as aot_warmup
@@ -587,20 +588,14 @@ def _warmup_mode(ns) -> int:
     from galvatron_tpu.core.arguments import model_config_from_args
     from galvatron_tpu.core.strategy import HybridParallelConfig
 
-    # same sentinel rules as train/serve: '0'/'off'/'none' disables the
-    # persistent layer — the sweep still compiles (a compile-only run is a
-    # legitimate memory-feasibility check) but persists and accounts nothing
+    # same placement and sentinel rules as train/serve: '0'/'off'/'none'
+    # disables the persistent layer — the sweep still compiles (a
+    # compile-only run is a legitimate memory-feasibility check) but
+    # persists and accounts nothing
     wdir = resolve_compile_cache_dir(ns)
-    if wdir is None and not ns.compile_cache_dir:
-        # nothing wired anywhere (no flag, no JAX_COMPILATION_CACHE_DIR, no
-        # configured jax cache): default to ./.jax_cache. A default that
-        # lived on the argparse flag instead would SHADOW the operator's
-        # env wiring — warming a cache no later run consults. An explicit
-        # 0/off/none sentinel keeps the sweep compile-only.
-        wdir = os.path.abspath(".jax_cache")
     store = None
     if wdir:
-        eff = enable_persistent_cache(wdir, override=True)
+        eff = enable_persistent_cache(wdir)
         store = ArtifactStore(eff)
         print(f"compile cache: {eff}")
     else:

@@ -203,10 +203,10 @@ def _add_training_args(p: argparse.ArgumentParser):
                    "(aot/cache.py): startup AOT-compiles every registered "
                    "program, accounts plan-keyed hit/miss in the manifest, "
                    "and a warm start shrinks the watchdog's first-step "
-                   "compile grace. Default: an already-configured jax cache "
-                   "(JAX_COMPILATION_CACHE_DIR / conftest) or the .jax_cache "
-                   "sibling of --save, consulted only when this flag is "
-                   "passed explicitly; '0'/'off'/'none' disables")
+                   "compile grace. JAX's persistent cache itself is always "
+                   "on: at JAX_COMPILATION_CACHE_DIR when that is set "
+                   "(whatever this flag says), else here, else "
+                   "<repo>/.jax_cache; '0'/'off'/'none' disables")
     # checkpoint/resume (capability the reference only gestures at; SURVEY §5)
     g.add_argument("--data_path", type=str, default=None,
                    help="corpus prefix: a sharded manifest "
@@ -489,7 +489,9 @@ def _add_generate_args(p: argparse.ArgumentParser):
                    help="serve: persistent compile cache (aot/cache.py); the "
                    "engine warm-starts its two pinned programs before "
                    "accepting traffic, so a restarted server's first request "
-                   "pays a cache deserialize, not two XLA compiles")
+                   "pays a cache deserialize, not two XLA compiles. The "
+                   "cache is placed as for train (JAX_COMPILATION_CACHE_DIR "
+                   "> this flag > <repo>/.jax_cache)")
     # SLO burn-rate engine (obs/slo.py). Deliberately NOT fleet-only flags:
     # serve-fleet forwards them verbatim to every replica, so the router
     # (availability/deadline from dispatch outcomes) and the replicas
@@ -603,8 +605,8 @@ def _add_warmup_args(p: argparse.ArgumentParser):
     g.add_argument("--compile_cache_dir", type=str, default=None,
                    help="persistent compile-artifact cache directory (the "
                    "manifest with hit/miss accounting lives beside jax's "
-                   "cache entries); unset = JAX_COMPILATION_CACHE_DIR / an "
-                   "already-configured jax cache, else ./.jax_cache "
+                   "cache entries). JAX_COMPILATION_CACHE_DIR, when set, "
+                   "wins over this flag; unset = <repo>/.jax_cache "
                    "('0'/'off'/'none' disables persistence)")
     g.add_argument("--report", type=str, default=None,
                    help="write the per-program JSONL report (compile_ms, "

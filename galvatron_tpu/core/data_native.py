@@ -10,43 +10,23 @@ megatron/data/helpers.cpp sample/shuffle index builders.
 from __future__ import annotations
 
 import ctypes
-import subprocess
-from pathlib import Path
 from typing import Optional
 
 import numpy as np
 
-_REPO_ROOT = Path(__file__).resolve().parents[2]
-_SRC = _REPO_ROOT / "csrc" / "data_helpers.cpp"
-_BUILD_DIR = _REPO_ROOT / "build"
-_SO = _BUILD_DIR / "libgalvatron_data_helpers.so"
+from galvatron_tpu.utils.native_build import load_native
 
 _lib: Optional[ctypes.CDLL] = None
 _load_failed = False
 
 
-def _build() -> bool:
-    _BUILD_DIR.mkdir(exist_ok=True)
-    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", str(_SRC), "-o", str(_SO)]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        return True
-    except Exception:
-        return False
-
-
 def get_data_helpers() -> Optional[ctypes.CDLL]:
     global _lib, _load_failed
-    if _lib is not None:
-        return _lib
-    if _load_failed:
-        return None
-    try:
-        if not _SO.exists() or _SO.stat().st_mtime < _SRC.stat().st_mtime:
-            if not _build():
-                _load_failed = True
-                return None
-        lib = ctypes.CDLL(str(_SO))
+    if _lib is None and not _load_failed:
+        lib = load_native("data_helpers")
+        if lib is None:
+            _load_failed = True
+            return None
         lib.galvatron_shuffle_index.restype = None
         lib.galvatron_shuffle_index.argtypes = [
             ctypes.c_int64,
@@ -54,10 +34,7 @@ def get_data_helpers() -> Optional[ctypes.CDLL]:
             np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS"),
         ]
         _lib = lib
-        return _lib
-    except Exception:
-        _load_failed = True
-        return None
+    return _lib
 
 
 def _splitmix64_np(x: np.ndarray) -> np.ndarray:

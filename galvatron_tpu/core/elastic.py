@@ -245,7 +245,7 @@ def _prewarm_plan(ns, plan_path: str, verbose: bool = True) -> Optional[Dict[str
         if getattr(ns, "pack_sequences", 0):
             cfg = cfg.replace(pack_sequences=True)
         cfg = resolve_execution_config(cfg, ns)
-        store = ArtifactStore(enable_persistent_cache(cache_dir, override=True))
+        store = ArtifactStore(enable_persistent_cache(cache_dir))
         # train_step only: a re-planned child RESUMES (restore, never init),
         # and eval_loss belongs to `cli warmup` — the step program is the
         # whole first-step compile the restart would otherwise pay

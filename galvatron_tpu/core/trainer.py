@@ -270,28 +270,38 @@ def _train_impl(ns: argparse.Namespace, verbose: bool, tracer,
         "xla_overlap": getattr(ns, "xla_overlap", "off"),
         "xla_overlap_flags": list(getattr(ns, "xla_overlap_applied", []) or []),
     }
+    # JAX's persistent compile cache is always on, at the one place
+    # resolve_compile_cache_dir names (JAX_COMPILATION_CACHE_DIR, else an
+    # explicit --compile_cache_dir, else <repo>/.jax_cache; the flag's
+    # 0/off/none sentinel wires none).
     # AOT compile subsystem (galvatron_tpu/aot; DESIGN.md § AOT compile
-    # subsystem): an explicit --compile_cache_dir arms the startup consult —
-    # enable the shared persistent cache, AOT-compile the programs THIS run
-    # will dispatch (always train_step; init_state only when a fresh init is
-    # coming — a resume never calls it, and eval_loss belongs to `cli
-    # warmup`, not a train run), and account plan-keyed hit/miss in the
-    # artifact manifest. Running BEFORE restore/init means the init compile
-    # below is already a cache deserialize, the loop's first step pays no
-    # XLA compile, and a proven-warm start shrinks the watchdog's
-    # first-step compile grace to the normal deadline. Without the flag the
-    # subsystem stays out of the way entirely (an already-configured jax
-    # cache keeps working; no manifest, no extra lowering).
+    # subsystem): an explicit --compile_cache_dir also arms the startup
+    # consult — AOT-compile the programs THIS run will dispatch (always
+    # train_step; init_state only when a fresh init is coming — a resume
+    # never calls it, and eval_loss belongs to `cli warmup`, not a train
+    # run), and account plan-keyed hit/miss in the artifact manifest.
+    # Running BEFORE restore/init means the init compile below is already a
+    # cache deserialize, the loop's first step pays no XLA compile, and a
+    # proven-warm start shrinks the watchdog's first-step compile grace to
+    # the normal deadline. Without the flag there is no manifest and no
+    # extra lowering.
+    from galvatron_tpu.aot.cache import (
+        ArtifactStore,
+        enable_persistent_cache,
+        resolve_compile_cache_dir,
+    )
+
+    aot_dir = resolve_compile_cache_dir(ns)
+    try:
+        cache_dir = enable_persistent_cache(aot_dir)
+    except OSError as e:  # read-only mount: costs only warmth
+        cache_dir = aot_dir = None
+        print(f"warning: compile cache unavailable ({e}); compiling cold")
+    if verbose:
+        print(f"compile cache: {cache_dir or 'disabled'}")
     aot_warm_hint = False
     aot_summ = None
     if getattr(ns, "compile_cache_dir", None):
-        from galvatron_tpu.aot.cache import (
-            ArtifactStore,
-            enable_persistent_cache,
-            resolve_compile_cache_dir,
-        )
-
-        aot_dir = resolve_compile_cache_dir(ns)
         # best-effort by contract, like the elastic prewarm: a cache-
         # infrastructure failure (read-only mount, torn store) costs only
         # warmth — the run must still train cold
@@ -303,9 +313,7 @@ def _train_impl(ns: argparse.Namespace, verbose: bool, tracer,
                 include = ["train_step"]
                 if not will_restore and hf_params is None:
                     include.append("init_state")
-                store = ArtifactStore(
-                    enable_persistent_cache(aot_dir, override=True)
-                )
+                store = ArtifactStore(cache_dir)
                 t0_warm = time.perf_counter()
                 aot_reports = aot_warmup.warmup_runtime(
                     rt, ns.global_train_batch_size, seq, store=store,
@@ -1285,6 +1293,9 @@ def _train_impl(ns: argparse.Namespace, verbose: bool, tracer,
         "losses": losses,
         "iter_ms": prof.avg_iter_ms if prof.iter_times_ms else None,
         "state": state,
+        # the runtime the steps ran on (chip_smoke.py reads its compiled
+        # step program and mesh)
+        "runtime": rt,
         # the elastic child maps this to EXIT_PREEMPTED: a signal-stop run
         # completed nothing abnormal, but the supervisor must restart it.
         # A notice-file drain (no signal delivered) reports its reason in

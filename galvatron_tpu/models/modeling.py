@@ -30,7 +30,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 from jax.ad_checkpoint import checkpoint_name
 
-from galvatron_tpu import compat
 import jax.numpy as jnp
 import numpy as np
 
@@ -849,7 +848,7 @@ def _flash_shard_map(cfg: ModelConfig, fn, arg_dims, out_dims):
         in_specs = tuple(spec(d, a.ndim) for d, a in zip(arg_dims, args))
         out_shape = jax.eval_shape(fn, *args)
         am = ambient_or(mesh)
-        return compat.shard_map(
+        return jax.shard_map(
             fn, mesh=am, in_specs=in_specs,
             out_specs=spec(out_dims, len(out_shape.shape)),
             axis_names=manual_axis_names(am), check_vma=False,

@@ -58,11 +58,12 @@ def peak_flops_per_device(override_tflops: float = 0.0) -> Optional[float]:
         try:
             return float(env) * 1e12
         except ValueError:
-            pass
-    try:
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:
-        return None
+            # a peak someone set on purpose and mistyped must not fall back
+            # to the table in silence: every MFU after it would be wrong
+            raise ValueError(
+                f"GALVATRON_PEAK_TFLOPS={env!r} is not a number (TFLOP/s)"
+            ) from None
+    kind = jax.devices()[0].device_kind.lower()
     for key, tf in _PEAK_TFLOPS_BY_KIND:
         if key in kind:
             return tf * 1e12

@@ -48,7 +48,6 @@ from typing import Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from galvatron_tpu import compat
 
 
 def tp_group_size(mesh, tp_axes: Sequence[str]) -> int:
@@ -150,7 +149,7 @@ def allgather_einsum(
         return out
 
     am = ambient_or(mesh)
-    return compat.shard_map(
+    return jax.shard_map(
         local_fn,
         mesh=am,
         in_specs=(spec(x_sub, x_entries), w_spec),
@@ -238,7 +237,7 @@ def einsum_reducescatter(
         return acc
 
     am = ambient_or(mesh)
-    return compat.shard_map(
+    return jax.shard_map(
         local_fn,
         mesh=am,
         in_specs=(spec(x_sub, x_entries), w_spec),

@@ -46,26 +46,18 @@ def _use_interpret() -> bool:
 _VMEM_LIMIT_MB = int(os.environ.get("GALVATRON_FLASH_VMEM_MB", "64"))
 
 
-# jax < 0.6 spells the Mosaic params class TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
-
 def _compiler_params(**kw):
     if _VMEM_LIMIT_MB:
         kw.setdefault("vmem_limit_bytes", _VMEM_LIMIT_MB << 20)
-    return _CompilerParams(**kw)
+    return pltpu.CompilerParams(**kw)
 
 
 def _single_buffered(shape, index_map) -> pl.BlockSpec:
-    """BlockSpec pinned to single-buffering where pallas supports it
-    (pl.Buffered, jax >= 0.6); older pallas falls back to Mosaic's default
-    double-buffering — a VMEM-budget optimization only, numerics identical
-    (the raised vmem_limit_bytes still covers the measured shapes there)."""
-    if hasattr(pl, "Buffered"):
-        return pl.BlockSpec(
-            shape, index_map, pipeline_mode=pl.Buffered(buffer_count=1)
-        )
-    return pl.BlockSpec(shape, index_map)
+    """BlockSpec pinned to single-buffering — a VMEM-budget optimization
+    only, numerics identical to Mosaic's default double-buffering."""
+    return pl.BlockSpec(
+        shape, index_map, pipeline_mode=pl.Buffered(buffer_count=1)
+    )
 
 
 def _rope_rows(x, c, s):

@@ -33,7 +33,6 @@ from typing import List, Optional, Sequence, Tuple
 
 import jax
 
-from galvatron_tpu import compat
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -258,9 +257,9 @@ def ambient_or(mesh):
     must be given the ambient AbstractMesh — whose manual axes are marked
     Manual — not the original concrete mesh, or tracing fails with an
     axis-type mismatch. Load-bearing for every cp impl (ring/a2a) at pp>1."""
-    am = compat.get_abstract_mesh()
+    am = jax.sharding.get_abstract_mesh()
     types = getattr(am, "axis_types", None) or ()
-    if any(t == compat.AxisType.Manual for t in types):
+    if any(t == jax.sharding.AxisType.Manual for t in types):
         return am
     return mesh
 
@@ -277,6 +276,6 @@ def manual_axis_names(am) -> set:
     types = getattr(am, "axis_types", None) or ()
     manual = {
         n for n, t in zip(am.axis_names, types)
-        if t != compat.AxisType.Manual
+        if t != jax.sharding.AxisType.Manual
     }
     return manual or set(am.axis_names)

@@ -48,7 +48,6 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 
-from galvatron_tpu import compat
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -585,7 +584,7 @@ def build_pipeline_runtime(
     # full-batch spec for embedding/head compute: batch over pp + all data axes
     full_spec = P(("pp",) + axes.data_axes, None, None)
 
-    pipe_sm = compat.shard_map(
+    pipe_sm = jax.shard_map(
         pipe,
         mesh=mesh,
         # stage params: pp-stacked; x_mbs (and packed seg_mbs) replicated

@@ -30,7 +30,6 @@ from typing import Any, Dict
 
 import jax
 
-from galvatron_tpu import compat
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -246,7 +245,7 @@ def make_1f1b_train_step(
             carry["dx_embed"][None, :chunks],
         )
 
-    body_sm = compat.shard_map(
+    body_sm = jax.shard_map(
         pipeline_body,
         mesh=mesh,
         in_specs=(P("pp"), P(), P(), P(), P(), P()) if packed
@@ -295,7 +294,7 @@ def make_1f1b_train_step(
         carry, _ = jax.lax.scan(tick, carry0, jnp.arange(chunks + pp - 1))
         return carry["loss_sum"][None], carry["tok"][None]
 
-    eval_sm = compat.shard_map(
+    eval_sm = jax.shard_map(
         eval_body,
         mesh=mesh,
         in_specs=(P("pp"), P(), P(), P(), P()) if packed else (P("pp"), P(), P(), P()),

@@ -34,7 +34,6 @@ from typing import Any, Dict, List, Tuple
 
 import jax
 
-from galvatron_tpu import compat
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -446,7 +445,7 @@ def build_encdec_pipeline_runtime(
         carry, _ = jax.lax.scan(tick, carry0, jnp.arange(T))
         return carry["ys"][None, :chunks]
 
-    pipe_sm = compat.shard_map(
+    pipe_sm = jax.shard_map(
         pipeline,
         mesh=mesh,
         in_specs=(P("pp"), P("pp"), P(), P(), P()),
@@ -683,7 +682,7 @@ def build_encdec_pipeline_runtime(
             carry["dxd"][None, :chunks],
         )
 
-    body_1f1b_sm = compat.shard_map(
+    body_1f1b_sm = jax.shard_map(
         pipeline_body_1f1b,
         mesh=mesh,
         in_specs=(P("pp"), P("pp"), P(), P(), P(), P(), P(), P()),

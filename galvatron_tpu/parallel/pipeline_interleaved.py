@@ -32,7 +32,6 @@ from typing import Any, List
 
 import jax
 
-from galvatron_tpu import compat
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -386,7 +385,7 @@ def make_interleaved_1f1b_train_step(
             carry["dx_embed"][None, :chunks],
         )
 
-    body_sm = compat.shard_map(
+    body_sm = jax.shard_map(
         pipeline_body,
         mesh=mesh,
         in_specs=(P("pp"), P(), P(), P(), P()),
@@ -441,7 +440,7 @@ def make_interleaved_1f1b_train_step(
         carry, _ = jax.lax.scan(tick, carry0, jnp.arange(vpp * chunks + pp - 1))
         return carry["loss_sum"][None], carry["tok"][None]
 
-    eval_sm = compat.shard_map(
+    eval_sm = jax.shard_map(
         eval_body,
         mesh=mesh,
         in_specs=(P("pp"), P(), P(), P()),

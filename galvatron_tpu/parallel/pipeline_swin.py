@@ -36,7 +36,6 @@ from typing import Any, Dict, List
 
 import jax
 
-from galvatron_tpu import compat
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -351,7 +350,7 @@ def build_swin_pipeline_runtime(
         carry, _ = jax.lax.scan(tick, carry0, jnp.arange(T))
         return carry["ys"][None, :chunks]
 
-    pipe_sm = compat.shard_map(
+    pipe_sm = jax.shard_map(
         pipeline,
         mesh=mesh,
         in_specs=(P("pp"), P(), P()),
@@ -553,7 +552,7 @@ def build_swin_pipeline_runtime(
             carry["dxe"][None, :chunks],
         )
 
-    body_1f1b_sm = compat.shard_map(
+    body_1f1b_sm = jax.shard_map(
         pipeline_body_1f1b,
         mesh=mesh,
         in_specs=(P("pp"), P(), P(), P(), P(), P()),

@@ -32,7 +32,6 @@ from typing import Sequence
 
 import jax
 
-from galvatron_tpu import compat
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
@@ -290,7 +289,7 @@ def ring_attention(
     # while plain data sharding over the cp axes works — same linearization
     # as ppermute over the axis tuple
     idx_arr = jnp.arange(cp, dtype=jnp.int32)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(spec, spec, spec, P(axis)),

@@ -27,7 +27,6 @@ from typing import Any, Optional, Sequence, Tuple
 
 import jax
 
-from galvatron_tpu import compat
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from galvatron_tpu.core.strategy import LayerStrategy
@@ -112,7 +111,7 @@ def constrain(x, mesh: Mesh, spec: P):
     on the tracing context's AbstractMesh (whose manual axes are typed
     Manual); the concrete mesh's sharding would be rejected in the
     transpose/grad path."""
-    am = compat.get_abstract_mesh()
+    am = jax.sharding.get_abstract_mesh()
     target = am if (am is not None and not am.empty) else mesh
     return jax.lax.with_sharding_constraint(x, NamedSharding(target, spec))
 

@@ -23,7 +23,6 @@ from typing import Sequence
 
 import jax
 
-from galvatron_tpu import compat
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -75,7 +74,7 @@ def ulysses_attention(
     axis = tuple(cp_axes)
     spec = P(tuple(batch_axes) or None, axis, tuple(head_axes) or None, None)
     mesh = ambient_or(mesh)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         functools.partial(_a2a_attn_local, cfg=cfg, axis_name=axis, cp=cp),
         mesh=mesh,
         in_specs=(spec, spec, spec),

@@ -23,7 +23,6 @@ from typing import Dict, Optional, Sequence
 
 import jax
 
-from galvatron_tpu import compat
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -96,7 +95,7 @@ def profile_allreduce(
                 y = jax.lax.with_sharding_constraint(
                     x, NamedSharding(mesh, P(axes.data_axes))
                 )
-                return compat.shard_map(
+                return jax.shard_map(
                     lambda v: jax.lax.psum(v, group),
                     mesh=mesh,
                     in_specs=P(axes.data_axes),
@@ -130,7 +129,7 @@ def profile_p2p(
 
         @jax.jit
         def send(x, mesh=mesh, perm=perm):
-            return compat.shard_map(
+            return jax.shard_map(
                 lambda v: jax.lax.ppermute(v, "pp", perm),
                 mesh=mesh,
                 in_specs=P("pp"),
@@ -160,7 +159,7 @@ def profile_overlap_coe(mesh: Mesh, axes: MeshAxes, size_mb: float = 64.0) -> fl
             a = a @ a * 0.01
         return a
 
-    sm = lambda f: compat.shard_map(
+    sm = lambda f: jax.shard_map(
         f, mesh=mesh, in_specs=P(axes.data_axes), out_specs=P(axes.data_axes),
         axis_names=set(axes.data_axes) | {axes.pp}, check_vma=False,
     )

@@ -11,45 +11,24 @@ galvatron/core/dynamic_programming.py:98-128).
 from __future__ import annotations
 
 import ctypes
-import os
-import subprocess
-from pathlib import Path
 from typing import Optional
 
 import numpy as np
 
-_REPO_ROOT = Path(__file__).resolve().parents[2]
-_SRC = _REPO_ROOT / "csrc" / "dp_core.cpp"
-_BUILD_DIR = _REPO_ROOT / "build"
-_SO = _BUILD_DIR / "libgalvatron_dp_core.so"
+from galvatron_tpu.utils.native_build import load_native
 
 _lib: Optional[ctypes.CDLL] = None
 _load_failed = False
 
 
-def _build() -> bool:
-    _BUILD_DIR.mkdir(exist_ok=True)
-    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", str(_SRC), "-o", str(_SO)]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        return True
-    except Exception:
-        return False
-
-
 def get_dp_core() -> Optional[ctypes.CDLL]:
     """Returns the loaded library or None (→ NumPy fallback)."""
     global _lib, _load_failed
-    if _lib is not None:
-        return _lib
-    if _load_failed:
-        return None
-    try:
-        if not _SO.exists() or _SO.stat().st_mtime < _SRC.stat().st_mtime:
-            if not _build():
-                _load_failed = True
-                return None
-        lib = ctypes.CDLL(str(_SO))
+    if _lib is None and not _load_failed:
+        lib = load_native("dp_core")
+        if lib is None:
+            _load_failed = True
+            return None
         lib.galvatron_dp_core.restype = ctypes.c_double
         lib.galvatron_dp_core.argtypes = [
             ctypes.c_int32, ctypes.c_int32, ctypes.c_int32,
@@ -60,10 +39,7 @@ def get_dp_core() -> Optional[ctypes.CDLL]:
             ctypes.POINTER(ctypes.c_int32),
         ]
         _lib = lib
-        return _lib
-    except Exception:
-        _load_failed = True
-        return None
+    return _lib
 
 
 def dp_core_native(mem: np.ndarray, intra: np.ndarray, inter: np.ndarray, budget: int):

@@ -43,10 +43,10 @@ def tmp_cache(tmp_path):
     warm-cache timing must not be collateral."""
     old = getattr(jax.config, "jax_compilation_cache_dir", None)
     d = str(tmp_path / "aot_cache")
-    aot_cache.enable_persistent_cache(d, override=True)
+    aot_cache.enable_persistent_cache(d)
     yield d
     if old:
-        aot_cache.enable_persistent_cache(old, min_compile_time_s=0.5, override=True)
+        aot_cache.enable_persistent_cache(old, min_compile_time_s=0.5)
 
 
 # ---------------------------------------------------------------------------
@@ -165,20 +165,33 @@ def test_resolve_compile_cache_dir_precedence(tmp_path, monkeypatch):
         save = None
 
     ns = NS()
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-    # jax.config already carries the suite's cache dir → that wins
-    configured = aot_cache.resolve_compile_cache_dir(ns)
-    assert configured == os.path.abspath(jax.config.jax_compilation_cache_dir)
-    # explicit flag wins over everything; the disable spellings disable
+    # nothing given: the one fixed path inside the checkout, --save or not
+    assert aot_cache.resolve_compile_cache_dir(ns) == os.path.join(repo, ".jax_cache")
+    ns.save = str(tmp_path / "ckpt")
+    assert aot_cache.resolve_compile_cache_dir(ns) == os.path.join(repo, ".jax_cache")
+    # without the env an explicit flag places it; the disable spellings disable
     ns.compile_cache_dir = str(tmp_path / "x")
     assert aot_cache.resolve_compile_cache_dir(ns) == str(tmp_path / "x")
     for off in ("0", "off", "none"):
         ns.compile_cache_dir = off
         assert aot_cache.resolve_compile_cache_dir(ns) is None
-    # env beats the configured dir
+    # the env places the cache from outside: it beats the default AND the flag
+    envd = str(tmp_path / "envd")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", envd)
     ns.compile_cache_dir = None
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "envd"))
-    assert aot_cache.resolve_compile_cache_dir(ns) == str(tmp_path / "envd")
+    assert aot_cache.resolve_compile_cache_dir(ns) == envd
+    ns.compile_cache_dir = str(tmp_path / "x")
+    assert aot_cache.resolve_compile_cache_dir(ns) == envd
+    # ... and enable_persistent_cache will not redirect away from it either
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        assert aot_cache.enable_persistent_cache(str(tmp_path / "x")) == envd
+        assert jax.config.jax_compilation_cache_dir == envd
+    finally:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        aot_cache.enable_persistent_cache(prev)
 
 
 # ---------------------------------------------------------------------------

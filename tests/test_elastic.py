@@ -224,6 +224,25 @@ def test_supervisor_gives_up_without_progress(tmp_path, child_env):
     assert all(e["mode"] == "crash" for e in evs if e["event"] == "child_exit")
 
 
+def test_supervisor_never_touches_a_backend(tmp_path, child_env):
+    """One process for each chip: a parent that has touched JAX holds the
+    chip and its children then fail or hang.  The supervisor runs under a
+    platform name no backend answers to, so ANY backend touch on its side
+    (``jax.devices()``, ``default_backend()``, a stray jit) is an error;
+    the children bootstrap their own simulated world and train normally."""
+    env = dict(os.environ, JAX_PLATFORMS="no_such_platform")
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    env[SIM_WORLD_ENV] = "8"
+    proc = subprocess.run(
+        [sys.executable, "-m", "galvatron_tpu.cli", "run-elastic"] + TINY
+        + ["--train_iters", "2", "--save", str(tmp_path / "ck"), "--max_restarts", "0"],
+        env=env, cwd=REPO, timeout=240,
+        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
+    )
+    assert proc.returncode == 0, proc.stdout[-3000:]
+    assert "completed after 1 attempt(s), 0 restart(s)" in proc.stdout
+
+
 # ---------------------------------------------------------------------------
 # supervisor decision matrix (in-process spawn stub — no subprocesses)
 # ---------------------------------------------------------------------------

@@ -234,6 +234,10 @@ def test_peak_flops_env_override(monkeypatch):
     assert stepstats.peak_flops_per_device() == 123.5e12
     # explicit override wins over env
     assert stepstats.peak_flops_per_device(2.0) == 2.0e12
+    # a malformed override is an error, not a silent fall-through to the table
+    monkeypatch.setenv("GALVATRON_PEAK_TFLOPS", "197 TFLOPs")
+    with pytest.raises(ValueError, match="GALVATRON_PEAK_TFLOPS"):
+        stepstats.peak_flops_per_device()
     monkeypatch.delenv("GALVATRON_PEAK_TFLOPS")
     # CPU device kind is unknown → None, never a made-up denominator
     assert stepstats.peak_flops_per_device() is None
@@ -514,18 +518,11 @@ def test_traced_training_exports_nested_spans_and_mfu(tmp_path, monkeypatch):
 def test_traced_pp_training_has_stage_spans(tmp_path):
     """Under a pipeline schedule the timeline carries synthetic per-stage
     per-microbatch spans (the schedule clock model rendered onto the measured
-    step). Skipped where this container cannot compile CPU-sim pipelines
-    (the repeated-field compiler_options limitation — same family as the
-    seed-failing pipeline tests)."""
+    step)."""
     trace = str(tmp_path / "pp.trace.json")
-    try:
-        _train(["--train_iters", "3", "--pp_deg", "2", "--chunks", "2",
-                "--pipeline_type", "pipedream_flush", "--trace_spans", trace],
-               verbose=False)
-    except RuntimeError as e:
-        if "Protocol Buffer" in str(e) or "xla_disable_hlo_passes" in str(e):
-            pytest.skip("CPU-sim pipeline compile unavailable on this jax build")
-        raise
+    _train(["--train_iters", "3", "--pp_deg", "2", "--chunks", "2",
+            "--pipeline_type", "pipedream_flush", "--trace_spans", trace],
+           verbose=False)
     doc = json.load(open(trace))
     stage_spans = [e for e in doc["traceEvents"]
                    if e["ph"] == "X" and e["name"].startswith("stage")]

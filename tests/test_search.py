@@ -43,6 +43,29 @@ def brute_force(mem, intra, inter, V):
     return best, best_choice
 
 
+
+def test_native_build_keyed_by_source_content(tmp_path, monkeypatch, capsys):
+    """build/ is untracked: a fresh tree compiles from csrc/ on first use,
+    staleness is the .cpp's content hash (in the file name) and never its
+    mtime, and a failed build says so on stderr instead of failing silently."""
+    import hashlib
+    import os
+
+    from galvatron_tpu.utils import native_build
+
+    monkeypatch.setattr(native_build, "_BUILD_DIR", tmp_path / "build")
+    assert native_build.load_native("dp_core") is not None
+    (so,) = (tmp_path / "build").glob("libgalvatron_dp_core.*.so")
+    src = native_build._REPO_ROOT / "csrc" / "dp_core.cpp"
+    assert hashlib.sha256(src.read_bytes()).hexdigest()[:16] in so.name
+    os.utime(so, (0, 0))  # older than the source: a copy resets mtimes
+    assert native_build.load_native("dp_core") is not None
+    assert so.stat().st_mtime == 0, "rebuilt on mtime"
+    monkeypatch.setattr(native_build, "_REPO_ROOT", tmp_path)  # no csrc/ here
+    assert native_build.load_native("dp_core") is None
+    assert "native dp_core unavailable" in capsys.readouterr().err
+
+
 def test_native_core_builds():
     assert get_dp_core() is not None, "C++ DP core failed to build/load"
 
