@@ -384,11 +384,7 @@ def _overlap_step_ms(cfg, hp, bsz, seq, iters):
 
 def _overlap_pair(cfg, hp_off, hp_on, metric, bsz, seq, iters, **tags):
     """Time the paired off/on arms and emit one metric line: value = the
-    overlap-ON step time, extras carry the off arm, the delta, and (when the
-    device peak is known) the bubble fraction of each arm — the number the
-    overlap work is supposed to move DOWN."""
-    from galvatron_tpu.obs.stepstats import StepStats
-
+    overlap-ON step time, extras carry the off arm and the delta."""
     off_ms, off_loss = _overlap_step_ms(cfg, hp_off, bsz, seq, iters)
     on_ms, on_loss = _overlap_step_ms(cfg, hp_on, bsz, seq, iters)
     extra = dict(tags)
@@ -400,11 +396,6 @@ def _overlap_pair(cfg, hp_off, hp_on, metric, bsz, seq, iters, **tags):
         # same data, so their losses agree to dtype tolerance
         loss_abs_diff=round(abs(off_loss - on_loss), 6),
     )
-    for name, hp, ms in (("off", hp_off, off_ms), ("on", hp_on, on_ms)):
-        stat = StepStats(cfg, bsz, seq, hp=hp).per_iter(ms)
-        if stat.get("bubble_fraction") is not None:
-            extra[f"bubble_fraction_{name}"] = stat["bubble_fraction"]
-            extra[f"comm_wait_ms_{name}"] = stat["comm_wait_ms"]
     emit(metric, round(on_ms, 4), "ms", **extra)
     return {"on_ms": on_ms, "off_ms": off_ms, **extra}
 

@@ -446,7 +446,7 @@ def run_elastic(
         print(f"elastic supervisor sidecar: http://127.0.0.1:{obs_server.port}/healthz")
         # child train-gauge aggregation: the child logs train_iter JSONL to
         # --metrics_path and the sidecar tails the last 64KB at scrape time
-        # (prom.ElasticStats.child_train_gauges) — mfu/bubble/tokens_per_s
+        # (prom.ElasticStats.child_train_gauges) — mfu/tokens_per_s
         # survive on the supervisor's scrape target across child restarts
         # with no IPC and no second port. A user-passed --metrics_path is
         # honored; otherwise one is injected beside the checkpoints.

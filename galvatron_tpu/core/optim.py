@@ -82,6 +82,7 @@ def apply_update_with_scaler(state, loss, grads, adam: "AdamConfig", scaler_cfg)
     }, loss
 
 
+@jax.named_scope("optimizer")
 def adamw_update(params, grads, opt_state, cfg: AdamConfig, lr_scale=1.0):
     """One AdamW step in fp32 master precision; returns (params, opt_state)."""
     count = opt_state["count"] + 1

@@ -231,10 +231,11 @@ def _train_impl(ns: argparse.Namespace, verbose: bool, tracer,
         from galvatron_tpu.parallel.mesh import build_mesh
 
         mesh, axes = build_mesh(pp=hp.pp, num_slices=ns.num_slices)
-    rt = build_runtime(
-        cfg, hp, mesh=mesh, axes=axes, adam=adam,
-        global_batch_size=ns.global_train_batch_size, seq_len=seq,
-    )
+    with tracer.span("build_runtime"):
+        rt = build_runtime(
+            cfg, hp, mesh=mesh, axes=axes, adam=adam,
+            global_batch_size=ns.global_train_batch_size, seq_len=seq,
+        )
 
     from galvatron_tpu.obs import tracing as obs_tracing
     from galvatron_tpu.utils.metrics import SCHEMA_VERSION, MetricsLogger
@@ -517,7 +518,9 @@ def _train_impl(ns: argparse.Namespace, verbose: bool, tracer,
         if verbose:
             print(f"initialized from HF checkpoint {ns.load_hf}")
     else:
-        state = rt.init_state(jax.random.key(ns.seed))
+        with tracer.span("init_state") as init_sp:
+            # traced: the span closes on the realized state, not the dispatch
+            state = init_sp.sync(rt.init_state(jax.random.key(ns.seed)))
 
     # start_batch fast-forwards by index arithmetic so resume sees the batches
     # an uninterrupted run would (reference has no resume at all); the offset
@@ -536,32 +539,33 @@ def _train_impl(ns: argparse.Namespace, verbose: bool, tracer,
             "--prefetch_depth. Resume with the original data flags, or point "
             "--load elsewhere."
         )
-    if use_data_pipe:
-        # production input path (galvatron_tpu/data/): sharded corpora,
-        # deterministic mixture, sequence packing, async device prefetch.
-        # The pipeline applies rt.shard_batch itself (on the prefetch thread
-        # when armed), so the loop's data span is a dequeue. A restored
-        # checkpoint's per-source cursor is verified against the rebuilt
-        # schedule — a changed mixture fails loudly instead of silently
-        # replaying or skipping samples.
-        from galvatron_tpu.data import build_data_pipeline
+    with tracer.span("data_open"):
+        if use_data_pipe:
+            # production input path (galvatron_tpu/data/): sharded corpora,
+            # deterministic mixture, sequence packing, async device prefetch.
+            # The pipeline applies rt.shard_batch itself (on the prefetch thread
+            # when armed), so the loop's data span is a dequeue. A restored
+            # checkpoint's per-source cursor is verified against the rebuilt
+            # schedule — a changed mixture fails loudly instead of silently
+            # replaying or skipping samples.
+            from galvatron_tpu.data import build_data_pipeline
 
-        data_pipe = build_data_pipeline(
-            cfg, ns.global_train_batch_size, seq, seed=ns.seed,
-            start_batch=batch_offset,
-            data_path=getattr(ns, "data_path", None),
-            mixture=getattr(ns, "data_mixture", None),
-            pack=cfg.pack_sequences,
-            prefetch_depth=getattr(ns, "prefetch_depth", 0),
-            put_fn=rt.shard_batch,
-            resume_state=saved_data_state,
-        )
-        loader = iter(data_pipe)
-    else:
-        loader = build_dataloader(
-            cfg, ns.global_train_batch_size, seq, seed=ns.seed, start_batch=batch_offset,
-            data_path=getattr(ns, "data_path", None),
-        )
+            data_pipe = build_data_pipeline(
+                cfg, ns.global_train_batch_size, seq, seed=ns.seed,
+                start_batch=batch_offset,
+                data_path=getattr(ns, "data_path", None),
+                mixture=getattr(ns, "data_mixture", None),
+                pack=cfg.pack_sequences,
+                prefetch_depth=getattr(ns, "prefetch_depth", 0),
+                put_fn=rt.shard_batch,
+                resume_state=saved_data_state,
+            )
+            loader = iter(data_pipe)
+        else:
+            loader = build_dataloader(
+                cfg, ns.global_train_batch_size, seq, seed=ns.seed, start_batch=batch_offset,
+                data_path=getattr(ns, "data_path", None),
+            )
     from galvatron_tpu.core.signals import GracefulExitHandler
 
     # per-iter host syncs (float(loss) every step) serialize dispatch with
@@ -654,6 +658,12 @@ def _train_impl(ns: argparse.Namespace, verbose: bool, tracer,
         pw = ProfilerWindow(
             trace_dir or tempfile.mkdtemp(prefix="galvatron_profile_"), a, b
         )
+
+    def _log_profile_window(rec):
+        # where the window went and which steps it covers: without
+        # --trace_dir it is a mkdtemp nothing else names
+        if rec:
+            metrics.log("profile_window", **rec)
     # pipeline schedules run inside ONE jitted scan — per-stage activity is
     # rendered from the schedule's structural clock model instead
     # (obs/tracing.emit_tick_spans; spans are labeled synthetic)
@@ -919,15 +929,15 @@ def _train_impl(ns: argparse.Namespace, verbose: bool, tracer,
                 if trace_dir and pw is None and not trace_started and iters_run >= 1:
                     jax.profiler.start_trace(trace_dir)
                     trace_started = True
+                    tracer.profiling = True
                 if pw is not None:
                     # stop is checked at the loop TOP (previous iteration's
                     # index) so an anomaly-skip `continue` cannot carry the
                     # window past its STOP boundary; the run-end close lives
                     # in the finally below
-                    pw.maybe_stop(it - 1, verbose=verbose)
+                    _log_profile_window(pw.maybe_stop(it - 1, verbose=verbose))
                     pw.maybe_start(it)
-                step_sp = tracer.span("step", step=it)
-                with _watchdog_step(it), step_sp:
+                with _watchdog_step(it), tracer.span("step", step=it):
                     if rampup is not None:
                         bs = rampup(consumed)
                         if bs != cur_bs or it == batch_offset:
@@ -1056,11 +1066,6 @@ def _train_impl(ns: argparse.Namespace, verbose: bool, tracer,
                         if metrics.path or train_obs is not None
                         else {}
                     )
-                    if stat.get("comm_wait_ms") is not None:
-                        step_sp.set(
-                            comm_wait_ms=stat["comm_wait_ms"],
-                            bubble_fraction=stat["bubble_fraction"],
-                        )
                     # step-time drift vs the plan's prediction: the signed
                     # ratio the re-planner (ROADMAP item 2) and the drift
                     # SLO both consume
@@ -1101,10 +1106,6 @@ def _train_impl(ns: argparse.Namespace, verbose: bool, tracer,
                             train_obs.tflops_per_device = stat.get("tflops_per_device")
                             train_obs.mfu = stat.get("mfu")
                             train_obs.hfu = stat.get("hfu")
-                            train_obs.comm_wait_ms = stat.get("comm_wait_ms")
-                            train_obs.bubble_fraction = stat.get(
-                                "bubble_fraction"
-                            )
                             train_obs.packing_efficiency = stat.get(
                                 "packing_efficiency"
                             )
@@ -1180,8 +1181,11 @@ def _train_impl(ns: argparse.Namespace, verbose: bool, tracer,
         # rob the crash path of its emergency checkpoint below, nor mask
         # the original training exception
         if pw is not None:
-            pw.close(verbose=verbose)
+            if pw.active:
+                pw.last_step = batch_offset + iters_run - 1
+            _log_profile_window(pw.close(verbose=verbose))
         if trace_started:
+            tracer.profiling = False
             try:
                 jax.profiler.stop_trace()
                 if verbose:

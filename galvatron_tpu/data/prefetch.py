@@ -5,7 +5,11 @@ The trainer's input path was fully synchronous: assemble batch k on the host,
 moves assembly + transfer to a background thread: while step k runs on the
 device, the thread builds batch k+1 and calls ``put_fn`` (the runtime's
 ``shard_batch`` — ``jax.device_put`` onto the train step's input shardings),
-so the trainer's ``data`` span collapses to a bounded-queue dequeue.
+so the trainer's ``data`` span collapses to a bounded-queue dequeue. With
+the tracer on, each batch the producer builds and puts is a ``data_produce``
+span on the producer's own track (``batch``: its index since this prefetcher
+started; no ``step``: the producer runs ahead of the loop), so the data layer
+has time busy beside the loop's time waited.
 
 Correctness rules:
 
@@ -28,6 +32,8 @@ from __future__ import annotations
 import queue
 import threading
 from typing import Any, Callable, Optional, Tuple
+
+from galvatron_tpu.obs.tracing import tracer
 
 _STOP = object()
 
@@ -60,9 +66,12 @@ class AsyncPrefetcher:
 
     def _run(self) -> None:
         try:
+            built = 0
             while not self._stop.is_set():
-                host_batch, meta = self._make_item()
-                item = (self._put_fn(host_batch), meta)
+                with tracer.span("data_produce", batch=built):
+                    host_batch, meta = self._make_item()
+                    item = (self._put_fn(host_batch), meta)
+                built += 1
                 # the host buffer reference is dropped here — nothing can
                 # mutate it behind the in-flight device_put
                 del host_batch

@@ -630,15 +630,16 @@ def norm(x, p, cfg: ModelConfig):
     in the backward from the compute-dtype input — without the wrap, autodiff
     saves an fp32-widened (B, S, H) copy of every normed activation (the
     round-5 HLO buffer audit's 67 MB/layer class)."""
-    if cfg.fused_norm:
-        from galvatron_tpu.ops import fused_norm
+    with jax.named_scope("norm"):
+        if cfg.fused_norm:
+            from galvatron_tpu.ops import fused_norm
 
-        if cfg.norm_type == "rms":
-            return fused_norm.fused_rmsnorm(x, p["scale"], cfg.norm_eps)
-        return fused_norm.fused_layernorm(x, p["scale"], p["bias"], cfg.norm_eps)
-    if cfg.mlp_recompute == "policy":
-        return jax.checkpoint(lambda x_, p_: _norm_impl(x_, p_, cfg))(x, p)
-    return _norm_impl(x, p, cfg)
+            if cfg.norm_type == "rms":
+                return fused_norm.fused_rmsnorm(x, p["scale"], cfg.norm_eps)
+            return fused_norm.fused_layernorm(x, p["scale"], p["bias"], cfg.norm_eps)
+        if cfg.mlp_recompute == "policy":
+            return jax.checkpoint(lambda x_, p_: _norm_impl(x_, p_, cfg))(x, p)
+        return _norm_impl(x, p, cfg)
 
 
 def rope_tables(cfg: ModelConfig, seq_len: int, offset: int = 0):
@@ -954,11 +955,23 @@ def _attn_block_headmajor(x, p, cfg: ModelConfig, rope, remat_attn: bool):
     hd = cfg.head_dim
     n = cfg.num_heads
     w = p["wqkv"].astype(x.dtype)
+
+    def out_proj(o):
+        with jax.named_scope("out_proj"):
+            y = _proj_down(
+                "bnsd,nde->bse", o, p["wo"].astype(x.dtype).reshape(n, hd, h),
+                cfg, w_shard_dim=0,
+            )
+            if "wo_b" in p:
+                y = y + p["wo_b"].astype(x.dtype)
+            return y
+
     if cfg.qkv_blocked:
-        qkv = _proj_up("bsh,hcnd->bcnsd", x, w.reshape(h, 3, n, hd), cfg, w_shard_dim=2)
-        if "wqkv_b" in p:
-            qkv = qkv + p["wqkv_b"].astype(x.dtype).reshape(3, n, hd)[None, :, :, None, :]
-        qkv = _constrain_qkv(qkv, cfg)
+        with jax.named_scope("qkv_proj"):
+            qkv = _proj_up("bsh,hcnd->bcnsd", x, w.reshape(h, 3, n, hd), cfg, w_shard_dim=2)
+            if "wqkv_b" in p:
+                qkv = qkv + p["wqkv_b"].astype(x.dtype).reshape(3, n, hd)[None, :, :, None, :]
+            qkv = _constrain_qkv(qkv, cfg)
         if flash_qkv_supported(s, hd, cfg.causal, rope):
             # the kernels consume the STACKED projection output directly —
             # index-mapped block specs instead of q/k/v slice copies
@@ -974,19 +987,15 @@ def _attn_block_headmajor(x, p, cfg: ModelConfig, rope, remat_attn: bool):
 
             if remat_attn:
                 core_qkv = jax.checkpoint(core_qkv)
-            o = _constrain_attn_out(core_qkv(qkv), cfg)
-            y = _proj_down(
-                "bnsd,nde->bse", o, p["wo"].astype(x.dtype).reshape(n, hd, h),
-                cfg, w_shard_dim=0,
-            )
-            if "wo_b" in p:
-                y = y + p["wo_b"].astype(x.dtype)
-            return y
+            with jax.named_scope("attn_core"):
+                o = _constrain_attn_out(core_qkv(qkv), cfg)
+            return out_proj(o)
         q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
     else:
         kv, group = qkv_dims(cfg)
         npg = group // hd - 2  # query heads per kv group, per the stored layout
-        r = jnp.einsum("bsh,hknd->bknsd", x, w.reshape(h, kv, npg + 2, hd))
+        with jax.named_scope("qkv_proj"):
+            r = jnp.einsum("bsh,hknd->bknsd", x, w.reshape(h, kv, npg + 2, hd))
         q = r[:, :, :npg].reshape(b, n, s, hd)
         # GQA-NATIVE: K/V stay at kv_heads — the flash kernels serve each kv
         # group's queries from the resident grouped block (flash_attention_hm
@@ -1030,16 +1039,12 @@ def _attn_block_headmajor(x, p, cfg: ModelConfig, rope, remat_attn: bool):
 
     if remat_attn:
         core = jax.checkpoint(core)
-    o = _constrain_attn_out(core(q, k, v), cfg)
-    y = _proj_down(
-        "bnsd,nde->bse", o, p["wo"].astype(x.dtype).reshape(n, hd, h),
-        cfg, w_shard_dim=0,
-    )
-    if "wo_b" in p:
-        y = y + p["wo_b"].astype(x.dtype)
-    return y
+    with jax.named_scope("attn_core"):
+        o = _constrain_attn_out(core(q, k, v), cfg)
+    return out_proj(o)
 
 
+@jax.named_scope("attn")
 def attn_block(x, p, cfg: ModelConfig, cos_sin=None, alibi=None, remat_attn: bool = False,
                seg_ids=None):
     """``remat_attn`` rematerializes only the attention core (scores/softmax/
@@ -1062,7 +1067,8 @@ def attn_block(x, p, cfg: ModelConfig, cos_sin=None, alibi=None, remat_attn: boo
             return _attn_block_headmajor(x, p, cfg, rope, remat_attn)
     # one fused qkv GEMM (~2 ms/layer-batch over three narrow matmuls on the
     # v5e 7B-shape bench); layout per qkv_dims/qkv_project
-    q, k, v = project_qkv_heads(x, p, cfg)
+    with jax.named_scope("qkv_proj"):
+        q, k, v = project_qkv_heads(x, p, cfg)
     rope = cos_sin if cfg.pos_embed == "rope" else None
     bias = None
     if cfg.pos_embed == "alibi":
@@ -1075,10 +1081,13 @@ def attn_block(x, p, cfg: ModelConfig, cos_sin=None, alibi=None, remat_attn: boo
 
     if remat_attn:
         core = jax.checkpoint(core)
-    o = _constrain_attn_out(core(q, k, v, bias, seg_ids), cfg)
-    return attn_output(o, p, cfg, x.dtype)
+    with jax.named_scope("attn_core"):
+        o = _constrain_attn_out(core(q, k, v, bias, seg_ids), cfg)
+    with jax.named_scope("out_proj"):
+        return attn_output(o, p, cfg, x.dtype)
 
 
+@jax.named_scope("mlp")
 def mlp_block(x, p, cfg: ModelConfig, train: bool = True):
     """SwiGLU or GeLU MLP (reference: ParallelMLP, galvatron/core/
     tensor_parallel/transformer.py:78-159); switch-MoE when moe_experts > 0
@@ -1160,8 +1169,12 @@ def mlp_residual(x, p, cfg: ModelConfig, train: bool = True):
         # unnamed — a nested per-norm checkpoint would only add bookkeeping.
         # fused_norm layers keep the plain branch (the Pallas kernels carry
         # their own custom-VJP residuals the policy cannot reach).
+        def normed(x_, pn_):
+            with jax.named_scope("norm"):
+                return _norm_impl(x_, pn_, cfg)
+
         branch = jax.checkpoint(
-            lambda x_, pn_, pm_: mlp_block(_norm_impl(x_, pn_, cfg), pm_, cfg, train=train),
+            lambda x_, pn_, pm_: mlp_block(normed(x_, pn_), pm_, cfg, train=train),
             policy=jax.checkpoint_policies.save_only_these_names("mlp_gate"),
         )
         return x + branch(x, p["mlp_norm"], p["mlp"])
@@ -1207,6 +1220,7 @@ def decoder_layer(
     return mlp_residual(x, p, cfg)
 
 
+@jax.named_scope("embed")
 def embed(tokens, params, cfg: ModelConfig, pos_ids=None):
     """``pos_ids`` ((B, S), packed sequences): learned positions gathered by
     per-segment position ids instead of the ``arange(S)`` slice — each packed
@@ -1268,12 +1282,14 @@ def forward(params, tokens, cfg: ModelConfig, layer_hook=None):
     hook_kw = {"seg_ids": seg} if seg is not None else {}
     x = embed(tokens, params, cfg, pos_ids=pos_ids)
     for i, lp in enumerate(params["layers"]):
-        if layer_hook is not None:
-            x = layer_hook(i, x, lp, **hook_kw)
-        else:
-            x = decoder_layer(x, lp, cfg, cos_sin, alibi, seg_ids=seg)
-    x = norm(x, params["final_norm"], cfg)
-    return lm_head(x, params, cfg)
+        with jax.named_scope(f"layer_{i}"):
+            if layer_hook is not None:
+                x = layer_hook(i, x, lp, **hook_kw)
+            else:
+                x = decoder_layer(x, lp, cfg, cos_sin, alibi, seg_ids=seg)
+    with jax.named_scope("head"):
+        x = norm(x, params["final_norm"], cfg)
+        return lm_head(x, params, cfg)
 
 
 def forward_encdec(params, enc_tokens, dec_tokens, cfg: ModelConfig, layer_hook=None):
@@ -1467,11 +1483,12 @@ def cross_entropy_sum(logits, labels, ignore_index: int = -100, remat: bool = Fa
     the compute-dtype logits instead of letting autodiff save the fp32-widened
     (B, S, V/vocab_tp) copy — the "cast at the consumer" rule; loss-carrying
     callers pass ``cfg.mlp_recompute == 'policy'``."""
-    if remat:
-        return jax.checkpoint(
-            partial(_cross_entropy_sum_impl, ignore_index=ignore_index)
-        )(logits, labels)
-    return _cross_entropy_sum_impl(logits, labels, ignore_index)
+    with jax.named_scope("loss"):
+        if remat:
+            return jax.checkpoint(
+                partial(_cross_entropy_sum_impl, ignore_index=ignore_index)
+            )(logits, labels)
+        return _cross_entropy_sum_impl(logits, labels, ignore_index)
 
 
 def cross_entropy_loss(logits, labels, ignore_index: int = -100):
@@ -1560,9 +1577,9 @@ def ce_remat(cfg: ModelConfig) -> bool:
 def head_loss_sum(y, params, labels, cfg: ModelConfig):
     """Final-norm'd features (B, S, H) → (nll_sum, count): LM head + token
     cross entropy, or pooled classification head + class cross entropy."""
-    if cfg.objective == "cls":
-        return cross_entropy_sum(cls_head(y, params, cfg), labels, remat=ce_remat(cfg))
-    return cross_entropy_sum(lm_head(y, params, cfg), labels, remat=ce_remat(cfg))
+    with jax.named_scope("head"):
+        logits = cls_head(y, params, cfg) if cfg.objective == "cls" else lm_head(y, params, cfg)
+    return cross_entropy_sum(logits, labels, remat=ce_remat(cfg))
 
 
 def loss_tokens_per_sample(cfg: ModelConfig, seq_len: int) -> int:

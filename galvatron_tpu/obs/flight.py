@@ -13,17 +13,34 @@ Profiler capture: ``--profile_steps A:B`` (trainer) and ``POST
 full XLA op/kernel timeline for exactly the steps asked for, instead of the
 whole-run ``--trace_dir`` firehose. Backends without xprof support degrade
 to a logged warning: profiling is an observation, never a crash source.
+While a window is open the tracer's spans also land in the profiler's trace
+(``tracer.profiling``), and where the last window went is kept:
+``last_profile_window()`` returns it, the trainer logs it as a
+``profile_window`` record.
 """
 
 from __future__ import annotations
 
+import glob
 import json
 import os
 import re
 import time
 from typing import Any, Callable, Dict, Optional, Tuple
 
+from galvatron_tpu.obs.tracing import tracer
+
 FLIGHT_SCHEMA = "galvatron-flight-v1"
+
+_last_window: Optional[Dict[str, Any]] = None
+
+
+def last_profile_window() -> Optional[Dict[str, Any]]:
+    """The last ``ProfilerWindow`` this process closed: ``{trace_dir, xplane,
+    start_step, stop_step, first_step, last_step}`` (``xplane``: the
+    ``.xplane.pb`` it wrote, None when the backend left none; ``first_step``
+    .. ``last_step``: the iterations it really covered). None before any."""
+    return dict(_last_window) if _last_window else None
 
 
 def dump_flight(
@@ -113,6 +130,7 @@ class ProfilerWindow:
         self.active = False
         self.failed = False
         self.done = False
+        self.first_step = self.last_step = None
 
     def maybe_start(self, it: int) -> None:
         # >= not ==: a resumed run whose batch offset already passed START
@@ -127,23 +145,34 @@ class ProfilerWindow:
 
             jax.profiler.start_trace(self.trace_dir)
             self.active = True
+            self.first_step = it
+            tracer.profiling = True
         except Exception as e:  # noqa: BLE001 — degrade, don't crash training
             self.failed = True
             print(f"--profile_steps: backend lacks profiler support ({e!r}); "
                   "continuing without capture")
 
-    def maybe_stop(self, it: int, verbose: bool = True) -> None:
-        if not self.active or it + 1 < self.stop_step:
-            return
-        self.close(verbose=verbose)
-
-    def close(self, verbose: bool = True) -> None:
-        """Idempotent stop — also called from the trainer ``finally`` so a
-        crash inside the window cannot wedge process-wide profiler state."""
+    def maybe_stop(self, it: int, verbose: bool = True) -> Optional[Dict[str, Any]]:
+        """Closes the window once iteration ``it`` (the last one run) reaches
+        STOP; returns what ``close`` returns."""
         if not self.active:
-            return
+            return None
+        self.last_step = it
+        if it + 1 < self.stop_step:
+            return None
+        return self.close(verbose=verbose)
+
+    def close(self, verbose: bool = True) -> Optional[Dict[str, Any]]:
+        """Idempotent stop — also called from the trainer ``finally`` so a
+        crash inside the window cannot wedge process-wide profiler state.
+        Returns the window's record (see ``last_profile_window``) the one
+        time it closes an open window, else None."""
+        global _last_window
+        if not self.active:
+            return None
         self.active = False
         self.done = True
+        tracer.profiling = False
         try:
             import jax
 
@@ -153,6 +182,14 @@ class ProfilerWindow:
                       f"→ {self.trace_dir}")
         except Exception as e:  # noqa: BLE001
             print(f"failed to close profiler window: {e!r}")
+        found = sorted(glob.glob(
+            os.path.join(self.trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
+        _last_window = {
+            "trace_dir": self.trace_dir, "xplane": found[-1] if found else None,
+            "start_step": self.start_step, "stop_step": self.stop_step,
+            "first_step": self.first_step, "last_step": self.last_step,
+        }
+        return dict(_last_window)
 
 
 def capture_profile(

@@ -459,11 +459,6 @@ class TrainStats:
         self.anomaly_skips = 0
         self.checkpoints_saved = 0
         self.packing_efficiency: Optional[float] = None
-        # comm/compute overlap accounting (obs/stepstats.per_iter; DESIGN.md
-        # "Overlap"): per-step non-compute exposure — the numbers the overlap
-        # work (collective-matmul, grad_overlap, --xla_overlap) must move
-        self.comm_wait_ms: Optional[float] = None
-        self.bubble_fraction: Optional[float] = None
         # AOT compile subsystem (galvatron_tpu/aot): startup warmup accounting
         self.compile_cache_hits: Optional[int] = None
         self.compile_cache_misses: Optional[int] = None
@@ -495,13 +490,6 @@ class TrainStats:
         out.add("train_packing_efficiency", self.packing_efficiency,
                 help_="non-pad fraction of packed input rows (None-skipped "
                 "when sequence packing is off)")
-        out.add("train_comm_wait_ms", self.comm_wait_ms,
-                help_="per-step time above the hardware-FLOPs ideal — "
-                "collective exposure + launch gaps (read as a paired "
-                "overlap-on/off delta, not an absolute)")
-        out.add("train_bubble_fraction", self.bubble_fraction,
-                help_="fraction of the step spent off the MXUs (1 - "
-                "ideal_ms/iter_ms); decreases when overlap is on")
         out.add("train_compile_cache_hits", self.compile_cache_hits,
                 mtype="counter",
                 help_="startup AOT warmup programs served warm from the "
@@ -627,7 +615,6 @@ class ElasticStats:
         out.add("elastic_child_iter_ms", rec.get("iter_ms"))
         out.add("elastic_child_mfu", rec.get("mfu"),
                 help_="child trainer's model FLOPs utilization")
-        out.add("elastic_child_bubble_fraction", rec.get("bubble_fraction"))
         out.add("elastic_child_tokens_per_s", rec.get("tokens_per_s"))
         out.add("elastic_child_step_time_drift", rec.get("step_time_drift"),
                 help_="child's predicted-vs-observed step-time drift (the "

@@ -17,10 +17,11 @@ Two FLOPs totals, following the PaLM convention:
   the MLP branch when ``mlp_recompute`` is ``gate``/``policy`` (PR 3's
   policy replays the gate product + fp32 norm statistics in backward).
 
-Attention-core FLOPs use the full ``s x s`` matmul pair (no causal-mask
-discount), matching Megatron's accounting. MoE layers are priced at the
-dense per-token cost of one expert (top-1 switch routing); router compute
-is ignored.
+Attention-core FLOPs count the pairs the mask keeps: the causal half,
+``s (s + 1) / 2`` of ``s x s``, when the model is causal (what the flash
+kernels compute, and what ``benchmark/lib/flops.py`` counts), the full square
+otherwise. MoE layers are priced at the dense per-token cost of one expert
+(top-1 switch routing); router compute is ignored.
 """
 
 from __future__ import annotations
@@ -78,9 +79,10 @@ def attn_proj_flops_per_token(cfg: ModelConfig) -> float:
 
 
 def attn_core_flops_per_token(cfg: ModelConfig, seq_len: int) -> float:
-    """q@k^T and p@v for one token against ``seq_len`` keys (full square,
-    no causal discount — Megatron's convention)."""
-    return 2.0 * 2.0 * seq_len * cfg.hidden_size
+    """q@k^T and p@v for one token against the keys its mask keeps: on
+    average ``(seq_len + 1) / 2`` under a causal mask, ``seq_len`` without."""
+    keys = (seq_len + 1) / 2.0 if cfg.causal else float(seq_len)
+    return 2.0 * 2.0 * keys * cfg.hidden_size
 
 
 def mlp_flops_per_token(cfg: ModelConfig) -> float:
@@ -182,7 +184,6 @@ class StepStats:
             out: Dict[str, Optional[float]] = {
                 "tokens_per_s": None, "tflops_per_device": None,
                 "mfu": None, "hfu": None,
-                "comm_wait_ms": None, "bubble_fraction": None,
             }
             if nonpad_tokens is not None:
                 out["tokens_per_s_raw"] = None
@@ -204,25 +205,11 @@ class StepStats:
         if nonpad_tokens is not None:
             out["tokens_per_s_raw"] = round(raw_tokens / s, 3)
             out["packing_efficiency"] = round(useful_frac, 6)
-        # comm-wait / bubble accounting (DESIGN.md "Overlap"): the host tracer
-        # cannot see device-side collective stalls, so the aggregate is
-        # derived — ideal_ms is the step's hardware-FLOPs time at peak, and
-        # everything above it is non-compute (collective exposure, launch
-        # gaps, stragglers). Absolute values lean on the analytic FLOPs model;
-        # what the overlap work reads is the paired on/off DELTA on a fixed
-        # shape, where the model error cancels. None on unknown peaks (CPU).
-        out["comm_wait_ms"] = None
-        out["bubble_fraction"] = None
         if self._peak:
             denom = self._peak * self.num_devices
             out["mfu"] = round(flops_rate / denom, 6)
             out["hfu"] = round(
                 useful_frac * scale * self.hardware_flops_per_step / s / denom, 6
-            )
-            ideal_ms = scale * self.hardware_flops_per_step / denom * 1000.0
-            out["comm_wait_ms"] = round(max(0.0, iter_ms - ideal_ms), 3)
-            out["bubble_fraction"] = round(
-                max(0.0, 1.0 - ideal_ms / iter_ms), 6
             )
         return out
 

@@ -13,6 +13,19 @@ first-class subsystem. This module is the host half of that layer:
   ``tracer.span`` returns a no-op singleton without reading the clock.
 - ``chrome_trace(spans)`` / ``export_chrome_trace(path)`` — Chrome
   trace-event JSON (the format Perfetto and chrome://tracing load).
+- one instrumentation point, two sinks: while a ``jax.profiler`` window is
+  open (``tracer.profiling``, set by ``obs/flight.ProfilerWindow`` and the
+  trainer's whole-run ``--trace_dir`` capture) every span also opens a
+  ``jax.profiler.TraceAnnotation`` of its name, and the trainer's ``step``
+  span a ``StepTraceAnnotation("train", step_num=...)`` — the host's rows
+  land on the ``python`` line of the profiler's own ``.xplane.pb``, on the
+  clock the device's operations are on.
+- what the runtime does behind the loop's back, as spans (tracer ON only):
+  ``jax_trace`` / ``jax_lower`` / ``jax_compile`` from the ``jax.monitoring``
+  duration events (every trace, lowering, backend compile or cache load —
+  ``jax_compile`` carries ``hit`` and ``retrieval_s``), and ``gc`` from
+  ``gc.callbacks``. Each carries the ``step`` of the span open on its
+  thread, so a compile or a collection inside a training step names it.
 - synthetic schedule spans (``emit_tick_spans``) — pipeline schedules run
   inside ONE jitted clocked scan, so no host probe can observe per-tick
   activity; instead the schedule's exact clock model (the same index
@@ -30,6 +43,7 @@ search engine, and serving engine all record into — enable it once
 
 from __future__ import annotations
 
+import gc
 import json
 import os
 import threading
@@ -65,11 +79,24 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+#: collections shorter than this leave no ``gc`` span: a trace-and-lower makes
+#: hundreds of young-generation passes of ~0.1 ms that explain no slow step
+#: and would push what does out of the ring
+GC_SPAN_MIN_S = 1e-3
+
+
+def _annotation(name: str, args: Dict[str, Any]):
+    """The span's twin in the profiler's trace. The trainer's ``step`` span
+    is the profiler's step boundary too (xprof groups device work by it)."""
+    if name == "step" and "step" in args:
+        return jax.profiler.StepTraceAnnotation("train", step_num=args["step"])
+    return jax.profiler.TraceAnnotation(name)
+
 
 class Span:
     """One live span; records itself into the tracer ring on ``__exit__``."""
 
-    __slots__ = ("_tracer", "name", "args", "_t0", "_tid", "_tname", "_synced")
+    __slots__ = ("_tracer", "name", "args", "_t0", "_tid", "_tname", "_synced", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any]):
         self._tracer = tracer
@@ -77,12 +104,16 @@ class Span:
         self.args = args
         self._t0 = 0.0
         self._synced = False
+        self._ann = None
         t = threading.current_thread()
         self._tid = t.ident or 0
         self._tname = t.name
 
     def __enter__(self):
-        self._tracer._stack_for_thread().append(self.name)
+        self._tracer._stack_for_thread().append(self)
+        if self._tracer.profiling:
+            self._ann = _annotation(self.name, self.args)
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -100,6 +131,8 @@ class Span:
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         stack = self._tracer._stack_for_thread()
         if stack:
             stack.pop()
@@ -133,6 +166,8 @@ class Tracer:
 
     def __init__(self, capacity: int = 4096):
         self.enabled = False
+        #: a jax.profiler window is open: spans also open TraceAnnotations
+        self.profiling = False
         self._ring: deque = deque(maxlen=capacity)
         self._local = threading.local()
         self._epoch_pc = time.perf_counter()
@@ -143,11 +178,19 @@ class Tracer:
     def enable(self, capacity: Optional[int] = None) -> "Tracer":
         if capacity is not None and capacity != self._ring.maxlen:
             self._ring = deque(self._ring, maxlen=max(16, capacity))
+        if self is tracer:
+            # the process's hooks feed the process-wide tracer alone
+            if not self.enabled:
+                gc.callbacks.append(self._on_gc)
+            _install_jax_listeners()
         self.enabled = True
         return self
 
     def disable(self) -> "Tracer":
+        if self.enabled and self is tracer:
+            gc.callbacks.remove(self._on_gc)
         self.enabled = False
+        self.profiling = False
         return self
 
     def clear(self) -> None:
@@ -184,11 +227,58 @@ class Tracer:
         # path; snapshot() copies defensively for readers
         self._ring.append(rec)
 
-    def _stack_for_thread(self) -> List[str]:
+    def _stack_for_thread(self) -> List[Span]:
         st = getattr(self._local, "stack", None)
         if st is None:
             st = self._local.stack = []
         return st
+
+    def current_step(self) -> Optional[int]:
+        """``step`` of the innermost span open on this thread that has one."""
+        for sp in reversed(self._stack_for_thread()):
+            if "step" in sp.args:
+                return sp.args["step"]
+        return None
+
+    def record_span(self, name: str, dur_s: float, **attrs) -> None:
+        """A span that ended now and lasted ``dur_s``, reported after the
+        fact (a ``jax.monitoring`` duration event, a finished collection).
+        Carries the ``step`` of the span open on this thread, if any."""
+        if not self.enabled:
+            return
+        step = self.current_step()
+        if step is not None:
+            attrs["step"] = step
+        t = threading.current_thread()
+        self._record(
+            {
+                "name": name,
+                "ph": "X",
+                "ts": self.pc_to_us(time.perf_counter() - dur_s),
+                "dur": dur_s * 1e6,
+                "tid": t.ident or 0,
+                "tname": t.name,
+                "depth": len(self._stack_for_thread()),
+                "args": attrs,
+            }
+        )
+
+    def _on_gc(self, phase: str, info: Dict[str, Any]) -> None:
+        # the collector runs on whichever thread tripped it, start and stop
+        # on the same one
+        if phase == "start":
+            self._local.gc_t0 = time.perf_counter()
+            return
+        t0 = getattr(self._local, "gc_t0", None)
+        if t0 is None:
+            return
+        self._local.gc_t0 = None
+        dur = time.perf_counter() - t0
+        if dur >= GC_SPAN_MIN_S:
+            self.record_span(
+                "gc", dur,
+                generation=info.get("generation"), collected=info.get("collected"),
+            )
 
     def pc_to_us(self, pc: float) -> float:
         return (pc - self._epoch_pc) * 1e6
@@ -215,6 +305,69 @@ class Tracer:
 
 #: the process-wide tracer every subsystem records into
 tracer = Tracer()
+
+
+# ---------------------------------------------------------------------------
+# jax.monitoring -> spans
+# ---------------------------------------------------------------------------
+
+#: duration events of jax 0.9 -> span names. They fire on a trace, a lowering
+#: and a backend compile (or its cache load) only: never in a warm step.
+_JAX_DURATION_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax_trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax_lower",
+    "/jax/core/compile/backend_compile_duration": "jax_compile",
+}
+_CACHE_HITS = "/jax/compilation_cache/cache_hits"
+_CACHE_MISSES = "/jax/compilation_cache/cache_misses"
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+#: a jitted function's trace holds one short trace for each jitted helper it
+#: calls (hundreds under a train step); those under this floor leave no span
+JAX_TRACE_SPAN_MIN_S = 1e-3
+_jax_listeners_installed = False
+# what the persistent cache said during the backend compile now running on
+# this thread; its events fire inside the compile's own duration event
+_compile_local = threading.local()
+
+
+def _on_jax_event(event: str, **_kw) -> None:
+    if not tracer.enabled:
+        return
+    if event == _CACHE_HITS:
+        _compile_local.hit = True
+    elif event == _CACHE_MISSES:
+        _compile_local.hit = False
+
+
+def _on_jax_duration(event: str, duration_secs: float, **kw) -> None:
+    if not tracer.enabled:
+        return
+    if event == _CACHE_RETRIEVAL:
+        _compile_local.retrieval_s = duration_secs
+        return
+    name = _JAX_DURATION_SPANS.get(event)
+    if name is None or (name == "jax_trace" and duration_secs < JAX_TRACE_SPAN_MIN_S):
+        return
+    attrs: Dict[str, Any] = {}
+    if kw.get("fun_name"):
+        attrs["fun_name"] = str(kw["fun_name"])
+    if name == "jax_compile":
+        # hit None: the persistent cache was not consulted for this program
+        attrs["hit"] = getattr(_compile_local, "hit", None)
+        attrs["retrieval_s"] = getattr(_compile_local, "retrieval_s", None)
+        _compile_local.hit = _compile_local.retrieval_s = None
+    tracer.record_span(name, float(duration_secs), **attrs)
+
+
+def _install_jax_listeners() -> None:
+    """Once per process, at the singleton's first ``enable()``; the listeners
+    stay registered and return at once while the tracer is off."""
+    global _jax_listeners_installed
+    if _jax_listeners_installed:
+        return
+    _jax_listeners_installed = True
+    jax.monitoring.register_event_listener(_on_jax_event)
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
 
 
 # ---------------------------------------------------------------------------

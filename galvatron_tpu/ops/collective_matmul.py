@@ -149,14 +149,15 @@ def allgather_einsum(
         return out
 
     am = ambient_or(mesh)
-    return jax.shard_map(
-        local_fn,
-        mesh=am,
-        in_specs=(spec(x_sub, x_entries), w_spec),
-        out_specs=spec(out_sub, out_entries),
-        axis_names=manual_axis_names(am),
-        check_vma=False,
-    )(x, w)
+    with jax.named_scope("allgather_einsum"):
+        return jax.shard_map(
+            local_fn,
+            mesh=am,
+            in_specs=(spec(x_sub, x_entries), w_spec),
+            out_specs=spec(out_sub, out_entries),
+            axis_names=manual_axis_names(am),
+            check_vma=False,
+        )(x, w)
 
 
 def einsum_reducescatter(
@@ -237,11 +238,12 @@ def einsum_reducescatter(
         return acc
 
     am = ambient_or(mesh)
-    return jax.shard_map(
-        local_fn,
-        mesh=am,
-        in_specs=(spec(x_sub, x_entries), w_spec),
-        out_specs=spec(out_sub, out_entries),
-        axis_names=manual_axis_names(am),
-        check_vma=False,
-    )(x, w)
+    with jax.named_scope("einsum_reducescatter"):
+        return jax.shard_map(
+            local_fn,
+            mesh=am,
+            in_specs=(spec(x_sub, x_entries), w_spec),
+            out_specs=spec(out_sub, out_entries),
+            axis_names=manual_axis_names(am),
+            check_vma=False,
+        )(x, w)

@@ -238,6 +238,7 @@ def _flash_fwd(q, k, v, rope, sm_scale, causal, block_q, block_k, interpret,
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="flash_fwd_grid",
     )(*inputs)
     return out, lse
 
@@ -464,6 +465,7 @@ def _flash_fwd_blocked(
                 dimension_semantics=("parallel", "parallel")
             ),
             interpret=interpret,
+            name="flash_fwd_qkv" if stacked else "flash_fwd_blocked",
         )(*inputs, cqs, sqs, cos, sin, tri)
         outs.append(out_i)
         lses.append(lse_i)
@@ -664,6 +666,7 @@ def _flash_bwd_blocked(
             dimension_semantics=("parallel", "parallel")
         ),
         interpret=interpret,
+        name="flash_bwd_blocked",
     )(*qkv_inputs, do, out, lse, cos, sin)
     return res[0] if do_stacked_out else tuple(res)
 
@@ -895,6 +898,7 @@ def _flash_bwd_parts(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(*dkv_inputs)
 
     qspec2 = pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, i, j: (b_, h_, i, 0))
@@ -919,6 +923,7 @@ def _flash_bwd_parts(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(*dq_inputs)
     return dq, dk, dv
 
@@ -1268,5 +1273,6 @@ def paged_decode_attention(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=_use_interpret(),
+        name="flash_paged_decode",
     )(block_tables.astype(jnp.int32), offsets, qg, k_pages, v_pages)
     return out.reshape(b, 1, n, d)

@@ -130,6 +130,7 @@ def _rms_fwd(x2d, scale, eps, interpret):
         ],
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="fused_norm_rms_fwd",
     )(x2d, scale.reshape(1, h))
     return y, r
 
@@ -158,6 +159,7 @@ def _rms_bwd(x2d, scale, r, dy2d, interpret):
         scratch_shapes=[pltpu.VMEM((8, h), jnp.float32)],
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="fused_norm_rms_bwd",
     )(x2d, scale.reshape(1, h), r, dy2d)
     return dx, dg_acc[0]
 
@@ -252,6 +254,7 @@ def _ln_fwd(x2d, scale, bias, eps, interpret):
         ],
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="fused_norm_ln_fwd",
     )(x2d, scale.reshape(1, h), bias.reshape(1, h))
 
 
@@ -284,6 +287,7 @@ def _ln_bwd(x2d, scale, mu, rstd, dy2d, interpret):
         ],
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="fused_norm_ln_bwd",
     )(x2d, scale.reshape(1, h), mu, rstd, dy2d)
     return dx, dg_acc[0], db_acc[0]
 

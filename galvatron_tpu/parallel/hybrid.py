@@ -184,7 +184,8 @@ def _make_layer_hook(cfg: ModelConfig, hp: HybridParallelConfig, mesh: Mesh, axe
 
     def hook(i: int, x, lp, enc_out=None, seg_ids=None):
         s = hp.layer_strategies[i]
-        x = constrain(x, mesh, activation_spec(axes, s))
+        with jax.named_scope("redistribute"):
+            x = constrain(x, mesh, activation_spec(axes, s))
         layer_cfg = cfg
         if s.ckpt == "full" and cfg.mlp_recompute != "off":
             # full-layer remat saves only the layer boundary — a nested
@@ -438,7 +439,8 @@ def build_runtime(
             jnp.zeros((), jnp.int32),
             jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params),
         )
-        (tot_s, tot_n, tot_g), _ = jax.lax.scan(body, zero, mbs)
+        with jax.named_scope("grad_accum"):
+            (tot_s, tot_n, tot_g), _ = jax.lax.scan(body, zero, mbs)
         denom = jnp.maximum(tot_n, 1).astype(jnp.float32)
         gdenom = denom if scale is None else denom * scale / n_static
         return tot_s / denom, jax.tree.map(lambda g: g / gdenom, tot_g)

@@ -172,7 +172,8 @@ def _grad_shard_fwd(x, mesh, spec):
 
 
 def _grad_shard_bwd(mesh, spec, _res, g):
-    return (constrain(g, mesh, spec),)
+    with jax.named_scope("grad_sync"):
+        return (constrain(g, mesh, spec),)
 
 
 _grad_shard.defvjp(_grad_shard_fwd, _grad_shard_bwd)

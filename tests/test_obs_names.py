@@ -1,0 +1,346 @@
+"""The names the program gives its work, and the tracer's second sink and
+process hooks (PR 25): kernel ``name=``s, ``jax.named_scope``s in the compiled
+step's ``op_name``s, ``TraceAnnotation``s while a profiler window is open, the
+``jax.monitoring`` and ``gc`` spans, and the trainer's set-up spans and
+``profile_window`` record.  All on the CPU; the chip's compiler sees the same
+names in ``tests/test_topology_aot.py``."""
+
+import ast
+import gc
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from galvatron_tpu.obs import flight, stepstats, tracing
+from galvatron_tpu.obs.tracing import _NULL_SPAN, tracer
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+#: kernel name -> file that holds its ``pl.pallas_call`` (PERF.md §3's table)
+KERNEL_NAMES = {
+    "flash_fwd_grid": "flash_attention.py", "flash_fwd_blocked": "flash_attention.py",
+    "flash_fwd_qkv": "flash_attention.py", "flash_bwd_blocked": "flash_attention.py",
+    "flash_bwd_dkv": "flash_attention.py", "flash_bwd_dq": "flash_attention.py",
+    "flash_paged_decode": "flash_attention.py",
+    "fused_norm_rms_fwd": "fused_norm.py", "fused_norm_rms_bwd": "fused_norm.py",
+    "fused_norm_ln_fwd": "fused_norm.py", "fused_norm_ln_bwd": "fused_norm.py",
+}
+
+
+def _pallas_call_names():
+    """{file: [names of each ``pl.pallas_call``'s literal ``name=``]}; a call
+    without one, or with one that is not made of string literals, fails."""
+    ops_dir = os.path.join(REPO, "galvatron_tpu", "ops")
+    found = {}
+    for fn in sorted(os.listdir(ops_dir)):
+        if not fn.endswith(".py"):
+            continue
+        tree = ast.parse(open(os.path.join(ops_dir, fn)).read())
+        for node in ast.walk(tree):
+            if not (isinstance(node, ast.Call) and isinstance(node.func, ast.Attribute)
+                    and node.func.attr == "pallas_call"):
+                continue
+            kw = {k.arg: k.value for k in node.keywords}
+            assert "name" in kw, f"{fn}:{node.lineno}: pl.pallas_call without name="
+            value = kw["name"]
+            literals = ([value.body, value.orelse] if isinstance(value, ast.IfExp) else [value])
+            for lit in literals:
+                assert isinstance(lit, ast.Constant) and isinstance(lit.value, str), (
+                    f"{fn}:{node.lineno}: name= is not a string literal")
+                found.setdefault(fn, []).append(lit.value)
+    return found
+
+
+@pytest.mark.parametrize("name", sorted(KERNEL_NAMES))
+def test_every_pallas_call_has_a_name_from_the_table(name):
+    found = _pallas_call_names()
+    assert name in found[KERNEL_NAMES[name]]
+    # and nothing outside the table: a new kernel joins it, with its metric
+    assert {n for names in found.values() for n in names} == set(KERNEL_NAMES)
+    assert all(n.startswith(("flash_fwd", "flash_bwd", "flash_paged", "fused_norm_"))
+               for n in KERNEL_NAMES)
+
+
+# ---------------------------------------------------------------------------
+# scopes in the compiled step
+# ---------------------------------------------------------------------------
+
+SCOPES = ("embed", "layer_0", "layer_1", "attn", "qkv_proj", "attn_core", "out_proj", "mlp",
+          "norm", "head", "loss", "optimizer", "grad_accum")
+
+
+@pytest.fixture(scope="module")
+def toy_step_text():
+    """Compiled text of a tiny two-layer train step with two micro-batches."""
+    from galvatron_tpu.core.checkpoint import abstract_state_of
+    from galvatron_tpu.core.optim import AdamConfig
+    from galvatron_tpu.core.strategy import HybridParallelConfig
+    from galvatron_tpu.models.modeling import ModelConfig
+    from galvatron_tpu.parallel.hybrid import build_runtime
+    from galvatron_tpu.parallel.mesh import build_mesh
+
+    cfg = ModelConfig(vocab_size=128, hidden_size=64, num_layers=2, num_heads=2,
+                      ffn_dim=128, max_seq_len=32)
+    hp = HybridParallelConfig.uniform(2, chunks=2, mixed_precision="fp32")
+    mesh, axes = build_mesh(pp=1, devices=jax.devices()[:1])
+    rt = build_runtime(cfg, hp, mesh=mesh, axes=axes, adam=AdamConfig(lr=1e-3),
+                       global_batch_size=4, seq_len=32)
+    batch = jax.ShapeDtypeStruct((4, 33), jnp.int32, sharding=rt.batch_sharding)
+    return rt.train_step.lower(abstract_state_of(rt), batch).compile().as_text()
+
+
+@pytest.mark.parametrize("scope", SCOPES)
+def test_compiled_step_carries_the_scope(toy_step_text, scope):
+    import re
+
+    names = re.findall(r'op_name="([^"]*)"', toy_step_text)
+    assert any(re.search(rf"[/(]{scope}[/)]", n) for n in names), scope
+
+
+def test_backward_is_marked_by_transpose(toy_step_text):
+    import re
+
+    names = re.findall(r'op_name="([^"]*)"', toy_step_text)
+    bwd = [n for n in names if "transpose(" in n]
+    assert any("layer_0" in n and "attn" in n for n in bwd)
+    assert any("optimizer" in n and "transpose(" not in n for n in names)
+
+
+# ---------------------------------------------------------------------------
+# tracer: off costs nothing, on feeds two sinks and the process's hooks
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture()
+def traced():
+    assert not tracer.enabled
+    tracer.enable(capacity=4096)
+    try:
+        yield tracer
+    finally:
+        tracer.disable()
+        tracer.clear()
+
+
+def test_tracer_off_has_no_hook_and_no_annotation(monkeypatch):
+    assert not tracer.enabled
+    assert tracer._on_gc not in gc.callbacks
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                        lambda *a, **k: pytest.fail("annotation while off"))
+    tracer.profiling = True  # a window open, the tracer off: still nothing
+    try:
+        assert tracer.span("step", step=1) is _NULL_SPAN
+        tracing._install_jax_listeners()
+        jax.jit(lambda x: x * 3.0 + 1.0)(jnp.ones(7)).block_until_ready()
+        gc.collect()
+        tracer.record_span("jax_compile", 0.5)
+    finally:
+        tracer.profiling = False
+    assert tracer.snapshot() == []
+
+
+def test_gc_callback_lives_while_the_tracer_is_on(traced, monkeypatch):
+    assert traced._on_gc in gc.callbacks
+    monkeypatch.setattr(tracing, "GC_SPAN_MIN_S", 0.0)
+    with traced.span("step", step=5):
+        gc.collect()
+    spans = [r for r in traced.snapshot() if r["name"] == "gc"]
+    assert spans and spans[-1]["args"]["generation"] == 2 and spans[-1]["args"]["step"] == 5
+    traced.disable()
+    assert traced._on_gc not in gc.callbacks
+    traced.enable()  # the fixture's disable() finds its callback again
+
+
+def test_short_collections_leave_no_span(traced):
+    gc.collect()  # settle, then a young-generation pass of an empty heap
+    traced.clear()
+    gc.collect(0)
+    assert [r for r in traced.snapshot() if r["name"] == "gc" and r["dur"] < 1e3] == []
+
+
+def test_jax_listeners_record_trace_lower_compile_with_the_open_step(traced, monkeypatch):
+    monkeypatch.setattr(tracing, "JAX_TRACE_SPAN_MIN_S", 0.0)
+    with traced.span("step", step=3):
+        with traced.span("fwd_bwd", step=3):
+            jax.jit(lambda x: jnp.sin(x) * 2.5 + 0.125)(jnp.ones(11)).block_until_ready()
+    recs = traced.snapshot()
+    for name in ("jax_trace", "jax_lower", "jax_compile"):
+        mine = [r for r in recs if r["name"] == name]
+        assert mine, name
+        assert all(r["args"]["step"] == 3 for r in mine)
+        assert all(r["ph"] == "X" and r["dur"] > 0 for r in mine)
+    compile_args = [r["args"] for r in recs if r["name"] == "jax_compile"][-1]
+    assert {"hit", "retrieval_s", "fun_name"} <= set(compile_args)
+    # a span reported after the fact ends at the report: inside the step
+    step = next(r for r in recs if r["name"] == "step")
+    last = [r for r in recs if r["name"] == "jax_compile"][-1]
+    assert step["ts"] <= last["ts"] and last["ts"] + last["dur"] <= step["ts"] + step["dur"] + 1.0
+    # before any loop: no step
+    jax.jit(lambda x: jnp.cos(x) - 0.375)(jnp.ones(13)).block_until_ready()
+    assert "step" not in traced.snapshot()[-1]["args"]
+
+
+def test_a_warm_call_records_nothing(traced):
+    f = jax.jit(lambda x: x * 1.75 - 2.0)
+    x = jnp.ones(17)
+    f(x).block_until_ready()
+    traced.clear()
+    for _ in range(3):
+        f(x).block_until_ready()
+    assert [r["name"] for r in traced.snapshot()] == []
+
+
+def test_spans_open_annotations_only_while_a_window_is_open(traced, monkeypatch):
+    seen = []
+
+    class Spy:
+        def __init__(self, name, **kw):
+            self.rec = (type(self).__name__, name, kw)
+
+        def __enter__(self):
+            seen.append(("enter",) + self.rec)
+
+        def __exit__(self, *exc):
+            seen.append(("exit",) + self.rec)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", type("TraceAnnotation", (Spy,), {}))
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation",
+                        type("StepTraceAnnotation", (Spy,), {}))
+    with traced.span("step", step=7):
+        pass
+    assert seen == []
+    traced.profiling = True
+    with traced.span("step", step=8):
+        with traced.span("sync", step=8):
+            pass
+    traced.profiling = False
+    assert seen == [
+        ("enter", "StepTraceAnnotation", "train", {"step_num": 8}),
+        ("enter", "TraceAnnotation", "sync", {}),
+        ("exit", "TraceAnnotation", "sync", {}),
+        ("exit", "StepTraceAnnotation", "train", {"step_num": 8}),
+    ]
+    assert [r["name"] for r in traced.snapshot()] == ["step", "sync", "step"]
+
+
+def test_profiler_window_flags_the_tracer_and_keeps_its_record(monkeypatch, tmp_path):
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda d: None)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    run = tmp_path / "plugins" / "profile" / "2026_01_01"
+    run.mkdir(parents=True)
+    (run / "host.xplane.pb").write_bytes(b"")
+    pw = flight.ProfilerWindow(str(tmp_path), 2, 4)
+    pw.maybe_start(1)
+    assert not tracer.profiling
+    pw.maybe_start(2)
+    assert tracer.profiling and pw.maybe_stop(2) is None
+    rec = pw.maybe_stop(3, verbose=False)
+    assert not tracer.profiling
+    assert rec == flight.last_profile_window() == {
+        "trace_dir": str(tmp_path), "xplane": str(run / "host.xplane.pb"),
+        "start_step": 2, "stop_step": 4, "first_step": 2, "last_step": 3}
+    assert pw.close() is None  # closed once
+
+
+# ---------------------------------------------------------------------------
+# the trainer end to end
+# ---------------------------------------------------------------------------
+
+TINY_TRAIN = [
+    "--model_size", "llama-0.3b", "--num_layers", "2", "--hidden_size", "64",
+    "--num_heads", "4", "--vocab_size", "256", "--seq_length", "32",
+    "--global_train_batch_size", "8", "--mixed_precision", "fp32",
+]
+
+
+@pytest.fixture(scope="module")
+def traced_run(tmp_path_factory):
+    from galvatron_tpu.core.arguments import initialize_galvatron
+    from galvatron_tpu.core.trainer import train
+    from galvatron_tpu.data.shards import write_sharded_dataset
+
+    tmp = tmp_path_factory.mktemp("traced_run")
+    rng = np.random.default_rng(0)
+    docs = [rng.integers(0, 256, size=200, dtype=np.int64) for _ in range(64)]
+    prefix = str(tmp / "corpus")
+    write_sharded_dataset(prefix, docs, 256)
+    spans, mpath = str(tmp / "spans.json"), str(tmp / "m.jsonl")
+    train(initialize_galvatron("train", TINY_TRAIN + [
+        "--train_iters", "6", "--trace_spans", spans, "--metrics_path", mpath,
+        "--data_path", prefix, "--prefetch_depth", "2", "--profile_steps", "2:4"]),
+        verbose=False)
+    events = json.load(open(spans))["traceEvents"]
+    records = [json.loads(line) for line in open(mpath)]
+    return events, records
+
+
+@pytest.mark.parametrize("name", ["build_runtime", "init_state", "data_open", "jax_trace",
+                                  "jax_lower", "jax_compile", "data_produce"])
+def test_traced_train_exports_the_span(traced_run, name):
+    events, _ = traced_run
+    mine = [e for e in events if e["ph"] == "X" and e["name"] == name]
+    assert mine, name
+    steps = {e["args"].get("step") for e in mine}
+    if name in ("build_runtime", "init_state", "data_open"):
+        assert len(mine) == 1 and steps == {None}
+    if name == "data_produce":
+        # its own track, its own index, never a step
+        main_tid = next(e["tid"] for e in events if e["name"] == "step")
+        assert steps == {None} and all(e["tid"] != main_tid for e in mine)
+        assert [e["args"]["batch"] for e in mine][:3] == [0, 1, 2]
+    if name == "jax_compile":
+        # the step program compiles (or loads) inside the call's first step
+        assert 0 in steps
+
+
+def test_traced_train_logs_the_profile_window(traced_run):
+    _, records = traced_run
+    recs = [r for r in records if r["event"] == "profile_window"]
+    assert len(recs) == 1
+    rec = recs[0]
+    assert (rec["start_step"], rec["stop_step"], rec["first_step"], rec["last_step"]) == (2, 4, 2, 3)
+    assert os.path.basename(rec["xplane"]).endswith(".xplane.pb")
+    assert rec["trace_dir"] == flight.last_profile_window()["trace_dir"]
+    assert not tracer.enabled and not tracer.profiling and tracer._on_gc not in gc.callbacks
+
+
+def test_train_iter_records_lost_the_derived_wait(traced_run):
+    _, records = traced_run
+    iters = [r for r in records if r["event"] == "train_iter"]
+    assert len(iters) == 6
+    assert not any(k in r for r in iters for k in ("comm_wait_ms", "bubble_fraction"))
+
+
+# ---------------------------------------------------------------------------
+# the program's FLOP count is the benchmark's
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("config,seq", [("baichuan-7b", 4096), ("baichuan-7b", 512),
+                                        ("opt-1.3b", 2048)])
+def test_model_flops_per_token_equal_the_benchmarks(config, seq):
+    import sys
+
+    sys.path.insert(0, REPO)
+    from benchmark.lib import flops, reference
+    from galvatron_tpu.core.arguments import initialize_galvatron, model_config_from_args
+
+    doc = json.load(open(os.path.join(REPO, "benchmark", "configs", f"{config}.json")))
+    arch = reference.load(REPO, doc["model_type"])
+    cfg = model_config_from_args(initialize_galvatron(
+        "train", doc["program_flags"] + ["--seq_length", str(seq)]))
+    st = stepstats.StepStats(cfg, 4, seq, num_devices=1)
+    mine = st.model_flops_per_step / st.tokens_per_step
+    assert mine == pytest.approx(flops.model_flops_per_token(arch, doc, seq), rel=1e-12)
+
+
+def test_attention_core_counts_what_the_mask_keeps():
+    from galvatron_tpu.models.modeling import ModelConfig
+
+    cfg = ModelConfig(vocab_size=128, hidden_size=64, num_layers=2, num_heads=4, max_seq_len=32)
+    assert stepstats.attn_core_flops_per_token(cfg, 32) == 4.0 * 64 * 33 / 2
+    assert stepstats.attn_core_flops_per_token(cfg.replace(causal=False), 32) == 4.0 * 64 * 32

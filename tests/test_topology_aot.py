@@ -156,21 +156,90 @@ def test_fused_norm_kernels_compile_at_7b_width(norm, one_chip, real_mosaic):
     assert text.count("tpu_custom_call") >= 2  # forward and backward kernels
 
 
+_ONE_CHIP_STEP = {}
+
+
+def _one_chip_step(topo):
+    """(compiled text, memory analysis) of the step program chip_smoke.py
+    trains, compiled once for the tests that read it (call under
+    ``real_mosaic``)."""
+    from galvatron_tpu.core.strategy import HybridParallelConfig
+    from galvatron_tpu.models.modeling import PRESETS
+
+    if not _ONE_CHIP_STEP:
+        cfg = PRESETS["llama-7b"].replace(num_layers=2, attn_impl="flash")
+        assert (cfg.hidden_size, cfg.num_heads, cfg.ffn_dim, cfg.vocab_size,
+                cfg.max_seq_len) == (4096, 32, 11008, 32000, 2048)
+        hp = HybridParallelConfig.uniform(2, mixed_precision="bf16")
+        compiled, ma = _compile(cfg, hp, topo.devices[:1], bsz=4, seq=2048)
+        _ONE_CHIP_STEP.update(text=compiled.as_text(), ma=ma)
+    return _ONE_CHIP_STEP["text"], _ONE_CHIP_STEP["ma"]
+
+
 def test_one_chip_train_step_compiles_at_7b_width(topo, real_mosaic):
     """The step program chip_smoke.py trains: llama-7b widths, 2 layers,
     global batch 4, seq 2048, bf16 compute over fp32 params with Adam, flash
     attention — it must hold the kernels and fit one 16 GB chip."""
-    from galvatron_tpu.core.strategy import HybridParallelConfig
-    from galvatron_tpu.models.modeling import PRESETS
-
-    cfg = PRESETS["llama-7b"].replace(num_layers=2, attn_impl="flash")
-    assert (cfg.hidden_size, cfg.num_heads, cfg.ffn_dim, cfg.vocab_size,
-            cfg.max_seq_len) == (4096, 32, 11008, 32000, 2048)
-    hp = HybridParallelConfig.uniform(2, mixed_precision="bf16")
-    compiled, ma = _compile(cfg, hp, topo.devices[:1], bsz=4, seq=2048)
+    _, ma = _one_chip_step(topo)
     total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
              - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
     assert total < HBM_V5E_GIB * 2**30, f"{total / 2**30:.2f} GiB"
+
+
+# --- the names the metrics match (PERF.md §3's table) -----------------------
+
+#: scopes of galvatron_tpu's step program; ``layer_<i>`` is matched apart
+STEP_SCOPES = ("embed", "attn", "qkv_proj", "attn_core", "out_proj", "mlp", "norm", "head",
+               "loss", "optimizer", "grad_accum", "grad_sync", "redistribute",
+               "allgather_einsum", "einsum_reducescatter")
+
+
+def _entry_work(text):
+    """(instruction name, op_name or "") of the ENTRY computation's fusions and
+    custom calls: the operations a device trace shows."""
+    import re
+
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    rows = []
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = .*? (fusion|custom-call)\(", line)
+        if m:
+            op = re.search(r'op_name="([^"]*)"', line)
+            rows.append((m.group(1), op.group(1) if op else ""))
+    return rows
+
+
+def _has_scope(op_name):
+    import re
+
+    parts = [re.sub(r"^(?:\w+\()+|\)+$", "", p) for p in op_name.split("/")]
+    return any(p in STEP_SCOPES or re.fullmatch(r"layer_\d+", p) for p in parts)
+
+
+@pytest.mark.parametrize("prefix", ["flash_fwd", "flash_bwd"])
+def test_one_chip_train_step_names_its_kernels(topo, real_mosaic, prefix):
+    """A Pallas kernel's ``name=`` is its instruction's name in the compiled
+    step (what a device trace's events are called), forward and backward."""
+    text, _ = _one_chip_step(topo)
+    kernels = [n for n, _ in _entry_work(text) if n.startswith(prefix)]
+    assert kernels, f"no instruction named {prefix}* in the compiled step"
+    # 2 layers x 2 row blocks forward, 2 layers x 1 combined backward
+    assert len(kernels) == (4 if prefix == "flash_fwd" else 2), kernels
+
+
+def test_one_chip_train_step_scopes_cover_its_work(topo, real_mosaic):
+    """At least 90% of the compiled step's ENTRY fusions and custom calls carry
+    one of the program's scopes in ``op_name``; every Mosaic call is a named
+    kernel under ``attn_core``."""
+    text, _ = _one_chip_step(topo)
+    rows = _entry_work(text)
+    scoped = [r for r in rows if _has_scope(r[1])]
+    assert len(rows) > 100 and len(scoped) >= 0.9 * len(rows), (
+        len(scoped), len(rows), [r for r in rows if not _has_scope(r[1])][:10])
+    mosaic = [r for r in rows if r[0].startswith(("flash_fwd", "flash_bwd"))]
+    assert mosaic and all("attn_core" in op for _, op in mosaic), mosaic
+    assert any("transpose(" in op for _, op in mosaic)  # the backward, marked for free
 
 
 def test_flash_multichip_compile_smoke(topo, real_mosaic):
@@ -188,7 +257,12 @@ def test_flash_multichip_compile_smoke(topo, real_mosaic):
         pp=1, layer_strategies=[LayerStrategy(tp=2, dp_type="zero3")] * 2,
         chunks=1, vocab_tp=2, mixed_precision="bf16",
     )
-    _compile(cfg, hp, topo.devices, bsz=8, seq=256)
+    compiled, _ = _compile(cfg, hp, topo.devices, bsz=8, seq=256)
+    # the kernels keep their names under shard_map (they were ``shard_map.<n>``)
+    names = [n for n, _ in _entry_work(compiled.as_text())]
+    assert any(n.startswith("flash_fwd") for n in names), names[:20]
+    assert any(n.startswith("flash_bwd") for n in names), names[:20]
+    assert not any(n.startswith("shard_map") for n in names)
 
 
 @pytest.mark.slow
