@@ -85,7 +85,7 @@ def phase_kernel_parity(*, batch: int, seq: int, heads: int, kv_heads: int,
     # the wrappers give way to an einsum when a shape does not tile: the
     # smoke is about the kernels, so its shapes must take the kernel path
     require(fa.flash_tileable(seq), f"seq {seq} does not tile the flash kernels")
-    require(fa.flash_qkv_supported(seq, head_dim, True, rope),
+    require(fa.flash_qkv_supported(seq, head_dim, True),
             f"s={seq} d={head_dim} is outside the stacked-qkv kernel's envelope")
 
     def to_bsnd(x):

@@ -972,18 +972,21 @@ def _attn_block_headmajor(x, p, cfg: ModelConfig, rope, remat_attn: bool):
             if "wqkv_b" in p:
                 qkv = qkv + p["wqkv_b"].astype(x.dtype).reshape(3, n, hd)[None, :, :, None, :]
             qkv = _constrain_qkv(qkv, cfg)
-        if flash_qkv_supported(s, hd, cfg.causal, rope):
+        if flash_qkv_supported(s, hd, cfg.causal):
             # the kernels consume the STACKED projection output directly —
             # index-mapped block specs instead of q/k/v slice copies
-            kernel = _flash_shard_map(
-                cfg,
-                lambda qkv_, c_, s_: flash_attention_qkv(qkv_, rope=(c_, s_)),
-                [(0, 2), (None, None), (None, None)],
-                (0, 1),
-            )
+            if rope is None:  # learned / absolute positions: no table operands
+                core_qkv = _flash_shard_map(cfg, flash_attention_qkv, [(0, 2)], (0, 1))
+            else:
+                kernel = _flash_shard_map(
+                    cfg,
+                    lambda qkv_, c_, s_: flash_attention_qkv(qkv_, rope=(c_, s_)),
+                    [(0, 2), (None, None), (None, None)],
+                    (0, 1),
+                )
 
-            def core_qkv(qkv_):
-                return kernel(qkv_, *rope)
+                def core_qkv(qkv_):
+                    return kernel(qkv_, *rope)
 
             if remat_attn:
                 core_qkv = jax.checkpoint(core_qkv)

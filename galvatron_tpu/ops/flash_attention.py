@@ -11,6 +11,11 @@ online-softmax kernel for the MXU:
 - backward: two kernels — dK/dV (grid over k blocks, q innermost) and dQ
   (grid over q blocks, k innermost) — recomputing probabilities from the
   saved LSE, never materializing the (S, S) score matrix.
+- causal attention inside the VMEM envelopes (``_use_blocked``), with fused
+  RoPE or with none, takes the blocked family instead: one forward call per
+  q row block with the causal structure static, and one combined dq/dk/dv
+  backward. The grid family serves non-causal attention, shapes outside the
+  envelopes and ring attention's per-hop calls.
 
 Falls back to the einsum path automatically on CPU (interpret mode is used in
 tests) and for shapes that don't tile (seq % block != 0).
@@ -248,32 +253,49 @@ def _flash_fwd(q, k, v, rope, sm_scale, causal, block_q, block_k, interpret,
 # unrolled k loop, value-carried (m, l, acc)
 # ---------------------------------------------------------------------------
 #
-# For the common causal+rope case the grid-scan kernel above leaves real time
-# on the table (measured on v5e, LLaMA-7B shape: ~0.14 ms/layer/sample):
+# For causal attention the grid-scan kernel above leaves real time on the
+# table (measured on v5e, LLaMA-7B shape with RoPE: ~0.14 ms/layer/sample;
+# opt-1.3b's tp-4 shard, no RoPE, d 64: 0.46 -> 0.27 ms a call, PERF.md §6):
 # every (i, j) grid step re-ropes q, pays scratch init/finalize bookkeeping,
 # and diagonal blocks run an iota+compare+select mask over the full score
 # block. Specializing ONE pallas call per q row block makes the causal
 # structure static — call i unrolls exactly the j <= i contributing k blocks,
 # the diagonal block applies a precomputed additive triangular bias, q is
 # roped once, and (m, l, acc) stay SSA values so Mosaic sees the whole
-# dependence graph. The softmax scale (and the exp->exp2 base change) is
+# dependence graph.
+#
+# ``rope`` is a static flag of the body (``rope is None`` at the caller, a
+# Python branch taken while tracing): the two instances share no operand or
+# equation the other does not need, and the RoPE instance lowers to the text
+# it had before the no-RoPE one existed (tests/test_ops.py pins its equation
+# counts). With RoPE the softmax scale (and the exp->exp2 base change) is
 # folded into the q-side rope tables at trace time: the fp32 rotation output
 # is cast to bf16 regardless, so the scale costs nothing and the score block
-# needs no post-matmul multiply.
+# needs no post-matmul multiply. Without RoPE there are no table operands
+# and the scale is one fp32 multiply on the score block, as in the grid
+# kernels (scaling q instead would requantize it to bf16, see _fwd_kernel).
 
 
-def _fwd_kernel_blocked(*refs, nkb, block_q, block_k, stacked=False):
-    (q_ref, k_ref, v_ref, cq_ref, sq_ref, ck_ref, sk_ref, tri_ref,
-     o_ref, lse_ref) = refs
+def _fwd_kernel_blocked(*refs, nkb, block_q, block_k, stacked=False, rope=True,
+                        sm_scale=None):
+    if rope:
+        (q_ref, k_ref, v_ref, cq_ref, sq_ref, ck_ref, sk_ref, tri_ref,
+         o_ref, lse_ref) = refs
+    else:
+        q_ref, k_ref, v_ref, tri_ref, o_ref, lse_ref = refs
     # ``stacked``: q/k/v are index-mapped blocks of ONE (b, 3, h, s, d)
     # array (one extra leading unit dim) — feeding the projection's stacked
     # output directly removes the q/k/v slice copies XLA otherwise
     # materializes for the custom-call operands (~1.2 ms/layer-batch on the
     # v5e 7B bench, the last structural copy the trace showed)
     lead = (0, 0, 0) if stacked else (0, 0)
-    # cq/sq pre-scaled by sm_scale*LOG2E: scores come out in base-2 units
-    q = _rope_rows(q_ref[lead], cq_ref[...], sq_ref[...]).astype(q_ref.dtype)
-    kf = _rope_rows(k_ref[lead], ck_ref[...], sk_ref[...]).astype(k_ref.dtype)
+    if rope:
+        # cq/sq pre-scaled by sm_scale*LOG2E: scores come out in base-2 units
+        q = _rope_rows(q_ref[lead], cq_ref[...], sq_ref[...]).astype(q_ref.dtype)
+        kf = _rope_rows(k_ref[lead], ck_ref[...], sk_ref[...]).astype(k_ref.dtype)
+    else:
+        q = q_ref[lead]
+        kf = k_ref[lead]
     vf = v_ref[lead]
     m = l = acc = None
     for j in range(nkb):
@@ -281,6 +303,8 @@ def _fwd_kernel_blocked(*refs, nkb, block_q, block_k, stacked=False):
         s = jax.lax.dot_general(
             q, kj, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
+        if not rope:
+            s = s * (sm_scale * LOG2E)  # fp32, post-matmul: base-2 logits
         if j == nkb - 1:  # bq == bk: only the last block straddles the diagonal
             s = s + tri_ref[...].astype(jnp.float32)
         if j == 0:
@@ -318,9 +342,9 @@ def _flash_qkv_fwd_rule(qkv, rope, sm_scale, block_q):
 def _flash_qkv_bwd_rule(sm_scale, block_q, res, do):
     qkv, out, lse, rope = res
     s, d = qkv.shape[3], qkv.shape[4]
-    if _use_blocked_bwd(s, d, True, rope, block_q, block_q):
-        bk, bq_sub = _bwd_blocks(block_q)
-        dqkv = _flash_bwd_blocked(
+    if _use_blocked_bwd(s, d, True, block_q, block_q):
+        bk, bq_sub = _bwd_blocks(block_q, rope is not None)
+        dqkv = _blocked_bwd(rope)(
             None, None, None, do, out, lse, rope, sm_scale, bk, bq_sub,
             _use_interpret(), qkv=qkv, do_stacked_out=True,
         )
@@ -340,17 +364,22 @@ _flash_qkv.defvjp(_flash_qkv_fwd_rule, _flash_qkv_bwd_rule)
 
 def flash_attention_qkv(qkv, sm_scale=None, block_q: int = 1024, rope=None):
     """Stacked head-major entry: ``qkv`` is the fused projection's
-    (b, 3, h, s, d) output, consumed directly (causal + fused-rope path
-    only — callers gate on flash_qkv_supported)."""
+    (b, 3, h, s, d) output (bias already added), consumed directly by the
+    blocked-causal kernels and answered in the backward by one stacked dqkv.
+    Causal attention only, with fused RoPE (``rope`` = (cos, sin)) or with
+    none (learned / absolute positions) — callers gate on
+    flash_qkv_supported."""
     d = qkv.shape[-1]
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(d))
     return _flash_qkv(qkv, rope, sm_scale, min(block_q, qkv.shape[3]))
 
 
-def flash_qkv_supported(s: int, d: int, causal: bool, rope, block_q: int = 1024) -> bool:
-    """Whether the stacked-qkv blocked path applies (modeling's gate)."""
-    return _use_blocked(s, d, causal, rope, min(block_q, s), min(block_q, s))
+def flash_qkv_supported(s: int, d: int, causal: bool, block_q: int = 1024) -> bool:
+    """Whether the stacked-qkv blocked path applies (modeling's gate): causal
+    attention whose (s, d) lies inside the blocked forward's envelope, with
+    RoPE or without."""
+    return _use_blocked(s, d, causal, min(block_q, s), min(block_q, s))
 
 
 # The last q-block call keeps the full k prefix resident in VMEM (k, v, rope
@@ -388,13 +417,33 @@ _BLOCKED_MAX_SEQ_X_DIM = _seq_envelope(_FWD_MB_PER_SXD, (8192 * 128,), 4096 * 12
 _BLOCKED_MAX_UNROLL = 8
 
 
-def _use_blocked(s, d, causal, rope, block_q, block_k):
+def _vmem_lanes(d):
+    """Lanes an (s, d) slab occupies in VMEM: the envelopes were measured at
+    d 128, and a d-64 slab pads to the same 128-lane tiles."""
+    return -(-d // 128) * 128
+
+
+# Forward row block of the no-RoPE instance. Its time is the softmax's, not
+# the MXU's (d 64 and d 128 take the same time), so what counts is how much
+# of the square above the diagonal a row block drags in: 62.5% of the square
+# at 512 rows against 75% at 1024 (s 2048; measured -24 / -17 / -11% at s
+# 1024 / 2048 / 4096, PERF.md §6). The RoPE instance re-ropes the k prefix in
+# every call, which eats that (measured +3% at d 64), and keeps block_q.
+_NO_ROPE_BQ = 512
+
+
+def _no_rope_rows(block_q, s):
+    if block_q % _NO_ROPE_BQ == 0 and s // _NO_ROPE_BQ <= _BLOCKED_MAX_UNROLL:
+        return _NO_ROPE_BQ
+    return block_q
+
+
+def _use_blocked(s, d, causal, block_q, block_k):
     return (
         causal
-        and rope is not None
         and block_q == block_k
         and s % block_q == 0
-        and s * d <= _BLOCKED_MAX_SEQ_X_DIM
+        and s * _vmem_lanes(d) <= _BLOCKED_MAX_SEQ_X_DIM
         and s // block_q <= _BLOCKED_MAX_UNROLL
     )
 
@@ -406,7 +455,8 @@ def _flash_fwd_blocked(
     """Blocked-causal forward. Either q/k/v (b, h, s, d) separately, or
     ``qkv`` stacked (b, 3, h, s, d) consumed via index-mapped block specs
     (no slice copies). Returns (out, lse). ``kv_rep`` > 1: GQA-native k/v at
-    kv_heads = h/kv_rep, index-mapped h -> h // kv_rep (see _flash_fwd)."""
+    kv_heads = h/kv_rep, index-mapped h -> h // kv_rep (see _flash_fwd).
+    ``rope`` None: the no-RoPE instance, without table operands."""
     stacked = qkv is not None
     if stacked:
         b, _, h, s, d = qkv.shape
@@ -416,10 +466,14 @@ def _flash_fwd_blocked(
         b, h, s, d = q.shape
         dtype = q.dtype
         inputs = (q, k, v)
+    if rope is None:
+        tables = ()
+        block_q = _no_rope_rows(block_q, s)
+    else:
+        lam = sm_scale * LOG2E
+        cos, sin = rope
+        tables = (cos * lam, sin * lam, cos, sin)
     nq = s // block_q
-    lam = sm_scale * LOG2E
-    cos, sin = rope
-    cqs, sqs = cos * lam, sin * lam
     r = np.arange(block_q)
     tri = jnp.asarray(
         np.where(r[:, None] >= r[None, :], 0.0, NEG_INF), jnp.bfloat16
@@ -440,17 +494,19 @@ def _flash_fwd_blocked(
                 pl.BlockSpec((1, 1, kl, d), lambda b_, h_: (b_, h_ // kv_rep, 0, 0)),
                 pl.BlockSpec((1, 1, kl, d), lambda b_, h_: (b_, h_ // kv_rep, 0, 0)),
             ]
+        table_specs = [] if rope is None else [
+            pl.BlockSpec((block_q, d // 2), lambda b_, h_, i=i: (i, 0)),
+            pl.BlockSpec((block_q, d // 2), lambda b_, h_, i=i: (i, 0)),
+            pl.BlockSpec((kl, d // 2), lambda b_, h_: (0, 0)),
+            pl.BlockSpec((kl, d // 2), lambda b_, h_: (0, 0)),
+        ]
         out_i, lse_i = pl.pallas_call(
             functools.partial(
                 _fwd_kernel_blocked, nkb=nkb, block_q=block_q, block_k=block_q,
-                stacked=stacked,
+                stacked=stacked, rope=rope is not None, sm_scale=float(sm_scale),
             ),
             grid=(b, h),
-            in_specs=qkv_specs + [
-                pl.BlockSpec((block_q, d // 2), lambda b_, h_, i=i: (i, 0)),
-                pl.BlockSpec((block_q, d // 2), lambda b_, h_, i=i: (i, 0)),
-                pl.BlockSpec((kl, d // 2), lambda b_, h_: (0, 0)),
-                pl.BlockSpec((kl, d // 2), lambda b_, h_: (0, 0)),
+            in_specs=qkv_specs + table_specs + [
                 pl.BlockSpec((block_q, block_q), lambda b_, h_: (0, 0)),
             ],
             out_specs=[
@@ -466,7 +522,7 @@ def _flash_fwd_blocked(
             ),
             interpret=interpret,
             name="flash_fwd_qkv" if stacked else "flash_fwd_blocked",
-        )(*inputs, cqs, sqs, cos, sin, tri)
+        )(*inputs, *tables, tri)
         outs.append(out_i)
         lses.append(lse_i)
     if nq == 1:
@@ -474,8 +530,24 @@ def _flash_fwd_blocked(
     return jnp.concatenate(outs, axis=2), jnp.concatenate(lses, axis=2)
 
 
+# The no-RoPE instance is called through ``jax.jit`` (here and at the combined
+# backward): a model's layers hand it the same shapes, so its unrolled bodies
+# are traced and lowered for Mosaic once a model, not once a layer (opt-1.3b's
+# 24 layers: PERF.md §6). XLA inlines the calls; each keeps its caller's
+# scopes. The RoPE instance is called directly, as it always was: its lowered
+# text is pinned (PERF.md §6), and a private function around it is a change.
+_flash_fwd_blocked_no_rope = jax.jit(
+    _flash_fwd_blocked,
+    static_argnames=("sm_scale", "block_q", "interpret", "out_dtype", "kv_rep"),
+)
+
+
+def _blocked_fwd(rope):
+    return _flash_fwd_blocked if rope is not None else _flash_fwd_blocked_no_rope
+
+
 def _flash_fwd_blocked_qkv(qkv, rope, sm_scale, block_q, interpret):
-    return _flash_fwd_blocked(
+    return _blocked_fwd(rope)(
         None, None, None, rope, sm_scale, block_q, interpret, qkv=qkv
     )
 
@@ -502,16 +574,24 @@ def _flash_fwd_blocked_qkv(qkv, rope, sm_scale, block_q, interpret):
 # by sm_scale*LOG2E, so base-2 scores are a plain dot and
 #   dk_roped = sm_scale * ds^T @ R(q) = LN2 * ds^T @ q_scaled
 #   dq_roped = sm_scale * ds   @ R(k)
-# with the counter-rotations using the UNSCALED tables.
+# with the counter-rotations using the UNSCALED tables. Without RoPE (the
+# body's static ``rope`` flag, as in the forward: no table operands, no
+# rotations) the scores take the fp32 multiply after the dot and dk, dq are
+# scaled by sm_scale on the way out.
 
 
-def _bwd_kernel_blocked(*refs, nk, ratio, bq_sub, bk, stacked, sm_scale):
-    (q_ref, k_ref, v_ref, do_ref, out_ref, lse_ref,
-     cos_ref, sin_ref) = refs[:8]
-    if stacked:
-        (dqkv_ref,) = refs[8:]
+def _bwd_kernel_blocked(*refs, nk, ratio, bq_sub, bk, stacked, sm_scale, rope=True):
+    if rope:
+        (q_ref, k_ref, v_ref, do_ref, out_ref, lse_ref,
+         cos_ref, sin_ref) = refs[:8]
+        outs = refs[8:]
     else:
-        dq_ref, dk_ref, dv_ref = refs[8:]
+        q_ref, k_ref, v_ref, do_ref, out_ref, lse_ref = refs[:6]
+        outs = refs[6:]
+    if stacked:
+        (dqkv_ref,) = outs
+    else:
+        dq_ref, dk_ref, dv_ref = outs
     lead = (0, 0, 0) if stacked else (0, 0)
     s_len = q_ref.shape[-2]
     nqs = s_len // bq_sub
@@ -520,12 +600,15 @@ def _bwd_kernel_blocked(*refs, nk, ratio, bq_sub, bk, stacked, sm_scale):
     # q sub-blocks roped lazily through scale-folded tables derived from the
     # unscaled ones in-kernel (separate scaled inputs would cost another
     # s x d/2 x 2 fp32 of VMEM; full-s rope would hold s x d fp32
-    # intermediates — per-block keeps transients at bq_sub x d)
+    # intermediates — per-block keeps transients at bq_sub x d). Without
+    # RoPE q stays raw and the scale goes onto the fp32 score block.
     q_s = [None] * nqs
 
     def q_rows(i):
+        rows = slice(i * bq_sub, (i + 1) * bq_sub)
+        if not rope:
+            return q_ref[lead][rows]
         if q_s[i] is None:
-            rows = slice(i * bq_sub, (i + 1) * bq_sub)
             q_s[i] = _rope_rows(
                 q_ref[lead][rows], cos_ref[rows] * lam, sin_ref[rows] * lam
             ).astype(q_ref.dtype)
@@ -550,10 +633,11 @@ def _bwd_kernel_blocked(*refs, nk, ratio, bq_sub, bk, stacked, sm_scale):
 
     dq = [None] * nqs
     for j in range(nk):
-        k_r = _rope_rows(
-            k_ref[lead][j * bk:(j + 1) * bk],
-            cos_ref[j * bk:(j + 1) * bk], sin_ref[j * bk:(j + 1) * bk],
-        ).astype(k_ref.dtype)
+        k_r = k_ref[lead][j * bk:(j + 1) * bk]
+        if rope:
+            k_r = _rope_rows(
+                k_r, cos_ref[j * bk:(j + 1) * bk], sin_ref[j * bk:(j + 1) * bk],
+            ).astype(k_ref.dtype)
         v_j = v_ref[lead][j * bk:(j + 1) * bk]
         dk_acc = dv_acc = None
         for i in range(j * ratio, nqs):
@@ -562,6 +646,8 @@ def _bwd_kernel_blocked(*refs, nk, ratio, bq_sub, bk, stacked, sm_scale):
                 q_rows(i), k_r, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
+            if not rope:
+                s2 = s2 * lam
             t = i - j * ratio
             if t < ratio:  # diagonal-straddling sub-block: iota mask with
                 # the static row offset (cheaper in VMEM than a mask input)
@@ -590,7 +676,10 @@ def _bwd_kernel_blocked(*refs, nk, ratio, bq_sub, bk, stacked, sm_scale):
             dq_i = jax.lax.dot(ds, k_r, preferred_element_type=jnp.float32)
             dq[i] = dq_i if dq[i] is None else dq[i] + dq_i
         cols = slice(j * bk, (j + 1) * bk)
-        dk_out = _rope_rows_t(dk_acc * LN2, cos_ref[cols], sin_ref[cols])
+        if rope:  # dk_acc was taken against q scaled by sm_scale*LOG2E
+            dk_out = _rope_rows_t(dk_acc * LN2, cos_ref[cols], sin_ref[cols])
+        else:
+            dk_out = dk_acc * sm_scale
         if stacked:
             dqkv_ref[0, 1, 0, cols] = dk_out.astype(dqkv_ref.dtype)
             dqkv_ref[0, 2, 0, cols] = dv_acc.astype(dqkv_ref.dtype)
@@ -599,8 +688,10 @@ def _bwd_kernel_blocked(*refs, nk, ratio, bq_sub, bk, stacked, sm_scale):
             dv_ref[0, 0, cols] = dv_acc.astype(dv_ref.dtype)
     for i in range(nqs):
         rows = slice(i * bq_sub, (i + 1) * bq_sub)
-        # dq was accumulated against R(k) (unscaled tables)
-        dq_out = _rope_rows_t(dq[i] * sm_scale, cos_ref[rows], sin_ref[rows])
+        # dq was accumulated against R(k) (unscaled tables), or against k
+        dq_out = dq[i] * sm_scale
+        if rope:
+            dq_out = _rope_rows_t(dq_out, cos_ref[rows], sin_ref[rows])
         if stacked:
             dqkv_ref[0, 0, 0, rows] = dq_out.astype(dqkv_ref.dtype)
         else:
@@ -622,7 +713,7 @@ def _flash_bwd_blocked(
         dtype = q.dtype
     nk = s // bk
     ratio = bk // bq_sub
-    cos, sin = rope
+    tables = () if rope is None else tuple(rope)  # cos, sin
     # single-buffer the big (s, d) slabs: Mosaic's default double-buffering
     # across grid steps costs 2x VMEM on every operand, which blows the 16M
     # scoped limit at the 7B shape (measured 19.3M); per-invocation compute
@@ -649,7 +740,7 @@ def _flash_bwd_blocked(
     res = pl.pallas_call(
         functools.partial(
             _bwd_kernel_blocked, nk=nk, ratio=ratio, bq_sub=bq_sub, bk=bk,
-            stacked=stacked, sm_scale=float(sm_scale),
+            stacked=stacked, sm_scale=float(sm_scale), rope=rope is not None,
         ),
         grid=(b, h),
         in_specs=qkv_specs + [
@@ -658,8 +749,7 @@ def _flash_bwd_blocked(
             # (s, 1) pads to (s, 128) lanes under TPU tiling — 1M fp32, so
             # single-buffer it like the slabs
             _single_buffered((1, 1, s, 1), lambda b_, h_: (b_, h_, 0, 0)),
-            rows, rows,
-        ],
+        ] + [rows] * len(tables),
         out_specs=out_specs,
         out_shape=out_shape,
         compiler_params=_compiler_params(
@@ -667,8 +757,18 @@ def _flash_bwd_blocked(
         ),
         interpret=interpret,
         name="flash_bwd_blocked",
-    )(*qkv_inputs, do, out, lse, cos, sin)
+    )(*qkv_inputs, do, out, lse, *tables)
     return res[0] if do_stacked_out else tuple(res)
+
+
+_flash_bwd_blocked_no_rope = jax.jit(
+    _flash_bwd_blocked,
+    static_argnames=("sm_scale", "bk", "bq_sub", "interpret", "do_stacked_out"),
+)
+
+
+def _blocked_bwd(rope):
+    return _flash_bwd_blocked if rope is not None else _flash_bwd_blocked_no_rope
 
 
 # VMEM budget for the combined backward: resident operands + the (bq_sub, bk)
@@ -679,6 +779,10 @@ def _flash_bwd_blocked(
 # bookkeeping is not what bounds this kernel — so the proven config stays.
 _BWD_BQ_SUB = 256
 _BWD_BK = 512
+# the no-RoPE instance has no (s, d/2) tables resident and no rotations in its
+# pairs; (512, 512) measures -7% against (512, 256) at s 1024 ... 4096, d 64 and
+# d 128 (PERF.md §6), larger k blocks measure worse
+_BWD_BQ_SUB_NO_ROPE = 512
 # the combined backward keeps ALL slabs + dq accumulators resident per
 # invocation (s=4096/d=128 measures 21.4M scoped), which overflowed Mosaic's
 # 16 MB default budget beyond s=2048; under the raised vmem_limit_bytes the
@@ -694,25 +798,27 @@ _BWD_MAX_SEQ_X_DIM = _seq_envelope(
 )
 
 
-def _bwd_blocks(block_q):
+def _bwd_blocks(block_q, rope=True):
     """(bk, bq_sub) the combined backward actually uses for a forward block
-    size ``block_q``."""
+    size ``block_q``, for the RoPE instance or the no-RoPE one (whose
+    sub-block is the k block or 512, so it tiles whatever the RoPE one does)."""
     bk = min(_BWD_BK, block_q)
-    return bk, min(_BWD_BQ_SUB, bk)
+    return bk, min(_BWD_BQ_SUB if rope else _BWD_BQ_SUB_NO_ROPE, bk)
 
 
-def _use_blocked_bwd(s, d, causal, rope, block_q, block_k):
+def _use_blocked_bwd(s, d, causal, block_q, block_k):
     bk, bq_sub = _bwd_blocks(block_q)
     return (
-        _use_blocked(s, d, causal, rope, block_q, block_k)
-        and s * d <= _BWD_MAX_SEQ_X_DIM
+        _use_blocked(s, d, causal, block_q, block_k)
+        and s * _vmem_lanes(d) <= _BWD_MAX_SEQ_X_DIM
         and s % bk == 0
         and bk % bq_sub == 0
     )
 
 
 # ---------------------------------------------------------------------------
-# Backward kernels (grid style — non-causal / no-rope / ring per-hop paths)
+# Backward kernels (grid style — non-causal attention, shapes outside the
+# blocked envelopes, ring attention's per-hop calls)
 # ---------------------------------------------------------------------------
 
 
@@ -938,8 +1044,8 @@ def _fwd_dispatch(q, k, v, rope, sm_scale, causal, block_q, block_k, interpret):
     # group's queries from the resident grouped K/V block (h -> h // rep
     # index maps) instead of a materialized repeated copy
     kv_rep = q.shape[1] // k.shape[1]
-    if _use_blocked(q.shape[2], q.shape[3], causal, rope, block_q, block_k):
-        return _flash_fwd_blocked(
+    if _use_blocked(q.shape[2], q.shape[3], causal, block_q, block_k):
+        return _blocked_fwd(rope)(
             q, k, v, rope, sm_scale, block_q, interpret, kv_rep=kv_rep
         )
     return _flash_fwd(
@@ -973,9 +1079,9 @@ def _flash_bwd_rule(sm_scale, causal, block_q, block_k, res, do):
             b, kvh * kv_rep, s, d
         )
         res = (q, k, v, out, lse, rope)
-    if _use_blocked_bwd(q.shape[2], q.shape[3], causal, rope, block_q, block_k):
-        bk, bq_sub = _bwd_blocks(block_q)
-        dq, dk, dv = _flash_bwd_blocked(
+    if _use_blocked_bwd(q.shape[2], q.shape[3], causal, block_q, block_k):
+        bk, bq_sub = _bwd_blocks(block_q, rope is not None)
+        dq, dk, dv = _blocked_bwd(rope)(
             q, k, v, do, out, lse, rope, sm_scale, bk, bq_sub, _use_interpret(),
         )
     else:

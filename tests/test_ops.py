@@ -100,7 +100,7 @@ def test_flash_blocked_causal_path_matches_reference():
     s, d = 128, 32
     q, k, v = rand_qkv(jax.random.key(7), s=s, d=d)
     cos, sin = _rope_tables(s, d)
-    assert fa._use_blocked(s, d, True, (cos, sin), 32, 32)
+    assert fa._use_blocked(s, d, True, 32, 32)
 
     def f_blocked(q, k, v):
         return (
@@ -120,16 +120,16 @@ def test_flash_blocked_causal_path_matches_reference():
     # the gate scales with head_dim and unroll count, not bare seq length
     # (the s*d envelope is 8192*128 under the raised vmem_limit_bytes —
     # experiments/vmem_probe.py / ab_flash_bwd.py)
-    assert not fa._use_blocked(16384, 128, True, (cos, sin), 1024, 1024)
-    assert not fa._use_blocked(8192, 256, True, (cos, sin), 1024, 1024)
-    assert not fa._use_blocked(4096, 128, True, (cos, sin), 128, 128)
-    assert fa._use_blocked(8192, 128, True, (cos, sin), 1024, 1024)
-    assert fa._use_blocked(2048, 128, True, (cos, sin), 1024, 1024)
+    assert not fa._use_blocked(16384, 128, True, 1024, 1024)
+    assert not fa._use_blocked(8192, 256, True, 1024, 1024)
+    assert not fa._use_blocked(4096, 128, True, 128, 128)
+    assert fa._use_blocked(8192, 128, True, 1024, 1024)
+    assert fa._use_blocked(2048, 128, True, 1024, 1024)
     # the combined backward now shares the 8k envelope (measured -9%/-15%
     # on the full train step at s=4096/8192 vs the grid kernels)
-    assert fa._use_blocked_bwd(4096, 128, True, (cos, sin), 1024, 1024)
-    assert fa._use_blocked_bwd(8192, 128, True, (cos, sin), 1024, 1024)
-    assert not fa._use_blocked_bwd(16384, 128, True, (cos, sin), 1024, 1024)
+    assert fa._use_blocked_bwd(4096, 128, True, 1024, 1024)
+    assert fa._use_blocked_bwd(8192, 128, True, 1024, 1024)
+    assert not fa._use_blocked_bwd(16384, 128, True, 1024, 1024)
     # each envelope's threshold is derived from its own measured scoped
     # charge: the bwd 8k extension charges ~43 MB (21.4 MB at s=4096 anchor),
     # so a 32-42 MB budget must NOT admit it (it passes the fwd's ~24 MB
@@ -188,7 +188,7 @@ def test_flash_qkv_stacked_matches_reference():
     s, d = 128, 32
     q, k, v = rand_qkv(jax.random.key(8), s=s, d=d)
     cos, sin = _rope_tables(s, d)
-    assert flash_qkv_supported(s, d, True, (cos, sin))
+    assert flash_qkv_supported(s, d, True)
     # (b, s, n, d) triple -> stacked (b, 3, n, s, d) head-major
     qkv = jnp.stack(
         [jnp.transpose(t, (0, 2, 1, 3)) for t in (q, k, v)], axis=1
@@ -239,7 +239,7 @@ def test_flash_bwd_subblock_ratio():
     orig = fa._BWD_BQ_SUB
     fa._BWD_BQ_SUB = 32
     try:
-        assert fa._use_blocked_bwd(s, d, True, (cos, sin), 64, 64)
+        assert fa._use_blocked_bwd(s, d, True, 64, 64)
         g_flash = jax.grad(f_flash, argnums=(0, 1, 2))(q, k, v)
     finally:
         fa._BWD_BQ_SUB = orig
@@ -248,6 +248,182 @@ def test_flash_bwd_subblock_ratio():
         np.testing.assert_allclose(
             np.asarray(gf), np.asarray(gr), rtol=5e-4, atol=5e-4, err_msg=name
         )
+
+
+def _eqns(jaxpr, into_kernels=False):
+    """Every equation of ``jaxpr`` and of the jaxprs its equations carry
+    (pjit, custom_vjp, ...), in program order; a pallas_call's body only
+    when asked."""
+    for e in jaxpr.eqns:
+        yield e
+        if e.primitive.name == "pallas_call" and not into_kernels:
+            continue
+        for v in e.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else [v]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner, into_kernels)
+
+
+def _pallas_calls(fn, *args):
+    """The pallas_call equations ``fn`` traces to, in program order."""
+    return [e for e in _eqns(jax.make_jaxpr(fn)(*args).jaxpr) if e.primitive.name == "pallas_call"]
+
+
+def _kernel_names(fn, *args):
+    return [e.params["name"] for e in _pallas_calls(fn, *args)]
+
+
+def _ref_attention_hm(q, k, v):
+    """float32 causal reference, head-major (b, h, s, d); GQA k/v repeated."""
+    rep = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1)
+    t = lambda x: jnp.transpose(x, (0, 2, 1, 3))  # noqa: E731
+    return t(ref_attention(t(q), t(k), t(v)))
+
+
+@pytest.mark.parametrize("entry", ["qkv", "hm", "hm_gqa"])
+@pytest.mark.parametrize("s,d,bq_sub", [
+    (1024, 64, 512), (2048, 64, 512), (1024, 128, 512), (2048, 128, 512), (1024, 64, 256)])
+def test_flash_blocked_no_rope_matches_reference(entry, s, d, bq_sub, monkeypatch):
+    """Causal attention WITHOUT RoPE (gpt / opt: learned positions) takes the
+    blocked family too: no table operands, the scale on the fp32 score block.
+    Forward and dq / dk / dv against the float32 reference at the tolerances
+    the grid kernels' tests use, through the stacked entry, the head-major
+    entry and GQA (kv_rep 2) through ``_flash``, at the blocks production
+    takes: 512-row forward calls (2-4 of them) under block_q 1024 and a
+    (512, 512) backward; the last case forces the backward's sub-block ratio
+    to 2 (the static row offset of the diagonal mask)."""
+    from galvatron_tpu.ops import flash_attention as fa
+
+    bq = min(1024, s)
+    monkeypatch.setattr(fa, "_BWD_BQ_SUB_NO_ROPE", bq_sub)
+    assert fa._use_blocked_bwd(s, d, True, bq, bq)
+    assert fa._no_rope_rows(bq, s) == 512 and fa._bwd_blocks(bq, rope=False) == (512, bq_sub)
+    h, kvh = 2, (1 if entry == "hm_gqa" else 2)
+    ks = jax.random.split(jax.random.key(s + d), 3)
+    q = jax.random.normal(ks[0], (1, h, s, d), jnp.float32)
+    k = jax.random.normal(ks[1], (1, kvh, s, d), jnp.float32)
+    v = jax.random.normal(ks[2], (1, kvh, s, d), jnp.float32)
+
+    if entry == "qkv":
+        def out_flash(q_, k_, v_):
+            return fa.flash_attention_qkv(jnp.stack([q_, k_, v_], axis=1))
+    else:
+        def out_flash(q_, k_, v_):
+            return fa.flash_attention_hm(q_, k_, v_, causal=True)
+
+    def loss(fn):
+        return lambda *a: (fn(*a) ** 2).sum()
+
+    grad = jax.grad(loss(out_flash), argnums=(0, 1, 2))
+    fwd = "flash_fwd_qkv" if entry == "qkv" else "flash_fwd_blocked"
+    assert _kernel_names(grad, q, k, v) == [fwd] * (s // 512) + ["flash_bwd_blocked"]
+    g_flash = grad(q, k, v)
+    np.testing.assert_allclose(
+        np.asarray(out_flash(q, k, v)), np.asarray(_ref_attention_hm(q, k, v)),
+        rtol=2e-5, atol=2e-5,
+    )
+    g_ref = jax.grad(loss(_ref_attention_hm), argnums=(0, 1, 2))(q, k, v)
+    for name, gf, gr in zip(("dq", "dk", "dv"), g_flash, g_ref):
+        np.testing.assert_allclose(
+            np.asarray(gf), np.asarray(gr), rtol=5e-4, atol=5e-4, err_msg=name
+        )
+
+
+_G, _B = "grid", "blocked"
+
+
+@pytest.mark.parametrize("rope", [True, False], ids=["rope", "no_rope"])
+@pytest.mark.parametrize("causal,s,d,block,family", [
+    (True, 2048, 64, 1024, _B),    # opt-1.3b under tp 4: the four-chip cell
+    (True, 4096, 128, 1024, _B),   # baichuan-7b at its context
+    (True, 512, 128, 512, _B),
+    (True, 8192, 64, 1024, _B),    # a d-64 slab pads to 128 lanes: the edge
+    (True, 8192, 128, 1024, _B),
+    (False, 2048, 64, 1024, _G),   # non-causal (bert, vit, t5's encoder)
+    (False, 512, 128, 512, _G),
+    (True, 16384, 64, 1024, _G),   # beyond the s*d envelope and the unroll
+    (True, 16384, 128, 1024, _G),
+    (True, 8192, 256, 1024, _G),
+    (True, 4096, 128, 128, _G),    # 32 row blocks: unroll > 8
+    (True, 1536, 64, 1024, _G),    # s does not tile the block
+])
+def test_flash_selector_table(rope, causal, s, d, block, family):
+    """Which family serves a shape: causal inside the envelopes -> blocked,
+    with RoPE or without; non-causal or beyond them -> grid. The selectors see
+    shapes only (``rope`` picks the instance inside the blocked family)."""
+    from galvatron_tpu.ops import flash_attention as fa
+
+    assert fa._use_blocked(s, d, causal, block, block) == (family == _B)
+    assert fa._use_blocked_bwd(s, d, causal, block, block) == (family == _B)
+    if s > 2048 or d > 128 or s % block:
+        return  # the names below need a trace; the large shapes add nothing
+    x = jax.ShapeDtypeStruct((1, 2, s, d), jnp.bfloat16)
+    t = jax.ShapeDtypeStruct((s, d // 2), jnp.float32)
+
+    def loss(q, k, v, *tables):
+        out = fa._flash(q, k, v, tables or None, 1.0, causal, block, block)
+        return out.astype(jnp.float32).sum()
+
+    names = set(_kernel_names(jax.grad(loss, argnums=(0, 1, 2)), x, x, x, *([t, t] * rope)))
+    want = {_B: {"flash_fwd_blocked", "flash_bwd_blocked"},
+            _G: {"flash_fwd_grid", "flash_bwd_dkv", "flash_bwd_dq"}}
+    assert names == want[family], names
+
+
+#: equations of the RoPE kernel bodies at baichuan-7b_s4096's shape
+#: (2, 3, 32, 4096, 128), counted at 4365126 (PR 25's tree, before the no-RoPE
+#: instance): forward row block 4 of 4; the combined backward
+_ROPE_BODY_EQNS = {"flash_fwd_qkv": 106, "flash_bwd_blocked": 2139}
+
+
+def test_flash_rope_kernel_bodies_do_not_grow(monkeypatch):
+    """What refused PR 26: a generalisation of the blocked bodies that puts
+    work into the RoPE instance shows in ``setup_s`` (the bodies are unrolled:
+    4 forward calls a layer and 72 backward pairs at s 4096, traced and
+    lowered by Python each time) and in the kernels. The RoPE bodies count no
+    more equations than at the parent; the no-RoPE bodies, held to the same
+    blocks, count fewer. Whoever adds a window, a segment mask or a 192/128
+    head here: give the new case a trace-time branch and leave these numbers
+    alone."""
+    from galvatron_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_NO_ROPE_BQ", 1024)
+    monkeypatch.setattr(fa, "_BWD_BQ_SUB_NO_ROPE", fa._BWD_BQ_SUB)
+    b, h, s, d = 2, 32, 4096, 128
+    qkv = jax.ShapeDtypeStruct((b, 3, h, s, d), jnp.bfloat16)
+    t = jax.ShapeDtypeStruct((s, d // 2), jnp.float32)
+
+    def body_eqns(*tables):
+        fn = jax.grad(lambda x, *tb: fa.flash_attention_qkv(
+            x, rope=tb or None).astype(jnp.float32).sum())
+        calls = _pallas_calls(fn, qkv, *tables)
+        assert [c.params["name"] for c in calls] == ["flash_fwd_qkv"] * 4 + ["flash_bwd_blocked"]
+        return {c.params["name"]: sum(1 for _ in _eqns(c.params["jaxpr"], into_kernels=True))
+                for c in calls[3:]}
+
+    rope, no_rope = body_eqns(t, t), body_eqns()
+    for name, at_parent in _ROPE_BODY_EQNS.items():
+        assert rope[name] <= at_parent, (name, rope[name], at_parent)
+        assert no_rope[name] < rope[name], (name, no_rope[name], rope[name])
+
+
+def test_flash_grid_causal_gradients_match_reference():
+    """Causal shapes the blocked family does not take (here unequal blocks)
+    keep the grid kernels, forward and backward."""
+    q, k, v = rand_qkv(jax.random.key(21), s=128)
+
+    def f_flash(q, k, v):
+        return (flash_attention(q, k, v, causal=True, block_q=64, block_k=32) ** 2).sum()
+
+    def f_ref(q, k, v):
+        return (ref_attention(q, k, v) ** 2).sum()
+
+    grad = jax.grad(f_flash, argnums=(0, 1, 2))
+    assert set(_kernel_names(grad, q, k, v)) == {"flash_fwd_grid", "flash_bwd_dkv", "flash_bwd_dq"}
+    for a, b in zip(grad(q, k, v), jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-4)
 
 
 def test_flash_fallback_preserves_causal_and_scale():

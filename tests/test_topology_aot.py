@@ -97,41 +97,63 @@ def _rope_avals(one_chip):
     return t, t
 
 
+#: what one opt-1.3b layer hands the kernels on a chip of the four-chip cell's
+#: plan (tp 4, 4 micro-batches): no RoPE, 8 of 32 heads, d 64
+OPT_B, OPT_H, OPT_D = 4, 8, 64
+
+def _kernel_names(text):
+    """The flash kernels among the compiled ENTRY's instructions, by their
+    ``pallas_call(name=)`` (bare autodiff prefixes ``jvp_`` / ``transpose_jvp_``
+    to it, a scoped step program does not; XLA appends ``.<n>``)."""
+    import re
+
+    found = [re.search(r"flash_(?:fwd|bwd)_[a-z]+", n) for n, _ in _entry_work(text)]
+    return [m.group(0) for m in found if m]
+
+
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
-@pytest.mark.parametrize("entry", ["qkv", "hm", "hm_gqa"])
+@pytest.mark.parametrize("entry", ["qkv", "hm", "hm_gqa", "opt_qkv", "opt_hm"])
 def test_flash_kernels_compile_at_7b_width(entry, grad, one_chip, real_mosaic):
     """The two entries modeling._attn_block_headmajor dispatches to — stacked
     qkv (MHA) and head-major (here MHA and the 8-kv-head GQA form) — with
     fused RoPE, forward and forward+backward, at b4 x s2048 x 32 heads x d128
-    bf16: what one llama-7b layer hands the kernels."""
+    bf16: what one llama-7b layer hands the kernels. And the same two entries
+    WITHOUT RoPE at opt-1.3b's per-chip shape, b4 x s2048 x 8 heads x d64.
+    Every one takes the blocked family: row blocks of 1024 forward with RoPE
+    and of 512 without, one combined backward, no grid kernel."""
     from galvatron_tpu.ops import flash_attention as fa
 
     def aval(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
 
     assert fa.flash_tileable(S)
-    if entry == "qkv":
-        assert fa.flash_qkv_supported(S, D, True, object())
-        args = (aval(B, 3, H, S, D),)
+    rope = not entry.startswith("opt")
+    b, h, d = (B, H, D) if rope else (OPT_B, OPT_H, OPT_D)
+    tables = _rope_avals(one_chip) if rope else ()
+    assert fa.flash_qkv_supported(S, d, True)
+    if entry.endswith("qkv"):
+        args = (aval(b, 3, h, S, d),)
 
-        def fwd(qkv, c, s):
-            return fa.flash_attention_qkv(qkv, rope=(c, s))
+        def fwd(qkv, *t):
+            return fa.flash_attention_qkv(qkv, rope=t or None)
     else:
-        kv = KV if entry == "hm_gqa" else H
-        args = (aval(B, H, S, D), aval(B, kv, S, D), aval(B, kv, S, D))
+        kv = KV if entry == "hm_gqa" else h
+        args = (aval(b, h, S, d), aval(b, kv, S, d), aval(b, kv, S, d))
 
-        def fwd(q, k, v, c, s):
-            return fa.flash_attention_hm(q, k, v, causal=True, rope=(c, s))
+        def fwd(q, k, v, *t):
+            return fa.flash_attention_hm(q, k, v, causal=True, rope=t or None)
 
     fn = fwd
     if grad:
         def fn(*a):
-            loss = lambda *qkv: jnp.sum(fwd(*qkv, *a[-2:]).astype(jnp.float32))  # noqa: E731
-            return jax.grad(loss, argnums=tuple(range(len(args))))(*a[:-2])
+            t = a[len(args):]
+            loss = lambda *qkv: jnp.sum(fwd(*qkv, *t).astype(jnp.float32))  # noqa: E731
+            return jax.grad(loss, argnums=tuple(range(len(args))))(*a[:len(args)])
 
-    text = _kernel_text(fn, *args, *_rope_avals(one_chip))
-    # forward is one kernel; the backward adds at least one more
-    assert text.count("tpu_custom_call") >= (2 if grad else 1)
+    names = _kernel_names(_kernel_text(fn, *args, *tables))
+    fwd_name = "flash_fwd_qkv" if entry.endswith("qkv") else "flash_fwd_blocked"
+    # these and no other: no flash_fwd_grid, flash_bwd_dkv or flash_bwd_dq
+    assert names == [fwd_name] * (2 if rope else 4) + ["flash_bwd_blocked"] * grad, names
 
 
 @pytest.mark.parametrize("norm", ["rmsnorm", "layernorm"])
@@ -260,9 +282,12 @@ def test_flash_multichip_compile_smoke(topo, real_mosaic):
     compiled, _ = _compile(cfg, hp, topo.devices, bsz=8, seq=256)
     # the kernels keep their names under shard_map (they were ``shard_map.<n>``)
     names = [n for n, _ in _entry_work(compiled.as_text())]
-    assert any(n.startswith("flash_fwd") for n in names), names[:20]
-    assert any(n.startswith("flash_bwd") for n in names), names[:20]
     assert not any(n.startswith("shard_map") for n in names)
+    # causal, learned positions (no RoPE), s 256 x d 128: the blocked family
+    # under shard_map, one row block and one combined backward a layer
+    # (the metrics match instruction names by their start: no prefix allowed)
+    flash = sorted(n.split(".")[0] for n in names if n.startswith("flash_"))
+    assert flash == ["flash_bwd_blocked"] * 2 + ["flash_fwd_qkv"] * 2, (flash, names[:20])
 
 
 @pytest.mark.slow
