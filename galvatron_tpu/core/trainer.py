@@ -1011,6 +1011,13 @@ def _train_impl(ns: argparse.Namespace, verbose: bool, tracer,
                         prof.end_iter(loss)
                         loss_val = float(loss) if sync_each else None  # gta: disable=GTL101 — deliberate sync, gated by sync_each (off unless per-iter observables, span tracing, or the anomaly sentinel need the realized loss)
                         sync_sp.sync(loss)
+                        # a dropless top-k MoE step leaves its auxiliary loss and
+                        # expert load in the state: fetched with the loss, after
+                        # the same sync, never by a sync of their own
+                        moe_vals = (
+                            {k: float(v) for k, v in state["moe_stats"].items()}
+                            if sync_each and "moe_stats" in state else {}
+                        )
                     if sched_ticks is not None:
                         # the fwd_bwd+sync window is the realized step; render
                         # the schedule's per-stage tick grid onto it so 1F1B
@@ -1087,6 +1094,7 @@ def _train_impl(ns: argparse.Namespace, verbose: bool, tracer,
                             ),
                             batch_size=cur_bs,
                             iter_ms=iter_ms,
+                            **moe_vals,
                             **stat,
                             **({"step_time_drift": round(drift, 4)}
                                if drift is not None else {}),

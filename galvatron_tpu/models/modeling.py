@@ -85,11 +85,33 @@ class ModelConfig:
     moe_experts: int = 0
     moe_capacity_factor: float = 1.25
     moe_sinkhorn_iters: int = 8
+    # Router kind. 'switch': the reference's top-1 router (sinkhorn-balanced,
+    # static capacity, one-hot dispatch einsums, expert parallelism).
+    # 'softmax_topk': OLMoE-class token choice — fp32 softmax, the
+    # ``moe_top_k`` largest probabilities as combine weights (not
+    # renormalised), dropless (sort by expert + grouped GEMM,
+    # moe.moe_topk_block), with the load-balancing loss
+    # ``moe_aux_coef * E * sum_e f_e P_e`` added to the loss that is
+    # differentiated (the logged loss stays the cross entropy). ep>1 and pp>1
+    # are refused for it by build_runtime and left out by the search.
+    moe_router: str = "switch"
+    moe_top_k: int = 1
+    moe_aux_coef: float = 0.0
+    # RMSNorm with a learned scale on the q and k projections, each over the
+    # WHOLE projection width (all heads together) before the split into heads
+    # and before rope (OLMoE; HF modeling_olmoe.py q_norm / k_norm).
+    qk_norm: bool = False
     # (mesh, ep_axes, token_axes) installed by the layer hooks for ep>1
     # layers so moe_block can pin dispatch-buffer shardings (keeps the
     # expert all-to-all at the dispatch einsum instead of an SPMD
     # replicate-and-repartition). None → unconstrained (single-device paths).
     moe_shard_ctx: Optional[Any] = None
+    # (mesh, PartitionSpec of the layer's (B, S, H) activation) installed by
+    # the layer hook for dropless top-k MoE layers on ANY multi-device mesh:
+    # moe.moe_topk_block then routes each device's own tokens under a
+    # shard_map (Mosaic kernels cannot be partitioned by GSPMD, and a global
+    # sort would gather every token). None → direct call (single device).
+    moe_token_shard_ctx: Optional[Any] = None
     # (mesh, batch_axes) installed by the layer hooks for zero3+tp layers:
     # attn_block pins the attention context o to batch-sharded/head-replicated
     # before the output projection. Without it the dWo^T grad dot (output
@@ -178,6 +200,12 @@ class ModelConfig:
     # boundaries and on padding (split_batch). CLM decoder-only; requires
     # the 'xla' attention path (the Pallas kernels carry no segment mask).
     pack_sequences: bool = False
+
+    @property
+    def moe_dropless(self) -> bool:
+        """The layers are dropless top-k MoE layers, which hand the router's
+        statistics up beside their activations (decoder_layer)."""
+        return self.moe_experts > 0 and self.moe_router == "softmax_topk"
 
     @property
     def kv_heads(self) -> int:
@@ -277,7 +305,32 @@ def project_qkv_heads(x, p_attn, cfg: ModelConfig):
     y = qkv_project(x, p_attn["wqkv"], cfg)
     if "wqkv_b" in p_attn:
         y = y + p_attn["wqkv_b"].astype(y.dtype)
-    return split_qkv(y, cfg)
+    q, k, v = split_qkv(y, cfg)
+    if cfg.qk_norm:
+        with jax.named_scope("qk_norm"):
+            q = qk_norm(q, p_attn["q_norm"], cfg, (-2, -1))
+            k = qk_norm(k, p_attn["k_norm"], cfg, (-2, -1))
+    return q, k, v
+
+
+def qk_norm(t, scale, cfg: ModelConfig, axes):
+    """RMSNorm of a q or k projection over ALL its heads together (``axes``:
+    the head and head-dim axes of ``t``), learned ``scale`` of the whole
+    projection width (n·hd,) — OLMoE's q_norm / k_norm, applied before rope.
+    fp32 statistics, rematerialized under the 'policy' recompute like every
+    other norm (no fp32-widened copy of the projection survives)."""
+    n, hd = t.shape[axes[0]], t.shape[axes[1]]
+    shape = [1] * t.ndim
+    shape[axes[0]], shape[axes[1]] = n, hd
+
+    def impl(t_, scale_):
+        t32 = t_.astype(jnp.float32)
+        t32 = t32 * jax.lax.rsqrt(jnp.mean(t32 * t32, axis=axes, keepdims=True) + cfg.norm_eps)
+        return (t32 * scale_.astype(jnp.float32).reshape(shape)).astype(t_.dtype)
+
+    if cfg.mlp_recompute == "policy":
+        impl = jax.checkpoint(impl)
+    return impl(t, scale)
 
 
 def attn_output(o, p_attn, cfg: ModelConfig, dtype):
@@ -323,6 +376,9 @@ def init_layer_params(key, cfg: ModelConfig, cross: bool = False) -> Params:
         },
         "mlp_norm": {"scale": jnp.ones((h,), cfg.param_dtype)},
     }
+    if cfg.qk_norm:
+        p["attn"]["q_norm"] = jnp.ones((q_out,), cfg.param_dtype)
+        p["attn"]["k_norm"] = jnp.ones((kv_out,), cfg.param_dtype)
     if cfg.use_bias:
         if not cfg.qkv_blocked:
             raise ValueError("use_bias needs the blocked qkv layout (no GQA)")
@@ -380,6 +436,10 @@ def layer_annotations(cfg: ModelConfig, cross: bool = False) -> Params:
         },
         "mlp_norm": {"scale": ("fsdp",)},
     }
+    if cfg.qk_norm:
+        # scales of the projection's output width: sharded with the heads
+        a["attn"]["q_norm"] = ("tp",)
+        a["attn"]["k_norm"] = ("tp",)
     if cfg.use_bias:
         # column-parallel biases shard with their output dim; the
         # row-parallel output bias is added once after the reduction
@@ -972,6 +1032,13 @@ def _attn_block_headmajor(x, p, cfg: ModelConfig, rope, remat_attn: bool):
             if "wqkv_b" in p:
                 qkv = qkv + p["wqkv_b"].astype(x.dtype).reshape(3, n, hd)[None, :, :, None, :]
             qkv = _constrain_qkv(qkv, cfg)
+        if cfg.qk_norm:
+            # outside the kernels: the q and k slots of the stacked projection,
+            # each over its (n, d) axes together; v passes through
+            with jax.named_scope("qk_norm"):
+                qkv = jnp.stack(
+                    [qk_norm(qkv[:, 0], p["q_norm"], cfg, (1, 3)),
+                     qk_norm(qkv[:, 1], p["k_norm"], cfg, (1, 3)), qkv[:, 2]], axis=1)
         if flash_qkv_supported(s, hd, cfg.causal):
             # the kernels consume the STACKED projection output directly —
             # index-mapped block specs instead of q/k/v slice copies
@@ -1065,7 +1132,10 @@ def attn_block(x, p, cfg: ModelConfig, cos_sin=None, alibi=None, remat_attn: boo
     ):
         from galvatron_tpu.ops.flash_attention import flash_tileable
 
-        if flash_tileable(s) and ("wqkv_b" not in p or cfg.qkv_blocked):
+        # (biases and qk-norm ride the blocked stacked projection only)
+        if flash_tileable(s) and (
+            ("wqkv_b" not in p and not cfg.qk_norm) or cfg.qkv_blocked
+        ):
             rope = cos_sin if cfg.pos_embed == "rope" else None
             return _attn_block_headmajor(x, p, cfg, rope, remat_attn)
     # one fused qkv GEMM (~2 ms/layer-batch over three narrow matmuls on the
@@ -1104,6 +1174,8 @@ def mlp_block(x, p, cfg: ModelConfig, train: bool = True):
     if cfg.moe_experts > 0:
         from galvatron_tpu.models import moe
 
+        if cfg.moe_dropless:  # callers of mlp_block want activations only
+            return moe.moe_topk_block(x, p, cfg)[0]
         return moe.moe_block(x, p, cfg, train=train)
     # _proj_up/_proj_down only serve the (B, S, H) token stream; vision /
     # windowed layouts keep the plain matmul (tp_overlap_ctx is token-only)
@@ -1167,6 +1239,15 @@ def mlp_residual(x, p, cfg: ModelConfig, train: bool = True):
     the saved compute-dtype layer input. MoE layers fall back to the plain
     branch (dispatch buffers carry their own sharding pins; the router is
     deterministic but its recompute under a policy region is unvalidated)."""
+    if cfg.moe_dropless:
+        # a dropless top-k MoE layer hands the router's statistics up beside
+        # the activations: (x, (f, P)) — see decoder_layer
+        from galvatron_tpu.models import moe
+
+        normed = norm(x, p["mlp_norm"], cfg)
+        with jax.named_scope("mlp"):
+            y, stats = moe.moe_topk_block(normed, p["mlp"], cfg)
+        return x + y, stats
     if cfg.mlp_recompute == "policy" and cfg.moe_experts == 0 and not cfg.fused_norm:
         # _norm_impl, not norm: the policy region already remats everything
         # unnamed — a nested per-norm checkpoint would only add bookkeeping.
@@ -1214,6 +1295,9 @@ def decoder_layer(
     x, p, cfg: ModelConfig, cos_sin=None, alibi=None, remat_attn: bool = False,
     enc_out=None, seg_ids=None
 ):
+    """One decoder layer -> x. When ``cfg.moe_dropless`` it returns
+    ``(x, router_stats)`` instead: the layer's (f, P) of moe.router_stats,
+    which the load-balancing loss needs (forward_with_stats collects them)."""
     x = x + attn_block(
         norm(x, p["attn_norm"], cfg), p["attn"], cfg, cos_sin, alibi,
         remat_attn=remat_attn, seg_ids=seg_ids,
@@ -1260,7 +1344,15 @@ def lm_head(x, params, cfg: ModelConfig):
 
 
 def forward(params, tokens, cfg: ModelConfig, layer_hook=None):
-    """Full forward → logits. ``layer_hook(i, x)`` lets the hybrid-parallel
+    """Full forward → logits (see forward_with_stats)."""
+    return forward_with_stats(params, tokens, cfg, layer_hook=layer_hook)[0]
+
+
+def forward_with_stats(params, tokens, cfg: ModelConfig, layer_hook=None):
+    """Full forward → (logits, router statistics of the dropless MoE layers:
+    one (f, P) a layer, empty for every other model).
+
+    ``layer_hook(i, x)`` lets the hybrid-parallel
     runtime insert per-layer sharding constraints and remat (the
     Module_with_relocation + checkpoint_wrapper equivalent, reference:
     galvatron/core/parallel.py:109-172).
@@ -1284,15 +1376,19 @@ def forward(params, tokens, cfg: ModelConfig, layer_hook=None):
     alibi = jnp.asarray(alibi_slopes(cfg.num_heads)) if cfg.pos_embed == "alibi" else None
     hook_kw = {"seg_ids": seg} if seg is not None else {}
     x = embed(tokens, params, cfg, pos_ids=pos_ids)
+    stats = []
     for i, lp in enumerate(params["layers"]):
         with jax.named_scope(f"layer_{i}"):
             if layer_hook is not None:
                 x = layer_hook(i, x, lp, **hook_kw)
             else:
                 x = decoder_layer(x, lp, cfg, cos_sin, alibi, seg_ids=seg)
+            if cfg.moe_dropless:
+                x, layer_stats = x
+                stats.append(layer_stats)
     with jax.named_scope("head"):
         x = norm(x, params["final_norm"], cfg)
-        return lm_head(x, params, cfg)
+        return lm_head(x, params, cfg), stats
 
 
 def forward_encdec(params, enc_tokens, dec_tokens, cfg: ModelConfig, layer_hook=None):
@@ -1625,6 +1721,27 @@ def lm_loss(params, batch, cfg: ModelConfig, layer_hook=None):
     return s / jnp.maximum(n, 1)
 
 
+def moe_loss_sum(params, batch, cfg: ModelConfig, layer_hook=None):
+    """lm_loss_sum of a dropless top-k MoE model with what its training
+    objective adds: (nll_sum, token_count, aux), aux = {"moe_aux_loss": the
+    load-balancing loss L_aux of this batch, "moe_load_max_over_mean": the
+    fullest expert's pairs over the even share}. The objective that is
+    differentiated is nll_sum / count + cfg.moe_aux_coef * L_aux; the loss
+    that is logged and evaluated stays the cross entropy."""
+    from galvatron_tpu.models import moe
+
+    tokens, labels = split_batch(batch, cfg)
+    logits, stats = forward_with_stats(params, tokens, cfg, layer_hook=layer_hook)
+    s, n = cross_entropy_sum(logits, labels, remat=ce_remat(cfg))
+    with jax.named_scope("loss"):
+        aux = {
+            "moe_aux_loss": moe.load_balancing_loss(stats, cfg.moe_experts),
+            "moe_load_max_over_mean": moe.load_max_over_mean(
+                stats, cfg.moe_experts, cfg.moe_top_k),
+        }
+    return s, n, aux
+
+
 # Preset configs mirroring the reference model zoo sizes
 # (galvatron/models/llama_hf/arguments.py:6, gpt_hf/arguments.py:6)
 PRESETS: Dict[str, ModelConfig] = {
@@ -1771,6 +1888,14 @@ PRESETS: Dict[str, ModelConfig] = {
     "baichuan-7b": ModelConfig(
         vocab_size=64000, hidden_size=4096, num_layers=32, num_heads=32,
         ffn_dim=11008, max_seq_len=4096,
+    ),
+    # allenai/OLMoE-1B-7B-0125-Instruct: 64 experts of width 1024 (ffn_dim is
+    # the EXPERT width), 8 a token, softmax top-k without renormalisation,
+    # dropless; qk-norm over the whole projection; router_aux_loss_coef 0.01
+    "olmoe-1b-7b": ModelConfig(
+        vocab_size=50304, hidden_size=2048, num_layers=16, num_heads=16,
+        ffn_dim=1024, max_seq_len=4096, moe_experts=64, moe_router="softmax_topk",
+        moe_top_k=8, moe_aux_coef=0.01, qk_norm=True,
     ),
     "baichuan-13b": ModelConfig(
         vocab_size=64000, hidden_size=5120, num_layers=40, num_heads=40,

@@ -1,4 +1,8 @@
-"""Switch-style Mixture-of-Experts MLP with expert parallelism.
+"""Mixture-of-Experts MLPs: the switch path (top-1, capacity, expert
+parallelism) and the dropless softmax top-k path (``cfg.moe_router ==
+"softmax_topk"``: OLMoE-class models; second half of this file).
+
+Switch-style Mixture-of-Experts MLP with expert parallelism.
 
 Counterpart of the reference's ``SwitchMLP`` (reference:
 galvatron/core/tensor_parallel/transformer.py:161-295): a top-1 router with
@@ -28,7 +32,7 @@ model (pinned in test_moe.py::test_moe_pipeline_parallel_parity).
 
 from __future__ import annotations
 
-from typing import Any, Dict
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -190,3 +194,199 @@ def moe_block(x: jax.Array, p: Params, cfg, train: bool = True) -> jax.Array:
     ye = pin_ep(jnp.einsum("ecf,efh->ech", hmid, w2))
     yt = pin_tok(jnp.einsum("tec,ech->th", combine.astype(x.dtype), ye))
     return yt.reshape(b, s, h)
+
+
+# ---------------------------------------------------------------------------
+# Dropless softmax top-k token-choice routing (cfg.moe_router == "softmax_topk")
+# ---------------------------------------------------------------------------
+# OLMoE-class layers: p = softmax_fp32(y Wg); the k largest p of a token are
+# its combine weights as they are (no renormalisation); every chosen (token,
+# expert) pair is computed.  A (tokens, experts, capacity) one-hot cannot exist
+# at these sizes (16384 x 64 x 2560), so the T*k pairs are sorted by expert
+# into a row buffer whose groups start on row-tile boundaries
+# (ops/grouped_matmul.py), three grouped GEMMs run over it, and the results
+# are gathered back.  Both directions of the permutation are gathers, forward
+# and backward (a pair has one row and a row one pair), through two custom
+# VJPs: autodiff's transpose of a gather is a scatter-add, which the TPU
+# serializes.  Every shape is static: the buffer has T*k + E*tile rows, the
+# most that whole-tile groups can need.
+
+class SortedLayout(NamedTuple):
+    """Where each (token, choice) pair lives in the expert-sorted row buffer."""
+
+    pair_row: jax.Array  # (T*k,) row of pair t*k + j
+    row_pair: jax.Array  # (M,) pair of a row (0 where the row is padding)
+    row_valid: jax.Array  # (M,) bool
+    tile_group: jax.Array  # (M / tile,) expert of a row tile
+    num_tiles: jax.Array  # (1,) row tiles in use
+    sizes: jax.Array  # (E,) pairs an expert got
+
+
+def sorted_layout(expert_idx: jax.Array, num_experts: int, tile: int) -> SortedLayout:
+    """Sort the pairs of ``expert_idx`` (T, k) by expert, stably, into groups
+    of whole ``tile``-row tiles; an expert without a pair still owns one
+    (all-padding) tile, so that its weight gradient is written."""
+    flat = expert_idx.reshape(-1).astype(jnp.int32)
+    pairs = flat.shape[0]
+    rows = -(-pairs // tile) * tile + num_experts * tile
+    sizes = jnp.bincount(flat, length=num_experts).astype(jnp.int32)
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)  # sorted position -> pair
+    tiles = jnp.maximum(-(-sizes // tile), 1)
+    tile_end = jnp.cumsum(tiles)
+    row_start = (tile_end - tiles) * tile  # a group's first row
+    pair_start = jnp.cumsum(sizes) - sizes  # its first sorted position
+    tile_group = jnp.minimum(
+        jnp.searchsorted(tile_end, jnp.arange(rows // tile, dtype=jnp.int32), side="right"),
+        num_experts - 1).astype(jnp.int32)
+    g_sorted = flat[order]
+    row_sorted = row_start[g_sorted] + jnp.arange(pairs, dtype=jnp.int32) - pair_start[g_sorted]
+    pair_row = jnp.zeros((pairs,), jnp.int32).at[order].set(row_sorted, unique_indices=True)
+    r = jnp.arange(rows, dtype=jnp.int32)
+    g_row = tile_group[r // tile]
+    off = r - row_start[g_row]
+    valid = off < sizes[g_row]
+    row_pair = jnp.where(valid, order[jnp.clip(pair_start[g_row] + off, 0, pairs - 1)], 0)
+    return SortedLayout(pair_row, row_pair, valid, tile_group, tile_end[-1:].astype(jnp.int32),
+                        sizes)
+
+
+@jax.custom_vjp
+def _dispatch(x, row_token, row_valid, pair_row):
+    """x (T, h) -> the row buffer (M, h): a pair's row is its token's activation,
+    padding rows are zero."""
+    return jnp.where(row_valid[:, None], x[row_token], jnp.zeros((), x.dtype))
+
+
+def _dispatch_fwd(x, row_token, row_valid, pair_row):
+    return _dispatch(x, row_token, row_valid, pair_row), (pair_row, x.shape[0])
+
+
+def _dispatch_bwd(res, g):
+    pair_row, tokens = res
+    dx = jnp.sum(g[pair_row].reshape(tokens, -1, g.shape[-1]).astype(jnp.float32), axis=1)
+    return dx.astype(g.dtype), None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(y, weights, pair_row, row_pair, row_valid):
+    """The row buffer y (M, h) and the combine weights (T, k) fp32 -> (T, h):
+    out[t] = sum_j weights[t, j] * y[row of pair (t, j)], summed in fp32."""
+    t, k = weights.shape
+    picked = y[pair_row].reshape(t, k, y.shape[-1]).astype(jnp.float32)
+    return jnp.sum(picked * weights[:, :, None], axis=1).astype(y.dtype)
+
+
+def _combine_fwd(y, weights, pair_row, row_pair, row_valid):
+    return _combine(y, weights, pair_row, row_pair, row_valid), (
+        y, weights, pair_row, row_pair, row_valid)
+
+
+def _combine_bwd(res, g):
+    y, weights, pair_row, row_pair, row_valid = res
+    t, k = weights.shape
+    w_row = jnp.where(row_valid, weights.reshape(-1)[row_pair], 0.0)
+    dy = (g[row_pair // k].astype(jnp.float32) * w_row[:, None]).astype(y.dtype)
+    # (reducing <y, g> per row and gathering scalars instead of re-gathering y
+    # measured slower on the chip: combine backward 5.65 against 5.19 ms, PR 28)
+    picked = y[pair_row].reshape(t, k, y.shape[-1]).astype(jnp.float32)
+    dw = jnp.sum(picked * g.astype(jnp.float32)[:, None, :], axis=-1)
+    return dy, dw, None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def grouped_gemm(lhs, rhs, layout: SortedLayout, tile: int):
+    """Rows of group g of ``lhs`` (M, K) by ``rhs[g]`` (E, K, N): the Pallas
+    kernels of ops/grouped_matmul.py, which beat ``jax.lax.ragged_dot`` 1.40x
+    at the OLMoE cell's shape (experiments/moe_gmm_bench.py swaps this name
+    for its candidates; PERF.md §6, PR 28)."""
+    from galvatron_tpu.ops.grouped_matmul import grouped_matmul
+
+    return grouped_matmul(lhs, rhs, layout.tile_group, layout.num_tiles, tile)
+
+
+def router_stats(probs: jax.Array, sizes: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """What the load-balancing loss needs of one layer, both (E,) fp32:
+    f_e = pairs of expert e over tokens (= sum_j f_{j,e}; not differentiated)
+    and P_e = mean of p_e over tokens."""
+    tokens = probs.shape[0]
+    return sizes.astype(jnp.float32) / tokens, jnp.mean(probs, axis=0)
+
+
+def load_balancing_loss(stats, num_experts: int) -> jax.Array:
+    """HF ``load_balancing_loss_func`` on the layers' ``router_stats``: the
+    layers' gate outputs are concatenated there, so f and P are means over
+    layers and tokens together: L_aux = E * sum_e f_e * P_e."""
+    f = jnp.mean(jnp.stack([s[0] for s in stats]), axis=0)
+    p = jnp.mean(jnp.stack([s[1] for s in stats]), axis=0)
+    return num_experts * jnp.sum(f * p)
+
+
+def load_max_over_mean(stats, num_experts: int, top_k: int) -> jax.Array:
+    """Largest expert's pairs over the even share T*k/E, the fullest layer's."""
+    f = jnp.stack([s[0] for s in stats])
+    return jnp.max(f) * num_experts / top_k
+
+
+def moe_topk_block(x: jax.Array, p: Params, cfg, tile: Optional[int] = None):
+    """Dropless top-k MoE MLP on (B, S, H) -> (y, router_stats).
+
+    On a multi-device mesh (``cfg.moe_token_shard_ctx``, installed by the
+    layer hook) the block runs under a ``shard_map`` over the axes the
+    activation is sharded on, with every expert's weights whole on every
+    device: routing is per token, so each device sorts and computes its own
+    tokens and only the statistics cross devices (a mean).  GSPMD cannot
+    partition the Mosaic kernels, and a global sort would gather every
+    token.  Devices that hold the same tokens (tp without sp) repeat the
+    work; expert parallelism is refused upstream (build_runtime)."""
+    ctx = cfg.moe_token_shard_ctx
+    if ctx is None:
+        return _topk_local(x, p, cfg, tile, ())
+    from jax.sharding import PartitionSpec as P
+
+    from galvatron_tpu.parallel.mesh import ambient_or, manual_axis_names
+
+    mesh, spec = ctx
+    over = tuple(a for e in spec if e is not None
+                 for a in ((e,) if isinstance(e, str) else e))
+    am = ambient_or(mesh)
+    return jax.shard_map(
+        lambda x_, p_: _topk_local(x_, p_, cfg, tile, over),
+        mesh=am, in_specs=(spec, P()), out_specs=(spec, (P(), P())),
+        axis_names=manual_axis_names(am), check_vma=False,
+    )(x, p)
+
+
+def _topk_local(x, p, cfg, tile, over):
+    """The block on the tokens this device holds; ``over``: the mesh axes the
+    tokens are split on (the statistics are means over all of them).  The
+    router's input, GEMM and softmax are fp32 whatever the compute dtype: a
+    bf16 logit flips a choice wherever two probabilities lie within a bf16 ulp."""
+    from galvatron_tpu.ops.grouped_matmul import TILE_M
+
+    tile = tile or TILE_M
+    b, s, h = x.shape
+    tokens, k, e = b * s, cfg.moe_top_k, cfg.moe_experts
+    xt = x.reshape(tokens, h)
+    with jax.named_scope("router"):
+        logits = xt.astype(jnp.float32) @ p["router"]["w"].astype(jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+    with jax.named_scope("dispatch"):
+        weights, idx = jax.lax.top_k(probs, k)  # weights: p's own values, not renormalised
+        layout = sorted_layout(idx, e, tile)
+        rows = _dispatch(xt, layout.row_pair // k, layout.row_valid, layout.pair_row)
+    with jax.named_scope("experts"):
+        w1, w3, w2 = (p[n].astype(x.dtype) for n in ("w1", "w3", "w2"))
+        gate = grouped_gemm(rows, w1, layout, tile)
+        up = grouped_gemm(rows, w3, layout, tile)
+        out = grouped_gemm(jax.nn.silu(gate) * up, w2, layout, tile)
+    with jax.named_scope("combine"):
+        y = _combine(out, weights, layout.pair_row, layout.row_pair, layout.row_valid)
+    stats = router_stats(probs, layout.sizes)
+    if over:
+        stats = tuple(jax.lax.pmean(s_, over) for s_ in stats)
+    return y.reshape(b, s, h), stats

@@ -1,0 +1,186 @@
+"""Grouped matrix multiplication for the dropless top-k MoE path: rows sorted by
+expert, one weight matrix an expert.
+
+The layout is *tile-aligned*: the caller (``models/moe.py``) places each
+expert's rows at a multiple of ``tile_m`` and pads the group to whole tiles
+with zero rows, so that every row tile belongs to exactly one expert.  The
+kernels are then plain tiled GEMMs whose weight block is chosen by a
+scalar-prefetched ``tile_group`` array: no row masks, no tile visited twice
+(a layout whose groups start anywhere re-visits one tile at every group
+boundary: 63 more at this PR's cell).  What the padding costs is at
+most ``tile_m`` rows a group, ``tile_m / 2`` on average.
+
+- ``moe_gmm``: ``out[rows of g] = lhs[rows of g] @ rhs[g]`` (and, with
+  ``transpose_rhs``, ``@ rhs[g].T``: the gradient of the left operand);
+- ``moe_tgmm``: ``out[g] = lhs[rows of g].T @ grad[rows of g]``, the
+  gradient of the weights; every group owns at least one tile, so every
+  output block is written.
+
+Row tiles past ``num_tiles`` (the static row count is an upper bound) are
+skipped: ``moe_gmm`` writes zeros there, ``moe_tgmm`` leaves them out.  The
+contraction is not tiled: at the widths this serves (hidden 2048, expert
+width 1024) a whole ``(tile_m, K)`` by ``(K, tile_n)`` product fits the
+scoped VMEM, and a weight block is then fetched once a group and not once a
+row tile.
+
+``grouped_matmul`` ties the three together with a custom VJP.  The
+``pl.pallas_call`` names are a contract (PERF.md §3): the benchmark's trace
+reduction finds the kernels by the prefix ``moe_gmm`` / ``moe_tgmm``.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+#: rows a tile: the alignment of a group's first row in the sorted layout.
+#: 256 over 128 / 512 / 1024 on the chip (PERF.md §6, PR 28): smaller tiles pad
+#: less (147,456 rows for 131,072 pairs over 64 experts, 512 gives 163,840),
+#: at 128 the kernels' rate begins to fall
+TILE_M = 256
+#: scoped VMEM the kernels ask for (the v5e default is 16 MiB of 128)
+_VMEM_LIMIT = 64 << 20
+
+
+def _use_interpret() -> bool:
+    return jax.default_backend() == "cpu"
+
+
+def _tile(n: int, want: int) -> int:
+    """Largest power-of-two multiple of 128 that divides ``n``, at most
+    ``want``; a width that none divides is one whole block (always legal)."""
+    t = want
+    while t >= 128:
+        if n % t == 0:
+            return t
+        t //= 2
+    return n
+
+
+def _params(*semantics):
+    return pltpu.CompilerParams(dimension_semantics=semantics, vmem_limit_bytes=_VMEM_LIMIT)
+
+
+def _gmm(lhs, rhs, tile_group, num_tiles, *, transpose_rhs: bool, tile_m: int, tile_n: int):
+    m, k = lhs.shape
+    n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    tn = _tile(n, tile_n)
+    dims = (((1,), (1,)), ((), ())) if transpose_rhs else (((1,), (0,)), ((), ()))
+
+    def kernel(group_ref, count_ref, lhs_ref, rhs_ref, out_ref):
+        del group_ref
+
+        @pl.when(pl.program_id(1) < count_ref[0])
+        def _():
+            out_ref[...] = jax.lax.dot_general(
+                lhs_ref[...], rhs_ref[...], dims,
+                preferred_element_type=jnp.float32).astype(out_ref.dtype)
+
+        @pl.when(pl.program_id(1) >= count_ref[0])
+        def _():
+            out_ref[...] = jnp.zeros_like(out_ref)
+
+    # a skipped tile names the last used tile's blocks, so nothing is fetched for it
+    def used(i, count):
+        return jnp.minimum(i, count[0] - 1)
+
+    rhs_block = (None, tn, k) if transpose_rhs else (None, k, tn)
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n // tn, m // tile_m),
+            in_specs=[
+                pl.BlockSpec((tile_m, k), lambda j, i, g, c: (used(i, c), 0)),
+                pl.BlockSpec(rhs_block, (lambda j, i, g, c: (g[used(i, c)], j, 0))
+                             if transpose_rhs else (lambda j, i, g, c: (g[used(i, c)], 0, j))),
+            ],
+            out_specs=pl.BlockSpec((tile_m, tn), lambda j, i, g, c: (i, j)),
+        ),
+        compiler_params=_params("parallel", "arbitrary"),
+        interpret=_use_interpret(),
+        name="moe_gmm_dlhs" if transpose_rhs else "moe_gmm",
+    )(tile_group, num_tiles, lhs, rhs)
+
+
+def _tgmm(lhs, grad, tile_group, num_tiles, num_groups: int, *, tile_m: int, tile_n: int,
+          out_dtype):
+    m, k = lhs.shape
+    n = grad.shape[1]
+    tk, tn = _tile(k, tile_n), _tile(n, tile_n)
+    tiles = m // tile_m
+
+    def kernel(group_ref, count_ref, lhs_ref, grad_ref, out_ref, acc_ref):
+        i = pl.program_id(2)
+        here = group_ref[i]
+        first = jnp.logical_or(i == 0, group_ref[jnp.maximum(i - 1, 0)] != here)
+        last = jnp.logical_or(i == tiles - 1, group_ref[jnp.minimum(i + 1, tiles - 1)] != here)
+
+        @pl.when(first)
+        def _():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        @pl.when(i < count_ref[0])
+        def _():
+            acc_ref[...] += jax.lax.dot_general(
+                lhs_ref[...], grad_ref[...], (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        @pl.when(last)
+        def _():
+            out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+    def used(i, count):
+        return jnp.minimum(i, count[0] - 1)
+
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((num_groups, k, n), out_dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(k // tk, n // tn, tiles),
+            in_specs=[
+                pl.BlockSpec((tile_m, tk), lambda a, b, i, g, c: (used(i, c), a)),
+                pl.BlockSpec((tile_m, tn), lambda a, b, i, g, c: (used(i, c), b)),
+            ],
+            out_specs=pl.BlockSpec((None, tk, tn), lambda a, b, i, g, c: (g[i], a, b)),
+            scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)],
+        ),
+        compiler_params=_params("parallel", "parallel", "arbitrary"),
+        interpret=_use_interpret(),
+        name="moe_tgmm",
+    )(tile_group, num_tiles, lhs, grad)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def grouped_matmul(lhs, rhs, tile_group, num_tiles, tile_m: int = TILE_M, tile_n: int = 1024):
+    """``lhs`` (M, K) by ``rhs`` (G, K, N) -> (M, N), row tile ``i`` (``tile_m``
+    rows) against ``rhs[tile_group[i]]``.  ``tile_group`` (M / tile_m,) int32 is
+    non-decreasing and names every group at least once; ``num_tiles`` (1,) int32
+    counts the leading tiles that hold rows, the rest come out zero.  Rows of a
+    tile beyond its group's size must be zero in ``lhs`` (they are multiplied
+    like any other)."""
+    return _gmm(lhs, rhs, tile_group, num_tiles, transpose_rhs=False,
+                tile_m=tile_m, tile_n=tile_n)
+
+
+def _fwd(lhs, rhs, tile_group, num_tiles, tile_m, tile_n):
+    out = grouped_matmul(lhs, rhs, tile_group, num_tiles, tile_m, tile_n)
+    return out, (lhs, rhs, tile_group, num_tiles)
+
+
+def _bwd(tile_m, tile_n, res, grad):
+    lhs, rhs, tile_group, num_tiles = res
+    dlhs = _gmm(grad, rhs, tile_group, num_tiles, transpose_rhs=True,
+                tile_m=tile_m, tile_n=tile_n)
+    drhs = _tgmm(lhs, grad, tile_group, num_tiles, rhs.shape[0], tile_m=tile_m,
+                 tile_n=tile_n, out_dtype=rhs.dtype)
+    return dlhs, drhs, None, None
+
+
+grouped_matmul.defvjp(_fwd, _bwd)

@@ -61,6 +61,11 @@ from galvatron_tpu.parallel.sharding import (
 )
 
 
+#: what a dropless top-k MoE model's train state carries of its last step
+#: (``state["moe_stats"]``), logged in the trainer's ``train_iter`` record
+MOE_STATS = ("moe_aux_loss", "moe_load_max_over_mean")
+
+
 def activation_spec(axes: MeshAxes, s: LayerStrategy) -> P:
     """(B, S, H) activation spec at a layer boundary."""
     bs = batch_spec(axes, s)
@@ -122,8 +127,11 @@ def state_specs(state_shape, cfg, hp, axes):
         "opt": {"mu": ospec, "nu": ospec, "count": P()},
         "step": P(),
     }
-    if "scaler" in state_shape:  # fp16 dynamic loss scale: replicated scalars
-        specs["scaler"] = jax.tree.map(lambda _: P(), state_shape["scaler"])
+    for key in ("scaler", "moe_stats"):
+        # replicated scalars: the fp16 dynamic loss scale; the last step's
+        # auxiliary loss and expert load of a dropless top-k MoE model
+        if key in state_shape:
+            specs[key] = jax.tree.map(lambda _: P(), state_shape[key])
     return specs
 
 
@@ -200,6 +208,11 @@ def _make_layer_hook(cfg: ModelConfig, hp: HybridParallelConfig, mesh: Mesh, axe
                     axes.ep_axes(s.tp, s.tp_consec, s.ep),
                     moe_token_axes(axes, s),
                 )
+            )
+        if cfg.moe_dropless and mesh.devices.size > 1:
+            # each device routes its own tokens — see moe.moe_topk_block
+            layer_cfg = layer_cfg.replace(
+                moe_token_shard_ctx=(mesh, activation_spec(axes, s))
             )
         if s.dp_type == "zero3" and s.tp > 1:
             # fsdp x tp wgrad shardings trip an SPMD partitioner fallback
@@ -344,6 +357,33 @@ def build_runtime(
                 "pack_sequences is not threaded through the interleaved "
                 "(vpp>1) schedule; use vpp=1 pipelines"
             )
+    if cfg.moe_dropless:
+        # the sorted-rows path keeps every expert on every device and hands its
+        # auxiliary loss up through the GSPMD step; what it does not implement
+        # is refused here, by name — nothing falls back to the one-hot dispatch
+        if any(s.ep > 1 for s in hp.layer_strategies):
+            raise ValueError(
+                "expert parallelism (ep>1) is not implemented for the dropless "
+                "top-k MoE path (moe_router='softmax_topk'): its sorted-row "
+                "grouped GEMM has no expert all-to-all yet; use ep=1"
+            )
+        if hp.pp > 1:
+            raise ValueError(
+                "pipeline parallelism (pp>1) is not implemented for the dropless "
+                "top-k MoE path (moe_router='softmax_topk'): the pipeline engines "
+                "carry no auxiliary loss between stages; use pp=1"
+            )
+        if any(s.cp > 1 for s in hp.layer_strategies):
+            raise ValueError(
+                "context parallelism (cp>1) is not implemented for the dropless "
+                "top-k MoE path (moe_router='softmax_topk'): the ring/Ulysses "
+                "layers hand no router statistics up; use cp=1"
+            )
+        if hp.mixed_precision == "fp16":
+            raise ValueError(
+                "fp16 loss scaling is not threaded through the dropless top-k MoE "
+                "objective (moe_router='softmax_topk'); use bf16 or fp32"
+            )
     seq_len = seq_len or cfg.sample_len
 
     # the strategy's activation-recompute mode rides the model config so
@@ -395,6 +435,45 @@ def build_runtime(
         raise ValueError(
             f"global batch {global_batch_size} not divisible by chunks {chunks}"
         )
+
+    def moe_grads_fn(params, batch):
+        """(loss, grads, aux) of a dropless top-k MoE model: ``loss`` is the
+        cross entropy alone (what is logged and checked); the gradient is that
+        of cross entropy + moe_aux_coef * L_aux, the auxiliary loss weighted
+        by each micro-batch's share of the loss tokens; ``aux`` holds the
+        step's ``moe_aux_loss`` (same weighting) and ``moe_load_max_over_mean``
+        (the fullest micro-batch's)."""
+        if chunks == 1:
+            def mean_objective(params):
+                s, n, aux = modeling.moe_loss_sum(params, batch, cfg, layer_hook=hook)
+                ce = s / jnp.maximum(n, 1)
+                return ce + cfg.moe_aux_coef * aux["moe_aux_loss"], (ce, aux)
+
+            (_, (loss, aux)), grads = jax.value_and_grad(mean_objective, has_aux=True)(params)
+            return loss, grads, aux
+        # micro-batches: sums, as grads_fn accumulates them
+        b = batch.shape[0]
+        mbs = batch.reshape(chunks, b // chunks, *batch.shape[1:])
+
+        def sum_objective(params, mb):
+            s, n, aux = modeling.moe_loss_sum(params, mb, cfg, layer_hook=hook)
+            n = n.astype(jnp.float32)
+            return s + cfg.moe_aux_coef * aux["moe_aux_loss"] * n, (s, n, aux)
+
+        def body(acc, mb):
+            (_, (s, n, aux)), g = jax.value_and_grad(sum_objective, has_aux=True)(params, mb)
+            acc_s, acc_n, acc_aux, acc_load, acc_g = acc
+            return (acc_s + s, acc_n + n, acc_aux + aux["moe_aux_loss"] * n,
+                    jnp.maximum(acc_load, aux["moe_load_max_over_mean"]),
+                    jax.tree.map(jnp.add, acc_g, g)), None
+
+        zero = (jnp.zeros((), jnp.float32),) * 4 + (
+            jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params),)
+        with jax.named_scope("grad_accum"):
+            (tot_s, tot_n, tot_aux, load, tot_g), _ = jax.lax.scan(body, zero, mbs)
+        denom = jnp.maximum(tot_n, 1.0)
+        aux = {"moe_aux_loss": tot_aux / denom, "moe_load_max_over_mean": load}
+        return tot_s / denom, jax.tree.map(lambda g: g / denom, tot_g), aux
 
     def grads_fn(params, batch, scale=None):
         """(loss, grads); with ``scale`` (fp16) the backward runs on
@@ -449,16 +528,18 @@ def build_runtime(
         if fp16:
             loss, grads = grads_fn(state["params"], batch, state["scaler"]["scale"])
             return apply_update_with_scaler(state, loss, grads, adam, scaler_cfg)
-        loss, grads = grads_fn(state["params"], batch)
+        if cfg.moe_dropless:
+            # the step's auxiliary loss and expert load ride the state, as the
+            # fp16 scaler does: (state, loss) stays the step's whole signature
+            # and the trainer reads them after the sync on the loss it makes anyway
+            loss, grads, moe_stats = moe_grads_fn(state["params"], batch)
+        else:
+            loss, grads = grads_fn(state["params"], batch)
         new_params, new_opt = adamw_update(state["params"], grads, state["opt"], adam)
-        return {"params": new_params, "opt": new_opt, "step": state["step"] + 1}, loss
-
-    def init_state(key):
-        params = modeling.init_model_params(key, cfg)
-        state = {"params": params, "opt": init_opt_state(params), "step": jnp.zeros((), jnp.int32)}
-        if fp16:
-            state["scaler"] = init_scaler_state(scaler_cfg)
-        return state
+        new_state = {"params": new_params, "opt": new_opt, "step": state["step"] + 1}
+        if cfg.moe_dropless:
+            new_state["moe_stats"] = moe_stats
+        return new_state, loss
 
     def state_from(params):
         state = {
@@ -468,7 +549,12 @@ def build_runtime(
         }
         if fp16:
             state["scaler"] = init_scaler_state(scaler_cfg)
+        if cfg.moe_dropless:
+            state["moe_stats"] = {k: jnp.zeros((), jnp.float32) for k in MOE_STATS}
         return state
+
+    def init_state(key):
+        return state_from(modeling.init_model_params(key, cfg))
 
     # shardings
     state_shape = jax.eval_shape(init_state, jax.random.key(0))

@@ -311,7 +311,7 @@ def profile_model(
     # NOT shard by ep (the param-fraction proxy overstated the ep win by
     # pricing it as shardable). Measured on-chip (experiments/ab_moe.py).
     moe_tfrac = None
-    if measure_time and cfg.moe_experts > 0:
+    if measure_time and cfg.moe_experts > 0 and not cfg.moe_dropless:
         try:
             f1 = cfg.ffn
             f2 = max(256, (f1 // 4 + 255) // 256 * 256)
@@ -369,7 +369,8 @@ def profile_model(
                 boundary_activation_mb_per_sample=float(boundary_mb),
                 moe_expert_param_fraction=float(moe_frac),
                 moe_a2a_mb_per_sample=float(moe_a2a),
-                moe_expert_time_fraction=moe_tfrac,
+                moe_expert_time_fraction=0.0 if cfg.moe_dropless else moe_tfrac,
+                moe_untp_time_fraction=theoretical.moe_untp_time_fraction(cfg, seq),
             )
         },
         other_param_mb=float(other_param_count(cfg) * 4 / 1e6),

@@ -78,6 +78,12 @@ class ProfiledLayerType:
     # profiling/model.py's two-point ffn fit (experiments/ab_moe.py,
     # BASELINE.md round-5).
     moe_expert_time_fraction: Optional[float] = None
+    # Dropless top-k MoE layers (moe.moe_topk_block): the share of the fwd
+    # time spent in the routed MLP, which tensor parallelism does NOT divide —
+    # every device runs whole experts on the tokens it holds. Sequence
+    # parallelism splits the tokens over the tp axes and so does divide it;
+    # plain tp repeats the work on every tp rank. 0 → dense or switch layer.
+    moe_untp_time_fraction: float = 0.0
 
     def __post_init__(self):
         if not (0.0 <= self.moe_expert_param_fraction < 1.0):
@@ -652,8 +658,10 @@ def layer_time_cost(
         if lt.moe_expert_time_fraction is not None
         else frac
     )
+    nfrac = lt.moe_untp_time_fraction
     per_sample = lt.fwd_ms_per_sample * (
-        (1.0 - tfrac) / s.tp + tfrac / (s.tp * max(1, s.ep))
+        (1.0 - tfrac - nfrac) / s.tp + tfrac / (s.tp * max(1, s.ep))
+        + nfrac / (s.tp if s.sp else 1)
     )
     fwd = per_sample * local_bsz
     factor = (

@@ -234,6 +234,21 @@ class SearchEngine:
                 vocab_size=space.vocab_size
                 or int(getattr(model_config, "vocab_size", 0) or 0),
             )
+        # standing exclusions of the MODEL, reported with every result: the
+        # dropless top-k MoE path runs neither expert nor context parallelism
+        # nor pipeline stages (build_runtime refuses them by name), so the
+        # enumeration leaves them out instead of emitting a plan that cannot run
+        self._standing: List[str] = []
+        if model_config is not None and getattr(model_config, "moe_dropless", False):
+            self.space = space = dataclasses.replace(
+                space, allow_ep=False, allow_cp=False, pp_choices=[1])
+            self._standing = ["dropless_topk_moe_no_ep", "dropless_topk_moe_no_cp",
+                              "dropless_topk_moe_no_pp"]
+            print(
+                "search: dropless top-k MoE model — expert parallelism (ep>1), context "
+                "parallelism (cp>1) and pipeline stages (pp>1) are not implemented for its "
+                "sorted-row path and are left out of the enumeration"
+            )
         # structural bail-outs that fired during the last sweep (multi-type
         # schedule/shape classes the engines cannot realize) — written into
         # the emitted config as `search_restrictions` the way
@@ -791,7 +806,7 @@ class SearchEngine:
                             yield r
 
     def _active_restrictions(self) -> List[str]:
-        return sorted(self._restrictions)
+        return sorted(self._restrictions | set(self._standing))
 
     def search_topk(
         self, global_bsz_list: Sequence[int], k: int, max_chunks: int = 64,

@@ -32,6 +32,29 @@ def moe_expert_params(cfg: ModelConfig) -> int:
     return cfg.moe_experts * mats * cfg.hidden_size * cfg.ffn
 
 
+def layer_active_param_count(cfg: ModelConfig) -> int:
+    """Weights ONE token is multiplied by in a layer — what its time scales
+    with. A dropless top-k MoE layer holds E experts (layer_param_count, its
+    memory) and runs ``moe_top_k`` of them a token, plus the router; every
+    other layer runs all it holds."""
+    p = layer_param_count(cfg)
+    if cfg.moe_dropless:
+        p -= (cfg.moe_experts - cfg.moe_top_k) * 3 * cfg.hidden_size * cfg.ffn
+    return p
+
+
+def moe_untp_time_fraction(cfg: ModelConfig, seq_len: int) -> float:
+    """Share of a dropless top-k MoE layer's forward FLOPs in its routed MLP
+    (router + k experts a token): what tensor parallelism does not divide
+    (cost_model.ProfiledLayerType.moe_untp_time_fraction). 0 for other layers."""
+    if not cfg.moe_dropless:
+        return 0.0
+    h = cfg.hidden_size
+    routed = 2.0 * (h * cfg.moe_experts + cfg.moe_top_k * 3 * h * cfg.ffn)
+    total = 2.0 * layer_active_param_count(cfg) + 4.0 * cfg.num_heads * cfg.head_dim * seq_len
+    return routed / total
+
+
 def layer_param_count(cfg: ModelConfig, cross: bool = False) -> int:
     """Exact per-layer parameter count (matches init_layer_params).
     ``cross``: enc-dec decoder layers carry a cross-attention block
@@ -50,6 +73,8 @@ def layer_param_count(cfg: ModelConfig, cross: bool = False) -> int:
     else:
         mlp = 2 * h * cfg.ffn
     norms = 2 * h if cfg.norm_type == "rms" else 4 * h
+    if cfg.qk_norm:
+        norms += q_out + kv_out
     bias = 0
     if cfg.use_bias:  # qkv slots + wo (+ dense-MLP biases; MoE MLPs carry none)
         bias = 3 * q_out + h
@@ -136,7 +161,12 @@ def layer_activation_mb_per_sample(
     qkv = (n + 2 * kvn) * hd * b / tp
     ctx = n * hd * b / tp
     recompute = getattr(cfg, "mlp_recompute", "policy") in ("gate", "policy")
-    if cfg.moe_experts > 0:
+    if cfg.moe_dropless:
+        # every one of a token's k pairs keeps its row in and out (2h) and
+        # its gate, up and product (3f) for the backward; the experts run
+        # whole on every device (moe.moe_topk_block), so tp divides nothing
+        mlp = cfg.moe_top_k * (2 * h + 3 * cfg.ffn) * b
+    elif cfg.moe_experts > 0:
         mlp = 3 * cfg.ffn * b / tp  # per routed token (capacity ~1); the
         # recompute policy excludes MoE layers (modeling.mlp_residual)
     elif cfg.act_fn == "swiglu":
@@ -169,7 +199,9 @@ def analytic_model_costs(
     S = seq_len or cfg.max_seq_len
     b = _BYTES[mixed_precision]
     p_layer = layer_param_count(cfg)
-    flops = 2.0 * p_layer * S  # fwd multiply-accumulate per sample
+    # fwd multiply-accumulate per sample: the weights a token meets (a top-k
+    # MoE layer: k experts and the router, not all E it holds in memory)
+    flops = 2.0 * layer_active_param_count(cfg) * S
     if cfg.attn_impl == "xla" or cfg.attn_impl == "flash":
         flops += 2.0 * 2.0 * cfg.num_heads * cfg.head_dim * S * S  # qk^T + pv
     fwd_ms = flops / (peak_tflops * 1e12 * mfu) * 1e3
@@ -200,6 +232,10 @@ def analytic_model_costs(
                 boundary_activation_mb_per_sample=S * cfg.hidden_size * b / 1e6,
                 moe_expert_param_fraction=frac,
                 moe_a2a_mb_per_sample=a2a,
+                # a dropless layer has no ep to shard its time by (the search
+                # leaves ep out for it); its routed share is what tp leaves whole
+                moe_expert_time_fraction=0.0 if cfg.moe_dropless else None,
+                moe_untp_time_fraction=moe_untp_time_fraction(cfg, S),
             )
         },
         other_param_mb=other_p * 4 / 1e6,

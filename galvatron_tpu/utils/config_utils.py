@@ -66,6 +66,7 @@ def save_profiled_model(costs: ProfiledModelCosts, time_path=None, mem_path=None
                 "moe_expert_param_fraction": lt.moe_expert_param_fraction,
                 "moe_a2a_mb_per_sample": lt.moe_a2a_mb_per_sample,
                 "moe_expert_time_fraction": lt.moe_expert_time_fraction,
+                "moe_untp_time_fraction": lt.moe_untp_time_fraction,
             }
         mem["other"] = {
             "param_mb": costs.other_param_mb,
@@ -135,6 +136,7 @@ def _load_layer_type(t, m) -> ProfiledLayerType:
             if m.get("moe_expert_time_fraction") is None
             else float(m["moe_expert_time_fraction"])
         ),
+        moe_untp_time_fraction=float(m.get("moe_untp_time_fraction", 0.0)),
     )
 
 

@@ -28,6 +28,8 @@ KERNEL_NAMES = {
     "flash_paged_decode": "flash_attention.py",
     "fused_norm_rms_fwd": "fused_norm.py", "fused_norm_rms_bwd": "fused_norm.py",
     "fused_norm_ln_fwd": "fused_norm.py", "fused_norm_ln_bwd": "fused_norm.py",
+    "moe_gmm": "grouped_matmul.py", "moe_gmm_dlhs": "grouped_matmul.py",
+    "moe_tgmm": "grouped_matmul.py",
 }
 
 
@@ -61,7 +63,8 @@ def test_every_pallas_call_has_a_name_from_the_table(name):
     assert name in found[KERNEL_NAMES[name]]
     # and nothing outside the table: a new kernel joins it, with its metric
     assert {n for names in found.values() for n in names} == set(KERNEL_NAMES)
-    assert all(n.startswith(("flash_fwd", "flash_bwd", "flash_paged", "fused_norm_"))
+    assert all(n.startswith(("flash_fwd", "flash_bwd", "flash_paged", "fused_norm_",
+                             "moe_gmm", "moe_tgmm"))
                for n in KERNEL_NAMES)
 
 
@@ -99,6 +102,46 @@ def test_compiled_step_carries_the_scope(toy_step_text, scope):
 
     names = re.findall(r'op_name="([^"]*)"', toy_step_text)
     assert any(re.search(rf"[/(]{scope}[/)]", n) for n in names), scope
+
+
+#: what a dropless top-k MoE layer with qk-norm adds below ``mlp`` and ``attn``
+MOE_SCOPES = ("router", "dispatch", "experts", "combine", "qk_norm")
+
+
+@pytest.fixture(scope="module")
+def olmoe_step_text():
+    """Lowered text (with locations: the scopes) of the one-layer OLMoE step,
+    tiny widths, as ``cli train --model_size olmoe-1b-7b --num_layers 1`` builds it."""
+    from galvatron_tpu.core.checkpoint import abstract_state_of
+    from galvatron_tpu.core.optim import AdamConfig
+    from galvatron_tpu.core.strategy import HybridParallelConfig
+    from galvatron_tpu.models.modeling import PRESETS
+    from galvatron_tpu.parallel.hybrid import build_runtime
+    from galvatron_tpu.parallel.mesh import build_mesh
+
+    cfg = PRESETS["olmoe-1b-7b"].replace(vocab_size=128, hidden_size=64, num_layers=1,
+                                         num_heads=2, ffn_dim=32, max_seq_len=32,
+                                         moe_experts=8, moe_top_k=2)
+    hp = HybridParallelConfig.uniform(1, mixed_precision="fp32")
+    mesh, axes = build_mesh(pp=1, devices=jax.devices()[:1])
+    rt = build_runtime(cfg, hp, mesh=mesh, axes=axes, adam=AdamConfig(lr=1e-3),
+                       global_batch_size=4, seq_len=32)
+    batch = jax.ShapeDtypeStruct((4, 33), jnp.int32, sharding=rt.batch_sharding)
+    return rt.train_step.lower(abstract_state_of(rt), batch).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("scope", MOE_SCOPES)
+def test_lowered_olmoe_step_carries_the_scope(olmoe_step_text, scope):
+    """Each new scope is in the lowered step, forward and backward, below the
+    scope ``benchmark/lib/scoped.py`` knows (``mlp``; ``attn`` for ``qk_norm``)."""
+    import re
+
+    parent = "attn" if scope == "qk_norm" else "mlp"
+    names = set(re.findall(r'"(jit\(train_step\)[^"]*)"', olmoe_step_text))
+    mine = [n for n in names if re.search(rf"/{parent}/(?:[^/\"]+/)*{scope}/", n)]
+    assert mine, scope
+    assert any("transpose(" in n for n in mine), f"{scope}: no backward operation carries it"
+    assert all("layer_0" in n for n in mine)
 
 
 def test_backward_is_marked_by_transpose(toy_step_text):
@@ -308,11 +351,33 @@ def test_traced_train_logs_the_profile_window(traced_run):
     assert not tracer.enabled and not tracer.profiling and tracer._on_gc not in gc.callbacks
 
 
+def test_train_iter_records_of_a_topk_moe_run_carry_aux_loss_and_load(tmp_path):
+    """``moe_aux_loss`` and ``moe_load_max_over_mean`` ride the ``train_iter``
+    record of a dropless top-k MoE model, beside a ``loss`` that is the cross
+    entropy alone; a dense model's record has neither."""
+    from galvatron_tpu.core.arguments import initialize_galvatron
+    from galvatron_tpu.core.trainer import train
+
+    mpath = str(tmp_path / "m.jsonl")
+    out = train(initialize_galvatron("train", [
+        "--model_size", "olmoe-1b-7b", "--num_layers", "1", "--hidden_size", "64",
+        "--num_heads", "2", "--ffn_dim", "32", "--moe_experts", "8", "--vocab_size", "128",
+        "--seq_length", "32", "--global_train_batch_size", "8", "--mixed_precision", "fp32",
+        "--train_iters", "3", "--metrics_path", mpath]), verbose=False)
+    assert out["runtime"].cfg.moe_dropless and out["runtime"].cfg.moe_top_k == 8
+    iters = [r for r in map(json.loads, open(mpath)) if r["event"] == "train_iter"]
+    assert len(iters) == 3
+    for r in iters:
+        assert 0.5 < r["moe_aux_loss"] < 64 and r["moe_load_max_over_mean"] >= 1.0
+        assert abs(r["loss"] - np.log(128)) < 1.0  # the cross entropy, no auxiliary term in it
+
+
 def test_train_iter_records_lost_the_derived_wait(traced_run):
     _, records = traced_run
     iters = [r for r in records if r["event"] == "train_iter"]
     assert len(iters) == 6
-    assert not any(k in r for r in iters for k in ("comm_wait_ms", "bubble_fraction"))
+    assert not any(k in r for r in iters for k in (
+        "comm_wait_ms", "bubble_fraction", "moe_aux_loss", "moe_load_max_over_mean"))
 
 
 # ---------------------------------------------------------------------------

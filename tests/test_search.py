@@ -636,3 +636,54 @@ def test_spmd_crash_guard_keeps_safe_vocab_tp_choices():
     r = eng.evaluate(2, 16, 4, "pipedream_flush")
     assert r is not None
     assert not _crash_cell(r.config)
+
+
+# -- dropless top-k MoE (OLMoE-class): what the sorted path cannot run is left out ----
+
+
+def test_cli_search_on_olmoe_returns_a_plan_without_ep_cp_or_pp(tmp_path, capsys):
+    """`cli search --model_size olmoe-1b-7b --num_devices 4 --analytic_costs 1` returns a
+    plan; with expert and context parallelism switched ON and every pp allowed, the plan
+    still has none of them (build_runtime would refuse it), the exclusions are named on
+    the console and in the plan, and the plan passes the plan checker. Six layers: what a
+    four-chip host holds of this model (2.7 B parameters x 16 B = 44 GB of 64)."""
+    from galvatron_tpu import cli
+
+    path = str(tmp_path / "plan.json")
+    rc = cli.main(["search", "--model_size", "olmoe-1b-7b", "--num_layers", "6",
+                   "--num_devices", "4", "--analytic_costs", "1", "--enable_ep", "1",
+                   "--enable_cp", "1", "--min_bsz", "8", "--max_bsz", "8",
+                   "--output_config_path", path])
+    assert rc in (0, None)
+    said = capsys.readouterr().out
+    assert "ep>1" in said and "cp>1" in said and "pp>1" in said
+    plan = json.load(open(path))
+    assert plan["pp_deg"] == 1
+    assert set(plan["ep_sizes_enc"].split(",")) == {"1"}
+    assert set(plan.get("cp_sizes_enc", "1").split(",")) == {"1"}
+    assert plan["search_restrictions"] == [
+        "dropless_topk_moe_no_cp", "dropless_topk_moe_no_ep", "dropless_topk_moe_no_pp"]
+    assert plan["model_config"]["moe_experts"] == 64 and plan["model_config"]["num_layers"] == 6
+    hp = HybridParallelConfig.load(path)
+    hp.validate(4)
+    assert cli.main(["check-plan", path]) in (0, None)
+
+
+def test_enumeration_for_a_topk_moe_model_has_no_ep_cp_or_pp():
+    from galvatron_tpu.models.modeling import PRESETS
+    from galvatron_tpu.search.theoretical import analytic_model_costs
+
+    cfg = PRESETS["olmoe-1b-7b"].replace(num_layers=2)
+    space = SearchSpace(world_size=8, allow_ep=True, allow_cp=True, moe_experts=64)
+    assert any(s.ep > 1 for s in generate_layer_strategies(space, 1))  # the space did allow it
+    eng = SearchEngine(analytic_model_costs(cfg, seq_len=512), ProfiledHardware(), num_layers=2,
+                       space=space, memory_budget_mb=64000.0, model_config=cfg)
+    assert eng.space.pp_choices == [1] and not eng.space.allow_ep and not eng.space.allow_cp
+    assert space.allow_ep  # the caller's space is copied, never mutated
+    assert not any(s.ep > 1 or s.cp > 1 for s in generate_layer_strategies(eng.space, 1))
+    results = eng.search_topk([8], k=20, max_chunks=4)
+    assert results
+    for r in results:
+        assert r.config.pp == 1
+        assert all(s.ep == 1 and s.cp == 1 for s in r.config.layer_strategies)
+        assert "dropless_topk_moe_no_ep" in r.details["search_restrictions"]

@@ -52,10 +52,10 @@ def real_mosaic(monkeypatch):
     written to the cache but cannot be read back without a chip, and the
     next run would warn on every such entry."""
     from galvatron_tpu.aot.cache import persistent_cache_off
-    from galvatron_tpu.ops import flash_attention, fused_norm
+    from galvatron_tpu.ops import flash_attention, fused_norm, grouped_matmul
     from galvatron_tpu.parallel import ring
 
-    for mod in (flash_attention, fused_norm, ring):
+    for mod in (flash_attention, fused_norm, grouped_matmul, ring):
         monkeypatch.setattr(mod, "_use_interpret", lambda: False)
     with persistent_cache_off():
         yield
@@ -262,6 +262,76 @@ def test_one_chip_train_step_scopes_cover_its_work(topo, real_mosaic):
     mosaic = [r for r in rows if r[0].startswith(("flash_fwd", "flash_bwd"))]
     assert mosaic and all("attn_core" in op for _, op in mosaic), mosaic
     assert any("transpose(" in op for _, op in mosaic)  # the backward, marked for free
+
+
+# --- OLMoE at its published widths: dropless top-8 of 64, sort + grouped GEMM ----
+
+_OLMOE_STEP = {}
+
+
+def _olmoe_step(topo):
+    """(compiled text, memory analysis) of the step `olmoe-1b-7b_s4096` trains:
+    one OLMoE layer at published widths, batch 4 x 4096 = 16,384 tokens =
+    131,072 routed pairs over 64 experts, forward + backward + Adam."""
+    from galvatron_tpu.core.strategy import HybridParallelConfig
+    from galvatron_tpu.models.modeling import PRESETS
+
+    if not _OLMOE_STEP:
+        cfg = PRESETS["olmoe-1b-7b"].replace(num_layers=1, attn_impl="flash")
+        assert (cfg.hidden_size, cfg.num_heads, cfg.ffn, cfg.vocab_size, cfg.max_seq_len,
+                cfg.moe_experts, cfg.moe_top_k, cfg.qk_norm) == (
+                    2048, 16, 1024, 50304, 4096, 64, 8, True)
+        hp = HybridParallelConfig.uniform(1, mixed_precision="bf16")
+        compiled, ma = _compile(cfg, hp, topo.devices[:1], bsz=4, seq=4096)
+        _OLMOE_STEP.update(text=compiled.as_text(), ma=ma)
+    return _OLMOE_STEP["text"], _OLMOE_STEP["ma"]
+
+
+def test_olmoe_step_compiles_and_fits_one_chip(topo, real_mosaic):
+    """A grouped GEMM the chip's compiler refuses, or a step that outgrows 16 GB
+    at batch 4 (the cell would then have to take batch 2), shows here."""
+    _, ma = _olmoe_step(topo)
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    assert total < HBM_V5E_GIB * 2**30, f"{total / 2**30:.2f} GiB"
+
+
+def test_olmoe_step_names_its_kernels_and_scopes(topo, real_mosaic):
+    """The grouped GEMM kernels keep their ``name=`` as instruction names and
+    sit under ``mlp/experts``, forward and backward; every new scope reaches
+    the compiled ENTRY; the attention is the RoPE stacked-qkv flash family."""
+    text, _ = _olmoe_step(topo)
+    rows = _entry_work(text)
+    gemms = [(n, op) for n, op in rows if n.startswith(("moe_gmm", "moe_tgmm"))]
+    kinds = sorted(n.split(".")[0] for n, _ in gemms)
+    # forward gate, up, down; backward a dlhs and a tgmm for each
+    assert kinds == ["moe_gmm"] * 3 + ["moe_gmm_dlhs"] * 3 + ["moe_tgmm"] * 3, kinds
+    assert all("/mlp/" in op and "experts" in op for _, op in gemms), gemms
+    assert sum("transpose(" in op for _, op in gemms) == 6
+    ops = [op for _, op in rows]
+    for scope in ("router", "dispatch", "experts", "combine", "qk_norm"):
+        assert any(f"/{scope}/" in op for op in ops), scope
+    assert sorted(set(_kernel_names(text))) == ["flash_bwd_blocked", "flash_fwd_qkv"]
+    # (at this size the compiler adds buffer-placement custom calls that carry
+    # no op_name and do no work: left out of the count)
+    work = [r for r in rows if r[1] or not r[0].startswith("custom-call")]
+    scoped_rows = [r for r in work if _has_scope(r[1])]
+    assert len(scoped_rows) >= 0.9 * len(work), (len(scoped_rows), len(work))
+
+
+def test_olmoe_block_partitions_on_four_chips(topo, real_mosaic):
+    """Under a data-parallel mesh each device routes its own tokens inside a
+    ``shard_map``: the Mosaic kernels compile for four chips and keep their names."""
+    from galvatron_tpu.core.strategy import HybridParallelConfig
+    from galvatron_tpu.models.modeling import PRESETS
+
+    cfg = PRESETS["olmoe-1b-7b"].replace(num_layers=1, attn_impl="flash", vocab_size=1024,
+                                         max_seq_len=512)
+    hp = HybridParallelConfig.uniform(1, dp_type="zero3", mixed_precision="bf16")
+    compiled, _ = _compile(cfg, hp, topo.devices, bsz=8, seq=512)
+    names = [n for n, _ in _entry_work(compiled.as_text())]
+    assert sum(n.startswith(("moe_gmm", "moe_tgmm")) for n in names) == 9, names[:40]
+    assert not any(n.startswith("shard_map") for n in names)
 
 
 def test_flash_multichip_compile_smoke(topo, real_mosaic):
