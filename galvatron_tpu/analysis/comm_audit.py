@@ -892,7 +892,7 @@ def resharding_lint(
                 f"{sum(c.count for c in monolith)} monolithic tp-group "
                 "collective site(s)",
                 hint="ops/collective_matmul did not fire (shape/dtype gate?) "
-                "— the plan's TP_OVERLAP_RESIDUAL pricing is unearned",
+                "— the credit the search gave it (cost_model.tp_overlap_exposed) is unearned",
                 field=f"tp_overlap_flags[{overlap_layers[0]}]", source=source,
             ))
     return diags

@@ -154,7 +154,6 @@ def main(argv: Optional[List[str]] = None, model_default: Optional[str] = None) 
             allow_strided=not ns.disable_tp_consec,
             allow_cp=bool(ns.enable_cp),
             allow_ep=bool(ns.enable_ep),
-            allow_tp_overlap=bool(getattr(ns, "enable_tp_overlap", 0)),
             max_ep=ns.max_ep_deg,
             moe_experts=cfg.moe_experts,
             max_vpp=ns.max_vpp_deg,

@@ -334,11 +334,6 @@ def _add_search_args(p: argparse.ArgumentParser):
     g.add_argument("--enable_cp", type=int, default=0)
     g.add_argument("--enable_ep", type=int, default=0,
                    help="search expert parallelism (MoE models)")
-    g.add_argument("--enable_tp_overlap", type=int, default=0,
-                   help="enumerate the collective-matmul tp_overlap variant "
-                   "on tp>1 layers (doubles those cells of the space; the "
-                   "cost model prices the overlapped tp time at "
-                   "TP_OVERLAP_RESIDUAL)")
     g.add_argument("--max_ep_deg", type=int, default=8)
     g.add_argument("--max_tp_deg", type=int, default=8)
     g.add_argument("--max_vpp_deg", type=int, default=1,

@@ -231,11 +231,12 @@ def _train_impl(ns: argparse.Namespace, verbose: bool, tracer,
         from galvatron_tpu.parallel.mesh import build_mesh
 
         mesh, axes = build_mesh(pp=hp.pp, num_slices=ns.num_slices)
-    with tracer.span("build_runtime"):
+    with tracer.span("build_runtime") as build_span:
         rt = build_runtime(
             cfg, hp, mesh=mesh, axes=axes, adam=adam,
             global_batch_size=ns.global_train_batch_size, seq_len=seq,
         )
+        build_span.set(tp_overlap_seams=rt.tp_overlap_seams)
 
     from galvatron_tpu.obs import tracing as obs_tracing
     from galvatron_tpu.utils.metrics import SCHEMA_VERSION, MetricsLogger
@@ -270,6 +271,9 @@ def _train_impl(ns: argparse.Namespace, verbose: bool, tracer,
         # appended, so a perf delta across manifests is attributable
         "xla_overlap": getattr(ns, "xla_overlap", "off"),
         "xla_overlap_flags": list(getattr(ns, "xla_overlap_applied", []) or []),
+        # projection seams of the plan's tp_overlap layers that run the
+        # collective-matmul ring / the plain einsum (the ring's shape test)
+        "tp_overlap_seams": rt.tp_overlap_seams,
     }
     # JAX's persistent compile cache is always on, at the one place
     # resolve_compile_cache_dir names (JAX_COMPILATION_CACHE_DIR, else an

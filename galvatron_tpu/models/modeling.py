@@ -947,12 +947,14 @@ def _proj_up(subscripts, x, w, cfg: ModelConfig, w_shard_dim: int):
     )
 
 
-def _proj_down(subscripts, x, w, cfg: ModelConfig, w_shard_dim: int):
+def _proj_down(subscripts, x, w, cfg: ModelConfig, w_shard_dim: int, activation=None):
     """Row-parallel projection einsum (wo, MLP down). With tp_overlap_ctx
     installed the trailing TP reduction is pipelined as the accumulator-ring
     reduce-scatter⊗matmul (ops.collective_matmul): sp layers keep the
     seq-scattered output layout; non-sp layers gather it back (the reduce
-    half of the all-reduce still overlaps)."""
+    half of the all-reduce still overlaps). ``activation`` (only with
+    tp_overlap_ctx): applied to ``x`` inside the seam, which then keeps ``x``
+    and recomputes the activation in its backward (mlp_block)."""
     if cfg.tp_overlap_ctx is None:
         if isinstance(w, QuantTensor):
             return qeinsum(subscripts, x, w)
@@ -964,8 +966,37 @@ def _proj_down(subscripts, x, w, cfg: ModelConfig, w_shard_dim: int):
     mesh, dp_ax, tp_ax, sp = cfg.tp_overlap_ctx
     return cm.einsum_reducescatter(
         subscripts, x, w, mesh=mesh, dp_axes=dp_ax, tp_axes=tp_ax,
-        w_shard_dim=w_shard_dim, scatter_output=bool(sp),
+        w_shard_dim=w_shard_dim, scatter_output=bool(sp), activation=activation,
     )
+
+
+def projection_seams(cfg: ModelConfig, seq_len: int) -> Tuple[Tuple[str, str, int, bool], ...]:
+    """The projections of one token-stream layer that go through ``_proj_up`` /
+    ``_proj_down``, as ``(scope, kind, width, blockwise)``: ``kind`` "ag"
+    (column-parallel, ``width`` its output columns) or "rs" (row-parallel,
+    ``width`` its contraction), the width being what the tp axes divide;
+    ``blockwise`` False where the seam's all-gather side (an "ag" seam's
+    forward, an "rs" seam's backward) puts out head-major dims and so gathers
+    whole (ops.collective_matmul._allgather_matmul). What the ring's shape
+    test (ops.collective_matmul.ring_pays) is asked about by the runtime's
+    ``tp_overlap_seams`` count and by the search's pricing; mirrors the
+    dispatch in ``attn_block`` / ``_attn_block_headmajor`` / ``mlp_block``."""
+    from galvatron_tpu.ops.flash_attention import flash_tileable
+
+    seams = []
+    headmajor = (
+        cfg.attn_impl == "flash" and cfg.pos_embed != "alibi" and cfg.flash_headmajor
+        and not cfg.pack_sequences and not cfg.image_size and flash_tileable(seq_len)
+        and (cfg.qkv_blocked or not (cfg.use_bias or cfg.qk_norm))
+    )
+    if headmajor:
+        if cfg.qkv_blocked:
+            seams.append(("qkv_proj", "ag", 3 * cfg.num_heads * cfg.head_dim, False))
+        seams.append(("out_proj", "rs", cfg.num_heads * cfg.head_dim, False))
+    if cfg.moe_experts == 0 and not cfg.image_size:
+        up = cfg.ffn * (2 if cfg.act_fn == "swiglu" else 1)
+        seams += [("mlp_up", "ag", up, True), ("mlp_down", "rs", cfg.ffn, True)]
+    return tuple(seams)
 
 
 def _constrain_qkv(qkv, cfg: ModelConfig):
@@ -1160,6 +1191,9 @@ def attn_block(x, p, cfg: ModelConfig, cos_sin=None, alibi=None, remat_attn: boo
         return attn_output(o, p, cfg, x.dtype)
 
 
+_gelu_tanh = partial(jax.nn.gelu, approximate=True)  # one object: it keys the seam's programs
+
+
 @jax.named_scope("mlp")
 def mlp_block(x, p, cfg: ModelConfig, train: bool = True):
     """SwiGLU or GeLU MLP (reference: ParallelMLP, galvatron/core/
@@ -1211,18 +1245,27 @@ def mlp_block(x, p, cfg: ModelConfig, train: bool = True):
             prod = jax.checkpoint(prod)
         y = down(prod(g), p["w2"].astype(x.dtype))
     else:
-        g = up(x, p["w1"].astype(x.dtype))
+        overlap = cfg.tp_overlap_ctx is not None and x.ndim == 3
+        # a tp_overlap layer leaves mlp_residual's policy region (it would
+        # rerun the up projection's ring in the backward and re-derive the
+        # seams' programs in every layer) and saves what the region saves
+        # without one: its seams take the weights as stored and cast them
+        # inside their programs (no compute-dtype copy kept for the backward),
+        # and the row-parallel seam applies the activation itself and keeps
+        # g, not act(g)
+        g = up(x, p["w1"] if overlap else p["w1"].astype(x.dtype))
         if "w1_b" in p:
             g = g + p["w1_b"].astype(x.dtype)
         g = checkpoint_name(g, "mlp_gate")
-        act = jax.nn.relu if cfg.act_fn == "relu" else partial(
-            jax.nn.gelu, approximate=True
-        )
-        if cfg.mlp_recompute == "gate" or (
-            cfg.mlp_recompute == "policy" and cfg.fused_norm
-        ):
-            act = jax.checkpoint(act)
-        y = down(act(g), p["w2"].astype(x.dtype))
+        act = jax.nn.relu if cfg.act_fn == "relu" else _gelu_tanh
+        if overlap:
+            y = _proj_down("bsf,fh->bsh", g, p["w2"], cfg, w_shard_dim=0, activation=act)
+        else:
+            if cfg.mlp_recompute == "gate" or (
+                cfg.mlp_recompute == "policy" and cfg.fused_norm
+            ):
+                act = jax.checkpoint(act)
+            y = down(act(g), p["w2"].astype(x.dtype))
     if "w2_b" in p:
         y = y + p["w2_b"].astype(x.dtype)
     return y
@@ -1248,7 +1291,12 @@ def mlp_residual(x, p, cfg: ModelConfig, train: bool = True):
         with jax.named_scope("mlp"):
             y, stats = moe.moe_topk_block(normed, p["mlp"], cfg)
         return x + y, stats
-    if cfg.mlp_recompute == "policy" and cfg.moe_experts == 0 and not cfg.fused_norm:
+    if (
+        cfg.mlp_recompute == "policy" and cfg.moe_experts == 0 and not cfg.fused_norm
+        # a tp_overlap layer's down seam saves the gate itself (mlp_block);
+        # SwiGLU's halves are not device-local, so it keeps the region
+        and (cfg.tp_overlap_ctx is None or cfg.act_fn == "swiglu")
+    ):
         # _norm_impl, not norm: the policy region already remats everything
         # unnamed — a nested per-norm checkpoint would only add bookkeeping.
         # fused_norm layers keep the plain branch (the Pallas kernels carry

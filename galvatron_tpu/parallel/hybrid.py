@@ -56,6 +56,7 @@ from galvatron_tpu.parallel.sharding import (
     overlap_grad_sync,
     param_spec,
     sharding_tree,
+    tp_overlap_seam_counts,
     with_flash_shard_ctx,
     with_tp_overlap_ctx,
 )
@@ -159,6 +160,11 @@ class HybridParallelRuntime:
     # resume works across pipeline degrees/schedules (core/checkpoint.py).
     flatten_params: Callable = None
     restack_params: Callable = None
+    # {"ring": n, "plain": m}: projection seams of the plan's tp_overlap layers
+    # that take the collective-matmul ring / stay the plain einsum
+    # (sharding.tp_overlap_seam_counts); the trainer puts it in the run's
+    # fingerprint and on the build_runtime span
+    tp_overlap_seams: Any = None
 
     def shard_batch(self, batch_np):
         """Global on-device batch from a (host-replicated) numpy batch.
@@ -291,11 +297,34 @@ def _make_layer_hook(cfg: ModelConfig, hp: HybridParallelConfig, mesh: Mesh, axe
                 seg_ids=seg_ids,
             )
 
+        if (
+            layer_cfg.tp_overlap_ctx is not None and not cfg.swin_depths and not is_encoder
+            and enc_out is None and seg_ids is None
+        ):
+            # layers of one plan entry are one program: see _decoder_layer_once
+            return _decoder_layer_once(x, lp, cos_sin, alibi, cfg=layer_cfg, ckpt=s.ckpt)
         if s.ckpt == "full":
             run = jax.checkpoint(run)
         return run(x, lp)
 
     return hook
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "ckpt"))
+def _decoder_layer_once(x, lp, cos_sin, alibi, *, cfg: ModelConfig, ckpt):
+    """A tp_overlap decoder layer through ``jax.jit``: layers with the same
+    configuration and strategy (all 24 of the four-chip cell) are traced,
+    differentiated and lowered once, not once each — the rings' unrolled steps
+    are the longest Python a layer has, and set-up time is gated. The caller's
+    ``layer_<i>`` scope stays around the call."""
+
+    def run(x_, lp_):
+        return modeling.decoder_layer(
+            x_, lp_, cfg, cos_sin, alibi, remat_attn=(ckpt == "selective"))
+
+    if ckpt == "full":
+        run = jax.checkpoint(run)
+    return run(x, lp)
 
 
 def build_runtime(
@@ -404,26 +433,30 @@ def build_runtime(
         cfg = cfg.replace(dtype=jnp.float16)
         scaler_cfg = LossScalerConfig()
 
+    seams = tp_overlap_seam_counts(cfg, hp, mesh, axes, global_batch_size, seq_len)
     if hp.pp > 1:
         if cfg.swin_depths:
             from galvatron_tpu.parallel.pipeline_swin import (
                 build_swin_pipeline_runtime,
             )
 
-            return build_swin_pipeline_runtime(
+            rt = build_swin_pipeline_runtime(
                 cfg, hp, mesh, axes, adam, global_batch_size, seq_len
             )
-        if cfg.enc_layers > 0:
+        elif cfg.enc_layers > 0:
             from galvatron_tpu.parallel.pipeline_encdec import (
                 build_encdec_pipeline_runtime,
             )
 
-            return build_encdec_pipeline_runtime(
+            rt = build_encdec_pipeline_runtime(
                 cfg, hp, mesh, axes, adam, global_batch_size, seq_len
             )
-        from galvatron_tpu.parallel.pipeline import build_pipeline_runtime
+        else:
+            from galvatron_tpu.parallel.pipeline import build_pipeline_runtime
 
-        return build_pipeline_runtime(cfg, hp, mesh, axes, adam, global_batch_size, seq_len)
+            rt = build_pipeline_runtime(cfg, hp, mesh, axes, adam, global_batch_size, seq_len)
+        rt.tp_overlap_seams = seams
+        return rt
 
     hook = _make_layer_hook(cfg, hp, mesh, axes)
 
@@ -580,7 +613,7 @@ def build_runtime(
         cfg=cfg, hp=hp, mesh=mesh, axes=axes, adam=adam,
         train_step=jit_train, eval_loss=jit_eval, init_state=jit_init,
         state_shardings=shardings, batch_sharding=batch_sharding,
-        init_state_from=jit_state_from,
+        init_state_from=jit_state_from, tp_overlap_seams=seams,
     )
 
 

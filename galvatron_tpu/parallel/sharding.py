@@ -29,7 +29,7 @@ import jax
 
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from galvatron_tpu.core.strategy import LayerStrategy
+from galvatron_tpu.core.strategy import HybridParallelConfig, LayerStrategy
 from galvatron_tpu.parallel.mesh import MeshAxes
 
 Annotation = Tuple[Optional[str], ...]
@@ -160,6 +160,37 @@ def with_tp_overlap_ctx(layer_cfg, s: LayerStrategy, mesh: Mesh, axes: MeshAxes)
             bool(s.sp),
         )
     )
+
+
+def tp_overlap_seam_counts(
+    cfg, hp: HybridParallelConfig, mesh: Mesh, axes: MeshAxes,
+    global_batch_size: int, seq_len: int,
+) -> dict:
+    """``{"ring": n, "plain": m}`` over the plan's ``tp_overlap`` layers: how
+    many projection seams take the collective-matmul ring and how many stay
+    the plain einsum, by the shape test the seams themselves apply to a
+    micro-batch (ops.collective_matmul.ring_pays; non-sp layers have no
+    all-gather to decompose, so their column-parallel seams are plain)."""
+    from galvatron_tpu.models.modeling import projection_seams
+    from galvatron_tpu.ops.collective_matmul import ring_pays, tp_group_size
+
+    counts = {"ring": 0, "plain": 0}
+    itemsize = 4 if hp.mixed_precision == "fp32" else 2
+    seams = projection_seams(cfg, seq_len)
+    micro = global_batch_size // max(1, hp.chunks)
+    for s in hp.layer_strategies:
+        if with_tp_overlap_ctx(cfg, s, mesh, axes) is cfg:
+            continue
+        dp = tp_group_size(mesh, axes.dp_axes(s.tp, s.tp_consec, s.cp))
+        rows = micro // dp * (seq_len // s.tp)
+        for _, kind, width, _ in seams:
+            ring = (
+                (s.sp or kind == "rs")
+                and seq_len % s.tp == 0 and width % s.tp == 0 and micro % dp == 0
+                and ring_pays(s.tp, rows, width // s.tp, itemsize)
+            )
+            counts["ring" if ring else "plain"] += 1
+    return counts
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))

@@ -84,6 +84,11 @@ class ProfiledLayerType:
     # parallelism splits the tokens over the tp axes and so does divide it;
     # plain tp repeats the work on every tp rank. 0 → dense or switch layer.
     moe_untp_time_fraction: float = 0.0
+    # The layer's projection seams that can run the collective-matmul ring,
+    # as (kind, width the tp axes divide, rows a sample, blockwise) — see
+    # modeling.projection_seams; what tp_overlap_exposed prices s.tp_overlap
+    # from. Empty (a profile without the model's shapes): no credit.
+    tp_seams: tuple = ()
 
     def __post_init__(self):
         if not (0.0 <= self.moe_expert_param_fraction < 1.0):
@@ -604,14 +609,6 @@ def other_time_cost(
 # recompute — re-measure in ONE place.
 REMAT_FULL_FACTOR = 3.85
 REMAT_SELECTIVE_FACTOR = 3.25
-# Residual fraction of the blocking TP-collective time that survives when the
-# layer runs the decomposed collective-matmul (s.tp_overlap — ops/
-# collective_matmul.py): the ring hides T-1 of T hops behind the GEMM chunks,
-# leaving the first hop, the per-chunk launch overhead, and (non-sp) the
-# output-gather half exposed. ASPLOS'23 (Wang et al.) reports 60-80% of the
-# collective hidden on TPU ICI for transformer projection shapes; priced
-# conservatively until a measured profile replaces it.
-TP_OVERLAP_RESIDUAL = 0.4
 # Comm-volume conventions the analytic terms below price — named (instead of
 # inline literals) because analysis/comm_audit.py replays them as
 # ``comm_volume_breakdown`` and gates predicted-vs-lowered fidelity on the
@@ -621,6 +618,35 @@ TP_BOUNDARY_COLLECTIVES = 4.0  # Megatron f/g: 2 fwd + 2 bwd boundary allreduces
 REMAT_TP_REPLAY = 1.5  # full-remat forward replay repeats the 2 fwd collectives
 ZERO3_GATHER_PASSES = 2.0  # fwd + bwd param all-gathers per iteration
 GRAD_REDUCE_FP32_FACTOR = 2.0  # grads reduce at fp32 = 2x the bf16 wire bytes
+
+
+def tp_overlap_exposed(
+    lt: ProfiledLayerType, s: LayerStrategy, local_bsz: float, itemsize: int
+) -> float:
+    """Share of a layer's TP-collective time left exposed when it runs the
+    decomposed collective-matmul (s.tp_overlap — ops/collective_matmul.py),
+    from the shape test the ring itself applies: the layer's eight boundary
+    collectives (TP_BOUNDARY_COLLECTIVES all-reduces = four seams, forward
+    and backward, each moving one (b, s, h) activation) are exposed in full
+    where the seam stays the plain einsum, and by what its piece GEMMs do
+    not cover where it takes the ring. Non-sp layers get no credit: only
+    their row-parallel seams decompose, and no chip has measured that."""
+    from galvatron_tpu.ops.collective_matmul import exposed_share
+
+    if not (s.tp_overlap and s.tp > 1 and s.sp):
+        return 1.0
+    slots = 2.0 * TP_BOUNDARY_COLLECTIVES
+    exposed = slots
+    for kind, width, rows_per_sample, blockwise in lt.tp_seams:
+        rows = int(local_bsz * rows_per_sample) // s.tp
+        # forward, backward as (is it the all-gather ring, GEMMs on each piece
+        # in hand): a seam that is not blockwise gathers whole on its
+        # all-gather side, exposed in full
+        directions = ((True, 1), (False, 1)) if kind == "ag" else ((False, 1), (True, 2))
+        for allgather, gemms in directions:
+            if blockwise or not allgather:
+                exposed -= 1.0 - exposed_share(s.tp, rows, width // s.tp, itemsize, gemms)
+    return max(0.0, exposed) / slots
 
 
 def layer_time_cost(
@@ -681,10 +707,11 @@ def layer_time_cost(
     tp_ms = TP_BOUNDARY_COLLECTIVES * _allreduce_ms(act_msg, s.tp, tp_bw)
     if s.ckpt == "full" or recompute_factor is not None:
         tp_ms *= REMAT_TP_REPLAY  # forward-replay schedules replay the fwd collectives
-    if s.tp_overlap and s.tp > 1:
-        # decomposed collective-matmul pipelines the projection collectives
-        # behind the GEMM chunks — only the residual exposure is priced
-        tp_ms *= TP_OVERLAP_RESIDUAL
+    # decomposed collective-matmul pipelines the projection collectives
+    # behind the GEMM chunks — only what stays exposed is priced
+    tp_ms *= tp_overlap_exposed(
+        lt, s, local_bsz, 2 if mixed_precision in ("bf16", "fp16") else 4
+    )
     # (selective recompute replays no TP collectives: the attention core sits
     # between the column- and row-parallel linears)
     # CP: the ring rotates K/V cp-1 hops per pass (the diagonal hop is

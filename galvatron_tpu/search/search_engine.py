@@ -37,6 +37,7 @@ from galvatron_tpu.search.cost_model import (
     ProfiledModelCosts,
     layer_memory_cost,
     layer_time_cost,
+    tp_overlap_exposed,
     other_memory_cost,
     other_time_cost,
     pipeline_time_cost,
@@ -55,11 +56,6 @@ class SearchSpace:
     allow_zero3: bool = True
     allow_strided: bool = True
     allow_cp: bool = False
-    # decomposed collective-matmul on the TP projection seams as a searched
-    # dimension (LayerStrategy.tp_overlap; cost_model.TP_OVERLAP_RESIDUAL
-    # prices the hidden collective). Opt-in: it doubles the tp>1 candidate
-    # count and only helps where the projection collectives are exposed.
-    allow_tp_overlap: bool = False
     # expert parallelism as a searched dimension (MoE models; the reference
     # carries SwitchMLP but never searches EP — SURVEY §2.3 ⚠). ep candidates
     # ∈ powers of two up to the dp extent (and max_ep) that divide
@@ -160,15 +156,18 @@ def generate_layer_strategies(space: SearchSpace, pp: int) -> List[LayerStrategy
                 and (space.max_ep is None or e <= space.max_ep)
                 and space.moe_experts % e == 0
             ]
-        tov_opts = [False, True] if (space.allow_tp_overlap and tp > 1) else [False]
+        # the decomposed collective-matmul (LayerStrategy.tp_overlap) on every
+        # sequence-parallel tp>1 layer: cost_model.tp_overlap_exposed prices
+        # it from the ring's own shape test, so it wins only where a seam
+        # takes the ring (sp excludes cp, whose layers own their seams)
         for consec, sp, dpt, cp, ep, tov in itertools.product(
-            consec_opts, sp_opts, dp_types, cp_opts, ep_opts, tov_opts
+            consec_opts, sp_opts, dp_types, cp_opts, ep_opts, [False, True]
         ):
             if cp > 1 and sp:
                 continue
             if cp > 1 and ep > 1:  # they share mesh axes (strategy.validate)
                 continue
-            if cp > 1 and tov:  # cp layers own their projection seams
+            if tov and not (sp and tp > 1):
                 continue
             for ckpt in [False, True] if space.allow_ckpt else [False]:
                 out.append(
@@ -220,6 +219,17 @@ class SearchEngine:
         # emitted plan is validated against the model before it is written
         self.model_config = model_config
         self.model_name = model_name
+        from galvatron_tpu.models.modeling import ModelConfig, projection_seams
+
+        if isinstance(model_config, ModelConfig):
+            # the shapes cost_model.tp_overlap_exposed prices s.tp_overlap from
+            # (a profile carries times and sizes, not the projections' widths)
+            seq = int(model_config.max_seq_len)
+            seams = tuple((k, w, seq, blk) for _, k, w, blk in projection_seams(model_config, seq))
+            self.costs = dataclasses.replace(model_costs, layer_types={
+                i: lt if lt.tp_seams else dataclasses.replace(lt, tp_seams=seams)
+                for i, lt in model_costs.layer_types.items()
+            })
         if model_config is not None:
             # model divisibility constraints on the candidate space: a tp
             # that cannot split the heads or a vocab_tp that cannot shard
@@ -323,7 +333,24 @@ class SearchEngine:
             dp = world // (pp * s.tp * s.cp)
             return (global_bsz % (dp * chunks * max(1, s.cp))) == 0
 
-        return [s for s in generate_layer_strategies(self.space, pp) if feasible(s)]
+        cands = [s for s in generate_layer_strategies(self.space, pp) if feasible(s)]
+        # of a (plain, tp_overlap) pair the memory model prices alike, one
+        # dominates wherever every layer type agrees: the ring where a seam
+        # takes it (never slower), the plain layer where none does (so that
+        # the plan says what runs). Half the pair goes before the DP sees it.
+        itemsize = 2 if self.mp in ("bf16", "fp16") else 4
+        drop = set()
+        for s in cands:
+            if not s.tp_overlap:
+                continue
+            local_bsz = global_bsz / (world // (pp * s.tp * s.cp))
+            credit = {tp_overlap_exposed(lt, s, local_bsz, itemsize) < 1.0
+                      for lt in self.costs.layer_types.values()}
+            if credit == {True}:
+                drop.add(dataclasses.replace(s, tp_overlap=False))
+            elif credit == {False}:
+                drop.add(s)
+        return [s for s in cands if s not in drop]
 
     def _boundary_msg_mb(self, lt, global_bsz: int, chunks: int) -> float:
         """Per-micro-batch p2p boundary volume (comm-dtype bytes)."""

@@ -6,7 +6,14 @@ backward (the VJP of the AG ring is the RS ring and vice versa — a schedule
 bug shows up as a permuted-chunk output or a wrong-chunk gradient, both
 caught by allclose against the reference). Runs on the suite's virtual
 8-device CPU mesh; tp in {1, 2, 4} x both tp_consec layouts covers single-
-axis and multi-axis (tuple ppermute) rings.
+axis and multi-axis (tuple ppermute) rings. With four devices the ring is
+two-way (half chunks in opposite directions); with two, or a chunk of odd
+length, one-way.
+
+The toy shapes are far below what the ring's shape test (``ring_pays``)
+lets through, so every test but the shape test's own switches it off
+(``always_ring``): a seam that fell back in silence would prove nothing,
+and the parity cases assert ``collective_permute`` in the lowered text.
 """
 
 import jax
@@ -25,13 +32,194 @@ def _mesh_axes(tp, consec):
     return mesh, axes.dp_axes(tp, consec), axes.tp_axes(tp, consec)
 
 
-def _rand(key, shape):
-    return jnp.asarray(np.random.RandomState(key).standard_normal(shape), jnp.float32)
+def _rand(key, shape, dtype=jnp.float32):
+    return jnp.asarray(np.random.RandomState(key).standard_normal(shape), dtype)
+
+
+@pytest.fixture
+def always_ring(monkeypatch):
+    """The ring wherever it can be formed, whatever the shapes."""
+    monkeypatch.setattr(cm, "ring_pays", lambda tp, *a, **k: tp > 1)
+
+
+#: the four projection seams of a layer (modeling._proj_up / _proj_down), at toy
+#: sizes: name -> (entry point, subscripts, x shape, w shape, w_shard_dim)
+N, HD = 4, 2
+SEAMS = {
+    "qkv_blocked": (cm.allgather_einsum, "bsh,hcnd->bcnsd", (B, S, H), (H, 3, N, HD), 2),
+    "mlp_up": (cm.allgather_einsum, "bsh,hf->bsf", (B, S, H), (H, F), 1),
+    "out_proj": (cm.einsum_reducescatter, "bnsd,nde->bse", (B, N, S, HD), (N, HD, H), 0),
+    "mlp_down": (cm.einsum_reducescatter, "bsf,fh->bsh", (B, S, F), (F, H), 0),
+}
+#: against the plain einsum of the same dtype: float32 differs by summation
+#: order only; bfloat16 rounds each partial sum's hop once more (8 mantissa
+#: bits: 2^-7 of the largest value per rounding, sums of 8-12 unit normals)
+TOL = {jnp.float32: 1e-5, jnp.bfloat16: 0.25}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["fp32", "bf16"])
+@pytest.mark.parametrize("order", ["flat", "gray"])
+@pytest.mark.parametrize("consec", [True, False])
+@pytest.mark.parametrize("tp", [2, 4])
+@pytest.mark.parametrize("seam", sorted(SEAMS))
+def test_ring_matches_einsum(seam, tp, consec, order, dtype, always_ring, monkeypatch):
+    """Forward and both gradients of every seam on the ring (two-way at tp 4)
+    against the plain einsum, in the flattened-index order and in the order a
+    2x2's coordinates give (0 -> 1 -> 3 -> 2)."""
+    if order == "gray":
+        monkeypatch.setattr(
+            cm, "mesh_ring_order", lambda mesh, tpa: (0, 1, 3, 2)[:tp] if tp == 4 else (0, 1))
+    entry, sub, x_shape, w_shape, w_shard_dim = SEAMS[seam]
+    mesh, dp, tpa = _mesh_axes(tp, consec)
+    x, w = _rand(10, x_shape, dtype), _rand(11, w_shape, dtype)
+
+    def run(x, w):
+        return entry(sub, x, w, mesh=mesh, dp_axes=dp, tp_axes=tpa, w_shard_dim=w_shard_dim)
+
+    def ref(x, w):
+        return jnp.einsum(sub, x, w)
+
+    tol = TOL[dtype]
+    f32 = lambda a: np.asarray(a, np.float32)  # noqa: E731
+    np.testing.assert_allclose(f32(run(x, w)), f32(ref(x, w)), atol=tol)
+    loss = lambda fn: lambda x, w: jnp.sum(jnp.sin(fn(x, w).astype(jnp.float32)))  # noqa: E731
+    # a ring in at least one direction of every seam (the two whose all-gather
+    # side puts out head-major dims gather whole there: qkv forward, out_proj backward)
+    text = jax.jit(jax.grad(loss(run), argnums=(0, 1))).lower(x, w).as_text()
+    assert "collective_permute" in text
+    assert ("all_gather" in text) == (seam in ("qkv_blocked", "out_proj"))
+    for got, want in zip(jax.grad(loss(run), argnums=(0, 1))(x, w),
+                         jax.grad(loss(ref), argnums=(0, 1))(x, w)):
+        assert got.dtype == want.dtype
+        np.testing.assert_allclose(f32(got), f32(want), atol=tol * 2)
+
+
+@pytest.mark.parametrize("scatter", [True, False])
+def test_reducescatter_seam_applies_and_recomputes_its_activation(scatter, always_ring):
+    """``activation=``: the row-parallel seam multiplies act(x), keeps x, and
+    differentiates through the activation it recomputes (modeling.mlp_block
+    hands it the MLP's gate) — against the plain einsum of act(x)."""
+    mesh, dp, tpa = _mesh_axes(4, True)
+    x, w = _rand(16, (B, S, F)), _rand(17, (F, H))
+
+    def run(x, w):
+        return cm.einsum_reducescatter(
+            "bsf,fh->bsh", x, w, mesh=mesh, dp_axes=dp, tp_axes=tpa, w_shard_dim=0,
+            scatter_output=scatter, activation=jax.nn.relu)
+
+    def ref(x, w):
+        return jnp.einsum("bsf,fh->bsh", jax.nn.relu(x), w)
+
+    np.testing.assert_allclose(run(x, w), ref(x, w), atol=1e-5)
+    loss = lambda fn: lambda x, w: jnp.sum(jnp.sin(fn(x, w)))  # noqa: E731
+    for got, want in zip(jax.grad(loss(run), argnums=(0, 1))(x, w),
+                         jax.grad(loss(ref), argnums=(0, 1))(x, w)):
+        np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+@pytest.mark.parametrize("entry", ["allgather", "reducescatter"])
+def test_seam_takes_the_weight_as_stored(entry, always_ring):
+    """A float32 weight under bfloat16 activations (the master parameter, which
+    modeling.mlp_block hands a tp_overlap layer's seams uncast): cast inside the
+    seam's programs, the result in the activations' dtype, the weight's
+    gradient in the weight's."""
+    mesh, dp, tpa = _mesh_axes(4, True)
+    if entry == "allgather":
+        sub, x, w, dim, fn = "bsh,hf->bsf", _rand(18, (B, S, H), jnp.bfloat16), _rand(19, (H, F)), 1, cm.allgather_einsum
+    else:
+        sub, x, w, dim, fn = "bsf,fh->bsh", _rand(18, (B, S, F), jnp.bfloat16), _rand(19, (F, H)), 0, cm.einsum_reducescatter
+
+    def run(x, w):
+        return fn(sub, x, w, mesh=mesh, dp_axes=dp, tp_axes=tpa, w_shard_dim=dim)
+
+    def ref(x, w):
+        return jnp.einsum(sub, x, w.astype(x.dtype))
+
+    assert run(x, w).dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(run(x, w), np.float32), np.asarray(ref(x, w), np.float32),
+                               atol=TOL[jnp.bfloat16])
+    loss = lambda f: lambda x, w: jnp.sum(f(x, w).astype(jnp.float32))  # noqa: E731
+    (dx, dw), (rx, rw) = (jax.grad(loss(f), argnums=(0, 1))(x, w) for f in (run, ref))
+    assert (dx.dtype, dw.dtype) == (jnp.bfloat16, jnp.float32)
+    np.testing.assert_allclose(np.asarray(dx, np.float32), np.asarray(rx, np.float32), atol=0.5)
+    np.testing.assert_allclose(dw, rw, atol=0.5)
+
+
+def test_one_way_ring_for_an_odd_chunk(always_ring):
+    """tp 4 with a chunk of odd length cannot be halved: whole chunks one way."""
+    mesh, dp, tpa = _mesh_axes(4, True)
+    x, w = _rand(12, (B, 12, H)), _rand(13, (H, F))  # 12 / 4 = 3 rows a chunk
+    run = lambda x, w: cm.allgather_einsum(  # noqa: E731
+        "bsh,hf->bsf", x, w, mesh=mesh, dp_axes=dp, tp_axes=tpa, w_shard_dim=1)
+    assert jax.jit(run).lower(x, w).as_text().count("collective_permute") == 3
+    np.testing.assert_allclose(run(x, w), jnp.einsum("bsh,hf->bsf", x, w), atol=1e-5)
+
+
+COORDS_2X2 = [(0, 0, 0), (1, 0, 0), (0, 1, 0), (1, 1, 0)]  # jax.devices() order of a v5e 2x2
+
+
+@pytest.mark.parametrize("case", ["2x2", "two_groups", "no_coords", "line", "pair"])
+def test_ring_order_is_all_nearest_neighbours(case):
+    """Pure function of the coordinates: every hop of the order is one link in
+    every group; without coordinates, or without such a cycle, 0..T-1."""
+    groups = {
+        "2x2": [COORDS_2X2],
+        # tp 4 x dp 2 on a 2x4: both groups are 2x2 blocks, one ppermute serves both
+        "two_groups": [COORDS_2X2, [(x + 2, y, z) for x, y, z in COORDS_2X2]],
+        "no_coords": [[None] * 4],
+        "line": [[(i, 0, 0) for i in range(4)]],  # no wraparound link: no cycle
+        "pair": [[(0, 0, 0), (1, 0, 0)]],
+    }[case]
+    order = cm.ring_order(groups)
+    T = len(groups[0])
+    assert sorted(order) == list(range(T))
+    if case in ("2x2", "two_groups"):
+        for g in groups:
+            for p in range(T):
+                a, b = g[order[p]], g[order[(p + 1) % T]]
+                assert sum(abs(i - j) for i, j in zip(a, b)) == 1, (order, a, b)
+        assert order != tuple(range(T))  # 1 -> 2 and 3 -> 0 are diagonals of the 2x2
+    else:
+        assert order == tuple(range(T))
+
+
+def test_mesh_ring_order_falls_back_on_cpu_devices():
+    mesh, _, tpa = _mesh_axes(4, True)
+    assert cm.mesh_ring_order(mesh, tpa) == (0, 1, 2, 3)
+
+
+@pytest.mark.parametrize("side", ["ring", "narrow", "few_rows", "tp1"])
+def test_shape_test_two_sides(side):
+    """``ring_pays``: the opt-1.3b MLP seam of the four-chip cell takes the
+    ring (so does its attention output projection, local contraction 512:
+    the narrowest measured to win); a seam a quarter as wide, a chunk too
+    short to fill the MXU in halves, and tp 1 do not. ``exposed_share``
+    prices the same decision."""
+    assert cm.ring_pays(4, 2048, 512, 2)
+    tp, rows, width = {"ring": (4, 2048, 2048), "narrow": (4, 2048, 128),
+                       "few_rows": (4, 256, 2048), "tp1": (1, 2048, 2048)}[side]
+    assert cm.ring_pays(tp, rows, width, 2) == (side == "ring")
+    share = cm.exposed_share(tp, rows, width, 2)
+    assert (share < 1.0) == (side == "ring") and share >= 0.0
+    if side == "ring":
+        # two GEMMs on each piece in hand (the backward of a row-parallel seam)
+        assert cm.exposed_share(tp, rows, width, 2, backward_gemms=2) <= share
+        assert cm.hop_cover(2, width, 2) == cm.hop_cover(4, width, 2) / 2  # one way at tp 2
+
+
+def test_seams_below_the_shape_test_stay_plain():
+    """Without ``always_ring`` the toy shapes lower to the plain einsum: no
+    shard_map, no permute (what every one-chip and narrow layer keeps)."""
+    mesh, dp, tpa = _mesh_axes(4, True)
+    x, w = _rand(14, (B, S, H)), _rand(15, (H, F))
+    text = jax.jit(lambda x, w: cm.allgather_einsum(
+        "bsh,hf->bsf", x, w, mesh=mesh, dp_axes=dp, tp_axes=tpa, w_shard_dim=1)).lower(x, w).as_text()
+    assert "collective_permute" not in text and "shard_map" not in text
 
 
 @pytest.mark.parametrize("consec", [True, False])
 @pytest.mark.parametrize("tp", [1, 2, 4])
-def test_allgather_einsum_matches_einsum(tp, consec):
+def test_allgather_einsum_matches_einsum(tp, consec, always_ring):
     mesh, dp, tpa = _mesh_axes(tp, consec)
     x, w = _rand(0, (B, S, H)), _rand(1, (H, F))
     ref = jnp.einsum("bsh,hf->bsf", x, w)
@@ -54,7 +242,7 @@ def test_allgather_einsum_matches_einsum(tp, consec):
 @pytest.mark.parametrize("scatter", [True, False])
 @pytest.mark.parametrize("consec", [True, False])
 @pytest.mark.parametrize("tp", [1, 2, 4])
-def test_einsum_reducescatter_matches_einsum(tp, consec, scatter):
+def test_einsum_reducescatter_matches_einsum(tp, consec, scatter, always_ring):
     mesh, dp, tpa = _mesh_axes(tp, consec)
     x, w = _rand(2, (B, S, F)), _rand(3, (F, H))
     ref = jnp.einsum("bsf,fh->bsh", x, w)
@@ -75,7 +263,7 @@ def test_einsum_reducescatter_matches_einsum(tp, consec, scatter):
 
 
 @pytest.mark.parametrize("consec", [True, False])
-def test_blocked_qkv_shape_einsum(consec):
+def test_blocked_qkv_shape_einsum(consec, always_ring):
     """The 4-operand qkv seam: 'bsh,hcnd->bcnsd' with the head dim sharded
     (w_shard_dim=2) — exercises output-shape derivation for subscripts where
     the sharded letter is neither first nor last."""
@@ -90,7 +278,7 @@ def test_blocked_qkv_shape_einsum(consec):
     np.testing.assert_allclose(out, ref, atol=1e-5)
 
 
-def test_indivisible_shapes_fall_back():
+def test_indivisible_shapes_fall_back(always_ring):
     """seq or shard dims the ring does not divide take the plain-einsum path
     (and still produce the right answer) instead of crashing shard_map."""
     tp = 4
@@ -110,7 +298,7 @@ def test_indivisible_shapes_fall_back():
 
 
 @pytest.mark.parametrize("sp", [True, False])
-def test_train_step_parity_with_tp_overlap(sp):
+def test_train_step_parity_with_tp_overlap(sp, always_ring):
     """End-to-end: the same model + data trains to the same losses with the
     collective-matmul decomposition on and off (fp32, tp=4 over the 8-device
     mesh) — the dispatch seams in modeling._proj_up/_proj_down change only

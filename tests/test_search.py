@@ -141,35 +141,56 @@ def test_strategy_space_generation():
 
 
 def test_tp_overlap_enumeration_and_pricing():
-    """allow_tp_overlap doubles only the tp>1 cells (never tp==1, never
-    cp>1 — the plan checker would reject tp==1 as GTA018), and the cost
-    model prices the overlapped variant strictly cheaper on any layer that
-    pays TP communication."""
+    """tp_overlap is enumerated on every sequence-parallel tp>1 cell without
+    being asked (never tp==1, which the plan checker rejects as GTA018, never
+    without sp, never cp>1), and the cost model prices it from the ring's own
+    shape test: strictly cheaper where a seam's piece GEMM covers its hop,
+    the plain price where none does or the profile carries no shapes."""
     import dataclasses
 
-    from galvatron_tpu.search.cost_model import (
-        TP_OVERLAP_RESIDUAL, layer_time_cost,
-    )
+    from galvatron_tpu.search.cost_model import layer_time_cost, tp_overlap_exposed
 
     space = SearchSpace(world_size=8)
-    base = generate_layer_strategies(space, pp=1)
-    assert not any(s.tp_overlap for s in base)  # opt-in: default space unchanged
-    space.allow_tp_overlap = True
     cands = generate_layer_strategies(space, pp=1)
     assert any(s.tp_overlap and s.tp > 1 for s in cands)
-    assert not any(s.tp_overlap and (s.tp == 1 or s.cp > 1) for s in cands)
-    assert 0.0 < TP_OVERLAP_RESIDUAL < 1.0
-    lt, hw = toy_costs().layer_types[0], toy_hw()
+    assert not any(s.tp_overlap and (s.tp == 1 or s.cp > 1 or not s.sp) for s in cands)
+    assert {(s.tp, s.tp_consec, s.dp_type, s.ckpt) for s in cands if s.sp and s.tp_overlap} == {
+        (s.tp, s.tp_consec, s.dp_type, s.ckpt) for s in cands if s.sp and not s.tp_overlap}
+    hw = toy_hw()
+    seams = {  # (kind, width the tp axes divide, rows a sample, blockwise)
+        "wide": (("ag", 6144, 2048, False), ("rs", 2048, 2048, False),
+                 ("ag", 8192, 2048, True), ("rs", 8192, 2048, True)),
+        "narrow": (("ag", 768, 2048, False), ("rs", 256, 2048, False),
+                   ("ag", 1024, 2048, True), ("rs", 1024, 2048, True)),
+        "none": (),
+    }
     checked = 0
-    for s in cands:
-        if not (s.tp_overlap and s.tp > 1):
-            continue
-        plain = dataclasses.replace(s, tp_overlap=False)
-        t_ov = layer_time_cost(lt, s, hw, world=8, pp=1, global_bsz=8)
-        t_plain = layer_time_cost(lt, plain, hw, world=8, pp=1, global_bsz=8)
-        assert t_ov < t_plain, (s, t_ov, t_plain)
-        checked += 1
+    for name, tp_seams in seams.items():
+        lt = dataclasses.replace(toy_costs().layer_types[0], tp_seams=tp_seams)
+        for s in cands:
+            if not s.tp_overlap:
+                continue
+            plain = dataclasses.replace(s, tp_overlap=False)
+            t_ov = layer_time_cost(lt, s, hw, world=8, pp=1, global_bsz=8)
+            t_plain = layer_time_cost(lt, plain, hw, world=8, pp=1, global_bsz=8)
+            share = tp_overlap_exposed(lt, s, 8 * s.tp / 8, 2)
+            if name == "wide" and s.tp == 4:
+                assert t_ov < t_plain and 0.0 <= share < 1.0, (s, t_ov, t_plain, share)
+            if name != "wide":
+                assert t_ov == t_plain and share == 1.0, (name, s, t_ov, t_plain)
+            checked += 1
     assert checked > 0
+    # the engine hands the DP one of each (plain, tp_overlap) pair: the ring
+    # where the layer's seams take it, the plain layer where none does
+    for name, tp_seams in seams.items():
+        costs = toy_costs()
+        costs.layer_types[0] = dataclasses.replace(costs.layer_types[0], tp_seams=tp_seams)
+        eng = SearchEngine(costs, hw, num_layers=8, space=space, memory_budget_mb=20000.0)
+        kept = eng._feasible_strategies(pp=1, global_bsz=8, chunks=1)
+        sp_tp4 = [s for s in kept if s.sp and s.tp == 4]
+        assert sp_tp4 and {s.tp_overlap for s in sp_tp4} == {name == "wide"}, (name, sp_tp4)
+        assert len(kept) == len([s for s in cands if not s.tp_overlap
+                                 and 8 % (8 // (s.tp * s.cp) * max(1, s.cp)) == 0])
 
 
 def test_tight_budget_forces_sharded_strategies():
