@@ -4,7 +4,7 @@
 
 PY ?= python
 
-.PHONY: all native test test-all bench dryrun chip-smoke lint check-plan audit-comm chaos serving-chaos fleet-chaos data-smoke warmup clean
+.PHONY: all native test test-all dryrun chip-smoke lint check-plan audit-comm chaos serving-chaos fleet-chaos data-smoke warmup clean
 
 all: native
 
@@ -93,10 +93,6 @@ warmup:
 	env JAX_PLATFORMS=cpu $(PY) -m galvatron_tpu.cli warmup \
 	  configs/strategies/llama-0.3b_8dev_16gb.json --force_world 8 \
 	  --compile_cache_dir .jax_cache --report warmup_report.jsonl
-
-# headline metric on the real chip — prints one JSON line
-bench:
-	$(PY) bench.py
 
 # CPU SIMULATION of a multi-chip run: sharding/schedule validation in a child
 # process on 8 virtual CPU devices — never touches an accelerator

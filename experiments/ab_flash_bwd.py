@@ -7,7 +7,7 @@ the chip runs kernels with >=120 MB resident, so the (256, 512) blocks and
 the s*d <= 2048*128 combined-backward gate — both chosen against Mosaic's
 16 MB default — are no longer forced.
 
-Timing discipline follows bench.py: the window is ONE dispatch (a lax.scan
+Timing discipline: the window is ONE dispatch (a lax.scan
 whose params carry chains the iterations), synced by a scalar D2H fetch.
 
 Usage:

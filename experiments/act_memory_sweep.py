@@ -7,7 +7,7 @@ at BOTH the 7B-representative and the small shape, with mlp_recompute in
 
   - the act_mb sp/tp coefficient refit (search/cost_model.py),
   - the buffer-accounting pins in tests/test_topology_aot.py,
-  - the max-feasible-batch bench metric (bench.py --memory).
+  - the max feasible per-device batch under the v5e budget.
 
 Prints one JSON line per measurement; run from the repo root:
   JAX_PLATFORMS=cpu python experiments/act_memory_sweep.py [--quick]
@@ -95,7 +95,7 @@ def main():
             }), flush=True)
 
     # max feasible per-device batch at the 7B-representative shape under the
-    # v5e 16 GB HBM budget, tp2+zero3+sp cell (the bench.py --memory metric)
+    # v5e 16 GB HBM budget, tp2+zero3+sp cell
     budget_mb = 16384.0 * 0.92  # leave the runtime's own overhead headroom
     for mode in ("off", "policy"):
         feasible = 0

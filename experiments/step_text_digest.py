@@ -1,0 +1,113 @@
+"""Digest of the lowered text of every benchmark cell's whole train step, no chip needed.
+
+A cell a change must leave alone is protected only if its lowered program is the same
+text (PERF.md §6, PR 26/27). For each cell of ``BENCHMARK.json`` this builds what the
+cell's ``train()`` call builds -- the configuration's ``program_flags``, the traffic's
+batch and sequence, bf16, flash attention (what ``--attn_impl auto`` resolves to on a
+TPU), and for a searched cell the plan ``cli search`` emits for the cell's arguments --
+lowers ``rt.train_step`` for a described v5e (1 chip, or the 2x2 mesh) with the real
+Mosaic kernels, and prints a sha256 of the text normalised as ``flash_text_digest.py``
+does (kernel payloads replaced by their assembly without debug locations).
+
+Run it in a ``git archive`` of the parent commit and in the change; equal digests = the
+same program:
+
+    JAX_PLATFORMS=cpu python experiments/step_text_digest.py [--cell NAME] [--dump DIR]
+
+PERF.md §6 records the digests (jax 0.9.0; another jax prints other text).
+"""
+
+import argparse
+import hashlib
+import os
+import sys
+import tempfile
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax.experimental import topologies  # noqa: E402
+
+from benchmark.lib import harness  # noqa: E402  (the manifest's reader; no other part of it)
+from flash_text_digest import normalised  # noqa: E402  (experiments/ is sys.path[0])
+
+
+def plan_flags(cell, config, traffic, out_dir):
+    """The train flags of the cell's plan, as ``benchmark/lib/harness.resolve_plan``
+    makes them; a searched plan is searched here with the same arguments."""
+    plan = traffic["plan"]
+    if plan == "single":
+        return []
+    if "file" in plan:
+        return ["--galvatron_config_path", os.path.join(ROOT, plan["file"])]
+    from galvatron_tpu import cli
+
+    path = os.path.join(out_dir, cell["name"] + "_plan.json")
+    rc = cli.main(["search", *config["program_flags"], "--num_devices", str(cell["chips"]),
+                   "--seq_length", str(traffic["seq_len"]), "--mixed_precision", "bf16",
+                   "--attn_impl", "flash", *plan["search"], "--output_config_path", path])
+    if rc or not os.path.exists(path):
+        raise SystemExit("search returned %s and left no plan at %s" % (rc, path))
+    return ["--galvatron_config_path", path]
+
+
+def lowered_step_text(cell, config, traffic, topo, out_dir) -> str:
+    from galvatron_tpu.core.arguments import (
+        adam_config_from_args,
+        hybrid_config_from_args,
+        initialize_galvatron,
+        model_config_from_args,
+        resolve_attn_impl,
+    )
+    from galvatron_tpu.core.checkpoint import abstract_state_of
+    from galvatron_tpu.models.modeling import batch_row_width
+    from galvatron_tpu.parallel.hybrid import build_runtime
+    from galvatron_tpu.parallel.mesh import build_mesh
+
+    ns = initialize_galvatron("train", [
+        *config["program_flags"], "--seq_length", str(traffic["seq_len"]),
+        "--global_train_batch_size", str(traffic["global_batch"]),
+        "--mixed_precision", "bf16", "--attn_impl", "flash",
+        *plan_flags(cell, config, traffic, out_dir), *traffic.get("train_flags", [])])
+    cfg = resolve_attn_impl(model_config_from_args(ns), ns)
+    hp = hybrid_config_from_args(ns, cfg.total_layers, cell["chips"])
+    mesh, axes = build_mesh(pp=hp.pp, devices=list(topo.devices[:cell["chips"]]))
+    rt = build_runtime(cfg, hp, mesh=mesh, axes=axes, adam=adam_config_from_args(ns),
+                       global_batch_size=ns.global_train_batch_size, seq_len=cfg.sample_len)
+    batch = jax.ShapeDtypeStruct(
+        (ns.global_train_batch_size, batch_row_width(cfg, cfg.sample_len)), jnp.int32,
+        sharding=rt.batch_sharding)
+    text = rt.train_step.lower(abstract_state_of(rt), batch).as_text()
+    if "tpu_custom_call" not in text:
+        raise SystemExit("the lowered step of %s holds no Mosaic kernel" % cell["name"])
+    return text
+
+
+def main() -> None:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--cell", action="append", help="only this cell (may repeat)")
+    ap.add_argument("--dump", help="directory to write the normalised texts to")
+    args = ap.parse_args()
+    from galvatron_tpu.aot.cache import persistent_cache_off
+    from galvatron_tpu.ops import flash_attention, grouped_matmul
+
+    # the CPU is the backend here; lower the real kernels
+    for mod in (flash_attention, grouped_matmul):
+        mod._use_interpret = lambda: False
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    with tempfile.TemporaryDirectory() as out_dir, persistent_cache_off():
+        for name in args.cell or [w["name"] for w in harness.load_manifest(ROOT)["workloads"]]:
+            cell, config, traffic = harness.load_cell(ROOT, name)
+            text = normalised(lowered_step_text(cell, config, traffic, topo, out_dir))
+            print(cell["name"], "sha256", hashlib.sha256(text.encode()).hexdigest(),
+                  "bytes", len(text), flush=True)
+            if args.dump:
+                os.makedirs(args.dump, exist_ok=True)
+                with open(os.path.join(args.dump, cell["name"] + ".txt"), "w") as f:
+                    f.write(text)
+
+
+if __name__ == "__main__":
+    main()

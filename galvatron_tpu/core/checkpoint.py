@@ -202,7 +202,10 @@ def _leaf_digest(leaf: Any) -> Dict[str, Any]:
         # by verify_manifest as "not checkable"); the per-file digests still
         # guard the bytes on disk
         return {
-            "shape": list(leaf.shape),
+            # a scalar is recorded as [1], as the host-gathered branch below
+            # records it (np.ascontiguousarray returns ndim >= 1): a step the
+            # pod wrote must verify when one process restores it
+            "shape": list(leaf.shape) or [1],
             "dtype": str(np.dtype(leaf.dtype)),
             "digest": None,
         }

@@ -13,7 +13,7 @@ variants (galvatron/models/{gpt_hf,llama_hf,gpt_fa,llama_fa,baichuan}):
 Everything is pure functions over parameter pytrees — no Module wrapping — so
 per-layer hybrid strategies are just per-layer sharding specs applied to the
 same code (SURVEY §7 design stance). Each parameter has a logical-axes
-annotation consumed by galvatron_tpu.parallel.sharding.
+annotation consumed by the runtime's sharding rules (parallel/sharding.py).
 
 Attention dispatch mirrors the reference's core-vs-flash switch
 (galvatron/core/tensor_parallel/transformer.py:805-820): "xla" einsum path,
@@ -33,6 +33,7 @@ from jax.ad_checkpoint import checkpoint_name
 import jax.numpy as jnp
 import numpy as np
 
+from galvatron_tpu.models.placement import LOCAL, Placement
 from galvatron_tpu.ops.quant import QuantTensor, qeinsum, qmatmul
 
 Params = Dict[str, Any]
@@ -101,53 +102,6 @@ class ModelConfig:
     # WHOLE projection width (all heads together) before the split into heads
     # and before rope (OLMoE; HF modeling_olmoe.py q_norm / k_norm).
     qk_norm: bool = False
-    # (mesh, ep_axes, token_axes) installed by the layer hooks for ep>1
-    # layers so moe_block can pin dispatch-buffer shardings (keeps the
-    # expert all-to-all at the dispatch einsum instead of an SPMD
-    # replicate-and-repartition). None → unconstrained (single-device paths).
-    moe_shard_ctx: Optional[Any] = None
-    # (mesh, PartitionSpec of the layer's (B, S, H) activation) installed by
-    # the layer hook for dropless top-k MoE layers on ANY multi-device mesh:
-    # moe.moe_topk_block then routes each device's own tokens under a
-    # shard_map (Mosaic kernels cannot be partitioned by GSPMD, and a global
-    # sort would gather every token). None → direct call (single device).
-    moe_token_shard_ctx: Optional[Any] = None
-    # (mesh, batch_axes) installed by the layer hooks for zero3+tp layers:
-    # attn_block pins the attention context o to batch-sharded/head-replicated
-    # before the output projection. Without it the dWo^T grad dot (output
-    # sharded fsdp x tp) finds no common axes with the batch-sharded dy and
-    # the SPMD partitioner falls back to an involuntary full rematerialization
-    # (world-wide replicate) of dy — XLA b/433785288. The pin trades that for
-    # a tp-wide gather of o in forward. None → unconstrained.
-    attn_out_shard_ctx: Optional[Any] = None
-    # (mesh, batch_axes, head_axes) installed by the layer hooks for tp>1
-    # flash layers: _attn_block_headmajor pins the stacked (b, 3, n, s, d)
-    # qkv projection output to (dp, -, tp, -, -). The forward pin is a no-op
-    # (it matches propagation), but with_sharding_constraint's transpose
-    # applies the same spec to the BACKWARD cotangent — without it GSPMD has
-    # been seen sharding the combined bwd kernel's dqkv along the size-3
-    # stack axis (padding it across tp x dp devices) and paying an
-    # involuntary replicate-and-repartition. None → unconstrained.
-    qkv_shard_ctx: Optional[Any] = None
-    # (mesh, batch_axes, head_axes) installed by the layer hooks for flash
-    # layers on ANY multi-device mesh: GSPMD cannot partition Mosaic custom
-    # calls ("Mosaic kernels cannot be automatically partitioned"), so every
-    # kernel invocation is wrapped in a shard_map over the batch (dp) and
-    # head (tp) axes — each device runs the kernel on its local shard. The
-    # CPU simulation never surfaces this (interpret-mode kernels are plain
-    # jnp ops GSPMD can partition); a real-TPU topology AOT compile does
-    # (tests/test_topology_aot.py). None → direct call (single device).
-    flash_shard_ctx: Optional[Any] = None
-    # (mesh, dp_axes, tp_axes, sp) installed by the layer hooks for tp>1
-    # layers whose plan sets tp_overlap (core/strategy.LayerStrategy): the
-    # column-parallel projections (_proj_up: qkv, MLP gate/up) route through
-    # ops.collective_matmul.allgather_einsum on sp layers — the blocking
-    # GSPMD seq all-gather becomes a ppermute ring pipelined behind the GEMM
-    # chunks — and the row-parallel projections (_proj_down: wo, w2) through
-    # einsum_reducescatter, which pipelines the trailing all-reduce /
-    # reduce-scatter as an accumulator ring. None → plain einsums (GSPMD
-    # inserts the blocking collectives).
-    tp_overlap_ctx: Optional[Any] = None
     # vision families (reference legacy vit/swin model_type branches,
     # galvatron/core/parallel.py:64-89, cost_model.py:76,87-106).
     # image_size > 0 switches the input pipeline from token ids to uint8
@@ -827,7 +781,8 @@ def attention_xla(q, k, v, cfg: ModelConfig, bias=None, q_offset=0, seg_ids=None
     return jnp.einsum("bnqk,bknh->bqnh", probs, v)
 
 
-def attention(q, k, v, cfg: ModelConfig, bias=None, rope=None, seg_ids=None):
+def attention(q, k, v, cfg: ModelConfig, bias=None, rope=None, seg_ids=None,
+              place: Placement = LOCAL):
     """``rope``: optional (cos, sin) tables. On the flash path they are fused
     into the Pallas kernels (no HBM round-trip of roped q/k); otherwise
     apply_rope runs here before the einsum path. ``seg_ids`` (packed
@@ -841,15 +796,13 @@ def attention(q, k, v, cfg: ModelConfig, bias=None, rope=None, seg_ids=None):
         v = _repeat_kv(v, nh // v.shape[2])
         bsnd = (0, 2)  # (b, s, n, d) layout: batch dim 0, head dim 2
         if rope is None:
-            kernel = _flash_shard_map(
-                cfg,
+            kernel = place.shard_kernel(
                 lambda q_, k_, v_: flash_attention(q_, k_, v_, causal=cfg.causal),
                 [bsnd] * 3,
                 bsnd,
             )
             return kernel(q, k, v)
-        kernel = _flash_shard_map(
-            cfg,
+        kernel = place.shard_kernel(
             lambda q_, k_, v_, c_, s_: flash_attention(
                 q_, k_, v_, causal=cfg.causal, rope=(c_, s_)
             ),
@@ -874,105 +827,9 @@ def _repeat_kv_hm(x, n_rep: int):
     )
 
 
-def _flash_shard_map(cfg: ModelConfig, fn, arg_dims, out_dims):
-    """Wrap a flash-kernel entry in a shard_map over the layer's (dp, tp)
-    axes when flash_shard_ctx is installed (multi-device mesh) — Mosaic
-    custom calls cannot be partitioned by GSPMD, so each device must invoke
-    the kernel on its local (batch, head) shard. ``arg_dims``/``out_dims``:
-    per-array (batch_dim, head_dim) positions; rope tables (replicated) are
-    passed through with empty dims. Nests inside the pp engines' manual
-    region via ambient_or. Identity when the ctx is absent."""
-    if cfg.flash_shard_ctx is None:
-        return fn
-    from jax.sharding import PartitionSpec as P
-
-    from galvatron_tpu.parallel.mesh import ambient_or
-
-    mesh, dp_ax, tp_ax = cfg.flash_shard_ctx
-    dp = tuple(dp_ax) if dp_ax else ()
-    tp = tuple(tp_ax) if tp_ax else ()
-    if not dp and not tp:
-        return fn
-
-    def spec(dims, ndim):
-        entries = [None] * ndim
-        b_dim, h_dim = dims
-        if b_dim is not None and dp:
-            entries[b_dim] = dp if len(dp) > 1 else dp[0]
-        if h_dim is not None and tp:
-            entries[h_dim] = tp if len(tp) > 1 else tp[0]
-        return P(*entries)
-
-    def wrapped(*args):
-        from galvatron_tpu.parallel.mesh import manual_axis_names
-
-        in_specs = tuple(spec(d, a.ndim) for d, a in zip(arg_dims, args))
-        out_shape = jax.eval_shape(fn, *args)
-        am = ambient_or(mesh)
-        return jax.shard_map(
-            fn, mesh=am, in_specs=in_specs,
-            out_specs=spec(out_dims, len(out_shape.shape)),
-            axis_names=manual_axis_names(am), check_vma=False,
-        )(*args)
-
-    return wrapped
-
-
-def _proj_up(subscripts, x, w, cfg: ModelConfig, w_shard_dim: int):
-    """Column-parallel projection einsum (qkv, MLP gate/up). With
-    tp_overlap_ctx installed and the layer sequence-parallel, ``x`` arrives
-    seq-sharded over the tp axes and the GSPMD-inserted blocking seq
-    all-gather is replaced by the decomposed all-gather⊗matmul ring
-    (ops.collective_matmul). Non-sp layers keep the plain einsum — x is
-    already tp-replicated, there is no gather to overlap.
-
-    int8 weights (serving, ops.quant) dequantize inside the plain einsum;
-    the overlap ring streams fp weight shards, so under tp_overlap_ctx a
-    quantized weight is materialized back to fp first (serving never
-    installs the overlap ctx — this branch exists for safety, not speed)."""
-    if cfg.tp_overlap_ctx is None:
-        if isinstance(w, QuantTensor):
-            return qeinsum(subscripts, x, w)
-        return jnp.einsum(subscripts, x, w)
-    if isinstance(w, QuantTensor):
-        w = w.dequantize(x.dtype)
-    from galvatron_tpu.ops import collective_matmul as cm
-
-    mesh, dp_ax, tp_ax, sp = cfg.tp_overlap_ctx
-    if not sp:
-        return jnp.einsum(subscripts, x, w)
-    return cm.allgather_einsum(
-        subscripts, x, w, mesh=mesh, dp_axes=dp_ax, tp_axes=tp_ax,
-        w_shard_dim=w_shard_dim,
-    )
-
-
-def _proj_down(subscripts, x, w, cfg: ModelConfig, w_shard_dim: int, activation=None):
-    """Row-parallel projection einsum (wo, MLP down). With tp_overlap_ctx
-    installed the trailing TP reduction is pipelined as the accumulator-ring
-    reduce-scatter⊗matmul (ops.collective_matmul): sp layers keep the
-    seq-scattered output layout; non-sp layers gather it back (the reduce
-    half of the all-reduce still overlaps). ``activation`` (only with
-    tp_overlap_ctx): applied to ``x`` inside the seam, which then keeps ``x``
-    and recomputes the activation in its backward (mlp_block)."""
-    if cfg.tp_overlap_ctx is None:
-        if isinstance(w, QuantTensor):
-            return qeinsum(subscripts, x, w)
-        return jnp.einsum(subscripts, x, w)
-    if isinstance(w, QuantTensor):
-        w = w.dequantize(x.dtype)
-    from galvatron_tpu.ops import collective_matmul as cm
-
-    mesh, dp_ax, tp_ax, sp = cfg.tp_overlap_ctx
-    return cm.einsum_reducescatter(
-        subscripts, x, w, mesh=mesh, dp_axes=dp_ax, tp_axes=tp_ax,
-        w_shard_dim=w_shard_dim, scatter_output=bool(sp), activation=activation,
-    )
-
-
 def projection_seams(cfg: ModelConfig, seq_len: int) -> Tuple[Tuple[str, str, int, bool], ...]:
-    """The projections of one token-stream layer that go through ``_proj_up`` /
-    ``_proj_down``, as ``(scope, kind, width, blockwise)``: ``kind`` "ag"
+    """The projections of one token-stream layer that go through ``Placement.proj_up`` /
+    ``proj_down``, as ``(scope, kind, width, blockwise)``: ``kind`` "ag"
     (column-parallel, ``width`` its output columns) or "rs" (row-parallel,
     ``width`` its contraction), the width being what the tp axes divide;
     ``blockwise`` False where the seam's all-gather side (an "ag" seam's
@@ -999,37 +856,7 @@ def projection_seams(cfg: ModelConfig, seq_len: int) -> Tuple[Tuple[str, str, in
     return tuple(seams)
 
 
-def _constrain_qkv(qkv, cfg: ModelConfig):
-    """Pin the stacked (b, 3, n, s, d) qkv (and, via the vjp transpose, its
-    dqkv cotangent) to (dp, -, tp, -, -) when the layer hook installed
-    qkv_shard_ctx — see the ModelConfig field comment."""
-    if cfg.qkv_shard_ctx is None:
-        return qkv
-    from jax.sharding import PartitionSpec as P
-
-    from galvatron_tpu.parallel.sharding import constrain
-
-    mesh, dp_ax, tp_ax = cfg.qkv_shard_ctx
-    return constrain(
-        qkv, mesh, P(dp_ax or None, None, tp_ax or None, None, None)
-    )
-
-
-def _constrain_attn_out(o, cfg: ModelConfig):
-    """Pin the attention context to batch-sharded/head-replicated when the
-    layer hook installed attn_out_shard_ctx (zero3+tp layers) — see the
-    ModelConfig field comment. ``o``: (B, S, n, hd) or (B, n, S, hd)."""
-    if cfg.attn_out_shard_ctx is None:
-        return o
-    from jax.sharding import PartitionSpec as P
-
-    from galvatron_tpu.parallel.sharding import constrain
-
-    mesh, dp_ax = cfg.attn_out_shard_ctx
-    return constrain(o, mesh, P(dp_ax or None, *([None] * (o.ndim - 1))))
-
-
-def _attn_block_headmajor(x, p, cfg: ModelConfig, rope, remat_attn: bool):
+def _attn_block_headmajor(x, p, cfg: ModelConfig, rope, remat_attn: bool, place: Placement):
     """Flash-path attention with head-major (b, h, s, d) dataflow end to end:
     the QKV projection einsums straight to (b, 3, n, s, hd) and the output
     projection consumes (b, n, s, hd), so XLA realizes the head-major layout
@@ -1049,9 +876,8 @@ def _attn_block_headmajor(x, p, cfg: ModelConfig, rope, remat_attn: bool):
 
     def out_proj(o):
         with jax.named_scope("out_proj"):
-            y = _proj_down(
-                "bnsd,nde->bse", o, p["wo"].astype(x.dtype).reshape(n, hd, h),
-                cfg, w_shard_dim=0,
+            y = place.proj_down(
+                "bnsd,nde->bse", o, p["wo"].astype(x.dtype).reshape(n, hd, h), w_shard_dim=0
             )
             if "wo_b" in p:
                 y = y + p["wo_b"].astype(x.dtype)
@@ -1059,10 +885,10 @@ def _attn_block_headmajor(x, p, cfg: ModelConfig, rope, remat_attn: bool):
 
     if cfg.qkv_blocked:
         with jax.named_scope("qkv_proj"):
-            qkv = _proj_up("bsh,hcnd->bcnsd", x, w.reshape(h, 3, n, hd), cfg, w_shard_dim=2)
+            qkv = place.proj_up("bsh,hcnd->bcnsd", x, w.reshape(h, 3, n, hd), w_shard_dim=2)
             if "wqkv_b" in p:
                 qkv = qkv + p["wqkv_b"].astype(x.dtype).reshape(3, n, hd)[None, :, :, None, :]
-            qkv = _constrain_qkv(qkv, cfg)
+            qkv = place.constrain_qkv(qkv)
         if cfg.qk_norm:
             # outside the kernels: the q and k slots of the stacked projection,
             # each over its (n, d) axes together; v passes through
@@ -1074,10 +900,9 @@ def _attn_block_headmajor(x, p, cfg: ModelConfig, rope, remat_attn: bool):
             # the kernels consume the STACKED projection output directly —
             # index-mapped block specs instead of q/k/v slice copies
             if rope is None:  # learned / absolute positions: no table operands
-                core_qkv = _flash_shard_map(cfg, flash_attention_qkv, [(0, 2)], (0, 1))
+                core_qkv = place.shard_kernel(flash_attention_qkv, [(0, 2)], (0, 1))
             else:
-                kernel = _flash_shard_map(
-                    cfg,
+                kernel = place.shard_kernel(
                     lambda qkv_, c_, s_: flash_attention_qkv(qkv_, rope=(c_, s_)),
                     [(0, 2), (None, None), (None, None)],
                     (0, 1),
@@ -1089,7 +914,7 @@ def _attn_block_headmajor(x, p, cfg: ModelConfig, rope, remat_attn: bool):
             if remat_attn:
                 core_qkv = jax.checkpoint(core_qkv)
             with jax.named_scope("attn_core"):
-                o = _constrain_attn_out(core_qkv(qkv), cfg)
+                o = place.constrain_attn_out(core_qkv(qkv))
             return out_proj(o)
         q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
     else:
@@ -1102,22 +927,18 @@ def _attn_block_headmajor(x, p, cfg: ModelConfig, rope, remat_attn: bool):
         # group's queries from the resident grouped block (flash_attention_hm
         # kv_rep index maps), group-factor less K/V HBM traffic than the old
         # materialized _repeat_kv_hm copy. EXCEPT when the layer's tp degree
-        # does not divide kv_heads: _flash_shard_map shards the head dim
+        # does not divide kv_heads: place.shard_kernel shards the head dim
         # over the tp axes, so grouped K/V must be repeated first (the same
         # guard ulysses applies) — q heads always divide tp.
         k = r[:, :, npg]
         v = r[:, :, npg + 1]
-        if cfg.flash_shard_ctx is not None:
-            mesh_, _, tp_ax = cfg.flash_shard_ctx
-            tp_deg = int(np.prod([mesh_.shape[a] for a in (tp_ax or ())]))
-            if tp_deg > 1 and kv % tp_deg:
-                k = _repeat_kv_hm(k, npg)
-                v = _repeat_kv_hm(v, npg)
+        if place.kernel_tp > 1 and kv % place.kernel_tp:
+            k = _repeat_kv_hm(k, npg)
+            v = _repeat_kv_hm(v, npg)
 
     qkv_dim, rep_dim = (0, 1), (None, None)
     if rope is None:
-        kernel = _flash_shard_map(
-            cfg,
+        kernel = place.shard_kernel(
             lambda q_, k_, v_: flash_attention_hm(q_, k_, v_, causal=cfg.causal),
             [qkv_dim] * 3,
             qkv_dim,
@@ -1126,8 +947,7 @@ def _attn_block_headmajor(x, p, cfg: ModelConfig, rope, remat_attn: bool):
         def core(q_, k_, v_):
             return kernel(q_, k_, v_)
     else:
-        kernel = _flash_shard_map(
-            cfg,
+        kernel = place.shard_kernel(
             lambda q_, k_, v_, c_, s_: flash_attention_hm(
                 q_, k_, v_, causal=cfg.causal, rope=(c_, s_)
             ),
@@ -1141,13 +961,13 @@ def _attn_block_headmajor(x, p, cfg: ModelConfig, rope, remat_attn: bool):
     if remat_attn:
         core = jax.checkpoint(core)
     with jax.named_scope("attn_core"):
-        o = _constrain_attn_out(core(q, k, v), cfg)
+        o = place.constrain_attn_out(core(q, k, v))
     return out_proj(o)
 
 
 @jax.named_scope("attn")
 def attn_block(x, p, cfg: ModelConfig, cos_sin=None, alibi=None, remat_attn: bool = False,
-               seg_ids=None):
+               seg_ids=None, place: Placement = LOCAL):
     """``remat_attn`` rematerializes only the attention core (scores/softmax/
     context) in the backward pass — Megatron's "selective" recompute
     (reference: galvatron/core/tensor_parallel/transformer.py:597,615-636).
@@ -1168,7 +988,7 @@ def attn_block(x, p, cfg: ModelConfig, cos_sin=None, alibi=None, remat_attn: boo
             ("wqkv_b" not in p and not cfg.qk_norm) or cfg.qkv_blocked
         ):
             rope = cos_sin if cfg.pos_embed == "rope" else None
-            return _attn_block_headmajor(x, p, cfg, rope, remat_attn)
+            return _attn_block_headmajor(x, p, cfg, rope, remat_attn, place)
     # one fused qkv GEMM (~2 ms/layer-batch over three narrow matmuls on the
     # v5e 7B-shape bench); layout per qkv_dims/qkv_project
     with jax.named_scope("qkv_proj"):
@@ -1181,12 +1001,12 @@ def attn_block(x, p, cfg: ModelConfig, cos_sin=None, alibi=None, remat_attn: boo
         bias = (alibi[:, None, None] * rel[None]).astype(jnp.float32)[None]  # (1,n,q,k)
 
     def core(q_, k_, v_, bias_, seg_):
-        return attention(q_, k_, v_, cfg, bias=bias_, rope=rope, seg_ids=seg_)
+        return attention(q_, k_, v_, cfg, bias=bias_, rope=rope, seg_ids=seg_, place=place)
 
     if remat_attn:
         core = jax.checkpoint(core)
     with jax.named_scope("attn_core"):
-        o = _constrain_attn_out(core(q, k, v, bias, seg_ids), cfg)
+        o = place.constrain_attn_out(core(q, k, v, bias, seg_ids))
     with jax.named_scope("out_proj"):
         return attn_output(o, p, cfg, x.dtype)
 
@@ -1195,7 +1015,7 @@ _gelu_tanh = partial(jax.nn.gelu, approximate=True)  # one object: it keys the s
 
 
 @jax.named_scope("mlp")
-def mlp_block(x, p, cfg: ModelConfig, train: bool = True):
+def mlp_block(x, p, cfg: ModelConfig, train: bool = True, place: Placement = LOCAL):
     """SwiGLU or GeLU MLP (reference: ParallelMLP, galvatron/core/
     tensor_parallel/transformer.py:78-159); switch-MoE when moe_experts > 0
     (SwitchMLP, transformer.py:161-295). ``train`` only affects MoE routing
@@ -1209,20 +1029,20 @@ def mlp_block(x, p, cfg: ModelConfig, train: bool = True):
         from galvatron_tpu.models import moe
 
         if cfg.moe_dropless:  # callers of mlp_block want activations only
-            return moe.moe_topk_block(x, p, cfg)[0]
-        return moe.moe_block(x, p, cfg, train=train)
-    # _proj_up/_proj_down only serve the (B, S, H) token stream; vision /
-    # windowed layouts keep the plain matmul (tp_overlap_ctx is token-only)
+            return moe.moe_topk_block(x, p, cfg, place=place)[0]
+        return moe.moe_block(x, p, cfg, train=train, place=place)
+    # the placement's seams only serve the (B, S, H) token stream; vision /
+    # windowed layouts keep the plain matmul
     plain = lambda x_, w_: (  # noqa: E731 — non-token (vision) layouts
         qmatmul(x_, w_) if isinstance(w_, QuantTensor) else x_ @ w_
     )
     up = (
-        (lambda x_, w_: _proj_up("bsh,hf->bsf", x_, w_, cfg, w_shard_dim=1))
+        (lambda x_, w_: place.proj_up("bsh,hf->bsf", x_, w_, w_shard_dim=1))
         if x.ndim == 3
         else plain
     )
     down = (
-        (lambda x_, w_: _proj_down("bsf,fh->bsh", x_, w_, cfg, w_shard_dim=0))
+        (lambda x_, w_: place.proj_down("bsf,fh->bsh", x_, w_, w_shard_dim=0))
         if x.ndim == 3
         else plain
     )
@@ -1245,7 +1065,7 @@ def mlp_block(x, p, cfg: ModelConfig, train: bool = True):
             prod = jax.checkpoint(prod)
         y = down(prod(g), p["w2"].astype(x.dtype))
     else:
-        overlap = cfg.tp_overlap_ctx is not None and x.ndim == 3
+        overlap = place.tp_overlap and x.ndim == 3
         # a tp_overlap layer leaves mlp_residual's policy region (it would
         # rerun the up projection's ring in the backward and re-derive the
         # seams' programs in every layer) and saves what the region saves
@@ -1259,7 +1079,7 @@ def mlp_block(x, p, cfg: ModelConfig, train: bool = True):
         g = checkpoint_name(g, "mlp_gate")
         act = jax.nn.relu if cfg.act_fn == "relu" else _gelu_tanh
         if overlap:
-            y = _proj_down("bsf,fh->bsh", g, p["w2"], cfg, w_shard_dim=0, activation=act)
+            y = place.proj_down("bsf,fh->bsh", g, p["w2"], w_shard_dim=0, activation=act)
         else:
             if cfg.mlp_recompute == "gate" or (
                 cfg.mlp_recompute == "policy" and cfg.fused_norm
@@ -1271,7 +1091,7 @@ def mlp_block(x, p, cfg: ModelConfig, train: bool = True):
     return y
 
 
-def mlp_residual(x, p, cfg: ModelConfig, train: bool = True):
+def mlp_residual(x, p, cfg: ModelConfig, train: bool = True, place: Placement = LOCAL):
     """x + MLP(norm(x)) — the per-layer MLP residual branch, with the
     activation-memory saveable policy applied when cfg.mlp_recompute ==
     'policy': jax.checkpoint over the norm+MLP region saving ONLY the
@@ -1289,13 +1109,13 @@ def mlp_residual(x, p, cfg: ModelConfig, train: bool = True):
 
         normed = norm(x, p["mlp_norm"], cfg)
         with jax.named_scope("mlp"):
-            y, stats = moe.moe_topk_block(normed, p["mlp"], cfg)
+            y, stats = moe.moe_topk_block(normed, p["mlp"], cfg, place=place)
         return x + y, stats
     if (
         cfg.mlp_recompute == "policy" and cfg.moe_experts == 0 and not cfg.fused_norm
         # a tp_overlap layer's down seam saves the gate itself (mlp_block);
         # SwiGLU's halves are not device-local, so it keeps the region
-        and (cfg.tp_overlap_ctx is None or cfg.act_fn == "swiglu")
+        and (not place.tp_overlap or cfg.act_fn == "swiglu")
     ):
         # _norm_impl, not norm: the policy region already remats everything
         # unnamed — a nested per-norm checkpoint would only add bookkeeping.
@@ -1306,11 +1126,11 @@ def mlp_residual(x, p, cfg: ModelConfig, train: bool = True):
                 return _norm_impl(x_, pn_, cfg)
 
         branch = jax.checkpoint(
-            lambda x_, pn_, pm_: mlp_block(normed(x_, pn_), pm_, cfg, train=train),
+            lambda x_, pn_, pm_: mlp_block(normed(x_, pn_), pm_, cfg, train=train, place=place),
             policy=jax.checkpoint_policies.save_only_these_names("mlp_gate"),
         )
         return x + branch(x, p["mlp_norm"], p["mlp"])
-    return x + mlp_block(norm(x, p["mlp_norm"], cfg), p["mlp"], cfg, train=train)
+    return x + mlp_block(norm(x, p["mlp_norm"], cfg), p["mlp"], cfg, train=train, place=place)
 
 
 def cross_attn_block(x, enc_out, p, cfg: ModelConfig):
@@ -1330,29 +1150,36 @@ def cross_attn_block(x, enc_out, p, cfg: ModelConfig):
     return o.reshape(b, s, cfg.num_heads * hd) @ p["wo"].astype(x.dtype)
 
 
-def encoder_layer(x, p, cfg: ModelConfig, cos_sin=None, remat_attn: bool = False):
+def encoder_layer(x, p, cfg: ModelConfig, cos_sin=None, remat_attn: bool = False,
+                  place: Placement = LOCAL):
     """Bidirectional self-attention + MLP (the enc-dec encoder stack)."""
     ecfg = cfg if not cfg.causal else cfg.replace(causal=False)
     x = x + attn_block(
-        norm(x, p["attn_norm"], cfg), p["attn"], ecfg, cos_sin, None, remat_attn=remat_attn
+        norm(x, p["attn_norm"], cfg), p["attn"], ecfg, cos_sin, None, remat_attn=remat_attn,
+        place=place,
     )
-    return mlp_residual(x, p, cfg)
+    return mlp_residual(x, p, cfg, place=place)
 
 
 def decoder_layer(
     x, p, cfg: ModelConfig, cos_sin=None, alibi=None, remat_attn: bool = False,
-    enc_out=None, seg_ids=None
+    enc_out=None, seg_ids=None, place: Placement = LOCAL,
 ):
     """One decoder layer -> x. When ``cfg.moe_dropless`` it returns
     ``(x, router_stats)`` instead: the layer's (f, P) of moe.router_stats,
-    which the load-balancing loss needs (forward_with_stats collects them)."""
+    which the load-balancing loss needs (forward_with_stats collects them).
+
+    ``place`` (models/placement.py; static under ``jit``) is what the layer's
+    place on a mesh adds to the computation — pins, kernel ``shard_map``s,
+    collective-matmul seams; callers without a mesh (serving, the float32
+    references, the profiler) pass nothing."""
     x = x + attn_block(
         norm(x, p["attn_norm"], cfg), p["attn"], cfg, cos_sin, alibi,
-        remat_attn=remat_attn, seg_ids=seg_ids,
+        remat_attn=remat_attn, seg_ids=seg_ids, place=place,
     )
     if enc_out is not None and "cross" in p:
         x = x + cross_attn_block(norm(x, p["cross_norm"], cfg), enc_out, p["cross"], cfg)
-    return mlp_residual(x, p, cfg)
+    return mlp_residual(x, p, cfg, place=place)
 
 
 @jax.named_scope("embed")

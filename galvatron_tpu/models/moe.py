@@ -38,6 +38,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from galvatron_tpu.models.placement import LOCAL, Placement
+
 Params = Dict[str, Any]
 
 
@@ -137,36 +139,13 @@ def moe_annotations(cfg) -> Params:
     return a
 
 
-def moe_block(x: jax.Array, p: Params, cfg, train: bool = True) -> jax.Array:
+def moe_block(x: jax.Array, p: Params, cfg, train: bool = True,
+              place: Placement = LOCAL) -> jax.Array:
     """Switch-MoE MLP on a (B, S, H) activation (SwitchMLP.forward equivalent,
-    reference: transformer.py:210-295).
-
-    When ``cfg.moe_shard_ctx`` is installed (layer hooks, ep>1), the token-
-    side tensors are pinned to the token/batch sharding and the per-expert
-    buffers to the ep sharding, so the expert all-to-all happens exactly at
-    the dispatch/combine einsums — without the pins, sharding propagation
-    let the backward pick an SPMD replicate-and-repartition ("involuntary
-    full rematerialization") on the dispatch reshape."""
-    from jax.sharding import PartitionSpec as P
-
-    ctx = cfg.moe_shard_ctx
-
-    def pin_tok(a):  # (T, ...) token-major
-        if ctx is None:
-            return a
-        from galvatron_tpu.parallel.sharding import constrain
-
-        mesh, _, tok_ax = ctx
-        return constrain(a, mesh, P(tok_ax, *([None] * (a.ndim - 1))))
-
-    def pin_ep(a):  # (E, ...) expert-major
-        if ctx is None:
-            return a
-        from galvatron_tpu.parallel.sharding import constrain
-
-        mesh, ep_ax, _ = ctx
-        return constrain(a, mesh, P(ep_ax, *([None] * (a.ndim - 1))))
-
+    reference: transformer.py:210-295). ``place`` pins the token-major
+    tensors and the per-expert buffers of an ep>1 layer, so the expert
+    all-to-all happens exactly at the dispatch/combine einsums."""
+    pin_tok, pin_ep = place.pin_tokens, place.pin_experts
     b, s, h = x.shape
     T = b * s
     E = cfg.moe_experts
@@ -332,33 +311,16 @@ def load_max_over_mean(stats, num_experts: int, top_k: int) -> jax.Array:
     return jnp.max(f) * num_experts / top_k
 
 
-def moe_topk_block(x: jax.Array, p: Params, cfg, tile: Optional[int] = None):
+def moe_topk_block(x: jax.Array, p: Params, cfg, tile: Optional[int] = None,
+                   place: Placement = LOCAL):
     """Dropless top-k MoE MLP on (B, S, H) -> (y, router_stats).
 
-    On a multi-device mesh (``cfg.moe_token_shard_ctx``, installed by the
-    layer hook) the block runs under a ``shard_map`` over the axes the
-    activation is sharded on, with every expert's weights whole on every
-    device: routing is per token, so each device sorts and computes its own
-    tokens and only the statistics cross devices (a mean).  GSPMD cannot
-    partition the Mosaic kernels, and a global sort would gather every
-    token.  Devices that hold the same tokens (tp without sp) repeat the
-    work; expert parallelism is refused upstream (build_runtime)."""
-    ctx = cfg.moe_token_shard_ctx
-    if ctx is None:
-        return _topk_local(x, p, cfg, tile, ())
-    from jax.sharding import PartitionSpec as P
-
-    from galvatron_tpu.parallel.mesh import ambient_or, manual_axis_names
-
-    mesh, spec = ctx
-    over = tuple(a for e in spec if e is not None
-                 for a in ((e,) if isinstance(e, str) else e))
-    am = ambient_or(mesh)
-    return jax.shard_map(
-        lambda x_, p_: _topk_local(x_, p_, cfg, tile, over),
-        mesh=am, in_specs=(spec, P()), out_specs=(spec, (P(), P())),
-        axis_names=manual_axis_names(am), check_vma=False,
-    )(x, p)
+    On a multi-device mesh ``place.route_tokens`` runs the block on each
+    device's own tokens, every expert's weights whole on every device, and
+    only the statistics cross devices (a mean); expert parallelism is
+    refused upstream (build_runtime)."""
+    return place.route_tokens(
+        lambda x_, p_, over: _topk_local(x_, p_, cfg, tile, over))(x, p)
 
 
 def _topk_local(x, p, cfg, tile, over):

@@ -12,8 +12,8 @@ per-hop transfer hides behind a 1/T-sized matmul instead of serializing
 in front of a full one.
 
 Two entry points, einsum-parameterized so one implementation serves the
-qkv / MLP-up / attention-out / MLP-down seams (modeling._proj_up /
-_proj_down dispatch here when the layer strategy sets ``tp_overlap``):
+qkv / MLP-up / attention-out / MLP-down seams (parallel/placement.py's
+proj_up / proj_down dispatch here when the layer strategy sets ``tp_overlap``):
 
 - :func:`allgather_einsum` — all-gather⊗matmul. ``x`` arrives logically
   seq-sharded over the TP axes (the sp layer boundary layout); each

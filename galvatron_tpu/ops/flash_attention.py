@@ -559,7 +559,7 @@ def _flash_fwd_blocked_qkv(qkv, rope, sm_scale, block_q, interpret):
 #
 # The grid-style dK/dV + dQ kernels below recompute the score and dp matmuls
 # in BOTH kernels (7 dots per block pair) and pay per-(i,j) grid bookkeeping;
-# a round-4 train-step trace (experiments/trace_train.py) measured them at
+# a round-4 train-step trace measured them at
 # 13.7 ms/layer-batch plus 3.3 ms for the separate delta pass — 4.7x the
 # blocked forward's 3.59 ms for 3.5x the FLOPs. This kernel applies the
 # forward's round-3 treatment to the backward: ONE invocation per (b, h)

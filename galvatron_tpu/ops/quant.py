@@ -22,7 +22,7 @@ which the engine parity-gates against a declared max-abs logit drift.
 just enough for the modeling seams: ``.astype`` is the identity (dequant
 happens inside the matmul, not ahead of it), ``.shape``/``.ndim`` answer
 for the logical (unquantized) weight. Dispatch lives at the TP projection
-seams (modeling._proj_up/_proj_down, qkv_project, attn_output, lm_head) —
+seams (Placement.proj_up/proj_down, qkv_project, attn_output, lm_head) —
 the same seams the collective-matmul overlap owns — via an isinstance
 check, so training code never sees a branch.
 
@@ -106,7 +106,7 @@ def quantize_int8(w) -> QuantTensor:
 def _out_suffix_ok(subscripts: str, qw: QuantTensor) -> None:
     """The scale broadcast below relies on every seam's einsum putting the
     weight's output letters LAST in the output, in order — true for all of
-    qkv_project / attn_output / _proj_up / _proj_down / lm_head. Fail
+    qkv_project / attn_output / proj_up / proj_down / lm_head. Fail
     loudly (at trace time, free at runtime) if a new caller breaks that."""
     inputs, out = subscripts.replace("...", "").split("->")
     x_sub, w_sub = inputs.split(",")

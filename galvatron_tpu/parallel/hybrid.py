@@ -43,34 +43,20 @@ from galvatron_tpu.core.schedules import (
 from galvatron_tpu.core.strategy import HybridParallelConfig, LayerStrategy
 from galvatron_tpu.models import modeling
 from galvatron_tpu.models.modeling import ModelConfig
-from galvatron_tpu.parallel.mesh import (
-    MeshAxes,
-    batch_spec,
-    build_mesh,
-    global_batch_spec,
-    moe_token_axes,
-)
+from galvatron_tpu.parallel import placement
+from galvatron_tpu.parallel.mesh import MeshAxes, build_mesh, global_batch_spec
 from galvatron_tpu.parallel.sharding import (
     constrain,
     cp_shard_axes,
     overlap_grad_sync,
     param_spec,
     sharding_tree,
-    tp_overlap_seam_counts,
-    with_flash_shard_ctx,
-    with_tp_overlap_ctx,
 )
 
 
 #: what a dropless top-k MoE model's train state carries of its last step
 #: (``state["moe_stats"]``), logged in the trainer's ``train_iter`` record
 MOE_STATS = ("moe_aux_loss", "moe_load_max_over_mean")
-
-
-def activation_spec(axes: MeshAxes, s: LayerStrategy) -> P:
-    """(B, S, H) activation spec at a layer boundary."""
-    bs = batch_spec(axes, s)
-    return P(bs[0], bs[1], None)
 
 
 def model_param_specs(
@@ -162,7 +148,7 @@ class HybridParallelRuntime:
     restack_params: Callable = None
     # {"ring": n, "plain": m}: projection seams of the plan's tp_overlap layers
     # that take the collective-matmul ring / stay the plain einsum
-    # (sharding.tp_overlap_seam_counts); the trainer puts it in the run's
+    # (placement.tp_overlap_seam_counts); the trainer puts it in the run's
     # fingerprint and on the build_runtime span
     tp_overlap_seams: Any = None
 
@@ -195,54 +181,13 @@ def _make_layer_hook(cfg: ModelConfig, hp: HybridParallelConfig, mesh: Mesh, axe
     # each zero2/zero3 layer's param cotangents to their reduce-scattered
     # sharding, so the per-layer gradient buckets issue during backward
     grad_annots = modeling.model_annotations(cfg) if hp.grad_overlap else None
+    placed = [placement.place_layer(cfg, s, mesh, axes) for s in hp.layer_strategies]
 
     def hook(i: int, x, lp, enc_out=None, seg_ids=None):
         s = hp.layer_strategies[i]
         with jax.named_scope("redistribute"):
-            x = constrain(x, mesh, activation_spec(axes, s))
-        layer_cfg = cfg
-        if s.ckpt == "full" and cfg.mlp_recompute != "off":
-            # full-layer remat saves only the layer boundary — a nested
-            # gate-save policy inside the remat region is pure overhead
-            layer_cfg = layer_cfg.replace(mlp_recompute="off")
-        if s.cp > 1 and s.cp_impl == "ring":
-            layer_cfg = layer_cfg.replace(attn_impl="ring")
-        if cfg.moe_experts > 0 and s.ep > 1:
-            layer_cfg = layer_cfg.replace(
-                moe_shard_ctx=(
-                    mesh,
-                    axes.ep_axes(s.tp, s.tp_consec, s.ep),
-                    moe_token_axes(axes, s),
-                )
-            )
-        if cfg.moe_dropless and mesh.devices.size > 1:
-            # each device routes its own tokens — see moe.moe_topk_block
-            layer_cfg = layer_cfg.replace(
-                moe_token_shard_ctx=(mesh, activation_spec(axes, s))
-            )
-        if s.dp_type == "zero3" and s.tp > 1:
-            # fsdp x tp wgrad shardings trip an SPMD partitioner fallback
-            # (involuntary full remat of dy) without this pin — see
-            # modeling._constrain_attn_out
-            layer_cfg = layer_cfg.replace(
-                attn_out_shard_ctx=(mesh, axes.dp_axes(s.tp, s.tp_consec, s.cp))
-            )
-        if s.tp > 1:
-            # pin the stacked qkv (and its dqkv cotangent) — see
-            # modeling._constrain_qkv
-            layer_cfg = layer_cfg.replace(
-                qkv_shard_ctx=(
-                    mesh,
-                    axes.dp_axes(s.tp, s.tp_consec, s.cp),
-                    axes.tp_axes(s.tp, s.tp_consec),
-                )
-            )
-        # Mosaic kernels cannot be auto-partitioned by GSPMD — see
-        # sharding.with_flash_shard_ctx / modeling._flash_shard_map
-        layer_cfg = with_flash_shard_ctx(layer_cfg, s, mesh, axes)
-        # decomposed collective-matmul on the TP projection seams — see
-        # sharding.with_tp_overlap_ctx / ops.collective_matmul
-        layer_cfg = with_tp_overlap_ctx(layer_cfg, s, mesh, axes)
+            x = constrain(x, mesh, placement.activation_spec(axes, s))
+        layer_cfg, place = placed[i]
         if layer_cfg.pos_embed == "rope":
             # packed rows: per-segment position reset → per-row gathered tables
             cos_sin = (
@@ -275,7 +220,8 @@ def _make_layer_hook(cfg: ModelConfig, hp: HybridParallelConfig, mesh: Mesh, axe
                 )
             if is_encoder:
                 return modeling.encoder_layer(
-                    x_, lp_, layer_cfg, cos_sin, remat_attn=(s.ckpt == "selective")
+                    x_, lp_, layer_cfg, cos_sin, remat_attn=(s.ckpt == "selective"),
+                    place=place,
                 )
             if s.cp > 1:
                 cp_axes = axes.cp_axes(s.tp, s.tp_consec, s.cp)
@@ -284,25 +230,26 @@ def _make_layer_hook(cfg: ModelConfig, hp: HybridParallelConfig, mesh: Mesh, axe
                     from galvatron_tpu.parallel.ulysses import ulysses_decoder_layer
 
                     return ulysses_decoder_layer(
-                        x_, lp_, layer_cfg, mesh, cp_axes, cos_sin, **cp_kw
+                        x_, lp_, layer_cfg, mesh, cp_axes, cos_sin, place=place, **cp_kw
                     )
                 from galvatron_tpu.parallel.ring import ring_decoder_layer
 
                 return ring_decoder_layer(
-                    x_, lp_, layer_cfg, mesh, cp_axes, cos_sin, **cp_kw
+                    x_, lp_, layer_cfg, mesh, cp_axes, cos_sin, place=place, **cp_kw
                 )
             return modeling.decoder_layer(
                 x_, lp_, layer_cfg, cos_sin, alibi,
                 remat_attn=(s.ckpt == "selective"), enc_out=enc_out,
-                seg_ids=seg_ids,
+                seg_ids=seg_ids, place=place,
             )
 
         if (
-            layer_cfg.tp_overlap_ctx is not None and not cfg.swin_depths and not is_encoder
+            place.tp_overlap and not cfg.swin_depths and not is_encoder
             and enc_out is None and seg_ids is None
         ):
             # layers of one plan entry are one program: see _decoder_layer_once
-            return _decoder_layer_once(x, lp, cos_sin, alibi, cfg=layer_cfg, ckpt=s.ckpt)
+            return _decoder_layer_once(
+                x, lp, cos_sin, alibi, cfg=layer_cfg, place=place, ckpt=s.ckpt)
         if s.ckpt == "full":
             run = jax.checkpoint(run)
         return run(x, lp)
@@ -310,17 +257,17 @@ def _make_layer_hook(cfg: ModelConfig, hp: HybridParallelConfig, mesh: Mesh, axe
     return hook
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "ckpt"))
-def _decoder_layer_once(x, lp, cos_sin, alibi, *, cfg: ModelConfig, ckpt):
+@functools.partial(jax.jit, static_argnames=("cfg", "place", "ckpt"))
+def _decoder_layer_once(x, lp, cos_sin, alibi, *, cfg: ModelConfig, place, ckpt):
     """A tp_overlap decoder layer through ``jax.jit``: layers with the same
-    configuration and strategy (all 24 of the four-chip cell) are traced,
+    configuration and placement (all 24 of the four-chip cell) are traced,
     differentiated and lowered once, not once each — the rings' unrolled steps
     are the longest Python a layer has, and set-up time is gated. The caller's
     ``layer_<i>`` scope stays around the call."""
 
     def run(x_, lp_):
         return modeling.decoder_layer(
-            x_, lp_, cfg, cos_sin, alibi, remat_attn=(ckpt == "selective"))
+            x_, lp_, cfg, cos_sin, alibi, remat_attn=(ckpt == "selective"), place=place)
 
     if ckpt == "full":
         run = jax.checkpoint(run)
@@ -433,7 +380,7 @@ def build_runtime(
         cfg = cfg.replace(dtype=jnp.float16)
         scaler_cfg = LossScalerConfig()
 
-    seams = tp_overlap_seam_counts(cfg, hp, mesh, axes, global_batch_size, seq_len)
+    seams = placement.tp_overlap_seam_counts(cfg, hp, mesh, axes, global_batch_size, seq_len)
     if hp.pp > 1:
         if cfg.swin_depths:
             from galvatron_tpu.parallel.pipeline_swin import (

@@ -70,14 +70,13 @@ from galvatron_tpu.core.strategy import (
 )
 from galvatron_tpu.models import modeling
 from galvatron_tpu.models.modeling import ModelConfig
-from galvatron_tpu.parallel.mesh import MeshAxes, batch_spec, moe_token_axes
+from galvatron_tpu.parallel import placement
+from galvatron_tpu.parallel.mesh import MeshAxes
 from galvatron_tpu.parallel.sharding import (
     constrain,
     cp_shard_axes,
     param_spec,
     sharding_tree,
-    with_flash_shard_ctx,
-    with_tp_overlap_ctx,
 )
 
 def cpu_sim_compiler_options(mesh=None):
@@ -350,9 +349,8 @@ def make_block_fn(
     gpipe_pipeline / the 1F1B body) and drives the intra-segment attention
     mask + per-segment rope positions in every layer."""
 
-    def act_spec(s: LayerStrategy) -> P:
-        bs = batch_spec(axes, s)
-        return P(bs[0], bs[1], None)
+    # the same per-layer rules as the pp=1 hook (hybrid._make_layer_hook)
+    placed = [placement.place_layer(cfg, s, mesh, axes) for s in strategies]
 
     def stage_fn(stage_params: List[Any], x, seg=None):
         if cfg.pos_embed == "rope":
@@ -374,49 +372,29 @@ def make_block_fn(
             else jnp.asarray(active_counts)[jax.lax.axis_index("pp")]
         )
         for j, s in enumerate(strategies):
-            x = constrain(x, mesh, act_spec(s))
-            layer_cfg = cfg
-            if s.ckpt == "full" and cfg.mlp_recompute != "off":
-                # full-layer remat subsumes the gate-save policy — same rule
-                # as the pp=1 hook (hybrid._make_layer_hook)
-                layer_cfg = layer_cfg.replace(mlp_recompute="off")
-            if cfg.moe_experts > 0 and s.ep > 1:
-                layer_cfg = layer_cfg.replace(
-                    moe_shard_ctx=(
-                        mesh,
-                        axes.ep_axes(s.tp, s.tp_consec, s.ep),
-                        moe_token_axes(axes, s),
-                    )
-                )
-            if s.dp_type == "zero3" and s.tp > 1:
-                # same fsdp x tp wgrad pin as the pp=1 hook — see
-                # modeling._constrain_attn_out
-                layer_cfg = layer_cfg.replace(
-                    attn_out_shard_ctx=(mesh, axes.dp_axes(s.tp, s.tp_consec, s.cp))
-                )
-            layer_cfg = with_flash_shard_ctx(layer_cfg, s, mesh, axes)
-            layer_cfg = with_tp_overlap_ctx(layer_cfg, s, mesh, axes)
+            x = constrain(x, mesh, placement.activation_spec(axes, s))
+            layer_cfg, place = placed[j]
 
             def run(x_, lp_):
                 if s.cp > 1:
                     cp_axes = axes.cp_axes(s.tp, s.tp_consec, s.cp)
                     cp_kw = cp_shard_axes(s, axes)
-                    # layer_cfg (not cfg): an MoE layer with cp>1 must keep
-                    # its expert-dispatch sharding pins, as the pp=1 hook does
+                    # an MoE layer with cp>1 keeps its expert-dispatch
+                    # sharding pins (place), as the pp=1 hook does
                     if s.cp_impl == "a2a":
                         from galvatron_tpu.parallel.ulysses import ulysses_decoder_layer
 
                         return ulysses_decoder_layer(
-                            x_, lp_, layer_cfg, mesh, cp_axes, cos_sin, **cp_kw
+                            x_, lp_, layer_cfg, mesh, cp_axes, cos_sin, place=place, **cp_kw
                         )
                     from galvatron_tpu.parallel.ring import ring_decoder_layer
 
                     return ring_decoder_layer(
-                        x_, lp_, layer_cfg, mesh, cp_axes, cos_sin, **cp_kw
+                        x_, lp_, layer_cfg, mesh, cp_axes, cos_sin, place=place, **cp_kw
                     )
                 return modeling.decoder_layer(
                     x_, lp_, layer_cfg, cos_sin, alibi,
-                    remat_attn=(s.ckpt == "selective"), seg_ids=seg,
+                    remat_attn=(s.ckpt == "selective"), seg_ids=seg, place=place,
                 )
 
             if s.ckpt == "full":

@@ -52,15 +52,10 @@ from galvatron_tpu.core.schedules import (
 from galvatron_tpu.core.strategy import HybridParallelConfig, LayerStrategy
 from galvatron_tpu.models import modeling
 from galvatron_tpu.models.modeling import ModelConfig
-from galvatron_tpu.parallel.mesh import MeshAxes, batch_spec
+from galvatron_tpu.parallel import placement
+from galvatron_tpu.parallel.mesh import MeshAxes
 from galvatron_tpu.parallel.pipeline import cpu_sim_compiler_options
-from galvatron_tpu.parallel.sharding import (
-    constrain,
-    param_spec,
-    sharding_tree,
-    with_flash_shard_ctx,
-    with_tp_overlap_ctx,
-)
+from galvatron_tpu.parallel.sharding import constrain, param_spec, sharding_tree
 
 
 class EncDecLayout:
@@ -310,10 +305,9 @@ def _make_section_fns(cfg: ModelConfig, hp: HybridParallelConfig, mesh, axes):
     uneven_e = len(set(lay.div_e)) > 1
     uneven_d = len(set(lay.div_d)) > 1
 
-    def act_spec(s: LayerStrategy) -> P:
-        bs = batch_spec(axes, s)
-        return P(bs[0], bs[1], None)
-
+    # the same per-layer rules as the pp=1 hook (hybrid._make_layer_hook)
+    enc_placed = [placement.place_layer(cfg, s, mesh, axes) for s in enc_pos]
+    dec_placed = [placement.place_layer(cfg, s, mesh, axes) for s in dec_pos]
     cos_e = modeling.rope_tables(cfg, cfg.enc_seq) if cfg.pos_embed == "rope" else None
 
     def enc_section(stage_params, x):
@@ -321,14 +315,10 @@ def _make_section_fns(cfg: ModelConfig, hp: HybridParallelConfig, mesh, axes):
             jnp.asarray(lay.div_e)[jax.lax.axis_index("pp")] if uneven_e else None
         )
         for q, s in enumerate(enc_pos):
-            x = constrain(x, mesh, act_spec(s))
-            lcfg = with_flash_shard_ctx(cfg, s, mesh, axes)
-            lcfg = with_tp_overlap_ctx(lcfg, s, mesh, axes)
-            if s.ckpt == "full" and lcfg.mlp_recompute != "off":
-                # full-layer remat subsumes the gate-save policy
-                lcfg = lcfg.replace(mlp_recompute="off")
-            run = lambda x_, lp_, lcfg=lcfg: modeling.encoder_layer(
-                x_, lp_, lcfg, cos_e, remat_attn=(s.ckpt == "selective")
+            x = constrain(x, mesh, placement.activation_spec(axes, s))
+            lcfg, place = enc_placed[q]
+            run = lambda x_, lp_, lcfg=lcfg, place=place: modeling.encoder_layer(
+                x_, lp_, lcfg, cos_e, remat_attn=(s.ckpt == "selective"), place=place
             )
             if s.ckpt == "full":
                 run = jax.checkpoint(run)
@@ -344,14 +334,11 @@ def _make_section_fns(cfg: ModelConfig, hp: HybridParallelConfig, mesh, axes):
             jnp.asarray(lay.div_d)[jax.lax.axis_index("pp")] if uneven_d else None
         )
         for q, s in enumerate(dec_pos):
-            x = constrain(x, mesh, act_spec(s))
-            lcfg = with_flash_shard_ctx(cfg, s, mesh, axes)
-            lcfg = with_tp_overlap_ctx(lcfg, s, mesh, axes)
-            if s.ckpt == "full" and lcfg.mlp_recompute != "off":
-                lcfg = lcfg.replace(mlp_recompute="off")
-            run = lambda x_, lp_, lcfg=lcfg: modeling.decoder_layer(
+            x = constrain(x, mesh, placement.activation_spec(axes, s))
+            lcfg, place = dec_placed[q]
+            run = lambda x_, lp_, lcfg=lcfg, place=place: modeling.decoder_layer(
                 x_, lp_, lcfg, cos_d, None,
-                remat_attn=(s.ckpt == "selective"), enc_out=ctx,
+                remat_attn=(s.ckpt == "selective"), enc_out=ctx, place=place,
             )
             if s.ckpt == "full":
                 run = jax.checkpoint(run)

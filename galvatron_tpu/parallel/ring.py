@@ -38,6 +38,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from galvatron_tpu.models import modeling
 from galvatron_tpu.models.modeling import ModelConfig
+from galvatron_tpu.models.placement import LOCAL, Placement
 from galvatron_tpu.parallel.mesh import ambient_or, manual_axis_names
 from galvatron_tpu.ops.flash_attention import (
     _flash_bwd_parts,
@@ -303,6 +304,7 @@ def ring_attention(
 def ring_decoder_layer(
     x, p, cfg: ModelConfig, mesh, cp_axes, cos_sin,
     batch_axes: Sequence[str] = (), head_axes: Sequence[str] = (),
+    place: Placement = LOCAL,
 ):
     """Decoder layer with the attention core ring-parallelized (drop-in for
     modeling.decoder_layer when a layer strategy sets cp > 1)."""
@@ -317,15 +319,16 @@ def ring_decoder_layer(
             k = modeling.apply_rope(k, cos, sin)
         k = modeling._repeat_kv(k, cfg.num_heads // k.shape[2])
         v = modeling._repeat_kv(v, cfg.num_heads // v.shape[2])
-        o = modeling._constrain_attn_out(
+        o = place.constrain_attn_out(
             ring_attention(
                 q, k, v, mesh, cp_axes,
                 batch_axes=batch_axes, head_axes=head_axes,
-            ),
-            cfg,
+            )
         )
         return modeling.attn_output(o, p["attn"], cfg, xn.dtype)
 
     x = x + attn(modeling.norm(x, p["attn_norm"], cfg))
-    x = x + modeling.mlp_block(modeling.norm(x, p["mlp_norm"], cfg), p["mlp"], cfg)
+    x = x + modeling.mlp_block(
+        modeling.norm(x, p["mlp_norm"], cfg), p["mlp"], cfg, place=place
+    )
     return x

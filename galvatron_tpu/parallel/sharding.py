@@ -29,7 +29,7 @@ import jax
 
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from galvatron_tpu.core.strategy import HybridParallelConfig, LayerStrategy
+from galvatron_tpu.core.strategy import LayerStrategy
 from galvatron_tpu.parallel.mesh import MeshAxes
 
 Annotation = Tuple[Optional[str], ...]
@@ -116,83 +116,6 @@ def constrain(x, mesh: Mesh, spec: P):
     return jax.lax.with_sharding_constraint(x, NamedSharding(target, spec))
 
 
-def with_flash_shard_ctx(layer_cfg, s: LayerStrategy, mesh: Mesh, axes: MeshAxes):
-    """Install ``flash_shard_ctx`` on a layer's ModelConfig for flash layers
-    on multi-device meshes: GSPMD cannot partition Mosaic custom calls, so
-    modeling._flash_shard_map must route each kernel invocation through a
-    shard_map over the layer's (dp, tp) axes. One shared installer for every
-    engine (pp=1 hook, make_block_fn, enc-dec sections) so the engines
-    cannot diverge. cp>1 layers are excluded — the ring/ulysses paths carry
-    their own shard_maps."""
-    if (
-        getattr(layer_cfg, "attn_impl", None) != "flash"
-        or mesh.devices.size <= 1
-        or s.cp > 1
-    ):
-        return layer_cfg
-    return layer_cfg.replace(
-        flash_shard_ctx=(
-            mesh,
-            axes.dp_axes(s.tp, s.tp_consec, s.cp),
-            axes.tp_axes(s.tp, s.tp_consec),
-        )
-    )
-
-
-def with_tp_overlap_ctx(layer_cfg, s: LayerStrategy, mesh: Mesh, axes: MeshAxes):
-    """Install ``tp_overlap_ctx`` on a layer's ModelConfig when the plan sets
-    ``tp_overlap`` (decomposed collective-matmul on the TP projection seams —
-    see ops/collective_matmul.py and modeling._proj_up/_proj_down). Shared by
-    every engine, like with_flash_shard_ctx. cp>1 layers are excluded — the
-    ring/ulysses paths own their projection seams."""
-    if (
-        not getattr(s, "tp_overlap", False)
-        or s.tp <= 1
-        or mesh.devices.size <= 1
-        or s.cp > 1
-    ):
-        return layer_cfg
-    return layer_cfg.replace(
-        tp_overlap_ctx=(
-            mesh,
-            axes.dp_axes(s.tp, s.tp_consec, s.cp),
-            axes.tp_axes(s.tp, s.tp_consec),
-            bool(s.sp),
-        )
-    )
-
-
-def tp_overlap_seam_counts(
-    cfg, hp: HybridParallelConfig, mesh: Mesh, axes: MeshAxes,
-    global_batch_size: int, seq_len: int,
-) -> dict:
-    """``{"ring": n, "plain": m}`` over the plan's ``tp_overlap`` layers: how
-    many projection seams take the collective-matmul ring and how many stay
-    the plain einsum, by the shape test the seams themselves apply to a
-    micro-batch (ops.collective_matmul.ring_pays; non-sp layers have no
-    all-gather to decompose, so their column-parallel seams are plain)."""
-    from galvatron_tpu.models.modeling import projection_seams
-    from galvatron_tpu.ops.collective_matmul import ring_pays, tp_group_size
-
-    counts = {"ring": 0, "plain": 0}
-    itemsize = 4 if hp.mixed_precision == "fp32" else 2
-    seams = projection_seams(cfg, seq_len)
-    micro = global_batch_size // max(1, hp.chunks)
-    for s in hp.layer_strategies:
-        if with_tp_overlap_ctx(cfg, s, mesh, axes) is cfg:
-            continue
-        dp = tp_group_size(mesh, axes.dp_axes(s.tp, s.tp_consec, s.cp))
-        rows = micro // dp * (seq_len // s.tp)
-        for _, kind, width, _ in seams:
-            ring = (
-                (s.sp or kind == "rs")
-                and seq_len % s.tp == 0 and width % s.tp == 0 and micro % dp == 0
-                and ring_pays(s.tp, rows, width // s.tp, itemsize)
-            )
-            counts["ring" if ring else "plain"] += 1
-    return counts
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
 def _grad_shard(x, mesh, spec):
     return x
@@ -234,7 +157,7 @@ def overlap_grad_sync(params, annots, mesh: Mesh, axes: MeshAxes, s: LayerStrate
 def cp_shard_axes(s: LayerStrategy, axes: MeshAxes) -> dict:
     """(batch_axes, head_axes) kwargs for the ring/ulysses CP entries — one
     derivation shared by the pp=1 hook and the pipeline engines so they
-    cannot diverge (companion of with_flash_shard_ctx)."""
+    cannot diverge (the layer's other placement rules: placement.place_layer)."""
     return dict(
         batch_axes=axes.dp_axes(s.tp, s.tp_consec, s.cp),
         head_axes=axes.tp_axes(s.tp, s.tp_consec),

@@ -28,6 +28,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from galvatron_tpu.models import modeling
 from galvatron_tpu.models.modeling import ModelConfig
+from galvatron_tpu.models.placement import LOCAL, Placement
 from galvatron_tpu.parallel.mesh import ambient_or, manual_axis_names
 
 
@@ -88,6 +89,7 @@ def ulysses_attention(
 def ulysses_decoder_layer(
     x, p, cfg: ModelConfig, mesh, cp_axes, cos_sin,
     batch_axes: Sequence[str] = (), head_axes: Sequence[str] = (),
+    place: Placement = LOCAL,
 ):
     """Decoder layer with the attention core Ulysses-parallelized (drop-in for
     modeling.decoder_layer when a layer strategy sets cp > 1, cp_impl='a2a').
@@ -104,15 +106,16 @@ def ulysses_decoder_layer(
             k = modeling.apply_rope(k, cos, sin)
         # K/V stay at kv_heads across the all-to-all (GQA repeat happens in
         # the local attention core) — group_factor× less CP traffic
-        o = modeling._constrain_attn_out(
+        o = place.constrain_attn_out(
             ulysses_attention(
                 q, k, v, cfg, mesh, cp_axes,
                 batch_axes=batch_axes, head_axes=head_axes,
-            ),
-            cfg,
+            )
         )
         return modeling.attn_output(o, p["attn"], cfg, xn.dtype)
 
     x = x + attn(modeling.norm(x, p["attn_norm"], cfg))
-    x = x + modeling.mlp_block(modeling.norm(x, p["mlp_norm"], cfg), p["mlp"], cfg)
+    x = x + modeling.mlp_block(
+        modeling.norm(x, p["mlp_norm"], cfg), p["mlp"], cfg, place=place
+    )
     return x

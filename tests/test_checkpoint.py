@@ -233,3 +233,23 @@ def test_positive_layout_detection_survives_reworded_exceptions(tmp_path, monkey
         raise AssertionError("expected ValueError for neither-layout checkpoint")
     except ValueError as e:
         assert "neither" in str(e)
+
+
+def test_manifest_records_a_scalar_the_same_whoever_can_gather_it():
+    """A pod writes structure-only records for leaves no one process can gather
+    (``test_multihost.py``); the process that restores the step alone gathers
+    them and verifies against that manifest: the two records of a scalar
+    (``step``, ``opt.count``) must carry the same shape."""
+    import numpy as np
+
+    from galvatron_tpu.core.checkpoint import _leaf_digest, verify_manifest
+
+    class PodScalar:  # what a process of a multi-host job sees of a global scalar
+        is_fully_addressable = False
+        shape = ()
+        dtype = np.int32
+
+    whole = _leaf_digest(np.int32(3))
+    pod = _leaf_digest(PodScalar())
+    assert (pod["shape"], pod["dtype"], pod["digest"]) == (whole["shape"], whole["dtype"], None)
+    assert verify_manifest({"leaves": {"['step']": pod}}, {"step": np.int32(3)}) == []

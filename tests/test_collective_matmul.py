@@ -42,7 +42,7 @@ def always_ring(monkeypatch):
     monkeypatch.setattr(cm, "ring_pays", lambda tp, *a, **k: tp > 1)
 
 
-#: the four projection seams of a layer (modeling._proj_up / _proj_down), at toy
+#: the four projection seams of a layer (Placement.proj_up / proj_down), at toy
 #: sizes: name -> (entry point, subscripts, x shape, w shape, w_shard_dim)
 N, HD = 4, 2
 SEAMS = {
@@ -301,7 +301,7 @@ def test_indivisible_shapes_fall_back(always_ring):
 def test_train_step_parity_with_tp_overlap(sp, always_ring):
     """End-to-end: the same model + data trains to the same losses with the
     collective-matmul decomposition on and off (fp32, tp=4 over the 8-device
-    mesh) — the dispatch seams in modeling._proj_up/_proj_down change only
+    mesh) — the placement's proj_up/proj_down seams change only
     the collective schedule, never the math."""
     from galvatron_tpu.core.strategy import HybridParallelConfig
     from galvatron_tpu.models.modeling import ModelConfig

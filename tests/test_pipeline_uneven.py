@@ -223,8 +223,8 @@ def test_division_larger_max_measurably_slower():
         runners[key] = (rt, state)
         return time.perf_counter() - t0
 
-    # PAIRED interleaved rounds + median, per the repo's own measurement
-    # guidance (bench.py): single windows on a shared host are unreliable
+    # PAIRED interleaved rounds + median: single windows on a shared host
+    # are unreliable
     diffs = [window((1, 4)) / window((2, 3)) for _ in range(3)]
     ratio = float(np.median(diffs))
     # lps=4 runs 8 position-computes per stage pass vs 6 (~33% more); allow

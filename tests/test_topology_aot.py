@@ -4,7 +4,7 @@ The CPU simulation runs Pallas kernels in interpret mode (plain jnp ops
 GSPMD can partition), so it can never catch a kernel the chip's compiler
 refuses — a block shape Mosaic cannot tile, too much VMEM, a kernel that
 is not partitionable on a multi-device mesh ("Mosaic kernels cannot be
-automatically partitioned", what modeling._flash_shard_map exists for) —
+automatically partitioned", what LayerPlacement.shard_kernel exists for) —
 or a step program that does not fit the device's memory.  These tests
 AOT-compile for a described, not attached, v5e:2x2
 (jax.experimental.topologies): the real TPU compiler and the real Mosaic
@@ -374,7 +374,15 @@ def _opt_four_chip_step(topo, tmp_path_factory):
         rt = build_runtime(cfg, hp, mesh=mesh, axes=axes, adam=AdamConfig(lr=1e-3),
                            global_batch_size=16, seq_len=2048)
         batch = jax.ShapeDtypeStruct((16, 2049), jnp.int32, sharding=rt.batch_sharding)
-        lowered = rt.train_step.lower(abstract_state_of(rt), batch)
+        from galvatron_tpu.models import modeling
+
+        traced, layer = [], modeling.decoder_layer
+        modeling.decoder_layer = lambda *a, **k: traced.append(k["place"]) or layer(*a, **k)
+        try:
+            lowered = rt.train_step.lower(abstract_state_of(rt), batch)
+        finally:
+            modeling.decoder_layer = layer
+        _OPT_STEP["layer_traces"] = traced
         from galvatron_tpu.analysis import comm_audit as ca
 
         footprint = ca.extract_footprint(lowered.as_text(), program="train_step")
@@ -400,6 +408,15 @@ def test_four_chip_searched_plan_sets_tp_overlap(topo, real_mosaic, tmp_path_fac
     doc, seams, _ = _opt_four_chip_step(topo, tmp_path_factory)
     assert (doc["tp_sizes_enc"], doc["sp_flags"], doc["tp_overlap_flags"]) == ("4,4", "1,1", "1,1")
     assert seams == {"ring": 8, "plain": 0}
+
+
+def test_four_chip_step_traces_its_layers_once(topo, real_mosaic, tmp_path_factory):
+    """Layers of one plan entry have equal placements, so ``_decoder_layer_once``
+    runs the layer's Python once for all of them (``trace_lower_s`` of the
+    cell; its ``_cache_size()`` stays 0, being called under the step's trace)."""
+    _opt_four_chip_step(topo, tmp_path_factory)
+    (place,) = _OPT_STEP["layer_traces"]
+    assert place.tp_overlap and place.sp and place.kernel_tp == 4
 
 
 @pytest.mark.parametrize("scope,per_layer", [("allgather_einsum", 12), ("einsum_reducescatter", 24)])
