@@ -9,9 +9,14 @@ as ``ops/collective_matmul.py`` decomposes it, in the arms
     ring        two-way, ring order from the devices' coordinates (what the program runs)
     one_way     whole chunks one way, coordinate order
     flat_order  two-way, the flattened-index order (0 -> 1 -> 2 -> 3 -> 0)
+    whole       ``ring`` with the head-major all-gather sides (qkv forward, out_proj
+                backward) gathered whole in front of one GEMM (PR 29's program)
+    batch2/4    ``ring`` with those sides pipelined over the batch in 2 / 4 pieces
 
-The shape test is switched off here so that every seam runs on the ring: its threshold
-(``RING_MIN_COVER``) is set from this table (PERF.md §6, PR 29). ``--profile ARMS`` also
+The ring's shape test is switched off here so that every seam runs on the ring: its
+threshold (``RING_MIN_COVER``) is set from this table (PERF.md §6, PR 29); ``ring`` asks
+``batch_pieces`` as the program does, and what that returns is read from the three last
+arms (PERF.md §6, PR 32), which only the two head-major seams run. ``--profile ARMS`` also
 traces those arms and writes device 0's operations, by kind and as one pass's timeline,
 to ``chiprun_out/seam_profile_<seam>_<arm>.txt``: how the ring's copies were found.
 
@@ -49,7 +54,10 @@ SEAMS = {
     "mlp_up": ("ag", "bsh,hf->bsf", (B, S, H), (H, F), 1),
     "mlp_down": ("rs", "bsf,fh->bsh", (B, S, F), (F, H), 0),
 }
-ARMS = ("gspmd", "ring", "one_way", "flat_order")
+ARMS = ("gspmd", "ring", "one_way", "flat_order", "whole", "batch2", "batch4")
+#: arms that differ from ``ring`` only where an all-gather side is head-major
+BATCH_ARMS = {"whole": 1, "batch2": 2, "batch4": 4}
+HEAD_MAJOR = ("qkv_proj", "out_proj")
 REPEATS = 8  # seam applications inside one timed call (a scan over stacked weights)
 
 
@@ -114,8 +122,10 @@ class arm_settings:
         self.arm = arm
 
     def __enter__(self):
-        self.saved = (cm.ring_pays, cm.ring_ways, cm.mesh_ring_order)
+        self.saved = (cm.ring_pays, cm.ring_ways, cm.mesh_ring_order, cm.batch_pieces)
         cm.ring_pays = lambda tp, *a, **k: tp > 1
+        if self.arm in BATCH_ARMS:
+            cm.batch_pieces = lambda *a, **k: BATCH_ARMS[self.arm]
         if self.arm == "one_way":
             cm.ring_ways = lambda tp: 1
         if self.arm == "flat_order":
@@ -123,7 +133,7 @@ class arm_settings:
         jax.clear_caches()
 
     def __exit__(self, *exc):
-        cm.ring_pays, cm.ring_ways, cm.mesh_ring_order = self.saved
+        cm.ring_pays, cm.ring_ways, cm.mesh_ring_order, cm.batch_pieces = self.saved
         jax.clear_caches()
 
 
@@ -201,6 +211,8 @@ def main():
         local = (int(np.prod(w_shape)) // (x_shape[-1] if kind == "ag" else H)) // 4
         row = {"seam": name, "kind": kind, "hop_cover": round(cm.hop_cover(4, local, 2), 3)}
         for arm in ns.arms.split(","):
+            if arm in BATCH_ARMS and name not in HEAD_MAJOR:
+                continue
             with arm_settings(arm):
                 fn, avals = build(arm, name, mesh, axes)
                 t0 = time.time()

@@ -833,10 +833,11 @@ def projection_seams(cfg: ModelConfig, seq_len: int) -> Tuple[Tuple[str, str, in
     (column-parallel, ``width`` its output columns) or "rs" (row-parallel,
     ``width`` its contraction), the width being what the tp axes divide;
     ``blockwise`` False where the seam's all-gather side (an "ag" seam's
-    forward, an "rs" seam's backward) puts out head-major dims and so gathers
-    whole (ops.collective_matmul._allgather_matmul). What the ring's shape
-    test (ops.collective_matmul.ring_pays) is asked about by the runtime's
-    ``tp_overlap_seams`` count and by the search's pricing; mirrors the
+    forward, an "rs" seam's backward) puts out head-major dims and so cannot
+    ring over the sequence: it gathers whole, or in pieces along the batch
+    (ops.collective_matmul._allgather_matmul). What the seams' shape tests
+    (ops.collective_matmul.ring_pays, batch_pieces) are asked about by the
+    runtime's ``tp_overlap_seams`` count and by the search's pricing; mirrors the
     dispatch in ``attn_block`` / ``_attn_block_headmajor`` / ``mlp_block``."""
     from galvatron_tpu.ops.flash_attention import flash_tileable
 

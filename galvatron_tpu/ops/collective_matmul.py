@@ -37,16 +37,24 @@ The ring, as a chip wants it (four v5e chips, PERF.md §6, PR 29):
 - **two-way**: with more than two devices each chunk (or accumulator) is
   split in halves along the sequence that travel in opposite directions, so
   both of a device's incoming links carry half a chunk per hop.
-- **assembly**: what the all-gather ring gathers and computes is written as
-  whole leading slices of piece-major buffers, which the compiler does in
-  place; a seam whose all-gather side puts out head-major dims gathers whole
-  there and is a ring in its other direction only (:func:`_allgather_matmul`).
+- **assembly**: a result is assembled from whole leading slices only, which
+  the compiler writes in place (a piece put at a sequence offset of a
+  ``[b, S, n]`` result is a strided copy of its own). So a seam whose output
+  dims are in a plain GEMM's order (the MLP's two) rings over the *sequence*
+  into piece-major buffers; a seam whose all-gather side puts out head-major
+  dims (``bcnsd`` of the stacked qkv projection, ``bnsd`` of the output
+  projection's cotangent), where the sequence is not the leading dim, is a
+  ring in its other direction and pipelines that side over the *batch*,
+  which does lead: one tiled all-gather and one GEMM a piece of the
+  micro-batch's rows, piece b+1's gather in flight under piece b's GEMM
+  (:func:`_allgather_matmul`). With one row, or pieces too short, it gathers
+  whole in front of one GEMM (:func:`batch_pieces`).
 - **shape test** (:func:`ring_pays`): a seam takes the ring only where a
   piece's GEMM is long enough to cover a good part of its hop, which its
   shapes tell (:func:`hop_cover`); elsewhere, and wherever the chunking does
   not divide, it is the plain ``jnp.einsum`` (GSPMD collectives). The search
-  prices a ``tp_overlap`` layer from the same two functions
-  (:func:`exposed_share`).
+  prices a ``tp_overlap`` layer from the same functions
+  (:func:`exposed_share`, :func:`batch_exposed_share`).
 - **backward**: each entry point is a ``custom_vjp`` whose backward is the
   other ring (the transpose of AG⊗matmul is matmul⊗RS and vice versa) plus
   one whole weight-gradient GEMM, accumulated in fp32, on the gathered
@@ -55,8 +63,8 @@ The ring, as a chip wants it (four v5e chips, PERF.md §6, PR 29):
 - **one trace per distinct seam**: forward and backward of either kind are
   four ``jax.jit`` programs that the ``custom_vjp`` rules only bind.
 
-Scopes ``allgather_einsum`` / ``einsum_reducescatter`` wrap the hops (and a
-whole gather) only: the piece GEMMs stay under the caller's scope
+Scopes ``allgather_einsum`` / ``einsum_reducescatter`` wrap the hops (and the
+gathers of a batch-wise side) only: the piece GEMMs stay under the caller's scope
 (``qkv_proj`` / ``out_proj`` / ``mlp``).
 """
 
@@ -124,6 +132,50 @@ def exposed_share(tp: int, chunk_rows: int, local_width: int, itemsize: int,
     if not ring_pays(tp, chunk_rows, local_width, itemsize):
         return 1.0
     return max(0.0, 1.0 - backward_gemms * hop_cover(tp, local_width, itemsize))
+
+
+#: pieces the batch-wise pipeline cuts a micro-batch into where it can: the
+#: seam table (experiments/tp_overlap_seams.py, PERF.md §6, PR 32) is timed at
+#: 2 and at 4 on a device-local batch of 4
+BATCH_PIECES = (4, 2)
+
+
+def gather_cover(tp: int, local_width: int, itemsize: int) -> float:
+    """Time of a row's GEMM over the time of that row's whole all-gather over
+    the sequence (every device receives ``(tp - 1) / tp`` of the row, over the
+    links :func:`ring_ways` counts): :func:`hop_cover` scaled from a hop to a
+    gather."""
+    return hop_cover(tp, local_width, itemsize) * tp / (tp - 1)
+
+
+def batch_pieces(tp: int, local_batch: int, rows_per_sample: int, local_width: int,
+                 itemsize: int) -> int:
+    """Pieces along the batch that a head-major all-gather side (qkv forward,
+    out_proj backward) gathers and multiplies one after the other, the next
+    piece's gather under this piece's GEMM; 1 is the whole gather in front of
+    one GEMM. ``local_batch``: samples of the micro-batch a device holds;
+    ``rows_per_sample``: the gathered sequence; ``local_width``: the GEMM's
+    device-local output columns. The largest of :data:`BATCH_PIECES` that
+    divides the batch and leaves a piece's GEMM :data:`RING_MIN_PIECE_ROWS`
+    rows, where the seam is wide enough to take the ring at all."""
+    if tp <= 1 or hop_cover(tp, local_width, itemsize) < RING_MIN_COVER:
+        return 1
+    for p in BATCH_PIECES:
+        if local_batch % p == 0 and local_batch // p * rows_per_sample >= RING_MIN_PIECE_ROWS:
+            return p
+    return 1
+
+
+def batch_exposed_share(tp: int, local_batch: int, rows_per_sample: int, local_width: int,
+                        itemsize: int) -> float:
+    """Share of a head-major all-gather side's collective time that stays
+    exposed: all of it gathered whole, else the first piece's gather and what
+    a piece's GEMM leaves of the next piece's."""
+    p = batch_pieces(tp, local_batch, rows_per_sample, local_width, itemsize)
+    if p == 1:
+        return 1.0
+    uncovered = max(0.0, 1.0 - gather_cover(tp, local_width, itemsize))
+    return (1.0 + (p - 1) * uncovered) / p
 
 
 def ring_order(coords: Sequence[Sequence[Optional[Tuple[int, ...]]]]) -> Tuple[int, ...]:
@@ -287,6 +339,20 @@ def _natural(sub: Tuple[str, str, str]) -> str:
     return "".join(c for c in x_sub + w_sub if c in out_sub)
 
 
+def _batch_split(sub: Tuple[str, str, str], x_l, w_l, T: int, seq_x: int):
+    """``x_l`` in the pieces along its leading batch dim that a head-major
+    all-gather side pipelines over (:func:`batch_pieces`); whole where the
+    batch does not lead both ``x`` and the output."""
+    x_sub, w_sub, out_sub = sub
+    if seq_x == 0 or x_sub[0] != out_sub[0] or x_sub[0] in w_sub:
+        return [x_l]
+    dims = _widths(sub, x_l.shape, w_l.shape)
+    rows = int(np.prod([dims[c] for c in x_sub[1:] if c in out_sub])) * T
+    width = int(np.prod([dims[c] for c in w_sub if c in out_sub]))
+    p = batch_pieces(T, x_l.shape[0], rows, width, jnp.dtype(x_l.dtype).itemsize)
+    return [x_l] if p == 1 else jnp.split(x_l, p, axis=0)
+
+
 def _allgather_matmul(x_l, w_l, *, subscripts: str, tp, order, seq, scope):
     """Device-local all-gather⊗matmul: ``(einsum(subscripts, gathered x, w_l),
     gathered x)``; the gathered operand is the weight gradient's.
@@ -302,16 +368,28 @@ def _allgather_matmul(x_l, w_l, *, subscripts: str, tp, order, seq, scope):
     That holds only where the output's dims are in a plain GEMM's order. A
     result the GEMM has to transpose (the head-major ``bcnsd`` of the stacked
     qkv projection, ``bnsd`` of the output projection's cotangent) is copied
-    and then placed by a slow update, 200 us a piece: such a seam gathers
-    whole and multiplies once, as GSPMD would, and only its other direction
-    (the reduce-scatter ring, which reads pieces and writes none) is a ring."""
+    and then placed by a slow update, 200 us a piece of the sequence. Such a
+    seam's other direction is a ring (the reduce-scatter ring reads pieces
+    and writes none); this direction goes piece by piece along the batch,
+    which leads both operand and result: each piece is gathered whole over
+    the sequence and multiplied by itself, so piece b+1's gather runs under
+    piece b's GEMM, and result and gathered operand are joined from whole
+    leading slices (on four v5e chips 0.22 ms of the qkv projection's 1.47
+    and 0.05 of the output projection's 0.96, PERF.md §6, PR 32). The same
+    GEMM on the same rows; one piece (:func:`batch_pieces`) is the whole
+    gather in front of one GEMM, as GSPMD would do it."""
     x_sub, w_sub, out_sub = sub = _parse(subscripts)
     T = len(order)
     seq_x = x_sub.index(seq)
     if _natural(sub) != out_sub:
-        with jax.named_scope(scope):
-            full = jax.lax.all_gather(x_l, tp, axis=seq_x, tiled=True)
-        return jnp.einsum(subscripts, full, w_l), full
+        outs, fulls = [], []
+        for piece in _batch_split(sub, x_l, w_l, T, seq_x):
+            with jax.named_scope(scope):
+                fulls.append(jax.lax.all_gather(piece, tp, axis=seq_x, tiled=True))
+            outs.append(jnp.einsum(subscripts, fulls[-1], w_l))
+        if len(outs) == 1:
+            return outs[0], fulls[0]
+        return jnp.concatenate(outs, axis=0), jnp.concatenate(fulls, axis=0)
     lanes = _lanes(order, x_l.shape[seq_x])
     pieces = [jax.lax.slice_in_dim(x_l, o, o + n, axis=seq_x) for o, n, _ in lanes]
     dims = _widths(sub, pieces[0].shape, w_l.shape)

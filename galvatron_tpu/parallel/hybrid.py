@@ -146,10 +146,11 @@ class HybridParallelRuntime:
     # resume works across pipeline degrees/schedules (core/checkpoint.py).
     flatten_params: Callable = None
     restack_params: Callable = None
-    # {"ring": n, "plain": m}: projection seams of the plan's tp_overlap layers
-    # that take the collective-matmul ring / stay the plain einsum
-    # (placement.tp_overlap_seam_counts); the trainer puts it in the run's
-    # fingerprint and on the build_runtime span
+    # {"ring": n, "plain": m, "batchwise": k}: projection seams of the plan's
+    # tp_overlap layers that take the collective-matmul ring / stay the plain
+    # einsum, and the ring seams whose head-major all-gather side pipelines
+    # over the batch (placement.tp_overlap_seam_counts); the trainer puts it
+    # in the run's fingerprint and on the build_runtime span
     tp_overlap_seams: Any = None
 
     def shard_batch(self, batch_np):

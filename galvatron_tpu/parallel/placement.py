@@ -230,14 +230,17 @@ def tp_overlap_seam_counts(
     cfg, hp: HybridParallelConfig, mesh: Mesh, axes: MeshAxes,
     global_batch_size: int, seq_len: int,
 ) -> dict:
-    """``{"ring": n, "plain": m}`` over the plan's ``tp_overlap`` layers: how
-    many projection seams take the collective-matmul ring and how many stay
-    the plain einsum, by the shape test the seams themselves apply to a
-    micro-batch (ops.collective_matmul.ring_pays; non-sp layers have no
-    all-gather to decompose, so their column-parallel seams are plain)."""
+    """``{"ring": n, "plain": m, "batchwise": k}`` over the plan's
+    ``tp_overlap`` layers: how many projection seams take the
+    collective-matmul ring and how many stay the plain einsum, by the shape
+    test the seams themselves apply to a micro-batch
+    (ops.collective_matmul.ring_pays; non-sp layers have no all-gather to
+    decompose, so their column-parallel seams are plain), and how many of the
+    ring seams pipeline their head-major all-gather side over the batch
+    (ops.collective_matmul.batch_pieces; the others of them gather whole)."""
     from galvatron_tpu.models.modeling import projection_seams
 
-    counts = {"ring": 0, "plain": 0}
+    counts = {"ring": 0, "plain": 0, "batchwise": 0}
     itemsize = 4 if hp.mixed_precision == "fp32" else 2
     seams = projection_seams(cfg, seq_len)
     micro = global_batch_size // max(1, hp.chunks)
@@ -247,11 +250,14 @@ def tp_overlap_seam_counts(
             continue
         dp = cm.tp_group_size(mesh, place.dp_axes)
         rows = micro // dp * (seq_len // s.tp)
-        for _, kind, width, _ in seams:
+        for _, kind, width, blockwise in seams:
             ring = (
                 (s.sp or kind == "rs")
                 and seq_len % s.tp == 0 and width % s.tp == 0 and micro % dp == 0
                 and cm.ring_pays(s.tp, rows, width // s.tp, itemsize)
             )
             counts["ring" if ring else "plain"] += 1
+            if ring and not blockwise:
+                counts["batchwise"] += cm.batch_pieces(
+                    s.tp, micro // dp, seq_len, width // s.tp, itemsize) > 1
     return counts

@@ -629,9 +629,12 @@ def tp_overlap_exposed(
     collectives (TP_BOUNDARY_COLLECTIVES all-reduces = four seams, forward
     and backward, each moving one (b, s, h) activation) are exposed in full
     where the seam stays the plain einsum, and by what its piece GEMMs do
-    not cover where it takes the ring. Non-sp layers get no credit: only
-    their row-parallel seams decompose, and no chip has measured that."""
-    from galvatron_tpu.ops.collective_matmul import exposed_share
+    not cover where it takes the ring. A seam that is not blockwise gathers
+    whole on its all-gather side, or in pieces along the batch with the next
+    piece's gather under this piece's GEMM: what ``batch_exposed_share`` says
+    of ``local_bsz``. Non-sp layers get no credit: only their row-parallel
+    seams decompose, and no chip has measured that."""
+    from galvatron_tpu.ops.collective_matmul import batch_exposed_share, exposed_share, ring_pays
 
     if not (s.tp_overlap and s.tp > 1 and s.sp):
         return 1.0
@@ -639,13 +642,14 @@ def tp_overlap_exposed(
     exposed = slots
     for kind, width, rows_per_sample, blockwise in lt.tp_seams:
         rows = int(local_bsz * rows_per_sample) // s.tp
-        # forward, backward as (is it the all-gather ring, GEMMs on each piece
-        # in hand): a seam that is not blockwise gathers whole on its
-        # all-gather side, exposed in full
+        # forward, backward as (is it the all-gather ring, GEMMs on each piece in hand)
         directions = ((True, 1), (False, 1)) if kind == "ag" else ((False, 1), (True, 2))
         for allgather, gemms in directions:
             if blockwise or not allgather:
                 exposed -= 1.0 - exposed_share(s.tp, rows, width // s.tp, itemsize, gemms)
+            elif ring_pays(s.tp, rows, width // s.tp, itemsize):
+                exposed -= 1.0 - batch_exposed_share(
+                    s.tp, int(local_bsz), rows_per_sample, width // s.tp, itemsize)
     return max(0.0, exposed) / slots
 
 

@@ -207,6 +207,165 @@ def test_shape_test_two_sides(side):
         assert cm.hop_cover(2, width, 2) == cm.hop_cover(4, width, 2) / 2  # one way at tp 2
 
 
+#: (tp, local batch, rows a sample, local width) -> pieces along the batch; the
+#: first two are the four-chip cell's head-major all-gather sides
+BATCH_PIECES_CASES = {
+    "cell_qkv_forward": ((4, 4, 2048, 1536), 4),
+    "cell_out_proj_backward": ((4, 4, 2048, 512), 4),
+    "search_whole_batch": ((4, 16, 2048, 1536), 4),
+    "batch_2": ((4, 2, 2048, 1536), 2),
+    "batch_1": ((4, 1, 2048, 1536), 1),
+    "batch_3": ((4, 3, 2048, 1536), 1),
+    "batch_6": ((4, 6, 2048, 1536), 2),
+    "quarter_under_min_rows": ((4, 4, 128, 1536), 2),
+    "half_under_min_rows": ((4, 4, 64, 1536), 1),
+    "narrow": ((4, 4, 2048, 128), 1),
+    "tp2": ((2, 4, 2048, 1536), 4),
+    "tp1": ((1, 4, 2048, 1536), 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(BATCH_PIECES_CASES))
+def test_batch_pieces_from_shapes(case):
+    """``batch_pieces``: the head-major all-gather sides of the four-chip cell
+    (a micro-batch of 4 x 2048 on a device) go in four pieces; a batch of one,
+    a batch no piece count divides, a piece under ``RING_MIN_PIECE_ROWS``
+    rows, a seam too narrow for any ring and tp 1 gather whole.
+    ``batch_exposed_share`` prices the same decision: the first piece's
+    gather, and what a piece's GEMM leaves of the next."""
+    args, pieces = BATCH_PIECES_CASES[case]
+    assert cm.batch_pieces(*args, 2) == pieces
+    share = cm.batch_exposed_share(*args, 2)
+    if pieces == 1:
+        assert share == 1.0
+    else:
+        tp, _, _, width = args
+        left = max(0.0, 1.0 - cm.gather_cover(tp, width, 2))
+        assert share == (1.0 + (pieces - 1) * left) / pieces and 1.0 / pieces <= share < 1.0
+
+
+@pytest.mark.parametrize("local_bsz", [16, 4, 2, 1])
+def test_search_prices_the_cells_seams_as_the_shape_function_says(local_bsz):
+    """``cost_model.tp_overlap_exposed`` over opt-1.3b's four seams at tp 4 +
+    sp: the ring's share on the six block-wise sides, ``batch_exposed_share``
+    of the local batch on the two head-major all-gather sides (qkv forward,
+    out_proj backward), which a batch of one leaves exposed in full."""
+    from galvatron_tpu.core.strategy import LayerStrategy
+    from galvatron_tpu.models.modeling import PRESETS, projection_seams
+    from galvatron_tpu.search.cost_model import ProfiledLayerType, tp_overlap_exposed
+
+    cfg = PRESETS["opt-1.3b"].replace(attn_impl="flash", max_seq_len=2048)
+    seams = projection_seams(cfg, 2048)
+    assert [(n, k, w, blk) for n, k, w, blk in seams] == [
+        ("qkv_proj", "ag", 6144, False), ("out_proj", "rs", 2048, False),
+        ("mlp_up", "ag", 8192, True), ("mlp_down", "rs", 8192, True)]
+    lt = ProfiledLayerType(
+        fwd_ms_per_sample=2.0, parameter_mb=80.0, activation_mb_per_sample={1: 40.0},
+        boundary_activation_mb_per_sample=4.0,
+        tp_seams=tuple((k, w, 2048, blk) for _, k, w, blk in seams))
+    s = LayerStrategy(tp=4, sp=True, tp_overlap=True)
+    rows = local_bsz * 2048 // 4
+    ring = lambda w, gemms=1: cm.exposed_share(4, rows, w // 4, 2, gemms)  # noqa: E731
+    batch = lambda w: cm.batch_exposed_share(4, local_bsz, 2048, w // 4, 2)  # noqa: E731
+    want = (batch(6144) + ring(6144)  # qkv_proj: gather forward, ring backward
+            + ring(2048) + batch(2048)  # out_proj: ring forward, gather backward
+            + ring(8192) + ring(8192) + ring(8192) + ring(8192, 2)) / 8.0
+    assert tp_overlap_exposed(lt, s, local_bsz, 2) == pytest.approx(want, abs=1e-12)
+    assert (batch(6144) < 1.0) == (batch(2048) < 1.0) == (local_bsz > 1)
+    whole = tp_overlap_exposed(lt, s, 1, 2)
+    assert tp_overlap_exposed(lt, s, local_bsz, 2) <= whole < 1.0
+
+
+@pytest.fixture
+def toy_batch_pieces(monkeypatch):
+    """``batch_pieces`` as shipped but for its two thresholds, which the toy
+    shapes are far below; the jitted seams read it while they are traced, so
+    their caches are dropped around a switch."""
+    monkeypatch.setattr(cm, "RING_MIN_PIECE_ROWS", 1)
+    monkeypatch.setattr(cm, "RING_MIN_COVER", 0.0)
+    shipped = cm.batch_pieces
+
+    def switch(whole: bool):
+        monkeypatch.setattr(cm, "batch_pieces", (lambda *a: 1) if whole else shipped)
+        jax.clear_caches()
+
+    yield switch
+    jax.clear_caches()
+
+
+def _small_ints(key, shape, dtype=jnp.float32):
+    """Whole numbers in [-2, 2]: every product and every sum of the toy seams
+    is then exact in float32, whatever order a GEMM adds in (the CPU's GEMM
+    picks its order by the number of rows; the MXU's does not depend on it)."""
+    return jnp.asarray(np.random.RandomState(key).randint(-2, 3, shape), dtype)
+
+
+def _value_and_grads(seam, x, w):
+    """(y, dx, dw) of a seam at tp 4 on the suite's mesh (dp 2) under a
+    cotangent of small whole numbers, and the all-gathers in the lowered text
+    of its forward + backward."""
+    entry, sub, _, _, w_shard_dim = SEAMS[seam]
+    mesh, dp, tpa = _mesh_axes(4, True)
+
+    def run(x, w):
+        return entry(sub, x, w, mesh=mesh, dp_axes=dp, tp_axes=tpa, w_shard_dim=w_shard_dim)
+
+    def loss(x, w):
+        y = run(x, w)
+        return jnp.sum((y * _small_ints(24, y.shape, y.dtype)).astype(jnp.float32))
+
+    grads = jax.jit(jax.grad(loss, argnums=(0, 1)))
+    gathers = grads.lower(x, w).as_text().count("stablehlo.all_gather")
+    return [np.asarray(a, np.float32) for a in (run(x, w), *grads(x, w))], gathers
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["fp32", "bf16"])
+@pytest.mark.parametrize("local_batch", [4, 2])
+@pytest.mark.parametrize("seam", ["qkv_blocked", "out_proj"])
+def test_batch_piped_gather_equals_the_whole_gather(seam, local_batch, dtype, always_ring,
+                                                    toy_batch_pieces):
+    """The head-major all-gather side (qkv forward; out_proj backward, through
+    ``_reducescatter_backward``) cut along the batch, one gather and one GEMM
+    a piece: the value, ``dx`` and ``dw`` of the whole gather bit for bit
+    (every output row depends on its own input row only, and ``dw`` is still
+    one GEMM on the whole gathered operand), on operands whose sums are
+    exact, so that a row gone to the wrong place is all that can differ."""
+    _, _, x_shape, w_shape, _ = SEAMS[seam]
+    x = _small_ints(20, (2 * local_batch,) + x_shape[1:], dtype)  # dp 2
+    w = _small_ints(21, w_shape, dtype)
+    toy_batch_pieces(whole=True)
+    want, gathers = _value_and_grads(seam, x, w)
+    assert gathers == 1
+    toy_batch_pieces(whole=False)
+    got, gathers = _value_and_grads(seam, x, w)
+    assert gathers == local_batch
+    assert all(np.abs(a).max() > 0 for a in want)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+    np.testing.assert_array_equal(want[0], np.asarray(jnp.einsum(SEAMS[seam][1], x, w), np.float32))
+
+
+@pytest.mark.parametrize("case,batch,seq,gathers", [
+    ("four_rows", 8, 1024, 4), ("two_rows", 4, 1024, 2), ("one_row", 2, 1024, 1),
+    ("pieces_under_min_rows", 8, 64, 1)])
+@pytest.mark.parametrize("seam", ["qkv_blocked", "out_proj"])
+def test_batch_pieces_at_its_own_row_threshold(seam, case, batch, seq, gathers, always_ring,
+                                               monkeypatch):
+    """With ``RING_MIN_PIECE_ROWS`` as shipped: a device's 4 or 2 rows of 1024
+    tokens go a row a piece; one row, and pieces of fewer than 256 rows,
+    take the whole gather (one ``all_gather`` in the lowered text)."""
+    monkeypatch.setattr(cm, "RING_MIN_COVER", 0.0)  # the toy widths
+    x = _rand(22, (batch, N, seq, HD) if seam == "out_proj" else (batch, seq, H))
+    w = _rand(23, SEAMS[seam][3])
+    jax.clear_caches()
+    try:
+        (y, _, _), found = _value_and_grads(seam, x, w)
+    finally:
+        jax.clear_caches()
+    assert found == gathers
+    np.testing.assert_allclose(y, jnp.einsum(SEAMS[seam][1], x, w), atol=1e-4)
+
+
 def test_seams_below_the_shape_test_stay_plain():
     """Without ``always_ring`` the toy shapes lower to the plain einsum: no
     shard_map, no permute (what every one-chip and narrow layer keeps)."""

@@ -340,6 +340,15 @@ def test_traced_train_exports_the_span(traced_run, name):
         assert 0 in steps
 
 
+def test_build_runtime_span_counts_the_seams_by_name(traced_run):
+    """``tp_overlap_seams`` on the ``build_runtime`` span: the three keys a
+    reader may count on (ring / plain / batchwise; PERF.md §3), all 0 without
+    tensor parallelism."""
+    events, _ = traced_run
+    (span,) = [e for e in events if e["ph"] == "X" and e["name"] == "build_runtime"]
+    assert span["args"]["tp_overlap_seams"] == {"ring": 0, "plain": 0, "batchwise": 0}
+
+
 def test_traced_train_logs_the_profile_window(traced_run):
     _, records = traced_run
     recs = [r for r in records if r["event"] == "profile_window"]

@@ -338,6 +338,17 @@ def test_olmoe_block_partitions_on_four_chips(topo, real_mosaic):
 # --- collective-matmul rings (ops/collective_matmul.py) on the projection seams ----
 
 _OPT_STEP = {}
+#: what the parent of PR 32 (PR 30's tree) emits for ``_opt_four_chip_step``'s search
+#: arguments, every key of the document but the predicted cost and throughput
+PARENT_PLAN = {
+    "pp_deg": 1, "vpp_deg": 1, "tp_sizes_enc": "4,4", "tp_consecutive_flags": "1,1",
+    "dp_types_enc": "0,0", "dp_type_names": "ddp,ddp", "checkpoint": "0,0", "sp_flags": "1,1",
+    "cp_sizes_enc": "1,1", "cp_impls": "ring,ring", "ep_sizes_enc": "1,1",
+    "tp_overlap_flags": "1,1", "pp_division": "2", "chunks": 1, "pipeline_type": "gpipe",
+    "vocab_tp": 4, "vocab_sp": 0, "embed_dp_type": "ddp", "default_dp_type": "ddp",
+    "mixed_precision": "bf16", "mlp_recompute": "policy", "grad_overlap": 0, "global_bsz": 16,
+    "memory_mb": 2664.005632, "num_devices": 4, "memory_constraint_gb": 10.0,
+}
 
 
 def _opt_four_chip_step(topo, tmp_path_factory):
@@ -407,7 +418,11 @@ def test_four_chip_searched_plan_sets_tp_overlap(topo, real_mosaic, tmp_path_fac
     every projection seam of every layer passes the ring's shape test."""
     doc, seams, _ = _opt_four_chip_step(topo, tmp_path_factory)
     assert (doc["tp_sizes_enc"], doc["sp_flags"], doc["tp_overlap_flags"]) == ("4,4", "1,1", "1,1")
-    assert seams == {"ring": 8, "plain": 0}
+    assert seams == {"ring": 8, "plain": 0, "batchwise": 4}
+    # the plan document the parent (PR 30's tree) emits for the same arguments, key for
+    # key: pricing the batch-wise gathers moved nothing in it but the predicted cost
+    assert {k: doc[k] for k in PARENT_PLAN} == PARENT_PLAN, {
+        k: (doc.get(k), v) for k, v in PARENT_PLAN.items() if doc.get(k) != v}
 
 
 def test_four_chip_step_traces_its_layers_once(topo, real_mosaic, tmp_path_factory):
@@ -436,10 +451,15 @@ def test_four_chip_step_runs_its_seams_on_the_ring(topo, real_mosaic, tmp_path_f
 def test_four_chip_step_keeps_no_monolithic_collective_at_a_ring_seam(topo, real_mosaic,
                                                                       tmp_path_factory):
     """Inside the layers every collective belongs to one of the two scopes, and
-    what is left whole there is only the gather of the two seams whose
-    all-gather side puts out head-major dims (qkv forward, out_proj backward);
-    no reduce-scatter, no all-reduce, no fusion of one with its GEMM. The plan
-    checker's GTC012 reads the same from the lowered text."""
+    what is not a permute there is only the gathers of the two seams whose
+    all-gather side puts out head-major dims (qkv forward, out_proj backward):
+    one a row of the device's micro-batch of 4, none merged with another, each
+    of a row and not of the batch; no reduce-scatter, no all-reduce, no
+    fusion of one with its GEMM. The plan checker's GTC012 reads the same
+    from the lowered text."""
+    import collections
+    import re
+
     from galvatron_tpu.analysis import comm_audit as ca
 
     _, _, text = _opt_four_chip_step(topo, tmp_path_factory)
@@ -451,10 +471,24 @@ def test_four_chip_step_keeps_no_monolithic_collective_at_a_ring_seam(topo, real
     whole = {(kind, next(sc for sc in ("qkv_proj", "out_proj", "mlp") if f"/{sc}/" in op),
               "transpose(" in op) for kind, op in in_layers if kind != "collective-permute"}
     assert whole == {("all-gather", "qkv_proj", False), ("all-gather", "out_proj", True)}, whole
+    # a gather the compiler carries inside the next GEMM's fusion is written once in each
+    # computation of its chain (one ``chain_id``); the others once, by their own name
+    gathers = collections.defaultdict(set)
+    for m in re.finditer(r"%(all-gather[\w.-]*) = bf16\[([\d,]+)\][^\n]*? all-gather(?:-start)?\("
+                         r"[^\n]*?op_name=\"([^\"]*)\"", text):
+        name, shape, op = m.groups()
+        if "layer_" not in op:
+            continue
+        chain = re.search(r'chain_id="(\d+)"', m.group(0))
+        assert shape == "1,2048,2048", (shape, op)  # a row of the micro-batch, the sequence whole
+        layer = re.search(r"layer_(\d+)", op).group(1)
+        gathers[layer, "out_proj" if "/out_proj/" in op else "qkv_proj", "transpose(" in op].add(
+            "chain " + chain.group(1) if chain else name)
+    assert {k: len(v) for k, v in gathers.items()} == {
+        (layer, *side): 4 for layer in "01"
+        for side in (("qkv_proj", False), ("out_proj", True))}, dict(gathers)
     # the fusion of a reduce-scatter with its GEMM (the parent's 139 ms) is left
     # to the embedding's way into the sequence-parallel layout
-    import re
-
     fused = re.findall(r"calls=%all-reduce-scatter[^\n]*?op_name=\"([^\"]*)\"", text)
     assert not [op for op in fused if "layer_" in op], fused
     fp = _OPT_STEP["footprint"]
@@ -480,7 +514,7 @@ def test_one_chip_step_has_no_ring(topo, real_mosaic):
     rt = build_runtime(PRESETS["baichuan-7b"].replace(num_layers=2, attn_impl="flash"),
                        HybridParallelConfig.uniform(2, mixed_precision="bf16"), mesh=mesh,
                        axes=axes, global_batch_size=2, seq_len=4096)
-    assert rt.tp_overlap_seams == {"ring": 0, "plain": 0}
+    assert rt.tp_overlap_seams == {"ring": 0, "plain": 0, "batchwise": 0}
 
 
 def test_flash_multichip_compile_smoke(topo, real_mosaic):
