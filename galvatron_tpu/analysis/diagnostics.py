@@ -38,6 +38,8 @@ CODES = {
     "GTA016": ("abstract sharding pass: annotated dim unsharded or spec invalid", WARN),
     "GTA017": ("checkpoint topology/plan fingerprint does not match the live mesh", ERROR),
     "GTA018": ("tp_overlap (collective-matmul) set on a layer with tp == 1", ERROR),
+    "GTA019": ("tp or cp > 1 on a state-space layer of a hybrid stack", ERROR),
+    "GTA020": ("pp > 1 over interleaved layer kinds", ERROR),
     # --- trace-hygiene linter (GTL1xx) ---
     "GTL100": ("malformed suppression: '# gta: disable=<rule>' needs a reason", ERROR),
     "GTL101": ("host-device sync on a jitted result inside a hot loop", WARN),

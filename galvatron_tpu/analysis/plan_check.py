@@ -787,6 +787,45 @@ def _check_model(hp: HybridParallelConfig, cfg, source) -> List[Diagnostic]:
                     source=source,
                 )
             )
+    kinds = getattr(cfg, "kinds", ())
+    if "ssm" in kinds:
+        # a hybrid stack (build_runtime refuses the same, by the same names)
+        for i, (kind, s) in enumerate(zip(kinds, hp.layer_strategies[enc:])):
+            if kind != "ssm":
+                continue
+            if s.tp > 1:
+                out.append(
+                    Diagnostic(
+                        "GTA019",
+                        f"layer {enc + i}: tp={s.tp} on a state-space layer — tensor "
+                        "parallelism is not implemented for the Mamba-2 mixer",
+                        hint=f"set tp_sizes_enc[{enc + i}] to 1",
+                        field=f"tp_sizes_enc[{enc + i}]",
+                        source=source,
+                    )
+                )
+            if s.cp > 1:
+                out.append(
+                    Diagnostic(
+                        "GTA019",
+                        f"layer {enc + i}: cp={s.cp} on a state-space layer — the scan's "
+                        "state is not passed between sequence shards",
+                        hint=f"set cp_sizes_enc[{enc + i}] to 1",
+                        field=f"cp_sizes_enc[{enc + i}]",
+                        source=source,
+                    )
+                )
+        if hp.pp > 1 and len(set(kinds)) > 1:
+            out.append(
+                Diagnostic(
+                    "GTA020",
+                    f"pp={hp.pp} over interleaved layer kinds — the pipeline engines "
+                    "stack one kind of layer a stage position",
+                    hint="use pp_deg 1 for a hybrid stack",
+                    field="pp_deg",
+                    source=source,
+                )
+            )
     if hp.vocab_tp > 1 and cfg.vocab_size % hp.vocab_tp:
         out.append(
             Diagnostic(

@@ -131,9 +131,12 @@ def main(argv: Optional[List[str]] = None, model_default: Optional[str] = None) 
             return 2
         if ns.time_profile_path and ns.memory_profile_path:
             costs = load_profiled_model(ns.time_profile_path, ns.memory_profile_path)
-        elif ns.analytic_costs or ns.check_cost_model:
+        elif ns.analytic_costs or ns.check_cost_model or "ssm" in cfg.kinds:
             from galvatron_tpu.search.theoretical import analytic_model_costs
 
+            if "ssm" in cfg.kinds:
+                print("hybrid stack: the in-process profiler measures one layer kind; "
+                      "each kind is priced analytically")
             print("using analytic (unprofiled) model costs")
             costs = analytic_model_costs(cfg)
         else:

@@ -13,6 +13,7 @@ instead of silently poisoning the optimizer state.
 from __future__ import annotations
 
 import argparse
+import collections
 import math
 import os
 import time
@@ -236,7 +237,10 @@ def _train_impl(ns: argparse.Namespace, verbose: bool, tracer,
             cfg, hp, mesh=mesh, axes=axes, adam=adam,
             global_batch_size=ns.global_train_batch_size, seq_len=seq,
         )
-        build_span.set(tp_overlap_seams=rt.tp_overlap_seams)
+        # how many decoder layers of each kind the run has (a hybrid stack:
+        # {"ssm": 9, "attention": 1} for one granite period)
+        layer_kinds = dict(collections.Counter(rt.cfg.kinds))
+        build_span.set(tp_overlap_seams=rt.tp_overlap_seams, layer_kinds=layer_kinds)
 
     from galvatron_tpu.obs import tracing as obs_tracing
     from galvatron_tpu.utils.metrics import SCHEMA_VERSION, MetricsLogger
@@ -274,6 +278,7 @@ def _train_impl(ns: argparse.Namespace, verbose: bool, tracer,
         # projection seams of the plan's tp_overlap layers that run the
         # collective-matmul ring / the plain einsum (the ring's shape test)
         "tp_overlap_seams": rt.tp_overlap_seams,
+        "layer_kinds": layer_kinds,
     }
     # JAX's persistent compile cache is always on, at the one place
     # resolve_compile_cache_dir names (JAX_COMPILATION_CACHE_DIR, else an

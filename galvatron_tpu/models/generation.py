@@ -34,6 +34,11 @@ class KVCache(NamedTuple):
 
 
 def init_kv_cache(cfg: ModelConfig, batch_size: int, max_len: int) -> KVCache:
+    if "ssm" in cfg.kinds:
+        # every cache of the serving stack (slots, paged pool, generate) starts here
+        raise ValueError(
+            "generation is not implemented for a stack with state-space layers: a "
+            "key/value cache holds no recurrent (conv + scan) state; train-only")
     shape = (cfg.num_layers, batch_size, max_len, cfg.kv_heads, cfg.head_dim)
     return KVCache(jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))
 

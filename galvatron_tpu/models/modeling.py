@@ -48,7 +48,7 @@ class ModelConfig:
     num_kv_heads: Optional[int] = None  # None → MHA; < num_heads → GQA
     ffn_dim: Optional[int] = None  # None → 4h (gelu) or llama 8h/3 rounding
     max_seq_len: int = 2048
-    pos_embed: str = "rope"  # 'rope' | 'learned' | 'alibi'
+    pos_embed: str = "rope"  # 'rope' | 'learned' | 'alibi' | 'nope' (none at all)
     norm_type: str = "rms"  # 'rms' | 'layernorm'
     act_fn: str = "swiglu"  # 'swiglu' | 'gelu' | 'relu' (OPT-style)
     tie_word_embeddings: bool = False
@@ -102,6 +102,27 @@ class ModelConfig:
     # WHOLE projection width (all heads together) before the split into heads
     # and before rope (OLMoE; HF modeling_olmoe.py q_norm / k_norm).
     qk_norm: bool = False
+    # Hybrid stacks (granitemoehybrid-class): the kind of every layer,
+    # "attention" | "ssm" (a Mamba-2 mixer, models/ssm.py, in place of
+    # attention; the MLP is the same), as published for the WHOLE model; a
+    # model cut in depth keeps the first ``num_layers`` entries (``kinds``).
+    # Empty: every layer is attention. tp>1 / cp>1 on an ssm layer and pp>1
+    # over mixed kinds are refused by build_runtime and left out by the search.
+    layer_kinds: Tuple[str, ...] = ()
+    ssm_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_state: int = 128
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 256
+    # Granite's scalar multipliers (HF config keys of the same names): the
+    # softmax scale in place of 1/sqrt(head_dim) (None: that default), the
+    # embedding's factor, the factor on every residual branch, and the divisor
+    # of the logits.
+    attention_multiplier: Optional[float] = None
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     # vision families (reference legacy vit/swin model_type branches,
     # galvatron/core/parallel.py:64-89, cost_model.py:76,87-106).
     # image_size > 0 switches the input pipeline from token ids to uint8
@@ -160,6 +181,16 @@ class ModelConfig:
         """The layers are dropless top-k MoE layers, which hand the router's
         statistics up beside their activations (decoder_layer)."""
         return self.moe_experts > 0 and self.moe_router == "softmax_topk"
+
+    @property
+    def kinds(self) -> Tuple[str, ...]:
+        """The kind of each of the ``num_layers`` decoder layers."""
+        if not self.layer_kinds:
+            return ("attention",) * self.num_layers
+        if len(self.layer_kinds) < self.num_layers:
+            raise ValueError(
+                f"layer_kinds has {len(self.layer_kinds)} entries for {self.num_layers} layers")
+        return self.layer_kinds[: self.num_layers]
 
     @property
     def kv_heads(self) -> int:
@@ -313,8 +344,18 @@ def split_qkv(qkv, cfg: ModelConfig):
     return q, r[..., npg, :], r[..., npg + 1, :]
 
 
-def init_layer_params(key, cfg: ModelConfig, cross: bool = False) -> Params:
+def init_layer_params(key, cfg: ModelConfig, cross: bool = False,
+                      kind: str = "attention") -> Params:
     h, hd = cfg.hidden_size, cfg.head_dim
+    if kind == "ssm":
+        # the mixer in place of attention; norms and MLP are an attention layer's
+        from galvatron_tpu.models import ssm
+
+        k_mix, k_rest = jax.random.split(key)
+        p = init_layer_params(k_rest, cfg, cross=cross)
+        del p["attn"]
+        p["ssm"] = ssm.init_ssm_params(k_mix, cfg)
+        return p
     q_out = cfg.num_heads * hd
     kv_out = cfg.kv_heads * hd
     kv, group = qkv_dims(cfg)
@@ -377,10 +418,18 @@ def init_layer_params(key, cfg: ModelConfig, cross: bool = False) -> Params:
     return p
 
 
-def layer_annotations(cfg: ModelConfig, cross: bool = False) -> Params:
+def layer_annotations(cfg: ModelConfig, cross: bool = False,
+                      kind: str = "attention") -> Params:
     """Logical axes per layer param: 'tp' = Megatron-sharded dim (column-out /
     row-in), 'fsdp' = the dim ZeRO shards (reference: FSDP flat-param sharding,
     galvatron/core/parallel.py:174-207)."""
+    if kind == "ssm":
+        from galvatron_tpu.models import ssm
+
+        a = layer_annotations(cfg, cross=cross)
+        del a["attn"]
+        a["ssm"] = ssm.ssm_annotations(cfg)
+        return a
     a: Params = {
         "attn_norm": {"scale": ("fsdp",)},
         "attn": {
@@ -564,8 +613,8 @@ def init_model_params(key, cfg: ModelConfig) -> Params:
             * 0.02
         },
         "layers": [
-            init_layer_params(ks[cfg.enc_layers + i + 1], cfg, cross=cross)
-            for i in range(cfg.num_layers)
+            init_layer_params(ks[cfg.enc_layers + i + 1], cfg, cross=cross, kind=kind)
+            for i, kind in enumerate(cfg.kinds)
         ],
         "final_norm": {"scale": jnp.ones((cfg.hidden_size,), cfg.param_dtype)},
     }
@@ -599,7 +648,7 @@ def model_annotations(cfg: ModelConfig) -> Params:
     cross = cfg.enc_layers > 0
     a: Params = {
         "embed": {"tok": ("tp", "fsdp")},
-        "layers": [layer_annotations(cfg, cross=cross) for _ in range(cfg.num_layers)],
+        "layers": [layer_annotations(cfg, cross=cross, kind=kind) for kind in cfg.kinds],
         "final_norm": {"scale": ("fsdp",)},
     }
     if cross:
@@ -762,10 +811,14 @@ def attention_xla(q, k, v, cfg: ModelConfig, bias=None, q_offset=0, seg_ids=None
         # reads the cache once (tests/test_flash_attention.py parity case)
         from galvatron_tpu.ops.flash_attention import decode_attention
 
-        return decode_attention(q, k, v, q_offset=q_offset)
+        return decode_attention(q, k, v, q_offset=q_offset, sm_scale=cfg.attention_multiplier)
     k = _repeat_kv(k, nh // k.shape[2])
     v = _repeat_kv(v, nh // v.shape[2])
-    scores = jnp.einsum("bqnh,bknh->bnqk", q, k).astype(jnp.float32) / np.sqrt(hd)
+    scores = jnp.einsum("bqnh,bknh->bnqk", q, k).astype(jnp.float32)
+    if cfg.attention_multiplier is None:
+        scores = scores / np.sqrt(hd)
+    else:
+        scores = scores * cfg.attention_multiplier
     if bias is not None:
         scores = scores + bias
     if cfg.causal:
@@ -797,14 +850,15 @@ def attention(q, k, v, cfg: ModelConfig, bias=None, rope=None, seg_ids=None,
         bsnd = (0, 2)  # (b, s, n, d) layout: batch dim 0, head dim 2
         if rope is None:
             kernel = place.shard_kernel(
-                lambda q_, k_, v_: flash_attention(q_, k_, v_, causal=cfg.causal),
+                lambda q_, k_, v_: flash_attention(
+                    q_, k_, v_, causal=cfg.causal, sm_scale=cfg.attention_multiplier),
                 [bsnd] * 3,
                 bsnd,
             )
             return kernel(q, k, v)
         kernel = place.shard_kernel(
             lambda q_, k_, v_, c_, s_: flash_attention(
-                q_, k_, v_, causal=cfg.causal, rope=(c_, s_)
+                q_, k_, v_, causal=cfg.causal, sm_scale=cfg.attention_multiplier, rope=(c_, s_)
             ),
             [bsnd] * 3 + [(None, None)] * 2,
             bsnd,
@@ -900,11 +954,15 @@ def _attn_block_headmajor(x, p, cfg: ModelConfig, rope, remat_attn: bool, place:
         if flash_qkv_supported(s, hd, cfg.causal):
             # the kernels consume the STACKED projection output directly —
             # index-mapped block specs instead of q/k/v slice copies
-            if rope is None:  # learned / absolute positions: no table operands
-                core_qkv = place.shard_kernel(flash_attention_qkv, [(0, 2)], (0, 1))
+            scale = cfg.attention_multiplier
+            if rope is None:  # learned / absolute / no positions: no table operands
+                core_qkv = place.shard_kernel(
+                    flash_attention_qkv if scale is None
+                    else partial(flash_attention_qkv, sm_scale=scale), [(0, 2)], (0, 1))
             else:
                 kernel = place.shard_kernel(
-                    lambda qkv_, c_, s_: flash_attention_qkv(qkv_, rope=(c_, s_)),
+                    lambda qkv_, c_, s_: flash_attention_qkv(
+                        qkv_, sm_scale=scale, rope=(c_, s_)),
                     [(0, 2), (None, None), (None, None)],
                     (0, 1),
                 )
@@ -940,7 +998,8 @@ def _attn_block_headmajor(x, p, cfg: ModelConfig, rope, remat_attn: bool, place:
     qkv_dim, rep_dim = (0, 1), (None, None)
     if rope is None:
         kernel = place.shard_kernel(
-            lambda q_, k_, v_: flash_attention_hm(q_, k_, v_, causal=cfg.causal),
+            lambda q_, k_, v_: flash_attention_hm(
+                q_, k_, v_, causal=cfg.causal, sm_scale=cfg.attention_multiplier),
             [qkv_dim] * 3,
             qkv_dim,
         )
@@ -950,7 +1009,7 @@ def _attn_block_headmajor(x, p, cfg: ModelConfig, rope, remat_attn: bool, place:
     else:
         kernel = place.shard_kernel(
             lambda q_, k_, v_, c_, s_: flash_attention_hm(
-                q_, k_, v_, causal=cfg.causal, rope=(c_, s_)
+                q_, k_, v_, causal=cfg.causal, sm_scale=cfg.attention_multiplier, rope=(c_, s_)
             ),
             [qkv_dim] * 3 + [rep_dim, rep_dim],
             qkv_dim,
@@ -1092,6 +1151,11 @@ def mlp_block(x, p, cfg: ModelConfig, train: bool = True, place: Placement = LOC
     return y
 
 
+def residual_add(x, y, cfg: ModelConfig):
+    """``x + residual_multiplier * y``: a branch joining the residual stream."""
+    return x + y if cfg.residual_multiplier == 1.0 else x + y * cfg.residual_multiplier
+
+
 def mlp_residual(x, p, cfg: ModelConfig, train: bool = True, place: Placement = LOCAL):
     """x + MLP(norm(x)) — the per-layer MLP residual branch, with the
     activation-memory saveable policy applied when cfg.mlp_recompute ==
@@ -1130,8 +1194,9 @@ def mlp_residual(x, p, cfg: ModelConfig, train: bool = True, place: Placement = 
             lambda x_, pn_, pm_: mlp_block(normed(x_, pn_), pm_, cfg, train=train, place=place),
             policy=jax.checkpoint_policies.save_only_these_names("mlp_gate"),
         )
-        return x + branch(x, p["mlp_norm"], p["mlp"])
-    return x + mlp_block(norm(x, p["mlp_norm"], cfg), p["mlp"], cfg, train=train, place=place)
+        return residual_add(x, branch(x, p["mlp_norm"], p["mlp"]), cfg)
+    return residual_add(
+        x, mlp_block(norm(x, p["mlp_norm"], cfg), p["mlp"], cfg, train=train, place=place), cfg)
 
 
 def cross_attn_block(x, enc_out, p, cfg: ModelConfig):
@@ -1173,11 +1238,19 @@ def decoder_layer(
     ``place`` (models/placement.py; static under ``jit``) is what the layer's
     place on a mesh adds to the computation — pins, kernel ``shard_map``s,
     collective-matmul seams; callers without a mesh (serving, the float32
-    references, the profiler) pass nothing."""
-    x = x + attn_block(
+    references, the profiler) pass nothing.
+
+    A layer whose parameters hold ``ssm`` in place of ``attn`` (kind "ssm" of
+    a hybrid stack, ``cfg.kinds``) runs the Mamba-2 mixer there."""
+    if "ssm" in p:
+        from galvatron_tpu.models import ssm
+
+        x = residual_add(x, ssm.ssm_block(norm(x, p["attn_norm"], cfg), p["ssm"], cfg), cfg)
+        return mlp_residual(x, p, cfg, place=place)
+    x = residual_add(x, attn_block(
         norm(x, p["attn_norm"], cfg), p["attn"], cfg, cos_sin, alibi,
         remat_attn=remat_attn, seg_ids=seg_ids, place=place,
-    )
+    ), cfg)
     if enc_out is not None and "cross" in p:
         x = x + cross_attn_block(norm(x, p["cross_norm"], cfg), enc_out, p["cross"], cfg)
     return mlp_residual(x, p, cfg, place=place)
@@ -1190,6 +1263,8 @@ def embed(tokens, params, cfg: ModelConfig, pos_ids=None):
     document restarts at position 0 (rope gets the same treatment via
     packed_rope_tables)."""
     x = params["embed"]["tok"].astype(cfg.dtype)[tokens]
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
     if cfg.pos_embed == "learned":
         s = tokens.shape[1]
         table = params["embed"]["pos"].astype(cfg.dtype)[:s]
@@ -1208,6 +1283,9 @@ def embed(tokens, params, cfg: ModelConfig, pos_ids=None):
 
 
 def lm_head(x, params, cfg: ModelConfig):
+    if cfg.logits_scaling != 1.0:
+        # logits / s as (x / s) W: one pass over (B, S, H), not over (B, S, V)
+        x = x / cfg.logits_scaling
     if cfg.tie_word_embeddings:
         # the tied table also feeds the embed gather — it stays fp
         w = params["embed"]["tok"].astype(x.dtype).T
@@ -1444,7 +1522,16 @@ def _cross_entropy_sum_impl(logits, labels, ignore_index: int = -100):
     mask = labels != ignore_index
     safe = jnp.where(mask, labels, 0)
     lse = jax.nn.logsumexp(logits, axis=-1)
-    picked = jnp.take_along_axis(logits, safe[..., None], axis=-1)[..., 0]
+    if logits.shape[0] == 1 and logits.ndim == 3:
+        # one sequence a (micro-)batch: the compiler turns the gather's backward
+        # into a scatter over the FLATTENED logits and pays two relayouts of the
+        # whole (S, V) gradient for it (3.8 ms a step at 8192 x 25088, under no
+        # name; PERF.md §6, PR 33); a select and a row sum pick the same entry
+        # and differentiate to a select
+        hit = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 2) == safe[..., None]
+        picked = jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)
+    else:
+        picked = jnp.take_along_axis(logits, safe[..., None], axis=-1)[..., 0]
     nll = (lse - picked) * mask
     return nll.sum(), mask.sum()
 
@@ -1772,6 +1859,20 @@ PRESETS: Dict[str, ModelConfig] = {
         vocab_size=50304, hidden_size=2048, num_layers=16, num_heads=16,
         ffn_dim=1024, max_seq_len=4096, moe_experts=64, moe_router="softmax_topk",
         moe_top_k=8, moe_aux_coef=0.01, qk_norm=True,
+    ),
+    # ibm-granite/granite-4.0-h-micro (model_type granitemoehybrid): 40 layers,
+    # attention at 5, 15, 25, 35 and Mamba-2 mixers elsewhere, every layer with
+    # the shared gated MLP (num_local_experts 0: no routed part); GQA 32 / 8
+    # heads of 64 without any positional embedding, softmax scale 1/64; the
+    # embedding x 12, every residual branch x 0.22, logits / 8; tied head
+    "granite-4.0-h-micro": ModelConfig(
+        vocab_size=100352, hidden_size=2048, num_layers=40, num_heads=32, num_kv_heads=8,
+        ffn_dim=8192, max_seq_len=131072, pos_embed="nope", tie_word_embeddings=True,
+        layer_kinds=tuple(
+            "attention" if i % 10 == 5 else "ssm" for i in range(40)),
+        ssm_heads=64, ssm_head_dim=64, ssm_state=128, ssm_groups=1, ssm_conv=4,
+        ssm_chunk=256, attention_multiplier=0.015625, embedding_multiplier=12.0,
+        residual_multiplier=0.22, logits_scaling=8.0,
     ),
     "baichuan-13b": ModelConfig(
         vocab_size=64000, hidden_size=5120, num_layers=40, num_heads=40,

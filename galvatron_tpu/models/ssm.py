@@ -1,0 +1,108 @@
+"""The Mamba-2 mixer of a hybrid stack (granitemoehybrid-class models): what a
+layer of kind ``"ssm"`` runs in place of attention.
+
+    [z | xBC | dt] = W_in h                      widths d_inner | d_inner + 2 G N | H
+    xBC = silu(conv1d_causal_depthwise(xBC) + b)
+    [x | B | C] = xBC                             x as H heads of P
+    dt = softplus(dt + dt_bias),  A = -exp(A_log)
+    y = SSD(x, dt, A, B, C) + D x                 ops/ssd.py, chunked
+    y = RMSNorm(y * silu(z)) * scale              gate before the norm, over all d_inner
+    out = W_out y
+
+(HF ``modeling_granitemoehybrid.py`` GraniteMoeHybridMambaLayer; no projection
+biases, a conv bias.) Imported only where a configuration has such layers
+(``modeling.init_layer_params`` / ``decoder_layer`` on ``kind == "ssm"``), so
+every other model's imports stay what they were.
+
+Scopes under ``ssm``: ``in_proj``, ``conv``, ``scan``, ``gate_norm``,
+``out_proj`` (PERF.md §3; the ``ssm_*`` benchmark metrics read them).
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from galvatron_tpu.ops.ssd import causal_conv1d, ssd_scan
+
+Params = Dict[str, Any]
+F32 = jnp.float32
+
+
+def ssm_dims(cfg):
+    """(d_inner, conv channels, in_proj width) of the mixer."""
+    d_inner = cfg.ssm_heads * cfg.ssm_head_dim
+    conv_dim = d_inner + 2 * cfg.ssm_groups * cfg.ssm_state
+    return d_inner, conv_dim, d_inner + conv_dim + cfg.ssm_heads
+
+
+def ssm_param_count(cfg) -> int:
+    d_inner, conv_dim, in_width = ssm_dims(cfg)
+    return (cfg.hidden_size * in_width + conv_dim * (cfg.ssm_conv + 1)
+            + 3 * cfg.ssm_heads + d_inner + d_inner * cfg.hidden_size)
+
+
+def init_ssm_params(key, cfg) -> Params:
+    """The published code's initialisation: ``A_log = log(1..H)``, ``D = 1``,
+    ``dt_bias = 1``, gated-norm scale 1; the projections and the conv taps
+    uniform in +-1/sqrt(fan_in) like every other projection of the program."""
+    from galvatron_tpu.models.modeling import _dense_init
+
+    h = cfg.hidden_size
+    d_inner, conv_dim, in_width = ssm_dims(cfg)
+    ks = jax.random.split(key, 3)
+    bound = 1.0 / np.sqrt(cfg.ssm_conv)
+    return {
+        "in_proj": _dense_init(ks[0], h, in_width, cfg.param_dtype),
+        "conv_w": jax.random.uniform(
+            ks[1], (cfg.ssm_conv, conv_dim), cfg.param_dtype, -bound, bound),
+        "conv_b": jnp.zeros((conv_dim,), cfg.param_dtype),
+        "A_log": jnp.log(jnp.arange(1, cfg.ssm_heads + 1, dtype=cfg.param_dtype)),
+        "D": jnp.ones((cfg.ssm_heads,), cfg.param_dtype),
+        "dt_bias": jnp.ones((cfg.ssm_heads,), cfg.param_dtype),
+        "norm": jnp.ones((d_inner,), cfg.param_dtype),
+        "out_proj": _dense_init(ks[2], d_inner, h, cfg.param_dtype),
+    }
+
+
+def ssm_annotations(cfg) -> Params:
+    """No ``tp`` axis anywhere: tensor parallelism on a state-space layer is
+    refused (build_runtime); ZeRO shards the hidden-size dims."""
+    return {
+        "in_proj": ("fsdp", None), "conv_w": (None, None), "conv_b": (None,),
+        "A_log": (None,), "D": (None,), "dt_bias": (None,), "norm": (None,),
+        "out_proj": (None, "fsdp"),
+    }
+
+
+@jax.named_scope("ssm")
+def ssm_block(x, p: Params, cfg):
+    """(B, S, hidden) normed layer input -> the mixer's output, same shape."""
+    dtype = x.dtype
+    heads, hd, groups, state = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state
+    d_inner, conv_dim, _ = ssm_dims(cfg)
+    with jax.named_scope("in_proj"):
+        zxbcdt = x @ p["in_proj"].astype(dtype)
+        z = zxbcdt[..., :d_inner]
+        xbc = zxbcdt[..., d_inner:d_inner + conv_dim]
+        dt = zxbcdt[..., d_inner + conv_dim:]
+    with jax.named_scope("conv"):
+        xbc = jax.nn.silu(causal_conv1d(xbc, p["conv_w"], p["conv_b"]))
+    with jax.named_scope("scan"):
+        lead = xbc.shape[:2]
+        xs = xbc[..., :d_inner].reshape(*lead, heads, hd)
+        b_mat = xbc[..., d_inner:d_inner + groups * state].reshape(*lead, groups, state)
+        c_mat = xbc[..., d_inner + groups * state:].reshape(*lead, groups, state)
+        dt = jax.nn.softplus(dt.astype(F32) + p["dt_bias"].astype(F32))
+        y = ssd_scan(xs, dt, -jnp.exp(p["A_log"].astype(F32)), b_mat, c_mat, cfg.ssm_chunk)
+        y = (y.astype(F32) + p["D"].astype(F32)[:, None] * xs.astype(F32)).astype(dtype)
+        y = y.reshape(*lead, d_inner)
+    with jax.named_scope("gate_norm"):
+        g = y.astype(F32) * jax.nn.silu(z.astype(F32))
+        g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + cfg.norm_eps)
+        y = (g * p["norm"].astype(F32)).astype(dtype)
+    with jax.named_scope("out_proj"):
+        return y @ p["out_proj"].astype(dtype)

@@ -21,6 +21,7 @@ accumulation) and the shard_map pipeline schedules (pp>1).
 
 from __future__ import annotations
 
+import collections
 import functools
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional
@@ -245,10 +246,11 @@ def _make_layer_hook(cfg: ModelConfig, hp: HybridParallelConfig, mesh: Mesh, axe
             )
 
         if (
-            place.tp_overlap and not cfg.swin_depths and not is_encoder
+            (place.tp_overlap or cfg.layer_kinds) and not cfg.swin_depths and not is_encoder
             and enc_out is None and seg_ids is None
         ):
-            # layers of one plan entry are one program: see _decoder_layer_once
+            # layers of one plan entry (and, in a hybrid stack, of one kind)
+            # are one program: see _decoder_layer_once
             return _decoder_layer_once(
                 x, lp, cos_sin, alibi, cfg=layer_cfg, place=place, ckpt=s.ckpt)
         if s.ckpt == "full":
@@ -260,11 +262,14 @@ def _make_layer_hook(cfg: ModelConfig, hp: HybridParallelConfig, mesh: Mesh, axe
 
 @functools.partial(jax.jit, static_argnames=("cfg", "place", "ckpt"))
 def _decoder_layer_once(x, lp, cos_sin, alibi, *, cfg: ModelConfig, place, ckpt):
-    """A tp_overlap decoder layer through ``jax.jit``: layers with the same
-    configuration and placement (all 24 of the four-chip cell) are traced,
-    differentiated and lowered once, not once each — the rings' unrolled steps
-    are the longest Python a layer has, and set-up time is gated. The caller's
-    ``layer_<i>`` scope stays around the call."""
+    """A tp_overlap decoder layer, or a layer of a hybrid stack, through
+    ``jax.jit``: layers with the same configuration, placement and parameter
+    tree (all 24 of the four-chip cell; the nine state-space layers and the
+    one attention layer of a granite period: two programs for ten layers) are
+    traced, differentiated and lowered once, not once each — the rings'
+    unrolled steps and the chunked scan are the longest Python a layer has,
+    and set-up time is gated. The caller's ``layer_<i>`` scope stays around
+    the call."""
 
     def run(x_, lp_):
         return modeling.decoder_layer(
@@ -334,6 +339,40 @@ def build_runtime(
                 "pack_sequences is not threaded through the interleaved "
                 "(vpp>1) schedule; use vpp=1 pipelines"
             )
+    if "ssm" in cfg.kinds:
+        # a hybrid stack: what the state-space layers and the interleaving do
+        # not implement is refused here, by name — nothing is silently
+        # mis-sharded
+        ssm_tp = [i for i, (kind, s) in enumerate(
+            zip(cfg.kinds, hp.layer_strategies[cfg.enc_layers:])) if kind == "ssm" and s.tp > 1]
+        if ssm_tp:
+            raise ValueError(
+                f"tensor parallelism (tp>1) is not implemented for state-space layers "
+                f"(layers {ssm_tp} of this plan): the Mamba-2 mixer's heads, conv channels "
+                "and scan carry no tp sharding; use tp=1 on those layers"
+            )
+        if any(s.cp > 1 for s in hp.layer_strategies):
+            raise ValueError(
+                "context parallelism (cp>1) is not implemented for a stack with "
+                "state-space layers: the scan's state is not passed between sequence "
+                "shards; use cp=1"
+            )
+        if hp.pp > 1 and len(set(cfg.kinds)) > 1:
+            raise ValueError(
+                "pipeline parallelism (pp>1) over interleaved layer kinds is not "
+                "implemented: the pipeline engines stack one kind of layer a stage "
+                f"position (this model: {dict(collections.Counter(cfg.kinds))}); use pp=1"
+            )
+        if cfg.pack_sequences:
+            raise ValueError(
+                "pack_sequences is not implemented for state-space layers: the conv and "
+                "the scan do not reset their state at segment boundaries"
+            )
+    if cfg.attention_multiplier is not None and any(s.cp > 1 for s in hp.layer_strategies):
+        raise ValueError(
+            "context parallelism (cp>1) is not implemented with attention_multiplier: "
+            "the ring/Ulysses layers scale by 1/sqrt(head_dim); use cp=1"
+        )
     if cfg.moe_dropless:
         # the sorted-rows path keeps every expert on every device and hands its
         # auxiliary loss up through the GSPMD step; what it does not implement

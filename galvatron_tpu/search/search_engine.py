@@ -259,6 +259,21 @@ class SearchEngine:
                 "parallelism (cp>1) and pipeline stages (pp>1) are not implemented for its "
                 "sorted-row path and are left out of the enumeration"
             )
+        if model_config is not None and "ssm" in getattr(model_config, "kinds", ()):
+            # a hybrid stack: tensor parallelism on a state-space layer, context
+            # parallelism through the scan and pipeline stages over interleaved
+            # layer kinds are refused by build_runtime, so the enumeration
+            # leaves them out (tp for the whole stack: the one candidate list
+            # serves every layer)
+            self.space = space = dataclasses.replace(
+                space, max_tp=1, allow_cp=False, pp_choices=[1])
+            self._standing += ["state_space_layers_no_tp", "state_space_layers_no_cp",
+                               "interleaved_layer_kinds_no_pp"]
+            print(
+                "search: hybrid stack with state-space layers — tensor parallelism (tp>1), "
+                "context parallelism (cp>1) and pipeline stages (pp>1) over the interleaved "
+                "layer kinds are not implemented and are left out of the enumeration"
+            )
         # structural bail-outs that fired during the last sweep (multi-type
         # schedule/shape classes the engines cannot realize) — written into
         # the emitted config as `search_restrictions` the way

@@ -32,12 +32,12 @@ def moe_expert_params(cfg: ModelConfig) -> int:
     return cfg.moe_experts * mats * cfg.hidden_size * cfg.ffn
 
 
-def layer_active_param_count(cfg: ModelConfig) -> int:
+def layer_active_param_count(cfg: ModelConfig, kind: str = "attention") -> int:
     """Weights ONE token is multiplied by in a layer — what its time scales
     with. A dropless top-k MoE layer holds E experts (layer_param_count, its
     memory) and runs ``moe_top_k`` of them a token, plus the router; every
     other layer runs all it holds."""
-    p = layer_param_count(cfg)
+    p = layer_param_count(cfg, kind=kind)
     if cfg.moe_dropless:
         p -= (cfg.moe_experts - cfg.moe_top_k) * 3 * cfg.hidden_size * cfg.ffn
     return p
@@ -55,13 +55,18 @@ def moe_untp_time_fraction(cfg: ModelConfig, seq_len: int) -> float:
     return routed / total
 
 
-def layer_param_count(cfg: ModelConfig, cross: bool = False) -> int:
+def layer_param_count(cfg: ModelConfig, cross: bool = False, kind: str = "attention") -> int:
     """Exact per-layer parameter count (matches init_layer_params).
     ``cross``: enc-dec decoder layers carry a cross-attention block
-    (wq + wkv + wo + cross_norm)."""
+    (wq + wkv + wo + cross_norm). ``kind`` "ssm" (a hybrid stack's
+    state-space layer): the Mamba-2 mixer in place of attention."""
     h, hd = cfg.hidden_size, cfg.head_dim
     q_out, kv_out = cfg.num_heads * hd, cfg.kv_heads * hd
     attn = h * q_out + 2 * h * kv_out + q_out * h
+    if kind == "ssm":
+        from galvatron_tpu.models.ssm import ssm_param_count
+
+        attn = ssm_param_count(cfg)
     if cross:
         attn += h * q_out + 2 * h * kv_out + q_out * h
         attn += h if cfg.norm_type == "rms" else 2 * h  # cross_norm
@@ -114,7 +119,7 @@ def total_param_count(cfg: ModelConfig) -> int:
             layer_param_count(vision_layer_cfg(cfg, i)) for i in range(cfg.num_layers)
         )
         return layers + other_param_count(cfg)
-    return cfg.num_layers * layer_param_count(cfg) + other_param_count(cfg)
+    return sum(layer_param_count(cfg, kind=k) for k in cfg.kinds) + other_param_count(cfg)
 
 
 def layer_states_mb(
@@ -135,7 +140,7 @@ def layer_states_mb(
 
 def layer_activation_mb_per_sample(
     cfg: ModelConfig, s: LayerStrategy, seq_len: int = 0,
-    mixed_precision: str = "bf16",
+    mixed_precision: str = "bf16", kind: str = "attention",
 ) -> float:
     """Analytic activation MB per layer per sample, no remat.
 
@@ -173,6 +178,17 @@ def layer_activation_mb_per_sample(
         mlp = (2 if recompute else 3) * cfg.ffn * b / tp
     else:
         mlp = (1 if recompute else 2) * cfg.ffn * b / tp
+    if kind == "ssm":
+        # the mixer in place of qkv + context: the in_proj output, the conv's
+        # input and output, the scan's output and the gated product, and the
+        # decay-masked score blocks inside a chunk, which are kept: heads x
+        # chunk entries a token, float32 decays and compute-dtype scores
+        from galvatron_tpu.models.ssm import ssm_dims
+
+        d_inner, conv_dim, in_width = ssm_dims(cfg)
+        mixer = (in_width + 2 * conv_dim + 2 * d_inner) * b
+        mixer += cfg.ssm_heads * cfg.ssm_chunk * (4 + b)
+        return (repl + mixer + mlp) * S / 1e6
     per_token = repl + qkv + ctx + mlp
     total = per_token * S
     if cfg.attn_impl == "xla":
@@ -196,6 +212,8 @@ def analytic_model_costs(
         return _analytic_vision_costs(cfg, peak_tflops, mfu, mixed_precision)
     if cfg.enc_layers > 0:
         return _analytic_encdec_costs(cfg, peak_tflops, mfu, mixed_precision)
+    if "ssm" in cfg.kinds:
+        return _analytic_hybrid_costs(cfg, seq_len, peak_tflops, mfu, mixed_precision)
     S = seq_len or cfg.max_seq_len
     b = _BYTES[mixed_precision]
     p_layer = layer_param_count(cfg)
@@ -241,6 +259,49 @@ def analytic_model_costs(
         other_param_mb=other_p * 4 / 1e6,
         other_act_mb_per_sample=other_act,
         other_fwd_ms_per_sample=other_flops / (peak_tflops * 1e12 * mfu) * 1e3,
+    )
+
+
+def _analytic_hybrid_costs(
+    cfg: ModelConfig, seq_len: int, peak_tflops: float, mfu: float, mixed_precision: str
+):
+    """A hybrid stack: one layer type a KIND, keyed by layer index, so that the
+    multi-layer-type search prices the published interleaving (pp = 1; the
+    search leaves pp > 1 and tp > 1 out for such a model). A state-space
+    layer's FLOPs grow linearly with the sequence (its weights and the chunked
+    scan: inside a chunk the causal half of C B^T and of scores x, a chunk's
+    state, the entering state's read-out); the attention layer's quadratically."""
+    from galvatron_tpu.search.cost_model import ProfiledLayerType, ProfiledModelCosts
+
+    S = seq_len or cfg.max_seq_len
+    b = _BYTES[mixed_precision]
+    rate = peak_tflops * 1e12 * mfu
+
+    def make_lt(kind: str) -> ProfiledLayerType:
+        flops = 2.0 * layer_active_param_count(cfg, kind) * S
+        if kind == "ssm":
+            hp_, n_ = cfg.ssm_heads * cfg.ssm_head_dim, cfg.ssm_state
+            pairs = (cfg.ssm_chunk + 1) / 2
+            flops += (2.0 * pairs * (cfg.ssm_groups * n_ + hp_) + 4.0 * hp_ * n_) * S
+        else:
+            flops += 4.0 * cfg.num_heads * cfg.head_dim * S * S
+        return ProfiledLayerType(
+            fwd_ms_per_sample=flops / rate * 1e3,
+            parameter_mb=layer_param_count(cfg, kind=kind) * 4 / 1e6,
+            activation_mb_per_sample={
+                tp: layer_activation_mb_per_sample(
+                    cfg, LayerStrategy(tp=tp), S, mixed_precision, kind=kind)
+                for tp in (1, 2, 4, 8) if cfg.hidden_size % tp == 0
+            },
+            boundary_activation_mb_per_sample=S * cfg.hidden_size * b / 1e6,
+        )
+
+    by_kind = {kind: make_lt(kind) for kind in set(cfg.kinds)}
+    return ProfiledModelCosts(
+        layer_types={i: by_kind[kind] for i, kind in enumerate(cfg.kinds)},
+        other_param_mb=other_param_count(cfg) * 4 / 1e6,
+        other_act_mb_per_sample=S * cfg.vocab_size * b / 1e6,
+        other_fwd_ms_per_sample=2.0 * cfg.hidden_size * cfg.vocab_size * S / rate * 1e3,
     )
 
 

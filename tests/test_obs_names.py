@@ -144,6 +144,63 @@ def test_lowered_olmoe_step_carries_the_scope(olmoe_step_text, scope):
     assert all("layer_0" in n for n in mine)
 
 
+#: what a state-space layer of a hybrid stack opens in place of ``attn``
+SSM_SCOPES = ("in_proj", "conv", "scan", "gate_norm", "out_proj")
+
+
+@pytest.fixture(scope="module")
+def granite_step_text():
+    """Lowered text (with locations) of a six-layer granite step under full-layer
+    recomputation, tiny widths: five state-space layers and the attention layer."""
+    from galvatron_tpu.core.checkpoint import abstract_state_of
+    from galvatron_tpu.core.optim import AdamConfig
+    from galvatron_tpu.core.strategy import HybridParallelConfig
+    from galvatron_tpu.models.modeling import PRESETS
+    from galvatron_tpu.parallel.hybrid import build_runtime
+    from galvatron_tpu.parallel.mesh import build_mesh
+
+    cfg = PRESETS["granite-4.0-h-micro"].replace(
+        vocab_size=128, hidden_size=64, num_layers=6, num_heads=4, num_kv_heads=2, ffn_dim=96,
+        max_seq_len=64, ssm_heads=4, ssm_head_dim=16, ssm_state=16, ssm_chunk=32)
+    hp = HybridParallelConfig.uniform(6, ckpt="full", mixed_precision="fp32")
+    mesh, axes = build_mesh(pp=1, devices=jax.devices()[:1])
+    rt = build_runtime(cfg, hp, mesh=mesh, axes=axes, adam=AdamConfig(lr=1e-3),
+                       global_batch_size=2, seq_len=64)
+    batch = jax.ShapeDtypeStruct((2, 65), jnp.int32, sharding=rt.batch_sharding)
+    lowered = rt.train_step.lower(abstract_state_of(rt), batch)
+    return lowered.as_text(), lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("scope", SSM_SCOPES)
+def test_compiled_granite_step_carries_the_scope(granite_step_text, scope):
+    """Each of the mixer's scopes is in the compiled step below ``ssm``, forward and
+    backward, in a state-space layer and in no attention layer (the layers are one
+    function a kind, so the ``layer_<i>`` prefix exists only once its calls are
+    inlined: the compiled text, as the profiler's ``op_name``s); ``attn``, ``mlp`` and
+    ``norm`` stay what ``benchmark/lib/scoped.py`` knows."""
+    import re
+
+    names = {n for n in re.findall(r'op_name="([^"]*)"', granite_step_text[1])
+             if n.startswith("jit(train_step)")}
+    mine = [n for n in names if re.search(rf"/ssm/(?:[^/\"]+/)*{scope}/", n)]
+    assert mine, scope
+    assert any("transpose(" in n for n in mine), f"{scope}: no backward operation carries it"
+    assert all(re.search(r"layer_[0-4]\b", n) for n in mine)
+    assert not any("layer_5" in n for n in names if "/ssm/" in n)
+    attn = [n for n in names if "/attn/" in n]
+    assert attn and all("layer_5" in n for n in attn)
+    assert any("/mlp/" in n and "layer_0" in n for n in names)
+
+
+def test_hybrid_stack_traces_each_kind_once(granite_step_text):
+    """Two layer programs for six layers: the lowered module holds one function a kind
+    (and its backward), called from each ``layer_<i>``."""
+    import re
+
+    funcs = set(re.findall(r"func\.func private @(_decoder_layer_once\w*)\(", granite_step_text[0]))
+    assert 2 <= len(funcs) <= 4, funcs  # forward and backward of each kind, never 6 x
+
+
 def test_backward_is_marked_by_transpose(toy_step_text):
     import re
 

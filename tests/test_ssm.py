@@ -1,0 +1,468 @@
+"""Hybrid stacks (granitemoehybrid-class: Mamba-2 layers beside NoPE GQA
+attention, the scalar multipliers) on the normal path, against the plain
+reference ``benchmark/references/granitemoehybrid.py`` on seeded random weights,
+at a small size on the CPU; the chunked SSD scan against the recurrence it
+computes; and tests that fail on the likely mistakes (a decay in bf16, a conv
+that sees the future, a scan that forgets its state at a chunk boundary, a
+multiplier left out)."""
+
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.lib import reference
+from galvatron_tpu.analysis.plan_check import check_plan
+from galvatron_tpu.core.strategy import HybridParallelConfig, LayerStrategy
+from galvatron_tpu.models import modeling, ssm
+from galvatron_tpu.models.modeling import PRESETS
+from galvatron_tpu.ops import ssd
+from galvatron_tpu.parallel.hybrid import build_runtime
+from galvatron_tpu.parallel.mesh import build_mesh
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ARCH = reference.load(ROOT, "granitemoehybrid")
+
+# float32, the same arithmetic in another order: the program computes the scan
+# in chunks (a masked (L, L) product, one carried state a chunk), the reference
+# one position at a time; the program's softmax is over whole rows, the
+# reference's by blocks of queries. Differences are a few float32 ulps of the
+# largest element a sum went through. 2e-5 of a tensor's largest magnitude
+# leaves room for seven layers and would not pass any bf16 intermediate, decay
+# or state (2^-8 = 4e-3; test_a_bf16_decay_or_state_fails_the_tolerance)
+F32_TOL = 2e-5
+# gradients go through every layer twice and sum over tokens: ten times that
+GRAD_TOL = 2e-4
+# bf16 compute against the float32 reference: 8 bits an activation, seven
+# layers and the head stack a few roundings
+BF16_TOL = 3e-2
+
+KINDS = ("ssm",) * 5 + ("attention", "ssm")
+
+
+def small_cfg(**kw):
+    """Seven layers of the published pattern (so an attention layer sits between
+    state-space layers), 4 query / 2 key-value heads, 8 state-space heads of 16,
+    state 16, chunks of 16 over 72 positions: 4.5 chunks, so the carry crosses
+    four boundaries and the sequence is padded."""
+    base = dict(vocab_size=96, hidden_size=64, num_layers=7, num_heads=4, num_kv_heads=2,
+                ffn_dim=96, max_seq_len=72, ssm_heads=8, ssm_head_dim=16, ssm_state=16,
+                ssm_chunk=16, dtype=jnp.float32)
+    base.update(kw)
+    return PRESETS["granite-4.0-h-micro"].replace(**base)
+
+
+def ref_cfg(cfg):
+    return {"hidden_size": cfg.hidden_size, "num_attention_heads": cfg.num_heads,
+            "num_key_value_heads": cfg.kv_heads, "attention_multiplier": cfg.attention_multiplier,
+            "embedding_multiplier": cfg.embedding_multiplier,
+            "residual_multiplier": cfg.residual_multiplier, "logits_scaling": cfg.logits_scaling,
+            "rms_norm_eps": cfg.norm_eps, "shared_intermediate_size": cfg.ffn,
+            "mamba_n_heads": cfg.ssm_heads, "mamba_d_head": cfg.ssm_head_dim,
+            "mamba_d_state": cfg.ssm_state, "mamba_n_groups": cfg.ssm_groups,
+            "mamba_d_conv": cfg.ssm_conv, "mamba_chunk_size": cfg.ssm_chunk,
+            "num_hidden_layers": cfg.num_layers, "vocab_size": cfg.vocab_size,
+            "layer_types": ["mamba" if k == "ssm" else "attention" for k in cfg.kinds]}
+
+
+def seeded(cfg, seed=0, batch=2):
+    """Parameters with every vector (norm scales, conv bias, A_log, D, dt_bias)
+    away from its initial value, so that one the program ignores shows."""
+    params = modeling.init_model_params(jax.random.key(seed), cfg)
+    leaves, tree = jax.tree.flatten(params)
+    keys = jax.random.split(jax.random.key(seed + 1), len(leaves))
+    leaves = [a + 0.3 * jax.random.normal(k, a.shape, a.dtype) if a.ndim == 1 else a
+              for a, k in zip(leaves, keys)]
+    rows = jax.random.randint(jax.random.key(seed + 2), (batch, cfg.max_seq_len + 1), 0,
+                              cfg.vocab_size, jnp.int32)
+    return jax.tree.unflatten(tree, leaves), rows
+
+
+def reference_loss(params, rows, cfg):
+    rc = ref_cfg(cfg)
+    logp = jax.nn.log_softmax(
+        ARCH.logits(ARCH.published_weights(params, rc), rows[:, :-1], rc), axis=-1)
+    return -jnp.mean(jnp.take_along_axis(logp, rows[:, 1:, None], axis=-1))
+
+
+def close(got, want, tol):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    scale = max(np.abs(want).max(), 1e-30)
+    err = np.abs(got - want).max() / scale
+    assert err <= tol, f"largest difference {err:.3e} of the largest magnitude, bound {tol:.0e}"
+
+
+@pytest.fixture(autouse=True)
+def highest_precision():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+# -- the model against the reference -----------------------------------------
+
+
+def test_reference_imports_nothing_of_the_program():
+    src = open(os.path.join(ROOT, "benchmark", "references", "granitemoehybrid.py")).read()
+    assert "galvatron_tpu" not in src and "import ssd" not in src
+
+
+def test_preset_is_the_published_configuration():
+    cfg = PRESETS["granite-4.0-h-micro"]
+    assert (cfg.hidden_size, cfg.num_heads, cfg.kv_heads, cfg.head_dim, cfg.ffn, cfg.vocab_size,
+            cfg.num_layers, cfg.max_seq_len) == (2048, 32, 8, 64, 8192, 100352, 40, 131072)
+    assert (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_groups, cfg.ssm_conv,
+            cfg.ssm_chunk) == (64, 64, 128, 1, 4, 256)
+    assert (cfg.attention_multiplier, cfg.embedding_multiplier, cfg.residual_multiplier,
+            cfg.logits_scaling, cfg.norm_eps) == (0.015625, 12.0, 0.22, 8.0, 1e-5)
+    assert (cfg.pos_embed, cfg.tie_word_embeddings, cfg.act_fn, cfg.norm_type, cfg.use_bias) == (
+        "nope", True, "swiglu", "rms", False)
+    assert [i for i, k in enumerate(cfg.kinds) if k == "attention"] == [5, 15, 25, 35]
+    assert ssm.ssm_dims(cfg) == (4096, 4352, 8512)
+    from galvatron_tpu.models import granite  # the family's module entry
+
+    assert granite.DEFAULT_MODEL == "granite-4.0-h-micro" and set(granite.SIZES) <= set(PRESETS)
+
+
+def test_num_layers_truncates_the_pattern_and_the_parameters_count_as_reckoned():
+    from galvatron_tpu.core.arguments import initialize_galvatron, model_config_from_args
+    from galvatron_tpu.search.theoretical import layer_param_count, total_param_count
+
+    ns = initialize_galvatron("train", ["--model_size", "granite-4.0-h-micro", "--num_layers",
+                                        "10", "--vocab_size", "25088"])
+    cfg = model_config_from_args(ns)
+    assert cfg.kinds == ("ssm",) * 5 + ("attention",) + ("ssm",) * 4
+    shapes = jax.eval_shape(lambda k: modeling.init_model_params(k, cfg), jax.random.key(0))
+    count = lambda t: sum(int(np.prod(a.shape)) for a in jax.tree.leaves(t))  # noqa: E731
+    assert (count(shapes["layers"][0]), count(shapes["layers"][5])) == (76182976, 60821504)
+    assert (count(shapes["layers"][0]["ssm"]), count(shapes["layers"][5]["attn"])) == (
+        25847232, 10485760)
+    assert count(shapes) == 797850560 == total_param_count(cfg)
+    assert layer_param_count(cfg, kind="ssm") == 76182976
+    assert "pos" not in shapes["embed"] and "head" not in shapes  # no table, tied head
+    with pytest.raises(ValueError, match="layer_kinds has 40 entries for 41 layers"):
+        cfg.replace(num_layers=41).kinds
+
+
+def test_logits_and_loss_match_the_reference_in_float32():
+    cfg = small_cfg()
+    assert cfg.kinds == KINDS
+    params, rows = seeded(cfg)
+    logits = modeling.forward(params, rows[:, :-1], cfg)
+    rc = ref_cfg(cfg)
+    close(logits, ARCH.logits(ARCH.published_weights(params, rc), rows[:, :-1], rc), F32_TOL)
+    assert float(modeling.lm_loss(params, rows, cfg)) == pytest.approx(
+        float(reference_loss(params, rows, cfg)), rel=F32_TOL)
+
+
+def test_every_gradient_matches_the_reference_in_float32():
+    cfg = small_cfg()
+    params, rows = seeded(cfg, seed=5)
+    got = jax.grad(lambda p: modeling.lm_loss(p, rows, cfg))(params)
+    want = jax.grad(lambda p: reference_loss(p, rows, cfg))(params)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)):
+        try:
+            close(g, w, GRAD_TOL)
+        except AssertionError as e:
+            raise AssertionError(f"{jax.tree_util.keystr(path)}: {e}") from None
+    # every parameter of both kinds of layer is reached
+    assert all(float(jnp.abs(g).max()) > 0 for g in jax.tree.leaves(got))
+
+
+def test_bf16_compute_stays_within_what_bf16_warrants():
+    cfg = small_cfg(dtype=jnp.bfloat16)
+    params, rows = seeded(cfg)
+    logits = modeling.forward(params, rows[:, :-1], cfg).astype(jnp.float32)
+    rc = ref_cfg(cfg)
+    want = ARCH.logits(ARCH.published_weights(params, rc), rows[:, :-1], rc)
+    close(logits, want, BF16_TOL)
+    err = np.abs(np.asarray(logits) - np.asarray(want)).max() / np.abs(np.asarray(want)).max()
+    assert err > F32_TOL, "a bf16 run inside the float32 tolerance: the tolerance has no power"
+
+
+@pytest.mark.parametrize("field,value", [
+    ("attention_multiplier", None), ("embedding_multiplier", 1.0), ("residual_multiplier", 1.0),
+    ("logits_scaling", 1.0), ("pos_embed", "rope")])
+def test_each_departure_from_a_plain_decoder_shows(field, value):
+    """Dropping one multiplier, or adding rotary positions (one attention layer
+    of seven, its branch x 0.22: the smallest of the five, 7e-4), moves the
+    logits far outside the tolerance: the parity above holds the program to each."""
+    cfg = small_cfg()
+    params, rows = seeded(cfg)
+    want = modeling.forward(params, rows[:, :-1], cfg)
+    got = modeling.forward(params, rows[:, :-1], cfg.replace(**{field: value}))
+    err = float(jnp.abs(got - want).max() / jnp.abs(want).max())
+    assert err > 20 * F32_TOL
+
+
+def test_one_sequence_loss_picks_by_select_and_equals_the_gather():
+    """A batch of one sequence takes the select-and-sum pick (no flattened
+    scatter in its backward): same loss, same gradient, same count as the gather
+    the other batch sizes take, ignored labels included."""
+    logits = jax.random.normal(jax.random.key(0), (2, 24, 40))
+    labels = jax.random.randint(jax.random.key(1), (2, 24), 0, 40).at[:, 5].set(-100)
+
+    def both(lg):
+        return sum(modeling.cross_entropy_sum(lg[i:i + 1], labels[i:i + 1])[0] for i in range(2))
+
+    one, grad_one = jax.value_and_grad(both)(logits)
+    two, grad_two = jax.value_and_grad(lambda lg: modeling.cross_entropy_sum(lg, labels)[0])(logits)
+    assert float(one) == pytest.approx(float(two), rel=1e-6)
+    close(grad_one, grad_two, 1e-6)
+    assert int(modeling.cross_entropy_sum(logits[:1], labels[:1])[1]) == 23
+
+
+# -- the scan and the conv ------------------------------------------------------
+
+
+def ssd_sequential(x, dt, a, b_mat, c_mat):
+    """The recurrence itself, one position at a time in float32: what
+    ``ssd.ssd_scan`` must equal."""
+    f32 = jnp.float32
+    bsz, s, h, p = x.shape
+    g, n = b_mat.shape[2:]
+    r = h // g
+    x32 = (x.astype(f32) * dt.astype(f32)[..., None]).reshape(bsz, s, g, r, p)
+    dec = jnp.exp(dt.astype(f32) * a.astype(f32)).reshape(bsz, s, g, r)
+
+    def step(state, inp):
+        d_t, x_t, b_t, c_t = inp
+        state = state * d_t[..., None, None] + x_t[..., None] * b_t[:, :, None, None, :]
+        return state, jnp.einsum("bgrpn,bgn->bgrp", state, c_t)
+
+    _, y = jax.lax.scan(
+        step, jnp.zeros((bsz, g, r, p, n), f32),
+        tuple(jnp.moveaxis(t_, 1, 0) for t_ in (dec, x32, b_mat.astype(f32), c_mat.astype(f32))))
+    return jnp.moveaxis(y, 0, 1).reshape(bsz, s, h, p).astype(x.dtype)
+
+
+def scan_inputs(seed=0, b=2, s=72, h=4, p=8, g=2, n=16):
+    k = jax.random.split(jax.random.key(seed), 5)
+    x = jax.random.normal(k[0], (b, s, h, p))
+    dt = jax.nn.softplus(jax.random.normal(k[1], (b, s, h)) + 1.0)
+    a = -jnp.arange(1, h + 1, dtype=jnp.float32)
+    return x, dt, a, jax.random.normal(k[2], (b, s, g, n)), jax.random.normal(k[3], (b, s, g, n))
+
+
+@pytest.mark.parametrize("chunk", [8, 16, 72, 256])
+def test_chunked_scan_equals_the_recurrence_across_chunk_boundaries(chunk):
+    """Chunks of 8 and 16 over 72 positions (9 and 4.5 chunks: the carry and
+    the padding), one chunk exactly, and a chunk longer than the sequence."""
+    args = scan_inputs()
+    close(ssd.ssd_scan(*args, chunk), ssd_sequential(*args), F32_TOL)
+
+
+def test_chunked_scan_gradients_equal_the_recurrences():
+    args = scan_inputs(seed=3)
+    w = jax.random.normal(jax.random.key(9), args[0].shape)
+    got = jax.grad(lambda *t: jnp.sum(ssd.ssd_scan(*t, 16) * w), argnums=range(5))(*args)
+    want = jax.grad(lambda *t: jnp.sum(ssd_sequential(*t) * w), argnums=range(5))(*args)
+    for g, v in zip(got, want):
+        close(g, v, F32_TOL)
+
+
+def test_scan_carries_its_state_over_a_chunk_boundary():
+    """An impulse in the first chunk is still read in the last one (slow decay),
+    and an output never depends on a later input."""
+    x, dt, a, b_mat, c_mat = scan_inputs(h=2, g=1)
+    a = jnp.full_like(a, -0.01)
+    x0 = jnp.zeros_like(x).at[:, 3].set(1.0)
+    y = ssd.ssd_scan(x0, dt, a, b_mat, c_mat, 8)
+    assert float(jnp.abs(y[:, :3]).max()) == 0.0  # nothing before the impulse
+    assert float(jnp.abs(y[:, 64:]).max()) > 1e-3  # eight chunks later
+    moved = ssd.ssd_scan(x.at[:, 40:].add(1.0), dt, a, b_mat, c_mat, 8)
+    assert float(jnp.abs(moved[:, :40] - ssd.ssd_scan(x, dt, a, b_mat, c_mat, 8)[:, :40]).max()) == 0.0
+
+
+def test_a_bf16_decay_or_state_fails_the_tolerance():
+    """The tolerance has power over what the issue names: a decay exponent or a
+    carried state rounded to bf16 lands far outside it."""
+    x, dt, a, b_mat, c_mat = scan_inputs()
+    want = np.asarray(ssd_sequential(x, dt, a, b_mat, c_mat))
+    lossy = ssd.ssd_scan(x, dt.astype(jnp.bfloat16).astype(jnp.float32), a, b_mat, c_mat, 16)
+    assert np.abs(np.asarray(lossy) - want).max() / np.abs(want).max() > 50 * F32_TOL
+    # bf16 compute keeps float32 decays and states: its error is the operands' 8 bits
+    half = ssd.ssd_scan(x.astype(jnp.bfloat16), dt, a, b_mat.astype(jnp.bfloat16),
+                        c_mat.astype(jnp.bfloat16), 16)
+    assert half.dtype == jnp.bfloat16
+    close(half.astype(jnp.float32), want, BF16_TOL)
+
+
+def test_causal_conv_is_the_published_conv1d_and_leaks_nothing_from_the_future():
+    k = jax.random.split(jax.random.key(1), 3)
+    x = jax.random.normal(k[0], (2, 20, 6))
+    w, b = jax.random.normal(k[1], (4, 6)), jax.random.normal(k[2], (6,))
+    y = ssd.causal_conv1d(x, w, b)
+    # torch's conv1d(padding=3)[..., :s] with weight (C, 1, 4): a correlation
+    xp = np.pad(np.asarray(x), ((0, 0), (3, 0), (0, 0)))
+    want = sum(xp[:, j:j + 20] * np.asarray(w)[j] for j in range(4)) + np.asarray(b)
+    close(y, want, 1e-6)
+    later = ssd.causal_conv1d(x.at[:, 11:].add(5.0), w, b)
+    assert float(jnp.abs(later[:, :11] - y[:, :11]).max()) == 0.0
+    assert float(jnp.abs(later[:, 11] - y[:, 11]).max()) > 0.0
+    # the first position sees itself through the last tap only
+    close(y[:, 0], np.asarray(x)[:, 0] * np.asarray(w)[3] + np.asarray(b), 1e-6)
+
+
+# -- the runtime: layouts, refusals, the search ---------------------------------
+
+
+def test_runtime_trains_and_full_remat_changes_nothing():
+    cfg = small_cfg()
+    rows = np.asarray(jax.random.randint(jax.random.key(8), (4, cfg.max_seq_len + 1), 0,
+                                         cfg.vocab_size, jnp.int32))
+    runs = {}
+    for name, n, strat in (
+        ("one", 1, LayerStrategy()), ("remat", 1, LayerStrategy(ckpt="full")),
+        ("zero3", 4, LayerStrategy(dp_type="zero3")),
+    ):
+        mesh, axes = build_mesh(pp=1, devices=jax.devices()[:n])
+        hp = HybridParallelConfig(pp=1, layer_strategies=[strat] * cfg.num_layers,
+                                  mixed_precision="fp32")
+        rt = build_runtime(cfg, hp, mesh=mesh, axes=axes, global_batch_size=4,
+                           seq_len=cfg.max_seq_len)
+        state = rt.init_state(jax.random.key(0))
+        losses = []
+        for _ in range(3):
+            state, loss = rt.train_step(state, rt.shard_batch(rows))
+            losses.append(float(loss))
+        runs[name] = losses
+    assert runs["one"][2] < runs["one"][0]
+    assert runs["remat"] == pytest.approx(runs["one"], rel=1e-6)
+    assert runs["zero3"] == pytest.approx(runs["one"], rel=1e-4)
+
+
+def test_tp_on_the_attention_layer_alone_trains_like_one_device():
+    cfg = small_cfg()
+    rows = np.asarray(jax.random.randint(jax.random.key(8), (4, cfg.max_seq_len + 1), 0,
+                                         cfg.vocab_size, jnp.int32))
+    losses = []
+    for n, tp in ((1, 1), (4, 2)):
+        mesh, axes = build_mesh(pp=1, devices=jax.devices()[:n])
+        strat = [LayerStrategy(tp=tp) if k == "attention" else LayerStrategy() for k in cfg.kinds]
+        rt = build_runtime(cfg, HybridParallelConfig(pp=1, layer_strategies=strat,
+                                                     mixed_precision="fp32"),
+                           mesh=mesh, axes=axes, global_batch_size=4, seq_len=cfg.max_seq_len)
+        state = rt.init_state(jax.random.key(0))
+        state, loss = rt.train_step(state, rt.shard_batch(rows))
+        losses.append(float(loss))
+    assert losses[1] == pytest.approx(losses[0], rel=1e-4)
+
+
+def _plan(cfg, **kw):
+    pp = kw.pop("pp", 1)
+    return HybridParallelConfig(
+        pp=pp, layer_strategies=[LayerStrategy(**kw)] * cfg.num_layers, mixed_precision="fp32",
+        chunks=2 if pp > 1 else 1)
+
+
+@pytest.mark.parametrize("kw,named", [
+    ({"tp": 2}, r"tp>1.*state-space layers \(layers \[0, 1, 2, 3, 4, 6, 7\]"),
+    ({"cp": 2}, r"cp>1.*state-space"),
+    ({"pp": 2}, r"pp>1.*interleaved layer kinds"),
+])
+def test_build_runtime_refuses_by_name_what_a_hybrid_stack_cannot_run(kw, named):
+    cfg = small_cfg(num_layers=8)  # 8 layers so that pp 2 divides them
+    with pytest.raises(ValueError, match=named):
+        build_runtime(cfg, _plan(cfg, **dict(kw)), global_batch_size=8, seq_len=cfg.max_seq_len)
+
+
+@pytest.mark.parametrize("kw,code,named", [
+    ({"tp": 2}, "GTA019", "tp=2 on a state-space layer"),
+    ({"cp": 2}, "GTA019", "cp=2 on a state-space layer"),
+    ({"pp": 2}, "GTA020", "interleaved layer kinds"),
+])
+def test_check_plan_names_the_same_refusals(kw, code, named):
+    cfg = small_cfg(num_layers=8)
+    diags = check_plan(_plan(cfg, **dict(kw)), model_config=cfg, world_size=8, global_bsz=8)
+    hits = [d for d in diags if d.code == code]
+    assert hits and all(named in d.message for d in hits)
+    if code == "GTA019":  # one a state-space layer, none for the attention layer
+        assert len(hits) == 7 and not any("layer 5:" in d.message for d in hits)
+    # and a plan the runtime takes has neither
+    ok = check_plan(_plan(cfg, dp_type="zero3"), model_config=cfg, world_size=8, global_bsz=8)
+    assert not [d for d in ok if d.code in ("GTA019", "GTA020")]
+
+
+def test_serving_cache_refuses_recurrent_state_by_name():
+    from galvatron_tpu.models.generation import init_kv_cache
+
+    with pytest.raises(ValueError, match="state-space"):
+        init_kv_cache(small_cfg(), 1, 16)
+
+
+def test_analytic_costs_price_the_two_kinds():
+    from galvatron_tpu.search.theoretical import analytic_model_costs
+
+    cfg = PRESETS["granite-4.0-h-micro"].replace(num_layers=10, vocab_size=25088,
+                                                 attn_impl="flash")
+    at = {s: analytic_model_costs(cfg, seq_len=s).layer_types for s in (4096, 8192)}
+    assert sorted(at[8192]) == list(range(10))
+    ssm8, attn8, ssm4, attn4 = at[8192][0], at[8192][5], at[4096][0], at[4096][5]
+    assert at[8192][9] == ssm8 and ssm8 != attn8
+    assert (round(ssm8.parameter_mb, 1), round(attn8.parameter_mb, 1)) == (304.7, 243.3)
+    # a state-space layer's time is linear in the sequence, the attention layer's is not
+    assert ssm8.fwd_ms_per_sample == pytest.approx(2 * ssm4.fwd_ms_per_sample, rel=1e-9)
+    assert attn8.fwd_ms_per_sample > 2.1 * attn4.fwd_ms_per_sample
+    # the kept score blocks (64 heads x 256 x 6 B a token) are in the activation count
+    assert ssm8.activation_mb_per_sample[1] > attn8.activation_mb_per_sample[1] + 8192 * 64 * 256 * 5 / 1e6
+
+
+def test_cli_search_prices_both_kinds_at_pp1_and_its_plan_passes_the_checker(tmp_path, capsys):
+    """`cli search --model_size granite-4.0-h-micro --num_devices 4` on the whole
+    40-layer model: a plan comes back with pp 1 and tp 1 on every layer, names what
+    was left out, carries the two kinds' prices (remat differs by kind or not, but
+    both types were in the tables), and passes `check-plan`."""
+    from galvatron_tpu import cli
+    from galvatron_tpu.search.search_engine import SearchEngine, SearchSpace
+    from galvatron_tpu.search.cost_model import ProfiledHardware
+    from galvatron_tpu.search.theoretical import analytic_model_costs
+
+    path = str(tmp_path / "plan.json")
+    rc = cli.main(["search", "--model_size", "granite-4.0-h-micro", "--num_devices", "4",
+                   "--seq_length", "8192", "--settle_bsz", "4", "--memory_constraint_gb", "15",
+                   "--enable_cp", "1", "--output_config_path", path])
+    assert rc in (0, None)
+    said = capsys.readouterr().out
+    assert "tp>1" in said and "cp>1" in said and "pp>1" in said and "analytically" in said
+    plan = json.load(open(path))
+    assert plan["pp_deg"] == 1 and set(plan["tp_sizes_enc"].split(",")) == {"1"}
+    assert len(plan["tp_sizes_enc"].split(",")) == 40
+    assert plan["search_restrictions"] == [
+        "interleaved_layer_kinds_no_pp", "state_space_layers_no_cp", "state_space_layers_no_tp"]
+    assert plan["model_config"]["num_layers"] == 40
+    HybridParallelConfig.load(path).validate(4)
+    assert cli.main(["check-plan", path]) in (0, None)
+    # the engine's groups follow the published interleaving
+    cfg = PRESETS["granite-4.0-h-micro"].replace(max_seq_len=8192)
+    eng = SearchEngine(analytic_model_costs(cfg), ProfiledHardware(), num_layers=40,
+                       space=SearchSpace(world_size=4), memory_budget_mb=15360.0, model_config=cfg)
+    assert [(start, count) for start, count, _ in eng._type_groups()] == [
+        (0, 5), (5, 1), (6, 9), (15, 1), (16, 9), (25, 1), (26, 9), (35, 1), (36, 4)]
+    assert eng.space.max_tp == 1 and eng.space.pp_choices == [1] and not eng.space.allow_cp
+
+
+def test_a_searched_plan_runs(tmp_path):
+    """The plan `cli search` emits for a small hybrid stack on 4 devices goes
+    through `build_runtime` and trains."""
+    from galvatron_tpu import cli
+
+    path = str(tmp_path / "plan.json")
+    flags = ["--model_size", "granite-4.0-h-micro", "--num_layers", "7", "--hidden_size", "64",
+             "--num_heads", "4", "--num_kv_heads", "2", "--ffn_dim", "96", "--vocab_size", "96",
+             "--seq_length", "256"]
+    assert cli.main(["search", *flags, "--num_devices", "4", "--settle_bsz", "4",
+                     "--memory_constraint_gb", "15", "--output_config_path", path]) in (0, None)
+    hp = HybridParallelConfig.load(path)
+    cfg = PRESETS["granite-4.0-h-micro"].replace(
+        num_layers=7, hidden_size=64, num_heads=4, num_kv_heads=2, ffn_dim=96, vocab_size=96,
+        max_seq_len=256, ssm_heads=4, ssm_head_dim=16, ssm_state=16, ssm_chunk=64)
+    mesh, axes = build_mesh(pp=1, devices=jax.devices()[:4])
+    rt = build_runtime(cfg, hp, mesh=mesh, axes=axes, global_batch_size=4, seq_len=256)
+    state = rt.init_state(jax.random.key(0))
+    rows = np.asarray(jax.random.randint(jax.random.key(1), (4, 257), 0, 96, jnp.int32))
+    state, first = rt.train_step(state, rt.shard_batch(rows))
+    state, second = rt.train_step(state, rt.shard_batch(rows))
+    assert np.isfinite(float(first)) and float(second) < float(first)

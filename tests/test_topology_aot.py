@@ -319,6 +319,50 @@ def test_olmoe_step_names_its_kernels_and_scopes(topo, real_mosaic):
     assert len(scoped_rows) >= 0.9 * len(work), (len(scoped_rows), len(work))
 
 
+def test_granite_mixer_compiles_at_published_widths(one_chip, real_mosaic):
+    """One Mamba-2 mixer of `granite-4.0-h-micro_s8192`, forward + backward under
+    recomputation, as the chip's compiler sees it: 64 heads of 64, state 128, 32
+    chunks of 256 over 8192 tokens. A scan it refuses, or score blocks (64 x 32 x
+    256 x 256 a sequence) that outgrow what a layer may take beside 12 GiB of
+    state, shows here; the five scopes reach the compiled ENTRY under ``ssm``.
+    (The ten-layer step compiles in two minutes, 1.3 of them the 8192-key
+    ``flash_bwd_blocked``: a chip run's job, PERF.md §6.)"""
+    from galvatron_tpu.models import ssm
+    from galvatron_tpu.models.modeling import PRESETS
+
+    cfg = PRESETS["granite-4.0-h-micro"].replace(mlp_recompute="off")
+    assert ssm.ssm_dims(cfg) == (4096, 4352, 8512) and cfg.ssm_chunk == 256
+    shapes = jax.eval_shape(lambda k: ssm.init_ssm_params(k, cfg), jax.random.key(0))
+    p = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), shapes)
+    x = jax.ShapeDtypeStruct((1, 8192, 2048), jnp.bfloat16, sharding=one_chip)
+
+    def loss(x_, p_):
+        with jax.named_scope("layer_0"):
+            y = jax.checkpoint(lambda a, b: ssm.ssm_block(a, b, cfg))(x_, p_)
+        return jnp.sum(y.astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, p).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 2 * 2**30, f"{temp / 2**30:.2f} GiB"
+    ops = [op for _, op in _entry_work(compiled.as_text())]
+    for scope in ("in_proj", "conv", "scan", "gate_norm", "out_proj"):
+        mine = [op for op in ops if f"/ssm/{scope}/" in op]
+        assert mine and any("transpose(" in op for op in mine), scope
+
+
+def test_granite_attention_takes_the_blocked_gqa_kernel_at_8192(one_chip, real_mosaic):
+    """32 query heads over 8 key/value heads of 64, no rotary tables, softmax
+    scale 1/64, 8192 keys: the forward is ``flash_fwd_blocked``."""
+    from galvatron_tpu.ops.flash_attention import flash_attention_hm
+
+    q = jax.ShapeDtypeStruct((1, 32, 8192, 64), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 8, 8192, 64), jnp.bfloat16, sharding=one_chip)
+    text = _kernel_text(
+        lambda q_, k_, v_: flash_attention_hm(q_, k_, v_, causal=True, sm_scale=0.015625),
+        q, kv, kv)
+    assert sorted(set(_kernel_names(text))) == ["flash_fwd_blocked"]
+
+
 def test_olmoe_block_partitions_on_four_chips(topo, real_mosaic):
     """Under a data-parallel mesh each device routes its own tokens inside a
     ``shard_map``: the Mosaic kernels compile for four chips and keep their names."""
