@@ -93,7 +93,8 @@ def main() -> None:
     from galvatron_tpu.aot.cache import persistent_cache_off
     from galvatron_tpu.ops import flash_attention, grouped_matmul
 
-    # the CPU is the backend here; lower the real kernels
+    # the CPU is the backend here; lower the real kernels (ops/ssd.py goes by
+    # flash_attention's switch, for its kernels and for its choice of the scan's body)
     for mod in (flash_attention, grouped_matmul):
         mod._use_interpret = lambda: False
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")

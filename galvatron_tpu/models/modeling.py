@@ -1245,7 +1245,8 @@ def decoder_layer(
     if "ssm" in p:
         from galvatron_tpu.models import ssm
 
-        x = residual_add(x, ssm.ssm_block(norm(x, p["attn_norm"], cfg), p["ssm"], cfg), cfg)
+        x = residual_add(x, ssm.ssm_block(
+            norm(x, p["attn_norm"], cfg), p["ssm"], cfg, place=place), cfg)
         return mlp_residual(x, p, cfg, place=place)
     x = residual_add(x, attn_block(
         norm(x, p["attn_norm"], cfg), p["attn"], cfg, cos_sin, alibi,

@@ -26,7 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from galvatron_tpu.ops.ssd import causal_conv1d, ssd_scan
+from galvatron_tpu.models.placement import LOCAL, Placement
+from galvatron_tpu.ops.ssd import causal_conv1d, scan_path, ssd_scan
 
 Params = Dict[str, Any]
 F32 = jnp.float32
@@ -79,8 +80,11 @@ def ssm_annotations(cfg) -> Params:
 
 
 @jax.named_scope("ssm")
-def ssm_block(x, p: Params, cfg):
-    """(B, S, hidden) normed layer input -> the mixer's output, same shape."""
+def ssm_block(x, p: Params, cfg, place: Placement = LOCAL):
+    """(B, S, hidden) normed layer input -> the mixer's output, same shape.
+    ``place`` (models/placement.py) is asked for one thing: where the scan is
+    the fused kernels, a mesh must run them on each device's own batch rows
+    (GSPMD partitions the plain body by itself, a Mosaic call it cannot)."""
     dtype = x.dtype
     heads, hd, groups, state = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state
     d_inner, conv_dim, _ = ssm_dims(cfg)
@@ -97,7 +101,12 @@ def ssm_block(x, p: Params, cfg):
         b_mat = xbc[..., d_inner:d_inner + groups * state].reshape(*lead, groups, state)
         c_mat = xbc[..., d_inner + groups * state:].reshape(*lead, groups, state)
         dt = jax.nn.softplus(dt.astype(F32) + p["dt_bias"].astype(F32))
-        y = ssd_scan(xs, dt, -jnp.exp(p["A_log"].astype(F32)), b_mat, c_mat, cfg.ssm_chunk)
+        scan = lambda *t: ssd_scan(*t, cfg.ssm_chunk)  # noqa: E731
+        if scan_path(heads, hd, groups, state, cfg.ssm_chunk, dtype) == "fused":
+            # batch dim 0 over the data-parallel axes; the heads stay whole (tp is refused)
+            rows = (0, None)
+            scan = place.shard_kernel(scan, [rows, rows, (None, None), rows, rows], rows)
+        y = scan(xs, dt, -jnp.exp(p["A_log"].astype(F32)), b_mat, c_mat)
         y = (y.astype(F32) + p["D"].astype(F32)[:, None] * xs.astype(F32)).astype(dtype)
         y = y.reshape(*lead, d_inner)
     with jax.named_scope("gate_norm"):

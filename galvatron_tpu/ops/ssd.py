@@ -8,21 +8,39 @@ The recurrence, per head (``H`` in R^{P x N}; one scalar decay a head)::
 is computed in chunks of ``chunk`` positions (Dao & Gu 2024, "Transformers are
 SSMs", listing 1): inside a chunk the output is a decay-masked ``C B^T``
 product (an attention-like (L, L) block a head), one state a chunk summarises
-what the chunk adds, and the states are carried across chunks by a scan over
-the chunk axis. Written in plain ``jax.numpy`` so that autodiff gives the
-backward; XLA fuses the mask, the exponentials and the casts around the four
-batched GEMMs.
+what the chunk adds, and the states are carried across chunks.
 
-Precision: the log-decays, their cumulative sums, ``dt`` and the carried
-states are float32 always (a decay in bf16 loses the recurrence after a few
-hundred steps); the GEMM operands are the compute dtype with float32
-accumulation. B and C are shared by the heads of a group (``G`` groups).
+Two bodies with one contract. `ssd_scan_plain` is plain ``jax.numpy`` (autodiff
+gives its backward; XLA fuses the mask, the exponentials and the casts around
+four batched GEMMs and carries the states by a ``lax.scan``): the path outside
+the fused kernels' envelope and the tests' oracle. `ssd_scan_fused` is a
+``jax.custom_vjp`` over Pallas kernels (``ssd_fwd``, ``ssd_bwd`` and the two
+small ``ssd_decay`` / ``ssd_decay_bwd``): x read and y written token-major where
+the model holds them, the score blocks never outside VMEM, the state carried
+in VMEM across the chunk axis of the grid. `ssd_scan` chooses between them by
+`scan_path`, from shapes and backend alone. On a mesh GSPMD partitions the
+plain body by itself and cannot partition a Mosaic call: `models/ssm.ssm_block`
+runs the fused body under ``place.shard_kernel``, each device on its own batch
+rows (tp and cp on a state-space layer are refused, so the heads and the
+sequence are whole there).
+
+Precision, both bodies: the log-decays, their cumulative sums, ``dt`` and the
+carried states (and, in the backward, the carried gradient of the state) are
+float32 always (a decay in bf16 loses the recurrence after a few hundred
+steps); the GEMM operands are the compute dtype with float32 accumulation. B
+and C are shared by the heads of a group (``G`` groups).
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from galvatron_tpu.ops import flash_attention as fa
 
 F32 = jnp.float32
 
@@ -42,7 +60,7 @@ def causal_conv1d(x, w, b):
     return y.astype(x.dtype)
 
 
-def ssd_scan(x, dt, a, b_mat, c_mat, chunk: int):
+def ssd_scan_plain(x, dt, a, b_mat, c_mat, chunk: int):
     """Chunked SSD: ``x`` (B, S, H, P) in the compute dtype, ``dt`` (B, S, H)
     float32 and positive, ``a`` (H,) float32 and negative, ``b_mat`` / ``c_mat``
     (B, S, G, N) -> ``y`` (B, S, H, P) in ``x``'s dtype, without the ``D x``
@@ -98,3 +116,462 @@ def ssd_scan(x, dt, a, b_mat, c_mat, chunk: int):
                        preferred_element_type=F32)
     y = (y + y_off * jnp.exp(cum)[..., None]).astype(dtype)
     return y.reshape(bsz, h, nc * chunk, p).transpose(0, 2, 1, 3)[:, :s]
+
+
+# -- the fused kernels ---------------------------------------------------------
+#
+# One grid step is one chunk of one block of heads: grid (batch, head block,
+# chunk), the chunk axis sequential ("arbitrary"), the state of the block's
+# heads, float32 (N, heads x P), in VMEM scratch from one chunk to the next; the
+# heads of a block (at most `_MAX_HEAD_BLOCK`) are the only thing unrolled.
+# x, y, dy and dx are (L, heads x P) blocks of the token-major (B, S, H x P)
+# arrays, B and C the (L, N) blocks of the block's group, all through index maps:
+# nothing is transposed outside a kernel. dt comes as the model holds it,
+# (B, S, H): `ssd_decay` turns a chunk of it into head-major rows (a position a
+# lane) of dt and of the log-decays summed from the chunk's start, by float32
+# adds (no MXU pass); `ssd_fwd` / `ssd_bwd` turn the rows of their heads into
+# columns (a position a sublane) with one aligned (128, L) transpose a step and
+# broadcast two of them a head over the lanes. `ssd_decay_bwd` takes the
+# kernels' d dt and d cum back to (B, S, H).
+#
+# Residuals of the backward (what the forward rule keeps): the operands, the
+# two (B, H, S) float32 row arrays (2 MB each at the cell's shape: B 1, S 8192,
+# H 64, P 64, N 128, chunks of 256), and the state entering every chunk,
+# (B, S / L, N, H x P) float32 = 67 MB there. Nothing of size H x chunks x L x L
+# is ever kept or written: the backward recomputes its score blocks from x, B, C,
+# dt as `flash_bwd_blocked` does its own.
+
+_NEG = -1e30  # exp() of it is 0: the mask of the score blocks
+_LANES = 128
+_ROWS = 128  # rows of the scratch the per-position vectors are transposed through
+# Heads a grid step. 16 ran 4% faster and 32 another 4% (0.42 / 1.07 and 0.38 / 1.03 ms a
+# layer forward / backward against 0.47 / 1.16; PERF.md §6, PR 34), but a block's heads are
+# unrolled in the kernel's text: a program traces and lowers it three times (forward,
+# the forward that keeps the states, backward), ~0.4 s of set-up at 8 (PERF.md §6, PR 34)
+_MAX_HEAD_BLOCK = 8
+
+
+def _head_block(r: int, p: int) -> int:
+    """Heads a grid step: the largest power of two up to ``_MAX_HEAD_BLOCK``
+    that divides the heads of a group (a block never spans two groups, so
+    one ``C B^T`` serves it) and fills whole 128-lane tiles of x; 0 if none."""
+    hb = _MAX_HEAD_BLOCK
+    while hb and (r % hb or (hb * p) % _LANES):
+        hb //= 2
+    return hb
+
+
+def _fused_vmem_mb(hb: int, p: int, n: int, chunk: int, itemsize: int) -> float:
+    """What a backward step holds in VMEM, reckoned from the shapes: the blocks
+    of x, dy and dx, of B, C, dB and dC and of the entering state (two buffers
+    each), the carried gradient, and the float32 temporaries of a tile (a few
+    score blocks, a few (L, heads x P) products)."""
+    width = hb * p
+    blocks = 2 * (3 * chunk * width * itemsize + 2 * chunk * n * itemsize
+                  + 2 * chunk * n * 4 + n * width * 4)
+    scratch = n * width * 4 + _ROWS * chunk * 4
+    temps = 6 * chunk * chunk * 4 + 6 * chunk * width * 4
+    return (blocks + scratch + temps) / 2**20
+
+
+def scan_path(heads: int, head_dim: int, groups: int, state: int, chunk: int, dtype) -> str:
+    """``"fused"`` or ``"plain"`` for a Mamba-2 scan of these sizes, from the
+    shapes and the backend alone: no flag, no environment variable, no model's
+    name. `ssd_scan` and the trainer's ``ssm_scan_path`` counter both ask
+    here. The fused kernels take, and everything else takes the plain body:
+
+    - a chip (`flash_attention._use_interpret`'s rule, the one switch of this
+      repo's kernels: on the CPU they run interpreted, which only the tests
+      that call `ssd_scan_fused` themselves want);
+    - ``chunk`` a multiple of 128: the (L, L) score blocks and the rows of dt
+      tile the 128 lanes (a sequence is padded to whole chunks either way);
+    - ``head_dim`` 64 or 128: two heads or one fill a 128-lane tile of x;
+    - ``state`` a multiple of 128: a group's (L, N) block of B and C is cut out
+      of (B, S, G x N) by lanes (N 64 keeps the plain body: never run here);
+    - heads in whole groups, a group's heads a multiple of a head block that
+      fills whole tiles (`_head_block`: one ``C B^T`` serves a block);
+    - bf16 or float32 compute;
+    - a VMEM charge (`_fused_vmem_mb`, 7.6 MB at the granite sizes) inside the
+      budget `flash_attention._seq_envelope` reckons with.
+    """
+    dtype = jnp.dtype(dtype)
+    if fa._use_interpret() or heads % max(groups, 1) or dtype not in (jnp.bfloat16, jnp.float32):
+        return "plain"
+    hb = _head_block(heads // groups, head_dim)
+    inside = (chunk % _LANES == 0 and head_dim in (64, 128) and state % _LANES == 0 and hb > 0
+              and 1.1 * _fused_vmem_mb(hb, head_dim, state, chunk, dtype.itemsize) <= fa._VMEM_EFF_MB)
+    return "fused" if inside else "plain"
+
+
+def scan_path_counts(cfg) -> dict:
+    """``{"fused": n, "plain": m}``: how many of a configuration's state-space
+    layers take which body (the run's fingerprint, PERF.md §3)."""
+    counts = {"fused": 0, "plain": 0}
+    layers = sum(kind == "ssm" for kind in cfg.kinds)
+    if layers:
+        counts[scan_path(cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state,
+                         cfg.ssm_chunk, cfg.dtype)] = layers
+    return counts
+
+
+def ssd_scan(x, dt, a, b_mat, c_mat, chunk: int):
+    """`ssd_scan_plain`'s contract; the fused kernels where `scan_path` says
+    so, the plain body everywhere else."""
+    (h, p), (g, n) = x.shape[2:], b_mat.shape[2:]
+    if scan_path(h, p, g, n, chunk, x.dtype) == "fused":
+        return ssd_scan_fused(x, dt, a, b_mat, c_mat, chunk)
+    return ssd_scan_plain(x, dt, a, b_mat, c_mat, chunk)
+
+
+def ssd_scan_fused(x, dt, a, b_mat, c_mat, chunk: int):
+    """`ssd_scan_plain`'s contract through the kernels (`_ssd_core`), for sizes
+    inside `scan_path`'s envelope. Outside the kernels only the padding to
+    whole chunks and reshapes that move nothing."""
+    bsz, s, h, p = x.shape
+    g, n = b_mat.shape[2:]
+    pad = -s % chunk
+    if pad:
+        x, dt, b_mat, c_mat = (
+            jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+            for t in (x, dt, b_mat, c_mat))
+    sp, dtype = s + pad, x.dtype
+    y = _ssd_core(x.reshape(bsz, sp, h * p), dt.astype(F32), a.astype(F32).reshape(h, 1),
+                  b_mat.astype(dtype).reshape(bsz, sp, g * n),
+                  c_mat.astype(dtype).reshape(bsz, sp, g * n), chunk, n)
+    return y.reshape(bsz, sp, h, p)[:, :s]
+
+
+def _columns(rows_scr, rows):
+    """(hb, L) float32 rows, a position a lane -> (L, 128): column
+    ``q * hp + i`` is row i of the q-th array (hp: hb rounded up to 8), a
+    position a sublane. Through a (128, L) scratch and one aligned transpose."""
+    hb = rows[0].shape[0]
+    hp = -(-hb // 8) * 8
+    for q, r in enumerate(rows):
+        rows_scr[q * hp:q * hp + hb, :] = r
+    return rows_scr[...].T, hp
+
+
+def _tile(cols, hp, p, t):
+    """What the t-th 128-lane tile of a head block needs of its heads' dt and
+    summed log-decays, a position a sublane. Two lane broadcasts a head (its
+    cum and its dt column: the costly part of a step, PERF.md §6, PR 34);
+    every other per-position factor is an exponential of the broadcast cum.
+
+    -> heads, ``own(i, v)`` v on the lanes of head i and 0 on the other's,
+    ``crep[i]`` cum of head i over the lanes, and ``dt``, ``exp(cum)``,
+    ``exp(cum_last - cum)``, each lane holding the value of the head it belongs to."""
+    chunk = cols.shape[0]
+    hpt = _LANES // p
+    heads = range(t * hpt, (t + 1) * hpt)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
+
+    def own(i, v):  # v with the lanes of the tile's other head zeroed
+        if hpt == 1:
+            return v
+        return jnp.where((lane >= (i - heads[0]) * p) & (lane < (i - heads[0] + 1) * p), v, 0)
+
+    def pick(vals):
+        out = vals[0]
+        for k, v in enumerate(vals[1:], 1):
+            out = jnp.where(lane >= k * p, v, out)
+        return out
+
+    def rep(q, i):
+        return jnp.broadcast_to(cols[:, q * hp + i:q * hp + i + 1], (chunk, _LANES))
+
+    def last(v):  # the chunk's last position over the lanes, (1, 128); a slice of
+        # a broadcast folds to a (1, 1) that Mosaic cannot spread both ways
+        at_end = jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0) == chunk - 1
+        return jnp.sum(jnp.where(at_end, v, 0.0), axis=0, keepdims=True)
+
+    crep = {i: rep(0, i) for i in heads}
+    dt_l = pick([rep(1, i) for i in heads])
+    ecum_l = pick([jnp.exp(crep[i]) for i in heads])
+    grow_l = pick([jnp.exp(last(crep[i]) - crep[i]) for i in heads])
+    return heads, own, crep, dt_l, ecum_l, grow_l
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, (dims, ((), ())), preferred_element_type=F32)
+
+
+_NN, _NT, _TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
+
+
+def _decay(crep, cum_ref, i):
+    """(L, L) float32 of head i of the block: exp(cum_l - cum_s) where s <= l,
+    0 above the diagonal; ``crep`` (L, 128) is cum_l over the lanes, cum_s is
+    read from the rows a lane tile at a time (a slice of a loaded row at lane
+    128 keeps a layout Mosaic will not broadcast)."""
+    chunk = crep.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, _LANES), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, _LANES), 1)
+    return jnp.concatenate(
+        [jnp.exp(jnp.where(row >= col + k, crep - cum_ref[0, 0, i:i + 1, k:k + _LANES], _NEG))
+         for k in range(0, chunk, _LANES)], axis=1)
+
+
+def _fwd_kernel(x_ref, dt_ref, cum_ref, b_ref, c_ref, y_ref, *rest, p, keep_states):
+    state, rows_scr = rest[-2:]
+    chunk, dtype = x_ref.shape[1], x_ref.dtype
+    hb = dt_ref.shape[2]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _zero():
+        state[...] = jnp.zeros_like(state)
+
+    entering = state[...]  # (N, hb x P) float32
+    if keep_states:
+        rest[0][0, 0] = entering
+    cols, hp = _columns(rows_scr, (cum_ref[0, 0], dt_ref[0, 0]))  # (hb, L) each
+    b, c = b_ref[0], c_ref[0]
+    b_t = b.T  # (N, L), once for the block's tiles
+    cb = _dot(c, b, _NT)  # (L, L): C_l . B_s, once for the block's group
+    read = _dot(c, entering.astype(dtype), _NN)  # (L, hb x P): C_l . state
+    for t in range(hb * p // _LANES):
+        lanes = slice(t * _LANES, (t + 1) * _LANES)
+        heads, own, crep, dt_l, ecum_l, grow_l = _tile(cols, hp, p, t)
+        x32 = x_ref[0, :, lanes].astype(F32)
+        xdt = (x32 * dt_l).astype(dtype)
+        y = ecum_l * read[:, lanes]
+        for i in heads:
+            scores = (cb * _decay(crep[i], cum_ref, i)).astype(dtype)
+            y += _dot(scores, own(i, xdt), _NN)
+        y_ref[0, :, lanes] = y.astype(dtype)
+        grown = ecum_l[chunk - 1:chunk, :]  # exp(cum_last) a head, (1, 128)
+        state[:, lanes] = entering[:, lanes] * grown + _dot(
+            b_t, (x32 * (dt_l * grow_l)).astype(dtype), _NN)
+
+
+def _specs(bsz, sp, h, p, g, n, chunk, hb, rev):
+    """Block specs shared by the two kernels; ``rev`` walks the chunks from
+    the last to the first."""
+    nc, r = sp // chunk, h // g
+    ch = (lambda c: nc - 1 - c) if rev else (lambda c: c)
+    tokens = pl.BlockSpec((1, chunk, hb * p), lambda b, j, c: (b, ch(c), j))
+    rows = pl.BlockSpec((1, 1, hb, chunk), lambda b, j, c: (b, j, 0, ch(c)))
+    group = pl.BlockSpec((1, chunk, n), lambda b, j, c: (b, ch(c), (j * hb) // r))
+    states = pl.BlockSpec((1, 1, n, hb * p), lambda b, j, c: (b, ch(c), 0, j))
+    return (bsz, h // hb, nc), tokens, rows, group, states
+
+
+def _running_sum(v, reverse=False):
+    """Inclusive running sum along the lanes of (rows, L) float32 (from the
+    last lane backwards if ``reverse``): log2(L) shifted float32 adds, no MXU pass."""
+    n = v.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, v.shape, 1)
+    k = 1
+    while k < n:
+        if reverse:
+            v = v + jnp.where(lane < n - k, pltpu.roll(v, n - k, 1), 0.0)
+        else:
+            v = v + jnp.where(lane >= k, pltpu.roll(v, k, 1), 0.0)
+        k *= 2
+    return v
+
+
+def _decay_kernel(dt_ref, a_ref, dtr_ref, cum_ref, pad_scr):
+    """One chunk of dt, (L, H) as the model holds it -> head-major rows
+    (H / hb, hb, L) of dt and of the log-decays summed from the chunk's start."""
+    h = dt_ref.shape[2]
+    hb = dtr_ref.shape[2]
+    pad_scr[:, :h] = dt_ref[0]
+    rows = pad_scr[...].T[:h]  # (H, L)
+    cum = _running_sum(rows * a_ref[...])
+    for t in range(h // hb):
+        dtr_ref[0, t] = rows[t * hb:(t + 1) * hb]
+        cum_ref[0, t] = cum[t * hb:(t + 1) * hb]
+
+
+def _decay_bwd_kernel(ddt_ref, dcum_ref, a_ref, ddt_out, dla_out, pad_scr):
+    """The way back: head-major rows of the kernels' d dt and d cum -> (L, H)
+    of d(dt a) (the running sum's transpose: a sum from the chunk's end) and of
+    the whole d dt."""
+    h = ddt_out.shape[2]
+    hb = ddt_ref.shape[2]
+    for q, ref in enumerate((ddt_ref, dcum_ref)):
+        for t in range(h // hb):
+            pad_scr[q, t * hb:(t + 1) * hb, :] = ref[0, t]
+    dla = _running_sum(pad_scr[1, :h], reverse=True)
+    pad_scr[0, :h] = pad_scr[0, :h] + a_ref[...] * dla
+    pad_scr[1, :h] = dla
+    ddt_out[0] = pad_scr[0].T[:, :h]
+    dla_out[0] = pad_scr[1].T[:, :h]
+
+
+def _decay_specs(bsz, sp, h, chunk, hb):
+    natural = pl.BlockSpec((1, chunk, h), lambda b, c: (b, c, 0))
+    rows = pl.BlockSpec((1, h // hb, hb, chunk), lambda b, c: (b, 0, 0, c))
+    heads = pl.BlockSpec((h, 1), lambda b, c: (0, 0))
+    return (bsz, sp // chunk), natural, rows, heads, -(-h // _LANES) * _LANES
+
+
+def _decay_call(dt, a, chunk, hb):
+    bsz, sp, h = dt.shape
+    grid, natural, rows, heads, hpad = _decay_specs(bsz, sp, h, chunk, hb)
+    shape = jax.ShapeDtypeStruct((bsz, h // hb, hb, sp), F32)
+    return pl.pallas_call(
+        _decay_kernel, grid=grid, in_specs=[natural, heads], out_specs=[rows, rows],
+        out_shape=[shape, shape], scratch_shapes=[pltpu.VMEM((chunk, hpad), F32)],
+        compiler_params=fa._compiler_params(dimension_semantics=("parallel", "parallel")),
+        interpret=fa._use_interpret(), name="ssd_decay",
+    )(dt, a)
+
+
+def _decay_bwd_call(ddt, dcum, a, chunk):
+    bsz, nhb, hb, sp = ddt.shape
+    h = nhb * hb
+    grid, natural, rows, heads, hpad = _decay_specs(bsz, sp, h, chunk, hb)
+    shape = jax.ShapeDtypeStruct((bsz, sp, h), F32)
+    return pl.pallas_call(
+        _decay_bwd_kernel, grid=grid, in_specs=[rows, rows, heads], out_specs=[natural, natural],
+        out_shape=[shape, shape], scratch_shapes=[pltpu.VMEM((2, hpad, chunk), F32)],
+        compiler_params=fa._compiler_params(dimension_semantics=("parallel", "parallel")),
+        interpret=fa._use_interpret(), name="ssd_decay_bwd",
+    )(ddt, dcum, a)
+
+
+def _sizes(x, dtr, bm, n):
+    bsz, sp, width = x.shape
+    nhb, hb = dtr.shape[1:3]
+    h = nhb * hb
+    return bsz, sp, h, width // h, bm.shape[2] // n, hb
+
+
+def _fwd_call(x, dtr, cum, bm, cm, chunk, n, keep_states):
+    bsz, sp, h, p, g, hb = _sizes(x, dtr, bm, n)
+    grid, tokens, rows, group, states = _specs(bsz, sp, h, p, g, n, chunk, hb, rev=False)
+    out_shape = [jax.ShapeDtypeStruct(x.shape, x.dtype)]
+    out_specs = [tokens]
+    if keep_states:
+        out_shape.append(jax.ShapeDtypeStruct((bsz, sp // chunk, n, h * p), F32))
+        out_specs.append(states)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, p=p, keep_states=keep_states),
+        grid=grid, in_specs=[tokens, rows, rows, group, group],
+        out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((n, hb * p), F32), pltpu.VMEM((_ROWS, chunk), F32)],
+        compiler_params=fa._compiler_params(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=fa._use_interpret(), name="ssd_fwd",
+    )(x, dtr, cum, bm, cm)
+
+
+def _bwd_kernel(x_ref, dt_ref, cum_ref, b_ref, c_ref, dy_ref, st_ref,
+                dx_ref, ddt_ref, dcum_ref, db_ref, dc_ref, dstate, rows_scr, *, p):
+    chunk, dtype = x_ref.shape[1], x_ref.dtype
+    hb = dt_ref.shape[2]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _zero():
+        dstate[...] = jnp.zeros_like(dstate)
+
+    leaving = dstate[...]  # gradient of the state this chunk leaves, (N, hb x P) float32
+    entering = st_ref[0, 0]  # the state that entered it in the forward
+    cols, hp = _columns(rows_scr, (cum_ref[0, 0], dt_ref[0, 0]))  # (hb, L) each
+    b, c = b_ref[0], c_ref[0]
+    c_t = c.T  # (N, L), once for the block's tiles
+    cb = _dot(c, b, _NT)
+    read = _dot(c, entering.astype(dtype), _NN)  # (L, hb x P): C_l . state
+    pushed = _dot(b, leaving.astype(dtype), _NN)  # (L, hb x P): B_s . dstate
+    dg = jnp.zeros((chunk, chunk), F32)  # gradient of C B^T, summed over the block's heads
+    dc = jnp.zeros(c.shape, F32)
+    db = jnp.zeros(b.shape, F32)
+    last = jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1) == chunk - 1
+    for t in range(hb * p // _LANES):
+        lanes = slice(t * _LANES, (t + 1) * _LANES)
+        heads, own, crep, dt_l, ecum_l, grow_l = _tile(cols, hp, p, t)
+        w_l = dt_l * grow_l
+        x32 = x_ref[0, :, lanes].astype(F32)
+        dy = dy_ref[0, :, lanes]
+        dy32 = dy.astype(F32)
+        xdt = (x32 * dt_l).astype(dtype)
+        dxdt = jnp.zeros((chunk, _LANES), F32)  # gradient of dt x
+        y = ecum_l * read[:, lanes]  # the forward's output again, float32
+        for i in heads:
+            dyi = own(i, dy)
+            decay = _decay(crep[i], cum_ref, i)
+            scores = (cb * decay).astype(dtype)
+            dxdt += _dot(scores, dyi, _TN)
+            dg += _dot(dyi, xdt, _NT) * decay
+            y += _dot(scores, own(i, xdt), _NN)
+        push = pushed[:, lanes]
+        dx_ref[0, :, lanes] = (dt_l * dxdt + w_l * push).astype(dtype)
+        # per position and head, summed over the head's P lanes through a
+        # transpose (a position a lane again, as the outputs want it):
+        #   d dt  = x . (dxdt + grow push)
+        #   d cum = dy . y - (dt x) . dxdt - w x . push
+        # (cum_l scales all of y_l, cum_s what position s sends on: the two
+        # sums over the score block, taken from products the kernel already has;
+        # dt x as the GEMMs saw it, rounded, so that the diagonal cancels)
+        xpush = x32 * push
+        ddt_t = (x32 * dxdt + grow_l * xpush).T  # (128, L)
+        dcum_t = (dy32 * y - xdt.astype(F32) * dxdt - w_l * xpush).T
+        # cum_last moves the carried state and every w of the chunk
+        grown = ecum_l[chunk - 1:chunk, :]  # exp(cum_last) a head, (1, 128)
+        dlast_t = (jnp.sum(leaving[:, lanes] * entering[:, lanes] * grown, axis=0, keepdims=True)
+                   + jnp.sum(w_l * xpush, axis=0, keepdims=True))
+        for i in heads:
+            at = slice((i - heads[0]) * p, (i - heads[0] + 1) * p)
+            ddt_ref[0, 0, i:i + 1, :] = jnp.sum(ddt_t[at], axis=0, keepdims=True)
+            dlast = jnp.sum(dlast_t[:, at], axis=1, keepdims=True)  # (1, 1)
+            dcum_ref[0, 0, i:i + 1, :] = (jnp.sum(dcum_t[at], axis=0, keepdims=True)
+                                          + jnp.where(last, dlast, 0.0))
+        dz = (dy32 * ecum_l).astype(dtype)  # exp(cum) dy
+        dstate[:, lanes] = leaving[:, lanes] * grown + _dot(c_t, dz, _NN)
+        dc += _dot(dz, entering[:, lanes].astype(dtype), _NT)
+        db += _dot((x32 * w_l).astype(dtype), leaving[:, lanes].astype(dtype), _NT)
+    dgc = dg.astype(dtype)
+    dc_ref[0, 0] = dc + _dot(dgc, b, _NN)
+    db_ref[0, 0] = db + _dot(dgc, c, _TN)
+
+
+def _bwd_call(x, dtr, cum, bm, cm, dy, states, chunk, n):
+    bsz, sp, h, p, g, hb = _sizes(x, dtr, bm, n)
+    grid, tokens, rows, group, st = _specs(bsz, sp, h, p, g, n, chunk, hb, rev=True)
+    nc, per = sp // chunk, h // g // hb  # head blocks a group
+    # dB and dC: one partial a head block, laid so that a sum over axis 1 is (B, S, G x N)
+    partial = pl.BlockSpec((1, 1, chunk, n), lambda b, j, c: (b, j % per, nc - 1 - c, j // per))
+    part_shape = jax.ShapeDtypeStruct((bsz, per, sp, g * n), F32)
+    dx, ddt, dcum, db, dc = pl.pallas_call(
+        functools.partial(_bwd_kernel, p=p),
+        grid=grid, in_specs=[tokens, rows, rows, group, group, tokens, st],
+        out_specs=[tokens, rows, rows, partial, partial],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype), jax.ShapeDtypeStruct(dtr.shape, F32),
+                   jax.ShapeDtypeStruct(dtr.shape, F32), part_shape, part_shape],
+        scratch_shapes=[pltpu.VMEM((n, hb * p), F32), pltpu.VMEM((_ROWS, chunk), F32)],
+        compiler_params=fa._compiler_params(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=fa._use_interpret(), name="ssd_bwd",
+    )(x, dtr, cum, bm, cm, dy, states)
+    return dx, ddt, dcum, db.sum(axis=1).astype(bm.dtype), dc.sum(axis=1).astype(cm.dtype)
+
+
+def _forward(x, dt, a, bm, cm, chunk, n, keep_states):
+    h = a.shape[0]
+    hb = _head_block(h * n // bm.shape[2], x.shape[2] // h)
+    dtr, cum = _decay_call(dt, a, chunk, hb)
+    return dtr, cum, _fwd_call(x, dtr, cum, bm, cm, chunk, n, keep_states)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _ssd_core(x, dt, a, bm, cm, chunk, n):
+    """x (B, S, H x P), dt (B, S, H) float32, a (H, 1) float32, B and C
+    (B, S, G x N) in x's dtype, S in whole chunks -> y like x."""
+    return _forward(x, dt, a, bm, cm, chunk, n, keep_states=False)[2][0]
+
+
+def _core_fwd(x, dt, a, bm, cm, chunk, n):
+    dtr, cum, (y, states) = _forward(x, dt, a, bm, cm, chunk, n, keep_states=True)
+    return y, (x, dt, a, dtr, cum, bm, cm, states)
+
+
+def _core_bwd(chunk, n, res, dy):
+    x, dt, a, dtr, cum, bm, cm, states = res
+    dx, ddt, dcum, db, dc = _bwd_call(x, dtr, cum, bm, cm, dy, states, chunk, n)
+    ddt, dla = _decay_bwd_call(ddt, dcum, a, chunk)  # d dt whole, d(dt a)
+    return dx, ddt, jnp.sum(dla * dt, axis=(0, 1)).reshape(a.shape), db, dc
+
+
+_ssd_core.defvjp(_core_fwd, _core_bwd)

@@ -52,7 +52,7 @@ class LayerPlacement(Placement):
     token_axes: Tuple[str, ...] = ()  # the axes of act_spec, flat: the (B·S) token dim
     qkv_pin: bool = False  # tp > 1
     attn_out_pin: bool = False  # zero3 + tp
-    kernel_wrap: bool = False  # flash layers, cp == 1
+    kernel_wrap: bool = False  # flash layers and stacks with state-space layers, cp == 1
     tp_overlap: bool = False  # the plan's tp_overlap, tp > 1, cp == 1
     moe_pin: bool = False  # switch-MoE layers, ep > 1
     token_wrap: bool = False  # dropless top-k MoE layers
@@ -219,7 +219,7 @@ def place_layer(cfg, s: LayerStrategy, mesh: Mesh, axes: MeshAxes):
         token_axes=moe_token_axes(axes, s),
         qkv_pin=s.tp > 1,
         attn_out_pin=s.dp_type == "zero3" and s.tp > 1,
-        kernel_wrap=layer_cfg.attn_impl == "flash" and s.cp == 1,
+        kernel_wrap=(layer_cfg.attn_impl == "flash" or "ssm" in cfg.kinds) and s.cp == 1,
         tp_overlap=bool(s.tp_overlap) and s.tp > 1 and s.cp == 1,
         moe_pin=cfg.moe_experts > 0 and s.ep > 1,
         token_wrap=cfg.moe_dropless,

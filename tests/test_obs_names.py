@@ -30,6 +30,8 @@ KERNEL_NAMES = {
     "fused_norm_ln_fwd": "fused_norm.py", "fused_norm_ln_bwd": "fused_norm.py",
     "moe_gmm": "grouped_matmul.py", "moe_gmm_dlhs": "grouped_matmul.py",
     "moe_tgmm": "grouped_matmul.py",
+    # the fused Mamba-2 scan (PR 34): read through the `scan` scope they run under
+    "ssd_fwd": "ssd.py", "ssd_bwd": "ssd.py", "ssd_decay": "ssd.py", "ssd_decay_bwd": "ssd.py",
 }
 
 
@@ -64,7 +66,7 @@ def test_every_pallas_call_has_a_name_from_the_table(name):
     # and nothing outside the table: a new kernel joins it, with its metric
     assert {n for names in found.values() for n in names} == set(KERNEL_NAMES)
     assert all(n.startswith(("flash_fwd", "flash_bwd", "flash_paged", "fused_norm_",
-                             "moe_gmm", "moe_tgmm"))
+                             "moe_gmm", "moe_tgmm", "ssd_"))
                for n in KERNEL_NAMES)
 
 
@@ -404,6 +406,9 @@ def test_build_runtime_span_counts_the_seams_by_name(traced_run):
     events, _ = traced_run
     (span,) = [e for e in events if e["ph"] == "X" and e["name"] == "build_runtime"]
     assert span["args"]["tp_overlap_seams"] == {"ring": 0, "plain": 0, "batchwise": 0}
+    # and the scan of its state-space layers (PR 34): a stack with none counts none
+    # (9 / 0 in the granite cell: tests/test_ssm.py holds the count to `ops/ssd.scan_path`)
+    assert span["args"]["ssm_scan_path"] == {"fused": 0, "plain": 0}
 
 
 def test_traced_train_logs_the_profile_window(traced_run):

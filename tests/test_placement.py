@@ -22,6 +22,7 @@ DENSE = ModelConfig(vocab_size=128, hidden_size=64, num_layers=4, num_heads=4, f
                     max_seq_len=32, attn_impl="flash")
 SWITCH = DENSE.replace(moe_experts=4)
 DROPLESS = DENSE.replace(moe_experts=4, moe_router="softmax_topk", moe_top_k=2)
+HYBRID = DENSE.replace(attn_impl="xla", layer_kinds=("ssm", "attention", "ssm", "ssm"), ssm_heads=4)
 
 PINS = ("qkv_pin", "attn_out_pin", "kernel_wrap", "tp_overlap", "moe_pin", "token_wrap")
 
@@ -50,6 +51,11 @@ TABLE = [
     # ring cp: the ring layer carries its own shard_maps, so no kernel wrap
     ("cp2_ring", DENSE, LayerStrategy(cp=2, cp_impl="ring"), 4,
      set(), (("x0",), (), ()), P(("x0",), ("x1",), None), False, ("policy", "ring")),
+    # a stack with state-space layers: the fused scan (ops/ssd.py) is a Mosaic call
+    # whatever the attention layers run, so its layers wrap their kernels too
+    ("ssm_stack_xla_attn", HYBRID, LayerStrategy(dp_type="zero3"), 4,
+     {"kernel_wrap"}, (("x0", "x1"), (), ()), P(("x0", "x1"), None, None), False,
+     ("policy", "xla")),
     ("ckpt_full", DENSE, LayerStrategy(ckpt="full"), 4,
      {"kernel_wrap"}, (("x0", "x1"), (), ()), P(("x0", "x1"), None, None), False,
      ("off", "flash")),

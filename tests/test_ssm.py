@@ -238,11 +238,14 @@ def ssd_sequential(x, dt, a, b_mat, c_mat):
     return jnp.moveaxis(y, 0, 1).reshape(bsz, s, h, p).astype(x.dtype)
 
 
-def scan_inputs(seed=0, b=2, s=72, h=4, p=8, g=2, n=16):
+def scan_inputs(seed=0, b=2, s=72, h=4, p=8, g=2, n=16, slow=1.0):
+    """``slow`` scales the decay rates: over chunks of 128 and 256 positions the
+    summed log-decays of a = -1..-4 reach hundreds, where float32 itself
+    resolves a decay to 1e-4 and every order of summation differs by that."""
     k = jax.random.split(jax.random.key(seed), 5)
     x = jax.random.normal(k[0], (b, s, h, p))
     dt = jax.nn.softplus(jax.random.normal(k[1], (b, s, h)) + 1.0)
-    a = -jnp.arange(1, h + 1, dtype=jnp.float32)
+    a = -slow * jnp.arange(1, h + 1, dtype=jnp.float32)
     return x, dt, a, jax.random.normal(k[2], (b, s, g, n)), jax.random.normal(k[3], (b, s, g, n))
 
 
@@ -276,18 +279,166 @@ def test_scan_carries_its_state_over_a_chunk_boundary():
     assert float(jnp.abs(moved[:, :40] - ssd.ssd_scan(x, dt, a, b_mat, c_mat, 8)[:, :40]).max()) == 0.0
 
 
-def test_a_bf16_decay_or_state_fails_the_tolerance():
+@pytest.mark.parametrize("scan,sizes,chunk", [
+    ("plain", {}, 16), ("fused", dict(s=256, p=64, n=128, slow=0.25), 128)])
+def test_a_bf16_decay_or_state_fails_the_tolerance(scan, sizes, chunk):
     """The tolerance has power over what the issue names: a decay exponent or a
-    carried state rounded to bf16 lands far outside it."""
-    x, dt, a, b_mat, c_mat = scan_inputs()
+    carried state rounded to bf16 lands far outside it. Both bodies: the plain
+    one at the small sizes, the fused kernels at sizes inside their envelope."""
+    fn = {"plain": ssd.ssd_scan_plain, "fused": ssd.ssd_scan_fused}[scan]
+    x, dt, a, b_mat, c_mat = scan_inputs(**sizes)
     want = np.asarray(ssd_sequential(x, dt, a, b_mat, c_mat))
-    lossy = ssd.ssd_scan(x, dt.astype(jnp.bfloat16).astype(jnp.float32), a, b_mat, c_mat, 16)
+    close(fn(x, dt, a, b_mat, c_mat, chunk), want, F32_TOL)
+    lossy = fn(x, dt.astype(jnp.bfloat16).astype(jnp.float32), a, b_mat, c_mat, chunk)
     assert np.abs(np.asarray(lossy) - want).max() / np.abs(want).max() > 50 * F32_TOL
     # bf16 compute keeps float32 decays and states: its error is the operands' 8 bits
-    half = ssd.ssd_scan(x.astype(jnp.bfloat16), dt, a, b_mat.astype(jnp.bfloat16),
-                        c_mat.astype(jnp.bfloat16), 16)
+    half = fn(x.astype(jnp.bfloat16), dt, a, b_mat.astype(jnp.bfloat16),
+              c_mat.astype(jnp.bfloat16), chunk)
     assert half.dtype == jnp.bfloat16
     close(half.astype(jnp.float32), want, BF16_TOL)
+
+
+# sizes inside the fused kernels' envelope, small enough for the interpreter:
+# a state carried over chunk boundaries, a sequence the chunk does not divide
+# (padded), two groups (head blocks of 2, two heads a lane tile), one group of
+# four heads, heads of 128 (one a tile), chunks of 128 and 256
+FUSED_CASES = {
+    "3_chunks_2_groups": (dict(s=384, h=4, p=64, g=2, n=128), 128),
+    "padded_300": (dict(s=300, h=4, p=64, g=2, n=128), 128),
+    "chunk_256_1_group": (dict(s=512, h=4, p=64, g=1, n=128), 256),
+    "heads_of_128": (dict(s=256, h=2, p=128, g=1, n=128), 128),
+}
+
+
+def fused_case(name, seed=0):
+    sizes, chunk = FUSED_CASES[name]
+    return scan_inputs(seed=seed, slow=0.05, **sizes), chunk
+
+
+@pytest.fixture
+def on_a_chip(monkeypatch):
+    """`ssd.scan_path` as a chip would answer (it asks `flash_attention`'s
+    switch, the one every kernel of the repo goes by). For tests that ask which
+    body a shape takes; the kernels themselves run here, interpreted, through
+    `ssd.ssd_scan_fused`, and through the dispatch in tests/test_topology_aot.py."""
+    from galvatron_tpu.ops import flash_attention
+
+    monkeypatch.setattr(flash_attention, "_use_interpret", lambda: False)
+
+
+def test_the_fused_cases_lie_inside_the_envelope(on_a_chip):
+    for sizes, chunk in FUSED_CASES.values():
+        assert ssd.scan_path(sizes["h"], sizes["p"], sizes["g"], sizes["n"], chunk,
+                             jnp.float32) == "fused", sizes
+
+
+@pytest.mark.parametrize("case", list(FUSED_CASES))
+def test_fused_scan_equals_the_recurrence_and_the_plain_scan(case):
+    args, chunk = fused_case(case)
+    got = ssd.ssd_scan_fused(*args, chunk)
+    assert got.shape == args[0].shape and got.dtype == jnp.float32
+    close(got, ssd_sequential(*args), F32_TOL)
+    close(got, ssd.ssd_scan_plain(*args, chunk), F32_TOL)
+
+
+@pytest.mark.parametrize("case", list(FUSED_CASES))
+def test_fused_scan_gradients_equal_the_recurrences(case):
+    """x, dt, a, B and C (D: test_the_mixer_through_the_kernels...), plain and
+    under ``jax.checkpoint``, whose replayed forward keeps the same residuals."""
+    args, chunk = fused_case(case, seed=3)
+    w = jax.random.normal(jax.random.key(9), args[0].shape)
+    loss = lambda *t: jnp.sum(ssd.ssd_scan_fused(*t, chunk) * w)  # noqa: E731
+    got = jax.grad(loss, argnums=range(5))(*args)
+    want = jax.grad(lambda *t: jnp.sum(ssd_sequential(*t) * w), argnums=range(5))(*args)
+    for name, g, v in zip("x dt a B C".split(), got, want):
+        try:
+            close(g, v, F32_TOL)
+        except AssertionError as e:
+            raise AssertionError(f"d {name}: {e}") from None
+    again = jax.grad(jax.checkpoint(loss), argnums=range(5))(*args)
+    for g, v in zip(again, got):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(v))
+
+
+def test_fused_scan_in_bf16_stays_within_what_bf16_warrants():
+    """y and every gradient of the bf16 kernels against the float32 recurrence
+    on the same (rounded) operands; no worse than the plain body's by more
+    than a factor of two (d a once was 18 times worse: a cancelling pair of sums
+    taken from differently rounded operands)."""
+    args, chunk = fused_case("chunk_256_1_group", seed=4)
+    half = lambda t: t.astype(jnp.bfloat16)  # noqa: E731
+    lo = (half(args[0]), args[1], args[2], half(args[3]), half(args[4]))
+    ref = tuple(t.astype(jnp.float32) for t in lo)
+    w = jax.random.normal(jax.random.key(9), args[0].shape)
+    want = jax.grad(lambda *t: jnp.sum(ssd_sequential(*t) * w), argnums=range(5))(*ref)
+
+    def errors(fn):
+        got = jax.grad(lambda *t: jnp.sum(fn(*t, chunk).astype(jnp.float32) * w),
+                       argnums=range(5))(*lo)
+        return [float(np.abs(np.asarray(g, np.float64) - np.asarray(v, np.float64)).max()
+                      / np.abs(np.asarray(v)).max()) for g, v in zip(got, want)]
+
+    fused, plain = errors(ssd.ssd_scan_fused), errors(ssd.ssd_scan_plain)
+    assert max(fused) < BF16_TOL and all(f < 2 * p_ + 1e-3 for f, p_ in zip(fused, plain)), (
+        fused, plain)
+    close(ssd.ssd_scan_fused(*lo, chunk).astype(jnp.float32), ssd_sequential(*ref), BF16_TOL)
+
+
+def test_outside_the_envelope_the_plain_scan_runs_bit_for_bit(on_a_chip):
+    """Heads of 8 and states of 16 (the small configurations of this file), a
+    chunk of 72, float16: `scan_path` says plain even where a chip is there, and
+    `ssd_scan` is then `ssd_scan_plain` to the bit."""
+    assert ssd.scan_path(4, 8, 2, 16, 16, jnp.float32) == "plain"
+    assert ssd.scan_path(64, 64, 1, 128, 72, jnp.bfloat16) == "plain"  # chunk
+    assert ssd.scan_path(64, 64, 1, 64, 256, jnp.bfloat16) == "plain"  # state
+    assert ssd.scan_path(64, 32, 1, 128, 256, jnp.bfloat16) == "plain"  # head size
+    assert ssd.scan_path(6, 64, 2, 128, 256, jnp.bfloat16) == "plain"  # 3 heads a group
+    assert ssd.scan_path(64, 64, 1, 128, 256, jnp.float16) == "plain"  # dtype
+    assert ssd.scan_path(64, 64, 1, 128, 256, jnp.bfloat16) == "fused"
+    args = scan_inputs()
+    np.testing.assert_array_equal(np.asarray(ssd.ssd_scan(*args, 16)),
+                                  np.asarray(ssd.ssd_scan_plain(*args, 16)))
+
+
+def test_scan_path_counts_the_layers_of_a_configuration(monkeypatch):
+    """What the trainer writes as ``ssm_scan_path`` (fingerprint and the
+    ``build_runtime`` span): 9 / 0 for one granite period on a TPU, every
+    state-space layer plain on the CPU and for the small configurations, 0 / 0
+    for a stack without such layers."""
+    granite = PRESETS["granite-4.0-h-micro"].replace(num_layers=10, max_seq_len=8192,
+                                                     dtype=jnp.bfloat16)
+    from galvatron_tpu.ops import flash_attention
+
+    assert ssd.scan_path_counts(granite) == {"fused": 0, "plain": 9}  # no chip here
+    monkeypatch.setattr(flash_attention, "_use_interpret", lambda: False)
+    assert ssd.scan_path_counts(granite) == {"fused": 9, "plain": 0}
+    assert ssd.scan_path_counts(small_cfg()) == {"fused": 0, "plain": 6}
+    assert ssd.scan_path_counts(PRESETS["llama-7b"]) == {"fused": 0, "plain": 0}
+
+
+def test_the_mixer_through_the_kernels_equals_the_mixer_through_the_plain_scan(monkeypatch):
+    """`ssm_block` at sizes inside the envelope: output and the gradient of every
+    parameter (D's skip and the conv in front included) agree between the two
+    bodies, in float32."""
+    cfg = small_cfg(num_layers=1, ssm_heads=4, ssm_head_dim=64, ssm_state=128, ssm_chunk=128,
+                    max_seq_len=256)
+    p = ssm.init_ssm_params(jax.random.key(0), cfg)
+    p = {k: v + 0.3 * jax.random.normal(jax.random.key(i), v.shape) if v.ndim == 1 else v
+         for i, (k, v) in enumerate(p.items())}
+    x = jax.random.normal(jax.random.key(7), (2, 256, cfg.hidden_size))
+    w = jax.random.normal(jax.random.key(8), x.shape)
+    run = jax.value_and_grad(lambda x_, p_: jnp.sum(ssm.ssm_block(x_, p_, cfg) * w), argnums=(0, 1))
+    plain = run(x, p)
+    monkeypatch.setattr(ssm, "ssd_scan", ssd.ssd_scan_fused)
+    fused = run(x, p)
+    assert float(fused[0]) == pytest.approx(float(plain[0]), rel=F32_TOL)
+    for (path, g), v in zip(jax.tree_util.tree_leaves_with_path(fused[1]),
+                            jax.tree.leaves(plain[1])):
+        try:
+            close(g, v, GRAD_TOL)
+        except AssertionError as e:
+            raise AssertionError(f"{jax.tree_util.keystr(path)}: {e}") from None
+    assert float(jnp.abs(fused[1][1]["D"]).max()) > 0
 
 
 def test_causal_conv_is_the_published_conv1d_and_leaks_nothing_from_the_future():

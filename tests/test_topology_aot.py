@@ -55,6 +55,8 @@ def real_mosaic(monkeypatch):
     from galvatron_tpu.ops import flash_attention, fused_norm, grouped_matmul
     from galvatron_tpu.parallel import ring
 
+    # (ops/ssd.py asks flash_attention's switch, for its kernels and for its
+    # choice between them and the plain scan: no switch of its own)
     for mod in (flash_attention, fused_norm, grouped_matmul, ring):
         monkeypatch.setattr(mod, "_use_interpret", lambda: False)
     with persistent_cache_off():
@@ -325,6 +327,11 @@ def test_granite_mixer_compiles_at_published_widths(one_chip, real_mosaic):
     chunks of 256 over 8192 tokens. A scan it refuses, or score blocks (64 x 32 x
     256 x 256 a sequence) that outgrow what a layer may take beside 12 GiB of
     state, shows here; the five scopes reach the compiled ENTRY under ``ssm``.
+    The scan is the fused kernels (PR 34): ``ssd_fwd`` once for the replayed
+    forward, ``ssd_bwd`` once, both under ``ssm/scan`` with the two small
+    ``ssd_decay`` kernels, none borrowing the ``flash_`` prefix that the
+    ``flash_*_ms_per_step`` readers go by; the temporaries are the 67 MB of
+    entering states and the layer's own activations, no score block.
     (The ten-layer step compiles in two minutes, 1.3 of them the 8192-key
     ``flash_bwd_blocked``: a chip run's job, PERF.md §6.)"""
     from galvatron_tpu.models import ssm
@@ -343,11 +350,41 @@ def test_granite_mixer_compiles_at_published_widths(one_chip, real_mosaic):
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, p).compile()
     temp = compiled.memory_analysis().temp_size_in_bytes
-    assert temp < 2 * 2**30, f"{temp / 2**30:.2f} GiB"
-    ops = [op for _, op in _entry_work(compiled.as_text())]
+    assert temp < 1 * 2**30, f"{temp / 2**30:.2f} GiB"
+    rows = _entry_work(compiled.as_text())
+    ops = [op for _, op in rows]
     for scope in ("in_proj", "conv", "scan", "gate_norm", "out_proj"):
         mine = [op for op in ops if f"/ssm/{scope}/" in op]
         assert mine and any("transpose(" in op for op in mine), scope
+    kernels = sorted((n.split(".")[0], op) for n, op in rows if n.startswith("ssd_"))
+    assert [n for n, _ in kernels] == ["ssd_bwd", "ssd_decay", "ssd_decay_bwd", "ssd_fwd"], kernels
+    assert all("/ssm/scan/" in op for _, op in kernels), kernels
+    assert not [n for n, _ in rows if n.startswith("flash_")]
+
+
+@pytest.mark.parametrize("sizes", [
+    dict(h=4, p=64, g=2, n=128, chunk=128, dtype=jnp.bfloat16),  # head blocks of 2, two groups
+    dict(h=8, p=128, g=2, n=256, chunk=256, dtype=jnp.float32),  # a head a lane tile, state 256
+], ids=["p64_g2_bf16", "p128_n256_f32"])
+def test_fused_ssd_kernels_compile_across_their_envelope(sizes, one_chip, real_mosaic):
+    """Corners of `ops/ssd.scan_path`'s envelope the granite sizes do not
+    touch: what it calls fused, Mosaic lowers, forward and backward."""
+    from galvatron_tpu.ops import ssd
+
+    h, p, g, n, chunk, dtype = (sizes[k] for k in ("h", "p", "g", "n", "chunk", "dtype"))
+    assert ssd.scan_path(h, p, g, n, chunk, dtype) == "fused"
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    args = (sd((2, 500, h, p), dtype), sd((2, 500, h), jnp.float32), sd((h,), jnp.float32),
+            sd((2, 500, g, n), dtype), sd((2, 500, g, n), dtype))
+    text = _kernel_text(
+        jax.grad(lambda *t: jnp.sum(ssd.ssd_scan(*t, chunk).astype(jnp.float32)), argnums=range(5)),
+        *args)
+    import re
+
+    # (bare autodiff wraps the names: jvp_ssd_fwd_, transpose_jvp_ssd_bwd__)
+    found = [re.search(r"ssd_(?:decay_bwd|decay|fwd|bwd)", n) for n, _ in _entry_work(text)]
+    assert sorted(m.group(0) for m in found if m) == [
+        "ssd_bwd", "ssd_decay", "ssd_decay_bwd", "ssd_fwd"], found
 
 
 def test_granite_attention_takes_the_blocked_gqa_kernel_at_8192(one_chip, real_mosaic):
@@ -375,6 +412,27 @@ def test_olmoe_block_partitions_on_four_chips(topo, real_mosaic):
     compiled, _ = _compile(cfg, hp, topo.devices, bsz=8, seq=512)
     names = [n for n, _ in _entry_work(compiled.as_text())]
     assert sum(n.startswith(("moe_gmm", "moe_tgmm")) for n in names) == 9, names[:40]
+    assert not any(n.startswith("shard_map") for n in names)
+
+
+def test_granite_layers_partition_on_four_chips(topo, real_mosaic):
+    """A Mamba-2 layer (granite's head, state and chunk sizes; 16 heads), data-parallel
+    over four chips with ZeRO-3 (what `ssm_annotations` shards for, and `build_runtime` admits):
+    GSPMD cannot partition a Mosaic call, so `ssm_block` hands the fused scan to
+    `place.shard_kernel` and each device runs it on its own batch rows. Forward
+    and backward lower and compile; the kernels keep their names."""
+    from galvatron_tpu.core.strategy import HybridParallelConfig
+    from galvatron_tpu.models.modeling import PRESETS
+
+    cfg = PRESETS["granite-4.0-h-micro"].replace(
+        num_layers=1, attn_impl="flash", vocab_size=1024, max_seq_len=512, hidden_size=512,
+        num_heads=8, num_kv_heads=2, ffn_dim=1024, ssm_heads=16)
+    assert cfg.kinds == ("ssm",)
+    hp = HybridParallelConfig.uniform(1, dp_type="zero3", mixed_precision="bf16")
+    compiled, _ = _compile(cfg, hp, topo.devices, bsz=8, seq=512)
+    names = [n.split(".")[0] for n, _ in _entry_work(compiled.as_text())]
+    for kernel in ("ssd_fwd", "ssd_bwd", "ssd_decay", "ssd_decay_bwd"):
+        assert names.count(kernel) == 1, (kernel, [n for n in names if n.startswith("ssd_")])
     assert not any(n.startswith("shard_map") for n in names)
 
 
