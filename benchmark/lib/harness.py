@@ -1,4 +1,5 @@
-"""The harness: one cell, one process, the product's own train path.
+"""The harness: one cell, one process, the product's own train path (a serving
+cell's runner is ``lib/serve.py``; ``run`` picks by the traffic file's ``kind``).
 
 ``run_cell`` is a function of the files under a benchmark root (the directory
 that holds ``BENCHMARK.json``), so the tests drive it at a tiny size on the
@@ -291,6 +292,47 @@ def peak_bytes(stats: Sequence[Dict[str, int]]) -> int:
                 for st in stats), default=0)
 
 
+def collect_per_layer(root: str, name: str, ctx: Dict[str, Any], result: Dict[str, Any]) -> None:
+    """Every per-layer metric ``BENCHMARK.json`` declares for cell ``name``,
+    each read by its own module under ``benchmark/metrics``; a reader that
+    finds nothing to read returns None and its metric is left out."""
+    manifest = load_manifest(root)
+    end_to_end = {m["name"]: m for m in manifest["end_to_end"]}
+    declared = {m["name"]: m for m in manifest["per_layer"]}
+    for mod in discover_metrics(root):
+        entry, moved = declared.get(mod.NAME), end_to_end.get(mod.MOVES)
+        # a metric without a list of cells is read in every cell that reports
+        # the end-to-end metric it moves
+        if entry is None or moved is None or not all(
+                name in e.get("workloads", [name]) for e in (entry, moved)):
+            continue
+        value = mod.compute(ctx)
+        if value is not None:
+            result["metrics"][mod.NAME] = {"value": float(value), "unit": mod.UNIT}
+
+
+def device_breakdown(ctx: Dict[str, Any], result: Dict[str, Any]) -> None:
+    """``busy_s`` / ``window_s`` of the traced window (mean over the devices
+    used) and the breakdown: the device operations that took most time, and
+    the longest idle gaps by the host span open at the time."""
+    import numpy as np
+
+    used = [ops for ops in ((ctx["trace"] or {}).get("devices") or {}).values() if ops]
+    if not used:
+        return
+    device = result["device"]
+    device["busy_s"] = float(np.mean([xplane.busy_ns(o) for o in used])) / 1e9
+    device["window_s"] = float(np.mean([b - a for a, b in map(xplane.window_of, used)])) / 1e9
+    ops0 = xplane.first_device(ctx["trace"])
+    t0 = ctx["trace"]["start_unix_ns"] or 0
+    host = [((s["start"] * 1e9 - t0), (s["end"] * 1e9 - t0), s["name"])
+            for s in ctx["spans"] if s["name"] != "step"]
+    result["breakdown"] = {
+        "device_ops": [[k, v] for k, v in xplane.top_ops(ops0)],
+        "idle_gaps": [[k, v] for k, v in xplane.attribute_gaps(ops0, host)],
+    }
+
+
 # ---------------------------------------------------------------------------
 # one run of one cell
 # ---------------------------------------------------------------------------
@@ -305,7 +347,6 @@ def run_cell(root: str, name: str, *, seed: int, seconds: float, trace: bool,
     ``min_steps`` keeps the window long enough for the loss at step 20 to
     exist and for the profiled steps to be steady ones."""
     import jax
-    import numpy as np
 
     from galvatron_tpu.aot.cache import enable_persistent_cache, resolve_compile_cache_dir
     from galvatron_tpu.obs import tracing as program_tracing
@@ -423,26 +464,17 @@ def run_cell(root: str, name: str, *, seed: int, seconds: float, trace: bool,
         "plan": plan["doc"],
         "search_s": plan["search_s"], "peaks": peaks_row, "say": say,
     }
-    manifest = load_manifest(root)
-    reported = {m["name"] for m in manifest["end_to_end"]}
-    declared = {m["name"]: m for m in manifest["per_layer"]}
-    for mod in discover_metrics(root):
-        entry = declared.get(mod.NAME)
-        if entry is None or name not in entry.get("workloads", [name]) or mod.MOVES not in reported:
-            continue
-        value = mod.compute(ctx)
-        if value is not None:
-            result["metrics"][mod.NAME] = {"value": float(value), "unit": mod.UNIT}
-    used = [ops for ops in ((ctx["trace"] or {}).get("devices") or {}).values() if ops]
-    if used:
-        device["busy_s"] = float(np.mean([xplane.busy_ns(o) for o in used])) / 1e9
-        device["window_s"] = float(np.mean([b - a for a, b in map(xplane.window_of, used)])) / 1e9
-        ops0 = xplane.first_device(ctx["trace"])
-        t0 = ctx["trace"]["start_unix_ns"] or 0
-        host = [((s["start"] * 1e9 - t0), (s["end"] * 1e9 - t0), s["name"])
-                for s in ctx["spans"] if s["name"] != "step"]
-        result["breakdown"] = {
-            "device_ops": [[k, v] for k, v in xplane.top_ops(ops0)],
-            "idle_gaps": [[k, v] for k, v in xplane.attribute_gaps(ops0, host)],
-        }
+    collect_per_layer(root, name, ctx, result)
+    device_breakdown(ctx, result)
     return result
+
+
+def run(root: str, name: str, **kw) -> Dict[str, Any]:
+    """One run of cell ``name`` by the runner its traffic file's ``kind`` names:
+    ``"serve"`` is ``lib/serve.run_serve_cell``, absent is ``run_cell``."""
+    _, _, traffic = load_cell(root, name)
+    if traffic.get("kind", "train") == "serve":
+        from benchmark.lib import serve
+
+        return serve.run_serve_cell(root, name, **kw)
+    return run_cell(root, name, **kw)

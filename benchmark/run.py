@@ -61,9 +61,9 @@ def main(argv=None) -> int:
 
     out_dir = args.out or tempfile.mkdtemp(prefix="galvatron_bench_")
     try:
-        result = harness.run_cell(ROOT, args.workload, seed=args.seed, seconds=args.seconds,
-                                  trace=bool(args.trace), out_dir=out_dir, t_start=T_START,
-                                  peaks_row=peaks[d0.device_kind])
+        result = harness.run(ROOT, args.workload, seed=args.seed, seconds=args.seconds,
+                             trace=bool(args.trace), out_dir=out_dir, t_start=T_START,
+                             peaks_row=peaks[d0.device_kind])
     finally:
         if not args.out:
             shutil.rmtree(out_dir, ignore_errors=True)

@@ -74,9 +74,14 @@ def tiny_root(tmp_path):
     for tname, traffic in (("tiny", TINY_TRAFFIC), ("tiny_searched", SEARCHED_TRAFFIC)):
         with open(os.path.join(root, f"benchmark/traffic/{tname}.json"), "w") as f:
             json.dump(traffic, f)
-    for entry in manifest["per_layer"]:
-        if "workloads" in entry and entry["name"].startswith("search"):
-            entry["workloads"].append("tiny-opt_searched")
+    # a new training cell joins the lists a cell like it is in (throughput names
+    # its cells since serving joined), and the searched cell the search readers' too
+    for entry in manifest["end_to_end"] + manifest["per_layer"]:
+        listed = entry.get("workloads", [])
+        if "baichuan-7b_s512" in listed:
+            listed += [f"{cname}_tiny" for cname in TINY_CONFIGS] + ["tiny-opt_searched"]
+        elif entry["name"].startswith("search"):
+            listed.append("tiny-opt_searched")
     with open(os.path.join(root, "benchmark/metrics/steps_in_window.py"), "w") as f:
         f.write(EXTRA_METRIC)
     manifest["per_layer"].append({

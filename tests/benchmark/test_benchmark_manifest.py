@@ -91,10 +91,40 @@ def test_workloads(manifest):
         assert NAME.match(w["name"]) and NAME.match(w["traffic"]) and w["config"] in configs
         assert w["chips"] in (1, 4) and _line(w["why"])
         cell, config, traffic = harness.load_cell(REPO, w["name"])
+        assert traffic.get("kind", "train") in ("train", "serve") and _line(traffic["why"], 2000)
+        if traffic.get("kind") == "serve":
+            _check_serve_traffic(traffic, config)
+            continue
         assert traffic["global_batch"] % w["chips"] == 0
         assert traffic["seq_len"] <= config["max_position_embeddings"]
         assert traffic["corpus"]["tokens"] > 4 * traffic["global_batch"] * (traffic["seq_len"] + 1)
     assert sum(w["chips"] == 4 for w in cells) <= max(1, len(cells) // 4)
+
+
+def _check_serve_traffic(traffic, config):
+    """A serving mix is parameters only, and what it asks of the engine fits
+    the configuration: the published positions, a rate, a window rule the
+    runner knows, limits for what ``correct`` compares."""
+    from benchmark.lib import traffic as traffic_lib
+
+    assert set(traffic) == {"kind", "arrivals", "lengths", "sampling", "corpus", "serve_flags",
+                            "window", "correct", "why"}
+    shapes = traffic_lib.grid(traffic)
+    assert len(shapes) == traffic["lengths"]["grid"]
+    assert max(s["prompt_len"] + s["output_len"] for s in shapes) <= traffic["lengths"]["max_total"]
+    assert traffic["lengths"]["max_total"] < config["max_position_embeddings"]
+    assert traffic["arrivals"]["rate_rps"] > 0 and traffic["arrivals"]["burst_at_start"] >= 0
+    assert traffic["window"]["opens"] in ("all_slots_used", "traffic_start")
+    limits = traffic["correct"]
+    assert set(limits) == {"requests", "capture_every", "rows_kept", "logits_kl_max"}
+    assert limits["logits_kl_max"] > 0 and limits["requests"] >= 1 <= limits["capture_every"]
+    # room for the rows of the longest answer at the least
+    assert limits["rows_kept"] >= max(s["output_len"] for s in shapes)
+    flags = traffic["serve_flags"]
+    assert len(flags) % 2 == 0 and all(f.startswith("--") for f in flags[::2])
+    # the slot backend as it stands: no paged pool, no quantisation, no speculation
+    assert not {"--kv_num_blocks", "--serve_quant", "--spec_decode_k"} & set(flags)
+    assert {"--num_slots", "--prefill_chunk"} <= set(flags)
 
 
 def test_metrics(manifest):
@@ -107,7 +137,24 @@ def test_metrics(manifest):
         assert set(m) - {"workloads"} == {"name", "unit", "better", "bound", "source"}
         assert m["source"] in ("host_clock", "device_trace")
         assert 0.01 <= m["bound"] <= 0.1
-    assert {"setup_s", "tokens_per_s_per_chip"} <= {m["name"] for m in e2e}
+    assert {"setup_s", "tokens_per_s_per_chip", "serve_tokens_per_s_per_chip"} <= {
+        m["name"] for m in e2e}
+    by_name = {m["name"]: m for m in e2e}
+    # every cell reports set-up and one more end-to-end metric; a per-layer metric
+    # lists only cells that report the metric it moves
+    assert "workloads" not in by_name["setup_s"]
+    for cell in cells:
+        assert sum(cell in m.get("workloads", cells) for m in e2e) >= 2, cell
+    for m in per:
+        moved = by_name[m["moves"]]
+        assert set(m.get("workloads", [])) <= set(moved.get("workloads", cells)), m["name"]
+    # the serving cells are those whose traffic file says so, whatever their names;
+    # a serving reader names no cell: it is read wherever its end-to-end metric is
+    serving = {w["name"] for w in manifest["workloads"]
+               if harness.load_cell(REPO, w["name"])[2].get("kind") == "serve"}
+    assert serving and serving == set(by_name["serve_tokens_per_s_per_chip"]["workloads"])
+    assert not serving & set(by_name["tokens_per_s_per_chip"]["workloads"])
+    assert not any("workloads" in m for m in per if m["moves"] == "serve_tokens_per_s_per_chip")
     for m in e2e + per:
         assert NAME.match(m["name"]) and UNIT.match(m["unit"]), m
         assert m["better"] in ("lower", "higher") and m["source"] in SOURCES

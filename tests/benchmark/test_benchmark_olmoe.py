@@ -236,8 +236,9 @@ def test_whole_cell_tiny(tmp_path):
                                 "file": "benchmark/configs/tiny-olmoe.json", "why": "test"})
     manifest["workloads"].append({"name": "tiny-olmoe_tiny", "config": "tiny-olmoe",
                                   "traffic": "tiny", "chips": 1, "why": "test"})
-    for entry in manifest["per_layer"]:
-        if entry["name"].startswith("moe_"):
+    # the lists a one-chip training cell is in (throughput names its cells), and its own
+    for entry in manifest["end_to_end"] + manifest["per_layer"]:
+        if entry["name"].startswith("moe_") or "baichuan-7b_s512" in entry.get("workloads", []):
             entry["workloads"].append("tiny-olmoe_tiny")
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(manifest, f)
