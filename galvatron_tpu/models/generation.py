@@ -43,147 +43,127 @@ def init_kv_cache(cfg: ModelConfig, batch_size: int, max_len: int) -> KVCache:
     return KVCache(jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))
 
 
-def _cached_attention(q, k_cache, v_cache, q_offset, cfg: ModelConfig, alibi=None):
-    """q: (B, s, nh, hd); caches: (B, Smax, kvh, hd). Delegates to
-    modeling.attention_xla (same mask/softmax core); only the ALiBi bias needs
-    the absolute-position rewrite here."""
-    s, smax = q.shape[1], k_cache.shape[1]
-    bias = None
-    if alibi is not None:
-        q_pos = q_offset + jnp.arange(s)
-        k_pos = jnp.arange(smax)
-        rel = k_pos[None, :] - q_pos[:, None]  # (s, Smax)
-        bias = (alibi[:, None, None] * rel[None]).astype(jnp.float32)[None]
-    return modeling.attention_xla(q, k_cache, v_cache, cfg, bias=bias, q_offset=q_offset)
+def _positions(offsets, s: int):
+    """Absolute positions of ``s`` new tokens: (s,) for a scalar offset (the
+    same for every row), (B, s) for a (B,) one."""
+    return jnp.asarray(offsets)[..., None] + jnp.arange(s)
 
 
-def _layer_with_cache(x, p, cfg: ModelConfig, k_cache, v_cache, offset, cos_sin, alibi):
-    """decoder_layer variant that reads/writes the KV cache at ``offset``.
-    Returns (x_out, k_cache, v_cache)."""
-    b, s, h = x.shape
-    hd = cfg.head_dim
-    xa = modeling.norm(x, p["attn_norm"], cfg)
-    pa = p["attn"]
-    q, k, v = modeling.project_qkv_heads(xa, pa, cfg)
-    if cfg.pos_embed == "rope":
-        cos, sin = cos_sin
-        q = modeling.apply_rope(q, cos, sin)
-        k = modeling.apply_rope(k, cos, sin)
-    k_cache = jax.lax.dynamic_update_slice(k_cache, k.astype(k_cache.dtype), (0, offset, 0, 0))
-    v_cache = jax.lax.dynamic_update_slice(v_cache, v.astype(v_cache.dtype), (0, offset, 0, 0))
-    o = _cached_attention(q, k_cache, v_cache, offset, cfg, alibi=alibi)
-    x = x + modeling.attn_output(o, pa, cfg, x.dtype)
-    x = x + modeling.mlp_block(
-        modeling.norm(x, p["mlp_norm"], cfg), p["mlp"], cfg, train=False
-    )
-    return x, k_cache, v_cache
+def _rope_at(cfg: ModelConfig, smax: int, offsets, s: int):
+    """(cos, sin) for the ``s`` tokens at ``offsets``: full-length tables
+    indexed dynamically, so ``offsets`` can be traced. A scalar offset slices
+    (s, hd/2) for every row; a (B,) offset gathers per-row (B, s, hd/2)."""
+    if cfg.pos_embed != "rope":
+        return None
+    cos_all, sin_all = modeling.rope_tables(cfg, smax)
+    if jnp.ndim(offsets) == 0:
+        return (jax.lax.dynamic_slice_in_dim(cos_all, offsets, s, axis=0),
+                jax.lax.dynamic_slice_in_dim(sin_all, offsets, s, axis=0))
+    pos = _positions(offsets, s)
+    return cos_all[pos], sin_all[pos]
 
 
-def forward_with_cache(params: Params, tokens, cfg: ModelConfig, cache: KVCache, offset):
-    """Run ``tokens`` (B, s) through the model at absolute position ``offset``,
-    updating the cache. Returns (logits, new_cache). ``offset`` may be traced."""
+def _alibi_bias(cfg: ModelConfig, smax: int, offsets, s: int):
+    """ALiBi over absolute positions: (1, nh, s, Smax) for a scalar offset,
+    (B, nh, s, Smax) per row for a (B,) one; None for any other embedding."""
+    if cfg.pos_embed != "alibi":
+        return None
+    slopes = jnp.asarray(modeling.alibi_slopes(cfg.num_heads))
+    rel = jnp.arange(smax) - _positions(offsets, s)[..., None]  # ([B,] s, Smax)
+    bias = (slopes[:, None, None] * rel[..., None, :, :]).astype(jnp.float32)
+    return bias.reshape((-1,) + bias.shape[-3:])
+
+
+def _window_starts(offsets, slot, b: int):
+    """Where the new tokens land in the cache, as (row, position) pairs: one
+    pair for a scalar offset (rows [row, row + B) all at that position),
+    one a row for a (B,) offset."""
+    if jnp.ndim(offsets) == 0:
+        return [(0 if slot is None else slot, offsets)]
+    return [(r, offsets[r]) for r in range(b)]
+
+
+def _write_layer(stacked, layer: int, new, starts):
+    """Write layer ``layer``'s new keys or values ``new`` (B, s, kvh, hd) into
+    the stacked cache (L, Bc, Smax, kvh, hd) at ``starts``
+    (``_window_starts``), in place.
+
+    Only ``lax.dynamic_update_slice`` on the stacked array itself at a static
+    layer index keeps a donated cache where it is: slicing the layer's slab
+    out and re-stacking copies the slab both ways (and into another layout),
+    a vmapped update becomes a pass over the whole slab, and
+    ``stacked.at[layer, rows, pos].set`` copies the WHOLE cache (compiled for
+    a v5e at opt-1.3b widths, 8 slots x 2048: 5.4 GiB of temporaries, and 6.0
+    with the scatter, against 0.2). So rows with their own offsets take one
+    update each: ``B`` is static and small."""
+    new = new.astype(stacked.dtype)[None]  # (1, B, s, kvh, hd)
+    if len(starts) == 1:
+        (row, pos), = starts
+        return jax.lax.dynamic_update_slice(stacked, new, (layer, row, pos, 0, 0))
+    for row, pos in starts:
+        stacked = jax.lax.dynamic_update_slice(
+            stacked, jax.lax.slice_in_dim(new, row, row + 1, axis=1),
+            (layer, row, pos, 0, 0))
+    return stacked
+
+
+def _read_layer(stacked, layer: int, slot):
+    """Layer ``layer``'s keys or values for attention: every row of the cache,
+    or the one row ``slot`` (traced) as a batch of one."""
+    if slot is None:
+        return stacked[layer]
+    return jax.lax.dynamic_slice(
+        stacked, (layer, slot, 0, 0, 0), (1, 1) + stacked.shape[2:])[0]
+
+
+def forward_with_cache(params: Params, tokens, cfg: ModelConfig, cache: KVCache,
+                       offsets, slot=None):
+    """Run ``tokens`` (B, s) through the model at absolute positions
+    ``offsets``, writing the new keys and values into the cache and attending
+    over it. Returns (logits, new_cache). ``offsets`` may be traced:
+
+    - a scalar: every row at the same position (``generate``'s lockstep scan);
+      with ``slot`` (a traced scalar) ``tokens`` is (1, s) and lands in row
+      ``slot`` of a cache of many rows: one prefill chunk of one request;
+    - a (B,) vector: row ``b`` at ``offsets[b]``, the forward the
+      continuous-batching engine runs once per decode iteration over all slots
+      (and over its (B, 1+k) verify window). Rows are independent requests at
+      arbitrary depths; a row holding no request carries (0, 0): its write
+      lands at position 0 of its own row and is overwritten by the next
+      prefill before any query can attend it, since causal masking keeps
+      positions past a row's own offset invisible.
+
+    The stacked cache (L, B, Smax, kvh, hd) is carried whole through the
+    layers and written in place (``_write_layer``), write-then-attend; a
+    window that would cross the row's end is CLAMPED back by the update, so
+    callers keep ``offset + s <= Smax``."""
     s = tokens.shape[1]
-    if cfg.pos_embed == "rope":
-        # full-length tables indexed dynamically so offset can be traced
-        cos_all, sin_all = modeling.rope_tables(cfg, cache.k.shape[2])
-        cos = jax.lax.dynamic_slice_in_dim(cos_all, offset, s, axis=0)
-        sin = jax.lax.dynamic_slice_in_dim(sin_all, offset, s, axis=0)
-        cos_sin = (cos, sin)
-    else:
-        cos_sin = None
-    alibi = (
-        jnp.asarray(modeling.alibi_slopes(cfg.num_heads)) if cfg.pos_embed == "alibi" else None
-    )
-    x = params["embed"]["tok"].astype(cfg.dtype)[tokens]
-    if cfg.pos_embed == "learned":
-        pos = offset + jnp.arange(s)
-        x = x + params["embed"]["pos"].astype(cfg.dtype)[pos][None]
-    new_k, new_v = [], []
-    for i, lp in enumerate(params["layers"]):
-        x, ki, vi = _layer_with_cache(
-            x, lp, cfg, cache.k[i], cache.v[i], offset, cos_sin, alibi
-        )
-        new_k.append(ki)
-        new_v.append(vi)
-    x = modeling.norm(x, params["final_norm"], cfg)
-    logits = modeling.lm_head(x, params, cfg)
-    return logits, KVCache(jnp.stack(new_k), jnp.stack(new_v))
-
-
-# ---------------------------------------------------------------------------
-# Slot-wise forward: every batch row at its own absolute position
-# (continuous-batching serving — each row is a different request)
-# ---------------------------------------------------------------------------
-
-
-def _layer_with_cache_slots(x, p, cfg: ModelConfig, k_cache, v_cache, offsets,
-                            cos_sin, alibi):
-    """``_layer_with_cache`` variant where ``offsets`` is (B,): row ``b``
-    reads/writes its cache at its own position. Returns (x, k_cache, v_cache)."""
-    b, s, h = x.shape
-    xa = modeling.norm(x, p["attn_norm"], cfg)
-    pa = p["attn"]
-    q, k, v = modeling.project_qkv_heads(xa, pa, cfg)
-    if cfg.pos_embed == "rope":
-        cos, sin = cos_sin  # (B, s, hd/2) per-row tables
-        q = modeling.apply_rope(q, cos, sin)
-        k = modeling.apply_rope(k, cos, sin)
-    row_update = jax.vmap(
-        lambda c, u, o: jax.lax.dynamic_update_slice(c, u, (o, 0, 0))
-    )
-    k_cache = row_update(k_cache, k.astype(k_cache.dtype), offsets)
-    v_cache = row_update(v_cache, v.astype(v_cache.dtype), offsets)
-    bias = None
-    if alibi is not None:
-        q_pos = offsets[:, None] + jnp.arange(s)[None]  # (B, s)
-        k_pos = jnp.arange(k_cache.shape[1])
-        rel = k_pos[None, None, :] - q_pos[:, :, None]  # (B, s, Smax)
-        bias = (alibi[None, :, None, None] * rel[:, None]).astype(jnp.float32)
-    o = modeling.attention_xla(q, k_cache, v_cache, cfg, bias=bias, q_offset=offsets)
-    x = x + modeling.attn_output(o, pa, cfg, x.dtype)
-    x = x + modeling.mlp_block(
-        modeling.norm(x, p["mlp_norm"], cfg), p["mlp"], cfg, train=False
-    )
-    return x, k_cache, v_cache
-
-
-def forward_with_cache_slots(params: Params, tokens, cfg: ModelConfig,
-                             cache: KVCache, offsets):
-    """Run ``tokens`` (B, s) through the model with PER-ROW absolute positions
-    ``offsets`` (B,), updating row ``b`` of the cache at ``offsets[b]``.
-    Returns (logits, new_cache). ``offsets`` may be traced.
-
-    This is the forward the continuous-batching engine runs once per decode
-    iteration over all slots: rows are independent requests at arbitrary
-    depths into their sequences; rows holding no request are simply masked by
-    the caller (their writes land at their own row's offset and are
-    overwritten by the next prefill before ever becoming visible — causal
-    masking keeps positions > a row's own offset invisible)."""
-    b, s = tokens.shape
     smax = cache.k.shape[2]
-    if cfg.pos_embed == "rope":
-        cos_all, sin_all = modeling.rope_tables(cfg, smax)
-        pos = offsets[:, None] + jnp.arange(s)[None]  # (B, s)
-        cos_sin = (cos_all[pos], sin_all[pos])
-    else:
-        cos_sin = None
-    alibi = (
-        jnp.asarray(modeling.alibi_slopes(cfg.num_heads)) if cfg.pos_embed == "alibi" else None
-    )
+    cos_sin = _rope_at(cfg, smax, offsets, s)
+    bias = _alibi_bias(cfg, smax, offsets, s)
     x = params["embed"]["tok"].astype(cfg.dtype)[tokens]
     if cfg.pos_embed == "learned":
-        pos = offsets[:, None] + jnp.arange(s)[None]
-        x = x + params["embed"]["pos"].astype(cfg.dtype)[pos]
-    new_k, new_v = [], []
-    for i, lp in enumerate(params["layers"]):
-        x, ki, vi = _layer_with_cache_slots(
-            x, lp, cfg, cache.k[i], cache.v[i], offsets, cos_sin, alibi
+        x = x + params["embed"]["pos"].astype(cfg.dtype)[_positions(offsets, s)]
+    starts = _window_starts(offsets, slot, tokens.shape[0])
+    ks, vs = cache
+    for i, p in enumerate(params["layers"]):
+        pa = p["attn"]
+        q, k, v = modeling.project_qkv_heads(modeling.norm(x, p["attn_norm"], cfg), pa, cfg)
+        if cos_sin is not None:
+            q = modeling.apply_rope(q, *cos_sin)
+            k = modeling.apply_rope(k, *cos_sin)
+        ks = _write_layer(ks, i, k, starts)
+        vs = _write_layer(vs, i, v, starts)
+        o = modeling.attention_xla(
+            q, _read_layer(ks, i, slot), _read_layer(vs, i, slot), cfg,
+            bias=bias, q_offset=offsets)
+        x = x + modeling.attn_output(o, pa, cfg, x.dtype)
+        x = x + modeling.mlp_block(
+            modeling.norm(x, p["mlp_norm"], cfg), p["mlp"], cfg, train=False
         )
-        new_k.append(ki)
-        new_v.append(vi)
     x = modeling.norm(x, params["final_norm"], cfg)
     logits = modeling.lm_head(x, params, cfg)
-    return logits, KVCache(jnp.stack(new_k), jnp.stack(new_v))
+    return logits, KVCache(ks, vs)
 
 
 # ---------------------------------------------------------------------------
@@ -193,10 +173,10 @@ def forward_with_cache_slots(params: Params, tokens, cfg: ModelConfig,
 
 
 def _layer_with_cache_paged(x, p, cfg: ModelConfig, pool_k, pool_v, tables,
-                            offsets, cos_sin, alibi):
-    """``_layer_with_cache_slots`` variant over a paged pool: ``pool_k``/
-    ``pool_v`` are (num_blocks, block_size, kvh, hd), ``tables`` is (B,
-    max_blocks) int32 and row ``b``'s logical position ``p`` lives at
+                            offsets, cos_sin, bias):
+    """One decoder layer over a paged pool: ``pool_k``/``pool_v`` are
+    (num_blocks, block_size, kvh, hd), ``tables`` is (B, max_blocks) int32
+    and row ``b``'s logical position ``p`` lives at
     ``(tables[b, p // bs], p % bs)``. Returns (x, pool_k, pool_v)."""
     from galvatron_tpu.ops import flash_attention
 
@@ -217,7 +197,7 @@ def _layer_with_cache_paged(x, p, cfg: ModelConfig, pool_k, pool_v, tables,
     sub = pos % bs
     pool_k = pool_k.at[blk, sub].set(k.astype(pool_k.dtype))
     pool_v = pool_v.at[blk, sub].set(v.astype(pool_v.dtype))
-    if s == 1 and alibi is None and cfg.causal:
+    if s == 1 and bias is None and cfg.causal:
         # decode step: paged attention reads pages through the table (XLA
         # gather fallback is bit-identical to the slot engine's decode core)
         o = flash_attention.paged_decode_attention(q, pool_k, pool_v, tables, offsets)
@@ -226,12 +206,6 @@ def _layer_with_cache_paged(x, p, cfg: ModelConfig, pool_k, pool_v, tables,
         # contiguously and reuse the slot attention core unchanged
         k_ctx = pool_k[tables].reshape(b, smax, *pool_k.shape[2:])
         v_ctx = pool_v[tables].reshape(b, smax, *pool_v.shape[2:])
-        bias = None
-        if alibi is not None:
-            q_pos = offsets[:, None] + jnp.arange(s)[None]  # (B, s)
-            k_pos = jnp.arange(smax)
-            rel = k_pos[None, None, :] - q_pos[:, :, None]  # (B, s, Smax)
-            bias = (alibi[None, :, None, None] * rel[:, None]).astype(jnp.float32)
         o = modeling.attention_xla(q, k_ctx, v_ctx, cfg, bias=bias, q_offset=offsets)
     x = x + modeling.attn_output(o, pa, cfg, x.dtype)
     x = x + modeling.mlp_block(
@@ -249,30 +223,22 @@ def forward_with_cache_paged(params: Params, tokens, cfg: ModelConfig,
     both are fixed-shape operands, so the compiled program is reused across
     every allocation pattern the host-side allocator produces.
 
-    Numerics match :func:`forward_with_cache_slots` bit-for-bit when
+    Numerics match :func:`forward_with_cache` at per-row offsets bit-for-bit when
     ``block_size * max_blocks`` equals the slot cache's max_seq_len: per-row
     rope tables, scatter-then-attend ordering and the decode attention core
     are all shared, only the K/V addressing differs (the paged/slot parity
     tests pin this)."""
     b, s = tokens.shape
     smax = tables.shape[1] * pool.k.shape[2]
-    if cfg.pos_embed == "rope":
-        cos_all, sin_all = modeling.rope_tables(cfg, smax)
-        pos = offsets[:, None] + jnp.arange(s)[None]  # (B, s)
-        cos_sin = (cos_all[pos], sin_all[pos])
-    else:
-        cos_sin = None
-    alibi = (
-        jnp.asarray(modeling.alibi_slopes(cfg.num_heads)) if cfg.pos_embed == "alibi" else None
-    )
+    cos_sin = _rope_at(cfg, smax, offsets, s)
+    bias = _alibi_bias(cfg, smax, offsets, s)
     x = params["embed"]["tok"].astype(cfg.dtype)[tokens]
     if cfg.pos_embed == "learned":
-        pos = offsets[:, None] + jnp.arange(s)[None]
-        x = x + params["embed"]["pos"].astype(cfg.dtype)[pos]
+        x = x + params["embed"]["pos"].astype(cfg.dtype)[_positions(offsets, s)]
     new_k, new_v = [], []
     for i, lp in enumerate(params["layers"]):
         x, ki, vi = _layer_with_cache_paged(
-            x, lp, cfg, pool.k[i], pool.v[i], tables, offsets, cos_sin, alibi
+            x, lp, cfg, pool.k[i], pool.v[i], tables, offsets, cos_sin, bias
         )
         new_k.append(ki)
         new_v.append(vi)

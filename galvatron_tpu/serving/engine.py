@@ -86,14 +86,8 @@ def _prefill_chunk(params, cfg: ModelConfig, cache: KVCache, tokens, slot, offse
     Garbage k/v written by tail padding is invisible forever: positions
     beyond a row's own query offset are causally masked, and each decode
     step overwrites its position before attending to it."""
-    row = KVCache(
-        jax.lax.dynamic_slice_in_dim(cache.k, slot, 1, axis=1),
-        jax.lax.dynamic_slice_in_dim(cache.v, slot, 1, axis=1),
-    )
-    logits, row = generation.forward_with_cache(params, tokens, cfg, row, offset)
-    cache = KVCache(
-        jax.lax.dynamic_update_slice_in_dim(cache.k, row.k, slot, axis=1),
-        jax.lax.dynamic_update_slice_in_dim(cache.v, row.v, slot, axis=1),
+    logits, cache = generation.forward_with_cache(
+        params, tokens, cfg, cache, offset, slot=slot
     )
     return logits[0], cache
 
@@ -104,7 +98,7 @@ def _decode_step(params, cfg: ModelConfig, cache: KVCache, tokens, offsets):
     offsets (B,). Inactive rows carry (0, 0) — their write lands at position
     0 of their own free slot and is overwritten by the next prefill before
     any query can attend it. Returns ((B, V) next-position logits, cache)."""
-    logits, cache = generation.forward_with_cache_slots(
+    logits, cache = generation.forward_with_cache(
         params, tokens[:, None], cfg, cache, offsets
     )
     return logits[:, 0], cache
@@ -122,7 +116,7 @@ def _decode_verify(params, cfg: ModelConfig, cache: KVCache, tokens, offsets):
     positions past the accepted length is overwritten by the next step's
     window before any query attends it — the same scatter-then-attend
     discipline the (0, 0) inactive rows rely on."""
-    logits, cache = generation.forward_with_cache_slots(
+    logits, cache = generation.forward_with_cache(
         params, tokens, cfg, cache, offsets
     )
     return logits, cache

@@ -158,3 +158,80 @@ def test_moe_eval_routing_not_degenerate():
     dispatch, _ = moe.route_top1(logits, capacity=8, train=False)
     got = int(jnp.argmax(dispatch.sum(-1), axis=-1)[0])
     assert got == want
+
+
+# ---------------------------------------------------------------------------
+# The cached forward writes the stacked cache in place (PR 38): held bit for
+# bit to the forwards it replaced (tests/_cached_forward_reference.py), which
+# sliced every layer's slab out, rewrote it whole and re-stacked
+# ---------------------------------------------------------------------------
+
+SMAX = 32
+
+#: name -> (rows of the cache, tokens' shape, offsets, slot): scalar offsets
+#: (generate's lockstep rows; one prefill chunk into row ``slot`` of many) and
+#: per-row ones (the engine's decode step and its 1 + k verify window; an
+#: inactive row carries (0, 0)); "clamped" windows would cross the row's end
+CASES = {
+    "lockstep_prefill": (3, (3, 7), 0, None),
+    "lockstep_decode": (3, (3, 1), 9, None),
+    "lockstep_clamped": (2, (2, 5), 30, None),
+    "slot_chunk": (4, (1, 6), 11, 2),
+    "slot_chunk_clamped": (4, (1, 6), 29, 3),
+    "rows_decode_ragged": (4, (4, 1), [5, 0, 17, 31], None),
+    "rows_verify_window": (4, (4, 4), [5, 0, 17, 28], None),
+    "rows_clamped": (3, (3, 4), [30, 0, 31], None),
+    "rows_single": (1, (1, 3), [6], None),
+}
+
+
+def _reference_forward(params, tokens, cfg, cache, offsets, slot):
+    """What the replaced forwards (and the engine's old ``_prefill_chunk``)
+    computed."""
+    import _cached_forward_reference as ref
+
+    if slot is not None:
+        return ref.prefill_chunk(params, tokens, cfg, cache, slot, offsets)
+    if jnp.ndim(offsets) == 0:
+        return ref.forward_with_cache(params, tokens, cfg, cache, offsets)
+    return ref.forward_with_cache_slots(params, tokens, cfg, cache, offsets)
+
+
+@pytest.mark.parametrize("pos_embed", ["rope", "learned", "alibi"])
+@pytest.mark.parametrize("case", list(CASES))
+def test_inplace_cached_forward_matches_the_replaced_forwards_bitwise(case, pos_embed):
+    """Logits and cache equal the replaced forwards' bit for bit, jitted with
+    traced offsets and slot as the engine and ``generate`` call them; exactly
+    positions [offset, offset + s) of each written row change, in every layer
+    (the window clamped back where it would cross the row's end), and every
+    other element of the cache keeps its bits."""
+    from _cached_forward_reference import random_cache
+
+    rows, tok_shape, offsets, slot = CASES[case]
+    cfg = CFG.replace(pos_embed=pos_embed, max_seq_len=SMAX)
+    params = modeling.init_model_params(jax.random.key(0), cfg)
+    cache = random_cache(cfg, rows, SMAX, seed=3)
+    tokens = jnp.asarray(
+        np.random.RandomState(4).randint(1, cfg.vocab_size, tok_shape), jnp.int32)
+    offsets = jnp.asarray(offsets, jnp.int32)
+    slot_arg = None if slot is None else jnp.asarray(slot, jnp.int32)
+
+    new_fn = jax.jit(lambda t, c, o, sl: generation.forward_with_cache(
+        params, t, cfg, c, o, slot=sl))
+    ref_fn = jax.jit(lambda t, c, o, sl: _reference_forward(params, t, cfg, c, o, sl))
+    logits, out = new_fn(tokens, cache, offsets, slot_arg)
+    ref_logits, ref_out = ref_fn(tokens, cache, offsets, slot_arg)
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(ref_logits))
+    np.testing.assert_array_equal(np.asarray(out.k), np.asarray(ref_out.k))
+    np.testing.assert_array_equal(np.asarray(out.v), np.asarray(ref_out.v))
+
+    s = tok_shape[1]
+    written = np.zeros((rows, SMAX), bool)
+    starts = np.broadcast_to(np.asarray(offsets), (tok_shape[0],))
+    for b, start in enumerate(starts):
+        start = min(int(start), SMAX - s)  # dynamic_update_slice clamps the start
+        written[b if slot is None else slot, start:start + s] = True
+    for old, new in ((cache.k, out.k), (cache.v, out.v)):
+        changed = np.asarray(old != new)  # (L, rows, SMAX, kv, hd)
+        assert not changed[:, ~written].any(), "an element outside the windows changed"
+        assert changed[:, written].any(axis=(-1, -2)).all(), "a window position kept its bits"

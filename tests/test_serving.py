@@ -246,16 +246,62 @@ def test_engine_jit_cache_stays_bounded(params):
 
 
 def test_slotwise_forward_matches_scalar_offset(params):
-    """forward_with_cache_slots at uniform offsets == forward_with_cache
+    """forward_with_cache at uniform per-row offsets == at a scalar offset
     (the slot-wise entry point degrades to the lockstep one)."""
     cache = generation.init_kv_cache(CFG, 2, 32)
     toks = jnp.asarray(np.random.RandomState(8).randint(1, CFG.vocab_size, (2, 5)), jnp.int32)
     l_ref, c_ref = generation.forward_with_cache(params, toks, CFG, cache, 0)
-    l_slot, c_slot = generation.forward_with_cache_slots(
+    l_slot, c_slot = generation.forward_with_cache(
         params, toks, CFG, cache, jnp.zeros((2,), jnp.int32)
     )
     np.testing.assert_allclose(np.asarray(l_ref), np.asarray(l_slot), rtol=1e-5)
     np.testing.assert_allclose(np.asarray(c_ref.k), np.asarray(c_slot.k), rtol=1e-5)
+
+
+@pytest.mark.parametrize("program", ["prefill_chunk", "decode_step", "decode_verify"])
+def test_engine_programs_match_the_replaced_forwards_bitwise(params, program):
+    """The engine's three jitted programs over a DONATED cache of four slots,
+    against the forwards they ran before the cache was written in place (the
+    row sliced out and written back for a prefill chunk; a vmapped update and
+    a re-stack for the decode step and its 1 + k verify window): logits and
+    cache bit for bit, with ragged offsets and an inactive (0, 0) row, and
+    every slot a prefill chunk does not name left as it was."""
+    import _cached_forward_reference as ref
+    from galvatron_tpu.serving.engine import _decode_verify
+
+    smax = 32
+    cache = ref.random_cache(CFG, 4, smax, seed=5)
+    fresh = lambda: jax.tree.map(jnp.copy, cache)  # noqa: E731 — the programs donate theirs
+    rng = np.random.RandomState(9)
+    if program == "prefill_chunk":
+        toks = jnp.asarray(rng.randint(1, CFG.vocab_size, (1, 4)), jnp.int32)
+        slot, offset = np.int32(2), np.int32(8)
+        logits, out = _prefill_chunk(params, CFG, fresh(), toks, slot, offset)
+        # (compared under jit, as the engine runs: eager steps round otherwise)
+        ref_logits, ref_out = jax.jit(
+            lambda c, t, sl, o: ref.prefill_chunk(params, t, CFG, c, sl, o)
+        )(cache, toks, slot, offset)
+        ref_logits = ref_logits[0]
+        others = np.asarray([0, 1, 3])
+        np.testing.assert_array_equal(np.asarray(out.k)[:, others], np.asarray(cache.k)[:, others])
+        np.testing.assert_array_equal(np.asarray(out.v)[:, others], np.asarray(cache.v)[:, others])
+    else:
+        width = 1 if program == "decode_step" else 4
+        toks = jnp.asarray(rng.randint(1, CFG.vocab_size, (4, width)), jnp.int32)
+        toks = toks.at[1].set(0)  # the inactive row: token 0 at offset 0
+        offsets = jnp.asarray([5, 0, 17, smax - width], jnp.int32)
+        if program == "decode_step":
+            logits, out = _decode_step(params, CFG, fresh(), toks[:, 0], offsets)
+        else:
+            logits, out = _decode_verify(params, CFG, fresh(), toks, offsets)
+        ref_logits, ref_out = jax.jit(
+            lambda c, t, o: ref.forward_with_cache_slots(params, t, CFG, c, o)
+        )(cache, toks, offsets)
+        if program == "decode_step":
+            ref_logits = ref_logits[:, 0]
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(ref_logits))
+    np.testing.assert_array_equal(np.asarray(out.k), np.asarray(ref_out.k))
+    np.testing.assert_array_equal(np.asarray(out.v), np.asarray(ref_out.v))
 
 
 # ---------------------------------------------------------------------------

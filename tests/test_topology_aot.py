@@ -218,15 +218,19 @@ STEP_SCOPES = ("embed", "attn", "qkv_proj", "attn_core", "out_proj", "mlp", "nor
                "allgather_einsum", "einsum_reducescatter")
 
 
+def _entry_lines(text):
+    """The instructions of the compiled text's ENTRY computation, a line each."""
+    entry = text[text.index("\nENTRY "):]
+    return entry[:entry.index("\n}")].splitlines()
+
+
 def _entry_work(text):
     """(instruction name, op_name or "") of the ENTRY computation's fusions and
     custom calls: the operations a device trace shows."""
     import re
 
-    entry = text[text.index("\nENTRY "):]
-    entry = entry[:entry.index("\n}")]
     rows = []
-    for line in entry.splitlines():
+    for line in _entry_lines(text):
         m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = .*? (fusion|custom-call)\(", line)
         if m:
             op = re.search(r'op_name="([^"]*)"', line)
@@ -617,6 +621,71 @@ def test_one_chip_step_has_no_ring(topo, real_mosaic):
                        HybridParallelConfig.uniform(2, mixed_precision="bf16"), mesh=mesh,
                        axes=axes, global_batch_size=2, seq_len=4096)
     assert rt.tp_overlap_seams == {"ring": 0, "plain": 0, "batchwise": 0}
+
+
+# --- the serving programs at opt-1.3b widths: the slot cache is written in place ---
+
+def _entry_results(text):
+    """(opcode, elements of its largest result array, result type) of every
+    instruction of the ENTRY computation."""
+    import math
+    import re
+
+    rows = []
+    for line in _entry_lines(text):
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (.*?) ([\w\-]+)\(", line)
+        if m:
+            sizes = [math.prod(int(d) for d in dims.split(",") if d)
+                     for dims in re.findall(r"\b[a-z]+\d*\[([\d,]*)\]", m.group(1))]
+            rows.append((m.group(2), max(sizes, default=0), m.group(1)))
+    return rows
+
+
+@pytest.mark.parametrize("program,slots,spec_k", [
+    ("serving_decode", 8, 0), ("serving_decode", 16, 0),
+    ("serving_prefill", 8, 0), ("serving_prefill", 16, 0),
+    ("serving_decode_verify", 8, 4),
+])
+def test_serving_programs_write_the_slot_cache_in_place(program, slots, spec_k, one_chip,
+                                                        real_mosaic):
+    """The engine's declared programs (the AOT twins ``cli serve`` warms) at
+    ``opt-1.3b`` widths, slots x 2048, chunk 256, for one v5e chip: what the
+    cell ``opt-1.3b_serve_above_knee`` runs. The cached forwards carry the
+    stacked cache (L, B, S, kv, d) through the layers and write it with
+    ``dynamic-update-slice`` alone, so the compiled program moves no layer's
+    slab: no copy, scatter or fusion whose result is a slab or more, the
+    donated cache aliased input to output, temporaries far under the cache's
+    size (5.42 GiB before PR 38 at 8 slots; 12 slots did not fit)."""
+    from galvatron_tpu.aot import registry
+    from galvatron_tpu.models.modeling import PRESETS
+    from galvatron_tpu.serving import engine  # noqa: F401  (registers the serving family)
+
+    cfg, smax = PRESETS["opt-1.3b"], 2048
+    assert (cfg.hidden_size, cfg.num_layers, cfg.num_heads, cfg.head_dim,
+            cfg.vocab_size, cfg.max_seq_len) == (2048, 24, 32, 64, 50272, smax)
+    ctx = registry.ProgramContext(cfg=cfg, num_slots=slots, prefill_chunk=256,
+                                  max_seq_len=smax, spec_decode_k=spec_k)
+    spec, = [sp for sp in registry.enumerate_programs(ctx, include=("serving",))
+             if sp.name == program]
+    args = [a if a is cfg else jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), a)
+        for a in spec.args]
+    compiled = spec.fn.lower(*args).compile()
+    ma = compiled.memory_analysis()
+    slab = slots * smax * cfg.kv_heads * cfg.head_dim
+    cache_bytes = 2 * cfg.num_layers * slab * 2  # k and v, bf16
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    assert total < HBM_V5E_GIB * 2**30, f"{total / 2**30:.2f} GiB"
+    assert ma.temp_size_in_bytes < 0.5 * 2**30, f"{ma.temp_size_in_bytes / 2**30:.3f} GiB"
+    assert ma.alias_size_in_bytes == cache_bytes
+    # results of a slab or more: the in-place updates of the stacked cache, and
+    # the tied embedding table's conversion to bf16 (a weight, not the cache)
+    moved = [(op, shape) for op, n, shape in _entry_results(compiled.as_text())
+             if n >= slab and str(cfg.vocab_size) not in shape
+             and op not in ("parameter", "get-tuple-element", "tuple", "bitcast",
+                            "dynamic-update-slice")]
+    assert not moved, moved[:4]
 
 
 def test_flash_multichip_compile_smoke(topo, real_mosaic):
