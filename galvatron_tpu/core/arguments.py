@@ -113,7 +113,7 @@ def _add_training_args(p: argparse.ArgumentParser):
     # observability layer (obs/, DESIGN.md § Observability)
     g.add_argument("--trace_spans", type=str, default=None,
                    help="enable host-side span tracing (step/data/fwd_bwd/"
-                   "sync/ckpt + synthetic pipeline stage spans) and export "
+                   "sync/ckpt) and export "
                    "a Chrome trace-event / Perfetto JSON to this path on "
                    "exit; adds one host sync per iteration while enabled "
                    "(OFF = zero added syncs)")

@@ -682,20 +682,6 @@ def _train_impl(ns: argparse.Namespace, verbose: bool, tracer,
         # --trace_dir it is a mkdtemp nothing else names
         if rec:
             metrics.log("profile_window", **rec)
-    # pipeline schedules run inside ONE jitted scan — per-stage activity is
-    # rendered from the schedule's structural clock model instead
-    # (obs/tracing.emit_tick_spans; spans are labeled synthetic)
-    sched_ticks = None
-    if tracer.enabled and hp.pp > 1 and hp.vpp == 1:
-        if hp.pipeline_type == "pipedream_flush":
-            from galvatron_tpu.parallel.pipeline_1f1b import (
-                pipedream_schedule_ticks as _schedule_ticks,
-            )
-        else:
-            from galvatron_tpu.parallel.pipeline import (
-                gpipe_schedule_ticks as _schedule_ticks,
-            )
-        sched_ticks = _schedule_ticks(hp.pp, max(1, hp.chunks))
     obs_server = train_obs = None
     if obs_on:
         # headless-run scrape endpoint: GET /metrics + /healthz on a sidecar
@@ -1007,7 +993,6 @@ def _train_impl(ns: argparse.Namespace, verbose: bool, tracer,
                     # when the sentinel is disarmed: no memory cost)
                     snap = sentinel.snapshot(state)
                     prof.begin_iter()
-                    t_step0 = time.perf_counter() if sched_ticks is not None else None
                     if holder is not None:
                         # the dispatch below donates `state`: an emergency
                         # save between here and the post-step rebind would
@@ -1035,15 +1020,6 @@ def _train_impl(ns: argparse.Namespace, verbose: bool, tracer,
                         moe_vals = (
                             {k: float(v) for k, v in state["moe_stats"].items()}
                             if sync_each and "moe_stats" in state else {}
-                        )
-                    if sched_ticks is not None:
-                        # the fwd_bwd+sync window is the realized step; render
-                        # the schedule's per-stage tick grid onto it so 1F1B
-                        # bubbles are visible on the timeline
-                        obs_tracing.emit_tick_spans(
-                            tracer, sched_ticks[0], sched_ticks[1],
-                            tracer.pc_to_us(t_step0),
-                            (time.perf_counter() - t_step0) * 1e6, step=it,
                         )
                     # injection sits OUTSIDE the armed gate: chaos jobs force a
                     # NaN observation with or without the sentinel (a disarmed

@@ -116,6 +116,38 @@ def _read_layer(stacked, layer: int, slot):
         stacked, (layer, slot, 0, 0, 0), (1, 1) + stacked.shape[2:])[0]
 
 
+# The cached forwards carry the training forward's scope names (PERF.md
+# section 3: ``embed``; ``layer_<i>`` > ``attn`` > ``qkv_proj``, ``attn_core``,
+# ``out_proj``; ``mlp`` and ``norm`` through ``modeling``; ``head``) and one of
+# their own, ``cache_write``, so a device trace of a serving step reads by the
+# same names. Scopes are metadata: no operation is added.
+
+
+@jax.named_scope("embed")
+def _embed_at(params: Params, tokens, cfg: ModelConfig, offsets):
+    """Token embeddings of ``tokens`` (B, s), plus the learned positions at
+    ``offsets`` where the model has them."""
+    x = params["embed"]["tok"].astype(cfg.dtype)[tokens]
+    if cfg.pos_embed == "learned":
+        x = x + params["embed"]["pos"].astype(cfg.dtype)[_positions(offsets, tokens.shape[1])]
+    return x
+
+
+@jax.named_scope("qkv_proj")
+def _project_qkv_at(x, p, cfg: ModelConfig, cos_sin):
+    """A layer's pre-norm, q/k/v projection and RoPE at the new positions."""
+    q, k, v = modeling.project_qkv_heads(modeling.norm(x, p["attn_norm"], cfg), p["attn"], cfg)
+    if cos_sin is not None:
+        q = modeling.apply_rope(q, *cos_sin)
+        k = modeling.apply_rope(k, *cos_sin)
+    return q, k, v
+
+
+@jax.named_scope("head")
+def _head(x, params: Params, cfg: ModelConfig):
+    return modeling.lm_head(modeling.norm(x, params["final_norm"], cfg), params, cfg)
+
+
 def forward_with_cache(params: Params, tokens, cfg: ModelConfig, cache: KVCache,
                        offsets, slot=None):
     """Run ``tokens`` (B, s) through the model at absolute positions
@@ -141,29 +173,26 @@ def forward_with_cache(params: Params, tokens, cfg: ModelConfig, cache: KVCache,
     smax = cache.k.shape[2]
     cos_sin = _rope_at(cfg, smax, offsets, s)
     bias = _alibi_bias(cfg, smax, offsets, s)
-    x = params["embed"]["tok"].astype(cfg.dtype)[tokens]
-    if cfg.pos_embed == "learned":
-        x = x + params["embed"]["pos"].astype(cfg.dtype)[_positions(offsets, s)]
+    x = _embed_at(params, tokens, cfg, offsets)
     starts = _window_starts(offsets, slot, tokens.shape[0])
     ks, vs = cache
     for i, p in enumerate(params["layers"]):
-        pa = p["attn"]
-        q, k, v = modeling.project_qkv_heads(modeling.norm(x, p["attn_norm"], cfg), pa, cfg)
-        if cos_sin is not None:
-            q = modeling.apply_rope(q, *cos_sin)
-            k = modeling.apply_rope(k, *cos_sin)
-        ks = _write_layer(ks, i, k, starts)
-        vs = _write_layer(vs, i, v, starts)
-        o = modeling.attention_xla(
-            q, _read_layer(ks, i, slot), _read_layer(vs, i, slot), cfg,
-            bias=bias, q_offset=offsets)
-        x = x + modeling.attn_output(o, pa, cfg, x.dtype)
-        x = x + modeling.mlp_block(
-            modeling.norm(x, p["mlp_norm"], cfg), p["mlp"], cfg, train=False
-        )
-    x = modeling.norm(x, params["final_norm"], cfg)
-    logits = modeling.lm_head(x, params, cfg)
-    return logits, KVCache(ks, vs)
+        with jax.named_scope(f"layer_{i}"):
+            with jax.named_scope("attn"):
+                q, k, v = _project_qkv_at(x, p, cfg, cos_sin)
+                with jax.named_scope("cache_write"):
+                    ks = _write_layer(ks, i, k, starts)
+                    vs = _write_layer(vs, i, v, starts)
+                with jax.named_scope("attn_core"):
+                    o = modeling.attention_xla(
+                        q, _read_layer(ks, i, slot), _read_layer(vs, i, slot), cfg,
+                        bias=bias, q_offset=offsets)
+                with jax.named_scope("out_proj"):
+                    x = x + modeling.attn_output(o, p["attn"], cfg, x.dtype)
+            x = x + modeling.mlp_block(
+                modeling.norm(x, p["mlp_norm"], cfg), p["mlp"], cfg, train=False
+            )
+    return _head(x, params, cfg), KVCache(ks, vs)
 
 
 # ---------------------------------------------------------------------------
@@ -183,31 +212,29 @@ def _layer_with_cache_paged(x, p, cfg: ModelConfig, pool_k, pool_v, tables,
     b, s, h = x.shape
     bs = pool_k.shape[1]
     smax = tables.shape[1] * bs
-    xa = modeling.norm(x, p["attn_norm"], cfg)
-    pa = p["attn"]
-    q, k, v = modeling.project_qkv_heads(xa, pa, cfg)
-    if cfg.pos_embed == "rope":
-        cos, sin = cos_sin  # (B, s, hd/2) per-row tables
-        q = modeling.apply_rope(q, cos, sin)
-        k = modeling.apply_rope(k, cos, sin)
-    # scatter the new k/v through the table (duplicate targets only arise on
-    # the null block, whose contents are never attended)
-    pos = offsets[:, None] + jnp.arange(s)[None]  # (B, s)
-    blk = jnp.take_along_axis(tables, pos // bs, axis=1)  # (B, s)
-    sub = pos % bs
-    pool_k = pool_k.at[blk, sub].set(k.astype(pool_k.dtype))
-    pool_v = pool_v.at[blk, sub].set(v.astype(pool_v.dtype))
-    if s == 1 and bias is None and cfg.causal:
-        # decode step: paged attention reads pages through the table (XLA
-        # gather fallback is bit-identical to the slot engine's decode core)
-        o = flash_attention.paged_decode_attention(q, pool_k, pool_v, tables, offsets)
-    else:
-        # prefill chunk (or bias'd attention): materialize the row's context
-        # contiguously and reuse the slot attention core unchanged
-        k_ctx = pool_k[tables].reshape(b, smax, *pool_k.shape[2:])
-        v_ctx = pool_v[tables].reshape(b, smax, *pool_v.shape[2:])
-        o = modeling.attention_xla(q, k_ctx, v_ctx, cfg, bias=bias, q_offset=offsets)
-    x = x + modeling.attn_output(o, pa, cfg, x.dtype)
+    with jax.named_scope("attn"):
+        q, k, v = _project_qkv_at(x, p, cfg, cos_sin)
+        with jax.named_scope("cache_write"):
+            # scatter the new k/v through the table (duplicate targets only
+            # arise on the null block, whose contents are never attended)
+            pos = offsets[:, None] + jnp.arange(s)[None]  # (B, s)
+            blk = jnp.take_along_axis(tables, pos // bs, axis=1)  # (B, s)
+            sub = pos % bs
+            pool_k = pool_k.at[blk, sub].set(k.astype(pool_k.dtype))
+            pool_v = pool_v.at[blk, sub].set(v.astype(pool_v.dtype))
+        with jax.named_scope("attn_core"):
+            if s == 1 and bias is None and cfg.causal:
+                # decode step: paged attention reads pages through the table (XLA
+                # gather fallback is bit-identical to the slot engine's decode core)
+                o = flash_attention.paged_decode_attention(q, pool_k, pool_v, tables, offsets)
+            else:
+                # prefill chunk (or bias'd attention): materialize the row's context
+                # contiguously and reuse the slot attention core unchanged
+                k_ctx = pool_k[tables].reshape(b, smax, *pool_k.shape[2:])
+                v_ctx = pool_v[tables].reshape(b, smax, *pool_v.shape[2:])
+                o = modeling.attention_xla(q, k_ctx, v_ctx, cfg, bias=bias, q_offset=offsets)
+        with jax.named_scope("out_proj"):
+            x = x + modeling.attn_output(o, p["attn"], cfg, x.dtype)
     x = x + modeling.mlp_block(
         modeling.norm(x, p["mlp_norm"], cfg), p["mlp"], cfg, train=False
     )
@@ -232,19 +259,16 @@ def forward_with_cache_paged(params: Params, tokens, cfg: ModelConfig,
     smax = tables.shape[1] * pool.k.shape[2]
     cos_sin = _rope_at(cfg, smax, offsets, s)
     bias = _alibi_bias(cfg, smax, offsets, s)
-    x = params["embed"]["tok"].astype(cfg.dtype)[tokens]
-    if cfg.pos_embed == "learned":
-        x = x + params["embed"]["pos"].astype(cfg.dtype)[_positions(offsets, s)]
+    x = _embed_at(params, tokens, cfg, offsets)
     new_k, new_v = [], []
     for i, lp in enumerate(params["layers"]):
-        x, ki, vi = _layer_with_cache_paged(
-            x, lp, cfg, pool.k[i], pool.v[i], tables, offsets, cos_sin, bias
-        )
+        with jax.named_scope(f"layer_{i}"):
+            x, ki, vi = _layer_with_cache_paged(
+                x, lp, cfg, pool.k[i], pool.v[i], tables, offsets, cos_sin, bias
+            )
         new_k.append(ki)
         new_v.append(vi)
-    x = modeling.norm(x, params["final_norm"], cfg)
-    logits = modeling.lm_head(x, params, cfg)
-    return logits, KVCache(jnp.stack(new_k), jnp.stack(new_v))
+    return _head(x, params, cfg), KVCache(jnp.stack(new_k), jnp.stack(new_v))
 
 
 # ---------------------------------------------------------------------------

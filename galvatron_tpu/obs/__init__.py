@@ -14,13 +14,13 @@ Four coordinated pieces:
   (``--profile_steps``, ``POST /profile``).
 """
 
-from galvatron_tpu.obs.tracing import Tracer, chrome_trace, emit_tick_spans, tracer
+from galvatron_tpu.obs.tracing import Tracer, chrome_trace, tracer
 from galvatron_tpu.obs.stepstats import StepStats, peak_flops_per_device
 from galvatron_tpu.obs.flight import ProfilerWindow, dump_flight, parse_profile_steps
 from galvatron_tpu.obs.prom import ObsServer, PromText, TrainStats, server_metrics_text
 
 __all__ = [
-    "Tracer", "chrome_trace", "emit_tick_spans", "tracer",
+    "Tracer", "chrome_trace", "tracer",
     "StepStats", "peak_flops_per_device",
     "ProfilerWindow", "dump_flight", "parse_profile_steps",
     "ObsServer", "PromText", "TrainStats", "server_metrics_text",

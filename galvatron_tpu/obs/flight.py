@@ -132,6 +132,16 @@ class ProfilerWindow:
         self.done = False
         self.first_step = self.last_step = None
 
+    def open(self, it: int) -> None:
+        """Start the capture now, at iteration ``it``; raises what the
+        backend raises when it cannot (``maybe_start`` degrades instead)."""
+        import jax
+
+        jax.profiler.start_trace(self.trace_dir)
+        self.active = True
+        self.first_step = it
+        tracer.profiling = True
+
     def maybe_start(self, it: int) -> None:
         # >= not ==: a resumed run whose batch offset already passed START
         # must still capture (from where it is) rather than silently skip
@@ -141,12 +151,7 @@ class ProfilerWindow:
             self.done = True  # resumed entirely past the window: nothing to do
             return
         try:
-            import jax
-
-            jax.profiler.start_trace(self.trace_dir)
-            self.active = True
-            self.first_step = it
-            tracer.profiling = True
+            self.open(it)
         except Exception as e:  # noqa: BLE001 — degrade, don't crash training
             self.failed = True
             print(f"--profile_steps: backend lacks profiler support ({e!r}); "
@@ -173,6 +178,7 @@ class ProfilerWindow:
         self.active = False
         self.done = True
         tracer.profiling = False
+        stop_error = None
         try:
             import jax
 
@@ -181,6 +187,7 @@ class ProfilerWindow:
                 print(f"profiler window [{self.start_step}:{self.stop_step}) "
                       f"→ {self.trace_dir}")
         except Exception as e:  # noqa: BLE001
+            stop_error = repr(e)
             print(f"failed to close profiler window: {e!r}")
         found = sorted(glob.glob(
             os.path.join(self.trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
@@ -189,6 +196,8 @@ class ProfilerWindow:
             "start_step": self.start_step, "stop_step": self.stop_step,
             "first_step": self.first_step, "last_step": self.last_step,
         }
+        if stop_error:
+            _last_window["stop_error"] = stop_error
         return dict(_last_window)
 
 
@@ -196,31 +205,35 @@ def capture_profile(
     trace_dir: str, n_steps: int, counter_fn: Callable[[], int],
     timeout_s: float = 30.0, poll_s: float = 0.02,
 ) -> Dict[str, Any]:
-    """On-demand capture (server ``POST /profile``): start a jax.profiler
-    trace, wait until ``counter_fn`` advances by ``n_steps`` (engine decode
-    iterations) or ``timeout_s`` elapses, stop, report what happened.
-    Raises RuntimeError when the backend cannot start a trace at all."""
-    import jax
-
-    start_count = counter_fn()
+    """On-demand capture (server ``POST /profile``): a ``ProfilerWindow``
+    opened now and closed once ``counter_fn`` (the engine's ``steps``) has
+    advanced by ``n_steps`` or ``timeout_s`` has passed; an exception closes
+    it too. While it is open the engine's spans are annotations on the
+    profiler's clock (``tracer.profiling``), and ``last_profile_window()``
+    then names the ``.xplane.pb`` and the engine steps it covers. Raises
+    RuntimeError when the backend cannot start a trace at all."""
+    start = counter_fn()
+    pw = ProfilerWindow(trace_dir, start, start + n_steps)
     try:
-        jax.profiler.start_trace(trace_dir)
+        pw.open(start)
     except Exception as e:
         raise RuntimeError(f"profiler unavailable on this backend: {e!r}") from e
     deadline = time.time() + timeout_s
     try:
-        while counter_fn() - start_count < n_steps and time.time() < deadline:
+        while counter_fn() - start < n_steps and time.time() < deadline:
             time.sleep(poll_s)
     finally:
-        captured = counter_fn() - start_count
-        try:
-            jax.profiler.stop_trace()
-        except Exception as e:  # noqa: BLE001 — report, the capture dir may still be usable
-            return {"trace_dir": trace_dir, "steps_captured": captured,
-                    "requested": n_steps, "stop_error": repr(e)}
-    return {
+        captured = counter_fn() - start  # stopping takes seconds, the engine runs on
+        if captured > 0:
+            pw.last_step = start + captured - 1
+        rec = pw.close(verbose=False)
+    out = {
         "trace_dir": trace_dir,
+        "xplane": rec["xplane"],
         "steps_captured": captured,
         "requested": n_steps,
         "timed_out": captured < n_steps,
     }
+    if "stop_error" in rec:
+        out["stop_error"] = rec["stop_error"]
+    return out

@@ -14,27 +14,24 @@ first-class subsystem. This module is the host half of that layer:
 - ``chrome_trace(spans)`` / ``export_chrome_trace(path)`` — Chrome
   trace-event JSON (the format Perfetto and chrome://tracing load).
 - one instrumentation point, two sinks: while a ``jax.profiler`` window is
-  open (``tracer.profiling``, set by ``obs/flight.ProfilerWindow`` and the
+  open (``tracer.profiling``, set by ``obs/flight.ProfilerWindow``, by
+  ``obs/flight.capture_profile`` (the server's ``POST /profile``) and by the
   trainer's whole-run ``--trace_dir`` capture) every span also opens a
-  ``jax.profiler.TraceAnnotation`` of its name, and the trainer's ``step``
-  span a ``StepTraceAnnotation("train", step_num=...)`` — the host's rows
-  land on the ``python`` line of the profiler's own ``.xplane.pb``, on the
-  clock the device's operations are on.
+  ``jax.profiler.TraceAnnotation`` of its name, the trainer's ``step`` span
+  a ``StepTraceAnnotation("train", step_num=...)`` and the serving engine's
+  ``iteration`` span a ``StepTraceAnnotation("serve", step_num=...)`` — the
+  host's rows land on the ``python`` line of the profiler's own
+  ``.xplane.pb``, on the clock the device's operations are on.
 - what the runtime does behind the loop's back, as spans (tracer ON only):
   ``jax_trace`` / ``jax_lower`` / ``jax_compile`` from the ``jax.monitoring``
   duration events (every trace, lowering, backend compile or cache load —
   ``jax_compile`` carries ``hit`` and ``retrieval_s``), and ``gc`` from
   ``gc.callbacks``. Each carries the ``step`` of the span open on its
-  thread, so a compile or a collection inside a training step names it.
-- synthetic schedule spans (``emit_tick_spans``) — pipeline schedules run
-  inside ONE jitted clocked scan, so no host probe can observe per-tick
-  activity; instead the schedule's exact clock model (the same index
-  arithmetic the scan executes — ``gpipe_schedule_ticks`` /
-  ``pipedream_schedule_ticks``) is rendered onto the measured step window,
-  one track per stage. Gaps on a stage track are the schedule's bubbles.
-  These spans are labeled ``synthetic: true``: they are the schedule's
-  lockstep model scaled to the measured step, not a device-side measurement
-  (the XLA op timeline for that lives in ``--trace_dir``/``--profile_steps``).
+  thread, so a compile or a collection inside a training step or a serving
+  iteration names it.
+- ``record_span(..., track="serving queue")`` — a span reported after the
+  fact on a named track that is no thread's (a request's wait in the queue
+  began before the loop thread's open spans did, so it cannot nest there).
 
 The module-level ``tracer`` singleton is what the trainer, checkpoint layer,
 search engine, and serving engine all record into — enable it once
@@ -48,6 +45,7 @@ import json
 import os
 import threading
 import time
+import zlib
 from collections import deque
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -85,11 +83,22 @@ _NULL_SPAN = _NullSpan()
 GC_SPAN_MIN_S = 1e-3
 
 
+def _track_tid(track: str) -> int:
+    """The ``tid`` of a named track: a function of the name alone, in a range
+    far below any thread ident (those are addresses)."""
+    return 1_000_000 + zlib.crc32(track.encode()) % 1_000_000
+
+
+#: the spans that are the profiler's step boundary too (xprof groups device
+#: work by it): the trainer's ``step``, the serving engine's ``iteration``
+_STEP_ANNOTATIONS = {"step": "train", "iteration": "serve"}
+
+
 def _annotation(name: str, args: Dict[str, Any]):
-    """The span's twin in the profiler's trace. The trainer's ``step`` span
-    is the profiler's step boundary too (xprof groups device work by it)."""
-    if name == "step" and "step" in args:
-        return jax.profiler.StepTraceAnnotation("train", step_num=args["step"])
+    """The span's twin in the profiler's trace."""
+    if name in _STEP_ANNOTATIONS and "step" in args:
+        return jax.profiler.StepTraceAnnotation(
+            _STEP_ANNOTATIONS[name], step_num=args["step"])
     return jax.profiler.TraceAnnotation(name)
 
 
@@ -240,25 +249,33 @@ class Tracer:
                 return sp.args["step"]
         return None
 
-    def record_span(self, name: str, dur_s: float, **attrs) -> None:
+    def record_span(self, name: str, dur_s: float, track: Optional[str] = None,
+                    **attrs) -> None:
         """A span that ended now and lasted ``dur_s``, reported after the
-        fact (a ``jax.monitoring`` duration event, a finished collection).
-        Carries the ``step`` of the span open on this thread, if any."""
+        fact (a ``jax.monitoring`` duration event, a finished collection, a
+        request's wait in the queue). Carries the ``step`` of the span open
+        on this thread, if any. ``track`` puts it on a timeline track of that
+        name that is no thread's: a wait that began before the spans open
+        here would break their nesting on the thread's own track."""
         if not self.enabled:
             return
         step = self.current_step()
         if step is not None:
             attrs["step"] = step
-        t = threading.current_thread()
+        if track is None:
+            t = threading.current_thread()
+            tid, tname, depth = t.ident or 0, t.name, len(self._stack_for_thread())
+        else:
+            tid, tname, depth = _track_tid(track), track, 0
         self._record(
             {
                 "name": name,
                 "ph": "X",
                 "ts": self.pc_to_us(time.perf_counter() - dur_s),
                 "dur": dur_s * 1e6,
-                "tid": t.ident or 0,
-                "tname": t.name,
-                "depth": len(self._stack_for_thread()),
+                "tid": tid,
+                "tname": tname,
+                "depth": depth,
                 "args": attrs,
             }
         )
@@ -431,67 +448,3 @@ def chrome_trace(
             }
         )
     return {"traceEvents": events, "displayTimeUnit": "ms"}
-
-
-# ---------------------------------------------------------------------------
-# Synthetic pipeline-schedule spans
-# ---------------------------------------------------------------------------
-
-# synthetic stage tracks live at tids far from real thread idents
-_STAGE_TID_BASE = 1_000_000
-#: relative tick weights (the cost model's bwd = 2x fwd convention,
-#: reference galvatron/core/cost_model.py:190-191)
-_TICK_WEIGHTS = {"fwd": 1.0, "bwd": 2.0}
-
-
-def emit_tick_spans(
-    trc: Tracer,
-    ticks: Sequence[Dict[str, int]],
-    total_ticks: int,
-    t0_us: float,
-    dur_us: float,
-    step: Optional[int] = None,
-) -> int:
-    """Render a schedule's tick grid onto the measured step window.
-
-    ``ticks``: ``{"stage", "tick", "kind" ("fwd"|"bwd"), "mb"}`` records from
-    ``gpipe_schedule_ticks``/``pipedream_schedule_ticks``. Each stage gets
-    its own synthetic track (``pp stage S``); within a tick that carries both
-    a forward and a backward (1F1B steady state), the tick is split by the
-    fwd:bwd = 1:2 cost convention. Ticks with no work emit nothing — the
-    gaps on a stage track ARE the schedule's bubbles. Returns span count."""
-    if not trc.enabled or not ticks or total_ticks <= 0 or dur_us <= 0:
-        return 0
-    tick_us = dur_us / total_ticks
-    by_cell: Dict[Tuple[int, int], List[Dict[str, int]]] = {}
-    for t in ticks:
-        by_cell.setdefault((t["stage"], t["tick"]), []).append(t)
-    n = 0
-    for (stage, tick), cell in sorted(by_cell.items()):
-        cell_t0 = t0_us + tick * tick_us
-        wsum = sum(_TICK_WEIGHTS.get(c["kind"], 1.0) for c in cell)
-        off = 0.0
-        # fwd renders before bwd within a shared tick (the 1F1B last stage
-        # forwards a micro-batch, then backwards it, in one tick)
-        for c in sorted(cell, key=lambda c: 0 if c["kind"] == "fwd" else 1):
-            frac = _TICK_WEIGHTS.get(c["kind"], 1.0) / wsum
-            args: Dict[str, Any] = {
-                "mb": c["mb"], "tick": tick, "synthetic": True,
-                "model": "lockstep clocked schedule",
-            }
-            if step is not None:
-                args["step"] = step
-            trc._record(
-                {
-                    "name": f"stage{stage} {c['kind']} mb{c['mb']}",
-                    "ph": "X",
-                    "ts": cell_t0 + off * tick_us,
-                    "dur": frac * tick_us,
-                    "tid": _STAGE_TID_BASE + stage,
-                    "tname": f"pp stage {stage}",
-                    "args": args,
-                }
-            )
-            off += frac
-            n += 1
-    return n

@@ -475,29 +475,6 @@ def gpipe_pipeline(stage_fn, pp: int, chunks: int, mesh: Mesh, packed: bool = Fa
     return run
 
 
-def gpipe_schedule_ticks(pp: int, chunks: int):
-    """Structural clock model of the GPipe train step, for the observability
-    timeline (obs.tracing.emit_tick_spans): the schedule runs inside ONE
-    jitted scan, so per-tick activity is not host-observable — this renders
-    the exact index arithmetic the scan executes. Ticks ``0..chunks+pp-2``
-    are the forward clock (stage s computes micro-batch ``t - s``, the
-    ``tick`` function above); autodiff reverses it, so the backward occupies
-    the mirrored clock shifted by one forward phase. Returns
-    ``(ticks, total_ticks)`` with tick records {stage, tick, kind, mb}; a
-    (stage, tick) cell with no record is a schedule bubble."""
-    t_fwd = chunks + pp - 1
-    ticks = []
-    for s in range(pp):
-        for m in range(chunks):
-            ticks.append({"stage": s, "tick": m + s, "kind": "fwd", "mb": m})
-            # reverse pipeline: last stage backwards mb chunks-1 first
-            ticks.append({
-                "stage": s, "tick": t_fwd + (chunks - 1 - m) + (pp - 1 - s),
-                "kind": "bwd", "mb": m,
-            })
-    return ticks, 2 * t_fwd
-
-
 # ---------------------------------------------------------------------------
 # Runtime assembly
 # ---------------------------------------------------------------------------

@@ -67,28 +67,6 @@ def _head_loss(head_sub, y, labels, cfg: ModelConfig):
     return s, n.astype(jnp.float32)
 
 
-def pipedream_schedule_ticks(pp: int, chunks: int):
-    """Structural clock model of the 1F1B schedule, for the observability
-    timeline (obs.tracing.emit_tick_spans). Mirrors the validity arithmetic
-    of ``tick`` below exactly: on tick t stage s forwards micro-batch
-    ``t - s`` and backwards ``t - 2(pp-1) + s`` when those indices are in
-    range — so the warmup ramp, the steady 1F1B interleave, and the cooldown
-    bubbles render from the same formulas the jitted scan executes. Returns
-    ``(ticks, total_ticks)``; a (stage, tick) cell with no record is a
-    pipeline bubble (visible as a gap on that stage's track)."""
-    T = chunks + 2 * (pp - 1)
-    ticks = []
-    for s in range(pp):
-        for t in range(T):
-            m_f = t - s
-            if 0 <= m_f < chunks:
-                ticks.append({"stage": s, "tick": t, "kind": "fwd", "mb": m_f})
-            m_b = t - 2 * (pp - 1) + s
-            if 0 <= m_b < chunks:
-                ticks.append({"stage": s, "tick": t, "kind": "bwd", "mb": m_b})
-    return ticks, T
-
-
 def make_1f1b_train_step(
     cfg: ModelConfig,
     hp: HybridParallelConfig,

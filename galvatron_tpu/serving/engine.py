@@ -332,15 +332,26 @@ class Engine:
                        temperature: float = 0.0, top_k: int = 0,
                        top_p: float = 0.0,
                        ttl_s: Optional[float] = None,
-                       trace_id: Optional[str] = None) -> Request:
+                       trace_id: Optional[str] = None,
+                       capture_logits: Optional[np.ndarray] = None) -> Request:
         """Like :meth:`submit` but returns the :class:`Request`, which
         carries the lifecycle state, ``finish_reason`` (deadline
-        truncation), and the ``cancel()`` handle the server's disconnect
+        truncation), the host times (``admitted_at``, ``token_times``,
+        ``finished_at``) and the ``cancel()`` handle the server's disconnect
         poll uses. Refuses immediately — instead of parking a future that
         can never resolve — when the engine is draining or closed.
         ``trace_id`` is the fleet router's correlation id (propagated via
         the X-Galvatron-Trace-Id header, obs/correlate.py); it rides every
-        lifecycle instant and the prefill span."""
+        lifecycle instant and the prefill span.
+
+        ``capture_logits`` is the logits tap: the CALLER's writable float32
+        array of at least ``(max_new_tokens, vocab_size)``. Before token
+        ``k`` is drawn the loop thread copies the row it is drawn from into
+        ``capture_logits[k]`` (prefill's last row for ``k`` = 0, then each
+        decode step's) and counts ``Request.logits_rows``; it allocates
+        nothing. Refused with ``spec_decode_k > 0``: a token accepted from a
+        verify window, or drawn after a rejection from a row with the
+        rejected token struck out, has no one row it was drawn from."""
         if self._closed:
             raise rz.EngineClosed(
                 "engine is closed"
@@ -363,10 +374,13 @@ class Engine:
                 f"prompt ({len(tokens)}) + max_new_tokens ({max_new_tokens}) "
                 f"exceeds the engine's slot capacity {self.slots.max_seq_len}"
             )
+        if capture_logits is not None:
+            self._check_capture(capture_logits, max_new_tokens)
         req = Request(
             tokens=tokens, max_new_tokens=max_new_tokens,
             temperature=float(temperature), top_k=int(top_k),
             top_p=float(top_p), trace_id=trace_id,
+            capture_logits=capture_logits,
         )
         if trace_id is not None:
             _obs_tracer.instant("req_queued", rid=req.rid, tokens=len(tokens),
@@ -394,6 +408,25 @@ class Engine:
             self.scheduler.drain(exc)
             raise exc
         return req
+
+    def _check_capture(self, buf, max_new_tokens: int) -> None:
+        """Refuse a logits tap the loop thread could not write without
+        allocating, converting or running past its end."""
+        if self.spec_k > 0:
+            raise ValueError(
+                "capture_logits is not supported with spec_decode_k > 0: a token "
+                "taken from a verify window has no one row it was drawn from"
+            )
+        vocab = self._last_logits.shape[1]
+        if not (isinstance(buf, np.ndarray) and buf.dtype == np.float32
+                and buf.ndim == 2 and buf.shape[0] >= max_new_tokens
+                and buf.shape[1] == vocab and buf.flags.writeable):
+            raise ValueError(
+                f"capture_logits must be a writable float32 numpy array of at "
+                f"least ({max_new_tokens}, {vocab}) = (max_new_tokens, vocab_size); got "
+                f"{type(buf).__name__} {getattr(buf, 'dtype', None)} "
+                f"{getattr(buf, 'shape', None)}"
+            )
 
     def generate(self, prompts: Sequence[Sequence[int]], max_new_tokens: int = 32,
                  **kw) -> List[List[int]]:
@@ -530,9 +563,7 @@ class Engine:
     def step_once(self) -> None:
         """One scheduler+decode iteration, synchronously (tests and
         ``start_loop=False`` callers — deterministic interleaving)."""
-        self._admit()
-        if self._by_slot:
-            self._step()
+        self._iterate()
 
     def begin_drain(self) -> None:
         """Flip into draining mode without blocking: admission closes
@@ -654,9 +685,7 @@ class Engine:
             try:
                 self._working = True
                 try:
-                    self._admit()
-                    if self._by_slot:
-                        self._step()
+                    self._iterate()
                 finally:
                     self._working = False
             except Exception as e:  # noqa: BLE001 — engine must not die silently
@@ -681,27 +710,56 @@ class Engine:
                     ))
                     break
 
+    def _iterate(self) -> None:
+        """One iteration of the loop thread: admissions, then one decode step
+        over the slots in use. The span tree (tracer on only; every child on
+        this thread, inside its parent): ``iteration`` > ``admit`` >
+        ``prefill``; ``sample`` > ``sample_slot``; ``decode`` (or
+        ``decode_verify``) > ``decode_dispatch``, ``decode_wait``,
+        ``logits_readback``. ``step`` is the ``steps`` counter at entry, so a
+        compile or a collection inside an iteration names it
+        (``Tracer.current_step``) and the profiler groups device work by it."""
+        if self.scheduler.empty() and not self._by_slot:
+            return  # ``step_once`` on an idle engine: no work, no span
+        with _obs_tracer.span(
+                "iteration", step=self.counters.get("steps"),
+                active=self.slots.active_count, queued=self.scheduler.depth):
+            self._admit()
+            if self._by_slot:
+                self._step()
+
+    def _head_admissible(self) -> bool:
+        """Whether the queue's head can be popped now. On the paged backend
+        admission additionally gates on BLOCK headroom: the head request
+        stays queued (TTL still burning — that is the backpressure signal)
+        until the pool's free + evictable blocks cover its worst-case
+        footprint, so decode can never hit an empty pool."""
+        if not self.paged:
+            return not self.scheduler.empty()
+        head = self.scheduler.peek()
+        if head is None:
+            return False
+        if head.cancel_requested or head.future.cancelled():
+            return True
+        return self.slots.can_admit(
+            head.tokens, head.max_new_tokens, chunk=self.prefill_chunk)
+
     def _admit(self) -> None:
-        """Admit queued requests into free slots (chunked prefill). On the
-        paged backend, admission additionally gates on BLOCK headroom: the
-        head request stays queued (TTL still burning — that is the
-        backpressure signal) until the pool's free + evictable blocks cover
-        its worst-case footprint, so decode can never hit an empty pool."""
+        """Admit queued requests into free slots (chunked prefill); the
+        ``admit`` span opens only when there is a request to pop."""
         self.scheduler.expire()
-        while self.slots.free_slots > 0:
-            if self.paged:
-                head = self.scheduler.peek()
-                if head is None:
-                    return
-                blocked = not (head.cancel_requested or head.future.cancelled()
-                               ) and not self.slots.can_admit(
-                    head.tokens, head.max_new_tokens, chunk=self.prefill_chunk
-                )
-                if blocked:
-                    return
+        if self.slots.free_slots > 0 and self._head_admissible():
+            with _obs_tracer.span("admit") as sp:
+                sp.set(admitted=self._admit_queued())
+
+    def _admit_queued(self) -> int:
+        """Pop and prefill while a slot is free and the head is admissible;
+        returns how many requests took a slot."""
+        admitted = 0
+        while self.slots.free_slots > 0 and self._head_admissible():
             req = self.scheduler.pop()
             if req is None:
-                return
+                break
             if req.cancel_requested or req.future.cancelled():
                 # abandoned while queued: terminal before ever taking a slot
                 rz.advance(req, rz.CANCELLED, self.scheduler.counters,
@@ -714,6 +772,7 @@ class Engine:
                 continue
             try:
                 self._prefill(req)
+                admitted += 1
             except Exception as e:  # noqa: BLE001 — fail the one request
                 if req.slot is not None:
                     self._by_slot.pop(req.slot, None)
@@ -731,12 +790,13 @@ class Engine:
                                reason=type(e).__name__)
                 if not req.future.done():
                     req.future.set_exception(e)
+        return admitted
 
     def _prefill(self, req: Request) -> None:
-        # engine iteration spans (prefill/decode/sample) land on the same
-        # process timeline as everything else; tracing off = no-op singleton.
-        # The prefill span is per-request, so the fleet trace_id rides it
-        # (batch-wide sample/decode spans cover many requests and don't).
+        # the engine's spans land on the same process timeline as everything
+        # else; tracing off = no-op singleton. The prefill span is
+        # per-request, so the fleet trace_id rides it (batch-wide
+        # sample/decode spans cover many requests and don't).
         attrs = {"rid": req.rid, "tokens": len(req.tokens)}
         if req.trace_id is not None:
             attrs["trace_id"] = req.trace_id
@@ -748,6 +808,11 @@ class Engine:
         slot = self.slots.alloc()
         assert slot is not None
         req.slot = slot
+        req.admitted_at = time.time()
+        # on a track of its own: the wait began before this iteration's spans
+        _obs_tracer.record_span(
+            "queue_wait", req.admitted_at - req.submitted_at,
+            track="serving queue", rid=req.rid, depth=self.scheduler.depth)
         rz.advance(req, rz.PREFILLING, slot=slot)
         toks = np.asarray(req.tokens, np.int32)
         c = self.prefill_chunk
@@ -849,28 +914,36 @@ class Engine:
                     # 4096-token hog can no longer starve everything behind it
                     expired.append(slot)
                     continue
-                tok = _sample_host(
-                    self._rng[slot], self._last_logits[slot],
-                    req.temperature, req.top_k, req.top_p,
-                )
-                sampled += 1
-                if req.first_token_at is None:
-                    req.first_token_at = now
-                    self.ttft.add(now - req.submitted_at)
-                    self.ttft_hist.observe(now - req.submitted_at)
-                if self.eos_id >= 0 and tok == self.eos_id:
-                    req.finish_reason = "eos"
-                    retired.append(slot)
-                    continue
-                req.generated.append(tok)
-                appended += 1
-                if len(req.generated) >= req.max_new_tokens:
-                    req.finish_reason = "length"
-                    retired.append(slot)
-                    continue
-                tokens[slot] = tok
-                offsets[slot] = self.slots.lengths[slot]
-                self.slots.lengths[slot] += 1
+                with _obs_tracer.span("sample_slot", slot=slot, rid=req.rid,
+                                      greedy=req.temperature <= 0):
+                    if req.capture_logits is not None:
+                        # the tap: the row token k is about to be drawn from
+                        k = len(req.generated)
+                        req.capture_logits[k] = self._last_logits[slot]
+                        req.logits_rows = k + 1
+                    tok = _sample_host(
+                        self._rng[slot], self._last_logits[slot],
+                        req.temperature, req.top_k, req.top_p,
+                    )
+                    sampled += 1
+                    if req.first_token_at is None:
+                        req.first_token_at = now
+                        self.ttft.add(now - req.submitted_at)
+                        self.ttft_hist.observe(now - req.submitted_at)
+                    if self.eos_id >= 0 and tok == self.eos_id:
+                        req.finish_reason = "eos"
+                        retired.append(slot)
+                        continue
+                    req.generated.append(tok)
+                    req.token_times.append(time.time())
+                    appended += 1
+                    if len(req.generated) >= req.max_new_tokens:
+                        req.finish_reason = "length"
+                        retired.append(slot)
+                        continue
+                    tokens[slot] = tok
+                    offsets[slot] = self.slots.lengths[slot]
+                    self.slots.lengths[slot] += 1
         for slot in retired:
             self._retire(slot)
         for slot in cancelled:
@@ -882,31 +955,7 @@ class Engine:
         if still and drafts:
             appended += self._verify_step(still, tokens, offsets, drafts)
         elif still:
-            with _obs_tracer.span("decode", active=len(still)):
-                if self.paged:
-                    for slot in still:
-                        # provably a no-op today (decode writes past every
-                        # registered/shared block), kept as a cheap COW
-                        # invariant so a future sharing scheme cannot
-                        # silently corrupt cached prefixes
-                        off = int(offsets[slot])
-                        self.slots.ensure_writable(slot, off, off + 1)
-                    logits, pool = _paged_decode_step(
-                        self.params, self.cfg, self.slots.pool,
-                        jnp.asarray(tokens), jnp.asarray(self.slots.tables),
-                        jnp.asarray(offsets),
-                    )
-                    self.slots.pool = pool
-                else:
-                    logits, cache = _decode_step(
-                        self.params, self.cfg, self.slots.cache,
-                        jnp.asarray(tokens), jnp.asarray(offsets),
-                    )
-                    self.slots.cache = cache
-                # np.asarray is the engine's own readback sync (it needs the
-                # logits on host to sample the next token), so the decode
-                # span closes on realized compute, not dispatch
-                logits = np.asarray(logits)
+            logits = self._forward_step("decode", tokens, offsets, still)
             for slot in still:
                 self._last_logits[slot] = logits[slot]
         self.counters.inc("steps")
@@ -919,6 +968,48 @@ class Engine:
             self.decode_step_hist.observe(dt)
         if dt > 0:
             self._last_step_tps = sampled / dt
+
+    def _forward_step(self, name: str, tokens: np.ndarray, offsets: np.ndarray,
+                      still: Sequence[int], **attrs) -> np.ndarray:
+        """The iteration's one shared forward over ALL slots, to its logits on
+        the host: ``tokens`` (B,) is the plain decode step, (B, 1+k) the
+        speculative verify window. Its span ``name`` (``decode`` /
+        ``decode_verify``) closes on the logits' arrival and is covered by
+        three children: ``decode_dispatch`` (host: operands to the device and
+        the jitted call's return), ``decode_wait`` (the device: ``Span.sync``
+        blocks with the tracer on only, where ``np.asarray`` blocked anyway)
+        and ``logits_readback`` (the copy to the host, ``bytes``)."""
+        verify = tokens.ndim == 2
+        with _obs_tracer.span(name, active=len(still), **attrs):
+            with _obs_tracer.span("decode_dispatch"):
+                if self.paged:
+                    smax = self.slots.max_seq_len
+                    width = tokens.shape[1] if verify else 1
+                    for slot in still:
+                        # provably a no-op for a plain step today (decode writes
+                        # past every registered/shared block), kept as a cheap
+                        # COW invariant so a future sharing scheme cannot
+                        # silently corrupt cached prefixes
+                        off = int(offsets[slot])
+                        self.slots.ensure_writable(slot, off, min(off + width, smax))
+                    fn = _paged_decode_verify if verify else _paged_decode_step
+                    logits, self.slots.pool = fn(
+                        self.params, self.cfg, self.slots.pool, jnp.asarray(tokens),
+                        jnp.asarray(self.slots.tables), jnp.asarray(offsets),
+                    )
+                else:
+                    fn = _decode_verify if verify else _decode_step
+                    logits, self.slots.cache = fn(
+                        self.params, self.cfg, self.slots.cache,
+                        jnp.asarray(tokens), jnp.asarray(offsets),
+                    )
+            with _obs_tracer.span("decode_wait") as sp:
+                sp.sync(logits)
+            # np.asarray is the engine's own readback sync (it needs the
+            # logits on host to sample the next token), so the span closes on
+            # realized compute with the tracer off too
+            with _obs_tracer.span("logits_readback", bytes=logits.nbytes):
+                return np.asarray(logits)
 
     def _build_drafts(self, still, offsets) -> Dict[int, List[int]]:
         """Propose up to ``spec_k`` draft tokens per surviving slot from the
@@ -970,25 +1061,7 @@ class Engine:
         batch[:, 0] = tokens
         for slot, d in drafts.items():
             batch[slot, 1:1 + len(d)] = d
-        with _obs_tracer.span("decode_verify", active=len(still), k=k):
-            if self.paged:
-                smax = self.slots.max_seq_len
-                for slot in still:
-                    off = int(offsets[slot])
-                    self.slots.ensure_writable(slot, off, min(off + 1 + k, smax))
-                logits, pool = _paged_decode_verify(
-                    self.params, self.cfg, self.slots.pool,
-                    jnp.asarray(batch), jnp.asarray(self.slots.tables),
-                    jnp.asarray(offsets),
-                )
-                self.slots.pool = pool
-            else:
-                logits, cache = _decode_verify(
-                    self.params, self.cfg, self.slots.cache,
-                    jnp.asarray(batch), jnp.asarray(offsets),
-                )
-                self.slots.cache = cache
-            logits = np.asarray(logits)  # (B, 1+k, V)
+        logits = self._forward_step("decode_verify", batch, offsets, still, k=k)  # (B, 1+k, V)
         self.counters.inc("spec_steps")
         appended = 0
         retired: List[int] = []
@@ -1017,6 +1090,7 @@ class Engine:
                     finish = "eos"
                     break
                 req.generated.append(dt)
+                req.token_times.append(time.time())
                 appended += 1
                 if len(req.generated) >= req.max_new_tokens:
                     finish = "length"

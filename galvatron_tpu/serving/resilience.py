@@ -98,6 +98,8 @@ def advance(req, state: str, counters=None, **info) -> None:
             f"request {req.rid}: illegal lifecycle transition {cur} → {state}"
         )
     req.state = state
+    if state in TERMINAL:
+        req.finished_at = time.time()
     trace_id = getattr(req, "trace_id", None)
     if trace_id is not None:
         # the fleet-minted correlation id (obs/correlate.py) rides every

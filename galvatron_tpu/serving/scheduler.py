@@ -20,7 +20,7 @@ import time
 from collections import deque
 from concurrent.futures import Future
 from dataclasses import dataclass, field
-from typing import Deque, List, Optional
+from typing import Any, Deque, List, Optional
 
 from galvatron_tpu.analysis.locks import make_lock
 from galvatron_tpu.serving import resilience as rz
@@ -57,6 +57,19 @@ class Request:
     slot: Optional[int] = None
     generated: List[int] = field(default_factory=list)
     first_token_at: Optional[float] = None
+    # always-on host times (``time.time()``; an access log and the benchmark
+    # read them): the slot taken; one a generated token, read where the token
+    # is appended, after its draw; the terminal state reached. Ordered
+    # submitted_at <= admitted_at <= first_token_at <= token_times[0] <= ...
+    # <= finished_at (``first_token_at`` is read BEFORE the first draw)
+    admitted_at: Optional[float] = None
+    token_times: List[float] = field(default_factory=list)
+    finished_at: Optional[float] = None
+    # the caller's float32 array, at least (max_new_tokens, vocab), handed to
+    # ``Engine.submit_request(capture_logits=)``: row k is the logits row
+    # token k was drawn from; ``logits_rows`` counts the rows written
+    capture_logits: Optional[Any] = None
+    logits_rows: int = 0
     state: str = rz.QUEUED
     cancel_requested: bool = False
     cancel_reason: Optional[str] = None

@@ -102,78 +102,6 @@ def test_thread_aware_tracks():
 
 
 # ---------------------------------------------------------------------------
-# schedule tick models + synthetic spans
-# ---------------------------------------------------------------------------
-
-
-def test_pipedream_schedule_ticks_structure():
-    from galvatron_tpu.parallel.pipeline_1f1b import pipedream_schedule_ticks
-
-    pp, chunks = 4, 8
-    ticks, T = pipedream_schedule_ticks(pp, chunks)
-    assert T == chunks + 2 * (pp - 1)
-    for s in range(pp):
-        fwd = sorted(t["tick"] for t in ticks if t["stage"] == s and t["kind"] == "fwd")
-        bwd = sorted(t["tick"] for t in ticks if t["stage"] == s and t["kind"] == "bwd")
-        assert len(fwd) == chunks and len(bwd) == chunks
-        assert fwd[0] == s                      # warmup ramp
-        assert bwd[0] == 2 * (pp - 1) - s       # first backward
-    # the last stage forwards and backwards micro-batch m in the SAME tick
-    last = [t for t in ticks if t["stage"] == pp - 1]
-    for m in range(chunks):
-        cell = {t["kind"] for t in last if t["mb"] == m}
-        assert cell == {"fwd", "bwd"}
-    # stage 0's warmup bubble: ticks chunks..2(pp-1)-1 idle when chunks < 2(pp-1)
-    s0_busy = {t["tick"] for t in ticks if t["stage"] == 0}
-    assert set(range(chunks)) <= s0_busy
-
-
-def test_gpipe_schedule_ticks_structure():
-    from galvatron_tpu.parallel.pipeline import gpipe_schedule_ticks
-
-    pp, chunks = 2, 4
-    ticks, T = gpipe_schedule_ticks(pp, chunks)
-    assert T == 2 * (chunks + pp - 1)
-    # forward phase: stage s computes mb m at tick m + s (the scan's clock)
-    for t in ticks:
-        if t["kind"] == "fwd":
-            assert t["tick"] == t["mb"] + t["stage"]
-        else:
-            assert t["tick"] >= chunks + pp - 1  # backward strictly after
-
-
-def test_emit_tick_spans_renders_bubbles():
-    from galvatron_tpu.parallel.pipeline_1f1b import pipedream_schedule_ticks
-
-    t = Tracer(capacity=512)
-    t.enable()
-    pp, chunks = 2, 4
-    ticks, T = pipedream_schedule_ticks(pp, chunks)
-    n = tracing.emit_tick_spans(t, ticks, T, t0_us=1000.0, dur_us=6000.0, step=7)
-    assert n == 2 * pp * chunks  # every mb: one fwd + one bwd per stage
-    spans = t.snapshot()
-    assert all(s["args"]["synthetic"] for s in spans)
-    tick_us = 6000.0 / T
-    for s in spans:
-        assert 1000.0 - 1e-6 <= s["ts"] and s["ts"] + s["dur"] <= 7000.0 + 1e-6
-    # 1F1B steady state: a tick carrying fwd+bwd splits 1:2 (bwd = 2x fwd)
-    last_stage = [s for s in spans if s["tid"] == tracing._STAGE_TID_BASE + pp - 1]
-    fwd0 = next(s for s in last_stage if s["name"] == f"stage{pp-1} fwd mb0")
-    bwd0 = next(s for s in last_stage if s["name"] == f"stage{pp-1} bwd mb0")
-    assert fwd0["dur"] == pytest.approx(tick_us / 3, rel=1e-6)
-    assert bwd0["dur"] == pytest.approx(2 * tick_us / 3, rel=1e-6)
-    # and the fwd renders before the bwd within the shared tick
-    assert fwd0["ts"] + fwd0["dur"] == pytest.approx(bwd0["ts"], rel=1e-6)
-    # stage tracks are named
-    doc = chrome_trace(spans)
-    names = {e["args"]["name"] for e in doc["traceEvents"] if e["ph"] == "M"}
-    assert {"pp stage 0", "pp stage 1"} <= names
-    # disabled tracer emits nothing
-    t2 = Tracer()
-    assert tracing.emit_tick_spans(t2, ticks, T, 0.0, 100.0) == 0
-
-
-# ---------------------------------------------------------------------------
 # step accounting (FLOPs / MFU)
 # ---------------------------------------------------------------------------
 
@@ -492,24 +420,22 @@ def test_traced_training_exports_nested_spans_and_mfu(tmp_path, monkeypatch):
     assert not tracing.tracer.enabled and tracing.tracer.snapshot() == []
 
 
-def test_traced_pp_training_has_stage_spans(tmp_path):
-    """Under a pipeline schedule the timeline carries synthetic per-stage
-    per-microbatch spans (the schedule clock model rendered onto the measured
-    step)."""
+def test_traced_pp_training_records_measured_spans_only(tmp_path):
+    """Under a pipeline schedule the timeline carries what the host measured
+    (``step`` and its children) and nothing painted on: no per-stage track, no
+    span marked synthetic (a masked tick costs what a steady one does, so the
+    schedule's lockstep model said nothing about where the time went)."""
     trace = str(tmp_path / "pp.trace.json")
     _train(["--train_iters", "3", "--pp_deg", "2", "--chunks", "2",
             "--pipeline_type", "pipedream_flush", "--trace_spans", trace],
            verbose=False)
     doc = json.load(open(trace))
-    stage_spans = [e for e in doc["traceEvents"]
-                   if e["ph"] == "X" and e["name"].startswith("stage")]
-    assert stage_spans, "no synthetic pipeline stage spans in the trace"
-    assert all(e["args"]["synthetic"] for e in stage_spans)
-    tracks = {e["tid"] for e in stage_spans}
-    assert len(tracks) == 2  # one timeline track per stage
-    # every traced step rendered both stages' fwd and bwd micro-batches
-    kinds = {e["name"].split()[1] for e in stage_spans}
-    assert kinds == {"fwd", "bwd"}
+    spans = [e for e in doc["traceEvents"] if e["ph"] == "X"]
+    assert {"step", "fwd_bwd", "sync"} <= {e["name"] for e in spans}
+    assert not [e for e in spans if e["name"].startswith("stage")
+                or e["args"].get("synthetic")]
+    assert not [e for e in doc["traceEvents"]
+                if e["ph"] == "M" and str(e["args"].get("name", "")).startswith("pp stage")]
 
 
 def test_tracing_off_adds_zero_host_syncs(tmp_path, monkeypatch):

@@ -9,6 +9,7 @@ import ast
 import gc
 import json
 import os
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -217,6 +218,45 @@ def test_backward_is_marked_by_transpose(toy_step_text):
 # ---------------------------------------------------------------------------
 
 
+# ---------------------------------------------------------------------------
+# scopes in the serving programs (PR 39: the cached forwards carry the step's names)
+# ---------------------------------------------------------------------------
+
+SERVING_SCOPES = ("embed", "layer_0/attn/qkv_proj", "layer_0/attn/cache_write",
+                  "layer_0/attn/attn_core", "layer_0/attn/out_proj", "layer_0/mlp",
+                  "layer_1/attn/cache_write", "head")
+SERVING_PROGRAMS = ("serving_decode", "serving_prefill", "serving_decode_verify",
+                    "serving_paged_decode", "serving_paged_prefill")
+
+
+@pytest.fixture(scope="module")
+def serving_texts():
+    """Compiled text of the engine's declared programs for a toy model, as the
+    AOT registry enumerates them (what ``cli serve`` warms and the loop calls)."""
+    from galvatron_tpu.aot import registry
+    from galvatron_tpu.models.modeling import ModelConfig
+    from galvatron_tpu.serving import engine  # noqa: F401  (registers the serving family)
+
+    cfg = ModelConfig(vocab_size=128, hidden_size=64, num_layers=2, num_heads=2,
+                      ffn_dim=128, max_seq_len=32)
+    texts = {}
+    for blocks in (0, -1):
+        ctx = registry.ProgramContext(cfg=cfg, num_slots=2, prefill_chunk=8, max_seq_len=32,
+                                      kv_block_size=8, kv_num_blocks=blocks, spec_decode_k=2)
+        for spec in registry.enumerate_programs(ctx, include=("serving",)):
+            texts[spec.name] = spec.fn.lower(*spec.args).compile().as_text()
+    return texts
+
+
+@pytest.mark.parametrize("scope", SERVING_SCOPES)
+@pytest.mark.parametrize("program", SERVING_PROGRAMS)
+def test_compiled_serving_program_carries_the_scope(serving_texts, program, scope):
+    import re
+
+    names = re.findall(r'op_name="([^"]*)"', serving_texts[program])
+    assert any(re.search(rf"[/(]{scope}(?:[/)]|$)", n) for n in names), (program, scope)
+
+
 @pytest.fixture()
 def traced():
     assert not tracer.enabled
@@ -346,6 +386,70 @@ def test_profiler_window_flags_the_tracer_and_keeps_its_record(monkeypatch, tmp_
         "trace_dir": str(tmp_path), "xplane": str(run / "host.xplane.pb"),
         "start_step": 2, "stop_step": 4, "first_step": 2, "last_step": 3}
     assert pw.close() is None  # closed once
+
+
+def test_a_span_reported_on_a_named_track_is_no_threads(traced):
+    with traced.span("iteration", step=4):
+        traced.record_span("queue_wait", 0.25, track="serving queue", rid=9)
+        traced.record_span("gc", 0.002)
+    wait, own, _ = traced.snapshot()
+    assert (wait["tid"], wait["tname"], wait["depth"]) == (
+        tracing._track_tid("serving queue"), "serving queue", 0)
+    assert wait["tid"] != own["tid"] == threading.get_ident() and own["depth"] == 1
+    assert wait["args"] == {"rid": 9, "step": 4} and wait["dur"] == pytest.approx(0.25e6)
+    assert tracing._track_tid("serving queue") != tracing._track_tid("another")
+
+
+def test_an_iteration_span_is_the_serving_step_boundary():
+    assert tracing._annotation("iteration", {"step": 12}).__class__ is (
+        jax.profiler.StepTraceAnnotation)
+    # without a number, and under any other name, a plain annotation
+    assert tracing._annotation("iteration", {}).__class__ is jax.profiler.TraceAnnotation
+    assert tracing._annotation("sample", {"step": 12}).__class__ is jax.profiler.TraceAnnotation
+
+
+@pytest.mark.parametrize("ending", ["reached", "timed_out", "raised"])
+def test_capture_profile_is_one_profiler_window(monkeypatch, tmp_path, ending):
+    """What ``POST /profile`` runs: the tracer is flagged while the capture is
+    open, whatever ends it, and where it went is kept with the steps covered."""
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda d: None)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    run = tmp_path / "plugins" / "profile" / "2026_01_01"
+    run.mkdir(parents=True)
+    (run / "host.xplane.pb").write_bytes(b"")
+    inside = []
+    steps = iter([40, 40, 41, 43, 43] if ending == "reached" else [40, 40, 41, 41, 41, 41])
+
+    def counter():
+        inside.append(tracer.profiling)
+        if ending == "raised" and len(inside) == 3:
+            raise OSError("the engine went away")
+        return next(steps, 41)
+
+    kw = dict(timeout_s=30.0 if ending == "reached" else 0.05, poll_s=0.0)
+    if ending == "raised":
+        with pytest.raises(OSError):
+            flight.capture_profile(str(tmp_path), 3, counter, timeout_s=30.0)
+        # the finally's own reading raised nothing more: the window is closed
+    else:
+        out = flight.capture_profile(str(tmp_path), 3, counter, **kw)
+        assert out == {"trace_dir": str(tmp_path), "xplane": str(run / "host.xplane.pb"),
+                       "steps_captured": 3 if ending == "reached" else 1, "requested": 3,
+                       "timed_out": ending == "timed_out"}
+    assert inside[0] is False and all(inside[1:]) and not tracer.profiling
+    win = flight.last_profile_window()
+    assert win["xplane"] == str(run / "host.xplane.pb") and win["first_step"] == 40
+    assert win["last_step"] == {"reached": 42, "timed_out": 40, "raised": 40}[ending]
+
+
+def test_capture_profile_without_a_profiler_raises_and_flags_nothing(monkeypatch, tmp_path):
+    def no_xprof(d):
+        raise ValueError("no xprof")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", no_xprof)
+    with pytest.raises(RuntimeError, match="profiler unavailable"):
+        flight.capture_profile(str(tmp_path), 1, lambda: 0)
+    assert not tracer.profiling
 
 
 # ---------------------------------------------------------------------------

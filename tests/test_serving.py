@@ -424,6 +424,13 @@ def test_http_profile_capture_endpoint():
             gen.result(timeout=60)
         assert resp["requested"] == 2 and os.path.isdir(resp["trace_dir"])
         assert resp["steps_captured"] >= 0
+        # the capture was one profiler window: closed, and where it went is kept
+        from galvatron_tpu.obs import flight
+        from galvatron_tpu.obs.tracing import tracer
+
+        win = flight.last_profile_window()
+        assert not tracer.profiling and win["trace_dir"] == resp["trace_dir"]
+        assert win["xplane"] == resp["xplane"] and win["first_step"] is not None
         # usage errors are 400s, not tracebacks
         bad = urllib.request.Request(
             f"http://127.0.0.1:{port}/profile?steps=0", data=b"{}", method="POST"

@@ -1,0 +1,429 @@
+"""The serving engine's own observability (PR 39): the always-on host times and
+the logits tap on ``Request``, the span tree of one iteration on the loop
+thread, the queue's wait on a track of its own, and what tracing costs when it
+is off (no ring record, no annotation, no clock read through the tracer).
+CPU, a toy model; no number here is a device number."""
+
+import os
+import sys
+import time
+
+import jax
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+from galvatron_tpu.models import modeling  # noqa: E402
+from galvatron_tpu.models.modeling import ModelConfig  # noqa: E402
+from galvatron_tpu.obs import tracing  # noqa: E402
+from galvatron_tpu.obs.tracing import tracer  # noqa: E402
+from galvatron_tpu.serving import Engine  # noqa: E402
+
+CFG = ModelConfig(vocab_size=128, hidden_size=64, num_layers=2, num_heads=4, ffn_dim=128,
+                  max_seq_len=64)
+#: (engine flags, the span of the iteration's shared forward)
+BACKENDS = {
+    "slot-plain": ({}, "decode"),
+    "paged-plain": ({"kv_num_blocks": -1, "kv_block_size": 8}, "decode"),
+    "slot-verify": ({"spec_decode_k": 2}, "decode_verify"),
+    "paged-verify": ({"kv_num_blocks": -1, "kv_block_size": 8, "spec_decode_k": 2},
+                     "decode_verify"),
+}
+
+
+@pytest.fixture(scope="module")
+def params():
+    return modeling.init_model_params(jax.random.key(0), CFG)
+
+
+class _EchoDrafter:
+    """Drafts the last token again: a draft for every row, so every iteration
+    of a speculating engine runs the verify program."""
+
+    def draft(self, tokens, k):
+        return [int(tokens[-1])] * k
+
+
+def _engine(params, backend="slot-plain", **kw):
+    flags, _ = BACKENDS[backend]
+    eng = Engine(params, CFG, num_slots=kw.pop("num_slots", 2), prefill_chunk=8,
+                 start_loop=False, **flags, **kw)
+    if eng.spec_k:
+        eng.drafter = _EchoDrafter()
+    return eng
+
+
+def _drive(eng, reqs, limit=200):
+    for _ in range(limit):
+        if all(r.future.done() for r in reqs):
+            return
+        eng.step_once()
+    raise AssertionError("the engine did not finish its requests")
+
+
+@pytest.fixture()
+def traced():
+    assert not tracer.enabled
+    tracer.enable(capacity=1 << 14)
+    try:
+        yield tracer
+    finally:
+        tracer.disable()
+        tracer.clear()
+
+
+def _spans(trc, name=None):
+    return [r for r in trc.snapshot() if r["ph"] == "X" and (name is None or r["name"] == name)]
+
+
+def _inside(child, parent, slack_us=1.0):
+    return (parent["ts"] - slack_us <= child["ts"]
+            and child["ts"] + child["dur"] <= parent["ts"] + parent["dur"] + slack_us)
+
+
+# --- tracer off: what is always on, and what is not there -------------------
+
+
+@pytest.mark.parametrize("backend", sorted(BACKENDS))
+def test_request_times_are_filled_and_ordered_with_the_tracer_off(params, backend, monkeypatch):
+    assert not tracer.enabled
+    for ann in ("TraceAnnotation", "StepTraceAnnotation"):
+        monkeypatch.setattr(jax.profiler, ann, lambda *a, **k: pytest.fail("annotation while off"))
+    reads = []
+
+    class CountingTime:
+        """The tracer's own view of ``time``: any read through it is counted."""
+
+        def __getattr__(self, name):
+            reads.append(name)
+            return getattr(time, name)
+
+    monkeypatch.setattr(tracing, "time", CountingTime())
+    eng = _engine(params, backend)
+    try:
+        reqs = [eng.submit_request([1, 2, 3, 4, 5], 6), eng.submit_request([7, 8, 9], 4,
+                                                                          temperature=0.8),
+                eng.submit_request([9, 8, 7, 6], 3)]
+        _drive(eng, reqs)
+    finally:
+        eng.close()
+    assert tracer.snapshot() == [] and reads == []
+    for r in reqs:
+        assert len(r.token_times) == len(r.generated) == r.max_new_tokens
+        stamps = [r.submitted_at, r.admitted_at, r.first_token_at, *r.token_times, r.finished_at]
+        assert all(s is not None for s in stamps)
+        assert stamps == sorted(stamps)
+    # the third request waited for a slot: admitted after the first's first token
+    assert reqs[2].admitted_at >= reqs[0].first_token_at
+
+
+def test_a_request_that_never_takes_a_slot_is_finished_without_being_admitted(params):
+    eng = _engine(params, num_slots=1)
+    try:
+        a = eng.submit_request([1, 2, 3], 4)
+        b = eng.submit_request([4, 5, 6], 4)
+        b.cancel("test")
+        _drive(eng, [a, b])
+    finally:
+        eng.close()
+    assert b.admitted_at is None and b.token_times == [] and b.finished_at >= b.submitted_at
+    assert a.finished_at >= a.token_times[-1]
+
+
+# --- tracer on: the tree of one iteration ----------------------------------------------
+
+
+@pytest.fixture()
+def traced_run(params, traced, request):
+    """Three requests through two slots under the tracer, one cancelled while it
+    decodes; the ring's spans and the requests."""
+    backend = request.param
+    eng = _engine(params, backend)
+    try:
+        reqs = [eng.submit_request([1, 2, 3, 4, 5], 8), eng.submit_request([7, 8, 9], 8,
+                                                                          temperature=0.8),
+                eng.submit_request([9, 8, 7, 6], 3)]
+        for _ in range(3):
+            eng.step_once()
+        reqs[1].cancel("test")
+        _drive(eng, reqs)
+    finally:
+        eng.close()
+    return {"backend": backend, "spans": _spans(traced), "reqs": reqs,
+            "forward": BACKENDS[backend][1], "all": traced.snapshot()}
+
+
+@pytest.mark.parametrize("traced_run", sorted(BACKENDS), indirect=True)
+def test_every_child_lies_inside_its_parent_on_the_loop_thread(traced_run):
+    spans, fwd = traced_run["spans"], traced_run["forward"]
+    parents = {"admit": "iteration", "prefill": "admit", "sample": "iteration",
+               "sample_slot": "sample", fwd: "iteration", "decode_dispatch": fwd,
+               "decode_wait": fwd, "logits_readback": fwd}
+    loop_tid = {s["tid"] for s in spans if s["name"] == "iteration"}
+    assert len(loop_tid) == 1
+    for child, parent in parents.items():
+        mine = [s for s in spans if s["name"] == child]
+        assert mine, child
+        for s in mine:
+            assert s["tid"] in loop_tid
+            hosts = [p for p in spans if p["name"] == parent and _inside(s, p)]
+            assert len(hosts) == 1, (child, parent)
+            assert s["depth"] == hosts[0]["depth"] + 1
+    assert all(s["depth"] == 0 for s in spans if s["name"] == "iteration")
+
+
+@pytest.mark.parametrize("traced_run", sorted(BACKENDS), indirect=True)
+def test_iterations_are_numbered_by_the_engines_steps(traced_run):
+    its = [s for s in traced_run["spans"] if s["name"] == "iteration"]
+    assert [s["args"]["step"] for s in its] == list(range(len(its)))
+    assert all({"active", "queued"} <= set(s["args"]) for s in its)
+    assert its[0]["args"]["queued"] == 3 and its[0]["args"]["active"] == 0
+
+
+def test_a_compile_inside_an_iteration_carries_its_number(traced):
+    """What the runtime does behind the loop's back names the iteration it fell
+    into: a model no other test compiled, so both programs compile here, the
+    prefill program and the first decode step inside iteration 0."""
+    cfg = CFG.replace(vocab_size=136)
+    eng = Engine(modeling.init_model_params(jax.random.key(1), cfg), cfg, num_slots=2,
+                 prefill_chunk=8, start_loop=False)
+    try:
+        req = eng.submit_request([1, 2, 3], 4)
+        _drive(eng, [req])
+    finally:
+        eng.close()
+    compiles = {s["args"]["fun_name"]: s for s in _spans(traced, "jax_compile")
+                if s["args"]["fun_name"] in ("jit(_prefill_chunk)", "jit(_decode_step)")}
+    assert len(compiles) == 2
+    assert all(s["args"]["step"] == 0 for s in compiles.values())
+    lowered = [s for s in _spans(traced, "jax_lower") if "decode_step" in s["args"]["fun_name"]]
+    assert lowered and lowered[0]["args"]["step"] == 0
+    first = _spans(traced, "iteration")[0]
+    assert all(_inside(s, first) for s in compiles.values())
+
+
+@pytest.mark.parametrize("traced_run", sorted(BACKENDS), indirect=True)
+def test_the_three_children_cover_the_shared_forward(traced_run):
+    spans, fwd = traced_run["spans"], traced_run["forward"]
+    forwards = [s for s in spans if s["name"] == fwd]
+    assert forwards and not [s for s in spans if s["name"] in ("decode", "decode_verify")
+                             and s["name"] != fwd]
+    for f in forwards:
+        kids = [s for s in spans if s["name"] in ("decode_dispatch", "decode_wait",
+                                                  "logits_readback") and _inside(s, f)]
+        assert [k["name"] for k in sorted(kids, key=lambda k: k["ts"])] == [
+            "decode_dispatch", "decode_wait", "logits_readback"]
+        # what the three leave uncovered is two span exits and two entries
+        assert f["dur"] - sum(k["dur"] for k in kids) < max(200.0, 0.02 * f["dur"])
+        assert f["args"]["active"] >= 1
+    wait = [s for s in spans if s["name"] == "decode_wait"]
+    assert all(s["args"].get("synced") for s in wait)
+    width = 3 if fwd == "decode_verify" else 1
+    assert {s["args"]["bytes"] for s in spans if s["name"] == "logits_readback"} == {
+        2 * width * CFG.vocab_size * np.dtype(CFG.dtype).itemsize}
+
+
+@pytest.mark.parametrize("traced_run", sorted(BACKENDS), indirect=True)
+def test_one_requests_spans_and_instants_share_its_rid(traced_run):
+    spans, reqs = traced_run["spans"], traced_run["reqs"]
+    for r in reqs:
+        assert len([s for s in spans if s["name"] == "queue_wait" and s["args"]["rid"] == r.rid]) == 1
+        assert len([s for s in spans if s["name"] == "prefill" and s["args"]["rid"] == r.rid]) == 1
+        instants = {e["name"] for e in traced_run["all"]
+                    if e["ph"] == "i" and e["args"].get("rid") == r.rid}
+        assert {"req_queued", "req_prefilling", "req_decoding"} <= instants
+    for s in spans:
+        if s["name"] == "sample_slot":
+            assert {"slot", "rid", "greedy"} <= set(s["args"])
+    by_rid = {r.rid: r for r in reqs}
+    for s in (s for s in spans if s["name"] == "sample_slot"):
+        assert s["args"]["greedy"] == (by_rid[s["args"]["rid"]].temperature <= 0)
+    # a slot that draws opens one; the cancelled request's slot opened none when
+    # it was found cancelled, so it drew as often as it has tokens
+    cancelled = reqs[1]
+    assert cancelled.state == "CANCELLED"
+    drew = [s for s in spans if s["name"] == "sample_slot" and s["args"]["rid"] == cancelled.rid]
+    if traced_run["forward"] == "decode":
+        assert len(drew) == len(cancelled.generated) == len(cancelled.token_times)
+    else:  # a verify step appends the accepted drafts without a draw of their own
+        assert len(drew) <= len(cancelled.generated) == len(cancelled.token_times)
+
+
+def test_the_queues_wait_lies_on_a_track_of_its_own(params, traced):
+    """One slot, three requests: the third waits while the first two are
+    served, on a track that is no thread's."""
+    eng = _engine(params, num_slots=1)
+    try:
+        reqs = [eng.submit_request([1, 2, 3], 5) for _ in range(3)]
+        _drive(eng, reqs)
+    finally:
+        eng.close()
+    waits = {s["args"]["rid"]: s for s in _spans(traced, "queue_wait")}
+    assert set(waits) == {r.rid for r in reqs}
+    first, third = reqs[0], reqs[2]
+    service_us = 1e6 * (first.finished_at - first.admitted_at)
+    assert waits[third.rid]["dur"] >= service_us > 0
+    assert waits[third.rid]["dur"] == pytest.approx(
+        1e6 * (third.admitted_at - third.submitted_at), abs=1.0)
+    loop_tid = {s["tid"] for s in _spans(traced, "iteration")}
+    assert {s["tid"] for s in waits.values()} == {tracing._track_tid("serving queue")}
+    assert not loop_tid & {tracing._track_tid("serving queue")}
+    assert {s["tname"] for s in waits.values()} == {"serving queue"}
+    assert [waits[r.rid]["args"]["depth"] for r in reqs] == [2, 1, 0]
+    doc = tracing.chrome_trace(traced.snapshot())
+    assert "serving queue" in {e["args"]["name"] for e in doc["traceEvents"] if e["ph"] == "M"}
+
+
+def test_an_iteration_is_the_profilers_step_while_a_window_is_open(params, traced, monkeypatch):
+    seen = []
+
+    class Spy:
+        def __init__(self, name, **kw):
+            self.rec = (type(self).__name__, name, kw)
+
+        def __enter__(self):
+            seen.append(self.rec)
+
+        def __exit__(self, *exc):
+            pass
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", type("TraceAnnotation", (Spy,), {}))
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation",
+                        type("StepTraceAnnotation", (Spy,), {}))
+    eng = _engine(params)
+    try:
+        req = eng.submit_request([1, 2, 3], 3)
+        eng.step_once()
+        assert seen == []
+        traced.profiling = True
+        eng.step_once()
+        traced.profiling = False
+        _drive(eng, [req])
+    finally:
+        traced.profiling = False
+        eng.close()
+    assert seen[0] == ("StepTraceAnnotation", "serve", {"step_num": 1})
+    assert [name for _, name, _ in seen[1:]] == [
+        "sample", "sample_slot", "decode", "decode_dispatch", "decode_wait", "logits_readback"]
+
+
+def test_an_idle_engine_opens_no_iteration(params, traced):
+    eng = _engine(params)
+    try:
+        for _ in range(3):
+            eng.step_once()
+    finally:
+        eng.close()
+    assert _spans(traced) == []
+
+
+# --- the tap ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("backend", ["slot-plain", "paged-plain"])
+def test_tapped_rows_equal_the_benchmarks_own_bit_for_bit(params, backend):
+    """Both taps on the SAME requests in one run: the program's
+    ``capture_logits`` and the benchmark's ``serve.stamp`` (which swaps the
+    request's list and copies ``Engine._last_logits[req.slot]``)."""
+    from benchmark.lib import serve
+
+    eng = _engine(params, backend)
+    try:
+        store = serve.RowStore(64, CFG.vocab_size)
+        bufs = [np.full((n, CFG.vocab_size), np.nan, np.float32) for n in (7, 5, 4)]
+        where = [b.ctypes.data for b in bufs]
+        reqs = [eng.submit_request([1, 2, 3, 4, 5], 7, capture_logits=bufs[0]),
+                eng.submit_request([7, 8, 9], 5, temperature=0.8, top_p=0.95,
+                                   capture_logits=bufs[1]),
+                eng.submit_request([9, 8, 7, 6], 4, temperature=1e-4, capture_logits=bufs[2])]
+        stamped = [serve.stamp(eng, r, greedy=r.temperature < 1e-3, store=store) for r in reqs]
+        _drive(eng, reqs)
+    finally:
+        eng.close()
+    for r, buf, st, addr in zip(reqs, bufs, stamped, where):
+        assert r.capture_logits is buf and buf.ctypes.data == addr  # the caller's, not a copy
+        assert r.logits_rows == len(r.generated) == r.max_new_tokens
+        assert all(k is not None for k in st.lines)
+        theirs = np.stack([store.buf[k] for k in st.lines])
+        assert np.array_equal(buf[:r.logits_rows].view(np.uint32), theirs.view(np.uint32))
+        assert st.stamps == sorted(st.stamps)
+        # the program's token times are read after the benchmark's stamp of the same token
+        assert all(a <= b for a, b in zip(st.stamps, r.token_times))
+        assert all(a <= b for a, b in zip(r.token_times, st.stamps[1:]))
+        if r.temperature < 1e-3:
+            assert list(buf[:r.logits_rows].argmax(-1)) == r.generated and st.not_best == 0
+
+
+def test_a_tap_on_an_answer_cut_short_by_eos_counts_the_row_it_ended_on(params):
+    eng = _engine(params)
+    try:
+        probe = eng.submit_request([1, 2, 3, 4, 5], 6)
+        _drive(eng, [probe])
+        # greedy: the same prompt draws the same tokens, and ends on the first
+        # draw of ``eos``; take the token that first shows latest
+        firsts = {}
+        for i, tok in enumerate(probe.generated):
+            firsts.setdefault(tok, i)
+        eos, j = max(firsts.items(), key=lambda kv: kv[1])
+        eng.eos_id = eos
+        buf = np.zeros((6, CFG.vocab_size), np.float32)
+        req = eng.submit_request([1, 2, 3, 4, 5], 6, capture_logits=buf)
+        _drive(eng, [req])
+    finally:
+        eng.close()
+    assert req.finish_reason == "eos" and req.generated == probe.generated[:j]
+    assert req.logits_rows == j + 1 == len(req.token_times) + 1
+    assert int(buf[j].argmax()) == eos and buf[:j + 1].any(-1).all() and not buf[j + 1:].any()
+
+
+@pytest.mark.parametrize("bad,why", [
+    (np.zeros((3, 128), np.float32), "too few rows"),
+    (np.zeros((4, 127), np.float32), "another vocabulary"),
+    (np.zeros((4, 128), np.float64), "not float32"),
+    (np.zeros((4 * 128,), np.float32), "not two-dimensional"),
+    ([[0.0] * 128] * 4, "not an array"),
+    ("readonly", "not writable"),
+])
+def test_a_tap_the_loop_could_not_write_is_refused_at_submit(params, bad, why):
+    if isinstance(bad, str):
+        bad = np.zeros((4, 128), np.float32)
+        bad.flags.writeable = False
+    eng = _engine(params)
+    try:
+        with pytest.raises(ValueError, match="capture_logits must be a writable float32"):
+            eng.submit_request([1, 2, 3], 4, capture_logits=bad)
+        assert eng.scheduler.empty(), why
+    finally:
+        eng.close()
+
+
+def test_a_tap_on_a_speculating_engine_is_refused_by_name(params):
+    eng = _engine(params, "slot-verify")
+    try:
+        with pytest.raises(ValueError, match="capture_logits is not supported with spec_decode_k"):
+            eng.submit_request([1, 2, 3], 4, capture_logits=np.zeros((4, 128), np.float32))
+    finally:
+        eng.close()
+
+
+def test_the_engine_still_appends_to_the_list_it_was_handed(params):
+    """The invariant the benchmark stands on until it switches to the tap: one
+    ``append`` a token on ``req.generated``, with ``req.slot`` set and the row
+    the token was drawn from in ``_last_logits[req.slot]``."""
+    eng = _engine(params)
+    seen = []
+
+    class Spy(list):
+        def append(self, tok):
+            seen.append((req.slot, int(eng._last_logits[req.slot].argmax()), tok))
+            super().append(tok)
+
+    try:
+        req = eng.submit_request([1, 2, 3, 4], 5)
+        req.generated = Spy()
+        _drive(eng, [req])
+    finally:
+        eng.close()
+    assert len(seen) == 5 and all(slot is not None and best == tok for slot, best, tok in seen)

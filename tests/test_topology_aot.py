@@ -688,6 +688,45 @@ def test_serving_programs_write_the_slot_cache_in_place(program, slots, spec_k, 
     assert not moved, moved[:4]
 
 
+def test_serving_decode_step_names_its_device_work(one_chip, real_mosaic):
+    """The decode step of the cell ``opt-1.3b_serve_above_knee`` (8 slots x 2048
+    at ``opt-1.3b`` widths) as the chip's compiler leaves it: at least nine in ten
+    of the ENTRY computation's fusions carry a scope of the cached forward
+    (``embed``, ``layer_<i>/attn/{qkv_proj,cache_write,attn_core,out_proj}``,
+    ``mlp``, ``norm``, ``head``), so a device trace of a serving iteration reads
+    by name, and every layer's cache updates sit under ``cache_write``. (Here
+    395 of 420; the rest are 24 ``slice_bitcast_fusion`` the compiler makes of
+    a layer's q / k / v split, which it names after nothing.)"""
+    import re
+
+    from galvatron_tpu.aot import registry
+    from galvatron_tpu.models.modeling import PRESETS
+    from galvatron_tpu.serving import engine  # noqa: F401  (registers the serving family)
+
+    cfg = PRESETS["opt-1.3b"]
+    ctx = registry.ProgramContext(cfg=cfg, num_slots=8, prefill_chunk=256, max_seq_len=2048)
+    spec, = [sp for sp in registry.enumerate_programs(ctx, include=("serving",))
+             if sp.name == "serving_decode"]
+    args = [a if a is cfg else jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), a)
+        for a in spec.args]
+    text = spec.fn.lower(*args).compile().as_text()
+    # fusions only: the 26 ``ConcatBitcast`` custom calls are the compiler's own
+    # (it joins the slices of a weight it prefetched) and carry no metadata at all
+    work = [(n, op) for n, op in _entry_work(text) if not n.startswith("custom-call")]
+    scoped = [n for n, op in work if _has_scope(op)]
+    assert len(work) > 400 and len(scoped) >= 0.9 * len(work), (
+        len(scoped), len(work), [n for n, op in work if not _has_scope(op)][:8])
+    names = re.findall(r'op_name="([^"]*)"', text)
+    for i in (0, cfg.num_layers - 1):
+        for scope in ("qkv_proj", "cache_write", "attn_core", "out_proj"):
+            assert any(f"/layer_{i}/attn/{scope}" in n for n in names), (i, scope)
+        assert any(f"/layer_{i}/mlp" in n for n in names), i
+    updates = [line for line in _entry_lines(text) if " dynamic-update-slice(" in line]
+    assert len(updates) == 2 * 8 * cfg.num_layers
+    assert all("/attn/cache_write" in line for line in updates)
+
+
 def test_flash_multichip_compile_smoke(topo, real_mosaic):
     """One minimal multi-chip flash compile in the default selection — the
     cheapest canary for the Mosaic-partitioning failure class (a regression
