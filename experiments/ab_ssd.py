@@ -74,7 +74,7 @@ def device_ops(fn, args, calls=5, top=6):
     return sorted(sums.items(), key=lambda kv: -kv[1])[:top]
 
 
-def measure(name, fn, args, cot):
+def measure(name, fn, args, cot, top=6):
     """fn(*args) -> y; times y, its gradients, and the gradients under remat."""
     loss = lambda *t: jnp.sum(fn(*t).astype(jnp.float32) * cot)  # noqa: E731
     argnums = tuple(range(len(args)))
@@ -86,7 +86,7 @@ def measure(name, fn, args, cot):
     for key, f in (("fwd", fwd), ("fwd_bwd", grad), ("remat_fwd_bwd", remat)):
         mem = f.lower(*args).compile().memory_analysis()
         out[key + "_temp_mb"] = mem.temp_size_in_bytes / 2**20
-    out["remat_device_ops_ms"] = device_ops(remat, args)
+    out["remat_device_ops_ms"] = device_ops(remat, args, top=top)
     return out, fwd(*args), grad(*args)
 
 

@@ -240,15 +240,16 @@ def _train_impl(ns: argparse.Namespace, verbose: bool, tracer,
         # how many decoder layers of each kind the run has (a hybrid stack:
         # {"ssm": 9, "attention": 1} for one granite period)
         layer_kinds = dict(collections.Counter(rt.cfg.kinds))
-        # which scan its state-space layers take (ops/ssd.scan_path: the fused
-        # kernels or the plain body); a stack without such layers loads neither
-        ssm_scan_path = {"fused": 0, "plain": 0}
+        # which scan and which conv its state-space layers take (ops/ssd.scan_path,
+        # conv_path: the fused kernels or the plain body); a stack without such
+        # layers loads neither
+        ssm_scan_path, ssm_conv_path = {"fused": 0, "plain": 0}, {"fused": 0, "plain": 0}
         if layer_kinds.get("ssm"):
-            from galvatron_tpu.ops.ssd import scan_path_counts
+            from galvatron_tpu.ops.ssd import conv_path_counts, scan_path_counts
 
-            ssm_scan_path = scan_path_counts(rt.cfg)
+            ssm_scan_path, ssm_conv_path = scan_path_counts(rt.cfg), conv_path_counts(rt.cfg)
         build_span.set(tp_overlap_seams=rt.tp_overlap_seams, layer_kinds=layer_kinds,
-                       ssm_scan_path=ssm_scan_path)
+                       ssm_scan_path=ssm_scan_path, ssm_conv_path=ssm_conv_path)
 
     from galvatron_tpu.obs import tracing as obs_tracing
     from galvatron_tpu.utils.metrics import SCHEMA_VERSION, MetricsLogger
@@ -288,6 +289,7 @@ def _train_impl(ns: argparse.Namespace, verbose: bool, tracer,
         "tp_overlap_seams": rt.tp_overlap_seams,
         "layer_kinds": layer_kinds,
         "ssm_scan_path": ssm_scan_path,
+        "ssm_conv_path": ssm_conv_path,
     }
     # JAX's persistent compile cache is always on, at the one place
     # resolve_compile_cache_dir names (JAX_COMPILATION_CACHE_DIR, else an

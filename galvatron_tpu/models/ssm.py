@@ -20,6 +20,7 @@ Scopes under ``ssm``: ``in_proj``, ``conv``, ``scan``, ``gate_norm``,
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict
 
 import jax
@@ -27,7 +28,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from galvatron_tpu.models.placement import LOCAL, Placement
-from galvatron_tpu.ops.ssd import causal_conv1d, scan_path, ssd_scan
+from galvatron_tpu.ops.ssd import (
+    causal_conv1d, conv_path, conv_silu_fused, conv_windows, scan_path, ssd_scan)
 
 Params = Dict[str, Any]
 F32 = jnp.float32
@@ -79,27 +81,50 @@ def ssm_annotations(cfg) -> Params:
     }
 
 
+def conv_split(zxbcdt, w, b, cfg, place: Placement = LOCAL):
+    """``silu(conv1d(xBC) + b)`` of in_proj's output (B, S, z | xBC | dt), as the
+    three arrays the scan takes: x (B, S, d_inner), B and C (B, S, G N). Where
+    `ops/ssd.conv_path` says fused, each is one window of channels that the
+    kernels read out of ``zxbcdt`` where it lies (no slice in front, none
+    behind), under ``place.shard_kernel`` on a mesh like the scan; everywhere
+    else `causal_conv1d` + ``jax.nn.silu`` on the sliced channels, as ever."""
+    windows = conv_windows(cfg)
+    d_inner = windows[0]
+    starts = (0, d_inner, d_inner + windows[1])
+    if conv_path(windows, cfg.ssm_conv, zxbcdt.dtype) == "fused":
+        rows, whole = (0, None), (None, None)  # batch over the data-parallel axes
+
+        def window(a, n):
+            conv = functools.partial(conv_silu_fused, col0=d_inner + a)
+            return place.shard_kernel(conv, [rows, whole, whole], rows)(
+                zxbcdt, w[:, a:a + n], b[a:a + n])
+
+        return tuple(window(a, n) for a, n in zip(starts, windows))
+    xbc = jax.nn.silu(causal_conv1d(zxbcdt[..., d_inner:d_inner + sum(windows)], w, b))
+    return tuple(xbc[..., a:a + n] for a, n in zip(starts, windows))
+
+
 @jax.named_scope("ssm")
 def ssm_block(x, p: Params, cfg, place: Placement = LOCAL):
     """(B, S, hidden) normed layer input -> the mixer's output, same shape.
-    ``place`` (models/placement.py) is asked for one thing: where the scan is
-    the fused kernels, a mesh must run them on each device's own batch rows
-    (GSPMD partitions the plain body by itself, a Mosaic call it cannot)."""
+    ``place`` (models/placement.py) is asked for one thing: where the conv or
+    the scan is the fused kernels, a mesh must run them on each device's own
+    batch rows (GSPMD partitions the plain bodies by itself, a Mosaic call it
+    cannot)."""
     dtype = x.dtype
     heads, hd, groups, state = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state
     d_inner, conv_dim, _ = ssm_dims(cfg)
     with jax.named_scope("in_proj"):
         zxbcdt = x @ p["in_proj"].astype(dtype)
         z = zxbcdt[..., :d_inner]
-        xbc = zxbcdt[..., d_inner:d_inner + conv_dim]
         dt = zxbcdt[..., d_inner + conv_dim:]
     with jax.named_scope("conv"):
-        xbc = jax.nn.silu(causal_conv1d(xbc, p["conv_w"], p["conv_b"]))
+        xs, b_mat, c_mat = conv_split(zxbcdt, p["conv_w"], p["conv_b"], cfg, place)
     with jax.named_scope("scan"):
-        lead = xbc.shape[:2]
-        xs = xbc[..., :d_inner].reshape(*lead, heads, hd)
-        b_mat = xbc[..., d_inner:d_inner + groups * state].reshape(*lead, groups, state)
-        c_mat = xbc[..., d_inner + groups * state:].reshape(*lead, groups, state)
+        lead = xs.shape[:2]
+        xs = xs.reshape(*lead, heads, hd)
+        b_mat = b_mat.reshape(*lead, groups, state)
+        c_mat = c_mat.reshape(*lead, groups, state)
         dt = jax.nn.softplus(dt.astype(F32) + p["dt_bias"].astype(F32))
         scan = lambda *t: ssd_scan(*t, cfg.ssm_chunk)  # noqa: E731
         if scan_path(heads, hd, groups, state, cfg.ssm_chunk, dtype) == "fused":

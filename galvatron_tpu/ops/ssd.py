@@ -1,5 +1,6 @@
 """State-space duality (SSD) scan of a Mamba-2 layer, chunked, and the causal
-depthwise convolution in front of it.
+depthwise convolution in front of it (plain, `causal_conv1d`, and with its SiLU
+as one op over two kernels, `conv_silu_fused`: the last section of this file).
 
 The recurrence, per head (``H`` in R^{P x N}; one scalar decay a head)::
 
@@ -575,3 +576,274 @@ def _core_bwd(chunk, n, res, dy):
 
 
 _ssd_core.defvjp(_core_fwd, _core_bwd)
+
+
+# -- the causal conv and its SiLU as one op ---------------------------------------
+#
+# ``silu(causal_conv1d(x, w, b))`` as a ``jax.custom_vjp`` over two kernels,
+# ``ssm_conv_fwd`` and ``ssm_conv_bwd``: x is read once forward and once
+# backward, in the compute dtype, where the plain path pads it, takes K slices
+# shifted by sublanes, converts each to float32 and leaves autodiff K float32
+# pads and K full-size reductions (PERF.md §6, PR 40). The op reads a window of
+# channels out of a wider array in place (``col0``: `models/ssm.ssm_block` hands
+# it in_proj's whole output, and takes x, B and C as three windows, so neither
+# the slice in front nor the slices behind are copies).
+#
+# Grid (batch, channel block, sequence block), the sequence axis sequential. A
+# grid step walks its block in strips of `_CONV_STRIP` rows that stay in
+# registers; the shifts are rolls along the sublanes of a strip with the 8 rows
+# next to it on top (forward: the rows before, carried from strip to strip and,
+# in VMEM scratch, from block to block) or below (backward, which walks the
+# sequence from its end: the first rows of ``dpre`` of the strip after). The
+# backward recomputes the pre-activation from x (its only (S, C)-sized
+# residual is the op's own input; the rows before a block come through a second,
+# 16-row view of x) and sums ``dw`` and ``db`` over the sequence in the float32
+# output block that stays in VMEM, 8 partial rows a tap, folded outside.
+#
+# Precision: float32 taps, accumulation, SiLU and SiLU', ONE rounding to the
+# compute dtype at the end (the plain path rounds the conv's result and then
+# the SiLU's; the backward here sees the unrounded pre-activation).
+
+_HALO = 8  # float32 rows a strip takes from its neighbour: one sublane tile, K - 1 <= 8
+_HALO_VIEW = 16  # rows of the view of x before a block: one tile of a 16-bit dtype
+_MAX_TAPS = 4
+_CONV_STRIP = 32
+_CONV_BLOCK_S = 1024
+_CONV_BLOCK_C = 512
+
+
+def _conv_blocks(s: int, channels: int, col0: int):
+    """(sequence block, channel block) of a window ``channels`` wide that
+    starts at column ``col0``: the widest channel block of whole lane tiles
+    that divides both; a sequence shorter than a block is one block of whole
+    strips."""
+    tc = _CONV_BLOCK_C
+    while tc > _LANES and (channels % tc or col0 % tc):
+        tc //= 2
+    return min(_CONV_BLOCK_S, -(-s // _CONV_STRIP) * _CONV_STRIP), tc
+
+
+def _conv_vmem_mb(ts: int, tc: int, k: int, itemsize: int) -> float:
+    """What a backward step holds in VMEM: the blocks of x, the cotangent and
+    dx and the view before x (two buffers each), the sums' block, the carried
+    rows, and a strip's float32 temporaries should they all spill."""
+    blocks = 2 * ((3 * ts + _HALO_VIEW) * tc * itemsize + (k + 1) * _HALO * tc * 4)
+    return (blocks + _HALO * tc * 4 + (2 * k + 8) * (_CONV_STRIP + _HALO) * tc * 4) / 2**20
+
+
+def conv_path(windows, k: int, dtype) -> str:
+    """``"fused"`` or ``"plain"`` for the conv + SiLU in front of a scan, from
+    the shapes and the backend alone, as `scan_path`: `models/ssm.ssm_block`
+    and the trainer's ``ssm_conv_path`` counter both ask here. ``windows``:
+    the widths of the channel groups the mixer takes apart (x, B, C). Fused:
+
+    - a chip (`flash_attention._use_interpret`'s rule);
+    - every window whole 128-lane tiles (so the channels are, and each window
+      starts on a tile of in_proj's output if the first does);
+    - at most `_MAX_TAPS` taps: the K - 1 rows before a strip come with the
+      8 rows it takes over, and dw's K rows and db's share one output block;
+    - bf16 or float32 compute;
+    - a VMEM charge (`_conv_vmem_mb`, 7.5 MB at the granite sizes) inside
+      `flash_attention._seq_envelope`'s budget.
+
+    Everything else takes `causal_conv1d` + ``jax.nn.silu``."""
+    dtype = jnp.dtype(dtype)
+    if (fa._use_interpret() or dtype not in (jnp.bfloat16, jnp.float32)
+            or not 1 <= k <= _MAX_TAPS or any(w <= 0 or w % _LANES for w in windows)):
+        return "plain"
+    ts, tc = _conv_blocks(_CONV_BLOCK_S, max(windows), 0)
+    inside = 1.1 * _conv_vmem_mb(ts, tc, k, dtype.itemsize) <= fa._VMEM_EFF_MB
+    return "fused" if inside else "plain"
+
+
+def conv_windows(cfg):
+    """Widths of x, B and C among the conv's channels."""
+    gn = cfg.ssm_groups * cfg.ssm_state
+    return cfg.ssm_heads * cfg.ssm_head_dim, gn, gn
+
+
+def conv_path_counts(cfg) -> dict:
+    """``{"fused": n, "plain": m}``: how many of a configuration's state-space
+    layers take which conv (the run's fingerprint, PERF.md §3)."""
+    counts = {"fused": 0, "plain": 0}
+    layers = sum(kind == "ssm" for kind in cfg.kinds)
+    if layers:
+        counts[conv_path(conv_windows(cfg), cfg.ssm_conv, cfg.dtype)] = layers
+    return counts
+
+
+def conv_silu_fused(x, w, b, col0: int = 0):
+    """``silu(causal_conv1d(x[..., col0:col0 + C], w, b))`` through the kernels:
+    ``x`` (B, S, W) with W >= col0 + C, ``w`` (K, C), ``b`` (C,) -> (B, S, C) in
+    ``x``'s dtype, for sizes inside `conv_path`'s envelope (C and ``col0`` whole
+    lane tiles). A sequence is padded at its end to whole blocks (causal: nothing
+    earlier moves; the cotangent of the padding is zero)."""
+    s = x.shape[1]
+    pad = -s % _conv_blocks(s, w.shape[1], col0)[0]
+    if pad:
+        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
+    return _conv_core(x, w, b, col0)[:, :s]
+
+
+def _down(x, before, j):
+    """Row t of the result is row t - j of ``x`` (R, C); above row 0 the last
+    rows of ``before`` (8, C)."""
+    if j == 0:
+        return x
+    return pltpu.roll(jnp.concatenate([before, x], axis=0), j, 0)[_HALO:]
+
+
+def _up(d, after, j):
+    """Row t of the result is row t + j of ``d`` (R, C); below its last row the
+    first rows of ``after`` (8, C)."""
+    if j == 0:
+        return d
+    rows = d.shape[0]
+    return pltpu.roll(jnp.concatenate([d, after], axis=0), rows + _HALO - j, 0)[:rows]
+
+
+def _conv_fwd_kernel(x_ref, w_ref, b_ref, y_ref, tail, *, k):
+    strip = _CONV_STRIP
+
+    @pl.when(pl.program_id(2) == 0)
+    def _start():  # before the sequence: zeros
+        tail[...] = jnp.zeros_like(tail)
+
+    taps = [w_ref[j:j + 1, :] for j in range(k)]
+    bias = b_ref[...]
+
+    def piece(i, before):
+        r = pl.multiple_of(i * strip, strip)
+        x = x_ref[0, pl.ds(r, strip), :].astype(F32)
+        pre = bias + taps[k - 1] * x
+        for j in range(1, k):
+            pre = pre + taps[k - 1 - j] * _down(x, before, j)
+        y_ref[0, pl.ds(r, strip), :] = (pre * jax.nn.sigmoid(pre)).astype(y_ref.dtype)
+        return x[strip - _HALO:]
+
+    tail[...] = jax.lax.fori_loop(0, x_ref.shape[1] // strip, piece, tail[...])
+
+
+def _fold(v):
+    """(R, C) -> (8, C): the rows summed eight apart (whole-register adds; the
+    last eight are summed outside the kernel)."""
+    return sum(v[i:i + _HALO] for i in range(0, v.shape[0], _HALO))
+
+
+def _conv_bwd_kernel(x_ref, view_ref, g_ref, w_ref, b_ref, dx_ref, sums_ref, head, *, k):
+    strip = _CONV_STRIP
+    step = pl.program_id(2)  # the blocks come from the sequence's end
+
+    @pl.when(step == 0)
+    def _start():  # after the sequence: no cotangent
+        head[...] = jnp.zeros_like(head)
+        sums_ref[...] = jnp.zeros_like(sums_ref)
+
+    taps = [w_ref[j:j + 1, :] for j in range(k)]
+    bias = b_ref[...]
+
+    def piece(r, before, after):
+        x = x_ref[0, pl.ds(r, strip), :].astype(F32)
+        moved = [_down(x, before, j) for j in range(k)]  # x_{t-j}
+        pre = bias + taps[k - 1] * x
+        for j in range(1, k):
+            pre = pre + taps[k - 1 - j] * moved[j]
+        sig = jax.nn.sigmoid(pre)
+        dpre = g_ref[0, pl.ds(r, strip), :].astype(F32) * (sig * (1.0 + pre * (1.0 - sig)))
+        dx = taps[k - 1] * dpre
+        for j in range(1, k):
+            dx = dx + taps[k - 1 - j] * _up(dpre, after, j)
+        dx_ref[0, pl.ds(r, strip), :] = dx.astype(dx_ref.dtype)
+        for j in range(k):  # dw[K-1-j] = sum_t dpre_t x_{t-j}
+            rows = slice((k - 1 - j) * _HALO, (k - j) * _HALO)
+            sums_ref[0, rows, :] += _fold(dpre * moved[j])
+        sums_ref[0, k * _HALO:, :] += _fold(dpre)
+        return dpre[:_HALO]
+
+    strips = x_ref.shape[1] // strip
+
+    def inner(i, after):
+        r = pl.multiple_of((strips - 1 - i) * strip, strip)
+        above = x_ref[0, pl.ds(pl.multiple_of(r - _HALO_VIEW, _HALO_VIEW), _HALO_VIEW), :]
+        return piece(r, above.astype(F32)[_HALO_VIEW - _HALO:], after)
+
+    after = jax.lax.fori_loop(0, strips - 1, inner, head[...])
+    # the block's first strip: the rows before it are another block's, or none
+    above = view_ref[0].astype(F32)[_HALO_VIEW - _HALO:]
+    first = step == pl.num_programs(2) - 1
+    head[...] = piece(0, jnp.where(first, 0.0, above), after)
+
+
+def _conv_small(w, b, tc):
+    """The taps and the bias as the kernels read them, float32 (8, C) (rows K..7
+    zero) and (1, C), and their specs: a channel block, whatever the batch row
+    and the sequence block."""
+    vec = lambda rows: pl.BlockSpec((rows, tc), lambda b_, c, s: (0, c))  # noqa: E731
+    w8 = jnp.pad(w.astype(F32), ((0, _HALO - w.shape[0]), (0, 0)))
+    return (w8, b.astype(F32).reshape(1, -1)), [vec(_HALO), vec(1)]
+
+
+def _conv_fwd_call(x, w, b, col0):
+    ts, tc = _conv_blocks(x.shape[1], w.shape[1], col0)
+    c0 = col0 // tc
+    small, small_specs = _conv_small(w, b, tc)
+    return pl.pallas_call(
+        functools.partial(_conv_fwd_kernel, k=w.shape[0]),
+        grid=(x.shape[0], w.shape[1] // tc, x.shape[1] // ts),
+        in_specs=[pl.BlockSpec((1, ts, tc), lambda b_, c, s: (b_, s, c0 + c)), *small_specs],
+        out_specs=pl.BlockSpec((1, ts, tc), lambda b_, c, s: (b_, s, c)),
+        out_shape=jax.ShapeDtypeStruct((*x.shape[:2], w.shape[1]), x.dtype),
+        scratch_shapes=[pltpu.VMEM((_HALO, tc), F32)],
+        compiler_params=fa._compiler_params(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=fa._use_interpret(), name="ssm_conv_fwd",
+    )(x, *small)
+
+
+def _conv_bwd_call(x, w, b, col0, g):
+    ts, tc = _conv_blocks(x.shape[1], w.shape[1], col0)
+    (bsz, sp), (k, channels) = x.shape[:2], w.shape
+    c0, last, per = col0 // tc, sp // ts - 1, ts // _HALO_VIEW
+    small, small_specs = _conv_small(w, b, tc)
+    window = pl.BlockSpec((1, ts, tc), lambda b_, c, s: (b_, last - s, c0 + c))
+    own = pl.BlockSpec((1, ts, tc), lambda b_, c, s: (b_, last - s, c))
+    view = pl.BlockSpec((1, _HALO_VIEW, tc),
+                        lambda b_, c, s: (b_, jnp.maximum((last - s) * per - 1, 0), c0 + c))
+    rows = (k + 1) * _HALO
+    dx, sums = pl.pallas_call(
+        functools.partial(_conv_bwd_kernel, k=k),
+        grid=(bsz, channels // tc, sp // ts),
+        in_specs=[window, view, own, *small_specs],
+        out_specs=[own, pl.BlockSpec((1, rows, tc), lambda b_, c, s: (b_, 0, c))],
+        out_shape=[jax.ShapeDtypeStruct(g.shape, x.dtype),
+                   jax.ShapeDtypeStruct((bsz, rows, channels), F32)],
+        scratch_shapes=[pltpu.VMEM((_HALO, tc), F32)],
+        compiler_params=fa._compiler_params(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=fa._use_interpret(), name="ssm_conv_bwd",
+    )(x, x, g, *small)
+    sums = sums.reshape(bsz, k + 1, _HALO, channels).sum(axis=(0, 2))
+    return dx, sums[:k].astype(w.dtype), sums[k].astype(b.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _conv_core(x, w, b, col0):
+    """x (B, S, W) with S in whole blocks, w (K, C), b (C,) -> silu(conv) of
+    the channels col0 .. col0 + C, (B, S, C) in x's dtype."""
+    return _conv_fwd_call(x, w, b, col0)
+
+
+def _conv_core_fwd(x, w, b, col0):
+    return _conv_fwd_call(x, w, b, col0), (x, w, b)
+
+
+def _conv_core_bwd(col0, res, g):
+    x, w, b = res
+    dx, dw, db = _conv_bwd_call(x, w, b, col0, g)
+    right = x.shape[2] - col0 - w.shape[1]
+    if col0 or right:  # the window's place in the wider array
+        dx = jnp.pad(dx, ((0, 0), (0, 0), (col0, right)))
+    return dx, dw, db
+
+
+_conv_core.defvjp(_conv_core_fwd, _conv_core_bwd)

@@ -33,6 +33,9 @@ KERNEL_NAMES = {
     "moe_tgmm": "grouped_matmul.py",
     # the fused Mamba-2 scan (PR 34): read through the `scan` scope they run under
     "ssd_fwd": "ssd.py", "ssd_bwd": "ssd.py", "ssd_decay": "ssd.py", "ssd_decay_bwd": "ssd.py",
+    # the conv + SiLU in front of it as one op (PR 40): read through the `conv` scope;
+    # not `ssd_*` (tests/test_topology_aot.py holds that list to the scan's four)
+    "ssm_conv_fwd": "ssd.py", "ssm_conv_bwd": "ssd.py",
 }
 
 
@@ -67,7 +70,7 @@ def test_every_pallas_call_has_a_name_from_the_table(name):
     # and nothing outside the table: a new kernel joins it, with its metric
     assert {n for names in found.values() for n in names} == set(KERNEL_NAMES)
     assert all(n.startswith(("flash_fwd", "flash_bwd", "flash_paged", "fused_norm_",
-                             "moe_gmm", "moe_tgmm", "ssd_"))
+                             "moe_gmm", "moe_tgmm", "ssd_", "ssm_conv_"))
                for n in KERNEL_NAMES)
 
 
@@ -513,6 +516,16 @@ def test_build_runtime_span_counts_the_seams_by_name(traced_run):
     # and the scan of its state-space layers (PR 34): a stack with none counts none
     # (9 / 0 in the granite cell: tests/test_ssm.py holds the count to `ops/ssd.scan_path`)
     assert span["args"]["ssm_scan_path"] == {"fused": 0, "plain": 0}
+
+
+def test_build_runtime_span_counts_the_conv_path_beside_the_scan_path(traced_run):
+    """``ssm_conv_path`` (PR 40) on the same span, with the same two keys: which
+    conv the state-space layers take, by `ops/ssd.conv_path` (9 / 0 in the granite
+    cell: tests/test_ssm.py); a stack with no such layer counts none."""
+    events, _ = traced_run
+    (span,) = [e for e in events if e["ph"] == "X" and e["name"] == "build_runtime"]
+    assert span["args"]["ssm_conv_path"] == {"fused": 0, "plain": 0}
+    assert list(span["args"]).index("ssm_conv_path") == list(span["args"]).index("ssm_scan_path") + 1
 
 
 def test_traced_train_logs_the_profile_window(traced_run):

@@ -457,6 +457,186 @@ def test_causal_conv_is_the_published_conv1d_and_leaks_nothing_from_the_future()
     close(y[:, 0], np.asarray(x)[:, 0] * np.asarray(w)[3] + np.asarray(b), 1e-6)
 
 
+# -- the conv + SiLU as one op (`ssd.conv_silu_fused`, interpreted here) ---------
+
+# (batch, positions, width of the array, first column, channels, taps): one block
+# of three strips; three blocks of 1024 with the last one padded (the halo crosses
+# two boundaries, forward and backward); whole blocks exactly; batch 2 with two
+# taps; a window in the middle of a wider array, as the mixer reads in_proj's output
+CONV_CASES = {
+    "one_block": (1, 96, 128, 0, 128, 4),
+    "three_blocks_padded": (1, 2100, 128, 0, 128, 4),
+    "two_whole_blocks": (1, 2048, 128, 0, 128, 4),
+    "batch_2_two_taps": (2, 1100, 256, 0, 256, 2),
+    "window_of_a_wider_array": (2, 160, 900, 256, 512, 4),
+}
+
+
+def conv_case(name, dtype=jnp.float32, seed=0):
+    bsz, s, width, col0, channels, k = CONV_CASES[name]
+    ks = jax.random.split(jax.random.key(seed), 4)
+    x = jax.random.normal(ks[0], (bsz, s, width), dtype)
+    w = jax.random.uniform(ks[1], (k, channels), jnp.float32, -0.5, 0.5)
+    b = 0.3 * jax.random.normal(ks[2], (channels,))
+    return (x, w, b), col0, jax.random.normal(ks[3], (bsz, s, channels))
+
+
+def plain_conv_silu(x, w, b, col0=0):
+    return jax.nn.silu(ssd.causal_conv1d(x[..., col0:col0 + w.shape[1]], w, b))
+
+
+def published_conv_silu(x, w, b, col0=0):
+    """torch's ``silu(conv1d(x, weight (C, 1, K), bias, padding=K-1)[..., :s])`` in
+    float64 numpy: a correlation over the sequence."""
+    x, w, b = (np.asarray(t, np.float64) for t in (x, w, b))
+    k, s = w.shape[0], x.shape[1]
+    xp = np.pad(x[..., col0:col0 + w.shape[1]], ((0, 0), (k - 1, 0), (0, 0)))
+    pre = sum(xp[:, j:j + s] * w[j] for j in range(k)) + b
+    return pre / (1.0 + np.exp(-pre))
+
+
+@pytest.mark.parametrize("case", list(CONV_CASES))
+def test_fused_conv_equals_the_plain_conv_and_the_published_conv1d(case):
+    args, col0, _ = conv_case(case)
+    got = ssd.conv_silu_fused(*args, col0)
+    assert got.shape == (*args[0].shape[:2], args[1].shape[1]) and got.dtype == jnp.float32
+    close(got, plain_conv_silu(*args, col0), 1e-6)
+    close(got, published_conv_silu(*args, col0), 1e-6)
+
+
+@pytest.mark.parametrize("case", list(CONV_CASES))
+def test_fused_conv_gradients_equal_the_plain_convs(case):
+    """x (set into the wider array with zeros around it), w and b, to 1e-5 in
+    float32; the same bits again under ``jax.checkpoint``."""
+    args, col0, cot = conv_case(case, seed=3)
+    loss = lambda *t: jnp.sum(ssd.conv_silu_fused(*t, col0) * cot)  # noqa: E731
+    got = jax.grad(loss, argnums=(0, 1, 2))(*args)
+    want = jax.grad(lambda *t: jnp.sum(plain_conv_silu(*t, col0) * cot), argnums=(0, 1, 2))(*args)
+    for name, g, v in zip("x w b".split(), got, want):
+        assert g.shape == v.shape and g.dtype == v.dtype
+        try:
+            close(g, v, 1e-5)
+        except AssertionError as e:
+            raise AssertionError(f"d {name}: {e}") from None
+    again = jax.grad(jax.checkpoint(loss), argnums=(0, 1, 2))(*args)
+    for g, v in zip(again, got):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(v))
+
+
+def test_fused_conv_in_bf16_stays_within_what_bf16_warrants():
+    """y and the three gradients of the bf16 kernels against float32 on the same
+    (rounded) operands: inside the bf16 tolerance and no worse than the plain
+    path, which rounds the conv's result before the SiLU where the op rounds once."""
+    args, col0, cot = conv_case("three_blocks_padded", jnp.bfloat16, seed=4)
+    ref = (args[0].astype(jnp.float32), *args[1:])
+    grads = lambda fn, a: jax.grad(  # noqa: E731
+        lambda *t: jnp.sum(fn(*t, col0).astype(jnp.float32) * cot), argnums=(0, 1, 2))(*a)
+    want = [plain_conv_silu(*ref)] + list(grads(plain_conv_silu, ref))
+
+    def errors(fn):
+        got = [fn(*args, col0)] + list(grads(fn, args))
+        assert got[0].dtype == got[1].dtype == jnp.bfloat16
+        return [float(np.abs(np.asarray(g, np.float64) - np.asarray(v, np.float64)).max()
+                      / np.abs(np.asarray(v)).max()) for g, v in zip(got, want)]
+
+    fused, plain = errors(ssd.conv_silu_fused), errors(plain_conv_silu)
+    assert max(fused) < BF16_TOL and all(f < 1.5 * p_ + 1e-3 for f, p_ in zip(fused, plain)), (
+        fused, plain)
+    assert fused[0] > 1e-5, "a bf16 result inside the float32 tolerance: nothing was rounded"
+
+
+@pytest.mark.parametrize("conv", ["plain", "fused"])
+def test_neither_conv_leaks_from_the_future_across_a_block_boundary(conv):
+    """`test_causal_conv_is_the_published_conv1d_...` on both paths at 128
+    channels and 1100 positions: a change at position 1024 (the first row of the
+    fused op's second block) moves nothing before it and moves that row; its
+    gradient reaches back exactly K - 1 rows, over the boundary."""
+    fn = {"plain": plain_conv_silu, "fused": ssd.conv_silu_fused}[conv]
+    k = jax.random.split(jax.random.key(1), 3)
+    x = jax.random.normal(k[0], (2, 1100, 128))
+    w, b = jax.random.normal(k[1], (4, 128)), jax.random.normal(k[2], (128,))
+    y = fn(x, w, b)
+    later = fn(x.at[:, 1024:].add(5.0), w, b)
+    assert float(jnp.abs(later[:, :1024] - y[:, :1024]).max()) == 0.0
+    assert float(jnp.abs(later[:, 1024] - y[:, 1024]).min()) > 0.0
+    # the first position sees itself through the last tap only
+    pre = np.asarray(x)[:, 0] * np.asarray(w)[3] + np.asarray(b)
+    close(y[:, 0], pre / (1.0 + np.exp(-pre)), 1e-6)
+    dx = jax.grad(lambda x_: jnp.sum(fn(x_, w, b)[:, 1024:1026]))(x)
+    rows = np.flatnonzero(np.abs(np.asarray(dx)).max(axis=(0, 2)))
+    assert rows.tolist() == list(range(1021, 1026))
+
+
+def test_outside_the_envelope_the_plain_conv_runs_bit_for_bit(on_a_chip):
+    """The small configurations of this file (64 + 16 + 16 channels), five taps,
+    float16, a window that is no whole lane tile: `conv_path` says plain even
+    where a chip is there, and `ssm.conv_split` is then `causal_conv1d` +
+    ``jax.nn.silu`` on the sliced channels to the bit."""
+    granite = ssd.conv_windows(PRESETS["granite-4.0-h-micro"])
+    assert granite == (4096, 128, 128)
+    assert ssd.conv_path(granite, 4, jnp.bfloat16) == "fused"
+    assert ssd.conv_path(granite, 2, jnp.float32) == "fused"
+    assert ssd.conv_path((8448, 256, 256), 4, jnp.float32) == "fused"
+    assert ssd.conv_path(granite, 5, jnp.bfloat16) == "plain"  # taps
+    assert ssd.conv_path(granite, 4, jnp.float16) == "plain"  # dtype
+    assert ssd.conv_path((4096, 64, 64), 4, jnp.bfloat16) == "plain"  # B and C half a tile
+    cfg = small_cfg()
+    assert ssd.conv_path(ssd.conv_windows(cfg), cfg.ssm_conv, cfg.dtype) == "plain"
+    d_inner, conv_dim, width = ssm.ssm_dims(cfg)
+    k = jax.random.split(jax.random.key(2), 3)
+    zxbcdt = jax.random.normal(k[0], (2, 72, width))
+    w, b = jax.random.normal(k[1], (4, conv_dim)), jax.random.normal(k[2], (conv_dim,))
+    xbc = jax.nn.silu(ssd.causal_conv1d(zxbcdt[..., d_inner:d_inner + conv_dim], w, b))
+    got = ssm.conv_split(zxbcdt, w, b, cfg)
+    assert [t.shape[-1] for t in got] == [128, 16, 16]
+    np.testing.assert_array_equal(np.asarray(jnp.concatenate(got, axis=-1)), np.asarray(xbc))
+
+
+def test_conv_path_counts_the_layers_of_a_configuration(monkeypatch):
+    """What the trainer writes as ``ssm_conv_path`` beside ``ssm_scan_path``: 9 / 0
+    for one granite period on a TPU, plain on the CPU and for the small
+    configurations, 0 / 0 for a stack without state-space layers."""
+    granite = PRESETS["granite-4.0-h-micro"].replace(num_layers=10, max_seq_len=8192,
+                                                     dtype=jnp.bfloat16)
+    from galvatron_tpu.ops import flash_attention
+
+    assert ssd.conv_path_counts(granite) == {"fused": 0, "plain": 9}  # no chip here
+    monkeypatch.setattr(flash_attention, "_use_interpret", lambda: False)
+    assert ssd.conv_path_counts(granite) == {"fused": 9, "plain": 0}
+    assert ssd.conv_path_counts(small_cfg()) == {"fused": 0, "plain": 6}
+    assert ssd.conv_path_counts(PRESETS["llama-7b"]) == {"fused": 0, "plain": 0}
+
+
+def test_the_mixer_through_the_fused_conv_equals_the_mixer_through_the_plain_one(monkeypatch):
+    """`ssm_block` at sizes inside the conv's envelope (256 + 128 + 128 channels
+    read as three windows out of in_proj's 772 columns): output and the gradient
+    of every parameter agree between the two convs, in float32."""
+    cfg = small_cfg(num_layers=1, ssm_heads=4, ssm_head_dim=64, ssm_state=128, ssm_chunk=128,
+                    max_seq_len=256)
+    assert ssm.ssm_dims(cfg) == (256, 512, 772)
+    p = ssm.init_ssm_params(jax.random.key(0), cfg)
+    p = {k: v + 0.3 * jax.random.normal(jax.random.key(i), v.shape) if v.ndim == 1 else v
+         for i, (k, v) in enumerate(p.items())}
+    x = jax.random.normal(jax.random.key(7), (2, 256, cfg.hidden_size))
+    w = jax.random.normal(jax.random.key(8), x.shape)
+    run = jax.value_and_grad(lambda x_, p_: jnp.sum(ssm.ssm_block(x_, p_, cfg) * w), argnums=(0, 1))
+    plain = run(x, p)
+    calls = []
+    monkeypatch.setattr(ssm, "conv_path", lambda *a: "fused")
+    monkeypatch.setattr(ssm, "conv_silu_fused", lambda *a, **kw: (
+        calls.append(kw["col0"]), ssd.conv_silu_fused(*a, **kw))[1])
+    fused = run(x, p)
+    assert calls == [256, 512, 640]
+    assert float(fused[0]) == pytest.approx(float(plain[0]), rel=F32_TOL)
+    for (path, g), v in zip(jax.tree_util.tree_leaves_with_path(fused[1]),
+                            jax.tree.leaves(plain[1])):
+        try:
+            close(g, v, GRAD_TOL)
+        except AssertionError as e:
+            raise AssertionError(f"{jax.tree_util.keystr(path)}: {e}") from None
+    assert float(jnp.abs(fused[1][1]["conv_b"]).max()) > 0
+
+
 # -- the runtime: layouts, refusals, the search ---------------------------------
 
 

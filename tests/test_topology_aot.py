@@ -364,6 +364,25 @@ def test_granite_mixer_compiles_at_published_widths(one_chip, real_mosaic):
     assert [n for n, _ in kernels] == ["ssd_bwd", "ssd_decay", "ssd_decay_bwd", "ssd_fwd"], kernels
     assert all("/ssm/scan/" in op for _, op in kernels), kernels
     assert not [n for n, _ in rows if n.startswith("flash_")]
+    # the conv + SiLU is the fused op (PR 40): x, B and C are three windows of in_proj's
+    # output, so ``ssm_conv_fwd`` three times (the replay) and ``ssm_conv_bwd`` three
+    # times, all under ``ssm/conv``; nothing float32 of the size of the conv's channels
+    # (or of a window) is left there, and no slice of them anywhere in the layer
+    conv = sorted((n.split(".")[0], op) for n, op in rows if n.startswith("ssm_conv_"))
+    assert [n for n, _ in conv] == ["ssm_conv_bwd"] * 3 + ["ssm_conv_fwd"] * 3, conv
+    assert all("/ssm/conv/" in op for _, op in conv), conv
+    import re
+
+    for line in _entry_lines(compiled.as_text()):
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (.*?) (fusion|slice|copy)\(", line)
+        if not m:
+            continue
+        if m.group(2) == "fusion" and "/ssm/conv/" in line:
+            assert "f32[1,8192," not in m.group(1), line
+        if m.group(2) != "fusion":
+            assert not re.search(r"\[1,8192,(4096|4352|128)\]", m.group(1)), line
+    # (the plain conv's mixer: 607.5 MiB; the entering states and the layer's activations)
+    assert temp < 500 * 2**20, f"{temp / 2**20:.1f} MiB"
 
 
 @pytest.mark.parametrize("sizes", [
@@ -389,6 +408,30 @@ def test_fused_ssd_kernels_compile_across_their_envelope(sizes, one_chip, real_m
     found = [re.search(r"ssd_(?:decay_bwd|decay|fwd|bwd)", n) for n, _ in _entry_work(text)]
     assert sorted(m.group(0) for m in found if m) == [
         "ssd_bwd", "ssd_decay", "ssd_decay_bwd", "ssd_fwd"], found
+
+
+@pytest.mark.parametrize("sizes", [
+    dict(b=2, s=500, width=128, col0=0, c=128, k=2, dtype=jnp.float32),  # one lane tile, padded
+    dict(b=1, s=4096, width=17024, col0=8448, c=8448, k=4, dtype=jnp.float32),  # float32, 256-wide
+    dict(b=2, s=2048, width=8512, col0=4096, c=4096, k=4, dtype=jnp.bfloat16),  # granite's x, batch 2
+], ids=["c128_k2_f32", "c8448_f32", "c4096_b2_bf16"])
+def test_fused_conv_kernels_compile_across_their_envelope(sizes, one_chip, real_mosaic):
+    """Corners of `ops/ssd.conv_path`'s envelope: what it calls fused, Mosaic
+    lowers, forward and backward, under the kernels' own names (bare autodiff
+    wraps them: jvp_ssm_conv_fwd_, transpose_jvp_ssm_conv_bwd__)."""
+    from galvatron_tpu.ops import ssd
+
+    b, s, width, col0, c, k, dtype = (sizes[key] for key in "b s width col0 c k dtype".split())
+    assert ssd.conv_path((c,), k, dtype) == "fused"
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    text = _kernel_text(
+        jax.grad(lambda *t: jnp.sum(ssd.conv_silu_fused(*t, col0).astype(jnp.float32)),
+                 argnums=(0, 1, 2)),
+        sd((b, s, width), dtype), sd((k, c), jnp.float32), sd((c,), jnp.float32))
+    import re
+
+    found = [re.search(r"ssm_conv_(?:fwd|bwd)", n) for n, _ in _entry_work(text)]
+    assert sorted(m.group(0) for m in found if m) == ["ssm_conv_bwd"], found
 
 
 def test_granite_attention_takes_the_blocked_gqa_kernel_at_8192(one_chip, real_mosaic):
@@ -437,6 +480,9 @@ def test_granite_layers_partition_on_four_chips(topo, real_mosaic):
     names = [n.split(".")[0] for n, _ in _entry_work(compiled.as_text())]
     for kernel in ("ssd_fwd", "ssd_bwd", "ssd_decay", "ssd_decay_bwd"):
         assert names.count(kernel) == 1, (kernel, [n for n in names if n.startswith("ssd_")])
+    # the fused conv (PR 40) under the same wrap: three windows forward, three backward
+    for kernel in ("ssm_conv_fwd", "ssm_conv_bwd"):
+        assert names.count(kernel) == 3, (kernel, [n for n in names if n.startswith("ssm_")])
     assert not any(n.startswith("shard_map") for n in names)
 
 
