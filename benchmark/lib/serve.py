@@ -8,12 +8,11 @@ set-up) -> an open loop from ``lib/traffic.py`` -> the window -> what the
 engine served against the plain float32 reference.
 
 No HTTP, no children, no CPU fallback (``run.py`` demands the TPU; the tests
-drive this file tiny on the CPU).  The engine is the program's, unchanged: the
-benchmark stamps its own clock on every token by handing each request a list
-that notes the time of an ``append``, and for a seeded share of the requests
-keeps the float32 logits row the token was drawn from, which the engine has on
-the host at that moment (``Request`` has neither per-token times nor a logits
-tap yet: PERF.md section 7).
+drive this file tiny on the CPU).  The engine is the program's, unchanged, and
+so are its taps (PR 39): a token's time is ``Request.token_times`` (this
+process's ``time.time()``, read after the draw), and for a seeded share of the
+requests ``submit_request(capture_logits=...)`` copies the float32 logits row
+each token is drawn from into room the benchmark made during set-up.
 
 The window's clock is ``time.time()`` in this process.  Requests are timed from
 when they were DUE, not from when the generator got to them; how late the
@@ -59,6 +58,32 @@ class RowStore:
         start, self.used = self.used, self.used + n
         return start
 
+    def take(self, n: int) -> Optional[np.ndarray]:
+        """``n`` lines of the room as the engine's logits tap wants them (the
+        caller's writable float32 array), or None where there is none left: such
+        a request is timed only."""
+        start = self.reserve(n)
+        return None if start is None else self.buf[start:start + n]
+
+
+def stamps_of(rec: Dict[str, Any]) -> List[float]:
+    """When each token of a submitted request was drawn, on this process's clock."""
+    return rec["req"].token_times if rec["req"] is not None else []
+
+
+def not_best(rows: np.ndarray, tokens: Sequence[int]) -> int:
+    """How many of ``tokens`` do not have the largest logit of the row they
+    were drawn from (tokens whose logits tie share the best)."""
+    rows = rows[:len(tokens)]
+    return int((rows[np.arange(len(tokens)), list(tokens)] < rows.max(-1)).sum())
+
+
+# What the runner used until PR 41, in place of the program's taps: a list that
+# stamps every ``append`` and copies ``Engine._last_logits[req.slot]``.  No run
+# uses it any more.  It stays because ``tests/test_serving_obs.py``, which lies
+# outside the benchmark's directories, holds the program's taps to it bit for
+# bit; the PR that may edit that test deletes both (PERF.md section 7).
+
 
 class StampedTokens(list):
     """A request's generated tokens; notes this process's clock at every
@@ -93,13 +118,8 @@ class StampedTokens(list):
 
 
 def stamp(engine, req, *, greedy: bool = False, store: Optional[RowStore] = None) -> StampedTokens:
-    """Hand ``req`` a ``StampedTokens`` in place of its list.  A token the
-    engine appended before the swap is carried over once, stamped now, with no
-    row (not seen on the chip: a prompt's prefill lies between the submission
-    and its first token).  The row a token is drawn from is the request's
-    slot's in ``engine._last_logits`` (float32, on the host already); with
-    ``store``, room for the request's rows is reserved there, and a request
-    that finds none is stamped only."""
+    """Hand ``req`` a ``StampedTokens`` in place of its list (see the note
+    above: kept for one test outside the benchmark, used by no run)."""
     def row():
         return None if req.slot is None else engine._last_logits[req.slot]
 
@@ -213,20 +233,17 @@ class Generator(threading.Thread):
                 return
             if self._stop_event.is_set():
                 return
+            rows = (self.store.take(r["max_new_tokens"])
+                    if self.store is not None and r["capture"] else None)
             rec = {"i": r["i"], "due": due, "submitted": time.time(), "greedy": r["greedy"],
-                   "capture": r["capture"], "prompt": r["tokens"],
-                   "max_new_tokens": r["max_new_tokens"], "req": None, "tokens": None,
-                   "error": None}
+                   "prompt": r["tokens"], "max_new_tokens": r["max_new_tokens"], "req": None,
+                   "rows": rows, "error": None}
             try:
-                req = self.engine.submit_request(
+                rec["req"] = self.engine.submit_request(
                     r["tokens"], r["max_new_tokens"], temperature=r["temperature"],
-                    top_p=r["top_p"])
+                    top_p=r["top_p"], capture_logits=rows)
             except Exception as e:  # noqa: BLE001 - a refused request is a failed one, counted
                 rec["error"] = type(e).__name__
-            else:
-                rec["req"], rec["tokens"] = req, stamp(
-                    self.engine, req, greedy=r["greedy"],
-                    store=self.store if r["capture"] else None)
             self.records.append(rec)
 
 
@@ -236,7 +253,8 @@ def offer(engine, requests: List[Dict[str, Any]], win: Dict[str, Any], seconds: 
     """Offer ``requests`` to ``engine`` in an open loop and hold the window:
     it opens ``settle_s`` after the event ``win["opens"]`` names (``traffic_start``,
     or ``all_slots_used``: every slot has been occupied at once) and lasts
-    ``seconds``; after it, first tokens still owed to requests that were due
+    ``seconds`` (the queue's depth is read at both ends: above the knee it
+    grows); after it, first tokens still owed to requests that were due
     inside it are waited for, ``first_token_grace_s`` at most.  With a
     ``tracer`` a profiler window covers ``PROFILE_ITERS`` decode iterations
     early in the window."""
@@ -262,19 +280,23 @@ def offer(engine, requests: List[Dict[str, Any]], win: Dict[str, Any], seconds: 
         t_close = t_open + seconds
         say(f"window: opens {t_open - t0:.2f} s into the traffic ({win['opens']} + "
             f"{win['settle_s']} s), lasts {seconds} s")
+        time.sleep(max(0.0, t_open - time.time()))
+        depth_open = engine.scheduler.depth
         if tracer is not None:
             time.sleep(max(0.0, t_open + min(2.0, seconds / 4) - time.time()))
             profiled = profile_iterations(engine, tracer, trace_dir, PROFILE_ITERS,
                                           timeout_s=max(1.0, t_close - time.time()))
         time.sleep(max(0.0, t_close - time.time()))
+        depth_close = engine.scheduler.depth
         grace = time.time() + float(win.get("first_token_grace_s", 0.0))
         while time.time() < grace and any(
-                t_open <= r["due"] < t_close and r["tokens"] is not None and not r["tokens"].stamps
+                t_open <= r["due"] < t_close and r["req"] is not None and not stamps_of(r)
                 and not r["req"].future.done() for r in list(gen.records)):
             time.sleep(0.05)
     finally:
         gen.stop()
-    return {"t0": t0, "t_open": t_open, "t_close": t_close, "gen": gen, "profiled": profiled}
+    return {"t0": t0, "t_open": t_open, "t_close": t_close, "gen": gen, "profiled": profiled,
+            "queue_depth": (depth_open, depth_close)}
 
 
 def cancel_open(records: List[Dict[str, Any]]) -> None:
@@ -284,6 +306,22 @@ def cancel_open(records: List[Dict[str, Any]]) -> None:
         if rec["req"] is not None and not rec["req"].future.done():
             rec["dropped"] = True
             rec["req"].cancel("benchmark window closed")
+
+
+def release_cache(engine, timeout_s: float = 20.0) -> None:
+    """Once the engine stands idle (what was open is cancelled, nothing is
+    queued), let go of its slot cache, so that the engine's own shutdown can make
+    the fresh one it wants: ``KVSlots.reset`` builds a new cache BEFORE it drops
+    the old, and at 16 slots x 2048 two caches (2 x 6 GiB beside 4.9 GiB of
+    weights) do not fit a 16 GB chip: ``drain`` and ``close`` then raise
+    RESOURCE_EXHAUSTED (my chip run, PR 41, call 1; PERF.md section 7: the
+    program's to mend, a restart after a crash takes the same path).  The window
+    is closed and the memory read by then; nothing timed or compared is touched."""
+    deadline = time.time() + timeout_s
+    while (engine.slots.active_count or not engine.scheduler.empty()) and time.time() < deadline:
+        time.sleep(0.01)
+    time.sleep(0.2)  # the iteration that freed the last slot ends, its cache handed back
+    engine.slots.cache = None
 
 
 def outcome(rec: Dict[str, Any]) -> str:
@@ -298,20 +336,20 @@ def outcome(rec: Dict[str, Any]) -> str:
     if fut.exception() is not None:
         return "failed"
     want = len(rec["prompt"]) + rec["max_new_tokens"]
-    ok = (len(fut.result()) == want and len(rec["tokens"]) == rec["max_new_tokens"]
+    ok = (len(fut.result()) == want and len(req.generated) == rec["max_new_tokens"]
           and req.finish_reason == "length")
     return "completed" if ok else "failed"
 
 
 def window_numbers(records: List[Dict[str, Any]], t_open: float, t_close: float
                    ) -> Dict[str, Any]:
-    """What the window holds, from the benchmark's own stamps: tokens stamped
-    inside it; for the requests DUE inside it, the time from due to first
-    token; every gap between consecutive tokens of a request whose later
+    """What the window holds, from the times the engine notes a token
+    (``Request.token_times``): tokens drawn inside it; for the requests DUE
+    inside it, the time from due to first token; every gap between consecutive tokens of a request whose later
     token fell inside it.  Nothing outside the window counts."""
     tokens, ttft, itl, due_in = 0, [], [], []
     for rec in records:
-        stamps = rec["tokens"].stamps if rec["tokens"] is not None else []
+        stamps = stamps_of(rec)
         tokens += sum(1 for s in stamps if t_open <= s < t_close)
         itl += [b - a for a, b in zip(stamps, stamps[1:]) if t_open <= b < t_close]
         if t_open <= rec["due"] < t_close:
@@ -368,10 +406,8 @@ def compare_rows(arch, params, config, rows: List[Dict[str, Any]], pad_to: int, 
         buf = np.zeros((pad_to + 1,), np.int32)
         buf[:len(seq)] = seq
         served = np.zeros((most, vocab), np.float32)
-        valid = np.zeros((most,), bool)
-        for k, r in enumerate(row["rows"]):
-            if r is not None:
-                served[k], valid[k] = r, True
+        served[:len(row["rows"])] = row["rows"]
+        valid = np.arange(most) < len(row["rows"])
         e2, r2, kl = (np.asarray(x, np.float64) for x in step(
             w, jnp.asarray(buf), jnp.asarray(served), np.int32(len(row["prompt"]) - 1),
             jnp.asarray(valid)))
@@ -402,15 +438,16 @@ def pick_checked(finished: List[Dict[str, Any]], seed: int, n: int) -> List[Dict
 
 
 def serving_peak_bytes(stats: Sequence[Dict[str, int]]) -> int:
-    """Fullest device at its fullest: the arrays alive when the window closed
+    """Fullest device while it serves: the arrays alive when the window closed
     (weights and slot cache, constant through it) plus the largest reservation
-    of a program's temporaries, or the live arrays' own peak where that is
-    more.  Not ``peak_bytes_in_use + peak_bytes_reserved`` as for a train
-    step: here the two peaks fall at different times (set-up's transients,
-    the decode step's temporaries) and their sum exceeds the chip."""
-    return max((max(st.get("peak_bytes_in_use", 0),
-                    st.get("bytes_in_use", 0) + st.get("peak_bytes_reserved", 0))
-                for st in stats), default=0)
+    of a program's temporaries (the decode step's or a prefill chunk's).  Not
+    ``peak_bytes_in_use``: that is set-up's transient (the weights' float32
+    draw beside their copy), which no request pays for and which hid the step
+    from PR 38 on; and not its sum with ``peak_bytes_reserved`` as for a train
+    step: the two peaks fall at different times and their sum exceeds the chip.
+    The run prints all three."""
+    return max((st.get("bytes_in_use", 0) + st.get("peak_bytes_reserved", 0) for st in stats),
+               default=0)
 
 
 def ring_spans(tracer) -> List[Dict[str, Any]]:
@@ -482,7 +519,7 @@ def run_serve_cell(root: str, name: str, *, seed: int, seconds: float, trace: bo
     if trace:
         tracer.enable(capacity=1 << 17)
     win = spec["window"]
-    horizon = float(win["settle_s"]) + seconds + float(win.get("first_token_grace_s", 0.0)) + 30.0
+    horizon = traffic_lib.horizon_s(spec, seconds)
     requests = traffic_lib.schedule(seed, spec, int(config["vocab_size"]), horizon)
     t = mark(f"schedule drawn ({len(requests)} requests over {horizon:.0f} s)")
     engine, cfg, params = build_engine(config, spec, seed, overrides=overrides)
@@ -513,11 +550,12 @@ def run_serve_cell(root: str, name: str, *, seed: int, seconds: float, trace: bo
     t0, t_open, t_close, gen, profiled = (
         run[k] for k in ("t0", "t_open", "t_close", "gen", "profiled"))
     t_end = time.time()
+    mem = harness.memory_stats(jax.local_devices())  # at the window's close, the engine still full
     records = gen.records
     stats = engine.stats()
     cancel_open(records)
+    release_cache(engine)
     audit = engine.drain(timeout_s=20.0)
-    mem = harness.memory_stats(jax.local_devices())
 
     # -- what the window holds ---------------------------------------------
     num = window_numbers(records, t_open, t_close)
@@ -526,13 +564,20 @@ def run_serve_cell(root: str, name: str, *, seed: int, seconds: float, trace: bo
     failed = sum(1 for rec in num["due_in"] if outcomes[rec["i"]] == "failed")
     any_failed = sorted(i for i, o in outcomes.items() if o == "failed")
     completed = [rec for rec in records if outcomes[rec["i"]] == "completed"]
-    late_ms = [1e3 * (rec["submitted"] - rec["due"]) for rec in records]
+    # how late the generator ran where it can be read as a fast server: on the
+    # requests due inside the window (the opening burst is due all at once and
+    # handed over one by one, before the window; its lateness is printed beside)
+    late_ms = [1e3 * (rec["submitted"] - rec["due"]) for rec in num["due_in"]]
     late_p99 = percentile(late_ms, 99) if late_ms else 0.0
+    late_all = percentile([1e3 * (rec["submitted"] - rec["due"]) for rec in records] or [0.0], 99)
     say(f"traffic: {len(records)} submitted in {t_end - t0:.1f} s, {len(completed)} completed, "
         f"{len(any_failed)} failed {any_failed[:8]}, {attempted} due inside the window; queue "
-        f"depth at the end {stats['queue_depth']}, engine restarts {stats['engine_restarts']}")
+        f"depth {run['queue_depth'][0]} at the window's opening, {run['queue_depth'][1]} at its "
+        f"close, engine restarts {stats['engine_restarts']}")
     starved = f"  WARNING: above {LATE_WARN_MS} ms, the generator was starved"
-    say(f"generator_late_ms_p99 {late_p99:.3f}" + starved * (late_p99 > LATE_WARN_MS))
+    say(f"generator_late_ms_p99 {late_p99:.3f} over the {len(late_ms)} requests due inside the "
+        f"window ({late_all:.3f} over all {len(records)}, the opening burst among them)"
+        + starved * (late_p99 > LATE_WARN_MS))
     tokens_per_s = num["tokens"] / seconds / chips
     say(f"window: {num['tokens']} tokens in {seconds} s = {tokens_per_s:.3f} tokens/s/chip; "
         f"{len(num['ttft_s'])} first tokens, {len(num['itl_s'])} gaps between tokens; "
@@ -543,20 +588,23 @@ def run_serve_cell(root: str, name: str, *, seed: int, seconds: float, trace: bo
             "%.3f p95 %.3f" % (sum(itl) / len(itl), *(percentile(itl, q) for q in (50, 90, 95, 99)),
                                sum(ttft) / len(ttft), percentile(ttft, 50), percentile(ttft, 95)))
     say("memory: " + "; ".join(
-        f"dev{i} live {st.get('bytes_in_use', 0) / 2**30:.2f} GiB, peak_bytes_in_use "
-        f"{st.get('peak_bytes_in_use', 0) / 2**30:.2f} GiB, peak_bytes_reserved "
-        f"{st.get('peak_bytes_reserved', 0) / 2**30:.2f} GiB" for i, st in enumerate(mem)))
+        f"dev{i} live at the window's close {st.get('bytes_in_use', 0) / 2**30:.2f} GiB + "
+        f"peak_bytes_reserved {st.get('peak_bytes_reserved', 0) / 2**30:.2f} GiB = the step's "
+        f"{serving_peak_bytes([st]) / 2**30:.2f} GiB (memory_peak_bytes); set-up's transient, "
+        f"peak_bytes_in_use, {st.get('peak_bytes_in_use', 0) / 2**30:.2f} GiB"
+        for i, st in enumerate(mem)))
 
     # -- correct: free the engine's device state, then the reference ---------
     spans = ring_spans(tracer) if trace else []
     if trace:
         tracer.disable()
-    kept = [{"i": rec["i"], "prompt": rec["prompt"], "generated": list(rec["tokens"]),
-             "rows": [None if k is None else store.buf[k] for k in rec["tokens"].lines]}
-            for rec in completed if any(k is not None for k in rec["tokens"].lines)]
+    # the rows the engine's tap wrote: one a served token (no request ends on an eos here)
+    kept = [{"i": rec["i"], "prompt": rec["prompt"], "generated": list(rec["req"].generated),
+             "greedy": rec["greedy"], "rows": rec["rows"][:rec["req"].logits_rows]}
+            for rec in completed if rec["rows"] is not None]
     checked = pick_checked(kept, seed, int(limits["requests"]))
-    greedy = {"served": sum(rec["tokens"].checked for rec in completed if rec["greedy"]),
-              "wrong": sum(rec["tokens"].not_best for rec in completed if rec["greedy"])}
+    greedy = {"served": sum(len(r["generated"]) for r in kept if r["greedy"]),
+              "wrong": sum(not_best(r["rows"], r["generated"]) for r in kept if r["greedy"])}
     smax = engine.slots.max_seq_len
     engine.slots.cache = None
     engine.params = None
@@ -581,7 +629,8 @@ def run_serve_cell(root: str, name: str, *, seed: int, seconds: float, trace: bo
         f"{len(checked)} requests {[r['i'] for r in checked]}; read, no limit: the rows' relative "
         f"error {rel_err:.6f}, the worst row's {cmp['worst_row']:.6f}; reference took {ref_s:.1f} s",
         f"correct: greedy_tokens_not_best {greedy['wrong']} (limit 0) of {greedy['served']} served "
-        f"greedy tokens, each against the row it was drawn from",
+        f"greedy tokens of the finished requests whose rows were kept, each against the row it "
+        f"was drawn from",
         f"correct: failed_requests {len(any_failed)} (limit 0); leaked_slots "
         f"{int(audit['leaked'])} (limit 0); engine_restarts {stats['engine_restarts']} (limit 0)",
         f"checks: {json.dumps(checks)}",

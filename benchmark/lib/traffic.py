@@ -69,6 +69,19 @@ def gaps(spec: Dict[str, Any]) -> List[float]:
     return [g * scale for g in q]
 
 
+def mean_output_len(spec: Dict[str, Any]) -> float:
+    """Tokens in a mean answer of the mix: what turns tokens/s into requests/s."""
+    shapes = grid(spec)
+    return sum(sh["output_len"] for sh in shapes) / len(shapes)
+
+
+def horizon_s(spec: Dict[str, Any], seconds: float) -> float:
+    """How far a run's schedule is drawn: the window and what lies around it,
+    and half a minute to spare for the way to the window's opening."""
+    win = spec["window"]
+    return float(win["settle_s"]) + seconds + float(win.get("first_token_grace_s", 0.0)) + 30.0
+
+
 def schedule(seed: int, spec: Dict[str, Any], vocab_size: int, horizon_s: float
              ) -> List[Dict[str, Any]]:
     """Requests due in ``[0, horizon_s)``: ``burst_at_start`` of them at 0 (the
@@ -77,7 +90,8 @@ def schedule(seed: int, spec: Dict[str, Any], vocab_size: int, horizon_s: float
     "greedy", "capture"}``; the token ids are windows of the seeded Zipf corpus
     the training cells use.  ``capture`` marks the requests whose logits rows
     the runner keeps for ``correct``: every ``correct.capture_every``-th
-    arrival, from a place the seed draws."""
+    arrival, from a place the seed draws; coprime to ``greedy_every``, so that
+    sampled and greedy requests are both among them whatever that place."""
     shapes, gap = grid(spec), gaps(spec)
     n = len(shapes)
     rng = np.random.default_rng(np.random.SeedSequence([int(seed), 0x5E12FE]))
@@ -90,6 +104,11 @@ def schedule(seed: int, spec: Dict[str, Any], vocab_size: int, horizon_s: float
     t, i = 0.0, 0
     every = int(sampling["greedy_every"])
     capture_every = int(spec["correct"]["capture_every"])
+    if math.gcd(capture_every, every) != 1:
+        # the greedy tokens ``correct`` holds exactly are those of captured requests
+        raise ValueError(f"correct.capture_every {capture_every} must be coprime to greedy_every "
+                         f"{every}: from some places the seed draws no greedy request's rows "
+                         f"would be kept")
     capture_from = int(rng.integers(0, capture_every))
     greedy = [j for j, sh in enumerate(shapes) if sh["greedy"]]
     sampled = [j for j, sh in enumerate(shapes) if not sh["greedy"]]

@@ -168,11 +168,6 @@ def first_device(trace: Optional[Dict[str, Any]]) -> Optional[List[Op]]:
     return devs[min(devs)] if devs else None
 
 
-def mosaic_kernels(trace: Optional[Dict[str, Any]]) -> List[Op]:
-    """The first device's Pallas (Mosaic) kernel calls."""
-    return [o for o in first_device(trace) or [] if o.category == "mosaic-kernel"]
-
-
 def leaf_ops(ops: Sequence[Op]) -> List[Op]:
     """Operations that do work themselves (containers span their bodies)."""
     return [o for o in ops if o.category != "container"]

@@ -1,18 +1,19 @@
 """The flash-attention kernels' share of their roofline: the least time the
 chip could take for the algorithm's operations and bytes (``lib/flops.py``,
-this chip's share of batch and heads) over the kernels' device time."""
+this chip's share of batch and heads) over the device time of the kernels
+whose instruction name starts with ``flash_`` (no other Mosaic call counts)."""
 
-from benchmark.lib import flops, xplane
+from benchmark.lib import flops, scoped, xplane
 
 NAME, UNIT, BETTER, SOURCE = "flash_attention_roofline", "%", "higher", "device_trace"
 LAYER, MOVES = "kernels", "tokens_per_s_per_chip"
 
 
 def compute(ctx):
-    calls = xplane.mosaic_kernels(ctx["trace"])
+    ns, calls = scoped.kernel_ns(xplane.first_device(ctx["trace"]) or [], "flash_")
     if not calls or not ctx["peaks"]:
         return None
-    step_s = sum(o.end - o.start for o in calls) / 1e9 / ctx["n_profiled"]
+    step_s = ns / 1e9 / ctx["n_profiled"]
     cfg, traffic = ctx["config"], ctx["traffic"]
     heads = int(cfg["num_attention_heads"])
     shape = dict(batch=traffic["global_batch"], heads=heads, seq_len=traffic["seq_len"],

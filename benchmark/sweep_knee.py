@@ -10,7 +10,11 @@ no request expired in the queue, was refused or failed and the queue's depth
 at the end is at most the slot count (a request cut short by its end-to-end
 deadline while decoding is printed apart: at 87 ms a token a 448-token answer
 outlives the constructor's 30 s whatever the load).  Prints one line a rate and the knee; the table
-goes into PERF.md and the number into the traffic files.
+goes into PERF.md and the number into the traffic file's ``knee`` block.  The
+knee as a number, ``sustained_rps``, is what the engine completes with every
+slot in use (the most tokens/s any rate read) over the mix's mean answer: the
+rule by the queue alone admits a rate whose queue has only not yet grown past
+the slots in 60 s (PERF.md section 6, PR 35).
 """
 
 from __future__ import annotations
@@ -77,8 +81,13 @@ def main(argv=None) -> int:
         while ((engine.slots.active_count or not engine.scheduler.empty())
                and time.time() < deadline):
             time.sleep(0.05)
+    answer = traffic.mean_output_len(spec)
+    most = max(r["tokens_per_s"] for r in rows)
     print("KNEE " + json.dumps({"workload": args.workload, "knee_rps": knee, "num_slots": slots,
-                                "seconds": args.seconds}), flush=True)
+                                "seconds": args.seconds, "saturated_tokens_per_s": most,
+                                "mean_answer_tokens": answer, "sustained_rps": most / answer}),
+          flush=True)
+    serve.release_cache(engine)
     engine.close()
     return 0
 

@@ -107,8 +107,17 @@ def _check_serve_traffic(traffic, config):
     runner knows, limits for what ``correct`` compares."""
     from benchmark.lib import traffic as traffic_lib
 
-    assert set(traffic) == {"kind", "arrivals", "lengths", "sampling", "corpus", "serve_flags",
-                            "window", "correct", "why"}
+    assert set(traffic) - {"knee"} == {"kind", "arrivals", "lengths", "sampling", "corpus",
+                                       "serve_flags", "window", "correct", "why"}
+    if "knee" in traffic:
+        # what ``sweep_knee.py`` and its traced run read on the chip, and the fastest
+        # engine the mix is meant to judge: the numbers ``replay.py`` reads
+        knee = traffic["knee"]
+        assert set(knee) == {"sustained_rps", "engine_ms", "judges_up_to"}
+        assert 0 < knee["sustained_rps"] < traffic["arrivals"]["rate_rps"]
+        for engine in (knee["engine_ms"], knee["judges_up_to"]):
+            assert set(engine) == {"per_slot", "per_iteration", "prefill_chunk"}
+            assert all(isinstance(v, (int, float)) and v > 0 for v in engine.values())
     shapes = traffic_lib.grid(traffic)
     assert len(shapes) == traffic["lengths"]["grid"]
     assert max(s["prompt_len"] + s["output_len"] for s in shapes) <= traffic["lengths"]["max_total"]

@@ -144,6 +144,33 @@ def test_metrics_on_a_hand_made_step():
     assert any("bound by compute" in s for s in said)
 
 
+def test_the_flash_readers_count_flash_kernels_alone():
+    """A step with grouped-GEMM kernels beside the attention's: the whole-step
+    flash readers take the ``flash_`` names and no other Mosaic call (until PR 41
+    they read 40.5 ms in this cell where ``flash_fwd`` + ``flash_bwd`` are 8.8)."""
+    from benchmark.lib import flops, xplane
+
+    ops = [xplane.Op(0.0, 3e6, "flash_fwd_qkv.1", "mosaic-kernel"),
+           xplane.Op(3e6, 9e6, "flash_bwd_blocked.1", "mosaic-kernel"),
+           xplane.Op(9e6, 29e6, "moe_gmm.1", "mosaic-kernel"),
+           xplane.Op(29e6, 49e6, "moe_tgmm.2", "mosaic-kernel"),
+           xplane.Op(49e6, 50e6, "fusion.7", "fusion:kLoop")]
+    cfg = dict(CONFIG, num_attention_heads=16)
+    said = []
+    ctx = dict(_ctx(None, said), trace={"devices": {0: ops}}, config=cfg)
+    assert _metric("flash_attention_ms_per_step").compute(ctx) == pytest.approx(9.0)
+    assert any("2 flash_* calls a step on device 0 (flash_bwd_blocked, flash_fwd_qkv)" in s
+               for s in said)
+    shape = dict(batch=4, heads=16, seq_len=4096, head_dim=128, layers=1)
+    least = max(flops.flash_attention_flops(**shape) / 197e12,
+                flops.flash_attention_bytes(**shape) / 819e9)
+    assert _metric("flash_attention_roofline").compute(ctx) == pytest.approx(100 * least / 9e-3)
+    # a step with no flash kernel at all: both leave themselves out, never a 0
+    bare = dict(ctx, trace={"devices": {0: ops[2:]}})
+    assert _metric("flash_attention_ms_per_step").compute(bare) is None
+    assert _metric("flash_attention_roofline").compute(bare) is None
+
+
 def test_metrics_leave_themselves_out_without_the_scopes():
     """A dense model's step (or a parent's): ``mlp`` without the four scopes."""
     dense = [_op(0, 10, J + "jvp(layer_0)/mlp/dot_general:"), _op(10, 20, J + "jvp(head)/mul:")]

@@ -45,7 +45,7 @@ TINY_TRAFFIC = {
     "serve_flags": ["--num_slots", "4", "--prefill_chunk", "16", "--max_queue", "4096",
                     "--request_ttl_s", "0"],
     "window": {"opens": "all_slots_used", "settle_s": 0.2, "first_token_grace_s": 0},
-    "correct": {"requests": 12, "capture_every": 2, "rows_kept": 4096,
+    "correct": {"requests": 12, "capture_every": 3, "rows_kept": 4096,
                 "logits_kl_max": TINY_KL_MAX},
     "why": "tiny CPU rehearsal",
 }
@@ -122,8 +122,9 @@ def test_every_seed_offers_the_same_lengths_and_gaps():
         whole = reqs[:len(reqs) // n * n]
         shapes = Counter((len(r["tokens"]), r["max_new_tokens"], r["temperature"], r["top_p"])
                          for r in whole)
-        # the second cycle is past the burst: all of its gaps are there
-        due = [r["due_s"] for r in reqs[n - 1:2 * n]]
+        # the first cycle wholly past the burst: all of its gaps are there
+        c = -(-burst // n)
+        due = [r["due_s"] for r in reqs[c * n - 1:(c + 1) * n]]
         return shapes, sorted(round(b - a, 9) for a, b in zip(due, due[1:]))
 
     (s1, g1), (s2, g2) = shapes_and_gaps(11), shapes_and_gaps(2**31 + 12)
@@ -144,19 +145,23 @@ def test_grid_keeps_the_published_positions_and_spreads_greedy_and_kept_requests
     # greedy through the sampler's own path: the host pays the same for either kind
     assert all((r["temperature"], r["top_p"]) == ((1e-4 if r["greedy"] else 0.8), 0.95)
                for r in reqs)
-    # rows are kept for every second arrival, from a place the seed draws
-    kept = [r["capture"] for r in reqs]
-    assert kept in ([k % 2 == 0 for k in range(len(kept))], [k % 2 == 1 for k in range(len(kept))])
+    # rows are kept for every ``capture_every``-th arrival, from a place the seed draws
+    n, kept = spec["correct"]["capture_every"], [r["capture"] for r in reqs]
+    assert kept in [[(k + c) % n == 0 for k in range(len(kept))] for c in range(n)]
 
 
 # --- the window's accounting ---------------------------------------------------
 
 
+class _Timed:
+    """What the accounting reads of a ``Request``: when each token was drawn."""
+
+    def __init__(self, stamps):
+        self.token_times = list(stamps)
+
+
 def _record(i, due, stamps, **kw):
-    toks = serve.StampedTokens()
-    toks.extend(range(len(stamps)))
-    toks.stamps = list(stamps)
-    return dict({"i": i, "due": due, "tokens": toks, "error": None}, **kw)
+    return dict({"i": i, "due": due, "req": _Timed(stamps), "error": None}, **kw)
 
 
 def test_window_accounting_counts_nothing_outside_the_window():
@@ -171,6 +176,33 @@ def test_window_accounting_counts_nothing_outside_the_window():
     assert [r["i"] for r in num["due_in"]] == [1, 2]
     assert num["ttft_s"] == [0.5]
     assert sorted(round(g, 6) for g in num["itl_s"]) == sorted([0.1, 0.5, 9.49, 0.5])
+    # a request the engine refused has no ``Request``: it is due, and has no token
+    refused = dict(_record(4, 11.0, []), req=None, error="ValueError")
+    assert serve.window_numbers([refused], 10.0, 20.0) == {
+        "tokens": 0, "ttft_s": [], "itl_s": [], "due_in": [refused]}
+
+
+# --- the rows of the engine's tap ---------------------------------------------------
+
+
+def test_room_for_rows_is_handed_out_once_in_the_order_asked():
+    store = serve.RowStore(6, 4)
+    a, b = store.take(2), store.take(3)
+    assert a.shape == (2, 4) and b.shape == (3, 4) and a.dtype == np.float32 and a.flags.writeable
+    a[:] = 1.0
+    b[:] = 2.0  # views of the one array made during set-up, side by side
+    assert store.buf[:5].tolist() == [[1.0] * 4] * 2 + [[2.0] * 4] * 3 and store.used == 5
+    # a request that finds no room for all its rows is timed only
+    assert store.take(2) is None and store.used == 5 and store.take(1).shape == (1, 4)
+
+
+def test_greedy_tokens_are_held_to_the_rows_the_tap_kept():
+    rows = np.array([[0, 2, 2, 1], [0, 2, 2, 1], [0, 2, 2, 1], [9, 9, 9, 9]], np.float32)
+    assert serve.not_best(rows, [1, 2, 3]) == 1  # ties share the best; 3 is not it
+    assert serve.not_best(rows, [1, 2]) == 0 and serve.not_best(rows, []) == 0
+
+
+# --- the list that stamped, kept for ``tests/test_serving_obs.py`` -------------------
 
 
 def test_stamped_tokens_note_the_time_of_every_append():
