@@ -1,14 +1,19 @@
 #!/usr/bin/env python3
 """Read what ``correct`` compares, over seeds, for the program and its control.
 
-    python benchmark/control.py --workload <serve cell> --seeds 1,2,3 [--seconds 30] [--modes sound,int8]
+    python benchmark/control.py --workload <serve cell> --seeds 1,2,3 [--seconds 30] [--modes sound,int8,hot,no_nucleus]
 
 One process (set-up is long): for every seed and mode one run of the cell
 through ``run_serve_cell`` at the cell's own load.  Mode ``sound`` is the cell
 as it is.  Mode ``int8`` is the control: the program's own path in the nearest
 precision below the bfloat16 the configuration serves in, ``--serve_quant
 int8`` (per-channel int8 weights in every layer's GEMMs, bfloat16
-activations), switched on and nothing else changed.  Prints, a run, the mean
+activations), switched on and nothing else changed.  Modes ``hot`` and
+``no_nucleus`` plant a sampler's fault through the engine's public taps
+(``lib/faults.py``): the engine draws every sampled request at temperature 1.0,
+or with no ``top_p``, where the request states the traffic file's; they give the
+upper readings of ``sampled_logprob_z`` and ``sampled_outside_nucleus`` at the
+cell's own size.  Prints, a run, the mean
 divergence of the engine's softmax from the float32 reference's over the
 compared rows (and what is read beside it), and at the end the largest of the sound runs
 and the smallest of the control's: the limit in the traffic file lies between
@@ -41,19 +46,21 @@ def main(argv=None) -> int:
 
     import jax
 
-    from benchmark.lib import serve
+    from benchmark.lib import faults, serve
 
     if jax.devices()[0].platform != "tpu":
         raise SystemExit("control: needs a TPU; the limits are set from chip readings")
-    read = {"sound": [], "int8": []}
+    planted = {"sound": None, "int8": None, "hot": faults.hot, "no_nucleus": faults.no_nucleus}
+    read = {mode: [] for mode in planted}
     for seed in (int(x) for x in args.seeds.split(",")):
         for mode in args.modes.split(","):
             out_dir = tempfile.mkdtemp(prefix="galvatron_control_")
             try:
-                res = serve.run_serve_cell(
-                    ROOT, args.workload, seed=seed, seconds=args.seconds, trace=False,
-                    out_dir=out_dir, t_start=time.time(),
-                    overrides=INT8 if mode == "int8" else ())
+                with faults.submitting(planted[mode]):
+                    res = serve.run_serve_cell(
+                        ROOT, args.workload, seed=seed, seconds=args.seconds, trace=False,
+                        out_dir=out_dir, t_start=time.time(),
+                        overrides=INT8 if mode == "int8" else ())
             finally:
                 shutil.rmtree(out_dir, ignore_errors=True)
             read[mode].append(res["compared"]["logits_kl"])

@@ -15,8 +15,9 @@ with a wire-format reader for the few messages it needs (no protobuf schema
 is installed with jax), and reduces them with plain functions over tuples that
 the tests check by hand on a small recorded trace.
 
-Where the file is: ``ctx`` carries parsed operations and not the trace's path,
-so ``window()`` asks the program where its last profiler window went
+Where the file is: a serving run opens the profiler window itself and hands
+the file's path over as ``ctx["trace_path"]``; a training run's window is the
+program's, so ``window()`` asks the program where its last one went
 (``galvatron_tpu.obs.flight.last_profile_window``, same process).  A program
 without that function, without scopes or without annotations gives ``None``
 or empty results here, never an error: every metric built on this module
@@ -35,9 +36,10 @@ from benchmark.lib import xplane
 # the names the program promises (PERF.md §3 has the table)
 # ---------------------------------------------------------------------------
 
-#: ``jax.named_scope`` names of the step program; ``layer_<i>`` reads as ``layer``
-SCOPES = ("embed", "layer", "attn", "qkv_proj", "attn_core", "out_proj", "mlp", "norm",
-          "head", "loss", "optimizer", "grad_accum", "grad_sync", "redistribute",
+#: ``jax.named_scope`` names of the step program and of the cached forwards a
+#: serving engine runs (``cache_write`` is theirs alone); ``layer_<i>`` reads as ``layer``
+SCOPES = ("embed", "layer", "attn", "qkv_proj", "cache_write", "attn_core", "out_proj", "mlp",
+          "norm", "head", "loss", "optimizer", "grad_accum", "grad_sync", "redistribute",
           "allgather_einsum", "einsum_reducescatter")
 #: scopes whose work is communication by construction
 COMM_SCOPES = ("grad_sync", "redistribute", "allgather_einsum", "einsum_reducescatter")
@@ -283,16 +285,18 @@ def read(path: str) -> Dict[str, Any]:
             annotations += _host_annotations(plane)
     op_names: Dict[str, str] = {}
     categories: Dict[str, str] = {}
+    by_text: Dict[str, str] = {}  # two programs' ``fusion.12`` differ in their HLO text
     if device is not None:
         plane = device[1]
         for md in _event_metadata(plane, _stat_names(plane), ("tf_op", "hlo_category")).values():
             instruction = xplane.parse(md["name"])[0]
             if md.get("tf_op"):
-                op_names[instruction] = md["tf_op"]
+                op_names[instruction] = by_text[md["name"]] = md["tf_op"]
             if md.get("hlo_category"):
                 categories[instruction] = md["hlo_category"]
     annotations.sort(key=lambda a: a.start)
-    return {"op_names": op_names, "categories": categories, "annotations": annotations}
+    return {"op_names": op_names, "categories": categories, "annotations": annotations,
+            "op_names_by_text": by_text}
 
 
 def window() -> Optional[Dict[str, Any]]:
@@ -325,16 +329,26 @@ def exported_span_args(name: str) -> List[Dict[str, Any]]:
     return [ev.get("args", {}) for ev in events if ev.get("name") == name and ev.get("ph") == "X"]
 
 
+def trace_path(ctx) -> Optional[str]:
+    """The traced run's ``.xplane.pb``: the path a serving run hands over (its
+    profiler window is the benchmark's own), else the program's record of its
+    last window (a training run); None where there is neither."""
+    if not ctx.get("trace"):
+        return None
+    if ctx.get("trace_path"):
+        return ctx["trace_path"]
+    win = window()
+    return win.get("xplane") if win else None
+
+
 def of_ctx(ctx) -> Optional[Dict[str, Any]]:
     """``read`` of the traced run's file, or None: no trace, no record of the
     window, or a file the reader cannot parse."""
-    if not ctx.get("trace"):
-        return None
-    win = window()
-    if not win or not win.get("xplane"):
+    path = trace_path(ctx)
+    if not path:
         return None
     try:
-        return read(win["xplane"])
+        return read(path)
     except (OSError, ValueError, IndexError):
         return None
 
@@ -433,6 +447,141 @@ def is_comm(o: ScopedOp) -> bool:
 
 def comm_ns(sops: Sequence[ScopedOp]) -> float:
     return sum(o.end - o.start for o in sops if is_comm(o))
+
+
+# ---------------------------------------------------------------------------
+# a window of several jitted programs (a serving run): executions by program
+# ---------------------------------------------------------------------------
+# A training window holds one program, a step after a step.  A serving window
+# holds the decode step, prefill chunks between them, and whatever small
+# programs the loop dispatches; the device plane's line ``XLA Modules`` has one
+# event an execution (``jit__decode_step(<fingerprint>)``), and an operation of
+# ``XLA Ops`` belongs to the execution whose event covers its start.
+
+
+class Execution(NamedTuple):
+    program: str  # ``_decode_step``: the module's name less ``jit_`` and the fingerprint
+    start: float
+    end: float
+    ops: Tuple[ScopedOp, ...]  # its leaf operations
+
+
+_MODULE = re.compile(r"^(?:jit_)?(.*?)(?:\(\d+\))?$")
+
+
+def program_name(module: str) -> str:
+    """``jit__decode_step(9036214310235559293)`` -> ``_decode_step``."""
+    return _MODULE.match(module).group(1)
+
+
+def group_executions(modules: Sequence[Tuple[float, float, str]],
+                     ops: Sequence[ScopedOp]) -> List[Execution]:
+    """One :class:`Execution` a module event, in time order, each with the leaf
+    operations that start inside it; an operation outside every module event
+    (the window opened or closed inside its execution) is left out."""
+    import bisect
+
+    modules = sorted(modules)
+    starts = [m[0] for m in modules]
+    mine: List[List[ScopedOp]] = [[] for _ in modules]
+    for o in ops:
+        i = bisect.bisect_right(starts, o.start) - 1
+        if i >= 0 and o.start < modules[i][1] and o.category != "container":
+            mine[i].append(o)
+    return [Execution(program_name(name), a, b, tuple(its))
+            for (a, b, name), its in zip(modules, mine)]
+
+
+def device0_lines(path: str) -> Tuple[List[Tuple[float, float, str]], List[Tuple[float, float, str]]]:
+    """(module events, operations) of the lowest-numbered TPU plane, each
+    (start ns, end ns, the event's name: a module's, an operation's HLO text)."""
+    from jax.profiler import ProfileData
+
+    planes = [p for p in ProfileData.from_file(path).planes if xplane.DEVICE_PLANE.match(p.name)]
+    modules: List[Tuple[float, float, str]] = []
+    ops: List[Tuple[float, float, str]] = []
+    if planes:
+        plane = min(planes, key=lambda p: int(xplane.DEVICE_PLANE.match(p.name).group(1)))
+        for line in plane.lines:
+            into = {"XLA Modules": modules, xplane.OPS_LINE: ops}.get(line.name)
+            if into is not None:
+                into += [(float(ev.start_ns), float(ev.start_ns + ev.duration_ns), ev.name)
+                         for ev in line.events]
+    return modules, ops
+
+
+def executions(ctx) -> Optional[List[Execution]]:
+    """Device 0's executions in the traced window, every operation with the
+    ``op_name`` its own HLO text carries (two programs' ``fusion.12`` stay
+    apart); None without a trace, or where the trace has no module events."""
+    if "_executions" not in ctx:  # once a run: every reader of the decode step asks
+        path, out = trace_path(ctx), None
+        data = of_ctx(ctx) if path else None
+        if data is not None:
+            modules, ops = device0_lines(path)
+            by_text = data["op_names_by_text"]
+            sops = []
+            for start, end, text in ops:
+                name, category = xplane.parse(text)[:2]
+                sops.append(ScopedOp(start, end, name, category, by_text.get(text, ""),
+                                     data["categories"].get(name, "")))
+            out = group_executions(modules, sops) or None
+        ctx["_executions"] = out
+    return ctx["_executions"]
+
+
+def scope_path(op_name: str) -> str:
+    """``layer/attn/cache_write``, ``head``, ``unscoped``: every scope of the
+    program on the path, so a scope a later PR adds under a known one shows."""
+    return "/".join(scopes_of(op_name)) or "unscoped"
+
+
+def busy_ns_of(ex: Execution) -> float:
+    return xplane.length((o.start, o.end) for o in ex.ops)
+
+
+def scope_category_ns(ops: Sequence[ScopedOp]) -> Dict[str, Dict[str, float]]:
+    """``{scope path: {category: summed duration}}`` of some operations."""
+    out: Dict[str, Dict[str, float]] = {}
+    for o in ops:
+        cats = out.setdefault(scope_path(o.op_name), {})
+        cats[o.category] = cats.get(o.category, 0.0) + (o.end - o.start)
+    return out
+
+
+def scope_ns(ex: Execution) -> Dict[str, float]:
+    """``{scope path: summed duration}`` of one execution's operations."""
+    return {key: sum(cats.values()) for key, cats in scope_category_ns(ex.ops).items()}
+
+
+def by_program(execs: Sequence[Execution]) -> Dict[str, List[Execution]]:
+    out: Dict[str, List[Execution]] = {}
+    for ex in execs:
+        out.setdefault(ex.program, []).append(ex)
+    return out
+
+
+def program_table(execs: Sequence[Execution]) -> List[str]:
+    """The window's programs, most device time first: executions, median busy
+    ms of one, and under it every scope path with its mean ms an execution and
+    its largest categories.  Every program and every scope in the window is
+    listed, so one a later PR adds (a device sampler's) is printed without an
+    edit here."""
+    from benchmark.lib.stats import percentile
+
+    lines = []
+    for name, runs in sorted(by_program(execs).items(),
+                             key=lambda kv: -sum(map(busy_ns_of, kv[1]))):
+        lines.append(f"program {name}: {len(runs)} executions in the window, busy "
+                     f"{percentile([busy_ns_of(ex) / 1e6 for ex in runs], 50):.3f} ms each "
+                     f"(median; module event "
+                     f"{percentile([(ex.end - ex.start) / 1e6 for ex in runs], 50):.3f} ms)")
+        table = scope_category_ns([o for ex in runs for o in ex.ops])
+        for key, cats in sorted(table.items(), key=lambda kv: -sum(kv[1].values())):
+            top = sorted(cats.items(), key=lambda kv: -kv[1])[:3]
+            lines.append(f"  {key:28s} {sum(cats.values()) / 1e6 / len(runs):8.3f} ms  "
+                         + "; ".join(f"{c} {v / 1e6 / len(runs):.3f}" for c, v in top))
+    return lines
 
 
 def phase_ms_per_step(ctx, phase: str) -> Optional[float]:

@@ -5,7 +5,9 @@ says ``"kind": "serve"``.  A run is: weights from the seed (one jitted call, in
 the dtypes the program serves) -> ``serving.Engine`` built from the flags
 ``cli serve`` takes -> warm-up requests through both programs (all of it
 set-up) -> an open loop from ``lib/traffic.py`` -> the window -> what the
-engine served against the plain float32 reference.
+engine served against the plain float32 reference, every kept greedy token
+against its row's best and every kept sampled token against the distribution
+its request stated (``lib/reference.py``: nothing of the program).
 
 No HTTP, no children, no CPU fallback (``run.py`` demands the TPU; the tests
 drive this file tiny on the CPU).  The engine is the program's, unchanged, and
@@ -39,6 +41,15 @@ from benchmark.lib.stats import percentile
 LATE_WARN_MS = 5.0
 #: decode iterations the traced run's profiler window covers
 PROFILE_ITERS = 50
+#: mass past ``top_p`` a sampled token may lie before it counts as outside the
+#: nucleus: a float32 cumulative sum over 50,272 terms errs by ~1e-4; at the
+#: cell's near-flat rows 1e-3 admits ~300 ids past the cut, and a sampler with
+#: no nucleus still lands outside with probability 4.9% a token (PERF.md section 2)
+NUCLEUS_SLACK = 1e-3
+#: |z| of the sampled tokens' summed log-probability against its exact
+#: expectation (``reference.sampled_tokens_check``): N(0, 1) for a right sampler
+#: (false alarm 2e-9 a run); temperature 1.0 for 0.8 reads ~9 over 1,500 tokens
+LOGPROB_Z_MAX = 6.0
 
 
 class RowStore:
@@ -69,6 +80,14 @@ class RowStore:
 def stamps_of(rec: Dict[str, Any]) -> List[float]:
     """When each token of a submitted request was drawn, on this process's clock."""
     return rec["req"].token_times if rec["req"] is not None else []
+
+
+def sampled_check(kept: Sequence[Dict[str, Any]]) -> Dict[str, Any]:
+    """Every token of the kept SAMPLED requests against the row the tap kept for
+    it and the sampling the request stated (``reference.sampled_tokens_check``)."""
+    draws = ((r["rows"][k], tok, r["temperature"], 0, r["top_p"])
+             for r in kept if not r["greedy"] for k, tok in enumerate(r["generated"]))
+    return reference.sampled_tokens_check(draws, NUCLEUS_SLACK)
 
 
 def not_best(rows: np.ndarray, tokens: Sequence[int]) -> int:
@@ -236,6 +255,7 @@ class Generator(threading.Thread):
             rows = (self.store.take(r["max_new_tokens"])
                     if self.store is not None and r["capture"] else None)
             rec = {"i": r["i"], "due": due, "submitted": time.time(), "greedy": r["greedy"],
+                   "temperature": r["temperature"], "top_p": r["top_p"],
                    "prompt": r["tokens"], "max_new_tokens": r["max_new_tokens"], "req": None,
                    "rows": rows, "error": None}
             try:
@@ -357,6 +377,37 @@ def window_numbers(records: List[Dict[str, Any]], t_open: float, t_close: float
             if stamps:
                 ttft.append(stamps[0] - rec["due"])
     return {"tokens": tokens, "ttft_s": ttft, "itl_s": itl, "due_in": due_in}
+
+
+def window_work(records: List[Dict[str, Any]], t_open: float, t_close: float, chunk: int
+                ) -> Dict[str, int]:
+    """What the window's forwards had to work on, rebuilt from the requests' own
+    records (prompt length, ``admitted_at``, ``token_times``), not from the
+    cache's capacity: the counts ``lib/flops.py`` turns into least bytes and
+    FLOPs.  A request admitted inside the window is prefilled there, ``chunk``
+    tokens a call, each call attending to the positions up to its end.  Token k
+    of a request, drawn inside the window and not its last, is fed through the
+    decode step that follows the draw, at position ``prompt + k``, attending to
+    the ``prompt + k + 1`` positions then live in its slot."""
+    out = {"decode_tokens": 0, "decode_positions": 0, "prefills": 0, "prefill_chunks": 0,
+           "prefill_tokens": 0, "prefill_positions": 0, "prefill_pairs": 0}
+    for rec in records:
+        req = rec["req"]
+        if req is None or req.admitted_at is None:
+            continue
+        p = len(rec["prompt"])
+        if t_open <= req.admitted_at < t_close:
+            ends = [min(p, e) for e in range(chunk, p + chunk, chunk)]
+            out["prefills"] += 1
+            out["prefill_chunks"] += len(ends)
+            out["prefill_tokens"] += p
+            out["prefill_positions"] += sum(ends)
+            out["prefill_pairs"] += p * (p + 1) // 2
+        for k, t in enumerate(req.token_times):
+            if t_open <= t < t_close and k < rec["max_new_tokens"] - 1:
+                out["decode_tokens"] += 1
+                out["decode_positions"] += p + k + 1
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -600,11 +651,15 @@ def run_serve_cell(root: str, name: str, *, seed: int, seconds: float, trace: bo
         tracer.disable()
     # the rows the engine's tap wrote: one a served token (no request ends on an eos here)
     kept = [{"i": rec["i"], "prompt": rec["prompt"], "generated": list(rec["req"].generated),
-             "greedy": rec["greedy"], "rows": rec["rows"][:rec["req"].logits_rows]}
+             "greedy": rec["greedy"], "temperature": rec["temperature"], "top_p": rec["top_p"],
+             "rows": rec["rows"][:rec["req"].logits_rows]}
             for rec in completed if rec["rows"] is not None]
     checked = pick_checked(kept, seed, int(limits["requests"]))
     greedy = {"served": sum(len(r["generated"]) for r in kept if r["greedy"]),
               "wrong": sum(not_best(r["rows"], r["generated"]) for r in kept if r["greedy"])}
+    t_sampled = time.time()
+    sampled = sampled_check(kept)
+    sampled_s = time.time() - t_sampled
     smax = engine.slots.max_seq_len
     engine.slots.cache = None
     engine.params = None
@@ -619,6 +674,9 @@ def run_serve_cell(root: str, name: str, *, seed: int, seconds: float, trace: bo
     checks = {
         "logits": cmp["rows"] > 0 and kl_mean <= float(limits["logits_kl_max"]),
         "greedy_tokens": greedy["served"] > 0 and greedy["wrong"] == 0,
+        # (a mix of greedy requests alone has no sampled token to hold)
+        "sampled_tokens": (sampled["inside"] > 0 or int(spec["sampling"]["greedy_every"]) == 1)
+        and sampled["outside"] == 0 and abs(sampled["z"]) <= LOGPROB_Z_MAX,
         "lengths": not any_failed,
         "no_leak": not audit["leaked"],
         "no_restart": stats["engine_restarts"] == 0,
@@ -631,6 +689,14 @@ def run_serve_cell(root: str, name: str, *, seed: int, seconds: float, trace: bo
         f"correct: greedy_tokens_not_best {greedy['wrong']} (limit 0) of {greedy['served']} served "
         f"greedy tokens of the finished requests whose rows were kept, each against the row it "
         f"was drawn from",
+        f"correct: sampled_outside_nucleus {sampled['outside']} (limit 0), sampled_logprob_z "
+        f"{sampled['z']:.4f} (limit +-{LOGPROB_Z_MAX:g}) over {sampled['tokens']} sampled tokens of "
+        f"the finished requests whose rows were kept, each against the row it was drawn from and "
+        f"the temperature and top_p its request stated: outside = the mass of strictly larger "
+        f"logits >= top_p + {NUCLEUS_SLACK:g} ({sampled['past_cut']} more within that slack, in "
+        f"neither number); z = (sum ln p {sampled['logp']:.3f} - expected {sampled['mean']:.3f}) "
+        f"/ sqrt(variance {sampled['var']:.3f}) over the {sampled['inside']} inside the support; "
+        f"took {sampled_s:.1f} s",
         f"correct: failed_requests {len(any_failed)} (limit 0); leaked_slots "
         f"{int(audit['leaked'])} (limit 0); engine_restarts {stats['engine_restarts']} (limit 0)",
         f"checks: {json.dumps(checks)}",
@@ -648,9 +714,21 @@ def run_serve_cell(root: str, name: str, *, seed: int, seconds: float, trace: bo
                               "compared": {"logits_kl": kl_mean, "rows": cmp["rows"],
                                            "logits_rel_err": rel_err,
                                            "worst_row": cmp["worst_row"],
+                                           "greedy_requests": sum(r["greedy"] for r in kept),
                                            "greedy_served": greedy["served"],
                                            "greedy_not_best": greedy["wrong"],
-                                           "reference_s": ref_s}}
+                                           "sampled_requests": sum(not r["greedy"] for r in kept),
+                                           "sampled_tokens": sampled["tokens"],
+                                           "sampled_outside_nucleus": sampled["outside"],
+                                           "sampled_logprob_z": sampled["z"],
+                                           "sampled_check_s": sampled_s,
+                                           "reference_s": ref_s,
+                                           "limits": {
+                                               "logits_kl": float(limits["logits_kl_max"]),
+                                               "greedy_not_best": 0,
+                                               "sampled_outside_nucleus": 0,
+                                               "sampled_logprob_z": LOGPROB_Z_MAX},
+                                           "checks": checks}}
     # what a serving run can report end to end; a cell reports those of them
     # that BENCHMARK.json lists it under
     end_to_end = {
@@ -681,11 +759,12 @@ def run_serve_cell(root: str, name: str, *, seed: int, seconds: float, trace: bo
     ctx = {
         "cell": cell, "config": config, "traffic": spec, "chips": chips, "arch": arch,
         "spans": in_window, "setup_spans": setup_spans, "records": [], "step_s": [],
-        "trace": xplane.load(trace_path) if trace_path else None,
+        "trace": xplane.load(trace_path) if trace_path else None, "trace_path": trace_path,
         "n_profiled": profiled, "memory_peak_bytes": device["memory_peak_bytes"],
         "peaks": peaks_row, "say": say,
         "serve": {"num_slots": num_slots, "prefill_chunk": prefill_chunk,
-                  "ttft_s": num["ttft_s"], "itl_s": num["itl_s"]},
+                  "ttft_s": num["ttft_s"], "itl_s": num["itl_s"], "seconds": seconds,
+                  "work": window_work(records, t_open, t_close, prefill_chunk)},
     }
     harness.collect_per_layer(root, name, ctx, result)
     harness.device_breakdown(ctx, result)
@@ -700,4 +779,5 @@ def run_serve_cell(root: str, name: str, *, seed: int, seconds: float, trace: bo
         say("device time by category, ms an iteration: " + "; ".join(
             f"{k} {v / 1e6 / profiled:.3f}" for k, v in sorted(xplane.category_sums(ops0).items(),
                                                                    key=lambda kv: -kv[1])))
+    result["compared"] = result.pop("compared")  # the numbers compared come last in the line
     return result

@@ -55,3 +55,24 @@ def fwd_flops_per_token(cfg, seq_len):
         hidden=cfg["hidden_size"], heads=cfg["num_attention_heads"], ffn=cfg["ffn_dim"],
         mlp_matrices=2, layers=cfg["num_hidden_layers"], vocab=cfg["vocab_size"],
         seq_len=seq_len)
+
+
+def serve_dims(cfg):
+    """The sizes ``lib/flops.py`` counts a served forward's operations and bytes from."""
+    h, n = int(cfg["hidden_size"]), int(cfg["num_attention_heads"])
+    return {"hidden": h, "heads": n, "kv_heads": n, "head_dim": h // n, "ffn": int(cfg["ffn_dim"]),
+            "mlp_matrices": 2, "layers": int(cfg["num_hidden_layers"]),
+            "vocab": int(cfg["vocab_size"])}
+
+
+def served_params(cfg):
+    """Parameters a forward must read whatever implements it: ``a_forward``, once
+    however many tokens it holds (every layer's four projections and two MLP
+    matrices with their biases, the LayerNorms, and the tied table, which the
+    head multiplies whole: the embedding rows are in it); ``a_token``, once a
+    token (its position's row of the learned table).  opt-1.3b: 1,311,559,680
+    and 2,048; with all 2,048 position rows the model has 1,315,753,984."""
+    d = serve_dims(cfg)
+    h, f = d["hidden"], d["ffn"]
+    layer = (3 * h * h + 3 * h) + (h * h + h) + (h * f + f) + (f * h + h) + 2 * 2 * h
+    return {"a_forward": d["layers"] * layer + 2 * h + d["vocab"] * h, "a_token": h}

@@ -17,7 +17,7 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, REPO)
 
-from benchmark.lib import harness, serve, traffic  # noqa: E402
+from benchmark.lib import faults, harness, reference, serve, traffic  # noqa: E402
 
 TINY_CONFIG = {
     "model_type": "opt", "hidden_size": 256, "ffn_dim": 1024, "num_attention_heads": 4,
@@ -316,10 +316,15 @@ def test_whole_serve_run_tiny(tiny_root, tmp_path, cell, e2e):
     cmp = end["compared"]
     assert cmp["rows"] > 0 and 0 < cmp["logits_kl"] <= TINY_KL_MAX
     assert cmp["greedy_served"] > 0 and cmp["greedy_not_best"] == 0
+    assert cmp["sampled_tokens"] > 0 and cmp["sampled_outside_nucleus"] == 0
+    assert abs(cmp["sampled_logprob_z"]) <= serve.LOGPROB_Z_MAX
+    assert all(cmp["checks"].values()) and list(end)[-1] == "compared"
+    assert set(cmp["limits"]) == {"logits_kl", "greedy_not_best", "sampled_outside_nucleus",
+                                  "sampled_logprob_z"}
     json.dumps(end)
 
     traced = _run(tiny_root, cell, tmp_path, seed=105, trace=True)
-    assert traced["correct"] is True
+    assert traced["correct"] is True and list(traced)[-1] == "compared"
     got = set(traced["metrics"])
     assert not got & {"setup_s", "serve_tokens_per_s_per_chip", "ttft_p95_ms",
                       "tokens_per_s_per_chip"}
@@ -362,25 +367,168 @@ def test_a_cache_offset_off_by_one_is_not_correct(tiny_root, tmp_path, monkeypat
     assert res["correct"] is False and res["compared"]["logits_kl"] > 100 * TINY_KL_MAX
 
 
-def test_an_altered_token_is_not_correct(tiny_root, tmp_path, monkeypatch):
-    """A token altered where it is produced: the greedy sampler hands over the
-    runner-up.  Lengths, slots, the engine and its logits stay sound; the
-    served token is not the best of the row it was drawn from."""
-    from galvatron_tpu.serving import engine as engine_mod
+def _only_failed(res):
+    """The checks of ``correct`` that came out false."""
+    return sorted(k for k, ok in res["compared"]["checks"].items() if not ok)
 
-    real = engine_mod._sample_host
 
-    def runner_up(rng, logits, temperature, top_k, top_p):
-        if temperature < 1e-3:
-            return int(np.argsort(np.asarray(logits))[-2])
-        return real(rng, logits, temperature, top_k, top_p)
-
-    monkeypatch.setattr(engine_mod, "_sample_host", runner_up)
-    res = _run(tiny_root, "tiny_peak", tmp_path, seed=105)
+def test_an_altered_token_is_not_correct(tiny_root, tmp_path):
+    """A token altered where it is produced, through the engine's public taps
+    (``benchmark/lib/faults.py``: ``submit_request`` -> ``Request.generated``,
+    ``capture_logits``): the last token of every kept greedy request is handed
+    over as the runner-up of the row the tap has just written.  The last token
+    feeds no later step, so lengths, slots, the engine and every logits row stay
+    sound; one greedy token a kept request is not the best of its row, and no
+    other check fails."""
+    with faults.last_token(faults.runner_up_if_greedy):
+        res = _run(tiny_root, "tiny_peak", tmp_path, seed=105)
     assert res["correct"] is False and res["failed"] == 0
     cmp = res["compared"]
-    assert cmp["greedy_not_best"] == cmp["greedy_served"] > 0
+    assert cmp["greedy_not_best"] == cmp["greedy_requests"] > 0
+    assert cmp["greedy_served"] > cmp["greedy_requests"]
     assert cmp["logits_kl"] <= TINY_KL_MAX
+    assert _only_failed(res) == ["greedy_tokens"]
+
+
+def test_a_token_outside_the_nucleus_is_not_correct(tiny_root, tmp_path):
+    """The same seam, a sampled request: its last token is handed over as the id
+    with the smallest logit of its row, far outside the nucleus it was to be
+    drawn from.  ``sampled_outside_nucleus`` counts one a kept sampled request
+    and no other check fails (a token outside the support is not in z)."""
+    with faults.last_token(faults.outside_nucleus_if_sampled):
+        res = _run(tiny_root, "tiny_peak", tmp_path, seed=105)
+    assert res["correct"] is False and res["failed"] == 0
+    cmp = res["compared"]
+    assert cmp["sampled_outside_nucleus"] == cmp["sampled_requests"] > 0
+    assert abs(cmp["sampled_logprob_z"]) <= serve.LOGPROB_Z_MAX
+    assert cmp["greedy_not_best"] == 0 and cmp["logits_kl"] <= TINY_KL_MAX
+    assert _only_failed(res) == ["sampled_tokens"]
+
+
+def test_a_sampler_that_ignores_top_p_is_not_correct(tiny_root, tmp_path):
+    """The engine draws every sampled request without its nucleus (the request
+    states 0.95, the engine is handed 0): some of its tokens land past the cut,
+    and nothing else of the run is touched."""
+    with faults.submitting(faults.no_nucleus):
+        res = _run(tiny_root, "tiny_peak", tmp_path, seed=108, seconds=1.5)
+    assert res["correct"] is False and res["failed"] == 0
+    assert res["compared"]["sampled_outside_nucleus"] > 0
+    assert _only_failed(res) == ["sampled_tokens"]
+
+
+def test_the_seam_restores_the_engine(tiny_root):
+    from galvatron_tpu.serving import Engine
+
+    real = Engine.submit_request
+    with faults.submitting(faults.hot):
+        assert Engine.submit_request is not real
+    assert Engine.submit_request is real
+    assert faults.hot({"temperature": 0.8, "top_p": 0.95}) == {"temperature": 1.0, "top_p": 0.95}
+    assert faults.hot({"temperature": 1e-4}) == {"temperature": 1e-4}  # a greedy request stays
+    assert faults.no_nucleus({"temperature": 0.8, "top_p": 0.95})["top_p"] == 0.0
+
+
+# --- the sampled tokens' check at the function -----------------------------------------
+# rows and tokens made here, at the serving cell's vocabulary and logit variance
+# (``opt-1.3b``: 50,272 ids, ``initial_logit_variance`` 0.8192, bfloat16 values in
+# float32 as the engine's tap keeps them); the request states 0.8 / 0.95
+
+
+def _cell_rows(rng, n):
+    import ml_dtypes
+
+    _, config, spec = harness.load_cell(REPO, "opt-1.3b_serve_above_knee")
+    sd = float(config["initial_logit_variance"]) ** 0.5
+    assert (spec["sampling"]["temperature"], spec["sampling"]["top_p"]) == (0.8, 0.95)
+    for _ in range(n):
+        yield (sd * rng.standard_normal(int(config["vocab_size"]))
+               ).astype(ml_dtypes.bfloat16).astype(np.float32)
+
+
+def _drawn(rng, n, temperature=0.8, top_p=0.95):
+    """(row, token, 0.8, 0, 0.95): the token drawn by numpy from the plain
+    statement of the distribution at ``temperature`` / ``top_p``; what the
+    request STATED is 0.8 / 0.95 whatever it was drawn at."""
+    for row in _cell_rows(rng, n):
+        p = reference.processed_distribution(row, temperature, 0, top_p)
+        yield row, int(rng.choice(len(p), p=p)), 0.8, 0, 0.95
+
+
+@pytest.mark.parametrize("seed", range(20))
+def test_tokens_drawn_as_stated_pass_the_sampled_check(seed):
+    got = reference.sampled_tokens_check(_drawn(np.random.default_rng(seed), 60),
+                                         serve.NUCLEUS_SLACK)
+    assert got["tokens"] == got["inside"] == 60 and got["outside"] == got["past_cut"] == 0
+    assert abs(got["z"]) < serve.LOGPROB_Z_MAX
+
+
+def test_tokens_drawn_at_the_wrong_temperature_fail_by_z():
+    """Temperature 1.0 where the request says 0.8: every term shifts by ~0.26
+    against a deviation of ~1.0, so 1,500 tokens (what a 51 s window keeps) read
+    |z| ~ 9; the hotter draws also land past the cut now and then."""
+    got = reference.sampled_tokens_check(
+        _drawn(np.random.default_rng(42), 1500, temperature=1.0), serve.NUCLEUS_SLACK)
+    assert abs(got["z"]) > serve.LOGPROB_Z_MAX
+    assert 0.2 < (got["mean"] - got["logp"]) / got["inside"] < 0.32
+
+
+def test_tokens_drawn_without_the_nucleus_fall_outside_it():
+    """No nucleus at all: a token lands outside with probability ~4.9%."""
+    got = reference.sampled_tokens_check(
+        _drawn(np.random.default_rng(43), 300, top_p=0.0), serve.NUCLEUS_SLACK)
+    assert 4 <= got["outside"] <= 40 and got["tokens"] == 300
+
+
+def test_a_narrower_nucleus_is_not_caught():
+    """What the check cannot see (PERF.md section 2): top_p 0.90 for 0.95 keeps
+    every token inside and shifts z by ~4 over 1,500 tokens, under the limit;
+    600 tokens here read well under it."""
+    got = reference.sampled_tokens_check(
+        _drawn(np.random.default_rng(44), 600, top_p=0.90), serve.NUCLEUS_SLACK)
+    assert got["outside"] == 0 and abs(got["z"]) < serve.LOGPROB_Z_MAX
+
+
+@pytest.mark.parametrize("temperature,top_k,top_p", [
+    (0.8, 0, 0.95), (0.8, 50, 0.9), (1.3, 1000, 0.0), (0.8, 0, 0.0), (0.5, 7, 0.5)])
+def test_token_stats_are_the_plain_distributions(temperature, top_k, top_p):
+    """``token_stats`` (over a row's distinct values) against
+    ``processed_distribution`` (the plain statement): ln p of a token inside the
+    support, -inf outside it, the mass of strictly larger logits, the mean and
+    the variance of ln p."""
+    rng = np.random.default_rng(7)
+    for row in _cell_rows(rng, 3):
+        p = reference.processed_distribution(row, temperature, top_k, top_p)
+        assert p.sum() == pytest.approx(1.0, abs=1e-12)
+        sup = p > 0
+        logp = np.log(p[sup])
+        mean = float((p[sup] * logp).sum())
+        var = float((p[sup] * logp * logp).sum() - mean * mean)
+        full = reference.processed_distribution(row, temperature, top_k, 0.0)  # before the nucleus
+        for tok in (int(rng.choice(len(p), p=p)), int(np.argmax(row)), int(np.argmin(row))):
+            above, got, m, v = reference.token_stats(row, tok, temperature, top_k, top_p)
+            assert (m, v) == (pytest.approx(mean, abs=1e-9), pytest.approx(var, abs=1e-9))
+            if sup[tok]:
+                assert got == pytest.approx(float(np.log(p[tok])), abs=1e-9)
+                assert above == pytest.approx(float(full[row > row[tok]].sum()), abs=1e-9)
+                assert top_p == 0 or above < top_p
+            else:
+                assert got == float("-inf") and (above == float("inf") or above >= top_p)
+
+
+def test_the_nucleus_keeps_ties_at_the_cut_and_greedy_shares_the_best():
+    row = np.log(np.array([0.4, 0.3, 0.1, 0.1, 0.1]))
+    # the prefix {0.4, 0.3} reaches 0.7 exactly at its second token
+    assert (reference.processed_distribution(row, 1.0, 0, 0.7) > 0).tolist() == [1, 1, 0, 0, 0]
+    # 0.75 needs one of the three tied tokens: all three are kept
+    assert (reference.processed_distribution(row, 1.0, 0, 0.75) > 0).tolist() == [1] * 5
+    assert reference.processed_distribution(row, 1.0, 2, 0.0).tolist() == pytest.approx(
+        [4 / 7, 3 / 7, 0, 0, 0])
+    assert reference.processed_distribution([1.0, 3.0, 3.0], 0.0).tolist() == [0, 0.5, 0.5]
+    above, logp, _, _ = reference.token_stats(row, 3, 1.0, 0, 0.75)
+    assert above == pytest.approx(0.7) and logp == pytest.approx(np.log(0.1))
+    assert reference.token_stats(row, 3, 1.0, 0, 0.7)[1] == float("-inf")
+    with pytest.raises(ValueError):
+        reference.token_stats(row, 0, 0.0)
 
 
 def test_a_request_that_expires_counts_as_failed(tiny_root, tmp_path, monkeypatch):
@@ -408,6 +556,6 @@ def test_serving_readers_leave_a_training_context_alone():
     ctx = {"spans": [{"name": "sample", "start": 0.0, "end": 1.0, "step": 3, "args": {}}],
            "setup_spans": [], "trace": None, "memory_peak_bytes": 1 << 30, "say": print,
            "traffic": {"seq_len": 8}}
-    mods = [m for m in harness.discover_metrics(REPO) if "_serve" in open(m.__file__).read()]
-    assert len(mods) == 7
+    mods = [m for m in harness.discover_metrics(REPO) if m.MOVES == "serve_tokens_per_s_per_chip"]
+    assert len(mods) >= 22  # 7 of PR 35, 7 of PR 39, 8 of PR 42, and what later PRs add
     assert all(m.compute(ctx) is None for m in mods)
