@@ -399,7 +399,7 @@ def scenario_evict(out_dir):
     drain(port)
     rc, out = wait_exit(proc)
     check_common("evict", rc, out, out_dir)
-    assert "serving warm-start: 2/2" in out, \
+    assert "serving warm-start: 3/3" in out, \
         f"evict: paged programs never warm-started\n{out[-2000:]}"
     print(f"  {outcomes['ok']} served, {outcomes['queue_full']} shed with "
           f"Retry-After, {outcomes['engine_restarted']} crash 503s, "

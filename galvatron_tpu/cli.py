@@ -526,7 +526,7 @@ def main(argv: Optional[List[str]] = None, model_default: Optional[str] = None) 
 
 def _serve_warmup(ns, engine, service, listening) -> None:
     """`cli serve` startup warm (side thread): persistent-cache warm start
-    of the two pinned programs (when a cache is wired), then ONE real
+    of the pinned programs (when a cache is wired), then ONE real
     generation through the scheduler so the jitted entry points exist —
     only then does ``service.starting`` clear and ``/readyz`` report ready.
     Warmth is best-effort: any failure degrades to the lazy-compile path

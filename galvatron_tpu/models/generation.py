@@ -340,6 +340,78 @@ def host_probs(logits, temperature: float, top_k: int, top_p: float):
     return p / p.sum()
 
 
+# The serving engine's draw: :func:`host_probs`'s distribution for MANY rows at
+# once on the device, every parameter a per-row array, so one compiled program
+# serves any mix of greedy, top-k and nucleus requests. No sort: both cuts are
+# "the largest threshold t with weight(row >= t) >= target" (a count for top-k,
+# probability mass for the nucleus), a quantity that falls monotonically with
+# t, found by 32 bisection steps over the float32 order, one masked sum over
+# the rows each.
+
+
+def _order_keys(x):
+    """float32 (no NaN) -> uint32 with the same order; equal floats give equal
+    keys (``+ 0.0`` folds -0.0 into +0.0)."""
+    bits = jax.lax.bitcast_convert_type(x + 0.0, jnp.uint32)
+    return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(0x80000000))
+
+
+def _largest_threshold(keys, weight, target, wanted):
+    """Per row the largest uint32 ``t`` with ``sum(weight[keys >= t]) >=
+    target`` (the sum falls as ``t`` rises; it holds at ``t`` = 0), built bit
+    by bit from the top; 0 (keep everything) for a row not ``wanted``, and no
+    pass at all when no row wants one."""
+    top = jnp.uint32(0x80000000)
+
+    def step(i, t):
+        cand = t | (top >> i.astype(jnp.uint32))
+        got = jnp.sum(jnp.where(keys >= cand[:, None], weight, 0), axis=-1)
+        return jnp.where(got >= target, cand, t)
+
+    steps = jnp.where(jnp.any(wanted), 32, 0)
+    t = jax.lax.fori_loop(0, steps, step, jnp.zeros(keys.shape[:1], jnp.uint32))
+    return jnp.where(wanted, t, jnp.uint32(0))
+
+
+def kept_rows(logits, temperature, top_k, top_p):
+    """(scaled, keep) for rows ``logits`` (B, V) under per-row ``temperature``,
+    ``top_k``, ``top_p`` (B,): ``scaled`` the float32 logits over the
+    temperature (over 1 for a greedy row), ``keep`` the support of
+    :func:`host_probs`: the ``top_k`` largest (ties with the k-th kept), then
+    of those every token whose strictly larger tokens hold less than ``top_p``
+    of the mass (so at least one, ties at the cut kept). ``top_k`` <= 0 and
+    ``top_p`` outside (0, 1) switch a cut off, row by row."""
+    t = temperature.astype(jnp.float32)[:, None]
+    scaled = logits.astype(jnp.float32) / jnp.where(t > 0, t, 1.0)
+    keys = _order_keys(scaled)
+    k = jnp.minimum(top_k, logits.shape[-1]).astype(jnp.int32)
+    cut = _largest_threshold(keys, jnp.int32(1), k, top_k > 0)
+    mass = jnp.where(keys >= cut[:, None],
+                     jnp.exp(scaled - jnp.max(scaled, axis=-1, keepdims=True)), 0.0)
+    p = top_p.astype(jnp.float32)
+    nucleus = _largest_threshold(keys, mass, p * jnp.sum(mass, axis=-1), (p > 0) & (p < 1))
+    return scaled, keys >= jnp.maximum(cut, nucleus)[:, None]
+
+
+@jax.named_scope("sample")
+def draw_rows(logits, temperature, top_k, top_p, seed, rid, index):
+    """One token a row of ``logits`` (B, V) -> (B,) int32, each row under its
+    own ``temperature`` / ``top_k`` / ``top_p`` (B,): the argmax for
+    ``temperature`` <= 0, else a draw from :func:`host_probs`'s distribution
+    (:func:`kept_rows`, Gumbel-max over the kept tokens). The randomness is made
+    here from integers: ``seed`` (2,) uint32 words, and per row ``rid`` and
+    ``index`` (the request's id and which of its tokens this is), so a row's
+    token depends on (seed, rid, index) and its logits alone, not on its
+    neighbours or its place in the batch."""
+    scaled, keep = kept_rows(logits, temperature, top_k, top_p)
+    base = jax.random.wrap_key_data(seed.astype(jnp.uint32), impl="threefry2x32")
+    keys = jax.vmap(lambda r, i: jax.random.fold_in(jax.random.fold_in(base, r), i))(rid, index)
+    noise = jax.vmap(lambda key: jax.random.gumbel(key, logits.shape[-1:], jnp.float32))(keys)
+    drawn = jnp.argmax(jnp.where(keep, scaled + noise, -jnp.inf), axis=-1)
+    greedy = jnp.argmax(logits, axis=-1)
+    return jnp.where(temperature > 0, drawn, greedy).astype(jnp.int32)
+
+
 # ---------------------------------------------------------------------------
 # Generation loop
 # ---------------------------------------------------------------------------

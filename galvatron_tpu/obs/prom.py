@@ -259,6 +259,12 @@ def server_metrics_text(service) -> str:
                     help_="speculative-decoding draft tokens proposed by "
                     "the prompt-lookup drafter"
                     if name == "draft_proposed" else "")
+        for name, where in (("draws_device", "on the device by the engine's "
+                             "one sampler program"),
+                            ("draws_host", "on the host (the speculative "
+                             "engine's path)")):
+            out.add(f"serving_{name}_total", s.get(name), mtype="counter",
+                    help_=f"tokens drawn {where}")
         out.add("serving_accepted_tokens_per_step",
                 s.get("accepted_tokens_per_step"),
                 help_="tokens emitted per decode iteration (batched over "

@@ -20,8 +20,10 @@ Four layers, one per module:
   ``EngineRestarted``/``RequestShed``/``RequestCancelled``/
   ``DeadlineExceeded``).
 - [[engine]] ``Engine`` — the loop: one jitted decode step over all slots
-  per iteration, chunked prefill on admission, host-side per-request
-  sampling, retire-on-eos/budget/deadline/cancel, graceful ``drain`` with
+  per iteration, chunked prefill on admission, every slot's next token
+  drawn on the device by one batched program behind the step (per-request
+  parameters as data; the speculative engine draws on the host),
+  retire-on-eos/budget/deadline/cancel, graceful ``drain`` with
   a post-drain zero-leak ``audit``.  Optional numerics/speed levers:
   per-channel int8 weights (``--serve_quant int8``, ops/quant.py) and
   speculative decoding with the [[speculative]] prompt-lookup drafter

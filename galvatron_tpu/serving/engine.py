@@ -34,12 +34,22 @@ operand), with copy-on-write prefix sharing and block-headroom admission.
 The engine keeps the same pinned-program discipline — the paged prefill
 and decode twins replace the slot pair one-for-one.
 
-Sampling runs on host from the per-slot last logits: each request carries
-its own temperature/top_k/top_p, which therefore never enter the compiled
-program (a per-request static ``top_k`` would recompile; a host-side
-``np.argmax``/categorical over ``(V,)`` per slot is noise next to the
-forward). Greedy host sampling matches ``generate``'s on-device argmax
-bit-for-bit, which is what the parity tests pin.
+Sampling runs on the DEVICE, behind the decode step: the step's ``(num_slots,
+V)`` logits rows stay there and ONE batched program (``_sample_rows`` over
+``generation.draw_rows``) draws every active slot's next token from them; 16
+ids come back where 1.6 MB of logits and 16 numpy draws went. Everything a
+request may set (temperature, top-k, top-p) and the integers its randomness
+comes from (engine seed, request id, token index) are per-slot ARRAYS, never
+static arguments, so a greedy, a top-k and a nucleus request are the same
+compiled program and a request's tokens do not depend on its neighbours or its
+slot. A prompt's last row reaches the rows inside the prefill program (traced
+slot and row index). Rows come to the host only for a request that taps them
+(``capture_logits``), by a whole-buffer copy that needs no program. Greedy
+draws match ``generate``'s on-device argmax bit-for-bit, which is what the
+parity tests pin. The speculative engine (``spec_decode_k > 0``) keeps the
+host path (``_sample_host``): its verifier scores drafts against whole rows
+with ``generation.host_probs`` and draws its residual from an edited row, so
+its rows have to be on the host anyway.
 """
 
 from __future__ import annotations
@@ -76,20 +86,37 @@ _DECODE_STEP_BUCKETS = (
 )
 
 
-@partial(jax.jit, static_argnames=("cfg",), donate_argnames=("cache",))
-def _prefill_chunk(params, cfg: ModelConfig, cache: KVCache, tokens, slot, offset):
+def _keep_row(rows, logits, slot, last):
+    """``rows`` (num_slots, V) with row ``slot`` replaced by row ``last`` of a
+    chunk's ``logits`` (C, V); ``slot`` and ``last`` traced. The rows carry the
+    logits' own dtype: a draw sees no precision below its row's."""
+    if rows.dtype != logits.dtype:
+        raise ValueError(
+            f"the engine's rows are {rows.dtype} and the model's logits "
+            f"{logits.dtype}: a row must be kept in the precision it was computed in"
+        )
+    row = jax.lax.dynamic_slice_in_dim(logits, last, 1, axis=0)
+    return jax.lax.dynamic_update_slice_in_dim(rows, row, slot, axis=0)
+
+
+@partial(jax.jit, static_argnames=("cfg",), donate_argnames=("cache", "rows"))
+def _prefill_chunk(params, cfg: ModelConfig, cache: KVCache, tokens, slot, offset,
+                   rows, last):
     """Prefill one chunk of one request into its slot.
 
     tokens: (1, C) — the request's tokens [offset, offset+C) padded at the
     tail; slot/offset are traced scalars, so every chunk of every request
-    reuses this one compiled program. Returns ((C, V) logits, cache).
+    reuses this one compiled program. Row ``last`` of the chunk's (C, V)
+    logits lands in row ``slot`` of ``rows`` (``_keep_row``): after a
+    prompt's final chunk that is the row its first token is drawn from.
+    Returns (rows, cache).
     Garbage k/v written by tail padding is invisible forever: positions
     beyond a row's own query offset are causally masked, and each decode
     step overwrites its position before attending to it."""
     logits, cache = generation.forward_with_cache(
         params, tokens, cfg, cache, offset, slot=slot
     )
-    return logits[0], cache
+    return _keep_row(rows, logits[0], slot, last), cache
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnames=("cache",))
@@ -122,17 +149,17 @@ def _decode_verify(params, cfg: ModelConfig, cache: KVCache, tokens, offsets):
     return logits, cache
 
 
-@partial(jax.jit, static_argnames=("cfg",), donate_argnames=("pool",))
+@partial(jax.jit, static_argnames=("cfg",), donate_argnames=("pool", "rows"))
 def _paged_prefill_chunk(params, cfg: ModelConfig, pool: KVCache, tokens, table,
-                         offset):
+                         offset, rows, slot, last):
     """Paged twin of ``_prefill_chunk``: tokens (1, C) land in the request's
     blocks via its (1, max_blocks) table row; ``offset`` is a (1,) traced
     position. Tail-padding garbage goes to the null block or to positions
-    past the query offset — invisible either way."""
+    past the query offset — invisible either way. Returns (rows, pool)."""
     logits, pool = generation.forward_with_cache_paged(
         params, tokens, cfg, pool, table, offset
     )
-    return logits[0], pool
+    return _keep_row(rows, logits[0], slot, last), pool
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnames=("pool",))
@@ -160,6 +187,35 @@ def _paged_decode_verify(params, cfg: ModelConfig, pool: KVCache, tokens,
         params, tokens, cfg, pool, tables, offsets
     )
     return logits, pool
+
+
+#: the engine's counters; ``draws_device`` / ``draws_host`` count the tokens
+#: drawn by ``_sample_rows`` and by ``_sample_host`` (the speculative engine's)
+_COUNTERS = (
+    "steps", "prefill_chunks", "prefill_tokens", "tokens_generated",
+    "engine_restarts", "draft_proposed", "draft_accepted",
+    "spec_steps", "spec_fallbacks", "draws_device", "draws_host",
+)
+
+#: lines of ``_sample_rows``'s two operand tables, one column a slot
+_TEMPERATURE, _TOP_P = 0, 1
+_ACTIVE, _TOP_K, _RID, _INDEX, _SEED_LO, _SEED_HI = range(6)
+
+
+@jax.jit
+def _sample_rows(rows, knobs, ints):
+    """The engine's draw: one token a slot from the device-resident ``rows``
+    (num_slots, V), each slot under its own request's parameters. ``knobs``
+    (2, num_slots) float32 holds temperature and top-p, ``ints`` (6, num_slots)
+    uint32 the active mask, top-k and what the randomness is made from (request
+    id, token index, the engine seed's two words): data, all of it, so every mix
+    of requests is this one program. Returns (num_slots,) int32, 0 for a slot
+    that is not active."""
+    ids = generation.draw_rows(
+        rows, knobs[_TEMPERATURE], ints[_TOP_K].astype(jnp.int32), knobs[_TOP_P],
+        jnp.stack([ints[_SEED_LO, 0], ints[_SEED_HI, 0]]), ints[_RID], ints[_INDEX],
+    )
+    return jnp.where(ints[_ACTIVE] > 0, ids, 0)
 
 
 def _sample_host(rng: np.random.Generator, logits: np.ndarray,
@@ -268,11 +324,7 @@ class Engine:
             max_restarts=max_engine_restarts, backoff_s=restart_backoff_s,
             flight_dir=flight_dir,
         )
-        self.counters = Counters(
-            "steps", "prefill_chunks", "prefill_tokens", "tokens_generated",
-            "engine_restarts", "draft_proposed", "draft_accepted",
-            "spec_steps", "spec_fallbacks",
-        )
+        self.counters = Counters(*_COUNTERS)
         self.ttft = QuantileWindow(512)
         # cumulative-bucket twins of the quantile windows: quantiles are the
         # single-process readout; bucket counts SUM across replicas, so the
@@ -287,15 +339,26 @@ class Engine:
         # summary of the most recent restart's warm-up, for tests/probes
         self._store = None
         self.last_restart_warm: Optional[dict] = None
-        self._last_logits = np.zeros(
+        # where a token is drawn is what the engine knows of itself: plain
+        # decoding draws on the device from rows that stay there; the
+        # speculative verifier needs whole rows on the host and draws there
+        self._device_draw = self.spec_k == 0
+        # the rows the next tokens are drawn from: on the device (the decode
+        # step's logits, a prompt's last row set in by the prefill program),
+        # and on the host, float32, the rows of the slots that need them there
+        # (a request that taps; every slot of a speculative engine)
+        self._rows = self._fresh_rows()
+        self._host_rows = np.zeros(
             (self.slots.num_slots, cfg.vocab_size), np.float32
         )
+        # the token each slot's request takes next (device draws, read back)
+        self._drawn = np.zeros((self.slots.num_slots,), np.int32)
         self._by_slot: Dict[int, Request] = {}
         self._rng: Dict[int, np.random.Generator] = {}
         self._busy_s = 0.0
         self._last_step_tps = 0.0
         # GALVATRON_RECOMPILE_GUARD=1 (debug/CI): after the first decode
-        # iteration, the engine's two programs exist — any further jit-cache
+        # iteration, the engine's programs exist — any further jit-cache
         # growth is a static-arg/shape leak compiling per request, and the
         # guard fails the offending step loudly (analysis/guards.py) instead
         # of letting latency quietly collapse. Per-engine baseline: other
@@ -417,7 +480,7 @@ class Engine:
                 "capture_logits is not supported with spec_decode_k > 0: a token "
                 "taken from a verify window has no one row it was drawn from"
             )
-        vocab = self._last_logits.shape[1]
+        vocab = self._host_rows.shape[1]
         if not (isinstance(buf, np.ndarray) and buf.dtype == np.float32
                 and buf.ndim == 2 and buf.shape[0] >= max_new_tokens
                 and buf.shape[1] == vocab and buf.flags.writeable):
@@ -510,6 +573,10 @@ class Engine:
             ),
             "spec_steps": ec["spec_steps"],
             "spec_fallbacks": ec["spec_fallbacks"],
+            # where tokens are drawn: ``_sample_rows`` on the device, or the
+            # speculative engine's ``_sample_host``
+            "draws_device": ec["draws_device"],
+            "draws_host": ec["draws_host"],
             "submitted": sc["submitted"],
             "admitted": sc["admitted"],
             "completed": sc["completed"],
@@ -543,11 +610,7 @@ class Engine:
     def reset_metrics(self) -> None:
         """Zero counters/TTFT/throughput accounting (bench: drop warmup
         compile time from the measured window). Call while idle."""
-        self.counters = Counters(
-            "steps", "prefill_chunks", "prefill_tokens", "tokens_generated",
-            "engine_restarts", "draft_proposed", "draft_accepted",
-            "spec_steps", "spec_fallbacks",
-        )
+        self.counters = Counters(*_COUNTERS)
         self.scheduler.counters = Scheduler.new_counters()
         # the supervisor's progress detection reads the completed counter:
         # its high-water mark must reset with it, or post-reset completions
@@ -670,6 +733,53 @@ class Engine:
 
     def __exit__(self, *exc):
         self.close()
+
+    # -- the rows tokens are drawn from ---------------------------------------
+
+    def _fresh_rows(self):
+        """Zeroed device rows (num_slots, V) in the logits' dtype, by a copy
+        from the host: no program to compile."""
+        shape = (self.slots.num_slots, self.cfg.vocab_size)
+        return jax.device_put(np.zeros(shape, jnp.dtype(self.cfg.dtype)))
+
+    @property
+    def _last_logits(self) -> np.ndarray:
+        """(num_slots, V) float32: row ``slot`` is the row that slot's next
+        token is drawn from. The speculative engine holds them on the host;
+        otherwise this is a copy of the device's rows made on every read (tests
+        and an operator's probe read it; the loop itself does not)."""
+        if not self._device_draw:
+            return self._host_rows
+        return np.asarray(self._rows).astype(np.float32)
+
+    def _dispatch_draw(self, slots: Sequence[int]):
+        """Start ``_sample_rows`` for ``slots`` on the device rows: each slot's
+        next token under its request's own parameters, index ``len(generated)``.
+        Returns the ids, still on the device."""
+        n = self.slots.num_slots
+        knobs = np.zeros((2, n), np.float32)
+        ints = np.zeros((6, n), np.uint32)
+        ints[_SEED_LO], ints[_SEED_HI] = self.seed & 0xFFFFFFFF, (self.seed >> 32) & 0xFFFFFFFF
+        for slot in slots:
+            req = self._by_slot[slot]
+            knobs[:, slot] = req.temperature, req.top_p
+            ints[:_SEED_LO, slot] = (1, max(req.top_k, 0), req.rid & 0xFFFFFFFF,
+                                     len(req.generated))
+        return _sample_rows(self._rows, knobs, ints)
+
+    def _collect_draw(self, ids, slots: Sequence[int], tapped: Sequence[int]) -> None:
+        """Bring a draw to the host: the ids of ``slots`` (4 bytes a slot), and
+        for the ``tapped`` ones the rows they were drawn from, as float32."""
+        slots = list(slots)
+        # (only these: the others' tokens, drawn behind the last step, still wait)
+        self._drawn[slots] = np.asarray(ids)[slots]
+        if tapped:
+            rows = np.asarray(self._rows)  # the whole buffer: a copy, no program
+            for slot in tapped:
+                self._host_rows[slot] = rows[slot]
+
+    def _tapped(self, slots: Sequence[int]) -> List[int]:
+        return [s for s in slots if self._by_slot[s].capture_logits is not None]
 
     # -- engine loop (single thread owns cache + slots + jit calls) ---------
 
@@ -834,7 +944,6 @@ class Engine:
             # identical k/v (deterministic function of tokens + positions),
             # so the rewrite is idempotent.
             starts[-1] = smax - c
-        last_row = None
         for start in starts:
             # the deadline is end-to-end: a long prompt must not burn chip
             # time prefilling past the moment its client stops waiting
@@ -851,43 +960,48 @@ class Engine:
             # next chunk would corrupt the in-flight one's input
             buf = np.full((1, c), self.pad_id, np.int32)
             buf[0, :n] = chunk
+            # the chunk's last real row goes into the slot's row of the device
+            # rows inside the program; the final chunk's is the one that stays
             if self.paged:
                 # the slid-left window may dip below the attached prefix —
                 # COW-copy any shared/registered block the write covers
                 # (recomputed k/v is identical; this protects the CACHE
                 # entry and other holders, not this request's numerics)
                 self.slots.ensure_writable(slot, start, min(start + c, smax))
-                logits, pool = _paged_prefill_chunk(
+                self._rows, self.slots.pool = _paged_prefill_chunk(
                     self.params, self.cfg, self.slots.pool, jnp.asarray(buf),
                     jnp.asarray(self.slots.tables[slot:slot + 1]),
                     jnp.asarray([start], np.int32),
+                    self._rows, np.int32(slot), np.int32(n - 1),
                 )
-                self.slots.pool = pool
             else:
-                logits, cache = _prefill_chunk(
+                self._rows, self.slots.cache = _prefill_chunk(
                     self.params, self.cfg, self.slots.cache, jnp.asarray(buf),
-                    np.int32(slot), np.int32(start),
+                    np.int32(slot), np.int32(start), self._rows, np.int32(n - 1),
                 )
-                self.slots.cache = cache
-            last_row = (logits, n - 1)
             self.counters.inc("prefill_chunks")
             self.counters.inc("prefill_tokens", n)
-        logits, idx = last_row
-        self._last_logits[slot] = np.asarray(logits[idx], np.float32)
         self.slots.lengths[slot] = len(toks)
         if self.paged:
             # publish the prompt's full blocks while the request decodes, so
             # a same-prefix request admitted next iteration already shares
             self.slots.register_prefix(slot, req.tokens)
         self._by_slot[slot] = req
-        self._rng[slot] = np.random.default_rng((self.seed, req.rid))
+        if self._device_draw:
+            # the first token, drawn from the prompt's last row where it lies
+            self._collect_draw(self._dispatch_draw([slot]), [slot], self._tapped([slot]))
+        else:
+            self._host_rows[slot] = np.asarray(self._rows)[slot]
+            self._rng[slot] = np.random.default_rng((self.seed, req.rid))
         rz.advance(req, rz.DECODING, slot=slot)
         self._busy_s += time.perf_counter() - t0
 
     def _step(self) -> None:
-        """One decode iteration: sample for every active slot from its last
-        logits, retire eos/budget-exhausted/cancelled/over-deadline rows,
-        then run ONE shared forward for the survivors."""
+        """One decode iteration: every active slot takes its next token (drawn
+        on the device behind the forward that made its row, or here on the
+        host by the speculative engine), eos/budget-exhausted/cancelled/
+        over-deadline rows retire, then ONE shared forward runs for the
+        survivors, with the draw of their next tokens behind it."""
         t0 = time.perf_counter()
         # the chaos seam: engine_crash_at_iter raises here (the supervisor
         # must recover), slow_decode_ms stretches the iteration
@@ -917,14 +1031,19 @@ class Engine:
                 with _obs_tracer.span("sample_slot", slot=slot, rid=req.rid,
                                       greedy=req.temperature <= 0):
                     if req.capture_logits is not None:
-                        # the tap: the row token k is about to be drawn from
+                        # the tap: the row token k was drawn from
                         k = len(req.generated)
-                        req.capture_logits[k] = self._last_logits[slot]
+                        req.capture_logits[k] = self._host_rows[slot]
                         req.logits_rows = k + 1
-                    tok = _sample_host(
-                        self._rng[slot], self._last_logits[slot],
-                        req.temperature, req.top_k, req.top_p,
-                    )
+                    if self._device_draw:
+                        tok = int(self._drawn[slot])
+                        self.counters.inc("draws_device")
+                    else:
+                        tok = _sample_host(
+                            self._rng[slot], self._host_rows[slot],
+                            req.temperature, req.top_k, req.top_p,
+                        )
+                        self.counters.inc("draws_host")
                     sampled += 1
                     if req.first_token_at is None:
                         req.first_token_at = now
@@ -956,8 +1075,9 @@ class Engine:
             appended += self._verify_step(still, tokens, offsets, drafts)
         elif still:
             logits = self._forward_step("decode", tokens, offsets, still)
-            for slot in still:
-                self._last_logits[slot] = logits[slot]
+            if not self._device_draw:
+                for slot in still:
+                    self._host_rows[slot] = logits[slot]
         self.counters.inc("steps")
         self.counters.inc("tokens_generated", appended)
         if self._guard_armed:
@@ -970,15 +1090,19 @@ class Engine:
             self._last_step_tps = sampled / dt
 
     def _forward_step(self, name: str, tokens: np.ndarray, offsets: np.ndarray,
-                      still: Sequence[int], **attrs) -> np.ndarray:
-        """The iteration's one shared forward over ALL slots, to its logits on
-        the host: ``tokens`` (B,) is the plain decode step, (B, 1+k) the
-        speculative verify window. Its span ``name`` (``decode`` /
-        ``decode_verify``) closes on the logits' arrival and is covered by
-        three children: ``decode_dispatch`` (host: operands to the device and
-        the jitted call's return), ``decode_wait`` (the device: ``Span.sync``
+                      still: Sequence[int], **attrs) -> Optional[np.ndarray]:
+        """The iteration's one shared forward over ALL slots: ``tokens`` (B,)
+        is the plain decode step, (B, 1+k) the speculative verify window. Its
+        span ``name`` (``decode`` / ``decode_verify``) is covered by three
+        children: ``decode_dispatch`` (host: operands to the device and the
+        jitted calls' return), ``decode_wait`` (the device: ``Span.sync``
         blocks with the tracer on only, where ``np.asarray`` blocked anyway)
-        and ``logits_readback`` (the copy to the host, ``bytes``)."""
+        and ``logits_readback`` (the copy to the host, ``bytes``).
+
+        Drawing on the device, the logits stay there as the rows, the draw of
+        ``still``'s next tokens is dispatched behind the step, and what comes
+        back is their ids and the rows of the slots that tap (nothing is
+        returned). The speculative engine gets the logits on the host."""
         verify = tokens.ndim == 2
         with _obs_tracer.span(name, active=len(still), **attrs):
             with _obs_tracer.span("decode_dispatch"):
@@ -1003,13 +1127,27 @@ class Engine:
                         self.params, self.cfg, self.slots.cache,
                         jnp.asarray(tokens), jnp.asarray(offsets),
                     )
-            with _obs_tracer.span("decode_wait") as sp:
-                sp.sync(logits)
-            # np.asarray is the engine's own readback sync (it needs the
-            # logits on host to sample the next token), so the span closes on
+                if self._device_draw:
+                    self._rows = logits
+                    ids = self._dispatch_draw(still)
+                    tapped = self._tapped(still)
+                    # start the copies now: they run as soon as the device is
+                    # through, not when the host gets round to asking
+                    ids.copy_to_host_async()
+                    if tapped:
+                        logits.copy_to_host_async()
+            # np.asarray is the engine's own readback sync (the next iteration
+            # needs the ids, or the logits, on the host), so the span closes on
             # realized compute with the tracer off too
-            with _obs_tracer.span("logits_readback", bytes=logits.nbytes):
-                return np.asarray(logits)
+            with _obs_tracer.span("decode_wait") as sp:
+                sp.sync(ids if self._device_draw else logits)
+            if not self._device_draw:
+                with _obs_tracer.span("logits_readback", bytes=logits.nbytes):
+                    return np.asarray(logits)
+            with _obs_tracer.span("logits_readback",
+                                  bytes=ids.nbytes + (logits.nbytes if tapped else 0)):
+                self._collect_draw(ids, still, tapped)
+            return None
 
     def _build_drafts(self, still, offsets) -> Dict[int, List[int]]:
         """Propose up to ``spec_k`` draft tokens per surviving slot from the
@@ -1108,9 +1246,9 @@ class Engine:
             if rejected_at >= 0:
                 resid = np.asarray(L[rejected_at], np.float32).copy()
                 resid[d[rejected_at]] = -np.inf
-                self._last_logits[slot] = resid
+                self._host_rows[slot] = resid
             else:
-                self._last_logits[slot] = L[len(d)]
+                self._host_rows[slot] = L[len(d)]
         for slot in retired:
             self._retire(slot)
         return appended
@@ -1119,21 +1257,21 @@ class Engine:
         """Pin the DECLARED compiled-program set for the engine lifetime:
         the first call records the post-warmup baseline, later calls raise
         ``RecompileError`` on any growth (a static-arg or shape leak). Each
-        backend pins its own prefill + decode pair, plus the decode_verify
-        program when speculative decoding is on — the 2-program pin became
-        a declared set, not an open one; the paged backend's COW block copy
+        backend pins its own prefill + decode pair, plus the sampler, or the
+        decode_verify program when speculative decoding is on (that engine
+        draws on the host) — the 2-program pin became a declared set, not an
+        open one; the paged backend's COW block copy
         (one shape forever) compiles lazily at the first shared write, so
         it stays outside the guard."""
         from galvatron_tpu.analysis.guards import RecompileError, cache_sizes
 
         if self.paged:
             fns = [_paged_prefill_chunk, _paged_decode_step]
-            if self.spec_k > 0:
-                fns.append(_paged_decode_verify)
+            verify = _paged_decode_verify
         else:
             fns = [_prefill_chunk, _decode_step]
-            if self.spec_k > 0:
-                fns.append(_decode_verify)
+            verify = _decode_verify
+        fns.append(_sample_rows if self._device_draw else verify)
         sizes = cache_sizes(tuple(fns))
         if self._guard_baseline is None:
             # warmup isn't over until BOTH programs exist: a first step whose
@@ -1233,13 +1371,16 @@ class Engine:
             if not req.future.done():
                 req.future.set_exception(wrapped)
         self.slots.reset()
-        self._last_logits[:] = 0.0
+        # the prefill programs donate the rows too: after a step that died
+        # mid-call fresh ones are the only safe state
+        self._rows = self._fresh_rows()
+        self._host_rows[:] = 0.0
         # queued requests were never admitted: they survive the restart —
         # minus the ones whose TTL budget the crash already consumed
         self.scheduler.expire()
 
     def _warm_rebuild(self) -> None:
-        """Crash recovery, step 2: re-warm the two pinned programs from the
+        """Crash recovery, step 2: re-warm the pinned programs from the
         AOT artifact store (PR 9) so recovery costs cache-hit milliseconds,
         not a recompile. Best-effort — warmth is optional, serving is not."""
         if self._store is None:
@@ -1253,7 +1394,7 @@ class Engine:
             _obs_tracer.instant("engine_warm_rebuild_failed", error=repr(e))
 
     def warm_start(self, store=None, verbose: bool = True) -> List[dict]:
-        """AOT-compile the engine's two pinned programs from abstract inputs
+        """AOT-compile the engine's pinned programs from abstract inputs
         (galvatron_tpu/aot): with the persistent compile cache enabled, a
         server restart's first request pays a cache deserialize instead of
         two XLA compiles.  Call before serving traffic (the jit calls happen
@@ -1314,6 +1455,16 @@ def _serving_programs(ctx):
     num_slots = max(1, int(ctx.num_slots))
     chunk = min(max(1, int(ctx.prefill_chunk)), max_len)
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    # the rows tokens are drawn from, and the draw itself: the same program on
+    # both backends; the speculative engine draws on the host and has none
+    rows_abs = jax.ShapeDtypeStruct((num_slots, cfg.vocab_size), jnp.dtype(cfg.dtype))
+    draw = [] if spec_k > 0 else [ProgramSpec(
+        "serving_sample", _sample_rows,
+        (rows_abs, jax.ShapeDtypeStruct((2, num_slots), jnp.float32),
+         jax.ShapeDtypeStruct((6, num_slots), jnp.uint32)),
+        # (no weight enters it, but an int8 engine warms a set of its own)
+        meta={"num_slots": num_slots, **({"key_extra": key_extra} if key_extra else {})},
+    )]
     kv_num_blocks = int(getattr(ctx, "kv_num_blocks", 0) or 0)
     if kv_num_blocks:
         # paged backend: the pool/table shapes are fully determined by
@@ -1334,8 +1485,8 @@ def _serving_programs(ctx):
             ProgramSpec(
                 "serving_paged_prefill", _paged_prefill_chunk,
                 (params_abs, cfg, pool_abs, i32(1, chunk), i32(1, max_blocks),
-                 i32(1)),
-                meta={"donate": ("pool",), "num_slots": num_slots,
+                 i32(1), rows_abs, i32(), i32()),
+                meta={"donate": ("pool", "rows"), "num_slots": num_slots,
                       "prefill_chunk": chunk, **paged_meta},
             ),
             ProgramSpec(
@@ -1354,7 +1505,7 @@ def _serving_programs(ctx):
                 meta={"donate": ("pool",), "num_slots": num_slots,
                       "spec_decode_k": spec_k, **paged_meta},
             ))
-        return out
+        return out + draw
     cache_abs = jax.eval_shape(
         lambda: generation.init_kv_cache(cfg, num_slots, max_len)
     )
@@ -1362,8 +1513,8 @@ def _serving_programs(ctx):
     out = [
         ProgramSpec(
             "serving_prefill", _prefill_chunk,
-            (params_abs, cfg, cache_abs, i32(1, chunk), i32(), i32()),
-            meta={"donate": ("cache",), "num_slots": num_slots,
+            (params_abs, cfg, cache_abs, i32(1, chunk), i32(), i32(), rows_abs, i32()),
+            meta={"donate": ("cache", "rows"), "num_slots": num_slots,
                   "prefill_chunk": chunk, **slot_meta},
         ),
         ProgramSpec(
@@ -1382,7 +1533,7 @@ def _serving_programs(ctx):
             meta={"donate": ("cache",), "num_slots": num_slots,
                   "spec_decode_k": spec_k, **slot_meta},
         ))
-    return out
+    return out + draw
 
 
 def _register_aot_programs():
@@ -1391,7 +1542,7 @@ def _register_aot_programs():
     register_program(
         "serving", _serving_programs,
         programs=("serving_prefill", "serving_decode",
-                  "serving_decode_verify",
+                  "serving_decode_verify", "serving_sample",
                   "serving_paged_prefill", "serving_paged_decode",
                   "serving_paged_decode_verify"),
     )

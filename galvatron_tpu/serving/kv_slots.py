@@ -97,11 +97,15 @@ class SlotKVCache:
         """Release every slot and reallocate the device cache (engine
         failure recovery / drain). The engine's jitted steps DONATE the
         cache buffers — after a step that died mid-call the old arrays may
-        already be invalidated, so a fresh cache is the only safe state."""
+        already be invalidated, so a fresh cache is the only safe state.
+        The old cache is let go of BEFORE the new one is built: two do not fit
+        a chip beside the weights at a deployment's size (16 slots x 2048 of
+        opt-1.3b: 2 x 6 GiB), and a caller may have dropped it already."""
         with self._lock:
             self._active.clear()
             self.lengths[:] = 0
             self._free = list(range(self.num_slots - 1, -1, -1))
+            self.cache = None
             self.cache = generation.init_kv_cache(self.cfg, self.num_slots, self.max_seq_len)
 
     # -- views --------------------------------------------------------------

@@ -210,6 +210,7 @@ class PagedKVCache:
             self._registry.clear()
             self._block_hash.clear()
             self._lru.clear()
+            self.pool = None  # let go first: two pools need not fit the chip
             self.pool = generation.init_kv_cache(self.cfg, self.num_blocks, self.block_size)
 
     # -- views ---------------------------------------------------------------

@@ -30,7 +30,7 @@ same story. The terminal states are disjoint by *cause*:
 ``core/elastic.py``'s restart decision table: a decode/prefill-loop crash
 fails the in-flight requests fast (503 ``engine_restarted``), keeps queued
 requests that still have TTL budget, resets the KV cache, warm-rebuilds
-the two pinned programs from the PR 9 artifact store, and restarts the
+the pinned programs from the PR 9 artifact store, and restarts the
 loop under ``core/retry.py`` full-jitter backoff — bounded by
 ``max_restarts`` *consecutive no-progress* restarts (a completion between
 crashes resets the budget, exactly like elastic's committed-step rule).

@@ -216,7 +216,7 @@ def test_enumerate_include_filters_by_family_and_name():
     only = aot_registry.enumerate_programs(ctx, include=("serving_decode",))
     assert [s.name for s in only] == ["serving_decode"]
     fam = aot_registry.enumerate_programs(ctx, include=("serving",))
-    assert {s.name for s in fam} == {"serving_prefill", "serving_decode"}
+    assert {s.name for s in fam} == {"serving_prefill", "serving_decode", "serving_sample"}
 
 
 def test_non_causal_model_has_no_serving_or_generate_programs():

@@ -348,10 +348,11 @@ def test_aot_enumerates_verify_program_per_backend():
         include=("serving",))}
     assert paged == {"serving_paged_prefill", "serving_paged_decode",
                      "serving_paged_decode_verify"}
-    # spec off → the historical two-program set, unchanged
+    # spec off → the two forwards and the device's draw (a speculating engine
+    # draws on the host and declares no sampler)
     off = {s.name for s in aot_registry.enumerate_programs(
         aot_registry.ProgramContext(**base), include=("serving",))}
-    assert off == {"serving_prefill", "serving_decode"}
+    assert off == {"serving_prefill", "serving_decode", "serving_sample"}
     # the verify program's token aval carries k: (num_slots, 1+k)
     spec = next(s for s in aot_registry.enumerate_programs(
         aot_registry.ProgramContext(**base, spec_decode_k=3),

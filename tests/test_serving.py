@@ -276,7 +276,18 @@ def test_engine_programs_match_the_replaced_forwards_bitwise(params, program):
     if program == "prefill_chunk":
         toks = jnp.asarray(rng.randint(1, CFG.vocab_size, (1, 4)), jnp.int32)
         slot, offset = np.int32(2), np.int32(8)
-        logits, out = _prefill_chunk(params, CFG, fresh(), toks, slot, offset)
+        # the program keeps ONE row of the chunk's logits, row ``last``, in row
+        # ``slot`` of the engine's rows: ask for each in turn, on rows whose
+        # other slots must come back as they went in
+        before = np.asarray(rng.randn(4, CFG.vocab_size), np.float32)
+        got = []
+        for last in range(4):
+            rows, out = _prefill_chunk(params, CFG, fresh(), toks, slot, offset,
+                                       jnp.asarray(before), np.int32(last))
+            rows = np.asarray(rows)
+            np.testing.assert_array_equal(np.delete(rows, slot, 0), np.delete(before, slot, 0))
+            got.append(rows[slot])
+        logits = np.stack(got)
         # (compared under jit, as the engine runs: eager steps round otherwise)
         ref_logits, ref_out = jax.jit(
             lambda c, t, sl, o: ref.prefill_chunk(params, t, CFG, c, sl, o)

@@ -287,8 +287,9 @@ def test_prefill_fault_fails_one_request_not_engine(params):
 
 
 def test_crash_restart_hits_artifact_store(params, tmp_path):
-    """Recovery is warm: the supervisor re-warms the two pinned programs
-    from the AOT artifact store — the restart reports 2/2 cache hits (no
+    """Recovery is warm: the supervisor re-warms the three pinned programs
+    (prefill, decode, the draw) from the AOT artifact store — the restart
+    reports 3/3 cache hits (no
     clock is compared: a hit is the claim, and a busy host can make loading
     two tiny programs slower than compiling them)."""
     from galvatron_tpu.aot import warmup as aot_warmup
@@ -298,7 +299,7 @@ def test_crash_restart_hits_artifact_store(params, tmp_path):
     eng = Engine(params, CFG, num_slots=2, prefill_chunk=4,
                  restart_backoff_s=0.01)
     cold = aot_warmup.summarize(eng.warm_start(store, verbose=False))
-    assert cold["compiled"] == 2 and cold["misses"] == 2
+    assert cold["compiled"] == 3 and cold["misses"] == 3
     faults.configure(engine_crash_at_iter=eng.counters.get("steps") + 1)
     with pytest.raises(EngineRestarted):
         eng.submit(_prompts(1, seed=10)[0], 8).result(timeout=60)
@@ -307,7 +308,7 @@ def test_crash_restart_hits_artifact_store(params, tmp_path):
         time.sleep(0.02)
     warm = eng.last_restart_warm
     assert warm is not None, "restart did not re-warm from the store"
-    assert warm["hits"] == 2 and warm["misses"] == 0, warm
+    assert warm["hits"] == 3 and warm["misses"] == 0, warm
     # and the recovered engine serves
     assert eng.generate(_prompts(2, seed=11), max_new_tokens=3)
     eng.close()

@@ -724,7 +724,11 @@ def test_serving_programs_write_the_slot_cache_in_place(program, slots, spec_k, 
              - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
     assert total < HBM_V5E_GIB * 2**30, f"{total / 2**30:.2f} GiB"
     assert ma.temp_size_in_bytes < 0.5 * 2**30, f"{ma.temp_size_in_bytes / 2**30:.3f} GiB"
-    assert ma.alias_size_in_bytes == cache_bytes
+    # (the prefill program also takes and hands back the engine's logits rows,
+    # slots x vocabulary in bf16, its lanes padded to whole tiles of 128; a
+    # prompt's last row is set into them in place)
+    rows_bytes = slots * -(-cfg.vocab_size // 128) * 128 * 2 if program == "serving_prefill" else 0
+    assert ma.alias_size_in_bytes == cache_bytes + rows_bytes
     # results of a slab or more: the in-place updates of the stacked cache, and
     # the tied embedding table's conversion to bf16 (a weight, not the cache)
     moved = [(op, shape) for op, n, shape in _entry_results(compiled.as_text())
@@ -732,6 +736,28 @@ def test_serving_programs_write_the_slot_cache_in_place(program, slots, spec_k, 
              and op not in ("parameter", "get-tuple-element", "tuple", "bitcast",
                             "dynamic-update-slice")]
     assert not moved, moved[:4]
+
+
+def test_serving_sampler_compiles_for_the_chip_without_a_sort(one_chip, real_mosaic):
+    """The engine's draw at the cell's shapes (16 slots x 50,272 bf16 logits) for
+    one v5e chip: one program whose every operand is data, no sort over the
+    vocabulary (the cuts are bisected), temporaries of a few rows' size, and the
+    ``sample`` scope on its work."""
+    from galvatron_tpu.aot import registry
+    from galvatron_tpu.models.modeling import PRESETS
+    from galvatron_tpu.serving import engine  # noqa: F401  (registers the serving family)
+
+    cfg = PRESETS["opt-1.3b"]
+    ctx = registry.ProgramContext(cfg=cfg, num_slots=16, prefill_chunk=256, max_seq_len=2048)
+    spec, = [sp for sp in registry.enumerate_programs(ctx, include=("serving",))
+             if sp.name == "serving_sample"]
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip) for a in spec.args]
+    assert [a.shape for a in args] == [(16, 50272), (2, 16), (6, 16)]
+    compiled = spec.fn.lower(*args).compile()
+    text = compiled.as_text()
+    assert " sort(" not in text and "while(" in text
+    assert "jit(_sample_rows)/sample/" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
 
 
 def test_serving_decode_step_names_its_device_work(one_chip, real_mosaic):
