@@ -53,7 +53,7 @@ def plan_flags(cell, config, traffic, out_dir):
     return ["--galvatron_config_path", path]
 
 
-def lowered_step_text(cell, config, traffic, topo, out_dir) -> str:
+def lowered_step(cell, config, traffic, topo, out_dir):
     from galvatron_tpu.core.arguments import (
         adam_config_from_args,
         hybrid_config_from_args,
@@ -79,16 +79,19 @@ def lowered_step_text(cell, config, traffic, topo, out_dir) -> str:
     batch = jax.ShapeDtypeStruct(
         (ns.global_train_batch_size, batch_row_width(cfg, cfg.sample_len)), jnp.int32,
         sharding=rt.batch_sharding)
-    text = rt.train_step.lower(abstract_state_of(rt), batch).as_text()
-    if "tpu_custom_call" not in text:
+    lowered = rt.train_step.lower(abstract_state_of(rt), batch)
+    if "tpu_custom_call" not in lowered.as_text():
         raise SystemExit("the lowered step of %s holds no Mosaic kernel" % cell["name"])
-    return text
+    return lowered
 
 
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--cell", action="append", help="only this cell (may repeat)")
     ap.add_argument("--dump", help="directory to write the normalised texts to")
+    ap.add_argument("--compile", action="store_true",
+                    help="also compile the step for the described chip and print what the "
+                         "compiler plans of its memory (no chip: a plan, not a measurement)")
     args = ap.parse_args()
     from galvatron_tpu.aot.cache import persistent_cache_off
     from galvatron_tpu.ops import flash_attention, grouped_matmul
@@ -101,13 +104,24 @@ def main() -> None:
     with tempfile.TemporaryDirectory() as out_dir, persistent_cache_off():
         for name in args.cell or [w["name"] for w in harness.load_manifest(ROOT)["workloads"]]:
             cell, config, traffic = harness.load_cell(ROOT, name)
-            text = normalised(lowered_step_text(cell, config, traffic, topo, out_dir))
+            if traffic.get("kind", "train") != "train":
+                continue  # a serving cell has no train step
+            lowered = lowered_step(cell, config, traffic, topo, out_dir)
+            text = normalised(lowered.as_text())
             print(cell["name"], "sha256", hashlib.sha256(text.encode()).hexdigest(),
                   "bytes", len(text), flush=True)
             if args.dump:
                 os.makedirs(args.dump, exist_ok=True)
                 with open(os.path.join(args.dump, cell["name"] + ".txt"), "w") as f:
                     f.write(text)
+            if args.compile:
+                mem = lowered.compile().memory_analysis()
+                gib = {k: round(getattr(mem, k) / 2**30, 3) for k in (
+                    "argument_size_in_bytes", "output_size_in_bytes", "alias_size_in_bytes",
+                    "temp_size_in_bytes")}
+                print(cell["name"], "compiler's plan, GiB:", gib, "arguments + temporaries",
+                      round((mem.argument_size_in_bytes + mem.temp_size_in_bytes) / 2**30, 3),
+                      flush=True)
 
 
 if __name__ == "__main__":

@@ -788,44 +788,53 @@ def _check_model(hp: HybridParallelConfig, cfg, source) -> List[Diagnostic]:
                 )
             )
     kinds = getattr(cfg, "kinds", ())
-    if "ssm" in kinds:
-        # a hybrid stack (build_runtime refuses the same, by the same names)
-        for i, (kind, s) in enumerate(zip(kinds, hp.layer_strategies[enc:])):
-            if kind != "ssm":
-                continue
-            if s.tp > 1:
+    if getattr(cfg, "moe_dropless", False) and cfg.moe_holds_share:
+        for i, s in enumerate(hp.layer_strategies):
+            if s.ep > 1:
                 out.append(
                     Diagnostic(
-                        "GTA019",
-                        f"layer {enc + i}: tp={s.tp} on a state-space layer — tensor "
-                        "parallelism is not implemented for the Mamba-2 mixer",
-                        hint=f"set tp_sizes_enc[{enc + i}] to 1",
-                        field=f"tp_sizes_enc[{enc + i}]",
+                        "GTA014",
+                        f"layer {i}: ep={s.ep} on a held share of the experts (this copy "
+                        f"holds {cfg.moe_held} of {cfg.moe_experts}) — the share is one rank "
+                        "of an expert-parallel deployment already",
+                        hint=f"set ep_sizes_enc[{i}] to 1",
+                        field=f"ep_sizes_enc[{i}]",
                         source=source,
                     )
                 )
-            if s.cp > 1:
+    # a hybrid stack's recurrent layers (build_runtime refuses the same, by the same names)
+    recurrent = {
+        "ssm": ("a state-space layer", "the Mamba-2 mixer", "the scan's state"),
+        "gdn": ("a Gated DeltaNet layer", "the Gated DeltaNet mixer", "the delta rule's state"),
+    }
+    for i, (kind, s) in enumerate(zip(kinds, hp.layer_strategies[enc:])):
+        if kind not in recurrent:
+            continue
+        a_layer, mixer, state = recurrent[kind]
+        for deg, name, why in (
+                (s.tp, "tp", f"tensor parallelism is not implemented for {mixer}"),
+                (s.cp, "cp", f"{state} is not passed between sequence shards")):
+            if deg > 1:
                 out.append(
                     Diagnostic(
                         "GTA019",
-                        f"layer {enc + i}: cp={s.cp} on a state-space layer — the scan's "
-                        "state is not passed between sequence shards",
-                        hint=f"set cp_sizes_enc[{enc + i}] to 1",
-                        field=f"cp_sizes_enc[{enc + i}]",
+                        f"layer {enc + i}: {name}={deg} on {a_layer} — {why}",
+                        hint=f"set {name}_sizes_enc[{enc + i}] to 1",
+                        field=f"{name}_sizes_enc[{enc + i}]",
                         source=source,
                     )
                 )
-        if hp.pp > 1 and len(set(kinds)) > 1:
-            out.append(
-                Diagnostic(
-                    "GTA020",
-                    f"pp={hp.pp} over interleaved layer kinds — the pipeline engines "
-                    "stack one kind of layer a stage position",
-                    hint="use pp_deg 1 for a hybrid stack",
-                    field="pp_deg",
-                    source=source,
-                )
+    if hp.pp > 1 and len(set(kinds)) > 1:
+        out.append(
+            Diagnostic(
+                "GTA020",
+                f"pp={hp.pp} over interleaved layer kinds — the pipeline engines "
+                "stack one kind of layer a stage position",
+                hint="use pp_deg 1 for a hybrid stack",
+                field="pp_deg",
+                source=source,
             )
+        )
     if hp.vocab_tp > 1 and cfg.vocab_size % hp.vocab_tp:
         out.append(
             Diagnostic(

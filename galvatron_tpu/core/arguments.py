@@ -44,6 +44,10 @@ def _add_model_args(p: argparse.ArgumentParser):
     g.add_argument("--moe_experts", type=int, default=None,
                    help="switch-MoE expert count (0/None = dense MLP)")
     g.add_argument("--moe_capacity_factor", type=float, default=None)
+    g.add_argument("--moe_share", type=str, default=None, metavar="R/N",
+                   help="dropless top-k MoE: this copy holds rank R of N contiguous shares "
+                        "of the experts (one rank of an N-way expert-parallel deployment); "
+                        "the router still scores all of them")
 
 
 def _add_step_program_args(p: argparse.ArgumentParser):
@@ -768,6 +772,9 @@ def model_config_from_args(ns: argparse.Namespace, base=None):
         v = getattr(ns, attr, None)
         if v is not None:
             overrides[field] = v
+    if getattr(ns, "moe_share", None):
+        rank, _, of = str(ns.moe_share).partition("/")
+        overrides["moe_share"] = (int(rank), int(of))
     if getattr(ns, "swin_depths", None):
         overrides["swin_depths"] = tuple(
             int(d) for d in str(ns.swin_depths).split(",") if d

@@ -248,8 +248,15 @@ def _train_impl(ns: argparse.Namespace, verbose: bool, tracer,
             from galvatron_tpu.ops.ssd import conv_path_counts, scan_path_counts
 
             ssm_scan_path, ssm_conv_path = scan_path_counts(rt.cfg), conv_path_counts(rt.cfg)
+        # the same two for its Gated DeltaNet layers (models/gdn.py)
+        gdn_scan_path, gdn_conv_path = {"fused": 0, "plain": 0}, {"fused": 0, "plain": 0}
+        if layer_kinds.get("gdn"):
+            from galvatron_tpu.models import gdn
+
+            gdn_scan_path, gdn_conv_path = gdn.scan_path_counts(rt.cfg), gdn.conv_path_counts(rt.cfg)
         build_span.set(tp_overlap_seams=rt.tp_overlap_seams, layer_kinds=layer_kinds,
-                       ssm_scan_path=ssm_scan_path, ssm_conv_path=ssm_conv_path)
+                       ssm_scan_path=ssm_scan_path, ssm_conv_path=ssm_conv_path,
+                       gdn_scan_path=gdn_scan_path, gdn_conv_path=gdn_conv_path)
 
     from galvatron_tpu.obs import tracing as obs_tracing
     from galvatron_tpu.utils.metrics import SCHEMA_VERSION, MetricsLogger
@@ -290,6 +297,8 @@ def _train_impl(ns: argparse.Namespace, verbose: bool, tracer,
         "layer_kinds": layer_kinds,
         "ssm_scan_path": ssm_scan_path,
         "ssm_conv_path": ssm_conv_path,
+        "gdn_scan_path": gdn_scan_path,
+        "gdn_conv_path": gdn_conv_path,
     }
     # JAX's persistent compile cache is always on, at the one place
     # resolve_compile_cache_dir names (JAX_COMPILATION_CACHE_DIR, else an

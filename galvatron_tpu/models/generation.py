@@ -39,6 +39,10 @@ def init_kv_cache(cfg: ModelConfig, batch_size: int, max_len: int) -> KVCache:
         raise ValueError(
             "generation is not implemented for a stack with state-space layers: a "
             "key/value cache holds no recurrent (conv + scan) state; train-only")
+    if "gdn" in cfg.kinds:
+        raise ValueError(
+            "generation is not implemented for a stack with Gated DeltaNet layers: a "
+            "key/value cache holds no recurrent (conv + delta rule) state; train-only")
     shape = (cfg.num_layers, batch_size, max_len, cfg.kv_heads, cfg.head_dim)
     return KVCache(jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))
 

@@ -38,6 +38,16 @@ from galvatron_tpu.ops.quant import QuantTensor, qeinsum, qmatmul
 
 Params = Dict[str, Any]
 
+#: layer kinds whose mixer carries a state along the sequence: no tp, no cp, no
+#: packing, no key/value-cache generation (build_runtime, plan_check, the search)
+RECURRENT_KINDS = ("ssm", "gdn")
+
+
+def has_recurrent_layers(cfg) -> bool:
+    """The stack has a layer of a recurrent kind (a hybrid stack: each kind is
+    priced by itself, the in-process profiler measures one kind only)."""
+    return any(kind in RECURRENT_KINDS for kind in cfg.kinds)
+
 
 @dataclass(frozen=True)
 class ModelConfig:
@@ -98,16 +108,42 @@ class ModelConfig:
     moe_router: str = "switch"
     moe_top_k: int = 1
     moe_aux_coef: float = 0.0
+    # The dropless path's further settings (Qwen3-Next-class layers; the
+    # defaults are OLMoE's layer): the width of ONE routed expert where it is
+    # not ``ffn`` (None: ``ffn``); the top-k weights renormalised to sum 1; a
+    # shared expert of this width that every token runs, under a sigmoid gate
+    # of its own (0: none); and the HELD SHARE ``(r, R)``: this copy holds the
+    # contiguous experts ``[r E / R, (r + 1) E / R)`` of the ``moe_experts`` the
+    # router scores (one rank of an R-way expert-parallel deployment). Pairs on
+    # experts it does not hold are left out of the sum; no code stands in for
+    # the other ranks (moe._topk_local). ``ep`` > 1 on a held share is refused.
+    moe_ffn_dim: Optional[int] = None
+    moe_norm_topk: bool = False
+    moe_shared_ffn_dim: int = 0
+    moe_share: Tuple[int, int] = (0, 1)
     # RMSNorm with a learned scale on the q and k projections, each over the
     # WHOLE projection width (all heads together) before the split into heads
     # and before rope (OLMoE; HF modeling_olmoe.py q_norm / k_norm).
     qk_norm: bool = False
-    # Hybrid stacks (granitemoehybrid-class): the kind of every layer,
-    # "attention" | "ssm" (a Mamba-2 mixer, models/ssm.py, in place of
-    # attention; the MLP is the same), as published for the WHOLE model; a
-    # model cut in depth keeps the first ``num_layers`` entries (``kinds``).
-    # Empty: every layer is attention. tp>1 / cp>1 on an ssm layer and pp>1
-    # over mixed kinds are refused by build_runtime and left out by the search.
+    # Gated attention (Qwen3-Next's full-attention layers): a second query-wide
+    # projection ``wgate`` whose sigmoid multiplies the attention output per
+    # head and channel before ``wo``; q and k each through a zero-centred RMSNorm
+    # over the head size of EACH head (one weight vector of ``head_dim`` each);
+    # rotary on the first ``rotary_fraction`` of each head (``attn_block``).
+    attn_gate: bool = False
+    rotary_fraction: float = 1.0
+    # Head size where it is not ``hidden_size / num_heads`` (None: that).
+    attn_head_dim: Optional[int] = None
+    # Every RMSNorm over the hidden width is ``x * rsqrt(mean(x^2) + eps) * (1 + w)``
+    # with ``w`` initialised 0 (Qwen3-Next) in place of ``* w`` from 1.
+    norm_zero_centered: bool = False
+    # Hybrid stacks (granitemoehybrid- and qwen3_next-class): the kind of every
+    # layer, "attention" | "ssm" (a Mamba-2 mixer, models/ssm.py) | "gdn" (a
+    # Gated DeltaNet mixer, models/gdn.py) in place of attention; the MLP is the
+    # same), as published for the WHOLE model; a model cut in depth keeps the
+    # first ``num_layers`` entries (``kinds``). Empty: every layer is attention.
+    # tp>1 / cp>1 on a recurrent layer ("ssm", "gdn": ``RECURRENT_KINDS``) and
+    # pp>1 over mixed kinds are refused by build_runtime and left out by the search.
     layer_kinds: Tuple[str, ...] = ()
     ssm_heads: int = 0
     ssm_head_dim: int = 64
@@ -115,6 +151,14 @@ class ModelConfig:
     ssm_groups: int = 1
     ssm_conv: int = 4
     ssm_chunk: int = 256
+    # the Gated DeltaNet mixer: key heads (each serves value_heads / key_heads
+    # value heads), value heads, their sizes, conv taps, the delta rule's chunk
+    gdn_key_heads: int = 0
+    gdn_value_heads: int = 0
+    gdn_key_dim: int = 128
+    gdn_value_dim: int = 128
+    gdn_conv: int = 4
+    gdn_chunk: int = 64
     # Granite's scalar multipliers (HF config keys of the same names): the
     # softmax scale in place of 1/sqrt(head_dim) (None: that default), the
     # embedding's factor, the factor on every residual branch, and the divisor
@@ -219,7 +263,31 @@ class ModelConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.hidden_size // self.num_heads
+        return self.attn_head_dim or self.hidden_size // self.num_heads
+
+    @property
+    def rotary_dim(self) -> int:
+        """Leading dims of a head that rotate (the rest pass)."""
+        return int(self.head_dim * self.rotary_fraction)
+
+    @property
+    def expert_ffn(self) -> int:
+        """Width of one routed expert of the dropless path."""
+        return self.moe_ffn_dim or self.ffn
+
+    @property
+    def moe_held(self) -> int:
+        """Experts this copy holds (all of them unless ``moe_share`` says less)."""
+        return self.moe_experts // self.moe_share[1]
+
+    @property
+    def moe_first_held(self) -> int:
+        return self.moe_share[0] * self.moe_held
+
+    @property
+    def moe_holds_share(self) -> bool:
+        """This copy holds fewer experts than its router scores."""
+        return self.moe_share[1] > 1
 
     @property
     def qkv_blocked(self) -> bool:
@@ -344,17 +412,32 @@ def split_qkv(qkv, cfg: ModelConfig):
     return q, r[..., npg, :], r[..., npg + 1, :]
 
 
+def _norm_scale_init(cfg: ModelConfig, n: int):
+    """A norm's learned scale at its start: 1, or 0 where the norm is ``(1 + w)``."""
+    return (jnp.zeros if cfg.norm_zero_centered else jnp.ones)((n,), cfg.param_dtype)
+
+
+def _mixer_module(kind: str):
+    """The module of a recurrent layer kind's mixer (imported only where a
+    configuration has such layers)."""
+    if kind == "ssm":
+        from galvatron_tpu.models import ssm
+
+        return ssm
+    from galvatron_tpu.models import gdn
+
+    return gdn
+
+
 def init_layer_params(key, cfg: ModelConfig, cross: bool = False,
                       kind: str = "attention") -> Params:
     h, hd = cfg.hidden_size, cfg.head_dim
-    if kind == "ssm":
+    if kind in RECURRENT_KINDS:
         # the mixer in place of attention; norms and MLP are an attention layer's
-        from galvatron_tpu.models import ssm
-
         k_mix, k_rest = jax.random.split(key)
         p = init_layer_params(k_rest, cfg, cross=cross)
         del p["attn"]
-        p["ssm"] = ssm.init_ssm_params(k_mix, cfg)
+        p[kind] = _mixer_module(kind).init_params(k_mix, cfg)
         return p
     q_out = cfg.num_heads * hd
     kv_out = cfg.kv_heads * hd
@@ -364,13 +447,18 @@ def init_layer_params(key, cfg: ModelConfig, cross: bool = False,
     if cfg.qkv_blocked:
         wqkv = wqkv.reshape(h, 3, q_out)
     p: Params = {
-        "attn_norm": {"scale": jnp.ones((h,), cfg.param_dtype)},
+        "attn_norm": {"scale": _norm_scale_init(cfg, h)},
         "attn": {
             "wqkv": wqkv,
             "wo": _dense_init(ks[3], q_out, h, cfg.param_dtype),
         },
-        "mlp_norm": {"scale": jnp.ones((h,), cfg.param_dtype)},
+        "mlp_norm": {"scale": _norm_scale_init(cfg, h)},
     }
+    if cfg.attn_gate:
+        # the output gate's projection and the per-head norms' (1 + w) weights
+        p["attn"]["wgate"] = _dense_init(ks[1], h, q_out, cfg.param_dtype)
+        p["attn"]["q_norm"] = jnp.zeros((hd,), cfg.param_dtype)
+        p["attn"]["k_norm"] = jnp.zeros((hd,), cfg.param_dtype)
     if cfg.qk_norm:
         p["attn"]["q_norm"] = jnp.ones((q_out,), cfg.param_dtype)
         p["attn"]["k_norm"] = jnp.ones((kv_out,), cfg.param_dtype)
@@ -423,12 +511,10 @@ def layer_annotations(cfg: ModelConfig, cross: bool = False,
     """Logical axes per layer param: 'tp' = Megatron-sharded dim (column-out /
     row-in), 'fsdp' = the dim ZeRO shards (reference: FSDP flat-param sharding,
     galvatron/core/parallel.py:174-207)."""
-    if kind == "ssm":
-        from galvatron_tpu.models import ssm
-
+    if kind in RECURRENT_KINDS:
         a = layer_annotations(cfg, cross=cross)
         del a["attn"]
-        a["ssm"] = ssm.ssm_annotations(cfg)
+        a[kind] = _mixer_module(kind).annotations(cfg)
         return a
     a: Params = {
         "attn_norm": {"scale": ("fsdp",)},
@@ -439,6 +525,10 @@ def layer_annotations(cfg: ModelConfig, cross: bool = False,
         },
         "mlp_norm": {"scale": ("fsdp",)},
     }
+    if cfg.attn_gate:
+        a["attn"]["wgate"] = ("fsdp", "tp")
+        a["attn"]["q_norm"] = (None,)
+        a["attn"]["k_norm"] = (None,)
     if cfg.qk_norm:
         # scales of the projection's output width: sharded with the heads
         a["attn"]["q_norm"] = ("tp",)
@@ -616,7 +706,7 @@ def init_model_params(key, cfg: ModelConfig) -> Params:
             init_layer_params(ks[cfg.enc_layers + i + 1], cfg, cross=cross, kind=kind)
             for i, kind in enumerate(cfg.kinds)
         ],
-        "final_norm": {"scale": jnp.ones((cfg.hidden_size,), cfg.param_dtype)},
+        "final_norm": {"scale": _norm_scale_init(cfg, cfg.hidden_size)},
     }
     if cross:
         params["enc_layers"] = [
@@ -675,7 +765,8 @@ def _norm_impl(x, p, cfg: ModelConfig):
     x32 = x.astype(jnp.float32)
     if cfg.norm_type == "rms":
         x32 = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + cfg.norm_eps)
-        out = x32 * p["scale"].astype(jnp.float32)
+        scale = p["scale"].astype(jnp.float32)
+        out = x32 * (1.0 + scale if cfg.norm_zero_centered else scale)
     else:
         mu = jnp.mean(x32, axis=-1, keepdims=True)
         var = jnp.mean(jnp.square(x32 - mu), axis=-1, keepdims=True)
@@ -707,8 +798,9 @@ def norm(x, p, cfg: ModelConfig):
 
 def rope_tables(cfg: ModelConfig, seq_len: int, offset: int = 0):
     pos = np.arange(offset, offset + seq_len)
-    inv = 1.0 / (cfg.rope_theta ** (np.arange(0, cfg.head_dim, 2) / cfg.head_dim))
-    freqs = np.outer(pos, inv)  # (S, hd/2)
+    rot = cfg.rotary_dim  # the whole head unless rotary_fraction says less
+    inv = 1.0 / (cfg.rope_theta ** (np.arange(0, rot, 2) / rot))
+    freqs = np.outer(pos, inv)  # (S, rot/2)
     return jnp.asarray(np.cos(freqs), jnp.float32), jnp.asarray(np.sin(freqs), jnp.float32)
 
 
@@ -900,6 +992,7 @@ def projection_seams(cfg: ModelConfig, seq_len: int) -> Tuple[Tuple[str, str, in
         cfg.attn_impl == "flash" and cfg.pos_embed != "alibi" and cfg.flash_headmajor
         and not cfg.pack_sequences and not cfg.image_size and flash_tileable(seq_len)
         and (cfg.qkv_blocked or not (cfg.use_bias or cfg.qk_norm))
+        and not cfg.attn_gate  # the gated block keeps plain einsums
     )
     if headmajor:
         if cfg.qkv_blocked:
@@ -1025,6 +1118,74 @@ def _attn_block_headmajor(x, p, cfg: ModelConfig, rope, remat_attn: bool, place:
     return out_proj(o)
 
 
+def head_norm(t, w, cfg: ModelConfig):
+    """Zero-centred RMSNorm over the last axis of ``t`` (..., head_dim), weight
+    ``w`` (head_dim,) shared by the heads: Qwen3-Next's q_norm / k_norm. fp32
+    statistics, rematerialized under the 'policy' recompute like ``qk_norm``."""
+
+    def impl(t_, w_):
+        t32 = t_.astype(jnp.float32)
+        t32 = t32 * jax.lax.rsqrt(jnp.mean(t32 * t32, axis=-1, keepdims=True) + cfg.norm_eps)
+        return (t32 * (1.0 + w_.astype(jnp.float32))).astype(t_.dtype)
+
+    if cfg.mlp_recompute == "policy":
+        impl = jax.checkpoint(impl)
+    return impl(t, w)
+
+
+def _rope_leading_hm(x, cos, sin):
+    """Rotate-half rotary on the leading ``2 * cos.shape[-1]`` dims of a
+    head-major (b, n, s, d) tensor; the other dims pass."""
+    rot = 2 * cos.shape[-1]
+    turned = apply_rope(jnp.swapaxes(x[..., :rot], 1, 2), cos, sin)
+    return jnp.concatenate([jnp.swapaxes(turned, 1, 2), x[..., rot:]], axis=-1)
+
+
+def _attn_block_gated(x, p, cfg: ModelConfig, cos_sin, remat_attn: bool, seg_ids,
+                      place: Placement):
+    """Gated attention (``cfg.attn_gate``), head-major end to end: q, k, v from
+    the GQA-interleaved fused projection, the gate from ``wgate``; per-head
+    zero-centred norms on q and k; rotary on the leading ``rotary_dim`` of each
+    head, applied here (the kernels then run their no-RoPE GQA instance: a head
+    of 256 at s 4096 lies on the blocked envelope's edge); the attention core;
+    ``sigmoid(gate)`` on its output under scope ``gate``; the output projection."""
+    from galvatron_tpu.ops.flash_attention import flash_attention_hm, flash_tileable
+
+    b, s, h = x.shape
+    n, kv, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
+    npg = n // kv
+    with jax.named_scope("qkv_proj"):
+        r = jnp.einsum("bsh,hknd->bknsd", x, p["wqkv"].astype(x.dtype).reshape(h, kv, npg + 2, hd))
+        gate = jnp.einsum("bsh,hnd->bnsd", x, p["wgate"].astype(x.dtype).reshape(h, n, hd))
+    q, k, v = r[:, :, :npg].reshape(b, n, s, hd), r[:, :, npg], r[:, :, npg + 1]
+    with jax.named_scope("qk_norm"):
+        q, k = head_norm(q, p["q_norm"], cfg), head_norm(k, p["k_norm"], cfg)
+    if cfg.pos_embed == "rope":
+        with jax.named_scope("rope"):
+            q, k = _rope_leading_hm(q, *cos_sin), _rope_leading_hm(k, *cos_sin)
+    if cfg.attn_impl == "flash" and seg_ids is None and flash_tileable(s):
+        if place.kernel_tp > 1 and kv % place.kernel_tp:
+            k, v = _repeat_kv_hm(k, npg), _repeat_kv_hm(v, npg)
+        core = place.shard_kernel(
+            lambda q_, k_, v_: flash_attention_hm(
+                q_, k_, v_, causal=cfg.causal, sm_scale=cfg.attention_multiplier),
+            [(0, 1)] * 3, (0, 1))
+    else:
+        def core(q_, k_, v_):
+            o_ = attention_xla(*(jnp.swapaxes(t, 1, 2) for t in (q_, k_, v_)), cfg,
+                               seg_ids=seg_ids)
+            return jnp.swapaxes(o_, 1, 2)
+
+    if remat_attn:
+        core = jax.checkpoint(core)
+    with jax.named_scope("attn_core"):
+        o = place.constrain_attn_out(core(q, k, v))
+    with jax.named_scope("gate"):
+        o = (o.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(x.dtype)
+    with jax.named_scope("out_proj"):
+        return jnp.einsum("bnsd,nde->bse", o, p["wo"].astype(x.dtype).reshape(n, hd, h))
+
+
 @jax.named_scope("attn")
 def attn_block(x, p, cfg: ModelConfig, cos_sin=None, alibi=None, remat_attn: bool = False,
                seg_ids=None, place: Placement = LOCAL):
@@ -1037,6 +1198,8 @@ def attn_block(x, p, cfg: ModelConfig, cos_sin=None, alibi=None, remat_attn: boo
     kernels carry no segment mask)."""
     b, s, h = x.shape
     hd = cfg.head_dim
+    if cfg.attn_gate:
+        return _attn_block_gated(x, p, cfg, cos_sin, remat_attn, seg_ids, place)
     if (
         cfg.attn_impl == "flash" and cfg.pos_embed != "alibi"
         and cfg.flash_headmajor and seg_ids is None
@@ -1240,14 +1403,13 @@ def decoder_layer(
     collective-matmul seams; callers without a mesh (serving, the float32
     references, the profiler) pass nothing.
 
-    A layer whose parameters hold ``ssm`` in place of ``attn`` (kind "ssm" of
-    a hybrid stack, ``cfg.kinds``) runs the Mamba-2 mixer there."""
-    if "ssm" in p:
-        from galvatron_tpu.models import ssm
-
-        x = residual_add(x, ssm.ssm_block(
-            norm(x, p["attn_norm"], cfg), p["ssm"], cfg, place=place), cfg)
-        return mlp_residual(x, p, cfg, place=place)
+    A layer whose parameters hold ``ssm`` or ``gdn`` in place of ``attn`` (a
+    recurrent kind of a hybrid stack, ``cfg.kinds``) runs that mixer there."""
+    for kind in RECURRENT_KINDS:
+        if kind in p:
+            x = residual_add(x, _mixer_module(kind).block(
+                norm(x, p["attn_norm"], cfg), p[kind], cfg, place=place), cfg)
+            return mlp_residual(x, p, cfg, place=place)
     x = residual_add(x, attn_block(
         norm(x, p["attn_norm"], cfg), p["attn"], cfg, cos_sin, alibi,
         remat_attn=remat_attn, seg_ids=seg_ids, place=place,
@@ -1689,7 +1851,8 @@ def moe_loss_sum(params, batch, cfg: ModelConfig, layer_hook=None):
     """lm_loss_sum of a dropless top-k MoE model with what its training
     objective adds: (nll_sum, token_count, aux), aux = {"moe_aux_loss": the
     load-balancing loss L_aux of this batch, "moe_load_max_over_mean": the
-    fullest expert's pairs over the even share}. The objective that is
+    fullest expert's pairs over the even share; of a held share also
+    "moe_held_pairs_per_token"}. The objective that is
     differentiated is nll_sum / count + cfg.moe_aux_coef * L_aux; the loss
     that is logged and evaluated stays the cross entropy."""
     from galvatron_tpu.models import moe
@@ -1698,11 +1861,16 @@ def moe_loss_sum(params, batch, cfg: ModelConfig, layer_hook=None):
     logits, stats = forward_with_stats(params, tokens, cfg, layer_hook=layer_hook)
     s, n = cross_entropy_sum(logits, labels, remat=ce_remat(cfg))
     with jax.named_scope("loss"):
+        # a held share: the loss over all the experts the router scores, the
+        # load and the pairs a token brings over the experts held here
+        held = (cfg.moe_first_held, cfg.moe_held) if cfg.moe_holds_share else None
         aux = {
             "moe_aux_loss": moe.load_balancing_loss(stats, cfg.moe_experts),
             "moe_load_max_over_mean": moe.load_max_over_mean(
-                stats, cfg.moe_experts, cfg.moe_top_k),
+                stats, cfg.moe_experts, cfg.moe_top_k, held),
         }
+        if held:
+            aux["moe_held_pairs_per_token"] = moe.held_pairs_per_token(stats, held)
     return s, n, aux
 
 
@@ -1874,6 +2042,22 @@ PRESETS: Dict[str, ModelConfig] = {
         ssm_heads=64, ssm_head_dim=64, ssm_state=128, ssm_groups=1, ssm_conv=4,
         ssm_chunk=256, attention_multiplier=0.015625, embedding_multiplier=12.0,
         residual_multiplier=0.22, logits_scaling=8.0,
+    ),
+    # Qwen/Qwen3-Next-80B-A3B-Instruct (model_type qwen3_next): 48 layers, gated
+    # attention at (l + 1) % 4 == 0 and Gated DeltaNet mixers elsewhere; GQA 16 /
+    # 2 heads of 256 (not hidden / heads), per-head zero-centred qk norms, rotary
+    # on the first quarter of a head, theta 1e7; every norm (1 + w); every MLP 512
+    # experts of width 512 (ffn_dim 5120 is the published intermediate_size, used
+    # by no layer), top-10 renormalised, a gated shared expert of 512; aux
+    # coefficient 0.001 (assumed); untied head
+    "qwen3-next-80b-a3b": ModelConfig(
+        vocab_size=151936, hidden_size=2048, num_layers=48, num_heads=16, num_kv_heads=2,
+        attn_head_dim=256, ffn_dim=5120, max_seq_len=262144, rope_theta=1e7, norm_eps=1e-6,
+        rotary_fraction=0.25, attn_gate=True, norm_zero_centered=True,
+        layer_kinds=tuple("attention" if (i + 1) % 4 == 0 else "gdn" for i in range(48)),
+        gdn_key_heads=16, gdn_value_heads=32, gdn_key_dim=128, gdn_value_dim=128, gdn_conv=4,
+        gdn_chunk=64, moe_experts=512, moe_router="softmax_topk", moe_top_k=10,
+        moe_aux_coef=0.001, moe_ffn_dim=512, moe_norm_topk=True, moe_shared_ffn_dim=512,
     ),
     "baichuan-13b": ModelConfig(
         vocab_size=64000, hidden_size=5120, num_layers=40, num_heads=40,

@@ -104,18 +104,34 @@ def route_top1(logits: jax.Array, capacity: int, *, sinkhorn_iters: int = 8,
 
 
 def init_moe_params(key, cfg) -> Params:
-    """Router + stacked expert FFN weights (E leading dim)."""
-    h, f, e = cfg.hidden_size, cfg.ffn, cfg.moe_experts
+    """Router over all ``moe_experts`` + stacked expert FFN weights (leading
+    dim: the experts this copy holds, ``cfg.moe_held``; all of them unless
+    ``cfg.moe_share`` says less) + the gated shared expert where there is one."""
+    h, f, e = cfg.hidden_size, cfg.expert_ffn, cfg.moe_held
+    rank, of = cfg.moe_share
+    if not 0 <= rank < of or cfg.moe_experts % of:
+        raise ValueError(f"moe_share {cfg.moe_share}: rank r of R needs 0 <= r < R and R "
+                         f"dividing moe_experts ({cfg.moe_experts})")
     ks = jax.random.split(key, 4)
     scale_in = 1.0 / np.sqrt(h)
     scale_out = 1.0 / np.sqrt(f)
     p: Params = {
-        "router": {"w": jax.random.normal(ks[0], (h, e), cfg.param_dtype) * 0.02},
+        "router": {"w": jax.random.normal(ks[0], (h, cfg.moe_experts), cfg.param_dtype) * 0.02},
         "w1": jax.random.uniform(ks[1], (e, h, f), cfg.param_dtype, -scale_in, scale_in),
         "w2": jax.random.uniform(ks[2], (e, f, h), cfg.param_dtype, -scale_out, scale_out),
     }
     if cfg.act_fn == "swiglu":
         p["w3"] = jax.random.uniform(ks[3], (e, h, f), cfg.param_dtype, -scale_in, scale_in)
+    if cfg.moe_shared_ffn_dim:
+        from galvatron_tpu.models.modeling import _dense_init
+
+        fs = cfg.moe_shared_ffn_dim
+        sk = jax.random.split(jax.random.fold_in(key, 1), 3)
+        p["shared"] = {  # [gate | up] fused, down, and the gate on the whole expert
+            "w13": _dense_init(sk[0], h, 2 * fs, cfg.param_dtype),
+            "w2": _dense_init(sk[1], fs, h, cfg.param_dtype),
+            "gate": _dense_init(sk[2], h, 1, cfg.param_dtype),
+        }
     return p
 
 
@@ -136,6 +152,9 @@ def moe_annotations(cfg) -> Params:
     }
     if cfg.act_fn == "swiglu":
         a["w3"] = ("ep", "fsdp", "tp")
+    if cfg.moe_shared_ffn_dim:
+        # whole on every device like the dropless experts (tp divides nothing there)
+        a["shared"] = {"w13": ("fsdp", None), "w2": (None, "fsdp"), "gate": (None, None)}
     return a
 
 
@@ -229,6 +248,25 @@ def sorted_layout(expert_idx: jax.Array, num_experts: int, tile: int) -> SortedL
                         sizes)
 
 
+def held_layout(expert_idx, held: int, tile: int, first_held: int) -> SortedLayout:
+    """`sorted_layout` of a held share: the ``held`` experts from ``first_held``
+    on, of those ``expert_idx`` names. The dropped pairs sort behind the held
+    groups as one group more, whose tiles lie past ``num_tiles``: their rows
+    are not valid (the dispatch writes zeros), the grouped GEMMs skip them and
+    write zeros, so a dropped pair adds nothing forward and takes nothing
+    backward. The buffer keeps its worst-case size (every pair held): shapes
+    are static, and no pair that is held is ever dropped."""
+    local = expert_idx.astype(jnp.int32) - first_held
+    dropped = (local < 0) | (local >= held)
+    full = sorted_layout(jnp.where(dropped, held, local), held + 1, tile)
+    held_tiles = jnp.sum(jnp.maximum(-(-full.sizes[:held] // tile), 1)).astype(jnp.int32)
+    rows = full.row_valid.shape[0]
+    in_held = jnp.arange(rows, dtype=jnp.int32) < held_tiles * tile
+    return SortedLayout(
+        full.pair_row, full.row_pair, full.row_valid & in_held,
+        jnp.minimum(full.tile_group, held - 1), held_tiles[None], full.sizes[:held])
+
+
 @jax.custom_vjp
 def _dispatch(x, row_token, row_valid, pair_row):
     """x (T, h) -> the row buffer (M, h): a pair's row is its token's activation,
@@ -305,10 +343,21 @@ def load_balancing_loss(stats, num_experts: int) -> jax.Array:
     return num_experts * jnp.sum(f * p)
 
 
-def load_max_over_mean(stats, num_experts: int, top_k: int) -> jax.Array:
-    """Largest expert's pairs over the even share T*k/E, the fullest layer's."""
+def load_max_over_mean(stats, num_experts: int, top_k: int,
+                       held: Optional[Tuple[int, int]] = None) -> jax.Array:
+    """Largest expert's pairs over the even share T*k/E, the fullest layer's;
+    ``held`` = (first, count): over the held experts alone."""
     f = jnp.stack([s[0] for s in stats])
+    if held is not None:
+        f = f[:, held[0]:held[0] + held[1]]
     return jnp.max(f) * num_experts / top_k
+
+
+def held_pairs_per_token(stats, held: Tuple[int, int]) -> jax.Array:
+    """(token, expert) pairs a token puts on the held experts, mean over the
+    layers: ``k * held / E`` when the load is even."""
+    f = jnp.stack([s[0] for s in stats])
+    return jnp.mean(jnp.sum(f[:, held[0]:held[0] + held[1]], axis=1))
 
 
 def moe_topk_block(x: jax.Array, p: Params, cfg, tile: Optional[int] = None,
@@ -333,13 +382,19 @@ def _topk_local(x, p, cfg, tile, over):
     tile = tile or TILE_M
     b, s, h = x.shape
     tokens, k, e = b * s, cfg.moe_top_k, cfg.moe_experts
+    held_share = cfg.moe_holds_share  # trace-time: all held is the branch there always was
     xt = x.reshape(tokens, h)
     with jax.named_scope("router"):
         logits = xt.astype(jnp.float32) @ p["router"]["w"].astype(jnp.float32)
         probs = jax.nn.softmax(logits, axis=-1)
     with jax.named_scope("dispatch"):
-        weights, idx = jax.lax.top_k(probs, k)  # weights: p's own values, not renormalised
-        layout = sorted_layout(idx, e, tile)
+        weights, idx = jax.lax.top_k(probs, k)  # weights: p's own values
+        if cfg.moe_norm_topk:
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        if held_share:
+            layout = held_layout(idx, cfg.moe_held, tile, cfg.moe_first_held)
+        else:
+            layout = sorted_layout(idx, e, tile)
         rows = _dispatch(xt, layout.row_pair // k, layout.row_valid, layout.pair_row)
     with jax.named_scope("experts"):
         w1, w3, w2 = (p[n].astype(x.dtype) for n in ("w1", "w3", "w2"))
@@ -348,7 +403,25 @@ def _topk_local(x, p, cfg, tile, over):
         out = grouped_gemm(jax.nn.silu(gate) * up, w2, layout, tile)
     with jax.named_scope("combine"):
         y = _combine(out, weights, layout.pair_row, layout.row_pair, layout.row_valid)
-    stats = router_stats(probs, layout.sizes)
+    if cfg.moe_shared_ffn_dim:
+        with jax.named_scope("shared_expert"):
+            y = y + _shared_expert(xt, p["shared"])
+    # the statistics are over ALL the experts the router scores, held or not
+    sizes = jnp.bincount(idx.reshape(-1), length=e).astype(jnp.int32) if held_share \
+        else layout.sizes
+    stats = router_stats(probs, sizes)
     if over:
         stats = tuple(jax.lax.pmean(s_, over) for s_ in stats)
     return y.reshape(b, s, h), stats
+
+
+def _shared_expert(xt, p):
+    """``sigmoid(x w_gate) * down(silu(gate x) * up x)`` on (T, h): the expert
+    every token runs, whatever the router chose."""
+    dtype = xt.dtype
+    f = p["w13"].shape[1] // 2
+    gu = xt @ p["w13"].astype(dtype)
+    out = (jax.nn.silu(gu[:, :f]) * gu[:, f:]) @ p["w2"].astype(dtype)
+    gate = jax.nn.sigmoid(jnp.einsum(
+        "th,hc->tc", xt, p["gate"].astype(dtype), preferred_element_type=jnp.float32))
+    return (out.astype(jnp.float32) * gate).astype(dtype)

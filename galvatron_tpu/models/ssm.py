@@ -140,3 +140,7 @@ def ssm_block(x, p: Params, cfg, place: Placement = LOCAL):
         y = (g * p["norm"].astype(F32)).astype(dtype)
     with jax.named_scope("out_proj"):
         return y @ p["out_proj"].astype(dtype)
+
+
+# what `modeling` asks of a recurrent kind's module (models/gdn.py has the same three)
+init_params, annotations, block = init_ssm_params, ssm_annotations, ssm_block

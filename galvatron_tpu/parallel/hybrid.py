@@ -58,6 +58,23 @@ from galvatron_tpu.parallel.sharding import (
 #: what a dropless top-k MoE model's train state carries of its last step
 #: (``state["moe_stats"]``), logged in the trainer's ``train_iter`` record
 MOE_STATS = ("moe_aux_loss", "moe_load_max_over_mean")
+#: one more where the model holds a share of its experts (``cfg.moe_share``)
+MOE_HELD_STAT = "moe_held_pairs_per_token"
+
+
+def moe_stat_names(cfg) -> tuple:
+    return MOE_STATS + ((MOE_HELD_STAT,) if cfg.moe_holds_share else ())
+
+
+#: recurrent layer kind -> the words of its refusals (build_runtime): the layers,
+#: what of the mixer carries no tp sharding, the state cp would have to pass on,
+#: and what beside the conv does not reset at a segment boundary
+_RECURRENT_REFUSALS = {
+    "ssm": ("state-space layers", "the Mamba-2 mixer's heads, conv channels and scan",
+            "the scan's state", "the scan"),
+    "gdn": ("Gated DeltaNet layers", "the mixer's heads, conv channels and the delta rule's state",
+            "the delta rule's state", "the delta rule"),
+}
 
 
 def model_param_specs(
@@ -339,35 +356,36 @@ def build_runtime(
                 "pack_sequences is not threaded through the interleaved "
                 "(vpp>1) schedule; use vpp=1 pipelines"
             )
-    if "ssm" in cfg.kinds:
-        # a hybrid stack: what the state-space layers and the interleaving do
+    for kind, (layers_of, unsharded, state, rule) in _RECURRENT_REFUSALS.items():
+        # a hybrid stack: what its recurrent layers and the interleaving do
         # not implement is refused here, by name — nothing is silently
         # mis-sharded
-        ssm_tp = [i for i, (kind, s) in enumerate(
-            zip(cfg.kinds, hp.layer_strategies[cfg.enc_layers:])) if kind == "ssm" and s.tp > 1]
-        if ssm_tp:
+        if kind not in cfg.kinds:
+            continue
+        with_tp = [i for i, (k, s) in enumerate(
+            zip(cfg.kinds, hp.layer_strategies[cfg.enc_layers:])) if k == kind and s.tp > 1]
+        if with_tp:
             raise ValueError(
-                f"tensor parallelism (tp>1) is not implemented for state-space layers "
-                f"(layers {ssm_tp} of this plan): the Mamba-2 mixer's heads, conv channels "
-                "and scan carry no tp sharding; use tp=1 on those layers"
+                f"tensor parallelism (tp>1) is not implemented for {layers_of} "
+                f"(layers {with_tp} of this plan): {unsharded} carry no tp sharding; "
+                "use tp=1 on those layers"
             )
         if any(s.cp > 1 for s in hp.layer_strategies):
             raise ValueError(
-                "context parallelism (cp>1) is not implemented for a stack with "
-                "state-space layers: the scan's state is not passed between sequence "
-                "shards; use cp=1"
-            )
-        if hp.pp > 1 and len(set(cfg.kinds)) > 1:
-            raise ValueError(
-                "pipeline parallelism (pp>1) over interleaved layer kinds is not "
-                "implemented: the pipeline engines stack one kind of layer a stage "
-                f"position (this model: {dict(collections.Counter(cfg.kinds))}); use pp=1"
+                f"context parallelism (cp>1) is not implemented for a stack with "
+                f"{layers_of}: {state} is not passed between sequence shards; use cp=1"
             )
         if cfg.pack_sequences:
             raise ValueError(
-                "pack_sequences is not implemented for state-space layers: the conv and "
-                "the scan do not reset their state at segment boundaries"
+                f"pack_sequences is not implemented for {layers_of}: the conv and "
+                f"{rule} do not reset their state at segment boundaries"
             )
+    if hp.pp > 1 and len(set(cfg.kinds)) > 1:
+        raise ValueError(
+            "pipeline parallelism (pp>1) over interleaved layer kinds is not "
+            "implemented: the pipeline engines stack one kind of layer a stage "
+            f"position (this model: {dict(collections.Counter(cfg.kinds))}); use pp=1"
+        )
     if cfg.attention_multiplier is not None and any(s.cp > 1 for s in hp.layer_strategies):
         raise ValueError(
             "context parallelism (cp>1) is not implemented with attention_multiplier: "
@@ -377,6 +395,13 @@ def build_runtime(
         # the sorted-rows path keeps every expert on every device and hands its
         # auxiliary loss up through the GSPMD step; what it does not implement
         # is refused here, by name — nothing falls back to the one-hot dispatch
+        if cfg.moe_holds_share and any(s.ep > 1 for s in hp.layer_strategies):
+            raise ValueError(
+                f"expert parallelism (ep>1) on a held share of the experts (moe_share="
+                f"{cfg.moe_share}: this copy holds {cfg.moe_held} of {cfg.moe_experts}) is "
+                "not implemented: the share IS one rank of an expert-parallel deployment "
+                "and the sorted-row path has no expert all-to-all; use ep=1"
+            )
         if any(s.ep > 1 for s in hp.layer_strategies):
             raise ValueError(
                 "expert parallelism (ep>1) is not implemented for the dropless "
@@ -480,19 +505,28 @@ def build_runtime(
             n = n.astype(jnp.float32)
             return s + cfg.moe_aux_coef * aux["moe_aux_loss"] * n, (s, n, aux)
 
+        held = cfg.moe_holds_share
+
         def body(acc, mb):
             (_, (s, n, aux)), g = jax.value_and_grad(sum_objective, has_aux=True)(params, mb)
-            acc_s, acc_n, acc_aux, acc_load, acc_g = acc
-            return (acc_s + s, acc_n + n, acc_aux + aux["moe_aux_loss"] * n,
-                    jnp.maximum(acc_load, aux["moe_load_max_over_mean"]),
-                    jax.tree.map(jnp.add, acc_g, g)), None
+            acc_s, acc_n, acc_aux, acc_load, acc_g = acc[:5]
+            out = (acc_s + s, acc_n + n, acc_aux + aux["moe_aux_loss"] * n,
+                   jnp.maximum(acc_load, aux["moe_load_max_over_mean"]),
+                   jax.tree.map(jnp.add, acc_g, g))
+            if held:  # weighted like the auxiliary loss
+                out += (acc[5] + aux[MOE_HELD_STAT] * n,)
+            return out, None
 
         zero = (jnp.zeros((), jnp.float32),) * 4 + (
             jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params),)
+        if held:
+            zero += (jnp.zeros((), jnp.float32),)
         with jax.named_scope("grad_accum"):
-            (tot_s, tot_n, tot_aux, load, tot_g), _ = jax.lax.scan(body, zero, mbs)
+            (tot_s, tot_n, tot_aux, load, tot_g, *tot_held), _ = jax.lax.scan(body, zero, mbs)
         denom = jnp.maximum(tot_n, 1.0)
         aux = {"moe_aux_loss": tot_aux / denom, "moe_load_max_over_mean": load}
+        if held:
+            aux[MOE_HELD_STAT] = tot_held[0] / denom
         return tot_s / denom, jax.tree.map(lambda g: g / denom, tot_g), aux
 
     def grads_fn(params, batch, scale=None):
@@ -570,7 +604,7 @@ def build_runtime(
         if fp16:
             state["scaler"] = init_scaler_state(scaler_cfg)
         if cfg.moe_dropless:
-            state["moe_stats"] = {k: jnp.zeros((), jnp.float32) for k in MOE_STATS}
+            state["moe_stats"] = {k: jnp.zeros((), jnp.float32) for k in moe_stat_names(cfg)}
         return state
 
     def init_state(key):

@@ -259,18 +259,20 @@ class SearchEngine:
                 "parallelism (cp>1) and pipeline stages (pp>1) are not implemented for its "
                 "sorted-row path and are left out of the enumeration"
             )
-        if model_config is not None and "ssm" in getattr(model_config, "kinds", ()):
-            # a hybrid stack: tensor parallelism on a state-space layer, context
-            # parallelism through the scan and pipeline stages over interleaved
+        for kind, tag, layers_of in (("ssm", "state_space_layers", "state-space layers"),
+                                     ("gdn", "gated_delta_layers", "Gated DeltaNet layers")):
+            if model_config is None or kind not in getattr(model_config, "kinds", ()):
+                continue
+            # a hybrid stack: tensor parallelism on a recurrent layer, context
+            # parallelism through its scan and pipeline stages over interleaved
             # layer kinds are refused by build_runtime, so the enumeration
             # leaves them out (tp for the whole stack: the one candidate list
             # serves every layer)
             self.space = space = dataclasses.replace(
                 space, max_tp=1, allow_cp=False, pp_choices=[1])
-            self._standing += ["state_space_layers_no_tp", "state_space_layers_no_cp",
-                               "interleaved_layer_kinds_no_pp"]
+            self._standing += [f"{tag}_no_tp", f"{tag}_no_cp", "interleaved_layer_kinds_no_pp"]
             print(
-                "search: hybrid stack with state-space layers — tensor parallelism (tp>1), "
+                f"search: hybrid stack with {layers_of} — tensor parallelism (tp>1), "
                 "context parallelism (cp>1) and pipeline stages (pp>1) over the interleaved "
                 "layer kinds are not implemented and are left out of the enumeration"
             )

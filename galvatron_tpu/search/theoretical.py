@@ -20,7 +20,7 @@ from dataclasses import dataclass
 from typing import Dict
 
 from galvatron_tpu.core.strategy import LayerStrategy
-from galvatron_tpu.models.modeling import ModelConfig
+from galvatron_tpu.models.modeling import ModelConfig, has_recurrent_layers
 
 _BYTES = {"fp32": 4, "bf16": 2, "fp16": 2}
 
@@ -29,6 +29,8 @@ def moe_expert_params(cfg: ModelConfig) -> int:
     """Parameters in the expert stack (shardable by ep): E MLPs, w1/w2
     (+ w3 for swiglu) — matches moe.init_moe_params."""
     mats = 3 if cfg.act_fn == "swiglu" else 2
+    if cfg.moe_dropless:  # the experts held here, at the routed experts' own width
+        return cfg.moe_held * mats * cfg.hidden_size * cfg.expert_ffn
     return cfg.moe_experts * mats * cfg.hidden_size * cfg.ffn
 
 
@@ -39,8 +41,14 @@ def layer_active_param_count(cfg: ModelConfig, kind: str = "attention") -> int:
     other layer runs all it holds."""
     p = layer_param_count(cfg, kind=kind)
     if cfg.moe_dropless:
-        p -= (cfg.moe_experts - cfg.moe_top_k) * 3 * cfg.hidden_size * cfg.ffn
+        p -= (cfg.moe_held - _held_top_k(cfg)) * 3 * cfg.hidden_size * cfg.expert_ffn
     return p
+
+
+def _held_top_k(cfg: ModelConfig) -> float:
+    """Routed experts a token runs HERE: ``moe_top_k``, or its even share
+    ``k * held / E`` where this copy holds a share of the experts."""
+    return cfg.moe_top_k * cfg.moe_held / cfg.moe_experts
 
 
 def moe_untp_time_fraction(cfg: ModelConfig, seq_len: int) -> float:
@@ -50,7 +58,7 @@ def moe_untp_time_fraction(cfg: ModelConfig, seq_len: int) -> float:
     if not cfg.moe_dropless:
         return 0.0
     h = cfg.hidden_size
-    routed = 2.0 * (h * cfg.moe_experts + cfg.moe_top_k * 3 * h * cfg.ffn)
+    routed = 2.0 * (h * cfg.moe_experts + _held_top_k(cfg) * 3 * h * cfg.expert_ffn)
     total = 2.0 * layer_active_param_count(cfg) + 4.0 * cfg.num_heads * cfg.head_dim * seq_len
     return routed / total
 
@@ -59,20 +67,29 @@ def layer_param_count(cfg: ModelConfig, cross: bool = False, kind: str = "attent
     """Exact per-layer parameter count (matches init_layer_params).
     ``cross``: enc-dec decoder layers carry a cross-attention block
     (wq + wkv + wo + cross_norm). ``kind`` "ssm" (a hybrid stack's
-    state-space layer): the Mamba-2 mixer in place of attention."""
+    state-space layer): the Mamba-2 mixer in place of attention; "gdn": the
+    Gated DeltaNet mixer."""
     h, hd = cfg.hidden_size, cfg.head_dim
     q_out, kv_out = cfg.num_heads * hd, cfg.kv_heads * hd
     attn = h * q_out + 2 * h * kv_out + q_out * h
+    if cfg.attn_gate:  # the output gate's projection and the two per-head norms
+        attn += h * q_out + 2 * hd
     if kind == "ssm":
         from galvatron_tpu.models.ssm import ssm_param_count
 
         attn = ssm_param_count(cfg)
+    elif kind == "gdn":
+        from galvatron_tpu.models.gdn import param_count
+
+        attn = param_count(cfg)
     if cross:
         attn += h * q_out + 2 * h * kv_out + q_out * h
         attn += h if cfg.norm_type == "rms" else 2 * h  # cross_norm
     if cfg.moe_experts > 0:
-        # router + per-expert MLPs
+        # router + per-expert MLPs (+ the gated shared expert)
         mlp = h * cfg.moe_experts + moe_expert_params(cfg)
+        if cfg.moe_shared_ffn_dim:
+            mlp += 3 * h * cfg.moe_shared_ffn_dim + h
     elif cfg.act_fn == "swiglu":
         mlp = 3 * h * cfg.ffn
     else:
@@ -170,7 +187,7 @@ def layer_activation_mb_per_sample(
         # every one of a token's k pairs keeps its row in and out (2h) and
         # its gate, up and product (3f) for the backward; the experts run
         # whole on every device (moe.moe_topk_block), so tp divides nothing
-        mlp = cfg.moe_top_k * (2 * h + 3 * cfg.ffn) * b
+        mlp = cfg.moe_top_k * (2 * h + 3 * cfg.expert_ffn) * b + 3 * cfg.moe_shared_ffn_dim * b
     elif cfg.moe_experts > 0:
         mlp = 3 * cfg.ffn * b / tp  # per routed token (capacity ~1); the
         # recompute policy excludes MoE layers (modeling.mlp_residual)
@@ -189,6 +206,19 @@ def layer_activation_mb_per_sample(
         mixer = (in_width + 2 * conv_dim + 2 * d_inner) * b
         mixer += cfg.ssm_heads * cfg.ssm_chunk * (4 + b)
         return (repl + mixer + mlp) * S / 1e6
+    if kind == "gdn":
+        # in_proj's output, the conv's output, the delta rule's output and the
+        # gated product, and inside a chunk the float32 system, its solution's
+        # two halves and the decay-masked scores a value head
+        from galvatron_tpu.models.gdn import gdn_dims
+
+        _, value_dim, conv_dim, in_width = gdn_dims(cfg)
+        mixer = (in_width + conv_dim + 2 * value_dim) * b
+        mixer += cfg.gdn_value_heads * (
+            3 * cfg.gdn_chunk + 2 * (cfg.gdn_key_dim + cfg.gdn_value_dim)) * 4
+        return (repl + mixer + mlp) * S / 1e6
+    if cfg.attn_gate:
+        ctx *= 2  # the gate beside the context
     per_token = repl + qkv + ctx + mlp
     total = per_token * S
     if cfg.attn_impl == "xla":
@@ -212,7 +242,7 @@ def analytic_model_costs(
         return _analytic_vision_costs(cfg, peak_tflops, mfu, mixed_precision)
     if cfg.enc_layers > 0:
         return _analytic_encdec_costs(cfg, peak_tflops, mfu, mixed_precision)
-    if "ssm" in cfg.kinds:
+    if has_recurrent_layers(cfg):
         return _analytic_hybrid_costs(cfg, seq_len, peak_tflops, mfu, mixed_precision)
     S = seq_len or cfg.max_seq_len
     b = _BYTES[mixed_precision]
@@ -283,6 +313,13 @@ def _analytic_hybrid_costs(
             hp_, n_ = cfg.ssm_heads * cfg.ssm_head_dim, cfg.ssm_state
             pairs = (cfg.ssm_chunk + 1) / 2
             flops += (2.0 * pairs * (cfg.ssm_groups * n_ + hp_) + 4.0 * hp_ * n_) * S
+        elif kind == "gdn":
+            # the chunked delta rule: K K^T and Q K^T (causal half, a key head), a
+            # value head's solve, the entering state read twice and written once,
+            # the causal half of scores V'
+            dk, dv, c = cfg.gdn_key_dim, cfg.gdn_value_dim, cfg.gdn_chunk
+            flops += (cfg.gdn_key_heads * 2.0 * (c + 1) * dk + cfg.gdn_value_heads * (
+                (c - 1.0) * (dk + dv) + 6.0 * dk * dv + (c + 1.0) * dv)) * S
         else:
             flops += 4.0 * cfg.num_heads * cfg.head_dim * S * S
         return ProfiledLayerType(

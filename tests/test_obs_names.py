@@ -198,6 +198,67 @@ def test_compiled_granite_step_carries_the_scope(granite_step_text, scope):
     assert any("/mlp/" in n and "layer_0" in n for n in names)
 
 
+#: what a Gated DeltaNet layer opens in place of ``attn`` (the state-space layer's five
+#: names, under ``gdn``), what gated attention adds inside ``attn``, and what an expert
+#: layer with a shared expert adds beside the four MoE scopes under ``mlp`` (PR 47)
+GDN_SCOPES = ("in_proj", "conv", "scan", "gate_norm", "out_proj")
+
+
+@pytest.fixture(scope="module")
+def qwen3_next_step_names():
+    """``op_name``s of a compiled four-layer qwen3-next step (one period) under
+    full-layer recomputation, tiny widths, rank 1 of 4 of the experts."""
+    import re
+
+    from galvatron_tpu.core.checkpoint import abstract_state_of
+    from galvatron_tpu.core.optim import AdamConfig
+    from galvatron_tpu.core.strategy import HybridParallelConfig
+    from galvatron_tpu.models.modeling import PRESETS
+    from galvatron_tpu.parallel.hybrid import build_runtime
+    from galvatron_tpu.parallel.mesh import build_mesh
+
+    cfg = PRESETS["qwen3-next-80b-a3b"].replace(
+        vocab_size=128, hidden_size=32, num_layers=4, num_heads=4, num_kv_heads=2,
+        attn_head_dim=16, ffn_dim=80, max_seq_len=64, gdn_key_heads=2, gdn_value_heads=4,
+        gdn_key_dim=8, gdn_value_dim=8, gdn_chunk=32, moe_experts=16, moe_top_k=4,
+        moe_ffn_dim=24, moe_shared_ffn_dim=24, moe_share=(1, 4))
+    hp = HybridParallelConfig.uniform(4, ckpt="full", mixed_precision="fp32")
+    mesh, axes = build_mesh(pp=1, devices=jax.devices()[:1])
+    rt = build_runtime(cfg, hp, mesh=mesh, axes=axes, adam=AdamConfig(lr=1e-3),
+                       global_batch_size=2, seq_len=64)
+    batch = jax.ShapeDtypeStruct((2, 65), jnp.int32, sharding=rt.batch_sharding)
+    text = rt.train_step.lower(abstract_state_of(rt), batch).compile().as_text()
+    return {n for n in re.findall(r'op_name="([^"]*)"', text) if n.startswith("jit(train_step)")}
+
+
+@pytest.mark.parametrize("scope", GDN_SCOPES)
+def test_compiled_qwen3_next_step_carries_the_mixers_scope(qwen3_next_step_names, scope):
+    import re
+
+    names = qwen3_next_step_names
+    mine = [n for n in names if re.search(rf"/gdn/(?:[^/\"]+/)*{scope}/", n)]
+    assert mine, scope
+    assert any("transpose(" in n for n in mine), f"{scope}: no backward operation carries it"
+    assert all(re.search(r"layer_[0-2]\b", n) for n in mine)
+    assert not any("layer_3" in n for n in names if "/gdn/" in n)
+    assert not any("/ssm/" in n for n in names)
+
+
+@pytest.mark.parametrize("scope,under,layers", [
+    ("gate", "attn", "3"), ("qk_norm", "attn", "3"), ("rope", "attn", "3"),
+    ("attn_core", "attn", "3"), ("qkv_proj", "attn", "3"), ("out_proj", "attn", "3"),
+    ("router", "mlp", "0-3"), ("dispatch", "mlp", "0-3"), ("experts", "mlp", "0-3"),
+    ("combine", "mlp", "0-3"), ("shared_expert", "mlp", "0-3")])
+def test_compiled_qwen3_next_step_carries_the_attention_and_expert_scopes(
+        qwen3_next_step_names, scope, under, layers):
+    import re
+
+    mine = [n for n in qwen3_next_step_names if re.search(rf"/{under}/(?:[^/\"]+/)*{scope}/", n)]
+    assert mine, scope
+    assert any("transpose(" in n for n in mine), f"{scope}: no backward operation carries it"
+    assert all(re.search(rf"layer_[{layers}]\b", n) for n in mine)
+
+
 def test_hybrid_stack_traces_each_kind_once(granite_step_text):
     """Two layer programs for six layers: the lowered module holds one function a kind
     (and its backward), called from each ``layer_<i>``."""
@@ -526,6 +587,9 @@ def test_build_runtime_span_counts_the_conv_path_beside_the_scan_path(traced_run
     (span,) = [e for e in events if e["ph"] == "X" and e["name"] == "build_runtime"]
     assert span["args"]["ssm_conv_path"] == {"fused": 0, "plain": 0}
     assert list(span["args"]).index("ssm_conv_path") == list(span["args"]).index("ssm_scan_path") + 1
+    # and the same two for Gated DeltaNet layers (PR 47), behind them: none here
+    assert span["args"]["gdn_scan_path"] == span["args"]["gdn_conv_path"] == {"fused": 0, "plain": 0}
+    assert list(span["args"]).index("gdn_scan_path") == list(span["args"]).index("ssm_conv_path") + 1
 
 
 def test_traced_train_logs_the_profile_window(traced_run):

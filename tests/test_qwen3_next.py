@@ -1,0 +1,517 @@
+"""Qwen3-Next-class layers (Gated DeltaNet mixers beside gated partial-RoPE
+attention, zero-centred norms, a renormalised top-k expert layer that holds a
+share of its experts beside a gated shared expert) on the normal path, against
+the plain reference ``benchmark/references/qwen3_next.py`` on seeded random
+weights, at a small size on the CPU; the chunked gated delta rule against the
+recurrent form; the shares of the experts adding up to the uncut layer; and each
+refusal by name."""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.lib import reference
+from galvatron_tpu.core.optim import AdamConfig
+from galvatron_tpu.core.strategy import HybridParallelConfig, LayerStrategy
+from galvatron_tpu.models import gdn, modeling, moe
+from galvatron_tpu.models.modeling import PRESETS
+from galvatron_tpu.ops.gated_delta import gated_delta_chunked
+from galvatron_tpu.parallel.hybrid import build_runtime
+from galvatron_tpu.parallel.mesh import build_mesh
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ARCH = reference.load(ROOT, "qwen3_next")
+
+# float32, the same arithmetic in another order (the program solves a chunk's
+# triangular system and carries a state a chunk, the reference steps a token at a
+# time; the experts after a sort and a grouped GEMM against a masked loop): a few
+# float32 ulps of the largest element a sum went through, as tests/test_olmoe.py
+F32_TOL = 5e-5
+# bf16 compute against float32 ON THE SAME INPUT, one block: the delta rule's
+# operands carry 8 bits into a triangular solve and a state carried over chunks
+# (measured 2-10% of the largest output or gradient at these head sizes of 8), attention and
+# the norms a few roundings (under 1%). Through the whole small model a rounding
+# moves a top-k choice at some token (16 near-uniform probabilities) and the
+# normalised mixers pass the change on, so single logits stray far: there the
+# loss (a mean) is held to 1e-2 and the MEDIAN position's logits to BF16_TOL
+BF16_TOL = 1.5e-1
+
+
+def small_cfg(**kw):
+    """One period (linear, linear, linear, full) at small widths, rank 1 of 4
+    holding experts 4-7 of 16."""
+    base = dict(vocab_size=96, hidden_size=32, num_layers=4, num_heads=4, num_kv_heads=2,
+                attn_head_dim=16, ffn_dim=80, max_seq_len=100, gdn_key_heads=2,
+                gdn_value_heads=4, gdn_key_dim=8, gdn_value_dim=8, moe_experts=16, moe_top_k=4,
+                moe_ffn_dim=24, moe_shared_ffn_dim=24, moe_share=(1, 4), dtype=jnp.float32)
+    base.update(kw)
+    return PRESETS["qwen3-next-80b-a3b"].replace(**base)
+
+
+def ref_cfg(cfg, share=None):
+    rank, of = share or cfg.moe_share
+    return {"hidden_size": cfg.hidden_size, "num_attention_heads": cfg.num_heads,
+            "num_key_value_heads": cfg.kv_heads, "head_dim": cfg.head_dim,
+            "partial_rotary_factor": cfg.rotary_fraction, "rope_theta": cfg.rope_theta,
+            "rms_norm_eps": cfg.norm_eps, "linear_num_key_heads": cfg.gdn_key_heads,
+            "linear_num_value_heads": cfg.gdn_value_heads,
+            "linear_key_head_dim": cfg.gdn_key_dim, "linear_value_head_dim": cfg.gdn_value_dim,
+            "linear_conv_kernel_dim": cfg.gdn_conv, "full_attention_interval": 4,
+            "num_hidden_layers": cfg.num_layers, "vocab_size": cfg.vocab_size,
+            "moe_intermediate_size": cfg.expert_ffn,
+            "shared_expert_intermediate_size": cfg.moe_shared_ffn_dim,
+            "num_experts": cfg.moe_experts // of, "num_experts_per_tok": cfg.moe_top_k,
+            "norm_topk_prob": cfg.moe_norm_topk, "published": {"num_experts": cfg.moe_experts},
+            "expert_share": {"rank": rank, "of": of}}
+
+
+def seeded(cfg, seed=0, batch=2):
+    """Parameters with every vector (norm weights at 0 or 1, A_log, dt_bias)
+    moved off its initial value, and rows of tokens."""
+    params = modeling.init_model_params(jax.random.key(seed), cfg)
+    leaves, tree = jax.tree.flatten(params)
+    keys = jax.random.split(jax.random.key(seed + 1), len(leaves))
+    leaves = [a + 0.2 * jax.random.normal(k, a.shape, a.dtype) if a.ndim == 1 else a
+              for a, k in zip(leaves, keys)]
+    rows = jax.random.randint(jax.random.key(seed + 2), (batch, cfg.max_seq_len + 1), 0,
+                              cfg.vocab_size, jnp.int32)
+    return jax.tree.unflatten(tree, leaves), rows
+
+
+def reference_objective(params, rows, cfg):
+    rc = ref_cfg(cfg)
+    with jax.default_matmul_precision("highest"):
+        w = ARCH.published_weights(jax.tree.map(lambda a: a.astype(jnp.float32), params), rc)
+        logp = jax.nn.log_softmax(ARCH.logits(w, rows[:, :-1], rc), axis=-1)
+        ce = -jnp.mean(jnp.take_along_axis(logp, rows[:, 1:, None], axis=-1))
+        return ce, ARCH.aux_loss(w, rows[:, :-1], rc)
+
+
+def close(got, want, tol):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    scale = max(np.abs(want).max(), 1e-30)
+    err = np.abs(got - want).max() / scale
+    assert err <= tol, f"largest difference {err:.3e} of the largest magnitude, bound {tol:.0e}"
+
+
+@pytest.fixture(autouse=True)
+def highest_precision():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+# -- the preset, the reference file ---------------------------------------------
+
+
+def test_reference_is_plain_and_recurrent():
+    src = open(os.path.join(ROOT, "benchmark", "references", "qwen3_next.py")).read()
+    assert "galvatron_tpu" not in src and "solve_triangular" not in src
+    assert "jax.lax.scan(step" in src  # one position at a time
+
+
+def test_preset_runs_the_published_widths():
+    p = PRESETS["qwen3-next-80b-a3b"]
+    assert (p.hidden_size, p.num_layers, p.num_heads, p.kv_heads, p.head_dim, p.rotary_dim,
+            p.ffn, p.expert_ffn, p.moe_shared_ffn_dim, p.moe_experts, p.moe_top_k,
+            p.vocab_size, p.rope_theta, p.norm_eps, p.tie_word_embeddings) == (
+        2048, 48, 16, 2, 256, 64, 5120, 512, 512, 512, 10, 151936, 1e7, 1e-6, False)
+    assert (p.gdn_key_heads, p.gdn_value_heads, p.gdn_key_dim, p.gdn_value_dim, p.gdn_conv,
+            p.gdn_chunk) == (16, 32, 128, 128, 4, 64)
+    assert [i for i, k in enumerate(p.kinds) if k == "attention"] == list(range(3, 48, 4))
+    assert set(p.kinds) == {"attention", "gdn"} and p.moe_held == 512 and p.moe_norm_topk
+    # head_dim is its own field only here: every other preset keeps hidden / heads
+    for name, other in PRESETS.items():
+        if name != "qwen3-next-80b-a3b":
+            assert other.attn_head_dim is None and other.rotary_dim == other.head_dim
+            assert other.expert_ffn == other.ffn and other.moe_held == other.moe_experts
+
+
+def test_parameter_counts_are_the_configuration_files():
+    from galvatron_tpu.search import theoretical as th
+
+    cfg = PRESETS["qwen3-next-80b-a3b"].replace(num_layers=4, vocab_size=18992, moe_share=(0, 16))
+    assert gdn.param_count(cfg) == 33_718_464
+    assert th.layer_param_count(cfg, kind="gdn") == 138_582_208
+    assert th.layer_param_count(cfg, kind="attention") == 132_127_232
+    assert th.total_param_count(cfg) == 625_667_136
+    shapes = jax.eval_shape(lambda k: modeling.init_model_params(k, cfg), jax.random.key(0))
+    assert sum(int(np.prod(a.shape)) for a in jax.tree.leaves(shapes)) == 625_667_136
+    assert shapes["layers"][0]["mlp"]["w1"].shape == (32, 2048, 512)
+    assert shapes["layers"][0]["mlp"]["router"]["w"].shape == (2048, 512)
+    assert shapes["layers"][3]["attn"]["wqkv"].shape == (2048, 2 * 10 * 256)
+    notes = modeling.model_annotations(cfg)
+    is_note = lambda x: isinstance(x, tuple)  # noqa: E731
+    assert jax.tree.structure(shapes) == jax.tree.structure(
+        jax.tree.map(lambda _: 0, notes, is_leaf=is_note))
+    for a, note in zip(jax.tree.leaves(shapes), jax.tree.leaves(notes, is_leaf=is_note)):
+        assert len(note) == len(a.shape)
+
+
+def test_initialisation_is_the_published_codes():
+    cfg = small_cfg()
+    params = modeling.init_model_params(jax.random.key(0), cfg)
+    for lp in params["layers"]:
+        assert not np.any(np.asarray(lp["attn_norm"]["scale"]))  # (1 + w), w = 0
+        assert not np.any(np.asarray(lp["mlp_norm"]["scale"]))
+    assert not np.any(np.asarray(params["final_norm"]["scale"]))
+    mixer = params["layers"][0]["gdn"]
+    a = np.exp(np.asarray(mixer["A_log"]))
+    assert np.all((a > 0) & (a < 16)) and np.all(np.asarray(mixer["dt_bias"]) == 1)
+    assert np.all(np.asarray(mixer["norm"]) == 1)
+    attn = params["layers"][3]["attn"]
+    assert attn["q_norm"].shape == attn["k_norm"].shape == (cfg.head_dim,)
+    assert not np.any(np.asarray(attn["q_norm"]))
+
+
+# -- (a) the program against the reference ------------------------------------------
+
+
+def test_logits_loss_and_aux_loss_match_the_reference_in_float32():
+    cfg = small_cfg()
+    params, rows = seeded(cfg)
+    logits, stats = modeling.forward_with_stats(params, rows[:, :-1], cfg)
+    rc = ref_cfg(cfg)
+    close(logits, ARCH.logits(ARCH.published_weights(params, rc), rows[:, :-1], rc), F32_TOL)
+    s, n, aux = modeling.moe_loss_sum(params, rows, cfg)
+    ce, aux_ref = reference_objective(params, rows, cfg)
+    close(s / n, ce, F32_TOL)
+    close(aux["moe_aux_loss"], aux_ref, F32_TOL)  # over all 16 experts, held or not
+    assert len(stats) == 4 and stats[0][0].shape == (16,)
+    # a token's k pairs fall on all the experts; the held four get their share
+    assert float(sum(jnp.sum(f) for f, _ in stats)) == pytest.approx(4 * cfg.moe_top_k, rel=1e-6)
+    held = float(np.mean([np.sum(np.asarray(f)[4:8]) for f, _ in stats]))
+    assert float(aux["moe_held_pairs_per_token"]) == pytest.approx(held, rel=1e-6)
+    assert 0.2 < held < 3.0
+
+
+def test_every_gradient_matches_the_reference_in_float32():
+    cfg = small_cfg()
+    params, rows = seeded(cfg)
+
+    def program(p):
+        s, n, aux = modeling.moe_loss_sum(p, rows, cfg)
+        return s / n + cfg.moe_aux_coef * aux["moe_aux_loss"]
+
+    def plain(p):
+        ce, aux = reference_objective(p, rows, cfg)
+        return ce + cfg.moe_aux_coef * aux
+
+    got, want = jax.grad(program)(params), jax.grad(plain)(params)
+    flat_got, flat_want = jax.tree.leaves_with_path(got), jax.tree.leaves(want)
+    assert len(flat_got) == len(flat_want) > 50
+    for (path, g), w in zip(flat_got, flat_want):
+        name = jax.tree_util.keystr(path)
+        assert float(jnp.abs(w).max()) > 0, f"{name}: reference gradient is zero"
+        try:
+            close(g, w, 5 * F32_TOL)
+        except AssertionError as e:
+            raise AssertionError(f"{name}: {e}") from None
+
+
+@pytest.mark.parametrize("seed", [0, 3])
+def test_bf16_compute_stays_near_the_reference(seed):
+    cfg = small_cfg(dtype=jnp.bfloat16)
+    params, rows = seeded(cfg, seed=seed)
+    logits = modeling.forward(params, rows[:, :-1], cfg)
+    rc = ref_cfg(cfg)
+    want = np.asarray(ARCH.logits(ARCH.published_weights(params, rc), rows[:, :-1], rc))
+    assert logits.dtype == jnp.bfloat16
+    row_err = np.abs(np.asarray(logits, np.float32) - want).max(axis=-1) / np.abs(want).max()
+    assert np.median(row_err) <= BF16_TOL / 2, np.median(row_err)
+    s, n, _ = modeling.moe_loss_sum(params, rows, cfg)
+    assert float(s / n) == pytest.approx(float(reference_objective(params, rows, cfg)[0]), rel=1e-2)
+
+
+def test_bf16_blocks_stay_near_float32_on_the_same_input():
+    cfg = small_cfg(dtype=jnp.bfloat16)
+    f32 = cfg.replace(dtype=jnp.float32)
+    params, _ = seeded(cfg)
+    x = jax.random.normal(jax.random.key(5), (2, 100, cfg.hidden_size))
+    weight = jax.random.normal(jax.random.key(6), x.shape)
+    tables = modeling.rope_tables(cfg, 100)
+
+    def mixer(c, p):
+        return jnp.sum(gdn.block(x.astype(c.dtype), p, c).astype(jnp.float32) * weight)
+
+    def attn(c, p):
+        return jnp.sum(modeling.attn_block(x.astype(c.dtype), p, c, tables).astype(jnp.float32)
+                       * weight)
+
+    for fn, p, tol in ((mixer, params["layers"][0]["gdn"], BF16_TOL),
+                       (attn, params["layers"][3]["attn"], BF16_TOL / 4)):
+        got, want = jax.grad(lambda p_: fn(cfg, p_))(p), jax.grad(lambda p_: fn(f32, p_))(p)
+        for name in want:
+            if want[name].ndim == 2:  # the matrices: a vector's gradient is a sum of roundings
+                try:
+                    close(got[name], want[name], tol)
+                except AssertionError as e:
+                    raise AssertionError(f"{fn.__name__} d{name}: {e}") from None
+    close(gdn.block(x.astype(jnp.bfloat16), params["layers"][0]["gdn"], cfg).astype(jnp.float32),
+          gdn.block(x, params["layers"][0]["gdn"], f32), BF16_TOL)
+    close(modeling.attn_block(x.astype(jnp.bfloat16), params["layers"][3]["attn"], cfg,
+                              tables).astype(jnp.float32),
+          modeling.attn_block(x, params["layers"][3]["attn"], f32, tables), BF16_TOL / 4)
+
+
+def test_the_likely_mistakes_show():
+    """Each departure from the published layer moves the logits far past the
+    tolerance: weights not renormalised, a plain ``* w`` norm, rotary over the
+    whole head, the gate left off, the shared expert left out."""
+    cfg = small_cfg()
+    params, rows = seeded(cfg)
+    rc = ref_cfg(cfg)
+    want = np.asarray(ARCH.logits(ARCH.published_weights(params, rc), rows[:, :-1], rc))
+    for wrong in (dict(moe_norm_topk=False), dict(norm_zero_centered=False),
+                  dict(rotary_fraction=1.0), dict(moe_share=(0, 4))):
+        got = np.asarray(modeling.forward(params, rows[:, :-1], cfg.replace(**wrong)))
+        assert np.abs(got - want).max() / np.abs(want).max() > 1e-3, wrong
+
+
+# -- (b) the chunked delta rule against the recurrent form ------------------------
+
+
+def _delta_inputs(s, seed=0, b=2, hk=2, r=2, dk=16, dv=8, decay=0.3):
+    ks = jax.random.split(jax.random.key(seed), 5)
+    q = ARCH.l2norm(jax.random.normal(ks[0], (b, s, hk, dk))) / np.sqrt(dk)
+    # keys that share a direction: the chunk's system is far from the identity
+    k = ARCH.l2norm(jax.random.normal(ks[1], (b, s, hk, dk)) + 0.7)
+    v = jax.random.normal(ks[2], (b, s, hk * r, dv))
+    g = -decay * jax.nn.softplus(jax.random.normal(ks[3], (b, s, hk * r)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, s, hk * r)))
+    return q, k, v, g, beta
+
+
+def _recurrent(q, k, v, g, beta):
+    r = v.shape[2] // q.shape[2]
+    return ARCH.delta_rule_recurrent(jnp.repeat(q, r, 2), jnp.repeat(k, r, 2), v, g, beta)
+
+
+@pytest.mark.parametrize("s,chunk", [(64, 64), (128, 64), (192, 64), (100, 64), (7, 64),
+                                     (65, 64), (96, 32), (40, 16)])
+def test_chunked_delta_rule_is_the_recurrence(s, chunk):
+    args = _delta_inputs(s, seed=s)
+    close(gated_delta_chunked(*args, chunk), _recurrent(*args), F32_TOL)
+
+
+@pytest.mark.parametrize("s", [128, 100])
+def test_chunked_delta_rule_gradients_are_the_recurrences(s):
+    args = _delta_inputs(s, seed=s + 1)
+    weight = jax.random.normal(jax.random.key(9), (2, s, 4, 8))
+    got = jax.grad(lambda *a: jnp.sum(gated_delta_chunked(*a, 64) * weight),
+                   argnums=(0, 1, 2, 3, 4))(*args)
+    want = jax.grad(lambda *a: jnp.sum(_recurrent(*a) * weight), argnums=(0, 1, 2, 3, 4))(*args)
+    for name, a, b in zip("q k v g beta".split(), got, want):
+        try:
+            close(a, b, 5 * F32_TOL)
+        except AssertionError as e:
+            raise AssertionError(f"d{name}: {e}") from None
+
+
+def test_chunked_delta_rule_carries_its_state_across_chunks():
+    """A write in the first chunk is read in the third: zeroing the first
+    chunk's values moves the last chunk's output."""
+    q, k, v, g, beta = _delta_inputs(192, seed=3, decay=0.02)
+    out = gated_delta_chunked(q, k, v, g, beta, 64)
+    cut = gated_delta_chunked(q, k, v.at[:, :64].set(0), g, beta, 64)
+    assert float(jnp.abs(out[:, 128:] - cut[:, 128:]).max()) > 1e-3
+    # and nothing later moves anything earlier
+    later = gated_delta_chunked(q, k, v.at[:, 128:].set(0), g, beta, 64)
+    np.testing.assert_array_equal(np.asarray(out[:, :128]), np.asarray(later[:, :128]))
+
+
+def test_mixer_in_bf16_keeps_the_decays_and_the_state_in_float32():
+    cfg = small_cfg(dtype=jnp.bfloat16)
+    p = gdn.init_params(jax.random.key(0), cfg)
+    x = jax.random.normal(jax.random.key(1), (2, 100, cfg.hidden_size), jnp.bfloat16)
+    jaxpr = str(jax.make_jaxpr(lambda x_, p_: gdn.block(x_, p_, cfg))(x, p))
+    assert "triangular_solve" in jaxpr and "f32[2,2,2,8,8]" in jaxpr  # the solve; the state
+    y = gdn.block(x, p, cfg)
+    want = gdn.block(x.astype(jnp.float32), p, cfg.replace(dtype=jnp.float32))
+    assert y.dtype == jnp.bfloat16
+    close(y.astype(jnp.float32), want, BF16_TOL)
+    assert gdn.scan_path_counts(cfg) == {"fused": 0, "plain": 3}
+    assert gdn.conv_path_counts(cfg) == {"fused": 0, "plain": 3}  # the CPU: the plain conv
+
+
+# -- (c) the shares add up -----------------------------------------------------------
+
+
+def test_the_ranks_shares_add_up_to_the_uncut_layer():
+    """Over all R ranks the routed parts, plus the shared expert counted once,
+    equal the layer that holds every expert: the reference's uncut layer, and the
+    program's parts against it."""
+    cfg = small_cfg(moe_share=(0, 1))
+    full = moe.init_moe_params(jax.random.key(0), cfg)
+    x = jax.random.normal(jax.random.key(1), (2, 50, cfg.hidden_size))
+    fs = cfg.moe_shared_ffn_dim
+
+    def ref_weights(p):
+        return {"gate": p["router"]["w"], "gate_proj": p["w1"], "up_proj": p["w3"],
+                "down_proj": p["w2"], "shared_gate_proj": p["shared"]["w13"][:, :fs],
+                "shared_up_proj": p["shared"]["w13"][:, fs:], "shared_down_proj": p["shared"]["w2"],
+                "shared_expert_gate": p["shared"]["gate"]}
+
+    routed_all, shared_all, _ = ARCH.sparse_mlp(x, ref_weights(full), ref_cfg(cfg))
+    uncut = routed_all + shared_all
+    ranks = 4
+    total_ref = total_prog = 0.0
+    for rank in range(ranks):
+        rcfg = cfg.replace(moe_share=(rank, ranks))
+        lo, n = rcfg.moe_first_held, rcfg.moe_held
+        part = dict(full, **{name: full[name][lo:lo + n] for name in ("w1", "w3", "w2")})
+        routed, shared, _ = ARCH.sparse_mlp(x, ref_weights(part), ref_cfg(rcfg))
+        close(shared, shared_all, 1e-6)  # every rank computes the shared expert alike
+        total_ref = total_ref + routed
+        y, _ = moe.moe_topk_block(x, part, rcfg, tile=8)
+        close(y, routed + shared, F32_TOL)  # the program's share is the reference's
+        total_prog = total_prog + (y - shared)
+    close(total_ref + shared_all, uncut, F32_TOL)
+    close(total_prog + shared_all, uncut, F32_TOL)
+    # and a rank alone is NOT the layer: what the others hold is really left out
+    assert float(jnp.abs(routed + shared_all - uncut).max()) > 1e-2
+
+
+def test_held_layout_drops_the_pairs_it_does_not_hold():
+    idx = jnp.asarray([[0, 5], [4, 7], [6, 2], [5, 4], [9, 5]], jnp.int32)  # experts 4-7 held
+    lay = moe.held_layout(idx, 4, 4, 4)
+    assert list(np.asarray(lay.sizes)) == [2, 3, 1, 1]  # experts 4, 5, 6, 7
+    assert int(lay.num_tiles[0]) == 4  # a tile an expert; the dropped pairs' tiles lie past them
+    valid = np.asarray(lay.row_valid)
+    assert valid.sum() == 7 and not valid[16:].any()
+    rows = np.asarray(lay.pair_row).reshape(5, 2)
+    held = (np.asarray(idx) >= 4) & (np.asarray(idx) < 8)
+    assert (rows[held] < 16).all() and (rows[~held] >= 16).all()
+    assert list(np.asarray(lay.tile_group)[:4]) == [0, 1, 2, 3]
+    assert np.asarray(lay.tile_group).max() == 3
+    # all held: the layout there always was
+    whole = moe.sorted_layout(idx % 4, 4, 4)
+    again = moe.held_layout(idx % 4 + 4, 4, 4, 4)
+    np.testing.assert_array_equal(np.asarray(whole.pair_row), np.asarray(again.pair_row))
+    np.testing.assert_array_equal(np.asarray(whole.sizes), np.asarray(again.sizes))
+
+
+def test_held_share_must_divide_the_experts():
+    with pytest.raises(ValueError, match="moe_share"):
+        moe.init_moe_params(jax.random.key(0), small_cfg(moe_share=(0, 3)))
+    with pytest.raises(ValueError, match="moe_share"):
+        moe.init_moe_params(jax.random.key(0), small_cfg(moe_share=(4, 4)))
+
+
+# -- the runtime --------------------------------------------------------------------
+
+
+def _runtime(cfg, hp, batch=4):
+    mesh, axes = build_mesh(pp=1, devices=jax.devices()[:1])
+    return build_runtime(cfg, hp, mesh=mesh, axes=axes, adam=AdamConfig(lr=1e-3, grad_clip=None),
+                         global_batch_size=batch, seq_len=cfg.max_seq_len)
+
+
+@pytest.mark.parametrize("chunks", [1, 2])
+def test_runtime_steps_and_hands_up_the_held_pairs(chunks):
+    cfg = small_cfg(max_seq_len=64)
+    hp = HybridParallelConfig.uniform(cfg.num_layers, mixed_precision="fp32", chunks=chunks,
+                                      ckpt="full")
+    rt = _runtime(cfg, hp)
+    state = rt.init_state(jax.random.key(0))
+    rows = jax.random.randint(jax.random.key(1), (4, 65), 0, cfg.vocab_size, jnp.int32)
+    want = float(reference_objective(state["params"], rows, cfg)[0])
+    state, loss = rt.train_step(state, rt.shard_batch(rows))
+    assert float(loss) == pytest.approx(want, rel=1e-5)
+    assert set(state["moe_stats"]) == {"moe_aux_loss", "moe_load_max_over_mean",
+                                       "moe_held_pairs_per_token"}
+    assert 0.2 < float(state["moe_stats"]["moe_held_pairs_per_token"]) < 3.0
+    # a model that holds all its experts keeps the two statistics it had
+    whole = _runtime(small_cfg(max_seq_len=64, moe_share=(0, 1)), hp)
+    assert set(whole.init_state(jax.random.key(0))["moe_stats"]) == {
+        "moe_aux_loss", "moe_load_max_over_mean"}
+
+
+# -- (d) each refusal by name -------------------------------------------------------
+
+
+def _plan(cfg, layers=None, **kw):
+    layers = layers or [LayerStrategy() for _ in range(cfg.num_layers)]
+    return HybridParallelConfig(layer_strategies=layers, mixed_precision="fp32", **kw)
+
+
+REFUSALS = [
+    ("tp", lambda c: _plan(c, [LayerStrategy(tp=2)] + [LayerStrategy()] * 3),
+     "tensor parallelism .* Gated DeltaNet layers"),
+    ("cp", lambda c: _plan(c, [LayerStrategy(cp=2) for _ in range(4)]),
+     "context parallelism .* Gated\\s+DeltaNet"),
+    ("pp", lambda c: _plan(c, pp=2), "pipeline parallelism .* interleaved layer kinds"),
+    ("ep", lambda c: _plan(c, [LayerStrategy(ep=2) for _ in range(4)]),
+     "expert parallelism .* held share"),
+]
+
+
+@pytest.mark.parametrize("name,plan,message", REFUSALS, ids=[r[0] for r in REFUSALS])
+def test_build_runtime_refuses_by_name(name, plan, message):
+    cfg = small_cfg(max_seq_len=64)
+    world = {"tp": 2, "cp": 2, "pp": 2, "ep": 2}[name]
+    mesh, axes = build_mesh(pp=2 if name == "pp" else 1, devices=jax.devices()[:world])
+    with pytest.raises(ValueError, match=message):
+        build_runtime(cfg, plan(cfg), mesh=mesh, axes=axes, adam=AdamConfig(),
+                      global_batch_size=4, seq_len=64)
+
+
+def test_packing_and_generation_are_refused_by_name():
+    cfg = small_cfg(max_seq_len=64, pack_sequences=True, attn_impl="xla")
+    mesh, axes = build_mesh(pp=1, devices=jax.devices()[:1])
+    with pytest.raises(ValueError, match="pack_sequences .* Gated DeltaNet"):
+        build_runtime(cfg, _plan(cfg), mesh=mesh, axes=axes, adam=AdamConfig(),
+                      global_batch_size=4, seq_len=64)
+    from galvatron_tpu.models.generation import init_kv_cache
+
+    with pytest.raises(ValueError, match="generation .* Gated DeltaNet"):
+        init_kv_cache(small_cfg(), 1, 8)
+
+
+def test_plan_check_names_the_same_refusals():
+    from galvatron_tpu.analysis import plan_check
+
+    cfg = small_cfg(max_seq_len=64)
+    layers = [LayerStrategy(tp=2, cp=2)] + [LayerStrategy(ep=2)] * 3
+    found = plan_check.check_plan(_plan(cfg, layers, pp=2), cfg, 16)
+    text = "\n".join(f"{d.code} {d.message}" for d in found)
+    assert "GTA019 layer 0: tp=2 on a Gated DeltaNet layer" in text
+    assert "GTA019 layer 0: cp=2 on a Gated DeltaNet layer" in text
+    assert "GTA020 pp=2 over interleaved layer kinds" in text
+    assert "GTA014 layer 3: ep=2 on a held share of the experts" in text
+
+
+def test_search_leaves_out_what_the_runtime_refuses_and_prices_each_kind():
+    from galvatron_tpu.search import theoretical as th
+    from galvatron_tpu.search.cost_model import ProfiledHardware
+    from galvatron_tpu.search.search_engine import SearchEngine, SearchSpace
+
+    cfg = PRESETS["qwen3-next-80b-a3b"].replace(num_layers=4, vocab_size=18992,
+                                                moe_share=(0, 16), max_seq_len=4096)
+    costs = th.analytic_model_costs(cfg, seq_len=4096)
+    assert len(costs.layer_types) == 4
+    linear, full = costs.layer_types[0], costs.layer_types[3]
+    assert linear.parameter_mb == pytest.approx(138_582_208 * 4 / 1e6)
+    assert full.parameter_mb == pytest.approx(132_127_232 * 4 / 1e6)
+    # at s 4096 the full layer is the dearer one (the search counts all s x s pairs)
+    assert 1.0 < full.fwd_ms_per_sample / linear.fwd_ms_per_sample < 2.0
+    # the held share: 0.625 of an expert a token is active, not 10
+    assert th.layer_active_param_count(cfg, "gdn") == pytest.approx(
+        138_582_208 - (32 - 0.625) * 3 * 2048 * 512)
+    engine = SearchEngine(costs, ProfiledHardware(), num_layers=4, space=SearchSpace(world_size=4),
+                          memory_budget_mb=15360.0, model_config=cfg)
+    assert {"gated_delta_layers_no_tp", "gated_delta_layers_no_cp",
+            "interleaved_layer_kinds_no_pp", "dropless_topk_moe_no_ep"} <= set(engine._standing)
+    assert engine.space.max_tp == 1 and engine.space.pp_choices == [1]
+
+
+def test_cli_flag_names_the_share():
+    from galvatron_tpu.core.arguments import initialize_galvatron, model_config_from_args
+
+    ns = initialize_galvatron("train", ["--model_size", "qwen3-next-80b-a3b", "--num_layers", "4",
+                                        "--vocab_size", "18992", "--moe_share", "3/16"])
+    cfg = model_config_from_args(ns)
+    assert cfg.moe_share == (3, 16) and (cfg.moe_first_held, cfg.moe_held) == (96, 32)
+    assert cfg.kinds == ("gdn", "gdn", "gdn", "attention") and cfg.vocab_size == 18992

@@ -385,6 +385,87 @@ def test_granite_mixer_compiles_at_published_widths(one_chip, real_mosaic):
     assert temp < 500 * 2**20, f"{temp / 2**20:.1f} MiB"
 
 
+def test_qwen3_next_mixer_compiles_at_published_widths(one_chip, real_mosaic):
+    """One Gated DeltaNet mixer of `qwen3-next-80b-a3b_s4096`, forward + backward under
+    recomputation, as the chip's compiler sees it: 16 key / 32 value heads of 128, 64
+    chunks of 64 over 4096 tokens, batch 4. The five scopes reach the compiled ENTRY
+    under ``gdn``; the conv + SiLU is the fused op granite's mixer takes (one window of
+    8192 channels at column 0 of in_proj's output: ``ssm_conv_fwd`` / ``ssm_conv_bwd``
+    under ``gdn/conv``); the delta rule is plain XLA (no kernel of its own yet: PERF.md
+    §7), its chunks' systems go through a triangular solve. The mixer's backward is the
+    step's largest user of temporaries: 5.35 GiB as autodiff keeps the rule's float32
+    systems, solutions and carried states (the whole step plans 14.94 of 15.75 GiB,
+    PERF.md §6, PR 47); a rule with its own backward would keep a fraction (§7)."""
+    from galvatron_tpu.models import gdn
+    from galvatron_tpu.models.modeling import PRESETS
+
+    cfg = PRESETS["qwen3-next-80b-a3b"].replace(mlp_recompute="off")
+    assert gdn.gdn_dims(cfg) == (2048, 4096, 8192, 12288) and cfg.gdn_chunk == 64
+    assert gdn.conv_path_counts(cfg.replace(num_layers=4)) == {"fused": 3, "plain": 0}
+    shapes = jax.eval_shape(lambda k: gdn.init_params(k, cfg), jax.random.key(0))
+    p = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), shapes)
+    x = jax.ShapeDtypeStruct((4, 4096, 2048), jnp.bfloat16, sharding=one_chip)
+
+    def loss(x_, p_):
+        with jax.named_scope("layer_0"):
+            y = jax.checkpoint(lambda a, b: gdn.block(a, b, cfg))(x_, p_)
+        return jnp.sum(y.astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, p).compile()
+    text = compiled.as_text()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 5.6 * 2**30, f"{temp / 2**30:.2f} GiB"
+    import re
+
+    ops = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in ("in_proj", "conv", "scan", "gate_norm", "out_proj"):
+        mine = [op for op in ops if f"/gdn/{scope}/" in op]
+        assert mine and any("transpose(" in op for op in mine), scope
+    assert any("triangular_solve" in op and "/gdn/scan/" in op for op in ops)
+    rows = _entry_work(text)
+    conv = sorted((n.split(".")[0], op) for n, op in rows if n.startswith("ssm_conv_"))
+    assert [n for n, _ in conv] == ["ssm_conv_bwd", "ssm_conv_fwd"], conv
+    assert all("/gdn/conv/" in op for _, op in conv), conv
+    assert not [n for n, _ in rows if n.startswith(("flash_", "ssd_"))]
+
+
+def test_qwen3_next_attention_compiles_at_head_size_256(one_chip, real_mosaic):
+    """The gated attention layer of the same cell: 16 query / 2 key-value heads of 256
+    over 4096 keys, batch 4. ``s * lanes(d)`` = 4096 x 256 is exactly the blocked
+    kernels' envelope, so the no-RoPE GQA instance runs (the partial rotary is applied
+    in front of it): ``flash_fwd_blocked`` / ``flash_bwd_blocked`` under
+    ``attn/attn_core``, never run at d 256 before this PR; the gate has its own scope."""
+    from galvatron_tpu.models import modeling
+    from galvatron_tpu.models.modeling import PRESETS
+    from galvatron_tpu.ops.flash_attention import _use_blocked
+
+    cfg = PRESETS["qwen3-next-80b-a3b"].replace(mlp_recompute="off", attn_impl="flash")
+    assert _use_blocked(4096, 256, True, 1024, 1024) and not _use_blocked(8192, 256, True, 1024, 1024)
+    shapes = jax.eval_shape(lambda k: modeling.init_layer_params(k, cfg), jax.random.key(0))
+    p = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+                     shapes["attn"])
+    x = jax.ShapeDtypeStruct((4, 4096, 2048), jnp.bfloat16, sharding=one_chip)
+
+    def loss(x_, p_):
+        with jax.named_scope("layer_3"):
+            y = jax.checkpoint(lambda a, b: modeling.attn_block(
+                a, b, cfg, modeling.rope_tables(cfg, 4096)))(x_, p_)
+        return jnp.sum(y.astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, p).compile()
+    rows = _entry_work(compiled.as_text())
+    kernels = sorted((n.split(".")[0], op) for n, op in rows if n.startswith("flash_"))
+    # (the no-RoPE forward is a call a block of 512 query rows: 8 of them, replayed once)
+    assert [n for n, _ in kernels] == ["flash_bwd_blocked"] + ["flash_fwd_blocked"] * 8, kernels
+    assert all("/attn/attn_core/" in op for _, op in kernels), kernels
+    import re
+
+    # (the gate's multiply rides inside a neighbour's fusion: read every instruction's name)
+    ops = set(re.findall(r'op_name="([^"]*)"', compiled.as_text()))
+    for scope in ("qkv_proj", "qk_norm", "rope", "gate", "out_proj"):
+        assert any(f"/attn/{scope}/" in op for op in ops), scope
+
+
 @pytest.mark.parametrize("sizes", [
     dict(h=4, p=64, g=2, n=128, chunk=128, dtype=jnp.bfloat16),  # head blocks of 2, two groups
     dict(h=8, p=128, g=2, n=256, chunk=256, dtype=jnp.float32),  # a head a lane tile, state 256
