@@ -6,13 +6,20 @@ layer of kind ``"gdn"`` runs in place of attention.
     [q | k | v] = silu(conv1d_causal_depthwise([q | k | v]))      K taps, no bias
     beta = sigmoid(b);  g = -exp(A_log) * softplus(a + dt_bias)   float32, a value head
     q, k = l2norm(q), l2norm(k) over Dk;  q = q / sqrt(Dk)
-    o = gated_delta_rule(q, k, v, g, beta)                        ops/gated_delta.py, chunked
+    o = gated_delta_rule(q, k, v, g, beta)                        ops/gated_delta.py, chunks of 64
     y = W_out concat_heads(w * o * rsqrt(mean(o^2) + eps) * silu(z))   over Dv of each head
 
 (HF ``modeling_qwen3_next.py`` Qwen3NextGatedDeltaNet; no biases anywhere. The
 program's in-projections are flat as written above; the published ones are
 interleaved by key head, ``benchmark/references/qwen3_next.published_weights``
 maps one to the other.) Imported only where a configuration has such layers.
+
+The rule has two bodies behind one contract (PR 48), chosen by
+`ops/gated_delta.scan_path` from shapes and backend alone: on a chip at the
+published sizes the Pallas kernels ``gdn_fwd`` / ``gdn_bwd`` (a ``custom_vjp``
+that keeps its five inputs and the chunks' entering states; under
+``place.shard_kernel`` on a mesh), everywhere else the plain chunked body under
+this mixer's own ``jax.checkpoint``. `scan_path_counts` reports which.
 
 Scopes under ``gdn``: ``in_proj``, ``conv``, ``scan``, ``gate_norm``,
 ``out_proj`` (PERF.md §3; the ``gdn_*`` benchmark metrics read them).
@@ -27,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from galvatron_tpu.models.placement import LOCAL, Placement
-from galvatron_tpu.ops.gated_delta import gated_delta_chunked
+from galvatron_tpu.ops.gated_delta import gated_delta_chunked, gated_delta_fused, scan_path
 from galvatron_tpu.ops.ssd import causal_conv1d, conv_path, conv_silu_fused
 
 Params = Dict[str, Any]
@@ -105,10 +112,19 @@ def conv_path_counts(cfg) -> dict:
     return counts
 
 
+def _rule_path(cfg) -> str:
+    return scan_path(cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_key_dim, cfg.gdn_value_dim,
+                     cfg.gdn_chunk, cfg.dtype)
+
+
 def scan_path_counts(cfg) -> dict:
-    """As `conv_path_counts`, for the delta rule: one body so far, the plain
-    chunked one (a fused kernel would count under ``"fused"``)."""
-    return {"fused": 0, "plain": sum(kind == "gdn" for kind in cfg.kinds)}
+    """As `conv_path_counts`, for the delta rule: asks `ops/gated_delta.scan_path`,
+    the function `block` asks."""
+    counts = {"fused": 0, "plain": 0}
+    layers = sum(kind == "gdn" for kind in cfg.kinds)
+    if layers:
+        counts[_rule_path(cfg)] = layers
+    return counts
 
 
 def _l2norm(t):
@@ -137,10 +153,17 @@ def block(x, p: Params, cfg, place: Placement = LOCAL):
         beta = jax.nn.sigmoid(ba[..., :hv])
         g = -jnp.exp(p["A_log"].astype(F32)) * jax.nn.softplus(
             ba[..., hv:] + p["dt_bias"].astype(F32))
-        # rematerialized in the backward from its five inputs: the chunks' float32
-        # systems, solutions and carried states (~300 KB a token) are then live
-        # only while the rule's own backward runs, not beside the expert layer's
-        rule = jax.checkpoint(lambda *t: gated_delta_chunked(*t, cfg.gdn_chunk))
+        if _rule_path(cfg) == "fused":
+            # the kernels keep their own residuals (the five inputs and the chunks'
+            # entering states); on a mesh each device runs them on its own batch rows
+            rows = (0, None)
+            rule = place.shard_kernel(
+                lambda *t: gated_delta_fused(*t, cfg.gdn_chunk), [rows] * 5, rows)
+        else:
+            # rematerialized in the backward from its five inputs: the chunks' float32
+            # systems, solutions and carried states (~300 KB a token) are then live
+            # only while the rule's own backward runs, not beside the expert layer's
+            rule = jax.checkpoint(lambda *t: gated_delta_chunked(*t, cfg.gdn_chunk))
         o = rule(q, k, v, g, beta)
     with jax.named_scope("gate_norm"):
         o32 = o.astype(F32)

@@ -20,15 +20,21 @@ entering state ``S``::
     O  = (Q exp(G)) S + lower(Q K^T * D) V'
     S <- exp(G_last) S + (K exp(G_last - G))^T V'
 
-The states are carried across chunks by a ``lax.scan``; everything inside a
-chunk is batched over the chunks. Plain ``jax.numpy`` (autodiff gives the
-backward; the triangular solve is ``jax.scipy.linalg.solve_triangular``, whose
-derivative is another solve): a fused kernel is a later optimisation (PERF.md
-§7).
+Two bodies, one contract (as `ops/ssd.py`'s scan): `gated_delta_chunked`, plain
+``jax.numpy`` (a ``lax.scan`` carries the states across the chunks, everything
+inside a chunk is batched over the chunks, the system goes through
+``jax.scipy.linalg.solve_triangular``, autodiff gives the backward), and
+`gated_delta_fused`, a ``jax.custom_vjp`` over the Pallas kernels ``gdn_fwd`` /
+``gdn_bwd`` (further down: the state in VMEM across a sequential grid axis, the
+system inverted by matrix products, a backward of its own that keeps the five
+inputs and the chunks' entering states). `scan_path` chooses between them from
+shapes and backend alone; `models/gdn.block` asks it.
 
-Precision: the log-decays, their running sums, ``beta``, the system and its
-solve, and the carried state are float32 always; the operands of the other
-products are the compute dtype (``q``'s) with float32 accumulation.
+Precision, both bodies: the log-decays, their running sums, ``beta``, the
+system, its inverse (or solve) and its application, and the carried state (and
+its gradient) are float32 always, float32 operands at full precision; the
+operands of the other products are the compute dtype (``q``'s) with float32
+accumulation.
 
 Each key head serves ``Hv / Hk`` consecutive value heads (value head ``j``
 reads key head ``j // (Hv / Hk)``); q and k are never repeated in memory.
@@ -36,8 +42,15 @@ reads key head ``j // (Hv / Hk)``); q and k are never repeated in memory.
 
 from __future__ import annotations
 
+import functools
+from typing import Any, NamedTuple
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from galvatron_tpu.ops import flash_attention as fa
 
 F32 = jnp.float32
 
@@ -102,3 +115,545 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64):
     # (N, B, Hk, R, C, Dv) -> (B, S, Hv, Dv)
     o = o.transpose(1, 0, 4, 2, 3, 5).reshape(b, n * chunk, hv, dv)
     return o[:, :s].astype(v.dtype)
+
+
+# -- the fused kernels -----------------------------------------------------------
+#
+# One grid step is `_SLABS` slabs of one key head, a slab `_SLAB` = 128 positions =
+# two chunks of 64: grid (batch, key head, step), the step axis sequential
+# ("arbitrary"), the states of the key head's ``Hv / Hk`` value heads, float32
+# (Dk, Dv) each, in VMEM scratch from one step to the next. q, k, o and their
+# gradients are head-major, (B, H, S, D): a block is (positions, D) of one head,
+# contiguous, and the transpositions from and to the mixer's (B, S, H, D) are XLA's,
+# under the caller's scope, fused into the norms on both sides (token-major
+# (B, S, H x D) blocks made XLA re-tile the float32 norms' operands in copies that
+# carry no name: 12 x 0.8 ms a step in the cell, PERF.md §6, PR 48). v and its
+# gradient stay token-major, (positions, R x Dv) blocks of (B, S, Hv x Dv): the
+# conv's output as it lies. The per-position scalars come both ways, made outside
+# from (B, S, Hv) float32 (2 MB at the cell's sizes): a position a sublane (the
+# running sums G and beta, a value head picked by a lane mask) and a position a
+# lane (G again, (B, Hk, R, S)).
+#
+# Every (C, C) block of a value head lives PACKED: the two chunks of the slab side
+# by side on the 128 lanes, (64, 128) = [chunk a | chunk b], so an elementwise op
+# fills whole registers. ``x @ blockdiag(z_a, z_b)`` multiplies both chunks' blocks
+# in one (64, 128) x (128, 128) product (`_pdot`); a 128 x 128 Gram product of the
+# slab's rows gives both chunks' K K^T at once on its diagonal blocks (`_halves`).
+#
+# The system: ``A`` is strictly lower, so ``(I + A)^-1`` is built by products
+# alone, from 2 x 2 diagonal blocks (``I - A`` there) up: two inverted diagonal
+# blocks ``T11``, ``T22`` of size s/2 merge into one of size s as
+# ``[[T11, 0], [-T22 A21 T11, T22]]`` = ``T - T (A on the off-diagonal blocks) T``,
+# five times (s = 4 .. 64): block forward substitution, each step exact given its
+# halves, no power of ``A`` ever formed. Float32 operands at full precision
+# (`_dot`), two products a level, each waiting for the one before it.
+#
+# The order things are written in is the order they run in, nearly: Mosaic's
+# scheduler overlaps what stands close. So every chain of dependent products (a
+# head's inverse, a head's walk over its chunks) is written side by side with the
+# same chain of the step's other heads and slabs, stage by stage, and what does not
+# wait for the state is computed before the walk (`_heads_setup`, ``ready``): one
+# chain's elementwise work then runs under another's products. (On the chip, a
+# layer's forward at the cell's sizes: 15.2 ms one chain after the other, 7.3 ms
+# side by side; PERF.md §6, PR 48.)
+#
+# The backward walks the steps from the last to the first with the gradient of the
+# state in VMEM, rebuilds ``A``, ``T``, ``U``, ``W`` of its slabs from the inputs
+# and ``V'`` from the states the forward rule kept (one entering a chunk: (B, S/64,
+# Hv, Dk, Dv) float32, the only residual besides the inputs), and takes the
+# inverse's gradient as ``dA = -strict(dXu U^T + dXw W^T)`` with ``[dXu | dXw] =
+# T^T [dU | dW]``: products, no solve. ``dg`` leaves the kernel summed back over
+# each chunk (the running sum's transpose), a position a lane.
+
+_CHUNK = 64  # the chunk the kernels are written for (the published code's)
+_SLAB = 2 * _CHUNK
+_SLABS = 2  # slabs a grid step: their chains side by side (one: 9.8 ms a forward at the
+# cell's sizes, two: 7.3, four: 7.3 and three times the compile; PERF.md §6, PR 48)
+_NEG = -1e30  # exp() of it is 0: the mask of the decay blocks
+_NN, _NT, _TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
+
+
+def _dot(a, b, dims):
+    """Float32 accumulation; bf16 operands one pass, float32 operands at full
+    precision (``HIGHEST``: Mosaic's six bf16 passes, the MXU's default for float32
+    operands being fewer; ``experiments/ab_gdn.py`` holds it on the chip)."""
+    precision = jax.lax.Precision.HIGHEST if a.dtype == F32 else None
+    return jax.lax.dot_general(a, b, (dims, ((), ())), preferred_element_type=F32,
+                               precision=precision)
+
+
+def _geometry():
+    """Of a packed (64, 128) block: its row, its column inside its own chunk,
+    whether a lane belongs to the slab's first chunk."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (_CHUNK, _SLAB), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (_CHUNK, _SLAB), 1)
+    return row, lane & (_CHUNK - 1), lane < _CHUNK
+
+
+def _halves(x, first):
+    """The diagonal blocks of a (128, 128) product over the slab's rows, packed."""
+    return jnp.where(first, x[:_CHUNK], x[_CHUNK:])
+
+
+def _spread(col, first):
+    """(128, 1), a position a sublane -> packed: each chunk's own 64 over its lanes."""
+    return jnp.where(first, col[:_CHUNK], col[_CHUNK:])
+
+
+def _bd(x, first):
+    """Packed -> (128, 128) block diagonal."""
+    return jnp.concatenate([jnp.where(first, x, 0.0), jnp.where(first, 0.0, x)], axis=0)
+
+
+def _pdot(x, z, first):
+    """Packed ``[x_a z_a | x_b z_b]`` of packed float32 operands."""
+    return _dot(x, _bd(z, first), _NN)
+
+
+def _lower_rows(x, half):
+    """The rows of the lower half of every block of ``2 * half`` rows, one after
+    the other (``half`` whole sublane tiles: nothing moves)."""
+    return jnp.concatenate([x[b + half:b + 2 * half] for b in range(0, _CHUNK, 2 * half)], axis=0)
+
+
+def _with_lower_rows(x, low, half):
+    """``x`` with those rows replaced by ``low``'s."""
+    parts = []
+    for n, b in enumerate(range(0, _CHUNK, 2 * half)):
+        parts += [x[b:b + half], low[n * half:(n + 1) * half]]
+    return jnp.concatenate(parts, axis=0)
+
+
+def _inverses(blocks, row, col, first):
+    """Packed ``(I + A)^-1`` of each packed strictly lower ``A`` of ``blocks`` (see
+    above). The chains are independent and each product waits for the one before
+    it: written level by level across the blocks, so that one chain's elementwise
+    work runs under another's products. A merge changes only the lower half of
+    each block's rows: from halves of 8 rows up only those rows go through the
+    MXU (what bounds these products is popping their results)."""
+    ts = [(row == col).astype(F32) - jnp.where((row >> 1) == (col >> 1), a, 0.0) for a in blocks]
+    bits = 2
+    while (1 << bits) <= _CHUNK:
+        half = 1 << (bits - 1)
+        off = ((row >> bits) == (col >> bits)) & ((row >> (bits - 1)) != (col >> (bits - 1)))
+        if half < 8:
+            xs = [_pdot(jnp.where(off, a, 0.0), t, first) for a, t in zip(blocks, ts)]
+            ts = [t - _pdot(t, x, first) for t, x in zip(ts, xs)]
+        else:
+            zeros = jnp.zeros((_CHUNK, _SLAB), F32)
+            xs = [_pdot(_lower_rows(jnp.where(off, a, 0.0), half), t, first)
+                  for a, t in zip(blocks, ts)]
+            lows = [_lower_rows(t, half) for t in ts]
+            ts = [_with_lower_rows(t, low - _pdot(low, _with_lower_rows(zeros, x, half), first), half)
+                  for t, low, x in zip(ts, lows, xs)]
+        bits += 1
+    return ts
+
+
+def _last_row(col_block, width):
+    """The last of a chunk's (64, 1) column over ``width`` lanes, (1, width) (a
+    (1, 1) slice is not spread both ways by Mosaic: summed out of a broadcast)."""
+    at_end = jax.lax.broadcasted_iota(jnp.int32, (_CHUNK, width), 0) == _CHUNK - 1
+    return jnp.sum(jnp.where(at_end, jnp.broadcast_to(col_block, (_CHUNK, width)), 0.0),
+                   axis=0, keepdims=True)
+
+
+def _slab_rows(sl):
+    return slice(sl * _SLAB, (sl + 1) * _SLAB)
+
+
+def _chunk_rows(c):
+    return slice(c * _CHUNK, (c + 1) * _CHUNK)
+
+
+def _padded(x, c):
+    """A chunk's (64, n) rows where the slab has them, zeros in the other chunk's."""
+    zeros = jnp.zeros_like(x)
+    return jnp.concatenate([x, zeros] if c == 0 else [zeros, x], axis=0)
+
+
+class _Slab(NamedTuple):
+    """q and k of a slab (128 positions of a key head), as they come and in
+    float32, and their two Gram products, packed."""
+    q: Any
+    k: Any
+    q32: Any
+    k32: Any
+    kk: Any
+    qk: Any
+
+
+class _Head(NamedTuple):
+    """What is chunk parallel of a value head in a slab: the columns G and beta
+    (128, 1), the packed decay block, ``A``, ``T``, v in float32, ``beta e^G K``
+    and ``[U | W]`` (128, Dv + Dk)."""
+    gcol: Any
+    bcol: Any
+    decay: Any
+    a: Any
+    t: Any
+    v32: Any
+    k_seen: Any
+    uw: Any
+
+
+def _slab_setup(sl, q_ref, k_ref, first):
+    q, k = q_ref[0, 0, _slab_rows(sl)], k_ref[0, 0, _slab_rows(sl)]
+    return _Slab(q, k, q.astype(F32), k.astype(F32),
+                 _halves(_dot(k, k, _NT), first), _halves(_dot(q, k, _NT), first))
+
+
+def _heads_setup(r, slab, v_ref, gc_ref, bc_ref, gr_ref, geometry):
+    """The `_Head` of every slab ``sl`` of the step and value head ``i`` of its key
+    head, by ``(sl, i)``: what both kernels build chunk parallel."""
+    row, col, first = geometry
+    dv = v_ref.shape[2] // r
+    heads = jax.lax.broadcasted_iota(jnp.int32, (_SLAB, gc_ref.shape[2]), 1)
+    keys = [(sl, i) for sl in range(_SLABS) for i in range(r)]
+    built = {}
+    for sl, i in keys:
+        rows = _slab_rows(sl)
+        mine = heads == pl.program_id(1) * r + i
+        gcol = jnp.sum(jnp.where(mine, gc_ref[0, rows], 0.0), axis=1, keepdims=True)  # (128, 1)
+        bcol = jnp.sum(jnp.where(mine, bc_ref[0, rows], 0.0), axis=1, keepdims=True)
+        grow = gr_ref[0, 0, i:i + 1, rows]  # (1, 128)
+        decay = jnp.exp(jnp.where(row >= col, _spread(gcol, first) - grow, _NEG))
+        a = jnp.where(row > col, _spread(bcol, first) * slab[sl].kk * decay, 0.0)
+        built[sl, i] = (gcol, bcol, decay, a)
+    inverses = _inverses([built[key][3] for key in keys], row, col, first)
+    for (sl, i), t in zip(keys, inverses):
+        gcol, bcol, decay, a = built[sl, i]
+        v32 = v_ref[0, _slab_rows(sl), i * dv:(i + 1) * dv].astype(F32)
+        k_seen = slab[sl].k32 * (bcol * jnp.exp(gcol))
+        # both chunks' T on one load of [beta V | beta e^G K]: chunk a's rows, then b's
+        uw = _dot(_bd(t, first), jnp.concatenate([v32 * bcol, k_seen], axis=1), _NN)
+        built[sl, i] = _Head(gcol, bcol, decay, a, t, v32, k_seen, uw)
+    return built
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, gc_ref, bc_ref, gr_ref, o_ref, *rest, r, keep_states):
+    state = rest[-1]  # (R, Dk, Dv) float32
+    dtype = q_ref.dtype
+    dv, dk = v_ref.shape[2] // r, q_ref.shape[3]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _zero():
+        state[...] = jnp.zeros_like(state)
+
+    geometry = _geometry()
+    first = geometry[2]
+    # chunk parallel first (every slab and head of the step), then the chunks in
+    # their order, the value heads' chains side by side (the scheduler keeps close
+    # to the order written: one head's products run under the other's updates)
+    slab = [_slab_setup(sl, q_ref, k_ref, first) for sl in range(_SLABS)]
+    head = _heads_setup(r, slab, v_ref, gc_ref, bc_ref, gr_ref, geometry)
+    ready = {}
+    for sl in range(_SLABS):
+        q32, k32 = slab[sl].q32, slab[sl].k32
+        for i in range(r):
+            uw = head[sl, i].uw
+            intra = (slab[sl].qk * head[sl, i].decay).astype(dtype)  # packed, the diagonal included
+            for c in range(2):
+                at = _chunk_rows(c)
+                g_c = head[sl, i].gcol[at]
+                # [W ; Q e^G]: both read the entering state, one load of it
+                reads = jnp.concatenate([uw[at, dv:], q32[at] * jnp.exp(g_c)], axis=0).astype(dtype)
+                k_out = (k32[at] * jnp.exp(_last_row(g_c, dk) - g_c)).astype(dtype)
+                ready[sl, c, i] = (uw[at, :dv], reads, intra, k_out, jnp.exp(_last_row(g_c, dv)))
+    states = [state[i] for i in range(r)]
+    for sl in range(_SLABS):
+        for c in range(2):
+            for i in range(r):
+                u, reads, intra, k_out, kept = ready[sl, c, i]
+                entering = states[i]
+                if keep_states:
+                    rest[0][0, 2 * sl + c, i] = entering
+                seen = _dot(reads, entering.astype(dtype), _NN)  # [W S ; (Q e^G) S]
+                v_new = (u - seen[:_CHUNK]).astype(dtype)
+                o = seen[_CHUNK:] + _dot(intra, _padded(v_new, c), _NN)
+                start = sl * _SLAB + c * _CHUNK
+                o_ref[0, i, start:start + _CHUNK] = o.astype(o_ref.dtype)
+                states[i] = entering * kept + _dot(k_out, v_new, _TN)
+    for i in range(r):
+        state[i] = states[i]
+
+
+def _suffix_sums(v):
+    """(8, 128) float32 -> the sum from each lane to the end of its chunk (64
+    lanes): log2(64) shifted adds, the running sum's transpose."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, v.shape, 1) & (_CHUNK - 1)
+    n, k = v.shape[1], 1
+    while k < _CHUNK:
+        v = v + jnp.where(lane < _CHUNK - k, pltpu.roll(v, n - k, 1), 0.0)
+        k *= 2
+    return v
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, gc_ref, bc_ref, gr_ref, do_ref, st_ref,
+                dq_ref, dk_ref, dv_ref, dg_ref, db_ref, dstate, *, r):
+    dtype = q_ref.dtype
+    dv, dk = v_ref.shape[2] // r, q_ref.shape[3]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _zero():
+        dstate[...] = jnp.zeros_like(dstate)
+
+    geometry = _geometry()
+    row, col, first = geometry
+    lane = jax.lax.broadcasted_iota(jnp.int32, (_SLAB, _SLAB), 1)
+    slab = [_slab_setup(sl, q_ref, k_ref, first) for sl in range(_SLABS)]
+    head = _heads_setup(r, slab, v_ref, gc_ref, bc_ref, gr_ref, geometry)
+    dq = [jnp.zeros((_SLAB, dk), F32) for _ in range(_SLABS)]
+    dkey = [jnp.zeros((_SLAB, dk), F32) for _ in range(_SLABS)]
+    leaving = [dstate[i] for i in range(r)]
+    value_heads = range(r)
+
+    def row_sums(x):  # of a packed block, a chunk's rows where the slab has them
+        return jnp.concatenate(
+            [jnp.sum(jnp.where(first, x, 0.0), axis=1, keepdims=True),
+             jnp.sum(jnp.where(first, 0.0, x), axis=1, keepdims=True)], axis=0)
+
+    # (every stage below runs over the value heads side by side, as the forward's)
+    for sl in reversed(range(_SLABS)):
+        rows = _slab_rows(sl)
+        q, k, q32, k32, kk, qk = slab[sl]
+        # what does not wait for the state's gradient, chunk parallel
+        do, intra32, intra, w, sd, v_new, d_intra, entering = [[None] * r for _ in range(8)]
+        for i in value_heads:
+            decay, uw = head[sl, i].decay, head[sl, i].uw
+            do[i] = do_ref[0, i, rows]
+            intra32[i] = qk * decay
+            intra[i] = intra32[i].astype(dtype)
+            w[i] = uw[:, dv:].astype(dtype)
+            entering[i] = [st_ref[0, 2 * sl + c, i] for c in range(2)]
+            sd[i] = [e.astype(dtype) for e in entering[i]]
+            v_new[i] = jnp.concatenate(
+                [uw[_chunk_rows(c), :dv] - _dot(w[i][_chunk_rows(c)], sd[i][c], _NN)
+                 for c in range(2)], axis=0).astype(dtype)
+            d_intra[i] = jnp.where(row >= col, _halves(_dot(do[i], v_new[i], _NT), first), 0.0)
+        ready = {}
+        for c in (1, 0):
+            at = _chunk_rows(c)
+            for i in value_heads:
+                g_c = head[sl, i].gcol[at]
+                grown = jnp.exp(_last_row(g_c, dk) - g_c)  # e^(G_last - G) over Dk lanes
+                q_in32 = q32[at] * jnp.exp(g_c)
+                dq_in = _dot(do[i][at], sd[i][c], _NT)
+                mine = first if c == 0 else ~first
+                through = _dot(jnp.where(mine, intra[i], 0), do[i][at], _TN)[at]
+                ready[c, i] = (g_c, jnp.exp(_last_row(g_c, dv)), grown, k32[at] * grown, q_in32,
+                               dq_in, through)
+                dq[sl] = dq[sl] + _padded(dq_in * jnp.exp(g_c), c)
+
+        # the sequential part, the later chunk first
+        du, dw, g_cols, d_last = [[[None, None] for _ in value_heads] for _ in range(4)]
+        for c in (1, 0):
+            at = _chunk_rows(c)
+            for i in value_heads:
+                g_c, kept, grown, k_out32, q_in32, dq_in, through = ready[c, i]
+                ld = leaving[i].astype(dtype)
+                du[i][c] = through + _dot(k_out32.astype(dtype), ld, _NN)
+                dud = du[i][c].astype(dtype)
+                dk_out = _dot(v_new[i][at], ld, _NT)
+                dw[i][c] = -_dot(dud, sd[i][c], _NT)
+                carried = jnp.sum(jnp.sum(leaving[i] * entering[i][c], axis=0, keepdims=True)
+                                  * kept, axis=1, keepdims=True)  # (1, 1)
+                sent = jnp.sum(dk_out * k_out32, axis=1, keepdims=True)  # (64, 1)
+                d_last[i][c] = carried + jnp.sum(sent, axis=0, keepdims=True)
+                g_cols[i][c] = jnp.sum(dq_in * q_in32, axis=1, keepdims=True) - sent
+                dkey[sl] = dkey[sl] + _padded(dk_out * grown, c)
+                leaving[i] = (leaving[i] * kept + _dot(q_in32.astype(dtype), do[i][at], _TN)
+                              - _dot(w[i][at], dud, _TN))
+
+        # back through the application of T and through its inverse, chunk parallel
+        dx = [_dot(_bd(head[sl, i].t, first),
+                   jnp.concatenate([jnp.concatenate(du[i], axis=0),
+                                    jnp.concatenate(dw[i], axis=0)], axis=1), _TN)
+              for i in value_heads]  # T^T [dU | dW], (128, Dv + Dk)
+        da = [-jnp.where(row > col, _halves(_dot(dx[i], head[sl, i].uw, _NT), first), 0.0)
+              for i in value_heads]
+        for i in value_heads:
+            gcol, bcol, decay, a, _, v32, k_seen, _ = head[sl, i]
+            dxu, dxw = dx[i][:, :dv], dx[i][:, dv:]
+            dkk = (da[i] * _spread(bcol, first) * decay).astype(dtype)
+            dqk = (d_intra[i] * decay).astype(dtype)
+            # d decay x decay: + to its row's G, - to its column's
+            e = da[i] * a + d_intra[i] * intra32[i]
+            f = da[i] * kk * decay  # d beta of a row
+            for c in range(2):
+                at = _chunk_rows(c)
+                mine = first if c == 0 else ~first
+                dkk_c, dqk_c = jnp.where(mine, dkk, 0), jnp.where(mine, dqk, 0)
+                dq[sl] = dq[sl] + _padded(_dot(dqk_c, k, _NN), c)
+                dkey[sl] = (dkey[sl] + _padded(_dot(dkk_c, k, _NN), c) + _dot(dkk_c, k[at], _TN)
+                            + _dot(dqk_c, q[at], _TN))
+            dkey[sl] = dkey[sl] + dxw * (bcol * jnp.exp(gcol))
+            dv_ref[0, rows, i * dv:(i + 1) * dv] = (dxu * bcol).astype(dv_ref.dtype)
+            dg_col = (jnp.sum(dxw * k_seen, axis=1, keepdims=True)
+                      + jnp.concatenate(g_cols[i], axis=0) + row_sums(e))
+            db_col = (jnp.sum(dxu * v32, axis=1, keepdims=True)
+                      + jnp.sum(dxw * (k32 * jnp.exp(gcol)), axis=1, keepdims=True) + row_sums(f))
+            # a position a sublane -> a position a lane, through one aligned transpose
+            across = (jnp.where(lane == 0, dg_col, 0.0) + jnp.where(lane == 1, db_col, 0.0)).T
+            dg_row = (across[0:1] - jnp.sum(e, axis=0, keepdims=True)
+                      + jnp.where(lane[:1] == _CHUNK - 1, d_last[i][0], 0.0)
+                      + jnp.where(lane[:1] == _SLAB - 1, d_last[i][1], 0.0))
+            dg_ref[0, 0, i:i + 1, rows] = _suffix_sums(jnp.broadcast_to(dg_row, (8, _SLAB)))[:1]
+            db_ref[0, 0, i:i + 1, rows] = across[1:2]
+    for i in value_heads:
+        dstate[i] = leaving[i]
+    for sl in range(_SLABS):
+        dq_ref[0, 0, _slab_rows(sl)] = dq[sl].astype(dq_ref.dtype)
+        dk_ref[0, 0, _slab_rows(sl)] = dkey[sl].astype(dk_ref.dtype)
+
+
+
+
+def _specs(bsz, sp, hk, r, dk, dv, rev):
+    """Grid and block specs shared by the two kernels; ``rev`` walks the steps
+    from the last to the first."""
+    block = _SLABS * _SLAB
+    ns = sp // block
+    sl = (lambda c: ns - 1 - c) if rev else (lambda c: c)
+    keys = pl.BlockSpec((1, 1, block, dk), lambda b, j, c: (b, j, sl(c), 0))  # q, k: (B, Hk, S, Dk)
+    values = pl.BlockSpec((1, block, r * dv), lambda b, j, c: (b, sl(c), j))
+    heads = pl.BlockSpec((1, r, block, dv), lambda b, j, c: (b, j, sl(c), 0))  # o, do: (B, Hv, S, Dv)
+    cols = pl.BlockSpec((1, block, hk * r), lambda b, j, c: (b, sl(c), 0))
+    rows = pl.BlockSpec((1, 1, r, block), lambda b, j, c: (b, j, 0, sl(c)))
+    states = pl.BlockSpec((1, 2 * _SLABS, r, dk, dv), lambda b, j, c: (b, sl(c), j, 0, 0))
+    return (bsz, hk, ns), keys, values, heads, cols, rows, states
+
+
+def _sizes(q, v, gr):
+    bsz, hk, sp, dk = q.shape
+    r = gr.shape[2]
+    return bsz, sp, hk, r, dk, v.shape[2] // (hk * r)
+
+
+def _params():
+    return fa._compiler_params(dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+
+# (jitted and inlined: a program traces each kernel once, however many layers call
+# it, and every call site still lowers under its own scope names)
+@functools.partial(jax.jit, static_argnames="keep_states", inline=True)
+def _fwd_call(q, k, v, gc, bc, gr, keep_states):
+    bsz, sp, hk, r, dk, dv = _sizes(q, v, gr)
+    grid, keys, values, heads, cols, rows, states = _specs(bsz, sp, hk, r, dk, dv, rev=False)
+    out_shape, out_specs = [jax.ShapeDtypeStruct((bsz, hk * r, sp, dv), v.dtype)], [heads]
+    if keep_states:
+        out_shape.append(jax.ShapeDtypeStruct((bsz, sp // _CHUNK, hk * r, dk, dv), F32))
+        out_specs.append(states)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, r=r, keep_states=keep_states),
+        grid=grid, in_specs=[keys, keys, values, cols, cols, rows],
+        out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((r, dk, dv), F32)], compiler_params=_params(),
+        interpret=fa._use_interpret(), name="gdn_fwd",
+    )(q, k, v, gc, bc, gr)
+
+
+@functools.partial(jax.jit, inline=True)
+def _bwd_call(q, k, v, gc, bc, gr, do, states):
+    bsz, sp, hk, r, dk, dv = _sizes(q, v, gr)
+    grid, keys, values, heads, cols, rows, st = _specs(bsz, sp, hk, r, dk, dv, rev=True)
+    like = lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype)  # noqa: E731
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, r=r),
+        grid=grid, in_specs=[keys, keys, values, cols, cols, rows, heads, st],
+        out_specs=[keys, keys, values, rows, rows],
+        out_shape=[like(q), like(k), like(v), like(gr), like(gr)],
+        scratch_shapes=[pltpu.VMEM((r, dk, dv), F32)], compiler_params=_params(),
+        interpret=fa._use_interpret(), name="gdn_bwd",
+    )(q, k, v, gc, bc, gr, do, states)
+
+
+def _prepared(g, beta, hk):
+    """The running sums of the log-decays from each chunk's start, a position a
+    sublane and a position a lane; ``beta`` beside the first."""
+    bsz, sp, hv = g.shape
+    gc = jnp.cumsum(g.reshape(bsz, sp // _CHUNK, _CHUNK, hv), axis=2).reshape(bsz, sp, hv)
+    return gc, beta, gc.transpose(0, 2, 1).reshape(bsz, hk, hv // hk, sp)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _delta_core(q, k, v, g, beta, hk):
+    """q, k (B, Hk, S, Dk), v (B, S, Hv x Dv), g and beta (B, S, Hv) float32, S in
+    whole steps -> o (B, Hv, S, Dv)."""
+    return _fwd_call(q, k, v, *_prepared(g, beta, hk), keep_states=False)[0]
+
+
+def _core_fwd(q, k, v, g, beta, hk):
+    o, states = _fwd_call(q, k, v, *_prepared(g, beta, hk), keep_states=True)
+    return o, (q, k, v, g, beta, states)
+
+
+def _core_bwd(hk, res, do):
+    q, k, v, g, beta, states = res
+    dq, dk, dv, dg, db = _bwd_call(q, k, v, *_prepared(g, beta, hk), do, states)
+    bsz, sp, hv = g.shape
+    return dq, dk, dv, *(t.reshape(bsz, hv, sp).transpose(0, 2, 1) for t in (dg, db))
+
+
+_delta_core.defvjp(_core_fwd, _core_bwd)
+
+
+def gated_delta_fused(q, k, v, g, beta, chunk: int = _CHUNK):
+    """`gated_delta_chunked`'s contract through the kernels (`_delta_core`), for
+    sizes inside `scan_path`'s envelope. Outside the kernels only the padding to
+    whole steps, the transpositions of q, k and o (see above), and the running
+    sums and the transpose of the (B, S, Hv) scalars."""
+    if chunk != _CHUNK:
+        raise ValueError(f"the fused delta rule is written for chunks of {_CHUNK}, not {chunk}")
+    b, s, hk, dk = q.shape
+    hv, dv = v.shape[2:]
+    pad = -s % (_SLABS * _SLAB)
+    if pad:
+        q, k, v, g, beta = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+                            for t in (q, k, v, g, beta))
+    sp = s + pad
+    o = _delta_core(q.transpose(0, 2, 1, 3), k.astype(q.dtype).transpose(0, 2, 1, 3),
+                    v.astype(q.dtype).reshape(b, sp, hv * dv), g.astype(F32), beta.astype(F32), hk)
+    return o.transpose(0, 2, 1, 3)[:, :s].astype(v.dtype)
+
+
+def _fused_vmem_mb(r: int, dk: int, dv: int, hv: int, itemsize: int) -> float:
+    """What a backward step holds in VMEM, reckoned from the shapes: the blocks of
+    q, k, dq, dk, of v, do, dv, of the scalars and of the entering states of the
+    step's chunks (two buffers each), the carried gradient, and the float32
+    temporaries of a value head of a slab (a dozen packed blocks, a dozen
+    slab-sized products)."""
+    step = _SLABS * _SLAB
+    lanes = -(-hv // 128) * 128
+    blocks = 2 * (4 * step * dk * itemsize + 3 * step * r * dv * itemsize
+                  + 2 * step * lanes * 4 + 3 * 8 * step * 4 + 2 * _SLABS * r * dk * dv * 4)
+    scratch = r * dk * dv * 4
+    temps = (_SLABS * r * (12 * _CHUNK * _SLAB * 4 + 12 * _SLAB * (dk + dv) * 4)
+             + 2 * _SLAB * _SLAB * 4)
+    return (blocks + scratch + temps) / 2**20
+
+
+def scan_path(hk: int, hv: int, dk: int, dv: int, chunk: int, dtype) -> str:
+    """``"fused"`` or ``"plain"`` for a gated delta rule of these sizes, from the
+    shapes and the backend alone: no flag, no environment variable, no model's
+    name. `models/gdn.block` and `models/gdn.scan_path_counts` both ask here. The
+    fused kernels take, and everything else takes the plain body:
+
+    - a chip (`flash_attention._use_interpret`'s rule, the one switch of this
+      repo's kernels: on the CPU they run interpreted, which only the tests that
+      call `gated_delta_fused` themselves want);
+    - chunks of 64, the size the kernels are written for (two side by side fill
+      the 128 lanes);
+    - ``dk`` and ``dv`` multiples of 128: a head's v is whole lane tiles of the
+      token-major array, and every product's operands whole MXU tiles;
+    - ``hv`` a multiple of ``hk``: a grid step is a key head and its value heads;
+    - bf16 or float32 compute;
+    - a VMEM charge (`_fused_vmem_mb`, 10.5 MB at the published sizes) inside the
+      budget `flash_attention._seq_envelope` reckons with.
+    """
+    dtype = jnp.dtype(dtype)
+    if fa._use_interpret() or hk < 1 or hv % hk or dtype not in (jnp.bfloat16, jnp.float32):
+        return "plain"
+    inside = (chunk == _CHUNK and dk % 128 == 0 and dv % 128 == 0
+              and 1.1 * _fused_vmem_mb(hv // hk, dk, dv, hv, dtype.itemsize) <= fa._VMEM_EFF_MB)
+    return "fused" if inside else "plain"

@@ -36,6 +36,9 @@ KERNEL_NAMES = {
     # the conv + SiLU in front of it as one op (PR 40): read through the `conv` scope;
     # not `ssd_*` (tests/test_topology_aot.py holds that list to the scan's four)
     "ssm_conv_fwd": "ssd.py", "ssm_conv_bwd": "ssd.py",
+    # the fused gated delta rule (PR 48): read through the `gdn/scan` scope
+    # (`gdn_scan_ms_per_step`, `gdn_scan_roofline`)
+    "gdn_fwd": "gated_delta.py", "gdn_bwd": "gated_delta.py",
 }
 
 
@@ -70,7 +73,7 @@ def test_every_pallas_call_has_a_name_from_the_table(name):
     # and nothing outside the table: a new kernel joins it, with its metric
     assert {n for names in found.values() for n in names} == set(KERNEL_NAMES)
     assert all(n.startswith(("flash_fwd", "flash_bwd", "flash_paged", "fused_norm_",
-                             "moe_gmm", "moe_tgmm", "ssd_", "ssm_conv_"))
+                             "moe_gmm", "moe_tgmm", "ssd_", "ssm_conv_", "gdn_"))
                for n in KERNEL_NAMES)
 
 

@@ -386,22 +386,28 @@ def test_granite_mixer_compiles_at_published_widths(one_chip, real_mosaic):
 
 
 def test_qwen3_next_mixer_compiles_at_published_widths(one_chip, real_mosaic):
-    """One Gated DeltaNet mixer of `qwen3-next-80b-a3b_s4096`, forward + backward under
+    """One Gated DeltaNet mixer of `qwen3-next-80b-a3b_s4096`, forward and backward under
     recomputation, as the chip's compiler sees it: 16 key / 32 value heads of 128, 64
     chunks of 64 over 4096 tokens, batch 4. The five scopes reach the compiled ENTRY
     under ``gdn``; the conv + SiLU is the fused op granite's mixer takes (one window of
     8192 channels at column 0 of in_proj's output: ``ssm_conv_fwd`` / ``ssm_conv_bwd``
-    under ``gdn/conv``); the delta rule is plain XLA (no kernel of its own yet: PERF.md
-    §7), its chunks' systems go through a triangular solve. The mixer's backward is the
-    step's largest user of temporaries: 5.35 GiB as autodiff keeps the rule's float32
-    systems, solutions and carried states (the whole step plans 14.94 of 15.75 GiB,
-    PERF.md §6, PR 47); a rule with its own backward would keep a fraction (§7)."""
+    under ``gdn/conv``); the delta rule is its own kernels (PR 48: `ops/gated_delta`'s
+    ``gdn_fwd`` / ``gdn_bwd`` under ``gdn/scan``, the forward once plain and once replayed
+    under autodiff's mark, each call site under its own layer's name): no triangular
+    solve and no loop is left under ``scan``. The mixer's temporaries: 5.35 GiB while
+    autodiff kept the plain rule's float32 systems, solutions and carried states; the
+    kernels keep their five inputs and one entering state a chunk (537 MB), 2.33 GiB
+    planned in all."""
     from galvatron_tpu.models import gdn
     from galvatron_tpu.models.modeling import PRESETS
+    from galvatron_tpu.ops import flash_attention as fa
+    from galvatron_tpu.ops import gated_delta
 
     cfg = PRESETS["qwen3-next-80b-a3b"].replace(mlp_recompute="off")
     assert gdn.gdn_dims(cfg) == (2048, 4096, 8192, 12288) and cfg.gdn_chunk == 64
     assert gdn.conv_path_counts(cfg.replace(num_layers=4)) == {"fused": 3, "plain": 0}
+    assert gdn.scan_path_counts(cfg.replace(num_layers=4)) == {"fused": 3, "plain": 0}
+    assert 1.1 * gated_delta._fused_vmem_mb(2, 128, 128, 32, 2) <= fa._VMEM_EFF_MB
     shapes = jax.eval_shape(lambda k: gdn.init_params(k, cfg), jax.random.key(0))
     p = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), shapes)
     x = jax.ShapeDtypeStruct((4, 4096, 2048), jnp.bfloat16, sharding=one_chip)
@@ -409,24 +415,36 @@ def test_qwen3_next_mixer_compiles_at_published_widths(one_chip, real_mosaic):
     def loss(x_, p_):
         with jax.named_scope("layer_0"):
             y = jax.checkpoint(lambda a, b: gdn.block(a, b, cfg))(x_, p_)
-        return jnp.sum(y.astype(jnp.float32))
+        return jnp.sum(y.astype(jnp.float32) ** 2)  # (not linear: the forward is kept)
 
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, p).compile()
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(x, p).compile()
     text = compiled.as_text()
     temp = compiled.memory_analysis().temp_size_in_bytes
-    assert temp < 5.6 * 2**30, f"{temp / 2**30:.2f} GiB"
+    assert temp < 2.6 * 2**30, f"{temp / 2**30:.2f} GiB"
     import re
 
     ops = set(re.findall(r'op_name="([^"]*)"', text))
     for scope in ("in_proj", "conv", "scan", "gate_norm", "out_proj"):
         mine = [op for op in ops if f"/gdn/{scope}/" in op]
         assert mine and any("transpose(" in op for op in mine), scope
-    assert any("triangular_solve" in op and "/gdn/scan/" in op for op in ops)
+    under_scan = [op for op in ops if "/gdn/scan/" in op]
+    assert not [op for op in under_scan if "triangular_solve" in op or "while" in op]
+    assert not [ln for ln in text.splitlines() if "/gdn/scan/" in ln and " while(" in ln]
     rows = _entry_work(text)
     conv = sorted((n.split(".")[0], op) for n, op in rows if n.startswith("ssm_conv_"))
-    assert [n for n, _ in conv] == ["ssm_conv_bwd", "ssm_conv_fwd"], conv
+    assert [n for n, _ in conv] == ["ssm_conv_bwd", "ssm_conv_fwd", "ssm_conv_fwd"], conv
     assert all("/gdn/conv/" in op for _, op in conv), conv
+    rule = sorted((n.split(".")[0], "transpose(" in op, op) for n, op in rows if n.startswith("gdn_"))
+    assert [(n, t) for n, t, _ in rule] == [
+        ("gdn_bwd", True), ("gdn_fwd", False), ("gdn_fwd", True)], rule
+    assert all("/gdn/scan/" in op and op.endswith("/pallas_call") for _, _, op in rule), rule
     assert not [n for n, _ in rows if n.startswith(("flash_", "ssd_"))]
+    # q, k and o pass between the mixer and the kernels head-major: XLA's transpositions
+    # carry the mixer's scope (token-major blocks cost four 256 MiB float32 copies with
+    # no name a layer, `scope_coverage` 96.2% in the cell: PERF.md §6, PR 48)
+    nameless = [ln.split(" = ")[0].strip() for ln in _entry_lines(text)
+                if re.search(r" = f32\[2048,8,\d+,128\]\S* copy\(", ln) and "op_name=" not in ln]
+    assert not nameless, nameless
 
 
 def test_qwen3_next_attention_compiles_at_head_size_256(one_chip, real_mosaic):
@@ -564,6 +582,27 @@ def test_granite_layers_partition_on_four_chips(topo, real_mosaic):
     # the fused conv (PR 40) under the same wrap: three windows forward, three backward
     for kernel in ("ssm_conv_fwd", "ssm_conv_bwd"):
         assert names.count(kernel) == 3, (kernel, [n for n in names if n.startswith("ssm_")])
+    assert not any(n.startswith("shard_map") for n in names)
+
+
+def test_gated_deltanet_layer_partitions_on_four_chips(topo, real_mosaic):
+    """A Gated DeltaNet layer (the published head sizes, 2 key / 4 value heads), data-parallel
+    over four chips with ZeRO-3: `gdn.block` hands the fused delta rule (PR 48) to
+    `place.shard_kernel` as it hands the conv, and each device runs the kernels on its own
+    batch rows. Forward and backward lower and compile; the kernels keep their names."""
+    from galvatron_tpu.core.strategy import HybridParallelConfig
+    from galvatron_tpu.models.modeling import PRESETS
+
+    cfg = PRESETS["qwen3-next-80b-a3b"].replace(
+        num_layers=1, attn_impl="flash", vocab_size=1024, max_seq_len=512, hidden_size=512,
+        num_heads=4, num_kv_heads=2, gdn_key_heads=2, gdn_value_heads=4, moe_experts=8,
+        moe_top_k=2, moe_ffn_dim=128, moe_shared_ffn_dim=128, moe_share=(0, 1))
+    assert cfg.kinds == ("gdn",)
+    hp = HybridParallelConfig.uniform(1, dp_type="zero3", mixed_precision="bf16")
+    compiled, _ = _compile(cfg, hp, topo.devices, bsz=8, seq=512)
+    names = [n.split(".")[0] for n, _ in _entry_work(compiled.as_text())]
+    for kernel in ("gdn_fwd", "gdn_bwd", "ssm_conv_fwd", "ssm_conv_bwd"):
+        assert names.count(kernel) == 1, (kernel, [n for n in names if n.startswith(("gdn_", "ssm_"))])
     assert not any(n.startswith("shard_map") for n in names)
 
 
