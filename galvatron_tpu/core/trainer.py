@@ -254,9 +254,15 @@ def _train_impl(ns: argparse.Namespace, verbose: bool, tracer,
             from galvatron_tpu.models import gdn
 
             gdn_scan_path, gdn_conv_path = gdn.scan_path_counts(rt.cfg), gdn.conv_path_counts(rt.cfg)
+        # the layers whose held share of the experts does work in proportion to the
+        # pairs it holds, and those that run over the worst-case buffer (models/moe.py)
+        from galvatron_tpu.models.moe import held_path_counts
+
+        moe_held_path = held_path_counts(rt.cfg)
         build_span.set(tp_overlap_seams=rt.tp_overlap_seams, layer_kinds=layer_kinds,
                        ssm_scan_path=ssm_scan_path, ssm_conv_path=ssm_conv_path,
-                       gdn_scan_path=gdn_scan_path, gdn_conv_path=gdn_conv_path)
+                       gdn_scan_path=gdn_scan_path, gdn_conv_path=gdn_conv_path,
+                       moe_held_path=moe_held_path)
 
     from galvatron_tpu.obs import tracing as obs_tracing
     from galvatron_tpu.utils.metrics import SCHEMA_VERSION, MetricsLogger
@@ -299,6 +305,7 @@ def _train_impl(ns: argparse.Namespace, verbose: bool, tracer,
         "ssm_conv_path": ssm_conv_path,
         "gdn_scan_path": gdn_scan_path,
         "gdn_conv_path": gdn_conv_path,
+        "moe_held_path": moe_held_path,
     }
     # JAX's persistent compile cache is always on, at the one place
     # resolve_compile_cache_dir names (JAX_COMPILATION_CACHE_DIR, else an

@@ -1852,7 +1852,7 @@ def moe_loss_sum(params, batch, cfg: ModelConfig, layer_hook=None):
     objective adds: (nll_sum, token_count, aux), aux = {"moe_aux_loss": the
     load-balancing loss L_aux of this batch, "moe_load_max_over_mean": the
     fullest expert's pairs over the even share; of a held share also
-    "moe_held_pairs_per_token"}. The objective that is
+    "moe_held_pairs_per_token" and "moe_held_rows_share"}. The objective that is
     differentiated is nll_sum / count + cfg.moe_aux_coef * L_aux; the loss
     that is logged and evaluated stays the cross entropy."""
     from galvatron_tpu.models import moe
@@ -1871,6 +1871,7 @@ def moe_loss_sum(params, batch, cfg: ModelConfig, layer_hook=None):
         }
         if held:
             aux["moe_held_pairs_per_token"] = moe.held_pairs_per_token(stats, held)
+            aux["moe_held_rows_share"] = moe.held_rows_share(stats)
     return s, n, aux
 
 

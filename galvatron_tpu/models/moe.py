@@ -32,6 +32,7 @@ model (pinned in test_moe.py::test_moe_pipeline_parallel_parity).
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -251,11 +252,14 @@ def sorted_layout(expert_idx: jax.Array, num_experts: int, tile: int) -> SortedL
 def held_layout(expert_idx, held: int, tile: int, first_held: int) -> SortedLayout:
     """`sorted_layout` of a held share: the ``held`` experts from ``first_held``
     on, of those ``expert_idx`` names. The dropped pairs sort behind the held
-    groups as one group more, whose tiles lie past ``num_tiles``: their rows
-    are not valid (the dispatch writes zeros), the grouped GEMMs skip them and
-    write zeros, so a dropped pair adds nothing forward and takes nothing
-    backward. The buffer keeps its worst-case size (every pair held): shapes
-    are static, and no pair that is held is ever dropped."""
+    groups as one group more, whose tiles lie past ``num_tiles``
+    (``pair_row >= num_tiles * tile`` says "not held"): a dropped pair adds
+    nothing forward and takes nothing backward. The buffer keeps its worst-case
+    size (every pair held): shapes are static, and no pair that is held is ever
+    dropped. What lies past ``num_tiles`` depends on who runs over the layout:
+    the plain path (`_dispatch`, `grouped_gemm`, `_combine`) writes zeros there
+    and reads them; `held_experts` never writes those rows, so they are
+    UNDEFINED and every reader masks by index."""
     local = expert_idx.astype(jnp.int32) - first_held
     dropped = (local < 0) | (local >= held)
     full = sorted_layout(jnp.where(dropped, held, local), held + 1, tile)
@@ -326,6 +330,87 @@ def grouped_gemm(lhs, rhs, layout: SortedLayout, tile: int):
     return grouped_matmul(lhs, rhs, layout.tile_group, layout.num_tiles, tile)
 
 
+def held_path_counts(cfg) -> dict:
+    """``{"bounded": n, "worst_case": m}``: the layers of a model that holds a share of
+    its experts whose share does work in proportion to the pairs it holds
+    (`held_experts`), and those that run over the worst-case buffer; asks
+    `ops/moe_held.held_path`, the function `_topk_local` asks. A model that holds
+    every expert counts nothing."""
+    counts = {"bounded": 0, "worst_case": 0}
+    if cfg.moe_dropless and cfg.moe_holds_share:
+        from galvatron_tpu.ops.moe_held import held_path  # (a dense run loads no kernels)
+
+        counts[held_path(cfg.hidden_size, cfg.expert_ffn, cfg.dtype)] = cfg.num_layers
+    return counts
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9,))
+def held_experts(x, weights, w13, w2, pair_row, row_pair, row_valid, tile_group, num_tiles,
+                 tile: int):
+    """Dispatch, the experts' SwiGLU FFN and combine of a held share over
+    `held_layout`, every pass bounded by the rows that hold a pair: x (T, h),
+    weights (T, k) float32, w13 (E, h, 2f) = [w1 | w3], w2 (E, f, h) -> (T, h).
+    What `_dispatch`, three `grouped_gemm` and `_combine` compute, with the
+    permutations and the elementwise passes as kernels that stop at
+    ``num_tiles`` (`ops/moe_held.py`) and one backward written out, so that no
+    gradient is summed by XLA over the whole buffer either."""
+    return _held_forward(x, weights, w13, w2, pair_row, row_pair, row_valid, tile_group,
+                         num_tiles, tile)[0]
+
+
+def _held_forward(x, weights, w13, w2, pair_row, row_pair, row_valid, tile_group, num_tiles, tile):
+    from galvatron_tpu.ops import moe_held
+    from galvatron_tpu.ops.grouped_matmul import held_matmul
+
+    k = weights.shape[1]
+    pairs = pair_row.reshape(weights.shape)
+    with jax.named_scope("dispatch"):
+        tile_rows = jnp.sum(row_valid.reshape(-1, tile), axis=1, dtype=jnp.int32)
+        row_token = row_pair // k
+        rows = moe_held.gather_rows(moe_held.to_slab(x), row_token, tile_rows, num_tiles,
+                                    dtype=x.dtype, tile=tile)
+    with jax.named_scope("experts"):
+        gate_up = held_matmul(rows, w13, tile_group, num_tiles, tile_m=tile)
+        mid = moe_held.swiglu(gate_up, num_tiles, tile=tile)
+        out = held_matmul(mid, w2, tile_group, num_tiles, tile_m=tile, slab_out=True)
+    with jax.named_scope("combine"):
+        index = moe_held.pairs_index(pairs, num_tiles, tile=tile)
+        y = moe_held.gather_pairs(out, pairs, num_tiles, weights, index, dtype=x.dtype, tile=tile)
+    return y, (weights, w13, w2, rows, gate_up, mid, out, pairs, index, row_pair, row_valid,
+               row_token, tile_rows, tile_group, num_tiles)
+
+
+def _held_backward(tile, res, g):
+    from galvatron_tpu.ops import moe_held
+    from galvatron_tpu.ops.grouped_matmul import held_matmul, weight_grad
+
+    (weights, w13, w2, rows, gate_up, mid, out, pairs, index, row_pair, row_valid, row_token,
+     tile_rows, tile_group, num_tiles) = res
+    dtype, experts = g.dtype, w2.shape[0]
+    with jax.named_scope("combine"):
+        dweights = moe_held.gather_pairs(out, pairs, num_tiles, weights, index, dtype=dtype,
+                                         tile=tile, other=g)
+        w_row = jnp.where(row_valid, weights.reshape(-1)[row_pair], 0.0)
+        dout = moe_held.gather_rows(moe_held.to_slab(g), row_token, tile_rows, num_tiles,
+                                    dtype=dtype, tile=tile, scale=w_row)
+    with jax.named_scope("experts"):
+        dmid = held_matmul(dout, w2, tile_group, num_tiles, tile_m=tile, transpose_rhs=True)
+        dw2 = weight_grad(mid, dout, tile_group, num_tiles, experts, tile_m=tile,
+                          out_dtype=w2.dtype)
+        dgate_up = moe_held.swiglu_bwd(gate_up, dmid, num_tiles, tile=tile)
+        drows = held_matmul(dgate_up, w13, tile_group, num_tiles, tile_m=tile,
+                            transpose_rhs=True, slab_out=True)
+        dw13 = weight_grad(rows, dgate_up, tile_group, num_tiles, experts, tile_m=tile,
+                           out_dtype=w13.dtype)
+    with jax.named_scope("dispatch"):
+        dx = moe_held.gather_pairs(drows, pairs, num_tiles, jnp.ones_like(weights), index,
+                                   dtype=dtype, tile=tile)
+    return dx, dweights, dw13, dw2, None, None, None, None, None
+
+
+held_experts.defvjp(_held_forward, _held_backward)
+
+
 def router_stats(probs: jax.Array, sizes: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """What the load-balancing loss needs of one layer, both (E,) fp32:
     f_e = pairs of expert e over tokens (= sum_j f_{j,e}; not differentiated)
@@ -360,6 +445,13 @@ def held_pairs_per_token(stats, held: Tuple[int, int]) -> jax.Array:
     return jnp.mean(jnp.sum(f[:, held[0]:held[0] + held[1]], axis=1))
 
 
+def held_rows_share(stats) -> jax.Array:
+    """Rows of the worst-case buffer whose tiles are in use (``num_tiles * tile``
+    over the buffer's rows), mean over the layers: of a held share's statistics
+    the third. About ``held / E`` plus the tiles' padding when the load is even."""
+    return jnp.mean(jnp.stack([s[2] for s in stats]))
+
+
 def moe_topk_block(x: jax.Array, p: Params, cfg, tile: Optional[int] = None,
                    place: Placement = LOCAL):
     """Dropless top-k MoE MLP on (B, S, H) -> (y, router_stats).
@@ -378,6 +470,7 @@ def _topk_local(x, p, cfg, tile, over):
     router's input, GEMM and softmax are fp32 whatever the compute dtype: a
     bf16 logit flips a choice wherever two probabilities lie within a bf16 ulp."""
     from galvatron_tpu.ops.grouped_matmul import TILE_M
+    from galvatron_tpu.ops.moe_held import held_path
 
     tile = tile or TILE_M
     b, s, h = x.shape
@@ -395,14 +488,23 @@ def _topk_local(x, p, cfg, tile, over):
             layout = held_layout(idx, cfg.moe_held, tile, cfg.moe_first_held)
         else:
             layout = sorted_layout(idx, e, tile)
-        rows = _dispatch(xt, layout.row_pair // k, layout.row_valid, layout.pair_row)
-    with jax.named_scope("experts"):
-        w1, w3, w2 = (p[n].astype(x.dtype) for n in ("w1", "w3", "w2"))
-        gate = grouped_gemm(rows, w1, layout, tile)
-        up = grouped_gemm(rows, w3, layout, tile)
-        out = grouped_gemm(jax.nn.silu(gate) * up, w2, layout, tile)
-    with jax.named_scope("combine"):
-        y = _combine(out, weights, layout.pair_row, layout.row_pair, layout.row_valid)
+    if held_share and held_path(h, cfg.expert_ffn, x.dtype) == "bounded":
+        # a share of the experts pays for the pairs it holds (trace-time, by shape)
+        with jax.named_scope("experts"):
+            w13 = jnp.concatenate([p["w1"], p["w3"]], axis=-1).astype(x.dtype)
+            w2 = p["w2"].astype(x.dtype)
+        y = held_experts(xt, weights, w13, w2, layout.pair_row, layout.row_pair,
+                         layout.row_valid, layout.tile_group, layout.num_tiles, tile)
+    else:
+        with jax.named_scope("dispatch"):
+            rows = _dispatch(xt, layout.row_pair // k, layout.row_valid, layout.pair_row)
+        with jax.named_scope("experts"):
+            w1, w3, w2 = (p[n].astype(x.dtype) for n in ("w1", "w3", "w2"))
+            gate = grouped_gemm(rows, w1, layout, tile)
+            up = grouped_gemm(rows, w3, layout, tile)
+            out = grouped_gemm(jax.nn.silu(gate) * up, w2, layout, tile)
+        with jax.named_scope("combine"):
+            y = _combine(out, weights, layout.pair_row, layout.row_pair, layout.row_valid)
     if cfg.moe_shared_ffn_dim:
         with jax.named_scope("shared_expert"):
             y = y + _shared_expert(xt, p["shared"])
@@ -410,6 +512,10 @@ def _topk_local(x, p, cfg, tile, over):
     sizes = jnp.bincount(idx.reshape(-1), length=e).astype(jnp.int32) if held_share \
         else layout.sizes
     stats = router_stats(probs, sizes)
+    if held_share:
+        # the share of the worst-case buffer's rows whose tiles are in use: what is
+        # left of the work where the path is bounded
+        stats += (layout.num_tiles[0].astype(jnp.float32) * tile / layout.row_valid.shape[0],)
     if over:
         stats = tuple(jax.lax.pmean(s_, over) for s_ in stats)
     return y.reshape(b, s, h), stats

@@ -17,7 +17,11 @@ most ``tile_m`` rows a group, ``tile_m / 2`` on average.
   output block is written.
 
 Row tiles past ``num_tiles`` (the static row count is an upper bound) are
-skipped: ``moe_gmm`` writes zeros there, ``moe_tgmm`` leaves them out.  The
+skipped: ``moe_gmm`` writes zeros there, ``moe_tgmm`` leaves them out.  For a
+held share of the experts (`held_matmul`; `models/moe.held_experts`) the
+contract is weaker on purpose: a skipped tile writes NOTHING, the rows past
+``num_tiles`` are undefined, and every reader masks them by index
+(`ops/moe_held.py`): zero-filling 150,000 rows that hold no pair was the cost.  The
 contraction is not tiled: at the widths this serves (hidden 2048, expert
 width 1024) a whole ``(tile_m, K)`` by ``(K, tile_n)`` product fits the
 scoped VMEM, and a weight block is then fetched once a group and not once a
@@ -33,6 +37,7 @@ from __future__ import annotations
 import functools
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -61,37 +66,79 @@ def _tile(n: int, want: int) -> int:
     return n
 
 
+@functools.lru_cache(maxsize=None)
+def _traced(fn, avals, static, interpret):
+    del interpret  # a key: the kernels bind it while they are traced
+    return jax.make_jaxpr(functools.partial(fn, **dict(static)))(*avals)
+
+
+def traced_once(fn, *args, **static):
+    """``fn(*args, **static)`` (one array out), with ``fn`` traced once a signature
+    and its jaxpr evaluated at every other call site. A step program calls each
+    kernel of a held share's path 4 layers x (forward, replay, backward) times; a
+    `pl.pallas_call` traces its kernel body anew at each, which the set-up of every
+    run pays, warm cache or not (PERF.md §6, PR 49). The caller's name stack is
+    kept: `eval_jaxpr` puts it in front of the equations' own."""
+    avals = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args)
+    closed = _traced(fn, avals, tuple(sorted(static.items())), _use_interpret())
+    return jax.extend.core.jaxpr_as_fun(closed)(*args)[0]
+
+
 def _params(*semantics):
     return pltpu.CompilerParams(dimension_semantics=semantics, vmem_limit_bytes=_VMEM_LIMIT)
 
 
-def _gmm(lhs, rhs, tile_group, num_tiles, *, transpose_rhs: bool, tile_m: int, tile_n: int):
+def _gmm(lhs, rhs, tile_group, num_tiles, *, transpose_rhs: bool, tile_m: int, tile_n: int,
+         bounded: bool = False, slab_out: bool = False):
+    """``bounded`` (a held share, `models/moe.held_experts`; fixed at trace time):
+    a skipped tile writes nothing, so rows past ``num_tiles`` come out UNDEFINED and
+    a skipped grid step costs a step, not a block of zeros.  ``slab_out`` (bounded
+    only): the output is the slab ``(M, N / 128, 128)`` of `ops/moe_held.py`, whose
+    rows a later kernel fetches one DMA each; the whole width is one block."""
+    from galvatron_tpu.ops import moe_held
+
     m, k = lhs.shape
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
-    tn = _tile(n, tile_n)
+    tn = n if slab_out else _tile(n, tile_n)
     dims = (((1,), (1,)), ((), ())) if transpose_rhs else (((1,), (0,)), ((), ()))
+    per, lanes = moe_held.per_word(lhs.dtype), moe_held.LANES
+    chunks = n // (per * lanes)  # of a slab row
 
     def kernel(group_ref, count_ref, lhs_ref, rhs_ref, out_ref):
         del group_ref
 
         @pl.when(pl.program_id(1) < count_ref[0])
         def _():
-            out_ref[...] = jax.lax.dot_general(
-                lhs_ref[...], rhs_ref[...], dims,
-                preferred_element_type=jnp.float32).astype(out_ref.dtype)
+            acc = jax.lax.dot_general(
+                lhs_ref[...], rhs_ref[...], dims, preferred_element_type=jnp.float32)
+            if not slab_out:
+                out_ref[...] = acc.astype(out_ref.dtype)
+                return
+            for c in range(chunks):
+                at = c * per * lanes
+                out_ref[:, c, :] = moe_held.f32_to_words(
+                    [acc[:, at + p * lanes:at + (p + 1) * lanes] for p in range(per)], lhs.dtype)
 
-        @pl.when(pl.program_id(1) >= count_ref[0])
-        def _():
-            out_ref[...] = jnp.zeros_like(out_ref)
+        if not bounded:
+            @pl.when(pl.program_id(1) >= count_ref[0])
+            def _():
+                out_ref[...] = jnp.zeros_like(out_ref)
 
     # a skipped tile names the last used tile's blocks, so nothing is fetched for it
     def used(i, count):
         return jnp.minimum(i, count[0] - 1)
 
     rhs_block = (None, tn, k) if transpose_rhs else (None, k, tn)
+    if slab_out:
+        out_shape = jax.ShapeDtypeStruct((m, chunks, lanes), moe_held.slab_dtype(lhs.dtype))
+        out_spec = pl.BlockSpec((tile_m, chunks, lanes), lambda j, i, g, c: (used(i, c), 0, 0))
+    else:
+        out_shape = jax.ShapeDtypeStruct((m, n), lhs.dtype)
+        out_spec = pl.BlockSpec((tile_m, tn), (lambda j, i, g, c: (used(i, c), j))
+                                if bounded else (lambda j, i, g, c: (i, j)))
     return pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
+        out_shape=out_shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(n // tn, m // tile_m),
@@ -100,7 +147,7 @@ def _gmm(lhs, rhs, tile_group, num_tiles, *, transpose_rhs: bool, tile_m: int, t
                 pl.BlockSpec(rhs_block, (lambda j, i, g, c: (g[used(i, c)], j, 0))
                              if transpose_rhs else (lambda j, i, g, c: (g[used(i, c)], 0, j))),
             ],
-            out_specs=pl.BlockSpec((tile_m, tn), lambda j, i, g, c: (i, j)),
+            out_specs=out_spec,
         ),
         compiler_params=_params("parallel", "arbitrary"),
         interpret=_use_interpret(),
@@ -184,3 +231,21 @@ def _bwd(tile_m, tile_n, res, grad):
 
 
 grouped_matmul.defvjp(_fwd, _bwd)
+
+
+def held_matmul(lhs, rhs, tile_group, num_tiles, *, tile_m: int = TILE_M,
+                transpose_rhs: bool = False, slab_out: bool = False):
+    """`grouped_matmul`'s product (or, ``transpose_rhs``, its left gradient) for a
+    held share of the experts: tiles past ``num_tiles`` are not written, their rows
+    UNDEFINED; ``slab_out``: see `_gmm`.  No VJP of its own: `models/moe.held_experts`
+    writes the backward out."""
+    return traced_once(_gmm, lhs, rhs, tile_group, num_tiles, transpose_rhs=transpose_rhs,
+                       tile_m=tile_m, tile_n=1024, bounded=True, slab_out=slab_out)
+
+
+def weight_grad(lhs, grad, tile_group, num_tiles, num_groups: int, *, tile_m: int = TILE_M,
+                out_dtype):
+    """``out[g] = lhs[rows of g].T @ grad[rows of g]`` (`moe_tgmm`), which reads the
+    tiles before ``num_tiles`` alone."""
+    return traced_once(_tgmm, lhs, grad, tile_group, num_tiles, num_groups=num_groups,
+                       tile_m=tile_m, tile_n=1024, out_dtype=out_dtype)

@@ -58,12 +58,14 @@ from galvatron_tpu.parallel.sharding import (
 #: what a dropless top-k MoE model's train state carries of its last step
 #: (``state["moe_stats"]``), logged in the trainer's ``train_iter`` record
 MOE_STATS = ("moe_aux_loss", "moe_load_max_over_mean")
-#: one more where the model holds a share of its experts (``cfg.moe_share``)
-MOE_HELD_STAT = "moe_held_pairs_per_token"
+#: two more where the model holds a share of its experts (``cfg.moe_share``): the
+#: pairs a token puts on the held experts, and the share of the worst-case row
+#: buffer whose tiles are in use (the work left where the held path is bounded)
+MOE_HELD_STATS = ("moe_held_pairs_per_token", "moe_held_rows_share")
 
 
 def moe_stat_names(cfg) -> tuple:
-    return MOE_STATS + ((MOE_HELD_STAT,) if cfg.moe_holds_share else ())
+    return MOE_STATS + (MOE_HELD_STATS if cfg.moe_holds_share else ())
 
 
 #: recurrent layer kind -> the words of its refusals (build_runtime): the layers,
@@ -514,19 +516,19 @@ def build_runtime(
                    jnp.maximum(acc_load, aux["moe_load_max_over_mean"]),
                    jax.tree.map(jnp.add, acc_g, g))
             if held:  # weighted like the auxiliary loss
-                out += (acc[5] + aux[MOE_HELD_STAT] * n,)
+                out += tuple(a + aux[name] * n for a, name in zip(acc[5:], MOE_HELD_STATS))
             return out, None
 
         zero = (jnp.zeros((), jnp.float32),) * 4 + (
             jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params),)
         if held:
-            zero += (jnp.zeros((), jnp.float32),)
+            zero += (jnp.zeros((), jnp.float32),) * len(MOE_HELD_STATS)
         with jax.named_scope("grad_accum"):
             (tot_s, tot_n, tot_aux, load, tot_g, *tot_held), _ = jax.lax.scan(body, zero, mbs)
         denom = jnp.maximum(tot_n, 1.0)
         aux = {"moe_aux_loss": tot_aux / denom, "moe_load_max_over_mean": load}
         if held:
-            aux[MOE_HELD_STAT] = tot_held[0] / denom
+            aux.update({name: tot / denom for name, tot in zip(MOE_HELD_STATS, tot_held)})
         return tot_s / denom, jax.tree.map(lambda g: g / denom, tot_g), aux
 
     def grads_fn(params, batch, scale=None):

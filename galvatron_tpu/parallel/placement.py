@@ -188,7 +188,7 @@ class LayerPlacement(Placement):
             am = ambient_or(self.mesh)
             return jax.shard_map(
                 lambda x_, p_: local_fn(x_, p_, over),
-                mesh=am, in_specs=(spec, P()), out_specs=(spec, (P(), P())),
+                mesh=am, in_specs=(spec, P()), out_specs=(spec, P()),  # P(): every statistic
                 axis_names=manual_axis_names(am), check_vma=False,
             )(x, p)
 

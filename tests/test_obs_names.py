@@ -39,6 +39,10 @@ KERNEL_NAMES = {
     # the fused gated delta rule (PR 48): read through the `gdn/scan` scope
     # (`gdn_scan_ms_per_step`, `gdn_scan_roofline`)
     "gdn_fwd": "gated_delta.py", "gdn_bwd": "gated_delta.py",
+    # a held share's permutations and SwiGLU, bounded by the pairs held (PR 49): read
+    # through the `mlp/dispatch`, `mlp/experts`, `mlp/combine` scopes they run under
+    "moe_held_rows": "moe_held.py", "moe_held_pairs": "moe_held.py",
+    "moe_held_swiglu": "moe_held.py", "moe_held_swiglu_bwd": "moe_held.py",
 }
 
 
@@ -73,7 +77,7 @@ def test_every_pallas_call_has_a_name_from_the_table(name):
     # and nothing outside the table: a new kernel joins it, with its metric
     assert {n for names in found.values() for n in names} == set(KERNEL_NAMES)
     assert all(n.startswith(("flash_fwd", "flash_bwd", "flash_paged", "fused_norm_",
-                             "moe_gmm", "moe_tgmm", "ssd_", "ssm_conv_", "gdn_"))
+                             "moe_gmm", "moe_tgmm", "moe_held_", "ssd_", "ssm_conv_", "gdn_"))
                for n in KERNEL_NAMES)
 
 
@@ -593,6 +597,9 @@ def test_build_runtime_span_counts_the_conv_path_beside_the_scan_path(traced_run
     # and the same two for Gated DeltaNet layers (PR 47), behind them: none here
     assert span["args"]["gdn_scan_path"] == span["args"]["gdn_conv_path"] == {"fused": 0, "plain": 0}
     assert list(span["args"]).index("gdn_scan_path") == list(span["args"]).index("ssm_conv_path") + 1
+    # and the path a held share of the experts takes (PR 49), behind those: no share here
+    assert span["args"]["moe_held_path"] == {"bounded": 0, "worst_case": 0}
+    assert list(span["args"]).index("moe_held_path") == list(span["args"]).index("gdn_conv_path") + 1
 
 
 def test_traced_train_logs_the_profile_window(traced_run):
@@ -625,6 +632,42 @@ def test_train_iter_records_of_a_topk_moe_run_carry_aux_loss_and_load(tmp_path):
     for r in iters:
         assert 0.5 < r["moe_aux_loss"] < 64 and r["moe_load_max_over_mean"] >= 1.0
         assert abs(r["loss"] - np.log(128)) < 1.0  # the cross entropy, no auxiliary term in it
+
+
+@pytest.mark.parametrize("hidden,width,path", [(128, 128, "bounded"), (64, 32, "worst_case")])
+def test_a_held_share_names_its_path_and_its_rows_share(tmp_path, monkeypatch, hidden, width, path):
+    """``moe_held_path`` on the ``build_runtime`` span and in the checkpoint's
+    fingerprint, ``moe_held_rows_share`` beside ``moe_held_pairs_per_token`` in every
+    ``train_iter`` record of a model that holds a share of its experts (PR 49)."""
+    from galvatron_tpu.core.arguments import initialize_galvatron
+    from galvatron_tpu.core.checkpoint import latest_step, read_manifest, step_path
+    from galvatron_tpu.core.trainer import train
+    from galvatron_tpu.models.modeling import PRESETS
+
+    # (the head, DeltaNet and expert sizes have no flag: the test narrows the preset)
+    monkeypatch.setitem(PRESETS, "qwen3-next-80b-a3b", PRESETS["qwen3-next-80b-a3b"].replace(
+        attn_head_dim=16, gdn_key_heads=2, gdn_value_heads=4, gdn_key_dim=16, gdn_value_dim=16,
+        moe_top_k=4, moe_ffn_dim=width, moe_shared_ffn_dim=width))
+    mpath, spans, ckpt = (str(tmp_path / n) for n in ("m.jsonl", "spans.json", "ckpt"))
+    train(initialize_galvatron("train", [
+        "--model_size", "qwen3-next-80b-a3b", "--moe_share", "1/4", "--num_layers", "1",
+        "--hidden_size", str(hidden), "--num_heads", "2", "--moe_experts", "16",
+        "--vocab_size", "128", "--seq_length", "32", "--global_train_batch_size", "8",
+        "--mixed_precision", "fp32", "--train_iters", "2", "--metrics_path", mpath,
+        "--trace_spans", spans, "--save", ckpt]), verbose=False)
+    want = {"bounded": 0, "worst_case": 0, path: 1}
+    (span,) = [e for e in json.load(open(spans))["traceEvents"]
+               if e["ph"] == "X" and e["name"] == "build_runtime"]
+    assert span["args"]["moe_held_path"] == want
+    manifest = read_manifest(step_path(ckpt, latest_step(ckpt)))
+    assert manifest["meta"]["fingerprint"]["moe_held_path"] == want
+    iters = [r for r in map(json.loads, open(mpath)) if r["event"] == "train_iter"]
+    assert len(iters) == 2
+    for r in iters:
+        assert 0.0 < r["moe_held_pairs_per_token"] < 4.0
+        # a device's 32 tokens x 4 pairs fill one tile of 256 rows, the 5 groups own one
+        # each, and the 4 held experts' tiles are in use whatever they hold
+        assert 0.66 < r["moe_held_rows_share"] <= 1.0
 
 
 def test_train_iter_records_lost_the_derived_wait(traced_run):

@@ -181,10 +181,13 @@ def test_logits_loss_and_aux_loss_match_the_reference_in_float32():
     close(aux["moe_aux_loss"], aux_ref, F32_TOL)  # over all 16 experts, held or not
     assert len(stats) == 4 and stats[0][0].shape == (16,)
     # a token's k pairs fall on all the experts; the held four get their share
-    assert float(sum(jnp.sum(f) for f, _ in stats)) == pytest.approx(4 * cfg.moe_top_k, rel=1e-6)
-    held = float(np.mean([np.sum(np.asarray(f)[4:8]) for f, _ in stats]))
+    assert float(sum(jnp.sum(s_[0]) for s_ in stats)) == pytest.approx(4 * cfg.moe_top_k, rel=1e-6)
+    held = float(np.mean([np.sum(np.asarray(s_[0])[4:8]) for s_ in stats]))
     assert float(aux["moe_held_pairs_per_token"]) == pytest.approx(held, rel=1e-6)
     assert 0.2 < held < 3.0
+    # a held share hands up a third statistic: the share of the buffer's tiles in use
+    assert float(aux["moe_held_rows_share"]) == pytest.approx(
+        np.mean([float(s_[2]) for s_ in stats]), rel=1e-6)
 
 
 def test_every_gradient_matches_the_reference_in_float32():
@@ -400,6 +403,213 @@ def test_held_share_must_divide_the_experts():
         moe.init_moe_params(jax.random.key(0), small_cfg(moe_share=(4, 4)))
 
 
+# -- the bounded held path: work in proportion to the pairs held ---------------------
+# `moe.held_experts` (kernels of ops/moe_held.py, interpreted here) against the plain
+# path over the whole buffer (`_dispatch`, three `grouped_gemm`, `_combine`), which
+# stays in the program for the shapes outside the kernels' envelope and is the
+# reference: float32, the output and every gradient, over loads that hit the edges.
+
+HELD_T, HELD_K, HELD_E, HELD_N, HELD_FIRST, HELD_H, HELD_F, HELD_TILE = 64, 4, 16, 4, 4, 128, 128, 16
+HELD_NAMES = ("y", "dx", "dweights", "dw1", "dw3", "dw2")
+
+
+def _held_choices(load, seed=0):
+    """(T, k) expert choices; experts HELD_FIRST .. HELD_FIRST + 3 are held."""
+    ks = jax.random.split(jax.random.key(seed), 3)
+    inside = jax.random.randint(ks[0], (HELD_T, HELD_K), HELD_FIRST, HELD_FIRST + HELD_N)
+    outside = jax.random.randint(ks[1], (HELD_T, HELD_K), HELD_FIRST + HELD_N, HELD_E)
+    if load == "none_held":
+        return outside
+    if load == "every_pair_held":  # the buffer is as full as it gets
+        return inside
+    if load == "one_expert_every_pair_of_its_tokens":
+        rows = jnp.arange(HELD_T)[:, None] % 3 == 0
+        return jnp.where(rows, HELD_FIRST + 2, outside)
+    if load == "an_expert_with_exactly_a_tile":
+        flat = outside.reshape(-1).at[jnp.arange(HELD_TILE) * 5].set(HELD_FIRST + 1)
+        return flat.reshape(HELD_T, HELD_K)
+    assert load == "a_sixteenth"
+    return jnp.where(jax.random.uniform(ks[2], (HELD_T, HELD_K)) < 1 / 16, inside, outside)
+
+
+def _held_operands(dtype=jnp.float32, seed=0):
+    ks = jax.random.split(jax.random.key(100 + seed), 6)
+    hidden = HELD_H * (2 if dtype == jnp.bfloat16 else 1)  # a bf16 slab chunk is 256 columns
+    x = jax.random.normal(ks[0], (HELD_T, hidden), dtype)
+    weights = jax.nn.softmax(jax.random.normal(ks[1], (HELD_T, HELD_K)), axis=-1)
+    w1, w3 = (jax.random.normal(k, (HELD_N, hidden, HELD_F), dtype) * 0.09 for k in ks[2:4])
+    w2 = jax.random.normal(ks[4], (HELD_N, HELD_F, hidden), dtype) * 0.09
+    cot = jax.random.normal(ks[5], (HELD_T, hidden), jnp.float32)
+    return (x, weights, w1, w3, w2), cot
+
+
+# the same sums in another order (a kernel's float32 accumulator a column block, XLA's
+# reduction tree): two float32 ulps of the largest element
+HELD_TOL = 2e-6
+
+
+def _held_plain(x, weights, w1, w3, w2, idx):
+    lay = moe.held_layout(idx, HELD_N, HELD_TILE, HELD_FIRST)
+    rows = moe._dispatch(x, lay.row_pair // HELD_K, lay.row_valid, lay.pair_row)
+    gate = moe.grouped_gemm(rows, w1, lay, HELD_TILE)
+    up = moe.grouped_gemm(rows, w3, lay, HELD_TILE)
+    out = moe.grouped_gemm(jax.nn.silu(gate) * up, w2, lay, HELD_TILE)
+    return moe._combine(out, weights, lay.pair_row, lay.row_pair, lay.row_valid)
+
+
+def _held_bounded(x, weights, w1, w3, w2, idx):
+    lay = moe.held_layout(idx, HELD_N, HELD_TILE, HELD_FIRST)
+    return moe.held_experts(x, weights, jnp.concatenate([w1, w3], axis=-1), w2, lay.pair_row,
+                            lay.row_pair, lay.row_valid, lay.tile_group, lay.num_tiles, HELD_TILE)
+
+
+def _output_and_gradients(body, operands, cot, idx):
+    y, vjp = jax.vjp(lambda *t: body(*t, idx), *operands)
+    return (y,) + vjp(cot.astype(y.dtype))
+
+
+@pytest.mark.parametrize("load", ["none_held", "one_expert_every_pair_of_its_tokens",
+                                  "every_pair_held", "an_expert_with_exactly_a_tile",
+                                  "a_sixteenth"])
+def test_bounded_held_path_is_the_plain_path_in_float32(load):
+    idx = _held_choices(load)
+    lay = moe.held_layout(idx, HELD_N, HELD_TILE, HELD_FIRST)
+    held = int(((idx >= HELD_FIRST) & (idx < HELD_FIRST + HELD_N)).sum())
+    assert int(lay.row_valid.sum()) == held  # the load is the one its name says
+    if load == "none_held":
+        assert held == 0 and int(lay.num_tiles[0]) == HELD_N  # a padding tile an expert
+    if load == "every_pair_held":
+        assert held == HELD_T * HELD_K
+    if load == "an_expert_with_exactly_a_tile":
+        assert list(np.asarray(lay.sizes)) == [0, HELD_TILE, 0, 0]
+    operands, cot = _held_operands()
+    want = _output_and_gradients(_held_plain, operands, cot, idx)
+    got = _output_and_gradients(_held_bounded, operands, cot, idx)
+    for name, g, w in zip(HELD_NAMES, got, want):
+        assert np.isfinite(np.asarray(g)).all(), name
+        if float(jnp.abs(w).max()) == 0.0:  # nothing held: exact zeros, not small numbers
+            assert float(jnp.abs(g).max()) == 0.0, name
+        else:
+            close(g, w, HELD_TOL)
+
+
+def _poisoned(fn, tile_arg):
+    """``fn`` with every row past ``num_tiles`` of what it returns set to NaN (in a
+    bf16 slab: both halves of every word): what "undefined" may hold."""
+    def wrapped(*args, **kw):
+        out = fn(*args, **kw)
+        num_tiles = args[tile_arg] if isinstance(tile_arg, int) else kw[tile_arg]
+        tile = kw.get("tile", kw.get("tile_m"))
+        past = jnp.arange(out.shape[0]) >= num_tiles[0] * tile
+        past = past.reshape((-1,) + (1,) * (out.ndim - 1))
+        bad = jnp.uint32(0x7FC07FC0) if out.dtype == jnp.uint32 else jnp.asarray(jnp.nan, out.dtype)
+        return jnp.where(past, bad, out)
+    return wrapped
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bf16"])
+def test_rows_past_num_tiles_may_hold_anything(monkeypatch, dtype):
+    """The poison case: every buffer of the bounded path gets NaN into the rows past
+    ``num_tiles`` before its consumer runs; the output and every gradient are finite
+    and the ones they were."""
+    from galvatron_tpu.ops import grouped_matmul, moe_held
+
+    idx = _held_choices("a_sixteenth", seed=1)
+    operands, cot = _held_operands(dtype, seed=1)
+    want = _output_and_gradients(_held_bounded, operands, cot, idx)
+    monkeypatch.setattr(moe_held, "gather_rows", _poisoned(moe_held.gather_rows, 3))
+    monkeypatch.setattr(moe_held, "swiglu", _poisoned(moe_held.swiglu, 1))
+    monkeypatch.setattr(moe_held, "swiglu_bwd", _poisoned(moe_held.swiglu_bwd, 2))
+    monkeypatch.setattr(grouped_matmul, "held_matmul", _poisoned(grouped_matmul.held_matmul, 3))
+    got = _output_and_gradients(_held_bounded, operands, cot, idx)
+    for name, g, w in zip(HELD_NAMES, got, want):
+        assert np.isfinite(np.asarray(g, np.float32)).all(), name
+        np.testing.assert_array_equal(np.asarray(g, np.float32), np.asarray(w, np.float32), name)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bf16"])
+def test_a_slab_is_its_rows(dtype):
+    from galvatron_tpu.ops import moe_held
+
+    x = jax.random.normal(jax.random.key(0), (24, 512), dtype)
+    slab = moe_held.to_slab(x)
+    assert slab.shape == (24, 512 // (128 * moe_held.per_word(dtype)), 128)
+    assert slab.dtype == moe_held.slab_dtype(dtype)
+    np.testing.assert_array_equal(np.asarray(moe_held.from_slab(slab, dtype), np.float32),
+                                  np.asarray(x, np.float32))
+    # the words' halves in a kernel's arithmetic: column blocks of 128, low half first
+    pieces = moe_held.words_to_f32(slab[:, 1, :])
+    at = 128 * moe_held.per_word(dtype)
+    for part, piece in enumerate(pieces):
+        np.testing.assert_array_equal(
+            np.asarray(piece), np.asarray(x[:, at + part * 128:at + (part + 1) * 128], np.float32))
+    np.testing.assert_array_equal(np.asarray(moe_held.f32_to_words(pieces, dtype)),
+                                  np.asarray(slab[:, 1, :]))
+
+
+@pytest.mark.parametrize("hidden,width,dtype,path", [
+    (2048, 512, jnp.bfloat16, "bounded"),  # the published sizes
+    (128, 128, jnp.float32, "bounded"),
+    (256, 128, jnp.bfloat16, "bounded"),
+    (128, 128, jnp.bfloat16, "worst_case"),  # half a slab chunk in bf16
+    (32, 24, jnp.float32, "worst_case"),  # `small_cfg`
+    (2048, 512, jnp.float16, "worst_case"),
+    (2048, 96, jnp.bfloat16, "worst_case"),
+])
+def test_held_path_is_chosen_by_shape(hidden, width, dtype, path):
+    from galvatron_tpu.ops import moe_held
+
+    assert moe_held.held_path(hidden, width, dtype) == path
+    cfg = small_cfg(hidden_size=hidden, moe_ffn_dim=width, dtype=dtype)
+    other = "worst_case" if path == "bounded" else "bounded"
+    assert moe.held_path_counts(cfg) == {path: cfg.num_layers, other: 0}
+    assert moe.held_path_counts(cfg.replace(moe_share=(0, 1))) == {"bounded": 0, "worst_case": 0}
+
+
+def test_the_block_takes_the_bounded_path_and_keeps_its_gradients(monkeypatch):
+    """`moe_topk_block` at a shape inside the envelope: the layer against the
+    reference's, and the output, the statistics and every gradient (the router's
+    among them) against the same block held to the plain path."""
+    from galvatron_tpu.ops import moe_held
+
+    cfg = small_cfg(hidden_size=128, moe_ffn_dim=128, moe_shared_ffn_dim=128)
+    assert moe.held_path_counts(cfg)["bounded"] == cfg.num_layers
+    p = moe.init_moe_params(jax.random.key(0), cfg)
+    x = jax.random.normal(jax.random.key(1), (2, 32, cfg.hidden_size))
+    cot = jax.random.normal(jax.random.key(2), x.shape)
+    calls = []
+    real = moe.held_experts
+    monkeypatch.setattr(moe, "held_experts", lambda *a: calls.append(1) or real(*a))
+
+    def run():
+        (y, stats), vjp = jax.vjp(lambda x_, p_: moe.moe_topk_block(x_, p_, cfg, tile=8), x, p)
+        return y, stats, vjp((cot, jax.tree.map(jnp.zeros_like, stats)))
+
+    y, stats, (dx, dp) = run()
+    assert calls and len(stats) == 3
+    tiles = int(moe.held_layout(jax.lax.top_k(jax.nn.softmax(
+        x.reshape(-1, 128) @ p["router"]["w"]), cfg.moe_top_k)[1], 4, 8, 4).num_tiles[0])
+    assert float(stats[2]) == pytest.approx(tiles * 8 / (64 * cfg.moe_top_k + 5 * 8))
+    fs = cfg.moe_shared_ffn_dim
+    routed, shared, _ = ARCH.sparse_mlp(x, {
+        "gate": p["router"]["w"], "gate_proj": p["w1"], "up_proj": p["w3"], "down_proj": p["w2"],
+        "shared_gate_proj": p["shared"]["w13"][:, :fs], "shared_up_proj": p["shared"]["w13"][:, fs:],
+        "shared_down_proj": p["shared"]["w2"], "shared_expert_gate": p["shared"]["gate"]},
+        ref_cfg(cfg))
+    close(y, routed + shared, F32_TOL)
+    calls.clear()
+    monkeypatch.setattr(moe_held, "held_path", lambda *a: "worst_case")
+    y0, stats0, (dx0, dp0) = run()
+    assert not calls
+    close(y, y0, HELD_TOL)
+    close(dx, dx0, HELD_TOL)
+    for s_, s0 in zip(stats, stats0):
+        np.testing.assert_array_equal(np.asarray(s_), np.asarray(s0))
+    for (path, g), g0 in zip(jax.tree_util.tree_leaves_with_path(dp), jax.tree.leaves(dp0)):
+        assert float(jnp.abs(g0).max()) > 0, path
+        close(g, g0, HELD_TOL)
+
+
 # -- the runtime --------------------------------------------------------------------
 
 
@@ -421,8 +631,9 @@ def test_runtime_steps_and_hands_up_the_held_pairs(chunks):
     state, loss = rt.train_step(state, rt.shard_batch(rows))
     assert float(loss) == pytest.approx(want, rel=1e-5)
     assert set(state["moe_stats"]) == {"moe_aux_loss", "moe_load_max_over_mean",
-                                       "moe_held_pairs_per_token"}
+                                       "moe_held_pairs_per_token", "moe_held_rows_share"}
     assert 0.2 < float(state["moe_stats"]["moe_held_pairs_per_token"]) < 3.0
+    assert 0.0 < float(state["moe_stats"]["moe_held_rows_share"]) <= 1.0
     # a model that holds all its experts keeps the two statistics it had
     whole = _runtime(small_cfg(max_seq_len=64, moe_share=(0, 1)), hp)
     assert set(whole.init_state(jax.random.key(0))["moe_stats"]) == {

@@ -447,6 +447,65 @@ def test_qwen3_next_mixer_compiles_at_published_widths(one_chip, real_mosaic):
     assert not nameless, nameless
 
 
+def test_qwen3_next_held_experts_compile_at_published_widths(one_chip, real_mosaic):
+    """The expert layer of `qwen3-next-80b-a3b_s4096` (16,384 tokens x top-10 over 512
+    experts of width 512, rank 0 of 16 holding 32, a buffer of 172,288 rows), forward
+    and backward under recomputation, as the chip's compiler sees it. The held share
+    takes the bounded path (PR 49): the kernels of `ops/moe_held.py` and the grouped
+    GEMMs keep their names under ``dispatch`` / ``experts`` / ``combine``: the
+    forward's five, four of them again in the replay under autodiff's ``transpose(``
+    (the backward needs no combined output, so the replay gathers no pair), the
+    backward's eight beside them; and NO operation of XLA's runs over a row buffer
+    (172,288 rows of 512 / 1024 / 2048 values, or the 163,840 pairs' gathered rows):
+    what is left over the buffer's length is the layout's scalar passes."""
+    import re
+
+    from galvatron_tpu.models import modeling, moe
+    from galvatron_tpu.models.modeling import PRESETS
+
+    cfg = PRESETS["qwen3-next-80b-a3b"].replace(mlp_recompute="off", moe_share=(0, 16))
+    assert (cfg.hidden_size, cfg.expert_ffn, cfg.moe_experts, cfg.moe_held, cfg.moe_top_k) == (
+        2048, 512, 512, 32, 10)
+    assert moe.held_path_counts(cfg.replace(num_layers=4)) == {"bounded": 4, "worst_case": 0}
+    shapes = jax.eval_shape(
+        lambda k: {"mlp": moe.init_moe_params(k, cfg),
+                   "mlp_norm": {"scale": jnp.zeros((cfg.hidden_size,), cfg.param_dtype)}},
+        jax.random.key(0))
+    p = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), shapes)
+    x = jax.ShapeDtypeStruct((4, 4096, 2048), jnp.bfloat16, sharding=one_chip)
+
+    def loss(x_, p_):
+        with jax.named_scope("layer_0"):
+            y, _ = jax.checkpoint(lambda a, b: modeling.mlp_residual(a, b, cfg))(x_, p_)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(x, p).compile()
+    text = compiled.as_text()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 4.0 * 2**30, f"{temp / 2**30:.2f} GiB"
+    rows = _entry_work(text)
+    kernels = sorted((n.split(".")[0], re.search(r"/mlp/(\w+)/", op).group(1))
+                     for n, op in rows if n.startswith("moe_"))
+    forward = [("moe_held_rows", "dispatch"), ("moe_gmm", "experts"),
+               ("moe_held_swiglu", "experts"), ("moe_gmm", "experts"), ("moe_held_pairs", "combine")]
+    assert kernels == sorted(forward + forward[:-1] + [  # the backward:
+        ("moe_held_pairs", "combine"), ("moe_held_rows", "combine"),
+        ("moe_gmm_dlhs", "experts"), ("moe_tgmm", "experts"), ("moe_held_swiglu_bwd", "experts"),
+        ("moe_gmm_dlhs", "experts"), ("moe_tgmm", "experts"), ("moe_held_pairs", "dispatch"),
+    ]), kernels
+    mine = [op for n, op in rows if n.startswith("moe_")]
+    assert all("layer_0" in op for op in mine) and sum("transpose(" in op for op in mine) == 12
+    ops = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in ("router", "dispatch", "experts", "combine", "shared_expert"):
+        assert any(f"/mlp/{scope}/" in op for op in ops), scope
+    # nothing of XLA's over a row buffer: not a gather, not a zero-fill, not a sum
+    wide = re.compile(r"\[(?:172288,(?:512|1024|2048|8,128)|163840,2048|16384,10,2048)\]")
+    over = [ln.split(" = ")[0].strip() for ln in text.splitlines()
+            if " = " in ln and wide.search(ln.split(" = ")[1].split("(")[0])
+            and not re.search(r" (custom-call|parameter|get-tuple-element|bitcast)\(", ln)]
+    assert not over, over[:5]
+
+
 def test_qwen3_next_attention_compiles_at_head_size_256(one_chip, real_mosaic):
     """The gated attention layer of the same cell: 16 query / 2 key-value heads of 256
     over 4096 keys, batch 4. ``s * lanes(d)`` = 4096 x 256 is exactly the blocked
