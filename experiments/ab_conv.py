@@ -16,7 +16,7 @@ batch 2 in float32.
 - ``blocks``: the kernels on the mixer's largest window (4096 channels at
   column 4096 of in_proj's (1, 8192, 8512) output) over strips, sequence and
   channel blocks, and with the sigmoid's division approximate.
-- ``mixer``: `models/ssm.ssm_block`, the whole layer, forward, forward +
+- ``mixer``: `models/ssm.block`, the whole layer, forward, forward +
   backward and under ``jax.checkpoint`` (the cell's ``--global_checkpoint 1``),
   with the conv plain, as one fused window sliced in three behind it
   (``fused_sliced``: the plain boundary), and as three windows read in place
@@ -156,7 +156,7 @@ def seam_blocks(rows):
 
 def fused_sliced(zxbcdt, w, b, cfg, place=None):
     """The plain boundary: one fused window of all conv channels, sliced behind."""
-    windows = ssd.conv_windows(cfg)
+    windows = ssm.conv_windows(cfg)
     xbc = ssd.conv_silu_fused(zxbcdt, w, b, windows[0])
     starts = (0, windows[0], windows[0] + windows[1])
     return tuple(xbc[..., a:a + n] for a, n in zip(starts, windows))
@@ -166,18 +166,18 @@ def seam_mixer(rows):
     cfg = PRESETS["granite-4.0-h-micro"].replace(max_seq_len=SEQ, dtype=jnp.bfloat16)
     ks = jax.random.split(jax.random.key(0), 3)
     params = jax.tree.map(lambda a: a.astype(jnp.bfloat16) if a.ndim == 2 else a,
-                          ssm.init_ssm_params(ks[0], cfg))
+                          ssm.init_params(ks[0], cfg))
     hidden = jax.random.normal(ks[1], (1, SEQ, cfg.hidden_size), jnp.bfloat16)
     cot = jax.random.normal(ks[2], (1, SEQ, cfg.hidden_size))
-    assert ssd.conv_path(ssd.conv_windows(cfg), cfg.ssm_conv, cfg.dtype) == "fused"
+    assert ssd.conv_path(ssm.conv_windows(cfg), cfg.ssm_conv, cfg.dtype) == "fused"
     patches = {"plain": mock.patch.object(ssm, "conv_path", lambda *a: "plain"),
                "fused_sliced": mock.patch.object(ssm, "conv_split", fused_sliced),
                "fused": mock.patch.object(ssm, "conv_path", ssd.conv_path)}
     want = None
     for body, patch in patches.items():
-        def run(x_, p_, patch=patch):  # `ssm_block` with its conv bound while it is traced
+        def run(x_, p_, patch=patch):  # `ssm.block` with its conv bound while it is traced
             with patch:
-                return ssm.ssm_block(x_, p_, cfg)
+                return ssm.block(x_, p_, cfg)
 
         row, y, grads = measure(f"mixer/{body}", run, (hidden, params), cot, top=14)
         got = [y] + jax.tree.leaves(grads)

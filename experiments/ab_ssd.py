@@ -10,7 +10,7 @@ Two seams, each forward, forward + backward, and forward + backward under
 forward, backward), ms a layer and the compiler's peak of temporaries:
 
 - ``scan``: `ssd_scan` on its five operands;
-- ``mixer``: `models/ssm.ssm_block`, the whole layer, so that what the
+- ``mixer``: `models/ssm.block`, the whole layer, so that what the
   compiler inserts around the scan (the re-tiling copies of x) is in the time.
 
 The fused result is held to the plain one on the same inputs (largest
@@ -106,14 +106,14 @@ def main(argv=None) -> int:
         jax.random.normal(ks[3], (1, SEQ, g, n), jnp.bfloat16),
         jax.random.normal(ks[4], (1, SEQ, g, n), jnp.bfloat16))
     params = jax.tree.map(lambda a: a.astype(jnp.bfloat16) if a.ndim == 2 else a,
-                          ssm.init_ssm_params(ks[5], cfg))
+                          ssm.init_params(ks[5], cfg))
     hidden = jax.random.normal(ks[6], (1, SEQ, cfg.hidden_size), jnp.bfloat16)
     scans = {"plain": ssd.ssd_scan_plain, "fused": ssd.ssd_scan_fused}
 
     def mixer(body):
-        def run(x_, p_):  # `ssm_block` with its scan bound to one body while it is traced
+        def run(x_, p_):  # `ssm.block` with its scan bound to one body while it is traced
             with mock.patch.object(ssm, "ssd_scan", scans[body]):
-                return ssm.ssm_block(x_, p_, cfg)
+                return ssm.block(x_, p_, cfg)
         return run
 
     assert ssd.scan_path(h, p, g, n, chunk, jnp.bfloat16) == "fused"

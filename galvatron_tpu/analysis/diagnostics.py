@@ -33,13 +33,13 @@ CODES = {
     "GTA011": ("interleaved-schedule (vpp) constraint violated", ERROR),
     "GTA012": ("known XLA SPMD CHECK-crash cell: pp>1 × 1F1B × tp>1 × sp=0 × vocab_tp>1", ERROR),
     "GTA013": ("stage-stack seam: layers at the same stage position disagree (pp>1)", ERROR),
-    "GTA014": ("expert-parallel degree invalid for the model's expert count", ERROR),
+    "GTA014": ("expert-parallel degree invalid for the model's expert count or expert path", ERROR),
     "GTA015": ("cost-model memory estimate exceeds the device budget", ERROR),
     "GTA016": ("abstract sharding pass: annotated dim unsharded or spec invalid", WARN),
     "GTA017": ("checkpoint topology/plan fingerprint does not match the live mesh", ERROR),
     "GTA018": ("tp_overlap (collective-matmul) set on a layer with tp == 1", ERROR),
-    "GTA019": ("tp or cp > 1 on a state-space layer of a hybrid stack", ERROR),
-    "GTA020": ("pp > 1 over interleaved layer kinds", ERROR),
+    "GTA019": ("tp or cp > 1 on layers that do not implement it (models/mixers.limits)", ERROR),
+    "GTA020": ("pp > 1 over interleaved layer kinds or the dropless expert path", ERROR),
     # --- trace-hygiene linter (GTL1xx) ---
     "GTL100": ("malformed suppression: '# gta: disable=<rule>' needs a reason", ERROR),
     "GTL101": ("host-device sync on a jitted result inside a hot loop", WARN),

@@ -24,7 +24,9 @@ The checks, in order:
     (pp, …)-stacked parameter has exactly one sharding, so real layers at
     the same stack position must share one strategy).
  3. Model-dependent: layer count (GTA006), head/vocab/sequence divisibility
-    (GTA007/GTA008/GTA010), expert parallelism vs expert count (GTA014).
+    (GTA007/GTA008/GTA010), expert parallelism vs expert count (GTA014),
+    what the model's layers do not implement (``models/mixers.limits``:
+    GTA014 / GTA019 / GTA020, one table with ``build_runtime``'s refusals).
  4. Batch: chunks and per-layer dp-extent divisibility (GTA009 — mirrors
     the search engine's strict chunk filter, which is the runtime's static
     reshape requirement).
@@ -60,6 +62,7 @@ from galvatron_tpu.core.strategy import (
     LayerStrategy,
     balanced_division,
 )
+from galvatron_tpu.models import mixers
 
 # The strategy-JSON schema: codec keys (strategy.to_json_dict) plus the
 # extras SearchEngine.save_result and the checked-in configs carry. Anything
@@ -787,54 +790,36 @@ def _check_model(hp: HybridParallelConfig, cfg, source) -> List[Diagnostic]:
                     source=source,
                 )
             )
-    kinds = getattr(cfg, "kinds", ())
-    if getattr(cfg, "moe_dropless", False) and cfg.moe_holds_share:
-        for i, s in enumerate(hp.layer_strategies):
-            if s.ep > 1:
-                out.append(
-                    Diagnostic(
-                        "GTA014",
-                        f"layer {i}: ep={s.ep} on a held share of the experts (this copy "
-                        f"holds {cfg.moe_held} of {cfg.moe_experts}) — the share is one rank "
-                        "of an expert-parallel deployment already",
-                        hint=f"set ep_sizes_enc[{i}] to 1",
-                        field=f"ep_sizes_enc[{i}]",
-                        source=source,
-                    )
-                )
-    # a hybrid stack's recurrent layers (build_runtime refuses the same, by the same names)
-    recurrent = {
-        "ssm": ("a state-space layer", "the Mamba-2 mixer", "the scan's state"),
-        "gdn": ("a Gated DeltaNet layer", "the Gated DeltaNet mixer", "the delta rule's state"),
-    }
-    for i, (kind, s) in enumerate(zip(kinds, hp.layer_strategies[enc:])):
-        if kind not in recurrent:
+    # what the model's layers do not implement (models/mixers.limits: build_runtime
+    # refuses the same, from the same table): one diagnostic a layer that breaks a
+    # degree's limit, one for a limit on the run
+    for limit in mixers.limits(cfg):
+        at = limit.code and limit.broken_by(cfg, hp)
+        if not at:
             continue
-        a_layer, mixer, state = recurrent[kind]
-        for deg, name, why in (
-                (s.tp, "tp", f"tensor parallelism is not implemented for {mixer}"),
-                (s.cp, "cp", f"{state} is not passed between sequence shards")):
-            if deg > 1:
+        if limit.what not in mixers.DEGREES:
+            out.append(
+                Diagnostic(
+                    limit.code,
+                    f"{limit.what}={getattr(hp, limit.what)} {limit.diagnostic}",
+                    hint=limit.hint,
+                    field=f"{limit.what}_deg",
+                    source=source,
+                )
+            )
+            continue
+        for i in at:
+            if i in limit.layers:
                 out.append(
                     Diagnostic(
-                        "GTA019",
-                        f"layer {enc + i}: {name}={deg} on {a_layer} — {why}",
-                        hint=f"set {name}_sizes_enc[{enc + i}] to 1",
-                        field=f"{name}_sizes_enc[{enc + i}]",
+                        limit.code,
+                        f"layer {i}: {limit.what}={getattr(hp.layer_strategies[i], limit.what)} "
+                        f"{limit.diagnostic}",
+                        hint=f"set {limit.what}_sizes_enc[{i}] to 1",
+                        field=f"{limit.what}_sizes_enc[{i}]",
                         source=source,
                     )
                 )
-    if hp.pp > 1 and len(set(kinds)) > 1:
-        out.append(
-            Diagnostic(
-                "GTA020",
-                f"pp={hp.pp} over interleaved layer kinds — the pipeline engines "
-                "stack one kind of layer a stage position",
-                hint="use pp_deg 1 for a hybrid stack",
-                field="pp_deg",
-                source=source,
-            )
-        )
     if hp.vocab_tp > 1 and cfg.vocab_size % hp.vocab_tp:
         out.append(
             Diagnostic(

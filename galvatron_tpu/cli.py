@@ -115,7 +115,7 @@ def main(argv: Optional[List[str]] = None, model_default: Optional[str] = None) 
         # (kernel + dtype) — otherwise predicted-vs-measured fidelity is
         # broken by construction
         cfg = resolve_execution_config(model_config_from_args(ns), ns)
-        from galvatron_tpu.models.modeling import has_recurrent_layers
+        from galvatron_tpu.models.mixers import has_mixer_layers
         from galvatron_tpu.profiling.model import profile_model
         from galvatron_tpu.search.cost_model import ProfiledHardware
         from galvatron_tpu.search.search_engine import SearchEngine, SearchSpace
@@ -132,10 +132,10 @@ def main(argv: Optional[List[str]] = None, model_default: Optional[str] = None) 
             return 2
         if ns.time_profile_path and ns.memory_profile_path:
             costs = load_profiled_model(ns.time_profile_path, ns.memory_profile_path)
-        elif ns.analytic_costs or ns.check_cost_model or has_recurrent_layers(cfg):
+        elif ns.analytic_costs or ns.check_cost_model or has_mixer_layers(cfg):
             from galvatron_tpu.search.theoretical import analytic_model_costs
 
-            if has_recurrent_layers(cfg):
+            if has_mixer_layers(cfg):
                 print("hybrid stack: the in-process profiler measures one layer kind; "
                       "each kind is priced analytically")
             print("using analytic (unprofiled) model costs")

@@ -237,32 +237,25 @@ def _train_impl(ns: argparse.Namespace, verbose: bool, tracer,
             cfg, hp, mesh=mesh, axes=axes, adam=adam,
             global_batch_size=ns.global_train_batch_size, seq_len=seq,
         )
-        # how many decoder layers of each kind the run has (a hybrid stack:
-        # {"ssm": 9, "attention": 1} for one granite period)
-        layer_kinds = dict(collections.Counter(rt.cfg.kinds))
-        # which scan and which conv its state-space layers take (ops/ssd.scan_path,
-        # conv_path: the fused kernels or the plain body); a stack without such
-        # layers loads neither
-        ssm_scan_path, ssm_conv_path = {"fused": 0, "plain": 0}, {"fused": 0, "plain": 0}
-        if layer_kinds.get("ssm"):
-            from galvatron_tpu.ops.ssd import conv_path_counts, scan_path_counts
-
-            ssm_scan_path, ssm_conv_path = scan_path_counts(rt.cfg), conv_path_counts(rt.cfg)
-        # the same two for its Gated DeltaNet layers (models/gdn.py)
-        gdn_scan_path, gdn_conv_path = {"fused": 0, "plain": 0}, {"fused": 0, "plain": 0}
-        if layer_kinds.get("gdn"):
-            from galvatron_tpu.models import gdn
-
-            gdn_scan_path, gdn_conv_path = gdn.scan_path_counts(rt.cfg), gdn.conv_path_counts(rt.cfg)
-        # the layers whose held share of the experts does work in proportion to the
-        # pairs it holds, and those that run over the worst-case buffer (models/moe.py)
+        from galvatron_tpu.models import mixers
         from galvatron_tpu.models.moe import held_path_counts
 
-        moe_held_path = held_path_counts(rt.cfg)
-        build_span.set(tp_overlap_seams=rt.tp_overlap_seams, layer_kinds=layer_kinds,
-                       ssm_scan_path=ssm_scan_path, ssm_conv_path=ssm_conv_path,
-                       gdn_scan_path=gdn_scan_path, gdn_conv_path=gdn_conv_path,
-                       moe_held_path=moe_held_path)
+        # which bodies the run's layers take, for the span and the manifest:
+        layer_paths = {
+            # projection seams of the plan's tp_overlap layers that run the
+            # collective-matmul ring / the plain einsum (the ring's shape test)
+            "tp_overlap_seams": rt.tp_overlap_seams,
+            # how many decoder layers of each kind (a hybrid stack: one granite
+            # period is nine state-space layers and one of attention)
+            "layer_kinds": dict(collections.Counter(rt.cfg.kinds)),
+            # "<kind>_scan_path", "<kind>_conv_path" for every registered kind: the
+            # fused kernels or the plain body; a kind the stack lacks loads nothing
+            **mixers.path_counts(rt.cfg),
+            # the layers whose held share of the experts does work in proportion to
+            # the pairs it holds, and those over the worst-case buffer (models/moe.py)
+            "moe_held_path": held_path_counts(rt.cfg),
+        }
+        build_span.set(**layer_paths)
 
     from galvatron_tpu.obs import tracing as obs_tracing
     from galvatron_tpu.utils.metrics import SCHEMA_VERSION, MetricsLogger
@@ -297,15 +290,7 @@ def _train_impl(ns: argparse.Namespace, verbose: bool, tracer,
         # appended, so a perf delta across manifests is attributable
         "xla_overlap": getattr(ns, "xla_overlap", "off"),
         "xla_overlap_flags": list(getattr(ns, "xla_overlap_applied", []) or []),
-        # projection seams of the plan's tp_overlap layers that run the
-        # collective-matmul ring / the plain einsum (the ring's shape test)
-        "tp_overlap_seams": rt.tp_overlap_seams,
-        "layer_kinds": layer_kinds,
-        "ssm_scan_path": ssm_scan_path,
-        "ssm_conv_path": ssm_conv_path,
-        "gdn_scan_path": gdn_scan_path,
-        "gdn_conv_path": gdn_conv_path,
-        "moe_held_path": moe_held_path,
+        **layer_paths,
     }
     # JAX's persistent compile cache is always on, at the one place
     # resolve_compile_cache_dir names (JAX_COMPILATION_CACHE_DIR, else an

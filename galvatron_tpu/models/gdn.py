@@ -19,7 +19,7 @@ The rule has two bodies behind one contract (PR 48), chosen by
 published sizes the Pallas kernels ``gdn_fwd`` / ``gdn_bwd`` (a ``custom_vjp``
 that keeps its five inputs and the chunks' entering states; under
 ``place.shard_kernel`` on a mesh), everywhere else the plain chunked body under
-this mixer's own ``jax.checkpoint``. `scan_path_counts` reports which.
+this mixer's own ``jax.checkpoint``. `path_counts` reports which.
 
 Scopes under ``gdn``: ``in_proj``, ``conv``, ``scan``, ``gate_norm``,
 ``out_proj`` (PERF.md §3; the ``gdn_*`` benchmark metrics read them).
@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from galvatron_tpu.models.mixers import tally
 from galvatron_tpu.models.placement import LOCAL, Placement
 from galvatron_tpu.ops.gated_delta import gated_delta_chunked, gated_delta_fused, scan_path
 from galvatron_tpu.ops.ssd import causal_conv1d, conv_path, conv_silu_fused
@@ -54,6 +55,26 @@ def param_count(cfg) -> int:
     hv = cfg.gdn_value_heads
     return (cfg.hidden_size * (in_width + 2 * hv) + conv_dim * cfg.gdn_conv
             + 2 * hv + cfg.gdn_value_dim + value_dim * cfg.hidden_size)
+
+
+def saved_bytes_per_token(cfg, itemsize: int) -> float:
+    """What the mixer keeps for the backward, in place of an attention layer's
+    qkv + context: in_proj's output, the conv's output, the delta rule's output
+    and the gated product, and inside a chunk the float32 system, its solution's
+    two halves and the decay-masked scores a value head."""
+    _, value_dim, conv_dim, in_width = gdn_dims(cfg)
+    mixer = (in_width + conv_dim + 2 * value_dim) * itemsize
+    return mixer + cfg.gdn_value_heads * (
+        3 * cfg.gdn_chunk + 2 * (cfg.gdn_key_dim + cfg.gdn_value_dim)) * 4
+
+
+def fwd_flops_per_token(cfg) -> float:
+    """The chunked delta rule's forward FLOPs beside the weights': K K^T and Q K^T
+    (causal half, a key head), a value head's solve, the entering state read twice
+    and written once, the causal half of scores V'. Linear in the sequence."""
+    dk, dv, c = cfg.gdn_key_dim, cfg.gdn_value_dim, cfg.gdn_chunk
+    return cfg.gdn_key_heads * 2.0 * (c + 1) * dk + cfg.gdn_value_heads * (
+        (c - 1.0) * (dk + dv) + 6.0 * dk * dv + (c + 1.0) * dv)
 
 
 def init_params(key, cfg) -> Params:
@@ -102,29 +123,20 @@ def conv_silu(qkvz, w, cfg, place: Placement = LOCAL):
     return jax.nn.silu(causal_conv1d(qkvz[..., :conv_dim], w, bias))
 
 
-def conv_path_counts(cfg) -> dict:
-    """``{"fused": n, "plain": m}``: how many of a configuration's Gated
-    DeltaNet layers take which conv (the run's fingerprint, PERF.md §3)."""
-    counts = {"fused": 0, "plain": 0}
-    layers = sum(kind == "gdn" for kind in cfg.kinds)
-    if layers:
-        counts[conv_path((gdn_dims(cfg)[2],), cfg.gdn_conv, cfg.dtype)] = layers
-    return counts
-
-
 def _rule_path(cfg) -> str:
     return scan_path(cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_key_dim, cfg.gdn_value_dim,
                      cfg.gdn_chunk, cfg.dtype)
 
 
-def scan_path_counts(cfg) -> dict:
-    """As `conv_path_counts`, for the delta rule: asks `ops/gated_delta.scan_path`,
-    the function `block` asks."""
-    counts = {"fused": 0, "plain": 0}
-    layers = sum(kind == "gdn" for kind in cfg.kinds)
-    if layers:
-        counts[_rule_path(cfg)] = layers
-    return counts
+def path_counts(cfg) -> dict:
+    """Which delta rule and which conv a configuration's Gated DeltaNet layers
+    take (`ops/gated_delta.scan_path`, `ops/ssd.conv_path`: the functions
+    `block` asks)."""
+    layers = cfg.kinds.count("gdn")
+    return {
+        "scan": tally(_rule_path(cfg), layers),
+        "conv": tally(conv_path((gdn_dims(cfg)[2],), cfg.gdn_conv, cfg.dtype), layers),
+    }
 
 
 def _l2norm(t):

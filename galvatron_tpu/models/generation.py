@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from galvatron_tpu.models import modeling
+from galvatron_tpu.models import mixers, modeling
 from galvatron_tpu.models.modeling import ModelConfig, Params
 
 
@@ -34,15 +34,10 @@ class KVCache(NamedTuple):
 
 
 def init_kv_cache(cfg: ModelConfig, batch_size: int, max_len: int) -> KVCache:
-    if "ssm" in cfg.kinds:
+    for limit in mixers.limits(cfg):
         # every cache of the serving stack (slots, paged pool, generate) starts here
-        raise ValueError(
-            "generation is not implemented for a stack with state-space layers: a "
-            "key/value cache holds no recurrent (conv + scan) state; train-only")
-    if "gdn" in cfg.kinds:
-        raise ValueError(
-            "generation is not implemented for a stack with Gated DeltaNet layers: a "
-            "key/value cache holds no recurrent (conv + delta rule) state; train-only")
+        if limit.what == "kv_cache":
+            raise ValueError(limit.sentence())
     shape = (cfg.num_layers, batch_size, max_len, cfg.kv_heads, cfg.head_dim)
     return KVCache(jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))
 

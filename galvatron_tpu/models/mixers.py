@@ -1,0 +1,216 @@
+"""Layer kinds: the interface a kind's module fulfils, the registry of kinds, and
+the one table of what a model's layers do not implement.
+
+A layer of a kind other than ``"attention"`` (``ModelConfig.layer_kinds``) runs
+that kind's mixer in place of attention; norms and MLP are the same. A kind is
+ONE module and ONE row of ``MIXERS``. The module (``Mixer.module``, imported
+only where a configuration has such layers: `module`) exposes
+
+- ``init_params(key, cfg)``, ``annotations(cfg)``, ``block(x, p, cfg, place)``:
+  what `modeling` initialises, shards and runs under the layer's key ``kind``;
+- ``param_count(cfg)``, ``saved_bytes_per_token(cfg, itemsize)``,
+  ``fwd_flops_per_token(cfg)``: the mixer's prices (search/theoretical.py);
+- ``path_counts(cfg)`` -> ``{kernel: {"fused": n, "plain": m}}`` for the
+  row's ``kernels``: which body of each the configuration's layers take (the
+  run's fingerprint, `path_counts`).
+
+The row holds the kind's words and, under ``lacks``, what it does not implement
+with the clause that says why. `limits` turns the rows of a configuration's
+kinds, its interleaving and its expert path into `Limit`s; `build_runtime`
+raises the first one a plan breaks, `plan_check` reports each, the search
+leaves each out and names its tag, `init_kv_cache` refuses a stack with one on
+the cache. Plain data and functions of the configuration's fields: nothing here
+imports jax or a kernel, or anything of ``parallel/``, ``search/``, ``analysis/``.
+"""
+
+from __future__ import annotations
+
+import collections
+import dataclasses
+import importlib
+from typing import Dict, List, Mapping, Optional, Tuple
+
+_NO_PATHS = {"fused": 0, "plain": 0}
+
+
+@dataclasses.dataclass(frozen=True)
+class Mixer:
+    kind: str  # the entry of ``layer_kinds``, the key of the layer's parameters, the scope
+    module: str  # imported by `module`, never before a configuration has such a layer
+    layer: str  # "state-space layer": the refusals' and diagnostics' name for it
+    mixer: str  # "the Mamba-2 mixer"
+    tag: str  # "state_space_layers": head of the search's standing tags
+    # what the kind does not implement ("tp" | "cp" | "pack_sequences" |
+    # "kv_cache") -> the clause that says why
+    lacks: Mapping[str, str]
+    kernels: Tuple[str, ...] = ()  # the bodies `path_counts` reports, "<kind>_<kernel>_path"
+
+
+MIXERS: Dict[str, Mixer] = {entry.kind: entry for entry in (
+    Mixer(
+        kind="ssm", module="galvatron_tpu.models.ssm", layer="state-space layer",
+        mixer="the Mamba-2 mixer", tag="state_space_layers", kernels=("scan", "conv"),
+        lacks={
+            "tp": "the Mamba-2 mixer's heads, conv channels and scan carry no tp sharding",
+            "cp": "the scan's state is not passed between sequence shards",
+            "pack_sequences": ("the conv and the scan do not reset their state at segment "
+                               "boundaries"),
+            "kv_cache": "a key/value cache holds no recurrent (conv + scan) state",
+        }),
+    Mixer(
+        kind="gdn", module="galvatron_tpu.models.gdn", layer="Gated DeltaNet layer",
+        mixer="the Gated DeltaNet mixer", tag="gated_delta_layers", kernels=("scan", "conv"),
+        lacks={
+            "tp": ("the mixer's heads, conv channels and the delta rule's state carry no tp "
+                   "sharding"),
+            "cp": "the delta rule's state is not passed between sequence shards",
+            "pack_sequences": ("the conv and the delta rule do not reset their state at segment "
+                               "boundaries"),
+            "kv_cache": "a key/value cache holds no recurrent (conv + delta rule) state",
+        }),
+)}
+
+
+def module(kind: str):
+    """The module of a kind's mixer."""
+    return importlib.import_module(MIXERS[kind].module)
+
+
+def has_mixer_layers(cfg) -> bool:
+    """The stack has a layer whose mixer is a registered kind's, not attention
+    (each kind is then priced by itself: the in-process profiler measures one)."""
+    return any(kind in MIXERS for kind in cfg.kinds)
+
+
+def path_counts(cfg) -> Dict[str, Dict[str, int]]:
+    """``{"<kind>_<kernel>_path": {"fused": n, "plain": m}}`` over every
+    registered kind's kernels; a kind the stack lacks reads zeros and its module
+    stays unloaded."""
+    out = {}
+    for kind, entry in MIXERS.items():
+        has = entry.kernels and kind in cfg.kinds
+        counts = module(kind).path_counts(cfg) if has else {}
+        for kernel in entry.kernels:
+            out[f"{kind}_{kernel}_path"] = counts.get(kernel, dict(_NO_PATHS))
+    return out
+
+
+def tally(path: str, layers: int) -> Dict[str, int]:
+    """``layers`` layers all on ``path``, as a kind's ``path_counts`` reports a kernel."""
+    return {**_NO_PATHS, path: layers}
+
+
+#: the limits that are a layer's degree in the plan; the others are the run's
+DEGREES = ("tp", "cp", "ep")
+
+
+@dataclasses.dataclass(frozen=True)
+class Limit:
+    """One thing a model's layers do not implement."""
+
+    # "tp" | "cp" | "ep": a layer's degree > 1; "pp" | "pack_sequences" | "fp16":
+    # the run's; "kv_cache": generation's
+    what: str
+    layers: Tuple[int, ...]  # the strategy indices it is reported on: a kind's, or all
+    refusal: str  # build_runtime's (init_kv_cache's) sentence; "{at}": the layers that break it
+    stack: bool = False  # a degree ANY layer of the stack breaks, reported on ``layers``
+    tag: Optional[str] = None  # the search's standing tag (None: nothing the search enumerates)
+    code: Optional[str] = None  # plan_check's diagnostic (None: nothing a plan file carries)
+    diagnostic: str = ""  # what follows "layer 3: tp=2 " / "pp=2 "
+    hint: str = ""  # of a run's limit; a degree's names its field
+
+    def broken_by(self, cfg, hp):
+        """Where a plan (``core.strategy.HybridParallelConfig``, read by
+        attribute) breaks the limit: the layers whose degree does, or whether
+        the run does. Falsy where the plan keeps it."""
+        if self.what in DEGREES:
+            over = [i for i, s in enumerate(hp.layer_strategies) if getattr(s, self.what) > 1]
+            return over if self.stack else [i for i in over if i in self.layers]
+        return {"pp": hp.pp > 1, "fp16": hp.mixed_precision == "fp16",
+                "pack_sequences": bool(cfg.pack_sequences)}.get(self.what, False)
+
+    def sentence(self, at=()) -> str:
+        """The refusal, given what `broken_by` found."""
+        return self.refusal.replace("{at}", str(at))
+
+
+def limits(cfg) -> List[Limit]:
+    """What ``cfg``'s layers do not implement, in the order `build_runtime`
+    refuses: each recurrent kind's own, pipeline stages over interleaved kinds,
+    the dropless expert path's."""
+    kinds = tuple(getattr(cfg, "kinds", ()))
+    enc = getattr(cfg, "enc_layers", 0)
+    every = tuple(range(enc + len(kinds)))
+    out: List[Limit] = []
+    for kind, e in MIXERS.items():
+        at = tuple(enc + i for i, k in enumerate(kinds) if k == kind)
+        if not at:
+            continue
+        layers, why = f"{e.layer}s", e.lacks
+        if "tp" in why:
+            out.append(Limit(
+                "tp", at, tag=f"{e.tag}_no_tp", code="GTA019",
+                refusal=(f"tensor parallelism (tp>1) is not implemented for {layers} "
+                         f"(layers {{at}} of this plan): {why['tp']}; use tp=1 on those layers"),
+                diagnostic=(f"on a {e.layer} — tensor parallelism is not implemented for "
+                            f"{e.mixer}")))
+        if "cp" in why:
+            out.append(Limit(
+                "cp", at, stack=True, tag=f"{e.tag}_no_cp", code="GTA019",
+                refusal=(f"context parallelism (cp>1) is not implemented for a stack with "
+                         f"{layers}: {why['cp']}; use cp=1"),
+                diagnostic=f"on a {e.layer} — {why['cp']}"))
+        if "pack_sequences" in why:
+            out.append(Limit(
+                "pack_sequences", at,
+                refusal=f"pack_sequences is not implemented for {layers}: {why['pack_sequences']}"))
+        if "kv_cache" in why:
+            out.append(Limit(
+                "kv_cache", at,
+                refusal=(f"generation is not implemented for a stack with {layers}: "
+                         f"{why['kv_cache']}; train-only")))
+    if len(set(kinds)) > 1:
+        out.append(Limit(
+            "pp", every, tag="interleaved_layer_kinds_no_pp", code="GTA020",
+            refusal=("pipeline parallelism (pp>1) over interleaved layer kinds is not "
+                     "implemented: the pipeline engines stack one kind of layer a stage "
+                     f"position (this model: {dict(collections.Counter(kinds))}); use pp=1"),
+            diagnostic=("over interleaved layer kinds — the pipeline engines "
+                        "stack one kind of layer a stage position"),
+            hint="use pp_deg 1 for a hybrid stack"))
+    if getattr(cfg, "moe_dropless", False):
+        # the sorted-rows path keeps every expert (or its held share) on every
+        # device and hands its auxiliary loss up through the GSPMD step
+        path = "the dropless top-k MoE path (moe_router='softmax_topk')"
+        if cfg.moe_holds_share:
+            out.append(Limit(
+                "ep", every, tag="dropless_topk_moe_no_ep", code="GTA014",
+                refusal=(f"expert parallelism (ep>1) on a held share of the experts (moe_share="
+                         f"{cfg.moe_share}: this copy holds {cfg.moe_held} of {cfg.moe_experts}) "
+                         "is not implemented: the share IS one rank of an expert-parallel "
+                         "deployment and the sorted-row path has no expert all-to-all; use ep=1"),
+                diagnostic=(f"on a held share of the experts (this copy holds {cfg.moe_held} of "
+                            f"{cfg.moe_experts}) — the share is one rank of an expert-parallel "
+                            "deployment already")))
+        else:
+            out.append(Limit(
+                "ep", every, tag="dropless_topk_moe_no_ep", code="GTA014",
+                refusal=(f"expert parallelism (ep>1) is not implemented for {path}: its "
+                         "sorted-row grouped GEMM has no expert all-to-all yet; use ep=1"),
+                diagnostic=f"on {path} — its sorted-row grouped GEMM has no expert all-to-all yet"))
+        out.append(Limit(
+            "pp", every, tag="dropless_topk_moe_no_pp", code="GTA020",
+            refusal=(f"pipeline parallelism (pp>1) is not implemented for {path}: the pipeline "
+                     "engines carry no auxiliary loss between stages; use pp=1"),
+            diagnostic=f"on {path} — the pipeline engines carry no auxiliary loss between stages",
+            hint="use pp_deg 1 for a dropless top-k MoE model"))
+        out.append(Limit(
+            "cp", every, tag="dropless_topk_moe_no_cp", code="GTA019",
+            refusal=(f"context parallelism (cp>1) is not implemented for {path}: the "
+                     "ring/Ulysses layers hand no router statistics up; use cp=1"),
+            diagnostic=f"on {path} — the ring/Ulysses layers hand no router statistics up"))
+        out.append(Limit(
+            "fp16", every,
+            refusal=("fp16 loss scaling is not threaded through the dropless top-k MoE "
+                     "objective (moe_router='softmax_topk'); use bf16 or fp32")))
+    return out

@@ -33,20 +33,11 @@ from jax.ad_checkpoint import checkpoint_name
 import jax.numpy as jnp
 import numpy as np
 
+from galvatron_tpu.models import mixers
 from galvatron_tpu.models.placement import LOCAL, Placement
 from galvatron_tpu.ops.quant import QuantTensor, qeinsum, qmatmul
 
 Params = Dict[str, Any]
-
-#: layer kinds whose mixer carries a state along the sequence: no tp, no cp, no
-#: packing, no key/value-cache generation (build_runtime, plan_check, the search)
-RECURRENT_KINDS = ("ssm", "gdn")
-
-
-def has_recurrent_layers(cfg) -> bool:
-    """The stack has a layer of a recurrent kind (a hybrid stack: each kind is
-    priced by itself, the in-process profiler measures one kind only)."""
-    return any(kind in RECURRENT_KINDS for kind in cfg.kinds)
 
 
 @dataclass(frozen=True)
@@ -83,12 +74,6 @@ class ModelConfig:
     # pretraining) with deterministic token-hash masking (see mlm_loss_sum)
     objective: str = "clm"
     mlm_mask_rate: float = 0.15
-    # Pallas fused rms/layernorm kernels (opt-in). Off by default: measured
-    # on the v5e 7B-shape bench (2026-07-30), XLA's own norm fusion beats the
-    # custom kernels by ~0.05 ms/layer/sample fwd and ~0.27 fwd+bwd — the
-    # custom-call boundary blocks producer/consumer fusion with the residual
-    # adds and GEMMs around the norm (BASELINE.md round-2 notes).
-    fused_norm: bool = False
     dtype: Any = jnp.bfloat16  # compute dtype
     param_dtype: Any = jnp.float32
     # Mixture-of-Experts (SwitchMLP equivalent, reference:
@@ -138,12 +123,12 @@ class ModelConfig:
     # with ``w`` initialised 0 (Qwen3-Next) in place of ``* w`` from 1.
     norm_zero_centered: bool = False
     # Hybrid stacks (granitemoehybrid- and qwen3_next-class): the kind of every
-    # layer, "attention" | "ssm" (a Mamba-2 mixer, models/ssm.py) | "gdn" (a
-    # Gated DeltaNet mixer, models/gdn.py) in place of attention; the MLP is the
-    # same), as published for the WHOLE model; a model cut in depth keeps the
-    # first ``num_layers`` entries (``kinds``). Empty: every layer is attention.
-    # tp>1 / cp>1 on a recurrent layer ("ssm", "gdn": ``RECURRENT_KINDS``) and
-    # pp>1 over mixed kinds are refused by build_runtime and left out by the search.
+    # layer, "attention" or a kind of ``models/mixers.MIXERS`` (its mixer in
+    # place of attention; the MLP is the same), as published for the WHOLE model;
+    # a model cut in depth keeps the first ``num_layers`` entries (``kinds``).
+    # Empty: every layer is attention. What a kind or the interleaving does not
+    # implement (``mixers.limits``) is refused by build_runtime and left out by
+    # the search.
     layer_kinds: Tuple[str, ...] = ()
     ssm_heads: int = 0
     ssm_head_dim: int = 64
@@ -417,27 +402,15 @@ def _norm_scale_init(cfg: ModelConfig, n: int):
     return (jnp.zeros if cfg.norm_zero_centered else jnp.ones)((n,), cfg.param_dtype)
 
 
-def _mixer_module(kind: str):
-    """The module of a recurrent layer kind's mixer (imported only where a
-    configuration has such layers)."""
-    if kind == "ssm":
-        from galvatron_tpu.models import ssm
-
-        return ssm
-    from galvatron_tpu.models import gdn
-
-    return gdn
-
-
 def init_layer_params(key, cfg: ModelConfig, cross: bool = False,
                       kind: str = "attention") -> Params:
     h, hd = cfg.hidden_size, cfg.head_dim
-    if kind in RECURRENT_KINDS:
+    if kind in mixers.MIXERS:
         # the mixer in place of attention; norms and MLP are an attention layer's
         k_mix, k_rest = jax.random.split(key)
         p = init_layer_params(k_rest, cfg, cross=cross)
         del p["attn"]
-        p[kind] = _mixer_module(kind).init_params(k_mix, cfg)
+        p[kind] = mixers.module(kind).init_params(k_mix, cfg)
         return p
     q_out = cfg.num_heads * hd
     kv_out = cfg.kv_heads * hd
@@ -511,10 +484,10 @@ def layer_annotations(cfg: ModelConfig, cross: bool = False,
     """Logical axes per layer param: 'tp' = Megatron-sharded dim (column-out /
     row-in), 'fsdp' = the dim ZeRO shards (reference: FSDP flat-param sharding,
     galvatron/core/parallel.py:174-207)."""
-    if kind in RECURRENT_KINDS:
+    if kind in mixers.MIXERS:
         a = layer_annotations(cfg, cross=cross)
         del a["attn"]
-        a[kind] = _mixer_module(kind).annotations(cfg)
+        a[kind] = mixers.module(kind).annotations(cfg)
         return a
     a: Params = {
         "attn_norm": {"scale": ("fsdp",)},
@@ -776,21 +749,15 @@ def _norm_impl(x, p, cfg: ModelConfig):
 
 
 def norm(x, p, cfg: ModelConfig):
-    """RMSNorm / LayerNorm; Pallas fused kernel on TPU when cfg.fused_norm
-    (reference fused-norm CUDA ops: megatron fused_layer_norm / rms_norm,
-    flash-attn dropout_add_rms_norm — SURVEY §2.1).
+    """RMSNorm / LayerNorm, left to XLA's own fusion (reference fused-norm CUDA
+    ops: megatron fused_layer_norm / rms_norm, flash-attn dropout_add_rms_norm —
+    SURVEY §2.1).
 
     Under ``mlp_recompute='policy'`` the fp32 statistics are rematerialized
     in the backward from the compute-dtype input — without the wrap, autodiff
     saves an fp32-widened (B, S, H) copy of every normed activation (the
     round-5 HLO buffer audit's 67 MB/layer class)."""
     with jax.named_scope("norm"):
-        if cfg.fused_norm:
-            from galvatron_tpu.ops import fused_norm
-
-            if cfg.norm_type == "rms":
-                return fused_norm.fused_rmsnorm(x, p["scale"], cfg.norm_eps)
-            return fused_norm.fused_layernorm(x, p["scale"], p["bias"], cfg.norm_eps)
         if cfg.mlp_recompute == "policy":
             return jax.checkpoint(lambda x_, p_: _norm_impl(x_, p_, cfg))(x, p)
         return _norm_impl(x, p, cfg)
@@ -1278,13 +1245,7 @@ def mlp_block(x, p, cfg: ModelConfig, train: bool = True, place: Placement = LOC
             g = g + p["w13_b"].astype(x.dtype)
         g = checkpoint_name(g, "mlp_gate")
         prod = lambda g_: jax.nn.silu(g_[..., :f]) * g_[..., f:]
-        if cfg.mlp_recompute == "gate" or (
-            cfg.mlp_recompute == "policy" and cfg.fused_norm
-        ):
-            # 'policy' with fused_norm: mlp_residual skips the policy region
-            # (the fused kernels carry custom-VJP residuals it cannot
-            # reach), so the one-gate-save guarantee falls back to the
-            # product-only remat here
+        if cfg.mlp_recompute == "gate":
             prod = jax.checkpoint(prod)
         y = down(prod(g), p["w2"].astype(x.dtype))
     else:
@@ -1304,9 +1265,7 @@ def mlp_block(x, p, cfg: ModelConfig, train: bool = True, place: Placement = LOC
         if overlap:
             y = place.proj_down("bsf,fh->bsh", g, p["w2"], w_shard_dim=0, activation=act)
         else:
-            if cfg.mlp_recompute == "gate" or (
-                cfg.mlp_recompute == "policy" and cfg.fused_norm
-            ):
+            if cfg.mlp_recompute == "gate":
                 act = jax.checkpoint(act)
             y = down(act(g), p["w2"].astype(x.dtype))
     if "w2_b" in p:
@@ -1340,15 +1299,13 @@ def mlp_residual(x, p, cfg: ModelConfig, train: bool = True, place: Placement = 
             y, stats = moe.moe_topk_block(normed, p["mlp"], cfg, place=place)
         return x + y, stats
     if (
-        cfg.mlp_recompute == "policy" and cfg.moe_experts == 0 and not cfg.fused_norm
+        cfg.mlp_recompute == "policy" and cfg.moe_experts == 0
         # a tp_overlap layer's down seam saves the gate itself (mlp_block);
         # SwiGLU's halves are not device-local, so it keeps the region
         and (not place.tp_overlap or cfg.act_fn == "swiglu")
     ):
         # _norm_impl, not norm: the policy region already remats everything
         # unnamed — a nested per-norm checkpoint would only add bookkeeping.
-        # fused_norm layers keep the plain branch (the Pallas kernels carry
-        # their own custom-VJP residuals the policy cannot reach).
         def normed(x_, pn_):
             with jax.named_scope("norm"):
                 return _norm_impl(x_, pn_, cfg)
@@ -1403,11 +1360,11 @@ def decoder_layer(
     collective-matmul seams; callers without a mesh (serving, the float32
     references, the profiler) pass nothing.
 
-    A layer whose parameters hold ``ssm`` or ``gdn`` in place of ``attn`` (a
-    recurrent kind of a hybrid stack, ``cfg.kinds``) runs that mixer there."""
-    for kind in RECURRENT_KINDS:
+    A layer whose parameters hold a kind of ``mixers.MIXERS`` in place of
+    ``attn`` (a hybrid stack, ``cfg.kinds``) runs that kind's mixer there."""
+    for kind in mixers.MIXERS:
         if kind in p:
-            x = residual_add(x, _mixer_module(kind).block(
+            x = residual_add(x, mixers.module(kind).block(
                 norm(x, p["attn_norm"], cfg), p[kind], cfg, place=place), cfg)
             return mlp_residual(x, p, cfg, place=place)
     x = residual_add(x, attn_block(

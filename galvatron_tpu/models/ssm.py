@@ -11,8 +11,8 @@ layer of kind ``"ssm"`` runs in place of attention.
 
 (HF ``modeling_granitemoehybrid.py`` GraniteMoeHybridMambaLayer; no projection
 biases, a conv bias.) Imported only where a configuration has such layers
-(``modeling.init_layer_params`` / ``decoder_layer`` on ``kind == "ssm"``), so
-every other model's imports stay what they were.
+(`models/mixers.py` names this module in the kind's row and says what it
+exposes), so every other model's imports stay what they were.
 
 Scopes under ``ssm``: ``in_proj``, ``conv``, ``scan``, ``gate_norm``,
 ``out_proj`` (PERF.md §3; the ``ssm_*`` benchmark metrics read them).
@@ -27,9 +27,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from galvatron_tpu.models.mixers import tally
 from galvatron_tpu.models.placement import LOCAL, Placement
-from galvatron_tpu.ops.ssd import (
-    causal_conv1d, conv_path, conv_silu_fused, conv_windows, scan_path, ssd_scan)
+from galvatron_tpu.ops.ssd import causal_conv1d, conv_path, conv_silu_fused, scan_path, ssd_scan
 
 Params = Dict[str, Any]
 F32 = jnp.float32
@@ -42,13 +42,50 @@ def ssm_dims(cfg):
     return d_inner, conv_dim, d_inner + conv_dim + cfg.ssm_heads
 
 
-def ssm_param_count(cfg) -> int:
+def conv_windows(cfg):
+    """Widths of x, B and C among the conv's channels."""
+    gn = cfg.ssm_groups * cfg.ssm_state
+    return cfg.ssm_heads * cfg.ssm_head_dim, gn, gn
+
+
+def param_count(cfg) -> int:
     d_inner, conv_dim, in_width = ssm_dims(cfg)
     return (cfg.hidden_size * in_width + conv_dim * (cfg.ssm_conv + 1)
             + 3 * cfg.ssm_heads + d_inner + d_inner * cfg.hidden_size)
 
 
-def init_ssm_params(key, cfg) -> Params:
+def saved_bytes_per_token(cfg, itemsize: int) -> float:
+    """What the mixer keeps for the backward, in place of an attention layer's
+    qkv + context: the in_proj output, the conv's input and output, the scan's
+    output and the gated product, and the decay-masked score blocks inside a
+    chunk, which are kept: heads x chunk entries a token, float32 decays and
+    compute-dtype scores."""
+    d_inner, conv_dim, in_width = ssm_dims(cfg)
+    mixer = (in_width + 2 * conv_dim + 2 * d_inner) * itemsize
+    return mixer + cfg.ssm_heads * cfg.ssm_chunk * (4 + itemsize)
+
+
+def fwd_flops_per_token(cfg) -> float:
+    """The chunked scan's forward FLOPs beside the weights': inside a chunk the
+    causal half of C B^T and of scores x, a chunk's state, the entering state's
+    read-out. Linear in the sequence."""
+    hp_, n_ = cfg.ssm_heads * cfg.ssm_head_dim, cfg.ssm_state
+    pairs = (cfg.ssm_chunk + 1) / 2
+    return 2.0 * pairs * (cfg.ssm_groups * n_ + hp_) + 4.0 * hp_ * n_
+
+
+def path_counts(cfg) -> dict:
+    """Which scan and which conv a configuration's state-space layers take
+    (`ops/ssd.scan_path`, `conv_path`: the functions `block` asks)."""
+    layers = cfg.kinds.count("ssm")
+    return {
+        "scan": tally(scan_path(cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state,
+                                cfg.ssm_chunk, cfg.dtype), layers),
+        "conv": tally(conv_path(conv_windows(cfg), cfg.ssm_conv, cfg.dtype), layers),
+    }
+
+
+def init_params(key, cfg) -> Params:
     """The published code's initialisation: ``A_log = log(1..H)``, ``D = 1``,
     ``dt_bias = 1``, gated-norm scale 1; the projections and the conv taps
     uniform in +-1/sqrt(fan_in) like every other projection of the program."""
@@ -71,7 +108,7 @@ def init_ssm_params(key, cfg) -> Params:
     }
 
 
-def ssm_annotations(cfg) -> Params:
+def annotations(cfg) -> Params:
     """No ``tp`` axis anywhere: tensor parallelism on a state-space layer is
     refused (build_runtime); ZeRO shards the hidden-size dims."""
     return {
@@ -105,7 +142,7 @@ def conv_split(zxbcdt, w, b, cfg, place: Placement = LOCAL):
 
 
 @jax.named_scope("ssm")
-def ssm_block(x, p: Params, cfg, place: Placement = LOCAL):
+def block(x, p: Params, cfg, place: Placement = LOCAL):
     """(B, S, hidden) normed layer input -> the mixer's output, same shape.
     ``place`` (models/placement.py) is asked for one thing: where the conv or
     the scan is the fused kernels, a mesh must run them on each device's own
@@ -140,7 +177,3 @@ def ssm_block(x, p: Params, cfg, place: Placement = LOCAL):
         y = (g * p["norm"].astype(F32)).astype(dtype)
     with jax.named_scope("out_proj"):
         return y @ p["out_proj"].astype(dtype)
-
-
-# what `modeling` asks of a recurrent kind's module (models/gdn.py has the same three)
-init_params, annotations, block = init_ssm_params, ssm_annotations, ssm_block

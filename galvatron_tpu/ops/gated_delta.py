@@ -636,7 +636,7 @@ def _fused_vmem_mb(r: int, dk: int, dv: int, hv: int, itemsize: int) -> float:
 def scan_path(hk: int, hv: int, dk: int, dv: int, chunk: int, dtype) -> str:
     """``"fused"`` or ``"plain"`` for a gated delta rule of these sizes, from the
     shapes and the backend alone: no flag, no environment variable, no model's
-    name. `models/gdn.block` and `models/gdn.scan_path_counts` both ask here. The
+    name. `models/gdn.block` and `models/gdn.path_counts` both ask here. The
     fused kernels take, and everything else takes the plain body:
 
     - a chip (`flash_attention._use_interpret`'s rule, the one switch of this

@@ -20,7 +20,7 @@ small ``ssd_decay`` / ``ssd_decay_bwd``): x read and y written token-major where
 the model holds them, the score blocks never outside VMEM, the state carried
 in VMEM across the chunk axis of the grid. `ssd_scan` chooses between them by
 `scan_path`, from shapes and backend alone. On a mesh GSPMD partitions the
-plain body by itself and cannot partition a Mosaic call: `models/ssm.ssm_block`
+plain body by itself and cannot partition a Mosaic call: `models/ssm.block`
 runs the fused body under ``place.shard_kernel``, each device on its own batch
 rows (tp and cp on a state-space layer are refused, so the heads and the
 sequence are whole there).
@@ -202,17 +202,6 @@ def scan_path(heads: int, head_dim: int, groups: int, state: int, chunk: int, dt
     inside = (chunk % _LANES == 0 and head_dim in (64, 128) and state % _LANES == 0 and hb > 0
               and 1.1 * _fused_vmem_mb(hb, head_dim, state, chunk, dtype.itemsize) <= fa._VMEM_EFF_MB)
     return "fused" if inside else "plain"
-
-
-def scan_path_counts(cfg) -> dict:
-    """``{"fused": n, "plain": m}``: how many of a configuration's state-space
-    layers take which body (the run's fingerprint, PERF.md §3)."""
-    counts = {"fused": 0, "plain": 0}
-    layers = sum(kind == "ssm" for kind in cfg.kinds)
-    if layers:
-        counts[scan_path(cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state,
-                         cfg.ssm_chunk, cfg.dtype)] = layers
-    return counts
 
 
 def ssd_scan(x, dt, a, b_mat, c_mat, chunk: int):
@@ -585,7 +574,7 @@ _ssd_core.defvjp(_core_fwd, _core_bwd)
 # backward, in the compute dtype, where the plain path pads it, takes K slices
 # shifted by sublanes, converts each to float32 and leaves autodiff K float32
 # pads and K full-size reductions (PERF.md §6, PR 40). The op reads a window of
-# channels out of a wider array in place (``col0``: `models/ssm.ssm_block` hands
+# channels out of a wider array in place (``col0``: `models/ssm.block` hands
 # it in_proj's whole output, and takes x, B and C as three windows, so neither
 # the slice in front nor the slices behind are copies).
 #
@@ -633,8 +622,8 @@ def _conv_vmem_mb(ts: int, tc: int, k: int, itemsize: int) -> float:
 
 def conv_path(windows, k: int, dtype) -> str:
     """``"fused"`` or ``"plain"`` for the conv + SiLU in front of a scan, from
-    the shapes and the backend alone, as `scan_path`: `models/ssm.ssm_block`
-    and the trainer's ``ssm_conv_path`` counter both ask here. ``windows``:
+    the shapes and the backend alone, as `scan_path`: `models/ssm.block`
+    and its `path_counts` both ask here. ``windows``:
     the widths of the channel groups the mixer takes apart (x, B, C). Fused:
 
     - a chip (`flash_attention._use_interpret`'s rule);
@@ -654,22 +643,6 @@ def conv_path(windows, k: int, dtype) -> str:
     ts, tc = _conv_blocks(_CONV_BLOCK_S, max(windows), 0)
     inside = 1.1 * _conv_vmem_mb(ts, tc, k, dtype.itemsize) <= fa._VMEM_EFF_MB
     return "fused" if inside else "plain"
-
-
-def conv_windows(cfg):
-    """Widths of x, B and C among the conv's channels."""
-    gn = cfg.ssm_groups * cfg.ssm_state
-    return cfg.ssm_heads * cfg.ssm_head_dim, gn, gn
-
-
-def conv_path_counts(cfg) -> dict:
-    """``{"fused": n, "plain": m}``: how many of a configuration's state-space
-    layers take which conv (the run's fingerprint, PERF.md §3)."""
-    counts = {"fused": 0, "plain": 0}
-    layers = sum(kind == "ssm" for kind in cfg.kinds)
-    if layers:
-        counts[conv_path(conv_windows(cfg), cfg.ssm_conv, cfg.dtype)] = layers
-    return counts
 
 
 def conv_silu_fused(x, w, b, col0: int = 0):

@@ -21,7 +21,6 @@ accumulation) and the shard_map pipeline schedules (pp>1).
 
 from __future__ import annotations
 
-import collections
 import functools
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional
@@ -42,7 +41,7 @@ from galvatron_tpu.core.schedules import (
     scaled_value_and_grad,
 )
 from galvatron_tpu.core.strategy import HybridParallelConfig, LayerStrategy
-from galvatron_tpu.models import modeling
+from galvatron_tpu.models import mixers, modeling
 from galvatron_tpu.models.modeling import ModelConfig
 from galvatron_tpu.parallel import placement
 from galvatron_tpu.parallel.mesh import MeshAxes, build_mesh, global_batch_spec
@@ -66,17 +65,6 @@ MOE_HELD_STATS = ("moe_held_pairs_per_token", "moe_held_rows_share")
 
 def moe_stat_names(cfg) -> tuple:
     return MOE_STATS + (MOE_HELD_STATS if cfg.moe_holds_share else ())
-
-
-#: recurrent layer kind -> the words of its refusals (build_runtime): the layers,
-#: what of the mixer carries no tp sharding, the state cp would have to pass on,
-#: and what beside the conv does not reset at a segment boundary
-_RECURRENT_REFUSALS = {
-    "ssm": ("state-space layers", "the Mamba-2 mixer's heads, conv channels and scan",
-            "the scan's state", "the scan"),
-    "gdn": ("Gated DeltaNet layers", "the mixer's heads, conv channels and the delta rule's state",
-            "the delta rule's state", "the delta rule"),
-}
 
 
 def model_param_specs(
@@ -358,75 +346,19 @@ def build_runtime(
                 "pack_sequences is not threaded through the interleaved "
                 "(vpp>1) schedule; use vpp=1 pipelines"
             )
-    for kind, (layers_of, unsharded, state, rule) in _RECURRENT_REFUSALS.items():
-        # a hybrid stack: what its recurrent layers and the interleaving do
-        # not implement is refused here, by name — nothing is silently
-        # mis-sharded
-        if kind not in cfg.kinds:
-            continue
-        with_tp = [i for i, (k, s) in enumerate(
-            zip(cfg.kinds, hp.layer_strategies[cfg.enc_layers:])) if k == kind and s.tp > 1]
-        if with_tp:
-            raise ValueError(
-                f"tensor parallelism (tp>1) is not implemented for {layers_of} "
-                f"(layers {with_tp} of this plan): {unsharded} carry no tp sharding; "
-                "use tp=1 on those layers"
-            )
-        if any(s.cp > 1 for s in hp.layer_strategies):
-            raise ValueError(
-                f"context parallelism (cp>1) is not implemented for a stack with "
-                f"{layers_of}: {state} is not passed between sequence shards; use cp=1"
-            )
-        if cfg.pack_sequences:
-            raise ValueError(
-                f"pack_sequences is not implemented for {layers_of}: the conv and "
-                f"{rule} do not reset their state at segment boundaries"
-            )
-    if hp.pp > 1 and len(set(cfg.kinds)) > 1:
-        raise ValueError(
-            "pipeline parallelism (pp>1) over interleaved layer kinds is not "
-            "implemented: the pipeline engines stack one kind of layer a stage "
-            f"position (this model: {dict(collections.Counter(cfg.kinds))}); use pp=1"
-        )
+    # what the model's layers do not implement (a recurrent kind's tp / cp /
+    # packing, pp over interleaved kinds, the dropless expert path's ep / pp / cp /
+    # fp16: models/mixers.limits) is refused here, by name: nothing is silently
+    # mis-sharded, nothing falls back to another path
+    for limit in mixers.limits(cfg):
+        at = limit.broken_by(cfg, hp)
+        if at:
+            raise ValueError(limit.sentence(at))
     if cfg.attention_multiplier is not None and any(s.cp > 1 for s in hp.layer_strategies):
         raise ValueError(
             "context parallelism (cp>1) is not implemented with attention_multiplier: "
             "the ring/Ulysses layers scale by 1/sqrt(head_dim); use cp=1"
         )
-    if cfg.moe_dropless:
-        # the sorted-rows path keeps every expert on every device and hands its
-        # auxiliary loss up through the GSPMD step; what it does not implement
-        # is refused here, by name — nothing falls back to the one-hot dispatch
-        if cfg.moe_holds_share and any(s.ep > 1 for s in hp.layer_strategies):
-            raise ValueError(
-                f"expert parallelism (ep>1) on a held share of the experts (moe_share="
-                f"{cfg.moe_share}: this copy holds {cfg.moe_held} of {cfg.moe_experts}) is "
-                "not implemented: the share IS one rank of an expert-parallel deployment "
-                "and the sorted-row path has no expert all-to-all; use ep=1"
-            )
-        if any(s.ep > 1 for s in hp.layer_strategies):
-            raise ValueError(
-                "expert parallelism (ep>1) is not implemented for the dropless "
-                "top-k MoE path (moe_router='softmax_topk'): its sorted-row "
-                "grouped GEMM has no expert all-to-all yet; use ep=1"
-            )
-        if hp.pp > 1:
-            raise ValueError(
-                "pipeline parallelism (pp>1) is not implemented for the dropless "
-                "top-k MoE path (moe_router='softmax_topk'): the pipeline engines "
-                "carry no auxiliary loss between stages; use pp=1"
-            )
-        if any(s.cp > 1 for s in hp.layer_strategies):
-            raise ValueError(
-                "context parallelism (cp>1) is not implemented for the dropless "
-                "top-k MoE path (moe_router='softmax_topk'): the ring/Ulysses "
-                "layers hand no router statistics up; use cp=1"
-            )
-        if hp.mixed_precision == "fp16":
-            raise ValueError(
-                "fp16 loss scaling is not threaded through the dropless top-k MoE "
-                "objective (moe_router='softmax_topk'); use bf16 or fp32"
-            )
     seq_len = seq_len or cfg.sample_len
 
     # the strategy's activation-recompute mode rides the model config so

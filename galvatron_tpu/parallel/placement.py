@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from galvatron_tpu.core.strategy import HybridParallelConfig, LayerStrategy
-from galvatron_tpu.models.modeling import has_recurrent_layers
+from galvatron_tpu.models.mixers import has_mixer_layers
 from galvatron_tpu.models.placement import LOCAL, Placement
 from galvatron_tpu.ops import collective_matmul as cm
 from galvatron_tpu.ops.quant import QuantTensor
@@ -220,7 +220,7 @@ def place_layer(cfg, s: LayerStrategy, mesh: Mesh, axes: MeshAxes):
         token_axes=moe_token_axes(axes, s),
         qkv_pin=s.tp > 1,
         attn_out_pin=s.dp_type == "zero3" and s.tp > 1,
-        kernel_wrap=(layer_cfg.attn_impl == "flash" or has_recurrent_layers(cfg)) and s.cp == 1,
+        kernel_wrap=(layer_cfg.attn_impl == "flash" or has_mixer_layers(cfg)) and s.cp == 1,
         tp_overlap=bool(s.tp_overlap) and s.tp > 1 and s.cp == 1,
         moe_pin=cfg.moe_experts > 0 and s.ep > 1,
         token_wrap=cfg.moe_dropless,

@@ -25,6 +25,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from galvatron_tpu.core.strategy import HybridParallelConfig, LayerStrategy, form_strategy
+from galvatron_tpu.models import mixers
 from galvatron_tpu.obs.tracing import tracer as _obs_tracer
 from galvatron_tpu.search.cost_model import (
     REMAT_FULL_FACTOR,
@@ -244,38 +245,21 @@ class SearchEngine:
                 vocab_size=space.vocab_size
                 or int(getattr(model_config, "vocab_size", 0) or 0),
             )
-        # standing exclusions of the MODEL, reported with every result: the
-        # dropless top-k MoE path runs neither expert nor context parallelism
-        # nor pipeline stages (build_runtime refuses them by name), so the
-        # enumeration leaves them out instead of emitting a plan that cannot run
-        self._standing: List[str] = []
-        if model_config is not None and getattr(model_config, "moe_dropless", False):
-            self.space = space = dataclasses.replace(
-                space, allow_ep=False, allow_cp=False, pp_choices=[1])
-            self._standing = ["dropless_topk_moe_no_ep", "dropless_topk_moe_no_cp",
-                              "dropless_topk_moe_no_pp"]
-            print(
-                "search: dropless top-k MoE model — expert parallelism (ep>1), context "
-                "parallelism (cp>1) and pipeline stages (pp>1) are not implemented for its "
-                "sorted-row path and are left out of the enumeration"
-            )
-        for kind, tag, layers_of in (("ssm", "state_space_layers", "state-space layers"),
-                                     ("gdn", "gated_delta_layers", "Gated DeltaNet layers")):
-            if model_config is None or kind not in getattr(model_config, "kinds", ()):
-                continue
-            # a hybrid stack: tensor parallelism on a recurrent layer, context
-            # parallelism through its scan and pipeline stages over interleaved
-            # layer kinds are refused by build_runtime, so the enumeration
-            # leaves them out (tp for the whole stack: the one candidate list
-            # serves every layer)
-            self.space = space = dataclasses.replace(
-                space, max_tp=1, allow_cp=False, pp_choices=[1])
-            self._standing += [f"{tag}_no_tp", f"{tag}_no_cp", "interleaved_layer_kinds_no_pp"]
-            print(
-                f"search: hybrid stack with {layers_of} — tensor parallelism (tp>1), "
-                "context parallelism (cp>1) and pipeline stages (pp>1) over the interleaved "
-                "layer kinds are not implemented and are left out of the enumeration"
-            )
+        # standing exclusions of the MODEL, reported with every result: what its
+        # layers do not implement (models/mixers.limits; build_runtime refuses the
+        # same by name), so the enumeration leaves it out instead of emitting a
+        # plan that cannot run (a degree for the whole stack: the one candidate
+        # list serves every layer)
+        left_out = [limit for limit in mixers.limits(model_config) if limit.tag is not None
+                    ] if model_config is not None else []
+        narrow = {"tp": {"max_tp": 1}, "cp": {"allow_cp": False}, "ep": {"allow_ep": False},
+                  "pp": {"pp_choices": [1]}}
+        for limit in left_out:
+            self.space = space = dataclasses.replace(space, **narrow[limit.what])
+        self._standing: List[str] = [limit.tag for limit in left_out]
+        if left_out:
+            print("search: left out of the enumeration, not implemented by this model's "
+                  "layers: " + ", ".join(f"{limit.what}>1 ({limit.tag})" for limit in left_out))
         # structural bail-outs that fired during the last sweep (multi-type
         # schedule/shape classes the engines cannot realize) — written into
         # the emitted config as `search_restrictions` the way

@@ -20,7 +20,8 @@ from dataclasses import dataclass
 from typing import Dict
 
 from galvatron_tpu.core.strategy import LayerStrategy
-from galvatron_tpu.models.modeling import ModelConfig, has_recurrent_layers
+from galvatron_tpu.models import mixers
+from galvatron_tpu.models.modeling import ModelConfig
 
 _BYTES = {"fp32": 4, "bf16": 2, "fp16": 2}
 
@@ -66,22 +67,15 @@ def moe_untp_time_fraction(cfg: ModelConfig, seq_len: int) -> float:
 def layer_param_count(cfg: ModelConfig, cross: bool = False, kind: str = "attention") -> int:
     """Exact per-layer parameter count (matches init_layer_params).
     ``cross``: enc-dec decoder layers carry a cross-attention block
-    (wq + wkv + wo + cross_norm). ``kind`` "ssm" (a hybrid stack's
-    state-space layer): the Mamba-2 mixer in place of attention; "gdn": the
-    Gated DeltaNet mixer."""
+    (wq + wkv + wo + cross_norm). ``kind``: a kind of ``mixers.MIXERS`` (a
+    hybrid stack's layer) counts that kind's mixer in place of attention."""
     h, hd = cfg.hidden_size, cfg.head_dim
     q_out, kv_out = cfg.num_heads * hd, cfg.kv_heads * hd
     attn = h * q_out + 2 * h * kv_out + q_out * h
     if cfg.attn_gate:  # the output gate's projection and the two per-head norms
         attn += h * q_out + 2 * hd
-    if kind == "ssm":
-        from galvatron_tpu.models.ssm import ssm_param_count
-
-        attn = ssm_param_count(cfg)
-    elif kind == "gdn":
-        from galvatron_tpu.models.gdn import param_count
-
-        attn = param_count(cfg)
+    if kind in mixers.MIXERS:
+        attn = mixers.module(kind).param_count(cfg)
     if cross:
         attn += h * q_out + 2 * h * kv_out + q_out * h
         attn += h if cfg.norm_type == "rms" else 2 * h  # cross_norm
@@ -195,27 +189,9 @@ def layer_activation_mb_per_sample(
         mlp = (2 if recompute else 3) * cfg.ffn * b / tp
     else:
         mlp = (1 if recompute else 2) * cfg.ffn * b / tp
-    if kind == "ssm":
-        # the mixer in place of qkv + context: the in_proj output, the conv's
-        # input and output, the scan's output and the gated product, and the
-        # decay-masked score blocks inside a chunk, which are kept: heads x
-        # chunk entries a token, float32 decays and compute-dtype scores
-        from galvatron_tpu.models.ssm import ssm_dims
-
-        d_inner, conv_dim, in_width = ssm_dims(cfg)
-        mixer = (in_width + 2 * conv_dim + 2 * d_inner) * b
-        mixer += cfg.ssm_heads * cfg.ssm_chunk * (4 + b)
-        return (repl + mixer + mlp) * S / 1e6
-    if kind == "gdn":
-        # in_proj's output, the conv's output, the delta rule's output and the
-        # gated product, and inside a chunk the float32 system, its solution's
-        # two halves and the decay-masked scores a value head
-        from galvatron_tpu.models.gdn import gdn_dims
-
-        _, value_dim, conv_dim, in_width = gdn_dims(cfg)
-        mixer = (in_width + conv_dim + 2 * value_dim) * b
-        mixer += cfg.gdn_value_heads * (
-            3 * cfg.gdn_chunk + 2 * (cfg.gdn_key_dim + cfg.gdn_value_dim)) * 4
+    if kind in mixers.MIXERS:
+        # the kind's mixer in place of qkv + context: what it keeps a token
+        mixer = mixers.module(kind).saved_bytes_per_token(cfg, b)
         return (repl + mixer + mlp) * S / 1e6
     if cfg.attn_gate:
         ctx *= 2  # the gate beside the context
@@ -242,7 +218,7 @@ def analytic_model_costs(
         return _analytic_vision_costs(cfg, peak_tflops, mfu, mixed_precision)
     if cfg.enc_layers > 0:
         return _analytic_encdec_costs(cfg, peak_tflops, mfu, mixed_precision)
-    if has_recurrent_layers(cfg):
+    if mixers.has_mixer_layers(cfg):
         return _analytic_hybrid_costs(cfg, seq_len, peak_tflops, mfu, mixed_precision)
     S = seq_len or cfg.max_seq_len
     b = _BYTES[mixed_precision]
@@ -297,10 +273,9 @@ def _analytic_hybrid_costs(
 ):
     """A hybrid stack: one layer type a KIND, keyed by layer index, so that the
     multi-layer-type search prices the published interleaving (pp = 1; the
-    search leaves pp > 1 and tp > 1 out for such a model). A state-space
-    layer's FLOPs grow linearly with the sequence (its weights and the chunked
-    scan: inside a chunk the causal half of C B^T and of scores x, a chunk's
-    state, the entering state's read-out); the attention layer's quadratically."""
+    search leaves pp > 1 and tp > 1 out for such a model). A recurrent kind's
+    FLOPs grow linearly with the sequence (its weights and what its module's
+    ``fwd_flops_per_token`` counts); the attention layer's quadratically."""
     from galvatron_tpu.search.cost_model import ProfiledLayerType, ProfiledModelCosts
 
     S = seq_len or cfg.max_seq_len
@@ -309,17 +284,8 @@ def _analytic_hybrid_costs(
 
     def make_lt(kind: str) -> ProfiledLayerType:
         flops = 2.0 * layer_active_param_count(cfg, kind) * S
-        if kind == "ssm":
-            hp_, n_ = cfg.ssm_heads * cfg.ssm_head_dim, cfg.ssm_state
-            pairs = (cfg.ssm_chunk + 1) / 2
-            flops += (2.0 * pairs * (cfg.ssm_groups * n_ + hp_) + 4.0 * hp_ * n_) * S
-        elif kind == "gdn":
-            # the chunked delta rule: K K^T and Q K^T (causal half, a key head), a
-            # value head's solve, the entering state read twice and written once,
-            # the causal half of scores V'
-            dk, dv, c = cfg.gdn_key_dim, cfg.gdn_value_dim, cfg.gdn_chunk
-            flops += (cfg.gdn_key_heads * 2.0 * (c + 1) * dk + cfg.gdn_value_heads * (
-                (c - 1.0) * (dk + dv) + 6.0 * dk * dv + (c + 1.0) * dv)) * S
+        if kind in mixers.MIXERS:
+            flops += mixers.module(kind).fwd_flops_per_token(cfg) * S
         else:
             flops += 4.0 * cfg.num_heads * cfg.head_dim * S * S
         return ProfiledLayerType(

@@ -15,7 +15,7 @@ Two execution paths behind one API:
 - **Serialized legacy path** (``engine=None``): ``generate_np`` under the
   global service lock, pending work bounded by the ``max_pending`` gate
   (excess requests fail fast with 503). Kept as the compatible single-shot
-  path and as the baseline ``bench_serving.py`` measures against.
+  path.
 
 API (POST or PUT /api, JSON body):
   {"prompts": ["..."], "tokens_to_generate": 32, "temperature": 0.0,

@@ -37,7 +37,7 @@ def tiny_hf(num_kv_heads=4):
 
 def logits_parity(hf_model, atol=2e-4):
     cfg = config_from_hf_llama(hf_model.config).replace(
-        dtype=jnp.float32, param_dtype=jnp.float32, attn_impl="xla", fused_norm=False
+        dtype=jnp.float32, param_dtype=jnp.float32, attn_impl="xla"
     )
     params = from_hf_llama(hf_model, cfg)
     tokens = np.random.RandomState(0).randint(0, cfg.vocab_size, (2, 16))
@@ -165,7 +165,7 @@ def test_hf_gpt2_logit_parity():
     torch.manual_seed(2)
     hf = transformers.GPT2LMHeadModel(hf_cfg).eval()
     cfg = config_from_hf_gpt2(hf_cfg).replace(
-        dtype=jnp.float32, param_dtype=jnp.float32, attn_impl="xla", fused_norm=False
+        dtype=jnp.float32, param_dtype=jnp.float32, attn_impl="xla"
     )
     params = from_hf_gpt2(hf, cfg)
     tokens = np.random.RandomState(2).randint(0, 96, (2, 16))
@@ -228,7 +228,7 @@ def runtime_loss_parity(hp_kwargs, n_layers=2, atol=2e-4):
     torch.manual_seed(1)
     hf = transformers.LlamaForCausalLM(cfg_hf).eval()
     cfg = config_from_hf_llama(cfg_hf).replace(
-        dtype=jnp.float32, param_dtype=jnp.float32, attn_impl="xla", fused_norm=False
+        dtype=jnp.float32, param_dtype=jnp.float32, attn_impl="xla"
     )
     params = from_hf_llama(hf, cfg)
     hp = HybridParallelConfig(
@@ -312,7 +312,7 @@ def test_to_hf_llama_roundtrip():
     for kv in (4, 2):  # blocked and GQA-interleaved unpacking
         hf = tiny_hf(num_kv_heads=kv)
         cfg = config_from_hf_llama(hf.config).replace(
-            dtype=jnp.float32, param_dtype=jnp.float32, attn_impl="xla", fused_norm=False
+            dtype=jnp.float32, param_dtype=jnp.float32, attn_impl="xla"
         )
         params = from_hf_llama(hf, cfg)
         # perturb so the export is not just the identity of the import
@@ -455,7 +455,7 @@ def baichuan_parity(alibi: bool, seed: int):
         ns["max_position_embeddings"] = 64  # 7B-style
         hf_cfg = SimpleNamespace(**ns)
     cfg = config_from_hf_baichuan(hf_cfg).replace(
-        dtype=jnp.float32, param_dtype=jnp.float32, attn_impl="xla", fused_norm=False
+        dtype=jnp.float32, param_dtype=jnp.float32, attn_impl="xla"
     )
     assert cfg.pos_embed == ("alibi" if alibi else "rope")
     sd = make_baichuan_sd(seed, 128, 64, 2, 112)

@@ -135,12 +135,12 @@ def test_scan_path_envelope(monkeypatch, name, change, want):
 
 def test_dispatch_and_the_counter_ask_scan_path(monkeypatch):
     cfg = PRESETS["qwen3-next-80b-a3b"]
-    assert gdn.scan_path_counts(cfg.replace(num_layers=4)) == {"fused": 0, "plain": 3}
-    assert gdn.scan_path_counts(PRESETS["opt-1.3b"]) == {"fused": 0, "plain": 0}
+    assert gdn.path_counts(cfg.replace(num_layers=4))["scan"] == {"fused": 0, "plain": 3}
+    assert gdn.path_counts(PRESETS["opt-1.3b"])["scan"] == {"fused": 0, "plain": 0}
     monkeypatch.setattr(flash_attention, "_use_interpret", lambda: False)
-    assert gdn.scan_path_counts(cfg.replace(num_layers=4)) == {"fused": 3, "plain": 0}
-    assert gdn.scan_path_counts(cfg.replace(num_layers=8, gdn_key_dim=64)) == {"fused": 0, "plain": 6}
+    assert gdn.path_counts(cfg.replace(num_layers=4))["scan"] == {"fused": 3, "plain": 0}
+    assert gdn.path_counts(cfg.replace(num_layers=8, gdn_key_dim=64))["scan"] == {"fused": 0, "plain": 6}
     asked = []
     monkeypatch.setattr(gdn, "scan_path", lambda *a: asked.append(a) or "plain")
-    assert gdn.scan_path_counts(cfg.replace(num_layers=4)) == {"fused": 0, "plain": 3}
+    assert gdn.path_counts(cfg.replace(num_layers=4))["scan"] == {"fused": 0, "plain": 3}
     assert asked == [(16, 32, 128, 128, 64, cfg.dtype)]

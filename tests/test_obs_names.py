@@ -27,8 +27,6 @@ KERNEL_NAMES = {
     "flash_fwd_qkv": "flash_attention.py", "flash_bwd_blocked": "flash_attention.py",
     "flash_bwd_dkv": "flash_attention.py", "flash_bwd_dq": "flash_attention.py",
     "flash_paged_decode": "flash_attention.py",
-    "fused_norm_rms_fwd": "fused_norm.py", "fused_norm_rms_bwd": "fused_norm.py",
-    "fused_norm_ln_fwd": "fused_norm.py", "fused_norm_ln_bwd": "fused_norm.py",
     "moe_gmm": "grouped_matmul.py", "moe_gmm_dlhs": "grouped_matmul.py",
     "moe_tgmm": "grouped_matmul.py",
     # the fused Mamba-2 scan (PR 34): read through the `scan` scope they run under
@@ -76,7 +74,7 @@ def test_every_pallas_call_has_a_name_from_the_table(name):
     assert name in found[KERNEL_NAMES[name]]
     # and nothing outside the table: a new kernel joins it, with its metric
     assert {n for names in found.values() for n in names} == set(KERNEL_NAMES)
-    assert all(n.startswith(("flash_fwd", "flash_bwd", "flash_paged", "fused_norm_",
+    assert all(n.startswith(("flash_fwd", "flash_bwd", "flash_paged",
                              "moe_gmm", "moe_tgmm", "moe_held_", "ssd_", "ssm_conv_", "gdn_"))
                for n in KERNEL_NAMES)
 

@@ -335,8 +335,8 @@ def test_mixer_in_bf16_keeps_the_decays_and_the_state_in_float32():
     want = gdn.block(x.astype(jnp.float32), p, cfg.replace(dtype=jnp.float32))
     assert y.dtype == jnp.bfloat16
     close(y.astype(jnp.float32), want, BF16_TOL)
-    assert gdn.scan_path_counts(cfg) == {"fused": 0, "plain": 3}
-    assert gdn.conv_path_counts(cfg) == {"fused": 0, "plain": 3}  # the CPU: the plain conv
+    assert gdn.path_counts(cfg)["scan"] == {"fused": 0, "plain": 3}
+    assert gdn.path_counts(cfg)["conv"] == {"fused": 0, "plain": 3}  # the CPU: the plain conv
 
 
 # -- (c) the shares add up -----------------------------------------------------------

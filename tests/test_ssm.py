@@ -409,25 +409,25 @@ def test_scan_path_counts_the_layers_of_a_configuration(monkeypatch):
                                                      dtype=jnp.bfloat16)
     from galvatron_tpu.ops import flash_attention
 
-    assert ssd.scan_path_counts(granite) == {"fused": 0, "plain": 9}  # no chip here
+    assert ssm.path_counts(granite)["scan"] == {"fused": 0, "plain": 9}  # no chip here
     monkeypatch.setattr(flash_attention, "_use_interpret", lambda: False)
-    assert ssd.scan_path_counts(granite) == {"fused": 9, "plain": 0}
-    assert ssd.scan_path_counts(small_cfg()) == {"fused": 0, "plain": 6}
-    assert ssd.scan_path_counts(PRESETS["llama-7b"]) == {"fused": 0, "plain": 0}
+    assert ssm.path_counts(granite)["scan"] == {"fused": 9, "plain": 0}
+    assert ssm.path_counts(small_cfg())["scan"] == {"fused": 0, "plain": 6}
+    assert ssm.path_counts(PRESETS["llama-7b"])["scan"] == {"fused": 0, "plain": 0}
 
 
 def test_the_mixer_through_the_kernels_equals_the_mixer_through_the_plain_scan(monkeypatch):
-    """`ssm_block` at sizes inside the envelope: output and the gradient of every
+    """`ssm.block` at sizes inside the envelope: output and the gradient of every
     parameter (D's skip and the conv in front included) agree between the two
     bodies, in float32."""
     cfg = small_cfg(num_layers=1, ssm_heads=4, ssm_head_dim=64, ssm_state=128, ssm_chunk=128,
                     max_seq_len=256)
-    p = ssm.init_ssm_params(jax.random.key(0), cfg)
+    p = ssm.init_params(jax.random.key(0), cfg)
     p = {k: v + 0.3 * jax.random.normal(jax.random.key(i), v.shape) if v.ndim == 1 else v
          for i, (k, v) in enumerate(p.items())}
     x = jax.random.normal(jax.random.key(7), (2, 256, cfg.hidden_size))
     w = jax.random.normal(jax.random.key(8), x.shape)
-    run = jax.value_and_grad(lambda x_, p_: jnp.sum(ssm.ssm_block(x_, p_, cfg) * w), argnums=(0, 1))
+    run = jax.value_and_grad(lambda x_, p_: jnp.sum(ssm.block(x_, p_, cfg) * w), argnums=(0, 1))
     plain = run(x, p)
     monkeypatch.setattr(ssm, "ssd_scan", ssd.ssd_scan_fused)
     fused = run(x, p)
@@ -572,7 +572,7 @@ def test_outside_the_envelope_the_plain_conv_runs_bit_for_bit(on_a_chip):
     float16, a window that is no whole lane tile: `conv_path` says plain even
     where a chip is there, and `ssm.conv_split` is then `causal_conv1d` +
     ``jax.nn.silu`` on the sliced channels to the bit."""
-    granite = ssd.conv_windows(PRESETS["granite-4.0-h-micro"])
+    granite = ssm.conv_windows(PRESETS["granite-4.0-h-micro"])
     assert granite == (4096, 128, 128)
     assert ssd.conv_path(granite, 4, jnp.bfloat16) == "fused"
     assert ssd.conv_path(granite, 2, jnp.float32) == "fused"
@@ -581,7 +581,7 @@ def test_outside_the_envelope_the_plain_conv_runs_bit_for_bit(on_a_chip):
     assert ssd.conv_path(granite, 4, jnp.float16) == "plain"  # dtype
     assert ssd.conv_path((4096, 64, 64), 4, jnp.bfloat16) == "plain"  # B and C half a tile
     cfg = small_cfg()
-    assert ssd.conv_path(ssd.conv_windows(cfg), cfg.ssm_conv, cfg.dtype) == "plain"
+    assert ssd.conv_path(ssm.conv_windows(cfg), cfg.ssm_conv, cfg.dtype) == "plain"
     d_inner, conv_dim, width = ssm.ssm_dims(cfg)
     k = jax.random.split(jax.random.key(2), 3)
     zxbcdt = jax.random.normal(k[0], (2, 72, width))
@@ -600,26 +600,26 @@ def test_conv_path_counts_the_layers_of_a_configuration(monkeypatch):
                                                      dtype=jnp.bfloat16)
     from galvatron_tpu.ops import flash_attention
 
-    assert ssd.conv_path_counts(granite) == {"fused": 0, "plain": 9}  # no chip here
+    assert ssm.path_counts(granite)["conv"] == {"fused": 0, "plain": 9}  # no chip here
     monkeypatch.setattr(flash_attention, "_use_interpret", lambda: False)
-    assert ssd.conv_path_counts(granite) == {"fused": 9, "plain": 0}
-    assert ssd.conv_path_counts(small_cfg()) == {"fused": 0, "plain": 6}
-    assert ssd.conv_path_counts(PRESETS["llama-7b"]) == {"fused": 0, "plain": 0}
+    assert ssm.path_counts(granite)["conv"] == {"fused": 9, "plain": 0}
+    assert ssm.path_counts(small_cfg())["conv"] == {"fused": 0, "plain": 6}
+    assert ssm.path_counts(PRESETS["llama-7b"])["conv"] == {"fused": 0, "plain": 0}
 
 
 def test_the_mixer_through_the_fused_conv_equals_the_mixer_through_the_plain_one(monkeypatch):
-    """`ssm_block` at sizes inside the conv's envelope (256 + 128 + 128 channels
+    """`ssm.block` at sizes inside the conv's envelope (256 + 128 + 128 channels
     read as three windows out of in_proj's 772 columns): output and the gradient
     of every parameter agree between the two convs, in float32."""
     cfg = small_cfg(num_layers=1, ssm_heads=4, ssm_head_dim=64, ssm_state=128, ssm_chunk=128,
                     max_seq_len=256)
     assert ssm.ssm_dims(cfg) == (256, 512, 772)
-    p = ssm.init_ssm_params(jax.random.key(0), cfg)
+    p = ssm.init_params(jax.random.key(0), cfg)
     p = {k: v + 0.3 * jax.random.normal(jax.random.key(i), v.shape) if v.ndim == 1 else v
          for i, (k, v) in enumerate(p.items())}
     x = jax.random.normal(jax.random.key(7), (2, 256, cfg.hidden_size))
     w = jax.random.normal(jax.random.key(8), x.shape)
-    run = jax.value_and_grad(lambda x_, p_: jnp.sum(ssm.ssm_block(x_, p_, cfg) * w), argnums=(0, 1))
+    run = jax.value_and_grad(lambda x_, p_: jnp.sum(ssm.block(x_, p_, cfg) * w), argnums=(0, 1))
     plain = run(x, p)
     calls = []
     monkeypatch.setattr(ssm, "conv_path", lambda *a: "fused")

@@ -52,12 +52,12 @@ def real_mosaic(monkeypatch):
     written to the cache but cannot be read back without a chip, and the
     next run would warn on every such entry."""
     from galvatron_tpu.aot.cache import persistent_cache_off
-    from galvatron_tpu.ops import flash_attention, fused_norm, grouped_matmul
+    from galvatron_tpu.ops import flash_attention, grouped_matmul
     from galvatron_tpu.parallel import ring
 
     # (ops/ssd.py asks flash_attention's switch, for its kernels and for its
     # choice between them and the plain scan: no switch of its own)
-    for mod in (flash_attention, fused_norm, grouped_matmul, ring):
+    for mod in (flash_attention, grouped_matmul, ring):
         monkeypatch.setattr(mod, "_use_interpret", lambda: False)
     with persistent_cache_off():
         yield
@@ -156,28 +156,6 @@ def test_flash_kernels_compile_at_7b_width(entry, grad, one_chip, real_mosaic):
     fwd_name = "flash_fwd_qkv" if entry.endswith("qkv") else "flash_fwd_blocked"
     # these and no other: no flash_fwd_grid, flash_bwd_dkv or flash_bwd_dq
     assert names == [fwd_name] * (2 if rope else 4) + ["flash_bwd_blocked"] * grad, names
-
-
-@pytest.mark.parametrize("norm", ["rmsnorm", "layernorm"])
-def test_fused_norm_kernels_compile_at_7b_width(norm, one_chip, real_mosaic):
-    """fused_rmsnorm / fused_layernorm forward+backward at h=4096 take the
-    Pallas path (h tiles the 128 lanes) and lower for the chip."""
-    from galvatron_tpu.ops import fused_norm
-
-    x = jax.ShapeDtypeStruct((B, S, 4096), jnp.bfloat16, sharding=one_chip)
-    vec = jax.ShapeDtypeStruct((4096,), jnp.float32, sharding=one_chip)
-    assert fused_norm._tiles(4096)
-    if norm == "rmsnorm":
-        def loss(x_, g_):
-            return jnp.sum(fused_norm.fused_rmsnorm(x_, g_).astype(jnp.float32))
-
-        text = _kernel_text(jax.grad(loss, argnums=(0, 1)), x, vec)
-    else:
-        def loss(x_, g_, b_):
-            return jnp.sum(fused_norm.fused_layernorm(x_, g_, b_).astype(jnp.float32))
-
-        text = _kernel_text(jax.grad(loss, argnums=(0, 1, 2)), x, vec, vec)
-    assert text.count("tpu_custom_call") >= 2  # forward and backward kernels
 
 
 _ONE_CHIP_STEP = {}
@@ -343,13 +321,13 @@ def test_granite_mixer_compiles_at_published_widths(one_chip, real_mosaic):
 
     cfg = PRESETS["granite-4.0-h-micro"].replace(mlp_recompute="off")
     assert ssm.ssm_dims(cfg) == (4096, 4352, 8512) and cfg.ssm_chunk == 256
-    shapes = jax.eval_shape(lambda k: ssm.init_ssm_params(k, cfg), jax.random.key(0))
+    shapes = jax.eval_shape(lambda k: ssm.init_params(k, cfg), jax.random.key(0))
     p = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), shapes)
     x = jax.ShapeDtypeStruct((1, 8192, 2048), jnp.bfloat16, sharding=one_chip)
 
     def loss(x_, p_):
         with jax.named_scope("layer_0"):
-            y = jax.checkpoint(lambda a, b: ssm.ssm_block(a, b, cfg))(x_, p_)
+            y = jax.checkpoint(lambda a, b: ssm.block(a, b, cfg))(x_, p_)
         return jnp.sum(y.astype(jnp.float32))
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, p).compile()
@@ -405,8 +383,8 @@ def test_qwen3_next_mixer_compiles_at_published_widths(one_chip, real_mosaic):
 
     cfg = PRESETS["qwen3-next-80b-a3b"].replace(mlp_recompute="off")
     assert gdn.gdn_dims(cfg) == (2048, 4096, 8192, 12288) and cfg.gdn_chunk == 64
-    assert gdn.conv_path_counts(cfg.replace(num_layers=4)) == {"fused": 3, "plain": 0}
-    assert gdn.scan_path_counts(cfg.replace(num_layers=4)) == {"fused": 3, "plain": 0}
+    assert gdn.path_counts(cfg.replace(num_layers=4))["conv"] == {"fused": 3, "plain": 0}
+    assert gdn.path_counts(cfg.replace(num_layers=4))["scan"] == {"fused": 3, "plain": 0}
     assert 1.1 * gated_delta._fused_vmem_mb(2, 128, 128, 32, 2) <= fa._VMEM_EFF_MB
     shapes = jax.eval_shape(lambda k: gdn.init_params(k, cfg), jax.random.key(0))
     p = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), shapes)
@@ -622,8 +600,8 @@ def test_olmoe_block_partitions_on_four_chips(topo, real_mosaic):
 
 def test_granite_layers_partition_on_four_chips(topo, real_mosaic):
     """A Mamba-2 layer (granite's head, state and chunk sizes; 16 heads), data-parallel
-    over four chips with ZeRO-3 (what `ssm_annotations` shards for, and `build_runtime` admits):
-    GSPMD cannot partition a Mosaic call, so `ssm_block` hands the fused scan to
+    over four chips with ZeRO-3 (what `ssm.annotations` shards for, and `build_runtime` admits):
+    GSPMD cannot partition a Mosaic call, so `ssm.block` hands the fused scan to
     `place.shard_kernel` and each device runs it on its own batch rows. Forward
     and backward lower and compile; the kernels keep their names."""
     from galvatron_tpu.core.strategy import HybridParallelConfig
