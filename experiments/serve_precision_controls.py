@@ -1,0 +1,115 @@
+#!/usr/bin/env python3
+"""What a latent-attention serving cell's ``correct`` reads when one part of the
+program is computed below the precision its configuration states (PR 51).
+
+    python experiments/serve_precision_controls.py --workload sarvam-105b_serve_long_above_knee \
+        --seeds 2147488001,2147488002 [--seconds 30] [--modes sound,router_bf16,cache_e4m3,int8]
+
+One process; for every seed and mode one run of the cell through the benchmark's
+own ``run_serve_cell`` at the cell's own load, the fault planted underneath it:
+
+- ``sound``: the cell as it is;
+- ``router_bf16``: the float32 router's GEMM and sigmoid computed in bfloat16
+  (``models/moe.router_scores``);
+- ``cache_e4m3``: every latent cache entry ``[c~ | k_r]`` rounded to float8 e4m3's
+  3 mantissa bits before it is written (``models/mla.project``): a cache kept
+  below bfloat16;
+- ``int8``: the engine's own per-channel int8 weights (``--serve_quant int8``), as
+  ``benchmark/control.py`` reads them.
+
+Prints what ``correct`` compared a run and, at the end, each mode's readings beside
+the limit in the traffic file (PERF.md section 6).  No run of the benchmark plants
+any of these.
+"""
+
+from __future__ import annotations
+
+import argparse
+import contextlib
+import json
+import os
+import shutil
+import sys
+import tempfile
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+INT8 = ("--serve_quant", "int8", "--quant_drift_max", "1e9")
+
+
+@contextlib.contextmanager
+def planted(mode: str):
+    """The program with ``mode``'s fault under it; every compiled program is
+    dropped on the way in and out (a jitted step traced before would keep its own)."""
+    import jax
+    import jax.numpy as jnp
+
+    from galvatron_tpu.models import mla, moe
+
+    real_scores, real_project = moe.router_scores, mla.project
+
+    def scores_bf16(xt, router, cfg):
+        x, w = xt.astype(jnp.bfloat16), router["w"].astype(jnp.bfloat16)
+        return jax.nn.sigmoid(x @ w).astype(jnp.float32)
+
+    def project_e4m3(x, p, cfg, cos_sin):
+        q_nope, q_rope, new = real_project(x, p, cfg, cos_sin)
+        bits = jax.lax.bitcast_convert_type(new.astype(jnp.bfloat16), jnp.uint16)
+        # bfloat16 keeps 7 mantissa bits, e4m3 keeps 3: round the low 4 away (half up)
+        bits = (bits + jnp.uint16(8)) & jnp.uint16(0xFFF0)
+        return q_nope, q_rope, jax.lax.bitcast_convert_type(bits, jnp.bfloat16).astype(new.dtype)
+
+    jax.clear_caches()
+    if mode == "router_bf16":
+        moe.router_scores = scores_bf16
+    elif mode == "cache_e4m3":
+        mla.project = project_e4m3
+    try:
+        yield
+    finally:
+        moe.router_scores, mla.project = real_scores, real_project
+        jax.clear_caches()
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seeds", required=True)
+    ap.add_argument("--seconds", type=float, default=30.0)
+    ap.add_argument("--modes", default="sound,router_bf16,cache_e4m3,int8")
+    args = ap.parse_args(argv)
+
+    import jax
+
+    from benchmark.lib import harness, serve
+
+    if jax.devices()[0].platform != "tpu":
+        raise SystemExit("serve_precision_controls: needs a TPU; the limits are set from chip "
+                         "readings")
+    read = {mode: [] for mode in args.modes.split(",")}
+    for seed in (int(x) for x in args.seeds.split(",")):
+        for mode in read:
+            out_dir = tempfile.mkdtemp(prefix="galvatron_controls_")
+            try:
+                with planted(mode):
+                    res = serve.run_serve_cell(
+                        ROOT, args.workload, seed=seed, seconds=args.seconds, trace=False,
+                        out_dir=out_dir, t_start=time.time(),
+                        overrides=INT8 if mode == "int8" else ())
+            finally:
+                shutil.rmtree(out_dir, ignore_errors=True)
+            read[mode].append(res["compared"]["logits_kl"])
+            print("CONTROL " + json.dumps({
+                "mode": mode, "seed": seed, "correct": res["correct"], "failed": res["failed"],
+                "tokens_per_s": res["metrics"].get("serve_tokens_per_s_per_chip", {}).get("value"),
+                **res["compared"]}), flush=True)
+    limit = harness.load_cell(ROOT, args.workload)[2]["correct"]["logits_kl_max"]
+    print("READINGS " + json.dumps({"workload": args.workload, "logits_kl_max": limit, **read}),
+          flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

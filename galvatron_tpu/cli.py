@@ -1061,19 +1061,27 @@ def _load_or_init_params(ns, cfg):
                 f"checkpoint under {ns.load} does not match the model config "
                 f"(e.g. --vocab_size/--tokenizer mismatch); got vs want: {diff}"
             )
+        if getattr(ns, "param_dtype", "fp32") != "fp32":
+            # held ONCE at the width the configuration says (a router's stays float32)
+            want = {_path_key(path): leaf.dtype
+                    for path, leaf in jax.tree_util.tree_flatten_with_path(abstract)[0]}
+            params = jax.tree_util.tree_map_with_path(
+                lambda path, leaf: leaf.astype(want[_path_key(path)]), params)
         return params
     return modeling.init_model_params(jax.random.key(0), cfg)
 
 
+def _path_key(path) -> str:
+    """A tree path with list indices and '0'-style dict keys normalized."""
+    return "/".join(str(getattr(k, "key", getattr(k, "idx", k))) for k in path)
+
+
 def _shape_map(tree):
-    """path → shape, with list indices and '0'-style dict keys normalized."""
+    """path → shape (`_path_key`)."""
     import jax
 
-    out = {}
-    for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
-        keys = [str(getattr(k, "key", getattr(k, "idx", k))) for k in path]
-        out["/".join(keys)] = tuple(getattr(leaf, "shape", ()))
-    return out
+    return {_path_key(path): tuple(getattr(leaf, "shape", ()))
+            for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]}
 
 
 if __name__ == "__main__":

@@ -404,6 +404,10 @@ def _add_generate_args(p: argparse.ArgumentParser):
                    help="attention kernel override; 'auto' keeps the model's "
                    "own default (serving never switches kernels by backend). "
                    "A program-key term: pass the same value to `cli warmup`")
+    g.add_argument("--param_dtype", type=str, default="fp32", choices=["fp32", "bf16"],
+                   help="what the weights are initialised, loaded and held in: bf16 "
+                   "holds them ONCE at the compute width (no per-step conversion); "
+                   "a router's matrix and bias stay float32. A program-key term")
     g.add_argument("--port", type=int, default=5000)
     g.add_argument("--host", type=str, default="127.0.0.1")
     # serve: continuous-batching engine (serving.Engine); 0 slots = legacy
@@ -775,6 +779,10 @@ def model_config_from_args(ns: argparse.Namespace, base=None):
     if getattr(ns, "moe_share", None):
         rank, _, of = str(ns.moe_share).partition("/")
         overrides["moe_share"] = (int(rank), int(of))
+    if getattr(ns, "param_dtype", "fp32") == "bf16":  # (generate / serve)
+        import jax.numpy as jnp
+
+        overrides["param_dtype"] = jnp.bfloat16
     if getattr(ns, "swin_depths", None):
         overrides["swin_depths"] = tuple(
             int(d) for d in str(ns.swin_depths).split(",") if d

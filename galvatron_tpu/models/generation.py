@@ -27,19 +27,38 @@ from galvatron_tpu.models.modeling import ModelConfig, Params
 
 
 class KVCache(NamedTuple):
-    """Per-layer key/value tensors, (L, B, max_len, kv_heads, head_dim)."""
+    """An attention stack's cache: per-layer key/value tensors, each (L, B,
+    max_len, kv_heads, head_dim). A stack whose layers' kind keeps a cache of its
+    own (``mixers.cache_kind``) has that kind's NamedTuple instead (latent
+    attention: ``models/mla.LatentCache``, one array (L, B, max_len, r + dr));
+    every array of either is stacked (L, B, max_len, ...)."""
 
     k: jax.Array
     v: jax.Array
 
 
-def init_kv_cache(cfg: ModelConfig, batch_size: int, max_len: int) -> KVCache:
+def init_kv_cache(cfg: ModelConfig, batch_size: int, max_len: int):
+    """The cache ``cfg``'s layers say: K and V for attention, the kind's own otherwise."""
     for limit in mixers.limits(cfg):
         # every cache of the serving stack (slots, paged pool, generate) starts here
         if limit.what == "kv_cache":
             raise ValueError(limit.sentence())
+    kind = mixers.cache_kind(cfg)
+    if kind is not None:
+        return mixers.module(kind).init_cache(cfg, cfg.num_layers, batch_size, max_len)
     shape = (cfg.num_layers, batch_size, max_len, cfg.kv_heads, cfg.head_dim)
     return KVCache(jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))
+
+
+def cache_layout(cfg: ModelConfig) -> dict:
+    """What a position costs the cache ``init_kv_cache`` makes: ``{"kind": "kv" or
+    the kind's word ("latent"), "bytes_per_position": over all layers}``."""
+    kind = mixers.cache_kind(cfg)
+    if kind is not None:
+        per_layer = mixers.module(kind).cache_bytes_per_position(cfg)
+        return {"kind": mixers.MIXERS[kind].cache, "bytes_per_position": cfg.num_layers * per_layer}
+    per_layer = 2 * cfg.kv_heads * cfg.head_dim * jnp.dtype(cfg.dtype).itemsize
+    return {"kind": "kv", "bytes_per_position": cfg.num_layers * per_layer}
 
 
 def _positions(offsets, s: int):
@@ -82,10 +101,10 @@ def _window_starts(offsets, slot, b: int):
     return [(r, offsets[r]) for r in range(b)]
 
 
-def _write_layer(stacked, layer: int, new, starts):
-    """Write layer ``layer``'s new keys or values ``new`` (B, s, kvh, hd) into
-    the stacked cache (L, Bc, Smax, kvh, hd) at ``starts``
-    (``_window_starts``), in place.
+def write_layer(stacked, layer: int, new, starts):
+    """Write layer ``layer``'s new entries ``new`` (B, s, ...) (keys or values
+    (B, s, kvh, hd); a latent (B, s, r + dr)) into the stacked cache (L, Bc, Smax,
+    ...) at ``starts`` (``_window_starts``), in place.
 
     Only ``lax.dynamic_update_slice`` on the stacked array itself at a static
     layer index keeps a donated cache where it is: slicing the layer's slab
@@ -95,24 +114,25 @@ def _write_layer(stacked, layer: int, new, starts):
     a v5e at opt-1.3b widths, 8 slots x 2048: 5.4 GiB of temporaries, and 6.0
     with the scatter, against 0.2). So rows with their own offsets take one
     update each: ``B`` is static and small."""
-    new = new.astype(stacked.dtype)[None]  # (1, B, s, kvh, hd)
+    new = new.astype(stacked.dtype)[None]  # (1, B, s, ...)
+    rest = (0,) * (stacked.ndim - 3)
     if len(starts) == 1:
         (row, pos), = starts
-        return jax.lax.dynamic_update_slice(stacked, new, (layer, row, pos, 0, 0))
+        return jax.lax.dynamic_update_slice(stacked, new, (layer, row, pos) + rest)
     for row, pos in starts:
         stacked = jax.lax.dynamic_update_slice(
             stacked, jax.lax.slice_in_dim(new, row, row + 1, axis=1),
-            (layer, row, pos, 0, 0))
+            (layer, row, pos) + rest)
     return stacked
 
 
-def _read_layer(stacked, layer: int, slot):
-    """Layer ``layer``'s keys or values for attention: every row of the cache,
-    or the one row ``slot`` (traced) as a batch of one."""
+def read_layer(stacked, layer: int, slot):
+    """Layer ``layer``'s entries for attention: every row of the cache, or the
+    one row ``slot`` (traced) as a batch of one."""
     if slot is None:
         return stacked[layer]
     return jax.lax.dynamic_slice(
-        stacked, (layer, slot, 0, 0, 0), (1, 1) + stacked.shape[2:])[0]
+        stacked, (layer, slot) + (0,) * (stacked.ndim - 2), (1, 1) + stacked.shape[2:])[0]
 
 
 # The cached forwards carry the training forward's scope names (PERF.md
@@ -147,11 +167,27 @@ def _head(x, params: Params, cfg: ModelConfig):
     return modeling.lm_head(modeling.norm(x, params["final_norm"], cfg), params, cfg)
 
 
-def forward_with_cache(params: Params, tokens, cfg: ModelConfig, cache: KVCache,
-                       offsets, slot=None):
+def _mlp_at(x, p, cfg: ModelConfig, moe_stats: Optional[list]):
+    """A layer's pre-norm and MLP; a dropless expert layer's router statistics go
+    into ``moe_stats`` where the caller keeps them."""
+    normed = modeling.norm(x, p["mlp_norm"], cfg)
+    if moe_stats is None or not (cfg.moe_dropless and "router" in p["mlp"]):
+        return modeling.mlp_block(normed, p["mlp"], cfg, train=False)
+    from galvatron_tpu.models import moe
+
+    with jax.named_scope("mlp"):
+        y, stats = moe.moe_topk_block(normed, p["mlp"], cfg)
+    moe_stats.append(stats)
+    return y
+
+
+def forward_with_cache(params: Params, tokens, cfg: ModelConfig, cache, offsets, slot=None,
+                       moe_stats: Optional[list] = None):
     """Run ``tokens`` (B, s) through the model at absolute positions
-    ``offsets``, writing the new keys and values into the cache and attending
-    over it. Returns (logits, new_cache). ``offsets`` may be traced:
+    ``offsets``, writing the new keys and values (a latent-attention stack: the
+    new latents) into the cache and attending over it. Returns (logits,
+    new_cache). ``moe_stats``: a list that takes the router's statistics of every
+    dropless expert layer (``moe.router_stats``). ``offsets`` may be traced:
 
     - a scalar: every row at the same position (``generate``'s lockstep scan);
       with ``slot`` (a traced scalar) ``tokens`` is (1, s) and lands in row
@@ -164,34 +200,40 @@ def forward_with_cache(params: Params, tokens, cfg: ModelConfig, cache: KVCache,
       prefill before any query can attend it, since causal masking keeps
       positions past a row's own offset invisible.
 
-    The stacked cache (L, B, Smax, kvh, hd) is carried whole through the
-    layers and written in place (``_write_layer``), write-then-attend; a
+    The stacked cache (every array (L, B, Smax, ...)) is carried whole through
+    the layers and written in place (``write_layer``), write-then-attend; a
     window that would cross the row's end is CLAMPED back by the update, so
     callers keep ``offset + s <= Smax``."""
     s = tokens.shape[1]
-    smax = cache.k.shape[2]
+    smax = cache[0].shape[2]
     cos_sin = _rope_at(cfg, smax, offsets, s)
     bias = _alibi_bias(cfg, smax, offsets, s)
     x = _embed_at(params, tokens, cfg, offsets)
     starts = _window_starts(offsets, slot, tokens.shape[0])
-    ks, vs = cache
+    kind = mixers.cache_kind(cfg)
+    if kind is None:
+        ks, vs = cache
     for i, p in enumerate(params["layers"]):
         with jax.named_scope(f"layer_{i}"):
             with jax.named_scope("attn"):
-                q, k, v = _project_qkv_at(x, p, cfg, cos_sin)
-                with jax.named_scope("cache_write"):
-                    ks = _write_layer(ks, i, k, starts)
-                    vs = _write_layer(vs, i, v, starts)
-                with jax.named_scope("attn_core"):
-                    o = modeling.attention_xla(
-                        q, _read_layer(ks, i, slot), _read_layer(vs, i, slot), cfg,
-                        bias=bias, q_offset=offsets)
-                with jax.named_scope("out_proj"):
-                    x = x + modeling.attn_output(o, p["attn"], cfg, x.dtype)
-            x = x + modeling.mlp_block(
-                modeling.norm(x, p["mlp_norm"], cfg), p["mlp"], cfg, train=False
-            )
-    return _head(x, params, cfg), KVCache(ks, vs)
+                if kind is not None:
+                    y, cache = mixers.module(kind).cached_block(
+                        modeling.norm(x, p["attn_norm"], cfg), p[kind], cfg, cache, i, starts,
+                        slot, offsets, cos_sin)
+                    x = x + y
+                else:
+                    q, k, v = _project_qkv_at(x, p, cfg, cos_sin)
+                    with jax.named_scope("cache_write"):
+                        ks = write_layer(ks, i, k, starts)
+                        vs = write_layer(vs, i, v, starts)
+                    with jax.named_scope("attn_core"):
+                        o = modeling.attention_xla(
+                            q, read_layer(ks, i, slot), read_layer(vs, i, slot), cfg,
+                            bias=bias, q_offset=offsets)
+                    with jax.named_scope("out_proj"):
+                        x = x + modeling.attn_output(o, p["attn"], cfg, x.dtype)
+            x = x + _mlp_at(x, p, cfg, moe_stats)
+    return _head(x, params, cfg), (cache if kind is not None else KVCache(ks, vs))
 
 
 # ---------------------------------------------------------------------------

@@ -12,7 +12,13 @@ only where a configuration has such layers: `module`) exposes
   ``fwd_flops_per_token(cfg)``: the mixer's prices (search/theoretical.py);
 - ``path_counts(cfg)`` -> ``{kernel: {"fused": n, "plain": m}}`` for the
   row's ``kernels``: which body of each the configuration's layers take (the
-  run's fingerprint, `path_counts`).
+  run's fingerprint, `path_counts`);
+- where the row says ``cache`` (the kind keeps a cache of its own in the cached
+  forwards: no ``kv_cache`` under ``lacks``): ``init_cache(cfg, layers, rows,
+  positions)`` -> a NamedTuple of arrays stacked ``(layers, rows, positions,
+  ...)``, ``cache_bytes_per_position(cfg)`` (one layer's) and ``cached_block(x,
+  p, cfg, cache, layer, starts, slot, offsets, cos_sin)`` -> ``(y, cache)``, what
+  ``models/generation.forward_with_cache`` runs in place of attention over K and V.
 
 The row holds the kind's words and, under ``lacks``, what it does not implement
 with the clause that says why. `limits` turns the rows of a configuration's
@@ -44,6 +50,7 @@ class Mixer:
     # "kv_cache") -> the clause that says why
     lacks: Mapping[str, str]
     kernels: Tuple[str, ...] = ()  # the bodies `path_counts` reports, "<kind>_<kernel>_path"
+    cache: str = ""  # "latent": what the kind's own cache holds a position ("": it has none)
 
 
 MIXERS: Dict[str, Mixer] = {entry.kind: entry for entry in (
@@ -68,12 +75,30 @@ MIXERS: Dict[str, Mixer] = {entry.kind: entry for entry in (
                                "boundaries"),
             "kv_cache": "a key/value cache holds no recurrent (conv + delta rule) state",
         }),
+    Mixer(
+        kind="mla", module="galvatron_tpu.models.mla", layer="latent-attention layer",
+        mixer="the latent-attention (MLA) mixer", tag="latent_attention_layers", cache="latent",
+        lacks={
+            "tp": ("the mixer's heads share one latent and one rotary key, and its projections "
+                   "carry no tp sharding"),
+            "cp": "the latent and its rotary key are not passed between sequence shards",
+            "pack_sequences": "the latent attention's mask does not stop at segment boundaries",
+        }),
 )}
 
 
 def module(kind: str):
     """The module of a kind's mixer."""
     return importlib.import_module(MIXERS[kind].module)
+
+
+def cache_kind(cfg) -> Optional[str]:
+    """The kind whose own cache the cached forwards of ``cfg``'s stack keep: None
+    where every layer is attention over K and V. (A stack that mixes cache layouts
+    is refused: `limits`.)"""
+    kinds = set(getattr(cfg, "kinds", ()))
+    own = [k for k in kinds if k in MIXERS and MIXERS[k].cache]
+    return own[0] if own else None
 
 
 def has_mixer_layers(cfg) -> bool:
@@ -169,6 +194,12 @@ def limits(cfg) -> List[Limit]:
                 "kv_cache", at,
                 refusal=(f"generation is not implemented for a stack with {layers}: "
                          f"{why['kv_cache']}; train-only")))
+    if len(set(kinds)) > 1 and cache_kind(cfg):
+        out.append(Limit(
+            "kv_cache", every,
+            refusal=("generation is not implemented for a stack that interleaves cache layouts "
+                     f"(this model: {dict(collections.Counter(kinds))}): the slot cache is one "
+                     "kind's; train-only")))
     if len(set(kinds)) > 1:
         out.append(Limit(
             "pp", every, tag="interleaved_layer_kinds_no_pp", code="GTA020",
@@ -181,7 +212,7 @@ def limits(cfg) -> List[Limit]:
     if getattr(cfg, "moe_dropless", False):
         # the sorted-rows path keeps every expert (or its held share) on every
         # device and hands its auxiliary loss up through the GSPMD step
-        path = "the dropless top-k MoE path (moe_router='softmax_topk')"
+        path = f"the dropless top-k MoE path (moe_router={cfg.moe_router!r})"
         if cfg.moe_holds_share:
             out.append(Limit(
                 "ep", every, tag="dropless_topk_moe_no_ep", code="GTA014",
@@ -212,5 +243,5 @@ def limits(cfg) -> List[Limit]:
         out.append(Limit(
             "fp16", every,
             refusal=("fp16 loss scaling is not threaded through the dropless top-k MoE "
-                     "objective (moe_router='softmax_topk'); use bf16 or fp32")))
+                     f"objective (moe_router={cfg.moe_router!r}); use bf16 or fp32")))
     return out

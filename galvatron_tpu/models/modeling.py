@@ -23,6 +23,7 @@ Attention dispatch mirrors the reference's core-vs-flash switch
 from __future__ import annotations
 
 import dataclasses
+import math
 from dataclasses import dataclass
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
@@ -90,9 +91,15 @@ class ModelConfig:
     # ``moe_aux_coef * E * sum_e f_e P_e`` added to the loss that is
     # differentiated (the logged loss stays the cross entropy). ep>1 and pp>1
     # are refused for it by build_runtime and left out by the search.
+    # 'sigmoid_topk' (sarvam_mla-class layers): the same dropless path with
+    # fp32 scores ``s = sigmoid(y Wg)``; the ``moe_top_k`` experts are the
+    # largest of ``s + b`` (``b``: the router's selection bias, a parameter no
+    # gradient reaches: it SELECTS only and is served as loaded; training leaves
+    # it constant), their weights ``moe_route_scale * s_e / sum_chosen s``.
     moe_router: str = "switch"
     moe_top_k: int = 1
     moe_aux_coef: float = 0.0
+    moe_route_scale: float = 1.0
     # The dropless path's further settings (Qwen3-Next-class layers; the
     # defaults are OLMoE's layer): the width of ONE routed expert where it is
     # not ``ffn`` (None: ``ffn``); the top-k weights renormalised to sum 1; a
@@ -105,7 +112,11 @@ class ModelConfig:
     moe_ffn_dim: Optional[int] = None
     moe_norm_topk: bool = False
     moe_shared_ffn_dim: int = 0
+    moe_shared_gate: bool = True  # False: the shared expert's output is added as it is
     moe_share: Tuple[int, int] = (0, 1)
+    # Leading layers whose MLP is the plain MLP of ``ffn`` where the rest are
+    # expert layers (``first_k_dense_replace``).
+    moe_dense_layers: int = 0
     # RMSNorm with a learned scale on the q and k projections, each over the
     # WHOLE projection width (all heads together) before the split into heads
     # and before rope (OLMoE; HF modeling_olmoe.py q_norm / k_norm).
@@ -130,6 +141,17 @@ class ModelConfig:
     # implement (``mixers.limits``) is refused by build_runtime and left out by
     # the search.
     layer_kinds: Tuple[str, ...] = ()
+    # Latent attention (layers of kind "mla", models/mla.py): the rank of the
+    # compressed key/value latent, the non-rotary and rotary parts of a query /
+    # key head and the value head's size. ``attn_head_dim`` is their query head
+    # (nope + rope). The latent's RMSNorm is the only q/k norm.
+    mla_kv_rank: int = 0
+    mla_nope_dim: int = 128
+    mla_rope_dim: int = 64
+    mla_v_dim: int = 128
+    # YaRN rotary scaling (``deepseek_yarn``): (factor, original positions,
+    # beta_fast, beta_slow, mscale, mscale_all_dim); empty: plain rotary.
+    rope_yarn: Tuple[float, ...] = ()
     ssm_heads: int = 0
     ssm_head_dim: int = 64
     ssm_state: int = 128
@@ -209,7 +231,7 @@ class ModelConfig:
     def moe_dropless(self) -> bool:
         """The layers are dropless top-k MoE layers, which hand the router's
         statistics up beside their activations (decoder_layer)."""
-        return self.moe_experts > 0 and self.moe_router == "softmax_topk"
+        return self.moe_experts > 0 and self.moe_router in ("softmax_topk", "sigmoid_topk")
 
     @property
     def kinds(self) -> Tuple[str, ...]:
@@ -252,7 +274,10 @@ class ModelConfig:
 
     @property
     def rotary_dim(self) -> int:
-        """Leading dims of a head that rotate (the rest pass)."""
+        """Leading dims of a head that rotate (the rest pass); a latent-attention
+        model's rotary part is a width of its own."""
+        if self.mla_kv_rank:
+            return self.mla_rope_dim
         return int(self.head_dim * self.rotary_fraction)
 
     @property
@@ -403,12 +428,14 @@ def _norm_scale_init(cfg: ModelConfig, n: int):
 
 
 def init_layer_params(key, cfg: ModelConfig, cross: bool = False,
-                      kind: str = "attention") -> Params:
+                      kind: str = "attention", dense_mlp: bool = False) -> Params:
+    """``dense_mlp``: the layer's MLP is the plain one of ``ffn`` though the
+    model's are expert layers (a leading layer: ``moe_dense_layers``)."""
     h, hd = cfg.hidden_size, cfg.head_dim
     if kind in mixers.MIXERS:
         # the mixer in place of attention; norms and MLP are an attention layer's
         k_mix, k_rest = jax.random.split(key)
-        p = init_layer_params(k_rest, cfg, cross=cross)
+        p = init_layer_params(k_rest, cfg, cross=cross, dense_mlp=dense_mlp)
         del p["attn"]
         p[kind] = mixers.module(kind).init_params(k_mix, cfg)
         return p
@@ -450,7 +477,7 @@ def init_layer_params(key, cfg: ModelConfig, cross: bool = False,
         }
         if cfg.norm_type == "layernorm":
             p["cross_norm"]["bias"] = jnp.zeros((h,), cfg.param_dtype)
-    if cfg.moe_experts > 0:
+    if cfg.moe_experts > 0 and not dense_mlp:
         from galvatron_tpu.models import moe
 
         p["mlp"] = moe.init_moe_params(ks[4], cfg)
@@ -480,12 +507,12 @@ def init_layer_params(key, cfg: ModelConfig, cross: bool = False,
 
 
 def layer_annotations(cfg: ModelConfig, cross: bool = False,
-                      kind: str = "attention") -> Params:
+                      kind: str = "attention", dense_mlp: bool = False) -> Params:
     """Logical axes per layer param: 'tp' = Megatron-sharded dim (column-out /
     row-in), 'fsdp' = the dim ZeRO shards (reference: FSDP flat-param sharding,
     galvatron/core/parallel.py:174-207)."""
     if kind in mixers.MIXERS:
-        a = layer_annotations(cfg, cross=cross)
+        a = layer_annotations(cfg, cross=cross, dense_mlp=dense_mlp)
         del a["attn"]
         a[kind] = mixers.module(kind).annotations(cfg)
         return a
@@ -520,7 +547,7 @@ def layer_annotations(cfg: ModelConfig, cross: bool = False,
         }
         if cfg.norm_type == "layernorm":
             a["cross_norm"]["bias"] = ("fsdp",)
-    if cfg.moe_experts > 0:
+    if cfg.moe_experts > 0 and not dense_mlp:
         from galvatron_tpu.models import moe
 
         a["mlp"] = moe.moe_annotations(cfg)
@@ -676,7 +703,8 @@ def init_model_params(key, cfg: ModelConfig) -> Params:
             * 0.02
         },
         "layers": [
-            init_layer_params(ks[cfg.enc_layers + i + 1], cfg, cross=cross, kind=kind)
+            init_layer_params(ks[cfg.enc_layers + i + 1], cfg, cross=cross, kind=kind,
+                              dense_mlp=i < cfg.moe_dense_layers)
             for i, kind in enumerate(cfg.kinds)
         ],
         "final_norm": {"scale": _norm_scale_init(cfg, cfg.hidden_size)},
@@ -711,7 +739,9 @@ def model_annotations(cfg: ModelConfig) -> Params:
     cross = cfg.enc_layers > 0
     a: Params = {
         "embed": {"tok": ("tp", "fsdp")},
-        "layers": [layer_annotations(cfg, cross=cross, kind=kind) for kind in cfg.kinds],
+        "layers": [layer_annotations(cfg, cross=cross, kind=kind,
+                                     dense_mlp=i < cfg.moe_dense_layers)
+                   for i, kind in enumerate(cfg.kinds)],
         "final_norm": {"scale": ("fsdp",)},
     }
     if cross:
@@ -763,12 +793,41 @@ def norm(x, p, cfg: ModelConfig):
         return _norm_impl(x, p, cfg)
 
 
-def rope_tables(cfg: ModelConfig, seq_len: int, offset: int = 0):
-    pos = np.arange(offset, offset + seq_len)
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention factor ``0.1 mscale ln(factor) + 1`` (1 without scaling)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_inv_freq(cfg: ModelConfig) -> np.ndarray:
+    """One inverse frequency a rotary pair. With ``rope_yarn`` (``deepseek_yarn``)
+    pair i blends ``theta^(-2i/d)`` and the same over ``factor`` by a linear
+    ramp between the pairs that make ``beta_fast`` and ``beta_slow`` turns over
+    the original positions (floored / ceiled, as the published code does): fast
+    pairs keep their frequency, slow ones are interpolated."""
     rot = cfg.rotary_dim  # the whole head unless rotary_fraction says less
     inv = 1.0 / (cfg.rope_theta ** (np.arange(0, rot, 2) / rot))
-    freqs = np.outer(pos, inv)  # (S, rot/2)
-    return jnp.asarray(np.cos(freqs), jnp.float32), jnp.asarray(np.sin(freqs), jnp.float32)
+    if not cfg.rope_yarn:
+        return inv
+    factor, original, beta_fast, beta_slow = cfg.rope_yarn[:4]
+
+    def pair_of(turns):  # the (fractional) pair that makes ``turns`` turns
+        return rot * math.log(original / (turns * 2 * math.pi)) / (2 * math.log(cfg.rope_theta))
+
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), rot - 1)
+    ramp = np.clip((np.arange(rot // 2) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return inv / factor * ramp + inv * (1.0 - ramp)
+
+
+def rope_tables(cfg: ModelConfig, seq_len: int, offset: int = 0):
+    pos = np.arange(offset, offset + seq_len)
+    freqs = np.outer(pos, rope_inv_freq(cfg))  # (S, rot/2)
+    scale = 1.0
+    if cfg.rope_yarn:
+        factor, _, _, _, mscale, mscale_all_dim = cfg.rope_yarn
+        scale = yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim)
+    return (jnp.asarray(np.cos(freqs) * scale, jnp.float32),
+            jnp.asarray(np.sin(freqs) * scale, jnp.float32))
 
 
 def apply_rope(x, cos, sin):
@@ -1215,7 +1274,7 @@ def mlp_block(x, p, cfg: ModelConfig, train: bool = True, place: Placement = LOC
     mlp_residual saveable policy it is the ONE saved residual of the MLP
     branch — the activation product feeding w2 is recomputed in the backward
     instead of being saved as a second full-width copy."""
-    if cfg.moe_experts > 0:
+    if cfg.moe_experts > 0 and "router" in p:  # (a leading dense layer has none)
         from galvatron_tpu.models import moe
 
         if cfg.moe_dropless:  # callers of mlp_block want activations only
@@ -1289,7 +1348,8 @@ def mlp_residual(x, p, cfg: ModelConfig, train: bool = True, place: Placement = 
     the saved compute-dtype layer input. MoE layers fall back to the plain
     branch (dispatch buffers carry their own sharding pins; the router is
     deterministic but its recompute under a policy region is unvalidated)."""
-    if cfg.moe_dropless:
+    routed = cfg.moe_experts > 0 and "router" in p["mlp"]
+    if cfg.moe_dropless and routed:
         # a dropless top-k MoE layer hands the router's statistics up beside
         # the activations: (x, (f, P)) — see decoder_layer
         from galvatron_tpu.models import moe
@@ -1298,8 +1358,11 @@ def mlp_residual(x, p, cfg: ModelConfig, train: bool = True, place: Placement = 
         with jax.named_scope("mlp"):
             y, stats = moe.moe_topk_block(normed, p["mlp"], cfg, place=place)
         return x + y, stats
+    if cfg.moe_dropless:
+        # a leading dense layer of such a model (``moe_dense_layers``): no router, no statistics
+        return mlp_residual(x, p, cfg.replace(moe_experts=0), train=train, place=place), None
     if (
-        cfg.mlp_recompute == "policy" and cfg.moe_experts == 0
+        cfg.mlp_recompute == "policy" and not routed
         # a tp_overlap layer's down seam saves the gate itself (mlp_block);
         # SwiGLU's halves are not device-local, so it keeps the region
         and (not place.tp_overlap or cfg.act_fn == "swiglu")
@@ -1353,7 +1416,8 @@ def decoder_layer(
 ):
     """One decoder layer -> x. When ``cfg.moe_dropless`` it returns
     ``(x, router_stats)`` instead: the layer's (f, P) of moe.router_stats,
-    which the load-balancing loss needs (forward_with_stats collects them).
+    which the load-balancing loss needs (forward_with_stats collects them);
+    None for a leading dense layer of such a model.
 
     ``place`` (models/placement.py; static under ``jit``) is what the layer's
     place on a mesh adds to the computation — pins, kernel ``shard_map``s,
@@ -1459,7 +1523,8 @@ def forward_with_stats(params, tokens, cfg: ModelConfig, layer_hook=None):
                 x = decoder_layer(x, lp, cfg, cos_sin, alibi, seg_ids=seg)
             if cfg.moe_dropless:
                 x, layer_stats = x
-                stats.append(layer_stats)
+                if layer_stats is not None:
+                    stats.append(layer_stats)
     with jax.named_scope("head"):
         x = norm(x, params["final_norm"], cfg)
         return lm_head(x, params, cfg), stats
@@ -2020,5 +2085,19 @@ PRESETS: Dict[str, ModelConfig] = {
     "baichuan-13b": ModelConfig(
         vocab_size=64000, hidden_size=5120, num_layers=40, num_heads=40,
         ffn_dim=13696, max_seq_len=4096, pos_embed="alibi",
+    ),
+    # sarvamai/sarvam-105b (model_type sarvam_mla): 32 layers of latent attention
+    # (64 heads, query head 192 = 128 + 64 rotary, latent 512 + one shared rotary
+    # key of 64, value head 128; RMSNorm on the latent alone), YaRN x 40 over 4096
+    # positions; layer 0 a SwiGLU MLP of 16384, then 128 experts of width 2048,
+    # sigmoid scores with a selection bias, top-8 renormalised x 2.5, one ungated
+    # shared expert of 2048; untied head. Served (models/mla.py has the cache).
+    "sarvam-105b": ModelConfig(
+        vocab_size=262144, hidden_size=4096, num_layers=32, num_heads=64, attn_head_dim=192,
+        ffn_dim=16384, max_seq_len=131072, rope_theta=10000.0, norm_eps=1e-6,
+        layer_kinds=("mla",) * 32, mla_kv_rank=512, mla_nope_dim=128, mla_rope_dim=64,
+        mla_v_dim=128, rope_yarn=(40.0, 4096, 32.0, 1.0, 1.0, 1.0),
+        moe_experts=128, moe_router="sigmoid_topk", moe_top_k=8, moe_route_scale=2.5,
+        moe_ffn_dim=2048, moe_shared_ffn_dim=2048, moe_shared_gate=False, moe_dense_layers=1,
     ),
 }

@@ -107,7 +107,7 @@ def route_top1(logits: jax.Array, capacity: int, *, sinkhorn_iters: int = 8,
 def init_moe_params(key, cfg) -> Params:
     """Router over all ``moe_experts`` + stacked expert FFN weights (leading
     dim: the experts this copy holds, ``cfg.moe_held``; all of them unless
-    ``cfg.moe_share`` says less) + the gated shared expert where there is one."""
+    ``cfg.moe_share`` says less) + the shared expert where there is one."""
     h, f, e = cfg.hidden_size, cfg.expert_ffn, cfg.moe_held
     rank, of = cfg.moe_share
     if not 0 <= rank < of or cfg.moe_experts % of:
@@ -116,11 +116,15 @@ def init_moe_params(key, cfg) -> Params:
     ks = jax.random.split(key, 4)
     scale_in = 1.0 / np.sqrt(h)
     scale_out = 1.0 / np.sqrt(f)
+    # the router stays float32 whatever the weights are held in (`_topk_local`)
     p: Params = {
-        "router": {"w": jax.random.normal(ks[0], (h, cfg.moe_experts), cfg.param_dtype) * 0.02},
+        "router": {"w": jax.random.normal(ks[0], (h, cfg.moe_experts), jnp.float32) * 0.02},
         "w1": jax.random.uniform(ks[1], (e, h, f), cfg.param_dtype, -scale_in, scale_in),
         "w2": jax.random.uniform(ks[2], (e, f, h), cfg.param_dtype, -scale_out, scale_out),
     }
+    if cfg.moe_router == "sigmoid_topk":
+        # the selection bias (aux-loss-free balancing sets it; here it is loaded or 0)
+        p["router"]["bias"] = jnp.zeros((cfg.moe_experts,), jnp.float32)
     if cfg.act_fn == "swiglu":
         p["w3"] = jax.random.uniform(ks[3], (e, h, f), cfg.param_dtype, -scale_in, scale_in)
     if cfg.moe_shared_ffn_dim:
@@ -128,11 +132,12 @@ def init_moe_params(key, cfg) -> Params:
 
         fs = cfg.moe_shared_ffn_dim
         sk = jax.random.split(jax.random.fold_in(key, 1), 3)
-        p["shared"] = {  # [gate | up] fused, down, and the gate on the whole expert
+        p["shared"] = {  # [gate | up] fused, down
             "w13": _dense_init(sk[0], h, 2 * fs, cfg.param_dtype),
             "w2": _dense_init(sk[1], fs, h, cfg.param_dtype),
-            "gate": _dense_init(sk[2], h, 1, cfg.param_dtype),
         }
+        if cfg.moe_shared_gate:  # the gate on the whole expert
+            p["shared"]["gate"] = _dense_init(sk[2], h, 1, cfg.param_dtype)
     return p
 
 
@@ -153,9 +158,13 @@ def moe_annotations(cfg) -> Params:
     }
     if cfg.act_fn == "swiglu":
         a["w3"] = ("ep", "fsdp", "tp")
+    if cfg.moe_router == "sigmoid_topk":
+        a["router"]["bias"] = (None,)
     if cfg.moe_shared_ffn_dim:
         # whole on every device like the dropless experts (tp divides nothing there)
-        a["shared"] = {"w13": ("fsdp", None), "w2": (None, "fsdp"), "gate": (None, None)}
+        a["shared"] = {"w13": ("fsdp", None), "w2": (None, "fsdp")}
+        if cfg.moe_shared_gate:
+            a["shared"]["gate"] = (None, None)
     return a
 
 
@@ -349,7 +358,8 @@ def held_experts(x, weights, w13, w2, pair_row, row_pair, row_valid, tile_group,
                  tile: int):
     """Dispatch, the experts' SwiGLU FFN and combine of a held share over
     `held_layout`, every pass bounded by the rows that hold a pair: x (T, h),
-    weights (T, k) float32, w13 (E, h, 2f) = [w1 | w3], w2 (E, f, h) -> (T, h).
+    weights (T, k) float32, w13 (E, h, 2f) = [w1 | w3] or the pair (w1, w3) as
+    stored (a GEMM each, nothing joined but their outputs), w2 (E, f, h) -> (T, h).
     What `_dispatch`, three `grouped_gemm` and `_combine` compute, with the
     permutations and the elementwise passes as kernels that stop at
     ``num_tiles`` (`ops/moe_held.py`) and one backward written out, so that no
@@ -370,7 +380,11 @@ def _held_forward(x, weights, w13, w2, pair_row, row_pair, row_valid, tile_group
         rows = moe_held.gather_rows(moe_held.to_slab(x), row_token, tile_rows, num_tiles,
                                     dtype=x.dtype, tile=tile)
     with jax.named_scope("experts"):
-        gate_up = held_matmul(rows, w13, tile_group, num_tiles, tile_m=tile)
+        if isinstance(w13, tuple):
+            gate_up = jnp.concatenate(
+                [held_matmul(rows, w, tile_group, num_tiles, tile_m=tile) for w in w13], axis=-1)
+        else:
+            gate_up = held_matmul(rows, w13, tile_group, num_tiles, tile_m=tile)
         mid = moe_held.swiglu(gate_up, num_tiles, tile=tile)
         out = held_matmul(mid, w2, tile_group, num_tiles, tile_m=tile, slab_out=True)
     with jax.named_scope("combine"):
@@ -387,6 +401,9 @@ def _held_backward(tile, res, g):
     (weights, w13, w2, rows, gate_up, mid, out, pairs, index, row_pair, row_valid, row_token,
      tile_rows, tile_group, num_tiles) = res
     dtype, experts = g.dtype, w2.shape[0]
+    pair = isinstance(w13, tuple)  # (the backward of a pair joins it: training converts)
+    if pair:
+        w13 = jnp.concatenate(w13, axis=-1)
     with jax.named_scope("combine"):
         dweights = moe_held.gather_pairs(out, pairs, num_tiles, weights, index, dtype=dtype,
                                          tile=tile, other=g)
@@ -402,6 +419,8 @@ def _held_backward(tile, res, g):
                             transpose_rhs=True, slab_out=True)
         dw13 = weight_grad(rows, dgate_up, tile_group, num_tiles, experts, tile_m=tile,
                            out_dtype=w13.dtype)
+        if pair:
+            dw13 = tuple(jnp.split(dw13, 2, axis=-1))
     with jax.named_scope("dispatch"):
         dx = moe_held.gather_pairs(drows, pairs, num_tiles, jnp.ones_like(weights), index,
                                    dtype=dtype, tile=tile)
@@ -464,6 +483,18 @@ def moe_topk_block(x: jax.Array, p: Params, cfg, tile: Optional[int] = None,
         lambda x_, p_, over: _topk_local(x_, p_, cfg, tile, over))(x, p)
 
 
+def router_scores(xt, router: Params, cfg) -> jax.Array:
+    """A token's float32 score of every expert (T, E): the softmax of the router's
+    logits, or (``sigmoid_topk``) their sigmoid, there with the GEMM at ``highest``:
+    the chip's default runs a float32 GEMM in one bf16 pass, and a choice flips
+    wherever two scores lie within a bf16 ulp. (The softmax router keeps the GEMM it
+    had: its cells' programs stay as they were.)"""
+    x32, w32 = xt.astype(jnp.float32), router["w"].astype(jnp.float32)
+    if cfg.moe_router == "sigmoid_topk":
+        return jax.nn.sigmoid(jnp.matmul(x32, w32, precision=jax.lax.Precision.HIGHEST))
+    return jax.nn.softmax(x32 @ w32, axis=-1)
+
+
 def _topk_local(x, p, cfg, tile, over):
     """The block on the tokens this device holds; ``over``: the mesh axes the
     tokens are split on (the statistics are means over all of them).  The
@@ -477,13 +508,21 @@ def _topk_local(x, p, cfg, tile, over):
     tokens, k, e = b * s, cfg.moe_top_k, cfg.moe_experts
     held_share = cfg.moe_holds_share  # trace-time: all held is the branch there always was
     xt = x.reshape(tokens, h)
+    sigmoid = cfg.moe_router == "sigmoid_topk"
     with jax.named_scope("router"):
-        logits = xt.astype(jnp.float32) @ p["router"]["w"].astype(jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
+        probs = router_scores(xt, p["router"], cfg)
     with jax.named_scope("dispatch"):
-        weights, idx = jax.lax.top_k(probs, k)  # weights: p's own values
-        if cfg.moe_norm_topk:
-            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        if sigmoid:
+            # the bias SELECTS and never weighs: top-k of s + b, weights from s,
+            # renormalised over the chosen and scaled
+            _, idx = jax.lax.top_k(
+                probs + jax.lax.stop_gradient(p["router"]["bias"].astype(jnp.float32)), k)
+            weights = jnp.take_along_axis(probs, idx, axis=-1)
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True) * cfg.moe_route_scale
+        else:
+            weights, idx = jax.lax.top_k(probs, k)  # weights: p's own values
+            if cfg.moe_norm_topk:
+                weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
         if held_share:
             layout = held_layout(idx, cfg.moe_held, tile, cfg.moe_first_held)
         else:
@@ -491,7 +530,13 @@ def _topk_local(x, p, cfg, tile, over):
     if held_share and held_path(h, cfg.expert_ffn, x.dtype) == "bounded":
         # a share of the experts pays for the pairs it holds (trace-time, by shape)
         with jax.named_scope("experts"):
-            w13 = jnp.concatenate([p["w1"], p["w3"]], axis=-1).astype(x.dtype)
+            # weights held in another type are converted every step, and that pass
+            # joins gate and up into one stack for one GEMM; held in the compute type
+            # already (``cli serve --param_dtype bf16``) nothing is converted and the
+            # join would copy every held expert every step (1 GB a layer at
+            # sarvam-105b's widths), so each of the two gets its own GEMM
+            w13 = ((p["w1"], p["w3"]) if p["w1"].dtype == x.dtype
+                   else jnp.concatenate([p["w1"], p["w3"]], axis=-1).astype(x.dtype))
             w2 = p["w2"].astype(x.dtype)
         y = held_experts(xt, weights, w13, w2, layout.pair_row, layout.row_pair,
                          layout.row_valid, layout.tile_group, layout.num_tiles, tile)
@@ -522,12 +567,14 @@ def _topk_local(x, p, cfg, tile, over):
 
 
 def _shared_expert(xt, p):
-    """``sigmoid(x w_gate) * down(silu(gate x) * up x)`` on (T, h): the expert
-    every token runs, whatever the router chose."""
+    """``down(silu(gate x) * up x)`` on (T, h), times ``sigmoid(x w_gate)`` where
+    the expert has a gate: the expert every token runs, whatever the router chose."""
     dtype = xt.dtype
     f = p["w13"].shape[1] // 2
     gu = xt @ p["w13"].astype(dtype)
     out = (jax.nn.silu(gu[:, :f]) * gu[:, f:]) @ p["w2"].astype(dtype)
+    if "gate" not in p:
+        return out
     gate = jax.nn.sigmoid(jnp.einsum(
         "th,hc->tc", xt, p["gate"].astype(dtype), preferred_element_type=jnp.float32))
     return (out.astype(jnp.float32) * gate).astype(dtype)

@@ -156,6 +156,9 @@ def qmatmul(x, qw: QuantTensor):
 _ATTN_KEYS = ("wqkv", "wo")
 _CROSS_KEYS = ("wq", "wkv", "wo")
 _MLP_KEYS = ("w13", "w1", "w2")
+#: a latent-attention layer's plain GEMMs (W_kvb is absorbed into the queries and
+#: the context a head at a time: it stays fp)
+_MLA_KEYS = ("wq", "wkva", "wo")
 
 
 def quantize_params(params: Dict[str, Any], cfg) -> Dict[str, Any]:
@@ -167,14 +170,16 @@ def quantize_params(params: Dict[str, Any], cfg) -> Dict[str, Any]:
     layers = []
     for layer in params.get("layers", []):
         lp = dict(layer)
-        for group, keys in (("attn", _ATTN_KEYS), ("cross", _CROSS_KEYS)):
+        for group, keys in (("attn", _ATTN_KEYS), ("cross", _CROSS_KEYS), ("mla", _MLA_KEYS)):
             if group in lp:
                 gp = dict(lp[group])
                 for k in keys:
                     if k in gp and not isinstance(gp[k], QuantTensor):
                         gp[k] = quantize_int8(gp[k])
                 lp[group] = gp
-        if "mlp" in lp and getattr(cfg, "moe_experts", 0) == 0:
+        # (a dense MLP: every layer of a dense model, the leading layers of an expert
+        # model; the experts' stacks go through the grouped GEMMs and stay fp)
+        if "mlp" in lp and "router" not in lp["mlp"]:
             mp = dict(lp["mlp"])
             for k in _MLP_KEYS:
                 if k in mp and not isinstance(mp[k], QuantTensor):

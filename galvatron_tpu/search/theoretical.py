@@ -80,10 +80,13 @@ def layer_param_count(cfg: ModelConfig, cross: bool = False, kind: str = "attent
         attn += h * q_out + 2 * h * kv_out + q_out * h
         attn += h if cfg.norm_type == "rms" else 2 * h  # cross_norm
     if cfg.moe_experts > 0:
-        # router + per-expert MLPs (+ the gated shared expert)
+        # router (+ its selection bias) + per-expert MLPs (+ the shared expert and its
+        # gate); a leading dense layer (moe_dense_layers) is priced as an expert layer
         mlp = h * cfg.moe_experts + moe_expert_params(cfg)
+        if cfg.moe_router == "sigmoid_topk":
+            mlp += cfg.moe_experts
         if cfg.moe_shared_ffn_dim:
-            mlp += 3 * h * cfg.moe_shared_ffn_dim + h
+            mlp += 3 * h * cfg.moe_shared_ffn_dim + (h if cfg.moe_shared_gate else 0)
     elif cfg.act_fn == "swiglu":
         mlp = 3 * h * cfg.ffn
     else:

@@ -100,7 +100,7 @@ def _keep_row(rows, logits, slot, last):
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnames=("cache", "rows"))
-def _prefill_chunk(params, cfg: ModelConfig, cache: KVCache, tokens, slot, offset,
+def _prefill_chunk(params, cfg: ModelConfig, cache, tokens, slot, offset,
                    rows, last):
     """Prefill one chunk of one request into its slot.
 
@@ -119,20 +119,38 @@ def _prefill_chunk(params, cfg: ModelConfig, cache: KVCache, tokens, slot, offse
     return _keep_row(rows, logits[0], slot, last), cache
 
 
+def _router_counters(stats, cfg: ModelConfig) -> Dict[str, jax.Array]:
+    """A forward's expert-layer counters from its layers' router statistics, over
+    the forward's tokens (rows without a request among them): the (token, expert)
+    pairs a token puts on the experts held here, and the fullest held expert's
+    pairs over the even share. Empty for a model without dropless expert layers."""
+    if not stats:
+        return {}
+    from galvatron_tpu.models import moe
+
+    held = (cfg.moe_first_held, cfg.moe_held)
+    return {"moe_held_pairs_per_token": moe.held_pairs_per_token(stats, held),
+            "moe_load_imbalance": moe.load_max_over_mean(
+                stats, cfg.moe_experts, cfg.moe_top_k, held)}
+
+
 @partial(jax.jit, static_argnames=("cfg",), donate_argnames=("cache",))
-def _decode_step(params, cfg: ModelConfig, cache: KVCache, tokens, offsets):
+def _decode_step(params, cfg: ModelConfig, cache, tokens, offsets):
     """One decode iteration over ALL slots: tokens (B,) at per-row positions
     offsets (B,). Inactive rows carry (0, 0) — their write lands at position
     0 of their own free slot and is overwritten by the next prefill before
-    any query can attend it. Returns ((B, V) next-position logits, cache)."""
+    any query can attend it. Returns ((B, V) next-position logits, cache,
+    `_router_counters` of the step: a few scalars left on the device, which
+    the engine reads while the tracer is on and no caller otherwise)."""
+    stats: list = []
     logits, cache = generation.forward_with_cache(
-        params, tokens[:, None], cfg, cache, offsets
+        params, tokens[:, None], cfg, cache, offsets, moe_stats=stats
     )
-    return logits[:, 0], cache
+    return logits[:, 0], cache, _router_counters(stats, cfg)
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnames=("cache",))
-def _decode_verify(params, cfg: ModelConfig, cache: KVCache, tokens, offsets):
+def _decode_verify(params, cfg: ModelConfig, cache, tokens, offsets):
     """Speculative verify step: tokens (B, 1+k) — column 0 is each row's
     sampled token, columns 1..k its drafted continuation — scored in ONE
     forward at per-row positions ``offsets`` (the per-row q_offset machinery
@@ -142,11 +160,13 @@ def _decode_verify(params, cfg: ModelConfig, cache: KVCache, tokens, offsets):
     sampling scores draft j+1 against. Rejected-draft k/v written at
     positions past the accepted length is overwritten by the next step's
     window before any query attends it — the same scatter-then-attend
-    discipline the (0, 0) inactive rows rely on."""
+    discipline the (0, 0) inactive rows rely on. The third value as
+    `_decode_step`'s."""
+    stats: list = []
     logits, cache = generation.forward_with_cache(
-        params, tokens, cfg, cache, offsets
+        params, tokens, cfg, cache, offsets, moe_stats=stats
     )
-    return logits, cache
+    return logits, cache, _router_counters(stats, cfg)
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnames=("pool", "rows"))
@@ -307,6 +327,14 @@ class Engine:
         # to the same HBM as the slot cache. 0 keeps the contiguous slot
         # cache. Both expose the same allocator surface to the engine.
         self.paged = int(kv_num_blocks) != 0
+        # what a position costs the cache, under its kind's name
+        self.cache_layout = generation.cache_layout(cfg)
+        if self.paged and self.cache_layout["kind"] != "kv":
+            raise ValueError(
+                "the paged backend (--kv_num_blocks) holds blocks of K and V; a stack whose "
+                f"layers keep a {self.cache_layout['kind']} cache is served from the "
+                "slot cache (kv_num_blocks 0)"
+            )
         if self.paged:
             self.slots = PagedKVCache(
                 cfg, num_slots, block_size=kv_block_size,
@@ -355,6 +383,8 @@ class Engine:
         self._drawn = np.zeros((self.slots.num_slots,), np.int32)
         self._by_slot: Dict[int, Request] = {}
         self._rng: Dict[int, np.random.Generator] = {}
+        # the expert counters of the last step the tracer saw (host numbers; none for a dense model)
+        self._router_counters: Dict[str, float] = {}
         self._busy_s = 0.0
         self._last_step_tps = 0.0
         # GALVATRON_RECOMPILE_GUARD=1 (debug/CI): after the first decode
@@ -519,6 +549,12 @@ class Engine:
             # (analysis/locks.py); the fleet router rolls these into
             # galvatron_lock_* /metrics families per replica
             extra["lock_stats"] = lock_metrics()
+        if not self.paged:
+            # the slot cache by its layers' kind: what it holds a position, in all
+            extra["cache_kind"] = self.cache_layout["kind"]
+            extra["cache_bytes"] = (self.cache_layout["bytes_per_position"]
+                                    * self.slots.num_slots * self.slots.max_seq_len)
+            extra.update(self.step_counters())
         return {
             "kv_backend": "paged" if self.paged else "slot",
             # the replica's numerics contract rides /healthz: the fleet
@@ -591,6 +627,19 @@ class Engine:
             "draining": self._draining,
             "alive": self.alive,
         }
+
+    def step_counters(self, slots: Optional[Sequence[int]] = None) -> dict:
+        """What the last decode iteration worked on: the cache's bytes a position
+        (all layers) under its kind's name, the positions live in ``slots`` (the
+        slots in use) and, of a model with dropless expert layers, the step's
+        `_router_counters` of the last iteration the tracer saw (host numbers:
+        no caller waits for the device)."""
+        layout, lengths = self.cache_layout, self.slots.lengths
+        slots = self.slots.active_slots() if slots is None else slots
+        # (on the loop's thread between two spans: no array is built here)
+        return {f"{layout['kind']}_cache_bytes_per_position": layout["bytes_per_position"],
+                f"{layout['kind']}_live_positions": sum(int(lengths[s]) for s in slots),
+                **self._router_counters}
 
     @property
     def alive(self) -> bool:
@@ -1104,7 +1153,7 @@ class Engine:
         back is their ids and the rows of the slots that tap (nothing is
         returned). The speculative engine gets the logits on the host."""
         verify = tokens.ndim == 2
-        with _obs_tracer.span(name, active=len(still), **attrs):
+        with _obs_tracer.span(name, active=len(still), **attrs) as step_span:
             with _obs_tracer.span("decode_dispatch"):
                 if self.paged:
                     smax = self.slots.max_seq_len
@@ -1123,10 +1172,13 @@ class Engine:
                     )
                 else:
                     fn = _decode_verify if verify else _decode_step
-                    logits, self.slots.cache = fn(
+                    logits, self.slots.cache, router = fn(
                         self.params, self.cfg, self.slots.cache,
                         jnp.asarray(tokens), jnp.asarray(offsets),
                     )
+                    if _obs_tracer.enabled:
+                        for value in router.values():  # (read inside decode_wait, below)
+                            value.copy_to_host_async()
                 if self._device_draw:
                     self._rows = logits
                     ids = self._dispatch_draw(still)
@@ -1141,6 +1193,14 @@ class Engine:
             # realized compute with the tracer off too
             with _obs_tracer.span("decode_wait") as sp:
                 sp.sync(ids if self._device_draw else logits)
+                if _obs_tracer.enabled:
+                    # the iteration's counters ride its span (the device is through
+                    # and their copies were started with the step: a few bytes, kept as
+                    # host numbers for `stats`; inside this child so that the three
+                    # still cover the forward)
+                    if not self.paged:
+                        self._router_counters = {k: float(v) for k, v in router.items()}
+                    step_span.set(**self.step_counters(still))
             if not self._device_draw:
                 with _obs_tracer.span("logits_readback", bytes=logits.nbytes):
                     return np.asarray(logits)

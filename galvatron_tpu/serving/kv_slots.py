@@ -1,7 +1,9 @@
 """Slot-managed persistent KV cache for the continuous-batching engine.
 
-One fixed-shape device cache — ``(L, num_slots, max_seq_len, kv_heads,
-head_dim)`` k and v — lives for the whole server lifetime; requests borrow a
+One fixed-shape device cache — what the stack's layers say
+(``generation.init_kv_cache``): ``(L, num_slots, max_seq_len, kv_heads,
+head_dim)`` k and v for attention, one ``(L, num_slots, max_seq_len, r + dr)``
+latent for latent attention — lives for the whole server lifetime; requests borrow a
 *slot* (one batch row) for their duration and return it on retirement
 (vLLM's PagedAttention manages blocks within a sequence; here the unit is
 the whole-sequence slot, which is what maps onto JAX's static-shape jit:
@@ -44,7 +46,7 @@ def effective_max_seq_len(cfg: ModelConfig, max_seq_len: Optional[int]) -> int:
             f"requested max_seq_len={requested} exceeds model cfg.max_seq_len="
             f"{cfg.max_seq_len}; clamping — the replica serves at most "
             f"{cfg.max_seq_len} tokens per request (see max_seq_len_effective "
-            "in /healthz)",
+            "in /healthz); --seq_length sets the model's bound",
             RuntimeWarning,
             stacklevel=3,
         )
@@ -53,7 +55,7 @@ def effective_max_seq_len(cfg: ModelConfig, max_seq_len: Optional[int]) -> int:
 
 
 class SlotKVCache:
-    """Fixed ``(num_slots, max_seq_len)`` KV cache + slot allocator."""
+    """Fixed ``(num_slots, max_seq_len)`` cache of the stack's kind + slot allocator."""
 
     def __init__(self, cfg: ModelConfig, num_slots: int, max_seq_len: Optional[int] = None):
         if num_slots < 1:

@@ -425,6 +425,46 @@ def test_qwen3_next_mixer_compiles_at_published_widths(one_chip, real_mosaic):
     assert not nameless, nameless
 
 
+@pytest.mark.parametrize("tokens", [32, 1024])
+def test_sarvam_held_experts_serve_unjoined_at_published_widths(one_chip, real_mosaic, tokens):
+    """The expert layer of `sarvam-105b_serve_long_above_knee` (a decode step's 32
+    tokens, a prefill chunk's 1,024; top-8 over 128 experts of width 2048, rank 0 of 4
+    holding 32, weights HELD in bf16) as the chip's compiler sees it: the bounded
+    path's kernels, gate and up a grouped GEMM each, and no pass that joins or copies
+    a stack of the experts' weights (1 GB a layer: 16 of a 42 ms decode step while
+    the two were joined every step, PERF.md section 6, PR 51)."""
+    import re
+
+    from galvatron_tpu.models import modeling, moe
+    from galvatron_tpu.models.modeling import PRESETS
+
+    cfg = PRESETS["sarvam-105b"].replace(moe_share=(0, 4), param_dtype=jnp.bfloat16,
+                                         dtype=jnp.bfloat16)
+    assert (cfg.hidden_size, cfg.expert_ffn, cfg.moe_held, cfg.moe_top_k) == (4096, 2048, 32, 8)
+    assert moe.held_path_counts(cfg.replace(num_layers=4))["worst_case"] == 0
+    shapes = jax.eval_shape(
+        lambda k: {"mlp": moe.init_moe_params(k, cfg),
+                   "mlp_norm": {"scale": jnp.zeros((cfg.hidden_size,), cfg.param_dtype)}},
+        jax.random.key(0))
+    assert shapes["mlp"]["w1"].dtype == jnp.bfloat16 and shapes["mlp"]["router"]["w"].dtype == jnp.float32
+    p = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), shapes)
+    x = jax.ShapeDtypeStruct((1, tokens, 4096), jnp.bfloat16, sharding=one_chip)
+
+    def layer(x_, p_):
+        with jax.named_scope("layer_1"):
+            return modeling.mlp_residual(x_, p_, cfg)[0]
+
+    compiled = jax.jit(layer).lower(x, p).compile()
+    text = compiled.as_text()
+    kernels = sorted(n.split(".")[0] for n, _ in _entry_work(text) if n.startswith("moe_"))
+    assert kernels == sorted(["moe_held_rows", "moe_gmm", "moe_gmm", "moe_held_swiglu", "moe_gmm",
+                              "moe_held_pairs"]), kernels
+    # no operation writes a stack of the held experts' weights (32 x 4096 x 2048 or its double)
+    entry = "\n".join(_entry_lines(text))
+    assert not re.search(r"= bf16\[32,4096,(2048|4096)\]\S* (fusion|copy|concatenate)\(", entry)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * 2**30
+
+
 def test_qwen3_next_held_experts_compile_at_published_widths(one_chip, real_mosaic):
     """The expert layer of `qwen3-next-80b-a3b_s4096` (16,384 tokens x top-10 over 512
     experts of width 512, rank 0 of 16 holding 32, a buffer of 172,288 rows), forward
