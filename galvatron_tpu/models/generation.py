@@ -61,6 +61,19 @@ def cache_layout(cfg: ModelConfig) -> dict:
     return {"kind": "kv", "bytes_per_position": cfg.num_layers * per_layer}
 
 
+def cache_read_positions(cfg: ModelConfig, lengths, rows: int, positions: int, window: int = 1):
+    """Positions ONE layer's attention of a decode window (``window`` queries a row)
+    fetches by construction from a cache of ``rows`` slots x ``positions``, given the
+    positions the windows of the rows in use attend (``lengths``): the kind's own
+    answer (host arithmetic, which body its attention takes included); None for K
+    and V slots, whose decode attention reads every slot's capacity whatever the
+    lengths (ROADMAP A3 ii)."""
+    kind = mixers.cache_kind(cfg)
+    if kind is None:
+        return None
+    return mixers.module(kind).cache_read_positions(cfg, lengths, rows, positions, window)
+
+
 def _positions(offsets, s: int):
     """Absolute positions of ``s`` new tokens: (s,) for a scalar offset (the
     same for every row), (B, s) for a (B,) one."""

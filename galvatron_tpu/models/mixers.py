@@ -16,7 +16,9 @@ only where a configuration has such layers: `module`) exposes
 - where the row says ``cache`` (the kind keeps a cache of its own in the cached
   forwards: no ``kv_cache`` under ``lacks``): ``init_cache(cfg, layers, rows,
   positions)`` -> a NamedTuple of arrays stacked ``(layers, rows, positions,
-  ...)``, ``cache_bytes_per_position(cfg)`` (one layer's) and ``cached_block(x,
+  ...)``, ``cache_bytes_per_position(cfg)`` (one layer's), ``cache_read_positions(
+  cfg, lengths, rows, positions, window)`` (what a decode window's attention
+  fetches of one layer, by construction) and ``cached_block(x,
   p, cfg, cache, layer, starts, slot, offsets, cos_sin)`` -> ``(y, cache)``, what
   ``models/generation.forward_with_cache`` runs in place of attention over K and V.
 

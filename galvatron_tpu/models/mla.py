@@ -23,11 +23,18 @@ replaces. `cached_block` has two forms of the same mathematics:
 - every other window (a decode step, the verify window, `generate`) takes the
   ABSORBED form: ``q_nope W_kvb,k^T`` against ``c~``, ``q_rope`` against ``k_r``,
   the probabilities times ``c~``, then ``W_kvb,v``: no K or V of a cached
-  position is ever materialised.
+  position is ever materialised. The two products with ``W_kvb`` are XLA's; the
+  middle (scores, softmax, probabilities times ``c~``) is the kernel
+  ``mla_decode`` (``ops/mla_decode.py``) where `mla_decode.decode_path` says so (a
+  chip, slots of whole key blocks): it reads the stacked cache in place, a row up
+  to its own length, all heads off one block of the latent. Outside that envelope
+  the middle is XLA's over every slot's capacity (`_plain_context`), which is also
+  the kernel's reference.
 
 Scopes under ``attn``: ``qkv_proj``, ``cache_write``, ``attn_core`` (>
 ``absorb``: the two absorbed products; > ``expand``: the chunk form's ``W_kvb``
-expansion), ``out_proj`` (PERF.md §3; the ``mla_*`` benchmark metrics read them).
+expansion; the kernel ``mla_decode`` directly under it), ``out_proj`` (PERF.md §3;
+the ``mla_*`` benchmark metrics read them).
 """
 
 from __future__ import annotations
@@ -40,6 +47,7 @@ import jax.numpy as jnp
 
 from galvatron_tpu.models import modeling
 from galvatron_tpu.models.placement import LOCAL, Placement
+from galvatron_tpu.ops import mla_decode
 from galvatron_tpu.ops.quant import QuantTensor, qmatmul
 
 Params = Dict[str, Any]
@@ -181,21 +189,74 @@ def attend_expanded(q_nope, q_rope, latent, p: Params, cfg, q_pos):
     return jnp.einsum("bnqk,bknd->bqnd", probs, v)
 
 
-def attend_absorbed(q_nope, q_rope, latent, p: Params, cfg, q_pos):
-    """The ABSORBED form over ``latent`` (B, K, r + dr) -> (B, s, n, dv): the keys'
-    expansion moved onto the queries and the values' onto the context."""
-    _, dn, _, _, r = dims(cfg)
-    wkvb = _kvb(p, cfg, latent.dtype)
+def _absorbed_queries(q_nope, q_rope, wkvb_k):
+    """``[q_nope W_kvb,k^T | q_rope]`` (B, s, n, r + dr): the queries against ``[c~ | k_r]``."""
     with jax.named_scope("absorb"):
-        q_lat = jnp.einsum("bqnd,rnd->bqnr", q_nope, wkvb[..., :dn])
-    q_cat = jnp.concatenate([q_lat, q_rope], axis=-1)  # against [c~ | k_r]: one pass
+        q_lat = jnp.einsum("bqnd,rnd->bqnr", q_nope, wkvb_k)
+    return jnp.concatenate([q_lat, q_rope], axis=-1)
+
+
+def _absorbed_values(ctx, wkvb_v):
+    """The context over the latent (B, s, n, r) through ``W_kvb,v`` -> (B, s, n, dv)."""
+    with jax.named_scope("absorb"):
+        return jnp.einsum("bqnr,rnd->bqnd", ctx, wkvb_v)
+
+
+def _plain_context(q_cat, latent, q_pos, cfg):
+    """The absorbed form's middle as XLA runs it, over ALL of ``latent`` (B, K,
+    r + dr): float32 scores of every position, a softmax, the probabilities in the
+    compute type times the latent -> (B, s, n, r). What runs outside the kernel's
+    envelope (`mla_decode.decode_path`), and the kernel's reference."""
     scores = jnp.einsum("bqnc,bkc->bnqk", q_cat, latent, preferred_element_type=F32)
     scores = jnp.where(_allowed(q_pos, jnp.arange(latent.shape[1])),
                        scores * softmax_scale(cfg), _MASKED)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q_nope.dtype)
-    ctx = jnp.einsum("bnqk,bkc->bqnc", probs, latent)[..., :r]
-    with jax.named_scope("absorb"):
-        return jnp.einsum("bqnr,rnd->bqnd", ctx, wkvb[..., dn:])
+    probs = jax.nn.softmax(scores, axis=-1).astype(q_cat.dtype)
+    return jnp.einsum("bnqk,bkc->bqnc", probs, latent)[..., :cfg.mla_kv_rank]
+
+
+def attend_absorbed(q_nope, q_rope, latent, p: Params, cfg, q_pos):
+    """The ABSORBED form over ``latent`` (B, K, r + dr) -> (B, s, n, dv): the keys'
+    expansion moved onto the queries and the values' onto the context."""
+    dn = cfg.mla_nope_dim
+    wkvb = _kvb(p, cfg, latent.dtype)
+    q_cat = _absorbed_queries(q_nope, q_rope, wkvb[..., :dn])  # against [c~ | k_r]: one pass
+    return _absorbed_values(_plain_context(q_cat, latent, q_pos, cfg), wkvb[..., dn:])
+
+
+def attend_window(q_nope, q_rope, stacked, layer: int, offsets, p: Params, cfg):
+    """`attend_absorbed` for the windows at ``offsets`` (scalar | (B,)) of rows [0, B)
+    of the stacked cache. Inside `mla_decode.decode_path`'s envelope the middle is
+    the kernel `mla_decode`, which reads the stacked cache in place and a row up to
+    its window's end; outside it the plain body over the layer's whole slab."""
+    from galvatron_tpu.models import generation
+
+    b, s, n = q_nope.shape[:3]
+    dn, r = cfg.mla_nope_dim, cfg.mla_kv_rank
+    wkvb = _kvb(p, cfg, stacked.dtype)
+    q_cat = _absorbed_queries(q_nope, q_rope, wkvb[..., :dn])
+    first = jnp.broadcast_to(jnp.reshape(jnp.asarray(offsets, jnp.int32), (-1,)), (b,))
+    if mla_decode.decode_path(*stacked.shape[2:], s * n, r, stacked.dtype) == "kernel":
+        ctx = mla_decode.latent_attention(q_cat, stacked, layer, first, rank=r,
+                                          scale=softmax_scale(cfg))
+    else:
+        ctx = _plain_context(q_cat, generation.read_layer(stacked, layer, None),
+                             first[:, None] + jnp.arange(s)[None], cfg)
+    return _absorbed_values(ctx, wkvb[..., dn:])
+
+
+def cache_read_positions(cfg, lengths, rows: int, positions: int, window: int = 1) -> int:
+    """Positions ONE layer's attention of a decode window of ``window`` queries a
+    row fetches by construction, of a cache of ``rows`` slots x ``positions``:
+    ``lengths`` are the positions the windows of the rows in use attend, a row out of
+    use attends position 0 of its free slot. The kernel fetches a row's length
+    rounded up to the key block, the plain body every row's capacity (host
+    arithmetic; `attend_window`'s own choice of body)."""
+    path = mla_decode.decode_path(positions, cfg.mla_kv_rank + cfg.mla_rope_dim,
+                                  window * cfg.num_heads, cfg.mla_kv_rank, cfg.dtype)
+    if path != "kernel":
+        return rows * positions
+    block = mla_decode.KEY_BLOCK
+    return (sum(-(-int(n) // block) for n in lengths) + rows - len(lengths)) * block
 
 
 def key_block(positions: int) -> int:
@@ -260,7 +321,6 @@ def cached_block(x, p: Params, cfg, cache: LatentCache, layer: int, starts, slot
     otherwise. -> (y, cache)."""
     from galvatron_tpu.models import generation
 
-    s = x.shape[1]
     q_nope, q_rope, new = project(x, p, cfg, cos_sin)
     with jax.named_scope("cache_write"):
         stacked = generation.write_layer(cache.latent, layer, new, starts)
@@ -268,7 +328,5 @@ def cached_block(x, p: Params, cfg, cache: LatentCache, layer: int, starts, slot
         if slot is not None:
             o = attend_chunk(q_nope, q_rope, stacked, layer, slot, offsets, p, cfg)
         else:
-            q_pos = jnp.reshape(jnp.asarray(offsets), (-1, 1)) + jnp.arange(s)[None]
-            o = attend_absorbed(q_nope, q_rope, generation.read_layer(stacked, layer, None),
-                                p, cfg, q_pos)
+            o = attend_window(q_nope, q_rope, stacked, layer, offsets, p, cfg)
     return output(o, p, x.dtype), LatentCache(stacked)

@@ -1,0 +1,173 @@
+"""A decode window's latent attention (MLA, absorbed form) over the stacked latent
+slot cache, as one Pallas kernel bounded a row by the row's own length.
+
+    ctx[b, i] = softmax_j<=pos(b, i) (q_cat[b, i] . latent[layer, b, j] * scale) latent[layer, b, j, :r]
+
+``q_cat`` is ``[q_nope W_kvb,k^T | q_rope]`` (B, s, n, r + dr) as
+``models/mla.py`` builds it, ``latent`` the cache ``(layers, rows, positions,
+r + dr)`` WHOLE: the kernel's index map names ``layer`` (a prefetched scalar, so
+the five layers of a step share one traced body, `grouped_matmul.traced_once`),
+the row and a block of ``KEY_BLOCK`` keys, and nothing copies a layer's slab out
+(XLA would, handed ``stacked[layer]``: 604 MB a layer at 32 x 16,384 x 576).
+
+In place means in the layout the chip keeps: for a width that is no multiple of
+128 (576) its compiler lays a slot out with the POSITIONS on the lanes and the
+r + dr values on the sublanes (``{2,3,1,0:T(8,128)(2,1)}``; probed for a described
+v5e: 576, 320 and 192 wide so, 512 and 640 row-major; PERF.md section 6, PR 52). So
+the kernel is handed ``swapaxes(stacked, 2, 3)``, which is a bitcast of that, and
+its blocks are (r + dr, Tk): scores = q (s x n, r + dr) x block, context =
+e (s x n, Tk) x block[:r]^T. Handed the cache position-major the compiler copies all
+of it in and out every step; `decode_path` keeps a row-major width on the plain
+body for that reason, and ``tests/test_topology_aot.py`` holds the compiled step to
+no copy of a slab.
+
+A grid over (row, key block). The rows' first query positions are prefetched;
+a row's window attends ``offset + s`` positions. A block past the row's last
+live block is not fetched (its index map names the last live block again, so no
+DMA is issued) and not computed; a block whose every key lies at or before the
+row's first query takes the body without a mask; the last live block masks the
+keys past each query's position and zeroes the VALUES past the window (what a
+free position holds is never counted: a 0 probability times a NaN is a NaN).
+All n heads of a row share the one latent block; the softmax runs over the blocks
+with a float32 maximum, sum and accumulator, the exponentials cast to the compute
+type for the second product (the plain body's precision,
+``models/mla._plain_context``).
+
+The ``pl.pallas_call`` name ``mla_decode`` is what a device trace shows under
+``attn_core`` (PERF.md section 3).
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from galvatron_tpu.ops import flash_attention as fa
+from galvatron_tpu.ops.grouped_matmul import traced_once
+
+F32 = jnp.float32
+_LANES = 128
+#: keys a grid step fetches and attends
+KEY_BLOCK = 1024
+#: query rows (s x n) a row's window may hold: the float32 scores (rows, KEY_BLOCK)
+#: and accumulator (rows, r) are 2 + 1 MiB of VMEM at 512
+MAX_QUERY_ROWS = 512
+
+
+def decode_path(positions: int, width: int, query_rows: int, rank: int, dtype) -> str:
+    """``"kernel"`` or ``"plain"`` for a decode window over slots of ``positions``
+    keys of ``width`` = r + dr values with ``query_rows`` = s x n queries a row, from
+    the shapes and the backend alone: no flag, no environment variable, no model's
+    name. `models/mla.attend_window` and `models/mla.cache_read_positions` both ask
+    here. The kernel takes a TPU, or the CPU (interpreted:
+    `flash_attention._use_interpret`, the one switch of this repo's kernels); a
+    capacity of whole key blocks; at most ``MAX_QUERY_ROWS`` query rows; bf16 or
+    float32; and, compiled, a rank of whole lane tiles and a width that is NOT one
+    (the chip then keeps the positions on the lanes, the layout the kernel reads in
+    place: the module's docstring). Everything else keeps the plain body."""
+    if jax.default_backend() not in ("tpu", "cpu"):
+        return "plain"
+    if jnp.dtype(dtype) not in (jnp.bfloat16, jnp.float32):
+        return "plain"
+    laid_out = fa._use_interpret() or (rank % _LANES == 0 and width % _LANES != 0)
+    inside = positions % KEY_BLOCK == 0 and query_rows <= MAX_QUERY_ROWS and laid_out
+    return "kernel" if inside else "plain"
+
+
+def _kernel(layer_ref, first_ref, q_ref, kt_ref, o_ref, m_ref, l_ref, acc_ref, *,
+            scale: float, block_k: int, heads: int, window: int, rank: int):
+    del layer_ref  # (the index map's)
+    row, j = pl.program_id(0), pl.program_id(1)
+    first = first_ref[row]  # the first query's position
+    length = first + window  # the positions the row's window attends
+    start = j * block_k
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, fa.NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def accumulate(masked: bool):
+        q, kt = q_ref[...], kt_ref[...]  # (s x n, r + dr), (r + dr, Tk)
+        scores = jnp.dot(q, kt, preferred_element_type=F32) * scale
+        values = kt[:rank]
+        if masked:
+            k_pos = start + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+            q_pos = first + jax.lax.broadcasted_iota(jnp.int32, (q.shape[0], 1), 0) // heads
+            scores = jnp.where(k_pos <= q_pos, scores, fa.NEG_INF)
+            values = jnp.where(k_pos < length, values, jnp.zeros_like(values))
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
+        shrink = jnp.exp(m_prev - m_new)
+        e = jnp.exp(scores - m_new)
+        m_ref[...] = m_new
+        l_ref[...] = l_ref[...] * shrink + jnp.sum(e, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * shrink + jax.lax.dot_general(
+            e.astype(values.dtype), values, (((1,), (1,)), ((), ())),
+            preferred_element_type=F32)
+
+    # (block 0 holds position 0, which every query sees: the maximum is real from
+    # the first block on)
+    whole = start + block_k <= first + 1
+    pl.when(whole)(functools.partial(accumulate, False))
+    pl.when(jnp.logical_and(jnp.logical_not(whole), start < length))(
+        functools.partial(accumulate, True))
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finalize():
+        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def _attend(layer, first, q, stacked_t, *, scale: float, block_k: int, heads: int, rank: int,
+            interpret: bool):
+    """``q`` (B, s x n, r + dr), rows of a window query-major, against ``stacked_t``
+    (layers, rows, r + dr, positions); -> (B, s x n, r)."""
+    b, rows, width = q.shape
+    positions = stacked_t.shape[3]
+    blocks = positions // block_k
+    window = rows // heads
+
+    def live_block(row, j, layer_ref, first_ref):
+        last = jnp.minimum((first_ref[row] + window - 1) // block_k, blocks - 1)
+        return layer_ref[0], row, 0, jnp.minimum(j, last)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, blocks),
+        in_specs=[
+            pl.BlockSpec((None, rows, width), lambda row, j, *_: (row, 0, 0)),
+            pl.BlockSpec((None, None, width, block_k), live_block),
+        ],
+        out_specs=pl.BlockSpec((None, rows, rank), lambda row, j, *_: (row, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((rows, 1), F32), pltpu.VMEM((rows, 1), F32),
+                        pltpu.VMEM((rows, rank), F32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_kernel, scale=scale, block_k=block_k, heads=heads, window=window,
+                          rank=rank),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, rows, rank), q.dtype),
+        compiler_params=fa._compiler_params(dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="mla_decode",
+    )(layer, first, q, stacked_t)
+
+
+def latent_attention(q_cat, stacked, layer: int, first, *, rank: int, scale: float,
+                     block_k: Optional[int] = None):
+    """The context over the latent of the windows whose first queries stand at
+    ``first`` (B,): ``q_cat`` (B, s, n, r + dr) against rows [0, B) of layer
+    ``layer`` of ``stacked`` (layers, rows >= B, positions, r + dr) -> (B, s, n, r)
+    in ``q_cat``'s type. ``block_k`` (None: ``KEY_BLOCK``) divides the positions."""
+    b, s, n, width = q_cat.shape
+    out = traced_once(
+        _attend, jnp.full((1,), layer, jnp.int32), first.astype(jnp.int32),
+        q_cat.reshape(b, s * n, width), jnp.swapaxes(stacked, 2, 3), scale=float(scale),
+        block_k=block_k or KEY_BLOCK, heads=n, rank=rank, interpret=fa._use_interpret())
+    return out.reshape(b, s, n, rank)

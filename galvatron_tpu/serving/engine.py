@@ -628,18 +628,27 @@ class Engine:
             "alive": self.alive,
         }
 
-    def step_counters(self, slots: Optional[Sequence[int]] = None) -> dict:
+    def step_counters(self, slots: Optional[Sequence[int]] = None, window: int = 1) -> dict:
         """What the last decode iteration worked on: the cache's bytes a position
         (all layers) under its kind's name, the positions live in ``slots`` (the
-        slots in use) and, of a model with dropless expert layers, the step's
-        `_router_counters` of the last iteration the tracer saw (host numbers:
-        no caller waits for the device)."""
+        slots in use), of a kind with a cache of its own the positions a layer's
+        attention FETCHES for them by construction (a window of ``window`` queries
+        a row; `generation.cache_read_positions`: live over read is the share of
+        the fetched bytes that were needed) and, of a model with dropless expert
+        layers, the step's `_router_counters` of the last iteration the tracer saw
+        (host numbers: no caller waits for the device)."""
         layout, lengths = self.cache_layout, self.slots.lengths
         slots = self.slots.active_slots() if slots is None else slots
         # (on the loop's thread between two spans: no array is built here)
-        return {f"{layout['kind']}_cache_bytes_per_position": layout["bytes_per_position"],
-                f"{layout['kind']}_live_positions": sum(int(lengths[s]) for s in slots),
-                **self._router_counters}
+        live = [int(lengths[s]) for s in slots]
+        out = {f"{layout['kind']}_cache_bytes_per_position": layout["bytes_per_position"],
+               f"{layout['kind']}_live_positions": sum(live)}
+        read = generation.cache_read_positions(
+            self.cfg, [n + window - 1 for n in live], self.slots.num_slots,
+            self.slots.max_seq_len, window)
+        if read is not None:
+            out[f"{layout['kind']}_read_positions"] = read
+        return {**out, **self._router_counters}
 
     @property
     def alive(self) -> bool:
@@ -1200,7 +1209,8 @@ class Engine:
                     # still cover the forward)
                     if not self.paged:
                         self._router_counters = {k: float(v) for k, v in router.items()}
-                    step_span.set(**self.step_counters(still))
+                    step_span.set(**self.step_counters(
+                        still, tokens.shape[1] if verify else 1))
             if not self._device_draw:
                 with _obs_tracer.span("logits_readback", bytes=logits.nbytes):
                     return np.asarray(logits)

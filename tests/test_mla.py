@@ -19,6 +19,7 @@ from galvatron_tpu.core.optim import AdamConfig
 from galvatron_tpu.core.strategy import HybridParallelConfig, LayerStrategy
 from galvatron_tpu.models import generation, mixers, mla, modeling, moe
 from galvatron_tpu.models.modeling import PRESETS
+from galvatron_tpu.ops import mla_decode
 from galvatron_tpu.parallel.hybrid import build_runtime
 from galvatron_tpu.parallel.mesh import build_mesh
 
@@ -225,6 +226,109 @@ def test_lockstep_generation_runs_over_the_latent_cache():
     assert out.shape == (2, 14)
     logits = modeling.forward(params, out[:, :-1], cfg)
     np.testing.assert_array_equal(np.asarray(jnp.argmax(logits[:, 7:], -1)), np.asarray(out[:, 8:]))
+
+
+# --- the decode kernel (ops/mla_decode.py; interpreted on the CPU) -------------------------
+
+
+def _window_case(cfg, window, lengths, positions=64, seed=0):
+    """A layer's parameters, a stacked cache of 3 layers x len(lengths) rows, and the
+    projected queries of a window of ``window`` tokens ending each row's length."""
+    p = mla.init_params(jax.random.key(seed), cfg)
+    rows, width = len(lengths), cfg.mla_kv_rank + cfg.mla_rope_dim
+    stacked = jax.random.normal(jax.random.key(seed + 1), (3, rows, positions, width), cfg.dtype)
+    x = jax.random.normal(jax.random.key(seed + 2), (rows, window, cfg.hidden_size), cfg.dtype)
+    first = jnp.asarray([max(n - window, 0) for n in lengths], jnp.int32)
+    pos = first[:, None] + jnp.arange(window)[None]
+    cos_all, sin_all = modeling.rope_tables(cfg, positions)
+    q_nope, q_rope, _ = mla.project(x, p, cfg, (cos_all[pos], sin_all[pos]))
+    return p, stacked, q_nope, q_rope, first
+
+
+@pytest.mark.parametrize("block", [16, 32])
+@pytest.mark.parametrize("window", [1, 4], ids=["decode", "verify4"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_the_decode_kernel_is_the_plain_absorbed_body(monkeypatch, dtype, window, block):
+    """`attend_window` through the kernel against `attend_absorbed` over the layer's
+    slab: rows of length 1 (the window's own where it is longer), a key block less
+    one, a block, a block plus one and the slot's capacity, in one batch."""
+    monkeypatch.setattr(mla_decode, "KEY_BLOCK", block)
+    cfg = small_cfg(dtype=dtype)
+    lengths = [1, block - 1, block, block + 1, 64]
+    p, stacked, q_nope, q_rope, first = _window_case(cfg, window, lengths)
+    assert mla_decode.decode_path(64, 24, window * cfg.num_heads, 16, dtype) == "kernel"
+    got = jax.jit(lambda *t: mla.attend_window(*t, 1, first, p, cfg))(q_nope, q_rope, stacked)
+    want = mla.attend_absorbed(q_nope, q_rope, stacked[1], p, cfg,
+                               first[:, None] + jnp.arange(window)[None])
+    assert got.shape == (5, window, 4, 12) and got.dtype == dtype
+    close(got.astype(jnp.float32), want.astype(jnp.float32), F32_TOL if dtype == jnp.float32 else 2e-2)
+
+
+@pytest.mark.parametrize("window", [1, 4], ids=["decode", "verify4"])
+def test_what_lies_past_a_rows_length_is_never_read_or_never_counted(monkeypatch, window):
+    """Every position past each row's window filled with NaN: the kernel's outputs are
+    bit for bit the clean cache's (blocks past the last live one are not fetched, and
+    in the last live one such keys are masked and such values zeroed)."""
+    monkeypatch.setattr(mla_decode, "KEY_BLOCK", 16)
+    cfg = small_cfg()
+    lengths = [window, 15, 16, 17, 33, 64]
+    p, stacked, q_nope, q_rope, first = _window_case(cfg, window, lengths)
+    past = jnp.arange(64)[None, :] >= (first + window)[:, None]
+    dirty = jnp.where(past[None, :, :, None], jnp.nan, stacked)
+    assert bool(jnp.isnan(dirty[1, 0, window:]).all()) and not bool(jnp.isnan(dirty[:, 5]).any())
+    attend = jax.jit(lambda c: mla.attend_window(q_nope, q_rope, c, 1, first, p, cfg))
+    got, clean = attend(dirty), attend(stacked)
+    assert bool(jnp.isfinite(got).all())
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(clean))
+
+
+@pytest.mark.parametrize("why", ["capacity", "backend", "query_rows", "layout"])
+def test_outside_the_kernels_envelope_the_plain_body_runs_and_the_counter_says_so(monkeypatch, why):
+    """A capacity that is no multiple of the key block, a backend without the kernel,
+    a window of more query rows than the accumulator holds, compiled a width the chip
+    keeps row-major: the plain body over the whole slab (the kernel is not called),
+    `latent_read_positions` rows x capacity."""
+    monkeypatch.setattr(mla_decode, "KEY_BLOCK", 16)
+    cfg, positions, window = small_cfg(), 64, 1
+    if why == "capacity":
+        positions = 40
+    elif why == "backend":
+        monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    elif why == "query_rows":
+        window = 4
+        monkeypatch.setattr(mla_decode, "MAX_QUERY_ROWS", 8)
+    else:
+        from galvatron_tpu.ops import flash_attention
+
+        monkeypatch.setattr(flash_attention, "_use_interpret", lambda: False)
+        monkeypatch.setattr(mla_decode, "KEY_BLOCK", 1024)
+        # (the cell's slots take the kernel compiled; 640 wide they would lie row-major)
+        assert mla_decode.decode_path(16384, 576, 64, 512, jnp.bfloat16) == "kernel"
+        assert mla_decode.decode_path(16384, 640, 64, 512, jnp.bfloat16) == "plain"
+        positions = 1024
+    lengths = [5, 17, 33]
+    assert mla_decode.decode_path(positions, 24, window * 4, 16, cfg.dtype) == "plain"
+    assert mla.cache_read_positions(cfg, lengths, 4, positions, window) == 4 * positions
+
+    def refuse(*a, **k):
+        raise AssertionError("the kernel was called outside its envelope")
+
+    monkeypatch.setattr(mla_decode, "latent_attention", refuse)
+    p, stacked, q_nope, q_rope, first = _window_case(cfg, window, lengths, positions)
+    got = mla.attend_window(q_nope, q_rope, stacked, 2, first, p, cfg)
+    want = mla.attend_absorbed(q_nope, q_rope, stacked[2], p, cfg,
+                               first[:, None] + jnp.arange(window)[None])
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_read_positions_round_each_row_up_to_the_key_block(monkeypatch):
+    monkeypatch.setattr(mla_decode, "KEY_BLOCK", 16)
+    cfg = small_cfg()
+    # three rows in use (1, 2 and 3 blocks) and a free one, which attends its position 0
+    assert mla.cache_read_positions(cfg, [1, 17, 48], 4, 64) == (1 + 2 + 3 + 1) * 16
+    assert mla.cache_read_positions(cfg, [64] * 4, 4, 64) == 4 * 64
+    assert generation.cache_read_positions(cfg, [5], 2, 64) == 2 * 16
+    assert generation.cache_read_positions(PRESETS["opt-125m"], [5], 2, 64) is None
 
 
 # --- YaRN -----------------------------------------------------------------------------
@@ -459,6 +563,8 @@ def test_engine_serves_an_mla_stack_end_to_end():
     assert stats["cache_bytes"] == 3 * 24 * 4 * 2 * 64
     # the expert counters are the tracer's: off, they stay on the device and none is read
     assert stats["latent_live_positions"] == 0 and "moe_held_pairs_per_token" not in stats
+    # 64 positions are no whole key block: the plain body, which reads every slot's capacity
+    assert stats["latent_read_positions"] == 2 * 64
 
 
 def test_the_decode_span_carries_the_iterations_counters():
@@ -486,6 +592,38 @@ def test_the_decode_span_carries_the_iterations_counters():
     # and `stats` repeats the last iteration's, as host numbers
     assert isinstance(stats["moe_load_imbalance"], float)
     assert 0.0 <= stats["moe_held_pairs_per_token"] <= 2.0
+
+
+def test_the_engine_counts_the_positions_its_decode_kernel_fetches(monkeypatch):
+    """A small engine whose slots are whole key blocks decodes through the kernel: the
+    greedy tokens are the model's, and every `decode` span (and `stats()`) carries
+    `latent_read_positions`: the rows' lengths rounded up to the key block, one block
+    for the free row."""
+    from galvatron_tpu.obs.tracing import tracer
+    from galvatron_tpu.serving import Engine
+
+    monkeypatch.setattr(mla_decode, "KEY_BLOCK", 16)
+    cfg = small_cfg()
+    params, rows = seeded(cfg, batch=1, length=12)
+    prompt = np.asarray(rows[0]).tolist()
+    tracer.enable(capacity=4096)
+    tracer.clear()
+    try:
+        engine = Engine(params, cfg, num_slots=2, prefill_chunk=8, request_ttl_s=None)
+        out, = engine.generate([prompt], max_new_tokens=8)
+        stats = engine.stats()
+        engine.drain(timeout_s=10.0)
+        spans = [ev["args"] for ev in tracer.snapshot() if ev.get("name") == "decode"]
+    finally:
+        tracer.disable()
+        tracer.clear()
+    logits = modeling.forward(params, jnp.asarray([out[:-1]]), cfg)[0]
+    assert np.asarray(jnp.argmax(logits[11:], -1)).tolist() == out[12:]
+    lives = [a["latent_live_positions"] for a in spans]
+    assert min(lives) <= 16 < max(lives)  # the row grows past its first key block
+    for args in spans:
+        assert args["latent_read_positions"] == -(-args["latent_live_positions"] // 16) * 16 + 16
+    assert stats["latent_read_positions"] == 2 * 16  # no row in use: a block each
 
 
 def test_an_attention_engines_stats_name_its_kv_cache():

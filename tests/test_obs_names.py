@@ -41,6 +41,10 @@ KERNEL_NAMES = {
     # through the `mlp/dispatch`, `mlp/experts`, `mlp/combine` scopes they run under
     "moe_held_rows": "moe_held.py", "moe_held_pairs": "moe_held.py",
     "moe_held_swiglu": "moe_held.py", "moe_held_swiglu_bwd": "moe_held.py",
+    # a decode window's latent attention, bounded a row by its length (PR 52): read
+    # through the `attn_core` scope it runs under (`mla_attn_ms_per_step`,
+    # `mla_decode_attn_roofline`)
+    "mla_decode": "mla_decode.py",
 }
 
 
@@ -75,7 +79,8 @@ def test_every_pallas_call_has_a_name_from_the_table(name):
     # and nothing outside the table: a new kernel joins it, with its metric
     assert {n for names in found.values() for n in names} == set(KERNEL_NAMES)
     assert all(n.startswith(("flash_fwd", "flash_bwd", "flash_paged",
-                             "moe_gmm", "moe_tgmm", "moe_held_", "ssd_", "ssm_conv_", "gdn_"))
+                             "moe_gmm", "moe_tgmm", "moe_held_", "ssd_", "ssm_conv_", "gdn_",
+                             "mla_"))
                for n in KERNEL_NAMES)
 
 

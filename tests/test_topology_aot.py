@@ -465,6 +465,55 @@ def test_sarvam_held_experts_serve_unjoined_at_published_widths(one_chip, real_m
     assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * 2**30
 
 
+def test_sarvam_decode_step_reads_the_latent_cache_in_place(one_chip, real_mosaic):
+    """`_decode_step` of `sarvam-105b_serve_long_above_knee` (the 5-layer cut, 32 slots x
+    16,384 positions x 576 of bf16 latent, 64 heads) as the chip's compiler sees it:
+    every layer's attention is the kernel `mla_decode` under ``attn_core`` between the
+    two ``absorb`` products (so Mosaic takes the kernel at the real widths), handed the
+    stacked cache WHOLE: the chip keeps a slot's positions on the lanes
+    (``{2,3,1,0}``: its compact layout for a width that is no multiple of 128), the
+    kernel's operand is a bitcast of that, and no operation copies a layer's slab
+    (32, 16384, 576), let alone the cache (two copies of 3.0 GB a step when the
+    kernel asked for the latent position-major); the donated cache is aliased and the
+    temporaries are 0.14 GiB (1.15 with the plain body's float32 scores)."""
+    import re
+
+    from galvatron_tpu.aot import registry
+    from galvatron_tpu.models.modeling import PRESETS
+    from galvatron_tpu.serving import engine  # noqa: F401  (registers the serving family)
+
+    cfg = PRESETS["sarvam-105b"].replace(num_layers=5, vocab_size=65536, moe_share=(0, 4),
+                                         max_seq_len=16384, param_dtype=jnp.bfloat16,
+                                         dtype=jnp.bfloat16)
+    ctx = registry.ProgramContext(cfg=cfg, num_slots=32, prefill_chunk=1024, max_seq_len=16384)
+    spec, = [sp for sp in registry.enumerate_programs(ctx, include=("serving",))
+             if sp.name == "serving_decode"]
+    args = [a if a is cfg else jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), a)
+        for a in spec.args]
+    compiled = spec.fn.lower(*args).compile()
+    text = compiled.as_text()
+    entry = _entry_lines(text)
+    kernels = [line for line in entry if "custom-call(" in line and "mla_decode" in line]
+    assert len(kernels) == cfg.num_layers
+    for i, line in enumerate(sorted(kernels, key=lambda l: int(re.search(r"layer_(\d+)", l).group(1)))):
+        assert f"/layer_{i}/attn/attn_core" in line and "bf16[5,32,576,16384]{3,2,1,0}" in line
+    names = re.findall(r'op_name="([^"]*)"', text)
+    assert sum("/attn/attn_core/absorb" in n for n in names) >= 2 * cfg.num_layers
+    # the cache's way through the step: parameter, in-place updates, bitcasts for the kernel
+    slab = 32 * 16384 * 576
+    moved = [(op, shape) for op, n, shape in _entry_results(text)
+             if n >= slab and op not in ("parameter", "get-tuple-element", "tuple", "bitcast",
+                                         "dynamic-update-slice")]
+    assert not moved, moved[:4]
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes == cfg.num_layers * slab * 2
+    assert ma.temp_size_in_bytes < 0.25 * 2**30, f"{ma.temp_size_in_bytes / 2**30:.3f} GiB"
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes - ma.alias_size_in_bytes
+             + ma.temp_size_in_bytes)
+    assert total < HBM_V5E_GIB * 2**30, f"{total / 2**30:.2f} GiB"
+
+
 def test_qwen3_next_held_experts_compile_at_published_widths(one_chip, real_mosaic):
     """The expert layer of `qwen3-next-80b-a3b_s4096` (16,384 tokens x top-10 over 512
     experts of width 512, rank 0 of 16 holding 32, a buffer of 172,288 rows), forward
