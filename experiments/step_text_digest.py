@@ -1,4 +1,4 @@
-"""Digest of the lowered text of every benchmark cell's whole train step, no chip needed.
+"""Digest of the lowered text of every benchmark cell's programs, no chip needed.
 
 A cell a change must leave alone is protected only if its lowered program is the same
 text (PERF.md §6, PR 26/27). For each cell of ``BENCHMARK.json`` this builds what the
@@ -7,7 +7,10 @@ batch and sequence, bf16, flash attention (what ``--attn_impl auto`` resolves to
 TPU), and for a searched cell the plan ``cli search`` emits for the cell's arguments --
 lowers ``rt.train_step`` for a described v5e (1 chip, or the 2x2 mesh) with the real
 Mosaic kernels, and prints a sha256 of the text normalised as ``flash_text_digest.py``
-does (kernel payloads replaced by their assembly without debug locations).
+does (kernel payloads replaced by their assembly without debug locations). A serving
+cell has two programs, the engine's prompt chunk and decode step (the AOT registry's
+``serving_prefill`` / ``serving_decode`` at the traffic's ``serve_flags``), printed as
+``<cell>/<program>``.
 
 Run it in a ``git archive`` of the parent commit and in the change; equal digests = the
 same program:
@@ -85,6 +88,29 @@ def lowered_step(cell, config, traffic, topo, out_dir):
     return lowered
 
 
+def lowered_serving(config, traffic, topo):
+    """``{program: lowered}`` of a serving cell: what ``benchmark/lib/serve.build_engine``
+    makes of the configuration's ``program_flags`` and the traffic's ``serve_flags``,
+    as the AOT registry declares the slot engine's two programs."""
+    from galvatron_tpu.aot import registry
+    from galvatron_tpu.core.arguments import initialize_galvatron, model_config_from_args
+    from galvatron_tpu.serving import engine  # noqa: F401  (registers the serving family)
+
+    ns = initialize_galvatron("serve", [*config["program_flags"], *traffic["serve_flags"]])
+    cfg = model_config_from_args(ns)
+    ctx = registry.ProgramContext(cfg=cfg, num_slots=ns.num_slots,
+                                  prefill_chunk=ns.prefill_chunk, max_seq_len=cfg.max_seq_len)
+    one_chip = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    out = {}
+    for spec in registry.enumerate_programs(ctx, include=("serving",)):
+        if spec.name in ("serving_prefill", "serving_decode"):
+            args = [a if a is cfg else jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), a)
+                for a in spec.args]
+            out[spec.name] = spec.fn.lower(*args)
+    return out
+
+
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--cell", action="append", help="only this cell (may repeat)")
@@ -104,24 +130,27 @@ def main() -> None:
     with tempfile.TemporaryDirectory() as out_dir, persistent_cache_off():
         for name in args.cell or [w["name"] for w in harness.load_manifest(ROOT)["workloads"]]:
             cell, config, traffic = harness.load_cell(ROOT, name)
-            if traffic.get("kind", "train") != "train":
-                continue  # a serving cell has no train step
-            lowered = lowered_step(cell, config, traffic, topo, out_dir)
-            text = normalised(lowered.as_text())
-            print(cell["name"], "sha256", hashlib.sha256(text.encode()).hexdigest(),
-                  "bytes", len(text), flush=True)
-            if args.dump:
-                os.makedirs(args.dump, exist_ok=True)
-                with open(os.path.join(args.dump, cell["name"] + ".txt"), "w") as f:
-                    f.write(text)
-            if args.compile:
-                mem = lowered.compile().memory_analysis()
-                gib = {k: round(getattr(mem, k) / 2**30, 3) for k in (
-                    "argument_size_in_bytes", "output_size_in_bytes", "alias_size_in_bytes",
-                    "temp_size_in_bytes")}
-                print(cell["name"], "compiler's plan, GiB:", gib, "arguments + temporaries",
-                      round((mem.argument_size_in_bytes + mem.temp_size_in_bytes) / 2**30, 3),
-                      flush=True)
+            if traffic.get("kind", "train") == "serve":
+                programs = {f"{name}/{program}": lowered for program, lowered in
+                            lowered_serving(config, traffic, topo).items()}
+            else:
+                programs = {name: lowered_step(cell, config, traffic, topo, out_dir)}
+            for label, lowered in programs.items():
+                text = normalised(lowered.as_text())
+                print(label, "sha256", hashlib.sha256(text.encode()).hexdigest(),
+                      "bytes", len(text), flush=True)
+                if args.dump:
+                    os.makedirs(args.dump, exist_ok=True)
+                    with open(os.path.join(args.dump, label.replace("/", ".") + ".txt"), "w") as f:
+                        f.write(text)
+                if args.compile:
+                    mem = lowered.compile().memory_analysis()
+                    gib = {k: round(getattr(mem, k) / 2**30, 3) for k in (
+                        "argument_size_in_bytes", "output_size_in_bytes", "alias_size_in_bytes",
+                        "temp_size_in_bytes")}
+                    print(label, "compiler's plan, GiB:", gib, "arguments + temporaries",
+                          round((mem.argument_size_in_bytes + mem.temp_size_in_bytes) / 2**30, 3),
+                          flush=True)
 
 
 if __name__ == "__main__":

@@ -74,6 +74,18 @@ def cache_read_positions(cfg: ModelConfig, lengths, rows: int, positions: int, w
     return mixers.module(kind).cache_read_positions(cfg, lengths, rows, positions, window)
 
 
+def chunk_layout(cfg: ModelConfig, rows: int, positions: int) -> dict:
+    """What joins `cache_layout` once an engine knows its prompt chunk (``rows``) and
+    its slots (``positions``): of a kind with a cache of its own, which body its chunk
+    attention takes (``chunk_path``) and the keys a block of it fetches
+    (``chunk_key_block``), the kind's own answer from the shapes; nothing for K and V
+    slots, whose chunk attention is `generation`'s own."""
+    kind = mixers.cache_kind(cfg)
+    if kind is None:
+        return {}
+    return mixers.module(kind).chunk_layout(cfg, rows, positions)
+
+
 def _positions(offsets, s: int):
     """Absolute positions of ``s`` new tokens: (s,) for a scalar offset (the
     same for every row), (B, s) for a (B,) one."""

@@ -18,7 +18,9 @@ only where a configuration has such layers: `module`) exposes
   positions)`` -> a NamedTuple of arrays stacked ``(layers, rows, positions,
   ...)``, ``cache_bytes_per_position(cfg)`` (one layer's), ``cache_read_positions(
   cfg, lengths, rows, positions, window)`` (what a decode window's attention
-  fetches of one layer, by construction) and ``cached_block(x,
+  fetches of one layer, by construction), ``chunk_layout(cfg, rows,
+  positions)`` (a prompt chunk's: which body its attention takes, the keys a block
+  of it fetches) and ``cached_block(x,
   p, cfg, cache, layer, starts, slot, offsets, cos_sin)`` -> ``(y, cache)``, what
   ``models/generation.forward_with_cache`` runs in place of attention over K and V.
 

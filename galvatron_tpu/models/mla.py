@@ -18,8 +18,14 @@ replaces. `cached_block` has two forms of the same mathematics:
 
 - a prompt chunk (``slot`` given) takes the NON-ABSORBED form: the slot's latent
   up to the chunk's end is expanded through ``W_kvb`` a block of keys at a time
-  and attended at widths dn + dr / dv with a running softmax, so the work and the
-  float32 scores follow the live positions, not the slot's capacity;
+  and attended at widths dn + dr / dv with a running softmax, so the work follows
+  the live positions, not the slot's capacity. Where `mla_prefill.chunk_path`
+  says so (a chip, slots of whole key blocks, the published widths' tiling) all of
+  it, the expansion included, is the kernel ``mla_chunk`` (``ops/mla_prefill.py``):
+  it reads the stacked cache in place and its float32 scores, maximum, sum and
+  accumulator never leave the chip. Outside that envelope it is XLA's loop
+  (`_plain_chunk`), whose score blocks pass through HBM, and which is also the
+  kernel's reference;
 - every other window (a decode step, the verify window, `generate`) takes the
   ABSORBED form: ``q_nope W_kvb,k^T`` against ``c~``, ``q_rope`` against ``k_r``,
   the probabilities times ``c~``, then ``W_kvb,v``: no K or V of a cached
@@ -33,8 +39,9 @@ replaces. `cached_block` has two forms of the same mathematics:
 
 Scopes under ``attn``: ``qkv_proj``, ``cache_write``, ``attn_core`` (>
 ``absorb``: the two absorbed products; > ``expand``: the chunk form's ``W_kvb``
-expansion; the kernel ``mla_decode`` directly under it), ``out_proj`` (PERF.md §3;
-the ``mla_*`` benchmark metrics read them).
+expansion, which is the kernel ``mla_chunk`` where that runs; the kernel
+``mla_decode`` directly under it), ``out_proj`` (PERF.md §3; the ``mla_*`` benchmark
+metrics read them).
 """
 
 from __future__ import annotations
@@ -47,7 +54,7 @@ import jax.numpy as jnp
 
 from galvatron_tpu.models import modeling
 from galvatron_tpu.models.placement import LOCAL, Placement
-from galvatron_tpu.ops import mla_decode
+from galvatron_tpu.ops import mla_decode, mla_prefill
 from galvatron_tpu.ops.quant import QuantTensor, qmatmul
 
 Params = Dict[str, Any]
@@ -266,10 +273,12 @@ def key_block(positions: int) -> int:
     return block if positions > KEY_BLOCK and block >= 8 else positions
 
 
-def attend_chunk(q_nope, q_rope, stacked, layer: int, slot, offset, p: Params, cfg):
-    """`attend_expanded` for the chunk at ``offset`` of row ``slot`` of the stacked
-    cache, a block of keys at a time up to the chunk's end (a traced trip count:
-    blocks past it are neither read nor expanded), with a running softmax."""
+def _plain_chunk(q_nope, q_rope, stacked, layer: int, slot, offset, p: Params, cfg):
+    """The chunk form as XLA runs it: a `fori_loop` over blocks of `key_block` keys up
+    to the chunk's end (a traced trip count: blocks past it are neither read nor
+    expanded), each expanded through ``W_kvb`` and attended with a running softmax
+    whose float32 scores pass through HBM. What runs outside the kernel's envelope
+    (`mla_prefill.chunk_path`), and the kernel's reference."""
     n, _, _, dv, _ = dims(cfg)
     s = q_nope.shape[1]
     positions, width = stacked.shape[2], stacked.shape[3]
@@ -294,6 +303,34 @@ def attend_chunk(q_nope, q_rope, stacked, layer: int, slot, offset, p: Params, c
             jnp.zeros((1, n, s, dv), F32))
     _, total, acc = jax.lax.fori_loop(0, (offset + s + block - 1) // block, step, init)
     return jnp.transpose(acc / total[..., None], (0, 2, 1, 3)).astype(q_nope.dtype)
+
+
+def _chunk_path(cfg, rows: int, positions: int) -> str:
+    return mla_prefill.chunk_path(positions, cfg.mla_kv_rank + cfg.mla_rope_dim, rows, dims(cfg),
+                                  cfg.dtype)
+
+
+def attend_chunk(q_nope, q_rope, stacked, layer: int, slot, offset, p: Params, cfg):
+    """`attend_expanded` for the chunk at ``offset`` of row ``slot`` of the stacked
+    cache, a block of keys at a time up to the chunk's end with a running softmax.
+    Inside `mla_prefill.chunk_path`'s envelope all of it is the kernel `mla_chunk`
+    (the expansion included, so it runs under ``expand``), which reads the stacked
+    cache in place and keeps the scores on the chip; outside it `_plain_chunk`."""
+    if _chunk_path(cfg, q_nope.shape[1], stacked.shape[2]) != "kernel":
+        return _plain_chunk(q_nope, q_rope, stacked, layer, slot, offset, p, cfg)
+    with jax.named_scope("expand"):
+        return mla_prefill.latent_chunk_attention(
+            q_nope, q_rope, stacked, layer, slot, offset, _kvb(p, cfg, stacked.dtype),
+            dims=dims(cfg), scale=softmax_scale(cfg))
+
+
+def chunk_layout(cfg, rows: int, positions: int) -> dict:
+    """Which body `attend_chunk` takes for prompt chunks of ``rows`` queries over slots
+    of ``positions`` and the keys a block of that body fetches: fixed by the shapes,
+    so an engine asks once when it is built (`generation.chunk_layout`)."""
+    path = _chunk_path(cfg, rows, positions)
+    block = mla_prefill.KEY_BLOCK if path == "kernel" else key_block(positions)
+    return {"chunk_path": path, "chunk_key_block": block}
 
 
 @jax.named_scope("out_proj")

@@ -212,7 +212,7 @@ def _paged_decode_verify(params, cfg: ModelConfig, pool: KVCache, tokens,
 #: the engine's counters; ``draws_device`` / ``draws_host`` count the tokens
 #: drawn by ``_sample_rows`` and by ``_sample_host`` (the speculative engine's)
 _COUNTERS = (
-    "steps", "prefill_chunks", "prefill_tokens", "tokens_generated",
+    "steps", "prefill_chunks", "latent_chunks_kernel", "prefill_tokens", "tokens_generated",
     "engine_restarts", "draft_proposed", "draft_accepted",
     "spec_steps", "spec_fallbacks", "draws_device", "draws_host",
 )
@@ -345,6 +345,10 @@ class Engine:
             self.slots = SlotKVCache(cfg, num_slots, max_seq_len)
         # a chunk longer than the slot would slice past the cache end
         self.prefill_chunk = min(int(prefill_chunk), self.slots.max_seq_len)
+        # of a cache of a kind of its own: which body a prompt chunk's attention takes
+        # and the keys a block of it fetches (fixed by the shapes: asked once)
+        self.cache_layout.update(
+            generation.chunk_layout(cfg, self.prefill_chunk, self.slots.max_seq_len))
         self.scheduler = Scheduler(max_queue=max_queue, default_ttl_s=request_ttl_s)
         self.deadline_policy = deadline_policy
         self.drain_timeout_s = float(drain_timeout_s)
@@ -555,6 +559,11 @@ class Engine:
             extra["cache_bytes"] = (self.cache_layout["bytes_per_position"]
                                     * self.slots.num_slots * self.slots.max_seq_len)
             extra.update(self.step_counters())
+            if "chunk_path" in self.cache_layout:
+                # the body every prompt chunk's attention takes, and how many took the
+                # kernel (over ``prefill_chunks``: 1.0 or 0.0)
+                extra["chunk_path"] = self.cache_layout["chunk_path"]
+                extra["latent_chunks_kernel"] = ec["latent_chunks_kernel"]
         return {
             "kv_backend": "paged" if self.paged else "slot",
             # the replica's numerics contract rides /healthz: the fleet
@@ -968,10 +977,10 @@ class Engine:
         attrs = {"rid": req.rid, "tokens": len(req.tokens)}
         if req.trace_id is not None:
             attrs["trace_id"] = req.trace_id
-        with _obs_tracer.span("prefill", **attrs):
-            self._prefill_impl(req)
+        with _obs_tracer.span("prefill", **attrs) as span:
+            self._prefill_impl(req, span)
 
-    def _prefill_impl(self, req: Request) -> None:
+    def _prefill_impl(self, req: Request, span) -> None:
         t0 = time.perf_counter()
         slot = self.slots.alloc()
         assert slot is not None
@@ -1002,7 +1011,10 @@ class Engine:
             # identical k/v (deterministic function of tokens + positions),
             # so the rewrite is idempotent.
             starts[-1] = smax - c
-        for start in starts:
+        key_block = self.cache_layout.get("chunk_key_block")  # None for K and V slots
+        kernel = self.cache_layout.get("chunk_path") == "kernel"
+        key_blocks = 0
+        for i, start in enumerate(starts):
             # the deadline is end-to-end: a long prompt must not burn chip
             # time prefilling past the moment its client stops waiting
             if req.deadline is not None and time.time() > req.deadline:
@@ -1039,6 +1051,14 @@ class Engine:
                 )
             self.counters.inc("prefill_chunks")
             self.counters.inc("prefill_tokens", n)
+            if key_block:
+                # the chunks the chunk kernel took and the key blocks a layer's attention
+                # fetched for them so far (host arithmetic from the start: no array is
+                # built and nobody waits for the device)
+                key_blocks += -(-(start + c) // key_block)
+                if kernel:
+                    self.counters.inc("latent_chunks_kernel")
+                span.set(latent_chunks_kernel=(i + 1) * kernel, latent_chunk_key_blocks=key_blocks)
         self.slots.lengths[slot] = len(toks)
         if self.paged:
             # publish the prompt's full blocks while the request decodes, so

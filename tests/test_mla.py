@@ -19,7 +19,7 @@ from galvatron_tpu.core.optim import AdamConfig
 from galvatron_tpu.core.strategy import HybridParallelConfig, LayerStrategy
 from galvatron_tpu.models import generation, mixers, mla, modeling, moe
 from galvatron_tpu.models.modeling import PRESETS
-from galvatron_tpu.ops import mla_decode
+from galvatron_tpu.ops import mla_decode, mla_prefill
 from galvatron_tpu.parallel.hybrid import build_runtime
 from galvatron_tpu.parallel.mesh import build_mesh
 
@@ -172,16 +172,24 @@ def test_the_loss_and_every_gradient_are_finite_and_the_bias_takes_none():
         assert float(jnp.abs(lp["mlp"]["router"]["w"]).max()) > 0.0
 
 
-@pytest.mark.parametrize("key_block", [64, 16])
+def small_tiles(monkeypatch, key_block=16):
+    """The chunk kernel's key block at the tests' sizes: slots of 64 are whole blocks."""
+    monkeypatch.setattr(mla_prefill, "KEY_BLOCK", key_block)
+
+
+@pytest.mark.parametrize("key_block,path", [(64, "plain"), (16, "plain"), (16, "kernel")])
 def test_chunked_prefill_then_decoding_through_the_latent_cache_matches_the_reference(
-        monkeypatch, key_block):
+        monkeypatch, key_block, path):
     """One request in row 2 of a three-row latent slot cache: its prompt in chunks of
-    16 (the chunk form, several blocks of keys where ``key_block`` is 16), then token
-    by token at per-row offsets (the absorbed form), logits against ONE full forward
-    of the reference."""
+    16 (the chunk form, several blocks of keys where ``key_block`` is 16; the plain
+    body, and the kernel `mla_chunk` interpreted), then token by token at per-row
+    offsets (the absorbed form), logits against ONE full forward of the reference."""
     monkeypatch.setattr(mla, "KEY_BLOCK", key_block)
     monkeypatch.setattr(mla, "key_block", lambda positions: min(positions, key_block))
+    if path == "kernel":
+        small_tiles(monkeypatch, key_block)
     cfg = small_cfg()
+    assert mla._chunk_path(cfg, 16, 64) == path
     params, rows = seeded(cfg, batch=1)
     want = ref_logits(params, rows, cfg)
     cache = generation.init_kv_cache(cfg, 3, cfg.max_seq_len)
@@ -329,6 +337,118 @@ def test_read_positions_round_each_row_up_to_the_key_block(monkeypatch):
     assert mla.cache_read_positions(cfg, [64] * 4, 4, 64) == 4 * 64
     assert generation.cache_read_positions(cfg, [5], 2, 64) == 2 * 16
     assert generation.cache_read_positions(PRESETS["opt-125m"], [5], 2, 64) is None
+
+
+# --- the chunk kernel (ops/mla_prefill.py; interpreted on the CPU) --------------------------
+
+
+def _chunk_case(cfg, rows, offset, positions=64, seed=0):
+    """A layer's parameters, a stacked cache of 3 layers x 4 rows of random latents
+    and the projected queries of a chunk of ``rows`` tokens at ``offset``."""
+    p = mla.init_params(jax.random.key(seed), cfg)
+    width = cfg.mla_kv_rank + cfg.mla_rope_dim
+    stacked = jax.random.normal(jax.random.key(seed + 1), (3, 4, positions, width), cfg.dtype)
+    x = jax.random.normal(jax.random.key(seed + 2), (1, rows, cfg.hidden_size), cfg.dtype)
+    pos = offset + jnp.arange(rows)
+    cos_all, sin_all = modeling.rope_tables(cfg, positions)
+    q_nope, q_rope, _ = mla.project(x, p, cfg, (cos_all[pos][None], sin_all[pos][None]))
+    return p, stacked, q_nope, q_rope
+
+
+# (rows, offset, real rows, what lies from position offset + real rows on): key blocks
+# of 16, slots of 64
+CHUNKS = {
+    "offset_0": (16, 0, 16, None),  # one live key block, the diagonal's
+    "inside_a_key_block": (16, 5, 16, None),  # two blocks cross the diagonal
+    "on_a_block_boundary": (16, 16, 16, None),  # a whole block, then the diagonal's
+    "several_key_blocks": (16, 48, 16, None),  # four live blocks, up to the slot's end
+    "slid_left": (24, 40, 24, None),  # 64 - 24: no multiple of the chunk
+    "padded_tail": (16, 32, 10, 1e4),  # a final chunk: 10 tokens, then padding's latents
+    "nan_past_the_end": (16, 21, 16, float("nan")),  # never fetched or never counted
+}
+
+
+@pytest.mark.parametrize("case", list(CHUNKS))
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_the_chunk_kernel_is_the_plain_chunk_body(monkeypatch, dtype, case):
+    """`attend_chunk` through the kernel `mla_chunk` against `_plain_chunk` over a
+    CLEAN cache: the real rows' outputs are the plain body's whatever lies past them
+    (padding's latents; NaN past the chunk's end, where the output is bit for bit the
+    clean cache's)."""
+    small_tiles(monkeypatch)
+    rows, offset, real, dirt = CHUNKS[case]
+    cfg = small_cfg(dtype=dtype)
+    p, stacked, q_nope, q_rope = _chunk_case(cfg, rows, offset)
+    assert mla._chunk_path(cfg, rows, 64) == "kernel"
+    attend = jax.jit(lambda c, o: mla.attend_chunk(q_nope, q_rope, c, 1, jnp.int32(2), o, p, cfg))
+    want = mla._plain_chunk(q_nope, q_rope, stacked, 1, jnp.int32(2), jnp.int32(offset), p, cfg)
+    cache = stacked
+    if dirt is not None:
+        past = (jnp.arange(64) >= offset + real)[None, None, :, None]
+        cache = jnp.where(past, jnp.asarray(dirt, dtype), stacked)
+    got = attend(cache, jnp.int32(offset))
+    assert got.shape == (1, rows, 4, 12) and got.dtype == dtype
+    assert bool(jnp.isfinite(got).all())
+    close(got[:, :real].astype(jnp.float32), want[:, :real].astype(jnp.float32),
+          F32_TOL if dtype == jnp.float32 else 2e-2)
+    if case == "nan_past_the_end":
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(attend(stacked, jnp.int32(offset))))
+
+
+PUBLISHED = (64, 128, 64, 128, 512)  # heads, dn, dr, dv, r
+# (positions, width, rows, dims, dtype, what is patched) inside the envelope | just outside it
+ENVELOPE = {
+    "capacity": ((64, 24, 16, (4, 16, 8, 12, 16), jnp.float32, "tiles"),
+                 (40, 24, 16, (4, 16, 8, 12, 16), jnp.float32, "tiles")),
+    "backend": ((64, 24, 16, (4, 16, 8, 12, 16), jnp.float32, "tiles"),
+                (64, 24, 16, (4, 16, 8, 12, 16), jnp.float32, "tiles,gpu")),
+    "chunk_rows": ((16384, 576, 1024, PUBLISHED, jnp.bfloat16, "compiled"),
+                   (16384, 576, 1040, PUBLISHED, jnp.bfloat16, "compiled")),
+    "row_sublanes": ((16384, 576, 1008, PUBLISHED, jnp.bfloat16, "compiled"),
+                     (16384, 576, 1000, PUBLISHED, jnp.bfloat16, "compiled")),
+    "dtype": ((64, 24, 16, (4, 16, 8, 12, 16), jnp.bfloat16, "tiles"),
+              (64, 24, 16, (4, 16, 8, 12, 16), jnp.float16, "tiles")),
+    "latent_layout": ((16384, 576, 1024, PUBLISHED, jnp.bfloat16, "compiled"),
+                      (16384, 640, 1024, (64, 128, 128, 128, 512), jnp.bfloat16, "compiled")),
+    "lane_tiles": ((16384, 576, 1024, PUBLISHED, jnp.bfloat16, "compiled"),
+                   (16384, 576, 1024, (64, 96, 64, 128, 512), jnp.bfloat16, "compiled")),
+    "rope_sublanes": ((16384, 520, 1024, (64, 128, 8, 128, 512), jnp.float32, "compiled"),
+                      (16384, 520, 1024, (64, 128, 8, 128, 512), jnp.bfloat16, "compiled")),
+}
+
+
+@pytest.mark.parametrize("edge", list(ENVELOPE))
+def test_chunk_path_answers_from_shapes_and_the_backend_each_side_of_every_edge(monkeypatch, edge):
+    from galvatron_tpu.ops import flash_attention
+
+    for want, (positions, width, rows, dims, dtype, patched) in zip(("kernel", "plain"), ENVELOPE[edge]):
+        with monkeypatch.context() as m:
+            if "tiles" in patched:
+                small_tiles(m)
+            if "gpu" in patched:
+                m.setattr(jax, "default_backend", lambda: "gpu")
+            if "compiled" in patched:
+                m.setattr(flash_attention, "_use_interpret", lambda: False)
+            assert mla_prefill.chunk_path(positions, width, rows, dims, dtype) == want, (edge, want)
+
+
+def test_outside_the_chunk_kernels_envelope_the_plain_body_runs(monkeypatch):
+    """Slots of 64 under the real key block of 1,024: `attend_chunk` IS `_plain_chunk`
+    (the kernel is not called) and the host's count says so."""
+    def refuse(*a, **k):
+        raise AssertionError("the kernel was called outside its envelope")
+
+    monkeypatch.setattr(mla_prefill, "latent_chunk_attention", refuse)
+    cfg = small_cfg()
+    p, stacked, q_nope, q_rope = _chunk_case(cfg, 16, 16)
+    args = (q_nope, q_rope, stacked, 1, jnp.int32(2), jnp.int32(16), p, cfg)
+    np.testing.assert_array_equal(np.asarray(mla.attend_chunk(*args)),
+                                  np.asarray(mla._plain_chunk(*args)))
+    # all 64 keys are one block of the plain body
+    assert mla.chunk_layout(cfg, 16, 64) == {"chunk_path": "plain", "chunk_key_block": 64}
+    assert generation.chunk_layout(PRESETS["opt-125m"], 16, 64) == {}
+    small_tiles(monkeypatch)
+    assert generation.chunk_layout(cfg, 16, 64) == {"chunk_path": "kernel", "chunk_key_block": 16}
 
 
 # --- YaRN -----------------------------------------------------------------------------
@@ -624,6 +744,89 @@ def test_the_engine_counts_the_positions_its_decode_kernel_fetches(monkeypatch):
     for args in spans:
         assert args["latent_read_positions"] == -(-args["latent_live_positions"] // 16) * 16 + 16
     assert stats["latent_read_positions"] == 2 * 16  # no row in use: a block each
+
+
+@pytest.mark.parametrize("path", ["kernel", "plain"])
+def test_the_engine_counts_the_chunks_its_chunk_kernel_takes(monkeypatch, path):
+    """A prompt of 20 tokens in chunks of 8 (at 0, 8 and 16) through the chunk kernel
+    (slots of whole key blocks of 16) and outside its envelope (the real key block):
+    the greedy tokens are the model's; `latent_chunks_kernel / prefill_chunks` is 1.0 |
+    0.0; the request's `prefill` span carries the count and the key blocks a layer's
+    chunk attention fetched (1 + 1 + 2 of 16 keys | 3 times all 64)."""
+    from galvatron_tpu.obs.tracing import tracer
+    from galvatron_tpu.serving import Engine
+
+    if path == "kernel":
+        small_tiles(monkeypatch)
+    cfg = small_cfg()
+    params, rows = seeded(cfg, batch=1, length=20)
+    prompt = np.asarray(rows[0]).tolist()
+    tracer.enable(capacity=4096)
+    tracer.clear()
+    try:
+        engine = Engine(params, cfg, num_slots=2, prefill_chunk=8, request_ttl_s=None)
+        out, = engine.generate([prompt], max_new_tokens=4)
+        stats = engine.stats()
+        engine.drain(timeout_s=10.0)
+        span, = [ev["args"] for ev in tracer.snapshot() if ev.get("name") == "prefill"]
+    finally:
+        tracer.disable()
+        tracer.clear()
+    logits = modeling.forward(params, jnp.asarray([out[:-1]]), cfg)[0]
+    assert np.asarray(jnp.argmax(logits[19:], -1)).tolist() == out[20:]
+    assert stats["prefill_chunks"] == 3 and stats["chunk_path"] == path
+    share = stats["latent_chunks_kernel"] / stats["prefill_chunks"]
+    assert share == (1.0 if path == "kernel" else 0.0)
+    assert span["latent_chunks_kernel"] == (3 if path == "kernel" else 0)
+    assert span["latent_chunk_key_blocks"] == (4 if path == "kernel" else 3)
+
+
+def test_a_prefill_that_ends_early_keeps_the_kernels_share_whole(monkeypatch):
+    """A prompt of three chunks whose second chunk fails (`faults.prefill_fail_at`):
+    the chunk that ran is counted on both sides, so `latent_chunks_kernel /
+    prefill_chunks` stays 1.0 (the count is made a chunk, inside the loop)."""
+    from galvatron_tpu.core import faults
+    from galvatron_tpu.serving import Engine
+
+    small_tiles(monkeypatch)
+    cfg = small_cfg()
+    params, rows = seeded(cfg, batch=1, length=20)
+    engine = Engine(params, cfg, num_slots=2, prefill_chunk=8, start_loop=False)
+    faults.configure(prefill_fail_at=1)
+    try:
+        doomed = engine.submit_request(np.asarray(rows[0]).tolist(), 4)
+        engine.step_once()
+        with pytest.raises(faults.FaultInjected):
+            doomed.future.result(timeout=1)
+    finally:
+        faults.reset()
+    stats = engine.stats()
+    engine.close()
+    assert stats["chunk_path"] == "kernel"
+    assert stats["prefill_chunks"] == 1 == stats["latent_chunks_kernel"]
+
+
+def test_an_attention_engine_reports_no_latent_chunk():
+    from galvatron_tpu.obs.tracing import tracer
+    from galvatron_tpu.serving import Engine
+
+    cfg = PRESETS["opt-125m"].replace(num_layers=2, hidden_size=64, num_heads=4, ffn_dim=128,
+                                      vocab_size=128, max_seq_len=32, dtype=jnp.float32)
+    params = modeling.init_model_params(jax.random.key(0), cfg)
+    tracer.enable(capacity=4096)
+    tracer.clear()
+    try:
+        engine = Engine(params, cfg, num_slots=2, prefill_chunk=8, request_ttl_s=None)
+        engine.generate([list(range(1, 13))], max_new_tokens=2)
+        stats = engine.stats()
+        engine.drain(timeout_s=10.0)
+        span, = [ev["args"] for ev in tracer.snapshot() if ev.get("name") == "prefill"]
+    finally:
+        tracer.disable()
+        tracer.clear()
+    assert stats["prefill_chunks"] == 2
+    assert not {"chunk_path", "latent_chunks_kernel"} & set(stats)
+    assert not {"latent_chunks_kernel", "latent_chunk_key_blocks"} & set(span)
 
 
 def test_an_attention_engines_stats_name_its_kv_cache():

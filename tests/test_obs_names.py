@@ -45,6 +45,9 @@ KERNEL_NAMES = {
     # through the `attn_core` scope it runs under (`mla_attn_ms_per_step`,
     # `mla_decode_attn_roofline`)
     "mla_decode": "mla_decode.py",
+    # PR 53: a prompt chunk's latent attention, expansion included (read by
+    # `mla_prefill_chunk_attn_ms` under `attn_core` > `expand`)
+    "mla_chunk": "mla_prefill.py",
 }
 
 

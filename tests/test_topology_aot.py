@@ -465,6 +465,41 @@ def test_sarvam_held_experts_serve_unjoined_at_published_widths(one_chip, real_m
     assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * 2**30
 
 
+def _lowered_serving_program(cfg, name, one_chip, **context):
+    """The engine's declared program ``name`` (the AOT registry's twin of what ``cli
+    serve`` warms) for ``cfg`` under `registry.ProgramContext(**context)`, lowered for
+    one described chip from shapes alone."""
+    from galvatron_tpu.aot import registry
+    from galvatron_tpu.serving import engine  # noqa: F401  (registers the serving family)
+
+    ctx = registry.ProgramContext(cfg=cfg, **context)
+    spec, = [sp for sp in registry.enumerate_programs(ctx, include=("serving",))
+             if sp.name == name]
+    args = [a if a is cfg else jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), a)
+        for a in spec.args]
+    return spec.fn.lower(*args)
+
+
+def _sarvam_serving_program(name, one_chip):
+    """(cfg, the compiled program ``name`` of `sarvam-105b_serve_long_above_knee`: the
+    5-layer cut, 32 slots x 16,384 positions x 576 of bf16 latent, chunk 1,024)."""
+    from galvatron_tpu.models.modeling import PRESETS
+
+    cfg = PRESETS["sarvam-105b"].replace(num_layers=5, vocab_size=65536, moe_share=(0, 4),
+                                         max_seq_len=16384, param_dtype=jnp.bfloat16,
+                                         dtype=jnp.bfloat16)
+    return cfg, _lowered_serving_program(cfg, name, one_chip, num_slots=32, prefill_chunk=1024,
+                                         max_seq_len=16384).compile()
+
+
+def _moved_slabs(text, slab):
+    """Results of a cache slab's size or more that are no parameter, in-place update or bitcast."""
+    return [(op, shape) for op, n, shape in _entry_results(text)
+            if n >= slab and op not in ("parameter", "get-tuple-element", "tuple", "bitcast",
+                                        "dynamic-update-slice")]
+
+
 def test_sarvam_decode_step_reads_the_latent_cache_in_place(one_chip, real_mosaic):
     """`_decode_step` of `sarvam-105b_serve_long_above_knee` (the 5-layer cut, 32 slots x
     16,384 positions x 576 of bf16 latent, 64 heads) as the chip's compiler sees it:
@@ -478,20 +513,7 @@ def test_sarvam_decode_step_reads_the_latent_cache_in_place(one_chip, real_mosai
     temporaries are 0.14 GiB (1.15 with the plain body's float32 scores)."""
     import re
 
-    from galvatron_tpu.aot import registry
-    from galvatron_tpu.models.modeling import PRESETS
-    from galvatron_tpu.serving import engine  # noqa: F401  (registers the serving family)
-
-    cfg = PRESETS["sarvam-105b"].replace(num_layers=5, vocab_size=65536, moe_share=(0, 4),
-                                         max_seq_len=16384, param_dtype=jnp.bfloat16,
-                                         dtype=jnp.bfloat16)
-    ctx = registry.ProgramContext(cfg=cfg, num_slots=32, prefill_chunk=1024, max_seq_len=16384)
-    spec, = [sp for sp in registry.enumerate_programs(ctx, include=("serving",))
-             if sp.name == "serving_decode"]
-    args = [a if a is cfg else jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), a)
-        for a in spec.args]
-    compiled = spec.fn.lower(*args).compile()
+    cfg, compiled = _sarvam_serving_program("serving_decode", one_chip)
     text = compiled.as_text()
     entry = _entry_lines(text)
     kernels = [line for line in entry if "custom-call(" in line and "mla_decode" in line]
@@ -502,13 +524,44 @@ def test_sarvam_decode_step_reads_the_latent_cache_in_place(one_chip, real_mosai
     assert sum("/attn/attn_core/absorb" in n for n in names) >= 2 * cfg.num_layers
     # the cache's way through the step: parameter, in-place updates, bitcasts for the kernel
     slab = 32 * 16384 * 576
-    moved = [(op, shape) for op, n, shape in _entry_results(text)
-             if n >= slab and op not in ("parameter", "get-tuple-element", "tuple", "bitcast",
-                                         "dynamic-update-slice")]
+    moved = _moved_slabs(text, slab)
     assert not moved, moved[:4]
     ma = compiled.memory_analysis()
     assert ma.alias_size_in_bytes == cfg.num_layers * slab * 2
     assert ma.temp_size_in_bytes < 0.25 * 2**30, f"{ma.temp_size_in_bytes / 2**30:.3f} GiB"
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes - ma.alias_size_in_bytes
+             + ma.temp_size_in_bytes)
+    assert total < HBM_V5E_GIB * 2**30, f"{total / 2**30:.2f} GiB"
+
+
+def test_sarvam_prefill_chunk_keeps_its_scores_on_the_chip(one_chip, real_mosaic):
+    """`_prefill_chunk` of the same cell (a chunk of 1,024 tokens into one of 32 slots) as
+    the chip's compiler sees it: every layer's chunk attention is the kernel `mla_chunk`
+    under ``attn_core`` > ``expand`` (the expansion through ``W_kvb`` runs inside it, so
+    the benchmark's ``expand`` mark stays on the program), handed the stacked cache
+    WHOLE as a bitcast of the chip's own layout: no operation copies a layer's slab, no
+    float32 score block (64, 1024, 1024) of the plain body exists, nor any loop over key
+    blocks; the donated cache and the engine's rows are aliased and the temporaries are
+    0.29 GiB (0.36 with the plain body)."""
+    import re
+
+    cfg, compiled = _sarvam_serving_program("serving_prefill", one_chip)
+    text = compiled.as_text()
+    entry = _entry_lines(text)
+    kernels = [line for line in entry if "custom-call(" in line and "mla_chunk" in line]
+    assert len(kernels) == cfg.num_layers
+    for i, line in enumerate(sorted(kernels, key=lambda l: int(re.search(r"layer_(\d+)", l).group(1)))):
+        assert f"/layer_{i}/attn/attn_core/expand" in line
+        assert "bf16[5,32,576,16384]{3,2,1,0}" in line
+    assert not re.search(r"f32\[(1,)?64,1024,1024\]", text)
+    assert not any(" while(" in line and "/attn/" in line for line in text.splitlines())
+    slab = 32 * 16384 * 576
+    moved = _moved_slabs(text, slab)
+    assert not moved, moved[:4]
+    ma = compiled.memory_analysis()
+    rows_bytes = 32 * cfg.vocab_size * 2  # the engine's logits rows, set in place
+    assert ma.alias_size_in_bytes == cfg.num_layers * slab * 2 + rows_bytes
+    assert ma.temp_size_in_bytes < 0.32 * 2**30, f"{ma.temp_size_in_bytes / 2**30:.3f} GiB"
     total = (ma.argument_size_in_bytes + ma.output_size_in_bytes - ma.alias_size_in_bytes
              + ma.temp_size_in_bytes)
     assert total < HBM_V5E_GIB * 2**30, f"{total / 2**30:.2f} GiB"
@@ -948,21 +1001,13 @@ def test_serving_programs_write_the_slot_cache_in_place(program, slots, spec_k, 
     slab: no copy, scatter or fusion whose result is a slab or more, the
     donated cache aliased input to output, temporaries far under the cache's
     size (5.42 GiB before PR 38 at 8 slots; 12 slots did not fit)."""
-    from galvatron_tpu.aot import registry
     from galvatron_tpu.models.modeling import PRESETS
-    from galvatron_tpu.serving import engine  # noqa: F401  (registers the serving family)
 
     cfg, smax = PRESETS["opt-1.3b"], 2048
     assert (cfg.hidden_size, cfg.num_layers, cfg.num_heads, cfg.head_dim,
             cfg.vocab_size, cfg.max_seq_len) == (2048, 24, 32, 64, 50272, smax)
-    ctx = registry.ProgramContext(cfg=cfg, num_slots=slots, prefill_chunk=256,
-                                  max_seq_len=smax, spec_decode_k=spec_k)
-    spec, = [sp for sp in registry.enumerate_programs(ctx, include=("serving",))
-             if sp.name == program]
-    args = [a if a is cfg else jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), a)
-        for a in spec.args]
-    compiled = spec.fn.lower(*args).compile()
+    compiled = _lowered_serving_program(cfg, program, one_chip, num_slots=slots, prefill_chunk=256,
+                                        max_seq_len=smax, spec_decode_k=spec_k).compile()
     ma = compiled.memory_analysis()
     slab = slots * smax * cfg.kv_heads * cfg.head_dim
     cache_bytes = 2 * cfg.num_layers * slab * 2  # k and v, bf16
@@ -982,6 +1027,20 @@ def test_serving_programs_write_the_slot_cache_in_place(program, slots, spec_k, 
              and op not in ("parameter", "get-tuple-element", "tuple", "bitcast",
                             "dynamic-update-slice")]
     assert not moved, moved[:4]
+
+
+@pytest.mark.parametrize("program", ["serving_decode", "serving_prefill"])
+def test_opt_serving_programs_take_none_of_the_latent_paths(program, one_chip, real_mosaic):
+    """A stack of plain attention over K and V slots takes none of the latent
+    attention's paths: what `opt-1.3b_serve_above_knee` runs (16 slots x 2048, chunk
+    256) names no ``mla_`` kernel and none of the latent attention's scopes. (That the
+    text is the parent's to the letter is `experiments/step_text_digest.py`'s to say,
+    run on both trees: PERF.md section 6.)"""
+    from galvatron_tpu.models.modeling import PRESETS
+
+    text = _lowered_serving_program(PRESETS["opt-1.3b"], program, one_chip, num_slots=16,
+                                    prefill_chunk=256, max_seq_len=2048).as_text()
+    assert "mla_" not in text and "attn_core/expand" not in text and "absorb" not in text
 
 
 def test_serving_sampler_compiles_for_the_chip_without_a_sort(one_chip, real_mosaic):
@@ -1017,18 +1076,11 @@ def test_serving_decode_step_names_its_device_work(one_chip, real_mosaic):
     a layer's q / k / v split, which it names after nothing.)"""
     import re
 
-    from galvatron_tpu.aot import registry
     from galvatron_tpu.models.modeling import PRESETS
-    from galvatron_tpu.serving import engine  # noqa: F401  (registers the serving family)
 
     cfg = PRESETS["opt-1.3b"]
-    ctx = registry.ProgramContext(cfg=cfg, num_slots=8, prefill_chunk=256, max_seq_len=2048)
-    spec, = [sp for sp in registry.enumerate_programs(ctx, include=("serving",))
-             if sp.name == "serving_decode"]
-    args = [a if a is cfg else jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), a)
-        for a in spec.args]
-    text = spec.fn.lower(*args).compile().as_text()
+    text = _lowered_serving_program(cfg, "serving_decode", one_chip, num_slots=8,
+                                    prefill_chunk=256, max_seq_len=2048).compile().as_text()
     # fusions only: the 26 ``ConcatBitcast`` custom calls are the compiler's own
     # (it joins the slices of a weight it prefetched) and carry no metadata at all
     work = [(n, op) for n, op in _entry_work(text) if not n.startswith("custom-call")]
