@@ -3,7 +3,7 @@
 program is computed below the precision its configuration states (PR 51).
 
     python experiments/serve_precision_controls.py --workload sarvam-105b_serve_long_above_knee \
-        --seeds 2147488001,2147488002 [--seconds 30] [--modes sound,router_bf16,cache_e4m3,int8]
+        --seeds 2147488001,2147488002 [--seconds 30] [--modes sound,router_bf16,cache_e4m3,kv_e4m3,int8]
 
 One process; for every seed and mode one run of the cell through the benchmark's
 own ``run_serve_cell`` at the cell's own load, the fault planted underneath it:
@@ -14,6 +14,9 @@ own ``run_serve_cell`` at the cell's own load, the fault planted underneath it:
 - ``cache_e4m3``: every latent cache entry ``[c~ | k_r]`` rounded to float8 e4m3's
   3 mantissa bits before it is written (``models/mla.project``): a cache kept
   below bfloat16;
+- ``kv_e4m3``: every key and value rounded the same way before it is written to a
+  K/V slot cache (``models/generation._project_qkv_at``; a windowed stack's two
+  stacks alike): PR 54's cell;
 - ``int8``: the engine's own per-channel int8 weights (``--serve_quant int8``), as
   ``benchmark/control.py`` reads them.
 
@@ -46,9 +49,20 @@ def planted(mode: str):
     import jax
     import jax.numpy as jnp
 
-    from galvatron_tpu.models import mla, moe
+    from galvatron_tpu.models import generation, mla, moe
 
     real_scores, real_project = moe.router_scores, mla.project
+    real_qkv = generation._project_qkv_at
+
+    def e4m3(t):
+        bits = jax.lax.bitcast_convert_type(t.astype(jnp.bfloat16), jnp.uint16)
+        # bfloat16 keeps 7 mantissa bits, e4m3 keeps 3: round the low 4 away (half up)
+        bits = (bits + jnp.uint16(8)) & jnp.uint16(0xFFF0)
+        return jax.lax.bitcast_convert_type(bits, jnp.bfloat16).astype(t.dtype)
+
+    def qkv_e4m3(x, p, cfg, cos_sin):
+        q, k, v = real_qkv(x, p, cfg, cos_sin)
+        return q, e4m3(k), e4m3(v)
 
     def scores_bf16(xt, router, cfg):
         x, w = xt.astype(jnp.bfloat16), router["w"].astype(jnp.bfloat16)
@@ -56,20 +70,20 @@ def planted(mode: str):
 
     def project_e4m3(x, p, cfg, cos_sin):
         q_nope, q_rope, new = real_project(x, p, cfg, cos_sin)
-        bits = jax.lax.bitcast_convert_type(new.astype(jnp.bfloat16), jnp.uint16)
-        # bfloat16 keeps 7 mantissa bits, e4m3 keeps 3: round the low 4 away (half up)
-        bits = (bits + jnp.uint16(8)) & jnp.uint16(0xFFF0)
-        return q_nope, q_rope, jax.lax.bitcast_convert_type(bits, jnp.bfloat16).astype(new.dtype)
+        return q_nope, q_rope, e4m3(new)
 
     jax.clear_caches()
     if mode == "router_bf16":
         moe.router_scores = scores_bf16
     elif mode == "cache_e4m3":
         mla.project = project_e4m3
+    elif mode == "kv_e4m3":
+        generation._project_qkv_at = qkv_e4m3
     try:
         yield
     finally:
         moe.router_scores, mla.project = real_scores, real_project
+        generation._project_qkv_at = real_qkv
         jax.clear_caches()
 
 

@@ -812,6 +812,9 @@ def resolve_attn_impl(cfg, ns: argparse.Namespace):
         # packed sequences need the segment-masked einsum path; 'auto' must
         # not pick the flash kernels (build_runtime would refuse them loudly)
         return cfg.replace(attn_impl="xla")
+    if getattr(cfg, "windowed", False):
+        # a window is a mask of XLA's attention alone (mixers.limits refuses the rest)
+        return cfg.replace(attn_impl="xla")
     if jax.default_backend() != "cpu":
         return cfg.replace(attn_impl="flash")
     return cfg

@@ -31,14 +31,62 @@ class KVCache(NamedTuple):
     max_len, kv_heads, head_dim). A stack whose layers' kind keeps a cache of its
     own (``mixers.cache_kind``) has that kind's NamedTuple instead (latent
     attention: ``models/mla.LatentCache``, one array (L, B, max_len, r + dr));
-    every array of either is stacked (L, B, max_len, ...)."""
+    every array of either is stacked (L, B, max_len, ...). A stack with
+    sliding-window layers has a `WindowKVCache`."""
 
     k: jax.Array
     v: jax.Array
 
 
-def init_kv_cache(cfg: ModelConfig, batch_size: int, max_len: int):
-    """The cache ``cfg``'s layers say: K and V for attention, the kind's own otherwise."""
+class WindowKVCache(NamedTuple):
+    """The cache of a stack with sliding-window layers (``cfg.windowed``): TWO
+    stacks in one cache. The full layers' keys and values whole, ``k`` / ``v``
+    (L_full, B, kv_heads, max_len, head_dim), position p at p; the window layers'
+    in a RING, ``wk`` / ``wv`` (L_win, B, kv_heads, R, head_dim), position p at
+    ``p mod R`` with ``R = window + the most positions one forward writes a row``
+    (`ring_positions`). HEAD-major: a key/value head's positions are a matrix
+    (positions, head_dim), which the chip's matrix unit takes as it lies; from
+    (positions, kv_heads, head_dim) the compiler copied every stack whole, every
+    step, into that order (compiled for a described v5e at the cell's size: 7.76
+    GiB of temporaries beside 11.6 of weights and cache). Attention masks a ring entry by the ABSOLUTE position it
+    holds (`_ring_key_positions`), so an entry from a lap ago or from the slot's
+    previous request is never seen; write-then-attend stays: the newest write of a
+    forward of s positions overwrites position ``p + s - 1 - R``, which lies before
+    the oldest key the forward's first query sees (``p - window + 1``). A chunk's
+    write never crosses the ring's end (`write_ring`)."""
+
+    k: jax.Array
+    v: jax.Array
+    wk: jax.Array
+    wv: jax.Array
+
+
+def ring_positions(cfg: ModelConfig, max_len: int, tokens: int = 1) -> int:
+    """Positions a window layer's ring holds a row: the window plus the most
+    positions ONE forward writes a row (``tokens``: a prompt chunk, a verify
+    window; 1: plain decode), rounded up to a multiple of ``tokens`` so that a
+    chunk that starts at a multiple of them never crosses the ring's end
+    (`write_ring`); never more than the row's ``max_len`` (a ring that long is the
+    whole row)."""
+    tokens = max(1, int(tokens))
+    ring = -(-(cfg.sliding_window_size + tokens) // tokens) * tokens
+    return min(int(max_len), ring)
+
+
+def layer_stacks(cfg: ModelConfig):
+    """Of a windowed stack, for each layer: (it has a window, its index within its
+    stack of the `WindowKVCache`)."""
+    out, counts = [], [0, 0]
+    for windowed in cfg.window_layers:
+        out.append((windowed, counts[windowed]))
+        counts[windowed] += 1
+    return out
+
+
+def init_kv_cache(cfg: ModelConfig, batch_size: int, max_len: int, tokens: int = 1):
+    """The cache ``cfg``'s layers say: K and V for attention, the kind's own otherwise;
+    two stacks, one a ring sized for forwards of up to ``tokens`` positions a row
+    (`ring_positions`), where some layers have a window."""
     for limit in mixers.limits(cfg):
         # every cache of the serving stack (slots, paged pool, generate) starts here
         if limit.what == "kv_cache":
@@ -46,30 +94,60 @@ def init_kv_cache(cfg: ModelConfig, batch_size: int, max_len: int):
     kind = mixers.cache_kind(cfg)
     if kind is not None:
         return mixers.module(kind).init_cache(cfg, cfg.num_layers, batch_size, max_len)
+    if cfg.windowed:
+        win = sum(cfg.window_layers)
+        full = (cfg.num_layers - win, batch_size, cfg.kv_heads, max_len, cfg.head_dim)
+        ring = (win, batch_size, cfg.kv_heads, ring_positions(cfg, max_len, tokens), cfg.head_dim)
+        return WindowKVCache(jnp.zeros(full, cfg.dtype), jnp.zeros(full, cfg.dtype),
+                             jnp.zeros(ring, cfg.dtype), jnp.zeros(ring, cfg.dtype))
     shape = (cfg.num_layers, batch_size, max_len, cfg.kv_heads, cfg.head_dim)
     return KVCache(jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))
 
 
-def cache_layout(cfg: ModelConfig) -> dict:
-    """What a position costs the cache ``init_kv_cache`` makes: ``{"kind": "kv" or
-    the kind's word ("latent"), "bytes_per_position": over all layers}``."""
+def cache_layout(cfg: ModelConfig, max_len: Optional[int] = None, tokens: int = 1) -> dict:
+    """What the cache ``init_kv_cache`` makes costs: ``{"kind": "kv" or the kind's
+    word ("latent"), "bytes_per_position": over all layers}`` and, once ``max_len``
+    is known (an engine's slots), ``bytes_per_slot``. No one number holds for a
+    position of a windowed stack, so it has no ``bytes_per_position``: it says
+    ``bytes_per_position_per_layer`` and tells its stacks apart (``full_layers`` /
+    ``window_layers``, ``window``); with ``max_len`` and ``tokens`` (the engine's prompt
+    chunk) also ``ring_positions``, and its ``bytes_per_slot`` is the full layers over
+    ``max_len`` plus the window layers over the ring."""
     kind = mixers.cache_kind(cfg)
     if kind is not None:
-        per_layer = mixers.module(kind).cache_bytes_per_position(cfg)
-        return {"kind": mixers.MIXERS[kind].cache, "bytes_per_position": cfg.num_layers * per_layer}
-    per_layer = 2 * cfg.kv_heads * cfg.head_dim * jnp.dtype(cfg.dtype).itemsize
-    return {"kind": "kv", "bytes_per_position": cfg.num_layers * per_layer}
+        word, per_layer = mixers.MIXERS[kind].cache, mixers.module(kind).cache_bytes_per_position(cfg)
+    else:
+        word, per_layer = "kv", 2 * cfg.kv_heads * cfg.head_dim * jnp.dtype(cfg.dtype).itemsize
+    if kind is None and cfg.windowed:
+        win = sum(cfg.window_layers)
+        out = {"kind": word, "bytes_per_position_per_layer": per_layer,
+               "full_layers": cfg.num_layers - win, "window_layers": win,
+               "window": cfg.sliding_window_size}
+        if max_len is not None:
+            ring = ring_positions(cfg, max_len, tokens)
+            out.update(ring_positions=ring,
+                       bytes_per_slot=per_layer * (out["full_layers"] * max_len + win * ring))
+        return out
+    out = {"kind": word, "bytes_per_position": cfg.num_layers * per_layer}
+    if max_len is not None:
+        out["bytes_per_slot"] = out["bytes_per_position"] * max_len
+    return out
 
 
-def cache_read_positions(cfg: ModelConfig, lengths, rows: int, positions: int, window: int = 1):
+def cache_read_positions(cfg: ModelConfig, lengths, rows: int, positions: int, window: int = 1,
+                         ring: Optional[int] = None):
     """Positions ONE layer's attention of a decode window (``window`` queries a row)
     fetches by construction from a cache of ``rows`` slots x ``positions``, given the
     positions the windows of the rows in use attend (``lengths``): the kind's own
-    answer (host arithmetic, which body its attention takes included); None for K
-    and V slots, whose decode attention reads every slot's capacity whatever the
-    lengths (ROADMAP A3 ii)."""
+    answer (host arithmetic, which body its attention takes included); of a
+    windowed stack ``{"full": ..., "window": ...}``, a layer of each stack (their
+    decode attention reads every slot's capacity, the ring's ``ring`` positions of a
+    window layer, whatever the lengths); None for plain K and V slots, whose decode
+    attention reads every slot's capacity too (ROADMAP A3 ii)."""
     kind = mixers.cache_kind(cfg)
     if kind is None:
+        if cfg.windowed:
+            return {"full": rows * positions, "window": rows * (ring or positions)}
         return None
     return mixers.module(kind).cache_read_positions(cfg, lengths, rows, positions, window)
 
@@ -126,10 +204,12 @@ def _window_starts(offsets, slot, b: int):
     return [(r, offsets[r]) for r in range(b)]
 
 
-def write_layer(stacked, layer: int, new, starts):
+def write_layer(stacked, layer: int, new, starts, axis: int = 2):
     """Write layer ``layer``'s new entries ``new`` (B, s, ...) (keys or values
     (B, s, kvh, hd); a latent (B, s, r + dr)) into the stacked cache (L, Bc, Smax,
-    ...) at ``starts`` (``_window_starts``), in place.
+    ...) at ``starts`` (``_window_starts``), in place. ``axis``: where the stacked
+    array has its positions (``new`` has them one axis earlier): 2, or 3 in a
+    head-major stack (L, Bc, kvh, Smax, hd) with ``new`` (B, kvh, s, hd).
 
     Only ``lax.dynamic_update_slice`` on the stacked array itself at a static
     layer index keeps a donated cache where it is: slicing the layer's slab
@@ -140,15 +220,21 @@ def write_layer(stacked, layer: int, new, starts):
     with the scatter, against 0.2). So rows with their own offsets take one
     update each: ``B`` is static and small."""
     new = new.astype(stacked.dtype)[None]  # (1, B, s, ...)
-    rest = (0,) * (stacked.ndim - 3)
     if len(starts) == 1:
         (row, pos), = starts
-        return jax.lax.dynamic_update_slice(stacked, new, (layer, row, pos) + rest)
+        return jax.lax.dynamic_update_slice(stacked, new, _at(stacked, layer, row, pos, axis))
     for row, pos in starts:
         stacked = jax.lax.dynamic_update_slice(
             stacked, jax.lax.slice_in_dim(new, row, row + 1, axis=1),
-            (layer, row, pos) + rest)
+            _at(stacked, layer, row, pos, axis))
     return stacked
+
+
+def _at(stacked, layer, row, pos, axis: int):
+    """The start indices of (layer, row) at position ``pos`` along ``axis``."""
+    at = [layer, row] + [0] * (stacked.ndim - 2)
+    at[axis] = pos
+    return tuple(at)
 
 
 def read_layer(stacked, layer: int, slot):
@@ -187,22 +273,154 @@ def _project_qkv_at(x, p, cfg: ModelConfig, cos_sin):
     return q, k, v
 
 
+# -- a stack with sliding-window layers: two stacks, one a ring ---------------------
+
+#: keys a step of a prompt chunk's attention takes in a windowed stack
+KEY_BLOCK = 1024
+
+
+def write_ring(stacked, layer: int, new, starts, aligned: bool):
+    """`write_layer` into a head-major ring (L, Bc, kvh, R, hd): position p of
+    ``new`` (B, kvh, s, hd) lands at ``p mod R``, by ``dynamic_update_slice`` on the
+    stacked array alone (`write_layer`'s rule). An update cannot wrap (XLA clamps
+    its start, and the entries then lie where the mask reads other positions), so:
+
+    - ``aligned`` (a SCALAR offset: a prompt chunk, ``generate``'s prefill) is one
+      update of s positions at ``offset mod R``, and the CALLER keeps it from crossing
+      the ring's end: ``R`` is a multiple of the forwards' positions
+      (`ring_positions`) and a chunk starts at a multiple of them, as the engine's do;
+    - an offset a row with s > 1 (a verify window, anywhere; ONE row too: an engine
+      of one slot) is one update a position.
+
+    (A crossing chunk as two read-merge-write updates of s entries was tried: the
+    chip's compiler then re-laid the WHOLE ring stack to the new entries' order and
+    back, 4 copies of 1.9 GiB a chunk at the cell's size, compiled for a described
+    v5e; plain updates leave the stack where it is.)"""
+    ring, s = stacked.shape[3], new.shape[2]
+    if s > ring:
+        raise ValueError(f"a forward of {s} positions does not fit a ring of {ring}")
+    if s == 1 or aligned:
+        return write_layer(stacked, layer, new, [(row, pos % ring) for row, pos in starts], axis=3)
+    for i in range(s):
+        stacked = write_layer(stacked, layer, jax.lax.slice_in_dim(new, i, i + 1, axis=2),
+                              [(row, (pos + i) % ring) for row, pos in starts], axis=3)
+    return stacked
+
+
+def _ring_key_positions(last, slots, ring: int):
+    """The absolute position ring place ``slots`` (K,) holds for a row whose newest
+    write is position ``last`` (B | 1,): the largest p <= last with p mod R = place
+    -> (B | 1, K). Negative: the row has not come that far, nothing to see there."""
+    last = jnp.reshape(jnp.asarray(last, jnp.int32), (-1, 1))
+    return last - jnp.remainder(last - slots[None].astype(jnp.int32), ring)
+
+
+def _masked_scores(qg, k, q_pos, k_pos, window: int, scale):
+    """Grouped queries ``qg`` (B, s, kv, g, d) against keys ``k`` (B, kv, K, d) ->
+    float32 scores (B, kv, g, s, K), scaled, and masked by ABSOLUTE positions:
+    query at ``q_pos`` (B | 1, s) sees the key at ``k_pos`` (B | 1, K) where
+    ``0 <= k_pos <= q_pos`` and, with a window, ``k_pos > q_pos - window``. No
+    key/value head is repeated (``decode_attention``'s grouping, kv-major)."""
+    scores = jnp.einsum("bqkgh,bksh->bkgqs", qg, k, preferred_element_type=jnp.float32) * scale
+    kp, qp = k_pos[:, None, :], q_pos[:, :, None]
+    allowed = (kp <= qp) & (kp >= 0)
+    if window:
+        allowed = allowed & (kp > qp - window)
+    return jnp.where(allowed[:, None, None], scores, modeling.MASKED_SCORE)
+
+
+def _attend_rows(qg, k, v, q_pos, k_pos, window: int, scale):
+    """Rows' windows against their whole rows of the cache at once (a decode step,
+    a verify window, ``generate``): -> (B, s, kv, g, d)."""
+    probs = jax.nn.softmax(_masked_scores(qg, k, q_pos, k_pos, window, scale), axis=-1)
+    return jnp.einsum("bkgqs,bksh->bqkgh", probs.astype(qg.dtype), v)
+
+
+def _attend_chunk(qg, ks, vs, layer: int, slot, q_pos, key_positions, blocks, block: int,
+                  window: int, scale):
+    """One request's prompt chunk against row ``slot`` of the stacked cache, ``block``
+    keys at a time with a running softmax, so that no more than (heads, s, block)
+    float32 scores live at once (28 x 1,024 x 16,384 x 4 B = 1.9 GB a full layer
+    otherwise). ``key_positions(places)``: the absolute positions the places hold;
+    ``blocks`` may be traced (a full layer stops at the chunk's end)."""
+    b, s, kv, g, d = qg.shape
+    shape = (1, 1, kv, block, d)
+
+    def scored(j):
+        k = jax.lax.dynamic_slice(ks, (layer, slot, 0, j * block, 0), shape)[0]
+        v = jax.lax.dynamic_slice(vs, (layer, slot, 0, j * block, 0), shape)[0]
+        scores = _masked_scores(qg, k, q_pos, key_positions(j * block + jnp.arange(block)),
+                                window, scale)
+        return scores, lambda e: jnp.einsum(
+            "bkgqs,bksh->bkgqh", e.astype(qg.dtype), v, preferred_element_type=jnp.float32)
+
+    o = modeling.running_softmax(blocks, scored, (b, kv, g, s), d)
+    return jnp.transpose(o, (0, 3, 1, 2, 4)).astype(qg.dtype)
+
+
+def _windowed_attention(x, p, cfg: ModelConfig, cache: WindowKVCache, windowed: bool, index: int,
+                        starts, slot, offsets, cos_sin):
+    """A layer's attention of a windowed stack over the `WindowKVCache` -> (y, cache):
+    ``windowed`` says which stack the layer's keys and values live in (the ring, or
+    whole rows), ``index`` where in it; ``cfg`` is the layer's view. Scopes:
+    ``window`` | ``full`` > ``qkv_proj``, ``cache_write``, ``attn_core``,
+    ``out_proj``."""
+    b, s = x.shape[:2]
+    kv, g, d = cfg.kv_heads, cfg.num_heads // cfg.kv_heads, cfg.head_dim
+    scale = cfg.attention_multiplier if cfg.attention_multiplier is not None else d ** -0.5
+    ks, vs = (cache.wk, cache.wv) if windowed else (cache.k, cache.v)
+    positions = ks.shape[3]
+    with jax.named_scope("window" if windowed else "full"):
+        q, k, v = _project_qkv_at(x, p, cfg, cos_sin if cfg.pos_embed == "rope" else None)
+        with jax.named_scope("cache_write"):
+            write = (partial(write_ring, aligned=jnp.ndim(offsets) == 0) if windowed
+                     else partial(write_layer, axis=3))
+            k, v = jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)  # (B, kvh, s, hd): head-major
+            ks, vs = write(ks, index, k, starts), write(vs, index, v, starts)
+        with jax.named_scope("attn_core"):
+            qg = q.reshape(b, s, kv, g, d)
+            q_pos = jnp.reshape(_positions(offsets, s), (-1, s))
+            last = jnp.reshape(jnp.asarray(offsets), (-1,)) + s - 1
+            if windowed:
+                def key_positions(places):
+                    return _ring_key_positions(last, places, positions)
+            else:
+                def key_positions(places):
+                    return places[None]
+            if slot is None:
+                o = _attend_rows(qg, read_layer(ks, index, None), read_layer(vs, index, None),
+                                 q_pos, key_positions(jnp.arange(positions)), cfg.attn_window,
+                                 scale)
+            else:
+                block = modeling.key_block(positions, KEY_BLOCK)
+                # a ring is read whole; a full row up to the chunk's end
+                blocks = positions // block if windowed else (offsets + s + block - 1) // block
+                o = _attend_chunk(qg, ks, vs, index, slot, q_pos, key_positions, blocks, block,
+                                  cfg.attn_window, scale)
+        with jax.named_scope("out_proj"):
+            y = modeling.attn_output(o.reshape(b, s, kv * g, d), p["attn"], cfg, x.dtype)
+    cache = cache._replace(wk=ks, wv=vs) if windowed else cache._replace(k=ks, v=vs)
+    return y, cache
+
+
 @jax.named_scope("head")
 def _head(x, params: Params, cfg: ModelConfig):
     return modeling.lm_head(modeling.norm(x, params["final_norm"], cfg), params, cfg)
 
 
-def _mlp_at(x, p, cfg: ModelConfig, moe_stats: Optional[list]):
+def _mlp_at(x, p, cfg: ModelConfig, moe_stats: Optional[list], router_x=None):
     """A layer's pre-norm and MLP; a dropless expert layer's router statistics go
-    into ``moe_stats`` where the caller keeps them."""
+    into ``moe_stats`` where the caller keeps them; ``router_x``: what such a
+    layer's router reads where that is not the block's own normed input."""
     normed = modeling.norm(x, p["mlp_norm"], cfg)
-    if moe_stats is None or not (cfg.moe_dropless and "router" in p["mlp"]):
+    if not (cfg.moe_dropless and "router" in p["mlp"]):
         return modeling.mlp_block(normed, p["mlp"], cfg, train=False)
     from galvatron_tpu.models import moe
 
     with jax.named_scope("mlp"):
-        y, stats = moe.moe_topk_block(normed, p["mlp"], cfg)
-    moe_stats.append(stats)
+        y, stats = moe.moe_topk_block(normed, p["mlp"], cfg, router_x=router_x)
+    if moe_stats is not None:
+        moe_stats.append(stats)
     return y
 
 
@@ -228,20 +446,33 @@ def forward_with_cache(params: Params, tokens, cfg: ModelConfig, cache, offsets,
     The stacked cache (every array (L, B, Smax, ...)) is carried whole through
     the layers and written in place (``write_layer``), write-then-attend; a
     window that would cross the row's end is CLAMPED back by the update, so
-    callers keep ``offset + s <= Smax``."""
+    callers keep ``offset + s <= Smax``. A stack with sliding-window layers
+    (``cfg.windowed``) carries a `WindowKVCache` and maps each layer to its stack
+    (`layer_stacks`); ``s`` is then at most what the ring was sized for
+    (``init_kv_cache``'s ``tokens``)."""
     s = tokens.shape[1]
-    smax = cache[0].shape[2]
+    smax = cache[0].shape[3 if isinstance(cache, WindowKVCache) else 2]
     cos_sin = _rope_at(cfg, smax, offsets, s)
     bias = _alibi_bias(cfg, smax, offsets, s)
     x = _embed_at(params, tokens, cfg, offsets)
     starts = _window_starts(offsets, slot, tokens.shape[0])
     kind = mixers.cache_kind(cfg)
-    if kind is None:
+    ringed = kind is None and cfg.windowed
+    if ringed:
+        stacks = layer_stacks(cfg)
+    elif kind is None:
         ks, vs = cache
     for i, p in enumerate(params["layers"]):
         with jax.named_scope(f"layer_{i}"):
+            # (the router of such a layer reads what its attention block reads)
+            router_x = (modeling.norm(x, p["attn_norm"], cfg)
+                        if cfg.moe_router_input == "attn" and cfg.moe_dropless else None)
             with jax.named_scope("attn"):
-                if kind is not None:
+                if ringed:
+                    y, cache = _windowed_attention(
+                        x, p, cfg.layer_view(i), cache, *stacks[i], starts, slot, offsets, cos_sin)
+                    x = x + y
+                elif kind is not None:
                     y, cache = mixers.module(kind).cached_block(
                         modeling.norm(x, p["attn_norm"], cfg), p[kind], cfg, cache, i, starts,
                         slot, offsets, cos_sin)
@@ -257,8 +488,8 @@ def forward_with_cache(params: Params, tokens, cfg: ModelConfig, cache, offsets,
                             bias=bias, q_offset=offsets)
                     with jax.named_scope("out_proj"):
                         x = x + modeling.attn_output(o, p["attn"], cfg, x.dtype)
-            x = x + _mlp_at(x, p, cfg, moe_stats)
-    return _head(x, params, cfg), (cache if kind is not None else KVCache(ks, vs))
+            x = x + _mlp_at(x, p, cfg, moe_stats, router_x)
+    return _head(x, params, cfg), (cache if kind is not None or ringed else KVCache(ks, vs))
 
 
 # ---------------------------------------------------------------------------
@@ -525,7 +756,7 @@ def generate(
     if min_prompt_len is None:
         min_prompt_len = p_len
     max_len = p_len + max_new_tokens
-    cache = init_kv_cache(cfg, b, max_len)
+    cache = init_kv_cache(cfg, b, max_len, tokens=min_prompt_len)
 
     # prefill positions [0, min_prompt_len); all rows have real tokens there
     logits, cache = forward_with_cache(

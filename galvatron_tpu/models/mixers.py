@@ -29,7 +29,7 @@ with the clause that says why. `limits` turns the rows of a configuration's
 kinds, its interleaving and its expert path into `Limit`s; `build_runtime`
 raises the first one a plan breaks, `plan_check` reports each, the search
 leaves each out and names its tag, `init_kv_cache` refuses a stack with one on
-the cache. Plain data and functions of the configuration's fields: nothing here
+the cache (and `serving.Engine` one on the paged backend). Plain data and functions of the configuration's fields: nothing here
 imports jax or a kernel, or anything of ``parallel/``, ``search/``, ``analysis/``.
 """
 
@@ -137,8 +137,9 @@ DEGREES = ("tp", "cp", "ep")
 class Limit:
     """One thing a model's layers do not implement."""
 
-    # "tp" | "cp" | "ep": a layer's degree > 1; "pp" | "pack_sequences" | "fp16":
-    # the run's; "kv_cache": generation's
+    # "tp" | "cp" | "ep": a layer's degree > 1; "pp" | "pack_sequences" | "fp16" |
+    # "attn_impl" (another attention path than XLA's): the run's; "kv_cache":
+    # generation's; "paged_kv": the paged serving backend's
     what: str
     layers: Tuple[int, ...]  # the strategy indices it is reported on: a kind's, or all
     refusal: str  # build_runtime's (init_kv_cache's) sentence; "{at}": the layers that break it
@@ -156,17 +157,62 @@ class Limit:
             over = [i for i, s in enumerate(hp.layer_strategies) if getattr(s, self.what) > 1]
             return over if self.stack else [i for i in over if i in self.layers]
         return {"pp": hp.pp > 1, "fp16": hp.mixed_precision == "fp16",
-                "pack_sequences": bool(cfg.pack_sequences)}.get(self.what, False)
+                "pack_sequences": bool(cfg.pack_sequences),
+                "attn_impl": cfg.attn_impl != "xla"}.get(self.what, False)
 
     def sentence(self, at=()) -> str:
         """The refusal, given what `broken_by` found."""
         return self.refusal.replace("{at}", str(at))
 
 
+def _window_limits(cfg, enc: int, every: Tuple[int, ...]) -> List[Limit]:
+    """What a stack with sliding-window layers does not implement: the window is a
+    mask of XLA's attention (``modeling.attention_xla``) and a ring of the slot
+    cache (``generation.WindowKVCache``), and of nothing else."""
+    at = tuple(enc + i for i, w in enumerate(cfg.window_layers) if w)
+    layers = f"sliding-window layers (window {cfg.sliding_window_size}; layers {at})"
+    out = [
+        Limit("attn_impl", at,
+              refusal=("an attention path other than XLA's (attn_impl 'flash' or 'ring') is not "
+                       f"implemented for a stack with {layers}: the flash and ring kernels "
+                       "(ops/flash_attention.py, parallel/ring.py) carry no window; use "
+                       "attn_impl='xla'")),
+        Limit("cp", at, stack=True, tag="sliding_window_layers_no_cp", code="GTA019",
+              refusal=("context parallelism (cp>1) is not implemented for a stack with "
+                       f"{layers}: the ring / Ulysses layers mask causally over whole "
+                       "sequences and carry no window; use cp=1"),
+              diagnostic=("on a stack with sliding-window layers — the ring / Ulysses layers "
+                          "carry no window")),
+        Limit("pack_sequences", at,
+              refusal=(f"pack_sequences is not implemented for a stack with {layers}: a "
+                       "window counts positions of the row, not of the segment")),
+        Limit("pp", every, tag="sliding_window_layers_no_pp", code="GTA020",
+              refusal=(f"pipeline parallelism (pp>1) is not implemented for a stack with "
+                       f"{layers}: the pipeline engines run every layer of a stage under the "
+                       "model's one configuration, and the window and the position signal "
+                       "change by layer; use pp=1"),
+              diagnostic=("on a stack with sliding-window layers — the pipeline engines run "
+                          "every layer of a stage under one configuration"),
+              hint="use pp_deg 1 for a stack with sliding-window layers"),
+        Limit("paged_kv", at,
+              refusal=(f"the paged backend (--kv_num_blocks) is not implemented for a stack "
+                       f"with {layers}: a block pool holds every layer's positions alike and "
+                       "has no ring; serve it from the slot cache (kv_num_blocks 0)")),
+    ]
+    if has_mixer_layers(cfg):
+        out.append(Limit(
+            "kv_cache", every,
+            refusal=("generation is not implemented for a stack that has both sliding-window "
+                     "layers and layers of another kind than attention (this model: "
+                     f"{dict(collections.Counter(cfg.kinds))}): the ring holds keys and "
+                     "values; train-only")))
+    return out
+
+
 def limits(cfg) -> List[Limit]:
     """What ``cfg``'s layers do not implement, in the order `build_runtime`
     refuses: each recurrent kind's own, pipeline stages over interleaved kinds,
-    the dropless expert path's."""
+    a windowed stack's, the dropless expert path's."""
     kinds = tuple(getattr(cfg, "kinds", ()))
     enc = getattr(cfg, "enc_layers", 0)
     every = tuple(range(enc + len(kinds)))
@@ -213,6 +259,8 @@ def limits(cfg) -> List[Limit]:
             diagnostic=("over interleaved layer kinds — the pipeline engines "
                         "stack one kind of layer a stage position"),
             hint="use pp_deg 1 for a hybrid stack"))
+    if getattr(cfg, "windowed", False):
+        out.extend(_window_limits(cfg, enc, every))
     if getattr(cfg, "moe_dropless", False):
         # the sorted-rows path keeps every expert (or its held share) on every
         # device and hands its auxiliary loss up through the GSPMD step

@@ -46,7 +46,6 @@ metrics read them).
 
 from __future__ import annotations
 
-import math
 from typing import Any, Dict, NamedTuple
 
 import jax
@@ -59,7 +58,6 @@ from galvatron_tpu.ops.quant import QuantTensor, qmatmul
 
 Params = Dict[str, Any]
 F32 = jnp.float32
-_MASKED = -1e30
 #: keys a step of the chunk form expands and attends at once
 KEY_BLOCK = 1024
 
@@ -184,7 +182,8 @@ def _expanded_scores(q_nope, q_rope, latent, p: Params, cfg, q_pos, k_pos):
     scores = jnp.einsum("bqnd,bknd->bnqk", jnp.concatenate([q_nope, q_rope], axis=-1),
                         jnp.concatenate([kv[..., :dn], k_rope], axis=-1),
                         preferred_element_type=F32)
-    return jnp.where(_allowed(q_pos, k_pos), scores * softmax_scale(cfg), _MASKED), kv[..., dn:]
+    scores = jnp.where(_allowed(q_pos, k_pos), scores * softmax_scale(cfg), modeling.MASKED_SCORE)
+    return scores, kv[..., dn:]
 
 
 def attend_expanded(q_nope, q_rope, latent, p: Params, cfg, q_pos):
@@ -216,7 +215,7 @@ def _plain_context(q_cat, latent, q_pos, cfg):
     envelope (`mla_decode.decode_path`), and the kernel's reference."""
     scores = jnp.einsum("bqnc,bkc->bnqk", q_cat, latent, preferred_element_type=F32)
     scores = jnp.where(_allowed(q_pos, jnp.arange(latent.shape[1])),
-                       scores * softmax_scale(cfg), _MASKED)
+                       scores * softmax_scale(cfg), modeling.MASKED_SCORE)
     probs = jax.nn.softmax(scores, axis=-1).astype(q_cat.dtype)
     return jnp.einsum("bnqk,bkc->bqnc", probs, latent)[..., :cfg.mla_kv_rank]
 
@@ -267,10 +266,9 @@ def cache_read_positions(cfg, lengths, rows: int, positions: int, window: int = 
 
 
 def key_block(positions: int) -> int:
-    """Keys a step of `attend_chunk` takes: the largest power of two up to
-    ``KEY_BLOCK`` that divides the slot's capacity (all of it where that is small)."""
-    block = math.gcd(positions, KEY_BLOCK)
-    return block if positions > KEY_BLOCK and block >= 8 else positions
+    """Latent positions a step of the chunk form takes (`modeling.key_block` up to
+    ``KEY_BLOCK``)."""
+    return modeling.key_block(positions, KEY_BLOCK)
 
 
 def _plain_chunk(q_nope, q_rope, stacked, layer: int, slot, offset, p: Params, cfg):
@@ -285,24 +283,17 @@ def _plain_chunk(q_nope, q_rope, stacked, layer: int, slot, offset, p: Params, c
     block = key_block(positions)
     q_pos = (offset + jnp.arange(s))[None]
 
-    def step(j, carry):
-        m, total, acc = carry
+    def scored(j):
         latent = jax.lax.dynamic_slice(
             stacked, (layer, slot, j * block, 0), (1, 1, block, width))[0]
         scores, v = _expanded_scores(q_nope, q_rope, latent, p, cfg, q_pos,
                                      j * block + jnp.arange(block))
-        m_new = jnp.maximum(m, jnp.max(scores, axis=-1))
-        shrink = jnp.exp(m - m_new)
-        e = jnp.exp(scores - m_new[..., None])
-        acc = acc * shrink[..., None] + jnp.einsum(
+        return scores, lambda e: jnp.einsum(
             "bnqk,bknd->bnqd", e.astype(q_nope.dtype), v, preferred_element_type=F32)
-        return m_new, total * shrink + jnp.sum(e, axis=-1), acc
 
-    # (block 0 holds position 0, which every query sees: ``m`` is real from the start)
-    init = (jnp.full((1, n, s), _MASKED, F32), jnp.zeros((1, n, s), F32),
-            jnp.zeros((1, n, s, dv), F32))
-    _, total, acc = jax.lax.fori_loop(0, (offset + s + block - 1) // block, step, init)
-    return jnp.transpose(acc / total[..., None], (0, 2, 1, 3)).astype(q_nope.dtype)
+    # (block 0 holds position 0, which every query sees: the maximum is real from the start)
+    o = modeling.running_softmax((offset + s + block - 1) // block, scored, (1, n, s), dv)
+    return jnp.transpose(o, (0, 2, 1, 3)).astype(q_nope.dtype)
 
 
 def _chunk_path(cfg, rows: int, positions: int) -> str:

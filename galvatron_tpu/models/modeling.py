@@ -152,6 +152,36 @@ class ModelConfig:
     # YaRN rotary scaling (``deepseek_yarn``): (factor, original positions,
     # beta_fast, beta_slow, mscale, mscale_all_dim); empty: plain rotary.
     rope_yarn: Tuple[float, ...] = ()
+    # Sliding-window attention by layer (smallthinker-class stacks), as published
+    # for the WHOLE model; a model cut in depth keeps the first ``num_layers``
+    # entries. ``sliding_window_layout[i]`` 1: query p of layer i sees the keys j
+    # with ``p - sliding_window_size < j <= p`` (the window holds the query's own
+    # position); 0: every j <= p. ``rope_layout[i]`` 0: layer i has no position
+    # signal at all (NoPE) whatever ``pos_embed`` says. Both empty: every layer is
+    # the model's. A window layer is attention: its parameters are attention's;
+    # the cached forwards keep its keys and values in a ring (models/generation.py).
+    # A layer runs under its own VIEW of the configuration (``layer_view``), whose
+    # ``attn_window`` is the layer's window (0: none) and whose ``pos_embed`` is
+    # "nope" where the layout says so. What a windowed stack does not implement
+    # (the flash / ring / context-parallel paths, packing, pipelines, the paged
+    # backend) is ``mixers.limits``'s.
+    sliding_window_size: int = 0
+    sliding_window_layout: Tuple[int, ...] = ()
+    rope_layout: Tuple[int, ...] = ()
+    attn_window: int = 0
+    # The gate's activation of every gated unit (``act_fn`` "swiglu": the dense
+    # MLP, the experts, a shared expert): "silu" (SwiGLU) | "relu" (ReGLU).
+    glu_act: str = "silu"
+    # What a dropless expert layer's router reads: "mlp", the MLP block's normed
+    # input like every projection of the block, or "attn", the ATTENTION block's
+    # normed input of the same layer (smallthinker: the router sits before the
+    # attention).
+    moe_router_input: str = "mlp"
+    # The softmax router's float32 GEMM: "default" (the chip runs it in one bf16
+    # pass; the older softmax routers' programs) | "highest" (a choice no longer
+    # flips where two logits lie within a bf16 ulp). The sigmoid router's is
+    # always at ``highest``.
+    moe_router_precision: str = "default"
     ssm_heads: int = 0
     ssm_head_dim: int = 64
     ssm_state: int = 128
@@ -242,6 +272,35 @@ class ModelConfig:
             raise ValueError(
                 f"layer_kinds has {len(self.layer_kinds)} entries for {self.num_layers} layers")
         return self.layer_kinds[: self.num_layers]
+
+    @property
+    def windowed(self) -> bool:
+        """Some layer of the stack attends through a sliding window."""
+        return self.sliding_window_size > 0 and any(self.window_layers)
+
+    @property
+    def window_layers(self) -> Tuple[bool, ...]:
+        """Of each of the ``num_layers`` decoder layers: it has a window."""
+        if not self.sliding_window_layout or not self.sliding_window_size:
+            return (False,) * self.num_layers
+        if len(self.sliding_window_layout) < self.num_layers:
+            raise ValueError(f"sliding_window_layout has {len(self.sliding_window_layout)} "
+                             f"entries for {self.num_layers} layers")
+        return tuple(bool(w) for w in self.sliding_window_layout[: self.num_layers])
+
+    def layer_view(self, i: int) -> "ModelConfig":
+        """The configuration decoder layer ``i`` runs under: the model's, with the
+        layer's own window (``attn_window``) and "nope" for ``pos_embed`` where the
+        published layouts say so; the model's own where it has no such layout."""
+        if not self.sliding_window_layout and not self.rope_layout:
+            return self
+        if self.rope_layout and len(self.rope_layout) < self.num_layers:
+            raise ValueError(
+                f"rope_layout has {len(self.rope_layout)} entries for {self.num_layers} layers")
+        rope = not self.rope_layout or bool(self.rope_layout[i])
+        return self.replace(
+            attn_window=self.sliding_window_size if self.window_layers[i] else 0,
+            pos_embed=self.pos_embed if rope else "nope")
 
     @property
     def kv_heads(self) -> int:
@@ -905,6 +964,42 @@ def _repeat_kv(x, n_rep: int):
     )
 
 
+#: what a masked score reads, and where a running maximum starts
+MASKED_SCORE = -1e30
+
+
+def key_block(positions: int, most: int) -> int:
+    """Keys a step of a blockwise attention over a row of ``positions`` takes: the
+    largest divisor of the row up to ``most`` that `math.gcd` finds (all of a small
+    or odd row)."""
+    block = math.gcd(positions, most)
+    return block if positions > most and block >= 8 else positions
+
+
+def running_softmax(blocks, scored, rows: tuple, width: int):
+    """A softmax over keys taken a block at a time, so that only one block's float32
+    scores live at once: ``scored(j)`` -> (block ``j``'s masked float32 scores
+    (*rows, K), a function from their exponentials (*rows, K) to the block's float32
+    share of the output (*rows, width)``; ``blocks`` may be traced. -> (*rows, width)
+    float32, normalised. A block wholly masked for a query counts 1 a key against
+    `MASKED_SCORE`; the first key the query does see shrinks that to an exact 0, so
+    every query has to see a key (causal attention: its own)."""
+
+    def step(j, carry):
+        m, total, acc = carry
+        scores, weigh = scored(j)
+        m_new = jnp.maximum(m, jnp.max(scores, axis=-1))
+        shrink = jnp.exp(m - m_new)
+        e = jnp.exp(scores - m_new[..., None])
+        acc = acc * shrink[..., None] + weigh(e)
+        return m_new, total * shrink + jnp.sum(e, axis=-1), acc
+
+    init = (jnp.full(rows, MASKED_SCORE, jnp.float32), jnp.zeros(rows, jnp.float32),
+            jnp.zeros(rows + (width,), jnp.float32))
+    _, total, acc = jax.lax.fori_loop(0, blocks, step, init)
+    return acc / total[..., None]
+
+
 def attention_xla(q, k, v, cfg: ModelConfig, bias=None, q_offset=0, seg_ids=None):
     """Reference einsum attention (the 'CoreAttention' path, reference:
     galvatron/core/tensor_parallel/transformer.py:298-435).
@@ -916,6 +1011,9 @@ def attention_xla(q, k, v, cfg: ModelConfig, bias=None, q_offset=0, seg_ids=None
     by the continuous-batching serving engine, where every row of the batch
     is a different request at a different depth into its sequence.
 
+    ``cfg.attn_window`` (a window layer's view, ``ModelConfig.layer_view``): query
+    at position p sees the keys j with ``p - window < j <= p``.
+
     ``seg_ids`` ((B, S), packed sequences): the causal predicate tightens to
     intra-segment — query i attends to key j only when ``seg[i] == seg[j]``,
     so cross-document attention is structurally impossible. The combine is a
@@ -923,7 +1021,7 @@ def attention_xla(q, k, v, cfg: ModelConfig, bias=None, q_offset=0, seg_ids=None
     a row holding a single segment produces a bit-identical mask, which is
     what makes the packed-vs-padded gradient-parity test exact."""
     b, s, nh, hd = q.shape
-    if s == 1 and bias is None and seg_ids is None and cfg.causal:
+    if s == 1 and bias is None and seg_ids is None and cfg.causal and not cfg.attn_window:
         # KV-cache decode: skip the _repeat_kv materialization and the
         # (b, n, 1, k) score reshuffle — the GQA-native dot-product path
         # reads the cache once (tests/test_flash_attention.py parity case)
@@ -945,6 +1043,8 @@ def attention_xla(q, k, v, cfg: ModelConfig, bias=None, q_offset=0, seg_ids=None
         q_pos = jnp.reshape(jnp.asarray(q_offset), (-1, 1)) + jnp.arange(s)[None]
         k_pos = jnp.arange(k.shape[1])
         allowed = k_pos[None, None, :] <= q_pos[:, :, None]
+        if cfg.attn_window:  # (a layer's view: the window holds the query's own position)
+            allowed = allowed & (k_pos[None, None, :] > q_pos[:, :, None] - cfg.attn_window)
         if seg_ids is not None:
             allowed = allowed & (seg_ids[:, :, None] == seg_ids[:, None, :])
         scores = jnp.where(allowed[:, None], scores, -1e30)
@@ -1224,6 +1324,12 @@ def attn_block(x, p, cfg: ModelConfig, cos_sin=None, alibi=None, remat_attn: boo
     kernels carry no segment mask)."""
     b, s, h = x.shape
     hd = cfg.head_dim
+    if cfg.attn_window and cfg.attn_impl != "xla":
+        # (build_runtime refuses the stack through mixers.limits; a caller that comes
+        # past it, ``forward`` alone, is told the table's sentence and not given
+        # attention without the window)
+        raise ValueError(next(
+            limit.sentence() for limit in mixers.limits(cfg) if limit.what == "attn_impl"))
     if cfg.attn_gate:
         return _attn_block_gated(x, p, cfg, cos_sin, remat_attn, seg_ids, place)
     if (
@@ -1263,6 +1369,13 @@ def attn_block(x, p, cfg: ModelConfig, cos_sin=None, alibi=None, remat_attn: boo
 _gelu_tanh = partial(jax.nn.gelu, approximate=True)  # one object: it keys the seam's programs
 
 
+def glu_gate(cfg: ModelConfig):
+    """The activation on the gate of a gated unit: silu (SwiGLU) or relu (ReGLU)."""
+    if cfg.glu_act not in ("silu", "relu"):
+        raise ValueError(f"glu_act {cfg.glu_act!r}: 'silu' or 'relu'")
+    return jax.nn.relu if cfg.glu_act == "relu" else jax.nn.silu
+
+
 @jax.named_scope("mlp")
 def mlp_block(x, p, cfg: ModelConfig, train: bool = True, place: Placement = LOCAL):
     """SwiGLU or GeLU MLP (reference: ParallelMLP, galvatron/core/
@@ -1278,6 +1391,11 @@ def mlp_block(x, p, cfg: ModelConfig, train: bool = True, place: Placement = LOC
         from galvatron_tpu.models import moe
 
         if cfg.moe_dropless:  # callers of mlp_block want activations only
+            if cfg.moe_router_input != "mlp":
+                raise ValueError(
+                    f"moe_router_input={cfg.moe_router_input!r}: this layer's router reads "
+                    "another input than the block's; its callers hand it over "
+                    "(moe.moe_topk_block's router_x), mlp_block has none")
             return moe.moe_topk_block(x, p, cfg, place=place)[0]
         return moe.moe_block(x, p, cfg, train=train, place=place)
     # the placement's seams only serve the (B, S, H) token stream; vision /
@@ -1303,7 +1421,8 @@ def mlp_block(x, p, cfg: ModelConfig, train: bool = True, place: Placement = LOC
         if "w13_b" in p:
             g = g + p["w13_b"].astype(x.dtype)
         g = checkpoint_name(g, "mlp_gate")
-        prod = lambda g_: jax.nn.silu(g_[..., :f]) * g_[..., f:]
+        gate_act = glu_gate(cfg)
+        prod = lambda g_: gate_act(g_[..., :f]) * g_[..., f:]
         if cfg.mlp_recompute == "gate":
             prod = jax.checkpoint(prod)
         y = down(prod(g), p["w2"].astype(x.dtype))
@@ -1337,8 +1456,12 @@ def residual_add(x, y, cfg: ModelConfig):
     return x + y if cfg.residual_multiplier == 1.0 else x + y * cfg.residual_multiplier
 
 
-def mlp_residual(x, p, cfg: ModelConfig, train: bool = True, place: Placement = LOCAL):
-    """x + MLP(norm(x)) — the per-layer MLP residual branch, with the
+def mlp_residual(x, p, cfg: ModelConfig, train: bool = True, place: Placement = LOCAL,
+                 router_x=None):
+    """``router_x``: what a dropless expert layer's router reads where that is not
+    the block's own normed input (``cfg.moe_router_input``; `decoder_layer`).
+
+    x + MLP(norm(x)) — the per-layer MLP residual branch, with the
     activation-memory saveable policy applied when cfg.mlp_recompute ==
     'policy': jax.checkpoint over the norm+MLP region saving ONLY the
     'mlp_gate'-named projection output, so (a) the gate is saved exactly once
@@ -1356,7 +1479,7 @@ def mlp_residual(x, p, cfg: ModelConfig, train: bool = True, place: Placement = 
 
         normed = norm(x, p["mlp_norm"], cfg)
         with jax.named_scope("mlp"):
-            y, stats = moe.moe_topk_block(normed, p["mlp"], cfg, place=place)
+            y, stats = moe.moe_topk_block(normed, p["mlp"], cfg, place=place, router_x=router_x)
         return x + y, stats
     if cfg.moe_dropless:
         # a leading dense layer of such a model (``moe_dense_layers``): no router, no statistics
@@ -1425,18 +1548,24 @@ def decoder_layer(
     references, the profiler) pass nothing.
 
     A layer whose parameters hold a kind of ``mixers.MIXERS`` in place of
-    ``attn`` (a hybrid stack, ``cfg.kinds``) runs that kind's mixer there."""
+    ``attn`` (a hybrid stack, ``cfg.kinds``) runs that kind's mixer there.
+
+    ``cfg`` is the LAYER's view (``ModelConfig.layer_view``) where the model's
+    layers differ by a window or a position signal."""
     for kind in mixers.MIXERS:
         if kind in p:
             x = residual_add(x, mixers.module(kind).block(
                 norm(x, p["attn_norm"], cfg), p[kind], cfg, place=place), cfg)
             return mlp_residual(x, p, cfg, place=place)
+    normed = norm(x, p["attn_norm"], cfg)
     x = residual_add(x, attn_block(
-        norm(x, p["attn_norm"], cfg), p["attn"], cfg, cos_sin, alibi,
+        normed, p["attn"], cfg, cos_sin, alibi,
         remat_attn=remat_attn, seg_ids=seg_ids, place=place,
     ), cfg)
     if enc_out is not None and "cross" in p:
         x = x + cross_attn_block(norm(x, p["cross_norm"], cfg), enc_out, p["cross"], cfg)
+    if cfg.moe_router_input == "attn":  # the router reads what the attention block read
+        return mlp_residual(x, p, cfg, place=place, router_x=normed)
     return mlp_residual(x, p, cfg, place=place)
 
 
@@ -1520,7 +1649,7 @@ def forward_with_stats(params, tokens, cfg: ModelConfig, layer_hook=None):
             if layer_hook is not None:
                 x = layer_hook(i, x, lp, **hook_kw)
             else:
-                x = decoder_layer(x, lp, cfg, cos_sin, alibi, seg_ids=seg)
+                x = decoder_layer(x, lp, cfg.layer_view(i), cos_sin, alibi, seg_ids=seg)
             if cfg.moe_dropless:
                 x, layer_stats = x
                 if layer_stats is not None:
@@ -2099,5 +2228,20 @@ PRESETS: Dict[str, ModelConfig] = {
         mla_v_dim=128, rope_yarn=(40.0, 4096, 32.0, 1.0, 1.0, 1.0),
         moe_experts=128, moe_router="sigmoid_topk", moe_top_k=8, moe_route_scale=2.5,
         moe_ffn_dim=2048, moe_shared_ffn_dim=2048, moe_shared_gate=False, moe_dense_layers=1,
+    ),
+    # PowerInfer/SmallThinker-21BA3B-Instruct (model_type smallthinker): 52 layers in
+    # periods of four, one FULL layer without any position signal (NoPE) then three
+    # SLIDING-WINDOW layers of 4096 keys with rotary (theta 1.5e6); GQA 28 / 4 heads
+    # of 128 (not hidden / heads); every MLP 64 ReGLU experts of width 768, 6 a
+    # token, softmax over the chosen six, routed from the ATTENTION block's normed
+    # input; no dense MLP (ffn_dim 768 is used by no layer); untied head. Served
+    # (models/generation.py keeps the window layers' keys and values in a ring).
+    "smallthinker-21b-a3b": ModelConfig(
+        vocab_size=151936, hidden_size=2560, num_layers=52, num_heads=28, num_kv_heads=4,
+        attn_head_dim=128, ffn_dim=768, max_seq_len=16384, rope_theta=1.5e6, norm_eps=1e-6,
+        sliding_window_size=4096, sliding_window_layout=(0, 1, 1, 1) * 13,
+        rope_layout=(0, 1, 1, 1) * 13, moe_experts=64, moe_router="softmax_topk", moe_top_k=6,
+        moe_ffn_dim=768, moe_norm_topk=True, glu_act="relu", moe_router_input="attn",
+        moe_router_precision="highest",
     ),
 }

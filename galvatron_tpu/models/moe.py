@@ -104,6 +104,14 @@ def route_top1(logits: jax.Array, capacity: int, *, sinkhorn_iters: int = 8,
     return dispatch, combine
 
 
+def _glu_gate(cfg):
+    """The gate's activation of a gated expert (`modeling.glu_gate`; imported late:
+    `modeling` loads this module)."""
+    from galvatron_tpu.models.modeling import glu_gate
+
+    return glu_gate(cfg)
+
+
 def init_moe_params(key, cfg) -> Params:
     """Router over all ``moe_experts`` + stacked expert FFN weights (leading
     dim: the experts this copy holds, ``cfg.moe_held``; all of them unless
@@ -194,7 +202,7 @@ def moe_block(x: jax.Array, p: Params, cfg, train: bool = True,
     w2 = p["w2"].astype(x.dtype)
     if cfg.act_fn == "swiglu":
         w3 = p["w3"].astype(x.dtype)
-        hmid = jax.nn.silu(jnp.einsum("ech,ehf->ecf", xe, w1)) * jnp.einsum(
+        hmid = _glu_gate(cfg)(jnp.einsum("ech,ehf->ecf", xe, w1)) * jnp.einsum(
             "ech,ehf->ecf", xe, w3
         )
     else:
@@ -353,10 +361,11 @@ def held_path_counts(cfg) -> dict:
     return counts
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(9,))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10))
 def held_experts(x, weights, w13, w2, pair_row, row_pair, row_valid, tile_group, num_tiles,
-                 tile: int):
-    """Dispatch, the experts' SwiGLU FFN and combine of a held share over
+                 tile: int, act: str = "silu"):
+    """Dispatch, the experts' gated FFN (``act`` on the gate: "silu", SwiGLU, or
+    "relu", ReGLU) and combine of a held share over
     `held_layout`, every pass bounded by the rows that hold a pair: x (T, h),
     weights (T, k) float32, w13 (E, h, 2f) = [w1 | w3] or the pair (w1, w3) as
     stored (a GEMM each, nothing joined but their outputs), w2 (E, f, h) -> (T, h).
@@ -365,10 +374,11 @@ def held_experts(x, weights, w13, w2, pair_row, row_pair, row_valid, tile_group,
     ``num_tiles`` (`ops/moe_held.py`) and one backward written out, so that no
     gradient is summed by XLA over the whole buffer either."""
     return _held_forward(x, weights, w13, w2, pair_row, row_pair, row_valid, tile_group,
-                         num_tiles, tile)[0]
+                         num_tiles, tile, act)[0]
 
 
-def _held_forward(x, weights, w13, w2, pair_row, row_pair, row_valid, tile_group, num_tiles, tile):
+def _held_forward(x, weights, w13, w2, pair_row, row_pair, row_valid, tile_group, num_tiles, tile,
+                  act="silu"):
     from galvatron_tpu.ops import moe_held
     from galvatron_tpu.ops.grouped_matmul import held_matmul
 
@@ -385,7 +395,7 @@ def _held_forward(x, weights, w13, w2, pair_row, row_pair, row_valid, tile_group
                 [held_matmul(rows, w, tile_group, num_tiles, tile_m=tile) for w in w13], axis=-1)
         else:
             gate_up = held_matmul(rows, w13, tile_group, num_tiles, tile_m=tile)
-        mid = moe_held.swiglu(gate_up, num_tiles, tile=tile)
+        mid = moe_held.swiglu(gate_up, num_tiles, tile=tile, act=act)
         out = held_matmul(mid, w2, tile_group, num_tiles, tile_m=tile, slab_out=True)
     with jax.named_scope("combine"):
         index = moe_held.pairs_index(pairs, num_tiles, tile=tile)
@@ -394,7 +404,7 @@ def _held_forward(x, weights, w13, w2, pair_row, row_pair, row_valid, tile_group
                row_token, tile_rows, tile_group, num_tiles)
 
 
-def _held_backward(tile, res, g):
+def _held_backward(tile, act, res, g):
     from galvatron_tpu.ops import moe_held
     from galvatron_tpu.ops.grouped_matmul import held_matmul, weight_grad
 
@@ -414,7 +424,7 @@ def _held_backward(tile, res, g):
         dmid = held_matmul(dout, w2, tile_group, num_tiles, tile_m=tile, transpose_rhs=True)
         dw2 = weight_grad(mid, dout, tile_group, num_tiles, experts, tile_m=tile,
                           out_dtype=w2.dtype)
-        dgate_up = moe_held.swiglu_bwd(gate_up, dmid, num_tiles, tile=tile)
+        dgate_up = moe_held.swiglu_bwd(gate_up, dmid, num_tiles, tile=tile, act=act)
         drows = held_matmul(dgate_up, w13, tile_group, num_tiles, tile_m=tile,
                             transpose_rhs=True, slab_out=True)
         dw13 = weight_grad(rows, dgate_up, tile_group, num_tiles, experts, tile_m=tile,
@@ -472,32 +482,41 @@ def held_rows_share(stats) -> jax.Array:
 
 
 def moe_topk_block(x: jax.Array, p: Params, cfg, tile: Optional[int] = None,
-                   place: Placement = LOCAL):
-    """Dropless top-k MoE MLP on (B, S, H) -> (y, router_stats).
+                   place: Placement = LOCAL, router_x: Optional[jax.Array] = None):
+    """Dropless top-k MoE MLP on (B, S, H) -> (y, router_stats). ``router_x`` (B, S,
+    H): what the router reads where that is not ``x`` (``cfg.moe_router_input``
+    "attn": the attention block's normed input; split over the mesh like ``x``).
 
     On a multi-device mesh ``place.route_tokens`` runs the block on each
     device's own tokens, every expert's weights whole on every device, and
     only the statistics cross devices (a mean); expert parallelism is
     refused upstream (build_runtime)."""
+    if router_x is not None:
+        return place.route_tokens(
+            lambda xs, p_, over: _topk_local(xs[0], p_, cfg, tile, over, router_x=xs[1])
+        )((x, router_x), p)
     return place.route_tokens(
         lambda x_, p_, over: _topk_local(x_, p_, cfg, tile, over))(x, p)
 
 
 def router_scores(xt, router: Params, cfg) -> jax.Array:
     """A token's float32 score of every expert (T, E): the softmax of the router's
-    logits, or (``sigmoid_topk``) their sigmoid, there with the GEMM at ``highest``:
-    the chip's default runs a float32 GEMM in one bf16 pass, and a choice flips
-    wherever two scores lie within a bf16 ulp. (The softmax router keeps the GEMM it
-    had: its cells' programs stay as they were.)"""
+    logits, or (``sigmoid_topk``) their sigmoid. The GEMM runs at ``highest`` for the
+    sigmoid router and where ``cfg.moe_router_precision`` says so: the chip's default
+    runs a float32 GEMM in one bf16 pass, and a choice flips wherever two scores lie
+    within a bf16 ulp. (The older softmax routers keep the default: their cells'
+    programs stay as they were.)"""
     x32, w32 = xt.astype(jnp.float32), router["w"].astype(jnp.float32)
-    if cfg.moe_router == "sigmoid_topk":
-        return jax.nn.sigmoid(jnp.matmul(x32, w32, precision=jax.lax.Precision.HIGHEST))
-    return jax.nn.softmax(x32 @ w32, axis=-1)
+    sigmoid = cfg.moe_router == "sigmoid_topk"
+    highest = sigmoid or cfg.moe_router_precision == "highest"
+    logits = jnp.matmul(x32, w32, precision=jax.lax.Precision.HIGHEST if highest else None)
+    return jax.nn.sigmoid(logits) if sigmoid else jax.nn.softmax(logits, axis=-1)
 
 
-def _topk_local(x, p, cfg, tile, over):
+def _topk_local(x, p, cfg, tile, over, router_x=None):
     """The block on the tokens this device holds; ``over``: the mesh axes the
-    tokens are split on (the statistics are means over all of them).  The
+    tokens are split on (the statistics are means over all of them); ``router_x``:
+    what the router reads in place of ``x`` (`moe_topk_block`).  The
     router's input, GEMM and softmax are fp32 whatever the compute dtype: a
     bf16 logit flips a choice wherever two probabilities lie within a bf16 ulp."""
     from galvatron_tpu.ops.grouped_matmul import TILE_M
@@ -510,7 +529,8 @@ def _topk_local(x, p, cfg, tile, over):
     xt = x.reshape(tokens, h)
     sigmoid = cfg.moe_router == "sigmoid_topk"
     with jax.named_scope("router"):
-        probs = router_scores(xt, p["router"], cfg)
+        probs = router_scores(
+            xt if router_x is None else router_x.reshape(tokens, h), p["router"], cfg)
     with jax.named_scope("dispatch"):
         if sigmoid:
             # the bias SELECTS and never weighs: top-k of s + b, weights from s,
@@ -539,7 +559,8 @@ def _topk_local(x, p, cfg, tile, over):
                    else jnp.concatenate([p["w1"], p["w3"]], axis=-1).astype(x.dtype))
             w2 = p["w2"].astype(x.dtype)
         y = held_experts(xt, weights, w13, w2, layout.pair_row, layout.row_pair,
-                         layout.row_valid, layout.tile_group, layout.num_tiles, tile)
+                         layout.row_valid, layout.tile_group, layout.num_tiles, tile,
+                         cfg.glu_act)
     else:
         with jax.named_scope("dispatch"):
             rows = _dispatch(xt, layout.row_pair // k, layout.row_valid, layout.pair_row)
@@ -547,12 +568,12 @@ def _topk_local(x, p, cfg, tile, over):
             w1, w3, w2 = (p[n].astype(x.dtype) for n in ("w1", "w3", "w2"))
             gate = grouped_gemm(rows, w1, layout, tile)
             up = grouped_gemm(rows, w3, layout, tile)
-            out = grouped_gemm(jax.nn.silu(gate) * up, w2, layout, tile)
+            out = grouped_gemm(_glu_gate(cfg)(gate) * up, w2, layout, tile)
         with jax.named_scope("combine"):
             y = _combine(out, weights, layout.pair_row, layout.row_pair, layout.row_valid)
     if cfg.moe_shared_ffn_dim:
         with jax.named_scope("shared_expert"):
-            y = y + _shared_expert(xt, p["shared"])
+            y = y + _shared_expert(xt, p["shared"], _glu_gate(cfg))
     # the statistics are over ALL the experts the router scores, held or not
     sizes = jnp.bincount(idx.reshape(-1), length=e).astype(jnp.int32) if held_share \
         else layout.sizes
@@ -566,13 +587,13 @@ def _topk_local(x, p, cfg, tile, over):
     return y.reshape(b, s, h), stats
 
 
-def _shared_expert(xt, p):
-    """``down(silu(gate x) * up x)`` on (T, h), times ``sigmoid(x w_gate)`` where
+def _shared_expert(xt, p, act=jax.nn.silu):
+    """``down(act(gate x) * up x)`` on (T, h) (``act``: `modeling.glu_gate`), times ``sigmoid(x w_gate)`` where
     the expert has a gate: the expert every token runs, whatever the router chose."""
     dtype = xt.dtype
     f = p["w13"].shape[1] // 2
     gu = xt @ p["w13"].astype(dtype)
-    out = (jax.nn.silu(gu[:, :f]) * gu[:, f:]) @ p["w2"].astype(dtype)
+    out = (act(gu[:, :f]) * gu[:, f:]) @ p["w2"].astype(dtype)
     if "gate" not in p:
         return out
     gate = jax.nn.sigmoid(jnp.einsum(

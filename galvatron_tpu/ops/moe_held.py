@@ -15,8 +15,9 @@ do work in proportion to that and never to the buffer:
   ``weights[t, j] * buffer[pair_row[t, j]]`` in float32 in ``j`` order (or, with
   ``other``, the k dot products ``<buffer[pair_row[t, j]], other[t]>``); a pair
   that is not held fetches nothing and adds an exact zero by a mask;
-- ``moe_held_swiglu`` / ``moe_held_swiglu_bwd``: ``silu(gate) * up`` over the
-  used tiles of the fused ``[gate | up]`` buffer.
+- ``moe_held_swiglu`` / ``moe_held_swiglu_bwd``: ``silu(gate) * up`` (or, ``act``
+  "relu", ``relu(gate) * up``: the kernels keep their names) over the used tiles
+  of the fused ``[gate | up]`` buffer.
 
 **Rows past ``num_tiles`` of every buffer on this path are UNDEFINED, not zero**:
 every reader masks by index (`ops/grouped_matmul.py` skips them the same way).
@@ -335,13 +336,14 @@ def _gather_pairs(src, pair_row, num_tiles, weights, rows, counts, other=None, *
     return jnp.sum(jnp.where(first, out[:, None, :], 0.0), axis=2)
 
 
-def swiglu(gate_up, num_tiles, *, tile: int):
-    """``silu(gate) * up`` of the fused (M, 2f) ``[gate | up]`` buffer -> (M, f),
-    over the used tiles; later tiles undefined."""
-    return gm.traced_once(_swiglu, gate_up, num_tiles, tile=tile)
+def swiglu(gate_up, num_tiles, *, tile: int, act: str = "silu"):
+    """``act(gate) * up`` of the fused (M, 2f) ``[gate | up]`` buffer -> (M, f),
+    over the used tiles; later tiles undefined. ``act`` "silu" (SwiGLU) or "relu"
+    (ReGLU), fixed at trace time."""
+    return gm.traced_once(_swiglu, gate_up, num_tiles, tile=tile, act=act)
 
 
-def _swiglu(gate_up, num_tiles, *, tile):
+def _swiglu(gate_up, num_tiles, *, tile, act="silu"):
     m, f2 = gate_up.shape
     f = f2 // 2
 
@@ -350,7 +352,10 @@ def _swiglu(gate_up, num_tiles, *, tile):
         def _():
             gate = gu_ref[:, :f].astype(jnp.float32)
             up = gu_ref[:, f:].astype(jnp.float32)
-            out_ref[...] = (gate * jax.nn.sigmoid(gate) * up).astype(out_ref.dtype)
+            if act == "relu":
+                out_ref[...] = (jnp.maximum(gate, 0.0) * up).astype(out_ref.dtype)
+            else:
+                out_ref[...] = (gate * jax.nn.sigmoid(gate) * up).astype(out_ref.dtype)
 
     return pl.pallas_call(
         kernel,
@@ -367,13 +372,13 @@ def _swiglu(gate_up, num_tiles, *, tile):
     )(num_tiles, gate_up)
 
 
-def swiglu_bwd(gate_up, grad, num_tiles, *, tile: int):
+def swiglu_bwd(gate_up, grad, num_tiles, *, tile: int, act: str = "silu"):
     """`swiglu`'s backward: (M, 2f) ``[d gate | d up]`` from the saved buffer and
     the (M, f) gradient of its output, over the used tiles."""
-    return gm.traced_once(_swiglu_bwd, gate_up, grad, num_tiles, tile=tile)
+    return gm.traced_once(_swiglu_bwd, gate_up, grad, num_tiles, tile=tile, act=act)
 
 
-def _swiglu_bwd(gate_up, grad, num_tiles, *, tile):
+def _swiglu_bwd(gate_up, grad, num_tiles, *, tile, act="silu"):
     m, f2 = gate_up.shape
     f = f2 // 2
 
@@ -383,6 +388,11 @@ def _swiglu_bwd(gate_up, grad, num_tiles, *, tile):
             gate = gu_ref[:, :f].astype(jnp.float32)
             up = gu_ref[:, f:].astype(jnp.float32)
             g = grad_ref[...].astype(jnp.float32)
+            if act == "relu":
+                on = gate > 0.0
+                out_ref[:, :f] = jnp.where(on, g * up, 0.0).astype(out_ref.dtype)
+                out_ref[:, f:] = jnp.where(on, g * gate, 0.0).astype(out_ref.dtype)
+                return
             sig = jax.nn.sigmoid(gate)
             out_ref[:, :f] = (g * up * sig * (1.0 + gate * (1.0 - sig))).astype(out_ref.dtype)
             out_ref[:, f:] = (g * gate * sig).astype(out_ref.dtype)

@@ -190,7 +190,10 @@ def _make_layer_hook(cfg: ModelConfig, hp: HybridParallelConfig, mesh: Mesh, axe
     # each zero2/zero3 layer's param cotangents to their reduce-scattered
     # sharding, so the per-layer gradient buckets issue during backward
     grad_annots = modeling.model_annotations(cfg) if hp.grad_overlap else None
-    placed = [placement.place_layer(cfg, s, mesh, axes) for s in hp.layer_strategies]
+    # (a decoder layer is placed under its own view of the model: its window, its rope)
+    placed = [placement.place_layer(
+        cfg.layer_view(i - cfg.enc_layers) if i >= cfg.enc_layers else cfg, s, mesh, axes)
+        for i, s in enumerate(hp.layer_strategies)]
 
     def hook(i: int, x, lp, enc_out=None, seg_ids=None):
         s = hp.layer_strategies[i]

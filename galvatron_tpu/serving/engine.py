@@ -67,7 +67,7 @@ import numpy as np
 
 from galvatron_tpu.analysis.locks import lock_check_armed, lock_metrics, make_condition
 from galvatron_tpu.core import faults
-from galvatron_tpu.models import generation
+from galvatron_tpu.models import generation, mixers
 from galvatron_tpu.models.generation import KVCache
 from galvatron_tpu.models.modeling import ModelConfig
 from galvatron_tpu.obs.tracing import tracer as _obs_tracer
@@ -336,15 +336,32 @@ class Engine:
                 "slot cache (kv_num_blocks 0)"
             )
         if self.paged:
+            for limit in mixers.limits(cfg):
+                if limit.what == "paged_kv":
+                    raise ValueError(limit.sentence())
             self.slots = PagedKVCache(
                 cfg, num_slots, block_size=kv_block_size,
                 num_blocks=kv_num_blocks, max_seq_len=max_seq_len,
                 prefix_cache=prefix_cache,
             )
         else:
-            self.slots = SlotKVCache(cfg, num_slots, max_seq_len)
+            # (the most positions one forward writes a slot sizes a windowed stack's ring;
+            # what a slot of it holds is known once the slots know that)
+            self.slots = SlotKVCache(cfg, num_slots, max_seq_len,
+                                     tokens=max(int(prefill_chunk), 1 + self.spec_k))
+            self.cache_layout = generation.cache_layout(
+                cfg, self.slots.max_seq_len, self.slots.tokens)
         # a chunk longer than the slot would slice past the cache end
         self.prefill_chunk = min(int(prefill_chunk), self.slots.max_seq_len)
+        if cfg.windowed and self.slots.max_seq_len % self.prefill_chunk:
+            # a prompt's last chunk is slid left where it would cross the slot's end
+            # (`_prefill`), to a start that is no multiple of the chunk: the one write
+            # a ring cannot take whole (generation.write_ring)
+            raise ValueError(
+                f"a stack with sliding-window layers needs slots of a whole number of prompt "
+                f"chunks: max_seq_len {self.slots.max_seq_len} is no multiple of prefill_chunk "
+                f"{self.prefill_chunk} (a chunk then starts at a multiple of the chunk and "
+                "never crosses the ring's end)")
         # of a cache of a kind of its own: which body a prompt chunk's attention takes
         # and the keys a block of it fetches (fixed by the shapes: asked once)
         self.cache_layout.update(
@@ -556,8 +573,9 @@ class Engine:
         if not self.paged:
             # the slot cache by its layers' kind: what it holds a position, in all
             extra["cache_kind"] = self.cache_layout["kind"]
-            extra["cache_bytes"] = (self.cache_layout["bytes_per_position"]
-                                    * self.slots.num_slots * self.slots.max_seq_len)
+            extra["cache_bytes"] = self.cache_layout["bytes_per_slot"] * self.slots.num_slots
+            if "ring_positions" in self.cache_layout:  # two stacks, one a ring
+                extra["kv_ring_positions"] = self.cache_layout["ring_positions"]
             extra.update(self.step_counters())
             if "chunk_path" in self.cache_layout:
                 # the body every prompt chunk's attention takes, and how many took the
@@ -639,7 +657,8 @@ class Engine:
 
     def step_counters(self, slots: Optional[Sequence[int]] = None, window: int = 1) -> dict:
         """What the last decode iteration worked on: the cache's bytes a position
-        (all layers) under its kind's name, the positions live in ``slots`` (the
+        (all layers; ONE layer's of a windowed stack, whose stacks hold different
+        positions) under its kind's name, the positions live in ``slots`` (the
         slots in use), of a kind with a cache of its own the positions a layer's
         attention FETCHES for them by construction (a window of ``window`` queries
         a row; `generation.cache_read_positions`: live over read is the share of
@@ -650,12 +669,24 @@ class Engine:
         slots = self.slots.active_slots() if slots is None else slots
         # (on the loop's thread between two spans: no array is built here)
         live = [int(lengths[s]) for s in slots]
-        out = {f"{layout['kind']}_cache_bytes_per_position": layout["bytes_per_position"],
+        per_position = (layout["bytes_per_position_per_layer"] if "window" in layout
+                        else layout["bytes_per_position"])
+        out = {f"{layout['kind']}_cache_bytes_per_position": per_position,
                f"{layout['kind']}_live_positions": sum(live)}
         read = generation.cache_read_positions(
             self.cfg, [n + window - 1 for n in live], self.slots.num_slots,
-            self.slots.max_seq_len, window)
-        if read is not None:
+            self.slots.max_seq_len, window, ring=layout.get("ring_positions"))
+        if isinstance(read, dict):
+            # a windowed stack, by stack: what a layer of each holds live for the rows
+            # (a window layer the last ``window`` positions) and fetches, and how many
+            # layers each stack has (``kv_cache_bytes_per_position`` is ONE layer's there)
+            span = layout["window"]
+            out.update(
+                kv_full_live_positions=sum(live),
+                kv_window_live_positions=sum(min(n, span) for n in live),
+                kv_full_read_positions=read["full"], kv_window_read_positions=read["window"],
+                kv_full_layers=layout["full_layers"], kv_window_layers=layout["window_layers"])
+        elif read is not None:
             out[f"{layout['kind']}_read_positions"] = read
         return {**out, **self._router_counters}
 
@@ -1014,6 +1045,8 @@ class Engine:
         key_block = self.cache_layout.get("chunk_key_block")  # None for K and V slots
         kernel = self.cache_layout.get("chunk_path") == "kernel"
         key_blocks = 0
+        ring = self.cache_layout.get("ring_positions")  # a windowed stack's, else None
+        ring_wraps = 0
         for i, start in enumerate(starts):
             # the deadline is end-to-end: a long prompt must not burn chip
             # time prefilling past the moment its client stops waiting
@@ -1051,6 +1084,11 @@ class Engine:
                 )
             self.counters.inc("prefill_chunks")
             self.counters.inc("prefill_tokens", n)
+            if ring:
+                # chunks that began a new lap of the ring: from there on a chunk
+                # overwrites the window layers' oldest positions (host arithmetic)
+                ring_wraps += start > 0 and start % ring == 0
+                span.set(ring_wraps=ring_wraps)
             if key_block:
                 # the chunks the chunk kernel took and the key blocks a layer's attention
                 # fetched for them so far (host arithmetic from the start: no array is
@@ -1597,7 +1635,8 @@ def _serving_programs(ctx):
             ))
         return out + draw
     cache_abs = jax.eval_shape(
-        lambda: generation.init_kv_cache(cfg, num_slots, max_len)
+        # (a windowed stack's ring is sized as `Engine` sizes it: chunk or verify window)
+        lambda: generation.init_kv_cache(cfg, num_slots, max_len, max(chunk, 1 + spec_k))
     )
     slot_meta = {"key_extra": key_extra} if key_extra else {}
     out = [

@@ -3,7 +3,9 @@
 One fixed-shape device cache — what the stack's layers say
 (``generation.init_kv_cache``): ``(L, num_slots, max_seq_len, kv_heads,
 head_dim)`` k and v for attention, one ``(L, num_slots, max_seq_len, r + dr)``
-latent for latent attention — lives for the whole server lifetime; requests borrow a
+latent for latent attention, two stacks where some layers have a sliding window
+(the full layers at ``max_seq_len``, the window layers a ring of ``window +
+tokens`` positions: ``generation.WindowKVCache``) — lives for the whole server lifetime; requests borrow a
 *slot* (one batch row) for their duration and return it on retirement
 (vLLM's PagedAttention manages blocks within a sequence; here the unit is
 the whole-sequence slot, which is what maps onto JAX's static-shape jit:
@@ -57,14 +59,20 @@ def effective_max_seq_len(cfg: ModelConfig, max_seq_len: Optional[int]) -> int:
 class SlotKVCache:
     """Fixed ``(num_slots, max_seq_len)`` cache of the stack's kind + slot allocator."""
 
-    def __init__(self, cfg: ModelConfig, num_slots: int, max_seq_len: Optional[int] = None):
+    def __init__(self, cfg: ModelConfig, num_slots: int, max_seq_len: Optional[int] = None,
+                 tokens: int = 1):
+        """``tokens``: the most positions one forward writes a slot (the engine's
+        prompt chunk or verify window): what a windowed stack's ring is sized by
+        (``generation.ring_positions``); nothing else reads it, and admission,
+        ``fits`` and the scheduler know slots of ``max_seq_len`` only."""
         if num_slots < 1:
             raise ValueError(f"num_slots must be >= 1, got {num_slots}")
         self.cfg = cfg
         self.num_slots = int(num_slots)
         self.max_seq_len = effective_max_seq_len(cfg, max_seq_len)
+        self.tokens = min(max(1, int(tokens)), self.max_seq_len)
         # device arrays; reassigned by the engine after every jitted step
-        self.cache = generation.init_kv_cache(cfg, self.num_slots, self.max_seq_len)
+        self.cache = generation.init_kv_cache(cfg, self.num_slots, self.max_seq_len, self.tokens)
         # host bookkeeping: length = tokens materialized in the slot so far
         # (prompt + generated); the next token lands at position == length.
         # The allocator lock covers the free list + active set: the engine
@@ -108,7 +116,8 @@ class SlotKVCache:
             self.lengths[:] = 0
             self._free = list(range(self.num_slots - 1, -1, -1))
             self.cache = None
-            self.cache = generation.init_kv_cache(self.cfg, self.num_slots, self.max_seq_len)
+            self.cache = generation.init_kv_cache(self.cfg, self.num_slots, self.max_seq_len,
+                                                  self.tokens)
 
     # -- views --------------------------------------------------------------
 

@@ -209,6 +209,11 @@ CUT = {
     "olmoe-1b-7b": dict(
         vocab_size=96, hidden_size=64, num_layers=2, num_heads=4, ffn_dim=32, max_seq_len=32,
         moe_experts=8, moe_top_k=2),
+    # (a window is no kind: its limits are the stack's, from the same table)
+    "smallthinker-21b-a3b": dict(
+        vocab_size=96, hidden_size=32, num_layers=4, num_heads=4, num_kv_heads=2, attn_head_dim=8,
+        ffn_dim=24, max_seq_len=32, sliding_window_size=8, moe_experts=8, moe_top_k=2,
+        moe_ffn_dim=24),
 }
 
 
@@ -233,11 +238,16 @@ def _breaking(cfg, limit):
         kw["mixed_precision"] = "fp16"
     if limit.what == "pack_sequences":
         cfg = cfg.replace(pack_sequences=True, attn_impl="xla")
+    if limit.what == "attn_impl":
+        cfg = cfg.replace(attn_impl="flash")
     return cfg, HybridParallelConfig(**kw)
 
 
 def test_the_three_presets_yield_what_the_issue_counts():
-    assert [len(mixers.limits(cut(p))) for p in CUT] == [5, 9, 4] and len(CASES) == 18
+    assert [len(mixers.limits(cut(p))) for p in CUT] == [5, 9, 4, 9] and len(CASES) == 27
+    # a windowed stack's five (another attention path, cp, packing, pp, the paged backend)
+    assert [t.what for t in mixers.limits(cut("smallthinker-21b-a3b"))[:5]] == [
+        "attn_impl", "cp", "pack_sequences", "pp", "paged_kv"]
     assert mixers.limits(PRESETS["llama-7b"]) == []
 
 
@@ -248,6 +258,13 @@ def test_every_limit_is_refused_reported_and_left_out(preset, i):
     if limit.what == "kv_cache":  # no plan asks for a cache: generation's to refuse
         with pytest.raises(ValueError, match=re.escape(limit.sentence())):
             init_kv_cache(cfg, 1, 8)
+        assert limit.tag is None and limit.code is None
+        return
+    if limit.what == "paged_kv":  # nor for a block pool: the engine's to refuse
+        from galvatron_tpu.serving import Engine
+
+        with pytest.raises(ValueError, match=re.escape(limit.sentence())):
+            Engine(None, cfg, num_slots=2, prefill_chunk=8, kv_num_blocks=-1)
         assert limit.tag is None and limit.code is None
         return
     cfg, hp = _breaking(cfg, limit)
@@ -276,7 +293,8 @@ def test_every_limit_is_refused_reported_and_left_out(preset, i):
         space=SearchSpace(world_size=8, allow_cp=True, allow_ep=True, moe_experts=cfg.moe_experts),
         memory_budget_mb=15360.0, model_config=cfg)
     if limit.tag is None:
-        assert limit.what in ("pack_sequences", "fp16")  # nothing the search enumerates
+        # nothing the search enumerates
+        assert limit.what in ("pack_sequences", "fp16", "attn_impl")
     else:
         assert limit.tag in engine._standing
         assert {"tp": engine.space.max_tp == 1, "cp": not engine.space.allow_cp,

@@ -334,6 +334,53 @@ def test_compiled_serving_program_carries_the_scope(serving_texts, program, scop
     assert any(re.search(rf"[/(]{scope}(?:[/)]|$)", n) for n in names), (program, scope)
 
 
+#: a stack with sliding-window layers (PR 54): ``window`` | ``full`` between ``attn`` and
+#: the scopes above, by the stack the layer's keys and values live in; the expert
+#: layer's own under ``mlp`` (the router's GEMV there too, though it reads the
+#: attention block's input)
+WINDOWED_SCOPES = ("layer_0/attn/full/qkv_proj", "layer_0/attn/full/cache_write",
+                   "layer_0/attn/full/attn_core", "layer_0/attn/full/out_proj",
+                   "layer_1/attn/window/qkv_proj", "layer_1/attn/window/cache_write",
+                   "layer_1/attn/window/attn_core", "layer_1/attn/window/out_proj",
+                   "layer_1/mlp/router", "layer_1/mlp/dispatch", "layer_1/mlp/experts",
+                   "layer_1/mlp/combine")
+
+
+@pytest.fixture(scope="module")
+def windowed_texts():
+    from galvatron_tpu.aot import registry
+    from galvatron_tpu.models.modeling import PRESETS
+    from galvatron_tpu.serving import engine  # noqa: F401  (registers the serving family)
+
+    cfg = PRESETS["smallthinker-21b-a3b"].replace(
+        vocab_size=128, hidden_size=32, num_layers=2, num_heads=4, num_kv_heads=2,
+        attn_head_dim=8, ffn_dim=24, max_seq_len=32, sliding_window_size=8, moe_experts=8,
+        moe_top_k=2, moe_ffn_dim=24)
+    ctx = registry.ProgramContext(cfg=cfg, num_slots=2, prefill_chunk=8, max_seq_len=32,
+                                  spec_decode_k=2)
+    return {spec.name: spec.fn.lower(*spec.args).compile().as_text()
+            for spec in registry.enumerate_programs(ctx, include=("serving",))}
+
+
+@pytest.mark.parametrize("scope", WINDOWED_SCOPES)
+@pytest.mark.parametrize("program", SERVING_PROGRAMS[:3])
+def test_a_windowed_stacks_serving_program_carries_the_scope(windowed_texts, program, scope):
+    import re
+
+    names = re.findall(r'op_name="([^"]*)"', windowed_texts[program])
+    assert any(re.search(rf"[/(]{scope}(?:[/)]|$)", n) for n in names), (program, scope)
+
+
+def test_a_plain_stacks_serving_programs_carry_neither_window_nor_full(serving_texts):
+    """The two scopes are a windowed stack's alone: the accepted cells' op names, the
+    recorded fixtures and ``lib/scoped.SCOPES`` stay as they are."""
+    import re
+
+    for program, text in serving_texts.items():
+        names = re.findall(r'op_name="([^"]*)"', text)
+        assert not [n for n in names if "/window/" in n or "/full/" in n], program
+
+
 @pytest.fixture()
 def traced():
     assert not tracer.enabled

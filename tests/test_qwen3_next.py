@@ -122,11 +122,14 @@ def test_preset_runs_the_published_widths():
             p.gdn_chunk) == (16, 32, 128, 128, 4, 64)
     assert [i for i, k in enumerate(p.kinds) if k == "attention"] == list(range(3, 48, 4))
     assert set(p.kinds) == {"attention", "gdn"} and p.moe_held == 512 and p.moe_norm_topk
-    # head_dim is its own field only here (and where latent attention gives the query
-    # a head of its own: tests/test_mla.py): every other preset keeps hidden / heads
+    # head_dim is its own field only here, where latent attention gives the query a
+    # head of its own (tests/test_mla.py) and in smallthinker-21b-a3b (28 heads of 128
+    # on a hidden size of 2560: tests/test_smallthinker.py): every other preset keeps
+    # hidden / heads
     for name, other in PRESETS.items():
         if name != "qwen3-next-80b-a3b" and not other.mla_kv_rank:
-            assert other.attn_head_dim is None and other.rotary_dim == other.head_dim
+            assert (other.attn_head_dim is None) == (name != "smallthinker-21b-a3b")
+            assert other.rotary_dim == other.head_dim
             assert other.expert_ffn == other.ffn and other.moe_held == other.moe_experts
 
 

@@ -1266,3 +1266,44 @@ def test_mlp_recompute_buffer_accounting_tp2_zero3_sp(topo, real_mosaic):
     # 5.8 MB product floor; the remainder is the fp32 norm/CE widenings) —
     # a policy-mode temp within 5% of off means the widenings returned
     assert temps["policy"] <= temps["off"] * 0.95, temps
+
+
+@pytest.mark.parametrize("name", ["serving_decode", "serving_prefill"])
+def test_smallthinker_serving_programs_fit_one_chip_and_write_the_cache_in_place(
+        one_chip, real_mosaic, name):
+    """Both programs of `smallthinker-21b-a3b_serve_long_above_knee` (16 layers, 32 slots:
+    4 full layers x 16,384 positions and 12 window layers x a ring of 4,096 + 1,024, K
+    and V of 4 heads of 128 in bf16, head-major; chunk 1,024) as the chip's compiler sees
+    them: the donated cache of both stacks is aliased whole (8.32 GB), no operation but
+    an in-place update has a result as large as a window layer's slab (from a
+    position-major cache the compiler copied every stack whole each step, 7.76 GiB of
+    temporaries; a ring's chunk as two read-merge-write updates re-laid the ring stacks,
+    3.9 GiB), the plain bodies run under ``window`` / ``full`` in every layer, and
+    weights, cache and temporaries fit the chip (11.6 and 11.7 GiB of 15.75)."""
+    import re
+
+    from galvatron_tpu.models.modeling import PRESETS
+
+    cfg = PRESETS["smallthinker-21b-a3b"].replace(
+        num_layers=16, vocab_size=37984, moe_share=(0, 4), max_seq_len=16384,
+        param_dtype=jnp.bfloat16, dtype=jnp.bfloat16)
+    compiled = _lowered_serving_program(cfg, name, one_chip, num_slots=32, prefill_chunk=1024,
+                                        max_seq_len=16384).compile()
+    text = compiled.as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    for i, windowed in enumerate(cfg.window_layers):
+        stack = "window" if windowed else "full"
+        assert any(f"/layer_{i}/attn/{stack}/attn_core" in n for n in names), (i, stack)
+        assert any(f"/layer_{i}/attn/{stack}/cache_write" in n for n in names), (i, stack)
+    ma = compiled.memory_analysis()
+    cache = 2 * 32 * 4 * 128 * 2 * (4 * 16384 + 12 * 5120)
+    assert cache == 8_321_499_136 and ma.alias_size_in_bytes >= cache
+    # (a prompt chunk's loops over key blocks carry the stacks they read: no copy)
+    ring_slab = 32 * 5120 * 4 * 128
+    moved = [(op, shape) for op, shape in _moved_slabs(text, ring_slab) if op != "while"]
+    assert not moved, moved[:4]
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes - ma.alias_size_in_bytes
+             + ma.temp_size_in_bytes)
+    print(f"{name}: arguments {ma.argument_size_in_bytes / 2**30:.3f} GiB, temporaries "
+          f"{ma.temp_size_in_bytes / 2**30:.3f} GiB, in all {total / 2**30:.3f} GiB")
+    assert total < HBM_V5E_GIB * 2**30, f"{total / 2**30:.2f} GiB"
