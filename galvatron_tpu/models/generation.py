@@ -24,6 +24,7 @@ import numpy as np
 
 from galvatron_tpu.models import mixers, modeling
 from galvatron_tpu.models.modeling import ModelConfig, Params
+from galvatron_tpu.ops import kv_decode
 
 
 class KVCache(NamedTuple):
@@ -140,15 +141,24 @@ def cache_read_positions(cfg: ModelConfig, lengths, rows: int, positions: int, w
     fetches by construction from a cache of ``rows`` slots x ``positions``, given the
     positions the windows of the rows in use attend (``lengths``): the kind's own
     answer (host arithmetic, which body its attention takes included); of a
-    windowed stack ``{"full": ..., "window": ...}``, a layer of each stack (their
-    decode attention reads every slot's capacity, the ring's ``ring`` positions of a
-    window layer, whatever the lengths); None for plain K and V slots, whose decode
-    attention reads every slot's capacity too (ROADMAP A3 ii)."""
+    windowed stack ``{"full": ..., "window": ...}``, a layer of each stack (on the plain
+    body every slot's capacity, the ring's ``ring`` positions of a window layer,
+    whatever the lengths; through the kernel the rows' lengths rounded up to the key
+    block and no more than the slot or ring, `kv_decode.decode_path` asked of each
+    stack as `_windowed_attention` asks it); None for plain K and V slots, whose
+    decode attention reads every slot's capacity too (ROADMAP A3 ii)."""
     kind = mixers.cache_kind(cfg)
     if kind is None:
-        if cfg.windowed:
-            return {"full": rows * positions, "window": rows * (ring or positions)}
-        return None
+        if not cfg.windowed:
+            return None
+
+        def read(places):
+            path = kv_decode.decode_path(places, cfg.head_dim,
+                                         window * (cfg.num_heads // cfg.kv_heads), cfg.dtype)
+            return (kv_decode.read_positions(lengths, rows, places) if path == "kernel"
+                    else rows * places)
+
+        return {"full": read(positions), "window": read(ring or positions)}
     return mixers.module(kind).cache_read_positions(cfg, lengths, rows, positions, window)
 
 
@@ -279,6 +289,18 @@ def _project_qkv_at(x, p, cfg: ModelConfig, cos_sin):
 KEY_BLOCK = 1024
 
 
+def chunk_key_blocks(positions: int, end):
+    """Of a row of ``positions`` places that a prompt chunk ending at position ``end``
+    (traced, or a host number) attends a block of keys at a time: (the block, the
+    row's blocks, the blocks up to the chunk's end). The chunk reads the SMALLER
+    count: a full row up to the chunk's end; a ring the same until it has lapped
+    (``end`` > R), because the places at or past ``end`` then still hold what an
+    earlier request left, `_ring_key_positions` reads them as negative positions and
+    a block of masked scores adds exact zeros to the running sum."""
+    block = modeling.key_block(positions, KEY_BLOCK)
+    return block, positions // block, (end + block - 1) // block
+
+
 def write_ring(stacked, layer: int, new, starts, aligned: bool):
     """`write_layer` into a head-major ring (L, Bc, kvh, R, hd): position p of
     ``new`` (B, kvh, s, hd) lands at ``p mod R``, by ``dynamic_update_slice`` on the
@@ -331,7 +353,8 @@ def _masked_scores(qg, k, q_pos, k_pos, window: int, scale):
 
 def _attend_rows(qg, k, v, q_pos, k_pos, window: int, scale):
     """Rows' windows against their whole rows of the cache at once (a decode step,
-    a verify window, ``generate``): -> (B, s, kv, g, d)."""
+    a verify window, ``generate``): -> (B, s, kv, g, d). The plain body, outside
+    `kv_decode.decode_path`'s rule, and the kernel's reference."""
     probs = jax.nn.softmax(_masked_scores(qg, k, q_pos, k_pos, window, scale), axis=-1)
     return jnp.einsum("bkgqs,bksh->bqkgh", probs.astype(qg.dtype), v)
 
@@ -362,9 +385,13 @@ def _windowed_attention(x, p, cfg: ModelConfig, cache: WindowKVCache, windowed: 
                         starts, slot, offsets, cos_sin):
     """A layer's attention of a windowed stack over the `WindowKVCache` -> (y, cache):
     ``windowed`` says which stack the layer's keys and values live in (the ring, or
-    whole rows), ``index`` where in it; ``cfg`` is the layer's view. Scopes:
-    ``window`` | ``full`` > ``qkv_proj``, ``cache_write``, ``attn_core``,
-    ``out_proj``."""
+    whole rows), ``index`` where in it; ``cfg`` is the layer's view. Both forms fetch
+    a key block only if the row holds a position in it: a prompt chunk (``slot``)
+    loops over the blocks up to its end (`chunk_key_blocks`); rows' windows go
+    through the kernel `kv_decode`, bounded a row by the row's length, where
+    `kv_decode.decode_path` says so of the layer's stack, else over every slot's
+    capacity (`_attend_rows`). Scopes: ``window`` | ``full`` > ``qkv_proj``,
+    ``cache_write``, ``attn_core``, ``out_proj``."""
     b, s = x.shape[:2]
     kv, g, d = cfg.kv_heads, cfg.num_heads // cfg.kv_heads, cfg.head_dim
     scale = cfg.attention_multiplier if cfg.attention_multiplier is not None else d ** -0.5
@@ -387,16 +414,19 @@ def _windowed_attention(x, p, cfg: ModelConfig, cache: WindowKVCache, windowed: 
             else:
                 def key_positions(places):
                     return places[None]
-            if slot is None:
+            if slot is not None:
+                block, whole, live = chunk_key_blocks(positions, offsets + s)
+                o = _attend_chunk(qg, ks, vs, index, slot, q_pos, key_positions,
+                                  jnp.minimum(whole, live), block, cfg.attn_window, scale)
+            elif kv_decode.decode_path(positions, d, s * g, ks.dtype) == "kernel":
+                # a row is read up to its length, a ring up to that until it has lapped
+                first = jnp.broadcast_to(jnp.reshape(jnp.asarray(offsets, jnp.int32), (-1,)), (b,))
+                o = kv_decode.attend_rows(qg, ks, vs, index, first, scale=scale,
+                                          span=cfg.attn_window)
+            else:
                 o = _attend_rows(qg, read_layer(ks, index, None), read_layer(vs, index, None),
                                  q_pos, key_positions(jnp.arange(positions)), cfg.attn_window,
                                  scale)
-            else:
-                block = modeling.key_block(positions, KEY_BLOCK)
-                # a ring is read whole; a full row up to the chunk's end
-                blocks = positions // block if windowed else (offsets + s + block - 1) // block
-                o = _attend_chunk(qg, ks, vs, index, slot, q_pos, key_positions, blocks, block,
-                                  cfg.attn_window, scale)
         with jax.named_scope("out_proj"):
             y = modeling.attn_output(o.reshape(b, s, kv * g, d), p["attn"], cfg, x.dtype)
     cache = cache._replace(wk=ks, wv=vs) if windowed else cache._replace(k=ks, v=vs)

@@ -1046,7 +1046,7 @@ class Engine:
         kernel = self.cache_layout.get("chunk_path") == "kernel"
         key_blocks = 0
         ring = self.cache_layout.get("ring_positions")  # a windowed stack's, else None
-        ring_wraps = 0
+        ring_wraps = ring_blocks_read = ring_blocks = 0
         for i, start in enumerate(starts):
             # the deadline is end-to-end: a long prompt must not burn chip
             # time prefilling past the moment its client stops waiting
@@ -1088,7 +1088,13 @@ class Engine:
                 # chunks that began a new lap of the ring: from there on a chunk
                 # overwrites the window layers' oldest positions (host arithmetic)
                 ring_wraps += start > 0 and start % ring == 0
-                span.set(ring_wraps=ring_wraps)
+                # the key blocks a window layer's chunk attention fetched so far, of the
+                # ring's: up to the chunk's end until the ring has lapped
+                _, whole, live = generation.chunk_key_blocks(ring, start + c)
+                ring_blocks_read += min(whole, live)
+                ring_blocks += whole
+                span.set(ring_wraps=ring_wraps, kv_window_chunk_blocks_read=ring_blocks_read,
+                         kv_window_chunk_blocks=ring_blocks)
             if key_block:
                 # the chunks the chunk kernel took and the key blocks a layer's attention
                 # fetched for them so far (host arithmetic from the start: no array is

@@ -48,6 +48,10 @@ KERNEL_NAMES = {
     # PR 53: a prompt chunk's latent attention, expansion included (read by
     # `mla_prefill_chunk_attn_ms` under `attn_core` > `expand`)
     "mla_chunk": "mla_prefill.py",
+    # PR 55: rows' windows over a head-major K/V stack, bounded a row by its length
+    # (read through `full` | `window` > `attn_core`: `full_attn_ms_per_step`,
+    # `window_attn_ms_per_step`, `kv_decode_attn_roofline`)
+    "kv_decode": "kv_decode.py",
 }
 
 
@@ -83,7 +87,7 @@ def test_every_pallas_call_has_a_name_from_the_table(name):
     assert {n for names in found.values() for n in names} == set(KERNEL_NAMES)
     assert all(n.startswith(("flash_fwd", "flash_bwd", "flash_paged",
                              "moe_gmm", "moe_tgmm", "moe_held_", "ssd_", "ssm_conv_", "gdn_",
-                             "mla_"))
+                             "mla_", "kv_"))
                for n in KERNEL_NAMES)
 
 

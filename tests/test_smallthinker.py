@@ -354,15 +354,25 @@ def test_one_slots_verify_window_crosses_the_rings_end():
     assert cache.wk.shape[3] == 12 and close(got[0], want[0])
 
 
-def test_an_engine_of_one_slot_verifies_across_the_rings_end():
+@pytest.mark.parametrize("kernel", [False, True], ids=["plain", "kv_decode"])
+def test_an_engine_of_one_slot_verifies_across_the_rings_end(monkeypatch, kernel):
     """``num_slots=1`` with speculative decoding: the verify windows of 1 + 3 start
     wherever the accepted tokens left the row, across the ring's end among them;
-    greedy, the tokens equal plain generation's."""
-    cfg = small_cfg()
-    params, rows = seeded(cfg, seed=5, batch=1, length=30)
+    greedy, the tokens equal plain generation's. With heads of 128, slots of four key
+    blocks and a ring of 28 + 4 places = two, the windows of both stacks go through the
+    kernel `kv_decode` (and `generate`'s steps with them)."""
+    if kernel:
+        from galvatron_tpu.ops import kv_decode
+
+        monkeypatch.setattr(kv_decode, "KEY_BLOCK", 16)
+        cfg = small_cfg(attn_head_dim=128, num_layers=4, sliding_window_size=28)
+        assert kv_decode.decode_path(32, 128, 4 * 2, cfg.dtype) == "kernel"
+    else:
+        cfg = small_cfg()
+    params, rows = seeded(cfg, seed=1 if kernel else 5, batch=1, length=30)
     prompt = rows[0, :7].tolist()
     want = generation.generate_np(params, cfg, [prompt], max_new_tokens=40, length_bucket=1)
-    assert len(set(want[0][7:])) >= 8  # (a seed whose greedy answer moves about)
+    assert len(set(want[0][7:])) >= 8  # (seeds whose greedy answers move about)
     class Oracle:
         """Drafts plain generation's own tokens: every window is accepted whole."""
 
@@ -399,6 +409,76 @@ def test_a_slot_reused_by_a_shorter_request_never_sees_the_longer_ones_keys():
                                                   jnp.asarray(offs))
         got.append(np.asarray(lg[1]))
     assert close(np.concatenate(got), want[1, :10])
+
+
+def _five_block_ring(monkeypatch):
+    """A ring of five key blocks as the cell's is (4,096 + 1,024 in blocks of 1,024):
+    window 32, chunks and key blocks of 8, slots of 64."""
+    monkeypatch.setattr(generation, "KEY_BLOCK", 8)
+    cfg = small_cfg(sliding_window_size=32)
+    assert generation.chunk_key_blocks(generation.ring_positions(cfg, SLOT, 8), 8) == (8, 5, 1)
+    return cfg
+
+
+def test_a_chunk_reads_the_ring_up_to_its_end_until_the_ring_has_lapped(monkeypatch):
+    """A prompt of 7 chunks in a slot that an earlier, LONGER request filled with other
+    values: every chunk's logits with the ring read up to the chunk's end (1, 2, 3, 4,
+    then all 5 blocks once the ring has lapped) are those of the whole-ring read, bit
+    for bit: the blocks left out held only what the mask reads as negative positions."""
+    cfg = _five_block_ring(monkeypatch)
+    params, rows = seeded(cfg, batch=2, length=SLOT)
+    bounded = generation.chunk_key_blocks
+
+    def prefill(row, cache, whole_ring):
+        def every_block(positions, end):
+            block, whole, _ = bounded(positions, end)
+            return block, whole, whole
+
+        monkeypatch.setattr(generation, "chunk_key_blocks", every_block if whole_ring else bounded)
+        chunk = jax.jit(lambda cache, tokens, start: generation.forward_with_cache(
+            params, tokens, cfg, cache, start, slot=jnp.int32(1)))  # (traced under this patch)
+        out = []
+        for start in range(0, len(row), 8):
+            lg, cache = chunk(cache, row[None, start:start + 8], jnp.int32(start))
+            out.append(np.asarray(lg[0]))
+        return np.stack(out), cache
+
+    cache = generation.init_kv_cache(cfg, 2, SLOT, tokens=8)
+    _, dirty = prefill(rows[0], cache, whole_ring=True)  # 64 positions: the ring lapped
+    assert float(jnp.abs(dirty.wk[:, 1]).min()) > 0
+    got, after = prefill(rows[1, :56], dirty, whole_ring=False)
+    want, after_whole = prefill(rows[1, :56], dirty, whole_ring=True)
+    np.testing.assert_array_equal(got, want)
+    for a, b in zip(after, after_whole):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # and both are the reference's full forward of the shorter prompt
+    assert close(got.reshape(56, -1), np.asarray(ref_logits(params, rows[1:2, :56], cfg))[0])
+
+
+def test_the_prefill_span_counts_the_ring_blocks_a_request_read(monkeypatch):
+    """An engine of one slot serves a prompt of 8 chunks, then one of 7 in the same
+    slot: greedy, the tokens are plain generation's, and the second `prefill` span
+    says its chunks read 1 + 2 + 3 + 4 + 5 + 5 + 5 of the ring's 7 x 5 key blocks."""
+    from galvatron_tpu.obs.tracing import tracer
+
+    cfg = _five_block_ring(monkeypatch)
+    params, rows = seeded(cfg, seed=3, batch=2, length=60)
+    prompts = [rows[0].tolist(), rows[1, :53].tolist()]
+    engine = _engine(cfg, params, num_slots=1, prefill_chunk=8)
+    tracer.enable(capacity=1 << 12)
+    tracer.clear()
+    try:
+        served = engine.generate(prompts, max_new_tokens=4)
+        spans = [e["args"] for e in tracer.snapshot() if e.get("ph") == "X" and e["name"] == "prefill"]
+    finally:
+        tracer.disable()
+        engine.close()
+    for prompt, got in zip(prompts, served):
+        want = generation.generate_np(params, cfg, [prompt], max_new_tokens=4, length_bucket=1)
+        assert got == want[0]
+    by_tokens = {a["tokens"]: a for a in spans}
+    assert (by_tokens[53]["kv_window_chunk_blocks_read"], by_tokens[53]["kv_window_chunk_blocks"]) == (25, 35)
+    assert (by_tokens[60]["kv_window_chunk_blocks_read"], by_tokens[60]["kv_window_chunk_blocks"]) == (30, 40)
 
 
 def test_lockstep_generation_runs_over_the_ring():
@@ -476,6 +556,7 @@ def test_the_decode_span_carries_the_stacks_counters():
     params, rows = seeded(cfg, batch=2, length=30)
     engine = _engine(cfg, params)
     tracer.enable(capacity=1 << 12)
+    tracer.clear()
     try:
         engine.generate([rows[0, :26].tolist(), rows[1, :6].tolist()], max_new_tokens=6)
         spans = [e for e in tracer.snapshot() if e.get("ph") == "X"]
@@ -499,6 +580,44 @@ def test_the_decode_span_carries_the_stacks_counters():
     # and 24 begin a lap (none crosses the ring's end: 12 is 3 chunks)
     prefill = [e["args"] for e in spans if e["name"] == "prefill"]
     assert sorted(a.get("ring_wraps") for a in prefill) == [0, 2]
+
+
+@pytest.mark.parametrize("window", [WINDOW, 28], ids=["ring_plain", "ring_kernel"])
+def test_the_decode_span_counts_what_the_kernel_fetches(monkeypatch, window):
+    """Heads of 128 and slots of whole key blocks: the full layers decode through
+    `kv_decode`, and `kv_full_read_positions` is the rows' lengths rounded up to the
+    key block plus a block a free row (the plain path above: rows x positions). A ring
+    of 8 + 4 places is no whole number of key blocks and stays rows x ring; one of 28 +
+    4 = two key blocks goes through the kernel too and is read up to the row's last
+    write until the row has lapped it. The tokens are plain generation's."""
+    from galvatron_tpu.obs.tracing import tracer
+    from galvatron_tpu.ops import kv_decode
+
+    monkeypatch.setattr(kv_decode, "KEY_BLOCK", 16)
+    cfg = small_cfg(attn_head_dim=128, num_layers=4, sliding_window_size=window)
+    params, rows = seeded(cfg, batch=1, length=10)
+    prompt = rows[0].tolist()
+    engine = _engine(cfg, params)
+    tracer.enable(capacity=1 << 12)
+    tracer.clear()
+    try:
+        served, = engine.generate([prompt], max_new_tokens=28)
+        stats = engine.stats()
+        decode = [e["args"] for e in tracer.snapshot() if e.get("ph") == "X" and e["name"] == "decode"]
+    finally:
+        tracer.disable()
+        engine.close()
+    assert served == generation.generate_np(params, cfg, [prompt], max_new_tokens=28, length_bucket=1)[0]
+    lives = [a["kv_full_live_positions"] for a in decode]
+    assert min(lives) <= 16 and 32 < max(lives)  # the row grows past two key blocks' ends
+    ring = window + CHUNK
+    for a in decode:
+        # the row in use rounded up to the key block, and a block for each of the two free rows
+        blocks = -(-a["kv_full_live_positions"] // 16)
+        assert a["kv_full_read_positions"] == (blocks + 2) * 16
+        assert a["kv_window_read_positions"] == ((min(blocks, 2) + 2) * 16 if ring == 32 else 3 * ring)
+    assert stats["kv_full_read_positions"] == 3 * 16  # no row in use: a block each
+    assert stats["kv_window_read_positions"] == (3 * 16 if ring == 32 else 3 * ring)
 
 
 def test_slots_hold_a_whole_number_of_chunks():

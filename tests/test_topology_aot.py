@@ -1268,6 +1268,27 @@ def test_mlp_recompute_buffer_accounting_tp2_zero3_sp(topo, real_mosaic):
     assert temps["policy"] <= temps["off"] * 0.95, temps
 
 
+@pytest.mark.parametrize("dtype,s,positions,span", [
+    (jnp.bfloat16, 5, 16384, 0), (jnp.bfloat16, 5, 5120, 4096),
+    (jnp.float32, 18, 16384, 0), (jnp.float32, 1, 5120, 4096)],
+    ids=["bf16_verify5_rows", "bf16_verify5_ring", "f32_verify18_rows", "f32_decode_ring"])
+def test_kv_decode_takes_what_its_rule_lets_in(one_chip, real_mosaic, dtype, s, positions, span):
+    """What the benchmark's cell does not run but `kv_decode.decode_path` lets in, at the
+    cell's widths (32 slots, 4 key/value heads of 128, 7 grouped query heads): a verify
+    window of 1 + 4, float32, 18 x 7 = 126 query rows (the most: `MAX_QUERY_ROWS` 128),
+    over whole rows and over a ring: Mosaic takes each, no temporary beside the kernel."""
+    from galvatron_tpu.ops import kv_decode
+
+    assert kv_decode.decode_path(positions, 128, s * 7, dtype) == "kernel"
+    stack = jax.ShapeDtypeStruct((4, 32, 4, positions, 128), dtype, sharding=one_chip)
+    compiled = jax.jit(lambda q, k, v, first: kv_decode.attend_rows(
+        q, k, v, 2, first, scale=128 ** -0.5, span=span)).lower(
+        jax.ShapeDtypeStruct((32, s, 4, 7, 128), dtype, sharding=one_chip), stack, stack,
+        jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text() and "kv_decode" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
 @pytest.mark.parametrize("name", ["serving_decode", "serving_prefill"])
 def test_smallthinker_serving_programs_fit_one_chip_and_write_the_cache_in_place(
         one_chip, real_mosaic, name):
@@ -1278,8 +1299,12 @@ def test_smallthinker_serving_programs_fit_one_chip_and_write_the_cache_in_place
     an in-place update has a result as large as a window layer's slab (from a
     position-major cache the compiler copied every stack whole each step, 7.76 GiB of
     temporaries; a ring's chunk as two read-merge-write updates re-laid the ring stacks,
-    3.9 GiB), the plain bodies run under ``window`` / ``full`` in every layer, and
-    weights, cache and temporaries fit the chip (11.6 and 11.7 GiB of 15.75)."""
+    3.9 GiB), every layer runs under ``window`` / ``full``, and weights, cache and
+    temporaries fit the chip (11.6 and 11.7 GiB of 15.75). Every layer of the decode
+    step attends through the kernel `kv_decode` (one custom call under ``full`` |
+    ``window`` > ``attn_core`` of each of the 16, handed its K and V stacks whole
+    where they lie: no float32 score of 32 x 28 x 16,384 or x 5,120 is left), every
+    layer of a prompt chunk through the plain body."""
     import re
 
     from galvatron_tpu.models.modeling import PRESETS
@@ -1295,6 +1320,16 @@ def test_smallthinker_serving_programs_fit_one_chip_and_write_the_cache_in_place
         stack = "window" if windowed else "full"
         assert any(f"/layer_{i}/attn/{stack}/attn_core" in n for n in names), (i, stack)
         assert any(f"/layer_{i}/attn/{stack}/cache_write" in n for n in names), (i, stack)
+    kernels = [line for line in _entry_lines(text) if "custom-call(" in line and "kv_decode" in line]
+    under = sorted(re.search(r"/layer_(\d+)/attn/(\w+)/attn_core", line).groups() for line in kernels)
+    stacks = [(str(i), "window" if windowed else "full") for i, windowed in enumerate(cfg.window_layers)]
+    assert under == (sorted(stacks) if name == "serving_decode" else [])
+    for line in kernels:  # the stacks as they lie, not a layer's slab cut out
+        stack = ("bf16[12,32,4,5120,128]{4,3,2,1,0}" if "/window/" in line
+                 else "bf16[4,32,4,16384,128]{4,3,2,1,0}")
+        assert line.count(stack) >= 2, line
+    if name == "serving_decode":
+        assert not re.search(r"f32\[32,4,7,(1,)?(16384|5120)\]", text)
     ma = compiled.memory_analysis()
     cache = 2 * 32 * 4 * 128 * 2 * (4 * 16384 + 12 * 5120)
     assert cache == 8_321_499_136 and ma.alias_size_in_bytes >= cache
