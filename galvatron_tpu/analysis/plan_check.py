@@ -75,6 +75,7 @@ KNOWN_KEYS = frozenset(
     "search_throughput_samples_per_s",
     "global_bsz",
     "memory_mb",
+    "search_price",
     "fallback_bandwidths",
     "search_restrictions",
     "homogeneity_gap_pct",

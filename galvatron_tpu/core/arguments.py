@@ -149,10 +149,14 @@ def _add_training_args(p: argparse.ArgumentParser):
                    "a step is 'bad' when measured iter time exceeds the "
                    "plan's predicted step time by more than this fraction "
                    "(e.g. 0.25 = 25%% slow); sustained drift over both burn "
-                   "windows raises an slo_breach event. Needs a "
-                   "--galvatron_config_path whose search recorded "
-                   "search_cost_ms. The drift gauge is ROADMAP item 2's "
-                   "online re-plan signal. Implies a per-iter sync. 0 = off")
+                   "windows raises an slo_breach event. The predicted step "
+                   "time is the plan file's search_cost_ms where a search "
+                   "recorded one for the batch trained; for any other plan "
+                   "(flags, the defaults) it is the total of the price the "
+                   "trainer puts on the plan itself (search/price.price_plan "
+                   "on analytic costs: the plan_price record). The drift "
+                   "gauge is ROADMAP item 2's online re-plan signal. "
+                   "Implies a per-iter sync. 0 = off")
     # hybrid-parallel GLOBAL flags (used when no galvatron_config_path)
     g.add_argument("--pp_deg", type=int, default=1)
     g.add_argument("--pp_division", type=_int_list, default=None,

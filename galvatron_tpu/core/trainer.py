@@ -116,6 +116,41 @@ def _export_obs_artifacts(ns, tracer, exc, extra=None, verbose=True) -> None:
         print(f"observability export failed: {obs_err!r}")
 
 
+def _read_plan_doc(ns: argparse.Namespace) -> dict:
+    """The plan file as a document ({} without one, or where it cannot be read:
+    plan_check has refused a bad one before this is asked)."""
+    if not ns.galvatron_config_path:
+        return {}
+    import json
+
+    try:
+        with open(ns.galvatron_config_path) as f:
+            doc = json.load(f)
+    except (OSError, ValueError):
+        return {}
+    return doc if isinstance(doc, dict) else {}
+
+
+def _price_plan_as_run(plan_doc: dict, cfg, hp, world: int, global_bsz: int) -> dict:
+    """``search/price.price_plan`` of the plan this run trains.  Where the plan
+    document carries the search's own ``search_price`` for the batch trained
+    (the rule ``search_cost_ms`` follows) that is it; otherwise the plan is
+    priced here from the basis ``cli search --analytic_costs 1`` uses
+    (``theoretical.price_model_plan``).  ``basis["source"]`` says which;
+    ``{"error": why}`` where the model cannot be priced."""
+    doc_price = plan_doc.get("search_price")
+    if isinstance(doc_price, dict) and plan_doc.get("global_bsz") == global_bsz:
+        return {**doc_price, "basis": {**doc_price.get("basis", {}), "source": "plan_file"}}
+    try:
+        from galvatron_tpu.search.theoretical import price_model_plan
+
+        price = price_model_plan(cfg, hp, world, global_bsz)
+    except Exception as e:  # noqa: BLE001 — a price is an observation, never a crash source
+        return {"error": f"{type(e).__name__}: {e}"}
+    price["basis"]["source"] = "trainer"
+    return price
+
+
 def _train_impl(ns: argparse.Namespace, verbose: bool, tracer,
                 tracer_owned: bool) -> dict:
     faults.init_from_env()  # chaos hooks: no-ops unless GALVATRON_FAULTS is set
@@ -256,6 +291,18 @@ def _train_impl(ns: argparse.Namespace, verbose: bool, tracer,
             "moe_held_path": held_path_counts(rt.cfg),
         }
         build_span.set(**layer_paths)
+        # what the search's cost model charges THIS plan, term by term,
+        # whatever the plan's source (a file, flags, the defaults): the drift
+        # gauge's anchor and what a reader sets the device's time beside
+        plan_doc = _read_plan_doc(ns)
+        plan_price = None
+        if tracer.enabled or getattr(ns, "metrics_path", None):
+            with tracer.span("plan_price"):
+                plan_price = _price_plan_as_run(plan_doc, cfg, hp, world, ns.global_train_batch_size)
+            build_span.set(plan_price=plan_price)
+        from galvatron_tpu.obs import flight
+
+        flight.note_plan_price(plan_price)
 
     from galvatron_tpu.obs import tracing as obs_tracing
     from galvatron_tpu.utils.metrics import SCHEMA_VERSION, MetricsLogger
@@ -269,6 +316,10 @@ def _train_impl(ns: argparse.Namespace, verbose: bool, tracer,
     if metrics_path and jax.process_index() != 0:
         metrics_path = None
     metrics = MetricsLogger(metrics_path)
+    if plan_price is not None:
+        from galvatron_tpu.search.price import flat
+
+        metrics.log("plan_price", **flat(plan_price))
     # in-memory peer replication client (core/peer_store.py): armed by the
     # elastic supervisor under --peer_replicate (env carries the store
     # addresses + this peer's ring rank). None = the RAM tier is off and
@@ -607,21 +658,17 @@ def _train_impl(ns: argparse.Namespace, verbose: bool, tracer,
     # the loop would run ahead of a stalled collective by the dispatch
     # depth and the deadline would measure dispatch, not the hang
     watchdog_on = bool(getattr(ns, "step_timeout_s", 0.0))
-    # cost-model fidelity anchor: the plan's predicted step time
-    # (search_cost_ms, written by SearchEngine.save_result) — read ONCE
-    # here so the per-iter drift gauge and the end-of-run report share it.
-    # The prediction only applies when training the searched batch size.
+    # cost-model fidelity anchor: the plan's predicted step time — the plan
+    # file's search_cost_ms (written by SearchEngine.save_result; it only
+    # applies when training the searched batch size), else the total of the
+    # price the trainer put on the plan itself (a flag plan, the defaults) —
+    # read ONCE here so the per-iter drift gauge and the end-of-run report
+    # share it.
     predicted_ms = None
-    if ns.galvatron_config_path:
-        import json as _json
-
-        try:
-            with open(ns.galvatron_config_path) as f:
-                _plan_doc = _json.load(f)
-            if _plan_doc.get("global_bsz") == ns.global_train_batch_size:
-                predicted_ms = _plan_doc.get("search_cost_ms")
-        except (OSError, ValueError):
-            pass
+    if plan_doc.get("global_bsz") == ns.global_train_batch_size:
+        predicted_ms = plan_doc.get("search_cost_ms")
+    if predicted_ms is None and plan_price and "error" not in plan_price:
+        predicted_ms = plan_price["basis"]["total_ms"]
     # step-time-drift SLO (obs/slo.py): sustained (iter_ms - predicted)/
     # predicted past the flag's threshold raises a burn-rate breach — the
     # drift gauge is ROADMAP item 2's online re-plan signal. Drift needs

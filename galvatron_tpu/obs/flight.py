@@ -16,7 +16,9 @@ to a logged warning: profiling is an observation, never a crash source.
 While a window is open the tracer's spans also land in the profiler's trace
 (``tracer.profiling``), and where the last window went is kept:
 ``last_profile_window()`` returns it, the trainer logs it as a
-``profile_window`` record.
+``profile_window`` record.  The same way the trainer keeps what the search's
+cost model charges the plan it runs: ``last_plan_price()``, logged as a
+``plan_price`` record.
 """
 
 from __future__ import annotations
@@ -41,6 +43,25 @@ def last_profile_window() -> Optional[Dict[str, Any]]:
     ``.xplane.pb`` it wrote, None when the backend left none; ``first_step``
     .. ``last_step``: the iterations it really covered). None before any."""
     return dict(_last_window) if _last_window else None
+
+
+_last_plan_price: Optional[Dict[str, Any]] = None
+
+
+def note_plan_price(price: Optional[Dict[str, Any]]) -> None:
+    """The trainer's: what it priced the plan it is about to run at (None: a
+    run that priced nothing, so that an earlier run's price is not read as its)."""
+    global _last_plan_price
+    _last_plan_price = price
+
+
+def last_plan_price() -> Optional[Dict[str, Any]]:
+    """What the search's cost model charges the plan the last ``train()`` of this
+    process ran, by term (``search/price.price_plan``: ``time_ms``,
+    ``volume_mb``, ``memory_mb``, ``basis``, whose ``source`` says whether the
+    plan file carried it or the trainer priced the plan itself), or
+    ``{"error": why}`` where the model cannot be priced; None before any."""
+    return dict(_last_plan_price) if _last_plan_price else None
 
 
 def dump_flight(

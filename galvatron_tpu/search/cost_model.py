@@ -164,6 +164,9 @@ class ProfiledModelCosts:
     measured_vocab_slope_ms: Dict[int, float] = field(default_factory=dict)
     measured_vocab_const_ms: Dict[int, float] = field(default_factory=dict)
     measured_vocab_mp: str = ""
+    # how these costs were made, for ``price_plan``'s ``basis``: empty for a
+    # profile; theoretical.analytic_model_costs notes the rate it assumed
+    basis: Dict[str, object] = field(default_factory=dict)
 
     def vocab_measurement_for(self, vocab_tp: int, mixed_precision: str):
         """(slope_ms_per_sample, const_ms) when a matching-precision
@@ -522,7 +525,33 @@ def other_memory_cost(
     return states + act
 
 
-def other_time_cost(
+#: terms of a plan's time that are NOT on its critical path: traffic the model
+#: believes runs under compute (``price_plan`` keeps them beside the priced terms)
+HIDDEN_TERMS = ("dp_hidden", "tp_hidden")
+
+
+def _add_mb(out: Dict[str, float], term: str, mb: float) -> None:
+    """Terms absent from a plan (degree 1, no such traffic) stay absent."""
+    if mb > 0.0:
+        out[term] = out.get(term, 0.0) + mb
+
+
+@dataclass
+class OtherTimeTerms:
+    """``other_time_cost`` by term. ``total`` is what that function returns;
+    ``volume_mb`` / ``wire_ms`` are the on-wire MB of each comm term
+    (``comm_volume_breakdown``'s names) and the ms it takes at the bandwidth
+    priced. The vocab-parallel traffic is counted in ``volume_mb`` whatever the
+    basis; a measured fit carries its time inside ``compute``'s slope."""
+
+    compute: float
+    comm: float
+    total: float
+    volume_mb: Dict[str, float]
+    wire_ms: Dict[str, float]
+
+
+def other_time_terms(
     costs: ProfiledModelCosts,
     hw: ProfiledHardware,
     world: int,
@@ -532,10 +561,10 @@ def other_time_cost(
     global_bsz: int,
     mixed_precision: str = "bf16",
     use_measured: bool = True,
-) -> float:
+) -> OtherTimeTerms:
     """Embedding/head/loss time (ms) per iteration under the vocab strategy
     (the whole-model extension the reference prices via hp_config_whole_model,
-    galvatron/core/hybrid_parallel_config.py:141-179).
+    galvatron/core/hybrid_parallel_config.py:141-179), by term.
 
     When the profile carries a MEASURED per-vocab_tp fit (slope + const from
     profile_vocab_costs, matching precision), the compute + vocab-parallel-
@@ -554,11 +583,41 @@ def other_time_cost(
     p_mb = costs.other_param_mb / vocab_tp
     dp_consec = not (vocab_tp > 1)
     dp_bw = hw.bw(dp, dp_consec)
+    volume: Dict[str, float] = {}
+    wire: Dict[str, float] = {}
     # grad allreduce (ddp) / reduce-scatter+gathers (zero3 ≈ allreduce + 2
     # param all-gathers), same shape as the layer cost model
-    comm = _allreduce_ms(p_mb * comm_bytes * GRAD_REDUCE_FP32_FACTOR, dp, dp_bw)
+    grad_msg = p_mb * comm_bytes * GRAD_REDUCE_FP32_FACTOR
+    comm = _allreduce_ms(grad_msg, dp, dp_bw)
+    _add_mb(volume, "embed_dp", _allreduce_wire_mb(grad_msg, dp))
     if embed_dp_type == "zero3":
         comm += ZERO3_GATHER_PASSES * _allgather_ms(p_mb * comm_bytes, dp, dp_bw)
+        _add_mb(volume, "embed_dp",
+                ZERO3_GATHER_PASSES * _allgather_wire_mb(p_mb * comm_bytes, dp))
+    _add_mb(wire, "embed_dp", comm)
+    embed_ms = scalar_ms = 0.0
+    if vocab_tp > 1 and costs.layer_types:
+        lt0 = next(iter(costs.layer_types.values()))
+        vocab_bw = hw.bw(vocab_tp, True)
+        # vocab-parallel embedding: each device holds a vocab shard, so the
+        # (B, S, h) embedding output is a psum over the vocab_tp group, fwd
+        # and mirrored bwd (Megatron VocabParallelEmbedding semantics)
+        act_msg = (
+            lt0.boundary_activation_mb_per_sample * (global_bsz / dp) * comm_bytes
+        )
+        # vocab-parallel cross entropy allreduces per-token fp32 scalars
+        # (max, sum-exp, picked logit + the mirrored backward share ≈ 4):
+        # volume = S·4·4B per sample = boundary·(8/h) — derived, replacing
+        # the old hand-waved 0.002 constant (which equals h=4096 exactly)
+        h = costs.hidden_size or 4096
+        scalar_msg = (
+            lt0.boundary_activation_mb_per_sample * (global_bsz / dp) * (8.0 / h)
+        )
+        embed_ms = 2.0 * _allreduce_ms(act_msg, vocab_tp, vocab_bw)
+        scalar_ms = _allreduce_ms(scalar_msg, vocab_tp, vocab_bw)
+        _add_mb(volume, "vocab_embed", 2.0 * _allreduce_wire_mb(act_msg, vocab_tp))
+        _add_mb(volume, "vocab_embed", _allreduce_wire_mb(scalar_msg, vocab_tp))
+        _add_mb(wire, "vocab_embed", embed_ms + scalar_ms)
     fit = costs.vocab_measurement_for(vocab_tp, mixed_precision) if use_measured else None
     if fit is not None:
         slope, const = fit
@@ -573,27 +632,30 @@ def other_time_cost(
         if embed_dp_type == "zero3":
             adam_ms = min(const, 7.0 * p_mb / _HBM_GBPS)
             const = const - adam_ms + adam_ms / dp
-        return const + slope * (global_bsz / (dp * pp)) + comm
+        compute = const + slope * (global_bsz / (dp * pp))
+        return OtherTimeTerms(compute, comm, compute + comm, volume, wire)
     compute = costs.other_fwd_ms_per_sample * global_bsz / world * 3.0
-    if vocab_tp > 1 and costs.layer_types:
-        lt0 = next(iter(costs.layer_types.values()))
-        # vocab-parallel embedding: each device holds a vocab shard, so the
-        # (B, S, h) embedding output is a psum over the vocab_tp group, fwd
-        # and mirrored bwd (Megatron VocabParallelEmbedding semantics)
-        act_msg = (
-            lt0.boundary_activation_mb_per_sample * (global_bsz / dp) * comm_bytes
-        )
-        comm += 2.0 * _allreduce_ms(act_msg, vocab_tp, hw.bw(vocab_tp, True))
-        # vocab-parallel cross entropy allreduces per-token fp32 scalars
-        # (max, sum-exp, picked logit + the mirrored backward share ≈ 4):
-        # volume = S·4·4B per sample = boundary·(8/h) — derived, replacing
-        # the old hand-waved 0.002 constant (which equals h=4096 exactly)
-        h = costs.hidden_size or 4096
-        scalar_msg = (
-            lt0.boundary_activation_mb_per_sample * (global_bsz / dp) * (8.0 / h)
-        )
-        comm += _allreduce_ms(scalar_msg, vocab_tp, hw.bw(vocab_tp, True))
-    return compute + comm
+    comm += embed_ms
+    comm += scalar_ms
+    return OtherTimeTerms(compute, comm, compute + comm, volume, wire)
+
+
+def other_time_cost(
+    costs: ProfiledModelCosts,
+    hw: ProfiledHardware,
+    world: int,
+    pp: int,
+    vocab_tp: int,
+    embed_dp_type: str,
+    global_bsz: int,
+    mixed_precision: str = "bf16",
+    use_measured: bool = True,
+) -> float:
+    """``other_time_terms(...).total``: what the DP's sweep adds to a plan."""
+    return other_time_terms(
+        costs, hw, world, pp, vocab_tp, embed_dp_type, global_bsz, mixed_precision,
+        use_measured=use_measured,
+    ).total
 
 
 # ---------------------------------------------------------------------------
@@ -653,7 +715,36 @@ def tp_overlap_exposed(
     return max(0.0, exposed) / slots
 
 
-def layer_time_cost(
+@dataclass
+class LayerTimeTerms:
+    """``layer_time_cost`` by term (ms a layer an iteration). On the critical
+    path: ``compute`` (forward x the recomputation factor), ``overlap_slowdown``
+    ((overlap_coe - 1) x compute where dp traffic is priced under the compute),
+    ``dp_exposed`` (what of ``dp_ms`` outlasts the compute), ``tp_exposed``
+    (``tp_ms`` after ``tp_overlap_exposed``), ``cp``, ``ep``; off it
+    (``HIDDEN_TERMS``): ``dp_hidden``, ``tp_hidden``. ``total`` is what
+    ``layer_time_cost`` returns, in its own arithmetic. ``volume_mb`` /
+    ``wire_ms``: the on-wire MB of each comm term (``comm_volume_breakdown``'s
+    names) and the ms it takes at the bandwidth priced, before any overlap."""
+
+    compute: float
+    overlap_slowdown: float
+    dp_exposed: float
+    dp_hidden: float
+    tp_exposed: float
+    tp_hidden: float
+    cp: float
+    ep: float
+    total: float
+    volume_mb: Dict[str, float]
+    wire_ms: Dict[str, float]
+
+    def terms(self) -> Dict[str, float]:
+        return {f.name: getattr(self, f.name) for f in dataclasses.fields(self)
+                if f.name not in ("total", "volume_mb", "wire_ms")}
+
+
+def layer_time_terms(
     lt: ProfiledLayerType,
     s: LayerStrategy,
     hw: ProfiledHardware,
@@ -662,12 +753,13 @@ def layer_time_cost(
     global_bsz: int,
     mixed_precision: str = "bf16",
     recompute_factor: Optional[float] = None,
-) -> float:
+) -> LayerTimeTerms:
     """Per-iteration per-layer time (ms) under strategy ``s`` (reference:
-    TimeCostModel, galvatron/core/cost_model.py:125-349): compute (bwd=2×fwd,
-    remat adds one fwd), TP collectives on the critical path, DP grad
-    reduction + ZeRO gathers overlapped under the measured slowdown
-    coefficient.
+    TimeCostModel, galvatron/core/cost_model.py:125-349), by term: compute
+    (bwd=2×fwd, remat adds one fwd), TP collectives on the critical path, DP
+    grad reduction + ZeRO gathers overlapped under the measured slowdown
+    coefficient. Every message size is stated here once: the times the DP sums
+    and the volumes ``analysis/comm_audit.py`` gates come from the same lines.
 
     ``recompute_factor``: schedules that replay the layer's forward
     regardless of its own ckpt setting (the coupled enc-dec 1F1B recomputes
@@ -702,6 +794,8 @@ def layer_time_cost(
     if recompute_factor is not None:
         factor = max(factor, recompute_factor)
     compute = fwd * factor
+    volume: Dict[str, float] = {}
+    wire: Dict[str, float] = {}
 
     comm_bytes_factor = 0.5 if mixed_precision in ("bf16", "fp16") else 1.0
     # TP: 2 allreduces fwd + 2 bwd of one (b, s, h) activation (Megatron f/g;
@@ -709,8 +803,13 @@ def layer_time_cost(
     act_msg = lt.boundary_activation_mb_per_sample * local_bsz * comm_bytes_factor
     tp_bw = hw.bw(s.tp, s.tp_consec)
     tp_ms = TP_BOUNDARY_COLLECTIVES * _allreduce_ms(act_msg, s.tp, tp_bw)
+    tp_mb = TP_BOUNDARY_COLLECTIVES * _allreduce_wire_mb(act_msg, s.tp)
     if s.ckpt == "full" or recompute_factor is not None:
         tp_ms *= REMAT_TP_REPLAY  # forward-replay schedules replay the fwd collectives
+        tp_mb *= REMAT_TP_REPLAY
+    _add_mb(volume, "tp_boundary", tp_mb)
+    _add_mb(wire, "tp_boundary", tp_ms)
+    tp_wire_ms = tp_ms
     # decomposed collective-matmul pipelines the projection collectives
     # behind the GEMM chunks — only what stays exposed is priced
     tp_ms *= tp_overlap_exposed(
@@ -727,6 +826,8 @@ def layer_time_cost(
     if s.cp > 1:
         cp_bw = hw.bw(s.cp, True)
         cp_ms = 2.0 * _allgather_ms(act_msg / s.cp * 2.0, s.cp, cp_bw) * s.cp
+        _add_mb(volume, "cp_ring", 2.0 * _allgather_wire_mb(act_msg / s.cp * 2.0, s.cp) * s.cp)
+        _add_mb(wire, "cp_ring", cp_ms)
 
     # EP: moe_a2a_mb_per_sample already covers dispatch + combine; the
     # backward replays both, so total = 2× that volume in all-to-alls
@@ -735,6 +836,8 @@ def layer_time_cost(
     if s.ep > 1 and lt.moe_a2a_mb_per_sample > 0:
         a2a_msg = lt.moe_a2a_mb_per_sample * local_bsz * comm_bytes_factor
         ep_ms = 2.0 * _allgather_ms(a2a_msg, s.ep, hw.bw(s.ep, True))
+        _add_mb(volume, "ep_a2a", 2.0 * _allgather_wire_mb(a2a_msg, s.ep))
+        _add_mb(wire, "ep_a2a", ep_ms)
 
     # DP: grad allreduce (once per iteration); ZeRO-3 adds fwd+bwd param
     # all-gathers; ZeRO-2 reduce-scatter+all-gather ≈ allreduce volume.
@@ -744,11 +847,21 @@ def layer_time_cost(
     dp_exp = max(1, dp // max(1, s.ep))
     dp_consec = not s.tp_consec if s.tp > 1 else True
     dp_bw = hw.bw(dp, dp_consec)
-    dp_ms = _allreduce_ms(dense_mb * comm_bytes_factor * GRAD_REDUCE_FP32_FACTOR, dp, dp_bw)
-    dp_ms += _allreduce_ms(exp_mb * comm_bytes_factor * GRAD_REDUCE_FP32_FACTOR, dp_exp, dp_bw)
+    dense_grad = dense_mb * comm_bytes_factor * GRAD_REDUCE_FP32_FACTOR
+    exp_grad = exp_mb * comm_bytes_factor * GRAD_REDUCE_FP32_FACTOR
+    dp_ms = _allreduce_ms(dense_grad, dp, dp_bw)
+    dp_ms += _allreduce_ms(exp_grad, dp_exp, dp_bw)
+    _add_mb(volume, "dp_grad", _allreduce_wire_mb(dense_grad, dp))
+    _add_mb(volume, "dp_grad", _allreduce_wire_mb(exp_grad, dp_exp))
+    _add_mb(wire, "dp_grad", dp_ms)
     if s.dp_type == "zero3":
         dp_ms += ZERO3_GATHER_PASSES * _allgather_ms(dense_mb * comm_bytes_factor, dp, dp_bw)
         dp_ms += ZERO3_GATHER_PASSES * _allgather_ms(exp_mb * comm_bytes_factor, dp_exp, dp_bw)
+        _add_mb(volume, "zero3_gather",
+                ZERO3_GATHER_PASSES * _allgather_wire_mb(dense_mb * comm_bytes_factor, dp))
+        _add_mb(volume, "zero3_gather",
+                ZERO3_GATHER_PASSES * _allgather_wire_mb(exp_mb * comm_bytes_factor, dp_exp))
+        _add_mb(wire, "zero3_gather", dp_ms - wire.get("dp_grad", 0.0))
 
     # overlap model: DP traffic overlaps compute at a slowdown coefficient
     # (reference bct_dp_overlap, cost_model.py:230-246)
@@ -758,10 +871,51 @@ def layer_time_cost(
         overlapped = hw.overlap_coe * compute
     else:
         overlapped = hw.overlap_coe * compute + (dp_ms - compute)
-    return overlapped + tp_ms + cp_ms + ep_ms
+    return LayerTimeTerms(
+        compute=compute,
+        overlap_slowdown=(hw.overlap_coe - 1.0) * compute if dp_ms else 0.0,
+        dp_exposed=max(0.0, dp_ms - compute),
+        dp_hidden=min(dp_ms, compute),
+        tp_exposed=tp_ms,
+        tp_hidden=tp_wire_ms - tp_ms,
+        cp=cp_ms,
+        ep=ep_ms,
+        total=overlapped + tp_ms + cp_ms + ep_ms,
+        volume_mb=volume,
+        wire_ms=wire,
+    )
 
 
-def pipeline_time_cost(
+def layer_time_cost(
+    lt: ProfiledLayerType,
+    s: LayerStrategy,
+    hw: ProfiledHardware,
+    world: int,
+    pp: int,
+    global_bsz: int,
+    mixed_precision: str = "bf16",
+    recompute_factor: Optional[float] = None,
+) -> float:
+    """``layer_time_terms(...).total``: the number the DP's tables hold."""
+    return layer_time_terms(
+        lt, s, hw, world, pp, global_bsz, mixed_precision, recompute_factor
+    ).total
+
+
+@dataclass
+class PipelineTimeTerms:
+    """``pipeline_time_cost`` by term: ``work`` (the bottleneck stage over all
+    its micro-batches: what its layers' terms add up to), ``pp_bubble`` (fill
+    and drain ticks), ``pp_p2p`` (the boundary message, every tick);
+    ``total`` is what ``pipeline_time_cost`` returns."""
+
+    work: float
+    pp_bubble: float
+    pp_p2p: float
+    total: float
+
+
+def pipeline_time_terms(
     stage_ms: list,
     boundary_msg_mb: float,
     pp: int,
@@ -769,7 +923,7 @@ def pipeline_time_cost(
     hw: ProfiledHardware,
     vpp: int = 1,
     pipeline_type: str = "gpipe",
-) -> float:
+) -> PipelineTimeTerms:
     """Iteration time of the clocked pipeline (reference: pipeline_costmodel,
     galvatron/core/cost_model.py:372-427): fill + steady-state bottleneck.
     stage_ms: per-stage per-micro-batch compute+TP time (callers price
@@ -787,18 +941,87 @@ def pipeline_time_cost(
     interleaved 1F1B T = vpp*chunks + vpp*pp + pp - 1
     (pipeline_interleaved.py:276) — its drain scales with vpp too."""
     if pp == 1:
-        return sum(stage_ms)
+        total = sum(stage_ms)
+        return PipelineTimeTerms(total, 0.0, 0.0, total)
     p2p_ms = boundary_msg_mb / hw.p2p(pp) if boundary_msg_mb else 0.0
     per_tick = [c / vpp + p2p_ms for c in stage_ms]
     bottleneck = max(per_tick)
     extra = 0
     if pipeline_type == "pipedream_flush":
         extra = (pp - 1) if vpp == 1 else vpp * pp
-    return sum(per_tick) + bottleneck * (vpp * chunks - 1 + extra)
+    total = sum(per_tick) + bottleneck * (vpp * chunks - 1 + extra)
+    work = max(stage_ms) * chunks
+    p2p = p2p_ms * (pp + vpp * chunks - 1 + extra)
+    return PipelineTimeTerms(work, total - work - p2p, p2p, total)
+
+
+def pipeline_time_cost(
+    stage_ms: list,
+    boundary_msg_mb: float,
+    pp: int,
+    chunks: int,
+    hw: ProfiledHardware,
+    vpp: int = 1,
+    pipeline_type: str = "gpipe",
+) -> float:
+    """``pipeline_time_terms(...).total``."""
+    return pipeline_time_terms(
+        stage_ms, boundary_msg_mb, pp, chunks, hw, vpp=vpp, pipeline_type=pipeline_type
+    ).total
+
+
+def coupled_pipeline_time_cost(
+    tick_ms: float,
+    boundaries_mb: list,
+    pp: int,
+    chunks: int,
+    hw: ProfiledHardware,
+    global_bsz: int,
+    pipeline_type: str = "gpipe",
+    mixed_precision: str = "bf16",
+    sections: bool = False,
+) -> float:
+    """Iteration time of the coupled tick-synchronous pipelines from one
+    bottleneck tick — the ONE pricing SearchEngine.evaluate(),
+    homogeneity_gap() and price_plan use (a divergence here would make the
+    gap measure formula skew instead of the homogeneity restriction).
+    ``boundaries_mb``: each sub-stack's boundary activation MB a sample.
+
+    enc-dec (pipeline_encdec.py; ``boundaries_mb`` = [encoder, decoder]): every
+    tick runs one enc + one dec virtual stage; T = chunks + 2pp - 1 (gpipe
+    autodiff) or chunks + 4pp - 2 (coupled 1F1B; its per-tick section
+    recompute is priced in the intra table); three ppermutes per tick — enc
+    out and ctx at the encoder boundary size, dec y at the decoder's.
+    Swin (pipeline_swin.py; ``sections``, one boundary a section): every tick
+    runs one virtual stage of EVERY section; T = chunks + K*pp - 1 (gpipe
+    autodiff, K ring ppermutes) or chunks + 2K*pp - 2 (coupled 1F1B: per-tick
+    section recompute priced in the intra table, 3K-1 ring sends — K section
+    outputs + K-1 merged outputs + K backward cotangents)."""
+    bf = 0.5 if mixed_precision in ("bf16", "fp16") else 1.0
+    if not sections:
+        enc_b, dec_b = boundaries_mb
+        p2p_mb = (2.0 * enc_b + dec_b) * (global_bsz / chunks) * bf
+        T = (
+            chunks + 4 * pp - 2
+            if pipeline_type == "pipedream_flush"
+            else chunks + 2 * pp - 1
+        )
+    else:
+        bs = list(boundaries_mb)
+        Ks = len(bs)
+        if pipeline_type == "pipedream_flush":
+            # per tick: K section-output sends + K-1 merged sends (next
+            # section's size) + K backward dx sends (pipeline_swin.py)
+            p2p_mb = (2.0 * sum(bs) + sum(bs[1:])) * (global_bsz / chunks) * bf
+            T = chunks + 2 * Ks * pp - 2
+        else:
+            p2p_mb = sum(bs) * (global_bsz / chunks) * bf
+            T = chunks + Ks * pp - 1
+    return T * (tick_ms + p2p_mb / hw.p2p(pp))
 
 
 # ---------------------------------------------------------------------------
-# Comm-volume replay (the predicted side of the GTC fidelity gate)
+# Comm volume by term (the predicted side of the GTC fidelity gate)
 # ---------------------------------------------------------------------------
 
 
@@ -811,82 +1034,22 @@ def comm_volume_breakdown(
 ) -> Dict[str, float]:
     """Per-term analytic comm VOLUME (on-wire MB per device per iteration,
     every term — ``pp_p2p`` sums all of an iteration's boundary crossings)
-    for one plan — the exact message sizes and multiplicities
-    ``layer_time_cost`` / ``other_time_cost`` / ``pipeline_time_cost``
-    price, with the bandwidth divided back out.
+    for one plan: ``price_plan(...)["volume_mb"]``, the message sizes and
+    multiplicities ``layer_time_terms`` / ``other_time_terms`` state where
+    they price them, with the bandwidth left out.
 
     This is the *predicted* side of ``analysis/comm_audit.py``'s
     ``predicted_over_lowered`` gate: the audited (lowered) side re-derives
     the same volumes from the program's actual abstract shapes and lowered
     collectives with its own first-principles constants, so a drift in any
     constant above (TP_BOUNDARY_COLLECTIVES, ZERO3_GATHER_PASSES, …) or in a
-    message-size formula here moves only this side and trips GTC001.
+    message-size formula moves only this side and trips GTC001.
 
     Terms absent from the plan (degree 1) are omitted.  Multi-layer-type
     models (vision towers, MoE stacks) price every layer with its own
     strategy but layer type 0's sizes — the fidelity gate tolerance absorbs
     the approximation, and the audit report marks the basis.
     """
-    f = 0.5 if mixed_precision in ("bf16", "fp16") else 1.0
-    lt = costs.layer_types[min(costs.layer_types)] if costs.layer_types else None
-    out: Dict[str, float] = {}
+    from galvatron_tpu.search.price import plan_volume_mb
 
-    def add(term: str, mb: float) -> None:
-        if mb > 0.0:
-            out[term] = out.get(term, 0.0) + mb
-
-    pp = hp.pp
-    for s in hp.layer_strategies:
-        if lt is None:
-            break
-        dp = max(1, world // (pp * s.tp * max(1, s.cp)))
-        local_bsz = global_bsz / dp / max(1, s.cp)
-        act_msg = lt.boundary_activation_mb_per_sample * local_bsz * f
-        if s.tp > 1:
-            tp_mb = TP_BOUNDARY_COLLECTIVES * _allreduce_wire_mb(act_msg, s.tp)
-            if s.ckpt == "full":
-                tp_mb *= REMAT_TP_REPLAY
-            add("tp_boundary", tp_mb)
-        if s.cp > 1:
-            add("cp_ring", 2.0 * _allgather_wire_mb(act_msg / s.cp * 2.0, s.cp) * s.cp)
-        frac = lt.moe_expert_param_fraction
-        ep = max(1, s.ep)
-        if s.ep > 1 and lt.moe_a2a_mb_per_sample > 0:
-            a2a_msg = lt.moe_a2a_mb_per_sample * local_bsz * f
-            add("ep_a2a", 2.0 * _allgather_wire_mb(a2a_msg, s.ep))
-        dense_mb = lt.parameter_mb * (1.0 - frac) / s.tp
-        exp_mb = lt.parameter_mb * frac / (s.tp * ep)
-        dp_exp = max(1, dp // ep)
-        add("dp_grad", _allreduce_wire_mb(dense_mb * f * GRAD_REDUCE_FP32_FACTOR, dp))
-        add("dp_grad", _allreduce_wire_mb(exp_mb * f * GRAD_REDUCE_FP32_FACTOR, dp_exp))
-        if s.dp_type == "zero3":
-            add("zero3_gather", ZERO3_GATHER_PASSES * _allgather_wire_mb(dense_mb * f, dp))
-            add("zero3_gather", ZERO3_GATHER_PASSES * _allgather_wire_mb(exp_mb * f, dp_exp))
-
-    # embedding / head / loss under the vocab strategy (other_time_cost's
-    # analytic comm block, volumes only)
-    vocab_tp = max(1, hp.vocab_tp)
-    dp_o = max(1, world // (pp * vocab_tp))
-    p_mb = costs.other_param_mb / vocab_tp
-    add("embed_dp", _allreduce_wire_mb(p_mb * f * GRAD_REDUCE_FP32_FACTOR, dp_o))
-    if hp.embed_dp_type == "zero3":
-        add("embed_dp", ZERO3_GATHER_PASSES * _allgather_wire_mb(p_mb * f, dp_o))
-    if vocab_tp > 1 and lt is not None:
-        act_msg_v = lt.boundary_activation_mb_per_sample * (global_bsz / dp_o) * f
-        add("vocab_embed", 2.0 * _allreduce_wire_mb(act_msg_v, vocab_tp))
-        h = costs.hidden_size or 4096
-        add("vocab_embed", _allreduce_wire_mb(
-            lt.boundary_activation_mb_per_sample * (global_bsz / dp_o) * (8.0 / h),
-            vocab_tp,
-        ))
-
-    if pp > 1 and lt is not None:
-        # per-iteration per-device boundary p2p: every micro-batch crosses
-        # each boundary fwd (activation out) and bwd (grad in), so chunks ×
-        # the per-tick message pipeline_time_cost prices = the full local
-        # batch, twice
-        s0 = hp.layer_strategies[0]
-        dp0 = max(1, world // (pp * s0.tp * max(1, s0.cp)))
-        add("pp_p2p",
-            2.0 * lt.boundary_activation_mb_per_sample * (global_bsz / dp0) * f)
-    return out
+    return plan_volume_mb(costs, hp, world, global_bsz, mixed_precision)

@@ -28,12 +28,7 @@ from dataclasses import dataclass
 from typing import List, Optional
 
 from galvatron_tpu.core.strategy import HybridParallelConfig
-from galvatron_tpu.search.cost_model import (
-    ProfiledModelCosts,
-    layer_memory_cost,
-    other_memory_cost,
-    transient_overhead_mb,
-)
+from galvatron_tpu.search.cost_model import ProfiledModelCosts
 
 
 # single-host topologies this module knows how to declare to libtpu:
@@ -100,48 +95,14 @@ def predicted_train_mb(
     world: int,
     global_bsz: int,
 ) -> float:
-    """Per-device MB the search would charge this config: the heaviest
-    stage's (positions x layer_memory_cost) + the embed/head/loss 'other'
-    term (replicated over pp in this runtime, so charged on every stage)."""
-    from galvatron_tpu.core.strategy import balanced_division
+    """Per-device MB the search would charge this config: the sum of
+    ``price.plan_memory_mb``'s terms (what ``price_plan`` returns as
+    ``memory_mb``): the heaviest stage's (positions x layer_memory_cost), the
+    embed/head/loss 'other' term, the 1F1B engines' per-device constants and
+    the transient working set.  ``cfg`` is not read: the plan names its layers."""
+    from galvatron_tpu.search.price import plan_memory_mb
 
-    lt = costs.layer_types[0]
-    pp = hp.pp
-    L = cfg.total_layers
-    div = list(hp.pp_division) if hp.pp_division else balanced_division(L, pp)
-    stage_mb = []
-    off = 0
-    for st in range(pp):
-        mb = 0.0
-        for j in range(div[st]):
-            s = hp.layer_strategies[off + j]
-            mb += layer_memory_cost(
-                lt, s, world, pp, global_bsz, hp.chunks, stage_idx=st,
-                pipeline_type=hp.pipeline_type, mixed_precision=hp.mixed_precision,
-                vpp=hp.vpp,
-            ).total_mb
-        off += div[st]
-        stage_mb.append(mb)
-    other = other_memory_cost(
-        costs, world, pp, hp.vocab_tp, hp.embed_dp_type, global_bsz, hp.chunks,
-        hp.mixed_precision,
-    )
-    # single-stack/interleaved 1F1B per-device constants — THE SAME pricing
-    # evaluate() charges (cost_model.single_1f1b_rings_mb), not a
-    # re-derivation that could drift
-    pf = 0.0
-    if pp > 1 and hp.pipeline_type == "pipedream_flush":
-        from galvatron_tpu.search.cost_model import single_1f1b_rings_mb
-
-        pf = single_1f1b_rings_mb(
-            lt, hp.layer_strategies[0], world, pp, global_bsz, hp.chunks,
-            hp.mixed_precision, vpp=max(1, hp.vpp),
-            layers_per_device=max(div),
-        )
-    trans = transient_overhead_mb(
-        costs, min(s.tp for s in hp.layer_strategies), hp.mixed_precision
-    )
-    return max(stage_mb) + other + pf + trans
+    return sum(plan_memory_mb(costs, hp, world, global_bsz).values())
 
 
 def measured_train_mb(

@@ -36,8 +36,10 @@ from galvatron_tpu.search.cost_model import (
     ProfiledHardware,
     ProfiledLayerType,
     ProfiledModelCosts,
+    coupled_pipeline_time_cost,
     layer_memory_cost,
     layer_time_cost,
+    layer_time_terms,
     tp_overlap_exposed,
     other_memory_cost,
     other_time_cost,
@@ -45,6 +47,7 @@ from galvatron_tpu.search.cost_model import (
 )
 from galvatron_tpu.search.dynamic_programming import run_dp, transition_cost_ms
 from galvatron_tpu.search.pp_division import pp_division_memory_balanced
+from galvatron_tpu.search.price import layer_type_of, price_plan, total_memory_mb, type_groups
 
 
 @dataclass
@@ -220,17 +223,11 @@ class SearchEngine:
         # emitted plan is validated against the model before it is written
         self.model_config = model_config
         self.model_name = model_name
-        from galvatron_tpu.models.modeling import ModelConfig, projection_seams
+        from galvatron_tpu.models.modeling import ModelConfig
+        from galvatron_tpu.search.theoretical import with_tp_seams
 
         if isinstance(model_config, ModelConfig):
-            # the shapes cost_model.tp_overlap_exposed prices s.tp_overlap from
-            # (a profile carries times and sizes, not the projections' widths)
-            seq = int(model_config.max_seq_len)
-            seams = tuple((k, w, seq, blk) for _, k, w, blk in projection_seams(model_config, seq))
-            self.costs = dataclasses.replace(model_costs, layer_types={
-                i: lt if lt.tp_seams else dataclasses.replace(lt, tp_seams=seams)
-                for i, lt in model_costs.layer_types.items()
-            })
+            self.costs = with_tp_seams(model_costs, model_config)
         if model_config is not None:
             # model divisibility constraints on the candidate space: a tp
             # that cannot split the heads or a vocab_tp that cannot shard
@@ -306,8 +303,7 @@ class SearchEngine:
         )
 
     def _layer_type(self, i: int) -> ProfiledLayerType:
-        lts = self.costs.layer_types
-        return lts.get(i, lts[0]) if len(lts) > 1 else lts[0]
+        return layer_type_of(self.costs, i)
 
     def _vocab_use_measured(self) -> bool:
         """Consistent vocab pricing across the ENTIRE search: consume the
@@ -372,59 +368,26 @@ class SearchEngine:
         return (sum(intra[j, res[j]] for j in range(n_pos)) + inter_sum) * vpp / chunks
 
     def _type_groups(self):
-        """Contiguous (start, count, layer_type) runs over layer indices.
-        Grouped by VALUE equality — JSON-loaded profiles materialize a fresh
-        ProfiledLayerType per index, so identity would split every layer."""
-        groups = []
-        for i in range(self.L):
-            lt = self._layer_type(i)
-            if groups and groups[-1][2] == lt:
-                groups[-1][1] += 1
-            else:
-                groups.append([i, 1, lt])
-        return groups
+        """Contiguous (start, count, layer_type) runs over layer indices
+        (price.type_groups: the one rule price_plan reads a plan back by)."""
+        return type_groups(self.costs, self.L)
 
     def _coupled_total_ms(
         self, tick_ms: float, pp: int, chunks: int, pipeline_type: str,
         global_bsz: int, multi_type, swin_groups,
     ) -> float:
-        """Iteration time of the coupled tick-synchronous pipelines from one
-        bottleneck tick — the ONE pricing both evaluate() and
-        homogeneity_gap() use (a divergence here would make the gap measure
-        formula skew instead of the homogeneity restriction).
-
-        enc-dec (pipeline_encdec.py): every tick runs one enc + one dec
-        virtual stage; T = chunks + 2pp - 1 (gpipe autodiff) or
-        chunks + 4pp - 2 (coupled 1F1B; its per-tick section recompute is
-        priced in the intra table); three ppermutes per tick — enc out and
-        ctx at the encoder boundary size, dec y at the decoder's.
-        Swin (pipeline_swin.py): every tick runs one virtual stage of EVERY
-        section; T = chunks + K*pp - 1 (gpipe autodiff, K ring ppermutes) or
-        chunks + 2K*pp - 2 (coupled 1F1B: per-tick section recompute priced
-        in the intra table, 3K-1 ring sends — K section outputs + K-1 merged
-        outputs + K backward cotangents)."""
-        bf = 0.5 if self.mp in ("bf16", "fp16") else 1.0
+        """cost_model.coupled_pipeline_time_cost for this engine's sub-stacks:
+        ``multi_type`` the enc-dec layer counts, ``swin_groups`` the sections."""
         if multi_type is not None:
-            enc_b = self._layer_type(0).boundary_activation_mb_per_sample
-            dec_b = self._layer_type(multi_type[0]).boundary_activation_mb_per_sample
-            p2p_mb = (2.0 * enc_b + dec_b) * (global_bsz / chunks) * bf
-            T = (
-                chunks + 4 * pp - 2
-                if pipeline_type == "pipedream_flush"
-                else chunks + 2 * pp - 1
-            )
+            boundaries = [self._layer_type(0).boundary_activation_mb_per_sample,
+                          self._layer_type(multi_type[0]).boundary_activation_mb_per_sample]
         else:
-            bs = [lt.boundary_activation_mb_per_sample for _, lt in swin_groups]
-            Ks = len(swin_groups)
-            if pipeline_type == "pipedream_flush":
-                # per tick: K section-output sends + K-1 merged sends (next
-                # section's size) + K backward dx sends (pipeline_swin.py)
-                p2p_mb = (2.0 * sum(bs) + sum(bs[1:])) * (global_bsz / chunks) * bf
-                T = chunks + 2 * Ks * pp - 2
-            else:
-                p2p_mb = sum(bs) * (global_bsz / chunks) * bf
-                T = chunks + Ks * pp - 1
-        return T * (tick_ms + p2p_mb / self.hw.p2p(pp))
+            boundaries = [lt.boundary_activation_mb_per_sample for _, lt in swin_groups]
+        return coupled_pipeline_time_cost(
+            tick_ms, boundaries, pp, chunks, self.hw, global_bsz,
+            pipeline_type=pipeline_type, mixed_precision=self.mp,
+            sections=multi_type is None,
+        )
 
     # -- single (pp, bsz, chunks, pipeline_type) evaluation ------------------
 
@@ -855,7 +818,9 @@ class SearchEngine:
                     continue
                 seen.add(key)
                 out.append(r)
-        out.sort(key=lambda r: -r.throughput_samples_per_s)
+            out.sort(key=lambda r: -r.throughput_samples_per_s)
+            if out:
+                self.price(out[0])
         rs = self._active_restrictions()
         if rs:
             for r in out:
@@ -877,6 +842,8 @@ class SearchEngine:
                     r.throughput_samples_per_s > best.throughput_samples_per_s
                 ):
                     best = r
+            if best is not None:
+                self.price(best)
         if best is not None:
             rs = self._active_restrictions()
             if rs:
@@ -889,6 +856,33 @@ class SearchEngine:
                 f"(bsz {best.global_bsz}, {form_strategy(s0, best.config.pp, dp)})"
             )
         return best
+
+    def price(self, result: SearchResult) -> Dict:
+        """``price_plan`` of a result of this engine, once (kept in
+        ``result.details["search_price"]``; ``save_result`` writes it): the
+        plan by term from the costs and bandwidths the sweep ran on.  Its
+        ``basis`` also says how the DP's own ``memory_mb`` differs from the
+        terms' sum: the DP rounds every position up to its memory unit and
+        charges the transient working set at the smallest tp any CANDIDATE
+        has, the terms at the smallest tp the plan has."""
+        if "search_price" not in result.details:
+            with _obs_tracer.span("search_price") as sp:
+                price = price_plan(
+                    self.costs, self.hw, result.config, self.space.world_size,
+                    result.global_bsz, self.mp,
+                    use_measured_vocab=self._vocab_use_measured(),
+                    section_pipeline=self.section_pipeline,
+                )
+                price["basis"]["dp_memory_mb"] = result.memory_mb
+                price["basis"]["dp_memory_over_terms_mb"] = (
+                    result.memory_mb - total_memory_mb(price))
+                price["basis"]["dp_memory_unit_mb"] = self.unit
+                sp.set(total_ms=price["basis"]["total_ms"],
+                       hidden_ms=sum(price["time_ms"][t] for t in price["basis"]["hidden_terms"]),
+                       memory_mb=price["basis"]["total_memory_mb"],
+                       volume_mb=sum(price["volume_mb"].values()))
+            result.details["search_price"] = price
+        return result.details["search_price"]
 
     def recommend_min_bsz(self, scale: int = 8) -> int:
         """Prune sweep batch sizes that are search-time waste (reference:
@@ -1183,9 +1177,13 @@ class SearchEngine:
         for gi, (start, cnt, lt) in enumerate(groups):
             if len(groups) > 1:
                 lines.append(f"layer type {gi} (layers {start}..{start + cnt - 1}):")
+            # the time columns are layer_time_terms' own (what price_plan and the
+            # plan file's search_price hold): compute, tp and dp as priced on the
+            # critical path (tp after tp_overlap's credit; dp = the overlap's
+            # slowdown + what outlasts the compute), then the layer's total
             lines.append(
                 f"{'strategy':>16} | {'states MB':>9} | {'act MB':>8} | "
-                f"{'total MB':>8} | {'time ms':>8}"
+                f"{'total MB':>8} | {'compute':>8} | {'tp':>7} | {'dp':>7} | {'time ms':>8}"
             )
             # same stash-ring pricing evaluate() applies to the coupled
             # 1F1B schedules: enc-dec groups stash 4pp-1 / 2pp-1 slots,
@@ -1203,12 +1201,14 @@ class SearchEngine:
                     pipeline_type=pipeline_type, mixed_precision=self.mp,
                     stash_boundary_bound=stash_bound,
                 )
-                t = layer_time_cost(
+                t = layer_time_terms(
                     lt, s, self.hw, world, pp, global_bsz, mixed_precision=self.mp
                 )
                 lines.append(
                     f"{form_strategy(s, pp, dp):>16} | {mc.states_mb:9.1f} | "
-                    f"{mc.activation_mb:8.1f} | {mc.total_mb:8.1f} | {t:8.2f}"
+                    f"{mc.activation_mb:8.1f} | {mc.total_mb:8.1f} | {t.compute:8.2f} | "
+                    f"{t.tp_exposed:7.2f} | {t.overlap_slowdown + t.dp_exposed:7.2f} | "
+                    f"{t.total:8.2f}"
                 )
         # vocab/embedding strategy tradeoff (searched dimension); 'src' shows
         # whether the base term is measured (profile_vocab_costs table) or
@@ -1239,6 +1239,8 @@ class SearchEngine:
         d["search_throughput_samples_per_s"] = result.throughput_samples_per_s
         d["global_bsz"] = result.global_bsz
         d["memory_mb"] = result.memory_mb
+        # the same two numbers by term (price.price_plan), priced once, after the sweep
+        d["search_price"] = self.price(result)
         fb = result.details.get("fallback_bandwidths")
         if fb:
             d["fallback_bandwidths"] = fb  # priced from defaults, not measured

@@ -16,6 +16,7 @@ cross-check for the profiler's measured numbers (``check_cost_model``).
 
 from __future__ import annotations
 
+import dataclasses
 from dataclasses import dataclass
 from typing import Dict
 
@@ -215,6 +216,15 @@ def analytic_model_costs(
     profiling exists (the reference cannot: it always requires profiled JSON,
     search_engine.py:92-121). fwd time from the 2·P·T FLOP estimate at an
     assumed MFU; activation table from layer_activation_mb_per_sample."""
+    # what price_plan's ``basis`` says of these costs: the one compute rate
+    # every fwd_ms here is a FLOP count over
+    basis = {"costs": "analytic", "peak_tflops": peak_tflops, "efficiency": mfu,
+             "compute_tflops": peak_tflops * mfu}
+    return dataclasses.replace(
+        _analytic_model_costs(cfg, seq_len, peak_tflops, mfu, mixed_precision), basis=basis)
+
+
+def _analytic_model_costs(cfg, seq_len, peak_tflops, mfu, mixed_precision):
     from galvatron_tpu.search.cost_model import ProfiledLayerType, ProfiledModelCosts
 
     if cfg.image_size:
@@ -268,6 +278,36 @@ def analytic_model_costs(
         other_param_mb=other_p * 4 / 1e6,
         other_act_mb_per_sample=other_act,
         other_fwd_ms_per_sample=other_flops / (peak_tflops * 1e12 * mfu) * 1e3,
+    )
+
+
+def with_tp_seams(costs, cfg: ModelConfig):
+    """``costs`` with the model's projection seams on every layer type that
+    carries none: the shapes ``cost_model.tp_overlap_exposed`` prices
+    ``s.tp_overlap`` from (a profile carries times and sizes, not the
+    projections' widths).  ONE rule for the search and for whoever prices a
+    plan outside it."""
+    from galvatron_tpu.models.modeling import projection_seams
+
+    seq = int(cfg.max_seq_len)
+    seams = tuple((k, w, seq, blk) for _, k, w, blk in projection_seams(cfg, seq))
+    return dataclasses.replace(costs, layer_types={
+        i: lt if lt.tp_seams else dataclasses.replace(lt, tp_seams=seams)
+        for i, lt in costs.layer_types.items()
+    })
+
+
+def price_model_plan(cfg: ModelConfig, hp, world: int, global_bsz: int):
+    """``price.price_plan`` of ``hp`` from the basis ``cli search
+    --analytic_costs 1`` searches on: this module's costs for the model at its
+    own sequence length and ``ProfiledHardware``'s defaults.  How the trainer
+    prices a plan that no search priced."""
+    from galvatron_tpu.search.cost_model import ProfiledHardware
+    from galvatron_tpu.search.price import price_plan
+
+    return price_plan(
+        with_tp_seams(analytic_model_costs(cfg), cfg), ProfiledHardware(), hp,
+        world, global_bsz, hp.mixed_precision, section_pipeline=bool(cfg.swin_depths),
     )
 
 

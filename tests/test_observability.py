@@ -99,7 +99,8 @@ def test_trainer_stops_and_checkpoints_on_signal(tmp_path):
     final = int(np.asarray(out["state"]["step"]))
     assert final == 3  # stopped right after the signaled iteration
     assert latest_step(save) == 3  # checkpoint-on-exit
-    recs = read_metrics(metrics_path)
+    # three iterations logged (the run's one plan_price record, PR 56, is no iteration)
+    recs = [r for r in read_metrics(metrics_path) if r["event"] == "train_iter"]
     assert len(recs) == 3 and recs[-1]["step"] == 2
 
 
