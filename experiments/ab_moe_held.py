@@ -16,11 +16,26 @@ relative difference of the output and of each gradient between the bodies, wheth
 everything is finite, and the largest device operations of the bounded backward.
 
 One JSON line a measurement, the table at the end; no CPU fallback.
+
+    chiprun --chips 1 -- python experiments/ab_moe_held.py --serve [--tiles 16,32,64,128,256]
+
+``--serve``: the table `ops/grouped_matmul.row_tile`'s constant is read from. The
+bounded forward alone (layout + `moe.held_experts`, the ``(w1, w3)`` pair held in
+bf16 as `cli serve --param_dtype bf16` holds it) at the four serving shapes, a decode
+step's 32 tokens and a prompt chunk's 1,024 of `sarvam-105b_serve_long_above_knee`
+(top-8 over 128 scored, 32 held, 4096 x 2048) and of
+`smallthinker-21b-a3b_serve_long_above_knee` (top-6 over 64, 16 held, 2560 x 768,
+ReGLU), at each row tile: ms a call, the GB/s that makes of the held weights' bytes
+(every held expert's three matrices once), the buffer's rows in use, the largest
+difference from the tile-256 output, the largest device operations. ``--skew`` sets
+the routers' loads (0 even, 1 a few favourites); ``--tiny`` rehearses the mode at
+small widths on any backend.
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -76,14 +91,96 @@ def bounded(x, weights, w1, w3, w2, idx):
 
 BODIES = {"plain": plain, "bounded": bounded}
 
+#: the expert layers of the two long serving cells (scored, held, top-k, hidden, width, gate)
+SERVE_SHAPES = {
+    "sarvam-105b": (128, 32, 8, 4096, 2048, "silu"),
+    "smallthinker-21b-a3b": (64, 16, 6, 2560, 768, "relu"),
+}
+TINY_SHAPES = {"tiny": (16, 4, 2, 256, 128, "silu")}
+
+
+def serve_inputs(tokens, experts, held, top_k, hidden, width, skew=1.0, seed=0,
+                 dtype=jnp.bfloat16):
+    """A forward's activations, a router's choices over ALL the scored experts
+    (distinct a token; ``skew`` times a standard normal on every expert's logit: 0 an
+    even load, 1 a trained router's few favourites) with their renormalised weights,
+    and the held experts' three matrices."""
+    ks = jax.random.split(jax.random.key(seed), 6)
+    x = jax.random.normal(ks[0], (tokens, hidden), dtype)
+    scores = (jax.random.normal(ks[1], (tokens, experts))
+              + skew * jax.random.normal(ks[2], (experts,)))
+    weights, idx = jax.lax.top_k(jax.nn.softmax(scores, axis=-1), top_k)
+    weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    w1, w3 = (jax.random.normal(k, (held, hidden, width), dtype) * hidden ** -0.5
+              for k in ks[3:5])
+    w2 = jax.random.normal(ks[5], (held, width, hidden), dtype) * width ** -0.5
+    return x, weights, w1, w3, w2, idx.astype(jnp.int32)
+
+
+def serve_forward(x, weights, w1, w3, w2, idx, *, held, tile, act):
+    lay = moe.held_layout(idx, held, tile, 0)
+    return moe.held_experts(x, weights, (w1, w3), w2, lay.pair_row, lay.row_pair, lay.row_valid,
+                            lay.tile_group, lay.num_tiles, tile, act)
+
+
+def serve(args) -> int:
+    tiny = args.tiny
+    shapes = TINY_SHAPES if tiny else SERVE_SHAPES
+    tiles = [int(t) for t in args.tiles.split(",")]
+    rows = []
+    for model, (experts, held, top_k, hidden, width, act) in shapes.items():
+        weight_bytes = 3 * held * hidden * width * 2
+        for tokens, skew in ((t, float(k)) for t in ((8, 64) if tiny else (32, 1024))
+                             for k in args.skew.split(",")):
+            operands = serve_inputs(tokens, experts, held, top_k, hidden, width, skew)
+            mean_rows = tokens * top_k / experts
+            want = None
+            for tile in sorted(tiles, reverse=True):  # 256 first: the one the others are held to
+                fwd = jax.jit(functools.partial(serve_forward, held=held, tile=tile, act=act))
+                y = jax.block_until_ready(fwd(*operands))
+                ms = timed(fwd, *operands, iters=3 if tiny else 30)
+                ops = device_ops(fwd, operands, top=args.ops) if args.ops and not tiny else []
+                lay = jax.jit(functools.partial(moe.held_layout, held=held, tile=tile,
+                                                first_held=0))(operands[-1])
+                want = y if want is None else want
+                row = {"model": model, "tokens": tokens, "skew": skew,
+                       "mean_rows_an_expert": mean_rows, "tile": tile, "fwd_ms": ms,
+                       "weights_gb_per_s": weight_bytes / ms / 1e6,
+                       "buffer_rows": int(lay.row_valid.shape[0]),
+                       "rows_in_use": int(lay.num_tiles[0]) * tile,
+                       "held_pairs": int(jnp.sum(lay.sizes)),
+                       "rel_to_256": rel(y.astype(jnp.float32), want.astype(jnp.float32)),
+                       "finite": bool(jnp.isfinite(y.astype(jnp.float32)).all()),
+                       "device_ops_ms": ops}
+                print(json.dumps(row), flush=True)
+                rows.append(row)
+    print("| model | tokens | skew | mean rows an expert | tile | rows in use / buffer | "
+          "held pairs | fwd ms | GB/s of the weights |")
+    print("| --- | --- | --- | --- | --- | --- | --- | --- | --- |")
+    for r in rows:
+        print(f"| {r['model']} | {r['tokens']} | {r['skew']:g} | {r['mean_rows_an_expert']:g} | "
+              f"{r['tile']} | {r['rows_in_use']} / {r['buffer_rows']} | {r['held_pairs']} | "
+              f"{r['fwd_ms']:.3f} | {r['weights_gb_per_s']:.0f} |")
+    worst = max(r["rel_to_256"] for r in rows)
+    ok = all(r["finite"] for r in rows) and worst < 0.02
+    print(json.dumps({"ok": ok, "worst_rel_to_256": worst,
+                      "device": str(np.asarray(jax.devices())[0])}))
+    return 0 if ok else 1
+
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--shares", default="0.0625,0.25,0.98")
     ap.add_argument("--ops", type=int, default=8, help="device operations listed a case")
+    ap.add_argument("--serve", action="store_true", help="the forward at the serving shapes, by tile")
+    ap.add_argument("--tiles", default="16,32,64,128,256")
+    ap.add_argument("--skew", default="1", help="--serve: the routers' skews (0: an even load)")
+    ap.add_argument("--tiny", action="store_true", help="--serve at small widths, any backend")
     args = ap.parse_args(argv)
-    if jax.devices()[0].platform != "tpu":
+    if not (args.serve and args.tiny) and jax.devices()[0].platform != "tpu":
         raise SystemExit("ab_moe_held: needs a TPU")
+    if args.serve:
+        return serve(args)
     rows = []
     for share in (float(s) for s in args.shares.split(",")):
         operands, idx, cot = inputs(share)

@@ -238,13 +238,27 @@ class SortedLayout(NamedTuple):
     sizes: jax.Array  # (E,) pairs an expert got
 
 
+def buffer_rows(pairs: int, groups: int, tile: int) -> int:
+    """Rows of the sorted buffer: the most that ``groups`` groups of whole
+    ``tile``-row tiles can need for ``pairs`` pairs."""
+    return -(-pairs // tile) * tile + groups * tile
+
+
+def layer_row_tile(cfg, tokens: int, dtype=None) -> int:
+    """The row tile a dropless expert layer of ``cfg`` takes for a forward of
+    ``tokens`` tokens (`_topk_local` asks here; so does whoever reports it)."""
+    from galvatron_tpu.ops.grouped_matmul import row_tile
+
+    return row_tile(tokens, cfg.moe_top_k, cfg.moe_experts, dtype or cfg.dtype)
+
+
 def sorted_layout(expert_idx: jax.Array, num_experts: int, tile: int) -> SortedLayout:
     """Sort the pairs of ``expert_idx`` (T, k) by expert, stably, into groups
     of whole ``tile``-row tiles; an expert without a pair still owns one
     (all-padding) tile, so that its weight gradient is written."""
     flat = expert_idx.reshape(-1).astype(jnp.int32)
     pairs = flat.shape[0]
-    rows = -(-pairs // tile) * tile + num_experts * tile
+    rows = buffer_rows(pairs, num_experts, tile)
     sizes = jnp.bincount(flat, length=num_experts).astype(jnp.int32)
     order = jnp.argsort(flat, stable=True).astype(jnp.int32)  # sorted position -> pair
     tiles = jnp.maximum(-(-sizes // tile), 1)
@@ -481,6 +495,16 @@ def held_rows_share(stats) -> jax.Array:
     return jnp.mean(jnp.stack([s[2] for s in stats]))
 
 
+def live_rows_share(stats, cfg, tokens: int) -> jax.Array:
+    """Of the rows a held share's kernels multiply and move (``num_tiles * tile``), the
+    share that holds a pair, over the layers of a forward of ``tokens`` tokens at the
+    tile the layers chose themselves: what is left of a tile once an expert's rows are
+    in it. From the layers' statistics and static shapes alone."""
+    rows = buffer_rows(tokens * cfg.moe_top_k, cfg.moe_held + 1, layer_row_tile(cfg, tokens))
+    pairs = held_pairs_per_token(stats, (cfg.moe_first_held, cfg.moe_held)) * tokens
+    return pairs / (held_rows_share(stats) * rows)
+
+
 def moe_topk_block(x: jax.Array, p: Params, cfg, tile: Optional[int] = None,
                    place: Placement = LOCAL, router_x: Optional[jax.Array] = None):
     """Dropless top-k MoE MLP on (B, S, H) -> (y, router_stats). ``router_x`` (B, S,
@@ -519,12 +543,11 @@ def _topk_local(x, p, cfg, tile, over, router_x=None):
     what the router reads in place of ``x`` (`moe_topk_block`).  The
     router's input, GEMM and softmax are fp32 whatever the compute dtype: a
     bf16 logit flips a choice wherever two probabilities lie within a bf16 ulp."""
-    from galvatron_tpu.ops.grouped_matmul import TILE_M
     from galvatron_tpu.ops.moe_held import held_path
 
-    tile = tile or TILE_M
     b, s, h = x.shape
     tokens, k, e = b * s, cfg.moe_top_k, cfg.moe_experts
+    tile = tile or layer_row_tile(cfg, tokens, x.dtype)  # (trace-time, by shape)
     held_share = cfg.moe_holds_share  # trace-time: all held is the branch there always was
     xt = x.reshape(tokens, h)
     sigmoid = cfg.moe_router == "sigmoid_topk"

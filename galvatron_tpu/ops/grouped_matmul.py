@@ -2,8 +2,10 @@
 expert, one weight matrix an expert.
 
 The layout is *tile-aligned*: the caller (``models/moe.py``) places each
-expert's rows at a multiple of ``tile_m`` and pads the group to whole tiles
-with zero rows, so that every row tile belongs to exactly one expert.  The
+expert's rows at a multiple of ``tile_m`` (`row_tile`: chosen from the rows an
+expert can expect, 16 for a decode step, ``TILE_M`` for a training step) and
+pads the group to whole tiles with zero rows, so that every row tile belongs
+to exactly one expert.  The
 kernels are then plain tiled GEMMs whose weight block is chosen by a
 scalar-prefetched ``tile_group`` array: no row masks, no tile visited twice
 (a layout whose groups start anywhere re-visits one tile at every group
@@ -42,13 +44,32 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-#: rows a tile: the alignment of a group's first row in the sorted layout.
+#: the most rows a tile: the alignment of a group's first row in the sorted layout
+#: where an expert gets hundreds of rows or more (`row_tile`).
 #: 256 over 128 / 512 / 1024 on the chip (PERF.md §6, PR 28): smaller tiles pad
 #: less (147,456 rows for 131,072 pairs over 64 experts, 512 gives 163,840),
 #: at 128 the kernels' rate begins to fall
 TILE_M = 256
 #: scoped VMEM the kernels ask for (the v5e default is 16 MiB of 128)
 _VMEM_LIMIT = 64 << 20
+
+
+def row_tile(tokens: int, top_k: int, experts_scored: int, dtype) -> int:
+    """Rows a tile of the expert-sorted layout for a forward of these static shapes:
+    the largest power of two that the mean rows an expert gets, ``tokens * top_k /
+    experts_scored``, still fill, from the dtype's sublane packing (16 rows of a
+    16-bit type, 8 of float32: the smallest row block the kernels take) up to
+    ``TILE_M``.  Every expert owns at least one whole tile, and the MXU multiplies
+    and the kernels move whole tiles: a decode step's 2 or 3 rows an expert in tiles
+    of 256 are 99% padding, so the tile follows the rows; an expert that draws more
+    than a tile's rows gets consecutive tiles and its weights are still fetched
+    once.  Tile = the mean (not twice or four times it) is the chip's reading at the
+    four serving shapes (`experiments/ab_moe_held.py --serve`; PERF.md §6, PR 57);
+    a training step's hundreds of rows an expert keep ``TILE_M``."""
+    tile = 8 if jnp.dtype(dtype).itemsize >= 4 else 16
+    while 2 * tile <= TILE_M and 2 * tile * experts_scored <= tokens * top_k:
+        tile *= 2
+    return tile
 
 
 def _use_interpret() -> bool:

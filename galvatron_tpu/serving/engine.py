@@ -67,7 +67,7 @@ import numpy as np
 
 from galvatron_tpu.analysis.locks import lock_check_armed, lock_metrics, make_condition
 from galvatron_tpu.core import faults
-from galvatron_tpu.models import generation, mixers
+from galvatron_tpu.models import generation, mixers, moe
 from galvatron_tpu.models.generation import KVCache
 from galvatron_tpu.models.modeling import ModelConfig
 from galvatron_tpu.obs.tracing import tracer as _obs_tracer
@@ -109,29 +109,34 @@ def _prefill_chunk(params, cfg: ModelConfig, cache, tokens, slot, offset,
     reuses this one compiled program. Row ``last`` of the chunk's (C, V)
     logits lands in row ``slot`` of ``rows`` (``_keep_row``): after a
     prompt's final chunk that is the row its first token is drawn from.
-    Returns (rows, cache).
+    Returns (rows, cache, `_router_counters` of the chunk, as `_decode_step`'s).
     Garbage k/v written by tail padding is invisible forever: positions
     beyond a row's own query offset are causally masked, and each decode
     step overwrites its position before attending to it."""
+    stats: list = []
     logits, cache = generation.forward_with_cache(
-        params, tokens, cfg, cache, offset, slot=slot
+        params, tokens, cfg, cache, offset, slot=slot, moe_stats=stats
     )
-    return _keep_row(rows, logits[0], slot, last), cache
+    return (_keep_row(rows, logits[0], slot, last), cache,
+            _router_counters(stats, cfg, tokens.size))
 
 
-def _router_counters(stats, cfg: ModelConfig) -> Dict[str, jax.Array]:
+def _router_counters(stats, cfg: ModelConfig, tokens: int) -> Dict[str, jax.Array]:
     """A forward's expert-layer counters from its layers' router statistics, over
-    the forward's tokens (rows without a request among them): the (token, expert)
-    pairs a token puts on the experts held here, and the fullest held expert's
-    pairs over the even share. Empty for a model without dropless expert layers."""
+    the forward's ``tokens`` tokens (rows without a request among them): the (token,
+    expert) pairs a token puts on the experts held here, the fullest held expert's
+    pairs over the even share and, of a held share, the share of the rows its kernels
+    multiply that hold a pair (`moe.live_rows_share`). Empty for a model without
+    dropless expert layers."""
     if not stats:
         return {}
-    from galvatron_tpu.models import moe
-
     held = (cfg.moe_first_held, cfg.moe_held)
-    return {"moe_held_pairs_per_token": moe.held_pairs_per_token(stats, held),
-            "moe_load_imbalance": moe.load_max_over_mean(
-                stats, cfg.moe_experts, cfg.moe_top_k, held)}
+    out = {"moe_held_pairs_per_token": moe.held_pairs_per_token(stats, held),
+           "moe_load_imbalance": moe.load_max_over_mean(
+               stats, cfg.moe_experts, cfg.moe_top_k, held)}
+    if cfg.moe_holds_share:
+        out["moe_live_rows_share"] = moe.live_rows_share(stats, cfg, tokens)
+    return out
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnames=("cache",))
@@ -146,7 +151,7 @@ def _decode_step(params, cfg: ModelConfig, cache, tokens, offsets):
     logits, cache = generation.forward_with_cache(
         params, tokens[:, None], cfg, cache, offsets, moe_stats=stats
     )
-    return logits[:, 0], cache, _router_counters(stats, cfg)
+    return logits[:, 0], cache, _router_counters(stats, cfg, tokens.size)
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnames=("cache",))
@@ -166,7 +171,7 @@ def _decode_verify(params, cfg: ModelConfig, cache, tokens, offsets):
     logits, cache = generation.forward_with_cache(
         params, tokens, cfg, cache, offsets, moe_stats=stats
     )
-    return logits, cache, _router_counters(stats, cfg)
+    return logits, cache, _router_counters(stats, cfg, tokens.size)
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnames=("pool", "rows"))
@@ -577,6 +582,8 @@ class Engine:
             if "ring_positions" in self.cache_layout:  # two stacks, one a ring
                 extra["kv_ring_positions"] = self.cache_layout["ring_positions"]
             extra.update(self.step_counters())
+            if self.cfg.moe_dropless:
+                extra["moe_row_tile_prefill"] = moe.layer_row_tile(self.cfg, self.prefill_chunk)
             if "chunk_path" in self.cache_layout:
                 # the body every prompt chunk's attention takes, and how many took the
                 # kernel (over ``prefill_chunks``: 1.0 or 0.0)
@@ -663,7 +670,8 @@ class Engine:
         attention FETCHES for them by construction (a window of ``window`` queries
         a row; `generation.cache_read_positions`: live over read is the share of
         the fetched bytes that were needed) and, of a model with dropless expert
-        layers, the step's `_router_counters` of the last iteration the tracer saw
+        layers, the row tile the step's expert layers took (`moe.layer_row_tile`)
+        and the step's `_router_counters` of the last iteration the tracer saw
         (host numbers: no caller waits for the device)."""
         layout, lengths = self.cache_layout, self.slots.lengths
         slots = self.slots.active_slots() if slots is None else slots
@@ -688,6 +696,9 @@ class Engine:
                 kv_full_layers=layout["full_layers"], kv_window_layers=layout["window_layers"])
         elif read is not None:
             out[f"{layout['kind']}_read_positions"] = read
+        if self.cfg.moe_dropless:
+            # the row tile the step's expert layers compiled with (static: by shape)
+            out["moe_row_tile"] = moe.layer_row_tile(self.cfg, self.slots.num_slots * window)
         return {**out, **self._router_counters}
 
     @property
@@ -1042,6 +1053,9 @@ class Engine:
             # identical k/v (deterministic function of tokens + positions),
             # so the rewrite is idempotent.
             starts[-1] = smax - c
+        router: Dict[str, jax.Array] = {}  # the last chunk's `_router_counters`
+        if self.cfg.moe_dropless:
+            span.set(moe_row_tile=moe.layer_row_tile(self.cfg, c))
         key_block = self.cache_layout.get("chunk_key_block")  # None for K and V slots
         kernel = self.cache_layout.get("chunk_path") == "kernel"
         key_blocks = 0
@@ -1078,7 +1092,7 @@ class Engine:
                     self._rows, np.int32(slot), np.int32(n - 1),
                 )
             else:
-                self._rows, self.slots.cache = _prefill_chunk(
+                self._rows, self.slots.cache, router = _prefill_chunk(
                     self.params, self.cfg, self.slots.cache, jnp.asarray(buf),
                     np.int32(slot), np.int32(start), self._rows, np.int32(n - 1),
                 )
@@ -1115,6 +1129,9 @@ class Engine:
         else:
             self._host_rows[slot] = np.asarray(self._rows)[slot]
             self._rng[slot] = np.random.default_rng((self.seed, req.rid))
+        if _obs_tracer.enabled:
+            # (the first token's read above waited for the chunks: a few bytes more)
+            span.set(**{k: float(v) for k, v in router.items()})
         rz.advance(req, rz.DECODING, slot=slot)
         self._busy_s += time.perf_counter() - t0
 

@@ -701,6 +701,7 @@ def test_the_decode_span_carries_the_iterations_counters():
         stats = engine.stats()
         engine.drain(timeout_s=10.0)
         spans = [ev for ev in tracer.snapshot() if ev.get("name") == "decode"]
+        chunks = [ev for ev in tracer.snapshot() if ev.get("name") == "prefill"]
     finally:
         tracer.disable()
         tracer.clear()
@@ -712,6 +713,18 @@ def test_the_decode_span_carries_the_iterations_counters():
     # and `stats` repeats the last iteration's, as host numbers
     assert isinstance(stats["moe_load_imbalance"], float)
     assert 0.0 <= stats["moe_held_pairs_per_token"] <= 2.0
+    # the row tile each forward compiled with (float32's floor: 2 rows x top-2 over 8
+    # scored experts, and a chunk's 8 x 2 / 8, are under 8 rows an expert) and the share
+    # of the rows its held experts multiplied that hold a pair: the step's 2 experts'
+    # tiles of 8 rows hold at most the 2 x 2 pairs, a chunk's at most its 8 x 2
+    assert args["moe_row_tile"] == stats["moe_row_tile"] == stats["moe_row_tile_prefill"] == 8
+    held = args["moe_held_pairs_per_token"] * 2  # pairs of the step's 2 rows on the 4 held
+    assert args["moe_live_rows_share"] == pytest.approx(held / (4 * 8), rel=1e-5)  # a tile each
+    assert stats["moe_live_rows_share"] <= 4 / 32
+    assert len(chunks) == 2  # (a prompt's 12 tokens: two chunks; the span carries the last's)
+    for ev in chunks:
+        assert ev["args"]["moe_row_tile"] == 8
+        assert 0.0 <= ev["args"]["moe_live_rows_share"] <= 16 / (4 * 8)
 
 
 def test_the_engine_counts_the_positions_its_decode_kernel_fetches(monkeypatch):

@@ -2,6 +2,8 @@
 galvatron/core/tensor_parallel/transformer.py:161-295; EP groups
 site_package/megatron/core/parallel_state.py:450-478)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -370,3 +372,147 @@ def test_ep_memory_scaling_on_topology():
     saved_meas = meas[1] - meas[2]
     saved_pred = pred[1] - pred[2]
     assert saved_pred == pytest.approx(saved_meas, rel=0.25), (pred, meas)
+
+
+# -- the row tile of the expert-sorted layout follows the rows an expert holds (PR 57) ----
+# `ops/grouped_matmul.row_tile` from static shapes, and `moe.held_experts` (kernels of
+# ops/moe_held.py and the grouped GEMMs, interpreted here) at the tiles it can name.
+
+@pytest.mark.parametrize("tokens,top_k,scored,dtype,tile", [
+    (16384, 10, 512, jnp.bfloat16, 256),  # qwen3-next-80b-a3b_s4096: 320 rows an expert
+    (16384, 8, 64, jnp.bfloat16, 256),  # olmoe-1b-7b_s4096: 2,048
+    (32, 8, 128, jnp.bfloat16, 16),  # sarvam-105b, a decode step: 2 -> bf16's floor
+    (1024, 8, 128, jnp.bfloat16, 64),  # its prompt chunk: 64
+    (32, 6, 64, jnp.bfloat16, 16),  # smallthinker-21b-a3b, a decode step: 3
+    (1024, 6, 64, jnp.bfloat16, 64),  # its prompt chunk: 96 -> the power of two it still fills
+    (32, 8, 128, jnp.float32, 8),  # float32 packs 8 rows a sublane tile
+    (1, 1, 64, jnp.float16, 16),  # any 16-bit type packs 16
+    (1024, 8, 128, jnp.float32, 64),
+    (4096, 8, 64, jnp.float32, 256),  # never past TILE_M
+], ids=lambda v: getattr(v, "__name__", str(v)))
+def test_row_tile_follows_the_rows_an_expert_holds(tokens, top_k, scored, dtype, tile):
+    from galvatron_tpu.ops.grouped_matmul import TILE_M, row_tile
+
+    assert TILE_M == 256
+    assert row_tile(tokens, top_k, scored, dtype) == tile
+    cfg = small_moe_cfg(moe_router="softmax_topk", moe_top_k=top_k).replace(
+        moe_experts=scored, dtype=dtype)
+    assert moe.layer_row_tile(cfg, tokens) == tile
+
+
+TILE_T, TILE_K, TILE_E, TILE_HELD, TILE_FIRST, TILE_F = 160, 4, 16, 4, 4, 128
+#: float32: the same sums in another order; bf16: an ulp of the largest element between
+#: two tiles (the same rounding points), a few between the kernels' float32 gate x up and
+#: the plain body's bf16 one
+TILE_TOL = {jnp.float32: (2e-6, 2e-6), jnp.bfloat16: (2 ** -7, 3e-2)}
+
+
+def _tile_choices(load):
+    """(T, k) choices over 16 scored experts of which 4 .. 7 are held: the first held
+    expert draws 150 pairs (more than a tile's rows at every tile under 256), the second
+    none, the other two a few."""
+    ks = jax.random.split(jax.random.key(7), 3)
+    outside = jax.random.randint(ks[0], (TILE_T, TILE_K), TILE_FIRST + TILE_HELD, TILE_E)
+    if load == "none_held":
+        return outside
+    t = jnp.arange(TILE_T)
+    idx = outside.at[:, 0].set(jnp.where(t < 150, TILE_FIRST, outside[:, 0]))
+    idx = idx.at[:, 1].set(jnp.where(jax.random.uniform(ks[1], (TILE_T,)) < 0.2,
+                                     TILE_FIRST + 2, outside[:, 1]))
+    return idx.at[:, 2].set(jnp.where(jax.random.uniform(ks[2], (TILE_T,)) < 0.1,
+                                      TILE_FIRST + 3, outside[:, 2]))
+
+
+def _tile_operands(dtype):
+    ks = jax.random.split(jax.random.key(11), 6)
+    hidden = 256 if dtype == jnp.bfloat16 else 128  # one slab chunk of the dtype
+    x = jax.random.normal(ks[0], (TILE_T, hidden), dtype)
+    weights = jax.nn.softmax(jax.random.normal(ks[1], (TILE_T, TILE_K)), axis=-1)
+    w1, w3 = (jax.random.normal(k, (TILE_HELD, hidden, TILE_F), dtype) * hidden ** -0.5
+              for k in ks[2:4])
+    w2 = jax.random.normal(ks[4], (TILE_HELD, TILE_F, hidden), dtype) * TILE_F ** -0.5
+    cot = jax.random.normal(ks[5], (TILE_T, hidden), jnp.float32)
+    return (x, weights, w1, w3, w2), cot
+
+
+def _tile_bounded(x, weights, w1, w3, w2, idx, *, tile, act, joined):
+    lay = moe.held_layout(idx, TILE_HELD, tile, TILE_FIRST)
+    w13 = jnp.concatenate([w1, w3], axis=-1) if joined else (w1, w3)
+    return moe.held_experts(x, weights, w13, w2, lay.pair_row, lay.row_pair, lay.row_valid,
+                            lay.tile_group, lay.num_tiles, tile, act)
+
+
+def _tile_plain(x, weights, w1, w3, w2, idx, *, tile, act):
+    lay = moe.held_layout(idx, TILE_HELD, tile, TILE_FIRST)
+    rows = moe._dispatch(x, lay.row_pair // TILE_K, lay.row_valid, lay.pair_row)
+    gate = {"silu": jax.nn.silu, "relu": jax.nn.relu}[act](moe.grouped_gemm(rows, w1, lay, tile))
+    out = moe.grouped_gemm(gate * moe.grouped_gemm(rows, w3, lay, tile), w2, lay, tile)
+    return moe._combine(out, weights, lay.pair_row, lay.row_pair, lay.row_valid)
+
+
+@functools.lru_cache(maxsize=None)
+def _tile_want(dtype, act, joined):
+    """The tile-256 forward and the plain body's, once a combination."""
+    operands, _ = _tile_operands(dtype)
+    idx = _tile_choices("skewed")
+    return (_tile_bounded(*operands, idx, tile=256, act=act, joined=joined),
+            _tile_plain(*operands, idx, tile=256, act=act))
+
+
+def _within(got, want, tol):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    assert np.isfinite(got).all()
+    assert np.abs(got - want).max() <= tol * max(np.abs(want).max(), 1e-30)
+
+
+@pytest.mark.parametrize("joined", [True, False], ids=["w13", "w1_w3"])
+@pytest.mark.parametrize("act", ["silu", "relu"], ids=["swiglu", "reglu"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bf16"])
+@pytest.mark.parametrize("tile", [16, 32, 64, 128])
+def test_held_experts_at_a_small_tile_is_the_tile_256_forward(tile, dtype, act, joined):
+    idx = _tile_choices("skewed")
+    lay = moe.held_layout(idx, TILE_HELD, tile, TILE_FIRST)
+    sizes = list(np.asarray(lay.sizes))
+    assert sizes[0] == 150 > tile and sizes[1] == 0 and min(sizes[2:]) > 0
+    # the first expert's rows span consecutive tiles of its own, the empty one owns a tile
+    groups = list(np.asarray(lay.tile_group)[:int(lay.num_tiles[0])])
+    assert groups.count(0) == -(-150 // tile) and groups.count(1) == 1 and groups == sorted(groups)
+    assert lay.row_valid.shape[0] == moe.buffer_rows(TILE_T * TILE_K, TILE_HELD + 1, tile)
+    operands, _ = _tile_operands(dtype)
+    got = _tile_bounded(*operands, idx, tile=tile, act=act, joined=joined)
+    at_256, plain = _tile_want(dtype, act, joined)
+    same, other = TILE_TOL[dtype]
+    _within(got, at_256, same)
+    _within(got, plain, other)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bf16"])
+@pytest.mark.parametrize("tile", [16, 32, 64, 128])
+def test_held_experts_at_a_small_tile_with_no_pair_held_is_exact_zeros(tile, dtype):
+    idx = _tile_choices("none_held")
+    lay = moe.held_layout(idx, TILE_HELD, tile, TILE_FIRST)
+    assert int(lay.row_valid.sum()) == 0 and int(lay.num_tiles[0]) == TILE_HELD
+    operands, _ = _tile_operands(dtype)
+    got = _tile_bounded(*operands, idx, tile=tile, act="silu", joined=False)
+    assert float(jnp.abs(got.astype(jnp.float32)).max()) == 0.0
+
+
+@pytest.mark.parametrize("dtype,tile,act,joined", [
+    (jnp.float32, 8, "relu", False), (jnp.bfloat16, 16, "silu", True),
+    (jnp.bfloat16, 16, "relu", False)], ids=["float32_8_reglu_pair", "bf16_16_swiglu_w13",
+                                             "bf16_16_reglu_pair"])
+def test_held_experts_backward_at_the_dtypes_floor_tile(dtype, tile, act, joined):
+    """Training at tiny shapes takes the floor tile: the output and every gradient (x,
+    the combine weights, w1, w3, w2) against the plain body's at the same tile."""
+    idx = _tile_choices("skewed")
+    operands, cot = _tile_operands(dtype)
+
+    def run(body):
+        y, vjp = jax.vjp(lambda *t: body(*t, idx), *operands)
+        return (y,) + vjp(cot.astype(y.dtype))
+
+    got = run(functools.partial(_tile_bounded, tile=tile, act=act, joined=joined))
+    want = run(functools.partial(_tile_plain, tile=tile, act=act))
+    for name, g, w in zip(("y", "dx", "dweights", "dw1", "dw3", "dw2"), got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype, name
+        _within(g, w, TILE_TOL[dtype][1])

@@ -720,11 +720,18 @@ def test_a_held_share_names_its_path_and_its_rows_share(tmp_path, monkeypatch, h
     assert manifest["meta"]["fingerprint"]["moe_held_path"] == want
     iters = [r for r in map(json.loads, open(mpath)) if r["event"] == "train_iter"]
     assert len(iters) == 2
+    from galvatron_tpu.ops.grouped_matmul import row_tile
+
+    # a device's 32 tokens x 4 pairs over 16 scored experts are 8 rows an expert: float32's
+    # floor tile (`row_tile`, PR 57; 256 until then: one tile of pairs + 5 of the groups = 1,536
+    # rows, 4 of 6 in use whatever they hold). The buffer has 128 + 5 x 8 rows; the 4 held
+    # experts' tiles are in use whatever they hold, every held pair's row is, and an expert
+    # wastes less than a tile: (the mean over the devices keeps the three, all linear)
+    assert row_tile(32, 4, 16, jnp.float32) == 8
     for r in iters:
         assert 0.0 < r["moe_held_pairs_per_token"] < 4.0
-        # a device's 32 tokens x 4 pairs fill one tile of 256 rows, the 5 groups own one
-        # each, and the 4 held experts' tiles are in use whatever they hold
-        assert 0.66 < r["moe_held_rows_share"] <= 1.0
+        pairs = r["moe_held_pairs_per_token"] * 32
+        assert max(4 * 8, pairs) / 168 - 1e-6 <= r["moe_held_rows_share"] <= (pairs + 4 * 8) / 168
 
 
 def test_train_iter_records_lost_the_derived_wait(traced_run):

@@ -637,7 +637,18 @@ def test_runtime_steps_and_hands_up_the_held_pairs(chunks):
     assert set(state["moe_stats"]) == {"moe_aux_loss", "moe_load_max_over_mean",
                                        "moe_held_pairs_per_token", "moe_held_rows_share"}
     assert 0.2 < float(state["moe_stats"]["moe_held_pairs_per_token"]) < 3.0
-    assert 0.0 < float(state["moe_stats"]["moe_held_rows_share"]) <= 1.0
+    # a forward's 4 / chunks x 64 tokens x top-4 over 16 scored experts are 64 / 32 rows an
+    # expert, the tile `row_tile` takes (PR 57; 256 until then); of the buffer's pairs + 5
+    # tiles, the 4 held experts' tiles are in use whatever they hold, every held pair's row
+    # is, and an expert wastes less than a tile (all linear: the microbatches' mean keeps them)
+    from galvatron_tpu.ops.grouped_matmul import row_tile
+
+    tokens, tile = 4 // chunks * 64, 64 // chunks
+    assert row_tile(tokens, 4, 16, jnp.float32) == tile
+    pairs = float(state["moe_stats"]["moe_held_pairs_per_token"]) * tokens
+    rows = tokens * 4 + 5 * tile
+    share = float(state["moe_stats"]["moe_held_rows_share"])
+    assert max(4 * tile, pairs) / rows - 1e-6 <= share <= (pairs + 4 * tile) / rows
     # a model that holds all its experts keeps the two statistics it had
     whole = _runtime(small_cfg(max_seq_len=64, moe_share=(0, 1)), hp)
     assert set(whole.init_state(jax.random.key(0))["moe_stats"]) == {

@@ -752,7 +752,9 @@ def _expert_layer_text(name):
     x = jax.ShapeDtypeStruct((2, 16, 256), jnp.bfloat16)
 
     def loss(x_, p_):
-        y, stats = moe.moe_topk_block(x_, p_, cfg)
+        # (the parent's tile, handed in: since PR 57 the block names its own from the
+        # shape, 16 rows for these 32 tokens, and an explicit one still wins)
+        y, stats = moe.moe_topk_block(x_, p_, cfg, tile=256)
         return jnp.sum(y.astype(jnp.float32)) + sum(jnp.sum(s) for s in stats)
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1), allow_int=True)).lower(x, p).as_text()

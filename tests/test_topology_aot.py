@@ -426,21 +426,29 @@ def test_qwen3_next_mixer_compiles_at_published_widths(one_chip, real_mosaic):
 
 
 @pytest.mark.parametrize("tokens", [32, 1024])
-def test_sarvam_held_experts_serve_unjoined_at_published_widths(one_chip, real_mosaic, tokens):
-    """The expert layer of `sarvam-105b_serve_long_above_knee` (a decode step's 32
-    tokens, a prefill chunk's 1,024; top-8 over 128 experts of width 2048, rank 0 of 4
-    holding 32, weights HELD in bf16) as the chip's compiler sees it: the bounded
-    path's kernels, gate and up a grouped GEMM each, and no pass that joins or copies
-    a stack of the experts' weights (1 GB a layer: 16 of a 42 ms decode step while
-    the two were joined every step, PERF.md section 6, PR 51)."""
+@pytest.mark.parametrize("model,scored,held,top_k,hidden,width", [
+    ("sarvam-105b", 128, 32, 8, 4096, 2048), ("smallthinker-21b-a3b", 64, 16, 6, 2560, 768)])
+def test_held_experts_serve_unjoined_at_published_widths(one_chip, real_mosaic, model, scored, held,
+                                                         top_k, hidden, width, tokens):
+    """The expert layer of `sarvam-105b_serve_long_above_knee` (top-8 over 128 experts of
+    width 2048, SwiGLU) and of `smallthinker-21b-a3b_serve_long_above_knee` (top-6 over 64
+    of width 768, ReGLU, routed from the attention block's input), a decode step's 32
+    tokens and a prefill chunk's 1,024, rank 0 of 4 holding a quarter, weights HELD in
+    bf16, as the chip's compiler sees it: the bounded path's kernels, gate and up a
+    grouped GEMM each, no pass that joins or copies a stack of the experts' weights
+    (1 GB a layer: 16 of a 42 ms decode step while the two were joined every step,
+    PERF.md section 6, PR 51), the row buffer at the tile `row_tile` names for the shape
+    (PR 57: 784 rows a sarvam decode step, 8,704 at tiles of 256) and every grouped GEMM
+    reading its rows and its weights where their producer left them."""
     import re
 
     from galvatron_tpu.models import modeling, moe
     from galvatron_tpu.models.modeling import PRESETS
+    from galvatron_tpu.ops.grouped_matmul import TILE_M, row_tile
 
-    cfg = PRESETS["sarvam-105b"].replace(moe_share=(0, 4), param_dtype=jnp.bfloat16,
-                                         dtype=jnp.bfloat16)
-    assert (cfg.hidden_size, cfg.expert_ffn, cfg.moe_held, cfg.moe_top_k) == (4096, 2048, 32, 8)
+    cfg = PRESETS[model].replace(moe_share=(0, 4), param_dtype=jnp.bfloat16, dtype=jnp.bfloat16)
+    assert (cfg.moe_experts, cfg.moe_held, cfg.moe_top_k, cfg.hidden_size, cfg.expert_ffn) == (
+        scored, held, top_k, hidden, width)
     assert moe.held_path_counts(cfg.replace(num_layers=4))["worst_case"] == 0
     shapes = jax.eval_shape(
         lambda k: {"mlp": moe.init_moe_params(k, cfg),
@@ -448,21 +456,46 @@ def test_sarvam_held_experts_serve_unjoined_at_published_widths(one_chip, real_m
         jax.random.key(0))
     assert shapes["mlp"]["w1"].dtype == jnp.bfloat16 and shapes["mlp"]["router"]["w"].dtype == jnp.float32
     p = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), shapes)
-    x = jax.ShapeDtypeStruct((1, tokens, 4096), jnp.bfloat16, sharding=one_chip)
+    x = jax.ShapeDtypeStruct((1, tokens, hidden), jnp.bfloat16, sharding=one_chip)
 
     def layer(x_, p_):
         with jax.named_scope("layer_1"):
-            return modeling.mlp_residual(x_, p_, cfg)[0]
+            router_x = x_ if cfg.moe_router_input == "attn" else None
+            return modeling.mlp_residual(x_, p_, cfg, router_x=router_x)[0]
 
     compiled = jax.jit(layer).lower(x, p).compile()
     text = compiled.as_text()
     kernels = sorted(n.split(".")[0] for n, _ in _entry_work(text) if n.startswith("moe_"))
     assert kernels == sorted(["moe_held_rows", "moe_gmm", "moe_gmm", "moe_held_swiglu", "moe_gmm",
                               "moe_held_pairs"]), kernels
-    # no operation writes a stack of the held experts' weights (32 x 4096 x 2048 or its double)
-    entry = "\n".join(_entry_lines(text))
-    assert not re.search(r"= bf16\[32,4096,(2048|4096)\]\S* (fusion|copy|concatenate)\(", entry)
+    # no operation writes a stack of the held experts' weights (held x hidden x width or its double)
+    lines = _entry_lines(text)
+    entry = "\n".join(lines)
+    assert not re.search(rf"= bf16\[{held},{hidden},({width}|{2 * width})\]\S* (fusion|copy|concatenate)\(",
+                         entry)
     assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * 2**30
+    # the buffer's rows under the rule: a decode step's 2 / 3 rows an expert take bf16's
+    # floor, a chunk's 64 / 96 the power of two they fill
+    tile = row_tile(tokens, top_k, scored, jnp.bfloat16)
+    assert tile == {32: 16, 1024: 64}[tokens]
+    rows = moe.buffer_rows(tokens * top_k, held + 1, tile)
+    assert rows == {("sarvam-105b", 32): 784, ("sarvam-105b", 1024): 10304,
+                    ("smallthinker-21b-a3b", 32): 464, ("smallthinker-21b-a3b", 1024): 7232}[model, tokens]
+    at_256 = moe.buffer_rows(tokens * top_k, held + 1, TILE_M)
+    assert f"bf16[{rows},{hidden}]" in entry and f"[{at_256}," not in entry
+    # every grouped GEMM reads the rows a `moe_*` kernel wrote and a parameter, in place:
+    # no copy into another layout, no fusion between (a `copy-start` / `copy-done` pair is
+    # the compiler's prefetch of the same layout into another memory, not a re-layout)
+    made = {m.group(1): m.group(2) for m in (
+        re.match(r"\s*(?:ROOT )?%([\w.\-]+) = .*? copy-(?:start|done)\(%([\w.\-]+)", ln) for ln in lines) if m}
+    gemms = [re.search(r"custom-call\(([^)]*)\)", ln).group(1).replace("%", "").split(", ")
+             for ln in lines if re.match(r"\s*%moe_gmm[\w.]* = ", ln)]
+    assert len(gemms) == 3
+    for _, _, lhs, rhs in gemms:
+        while lhs in made:
+            lhs = made[lhs]
+        assert lhs.split(".")[0] in ("moe_held_rows", "moe_held_swiglu"), lhs
+        assert rhs.startswith("p_") and re.search(r"w[123]_", rhs), rhs
 
 
 def _lowered_serving_program(cfg, name, one_chip, **context):
