@@ -1,6 +1,7 @@
 #!/usr/bin/env python3
-"""What a latent-attention serving cell's ``correct`` reads when one part of the
-program is computed below the precision its configuration states (PR 51).
+"""What a serving cell's ``correct`` reads when one part of the program is computed
+below the precision its configuration states, or a row's state is left unreset (PR 51,
+54, 58).
 
     python experiments/serve_precision_controls.py --workload sarvam-105b_serve_long_above_knee \
         --seeds 2147488001,2147488002 [--seconds 30] [--modes sound,router_bf16,cache_e4m3,kv_e4m3,int8]
@@ -17,6 +18,12 @@ own ``run_serve_cell`` at the cell's own load, the fault planted underneath it:
 - ``kv_e4m3``: every key and value rounded the same way before it is written to a
   K/V slot cache (``models/generation._project_qkv_at``; a windowed stack's two
   stacks alike): PR 54's cell;
+- ``state_e4m3``: a layer's per-row state (the gated short convolution's last inputs)
+  rounded the same way before it is written (``models/shortconv.stored``): a state kept
+  below bfloat16; PR 58's cell;
+- ``state_stale``: a forward that starts a request at position 0 reads the state its
+  slot holds (the previous request's, or what idle decode steps left) in place of zeros
+  (``models/shortconv.fresh``): a state not reset at admission;
 - ``int8``: the engine's own per-channel int8 weights (``--serve_quant int8``), as
   ``benchmark/control.py`` reads them.
 
@@ -49,10 +56,11 @@ def planted(mode: str):
     import jax
     import jax.numpy as jnp
 
-    from galvatron_tpu.models import generation, mla, moe
+    from galvatron_tpu.models import generation, mla, moe, shortconv
 
     real_scores, real_project = moe.router_scores, mla.project
     real_qkv = generation._project_qkv_at
+    real_stored, real_fresh = shortconv.stored, shortconv.fresh
 
     def e4m3(t):
         bits = jax.lax.bitcast_convert_type(t.astype(jnp.bfloat16), jnp.uint16)
@@ -79,11 +87,16 @@ def planted(mode: str):
         mla.project = project_e4m3
     elif mode == "kv_e4m3":
         generation._project_qkv_at = qkv_e4m3
+    elif mode == "state_e4m3":
+        shortconv.stored = lambda new, dtype: e4m3(new).astype(dtype)
+    elif mode == "state_stale":
+        shortconv.fresh = lambda prev, offsets: prev
     try:
         yield
     finally:
         moe.router_scores, mla.project = real_scores, real_project
         generation._project_qkv_at = real_qkv
+        shortconv.stored, shortconv.fresh = real_stored, real_fresh
         jax.clear_caches()
 
 

@@ -11,6 +11,27 @@ dynamically growing torch tensors): the cache is a static-shape pytree and
 the decode loop is a single ``lax.scan`` inside one ``jit`` — XLA sees a
 fixed-shape program, so the whole generation runs on-device without host
 round-trips per token.
+
+What a cache holds goes by what a LAYER keeps, one rule for every stack:
+
+- whole slots: keys and values of every position of a row (attention);
+- a ring: keys and values of the last ``window`` positions (a sliding-window layer);
+- a state: a fixed-size array a row, no positions (a kind of ``mixers.MIXERS`` with
+  ``state``: the gated short convolution's last inputs);
+- or the kind's own position-indexed cache, every layer alike (latent attention).
+
+A stack whose layers all keep whole slots has a `KVCache`; one that mixes whole
+slots with rings or states has `SlotStacks`, a stack of arrays for each of the three,
+over THOSE layers only (`layer_stacks` maps a layer to its place). A state differs
+from positions in four ways, and the cached forwards and the engine hold to each:
+
+1. it is reset by READING: a forward that starts at position 0 reads zeros whatever
+   the row holds (positions are simply overwritten before a query can see them);
+2. it is written as of the forward's last REAL row (``last``): pad rows of a padded
+   prompt chunk must not reach it (a pad position's keys are harmless, masked);
+3. it cannot be wound back: no speculation (a rejected draft has advanced it);
+4. it cannot run positions twice: a prompt chunk is never slid left, so the slots
+   are a whole number of chunks (`serving.Engine`).
 """
 
 from __future__ import annotations
@@ -33,19 +54,22 @@ class KVCache(NamedTuple):
     own (``mixers.cache_kind``) has that kind's NamedTuple instead (latent
     attention: ``models/mla.LatentCache``, one array (L, B, max_len, r + dr));
     every array of either is stacked (L, B, max_len, ...). A stack with
-    sliding-window layers has a `WindowKVCache`."""
+    sliding-window layers or with layers that keep a state has `SlotStacks`."""
 
     k: jax.Array
     v: jax.Array
 
 
-class WindowKVCache(NamedTuple):
-    """The cache of a stack with sliding-window layers (``cfg.windowed``): TWO
-    stacks in one cache. The full layers' keys and values whole, ``k`` / ``v``
-    (L_full, B, kv_heads, max_len, head_dim), position p at p; the window layers'
+class SlotStacks(NamedTuple):
+    """The cache of a stack whose layers do not all keep whole slots (`stacked`):
+    stacks by what a layer keeps, in one cache, each over its own layers only and None
+    where the stack has no such layer. The full layers' keys and values whole, ``k`` /
+    ``v`` (L_full, B, kv_heads, max_len, head_dim), position p at p; the window layers'
     in a RING, ``wk`` / ``wv`` (L_win, B, kv_heads, R, head_dim), position p at
     ``p mod R`` with ``R = window + the most positions one forward writes a row``
-    (`ring_positions`). HEAD-major: a key/value head's positions are a matrix
+    (`ring_positions`); the state layers' STATE, ``state`` (L_state, B, ...), whose
+    trailing shape is the kind's (``init_state`` of its module: the module docstring
+    has what a state asks that positions do not). HEAD-major: a key/value head's positions are a matrix
     (positions, head_dim), which the chip's matrix unit takes as it lies; from
     (positions, kv_heads, head_dim) the compiler copied every stack whole, every
     step, into that order (compiled for a described v5e at the cell's size: 7.76
@@ -54,12 +78,15 @@ class WindowKVCache(NamedTuple):
     previous request is never seen; write-then-attend stays: the newest write of a
     forward of s positions overwrites position ``p + s - 1 - R``, which lies before
     the oldest key the forward's first query sees (``p - window + 1``). A chunk's
-    write never crosses the ring's end (`write_ring`)."""
+    write never crosses the ring's end (`write_ring`). A head of 64 values (half a lane
+    tile) costs no padding in this order: compiled for a described v5e the chip holds
+    (5, 32, 8, 16384, 64) of bf16 in 2.684 GB, its plain size."""
 
     k: jax.Array
     v: jax.Array
-    wk: jax.Array
-    wv: jax.Array
+    wk: Optional[jax.Array] = None
+    wv: Optional[jax.Array] = None
+    state: Optional[jax.Array] = None
 
 
 def ring_positions(cfg: ModelConfig, max_len: int, tokens: int = 1) -> int:
@@ -74,20 +101,45 @@ def ring_positions(cfg: ModelConfig, max_len: int, tokens: int = 1) -> int:
     return min(int(max_len), ring)
 
 
+STACKS = ("full", "window", "state")
+
+
+def stacked(cfg: ModelConfig) -> bool:
+    """The stack's cache is `SlotStacks`: some layer keeps a ring or a state."""
+    return mixers.cache_kind(cfg) is None and (cfg.windowed or bool(mixers.state_kinds(cfg)))
+
+
 def layer_stacks(cfg: ModelConfig):
-    """Of a windowed stack, for each layer: (it has a window, its index within its
-    stack of the `WindowKVCache`)."""
-    out, counts = [], [0, 0]
-    for windowed in cfg.window_layers:
-        out.append((windowed, counts[windowed]))
-        counts[windowed] += 1
+    """Of a `stacked` stack, for each layer: (the stack of `SlotStacks` it keeps its
+    entries in, ``"full"`` | ``"window"`` | ``"state"``; its index within that stack)."""
+    states = mixers.state_kinds(cfg)
+    out, counts = [], dict.fromkeys(STACKS, 0)
+    for kind, windowed in zip(cfg.kinds, cfg.window_layers):
+        stack = "state" if kind in states else "window" if windowed else "full"
+        out.append((stack, counts[stack]))
+        counts[stack] += 1
     return out
+
+
+def _state_module(cfg: ModelConfig):
+    """The module of the kind whose layers keep a state in ``cfg``'s stack (the
+    registry has one such kind; a second in one stack would need a stack each)."""
+    kind, = mixers.state_kinds(cfg)
+    return mixers.module(kind)
+
+
+def stack_layers(cfg: ModelConfig) -> dict:
+    """How many layers of a `stacked` stack keep each of `STACKS`."""
+    counts = dict.fromkeys(STACKS, 0)
+    for stack, _ in layer_stacks(cfg):
+        counts[stack] += 1
+    return counts
 
 
 def init_kv_cache(cfg: ModelConfig, batch_size: int, max_len: int, tokens: int = 1):
     """The cache ``cfg``'s layers say: K and V for attention, the kind's own otherwise;
-    two stacks, one a ring sized for forwards of up to ``tokens`` positions a row
-    (`ring_positions`), where some layers have a window."""
+    `SlotStacks` where some layers have a window (their ring sized for forwards of up
+    to ``tokens`` positions a row: `ring_positions`) or keep a state."""
     for limit in mixers.limits(cfg):
         # every cache of the serving stack (slots, paged pool, generate) starts here
         if limit.what == "kv_cache":
@@ -95,12 +147,18 @@ def init_kv_cache(cfg: ModelConfig, batch_size: int, max_len: int, tokens: int =
     kind = mixers.cache_kind(cfg)
     if kind is not None:
         return mixers.module(kind).init_cache(cfg, cfg.num_layers, batch_size, max_len)
-    if cfg.windowed:
-        win = sum(cfg.window_layers)
-        full = (cfg.num_layers - win, batch_size, cfg.kv_heads, max_len, cfg.head_dim)
-        ring = (win, batch_size, cfg.kv_heads, ring_positions(cfg, max_len, tokens), cfg.head_dim)
-        return WindowKVCache(jnp.zeros(full, cfg.dtype), jnp.zeros(full, cfg.dtype),
-                             jnp.zeros(ring, cfg.dtype), jnp.zeros(ring, cfg.dtype))
+    if stacked(cfg):
+        layers = stack_layers(cfg)
+        full = (layers["full"], batch_size, cfg.kv_heads, max_len, cfg.head_dim)
+        cache = SlotStacks(jnp.zeros(full, cfg.dtype), jnp.zeros(full, cfg.dtype))
+        if layers["window"]:
+            ring = (layers["window"], batch_size, cfg.kv_heads,
+                    ring_positions(cfg, max_len, tokens), cfg.head_dim)
+            cache = cache._replace(wk=jnp.zeros(ring, cfg.dtype), wv=jnp.zeros(ring, cfg.dtype))
+        if layers["state"]:
+            cache = cache._replace(
+                state=_state_module(cfg).init_state(cfg, layers["state"], batch_size))
+        return cache
     shape = (cfg.num_layers, batch_size, max_len, cfg.kv_heads, cfg.head_dim)
     return KVCache(jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))
 
@@ -109,25 +167,35 @@ def cache_layout(cfg: ModelConfig, max_len: Optional[int] = None, tokens: int = 
     """What the cache ``init_kv_cache`` makes costs: ``{"kind": "kv" or the kind's
     word ("latent"), "bytes_per_position": over all layers}`` and, once ``max_len``
     is known (an engine's slots), ``bytes_per_slot``. No one number holds for a
-    position of a windowed stack, so it has no ``bytes_per_position``: it says
+    position of a `stacked` stack, so it has no ``bytes_per_position``: it says
     ``bytes_per_position_per_layer`` and tells its stacks apart (``full_layers`` /
-    ``window_layers``, ``window``); with ``max_len`` and ``tokens`` (the engine's prompt
-    chunk) also ``ring_positions``, and its ``bytes_per_slot`` is the full layers over
-    ``max_len`` plus the window layers over the ring."""
+    ``window_layers``, ``window``; with layers that keep a state ``state_layers`` and
+    ``state_bytes_per_row``, ONE layer's); with ``max_len`` and ``tokens`` (the engine's
+    prompt chunk) also ``ring_positions`` where it has a ring, and its ``bytes_per_slot``
+    is the full layers over ``max_len`` plus the window layers over the ring plus the
+    state layers' rows."""
     kind = mixers.cache_kind(cfg)
     if kind is not None:
         word, per_layer = mixers.MIXERS[kind].cache, mixers.module(kind).cache_bytes_per_position(cfg)
     else:
         word, per_layer = "kv", 2 * cfg.kv_heads * cfg.head_dim * jnp.dtype(cfg.dtype).itemsize
-    if kind is None and cfg.windowed:
-        win = sum(cfg.window_layers)
+    if stacked(cfg):
+        layers = stack_layers(cfg)
+        win = layers["window"]
         out = {"kind": word, "bytes_per_position_per_layer": per_layer,
-               "full_layers": cfg.num_layers - win, "window_layers": win,
-               "window": cfg.sliding_window_size}
+               "full_layers": layers["full"], "window_layers": win,
+               "window": cfg.sliding_window_size if win else 0}
+        state_bytes = 0
+        if layers["state"]:
+            per_row = _state_module(cfg).state_bytes_per_row(cfg)
+            out.update(state_layers=layers["state"], state_bytes_per_row=per_row)
+            state_bytes = layers["state"] * per_row
         if max_len is not None:
-            ring = ring_positions(cfg, max_len, tokens)
-            out.update(ring_positions=ring,
-                       bytes_per_slot=per_layer * (out["full_layers"] * max_len + win * ring))
+            positions = layers["full"] * max_len
+            if win:
+                out["ring_positions"] = ring_positions(cfg, max_len, tokens)
+                positions += win * out["ring_positions"]
+            out["bytes_per_slot"] = per_layer * positions + state_bytes
         return out
     out = {"kind": word, "bytes_per_position": cfg.num_layers * per_layer}
     if max_len is not None:
@@ -141,7 +209,8 @@ def cache_read_positions(cfg: ModelConfig, lengths, rows: int, positions: int, w
     fetches by construction from a cache of ``rows`` slots x ``positions``, given the
     positions the windows of the rows in use attend (``lengths``): the kind's own
     answer (host arithmetic, which body its attention takes included); of a
-    windowed stack ``{"full": ..., "window": ...}``, a layer of each stack (on the plain
+    `stacked` stack ``{"full": ..., "window": ...}``, a layer of each stack (0 for a
+    stack it lacks; a state layer reads no position; on the plain
     body every slot's capacity, the ring's ``ring`` positions of a window layer,
     whatever the lengths; through the kernel the rows' lengths rounded up to the key
     block and no more than the slot or ring, `kv_decode.decode_path` asked of each
@@ -149,7 +218,7 @@ def cache_read_positions(cfg: ModelConfig, lengths, rows: int, positions: int, w
     decode attention reads every slot's capacity too (ROADMAP A3 ii)."""
     kind = mixers.cache_kind(cfg)
     if kind is None:
-        if not cfg.windowed:
+        if not stacked(cfg):
             return None
 
         def read(places):
@@ -158,7 +227,7 @@ def cache_read_positions(cfg: ModelConfig, lengths, rows: int, positions: int, w
             return (kv_decode.read_positions(lengths, rows, places) if path == "kernel"
                     else rows * places)
 
-        return {"full": read(positions), "window": read(ring or positions)}
+        return {"full": read(positions), "window": read(ring or positions) if cfg.windowed else 0}
     return mixers.module(kind).cache_read_positions(cfg, lengths, rows, positions, window)
 
 
@@ -283,7 +352,7 @@ def _project_qkv_at(x, p, cfg: ModelConfig, cos_sin):
     return q, k, v
 
 
-# -- a stack with sliding-window layers: two stacks, one a ring ---------------------
+# -- a stack whose layers keep whole slots, a ring or a state: `SlotStacks` ----------
 
 #: keys a step of a prompt chunk's attention takes in a windowed stack
 KEY_BLOCK = 1024
@@ -381,9 +450,9 @@ def _attend_chunk(qg, ks, vs, layer: int, slot, q_pos, key_positions, blocks, bl
     return jnp.transpose(o, (0, 3, 1, 2, 4)).astype(qg.dtype)
 
 
-def _windowed_attention(x, p, cfg: ModelConfig, cache: WindowKVCache, windowed: bool, index: int,
+def _windowed_attention(x, p, cfg: ModelConfig, cache: SlotStacks, windowed: bool, index: int,
                         starts, slot, offsets, cos_sin):
-    """A layer's attention of a windowed stack over the `WindowKVCache` -> (y, cache):
+    """An attention layer of a `stacked` stack over `SlotStacks` -> (y, cache):
     ``windowed`` says which stack the layer's keys and values live in (the ring, or
     whole rows), ``index`` where in it; ``cfg`` is the layer's view. Both forms fetch
     a key block only if the row holds a position in it: a prompt chunk (``slot``)
@@ -455,7 +524,7 @@ def _mlp_at(x, p, cfg: ModelConfig, moe_stats: Optional[list], router_x=None):
 
 
 def forward_with_cache(params: Params, tokens, cfg: ModelConfig, cache, offsets, slot=None,
-                       moe_stats: Optional[list] = None):
+                       moe_stats: Optional[list] = None, last=None):
     """Run ``tokens`` (B, s) through the model at absolute positions
     ``offsets``, writing the new keys and values (a latent-attention stack: the
     new latents) into the cache and attending over it. Returns (logits,
@@ -476,19 +545,23 @@ def forward_with_cache(params: Params, tokens, cfg: ModelConfig, cache, offsets,
     The stacked cache (every array (L, B, Smax, ...)) is carried whole through
     the layers and written in place (``write_layer``), write-then-attend; a
     window that would cross the row's end is CLAMPED back by the update, so
-    callers keep ``offset + s <= Smax``. A stack with sliding-window layers
-    (``cfg.windowed``) carries a `WindowKVCache` and maps each layer to its stack
-    (`layer_stacks`); ``s`` is then at most what the ring was sized for
-    (``init_kv_cache``'s ``tokens``)."""
+    callers keep ``offset + s <= Smax``. A `stacked` stack (sliding-window layers,
+    layers that keep a state) carries `SlotStacks` and maps each layer to its stack
+    (`layer_stacks`); ``s`` is then at most what a ring was sized for
+    (``init_kv_cache``'s ``tokens``). ``last`` (traced; None: ``s - 1``) is the
+    forward's last REAL row, as of which a state layer's state is written: a prompt
+    chunk padded at its tail hands over where its tokens end; a state layer takes no
+    window of ``s`` > 1 at per-row offsets (speculation: `mixers.limits`)."""
     s = tokens.shape[1]
-    smax = cache[0].shape[3 if isinstance(cache, WindowKVCache) else 2]
+    last = s - 1 if last is None else last
+    smax = cache[0].shape[3 if isinstance(cache, SlotStacks) else 2]
     cos_sin = _rope_at(cfg, smax, offsets, s)
     bias = _alibi_bias(cfg, smax, offsets, s)
     x = _embed_at(params, tokens, cfg, offsets)
     starts = _window_starts(offsets, slot, tokens.shape[0])
     kind = mixers.cache_kind(cfg)
-    ringed = kind is None and cfg.windowed
-    if ringed:
+    by_stack = stacked(cfg)
+    if by_stack:
         stacks = layer_stacks(cfg)
     elif kind is None:
         ks, vs = cache
@@ -498,9 +571,18 @@ def forward_with_cache(params: Params, tokens, cfg: ModelConfig, cache, offsets,
             router_x = (modeling.norm(x, p["attn_norm"], cfg)
                         if cfg.moe_router_input == "attn" and cfg.moe_dropless else None)
             with jax.named_scope("attn"):
-                if ringed:
+                if by_stack and stacks[i][0] == "state":
+                    # (built from the training layer's pieces: the kind's own gates,
+                    # conv and projections, the same residual)
+                    y, state = mixers.module(cfg.kinds[i]).cached_block(
+                        modeling.norm(x, p["attn_norm"], cfg), p[cfg.kinds[i]], cfg,
+                        cache.state, stacks[i][1], slot, offsets, last)
+                    cache = cache._replace(state=state)
+                    x = modeling.residual_add(x, y, cfg)
+                elif by_stack:
                     y, cache = _windowed_attention(
-                        x, p, cfg.layer_view(i), cache, *stacks[i], starts, slot, offsets, cos_sin)
+                        x, p, cfg.layer_view(i), cache, stacks[i][0] == "window",
+                        stacks[i][1], starts, slot, offsets, cos_sin)
                     x = x + y
                 elif kind is not None:
                     y, cache = mixers.module(kind).cached_block(
@@ -519,7 +601,7 @@ def forward_with_cache(params: Params, tokens, cfg: ModelConfig, cache, offsets,
                     with jax.named_scope("out_proj"):
                         x = x + modeling.attn_output(o, p["attn"], cfg, x.dtype)
             x = x + _mlp_at(x, p, cfg, moe_stats, router_x)
-    return _head(x, params, cfg), (cache if kind is not None or ringed else KVCache(ks, vs))
+    return _head(x, params, cfg), (cache if kind is not None or by_stack else KVCache(ks, vs))
 
 
 # ---------------------------------------------------------------------------

@@ -22,14 +22,22 @@ only where a configuration has such layers: `module`) exposes
   positions)`` (a prompt chunk's: which body its attention takes, the keys a block
   of it fetches) and ``cached_block(x,
   p, cfg, cache, layer, starts, slot, offsets, cos_sin)`` -> ``(y, cache)``, what
-  ``models/generation.forward_with_cache`` runs in place of attention over K and V.
+  ``models/generation.forward_with_cache`` runs in place of attention over K and V;
+- where the row says ``state`` (the kind keeps a per-row STATE in the cached forwards,
+  nothing indexed by position: no ``kv_cache`` under ``lacks`` either):
+  ``init_state(cfg, layers, rows)`` -> one array stacked ``(layers, rows, ...)``,
+  ``state_bytes_per_row(cfg)`` (one layer's) and ``cached_block(x, p, cfg, state,
+  layer, slot, offsets, last)`` -> ``(y, state)``: the layer over the state stack of
+  ``models/generation.SlotStacks``, beside the attention layers' keys and values. A
+  state is read ZERO by a forward that starts at position 0 and written as of the
+  forward's last REAL row (``last``); `limits` says what a stack with one cannot do.
 
 The row holds the kind's words and, under ``lacks``, what it does not implement
 with the clause that says why. `limits` turns the rows of a configuration's
 kinds, its interleaving and its expert path into `Limit`s; `build_runtime`
 raises the first one a plan breaks, `plan_check` reports each, the search
 leaves each out and names its tag, `init_kv_cache` refuses a stack with one on
-the cache (and `serving.Engine` one on the paged backend). Plain data and functions of the configuration's fields: nothing here
+the cache (and `serving.Engine` one on the paged backend or on speculation). Plain data and functions of the configuration's fields: nothing here
 imports jax or a kernel, or anything of ``parallel/``, ``search/``, ``analysis/``.
 """
 
@@ -55,6 +63,7 @@ class Mixer:
     lacks: Mapping[str, str]
     kernels: Tuple[str, ...] = ()  # the bodies `path_counts` reports, "<kind>_<kernel>_path"
     cache: str = ""  # "latent": what the kind's own cache holds a position ("": it has none)
+    state: str = ""  # "conv": what the kind's state holds a row, no positions ("": it has none)
 
 
 MIXERS: Dict[str, Mixer] = {entry.kind: entry for entry in (
@@ -88,6 +97,16 @@ MIXERS: Dict[str, Mixer] = {entry.kind: entry for entry in (
             "cp": "the latent and its rotary key are not passed between sequence shards",
             "pack_sequences": "the latent attention's mask does not stop at segment boundaries",
         }),
+    Mixer(
+        kind="shortconv", module="galvatron_tpu.models.shortconv",
+        layer="gated short-convolution layer", mixer="the gated short-convolution mixer",
+        tag="short_conv_layers", kernels=("conv",), state="conv",
+        lacks={
+            "tp": ("the mixer's three gates are slices of one projection's columns and the "
+                   "conv's channels carry no tp sharding"),
+            "cp": "the conv's state is not passed between sequence shards",
+            "pack_sequences": "the conv does not reset its state at segment boundaries",
+        }),
 )}
 
 
@@ -103,6 +122,13 @@ def cache_kind(cfg) -> Optional[str]:
     kinds = set(getattr(cfg, "kinds", ()))
     own = [k for k in kinds if k in MIXERS and MIXERS[k].cache]
     return own[0] if own else None
+
+
+def state_kinds(cfg) -> Tuple[str, ...]:
+    """The kinds of ``cfg``'s stack that keep a per-row state in the cached forwards
+    (``Mixer.state``), in the registry's order."""
+    kinds = set(getattr(cfg, "kinds", ()))
+    return tuple(k for k, e in MIXERS.items() if e.state and k in kinds)
 
 
 def has_mixer_layers(cfg) -> bool:
@@ -139,7 +165,8 @@ class Limit:
 
     # "tp" | "cp" | "ep": a layer's degree > 1; "pp" | "pack_sequences" | "fp16" |
     # "attn_impl" (another attention path than XLA's): the run's; "kv_cache":
-    # generation's; "paged_kv": the paged serving backend's
+    # generation's; "paged_kv": the paged serving backend's; "spec_decode": the
+    # speculative engine's (``spec_decode_k`` > 0)
     what: str
     layers: Tuple[int, ...]  # the strategy indices it is reported on: a kind's, or all
     refusal: str  # build_runtime's (init_kv_cache's) sentence; "{at}": the layers that break it
@@ -168,7 +195,7 @@ class Limit:
 def _window_limits(cfg, enc: int, every: Tuple[int, ...]) -> List[Limit]:
     """What a stack with sliding-window layers does not implement: the window is a
     mask of XLA's attention (``modeling.attention_xla``) and a ring of the slot
-    cache (``generation.WindowKVCache``), and of nothing else."""
+    cache (``generation.SlotStacks``), and of nothing else."""
     at = tuple(enc + i for i, w in enumerate(cfg.window_layers) if w)
     layers = f"sliding-window layers (window {cfg.sliding_window_size}; layers {at})"
     out = [
@@ -250,6 +277,22 @@ def limits(cfg) -> List[Limit]:
             refusal=("generation is not implemented for a stack that interleaves cache layouts "
                      f"(this model: {dict(collections.Counter(kinds))}): the slot cache is one "
                      "kind's; train-only")))
+    for kind in state_kinds(cfg):
+        # what a per-row state cannot do that positions can (models/generation.py)
+        e = MIXERS[kind]
+        at = tuple(enc + i for i, k in enumerate(kinds) if k == kind)
+        out.append(Limit(
+            "paged_kv", at,
+            refusal=(f"the paged backend (--kv_num_blocks) is not implemented for a stack with "
+                     f"{e.layer}s (layers {at}): a block pool holds positions, and the "
+                     f"{e.state} state of a row is none; serve it from the slot cache "
+                     "(kv_num_blocks 0)")))
+        out.append(Limit(
+            "spec_decode", at,
+            refusal=(f"speculative decoding (spec_decode_k > 0) is not implemented for a stack "
+                     f"with {e.layer}s (layers {at}): a rejected draft has already advanced the "
+                     f"{e.state} state, and a state cannot be wound back as a position is "
+                     "overwritten; use spec_decode_k 0")))
     if len(set(kinds)) > 1:
         out.append(Limit(
             "pp", every, tag="interleaved_layer_kinds_no_pp", code="GTA020",

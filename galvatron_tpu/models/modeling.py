@@ -121,6 +121,10 @@ class ModelConfig:
     # WHOLE projection width (all heads together) before the split into heads
     # and before rope (OLMoE; HF modeling_olmoe.py q_norm / k_norm).
     qk_norm: bool = False
+    # With ``qk_norm``: the norm runs over the head size of EACH head instead, one
+    # weight vector of ``head_dim`` for q and one for k, shared by the heads, plain
+    # ``* w`` (LFM2's q_layernorm / k_layernorm), before rope.
+    qk_norm_per_head: bool = False
     # Gated attention (Qwen3-Next's full-attention layers): a second query-wide
     # projection ``wgate`` whose sigmoid multiplies the attention output per
     # head and channel before ``wo``; q and k each through a zero-centred RMSNorm
@@ -182,6 +186,9 @@ class ModelConfig:
     # flips where two logits lie within a bf16 ulp). The sigmoid router's is
     # always at ``highest``.
     moe_router_precision: str = "default"
+    # the gated short convolution (layers of kind "shortconv", models/shortconv.py):
+    # taps of its depthwise causal conv (``conv_L_cache``)
+    shortconv_taps: int = 3
     ssm_heads: int = 0
     ssm_head_dim: int = 64
     ssm_state: int = 128
@@ -438,12 +445,17 @@ def project_qkv_heads(x, p_attn, cfg: ModelConfig):
 def qk_norm(t, scale, cfg: ModelConfig, axes):
     """RMSNorm of a q or k projection over ALL its heads together (``axes``:
     the head and head-dim axes of ``t``), learned ``scale`` of the whole
-    projection width (n·hd,) — OLMoE's q_norm / k_norm, applied before rope.
+    projection width (n·hd,) — OLMoE's q_norm / k_norm, applied before rope;
+    with ``cfg.qk_norm_per_head`` over each head's own head_dim, ``scale`` (hd,).
     fp32 statistics, rematerialized under the 'policy' recompute like every
     other norm (no fp32-widened copy of the projection survives)."""
     n, hd = t.shape[axes[0]], t.shape[axes[1]]
     shape = [1] * t.ndim
-    shape[axes[0]], shape[axes[1]] = n, hd
+    shape[axes[1]] = hd
+    if cfg.qk_norm_per_head:
+        axes = axes[1:]
+    else:
+        shape[axes[0]] = n
 
     def impl(t_, scale_):
         t32 = t_.astype(jnp.float32)
@@ -519,8 +531,9 @@ def init_layer_params(key, cfg: ModelConfig, cross: bool = False,
         p["attn"]["q_norm"] = jnp.zeros((hd,), cfg.param_dtype)
         p["attn"]["k_norm"] = jnp.zeros((hd,), cfg.param_dtype)
     if cfg.qk_norm:
-        p["attn"]["q_norm"] = jnp.ones((q_out,), cfg.param_dtype)
-        p["attn"]["k_norm"] = jnp.ones((kv_out,), cfg.param_dtype)
+        per_head = cfg.qk_norm_per_head
+        p["attn"]["q_norm"] = jnp.ones((hd if per_head else q_out,), cfg.param_dtype)
+        p["attn"]["k_norm"] = jnp.ones((hd if per_head else kv_out,), cfg.param_dtype)
     if cfg.use_bias:
         if not cfg.qkv_blocked:
             raise ValueError("use_bias needs the blocked qkv layout (no GQA)")
@@ -589,9 +602,9 @@ def layer_annotations(cfg: ModelConfig, cross: bool = False,
         a["attn"]["q_norm"] = (None,)
         a["attn"]["k_norm"] = (None,)
     if cfg.qk_norm:
-        # scales of the projection's output width: sharded with the heads
-        a["attn"]["q_norm"] = ("tp",)
-        a["attn"]["k_norm"] = ("tp",)
+        # scales of the projection's output width: sharded with the heads (a head's own: whole)
+        a["attn"]["q_norm"] = (None,) if cfg.qk_norm_per_head else ("tp",)
+        a["attn"]["k_norm"] = (None,) if cfg.qk_norm_per_head else ("tp",)
     if cfg.use_bias:
         # column-parallel biases shard with their output dim; the
         # row-parallel output bias is added once after the reduction
@@ -2243,5 +2256,22 @@ PRESETS: Dict[str, ModelConfig] = {
         rope_layout=(0, 1, 1, 1) * 13, moe_experts=64, moe_router="softmax_topk", moe_top_k=6,
         moe_ffn_dim=768, moe_norm_topk=True, glu_act="relu", moe_router_input="attn",
         moe_router_precision="highest",
+    ),
+    # LiquidAI/LFM2-24B-A2B (model_type lfm2_moe): 40 layers, conv, conv, then
+    # (full_attention, conv, conv, conv) nine times, then full_attention, conv: 30 gated
+    # short convolutions of 3 taps (models/shortconv.py) and 10 GQA layers, 32 / 8
+    # heads of 64, per-head q/k RMSNorm before rotary (theta 1e6); layers 0-1 a SwiGLU
+    # MLP of 11776, the other 38 carry 64 experts of width 1536, sigmoid scores with a
+    # selection bias, top-4 renormalised, scale 1; tied head (assumed: the LFM2 family
+    # ties). Served (models/generation.py keeps the conv layers' state a row beside the
+    # attention layers' keys and values).
+    "lfm2-24b-a2b": ModelConfig(
+        vocab_size=65536, hidden_size=2048, num_layers=40, num_heads=32, num_kv_heads=8,
+        ffn_dim=11776, max_seq_len=128000, rope_theta=1e6, norm_eps=1e-5,
+        tie_word_embeddings=True, qk_norm=True, qk_norm_per_head=True, shortconv_taps=3,
+        layer_kinds=("shortconv",) * 2 + ("attention", "shortconv", "shortconv", "shortconv") * 9
+        + ("attention", "shortconv"),
+        moe_experts=64, moe_router="sigmoid_topk", moe_top_k=4, moe_route_scale=1.0,
+        moe_ffn_dim=1536, moe_norm_topk=True, moe_dense_layers=2,
     ),
 }

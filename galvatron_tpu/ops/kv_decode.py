@@ -3,7 +3,7 @@ Pallas kernel bounded a row by the row's own length.
 
     o[b, h, i] = softmax_j<=pos(b, i) (q[b, h, i] . k[layer, b, h, j] * scale) v[layer, b, h, j]
 
-``k`` / ``v`` are a stack of a `generation.WindowKVCache` ``(layers, rows, kv_heads,
+``k`` / ``v`` are a stack of a `generation.SlotStacks` ``(layers, rows, kv_heads,
 positions, head_dim)``: the full layers', place j holding position j, or (``span`` >
 0) the window layers' RING, place j holding the newest position p <= the row's last
 write with ``p mod R = j``. Either is handed WHOLE: the index map names ``layer`` (a

@@ -93,8 +93,8 @@ def layer_param_count(cfg: ModelConfig, cross: bool = False, kind: str = "attent
     else:
         mlp = 2 * h * cfg.ffn
     norms = 2 * h if cfg.norm_type == "rms" else 4 * h
-    if cfg.qk_norm:
-        norms += q_out + kv_out
+    if cfg.qk_norm and kind not in mixers.MIXERS:
+        norms += 2 * hd if cfg.qk_norm_per_head else q_out + kv_out
     bias = 0
     if cfg.use_bias:  # qkv slots + wo (+ dense-MLP biases; MoE MLPs carry none)
         bias = 3 * q_out + h

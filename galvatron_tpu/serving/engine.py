@@ -112,10 +112,11 @@ def _prefill_chunk(params, cfg: ModelConfig, cache, tokens, slot, offset,
     Returns (rows, cache, `_router_counters` of the chunk, as `_decode_step`'s).
     Garbage k/v written by tail padding is invisible forever: positions
     beyond a row's own query offset are causally masked, and each decode
-    step overwrites its position before attending to it."""
+    step overwrites its position before attending to it. A layer's per-row
+    STATE is written as of row ``last``: the padding never reaches it."""
     stats: list = []
     logits, cache = generation.forward_with_cache(
-        params, tokens, cfg, cache, offset, slot=slot, moe_stats=stats
+        params, tokens, cfg, cache, offset, slot=slot, moe_stats=stats, last=last
     )
     return (_keep_row(rows, logits[0], slot, last), cache,
             _router_counters(stats, cfg, tokens.size))
@@ -340,10 +341,11 @@ class Engine:
                 f"layers keep a {self.cache_layout['kind']} cache is served from the "
                 "slot cache (kv_num_blocks 0)"
             )
+        for limit in mixers.limits(cfg):
+            if (limit.what == "paged_kv" and self.paged) or (
+                    limit.what == "spec_decode" and self.spec_k > 0):
+                raise ValueError(limit.sentence())
         if self.paged:
-            for limit in mixers.limits(cfg):
-                if limit.what == "paged_kv":
-                    raise ValueError(limit.sentence())
             self.slots = PagedKVCache(
                 cfg, num_slots, block_size=kv_block_size,
                 num_blocks=kv_num_blocks, max_seq_len=max_seq_len,
@@ -358,19 +360,23 @@ class Engine:
                 cfg, self.slots.max_seq_len, self.slots.tokens)
         # a chunk longer than the slot would slice past the cache end
         self.prefill_chunk = min(int(prefill_chunk), self.slots.max_seq_len)
-        if cfg.windowed and self.slots.max_seq_len % self.prefill_chunk:
+        if generation.stacked(cfg) and self.slots.max_seq_len % self.prefill_chunk:
             # a prompt's last chunk is slid left where it would cross the slot's end
             # (`_prefill`), to a start that is no multiple of the chunk: the one write
-            # a ring cannot take whole (generation.write_ring)
+            # a ring cannot take whole (generation.write_ring), and positions run twice
+            # through a layer's state
             raise ValueError(
-                f"a stack with sliding-window layers needs slots of a whole number of prompt "
+                f"a stack with sliding-window layers or with layers that keep a state needs "
+                f"slots of a whole number of prompt "
                 f"chunks: max_seq_len {self.slots.max_seq_len} is no multiple of prefill_chunk "
-                f"{self.prefill_chunk} (a chunk then starts at a multiple of the chunk and "
-                "never crosses the ring's end)")
+                f"{self.prefill_chunk} (a chunk then starts at a multiple of the chunk, "
+                "never crosses the ring's end and never runs a position twice through a state)")
         # of a cache of a kind of its own: which body a prompt chunk's attention takes
         # and the keys a block of it fetches (fixed by the shapes: asked once)
         self.cache_layout.update(
             generation.chunk_layout(cfg, self.prefill_chunk, self.slots.max_seq_len))
+        # which body each kind's layers take, of the kinds the stack has (static: asked once)
+        self._layer_paths = {k: v for k, v in mixers.path_counts(cfg).items() if any(v.values())}
         self.scheduler = Scheduler(max_queue=max_queue, default_ttl_s=request_ttl_s)
         self.deadline_policy = deadline_policy
         self.drain_timeout_s = float(drain_timeout_s)
@@ -581,6 +587,11 @@ class Engine:
             extra["cache_bytes"] = self.cache_layout["bytes_per_slot"] * self.slots.num_slots
             if "ring_positions" in self.cache_layout:  # two stacks, one a ring
                 extra["kv_ring_positions"] = self.cache_layout["ring_positions"]
+            if "full_layers" in self.cache_layout:  # which stacks the cache has, in layers
+                extra["cache_stacks"] = {
+                    stack: self.cache_layout.get(f"{stack}_layers", 0)
+                    for stack in generation.STACKS}
+            extra.update(self._layer_paths)
             extra.update(self.step_counters())
             if self.cfg.moe_dropless:
                 extra["moe_row_tile_prefill"] = moe.layer_row_tile(self.cfg, self.prefill_chunk)
@@ -677,7 +688,7 @@ class Engine:
         slots = self.slots.active_slots() if slots is None else slots
         # (on the loop's thread between two spans: no array is built here)
         live = [int(lengths[s]) for s in slots]
-        per_position = (layout["bytes_per_position_per_layer"] if "window" in layout
+        per_position = (layout["bytes_per_position_per_layer"] if "full_layers" in layout
                         else layout["bytes_per_position"])
         out = {f"{layout['kind']}_cache_bytes_per_position": per_position,
                f"{layout['kind']}_live_positions": sum(live)}
@@ -685,10 +696,14 @@ class Engine:
             self.cfg, [n + window - 1 for n in live], self.slots.num_slots,
             self.slots.max_seq_len, window, ring=layout.get("ring_positions"))
         if isinstance(read, dict):
-            # a windowed stack, by stack: what a layer of each holds live for the rows
+            # stacks by what a layer keeps: what a layer of each holds live for the rows
             # (a window layer the last ``window`` positions) and fetches, and how many
-            # layers each stack has (``kv_cache_bytes_per_position`` is ONE layer's there)
+            # layers each stack has (``kv_cache_bytes_per_position`` is ONE layer's there,
+            # and so is ``state_bytes_per_row``, what a state layer reads and writes a row)
             span = layout["window"]
+            if "state_layers" in layout:
+                out.update(state_layers=layout["state_layers"],
+                           state_bytes_per_row=layout["state_bytes_per_row"])
             out.update(
                 kv_full_live_positions=sum(live),
                 kv_window_live_positions=sum(min(n, span) for n in live),
@@ -969,7 +984,11 @@ class Engine:
         self.scheduler.expire()
         if self.slots.free_slots > 0 and self._head_admissible():
             with _obs_tracer.span("admit") as sp:
-                sp.set(admitted=self._admit_queued())
+                admitted = self._admit_queued()
+                # (a stack with a state: each admitted request's first chunk read zeros in
+                # its slot's place, whatever the slot held)
+                zeroed = {"state_rows_zeroed": admitted} if "state_layers" in self.cache_layout else {}
+                sp.set(admitted=admitted, **zeroed)
 
     def _admit_queued(self) -> int:
         """Pop and prefill while a slot is free and the head is admissible;

@@ -3,9 +3,10 @@
 One fixed-shape device cache — what the stack's layers say
 (``generation.init_kv_cache``): ``(L, num_slots, max_seq_len, kv_heads,
 head_dim)`` k and v for attention, one ``(L, num_slots, max_seq_len, r + dr)``
-latent for latent attention, two stacks where some layers have a sliding window
-(the full layers at ``max_seq_len``, the window layers a ring of ``window +
-tokens`` positions: ``generation.WindowKVCache``) — lives for the whole server lifetime; requests borrow a
+latent for latent attention, stacks by what a layer keeps where the layers differ
+(``generation.SlotStacks``: the full layers at ``max_seq_len``, sliding-window layers
+a ring of ``window + tokens`` positions, layers with a per-row state ``(L_state,
+num_slots, ...)``) — lives for the whole server lifetime; requests borrow a
 *slot* (one batch row) for their duration and return it on retirement
 (vLLM's PagedAttention manages blocks within a sequence; here the unit is
 the whole-sequence slot, which is what maps onto JAX's static-shape jit:
@@ -18,7 +19,10 @@ the engine threads through its jitted prefill/decode calls. Slots are NOT
 zeroed on reuse — a new request's prefill writes positions ``[0, P)`` before
 any query can see them, and causal masking hides every position beyond a
 row's own write offset, so stale keys from the previous occupant are never
-attended.
+attended. A layer's per-row STATE is not zeroed either: the forward that starts a
+request at position 0 reads zeros in its place (``mixers``' ``cached_block``), so
+neither the previous occupant nor an idle row's decode steps reach the new request.
+``reset`` rebuilds every stack, the state among them.
 """
 
 from __future__ import annotations

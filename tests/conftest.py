@@ -49,9 +49,23 @@ _PINNED_TO_THE_TAIL_BY_PR_51 = frozenset(
                            "serve_moe_held_pairs_per_token")])
 
 
+#: The same for ONE case of tests/benchmark/test_benchmark_search_terms.py, which holds
+#: PR 56's five readers to the last five of ``per_layer``: PR 58 appended four serving
+#: readers behind them (a new entry goes at the END of its list, by the driver's rule).
+#: Everything else it asserts (the five together in their order, the two four-chip cells
+#: on each, the two older search readers as they were) is held, with relative positions, by
+#: tests/benchmark/test_benchmark_lfm2.py::test_the_search_readers_stand_together_where_they_were.
+_PINNED_TO_THE_TAIL_BY_PR_56 = frozenset(
+    ["tests/benchmark/test_benchmark_search_terms.py::test_the_five_sit_at_the_tail_of_the_manifest"])
+
+
 def pytest_collection_modifyitems(items):
     for item in items:
         if item.nodeid in _PINNED_TO_THE_TAIL_BY_PR_51:
             item.add_marker(pytest.mark.xfail(
                 strict=True, raises=AssertionError,
                 reason="pins PR 51's entries to the tail of BENCHMARK.json; PR 54 appended"))
+        if item.nodeid in _PINNED_TO_THE_TAIL_BY_PR_56:
+            item.add_marker(pytest.mark.xfail(
+                strict=True, raises=AssertionError,
+                reason="pins PR 56's readers to the tail of per_layer; PR 58 appended"))

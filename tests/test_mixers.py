@@ -169,7 +169,8 @@ def test_a_third_kind_shows_in_the_run_s_fingerprint(glm, monkeypatch, tmp_path)
                if e["ph"] == "X" and e["name"] == "build_runtime"]
     assert span["args"]["layer_kinds"] == {"glm": 1, "attention": 1}
     assert [k for k in span["args"] if k.endswith("_path")] == [
-        "ssm_scan_path", "ssm_conv_path", "gdn_scan_path", "gdn_conv_path", "moe_held_path"]
+        "ssm_scan_path", "ssm_conv_path", "gdn_scan_path", "gdn_conv_path", "shortconv_conv_path",
+        "moe_held_path"]
 
 
 def test_the_registry_loads_a_kind_s_module_only_for_a_stack_that_has_it():
@@ -214,6 +215,10 @@ CUT = {
         vocab_size=96, hidden_size=32, num_layers=4, num_heads=4, num_kv_heads=2, attn_head_dim=8,
         ffn_dim=24, max_seq_len=32, sliding_window_size=8, moe_experts=8, moe_top_k=2,
         moe_ffn_dim=24),
+    # (a kind with a per-row state and no cache limit: served; the state's own two limits)
+    "lfm2-24b-a2b": dict(
+        vocab_size=96, hidden_size=32, num_layers=4, num_heads=4, num_kv_heads=2, ffn_dim=48,
+        max_seq_len=32, moe_experts=8, moe_top_k=2, moe_ffn_dim=24),
 }
 
 
@@ -244,11 +249,23 @@ def _breaking(cfg, limit):
 
 
 def test_the_three_presets_yield_what_the_issue_counts():
-    assert [len(mixers.limits(cut(p))) for p in CUT] == [5, 9, 4, 9] and len(CASES) == 27
+    assert [len(mixers.limits(cut(p))) for p in CUT] == [5, 9, 4, 9, 10] and len(CASES) == 37
     # a windowed stack's five (another attention path, cp, packing, pp, the paged backend)
     assert [t.what for t in mixers.limits(cut("smallthinker-21b-a3b"))[:5]] == [
         "attn_impl", "cp", "pack_sequences", "pp", "paged_kv"]
     assert mixers.limits(PRESETS["llama-7b"]) == []
+    # a stack with a per-row state: the kind's three, the state's two, then the stack's
+    served = mixers.limits(cut("lfm2-24b-a2b"))
+    assert [t.what for t in served] == ["tp", "cp", "pack_sequences", "paged_kv", "spec_decode",
+                                        "pp", "ep", "pp", "cp", "fp16"]
+    assert not [t for t in served if t.what == "kv_cache"]  # attention + shortconv is served
+    # the stacks that still cannot be served say why, each in its kind's own sentence
+    for preset, clause in (("granite-4.0-h-micro", "holds no recurrent (conv + scan) state"),
+                           ("qwen3-next-80b-a3b", "holds no recurrent (conv + delta rule) state")):
+        refusals = [t.sentence() for t in mixers.limits(cut(preset)) if t.what == "kv_cache"]
+        assert len(refusals) == 1 and clause in refusals[0], preset
+    latent = cut("lfm2-24b-a2b").replace(layer_kinds=("shortconv", "mla") * 2, mla_kv_rank=8)
+    assert any("interleaves cache layouts" in t.sentence() for t in mixers.limits(latent))
 
 
 @pytest.mark.parametrize("preset,i", CASES, ids=IDS)
@@ -260,11 +277,13 @@ def test_every_limit_is_refused_reported_and_left_out(preset, i):
             init_kv_cache(cfg, 1, 8)
         assert limit.tag is None and limit.code is None
         return
-    if limit.what == "paged_kv":  # nor for a block pool: the engine's to refuse
+    if limit.what in ("paged_kv", "spec_decode"):
+        # nor for a block pool or a draft window: the engine's to refuse
         from galvatron_tpu.serving import Engine
 
+        asked = {"kv_num_blocks": -1} if limit.what == "paged_kv" else {"spec_decode_k": 2}
         with pytest.raises(ValueError, match=re.escape(limit.sentence())):
-            Engine(None, cfg, num_slots=2, prefill_chunk=8, kv_num_blocks=-1)
+            Engine(None, cfg, num_slots=2, prefill_chunk=8, **asked)
         assert limit.tag is None and limit.code is None
         return
     cfg, hp = _breaking(cfg, limit)

@@ -375,6 +375,61 @@ def test_a_windowed_stacks_serving_program_carries_the_scope(windowed_texts, pro
     assert any(re.search(rf"[/(]{scope}(?:[/)]|$)", n) for n in names), (program, scope)
 
 
+#: a stack whose conv layers keep a per-row state beside the attention layers' keys and
+#: values (PR 58): ``shortconv`` in place of the attention's scopes in a conv layer, ``full``
+#: over an attention layer's as in a windowed stack (what `full_attn_ms_per_step`,
+#: `kv_prefill_chunk_attn_ms` and `kv_decode_attn_roofline` key on)
+STATE_SCOPES = ("layer_0/attn/shortconv/in_proj", "layer_0/attn/shortconv/state_read",
+                "layer_0/attn/shortconv/conv", "layer_0/attn/shortconv/state_write",
+                "layer_0/attn/shortconv/out_proj", "layer_2/attn/full/qkv_proj",
+                "layer_2/attn/full/cache_write", "layer_2/attn/full/attn_core",
+                "layer_2/attn/full/out_proj", "layer_2/mlp/router", "layer_2/mlp/experts")
+
+
+@pytest.fixture(scope="module")
+def state_texts():
+    from galvatron_tpu.aot import registry
+    from galvatron_tpu.models.modeling import PRESETS
+    from galvatron_tpu.serving import engine  # noqa: F401  (registers the serving family)
+
+    cfg = PRESETS["lfm2-24b-a2b"].replace(
+        vocab_size=128, hidden_size=32, num_layers=3, num_heads=4, num_kv_heads=2, ffn_dim=48,
+        max_seq_len=32, moe_experts=8, moe_top_k=2, moe_ffn_dim=24)
+    ctx = registry.ProgramContext(cfg=cfg, num_slots=2, prefill_chunk=8, max_seq_len=32)
+    return {spec.name: spec.fn.lower(*spec.args).compile().as_text()
+            for spec in registry.enumerate_programs(ctx, include=("serving",))}
+
+
+@pytest.mark.parametrize("scope", STATE_SCOPES)
+@pytest.mark.parametrize("program", SERVING_PROGRAMS[:2])
+def test_a_state_stacks_serving_program_carries_the_scope(state_texts, program, scope):
+    import re
+
+    names = re.findall(r'op_name="([^"]*)"', state_texts[program])
+    assert any(re.search(rf"[/(]{scope}(?:[/)]|$)", n) for n in names), (program, scope)
+    assert not [n for n in names if "/window/" in n], program
+
+
+def test_the_training_forward_opens_the_conv_layers_three_scopes():
+    import re
+
+    import jax
+
+    from galvatron_tpu.models import modeling
+    from galvatron_tpu.models.modeling import PRESETS
+
+    cfg = PRESETS["lfm2-24b-a2b"].replace(
+        vocab_size=128, hidden_size=32, num_layers=3, num_heads=4, num_kv_heads=2, ffn_dim=48,
+        max_seq_len=32, moe_experts=8, moe_top_k=2, moe_ffn_dim=24)
+    shapes = jax.eval_shape(lambda k: modeling.init_model_params(k, cfg), jax.random.key(0))
+    tokens = jax.ShapeDtypeStruct((2, 16), "int32")
+    text = jax.jit(lambda p, t: modeling.forward(p, t, cfg)).lower(shapes, tokens).compile().as_text()
+    names = re.findall(r'op_name="([^"]*)"', text)
+    for scope in ("in_proj", "conv", "out_proj"):
+        assert any(f"layer_0/shortconv/{scope}" in n for n in names), scope
+    assert not [n for n in names if "state_read" in n or "state_write" in n]
+
+
 def test_a_plain_stacks_serving_programs_carry_neither_window_nor_full(serving_texts):
     """The two scopes are a windowed stack's alone: the accepted cells' op names, the
     recorded fixtures and ``lib/scoped.SCOPES`` stay as they are."""
@@ -383,6 +438,7 @@ def test_a_plain_stacks_serving_programs_carry_neither_window_nor_full(serving_t
     for program, text in serving_texts.items():
         names = re.findall(r'op_name="([^"]*)"', text)
         assert not [n for n in names if "/window/" in n or "/full/" in n], program
+        assert not [n for n in names if "/shortconv/" in n], program
 
 
 @pytest.fixture()
@@ -656,7 +712,10 @@ def test_build_runtime_span_counts_the_conv_path_beside_the_scan_path(traced_run
     assert list(span["args"]).index("gdn_scan_path") == list(span["args"]).index("ssm_conv_path") + 1
     # and the path a held share of the experts takes (PR 49), behind those: no share here
     assert span["args"]["moe_held_path"] == {"bounded": 0, "worst_case": 0}
-    assert list(span["args"]).index("moe_held_path") == list(span["args"]).index("gdn_conv_path") + 1
+    # (between them since PR 58: the gated short convolution's one body, none here)
+    assert span["args"]["shortconv_conv_path"] == {"fused": 0, "plain": 0}
+    assert list(span["args"]).index("shortconv_conv_path") == list(span["args"]).index("gdn_conv_path") + 1
+    assert list(span["args"]).index("moe_held_path") == list(span["args"]).index("shortconv_conv_path") + 1
 
 
 def test_traced_train_logs_the_profile_window(traced_run):

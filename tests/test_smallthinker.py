@@ -497,6 +497,8 @@ def test_lockstep_generation_runs_over_the_ring():
 def test_cache_bytes_are_the_formula():
     cfg = small_cfg()
     cache = generation.init_kv_cache(cfg, 3, SLOT, tokens=CHUNK)
+    assert cache.state is None  # (no layer of this stack keeps a state: PR 58's third stack)
+    cache = cache[:4]
     assert [a.shape for a in cache] == [(2, 3, 2, SLOT, 8)] * 2 + [(6, 3, 2, WINDOW + CHUNK, 8)] * 2
     layout = generation.cache_layout(cfg, SLOT, CHUNK)
     per = 2 * 2 * 8 * 4

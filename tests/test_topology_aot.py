@@ -1375,3 +1375,54 @@ def test_smallthinker_serving_programs_fit_one_chip_and_write_the_cache_in_place
     print(f"{name}: arguments {ma.argument_size_in_bytes / 2**30:.3f} GiB, temporaries "
           f"{ma.temp_size_in_bytes / 2**30:.3f} GiB, in all {total / 2**30:.3f} GiB")
     assert total < HBM_V5E_GIB * 2**30, f"{total / 2**30:.2f} GiB"
+
+
+@pytest.mark.parametrize("name", ["serving_decode", "serving_prefill"])
+def test_lfm2_serving_programs_fit_one_chip_and_copy_neither_stack(one_chip, real_mosaic, name):
+    """Both programs of `lfm2-24b-a2b_serve_long_above_knee` (22 layers, 32 slots: the 5
+    attention layers' K and V of 8 heads of 64 in bf16 over 16,384 positions, head-major,
+    and the 17 conv layers' state of 2 x 2,048 values a row; chunk 1,024) as the chip's compiler
+    sees them: the donated cache of both stacks is aliased whole (5.37 GB of K and V at
+    their plain size, a head of 64 costing no padding, and 4.5 MB of state), no operation
+    but an in-place update has a result as large as an attention layer's slab (1.07 GB) or
+    as the whole state stack, every attention layer runs under ``full`` and every conv
+    layer under ``shortconv`` with its ``state_read`` and ``state_write``, and weights,
+    cache and temporaries fit the chip."""
+    import re
+
+    from galvatron_tpu.models import generation
+    from galvatron_tpu.models.modeling import PRESETS
+
+    cfg = PRESETS["lfm2-24b-a2b"].replace(
+        num_layers=22, vocab_size=16384, moe_share=(0, 4), max_seq_len=16384,
+        param_dtype=jnp.bfloat16, dtype=jnp.bfloat16)
+    compiled = _lowered_serving_program(cfg, name, one_chip, num_slots=32, prefill_chunk=1024,
+                                        max_seq_len=16384).compile()
+    text = compiled.as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    for i, (stack, _) in enumerate(generation.layer_stacks(cfg)):
+        scopes = (("shortconv/state_read", "shortconv/conv", "shortconv/state_write")
+                  if stack == "state" else ("full/attn_core", "full/cache_write"))
+        for scope in scopes:
+            assert any(f"/layer_{i}/attn/{scope}" in n for n in names), (i, scope)
+    ma = compiled.memory_analysis()
+    kv, state = 2 * 5 * 32 * 8 * 16384 * 64 * 2, 17 * 32 * 2 * 2048 * 2
+    assert (kv, state) == (5_368_709_120, 4_456_448)
+    assert ma.alias_size_in_bytes >= kv + state
+    layout = generation.cache_layout(cfg, 16384, 1024)
+    assert 32 * layout["bytes_per_slot"] == kv + state
+    # neither stack is copied: nothing as large as one layer's K or V slab moves, and no
+    # result has the state stack's shape but its in-place updates
+    moved = [(op, shape) for op, shape in _moved_slabs(text, 32 * 8 * 16384 * 64)
+             if op != "while"]
+    assert not moved, moved[:4]
+    # (a layer's write is a fusion whose root updates the stack in place: its name says so)
+    state_results = [line.strip()[:120] for line in _entry_lines(text)
+                     if re.match(r"\s*(?:ROOT )?%[\w.\-]+ = bf16\[17,32,4096\]\S* (?!parameter|bitcast)", line)
+                     and "state_write/dynamic_update_slice" not in line]
+    assert not state_results, state_results[:4]
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes - ma.alias_size_in_bytes
+             + ma.temp_size_in_bytes)
+    print(f"{name}: arguments {ma.argument_size_in_bytes / 2**30:.3f} GiB, temporaries "
+          f"{ma.temp_size_in_bytes / 2**30:.3f} GiB, in all {total / 2**30:.3f} GiB")
+    assert total < HBM_V5E_GIB * 2**30, f"{total / 2**30:.2f} GiB"
