@@ -80,7 +80,12 @@ class SlotStacks(NamedTuple):
     the oldest key the forward's first query sees (``p - window + 1``). A chunk's
     write never crosses the ring's end (`write_ring`). A head of 64 values (half a lane
     tile) costs no padding in this order: compiled for a described v5e the chip holds
-    (5, 32, 8, 16384, 64) of bf16 in 2.684 GB, its plain size."""
+    (5, 32, 8, 16384, 64) of bf16 in 2.684 GB, its plain size, by laying each head's
+    slab with the POSITIONS on the lanes (``{3,4,2,1,0:T(8,128)(2,1)}``: K-transposed,
+    (64, 16384)); a head of whole lane tiles (128) it keeps as written
+    (``{4,3,2,1,0}``). The logical shape is the same either way, and so are the writers
+    and the prompt chunk; the decode kernel reads each where it lies (`ops/kv_decode`:
+    a head of 64 through ``swapaxes(stack, 3, 4)``, a bitcast on the chip)."""
 
     k: jax.Array
     v: jax.Array

@@ -9,11 +9,24 @@ positions, head_dim)``: the full layers', place j holding position j, or (``span
 write with ``p mod R = j``. Either is handed WHOLE: the index map names ``layer`` (a
 prefetched scalar, so the layers of a step share one traced body a stack,
 `grouped_matmul.traced_once`), the row and a block of ``KEY_BLOCK`` keys of ALL
-its key/value heads, and nothing copies a layer's slab out. Head-major with a
-``head_dim`` of whole lane tiles is the layout the chip keeps as it is written
-(row-major; PERF.md section 6, PR 54), so the kernel reads the stacks where they
-lie; `decode_path` keeps every other head size on the plain body, and
-``tests/test_topology_aot.py`` holds the compiled step to no copy of a slab.
+its key/value heads, and nothing copies a layer's slab out. The kernel reads a stack
+where the chip keeps it, and which layout that is follows from ``head_dim`` (the
+shape's, not the program's; compiled for a described v5e, bf16 and float32 alike):
+
+- whole lane tiles (128, 256): head-major as it is written, row-major
+  (``bf16[4,32,4,16384,128]{4,3,2,1,0}``; PERF.md section 6, PR 54). A block is
+  (kv_heads, KEY_BLOCK, head_dim), the head's values on the lanes.
+- anything else (64; 32, 96 and 192 the same): the POSITIONS on the lanes, the head's
+  values on the sublanes, unpadded (``bf16[5,32,8,16384,64]{3,4,2,1,0:T(8,128)(2,1)}``:
+  each head's slab lies as K-transposed (64, 16384)). The kernel takes
+  ``swapaxes(stack, 3, 4)``, which is a bitcast of that, and a block is (kv_heads,
+  head_dim, KEY_BLOCK): the same algorithm with the keys on the lanes, the scores
+  contracting the sublanes of K, the second product the lanes of the exponentials and
+  of V, both masks an iota over the lanes.
+
+`decode_path` lets in the heads that ``tests/test_topology_aot.py`` compiled and saw
+arrive as a bitcast (whole lane tiles, and `TRANSPOSED_HEADS`), and the same tests hold
+the compiled step to no copy of a slab.
 
 A grid over (row, key block). The rows' first query positions are prefetched; a
 row's window attends ``first + s`` positions. A block past the row's last live
@@ -59,8 +72,14 @@ _LANES = 128
 _ROW_TILE = 16
 #: keys a grid step fetches and attends, of every key/value head of the row
 KEY_BLOCK = 1024
+#: heads of no whole lane tile that the kernel takes, reading the stack transposed:
+#: the widths ``tests/test_topology_aot.py`` compiled for a described v5e and saw the
+#: stack handed over as a bitcast. The compiler kept 32, 96 and 192 the same way in a
+#: probe, but a width the rule guesses wrong costs a copy of the stack a layer a step
+#: (not a wrong answer), so the rule is as narrow as what a test holds
+TRANSPOSED_HEADS = (64,)
 #: query rows (s x g) a key/value head's window may hold: the float32 scores
-#: (kv_heads, rows, KEY_BLOCK) are 2 MiB of VMEM at 4 heads x 128 rows
+#: (kv_heads, rows, KEY_BLOCK) are 2 MiB of VMEM at 4 heads x 128 rows, 4 MiB at 8
 MAX_QUERY_ROWS = 128
 
 
@@ -71,16 +90,18 @@ def decode_path(positions: int, head_dim: int, query_rows: int, dtype) -> str:
     variable, no model's name. `generation._windowed_attention` and
     `generation.cache_read_positions` both ask here. The kernel takes a TPU, or the
     CPU (interpreted: `flash_attention._use_interpret`, the one switch of this
-    repo's kernels); a ``head_dim`` of whole lane tiles (the stacks then lie as the
-    kernel reads them: the module's docstring); a capacity of whole key blocks; at
-    most ``MAX_QUERY_ROWS`` query rows; bf16 or float32. Everything else keeps the
-    plain body."""
+    repo's kernels); a ``head_dim`` of whole lane tiles, which the chip keeps as it is
+    written, or one of `TRANSPOSED_HEADS` (64), which it keeps with the positions on
+    the lanes and the kernel reads transposed (the module's docstring: either way the
+    stacks lie as the kernel reads them); a capacity of whole key blocks; at most
+    ``MAX_QUERY_ROWS`` query rows; bf16 or float32. Everything else keeps the plain
+    body."""
     if jax.default_backend() not in ("tpu", "cpu"):
         return "plain"
     if jnp.dtype(dtype) not in (jnp.bfloat16, jnp.float32):
         return "plain"
-    inside = (head_dim % _LANES == 0 and positions % KEY_BLOCK == 0
-              and query_rows <= MAX_QUERY_ROWS)
+    inside = ((head_dim % _LANES == 0 or head_dim in TRANSPOSED_HEADS)
+              and positions % KEY_BLOCK == 0 and query_rows <= MAX_QUERY_ROWS)
     return "kernel" if inside else "plain"
 
 
@@ -95,7 +116,9 @@ def read_positions(lengths, rows: int, positions: int) -> int:
 
 
 def _kernel(layer_ref, first_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-            scale: float, block_k: int, group: int, window: int, span: int, ring: int):
+            scale: float, block_k: int, group: int, window: int, span: int, ring: int, keys: int):
+    """``keys``: the axis of a K / V block (kv, ., .) its keys lie along: 1, a block
+    (kv, Tk, d) of head-major stacks; 2, a block (kv, d, Tk) of their transposes."""
     del layer_ref  # (the index map's)
     row, j = pl.program_id(0), pl.program_id(1)
     first = first_ref[row]  # the first query's position
@@ -117,15 +140,17 @@ def _kernel(layer_ref, first_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def accumulate(masked: bool):
-        q, k, v = q_ref[...], k_ref[...], v_ref[...]  # (kv, s x g, d), (kv, Tk, d) x 2
-        scores = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+        # (kv, s x g, d) against (kv, Tk, d) x 2, or transposed (kv, d, Tk) x 2
+        q, k, v = q_ref[...], k_ref[...], v_ref[...]
+        scores = jax.lax.dot_general(q, k, (((2,), (3 - keys,)), ((0,), (0,))),
                                      preferred_element_type=F32) * scale
         if masked:
             k_pos = start + jax.lax.broadcasted_iota(jnp.int32, (1, 1, block_k), 2)
             # (rows past s x g pad the tile: they attend as the window's last query)
             q_pos = first + jnp.minimum(
                 jax.lax.broadcasted_iota(jnp.int32, (1, q.shape[1], 1), 1) // group, window - 1)
-            v_pos = start + jax.lax.broadcasted_iota(jnp.int32, (1, block_k, 1), 1)
+            v_pos = start + jax.lax.broadcasted_iota(
+                jnp.int32, (1, block_k, 1) if keys == 1 else (1, 1, block_k), keys)
             if span:
                 k_pos, v_pos = held(k_pos), held(v_pos)
                 seen = (k_pos <= q_pos) & (k_pos > q_pos - span) & (k_pos >= 0)
@@ -141,7 +166,7 @@ def _kernel(layer_ref, first_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_
         m_ref[...] = m_new
         l_ref[...] = l_ref[...] * shrink + jnp.sum(e, axis=-1, keepdims=True)
         acc_ref[...] = acc_ref[...] * shrink + jax.lax.dot_general(
-            e.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))), preferred_element_type=F32)
+            e.astype(v.dtype), v, (((2,), (keys,)), ((0,), (0,))), preferred_element_type=F32)
 
     if span:
         pl.when(start < length)(functools.partial(accumulate, True))
@@ -162,21 +187,31 @@ def _attend(layer, first, q, ks, vs, *, scale: float, block_k: int, group: int, 
             span: int, interpret: bool):
     """``q`` (B, kv, rows, d), a window's queries query-major (row i x g + h is query
     i of grouped head h; rows past ``window x group`` pad the tile), against ``ks``
-    / ``vs`` (layers, slots, kv, positions, d); -> (B, kv, rows, d)."""
+    / ``vs`` (layers, slots, kv, positions, d); -> (B, kv, rows, d). A head of whole
+    lane tiles is read as it is handed; any other TRANSPOSED, a block (kv, d, Tk) of
+    ``swapaxes(ks, 3, 4)``, which is how the chip keeps such a stack (the module's
+    docstring): the transpose is a bitcast there, and the same algorithm runs with
+    the keys on the lanes."""
     b, kv, rows, d = q.shape
-    blocks = ks.shape[3] // block_k
+    positions = ks.shape[3]
+    blocks = positions // block_k
+    transposed = d % _LANES != 0
 
     def live_block(row, j, layer_ref, first_ref):
         last = jnp.minimum((first_ref[row] + window - 1) // block_k, blocks - 1)
-        return layer_ref[0], row, 0, jnp.minimum(j, last), 0
+        layer, live = layer_ref[0], jnp.minimum(j, last)
+        return (layer, row, 0, 0, live) if transposed else (layer, row, 0, live, 0)
 
+    if transposed:
+        ks, vs = jnp.swapaxes(ks, 3, 4), jnp.swapaxes(vs, 3, 4)
+    block = (None, None, kv, d, block_k) if transposed else (None, None, kv, block_k, d)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, blocks),
         in_specs=[
             pl.BlockSpec((None, kv, rows, d), lambda row, j, *_: (row, 0, 0, 0)),
-            pl.BlockSpec((None, None, kv, block_k, d), live_block),
-            pl.BlockSpec((None, None, kv, block_k, d), live_block),
+            pl.BlockSpec(block, live_block),
+            pl.BlockSpec(block, live_block),
         ],
         out_specs=pl.BlockSpec((None, kv, rows, d), lambda row, j, *_: (row, 0, 0, 0)),
         scratch_shapes=[pltpu.VMEM((kv, rows, 1), F32), pltpu.VMEM((kv, rows, 1), F32),
@@ -184,7 +219,7 @@ def _attend(layer, first, q, ks, vs, *, scale: float, block_k: int, group: int, 
     )
     return pl.pallas_call(
         functools.partial(_kernel, scale=scale, block_k=block_k, group=group, window=window,
-                          span=span, ring=ks.shape[3]),
+                          span=span, ring=positions, keys=2 if transposed else 1),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         compiler_params=fa._compiler_params(dimension_semantics=("parallel", "arbitrary")),

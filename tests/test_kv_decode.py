@@ -21,15 +21,15 @@ def small_blocks(monkeypatch):
     monkeypatch.setattr(kv_decode, "KEY_BLOCK", BLOCK)
 
 
-def _case(lengths, s, g, dtype, layers=3, seed=0):
+def _case(lengths, s, g, dtype, layers=3, seed=0, d=D):
     """Head-major stacks of ``layers`` x len(lengths) rows, and the grouped queries of
     a window of ``s`` positions that ends each row's length (a row out of use: 0,
     whose window starts at position 0)."""
     rows = len(lengths)
     keys = jax.random.split(jax.random.key(seed), 3)
-    ks = jax.random.normal(keys[0], (layers, rows, KV, POSITIONS, D), jnp.float32).astype(dtype)
-    vs = jax.random.normal(keys[1], (layers, rows, KV, POSITIONS, D), jnp.float32).astype(dtype)
-    qg = jax.random.normal(keys[2], (rows, s, KV, g, D), jnp.float32).astype(dtype)
+    ks = jax.random.normal(keys[0], (layers, rows, KV, POSITIONS, d), jnp.float32).astype(dtype)
+    vs = jax.random.normal(keys[1], (layers, rows, KV, POSITIONS, d), jnp.float32).astype(dtype)
+    qg = jax.random.normal(keys[2], (rows, s, KV, g, d), jnp.float32).astype(dtype)
     first = jnp.asarray([max(n - s, 0) for n in lengths], jnp.int32)
     return qg, ks, vs, first
 
@@ -59,14 +59,20 @@ def _close(got, want, dtype):
 LENGTHS = [1, BLOCK - 1, BLOCK, 2 * BLOCK, BLOCK + 1, POSITIONS, 0]
 
 
+# grouped query heads a key/value head and the head's size: whole lane tiles (the stacks
+# read as they are handed), and a head of 64, half a lane tile (read TRANSPOSED, the keys
+# on the lanes)
+HEADS = pytest.mark.parametrize("g,d", [(7, D), (1, D), (4, 64)], ids=["7", "1", "g4_d64"])
+
+
 @pytest.mark.parametrize("layer", [0, 2])
 @pytest.mark.parametrize("s", [1, 4], ids=["decode", "verify4"])
-@pytest.mark.parametrize("g", [7, 1])
+@HEADS
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
-def test_the_kernel_is_the_plain_body(dtype, g, s, layer):
-    qg, ks, vs, first = _case(LENGTHS, s, g, dtype)
-    scale = D ** -0.5
-    assert kv_decode.decode_path(POSITIONS, D, s * g, dtype) == "kernel"
+def test_the_kernel_is_the_plain_body(dtype, g, d, s, layer):
+    qg, ks, vs, first = _case(LENGTHS, s, g, dtype, d=d)
+    scale = d ** -0.5
+    assert kv_decode.decode_path(POSITIONS, d, s * g, dtype) == "kernel"
     got = jax.jit(lambda q, k, v: kv_decode.attend_rows(q, k, v, layer, first, scale=scale))(qg, ks, vs)
     assert got.shape == qg.shape and got.dtype == dtype
     _close(got, _plain(qg, ks, vs, layer, first, scale), dtype)
@@ -83,11 +89,11 @@ SPAN = 40
 
 
 @pytest.mark.parametrize("s", [1, 4], ids=["decode", "verify4"])
-@pytest.mark.parametrize("g", [7, 1])
+@HEADS
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
-def test_the_kernel_over_a_ring_is_the_plain_body(dtype, g, s):
-    qg, ks, vs, first = _case(RING_LENGTHS, s, g, dtype)
-    scale = D ** -0.5
+def test_the_kernel_over_a_ring_is_the_plain_body(dtype, g, d, s):
+    qg, ks, vs, first = _case(RING_LENGTHS, s, g, dtype, d=d)
+    scale = d ** -0.5
     got = jax.jit(lambda q, k, v: kv_decode.attend_rows(q, k, v, 1, first, scale=scale, span=SPAN))(
         qg, ks, vs)
     assert got.shape == qg.shape and got.dtype == dtype
@@ -101,13 +107,14 @@ def test_the_kernel_over_a_ring_is_the_plain_body(dtype, g, s):
 
 @pytest.mark.parametrize("span", [0, SPAN], ids=["rows", "ring"])
 @pytest.mark.parametrize("s", [1, 4], ids=["decode", "verify4"])
+@pytest.mark.parametrize("g,d", [(7, D), (4, 64)], ids=["7", "g4_d64"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
-def test_what_no_query_of_a_row_sees_never_reaches_the_output(dtype, s, span):
+def test_what_no_query_of_a_row_sees_never_reaches_the_output(dtype, g, d, s, span):
     """NaN, keys and values, in every place past each row's window (a ring: every
     place never written, a lap old or older than the window of the row's first
     query): bit for bit the clean stacks' output (blocks past the last live one are not
     fetched; elsewhere such keys are masked and such values zeroed)."""
-    qg, ks, vs, first = _case(RING_LENGTHS if span else LENGTHS, s, 7, dtype)
+    qg, ks, vs, first = _case(RING_LENGTHS if span else LENGTHS, s, g, dtype, d=d)
     held = jnp.broadcast_to(_held(first, s, span), (len(first), POSITIONS))
     past = (held >= (first + s)[:, None]) | (held < 0) | ((held <= (first - span)[:, None]) & bool(span))
     dirty = [jnp.where(past[None, :, None, :, None], jnp.nan, a) for a in (ks, vs)]
@@ -115,7 +122,7 @@ def test_what_no_query_of_a_row_sees_never_reaches_the_output(dtype, s, span):
     # what stays clean: the positions from the first query's oldest key to the last write
     oldest = jnp.maximum(first - span + 1, 0) if span else jnp.zeros_like(first)
     assert np.array_equal(np.asarray((~past).sum(1)), np.asarray(first + s - oldest))
-    attend = jax.jit(lambda k, v: kv_decode.attend_rows(qg, k, v, 1, first, scale=D ** -0.5, span=span))
+    attend = jax.jit(lambda k, v: kv_decode.attend_rows(qg, k, v, 1, first, scale=d ** -0.5, span=span))
     got, clean = attend(*dirty), attend(ks, vs)
     assert bool(jnp.isfinite(got.astype(jnp.float32)).all())
     np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(clean, np.float32))
@@ -142,7 +149,10 @@ def _cfg(**kw):
 RULE = {
     "inside": (POSITIONS, D, 7, jnp.bfloat16, "cpu", "kernel"),
     "inside_f32_verify": (POSITIONS, 2 * D, 4 * 7, jnp.float32, "tpu", "kernel"),
-    "head_dim_64": (POSITIONS, 64, 7, jnp.bfloat16, "cpu", "plain"),
+    "head_dim_64": (POSITIONS, 64, 7, jnp.bfloat16, "cpu", "kernel"),
+    "head_dim_64_f32_verify": (POSITIONS, 64, 4 * 7, jnp.float32, "tpu", "kernel"),
+    "head_dim_96": (POSITIONS, 96, 7, jnp.bfloat16, "cpu", "plain"),
+    "head_dim_32": (POSITIONS, 32, 7, jnp.bfloat16, "tpu", "plain"),
     "capacity": (POSITIONS + 8, D, 7, jnp.bfloat16, "cpu", "plain"),
     "query_rows": (POSITIONS, D, kv_decode.MAX_QUERY_ROWS + 1, jnp.bfloat16, "cpu", "plain"),
     "dtype": (POSITIONS, D, 7, jnp.float16, "cpu", "plain"),
@@ -171,15 +181,17 @@ def test_the_rule_answers_from_shapes_and_the_backend_and_the_counter_agrees(mon
     assert ringed["window"] == ((1 + 1 + 2 + 2 + 2) * BLOCK if in_rule else rows * 2 * BLOCK)
 
 
-@pytest.mark.parametrize("why", ["head_dim", "capacity", "query_rows", "dtype", "ring"])
+@pytest.mark.parametrize("why", ["head_dim", "capacity", "query_rows", "dtype", "ring", "head_dim_64"])
 def test_outside_the_rule_the_plain_body_runs(monkeypatch, why):
     """`_windowed_attention` asks the rule of the layer's own stack: outside it the
     kernel is not called (a ring of 8 + 4 places is no whole number of key blocks);
-    inside it, in a full layer of the same cache, it is."""
+    inside it, in a full layer of the same cache, it is, at a head of 64 too, and
+    gives what the plain body gives."""
     calls = []
     real = kv_decode.attend_rows
     monkeypatch.setattr(kv_decode, "attend_rows", lambda *a, **k: calls.append(1) or real(*a, **k))
-    over = {"head_dim": dict(attn_head_dim=64), "dtype": dict(dtype=jnp.float16)}.get(why, {})
+    over = {"head_dim": dict(attn_head_dim=96), "head_dim_64": dict(attn_head_dim=64),
+            "dtype": dict(dtype=jnp.float16)}.get(why, {})
     cfg = _cfg(**over)
     positions = POSITIONS + 8 if why == "capacity" else POSITIONS
     if why == "query_rows":
@@ -188,19 +200,23 @@ def test_outside_the_rule_the_plain_body_runs(monkeypatch, why):
 
     params = modeling.init_model_params(jax.random.key(0), cfg)
     cache = generation.init_kv_cache(cfg, 2, positions, tokens=4)
-    windowed = why == "ring"
-    layer = cfg.window_layers.index(windowed)
     x = jax.random.normal(jax.random.key(1), (2, 1, cfg.hidden_size), cfg.dtype)
     offsets = jnp.asarray([3, 20], jnp.int32)
-    generation._windowed_attention(
-        x, params["layers"][layer], cfg.layer_view(layer), cache, windowed, 0,
-        generation._window_starts(offsets, None, 2), None, offsets, None)
+
+    def attend(windowed):
+        layer = cfg.window_layers.index(windowed)
+        return generation._windowed_attention(
+            x, params["layers"][layer], cfg.layer_view(layer), cache, windowed, 0,
+            generation._window_starts(offsets, None, 2), None, offsets, None)[0]
+
+    inside = why in ("ring", "head_dim_64")  # (whose ring of 12 places is outside)
+    attend(inside)
     assert not calls
-    if why == "ring":  # the same step in a full layer takes the kernel
-        layer = cfg.window_layers.index(False)
-        generation._windowed_attention(
-            x, params["layers"][layer], cfg.layer_view(layer), cache, False, 0,
-            generation._window_starts(offsets, None, 2), None, offsets, None)
+    if inside:  # the same step in a full layer takes the kernel
+        got = attend(False)
+        assert calls == [1]
+        monkeypatch.setattr(kv_decode, "decode_path", lambda *a: "plain")
+        _close(got, attend(False), jnp.float32)
         assert calls == [1]
 
 

@@ -21,6 +21,7 @@ from galvatron_tpu.core.optim import AdamConfig
 from galvatron_tpu.core.strategy import HybridParallelConfig
 from galvatron_tpu.models import generation, mixers, modeling, moe, shortconv
 from galvatron_tpu.models.modeling import PRESETS
+from galvatron_tpu.ops import kv_decode
 from galvatron_tpu.parallel.hybrid import build_runtime
 from galvatron_tpu.parallel.mesh import build_mesh
 
@@ -435,9 +436,15 @@ def test_cache_bytes_are_the_formula():
     at = generation.cache_layout(big, 16384, 1024)
     assert (at["bytes_per_position_per_layer"], at["state_bytes_per_row"]) == (2048, 8192)
     assert 32 * at["bytes_per_slot"] == 32 * (5 * 16384 * 2048 + 17 * 8192) == 5_373_165_568
-    # a decode step's attention reads every slot's capacity at a head of 64 (no kernel
-    # takes half a lane tile: ROADMAP B2); a state layer reads no position
+    # a decode step's attention reads every slot's capacity where a slot is no whole
+    # key block (the plain body); a state layer reads no position
     assert generation.cache_read_positions(cfg, [5, 9], 3, SLOT) == {"full": 3 * SLOT, "window": 0}
+    # at the cell's size the kernel `kv_decode` takes the head of 64 (read transposed, as
+    # the chip keeps it): a row is read up to its length
+    lengths = [1300, 5000, 12288, 16384]
+    assert generation.cache_read_positions(big, lengths, 32, 16384) == {
+        "full": kv_decode.read_positions(lengths, 32, 16384), "window": 0}
+    assert kv_decode.read_positions(lengths, 32, 16384) == (2 + 5 + 12 + 16 + 28) * 1024
     assert generation.layer_stacks(cfg) == [
         ("state", 0), ("state", 1), ("full", 0), ("state", 2), ("state", 3), ("state", 4),
         ("full", 1), ("state", 5)]

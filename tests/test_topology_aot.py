@@ -1301,25 +1301,45 @@ def test_mlp_recompute_buffer_accounting_tp2_zero3_sp(topo, real_mosaic):
     assert temps["policy"] <= temps["off"] * 0.95, temps
 
 
-@pytest.mark.parametrize("dtype,s,positions,span", [
-    (jnp.bfloat16, 5, 16384, 0), (jnp.bfloat16, 5, 5120, 4096),
-    (jnp.float32, 18, 16384, 0), (jnp.float32, 1, 5120, 4096)],
-    ids=["bf16_verify5_rows", "bf16_verify5_ring", "f32_verify18_rows", "f32_decode_ring"])
-def test_kv_decode_takes_what_its_rule_lets_in(one_chip, real_mosaic, dtype, s, positions, span):
-    """What the benchmark's cell does not run but `kv_decode.decode_path` lets in, at the
-    cell's widths (32 slots, 4 key/value heads of 128, 7 grouped query heads): a verify
-    window of 1 + 4, float32, 18 x 7 = 126 query rows (the most: `MAX_QUERY_ROWS` 128),
-    over whole rows and over a ring: Mosaic takes each, no temporary beside the kernel."""
+@pytest.mark.parametrize("dtype,s,positions,span,heads", [
+    (jnp.bfloat16, 5, 16384, 0, (4, 7, 128)), (jnp.bfloat16, 5, 5120, 4096, (4, 7, 128)),
+    (jnp.float32, 18, 16384, 0, (4, 7, 128)), (jnp.float32, 1, 5120, 4096, (4, 7, 128)),
+    (jnp.bfloat16, 5, 16384, 0, (8, 4, 64)), (jnp.float32, 1, 16384, 0, (8, 4, 64)),
+    (jnp.bfloat16, 1, 5120, 4096, (8, 4, 64)), (jnp.float32, 32, 16384, 0, (8, 4, 64))],
+    ids=["bf16_verify5_rows", "bf16_verify5_ring", "f32_verify18_rows", "f32_decode_ring",
+         "d64_bf16_verify5_rows", "d64_f32_decode_rows", "d64_bf16_decode_ring",
+         "d64_f32_verify32_rows"])
+def test_kv_decode_takes_what_its_rule_lets_in(one_chip, real_mosaic, dtype, s, positions, span,
+                                               heads):
+    """What the benchmark's cells do not run but `kv_decode.decode_path` lets in, at their
+    widths (32 slots; smallthinker's 4 key/value heads of 128 under 7 grouped query heads,
+    lfm2's 8 of 64 under 4): a verify window of 1 + 4, float32, the most query rows
+    (`MAX_QUERY_ROWS` 128: 18 x 7 = 126, 32 x 4), over whole rows and over a ring: Mosaic
+    takes each, no temporary beside the kernel. A head of 64 is read TRANSPOSED, which is
+    how the chip keeps such a stack (positions on the lanes, ``{3,4,2,1,0}``): the
+    kernel's operands are bitcasts of the stacks, nothing is copied."""
+    import re
+
     from galvatron_tpu.ops import kv_decode
 
-    assert kv_decode.decode_path(positions, 128, s * 7, dtype) == "kernel"
-    stack = jax.ShapeDtypeStruct((4, 32, 4, positions, 128), dtype, sharding=one_chip)
+    kv, g, d = heads
+    assert kv_decode.decode_path(positions, d, s * g, dtype) == "kernel"
+    stack = jax.ShapeDtypeStruct((4, 32, kv, positions, d), dtype, sharding=one_chip)
     compiled = jax.jit(lambda q, k, v, first: kv_decode.attend_rows(
-        q, k, v, 2, first, scale=128 ** -0.5, span=span)).lower(
-        jax.ShapeDtypeStruct((32, s, 4, 7, 128), dtype, sharding=one_chip), stack, stack,
+        q, k, v, 2, first, scale=d ** -0.5, span=span)).lower(
+        jax.ShapeDtypeStruct((32, s, kv, g, d), dtype, sharding=one_chip), stack, stack,
         jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip)).compile()
-    assert "tpu_custom_call" in compiled.as_text() and "kv_decode" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "kv_decode" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+    if d % 128:
+        word = "bf16" if dtype == jnp.bfloat16 else "f32"
+        kept = re.escape(f"{word}[4,32,{kv},{positions},{d}]") + r"\{3,4,2,1,0:"
+        assert len(re.findall(rf"= {kept}\S* parameter\(", text)) == 2
+        call, = [line for line in _entry_lines(text) if "custom-call(" in line and "kv_decode" in line]
+        assert call.count(f"{word}[4,32,{kv},{d},{positions}]{{4,3,2,1,0}}") == 2, call
+        handed = re.search(r"custom-call\(([^)]*)\)", call).group(1).split(", ")[-2:]
+        assert all(name.startswith("%bitcast") for name in handed), handed
 
 
 @pytest.mark.parametrize("name", ["serving_decode", "serving_prefill"])
@@ -1387,7 +1407,12 @@ def test_lfm2_serving_programs_fit_one_chip_and_copy_neither_stack(one_chip, rea
     but an in-place update has a result as large as an attention layer's slab (1.07 GB) or
     as the whole state stack, every attention layer runs under ``full`` and every conv
     layer under ``shortconv`` with its ``state_read`` and ``state_write``, and weights,
-    cache and temporaries fit the chip."""
+    cache and temporaries fit the chip. The chip keeps a head of 64 with the positions on
+    the lanes (``{3,4,2,1,0}``), and every attention layer of the decode step attends
+    through the kernel `kv_decode` reading that in place: one custom call under ``full`` >
+    ``attn_core`` of each of the 5, handed both stacks whole as the bitcasts
+    (5, 32, 8, 64, 16384), and no float32 score of 32 x 32 x 16,384 is left; a prompt
+    chunk takes the plain body."""
     import re
 
     from galvatron_tpu.models import generation
@@ -1416,6 +1441,14 @@ def test_lfm2_serving_programs_fit_one_chip_and_copy_neither_stack(one_chip, rea
     moved = [(op, shape) for op, shape in _moved_slabs(text, 32 * 8 * 16384 * 64)
              if op != "while"]
     assert not moved, moved[:4]
+    kernels = [line for line in _entry_lines(text) if "custom-call(" in line and "kv_decode" in line]
+    under = sorted(int(re.search(r"/layer_(\d+)/attn/full/attn_core", line).group(1)) for line in kernels)
+    full = [i for i, (stack, _) in enumerate(generation.layer_stacks(cfg)) if stack == "full"]
+    assert len(full) == 5 and under == (full if name == "serving_decode" else [])
+    for line in kernels:  # the stacks where they lie, transposed by a bitcast
+        assert line.count("bf16[5,32,8,64,16384]{4,3,2,1,0}") == 2, line
+    if name == "serving_decode":
+        assert not re.search(r"f32\[32,8,4,(1,)?16384\]", text)
     # (a layer's write is a fusion whose root updates the stack in place: its name says so)
     state_results = [line.strip()[:120] for line in _entry_lines(text)
                      if re.match(r"\s*(?:ROOT )?%[\w.\-]+ = bf16\[17,32,4096\]\S* (?!parameter|bitcast)", line)
