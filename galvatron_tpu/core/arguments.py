@@ -44,6 +44,9 @@ def _add_model_args(p: argparse.ArgumentParser):
     g.add_argument("--moe_experts", type=int, default=None,
                    help="switch-MoE expert count (0/None = dense MLP)")
     g.add_argument("--moe_capacity_factor", type=float, default=None)
+    g.add_argument("--moe_dense_layers", type=int, default=None,
+                   help="leading layers whose MLP is the plain one of --ffn_dim where the rest "
+                        "are expert layers (a model cut in depth may keep fewer of them)")
     g.add_argument("--moe_share", type=str, default=None, metavar="R/N",
                    help="dropless top-k MoE: this copy holds rank R of N contiguous shares "
                         "of the experts (one rank of an N-way expert-parallel deployment); "
@@ -776,6 +779,7 @@ def model_config_from_args(ns: argparse.Namespace, base=None):
         ("num_classes", "num_classes"), ("swin_window", "swin_window"),
         ("moe_experts", "moe_experts"),
         ("moe_capacity_factor", "moe_capacity_factor"),
+        ("moe_dense_layers", "moe_dense_layers"),
     ]:
         v = getattr(ns, attr, None)
         if v is not None:

@@ -342,6 +342,8 @@ def _embed_at(params: Params, tokens, cfg: ModelConfig, offsets):
     """Token embeddings of ``tokens`` (B, s), plus the learned positions at
     ``offsets`` where the model has them."""
     x = params["embed"]["tok"].astype(cfg.dtype)[tokens]
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
     if cfg.pos_embed == "learned":
         x = x + params["embed"]["pos"].astype(cfg.dtype)[_positions(offsets, tokens.shape[1])]
     return x
@@ -355,6 +357,15 @@ def _project_qkv_at(x, p, cfg: ModelConfig, cos_sin):
         q = modeling.apply_rope(q, *cos_sin)
         k = modeling.apply_rope(k, *cos_sin)
     return q, k, v
+
+
+@jax.named_scope("qkv_proj")
+def _project_gate_at(x, p, cfg: ModelConfig):
+    """A layer's output gate from the same normed input (``modeling.project_gate``);
+    None where the block has none."""
+    if not cfg.attn_gate:
+        return None
+    return modeling.project_gate(modeling.norm(x, p["attn_norm"], cfg), p["attn"], cfg)
 
 
 # -- a stack whose layers keep whole slots, a ring or a state: `SlotStacks` ----------
@@ -464,8 +475,10 @@ def _windowed_attention(x, p, cfg: ModelConfig, cache: SlotStacks, windowed: boo
     loops over the blocks up to its end (`chunk_key_blocks`); rows' windows go
     through the kernel `kv_decode`, bounded a row by the row's length, where
     `kv_decode.decode_path` says so of the layer's stack, else over every slot's
-    capacity (`_attend_rows`). Scopes: ``window`` | ``full`` > ``qkv_proj``,
-    ``cache_write``, ``attn_core``, ``out_proj``."""
+    capacity (`_attend_rows`). A layer with an output gate (``cfg.attn_gate``)
+    projects it beside q, k and v and applies it between the core and the output
+    projection, in both stacks and both forms. Scopes: ``window`` | ``full`` >
+    ``qkv_proj``, ``cache_write``, ``attn_core``, ``gate``, ``out_proj``."""
     b, s = x.shape[:2]
     kv, g, d = cfg.kv_heads, cfg.num_heads // cfg.kv_heads, cfg.head_dim
     scale = cfg.attention_multiplier if cfg.attention_multiplier is not None else d ** -0.5
@@ -473,6 +486,7 @@ def _windowed_attention(x, p, cfg: ModelConfig, cache: SlotStacks, windowed: boo
     positions = ks.shape[3]
     with jax.named_scope("window" if windowed else "full"):
         q, k, v = _project_qkv_at(x, p, cfg, cos_sin if cfg.pos_embed == "rope" else None)
+        gate = _project_gate_at(x, p, cfg)
         with jax.named_scope("cache_write"):
             write = (partial(write_ring, aligned=jnp.ndim(offsets) == 0) if windowed
                      else partial(write_layer, axis=3))
@@ -501,8 +515,9 @@ def _windowed_attention(x, p, cfg: ModelConfig, cache: SlotStacks, windowed: boo
                 o = _attend_rows(qg, read_layer(ks, index, None), read_layer(vs, index, None),
                                  q_pos, key_positions(jnp.arange(positions)), cfg.attn_window,
                                  scale)
+        o = modeling.gate_output(o.reshape(b, s, kv * g, d), gate)
         with jax.named_scope("out_proj"):
-            y = modeling.attn_output(o.reshape(b, s, kv * g, d), p["attn"], cfg, x.dtype)
+            y = modeling.attn_output(o, p["attn"], cfg, x.dtype)
     cache = cache._replace(wk=ks, wv=vs) if windowed else cache._replace(k=ks, v=vs)
     return y, cache
 
@@ -583,19 +598,21 @@ def forward_with_cache(params: Params, tokens, cfg: ModelConfig, cache, offsets,
                         modeling.norm(x, p["attn_norm"], cfg), p[cfg.kinds[i]], cfg,
                         cache.state, stacks[i][1], slot, offsets, last)
                     cache = cache._replace(state=state)
-                    x = modeling.residual_add(x, y, cfg)
+                    x = modeling.residual_add(
+                        x, modeling.post_norm(y, p, "post_attn_norm", cfg), cfg)
                 elif by_stack:
                     y, cache = _windowed_attention(
                         x, p, cfg.layer_view(i), cache, stacks[i][0] == "window",
                         stacks[i][1], starts, slot, offsets, cos_sin)
-                    x = x + y
+                    x = x + modeling.post_norm(y, p, "post_attn_norm", cfg)
                 elif kind is not None:
                     y, cache = mixers.module(kind).cached_block(
                         modeling.norm(x, p["attn_norm"], cfg), p[kind], cfg, cache, i, starts,
                         slot, offsets, cos_sin)
-                    x = x + y
+                    x = x + modeling.post_norm(y, p, "post_attn_norm", cfg)
                 else:
                     q, k, v = _project_qkv_at(x, p, cfg, cos_sin)
+                    gate = _project_gate_at(x, p, cfg)
                     with jax.named_scope("cache_write"):
                         ks = write_layer(ks, i, k, starts)
                         vs = write_layer(vs, i, v, starts)
@@ -603,9 +620,12 @@ def forward_with_cache(params: Params, tokens, cfg: ModelConfig, cache, offsets,
                         o = modeling.attention_xla(
                             q, read_layer(ks, i, slot), read_layer(vs, i, slot), cfg,
                             bias=bias, q_offset=offsets)
+                    o = modeling.gate_output(o, gate)
                     with jax.named_scope("out_proj"):
-                        x = x + modeling.attn_output(o, p["attn"], cfg, x.dtype)
-            x = x + _mlp_at(x, p, cfg, moe_stats, router_x)
+                        y = modeling.attn_output(o, p["attn"], cfg, x.dtype)
+                    x = x + modeling.post_norm(y, p, "post_attn_norm", cfg)
+            x = x + modeling.post_norm(
+                _mlp_at(x, p, cfg, moe_stats, router_x), p, "post_mlp_norm", cfg)
     return _head(x, params, cfg), (cache if kind is not None or by_stack else KVCache(ks, vs))
 
 
@@ -628,6 +648,7 @@ def _layer_with_cache_paged(x, p, cfg: ModelConfig, pool_k, pool_v, tables,
     smax = tables.shape[1] * bs
     with jax.named_scope("attn"):
         q, k, v = _project_qkv_at(x, p, cfg, cos_sin)
+        gate = _project_gate_at(x, p, cfg)
         with jax.named_scope("cache_write"):
             # scatter the new k/v through the table (duplicate targets only
             # arise on the null block, whose contents are never attended)
@@ -647,12 +668,12 @@ def _layer_with_cache_paged(x, p, cfg: ModelConfig, pool_k, pool_v, tables,
                 k_ctx = pool_k[tables].reshape(b, smax, *pool_k.shape[2:])
                 v_ctx = pool_v[tables].reshape(b, smax, *pool_v.shape[2:])
                 o = modeling.attention_xla(q, k_ctx, v_ctx, cfg, bias=bias, q_offset=offsets)
+        o = modeling.gate_output(o, gate)
         with jax.named_scope("out_proj"):
-            x = x + modeling.attn_output(o, p["attn"], cfg, x.dtype)
-    x = x + modeling.mlp_block(
-        modeling.norm(x, p["mlp_norm"], cfg), p["mlp"], cfg, train=False
-    )
-    return x, pool_k, pool_v
+            y = modeling.attn_output(o, p["attn"], cfg, x.dtype)
+        x = x + modeling.post_norm(y, p, "post_attn_norm", cfg)
+    y = modeling.mlp_block(modeling.norm(x, p["mlp_norm"], cfg), p["mlp"], cfg, train=False)
+    return x + modeling.post_norm(y, p, "post_mlp_norm", cfg), pool_k, pool_v
 
 
 def forward_with_cache_paged(params: Params, tokens, cfg: ModelConfig,

@@ -122,16 +122,24 @@ class ModelConfig:
     # and before rope (OLMoE; HF modeling_olmoe.py q_norm / k_norm).
     qk_norm: bool = False
     # With ``qk_norm``: the norm runs over the head size of EACH head instead, one
-    # weight vector of ``head_dim`` for q and one for k, shared by the heads, plain
-    # ``* w`` (LFM2's q_layernorm / k_layernorm), before rope.
+    # weight vector of ``head_dim`` for q and one for k, shared by the heads, before
+    # rope: plain ``* w`` (LFM2's q_layernorm / k_layernorm), or ``* (1 + w)`` under
+    # ``norm_zero_centered`` (Qwen3-Next's q_norm / k_norm).
     qk_norm_per_head: bool = False
-    # Gated attention (Qwen3-Next's full-attention layers): a second query-wide
-    # projection ``wgate`` whose sigmoid multiplies the attention output per
-    # head and channel before ``wo``; q and k each through a zero-centred RMSNorm
-    # over the head size of EACH head (one weight vector of ``head_dim`` each);
-    # rotary on the first ``rotary_fraction`` of each head (``attn_block``).
+    # An output gate on the attention block: a second query-wide projection ``wgate``
+    # of the block's normed input whose sigmoid multiplies the attention output per
+    # head and channel, in float32, before ``wo`` (the training block
+    # ``_attn_block_gated`` and every cached forward, models/generation.py). Nothing
+    # else comes with it: the q/k norms are ``qk_norm``'s (per head), rotary
+    # ``rotary_fraction``'s and ``rope_layout``'s. Qwen3-Next's full-attention layers
+    # and Trinity's layers set it.
     attn_gate: bool = False
+    # Rotary on the first ``rotary_fraction`` of each head (Qwen3-Next: a quarter).
     rotary_fraction: float = 1.0
+    # Sandwich norms: a second RMSNorm on what the attention block and the MLP block
+    # RETURN (``post_attn_norm`` / ``post_mlp_norm``, learned scales of the hidden
+    # width), applied before each joins the residual stream: four norms a layer.
+    post_norms: bool = False
     # Head size where it is not ``hidden_size / num_heads`` (None: that).
     attn_head_dim: Optional[int] = None
     # Every RMSNorm over the hidden width is ``x * rsqrt(mean(x^2) + eps) * (1 + w)``
@@ -446,8 +454,8 @@ def qk_norm(t, scale, cfg: ModelConfig, axes):
     """RMSNorm of a q or k projection over ALL its heads together (``axes``:
     the head and head-dim axes of ``t``), learned ``scale`` of the whole
     projection width (n·hd,) — OLMoE's q_norm / k_norm, applied before rope;
-    with ``cfg.qk_norm_per_head`` over each head's own head_dim, ``scale`` (hd,).
-    fp32 statistics, rematerialized under the 'policy' recompute like every
+    with ``cfg.qk_norm_per_head`` over each head's own head_dim, ``scale`` (hd,);
+    ``(1 + scale)`` under ``cfg.norm_zero_centered``. fp32 statistics, rematerialized under the 'policy' recompute like every
     other norm (no fp32-widened copy of the projection survives)."""
     n, hd = t.shape[axes[0]], t.shape[axes[1]]
     shape = [1] * t.ndim
@@ -460,7 +468,8 @@ def qk_norm(t, scale, cfg: ModelConfig, axes):
     def impl(t_, scale_):
         t32 = t_.astype(jnp.float32)
         t32 = t32 * jax.lax.rsqrt(jnp.mean(t32 * t32, axis=axes, keepdims=True) + cfg.norm_eps)
-        return (t32 * scale_.astype(jnp.float32).reshape(shape)).astype(t_.dtype)
+        w32 = scale_.astype(jnp.float32).reshape(shape)
+        return (t32 * (1.0 + w32 if cfg.norm_zero_centered else w32)).astype(t_.dtype)
 
     if cfg.mlp_recompute == "policy":
         impl = jax.checkpoint(impl)
@@ -477,6 +486,25 @@ def attn_output(o, p_attn, cfg: ModelConfig, dtype):
     if "wo_b" in p_attn:
         y = y + p_attn["wo_b"].astype(dtype)
     return y
+
+
+def project_gate(x, p_attn, cfg: ModelConfig):
+    """The output gate's projection of the block's normed input ``x`` (..., h) ->
+    (..., n, hd) where the block has one (``cfg.attn_gate``), else None."""
+    if not cfg.attn_gate:
+        return None
+    g = x @ p_attn["wgate"].astype(x.dtype)  # (served int8 weights leave it as it is)
+    return g.reshape(*x.shape[:-1], cfg.num_heads, cfg.head_dim)
+
+
+def gate_output(o, gate):
+    """``o * sigmoid(gate)`` per head and channel in float32, both of one layout
+    ((..., n, hd), or head-major), under scope ``gate``; ``o`` as it is where the
+    block has no gate (None)."""
+    if gate is None:
+        return o
+    with jax.named_scope("gate"):
+        return (o.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(o.dtype)
 
 
 def split_qkv(qkv, cfg: ModelConfig):
@@ -526,14 +554,14 @@ def init_layer_params(key, cfg: ModelConfig, cross: bool = False,
         "mlp_norm": {"scale": _norm_scale_init(cfg, h)},
     }
     if cfg.attn_gate:
-        # the output gate's projection and the per-head norms' (1 + w) weights
         p["attn"]["wgate"] = _dense_init(ks[1], h, q_out, cfg.param_dtype)
-        p["attn"]["q_norm"] = jnp.zeros((hd,), cfg.param_dtype)
-        p["attn"]["k_norm"] = jnp.zeros((hd,), cfg.param_dtype)
+    if cfg.post_norms:
+        p["post_attn_norm"] = {"scale": _norm_scale_init(cfg, h)}
+        p["post_mlp_norm"] = {"scale": _norm_scale_init(cfg, h)}
     if cfg.qk_norm:
         per_head = cfg.qk_norm_per_head
-        p["attn"]["q_norm"] = jnp.ones((hd if per_head else q_out,), cfg.param_dtype)
-        p["attn"]["k_norm"] = jnp.ones((hd if per_head else kv_out,), cfg.param_dtype)
+        p["attn"]["q_norm"] = _norm_scale_init(cfg, hd if per_head else q_out)
+        p["attn"]["k_norm"] = _norm_scale_init(cfg, hd if per_head else kv_out)
     if cfg.use_bias:
         if not cfg.qkv_blocked:
             raise ValueError("use_bias needs the blocked qkv layout (no GQA)")
@@ -573,9 +601,15 @@ def init_layer_params(key, cfg: ModelConfig, cross: bool = False,
             p["mlp"]["w1_b"] = jnp.zeros((cfg.ffn,), cfg.param_dtype)
             p["mlp"]["w2_b"] = jnp.zeros((h,), cfg.param_dtype)
     if cfg.norm_type == "layernorm":
-        p["attn_norm"]["bias"] = jnp.zeros((h,), cfg.param_dtype)
-        p["mlp_norm"]["bias"] = jnp.zeros((h,), cfg.param_dtype)
+        for name in _layer_norms(cfg):
+            p[name]["bias"] = jnp.zeros((h,), cfg.param_dtype)
     return p
+
+
+def _layer_norms(cfg: ModelConfig) -> Tuple[str, ...]:
+    """The norms over the hidden width a decoder layer holds."""
+    post = ("post_attn_norm", "post_mlp_norm") if cfg.post_norms else ()
+    return ("attn_norm", "mlp_norm") + post
 
 
 def layer_annotations(cfg: ModelConfig, cross: bool = False,
@@ -599,8 +633,9 @@ def layer_annotations(cfg: ModelConfig, cross: bool = False,
     }
     if cfg.attn_gate:
         a["attn"]["wgate"] = ("fsdp", "tp")
-        a["attn"]["q_norm"] = (None,)
-        a["attn"]["k_norm"] = (None,)
+    if cfg.post_norms:
+        a["post_attn_norm"] = {"scale": ("fsdp",)}
+        a["post_mlp_norm"] = {"scale": ("fsdp",)}
     if cfg.qk_norm:
         # scales of the projection's output width: sharded with the heads (a head's own: whole)
         a["attn"]["q_norm"] = (None,) if cfg.qk_norm_per_head else ("tp",)
@@ -634,8 +669,8 @@ def layer_annotations(cfg: ModelConfig, cross: bool = False,
             a["mlp"]["w1_b"] = ("tp",)
             a["mlp"]["w2_b"] = ("fsdp",)
     if cfg.norm_type == "layernorm":
-        a["attn_norm"]["bias"] = ("fsdp",)
-        a["mlp_norm"]["bias"] = ("fsdp",)
+        for name in _layer_norms(cfg):
+            a[name]["bias"] = ("fsdp",)
     return a
 
 
@@ -1258,14 +1293,16 @@ def _attn_block_headmajor(x, p, cfg: ModelConfig, rope, remat_attn: bool, place:
 
 
 def head_norm(t, w, cfg: ModelConfig):
-    """Zero-centred RMSNorm over the last axis of ``t`` (..., head_dim), weight
-    ``w`` (head_dim,) shared by the heads: Qwen3-Next's q_norm / k_norm. fp32
+    """``qk_norm`` per head over the last axis of a head-major ``t`` (..., head_dim),
+    weight ``w`` (head_dim,) shared by the heads: ``* (1 + w)`` under
+    ``cfg.norm_zero_centered`` (Qwen3-Next's q_norm / k_norm), else ``* w``. fp32
     statistics, rematerialized under the 'policy' recompute like ``qk_norm``."""
 
     def impl(t_, w_):
         t32 = t_.astype(jnp.float32)
         t32 = t32 * jax.lax.rsqrt(jnp.mean(t32 * t32, axis=-1, keepdims=True) + cfg.norm_eps)
-        return (t32 * (1.0 + w_.astype(jnp.float32))).astype(t_.dtype)
+        w32 = w_.astype(jnp.float32)
+        return (t32 * (1.0 + w32 if cfg.norm_zero_centered else w32)).astype(t_.dtype)
 
     if cfg.mlp_recompute == "policy":
         impl = jax.checkpoint(impl)
@@ -1283,22 +1320,35 @@ def _rope_leading_hm(x, cos, sin):
 def _attn_block_gated(x, p, cfg: ModelConfig, cos_sin, remat_attn: bool, seg_ids,
                       place: Placement):
     """Gated attention (``cfg.attn_gate``), head-major end to end: q, k, v from
-    the GQA-interleaved fused projection, the gate from ``wgate``; per-head
-    zero-centred norms on q and k; rotary on the leading ``rotary_dim`` of each
-    head, applied here (the kernels then run their no-RoPE GQA instance: a head
-    of 256 at s 4096 lies on the blocked envelope's edge); the attention core;
-    ``sigmoid(gate)`` on its output under scope ``gate``; the output projection."""
+    the GQA-interleaved fused projection, the gate from ``wgate``; per-head norms
+    on q and k (``cfg.qk_norm``); rotary on the leading ``rotary_dim`` of each
+    head where the layer has a position signal, applied here (the kernels then
+    run their no-RoPE GQA instance: a head of 256 at s 4096 lies on the blocked
+    envelope's edge); the attention core (XLA's under a window); ``sigmoid(gate)``
+    on its output under scope ``gate`` (`gate_output`); the output projection."""
     from galvatron_tpu.ops.flash_attention import flash_attention_hm, flash_tileable
 
+    if cfg.qkv_blocked or (cfg.qk_norm and not cfg.qk_norm_per_head):
+        raise ValueError(
+            "the gated attention block (attn_gate) reads the GQA-interleaved projection "
+            "(num_kv_heads < num_heads) and norms q and k per head (qk_norm_per_head)")
     b, s, h = x.shape
     n, kv, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
     npg = n // kv
+    # (served int8 weights reach this block through `--serve_quant`'s parity forward
+    # alone: they keep their stored layout and are laid head-major after the GEMM)
+    quantized = isinstance(p["wqkv"], QuantTensor)
     with jax.named_scope("qkv_proj"):
-        r = jnp.einsum("bsh,hknd->bknsd", x, p["wqkv"].astype(x.dtype).reshape(h, kv, npg + 2, hd))
+        if quantized:
+            r = qkv_project(x, p["wqkv"], cfg).reshape(b, s, kv, npg + 2, hd).transpose(0, 2, 3, 1, 4)
+        else:
+            r = jnp.einsum("bsh,hknd->bknsd", x,
+                           p["wqkv"].astype(x.dtype).reshape(h, kv, npg + 2, hd))
         gate = jnp.einsum("bsh,hnd->bnsd", x, p["wgate"].astype(x.dtype).reshape(h, n, hd))
     q, k, v = r[:, :, :npg].reshape(b, n, s, hd), r[:, :, npg], r[:, :, npg + 1]
-    with jax.named_scope("qk_norm"):
-        q, k = head_norm(q, p["q_norm"], cfg), head_norm(k, p["k_norm"], cfg)
+    if cfg.qk_norm:
+        with jax.named_scope("qk_norm"):
+            q, k = head_norm(q, p["q_norm"], cfg), head_norm(k, p["k_norm"], cfg)
     if cfg.pos_embed == "rope":
         with jax.named_scope("rope"):
             q, k = _rope_leading_hm(q, *cos_sin), _rope_leading_hm(k, *cos_sin)
@@ -1319,9 +1369,10 @@ def _attn_block_gated(x, p, cfg: ModelConfig, cos_sin, remat_attn: bool, seg_ids
         core = jax.checkpoint(core)
     with jax.named_scope("attn_core"):
         o = place.constrain_attn_out(core(q, k, v))
-    with jax.named_scope("gate"):
-        o = (o.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(x.dtype)
+    o = gate_output(o, gate)
     with jax.named_scope("out_proj"):
+        if quantized:
+            return attn_output(jnp.swapaxes(o, 1, 2), p, cfg, x.dtype)
         return jnp.einsum("bnsd,nde->bse", o, p["wo"].astype(x.dtype).reshape(n, hd, h))
 
 
@@ -1469,6 +1520,16 @@ def residual_add(x, y, cfg: ModelConfig):
     return x + y if cfg.residual_multiplier == 1.0 else x + y * cfg.residual_multiplier
 
 
+def post_norm(y, p, name: str, cfg: ModelConfig):
+    """What a block returns, through the layer's norm ``name`` (``post_attn_norm`` |
+    ``post_mlp_norm``, under a scope of that name) where the layer holds one
+    (``cfg.post_norms``: sandwich norms), before it joins the residual stream."""
+    if name not in p:
+        return y
+    with jax.named_scope(name):
+        return norm(y, p[name], cfg)
+
+
 def mlp_residual(x, p, cfg: ModelConfig, train: bool = True, place: Placement = LOCAL,
                  router_x=None):
     """``router_x``: what a dropless expert layer's router reads where that is not
@@ -1493,7 +1554,7 @@ def mlp_residual(x, p, cfg: ModelConfig, train: bool = True, place: Placement = 
         normed = norm(x, p["mlp_norm"], cfg)
         with jax.named_scope("mlp"):
             y, stats = moe.moe_topk_block(normed, p["mlp"], cfg, place=place, router_x=router_x)
-        return x + y, stats
+        return x + post_norm(y, p, "post_mlp_norm", cfg), stats
     if cfg.moe_dropless:
         # a leading dense layer of such a model (``moe_dense_layers``): no router, no statistics
         return mlp_residual(x, p, cfg.replace(moe_experts=0), train=train, place=place), None
@@ -1513,9 +1574,10 @@ def mlp_residual(x, p, cfg: ModelConfig, train: bool = True, place: Placement = 
             lambda x_, pn_, pm_: mlp_block(normed(x_, pn_), pm_, cfg, train=train, place=place),
             policy=jax.checkpoint_policies.save_only_these_names("mlp_gate"),
         )
-        return residual_add(x, branch(x, p["mlp_norm"], p["mlp"]), cfg)
-    return residual_add(
-        x, mlp_block(norm(x, p["mlp_norm"], cfg), p["mlp"], cfg, train=train, place=place), cfg)
+        return residual_add(
+            x, post_norm(branch(x, p["mlp_norm"], p["mlp"]), p, "post_mlp_norm", cfg), cfg)
+    y = mlp_block(norm(x, p["mlp_norm"], cfg), p["mlp"], cfg, train=train, place=place)
+    return residual_add(x, post_norm(y, p, "post_mlp_norm", cfg), cfg)
 
 
 def cross_attn_block(x, enc_out, p, cfg: ModelConfig):
@@ -1539,10 +1601,10 @@ def encoder_layer(x, p, cfg: ModelConfig, cos_sin=None, remat_attn: bool = False
                   place: Placement = LOCAL):
     """Bidirectional self-attention + MLP (the enc-dec encoder stack)."""
     ecfg = cfg if not cfg.causal else cfg.replace(causal=False)
-    x = x + attn_block(
+    x = x + post_norm(attn_block(
         norm(x, p["attn_norm"], cfg), p["attn"], ecfg, cos_sin, None, remat_attn=remat_attn,
         place=place,
-    )
+    ), p, "post_attn_norm", cfg)
     return mlp_residual(x, p, cfg, place=place)
 
 
@@ -1567,14 +1629,15 @@ def decoder_layer(
     layers differ by a window or a position signal."""
     for kind in mixers.MIXERS:
         if kind in p:
-            x = residual_add(x, mixers.module(kind).block(
-                norm(x, p["attn_norm"], cfg), p[kind], cfg, place=place), cfg)
+            x = residual_add(x, post_norm(mixers.module(kind).block(
+                norm(x, p["attn_norm"], cfg), p[kind], cfg, place=place), p, "post_attn_norm",
+                cfg), cfg)
             return mlp_residual(x, p, cfg, place=place)
     normed = norm(x, p["attn_norm"], cfg)
-    x = residual_add(x, attn_block(
+    x = residual_add(x, post_norm(attn_block(
         normed, p["attn"], cfg, cos_sin, alibi,
         remat_attn=remat_attn, seg_ids=seg_ids, place=place,
-    ), cfg)
+    ), p, "post_attn_norm", cfg), cfg)
     if enc_out is not None and "cross" in p:
         x = x + cross_attn_block(norm(x, p["cross_norm"], cfg), enc_out, p["cross"], cfg)
     if cfg.moe_router_input == "attn":  # the router reads what the attention block read
@@ -2218,7 +2281,8 @@ PRESETS: Dict[str, ModelConfig] = {
     "qwen3-next-80b-a3b": ModelConfig(
         vocab_size=151936, hidden_size=2048, num_layers=48, num_heads=16, num_kv_heads=2,
         attn_head_dim=256, ffn_dim=5120, max_seq_len=262144, rope_theta=1e7, norm_eps=1e-6,
-        rotary_fraction=0.25, attn_gate=True, norm_zero_centered=True,
+        rotary_fraction=0.25, attn_gate=True, qk_norm=True, qk_norm_per_head=True,
+        norm_zero_centered=True,
         layer_kinds=tuple("attention" if (i + 1) % 4 == 0 else "gdn" for i in range(48)),
         gdn_key_heads=16, gdn_value_heads=32, gdn_key_dim=128, gdn_value_dim=128, gdn_conv=4,
         gdn_chunk=64, moe_experts=512, moe_router="softmax_topk", moe_top_k=10,
@@ -2273,5 +2337,24 @@ PRESETS: Dict[str, ModelConfig] = {
         + ("attention", "shortconv"),
         moe_experts=64, moe_router="sigmoid_topk", moe_top_k=4, moe_route_scale=1.0,
         moe_ffn_dim=1536, moe_norm_topk=True, moe_dense_layers=2,
+    ),
+    # arcee-ai/Trinity-Large-Preview (model_type afmoe): 60 layers in periods of four,
+    # three SLIDING-WINDOW layers of 4096 keys with rotary (theta 1e4) then one FULL
+    # layer without any position signal; GQA 48 / 8 heads of 128, per-head q/k RMSNorm,
+    # an OUTPUT GATE on the attention (``attn_gate``), a norm AFTER each block
+    # (``post_norms``: four RMSNorms a layer); the embedding scaled by sqrt(hidden)
+    # (``mup_enabled``); layers 0-5 a SwiGLU MLP of 12288, the other 54 carry 256
+    # experts of width 3072, sigmoid scores with a selection bias, top-4 renormalised
+    # x 2.448, one ungated shared expert of 3072; untied head. Served (the window
+    # layers' keys and values in a ring, as smallthinker's).
+    "trinity-large-preview": ModelConfig(
+        vocab_size=200192, hidden_size=3072, num_layers=60, num_heads=48, num_kv_heads=8,
+        attn_head_dim=128, ffn_dim=12288, max_seq_len=262144, rope_theta=10000.0, norm_eps=1e-5,
+        sliding_window_size=4096, sliding_window_layout=(1, 1, 1, 0) * 15,
+        rope_layout=(1, 1, 1, 0) * 15, qk_norm=True, qk_norm_per_head=True,
+        attn_gate=True, post_norms=True, embedding_multiplier=3072 ** 0.5,
+        moe_experts=256, moe_router="sigmoid_topk", moe_top_k=4, moe_route_scale=2.448,
+        moe_ffn_dim=3072, moe_norm_topk=True, moe_shared_ffn_dim=3072, moe_shared_gate=False,
+        moe_dense_layers=6,
     ),
 }

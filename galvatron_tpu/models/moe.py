@@ -488,6 +488,14 @@ def held_pairs_per_token(stats, held: Tuple[int, int]) -> jax.Array:
     return jnp.mean(jnp.sum(f[:, held[0]:held[0] + held[1]], axis=1))
 
 
+def held_experts_touched(stats, held: Tuple[int, int]) -> jax.Array:
+    """Held experts that got at least one pair, mean over the layers: ``count`` when
+    every held expert has a row, ``count * (1 - e^-r)`` at ``r`` rows an expert on
+    average under even routing."""
+    f = jnp.stack([s[0] for s in stats])
+    return jnp.mean(jnp.sum((f[:, held[0]:held[0] + held[1]] > 0).astype(jnp.float32), axis=1))
+
+
 def held_rows_share(stats) -> jax.Array:
     """Rows of the worst-case buffer whose tiles are in use (``num_tiles * tile``
     over the buffer's rows), mean over the layers: of a held share's statistics

@@ -362,6 +362,12 @@ def build_runtime(
             "context parallelism (cp>1) is not implemented with attention_multiplier: "
             "the ring/Ulysses layers scale by 1/sqrt(head_dim); use cp=1"
         )
+    if (cfg.attn_gate or cfg.post_norms) and any(s.cp > 1 for s in hp.layer_strategies):
+        raise ValueError(
+            "context parallelism (cp>1) is not implemented with attn_gate or post_norms: the "
+            "ring/Ulysses layers project and add their output themselves, without the gate "
+            "and without the norms after a block; use cp=1"
+        )
     seq_len = seq_len or cfg.sample_len
 
     # the strategy's activation-recompute mode rides the model config so

@@ -73,8 +73,8 @@ def layer_param_count(cfg: ModelConfig, cross: bool = False, kind: str = "attent
     h, hd = cfg.hidden_size, cfg.head_dim
     q_out, kv_out = cfg.num_heads * hd, cfg.kv_heads * hd
     attn = h * q_out + 2 * h * kv_out + q_out * h
-    if cfg.attn_gate:  # the output gate's projection and the two per-head norms
-        attn += h * q_out + 2 * hd
+    if cfg.attn_gate:  # the output gate's projection
+        attn += h * q_out
     if kind in mixers.MIXERS:
         attn = mixers.module(kind).param_count(cfg)
     if cross:
@@ -92,7 +92,7 @@ def layer_param_count(cfg: ModelConfig, cross: bool = False, kind: str = "attent
         mlp = 3 * h * cfg.ffn
     else:
         mlp = 2 * h * cfg.ffn
-    norms = 2 * h if cfg.norm_type == "rms" else 4 * h
+    norms = (4 if cfg.post_norms else 2) * (h if cfg.norm_type == "rms" else 2 * h)
     if cfg.qk_norm and kind not in mixers.MIXERS:
         norms += 2 * hd if cfg.qk_norm_per_head else q_out + kv_out
     bias = 0

@@ -126,15 +126,17 @@ def _router_counters(stats, cfg: ModelConfig, tokens: int) -> Dict[str, jax.Arra
     """A forward's expert-layer counters from its layers' router statistics, over
     the forward's ``tokens`` tokens (rows without a request among them): the (token,
     expert) pairs a token puts on the experts held here, the fullest held expert's
-    pairs over the even share and, of a held share, the share of the rows its kernels
-    multiply that hold a pair (`moe.live_rows_share`). Empty for a model without
-    dropless expert layers."""
+    pairs over the even share, the held experts that got a row at all
+    (`moe.held_experts_touched`: `moe.held_layout` gives the others a tile too) and, of a
+    held share, the share of the rows its kernels multiply that hold a pair
+    (`moe.live_rows_share`). Empty for a model without dropless expert layers."""
     if not stats:
         return {}
     held = (cfg.moe_first_held, cfg.moe_held)
     out = {"moe_held_pairs_per_token": moe.held_pairs_per_token(stats, held),
            "moe_load_imbalance": moe.load_max_over_mean(
-               stats, cfg.moe_experts, cfg.moe_top_k, held)}
+               stats, cfg.moe_experts, cfg.moe_top_k, held),
+           "moe_held_experts_touched": moe.held_experts_touched(stats, held)}
     if cfg.moe_holds_share:
         out["moe_live_rows_share"] = moe.live_rows_share(stats, cfg, tokens)
     return out
@@ -681,9 +683,9 @@ class Engine:
         attention FETCHES for them by construction (a window of ``window`` queries
         a row; `generation.cache_read_positions`: live over read is the share of
         the fetched bytes that were needed) and, of a model with dropless expert
-        layers, the row tile the step's expert layers took (`moe.layer_row_tile`)
-        and the step's `_router_counters` of the last iteration the tracer saw
-        (host numbers: no caller waits for the device)."""
+        layers, the row tile the step's expert layers took (`moe.layer_row_tile`),
+        the experts held here and the step's `_router_counters` of the last
+        iteration the tracer saw (host numbers: no caller waits for the device)."""
         layout, lengths = self.cache_layout, self.slots.lengths
         slots = self.slots.active_slots() if slots is None else slots
         # (on the loop's thread between two spans: no array is built here)
@@ -712,8 +714,10 @@ class Engine:
         elif read is not None:
             out[f"{layout['kind']}_read_positions"] = read
         if self.cfg.moe_dropless:
-            # the row tile the step's expert layers compiled with (static: by shape)
+            # the row tile the step's expert layers compiled with (static: by shape), and
+            # the experts this copy holds, which `moe_held_experts_touched` counts among
             out["moe_row_tile"] = moe.layer_row_tile(self.cfg, self.slots.num_slots * window)
+            out["moe_held_experts"] = self.cfg.moe_held
         return {**out, **self._router_counters}
 
     @property

@@ -61,8 +61,9 @@ LENGTHS = [1, BLOCK - 1, BLOCK, 2 * BLOCK, BLOCK + 1, POSITIONS, 0]
 
 # grouped query heads a key/value head and the head's size: whole lane tiles (the stacks
 # read as they are handed), and a head of 64, half a lane tile (read TRANSPOSED, the keys
-# on the lanes)
-HEADS = pytest.mark.parametrize("g,d", [(7, D), (1, D), (4, 64)], ids=["7", "1", "g4_d64"])
+# on the lanes); 6 query heads a key/value head are Trinity-Large's 48 / 8 (PR 61)
+HEADS = pytest.mark.parametrize("g,d", [(7, D), (6, D), (1, D), (4, 64)],
+                                ids=["7", "6", "1", "g4_d64"])
 
 
 @pytest.mark.parametrize("layer", [0, 2])

@@ -430,6 +430,65 @@ def test_the_training_forward_opens_the_conv_layers_three_scopes():
     assert not [n for n in names if "state_read" in n or "state_write" in n]
 
 
+#: gated attention under sandwich norms (PR 61): ``gate`` between ``attn_core`` and
+#: ``out_proj`` under ``window`` | ``full``; ``post_attn_norm`` behind the attention,
+#: ``post_mlp_norm`` behind the MLP (the names a reader of the sandwich norms would key on)
+GATED_SCOPES = ("layer_0/attn/window/gate", "layer_3/attn/full/gate",
+                "layer_0/attn/post_attn_norm", "layer_3/attn/post_attn_norm",
+                "layer_0/post_mlp_norm", "layer_1/post_mlp_norm", "layer_1/mlp/shared_expert",
+                "layer_3/attn/full/attn_core", "layer_4/attn/window/cache_write")
+
+
+def _gated_cfg():
+    from galvatron_tpu.models.modeling import PRESETS
+
+    return PRESETS["trinity-large-preview"].replace(
+        vocab_size=128, hidden_size=32, num_layers=5, num_heads=4, num_kv_heads=2,
+        attn_head_dim=8, ffn_dim=48, max_seq_len=32, sliding_window_size=8, moe_experts=8,
+        moe_top_k=2, moe_ffn_dim=24, moe_shared_ffn_dim=24, moe_dense_layers=1)
+
+
+@pytest.fixture(scope="module")
+def gated_texts():
+    from galvatron_tpu.aot import registry
+    from galvatron_tpu.serving import engine  # noqa: F401  (registers the serving family)
+
+    ctx = registry.ProgramContext(cfg=_gated_cfg(), num_slots=2, prefill_chunk=8, max_seq_len=32)
+    return {spec.name: spec.fn.lower(*spec.args).compile().as_text()
+            for spec in registry.enumerate_programs(ctx, include=("serving",))}
+
+
+@pytest.mark.parametrize("scope", GATED_SCOPES)
+@pytest.mark.parametrize("program", SERVING_PROGRAMS[:2])
+def test_a_gated_sandwich_stacks_serving_program_carries_the_scope(gated_texts, program, scope):
+    import re
+
+    names = re.findall(r'op_name="([^"]*)"', gated_texts[program])
+    assert any(re.search(rf"[/(]{scope}(?:[/)]|$)", n) for n in names), (program, scope)
+
+
+def test_the_training_forward_opens_the_gate_and_the_post_norms(serving_texts):
+    import re
+
+    import jax
+
+    from galvatron_tpu.models import modeling
+
+    cfg = _gated_cfg()
+    shapes = jax.eval_shape(lambda k: modeling.init_model_params(k, cfg), jax.random.key(0))
+    tokens = jax.ShapeDtypeStruct((2, 16), "int32")
+    text = jax.jit(lambda p, t: modeling.forward(p, t, cfg)).lower(shapes, tokens).compile().as_text()
+    names = re.findall(r'op_name="([^"]*)"', text)
+    for scope in ("layer_2/attn/gate", "layer_3/post_attn_norm", "layer_0/post_mlp_norm",
+                  "layer_4/post_mlp_norm"):
+        assert any(scope in n for n in names), scope
+    # and no other stack's program carries any of the three
+    for program, plain in serving_texts.items():
+        plain_names = re.findall(r'op_name="([^"]*)"', plain)
+        assert not [n for n in plain_names
+                    if re.search(r"/(gate|post_attn_norm|post_mlp_norm)(/|$)", n)], program
+
+
 def test_a_plain_stacks_serving_programs_carry_neither_window_nor_full(serving_texts):
     """The two scopes are a windowed stack's alone: the accepted cells' op names, the
     recorded fixtures and ``lib/scoped.SCOPES`` stay as they are."""

@@ -124,11 +124,12 @@ def test_preset_runs_the_published_widths():
     assert set(p.kinds) == {"attention", "gdn"} and p.moe_held == 512 and p.moe_norm_topk
     # head_dim is its own field only here, where latent attention gives the query a
     # head of its own (tests/test_mla.py) and in smallthinker-21b-a3b (28 heads of 128
-    # on a hidden size of 2560: tests/test_smallthinker.py): every other preset keeps
-    # hidden / heads
+    # on a hidden size of 2560: tests/test_smallthinker.py) and trinity-large-preview (48
+    # heads of 128 on 3072: tests/test_trinity.py): every other preset keeps hidden / heads
+    own_head = ("smallthinker-21b-a3b", "trinity-large-preview")
     for name, other in PRESETS.items():
         if name != "qwen3-next-80b-a3b" and not other.mla_kv_rank:
-            assert (other.attn_head_dim is None) == (name != "smallthinker-21b-a3b")
+            assert (other.attn_head_dim is None) == (name not in own_head)
             assert other.rotary_dim == other.head_dim
             # (lfm2-24b-a2b's two leading dense layers have a width of their own, 11776
             # beside experts of 1536: tests/test_lfm2.py)
