@@ -21,15 +21,20 @@ One JSON line a measurement, the table at the end; no CPU fallback.
 
 ``--serve``: the table `ops/grouped_matmul.row_tile`'s constant is read from. The
 bounded forward alone (layout + `moe.held_experts`, the ``(w1, w3)`` pair held in
-bf16 as `cli serve --param_dtype bf16` holds it) at the four serving shapes, a decode
+bf16 as `cli serve --param_dtype bf16` holds it) at the serving shapes, a decode
 step's 32 tokens and a prompt chunk's 1,024 of `sarvam-105b_serve_long_above_knee`
-(top-8 over 128 scored, 32 held, 4096 x 2048) and of
+(top-8 over 128 scored, 32 held, 4096 x 2048), of
 `smallthinker-21b-a3b_serve_long_above_knee` (top-6 over 64, 16 held, 2560 x 768,
-ReGLU), at each row tile: ms a call, the GB/s that makes of the held weights' bytes
-(every held expert's three matrices once), the buffer's rows in use, the largest
-difference from the tile-256 output, the largest device operations. ``--skew`` sets
-the routers' loads (0 even, 1 a few favourites); ``--tiny`` rehearses the mode at
-small widths on any backend.
+ReGLU) and of `trinity-large-preview_serve_agent_above_knee` (top-4 over 256, 32
+held, 3072 x 3072), at each row tile: ms a call, the GB/s that makes of the held
+weights' bytes (every held expert's three matrices once), the buffer's rows in use,
+the largest difference from the tile-256 output, the largest device operations; and
+beside each, the forward-only layout (PR 62: `held_layout(empty_tiles=False)` under
+`moe.held_forward`, what a cached forward runs): its ms, the held experts that got
+a row (whose weights alone it fetches), the rows its tiles cover, and whether its
+output is the default layout's to the bit. ``--skew`` sets the routers' loads (0
+even, 1 a few favourites); ``--tiny`` rehearses the mode at small widths on any
+backend.
 """
 
 from __future__ import annotations
@@ -91,10 +96,11 @@ def bounded(x, weights, w1, w3, w2, idx):
 
 BODIES = {"plain": plain, "bounded": bounded}
 
-#: the expert layers of the two long serving cells (scored, held, top-k, hidden, width, gate)
+#: the expert layers of three held-share serving cells (scored, held, top-k, hidden, width, gate)
 SERVE_SHAPES = {
     "sarvam-105b": (128, 32, 8, 4096, 2048, "silu"),
     "smallthinker-21b-a3b": (64, 16, 6, 2560, 768, "relu"),
+    "trinity-large-preview": (256, 32, 4, 3072, 3072, "silu"),
 }
 TINY_SHAPES = {"tiny": (16, 4, 2, 256, 128, "silu")}
 
@@ -117,10 +123,12 @@ def serve_inputs(tokens, experts, held, top_k, hidden, width, skew=1.0, seed=0,
     return x, weights, w1, w3, w2, idx.astype(jnp.int32)
 
 
-def serve_forward(x, weights, w1, w3, w2, idx, *, held, tile, act):
-    lay = moe.held_layout(idx, held, tile, 0)
-    return moe.held_experts(x, weights, (w1, w3), w2, lay.pair_row, lay.row_pair, lay.row_valid,
-                            lay.tile_group, lay.num_tiles, tile, act)
+def serve_forward(x, weights, w1, w3, w2, idx, *, held, tile, act, empty_tiles=True):
+    """``empty_tiles`` False: what `moe._topk_local` runs for a cached forward."""
+    lay = moe.held_layout(idx, held, tile, 0, empty_tiles=empty_tiles)
+    run = moe.held_experts if empty_tiles else moe.held_forward
+    return run(x, weights, (w1, w3), w2, lay.pair_row, lay.row_pair, lay.row_valid,
+               lay.tile_group, lay.num_tiles, tile, act)
 
 
 def serve(args) -> int:
@@ -136,12 +144,19 @@ def serve(args) -> int:
             mean_rows = tokens * top_k / experts
             want = None
             for tile in sorted(tiles, reverse=True):  # 256 first: the one the others are held to
-                fwd = jax.jit(functools.partial(serve_forward, held=held, tile=tile, act=act))
+                fwd, only = (jax.jit(functools.partial(serve_forward, held=held, tile=tile,
+                                                       act=act, empty_tiles=e))
+                             for e in (True, False))
+                iters = 3 if tiny else 30
                 y = jax.block_until_ready(fwd(*operands))
-                ms = timed(fwd, *operands, iters=3 if tiny else 30)
-                ops = device_ops(fwd, operands, top=args.ops) if args.ops and not tiny else []
-                lay = jax.jit(functools.partial(moe.held_layout, held=held, tile=tile,
-                                                first_held=0))(operands[-1])
+                ms = timed(fwd, *operands, iters=iters)
+                y_only = jax.block_until_ready(only(*operands))
+                only_ms = timed(only, *operands, iters=iters)
+                ops, only_ops = ((device_ops(f, operands, top=args.ops) for f in (fwd, only))
+                                 if args.ops and not tiny else ([], []))
+                lay, lay_only = (jax.jit(functools.partial(
+                    moe.held_layout, held=held, tile=tile, first_held=0, empty_tiles=e))(
+                        operands[-1]) for e in (True, False))
                 want = y if want is None else want
                 row = {"model": model, "tokens": tokens, "skew": skew,
                        "mean_rows_an_expert": mean_rows, "tile": tile, "fwd_ms": ms,
@@ -149,20 +164,29 @@ def serve(args) -> int:
                        "buffer_rows": int(lay.row_valid.shape[0]),
                        "rows_in_use": int(lay.num_tiles[0]) * tile,
                        "held_pairs": int(jnp.sum(lay.sizes)),
+                       "experts_touched": int(jnp.sum(lay.sizes > 0)), "experts_held": held,
+                       "fwd_only_ms": only_ms,
+                       "fwd_only_rows_in_use": int(lay_only.num_tiles[0]) * tile,
+                       "fwd_only_is_the_default_to_the_bit": bool(jnp.array_equal(y_only, y)),
+                       "fwd_only_device_ops_ms": only_ops,
                        "rel_to_256": rel(y.astype(jnp.float32), want.astype(jnp.float32)),
                        "finite": bool(jnp.isfinite(y.astype(jnp.float32)).all()),
                        "device_ops_ms": ops}
                 print(json.dumps(row), flush=True)
                 rows.append(row)
     print("| model | tokens | skew | mean rows an expert | tile | rows in use / buffer | "
-          "held pairs | fwd ms | GB/s of the weights |")
-    print("| --- | --- | --- | --- | --- | --- | --- | --- | --- |")
+          "held pairs | fwd ms | GB/s of the weights | touched / held | forward-only rows | "
+          "forward-only ms | same bits |")
+    print("| --- | --- | --- | --- | --- | --- | --- | --- | --- | --- | --- | --- | --- |")
     for r in rows:
         print(f"| {r['model']} | {r['tokens']} | {r['skew']:g} | {r['mean_rows_an_expert']:g} | "
               f"{r['tile']} | {r['rows_in_use']} / {r['buffer_rows']} | {r['held_pairs']} | "
-              f"{r['fwd_ms']:.3f} | {r['weights_gb_per_s']:.0f} |")
+              f"{r['fwd_ms']:.3f} | {r['weights_gb_per_s']:.0f} | "
+              f"{r['experts_touched']} / {r['experts_held']} | {r['fwd_only_rows_in_use']} | "
+              f"{r['fwd_only_ms']:.3f} | {r['fwd_only_is_the_default_to_the_bit']} |")
     worst = max(r["rel_to_256"] for r in rows)
-    ok = all(r["finite"] for r in rows) and worst < 0.02
+    ok = (all(r["finite"] and r["fwd_only_is_the_default_to_the_bit"] for r in rows)
+          and worst < 0.02)
     print(json.dumps({"ok": ok, "worst_rel_to_256": worst,
                       "device": str(np.asarray(jax.devices())[0])}))
     return 0 if ok else 1

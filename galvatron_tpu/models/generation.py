@@ -537,7 +537,9 @@ def _mlp_at(x, p, cfg: ModelConfig, moe_stats: Optional[list], router_x=None):
     from galvatron_tpu.models import moe
 
     with jax.named_scope("mlp"):
-        y, stats = moe.moe_topk_block(normed, p["mlp"], cfg, router_x=router_x)
+        # (a cached forward is never differentiated: `moe_topk_block`)
+        y, stats = moe.moe_topk_block(normed, p["mlp"], cfg, router_x=router_x,
+                                      forward_only=True)
     if moe_stats is not None:
         moe_stats.append(stats)
     return y

@@ -252,16 +252,25 @@ def layer_row_tile(cfg, tokens: int, dtype=None) -> int:
     return row_tile(tokens, cfg.moe_top_k, cfg.moe_experts, dtype or cfg.dtype)
 
 
-def sorted_layout(expert_idx: jax.Array, num_experts: int, tile: int) -> SortedLayout:
+def _group_tiles(sizes, tile: int, empty_tiles: bool):
+    """Whole ``tile``-row tiles of groups of ``sizes`` rows; an empty group's one or none."""
+    return jnp.maximum(-(-sizes // tile), 1 if empty_tiles else 0)
+
+
+def sorted_layout(expert_idx: jax.Array, num_experts: int, tile: int,
+                  empty_tiles: bool = True) -> SortedLayout:
     """Sort the pairs of ``expert_idx`` (T, k) by expert, stably, into groups
     of whole ``tile``-row tiles; an expert without a pair still owns one
-    (all-padding) tile, so that its weight gradient is written."""
+    (all-padding) tile, so that its weight gradient is written. ``empty_tiles``
+    False (a forward that is never differentiated): it owns none, no tile names
+    it and its weights are never fetched; ``num_tiles`` is then 0 where no expert
+    got a pair. The buffer's rows are the same either way."""
     flat = expert_idx.reshape(-1).astype(jnp.int32)
     pairs = flat.shape[0]
     rows = buffer_rows(pairs, num_experts, tile)
     sizes = jnp.bincount(flat, length=num_experts).astype(jnp.int32)
     order = jnp.argsort(flat, stable=True).astype(jnp.int32)  # sorted position -> pair
-    tiles = jnp.maximum(-(-sizes // tile), 1)
+    tiles = _group_tiles(sizes, tile, empty_tiles)
     tile_end = jnp.cumsum(tiles)
     row_start = (tile_end - tiles) * tile  # a group's first row
     pair_start = jnp.cumsum(sizes) - sizes  # its first sorted position
@@ -280,12 +289,13 @@ def sorted_layout(expert_idx: jax.Array, num_experts: int, tile: int) -> SortedL
                         sizes)
 
 
-def held_layout(expert_idx, held: int, tile: int, first_held: int) -> SortedLayout:
+def held_layout(expert_idx, held: int, tile: int, first_held: int,
+                empty_tiles: bool = True) -> SortedLayout:
     """`sorted_layout` of a held share: the ``held`` experts from ``first_held``
-    on, of those ``expert_idx`` names. The dropped pairs sort behind the held
-    groups as one group more, whose tiles lie past ``num_tiles``
-    (``pair_row >= num_tiles * tile`` says "not held"): a dropped pair adds
-    nothing forward and takes nothing backward. The buffer keeps its worst-case
+    on, of those ``expert_idx`` names (``empty_tiles``: as there). The dropped
+    pairs sort behind the held groups as one group more, whose tiles lie past
+    ``num_tiles`` (``pair_row >= num_tiles * tile`` says "not held"): a dropped pair
+    adds nothing forward and takes nothing backward. The buffer keeps its worst-case
     size (every pair held): shapes are static, and no pair that is held is ever
     dropped. What lies past ``num_tiles`` depends on who runs over the layout:
     the plain path (`_dispatch`, `grouped_gemm`, `_combine`) writes zeros there
@@ -293,8 +303,8 @@ def held_layout(expert_idx, held: int, tile: int, first_held: int) -> SortedLayo
     UNDEFINED and every reader masks by index."""
     local = expert_idx.astype(jnp.int32) - first_held
     dropped = (local < 0) | (local >= held)
-    full = sorted_layout(jnp.where(dropped, held, local), held + 1, tile)
-    held_tiles = jnp.sum(jnp.maximum(-(-full.sizes[:held] // tile), 1)).astype(jnp.int32)
+    full = sorted_layout(jnp.where(dropped, held, local), held + 1, tile, empty_tiles)
+    held_tiles = jnp.sum(_group_tiles(full.sizes[:held], tile, empty_tiles)).astype(jnp.int32)
     rows = full.row_valid.shape[0]
     in_held = jnp.arange(rows, dtype=jnp.int32) < held_tiles * tile
     return SortedLayout(
@@ -389,6 +399,14 @@ def held_experts(x, weights, w13, w2, pair_row, row_pair, row_valid, tile_group,
     gradient is summed by XLA over the whole buffer either."""
     return _held_forward(x, weights, w13, w2, pair_row, row_pair, row_valid, tile_group,
                          num_tiles, tile, act)[0]
+
+
+def held_forward(*args):
+    """`held_experts` (same arguments) as the forward body alone, with no VJP: what a
+    forward that is never differentiated runs over a layout without empty tiles
+    (`sorted_layout`), where a gradient must raise (Pallas has no JVP of these calls)
+    and never be read out of weight blocks that no tile wrote."""
+    return _held_forward(*args)[0]
 
 
 def _held_forward(x, weights, w13, w2, pair_row, row_pair, row_valid, tile_group, num_tiles, tile,
@@ -491,7 +509,9 @@ def held_pairs_per_token(stats, held: Tuple[int, int]) -> jax.Array:
 def held_experts_touched(stats, held: Tuple[int, int]) -> jax.Array:
     """Held experts that got at least one pair, mean over the layers: ``count`` when
     every held expert has a row, ``count * (1 - e^-r)`` at ``r`` rows an expert on
-    average under even routing."""
+    average under even routing. The experts whose weights a forward-only held share
+    fetches (`sorted_layout`: the others own no tile there); a differentiated one
+    fetches all ``count``."""
     f = jnp.stack([s[0] for s in stats])
     return jnp.mean(jnp.sum((f[:, held[0]:held[0] + held[1]] > 0).astype(jnp.float32), axis=1))
 
@@ -507,17 +527,23 @@ def live_rows_share(stats, cfg, tokens: int) -> jax.Array:
     """Of the rows a held share's kernels multiply and move (``num_tiles * tile``), the
     share that holds a pair, over the layers of a forward of ``tokens`` tokens at the
     tile the layers chose themselves: what is left of a tile once an expert's rows are
-    in it. From the layers' statistics and static shapes alone."""
+    in it (0 where no layer's share got a pair and no tile is in use). From the layers'
+    statistics and static shapes alone."""
     rows = buffer_rows(tokens * cfg.moe_top_k, cfg.moe_held + 1, layer_row_tile(cfg, tokens))
     pairs = held_pairs_per_token(stats, (cfg.moe_first_held, cfg.moe_held)) * tokens
-    return pairs / (held_rows_share(stats) * rows)
+    return pairs / jnp.maximum(held_rows_share(stats) * rows, 1e-9)
 
 
 def moe_topk_block(x: jax.Array, p: Params, cfg, tile: Optional[int] = None,
-                   place: Placement = LOCAL, router_x: Optional[jax.Array] = None):
+                   place: Placement = LOCAL, router_x: Optional[jax.Array] = None,
+                   forward_only: bool = False):
     """Dropless top-k MoE MLP on (B, S, H) -> (y, router_stats). ``router_x`` (B, S,
     H): what the router reads where that is not ``x`` (``cfg.moe_router_input``
     "attn": the attention block's normed input; split over the mesh like ``x``).
+    ``forward_only``: the caller never differentiates this forward (the cached
+    forwards of `models/generation`), so a bounded held share gives no tile to an
+    expert without a row (`sorted_layout`) and a decode step fetches the weights of
+    the experts it touched alone; differentiating such a forward raises (`held_forward`).
 
     On a multi-device mesh ``place.route_tokens`` runs the block on each
     device's own tokens, every expert's weights whole on every device, and
@@ -525,10 +551,12 @@ def moe_topk_block(x: jax.Array, p: Params, cfg, tile: Optional[int] = None,
     refused upstream (build_runtime)."""
     if router_x is not None:
         return place.route_tokens(
-            lambda xs, p_, over: _topk_local(xs[0], p_, cfg, tile, over, router_x=xs[1])
+            lambda xs, p_, over: _topk_local(xs[0], p_, cfg, tile, over, router_x=xs[1],
+                                             forward_only=forward_only)
         )((x, router_x), p)
     return place.route_tokens(
-        lambda x_, p_, over: _topk_local(x_, p_, cfg, tile, over))(x, p)
+        lambda x_, p_, over: _topk_local(x_, p_, cfg, tile, over, forward_only=forward_only)
+    )(x, p)
 
 
 def router_scores(xt, router: Params, cfg) -> jax.Array:
@@ -545,10 +573,10 @@ def router_scores(xt, router: Params, cfg) -> jax.Array:
     return jax.nn.sigmoid(logits) if sigmoid else jax.nn.softmax(logits, axis=-1)
 
 
-def _topk_local(x, p, cfg, tile, over, router_x=None):
+def _topk_local(x, p, cfg, tile, over, router_x=None, forward_only=False):
     """The block on the tokens this device holds; ``over``: the mesh axes the
     tokens are split on (the statistics are means over all of them); ``router_x``:
-    what the router reads in place of ``x`` (`moe_topk_block`).  The
+    what the router reads in place of ``x``, ``forward_only``: `moe_topk_block`.  The
     router's input, GEMM and softmax are fp32 whatever the compute dtype: a
     bf16 logit flips a choice wherever two probabilities lie within a bf16 ulp."""
     from galvatron_tpu.ops.moe_held import held_path
@@ -557,6 +585,8 @@ def _topk_local(x, p, cfg, tile, over, router_x=None):
     tokens, k, e = b * s, cfg.moe_top_k, cfg.moe_experts
     tile = tile or layer_row_tile(cfg, tokens, x.dtype)  # (trace-time, by shape)
     held_share = cfg.moe_holds_share  # trace-time: all held is the branch there always was
+    # a share of the experts pays for the pairs it holds (trace-time, by shape)
+    bounded = held_share and held_path(h, cfg.expert_ffn, x.dtype) == "bounded"
     xt = x.reshape(tokens, h)
     sigmoid = cfg.moe_router == "sigmoid_topk"
     with jax.named_scope("router"):
@@ -575,11 +605,15 @@ def _topk_local(x, p, cfg, tile, over, router_x=None):
             if cfg.moe_norm_topk:
                 weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
         if held_share:
-            layout = held_layout(idx, cfg.moe_held, tile, cfg.moe_first_held)
+            # an expert without a row owns a tile for its weight gradient's sake
+            # (`moe_tgmm`); a bounded forward that has none to write pays a fetch of the
+            # expert's weights for it, so it asks for none (the plain path runs over the
+            # whole buffer as it is, and keeps the layout its VJPs can take)
+            layout = held_layout(idx, cfg.moe_held, tile, cfg.moe_first_held,
+                                 empty_tiles=not (forward_only and bounded))
         else:
             layout = sorted_layout(idx, e, tile)
-    if held_share and held_path(h, cfg.expert_ffn, x.dtype) == "bounded":
-        # a share of the experts pays for the pairs it holds (trace-time, by shape)
+    if bounded:
         with jax.named_scope("experts"):
             # weights held in another type are converted every step, and that pass
             # joins gate and up into one stack for one GEMM; held in the compute type
@@ -589,9 +623,9 @@ def _topk_local(x, p, cfg, tile, over, router_x=None):
             w13 = ((p["w1"], p["w3"]) if p["w1"].dtype == x.dtype
                    else jnp.concatenate([p["w1"], p["w3"]], axis=-1).astype(x.dtype))
             w2 = p["w2"].astype(x.dtype)
-        y = held_experts(xt, weights, w13, w2, layout.pair_row, layout.row_pair,
-                         layout.row_valid, layout.tile_group, layout.num_tiles, tile,
-                         cfg.glu_act)
+        run = held_forward if forward_only else held_experts
+        y = run(xt, weights, w13, w2, layout.pair_row, layout.row_pair, layout.row_valid,
+                layout.tile_group, layout.num_tiles, tile, cfg.glu_act)
     else:
         with jax.named_scope("dispatch"):
             rows = _dispatch(xt, layout.row_pair // k, layout.row_valid, layout.pair_row)

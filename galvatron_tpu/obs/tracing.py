@@ -41,6 +41,7 @@ search engine, and serving engine all record into — enable it once
 from __future__ import annotations
 
 import gc
+import heapq
 import json
 import os
 import threading
@@ -76,6 +77,9 @@ class _NullSpan:
 
 
 _NULL_SPAN = _NullSpan()
+
+#: the ring of the rare spans (`Tracer`): a cold start of the largest cell leaves ~600
+RARE_SPANS = 4096
 
 #: collections shorter than this leave no ``gc`` span: a trace-and-lower makes
 #: hundreds of young-generation passes of ~0.1 ms that explain no slow step
@@ -171,13 +175,19 @@ class Tracer:
     ``enabled`` gates everything: disabled (the default), ``span``/``instant``
     return/do nothing without touching the clock. The ring is a
     ``deque(maxlen=capacity)`` — the flight recorder's "last N spans before
-    the crash" is exactly its contents (obs/flight.py dumps it)."""
+    the crash" is exactly its contents (obs/flight.py dumps it). The
+    ``jax.monitoring`` spans (a trace, a lowering, a backend compile: never in
+    a warm step) live in a small ring of their own, merged into
+    ``snapshot()`` where they ended: the hot path's spans, 37 an iteration of a 32-slot engine,
+    would otherwise push a run's compiles out within the minute, and "did
+    anything compile, and when" is read at a run's END."""
 
     def __init__(self, capacity: int = 4096):
         self.enabled = False
         #: a jax.profiler window is open: spans also open TraceAnnotations
         self.profiling = False
         self._ring: deque = deque(maxlen=capacity)
+        self._rare: deque = deque(maxlen=RARE_SPANS)
         self._local = threading.local()
         self._epoch_pc = time.perf_counter()
         self._epoch_wall = time.time()
@@ -204,6 +214,7 @@ class Tracer:
 
     def clear(self) -> None:
         self._ring.clear()
+        self._rare.clear()
 
     # -- recording ----------------------------------------------------------
 
@@ -231,10 +242,10 @@ class Tracer:
             }
         )
 
-    def _record(self, rec: Dict[str, Any]) -> None:
+    def _record(self, rec: Dict[str, Any], rare: bool = False) -> None:
         # deque.append with maxlen is atomic in CPython — no lock on the hot
         # path; snapshot() copies defensively for readers
-        self._ring.append(rec)
+        (self._rare if rare else self._ring).append(rec)
 
     def _stack_for_thread(self) -> List[Span]:
         st = getattr(self._local, "stack", None)
@@ -250,13 +261,14 @@ class Tracer:
         return None
 
     def record_span(self, name: str, dur_s: float, track: Optional[str] = None,
-                    **attrs) -> None:
+                    rare: bool = False, **attrs) -> None:
         """A span that ended now and lasted ``dur_s``, reported after the
         fact (a ``jax.monitoring`` duration event, a finished collection, a
         request's wait in the queue). Carries the ``step`` of the span open
         on this thread, if any. ``track`` puts it on a timeline track of that
         name that is no thread's: a wait that began before the spans open
-        here would break their nesting on the thread's own track."""
+        here would break their nesting on the thread's own track. ``rare``:
+        into the ring the hot path cannot push it out of (`Tracer`)."""
         if not self.enabled:
             return
         step = self.current_step()
@@ -277,7 +289,8 @@ class Tracer:
                 "tname": tname,
                 "depth": depth,
                 "args": attrs,
-            }
+            },
+            rare,
         )
 
     def _on_gc(self, phase: str, info: Dict[str, Any]) -> None:
@@ -303,7 +316,12 @@ class Tracer:
     # -- readout ------------------------------------------------------------
 
     def snapshot(self) -> List[Dict[str, Any]]:
-        return list(self._ring)
+        """Both rings' records in the order they were made (a record is made when its
+        span ends)."""
+        rare, ring = list(self._rare), list(self._ring)
+        if not rare:
+            return ring
+        return list(heapq.merge(rare, ring, key=lambda r: r["ts"] + r.get("dur", 0.0)))
 
     @property
     def epoch_wall(self) -> float:
@@ -373,7 +391,7 @@ def _on_jax_duration(event: str, duration_secs: float, **kw) -> None:
         attrs["hit"] = getattr(_compile_local, "hit", None)
         attrs["retrieval_s"] = getattr(_compile_local, "retrieval_s", None)
         _compile_local.hit = _compile_local.retrieval_s = None
-    tracer.record_span(name, float(duration_secs), **attrs)
+    tracer.record_span(name, float(duration_secs), rare=True, **attrs)
 
 
 def _install_jax_listeners() -> None:

@@ -15,8 +15,10 @@ most ``tile_m`` rows a group, ``tile_m / 2`` on average.
 - ``moe_gmm``: ``out[rows of g] = lhs[rows of g] @ rhs[g]`` (and, with
   ``transpose_rhs``, ``@ rhs[g].T``: the gradient of the left operand);
 - ``moe_tgmm``: ``out[g] = lhs[rows of g].T @ grad[rows of g]``, the
-  gradient of the weights; every group owns at least one tile, so every
-  output block is written.
+  gradient of the weights; every group owns at least one tile (the layout's
+  default, ``empty_tiles``: a forward that is never differentiated asks
+  `models/moe.sorted_layout` for none and pays no fetch of an empty group's
+  weights), so every output block is written.
 
 Row tiles past ``num_tiles`` (the static row count is an upper bound) are
 skipped: ``moe_gmm`` writes zeros there, ``moe_tgmm`` leaves them out.  For a
@@ -109,6 +111,15 @@ def _params(*semantics):
     return pltpu.CompilerParams(dimension_semantics=semantics, vmem_limit_bytes=_VMEM_LIMIT)
 
 
+def used_tile(i, count):
+    """The row tile a grid step ``i`` names in its block maps, of ``count`` (1,) used ones:
+    its own, or, skipped (``i >= count``; the body is under ``pl.when``), the last used
+    tile's, so that nothing is fetched or written for it.  ``count`` 0 (a forward-only
+    held share no token chose, `models/moe.sorted_layout`) names tile 0: the block before
+    the array is no block."""
+    return jnp.maximum(jnp.minimum(i, count[0] - 1), 0)
+
+
 def _gmm(lhs, rhs, tile_group, num_tiles, *, transpose_rhs: bool, tile_m: int, tile_n: int,
          bounded: bool = False, slab_out: bool = False):
     """``bounded`` (a held share, `models/moe.held_experts`; fixed at trace time):
@@ -145,17 +156,13 @@ def _gmm(lhs, rhs, tile_group, num_tiles, *, transpose_rhs: bool, tile_m: int, t
             def _():
                 out_ref[...] = jnp.zeros_like(out_ref)
 
-    # a skipped tile names the last used tile's blocks, so nothing is fetched for it
-    def used(i, count):
-        return jnp.minimum(i, count[0] - 1)
-
     rhs_block = (None, tn, k) if transpose_rhs else (None, k, tn)
     if slab_out:
         out_shape = jax.ShapeDtypeStruct((m, chunks, lanes), moe_held.slab_dtype(lhs.dtype))
-        out_spec = pl.BlockSpec((tile_m, chunks, lanes), lambda j, i, g, c: (used(i, c), 0, 0))
+        out_spec = pl.BlockSpec((tile_m, chunks, lanes), lambda j, i, g, c: (used_tile(i, c), 0, 0))
     else:
         out_shape = jax.ShapeDtypeStruct((m, n), lhs.dtype)
-        out_spec = pl.BlockSpec((tile_m, tn), (lambda j, i, g, c: (used(i, c), j))
+        out_spec = pl.BlockSpec((tile_m, tn), (lambda j, i, g, c: (used_tile(i, c), j))
                                 if bounded else (lambda j, i, g, c: (i, j)))
     return pl.pallas_call(
         kernel,
@@ -164,9 +171,9 @@ def _gmm(lhs, rhs, tile_group, num_tiles, *, transpose_rhs: bool, tile_m: int, t
             num_scalar_prefetch=2,
             grid=(n // tn, m // tile_m),
             in_specs=[
-                pl.BlockSpec((tile_m, k), lambda j, i, g, c: (used(i, c), 0)),
-                pl.BlockSpec(rhs_block, (lambda j, i, g, c: (g[used(i, c)], j, 0))
-                             if transpose_rhs else (lambda j, i, g, c: (g[used(i, c)], 0, j))),
+                pl.BlockSpec((tile_m, k), lambda j, i, g, c: (used_tile(i, c), 0)),
+                pl.BlockSpec(rhs_block, (lambda j, i, g, c: (g[used_tile(i, c)], j, 0))
+                             if transpose_rhs else (lambda j, i, g, c: (g[used_tile(i, c)], 0, j))),
             ],
             out_specs=out_spec,
         ),
@@ -203,9 +210,6 @@ def _tgmm(lhs, grad, tile_group, num_tiles, num_groups: int, *, tile_m: int, til
         def _():
             out_ref[...] = acc_ref[...].astype(out_ref.dtype)
 
-    def used(i, count):
-        return jnp.minimum(i, count[0] - 1)
-
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((num_groups, k, n), out_dtype),
@@ -213,8 +217,8 @@ def _tgmm(lhs, grad, tile_group, num_tiles, num_groups: int, *, tile_m: int, til
             num_scalar_prefetch=2,
             grid=(k // tk, n // tn, tiles),
             in_specs=[
-                pl.BlockSpec((tile_m, tk), lambda a, b, i, g, c: (used(i, c), a)),
-                pl.BlockSpec((tile_m, tn), lambda a, b, i, g, c: (used(i, c), b)),
+                pl.BlockSpec((tile_m, tk), lambda a, b, i, g, c: (used_tile(i, c), a)),
+                pl.BlockSpec((tile_m, tn), lambda a, b, i, g, c: (used_tile(i, c), b)),
             ],
             out_specs=pl.BlockSpec((None, tk, tn), lambda a, b, i, g, c: (g[i], a, b)),
             scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)],

@@ -44,6 +44,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from galvatron_tpu.ops import grouped_matmul as gm
+from galvatron_tpu.ops.grouped_matmul import used_tile
 
 LANES = 128
 _HIGH = 0xFFFF0000
@@ -108,11 +109,6 @@ def f32_to_words(pieces, dtype):
     return (low >> jnp.uint32(16)) | (high & jnp.uint32(_HIGH))
 
 
-def _used(i, count):
-    # a skipped tile names the last used tile's blocks: nothing fetched, nothing written
-    return jnp.minimum(i, count[0] - 1)
-
-
 def _wait_rows(n, src_ref, dst_ref, sem):
     """Wait for ``n`` row copies on ``sem`` (each wait takes one row's bytes)."""
     def body(_, carry):
@@ -171,7 +167,7 @@ def _gather_rows(src, row_token, tile_rows, num_tiles, scale=None, *, dtype, til
     in_specs = [pl.BlockSpec(memory_space=pl.ANY)]
     args = [src]
     if scale is not None:
-        in_specs.append(pl.BlockSpec((None, 1, tile), lambda i, t, r, c: (_used(i, c), 0, 0)))
+        in_specs.append(pl.BlockSpec((None, 1, tile), lambda i, t, r, c: (used_tile(i, c), 0, 0)))
         args.append(scale.astype(jnp.float32).reshape(tiles, 1, tile))
     return pl.pallas_call(
         kernel,
@@ -180,7 +176,7 @@ def _gather_rows(src, row_token, tile_rows, num_tiles, scale=None, *, dtype, til
             num_scalar_prefetch=3,
             grid=(tiles,),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((tile, h), lambda i, t, r, c: (_used(i, c), 0)),
+            out_specs=pl.BlockSpec((tile, h), lambda i, t, r, c: (used_tile(i, c), 0)),
             scratch_shapes=[pltpu.VMEM((tile, chunks, LANES), src.dtype),
                             pltpu.SemaphoreType.DMA(())],
         ),
@@ -363,8 +359,8 @@ def _swiglu(gate_up, num_tiles, *, tile, act="silu"):
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(m // tile,),
-            in_specs=[pl.BlockSpec((tile, f2), lambda i, c: (_used(i, c), 0))],
-            out_specs=pl.BlockSpec((tile, f), lambda i, c: (_used(i, c), 0)),
+            in_specs=[pl.BlockSpec((tile, f2), lambda i, c: (used_tile(i, c), 0))],
+            out_specs=pl.BlockSpec((tile, f), lambda i, c: (used_tile(i, c), 0)),
         ),
         compiler_params=gm._params("arbitrary"),
         interpret=gm._use_interpret(),
@@ -403,9 +399,9 @@ def _swiglu_bwd(gate_up, grad, num_tiles, *, tile, act="silu"):
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(m // tile,),
-            in_specs=[pl.BlockSpec((tile, f2), lambda i, c: (_used(i, c), 0)),
-                      pl.BlockSpec((tile, f), lambda i, c: (_used(i, c), 0))],
-            out_specs=pl.BlockSpec((tile, f2), lambda i, c: (_used(i, c), 0)),
+            in_specs=[pl.BlockSpec((tile, f2), lambda i, c: (used_tile(i, c), 0)),
+                      pl.BlockSpec((tile, f), lambda i, c: (used_tile(i, c), 0))],
+            out_specs=pl.BlockSpec((tile, f2), lambda i, c: (used_tile(i, c), 0)),
         ),
         compiler_params=gm._params("arbitrary"),
         interpret=gm._use_interpret(),

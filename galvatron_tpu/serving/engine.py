@@ -127,7 +127,8 @@ def _router_counters(stats, cfg: ModelConfig, tokens: int) -> Dict[str, jax.Arra
     the forward's ``tokens`` tokens (rows without a request among them): the (token,
     expert) pairs a token puts on the experts held here, the fullest held expert's
     pairs over the even share, the held experts that got a row at all
-    (`moe.held_experts_touched`: `moe.held_layout` gives the others a tile too) and, of a
+    (`moe.held_experts_touched`: the experts whose weights the forward fetched, since a
+    cached forward's `moe.held_layout` gives the others no tile) and, of a
     held share, the share of the rows its kernels multiply that hold a pair
     (`moe.live_rows_share`). Empty for a model without dropless expert layers."""
     if not stats:

@@ -415,6 +415,11 @@ def _tile_choices(load):
     outside = jax.random.randint(ks[0], (TILE_T, TILE_K), TILE_FIRST + TILE_HELD, TILE_E)
     if load == "none_held":
         return outside
+    if load == "decode":
+        # a decode step's few rows: two tokens name the third held expert, one the first,
+        # the other two held experts get nothing (most of the share is empty)
+        idx = outside.at[3, 1].set(TILE_FIRST + 2).at[90, 0].set(TILE_FIRST + 2)
+        return idx.at[17, 2].set(TILE_FIRST)
     t = jnp.arange(TILE_T)
     idx = outside.at[:, 0].set(jnp.where(t < 150, TILE_FIRST, outside[:, 0]))
     idx = idx.at[:, 1].set(jnp.where(jax.random.uniform(ks[1], (TILE_T,)) < 0.2,
@@ -435,11 +440,14 @@ def _tile_operands(dtype):
     return (x, weights, w1, w3, w2), cot
 
 
-def _tile_bounded(x, weights, w1, w3, w2, idx, *, tile, act, joined):
-    lay = moe.held_layout(idx, TILE_HELD, tile, TILE_FIRST)
+def _tile_bounded(x, weights, w1, w3, w2, idx, *, tile, act, joined, forward_only=False):
+    """``forward_only``: what `moe._topk_local` runs for a cached forward: the layout
+    without empty tiles under the forward body itself (no VJP)."""
+    lay = moe.held_layout(idx, TILE_HELD, tile, TILE_FIRST, empty_tiles=not forward_only)
     w13 = jnp.concatenate([w1, w3], axis=-1) if joined else (w1, w3)
-    return moe.held_experts(x, weights, w13, w2, lay.pair_row, lay.row_pair, lay.row_valid,
-                            lay.tile_group, lay.num_tiles, tile, act)
+    run = moe.held_forward if forward_only else moe.held_experts
+    return run(x, weights, w13, w2, lay.pair_row, lay.row_pair, lay.row_valid,
+               lay.tile_group, lay.num_tiles, tile, act)
 
 
 def _tile_plain(x, weights, w1, w3, w2, idx, *, tile, act):
@@ -516,3 +524,109 @@ def test_held_experts_backward_at_the_dtypes_floor_tile(dtype, tile, act, joined
     for name, g, w in zip(("y", "dx", "dweights", "dw1", "dw3", "dw2"), got, want):
         assert g.shape == w.shape and g.dtype == w.dtype, name
         _within(g, w, TILE_TOL[dtype][1])
+
+
+# -- a forward-only held share gives tiles to the experts that got a row (PR 62) ----------
+# `moe.held_layout(..., empty_tiles=False)`: what `generation._mlp_at` asks through
+# `moe_topk_block(forward_only=True)`; the default layout is the cases above, untouched.
+
+@pytest.mark.parametrize("load", ["skewed", "decode", "none_held"])
+@pytest.mark.parametrize("tile", [16, 32, 64, 128])
+def test_forward_only_layout_names_the_experts_that_got_a_pair(tile, load):
+    idx = _tile_choices(load)
+    lay = moe.held_layout(idx, TILE_HELD, tile, TILE_FIRST, empty_tiles=False)
+    default = moe.held_layout(idx, TILE_HELD, tile, TILE_FIRST)
+    sizes = np.asarray(lay.sizes)
+    assert (sizes == np.asarray(default.sizes)).all()
+    assert {"skewed": (sizes > 0).sum() == 3, "decode": list(sizes) == [1, 0, 2, 0],
+            "none_held": not sizes.any()}[load]
+    count = int(lay.num_tiles[0])
+    assert count == sum(-(-int(n) // tile) for n in sizes)
+    assert int(default.num_tiles[0]) == count + int((sizes == 0).sum())
+    groups = list(np.asarray(lay.tile_group)[:count])
+    assert groups == sorted(groups)
+    assert [groups.count(g) for g in range(TILE_HELD)] == [-(-int(n) // tile) for n in sizes]
+    # the buffer's rows are the default's (static, worst case); every held pair has a row
+    # of its own expert's tiles, before ``num_tiles * tile``, and no other pair has
+    assert lay.row_valid.shape == default.row_valid.shape
+    assert lay.row_valid.shape[0] == moe.buffer_rows(TILE_T * TILE_K, TILE_HELD + 1, tile)
+    assert int(lay.row_valid.sum()) == int(sizes.sum())
+    local = np.asarray(idx).reshape(-1) - TILE_FIRST
+    held = (local >= 0) & (local < TILE_HELD)
+    pair_row = np.asarray(lay.pair_row)
+    assert ((pair_row < count * tile) == held).all()
+    assert (np.asarray(lay.tile_group)[pair_row[held] // tile] == local[held]).all()
+    assert (np.asarray(lay.row_pair)[pair_row[held]] == np.flatnonzero(held)).all()
+    assert len(set(pair_row[held])) == int(held.sum())
+
+
+def test_sorted_layout_without_empty_tiles_keeps_the_groups_order():
+    """`moe.sorted_layout` itself (every expert held): experts 1 and 3 of 5 without a pair,
+    leading, inner and trailing groups of none."""
+    idx = jnp.asarray([[2, 4], [2, 0], [4, 2], [2, 2]], jnp.int32)
+    lay = moe.sorted_layout(idx, 5, 2, empty_tiles=False)
+    assert list(np.asarray(lay.sizes)) == [1, 0, 5, 0, 2] and int(lay.num_tiles[0]) == 5
+    assert list(np.asarray(lay.tile_group)[:5]) == [0, 2, 2, 2, 4]
+    assert list(np.asarray(lay.row_valid)[:10]) == [True, False] + [True] * 5 + [False, True, True]
+    assert not np.asarray(lay.row_valid)[10:].any()
+    assert list(np.asarray(moe.sorted_layout(idx, 5, 2).tile_group)[:7]) == [0, 1, 2, 2, 2, 3, 4]
+    lone = moe.sorted_layout(jnp.asarray([[0, 0]], jnp.int32), 5, 2, empty_tiles=False)
+    assert int(lone.num_tiles[0]) == 1 and list(np.asarray(lone.pair_row)) == [0, 1]
+
+
+@pytest.mark.parametrize("joined", [True, False], ids=["w13", "w1_w3"])
+@pytest.mark.parametrize("act", ["silu", "relu"], ids=["swiglu", "reglu"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bf16"])
+@pytest.mark.parametrize("tile", [16, 32])
+@pytest.mark.parametrize("load", ["skewed", "decode"])
+def test_forward_only_held_share_is_the_default_layouts_forward_exactly(load, tile, dtype, act,
+                                                                        joined):
+    """The same pairs by the same weights in the same order, only the rows' places in the
+    buffer move: not a bit of the output does."""
+    idx = _tile_choices(load)
+    operands, _ = _tile_operands(dtype)
+    want = _tile_bounded(*operands, idx, tile=tile, act=act, joined=joined)
+    got = _tile_bounded(*operands, idx, tile=tile, act=act, joined=joined, forward_only=True)
+    assert got.dtype == want.dtype and np.isfinite(np.asarray(got, np.float32)).all()
+    assert float(jnp.abs(want.astype(jnp.float32)).max()) > 0.0
+    assert np.array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bf16"])
+@pytest.mark.parametrize("tile", [16, 32, 64, 128])
+def test_forward_only_held_share_with_no_pair_held_runs_no_tile(tile, dtype):
+    """``num_tiles`` 0: every grid step is skipped and names tile 0 (`used_tile`'s clamp;
+    ``min(i, count - 1)`` alone names the block before the array); exact zeros come out."""
+    from galvatron_tpu.ops.grouped_matmul import used_tile
+
+    idx = _tile_choices("none_held")
+    lay = moe.held_layout(idx, TILE_HELD, tile, TILE_FIRST, empty_tiles=False)
+    assert int(lay.num_tiles[0]) == 0 and int(lay.row_valid.sum()) == 0
+    assert [int(used_tile(i, lay.num_tiles)) for i in (0, 1, 7)] == [0, 0, 0]
+    assert [int(used_tile(i, jnp.asarray([3]))) for i in (0, 2, 3, 7)] == [0, 2, 2, 2]
+    operands, _ = _tile_operands(dtype)
+    got = jax.jit(functools.partial(_tile_bounded, tile=tile, act="silu", joined=False,
+                                    forward_only=True))(*operands, idx)
+    got = np.asarray(got, np.float32)
+    assert np.isfinite(got).all() and not got.any()
+
+
+def test_differentiating_a_forward_only_held_share_raises():
+    """No VJP on the path (`moe.held_forward` and not `moe.held_experts`): a gradient is
+    refused and never read out of weight blocks that no tile wrote."""
+    cfg = ModelConfig(vocab_size=64, hidden_size=128, num_layers=1, num_heads=4, ffn_dim=128,
+                      moe_ffn_dim=128, max_seq_len=16, dtype=jnp.float32, act_fn="swiglu",
+                      moe_experts=8, moe_router="softmax_topk", moe_top_k=2, moe_share=(1, 2))
+    assert moe.held_path_counts(cfg)["bounded"] == 1
+    p = moe.init_moe_params(jax.random.key(0), cfg)
+    x = jax.random.normal(jax.random.key(1), (2, 8, 128), jnp.float32)
+
+    def loss(p_, forward_only):
+        return jnp.sum(moe.moe_topk_block(x, p_, cfg, forward_only=forward_only)[0] ** 2)
+
+    y = moe.moe_topk_block(x, p, cfg, forward_only=True)[0]
+    assert np.array_equal(np.asarray(y), np.asarray(moe.moe_topk_block(x, p, cfg)[0]))
+    grads = jax.grad(loss)(p, False)  # the default layout differentiates as ever
+    assert float(jnp.abs(grads["w2"]).max()) > 0.0
+    with pytest.raises(NotImplementedError):  # (Pallas has no JVP of a scalar-prefetch call)
+        jax.grad(loss)(p, True)

@@ -82,6 +82,25 @@ def test_ring_is_bounded():
     assert spans[-1]["args"]["i"] == 99  # newest survive
 
 
+def test_the_hot_paths_spans_cannot_push_a_compile_out_of_the_ring():
+    """The ``jax.monitoring`` spans live in a ring of their own (`record_span(rare=True)`):
+    a 32-slot engine records 37 spans an iteration, 130,000 a minute, and a run's compiles
+    are read at its END (`compile_or_load_s`, `serve_compiles_in_window`: PERF.md section 6,
+    PR 62, where an engine half as fast again lost them from a ring of 131,072)."""
+    t = Tracer(capacity=16)
+    t.enable()
+    t.record_span("jax_compile", 0.5, rare=True, fun_name="decode_step")
+    for i in range(100):
+        with t.span("sample_slot", i=i):
+            pass
+    spans = t.snapshot()
+    assert [s["name"] for s in spans].count("jax_compile") == 1 and len(spans) == 17
+    # in the order they ended: the compile (reported first) in front, the newest span last
+    assert spans[0]["args"] == {"fun_name": "decode_step"} and spans[-1]["args"]["i"] == 99
+    t.clear()
+    assert t.snapshot() == []
+
+
 def test_thread_aware_tracks():
     t = Tracer()
     t.enable()

@@ -498,6 +498,41 @@ def test_held_experts_serve_unjoined_at_published_widths(one_chip, real_mosaic, 
         assert rhs.startswith("p_") and re.search(r"w[123]_", rhs), rhs
 
 
+def test_a_cached_forwards_expert_layer_takes_the_forward_only_layout(one_chip, real_mosaic,
+                                                                      monkeypatch):
+    """The expert layer of `trinity-large-preview_serve_agent_above_knee` as a CACHED forward
+    runs it (`generation._mlp_at`: `moe_topk_block(forward_only=True)`, PR 62), a decode
+    step's 32 tokens, rank 0 of 8 holding 32 of 256, weights held in bf16, as the chip's
+    compiler sees it: the same six kernels over a layout that gives an expert without a row
+    no tile (`used_tile`'s clamp lowers for the chip), at the row tile of the shape."""
+    from galvatron_tpu.models import generation, moe
+    from galvatron_tpu.models.modeling import PRESETS
+
+    cfg = PRESETS["trinity-large-preview"].replace(
+        moe_share=(0, 8), param_dtype=jnp.bfloat16, dtype=jnp.bfloat16)
+    assert (cfg.moe_experts, cfg.moe_held, cfg.moe_top_k, cfg.expert_ffn) == (256, 32, 4, 3072)
+    shapes = jax.eval_shape(
+        lambda k: {"mlp": moe.init_moe_params(k, cfg),
+                   "mlp_norm": {"scale": jnp.zeros((cfg.hidden_size,), cfg.param_dtype)}},
+        jax.random.key(0))
+    p = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), shapes)
+    x = jax.ShapeDtypeStruct((32, 1, cfg.hidden_size), jnp.bfloat16, sharding=one_chip)
+    asked = []
+    real = moe.held_layout
+
+    def recording(*args, **kw):
+        asked.append((args[2], kw))
+        return real(*args, **kw)
+
+    monkeypatch.setattr(moe, "held_layout", recording)
+    compiled = jax.jit(lambda x_, p_: generation._mlp_at(x_, p_, cfg, None)).lower(x, p).compile()
+    assert asked == [(16, {"empty_tiles": False})]
+    kernels = sorted(n.split(".")[0] for n, _ in _entry_work(compiled.as_text())
+                     if n.startswith("moe_"))
+    assert kernels == sorted(["moe_held_rows", "moe_gmm", "moe_gmm", "moe_held_swiglu", "moe_gmm",
+                              "moe_held_pairs"]), kernels
+
+
 def _lowered_serving_program(cfg, name, one_chip, **context):
     """The engine's declared program ``name`` (the AOT registry's twin of what ``cli
     serve`` warms) for ``cfg`` under `registry.ProgramContext(**context)`, lowered for

@@ -459,6 +459,59 @@ def test_the_engines_tap_rows_are_the_references_and_its_spans_count_the_touched
     assert stats["cache_bytes"] == 3 * per * (SLOT + 4 * (WINDOW + CHUNK))
 
 
+def test_a_cached_forwards_held_share_gives_tiles_to_the_touched_experts_alone(monkeypatch):
+    """A prompt chunk and a decode step through `generation.forward_with_cache` at widths
+    the bounded held path takes (interpreted here): every expert layer asks `moe.held_layout`
+    for no empty tiles, the logits are the reference's as before, and the forward's
+    ``moe_held_experts_touched`` (`engine._router_counters`) is the experts its layouts'
+    used tiles name: the experts whose weights the step fetched."""
+    from galvatron_tpu.serving import engine
+
+    whole = small_cfg(hidden_size=128, moe_ffn_dim=128, moe_shared_ffn_dim=128,
+                      embedding_multiplier=128 ** 0.5)
+    cfg = whole.replace(moe_share=(1, 2))
+    assert moe.held_path_counts(cfg)["bounded"] and cfg.moe_held == 4
+    params, rows = seeded(whole, batch=1, length=9)
+    params = held_by(params, cfg, (1, 2))
+    asked, named = [], []
+    real = moe.held_layout
+
+    def recording(*args, empty_tiles=True):
+        lay = real(*args, empty_tiles=empty_tiles)
+        asked.append(empty_tiles)
+        jax.debug.callback(lambda g, n: named.append(len(set(g[:int(n[0])].tolist()))),
+                           lay.tile_group, lay.num_tiles, ordered=True)
+        return lay
+
+    monkeypatch.setattr(moe, "held_layout", recording)
+
+    @partial(jax.jit, static_argnames=("slot_form",))
+    def forward(cache, tokens, offsets, slot, slot_form):
+        stats = []
+        lg, cache = generation.forward_with_cache(
+            params, tokens, cfg, cache, offsets, slot=slot if slot_form else None,
+            moe_stats=stats)
+        return lg, cache, engine._router_counters(stats, cfg, tokens.size)
+
+    want = np.asarray(ref_logits(params, rows, cfg))[0]
+    cache = generation.init_kv_cache(cfg, 3, SLOT, tokens=8)
+    steps = [(rows[:, :8], jnp.int32(0), jnp.int32(1), True, lambda lg: lg[0], want[:8]),
+             (jnp.zeros((3, 1), jnp.int32).at[1, 0].set(rows[0, 8]),
+              jnp.asarray([0, 8, 0], jnp.int32), None, False, lambda lg: lg[1], want[8:9])]
+    for tokens, offsets, slot, slot_form, mine, ref in steps:
+        del named[:]
+        lg, cache, counters = forward(cache, tokens, offsets, slot, slot_form)
+        jax.effects_barrier()
+        assert close(mine(lg), ref)
+        assert len(named) == 4  # the expert layers (the first layer is dense)
+        touched = float(counters["moe_held_experts_touched"])
+        assert touched == pytest.approx(sum(named) / 4) and 0 < touched <= 4
+        assert 0.0 < float(counters["moe_live_rows_share"]) <= 1.0
+    # (the step's 3 rows x top-2 leave held experts without a row; the chunk's 16 pairs fewer)
+    assert min(named) < 4
+    assert asked and not any(asked)
+
+
 def test_held_experts_touched_counts_the_held_experts_with_a_row():
     """`moe.held_experts_touched` from the layers' statistics alone, a mean over the layers;
     every forward of a model with dropless expert layers carries it (`_router_counters`)."""
