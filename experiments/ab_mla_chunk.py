@@ -46,8 +46,7 @@ from jax.experimental.pallas import tpu as pltpu  # noqa: E402
 from experiments.ab_ssd import device_ops, rel, timed  # noqa: E402
 from galvatron_tpu.models import mla  # noqa: E402
 from galvatron_tpu.models.modeling import PRESETS  # noqa: E402
-from galvatron_tpu.ops import flash_attention as fa  # noqa: E402
-from galvatron_tpu.ops import mla_prefill  # noqa: E402
+from galvatron_tpu.ops import mla_prefill, pallas_common  # noqa: E402
 
 F32 = jnp.float32
 LAYER, SLOT = 2, 5
@@ -96,8 +95,8 @@ def _attend_expanded(q_nope, q_rope, stacked, offset, wkvb, *, live, dims, scale
         functools.partial(_expanded_kernel, scale=scale, nope=dn),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, n * dv), q.dtype),
-        compiler_params=fa._compiler_params(dimension_semantics=("parallel", "arbitrary")),
-        interpret=fa._use_interpret(),
+        compiler_params=pallas_common.compiler_params(dimension_semantics=("parallel", "arbitrary")),
+        interpret=pallas_common.use_interpret(),
         name="mla_chunk_expanded",
     )(jnp.reshape(offset, (1,)), q, kv_t, latent_t[r:])
     return out.reshape(1, s, n, dv)

@@ -33,6 +33,7 @@ from jax.experimental import topologies  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 from galvatron_tpu.ops import flash_attention as fa  # noqa: E402
+from galvatron_tpu.ops import pallas_common  # noqa: E402
 
 SHAPES = ((2, 3, 32, 4096, 128), (16, 3, 32, 512, 128))  # baichuan-7b_s4096, _s512
 _PAYLOAD = re.compile(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22')
@@ -78,7 +79,7 @@ def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--dump", help="directory to write the normalised texts to")
     args = ap.parse_args()
-    fa._use_interpret = lambda: False  # the CPU is the backend here; lower the real kernels
+    pallas_common.use_interpret = lambda: False  # the CPU is the backend here; lower the real kernels
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     device = SingleDeviceSharding(topo.devices[0])
     for shape in SHAPES:

@@ -120,12 +120,11 @@ def main() -> None:
                          "compiler plans of its memory (no chip: a plan, not a measurement)")
     args = ap.parse_args()
     from galvatron_tpu.aot.cache import persistent_cache_off
-    from galvatron_tpu.ops import flash_attention, grouped_matmul
+    from galvatron_tpu.ops import pallas_common
 
-    # the CPU is the backend here; lower the real kernels (ops/ssd.py goes by
-    # flash_attention's switch, for its kernels and for its choice of the scan's body)
-    for mod in (flash_attention, grouped_matmul):
-        mod._use_interpret = lambda: False
+    # the CPU is the backend here; lower the real kernels (every kernel module, and
+    # every choice of a kernel over its plain body, asks this one switch)
+    pallas_common.use_interpret = lambda: False
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     with tempfile.TemporaryDirectory() as out_dir, persistent_cache_off():
         for name in args.cell or [w["name"] for w in harness.load_manifest(ROOT)["workloads"]]:

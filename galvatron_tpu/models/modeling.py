@@ -239,10 +239,9 @@ class ModelConfig:
     # Head-major flash dataflow (einsum projections straight to (b, 3, n, s,
     # hd) + head-major kernels — the production flash path; see
     # _attn_block_headmajor). False routes flash layers through the legacy
-    # project→transpose→flash_attention wrapper instead — used by kernel A/B
-    # harnesses (experiments/ab_flash.py) that monkeypatch
-    # ops.flash_attention.flash_attention, which the head-major wiring
-    # bypasses.
+    # project→transpose→flash_attention wrapper instead (what a kernel A/B that
+    # patches ops.flash_attention.flash_attention needs: the head-major wiring
+    # bypasses it; `git show 384a03e:experiments/ab_flash.py`).
     flash_headmajor: bool = True
     # Activation-memory recompute over the MLP/norm/loss regions
     # (--mlp_recompute; DESIGN.md "Activation memory accounting"). The HLO
@@ -257,9 +256,8 @@ class ModelConfig:
     #     recomputed in the backward; standalone norms and the cross-entropy
     #     fp32 cast are likewise rematerialized from their narrow inputs
     #     (cast at the consumer, never saved widened). The default.
-    #   'gate': only the activation-product remat — the shape
-    #     experiments/swiglu_recompute_probe.py measured (one gate save,
-    #     fp32 widenings untouched).
+    #   'gate': only the activation-product remat — the shape BASELINE.md's
+    #     swiglu probe measured (one gate save, fp32 widenings untouched).
     #   'off': the pre-policy behaviour (double gate save + widened saves).
     mlp_recompute: str = "policy"
     # Packed-sequence input rows (--pack_sequences; galvatron_tpu.data):

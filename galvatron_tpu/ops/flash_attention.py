@@ -24,7 +24,6 @@ tests) and for shapes that don't tile (seq % block != 0).
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -33,28 +32,11 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from galvatron_tpu.ops import pallas_common
+from galvatron_tpu.ops.pallas_common import NEG_INF, compiler_params
+
 LOG2E = 1.4426950408889634  # log2(e)
 LN2 = 0.6931471805599453  # 1/log2(e)
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() == "cpu"
-
-
-# Mosaic's default per-kernel scoped-VMEM budget is ~16 MB, but the v5e chip
-# runs kernels with >=120 MB resident blocks when vmem_limit_bytes is raised
-# (experiments/vmem_probe.py, measured on-chip). The kernels here request a
-# larger budget so the combined blocked backward serves the 7B shape
-# (s=4096: 21.4 MB scoped) and bigger block configs become legal.
-# GALVATRON_FLASH_VMEM_MB=0 restores the Mosaic default.
-_VMEM_LIMIT_MB = int(os.environ.get("GALVATRON_FLASH_VMEM_MB", "64"))
-
-
-def _compiler_params(**kw):
-    if _VMEM_LIMIT_MB:
-        kw.setdefault("vmem_limit_bytes", _VMEM_LIMIT_MB << 20)
-    return pltpu.CompilerParams(**kw)
 
 
 def _single_buffered(shape, index_map) -> pl.BlockSpec:
@@ -239,7 +221,7 @@ def _flash_fwd(q, k, v, rope, sm_scale, causal, block_q, block_k, interpret,
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=compiler_params(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -330,12 +312,12 @@ def _fwd_kernel_blocked(*refs, nkb, block_q, block_k, stacked=False, rope=True,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
 def _flash_qkv(qkv, rope, sm_scale, block_q):
-    out, _ = _flash_fwd_blocked_qkv(qkv, rope, sm_scale, block_q, _use_interpret())
+    out, _ = _flash_fwd_blocked_qkv(qkv, rope, sm_scale, block_q, pallas_common.use_interpret())
     return out
 
 
 def _flash_qkv_fwd_rule(qkv, rope, sm_scale, block_q):
-    out, lse = _flash_fwd_blocked_qkv(qkv, rope, sm_scale, block_q, _use_interpret())
+    out, lse = _flash_fwd_blocked_qkv(qkv, rope, sm_scale, block_q, pallas_common.use_interpret())
     return out, (qkv, out, lse, rope)
 
 
@@ -346,13 +328,13 @@ def _flash_qkv_bwd_rule(sm_scale, block_q, res, do):
         bk, bq_sub = _bwd_blocks(block_q, rope is not None)
         dqkv = _blocked_bwd(rope)(
             None, None, None, do, out, lse, rope, sm_scale, bk, bq_sub,
-            _use_interpret(), qkv=qkv, do_stacked_out=True,
+            pallas_common.use_interpret(), qkv=qkv, do_stacked_out=True,
         )
     else:
         q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
         dq, dk, dv = _flash_bwd(
             (q, k, v, out, lse, rope), do, sm_scale, True, block_q, block_q,
-            _use_interpret(),
+            pallas_common.use_interpret(),
         )
         dqkv = jnp.stack([dq, dk, dv], axis=1)
     drope = None if rope is None else jax.tree.map(jnp.zeros_like, rope)
@@ -385,29 +367,19 @@ def flash_qkv_supported(s: int, d: int, causal: bool, block_q: int = 1024) -> bo
 # The last q-block call keeps the full k prefix resident in VMEM (k, v, rope
 # rows, fp32 rope intermediates scale with s*d) and statically unrolls nq k
 # iterations; both must stay bounded. With the raised vmem_limit_bytes
-# (see _compiler_params: the 16 MB figure was Mosaic's default, not the
-# chip's — experiments/vmem_probe.py) the envelope extends to s=8192 at
+# (`pallas_common.VMEM_LIMIT_MB`: the 16 MB figure was Mosaic's default, not the
+# chip's — BASELINE.md, "Round-4 VMEM discovery") the envelope extends to s=8192 at
 # d=128, measured −15% on the full train step vs the grid kernels at that
-# shape (experiments/ab_flash_bwd.py, v5e). When the env knob shrinks the
-# budget below what a wide envelope actually charges, that envelope shrinks
-# back so shapes route to the grid kernels instead of failing Mosaic's VMEM
-# check at compile time. Each envelope's threshold is derived from its
-# measured scoped-VMEM anchor (charges scale ~linearly in s·d): fwd ~24 MB
+# shape (BASELINE.md's dated table; v5e). Each envelope's threshold is derived from
+# its measured scoped-VMEM anchor (charges scale ~linearly in s·d): fwd ~24 MB
 # at s=8192·d=128; bwd 21.4 MB at s=4096·d=128 ⇒ ~43 MB at s=8192 — so the
-# bwd 8k extension needs a ≥ ~48 MB budget, not the fwd's ≥ 32 (a budget in
-# [32, 42] passed the old shared gate but would fail the bwd compile).
-_VMEM_EFF_MB = _VMEM_LIMIT_MB if _VMEM_LIMIT_MB else 16  # 0 → Mosaic default
-
-
-def _seq_envelope(mb_per_sxd, candidates, floor, budget_mb=None):
+# bwd 8k extension needs a ≥ ~48 MB budget, not the fwd's ≥ 32.
+def _seq_envelope(mb_per_sxd, candidates, floor, budget_mb=pallas_common.VMEM_LIMIT_MB):
     """Largest s·d envelope whose estimated scoped charge (with a 1.1×
-    safety factor) fits the effective VMEM budget. The floor is the envelope
-    proven under Mosaic's 16 MB default; a budget squeezed below even that
-    disables the blocked path entirely (0) rather than risking a
-    compile-time VMEM failure."""
-    budget = _VMEM_EFF_MB if budget_mb is None else budget_mb
+    safety factor) fits the VMEM budget. The floor is the envelope proven
+    under Mosaic's 16 MB default."""
     for sxd in candidates + (floor,):
-        if budget >= mb_per_sxd * sxd * 1.1:
+        if budget_mb >= mb_per_sxd * sxd * 1.1:
             return sxd
     return 0
 
@@ -517,7 +489,7 @@ def _flash_fwd_blocked(
                 jax.ShapeDtypeStruct((b, h, block_q, d), out_dtype or dtype),
                 jax.ShapeDtypeStruct((b, h, block_q, 1), jnp.float32),
             ],
-            compiler_params=_compiler_params(
+            compiler_params=compiler_params(
                 dimension_semantics=("parallel", "parallel")
             ),
             interpret=interpret,
@@ -752,7 +724,7 @@ def _flash_bwd_blocked(
         ] + [rows] * len(tables),
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=_compiler_params(
+        compiler_params=compiler_params(
             dimension_semantics=("parallel", "parallel")
         ),
         interpret=interpret,
@@ -775,7 +747,7 @@ def _blocked_bwd(rope):
 # fp32 score/p/dp/ds transients. (256, 512) was originally forced by
 # Mosaic's 16 MB default budget; with the raised limit, (512, 512) and
 # (512, 1024) are legal but measure FLAT on the full train step at s=2048
-# and within noise at s=4096 (experiments/ab_flash_bwd.py) — per-block
+# and within noise at s=4096 (BASELINE.md, "Round-4 VMEM discovery") — per-block
 # bookkeeping is not what bounds this kernel — so the proven config stays.
 _BWD_BQ_SUB = 256
 _BWD_BK = 512
@@ -788,10 +760,9 @@ _BWD_BQ_SUB_NO_ROPE = 512
 # 16 MB default budget beyond s=2048; under the raised vmem_limit_bytes the
 # envelope extends to s=8192, measured −9% (s=4096) / −15% (s=8192, with the
 # forward extension) on the full train step vs the grid kernels
-# (experiments/ab_flash_bwd.py, v5e). Beyond this — or whenever the env
-# knob shrinks the budget below what the wide envelope charges (per-shape
-# thresholds derived from the 21.4 MB s=4096 anchor; see _seq_envelope) —
-# the grid kernels serve.
+# (BASELINE.md's dated table; `git show 384a03e:experiments/ab_flash_bwd.py`, v5e).
+# Beyond this (per-shape thresholds derived from the 21.4 MB s=4096 anchor; see
+# _seq_envelope) the grid kernels serve.
 _BWD_MB_PER_SXD = 21.4 / (4096 * 128)
 _BWD_MAX_SEQ_X_DIM = _seq_envelope(
     _BWD_MB_PER_SXD, (8192 * 128, 4096 * 128), 2048 * 128
@@ -1000,7 +971,7 @@ def _flash_bwd_parts(
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=compiler_params(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -1025,7 +996,7 @@ def _flash_bwd_parts(
         out_specs=pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=compiler_params(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -1056,12 +1027,14 @@ def _fwd_dispatch(q, k, v, rope, sm_scale, causal, block_q, block_k, interpret):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def _flash(q, k, v, rope, sm_scale, causal, block_q, block_k):
-    out, _ = _fwd_dispatch(q, k, v, rope, sm_scale, causal, block_q, block_k, _use_interpret())
+    out, _ = _fwd_dispatch(q, k, v, rope, sm_scale, causal, block_q, block_k,
+                           pallas_common.use_interpret())
     return out
 
 
 def _flash_fwd_rule(q, k, v, rope, sm_scale, causal, block_q, block_k):
-    out, lse = _fwd_dispatch(q, k, v, rope, sm_scale, causal, block_q, block_k, _use_interpret())
+    out, lse = _fwd_dispatch(q, k, v, rope, sm_scale, causal, block_q, block_k,
+                             pallas_common.use_interpret())
     return out, (q, k, v, out, lse, rope)
 
 
@@ -1082,10 +1055,11 @@ def _flash_bwd_rule(sm_scale, causal, block_q, block_k, res, do):
     if _use_blocked_bwd(q.shape[2], q.shape[3], causal, block_q, block_k):
         bk, bq_sub = _bwd_blocks(block_q, rope is not None)
         dq, dk, dv = _blocked_bwd(rope)(
-            q, k, v, do, out, lse, rope, sm_scale, bk, bq_sub, _use_interpret(),
+            q, k, v, do, out, lse, rope, sm_scale, bk, bq_sub, pallas_common.use_interpret(),
         )
     else:
-        dq, dk, dv = _flash_bwd(res, do, sm_scale, causal, block_q, block_k, _use_interpret())
+        dq, dk, dv = _flash_bwd(res, do, sm_scale, causal, block_q, block_k,
+                                pallas_common.use_interpret())
     if kv_rep > 1:
         b, h, s, d = dk.shape
         dk = dk.reshape(b, h // kv_rep, kv_rep, s, d).sum(axis=2)
@@ -1375,10 +1349,10 @@ def paged_decode_attention(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kv, g, d), q.dtype),
-        compiler_params=_compiler_params(
+        compiler_params=compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
-        interpret=_use_interpret(),
+        interpret=pallas_common.use_interpret(),
         name="flash_paged_decode",
     )(block_tables.astype(jnp.int32), offsets, qg, k_pages, v_pages)
     return out.reshape(b, 1, n, d)

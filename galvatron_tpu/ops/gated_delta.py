@@ -50,7 +50,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from galvatron_tpu.ops import flash_attention as fa
+from galvatron_tpu.ops import pallas_common
 
 F32 = jnp.float32
 
@@ -531,7 +531,7 @@ def _sizes(q, v, gr):
 
 
 def _params():
-    return fa._compiler_params(dimension_semantics=("parallel", "parallel", "arbitrary"))
+    return pallas_common.compiler_params(dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 # (jitted and inlined: a program traces each kernel once, however many layers call
@@ -549,7 +549,7 @@ def _fwd_call(q, k, v, gc, bc, gr, keep_states):
         grid=grid, in_specs=[keys, keys, values, cols, cols, rows],
         out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((r, dk, dv), F32)], compiler_params=_params(),
-        interpret=fa._use_interpret(), name="gdn_fwd",
+        interpret=pallas_common.use_interpret(), name="gdn_fwd",
     )(q, k, v, gc, bc, gr)
 
 
@@ -564,7 +564,7 @@ def _bwd_call(q, k, v, gc, bc, gr, do, states):
         out_specs=[keys, keys, values, rows, rows],
         out_shape=[like(q), like(k), like(v), like(gr), like(gr)],
         scratch_shapes=[pltpu.VMEM((r, dk, dv), F32)], compiler_params=_params(),
-        interpret=fa._use_interpret(), name="gdn_bwd",
+        interpret=pallas_common.use_interpret(), name="gdn_bwd",
     )(q, k, v, gc, bc, gr, do, states)
 
 
@@ -639,7 +639,7 @@ def scan_path(hk: int, hv: int, dk: int, dv: int, chunk: int, dtype) -> str:
     name. `models/gdn.block` and `models/gdn.path_counts` both ask here. The
     fused kernels take, and everything else takes the plain body:
 
-    - a chip (`flash_attention._use_interpret`'s rule, the one switch of this
+    - a chip (`pallas_common.use_interpret`'s rule, the one switch of this
       repo's kernels: on the CPU they run interpreted, which only the tests that
       call `gated_delta_fused` themselves want);
     - chunks of 64, the size the kernels are written for (two side by side fill
@@ -652,8 +652,10 @@ def scan_path(hk: int, hv: int, dk: int, dv: int, chunk: int, dtype) -> str:
       budget `flash_attention._seq_envelope` reckons with.
     """
     dtype = jnp.dtype(dtype)
-    if fa._use_interpret() or hk < 1 or hv % hk or dtype not in (jnp.bfloat16, jnp.float32):
+    if (pallas_common.use_interpret() or hk < 1 or hv % hk
+            or dtype not in (jnp.bfloat16, jnp.float32)):
         return "plain"
     inside = (chunk == _CHUNK and dk % 128 == 0 and dv % 128 == 0
-              and 1.1 * _fused_vmem_mb(hv // hk, dk, dv, hv, dtype.itemsize) <= fa._VMEM_EFF_MB)
+              and 1.1 * _fused_vmem_mb(hv // hk, dk, dv, hv, dtype.itemsize)
+              <= pallas_common.VMEM_LIMIT_MB)
     return "fused" if inside else "plain"

@@ -41,10 +41,12 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.extend
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from galvatron_tpu.ops import pallas_common
+from galvatron_tpu.ops.pallas_common import traced_once
 
 #: the most rows a tile: the alignment of a group's first row in the sorted layout
 #: where an expert gets hundreds of rows or more (`row_tile`).
@@ -52,8 +54,6 @@ from jax.experimental.pallas import tpu as pltpu
 #: less (147,456 rows for 131,072 pairs over 64 experts, 512 gives 163,840),
 #: at 128 the kernels' rate begins to fall
 TILE_M = 256
-#: scoped VMEM the kernels ask for (the v5e default is 16 MiB of 128)
-_VMEM_LIMIT = 64 << 20
 
 
 def row_tile(tokens: int, top_k: int, experts_scored: int, dtype) -> int:
@@ -74,10 +74,6 @@ def row_tile(tokens: int, top_k: int, experts_scored: int, dtype) -> int:
     return tile
 
 
-def _use_interpret() -> bool:
-    return jax.default_backend() == "cpu"
-
-
 def _tile(n: int, want: int) -> int:
     """Largest power-of-two multiple of 128 that divides ``n``, at most
     ``want``; a width that none divides is one whole block (always legal)."""
@@ -87,28 +83,6 @@ def _tile(n: int, want: int) -> int:
             return t
         t //= 2
     return n
-
-
-@functools.lru_cache(maxsize=None)
-def _traced(fn, avals, static, interpret):
-    del interpret  # a key: the kernels bind it while they are traced
-    return jax.make_jaxpr(functools.partial(fn, **dict(static)))(*avals)
-
-
-def traced_once(fn, *args, **static):
-    """``fn(*args, **static)`` (one array out), with ``fn`` traced once a signature
-    and its jaxpr evaluated at every other call site. A step program calls each
-    kernel of a held share's path 4 layers x (forward, replay, backward) times; a
-    `pl.pallas_call` traces its kernel body anew at each, which the set-up of every
-    run pays, warm cache or not (PERF.md §6, PR 49). The caller's name stack is
-    kept: `eval_jaxpr` puts it in front of the equations' own."""
-    avals = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args)
-    closed = _traced(fn, avals, tuple(sorted(static.items())), _use_interpret())
-    return jax.extend.core.jaxpr_as_fun(closed)(*args)[0]
-
-
-def _params(*semantics):
-    return pltpu.CompilerParams(dimension_semantics=semantics, vmem_limit_bytes=_VMEM_LIMIT)
 
 
 def used_tile(i, count):
@@ -177,8 +151,9 @@ def _gmm(lhs, rhs, tile_group, num_tiles, *, transpose_rhs: bool, tile_m: int, t
             ],
             out_specs=out_spec,
         ),
-        compiler_params=_params("parallel", "arbitrary"),
-        interpret=_use_interpret(),
+        compiler_params=pallas_common.compiler_params(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=pallas_common.use_interpret(),
         name="moe_gmm_dlhs" if transpose_rhs else "moe_gmm",
     )(tile_group, num_tiles, lhs, rhs)
 
@@ -223,8 +198,9 @@ def _tgmm(lhs, grad, tile_group, num_tiles, num_groups: int, *, tile_m: int, til
             out_specs=pl.BlockSpec((None, tk, tn), lambda a, b, i, g, c: (g[i], a, b)),
             scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)],
         ),
-        compiler_params=_params("parallel", "parallel", "arbitrary"),
-        interpret=_use_interpret(),
+        compiler_params=pallas_common.compiler_params(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=pallas_common.use_interpret(),
         name="moe_tgmm",
     )(tile_group, num_tiles, lhs, grad)
 

@@ -8,7 +8,7 @@ positions, head_dim)``: the full layers', place j holding position j, or (``span
 0) the window layers' RING, place j holding the newest position p <= the row's last
 write with ``p mod R = j``. Either is handed WHOLE: the index map names ``layer`` (a
 prefetched scalar, so the layers of a step share one traced body a stack,
-`grouped_matmul.traced_once`), the row and a block of ``KEY_BLOCK`` keys of ALL
+`pallas_common.traced_once`), the row and a block of ``KEY_BLOCK`` keys of ALL
 its key/value heads, and nothing copies a layer's slab out. The kernel reads a stack
 where the chip keeps it, and which layout that is follows from ``head_dim`` (the
 shape's, not the program's; compiled for a described v5e, bf16 and float32 alike):
@@ -63,8 +63,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from galvatron_tpu.ops import flash_attention as fa
-from galvatron_tpu.ops.grouped_matmul import traced_once
+from galvatron_tpu.ops import pallas_common
 
 F32 = jnp.float32
 _LANES = 128
@@ -89,7 +88,7 @@ def decode_path(positions: int, head_dim: int, query_rows: int, dtype) -> str:
     key/value head, from the shapes and the backend alone: no flag, no environment
     variable, no model's name. `generation._windowed_attention` and
     `generation.cache_read_positions` both ask here. The kernel takes a TPU, or the
-    CPU (interpreted: `flash_attention._use_interpret`, the one switch of this
+    CPU (interpreted: `pallas_common.use_interpret`, the one switch of this
     repo's kernels); a ``head_dim`` of whole lane tiles, which the chip keeps as it is
     written, or one of `TRANSPOSED_HEADS` (64), which it keeps with the positions on
     the lanes and the kernel reads transposed (the module's docstring: either way the
@@ -135,7 +134,7 @@ def _kernel(layer_ref, first_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, fa.NEG_INF)
+        m_ref[...] = jnp.full_like(m_ref, pallas_common.NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
@@ -157,7 +156,7 @@ def _kernel(layer_ref, first_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_
                 kept = (v_pos > first - span) & (v_pos >= 0)
             else:
                 seen, kept = k_pos <= q_pos, v_pos < length
-            scores = jnp.where(seen, scores, fa.NEG_INF)
+            scores = jnp.where(seen, scores, pallas_common.NEG_INF)
             v = jnp.where(kept, v, jnp.zeros_like(v))
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
@@ -222,7 +221,8 @@ def _attend(layer, first, q, ks, vs, *, scale: float, block_k: int, group: int, 
                           span=span, ring=positions, keys=2 if transposed else 1),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=fa._compiler_params(dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pallas_common.compiler_params(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="kv_decode",
     )(layer, first, q, ks, vs)
@@ -238,8 +238,8 @@ def attend_rows(qg, ks, vs, layer: int, first, *, scale: float, span: int = 0):
     rows = -(-s * g // _ROW_TILE) * _ROW_TILE
     q = jnp.swapaxes(qg, 1, 2).reshape(b, kv, s * g, d)
     q = jnp.pad(q, ((0, 0), (0, 0), (0, rows - s * g), (0, 0)))
-    out = traced_once(
+    out = pallas_common.traced_once(
         _attend, jnp.full((1,), layer, jnp.int32), first.astype(jnp.int32), q, ks, vs,
         scale=float(scale), block_k=KEY_BLOCK, group=g, window=s, span=int(span),
-        interpret=fa._use_interpret())
+        interpret=pallas_common.use_interpret())
     return jnp.swapaxes(out[:, :, :s * g].reshape(b, kv, s, g, d), 1, 2)

@@ -6,7 +6,7 @@ slot cache, as one Pallas kernel bounded a row by the row's own length.
 ``q_cat`` is ``[q_nope W_kvb,k^T | q_rope]`` (B, s, n, r + dr) as
 ``models/mla.py`` builds it, ``latent`` the cache ``(layers, rows, positions,
 r + dr)`` WHOLE: the kernel's index map names ``layer`` (a prefetched scalar, so
-the five layers of a step share one traced body, `grouped_matmul.traced_once`),
+the five layers of a step share one traced body, `pallas_common.traced_once`),
 the row and a block of ``KEY_BLOCK`` keys, and nothing copies a layer's slab out
 (XLA would, handed ``stacked[layer]``: 604 MB a layer at 32 x 16,384 x 576).
 
@@ -47,8 +47,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from galvatron_tpu.ops import flash_attention as fa
-from galvatron_tpu.ops.grouped_matmul import traced_once
+from galvatron_tpu.ops import pallas_common
 
 F32 = jnp.float32
 _LANES = 128
@@ -65,7 +64,7 @@ def decode_path(positions: int, width: int, query_rows: int, rank: int, dtype) -
     the shapes and the backend alone: no flag, no environment variable, no model's
     name. `models/mla.attend_window` and `models/mla.cache_read_positions` both ask
     here. The kernel takes a TPU, or the CPU (interpreted:
-    `flash_attention._use_interpret`, the one switch of this repo's kernels); a
+    `pallas_common.use_interpret`, the one switch of this repo's kernels); a
     capacity of whole key blocks; at most ``MAX_QUERY_ROWS`` query rows; bf16 or
     float32; and, compiled, a rank of whole lane tiles and a width that is NOT one
     (the chip then keeps the positions on the lanes, the layout the kernel reads in
@@ -74,7 +73,7 @@ def decode_path(positions: int, width: int, query_rows: int, rank: int, dtype) -
         return "plain"
     if jnp.dtype(dtype) not in (jnp.bfloat16, jnp.float32):
         return "plain"
-    laid_out = fa._use_interpret() or (rank % _LANES == 0 and width % _LANES != 0)
+    laid_out = pallas_common.use_interpret() or (rank % _LANES == 0 and width % _LANES != 0)
     inside = positions % KEY_BLOCK == 0 and query_rows <= MAX_QUERY_ROWS and laid_out
     return "kernel" if inside else "plain"
 
@@ -89,7 +88,7 @@ def _kernel(layer_ref, first_ref, q_ref, kt_ref, o_ref, m_ref, l_ref, acc_ref, *
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, fa.NEG_INF)
+        m_ref[...] = jnp.full_like(m_ref, pallas_common.NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
@@ -100,7 +99,7 @@ def _kernel(layer_ref, first_ref, q_ref, kt_ref, o_ref, m_ref, l_ref, acc_ref, *
         if masked:
             k_pos = start + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
             q_pos = first + jax.lax.broadcasted_iota(jnp.int32, (q.shape[0], 1), 0) // heads
-            scores = jnp.where(k_pos <= q_pos, scores, fa.NEG_INF)
+            scores = jnp.where(k_pos <= q_pos, scores, pallas_common.NEG_INF)
             values = jnp.where(k_pos < length, values, jnp.zeros_like(values))
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
@@ -153,7 +152,8 @@ def _attend(layer, first, q, stacked_t, *, scale: float, block_k: int, heads: in
                           rank=rank),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, rows, rank), q.dtype),
-        compiler_params=fa._compiler_params(dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pallas_common.compiler_params(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="mla_decode",
     )(layer, first, q, stacked_t)
@@ -166,8 +166,8 @@ def latent_attention(q_cat, stacked, layer: int, first, *, rank: int, scale: flo
     ``layer`` of ``stacked`` (layers, rows >= B, positions, r + dr) -> (B, s, n, r)
     in ``q_cat``'s type. ``block_k`` (None: ``KEY_BLOCK``) divides the positions."""
     b, s, n, width = q_cat.shape
-    out = traced_once(
+    out = pallas_common.traced_once(
         _attend, jnp.full((1,), layer, jnp.int32), first.astype(jnp.int32),
         q_cat.reshape(b, s * n, width), jnp.swapaxes(stacked, 2, 3), scale=float(scale),
-        block_k=block_k or KEY_BLOCK, heads=n, rank=rank, interpret=fa._use_interpret())
+        block_k=block_k or KEY_BLOCK, heads=n, rank=rank, interpret=pallas_common.use_interpret())
     return out.reshape(b, s, n, rank)

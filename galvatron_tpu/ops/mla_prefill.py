@@ -21,7 +21,7 @@ are ``[q_nope | q_rope] (Tq, dn + dr) x [k_nope^T ; k_r^T]`` and the context
 
 A grid over (head, key block); ``layer``, ``slot`` and ``offset`` are prefetched
 scalars, so the layers of a program share one traced body
-(`grouped_matmul.traced_once`) and every chunk of every request the one compiled
+(`pallas_common.traced_once`) and every chunk of every request the one compiled
 program. ``offset`` is any value (the engine slides a prompt's last window left
 at the slot's end). A key block past the chunk's last live one is not fetched (its
 index map names the last live block again) and not computed; a block whose every
@@ -52,8 +52,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from galvatron_tpu.ops import flash_attention as fa
-from galvatron_tpu.ops.grouped_matmul import traced_once
+from galvatron_tpu.ops import pallas_common
 
 F32 = jnp.float32
 _LANES = 128
@@ -71,7 +70,7 @@ def chunk_path(positions: int, width: int, rows: int, dims: Tuple[int, ...], dty
     dv, r), from the shapes and the backend alone: no flag, no environment variable,
     no model's name. `models/mla.attend_chunk` and `models/mla.chunk_layout`
     both ask here. The kernel takes a TPU, or the CPU (interpreted:
-    `flash_attention._use_interpret`); bf16 or float32; a capacity of whole key
+    `pallas_common.use_interpret`); bf16 or float32; a capacity of whole key
     blocks; at most ``MAX_CHUNK_ROWS`` rows; and, compiled, shapes the chip tiles
     (dn, dv and r whole lane tiles; dr and the rows whole sublane tiles of the
     compute type) with a latent width that is NOT a whole lane tile (the chip then
@@ -83,7 +82,7 @@ def chunk_path(positions: int, width: int, rows: int, dims: Tuple[int, ...], dty
     if jnp.dtype(dtype) not in (jnp.bfloat16, jnp.float32):
         return "plain"
     sublanes = 32 // jnp.dtype(dtype).itemsize  # rows of a packed tile
-    laid_out = fa._use_interpret() or (
+    laid_out = pallas_common.use_interpret() or (
         dn % _LANES == 0 and dv % _LANES == 0 and r % _LANES == 0
         and dr % sublanes == 0 and rows % sublanes == 0 and width % _LANES != 0)
     inside = positions % KEY_BLOCK == 0 and rows <= MAX_CHUNK_ROWS and laid_out
@@ -93,7 +92,7 @@ def chunk_path(positions: int, width: int, rows: int, dims: Tuple[int, ...], dty
 def _init(j, m_ref, l_ref, acc_ref):
     @pl.when(j == 0)
     def _():
-        m_ref[...] = jnp.full_like(m_ref, fa.NEG_INF)
+        m_ref[...] = jnp.full_like(m_ref, pallas_common.NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
@@ -108,7 +107,7 @@ def _attend_block(q_ref, k_t, v_t, m_ref, l_ref, acc_ref, offset, start, *, scal
     if masked:
         k_pos = start + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
         q_pos = offset + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
-        scores = jnp.where(k_pos <= q_pos, scores, fa.NEG_INF)
+        scores = jnp.where(k_pos <= q_pos, scores, pallas_common.NEG_INF)
         v_t = jnp.where(k_pos < offset + rows, v_t, jnp.zeros_like(v_t))
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
@@ -183,7 +182,8 @@ def _attend(layer, slot, offset, q, w_t, stacked_t, *, scale: float, block_k: in
         functools.partial(_kernel, scale=scale, rank=rank, nope=nope),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, n * dv), q.dtype),
-        compiler_params=fa._compiler_params(dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pallas_common.compiler_params(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="mla_chunk",
     )(layer, slot, offset, q, w_t, stacked_t)
@@ -205,8 +205,8 @@ def latent_chunk_attention(q_nope, q_rope, stacked, layer: int, slot, offset, wk
     # want them
     q = jnp.transpose(jnp.concatenate([q_nope, q_rope], axis=-1)[0], (1, 0, 2))
     w_t = jnp.transpose(wkvb.astype(q.dtype), (1, 2, 0))
-    out = traced_once(
+    out = pallas_common.traced_once(
         _attend, one(layer), one(slot), one(offset), q, w_t, jnp.swapaxes(stacked, 2, 3),
         scale=float(scale), block_k=KEY_BLOCK, rank=r, nope=dn,
-        interpret=fa._use_interpret())
+        interpret=pallas_common.use_interpret())
     return out.reshape(1, s, n, dv)

@@ -43,7 +43,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from galvatron_tpu.ops import grouped_matmul as gm
+from galvatron_tpu.ops import pallas_common
 from galvatron_tpu.ops.grouped_matmul import used_tile
 
 LANES = 128
@@ -124,7 +124,7 @@ def gather_rows(src, row_token, tile_rows, num_tiles, *, dtype, tile: int, scale
     (M / tile,) the leading rows of a tile that hold a pair, ``num_tiles`` (1,).
     -> (M, h) ``dtype``; padding rows of a used tile zero, later tiles undefined."""
     args = (src, row_token, tile_rows, num_tiles) + (() if scale is None else (scale,))
-    return gm.traced_once(_gather_rows, *args, dtype=dtype, tile=tile)
+    return pallas_common.traced_once(_gather_rows, *args, dtype=dtype, tile=tile)
 
 
 def _gather_rows(src, row_token, tile_rows, num_tiles, scale=None, *, dtype, tile):
@@ -180,8 +180,8 @@ def _gather_rows(src, row_token, tile_rows, num_tiles, scale=None, *, dtype, til
             scratch_shapes=[pltpu.VMEM((tile, chunks, LANES), src.dtype),
                             pltpu.SemaphoreType.DMA(())],
         ),
-        compiler_params=gm._params("arbitrary"),
-        interpret=gm._use_interpret(),
+        compiler_params=pallas_common.compiler_params(dimension_semantics=("arbitrary",)),
+        interpret=pallas_common.use_interpret(),
         name="moe_held_rows",
     )(row_token, tile_rows, num_tiles, *args)
 
@@ -217,7 +217,7 @@ def pairs_index(pair_row, num_tiles, *, tile: int):
 def gather_pairs(src, pair_row, num_tiles, weights, index, *, dtype, tile: int, other=None):
     """See `_gather_pairs`."""
     args = (src, pair_row, num_tiles, weights, *index) + (() if other is None else (other,))
-    return gm.traced_once(_gather_pairs, *args, dtype=dtype, tile=tile)
+    return pallas_common.traced_once(_gather_pairs, *args, dtype=dtype, tile=tile)
 
 
 def _gather_pairs(src, pair_row, num_tiles, weights, rows, counts, other=None, *, dtype, tile):
@@ -322,8 +322,8 @@ def _gather_pairs(src, pair_row, num_tiles, weights, rows, counts, other=None, *
                             pltpu.VMEM((chunks * per, tt, LANES), jnp.float32),  # column blocks
                             pltpu.SemaphoreType.DMA(())],
         ),
-        compiler_params=gm._params("arbitrary"),
-        interpret=gm._use_interpret(),
+        compiler_params=pallas_common.compiler_params(dimension_semantics=("arbitrary",)),
+        interpret=pallas_common.use_interpret(),
         name="moe_held_pairs",
     )(rows, counts, *args)
     if other is None:
@@ -336,7 +336,7 @@ def swiglu(gate_up, num_tiles, *, tile: int, act: str = "silu"):
     """``act(gate) * up`` of the fused (M, 2f) ``[gate | up]`` buffer -> (M, f),
     over the used tiles; later tiles undefined. ``act`` "silu" (SwiGLU) or "relu"
     (ReGLU), fixed at trace time."""
-    return gm.traced_once(_swiglu, gate_up, num_tiles, tile=tile, act=act)
+    return pallas_common.traced_once(_swiglu, gate_up, num_tiles, tile=tile, act=act)
 
 
 def _swiglu(gate_up, num_tiles, *, tile, act="silu"):
@@ -362,8 +362,8 @@ def _swiglu(gate_up, num_tiles, *, tile, act="silu"):
             in_specs=[pl.BlockSpec((tile, f2), lambda i, c: (used_tile(i, c), 0))],
             out_specs=pl.BlockSpec((tile, f), lambda i, c: (used_tile(i, c), 0)),
         ),
-        compiler_params=gm._params("arbitrary"),
-        interpret=gm._use_interpret(),
+        compiler_params=pallas_common.compiler_params(dimension_semantics=("arbitrary",)),
+        interpret=pallas_common.use_interpret(),
         name="moe_held_swiglu",
     )(num_tiles, gate_up)
 
@@ -371,7 +371,7 @@ def _swiglu(gate_up, num_tiles, *, tile, act="silu"):
 def swiglu_bwd(gate_up, grad, num_tiles, *, tile: int, act: str = "silu"):
     """`swiglu`'s backward: (M, 2f) ``[d gate | d up]`` from the saved buffer and
     the (M, f) gradient of its output, over the used tiles."""
-    return gm.traced_once(_swiglu_bwd, gate_up, grad, num_tiles, tile=tile, act=act)
+    return pallas_common.traced_once(_swiglu_bwd, gate_up, grad, num_tiles, tile=tile, act=act)
 
 
 def _swiglu_bwd(gate_up, grad, num_tiles, *, tile, act="silu"):
@@ -403,7 +403,7 @@ def _swiglu_bwd(gate_up, grad, num_tiles, *, tile, act="silu"):
                       pl.BlockSpec((tile, f), lambda i, c: (used_tile(i, c), 0))],
             out_specs=pl.BlockSpec((tile, f2), lambda i, c: (used_tile(i, c), 0)),
         ),
-        compiler_params=gm._params("arbitrary"),
-        interpret=gm._use_interpret(),
+        compiler_params=pallas_common.compiler_params(dimension_semantics=("arbitrary",)),
+        interpret=pallas_common.use_interpret(),
         name="moe_held_swiglu_bwd",
     )(num_tiles, gate_up, grad)

@@ -41,7 +41,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from galvatron_tpu.ops import flash_attention as fa
+from galvatron_tpu.ops import pallas_common
 
 F32 = jnp.float32
 
@@ -181,7 +181,7 @@ def scan_path(heads: int, head_dim: int, groups: int, state: int, chunk: int, dt
     name. `ssd_scan` and the trainer's ``ssm_scan_path`` counter both ask
     here. The fused kernels take, and everything else takes the plain body:
 
-    - a chip (`flash_attention._use_interpret`'s rule, the one switch of this
+    - a chip (`pallas_common.use_interpret`'s rule, the one switch of this
       repo's kernels: on the CPU they run interpreted, which only the tests
       that call `ssd_scan_fused` themselves want);
     - ``chunk`` a multiple of 128: the (L, L) score blocks and the rows of dt
@@ -196,11 +196,13 @@ def scan_path(heads: int, head_dim: int, groups: int, state: int, chunk: int, dt
       budget `flash_attention._seq_envelope` reckons with.
     """
     dtype = jnp.dtype(dtype)
-    if fa._use_interpret() or heads % max(groups, 1) or dtype not in (jnp.bfloat16, jnp.float32):
+    if (pallas_common.use_interpret() or heads % max(groups, 1)
+            or dtype not in (jnp.bfloat16, jnp.float32)):
         return "plain"
     hb = _head_block(heads // groups, head_dim)
     inside = (chunk % _LANES == 0 and head_dim in (64, 128) and state % _LANES == 0 and hb > 0
-              and 1.1 * _fused_vmem_mb(hb, head_dim, state, chunk, dtype.itemsize) <= fa._VMEM_EFF_MB)
+              and 1.1 * _fused_vmem_mb(hb, head_dim, state, chunk, dtype.itemsize)
+              <= pallas_common.VMEM_LIMIT_MB)
     return "fused" if inside else "plain"
 
 
@@ -404,8 +406,8 @@ def _decay_call(dt, a, chunk, hb):
     return pl.pallas_call(
         _decay_kernel, grid=grid, in_specs=[natural, heads], out_specs=[rows, rows],
         out_shape=[shape, shape], scratch_shapes=[pltpu.VMEM((chunk, hpad), F32)],
-        compiler_params=fa._compiler_params(dimension_semantics=("parallel", "parallel")),
-        interpret=fa._use_interpret(), name="ssd_decay",
+        compiler_params=pallas_common.compiler_params(dimension_semantics=("parallel", "parallel")),
+        interpret=pallas_common.use_interpret(), name="ssd_decay",
     )(dt, a)
 
 
@@ -417,8 +419,8 @@ def _decay_bwd_call(ddt, dcum, a, chunk):
     return pl.pallas_call(
         _decay_bwd_kernel, grid=grid, in_specs=[rows, rows, heads], out_specs=[natural, natural],
         out_shape=[shape, shape], scratch_shapes=[pltpu.VMEM((2, hpad, chunk), F32)],
-        compiler_params=fa._compiler_params(dimension_semantics=("parallel", "parallel")),
-        interpret=fa._use_interpret(), name="ssd_decay_bwd",
+        compiler_params=pallas_common.compiler_params(dimension_semantics=("parallel", "parallel")),
+        interpret=pallas_common.use_interpret(), name="ssd_decay_bwd",
     )(ddt, dcum, a)
 
 
@@ -442,9 +444,9 @@ def _fwd_call(x, dtr, cum, bm, cm, chunk, n, keep_states):
         grid=grid, in_specs=[tokens, rows, rows, group, group],
         out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((n, hb * p), F32), pltpu.VMEM((_ROWS, chunk), F32)],
-        compiler_params=fa._compiler_params(
+        compiler_params=pallas_common.compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=fa._use_interpret(), name="ssd_fwd",
+        interpret=pallas_common.use_interpret(), name="ssd_fwd",
     )(x, dtr, cum, bm, cm)
 
 
@@ -531,9 +533,9 @@ def _bwd_call(x, dtr, cum, bm, cm, dy, states, chunk, n):
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype), jax.ShapeDtypeStruct(dtr.shape, F32),
                    jax.ShapeDtypeStruct(dtr.shape, F32), part_shape, part_shape],
         scratch_shapes=[pltpu.VMEM((n, hb * p), F32), pltpu.VMEM((_ROWS, chunk), F32)],
-        compiler_params=fa._compiler_params(
+        compiler_params=pallas_common.compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=fa._use_interpret(), name="ssd_bwd",
+        interpret=pallas_common.use_interpret(), name="ssd_bwd",
     )(x, dtr, cum, bm, cm, dy, states)
     return dx, ddt, dcum, db.sum(axis=1).astype(bm.dtype), dc.sum(axis=1).astype(cm.dtype)
 
@@ -626,7 +628,7 @@ def conv_path(windows, k: int, dtype) -> str:
     and its `path_counts` both ask here. ``windows``:
     the widths of the channel groups the mixer takes apart (x, B, C). Fused:
 
-    - a chip (`flash_attention._use_interpret`'s rule);
+    - a chip (`pallas_common.use_interpret`'s rule);
     - every window whole 128-lane tiles (so the channels are, and each window
       starts on a tile of in_proj's output if the first does);
     - at most `_MAX_TAPS` taps: the K - 1 rows before a strip come with the
@@ -637,11 +639,11 @@ def conv_path(windows, k: int, dtype) -> str:
 
     Everything else takes `causal_conv1d` + ``jax.nn.silu``."""
     dtype = jnp.dtype(dtype)
-    if (fa._use_interpret() or dtype not in (jnp.bfloat16, jnp.float32)
+    if (pallas_common.use_interpret() or dtype not in (jnp.bfloat16, jnp.float32)
             or not 1 <= k <= _MAX_TAPS or any(w <= 0 or w % _LANES for w in windows)):
         return "plain"
     ts, tc = _conv_blocks(_CONV_BLOCK_S, max(windows), 0)
-    inside = 1.1 * _conv_vmem_mb(ts, tc, k, dtype.itemsize) <= fa._VMEM_EFF_MB
+    inside = 1.1 * _conv_vmem_mb(ts, tc, k, dtype.itemsize) <= pallas_common.VMEM_LIMIT_MB
     return "fused" if inside else "plain"
 
 
@@ -767,9 +769,9 @@ def _conv_fwd_call(x, w, b, col0):
         out_specs=pl.BlockSpec((1, ts, tc), lambda b_, c, s: (b_, s, c)),
         out_shape=jax.ShapeDtypeStruct((*x.shape[:2], w.shape[1]), x.dtype),
         scratch_shapes=[pltpu.VMEM((_HALO, tc), F32)],
-        compiler_params=fa._compiler_params(
+        compiler_params=pallas_common.compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=fa._use_interpret(), name="ssm_conv_fwd",
+        interpret=pallas_common.use_interpret(), name="ssm_conv_fwd",
     )(x, *small)
 
 
@@ -791,9 +793,9 @@ def _conv_bwd_call(x, w, b, col0, g):
         out_shape=[jax.ShapeDtypeStruct(g.shape, x.dtype),
                    jax.ShapeDtypeStruct((bsz, rows, channels), F32)],
         scratch_shapes=[pltpu.VMEM((_HALO, tc), F32)],
-        compiler_params=fa._compiler_params(
+        compiler_params=pallas_common.compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=fa._use_interpret(), name="ssm_conv_bwd",
+        interpret=pallas_common.use_interpret(), name="ssm_conv_bwd",
     )(x, x, g, *small)
     sums = sums.reshape(bsz, k + 1, _HALO, channels).sum(axis=(0, 2))
     return dx, sums[:k].astype(w.dtype), sums[k].astype(b.dtype)

@@ -40,13 +40,9 @@ from galvatron_tpu.models import modeling
 from galvatron_tpu.models.modeling import ModelConfig
 from galvatron_tpu.models.placement import LOCAL, Placement
 from galvatron_tpu.parallel.mesh import ambient_or, manual_axis_names
-from galvatron_tpu.ops.flash_attention import (
-    _flash_bwd_parts,
-    _flash_fwd,
-    _use_interpret,
-)
-
-NEG_INF = -1e30
+from galvatron_tpu.ops import pallas_common
+from galvatron_tpu.ops.flash_attention import _flash_bwd_parts, _flash_fwd
+from galvatron_tpu.ops.pallas_common import NEG_INF
 
 
 def _ring_attn_local(q, k, v, idx_arr, axis_name: str, cp: int, sm_scale: float):
@@ -242,7 +238,8 @@ def _ring_flash_local(q, k, v, idx_arr, axis_name: str, cp: int, sm_scale: float
     kt = jnp.transpose(k, (0, 2, 1, 3))
     vt = jnp.transpose(v, (0, 2, 1, 3))
     out = _ring_flash(
-        qt, kt, vt, idx_arr[0], axis_name, cp, sm_scale, block, block, _use_interpret()
+        qt, kt, vt, idx_arr[0], axis_name, cp, sm_scale, block, block,
+        pallas_common.use_interpret(),
     )
     return jnp.transpose(out, (0, 2, 1, 3))
 

@@ -309,7 +309,7 @@ def profile_model(
     # over the expert FFN width — t(f) = a + b*f, expert share = b*f/(a+b*f);
     # the intercept a is the routing/sinkhorn/dispatch overhead that does
     # NOT shard by ep (the param-fraction proxy overstated the ep win by
-    # pricing it as shardable). Measured on-chip (experiments/ab_moe.py).
+    # pricing it as shardable). Measured on-chip (BASELINE.md round 5).
     moe_tfrac = None
     if measure_time and cfg.moe_experts > 0 and not cfg.moe_dropless:
         try:

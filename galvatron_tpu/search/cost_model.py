@@ -35,8 +35,8 @@ from galvatron_tpu.core.strategy import LayerStrategy
 
 # --- FITTED sharded-activation coefficients --------------------------------
 # Provenance: topology-measured activation classes against the v5e:2x4
-# compiler (experiments/act_memory_sweep.py; BASELINE.md round-5 probe and
-# the round-6 mlp_recompute sweep). ACT_TP_UNSHARDED: replicated share of
+# compiler (BASELINE.md round-5 probe and the round-6 mlp_recompute sweep;
+# `git show 384a03e:experiments/act_memory_sweep.py`). ACT_TP_UNSHARDED: replicated share of
 # saved activations that does not shrink with tp (round-5 measured tp1->tp2
 # at 0.71x => u = 2*0.71 - 1 = 0.42; the mlp_recompute policy removes the
 # fp32-widened norm saves from that share, keeping the fit there).
@@ -75,8 +75,7 @@ class ProfiledLayerType:
     # MEASURED share of the switch layer's fwd time that scales with ep
     # (the expert GEMMs; routing/sinkhorn/dispatch einsums do NOT shard by
     # ep). None → fall back to the param-fraction proxy. Measured on-chip by
-    # profiling/model.py's two-point ffn fit (experiments/ab_moe.py,
-    # BASELINE.md round-5).
+    # profiling/model.py's two-point ffn fit (BASELINE.md round-5).
     moe_expert_time_fraction: Optional[float] = None
     # Dropless top-k MoE layers (moe.moe_topk_block): the share of the fwd
     # time spent in the routed MLP, which tensor parallelism does NOT divide —
@@ -129,7 +128,7 @@ class ProfiledLayerType:
         share only — derived from the table (_replicated_mb), replacing the
         seed's unfitted flat ``0.5 + 0.5/tp`` discount which overstated the
         sp saving on attention-path-heavy tables. Coefficients fitted to
-        the topology-measured sweeps (experiments/act_memory_sweep.py;
+        the topology-measured sweeps (BASELINE.md round 6;
         tests/test_memory_fidelity.py pins)."""
         base = self.activation_mb_per_sample.get(tp)
         if base is None:
@@ -317,7 +316,7 @@ def layer_memory_cost(
     # working cast is likewise per-layer transient (cast → consume → free),
     # not a persistent 0.5x copy — it is charged once per device as part of
     # transient_overhead_mb, not per layer. Measured: memory-fidelity sweep
-    # vs the v5e:2x4 topology compiler, experiments/memory_fidelity.py
+    # vs the v5e:2x4 topology compiler, search/memory_fidelity.py
     # (BASELINE.md round-5).
     if s.dp_type == "zero3":
         states = 3.0 * sharded_mb

@@ -12,7 +12,7 @@ import pytest
 torch = pytest.importorskip("torch")
 transformers = pytest.importorskip("transformers")
 
-from galvatron_tpu.models import modeling
+from tests._stack_harness import forward
 from galvatron_tpu.models.convert import (
     config_from_hf_llama,
     from_hf_llama,
@@ -43,7 +43,7 @@ def logits_parity(hf_model, atol=2e-4):
     tokens = np.random.RandomState(0).randint(0, cfg.vocab_size, (2, 16))
     with torch.no_grad():
         ref = hf_model(torch.tensor(tokens)).logits.numpy()
-    ours = np.asarray(modeling.forward(params, jnp.asarray(tokens, jnp.int32), cfg))
+    ours = np.asarray(forward(params, jnp.asarray(tokens, jnp.int32), cfg))
     np.testing.assert_allclose(ours, ref, rtol=2e-4, atol=atol)
 
 
@@ -94,7 +94,7 @@ def test_hf_opt_logit_parity():
     tokens = np.random.RandomState(3).randint(0, 96, (2, 16))
     with torch.no_grad():
         ref = hf(torch.tensor(tokens)).logits.numpy()
-    ours = np.asarray(modeling.forward(params, jnp.asarray(tokens, jnp.int32), cfg))
+    ours = np.asarray(forward(params, jnp.asarray(tokens, jnp.int32), cfg))
     np.testing.assert_allclose(ours, ref, rtol=2e-4, atol=2e-4)
 
 
@@ -171,7 +171,7 @@ def test_hf_gpt2_logit_parity():
     tokens = np.random.RandomState(2).randint(0, 96, (2, 16))
     with torch.no_grad():
         ref = hf(torch.tensor(tokens)).logits.numpy()
-    ours = np.asarray(modeling.forward(params, jnp.asarray(tokens, jnp.int32), cfg))
+    ours = np.asarray(forward(params, jnp.asarray(tokens, jnp.int32), cfg))
     np.testing.assert_allclose(ours, ref, rtol=2e-4, atol=2e-4)
 
 
@@ -323,7 +323,7 @@ def test_to_hf_llama_roundtrip():
         tokens = np.random.RandomState(4).randint(0, cfg.vocab_size, (2, 12))
         with torch.no_grad():
             ref = hf2(torch.tensor(tokens)).logits.numpy()
-        ours = np.asarray(modeling.forward(params, jnp.asarray(tokens, jnp.int32), cfg))
+        ours = np.asarray(forward(params, jnp.asarray(tokens, jnp.int32), cfg))
         np.testing.assert_allclose(ours, ref, rtol=2e-4, atol=2e-4)
 
 
@@ -463,7 +463,7 @@ def baichuan_parity(alibi: bool, seed: int):
     tokens = np.random.RandomState(seed).randint(0, 128, (2, 16))
     with torch.no_grad():
         ref = torch_baichuan_forward(sd, tokens, 4, 2, alibi)
-    ours = np.asarray(modeling.forward(params, jnp.asarray(tokens, jnp.int32), cfg))
+    ours = np.asarray(forward(params, jnp.asarray(tokens, jnp.int32), cfg))
     np.testing.assert_allclose(ours, ref, rtol=2e-4, atol=2e-4)
 
 
@@ -558,7 +558,7 @@ def test_load_hf_baichuan_sharded_safetensors_rotary(tmp_path):
     tokens = np.random.RandomState(9).randint(0, 128, (2, 16))
     with torch.no_grad():
         ref = torch_baichuan_forward(sd, tokens, 4, 2, alibi=False)
-    ours = np.asarray(modeling.forward(params, jnp.asarray(tokens, jnp.int32), cfg))
+    ours = np.asarray(forward(params, jnp.asarray(tokens, jnp.int32), cfg))
     np.testing.assert_allclose(ours, ref, rtol=2e-4, atol=2e-4)
 
 

@@ -254,8 +254,10 @@ def test_packed_vs_padded_gradient_parity_bitexact(pos_embed):
     rng = np.random.RandomState(1)
     toks = rng.randint(0, 128, (4, 17)).astype(np.int32)
     packed = np.concatenate([toks, np.ones((4, 17), np.int32)], axis=1)
-    l_u, g_u = jax.value_and_grad(modeling.lm_loss)(params, jnp.asarray(toks), cfg)
-    l_p, g_p = jax.value_and_grad(modeling.lm_loss)(
+    # eager on purpose (a tiny model): op by op the two paths do the same arithmetic in the
+    # same order, which two separately compiled programs need not (5.96e-08 apart when jitted)
+    l_u, g_u = jax.value_and_grad(modeling.lm_loss)(params, jnp.asarray(toks), cfg)  # eager
+    l_p, g_p = jax.value_and_grad(modeling.lm_loss)(  # eager
         params, jnp.asarray(packed), cfg.replace(pack_sequences=True)
     )
     assert float(l_u) == float(l_p)
@@ -335,14 +337,12 @@ def test_cross_document_attention_leak_blocked():
     seg = np.zeros((1, 16), np.int32)
     toks[0, :8] = np.arange(1, 9); seg[0, :8] = 1
     toks[0, 8:14] = np.arange(20, 26); seg[0, 8:14] = 2
-    logits = modeling.forward(
-        params, jnp.asarray(np.concatenate([toks, seg], 1)), cfg
-    )
+    from tests._stack_harness import forward
+
+    logits = forward(params, jnp.asarray(np.concatenate([toks, seg], 1)), cfg)
     toks2 = toks.copy()
     toks2[0, 3] = 99  # sentinel in segment A
-    logits2 = modeling.forward(
-        params, jnp.asarray(np.concatenate([toks2, seg], 1)), cfg
-    )
+    logits2 = forward(params, jnp.asarray(np.concatenate([toks2, seg], 1)), cfg)
     np.testing.assert_array_equal(
         np.asarray(logits[0, 8:14]), np.asarray(logits2[0, 8:14])
     )
@@ -350,9 +350,7 @@ def test_cross_document_attention_leak_blocked():
     # padding is unreachable too: a pad-token change cannot move real logits
     toks3 = toks.copy()
     toks3[0, 15] = 77
-    logits3 = modeling.forward(
-        params, jnp.asarray(np.concatenate([toks3, seg], 1)), cfg
-    )
+    logits3 = forward(params, jnp.asarray(np.concatenate([toks3, seg], 1)), cfg)
     np.testing.assert_array_equal(
         np.asarray(logits[0, :14]), np.asarray(logits3[0, :14])
     )

@@ -167,7 +167,7 @@ def test_encdec_1f1b_training_matches_flat_trajectory(E, D, chunks):
     sub-pipelines, bounded stashes): two train steps must track a manual flat
     AdamW loop exactly — the strongest gradient check; includes a ragged
     (E=3, D=5) division."""
-    from galvatron_tpu.core.optim import adamw_update, init_opt_state
+    from tests._stack_harness import tracks_the_flat_trajectory
 
     cfg = T5.replace(enc_layers=E, num_layers=D)
     hp = HybridParallelConfig.uniform(
@@ -177,20 +177,9 @@ def test_encdec_1f1b_training_matches_flat_trajectory(E, D, chunks):
     rt = build_runtime(cfg, hp, adam=AdamConfig(lr=1e-3), global_batch_size=8)
     flat = modeling.init_model_params(jax.random.key(1), cfg)
     state = rt.init_state_from(flat)
-    opt = init_opt_state(flat)
-    ADAM = AdamConfig(lr=1e-3)
-    pipe_losses, ref_losses = [], []
-    for i in range(2):
-        rng = np.random.RandomState(i)
-        b = jnp.asarray(rng.randint(0, 128, (8, cfg.sample_len + 1)), jnp.int32)
-        state, loss = rt.train_step(state, b)
-        pipe_losses.append(float(loss))
-        ref_loss, grads = jax.jit(
-            jax.value_and_grad(lambda p, bb: modeling.lm_loss(p, bb, cfg))
-        )(flat, b)
-        flat, opt = adamw_update(flat, grads, opt, ADAM)
-        ref_losses.append(float(ref_loss))
-    np.testing.assert_allclose(pipe_losses, ref_losses, rtol=5e-5, atol=5e-5)
+    batches = [jnp.asarray(np.random.RandomState(i).randint(0, 128, (8, cfg.sample_len + 1)),
+                           jnp.int32) for i in range(2)]
+    tracks_the_flat_trajectory(rt, state, flat, cfg, batches, AdamConfig(lr=1e-3))
 
 
 @pytest.mark.slow  # fp16 pipeline variants are slow-marked across the suite
@@ -518,7 +507,7 @@ def test_encdec_any_chunks_parity():
     section-(k+1) slot for every m), so the former chunks % pp requirement
     was vestigial. Train-trajectory parity at chunks=3 and chunks=1 on pp=2,
     both schedules, against the flat single-device AdamW loop."""
-    from galvatron_tpu.core.optim import adamw_update, init_opt_state
+    from tests._stack_harness import flat_losses
 
     flat = modeling.init_model_params(jax.random.key(0), T5)
     rng = np.random.RandomState(7)
@@ -527,13 +516,7 @@ def test_encdec_any_chunks_parity():
         for _ in range(2)
     ]
     adam = AdamConfig(lr=1e-3)
-    params, opt = flat, init_opt_state(flat)
-    step = jax.jit(jax.value_and_grad(lambda p, b: modeling.lm_loss(p, b, T5)))
-    ref = []
-    for b in batches:
-        loss, grads = step(params, b)
-        params, opt = adamw_update(params, grads, opt, adam)
-        ref.append(float(loss))
+    ref = flat_losses(T5, flat, batches, adam)
     for chunks, ptype in [(3, "gpipe"), (3, "pipedream_flush"), (1, "pipedream_flush")]:
         hp = HybridParallelConfig.uniform(
             T5.total_layers, pp=2, chunks=chunks, mixed_precision="fp32",

@@ -8,6 +8,7 @@ tests/test_qwen3_next.py; the kernels as the chip's compiler sees them in
 tests/test_topology_aot.py; their numbers on the chip from
 ``experiments/ab_gdn.py``."""
 
+import functools
 import os
 
 import jax
@@ -18,19 +19,18 @@ import pytest
 from benchmark.lib import reference
 from galvatron_tpu.models import gdn
 from galvatron_tpu.models.modeling import PRESETS
-from galvatron_tpu.ops import flash_attention
 from galvatron_tpu.ops import gated_delta as gd
-from tests.test_qwen3_next import BF16_TOL, F32_TOL, close
+from tests import _stack_harness as harness
+from tests._stack_harness import highest_precision, on_a_chip  # noqa: F401  (a fixture)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ARCH = reference.load(ROOT, "qwen3_next")
 NAMES = "q k v g beta".split()
-
-
-@pytest.fixture(autouse=True)
-def highest_precision():
-    with jax.default_matmul_precision("highest"):
-        yield
+# the plain body's tolerances (tests/test_qwen3_next.py says what each leaves room for),
+# as a share of the largest magnitude alone
+F32_TOL, BF16_TOL = 5e-5, 1.5e-1
+close = functools.partial(harness.close, floor=0.0)
+pytestmark = pytest.mark.usefixtures("highest_precision")
 
 
 def inputs(s, seed=0, b=2, hk=2, r=2, dk=16, dv=8, decay=0.3, dtype=jnp.float32):
@@ -129,7 +129,7 @@ ENVELOPE = [
 def test_scan_path_envelope(monkeypatch, name, change, want):
     sizes = {**PUBLISHED, **change}
     assert gd.scan_path(**sizes) == "plain"  # the CPU: never the kernels
-    monkeypatch.setattr(flash_attention, "_use_interpret", lambda: False)
+    on_a_chip(monkeypatch)
     assert gd.scan_path(**sizes) == want
 
 
@@ -137,7 +137,7 @@ def test_dispatch_and_the_counter_ask_scan_path(monkeypatch):
     cfg = PRESETS["qwen3-next-80b-a3b"]
     assert gdn.path_counts(cfg.replace(num_layers=4))["scan"] == {"fused": 0, "plain": 3}
     assert gdn.path_counts(PRESETS["opt-1.3b"])["scan"] == {"fused": 0, "plain": 0}
-    monkeypatch.setattr(flash_attention, "_use_interpret", lambda: False)
+    on_a_chip(monkeypatch)
     assert gdn.path_counts(cfg.replace(num_layers=4))["scan"] == {"fused": 3, "plain": 0}
     assert gdn.path_counts(cfg.replace(num_layers=8, gdn_key_dim=64))["scan"] == {"fused": 0, "plain": 6}
     asked = []

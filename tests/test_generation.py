@@ -6,26 +6,19 @@ import numpy as np
 import pytest
 
 from galvatron_tpu.models import generation, modeling
-from galvatron_tpu.models.modeling import ModelConfig
-
-CFG = ModelConfig(
-    vocab_size=97,
-    hidden_size=64,
-    num_layers=2,
-    num_heads=4,
-    num_kv_heads=2,
-    ffn_dim=128,
-    max_seq_len=64,
-    dtype=jnp.float32,
-)
+from tests._serving_common import CFG
 
 
 def _greedy_uncached(params, cfg, prompt, n_new):
-    toks = prompt
-    for _ in range(n_new):
-        logits = modeling.forward(params, toks, cfg)
-        nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-        toks = jnp.concatenate([toks, nxt[:, None]], axis=1)
+    """Greedy tokens by the no-cache forward, ONE compiled program over the whole length:
+    the model is causal, so what lies past the tokens so far moves no logit before it."""
+    from tests._stack_harness import forward
+
+    n = prompt.shape[1]
+    toks = jnp.zeros((prompt.shape[0], n + n_new), jnp.int32).at[:, :n].set(prompt)
+    for t in range(n, n + n_new):
+        logits = forward(params, toks, cfg)
+        toks = toks.at[:, t].set(jnp.argmax(logits[:, t - 1], axis=-1).astype(jnp.int32))
     return toks
 
 

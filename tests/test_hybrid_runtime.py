@@ -9,25 +9,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from galvatron_tpu.core.optim import AdamConfig, adamw_update, init_opt_state
+from galvatron_tpu.core.optim import AdamConfig
 from galvatron_tpu.core.strategy import HybridParallelConfig, LayerStrategy
 from galvatron_tpu.models import modeling
-from galvatron_tpu.models.modeling import ModelConfig
 from galvatron_tpu.parallel.hybrid import build_runtime
 
-CFG = ModelConfig(
-    vocab_size=128,
-    hidden_size=64,
-    num_layers=4,
-    num_heads=4,
-    ffn_dim=128,
-    max_seq_len=32,
-    dtype=jnp.float32,
-)
+from tests._train_common import ADAM, CFG
+
 GPT_CFG = CFG.replace(
     pos_embed="learned", norm_type="layernorm", act_fn="gelu", tie_word_embeddings=True
 )
-ADAM = AdamConfig(lr=1e-3, grad_clip=1.0)
 STEPS = 3
 
 
@@ -39,17 +30,9 @@ def make_batches(seed=0, n=STEPS, batch=8, seq=32, vocab=128):
 def reference_losses(cfg, batches):
     """Single-device fp32 training loop (the reference's train.py baseline,
     models/llama_hf/train.py:21-74)."""
-    params = modeling.init_model_params(jax.random.key(0), cfg)
-    opt = init_opt_state(params)
-    losses = []
-    step = jax.jit(
-        lambda p, o, b: (jax.value_and_grad(lambda pp: modeling.lm_loss(pp, b, cfg))(p), o)
-    )
-    for b in batches:
-        (loss, grads), _ = step(params, opt, b)
-        params, opt = adamw_update(params, grads, opt, ADAM)
-        losses.append(float(loss))
-    return losses
+    from tests._stack_harness import flat_losses
+
+    return flat_losses(cfg, modeling.init_model_params(jax.random.key(0), cfg), batches, ADAM)
 
 
 def run_hybrid(cfg, hp, batches):
@@ -199,9 +182,10 @@ def test_shard_batch_places_global_batch():
 
 
 def _loss_and_grads(cfg, batch):
-    loss, grads = jax.value_and_grad(
-        lambda p: modeling.lm_loss(p, batch, cfg)
-    )(modeling.init_model_params(jax.random.key(0), cfg))
+    from tests._stack_harness import loss_and_gradients
+
+    loss, grads = loss_and_gradients(lambda p: modeling.lm_loss(p, batch, cfg),
+                                     modeling.init_model_params(jax.random.key(0), cfg))
     return float(loss), grads
 
 

@@ -11,6 +11,8 @@ import pytest
 from galvatron_tpu.models import generation
 from galvatron_tpu.models.modeling import PRESETS
 from galvatron_tpu.ops import kv_decode
+from tests._stack_harness import (  # noqa: F401  (`retraced`: a fixture)
+    close, prefill, retraced, small_tiles, step_forward)
 
 BLOCK, POSITIONS, KV, D = 16, 64, 2, 128
 
@@ -18,7 +20,7 @@ BLOCK, POSITIONS, KV, D = 16, 64, 2, 128
 @pytest.fixture(autouse=True)
 def small_blocks(monkeypatch):
     """The kernel's key block at the tests' sizes: slots of 64 are four blocks."""
-    monkeypatch.setattr(kv_decode, "KEY_BLOCK", BLOCK)
+    small_tiles(monkeypatch, kv_decode, key_block=BLOCK)
 
 
 def _case(lengths, s, g, dtype, layers=3, seed=0, d=D):
@@ -49,9 +51,8 @@ def _plain(qg, ks, vs, layer, first, scale, span=0):
 
 
 def _close(got, want, dtype):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    tol = 2e-5 if dtype == jnp.float32 else 2e-2
-    assert got.shape == want.shape and np.max(np.abs(got - want)) <= tol * max(1.0, np.max(np.abs(want)))
+    assert got.shape == want.shape
+    close(got, want, 2e-5 if dtype == jnp.float32 else 2e-2)
 
 
 # rows of unequal lengths: 1, a block less one, a whole number of blocks, a block plus
@@ -222,7 +223,8 @@ def test_outside_the_rule_the_plain_body_runs(monkeypatch, why):
 
 
 @pytest.mark.parametrize("window,kernels", [(8, 1), (28, 4)], ids=["ring_plain", "ring_kernel"])
-def test_a_decode_step_through_the_kernel_is_the_plain_steps(monkeypatch, window, kernels):
+def test_a_decode_step_through_the_kernel_is_the_plain_steps(monkeypatch, retraced, window,
+                                                            kernels):
     """`forward_with_cache` over a windowed stack whose full layer takes the kernel
     (and, with a ring of 28 + 4 places = two key blocks, its three window layers too),
     a row past the ring's first lap among them, against the same forward with the
@@ -233,20 +235,20 @@ def test_a_decode_step_through_the_kernel_is_the_plain_steps(monkeypatch, window
     params = modeling.init_model_params(jax.random.key(0), cfg)
     cache = generation.init_kv_cache(cfg, 3, POSITIONS, tokens=4)
     assert cache.wk.shape[3] == window + 4
-    row = jax.random.randint(jax.random.key(1), (1, 44), 0, cfg.vocab_size, jnp.int32)
-    chunk = jax.jit(lambda cache, tokens, start, slot: generation.forward_with_cache(
-        params, tokens, cfg, cache, start, slot=slot)[1])
+    row = jax.random.randint(jax.random.key(1), (44,), 0, cfg.vocab_size, jnp.int32).tolist()
+    retraced()  # (the key block and the rule are bound when a forward is traced)
     for slot, length in ((1, 44), (2, 8)):  # slot 1 has lapped the ring of 32, slot 2 not
-        for start in range(0, length, 4):
-            cache = chunk(cache, row[:, start:start + 4], jnp.int32(start), jnp.int32(slot))
+        _, cache = prefill(params, cfg, cache, slot, row[:length], chunk=4)
     toks = jnp.asarray([[0], [5], [7]], jnp.int32)
     offs = jnp.asarray([0, 44, 8], jnp.int32)
     calls = []
     real = kv_decode.attend_rows
     monkeypatch.setattr(kv_decode, "attend_rows", lambda *a, **k: calls.append(k["span"]) or real(*a, **k))
-    got, _ = generation.forward_with_cache(params, toks, cfg, cache, offs)
+    retraced()
+    got, _ = step_forward(params, cfg, cache, toks, offs)
     assert sorted(calls) == [0] + [window] * (kernels - 1)
     monkeypatch.setattr(kv_decode, "decode_path", lambda *a: "plain")
-    want, _ = generation.forward_with_cache(params, toks, cfg, cache, offs)
+    retraced()
+    want, _ = step_forward(params, cfg, cache, toks, offs)
     assert len(calls) == kernels
     _close(got, want, jnp.float32)

@@ -8,7 +8,6 @@ rows at different depths; the shares of the experts adding up to the uncut layer
 state held too low or left unreset failing the tolerance; the engine end to end; each
 refusal by its sentence."""
 
-import functools
 import os
 
 import jax
@@ -17,13 +16,12 @@ import numpy as np
 import pytest
 
 from benchmark.lib import reference
-from galvatron_tpu.core.optim import AdamConfig
-from galvatron_tpu.core.strategy import HybridParallelConfig
 from galvatron_tpu.models import generation, mixers, modeling, moe, shortconv
 from galvatron_tpu.models.modeling import PRESETS
 from galvatron_tpu.ops import kv_decode
-from galvatron_tpu.parallel.hybrid import build_runtime
-from galvatron_tpu.parallel.mesh import build_mesh
+from tests import _stack_harness as harness
+from tests._stack_harness import (  # noqa: F401  (`retraced`: a fixture)
+    close, decode, forward, prefill, retraced, seeded, through_the_cache, worst)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ARCH = reference.load(ROOT, "lfm2_moe")
@@ -61,53 +59,8 @@ def ref_cfg(cfg, share=None):
             "vocab_size": cfg.vocab_size, "expert_share": {"rank": rank, "of": of}}
 
 
-def seeded(cfg, seed=0, batch=2, length=None):
-    """Parameters with every norm scale and the selection bias moved off their start,
-    and rows of tokens."""
-    params = modeling.init_model_params(jax.random.key(seed), cfg)
-    leaves, tree = jax.tree.flatten(params)
-    keys = jax.random.split(jax.random.key(seed + 1), len(leaves))
-    leaves = [a + 0.2 * jax.random.normal(k, a.shape, a.dtype) if a.ndim == 1 else a
-              for a, k in zip(leaves, keys)]
-    rows = jax.random.randint(jax.random.key(seed + 2), (batch, length or cfg.max_seq_len), 0,
-                              cfg.vocab_size, jnp.int32)
-    return jax.tree.unflatten(tree, leaves), rows
-
-
-@functools.lru_cache(maxsize=None)
-def _ref_program(cfg, share):
-    rc = ref_cfg(cfg, share)
-
-    def run(params, rows):
-        with jax.default_matmul_precision("highest"):
-            return ARCH.logits(ARCH.published_weights(params, rc), rows, rc)
-
-    return jax.jit(run)
-
-
 def ref_logits(params, rows, cfg, share=None):
-    return _ref_program(cfg, share)(params, jnp.asarray(rows))
-
-
-# the two forwards the engine runs, jitted as the engine jits them (an eager forward
-# dispatches every operation of every layer by itself: minutes a test on the CPU)
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def _chunk_forward(params, cfg, cache, tokens, start, slot, last):
-    return generation.forward_with_cache(params, tokens, cfg, cache, start, slot=slot, last=last)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def _step_forward(params, cfg, cache, tokens, offsets):
-    return generation.forward_with_cache(params, tokens, cfg, cache, offsets)
-
-
-def worst(a, b):
-    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-    return np.max(np.abs(a - b)) / max(1.0, np.max(np.abs(b)))
-
-
-def close(a, b, tol=F32_TOL):
-    return worst(a, b) <= tol
+    return harness.reference(ARCH, ref_cfg, cfg, share).logits(params, jnp.asarray(rows))
 
 
 # -- the configuration ------------------------------------------------------------------
@@ -176,7 +129,7 @@ def test_the_theoretical_count_knows_the_kind():
 def test_no_cache_forward_matches_the_reference(share):
     cfg = small_cfg(moe_share=share)
     params, rows = seeded(cfg, length=40)
-    assert close(modeling.forward(params, rows, cfg), ref_logits(params, rows, cfg))
+    close(forward(params, rows, cfg), ref_logits(params, rows, cfg), F32_TOL)
 
 
 def test_the_conv_is_causal_and_three_taps_wide():
@@ -184,8 +137,8 @@ def test_the_conv_is_causal_and_three_taps_wide():
     nothing at p where every layer is a conv layer; p - 2 does."""
     cfg = small_cfg(num_layers=1, moe_dense_layers=1)
     params, rows = seeded(cfg, batch=1, length=20)
-    base = modeling.forward(params, rows, cfg)[0]
-    moved = lambda j: modeling.forward(  # noqa: E731
+    base = forward(params, rows, cfg)[0]
+    moved = lambda j: forward(  # noqa: E731
         params, rows.at[0, j].set((rows[0, j] + 1) % cfg.vocab_size), cfg)[0]
     assert np.array_equal(np.asarray(moved(10)[13]), np.asarray(base[13]))
     assert not np.array_equal(np.asarray(moved(10)[12]), np.asarray(base[12]))
@@ -195,22 +148,17 @@ def test_the_conv_is_causal_and_three_taps_wide():
 def test_the_qk_norm_is_each_heads_own():
     cfg = small_cfg(num_layers=3)
     params, rows = seeded(cfg, length=16)
-    per_head = modeling.forward(params, rows, cfg)
-    assert close(per_head, ref_logits(params, rows, cfg))
+    per_head = forward(params, rows, cfg)
+    close(per_head, ref_logits(params, rows, cfg), F32_TOL)
     whole = cfg.replace(qk_norm_per_head=False)
     wide = jax.tree.map(lambda a: a, params)
     a = wide["layers"][2]["attn"]
     a["q_norm"], a["k_norm"] = jnp.tile(a["q_norm"], 4), jnp.tile(a["k_norm"], 2)
-    assert not close(modeling.forward(wide, rows, whole), per_head, 1e-3)
+    assert worst(forward(wide, rows, whole), per_head) > 1e-3
 
 
 def test_bf16_in_place_of_float32_fails_the_tolerance():
-    cfg = small_cfg()
-    params, rows = seeded(cfg, length=40)
-    want = ref_logits(params, rows, cfg)
-    low = jax.tree.map(lambda a: a.astype(jnp.bfloat16) if a.ndim > 1 else a, params)
-    got = modeling.forward(low, rows, cfg.replace(dtype=jnp.bfloat16))
-    assert not close(got.astype(jnp.float32), want)
+    harness.bf16_fails_the_tolerance(small_cfg(), ref_logits, F32_TOL)
 
 
 def test_the_ranks_shares_add_up_to_the_uncut_layer():
@@ -227,51 +175,20 @@ def test_the_ranks_shares_add_up_to_the_uncut_layer():
         cut = whole.replace(moe_share=(rank, 4))
         mine = dict(mlp, **{k: mlp[k][rank * 2:(rank + 1) * 2] for k in ("w1", "w2", "w3")})
         total = total + moe.moe_topk_block(y, mine, cut)[0]
-    assert close(total, want)
+    close(total, want, F32_TOL)
     rc = ref_cfg(whole)
     fw = ARCH.published_weights(params, rc)["layers"][3]["feed_forward"]
     with jax.default_matmul_precision("highest"):
-        assert close(want[:1], ARCH.moe(y[:1], fw, rc))
+        close(want[:1], ARCH.moe(y[:1], fw, rc), F32_TOL)
         # and one rank's part is the reference's at that rank
         cut = ref_cfg(whole, (2, 4))
         part = dict(fw, experts={k: v[4:6] for k, v in fw["experts"].items()})
         mine = dict(mlp, **{k: mlp[k][4:6] for k in ("w1", "w2", "w3")})
-        assert close(moe.moe_topk_block(y, mine, whole.replace(moe_share=(2, 4)))[0][:1],
-                     ARCH.moe(y[:1], part, cut))
+        close(moe.moe_topk_block(y, mine, whole.replace(moe_share=(2, 4)))[0][:1],
+              ARCH.moe(y[:1], part, cut), F32_TOL)
 
 
 # -- the state beside the keys and values ------------------------------------------------
-
-
-def _prefill(params, cfg, cache, slot, prompt, chunk=CHUNK):
-    """``prompt`` into row ``slot`` in chunks padded to ``chunk``, the way the engine's
-    `_prefill_chunk` runs them (``last`` = the chunk's last real row) -> (logits of
-    every prompt position, cache)."""
-    out = []
-    for start in range(0, len(prompt), chunk):
-        n = min(chunk, len(prompt) - start)
-        buf = np.full((1, chunk), 7, np.int32)  # pad rows carry a token of their own
-        buf[0, :n] = prompt[start:start + n]
-        lg, cache = _chunk_forward(params, cfg, cache, jnp.asarray(buf), jnp.int32(start),
-                                   jnp.int32(slot), jnp.int32(n - 1))
-        out.append(np.asarray(lg[0, :n]))
-    return np.concatenate(out), cache
-
-
-def _decode(params, cfg, cache, rows_at, steps, slots=3):
-    """``steps`` shared decode steps over all ``slots`` rows: ``rows_at`` {slot: (row of
-    tokens, position)}; a slot out of use carries (0, 0) -> ({slot: logits}, cache)."""
-    out = {s: [] for s in rows_at}
-    at = {s: pos for s, (_, pos) in rows_at.items()}
-    for _ in range(steps):
-        toks, offs = np.zeros((slots, 1), np.int32), np.zeros((slots,), np.int32)
-        for s, (row, _) in rows_at.items():
-            toks[s, 0], offs[s] = row[at[s]], at[s]
-        lg, cache = _step_forward(params, cfg, cache, jnp.asarray(toks), jnp.asarray(offs))
-        for s in rows_at:
-            out[s].append(np.asarray(lg[s, 0]))
-            at[s] += 1
-    return {s: np.stack(v) for s, v in out.items()}, cache
 
 
 @pytest.mark.parametrize("chunk,prompt_len", [
@@ -287,9 +204,10 @@ def test_chunked_prefill_then_decode_matches_the_reference_at_every_position(chu
     want = np.asarray(ref_logits(params, rows, cfg))[0]
     row = rows[0].tolist()
     cache = generation.init_kv_cache(cfg, 3, SLOT, tokens=chunk)
-    pre, cache = _prefill(params, cfg, cache, 1, row[:prompt_len], chunk)
-    dec, _ = _decode(params, cfg, cache, {1: (row, prompt_len)}, 44 - prompt_len)
-    assert close(pre, want[:prompt_len]) and close(dec[1], want[prompt_len:])
+    got, _ = through_the_cache(params, cfg, {1: (row, prompt_len)}, {1: 44}, chunk=chunk,
+                               cache=cache)
+    close(got[1][:prompt_len], want[:prompt_len], F32_TOL)
+    close(got[1][prompt_len:], want[prompt_len:], F32_TOL)
 
 
 def test_pad_rows_of_a_chunk_do_not_reach_the_state():
@@ -299,12 +217,12 @@ def test_pad_rows_of_a_chunk_do_not_reach_the_state():
     params, rows = seeded(cfg, batch=1, length=12)
     row = rows[0].tolist()
     cache = generation.init_kv_cache(cfg, 2, SLOT, tokens=8)
-    _, padded = _prefill(params, cfg, cache, 0, row[:3], chunk=8)
-    _, exact = _prefill(params, cfg, cache, 0, row[:3], chunk=3)
+    _, padded = prefill(params, cfg, cache, 0, row[:3], chunk=8)
+    _, exact = prefill(params, cfg, cache, 0, row[:3], chunk=3)
     assert np.array_equal(np.asarray(padded.state[:, 0]), np.asarray(exact.state[:, 0]))
     buf = np.full((1, 8), 7, np.int32)
     buf[0, :3] = row[:3]
-    _, unled = _chunk_forward(params, cfg, cache, jnp.asarray(buf), jnp.int32(0), jnp.int32(0),
+    _, unled = harness.chunk_forward(params, cfg, cache, jnp.asarray(buf), jnp.int32(0), jnp.int32(0),
                               jnp.int32(7))  # as if the chunk were whole
     assert not np.array_equal(np.asarray(unled.state[:, 0]), np.asarray(exact.state[:, 0]))
 
@@ -325,20 +243,19 @@ def test_a_slot_used_twice_leaves_no_trace_in_the_next_request(between):
         cache = slots.cache
     else:
         cache = generation.init_kv_cache(cfg, 3, SLOT, tokens=CHUNK)
-    _, cache = _prefill(params, cfg, cache, 1, a[:22])
-    _, cache = _decode(params, cfg, cache, {1: (a, 22)}, 8)
+    _, cache = through_the_cache(params, cfg, {1: (a, 22)}, {1: 30}, cache=cache)
     assert float(jnp.max(jnp.abs(cache.state[:, 1]))) > 0
     if between == "idle_decode_steps":  # the slot free, other rows decoding: (0, 0) rows
-        _, cache = _decode(params, cfg, cache, {}, 3)
+        _, cache = decode(params, cfg, cache, {}, steps=3)
         assert float(jnp.max(jnp.abs(cache.state[:, 1]))) > 0  # an idle row wrote its own state
     if between == "reset":
         slots.cache = cache
         slots.reset()
         cache = slots.cache
         assert float(jnp.max(jnp.abs(cache.state))) == 0 and slots.free_slots == 3
-    pre, cache = _prefill(params, cfg, cache, 1, b[:9])
-    dec, _ = _decode(params, cfg, cache, {1: (b, 9)}, 12)
-    assert close(pre, want[1, :9]) and close(dec[1], want[1, 9:21])
+    got, _ = through_the_cache(params, cfg, {1: (b, 9)}, {1: 21}, cache=cache)
+    close(got[1][:9], want[1, :9], F32_TOL)
+    close(got[1][9:], want[1, 9:21], F32_TOL)
 
 
 def test_rows_at_different_depths_in_one_step_equal_each_row_alone():
@@ -347,18 +264,19 @@ def test_rows_at_different_depths_in_one_step_equal_each_row_alone():
     want = np.asarray(ref_logits(params, rows, cfg))
     a, b = rows[0].tolist(), rows[1].tolist()
     cache = generation.init_kv_cache(cfg, 3, SLOT, tokens=CHUNK)
-    _, cache = _prefill(params, cfg, cache, 2, a[:26])
-    _, cache = _prefill(params, cfg, cache, 0, b[:7])
-    both, _ = _decode(params, cfg, cache, {2: (a, 26), 0: (b, 7)}, 10)
-    assert close(both[2], want[0, 26:36]) and close(both[0], want[1, 7:17])
-    alone, _ = _decode(params, cfg, cache, {2: (a, 26)}, 10)
+    _, cache = prefill(params, cfg, cache, 2, a[:26])
+    _, cache = prefill(params, cfg, cache, 0, b[:7])
+    both, _ = decode(params, cfg, cache, {2: (a, 26, 36), 0: (b, 7, 17)})
+    close(both[2], want[0, 26:36], F32_TOL)
+    close(both[0], want[1, 7:17], F32_TOL)
+    alone, _ = decode(params, cfg, cache, {2: (a, 26, 36)})
     assert np.allclose(alone[2], both[2], atol=1e-6)
 
 
 @pytest.fixture
-def plant(monkeypatch):
+def plant(monkeypatch, retraced):
     """Plants a fault under the cached conv layer; the jitted forwards traced before and
-    after it are dropped (they keep the body they were traced with)."""
+    after it are dropped (`retraced`: they keep the body they were traced with)."""
     def planted(how):
         if how == "state_in_bf16":  # a state held below the float32 the configuration states
             monkeypatch.setattr(shortconv, "stored",
@@ -373,13 +291,9 @@ def plant(monkeypatch):
             monkeypatch.setattr(generation, "_project_qkv_at", rounded)
         else:  # a state not reset: the slot's previous request reaches the next one
             monkeypatch.setattr(shortconv, "fresh", lambda prev, offsets: prev)
-        _chunk_forward.clear_cache()
-        _step_forward.clear_cache()
+        retraced()
 
-    yield planted
-    monkeypatch.undo()
-    _chunk_forward.clear_cache()
-    _step_forward.clear_cache()
+    return planted
 
 
 @pytest.mark.parametrize("how", ["state_in_bf16", "kv_in_bf16", "state_not_reset"])
@@ -396,10 +310,8 @@ def test_a_state_held_too_low_or_left_unreset_fails_the_tolerance(plant, how):
 
     def served():
         cache = generation.init_kv_cache(cfg, 3, SLOT, tokens=CHUNK)
-        _, cache = _prefill(params, cfg, cache, 1, a[:22])
-        pre, cache = _prefill(params, cfg, cache, 1, b[:9])
-        dec, _ = _decode(params, cfg, cache, {1: (b, 9)}, 12)
-        return np.concatenate([pre, dec[1]])
+        _, cache = prefill(params, cfg, cache, 1, a[:22])
+        return through_the_cache(params, cfg, {1: (b, 9)}, {1: 21}, cache=cache)[0][1]
 
     sound = worst(served(), want[1, :21])
     assert sound <= F32_TOL
@@ -408,16 +320,7 @@ def test_a_state_held_too_low_or_left_unreset_fails_the_tolerance(plant, how):
 
 
 def test_lockstep_generation_carries_the_state():
-    cfg = small_cfg()
-    params, rows = seeded(cfg, batch=2, length=20)
-    out = generation.generate(params, rows, jnp.asarray([20, 14]), cfg, jax.random.key(0),
-                              max_new_tokens=8, min_prompt_len=14)
-    out = np.asarray(out)
-    assert np.array_equal(out[0, :20], np.asarray(rows[0])) and out.shape == (2, 28)
-    # greedy: each generated token is the reference's argmax given what came before
-    picks = np.asarray(ref_logits(params, out[:, :-1], cfg)).argmax(-1)
-    assert np.array_equal(out[0, 20:], picks[0, 19:])
-    assert np.array_equal(out[1, 14:], picks[1, 13:])
+    harness.lockstep_generation_is_greedy(small_cfg(), ref_logits, max_new_tokens=8)
 
 
 def test_cache_bytes_are_the_formula():
@@ -453,14 +356,6 @@ def test_cache_bytes_are_the_formula():
 # -- the engine ---------------------------------------------------------------------------
 
 
-def _engine(cfg, params, **kw):
-    from galvatron_tpu.serving import Engine
-
-    args = dict(num_slots=3, prefill_chunk=CHUNK, max_queue=64, eos_id=-1, pad_id=0, seed=0)
-    args.update(kw)
-    return Engine(params, cfg, **args)
-
-
 def test_engine_serves_the_stack_end_to_end():
     """Five requests through three slots (two slots are used twice, with a prompt that is
     no whole number of chunks among them): every served token is `generate`'s."""
@@ -468,15 +363,8 @@ def test_engine_serves_the_stack_end_to_end():
     params, rows = seeded(cfg, batch=5, length=30)
     prompts = [rows[0, :26].tolist(), rows[1, :5].tolist(), rows[2, :13].tolist(),
                rows[3, :30].tolist(), rows[4, :2].tolist()]
-    engine = _engine(cfg, params)
-    try:
-        served = engine.generate(prompts, max_new_tokens=16)
-        stats = engine.stats()
-    finally:
-        engine.close()
-    for prompt, got in zip(prompts, served):
-        want = generation.generate_np(params, cfg, [prompt], max_new_tokens=16, length_bucket=1)
-        assert got == want[0]
+    served, stats, _ = harness.serve(harness.engine(cfg, params), prompts, 16)
+    assert served == harness.generations(params, cfg, prompts, 16)
     per, state = 2 * 2 * 8 * 4, 2 * 32 * 4
     assert stats["cache_kind"] == "kv" and "kv_ring_positions" not in stats
     assert stats["cache_bytes"] == 3 * (per * 2 * SLOT + 6 * state)
@@ -487,21 +375,11 @@ def test_engine_serves_the_stack_end_to_end():
 
 
 def test_the_spans_carry_the_state_counters():
-    from galvatron_tpu.obs.tracing import tracer
-
     cfg = small_cfg()
     params, rows = seeded(cfg, batch=2, length=30)
-    engine = _engine(cfg, params)
-    tracer.enable(capacity=1 << 12)
-    tracer.clear()
-    try:
-        engine.generate([rows[0, :26].tolist(), rows[1, :6].tolist()], max_new_tokens=6)
-        spans = [e for e in tracer.snapshot() if e.get("ph") == "X"]
-    finally:
-        tracer.disable()
-        engine.close()
-    decode = [e["args"] for e in spans if e["name"] == "decode"]
-    both = [a for a in decode if a["active"] == 2]
+    _, _, spans = harness.serve(harness.engine(cfg, params),
+                                [rows[0, :26].tolist(), rows[1, :6].tolist()], 6, traced=True)
+    both = [a for a in spans["decode"] if a["active"] == 2]
     assert both
     for a in both:
         assert a["kv_full_live_positions"] == a["kv_live_positions"]
@@ -511,7 +389,7 @@ def test_the_spans_carry_the_state_counters():
         assert (a["state_layers"], a["state_bytes_per_row"]) == (6, 2 * 32 * 4)
         assert a["kv_cache_bytes_per_position"] == 2 * 2 * 8 * 4
         assert 0 < a["moe_held_pairs_per_token"] <= 2
-    admit = [e["args"] for e in spans if e["name"] == "admit"]
+    admit = spans["admit"]
     assert admit and sum(a["state_rows_zeroed"] for a in admit) == 2
     assert all(a["state_rows_zeroed"] == a["admitted"] for a in admit)
 
@@ -521,7 +399,7 @@ def test_slots_hold_a_whole_number_of_chunks():
     params, _ = seeded(cfg)
     with pytest.raises(ValueError, match="layers that keep a state needs slots of a whole "
                        "number of prompt chunks: max_seq_len 64 is no multiple of prefill_chunk 5"):
-        _engine(cfg, params, prefill_chunk=5)
+        harness.engine(cfg, params, prefill_chunk=5)
 
 
 @pytest.mark.parametrize("over,message", [
@@ -534,7 +412,7 @@ def test_the_engine_refuses_by_sentence(over, message):
     cfg = small_cfg()
     params, _ = seeded(cfg)
     with pytest.raises(ValueError, match=message):
-        _engine(cfg, params, **over)
+        harness.engine(cfg, params, **over)
 
 
 def test_other_kinds_beside_a_state_are_still_refused():
@@ -550,23 +428,16 @@ def test_other_kinds_beside_a_state_are_still_refused():
 
 
 def test_cli_serve_parses_the_cells_flags():
-    from galvatron_tpu.core.arguments import initialize_galvatron, model_config_from_args
-
-    ns = initialize_galvatron("serve", [
+    cfg = harness.cli_serve_parses([
         "--model_size", "lfm2-24b-a2b", "--num_layers", "22", "--vocab_size", "16384",
         "--moe_share", "0/4", "--seq_length", "16384", "--param_dtype", "bf16",
-        "--num_slots", "32", "--prefill_chunk", "1024"])
-    cfg = model_config_from_args(ns)
-    assert (cfg.num_layers, cfg.vocab_size, cfg.moe_share, cfg.moe_held) == (22, 16384, (0, 4), 16)
-    assert cfg.param_dtype == jnp.bfloat16 and cfg.max_seq_len == 16384
-    assert cfg.kinds.count("shortconv") == 17 and cfg.tie_word_embeddings
+        "--num_slots", "32", "--prefill_chunk", "1024"],
+        dict(num_layers=22, vocab_size=16384, moe_share=(0, 4), moe_held=16,
+             param_dtype=jnp.bfloat16, max_seq_len=16384, tie_word_embeddings=True))
+    assert cfg.kinds.count("shortconv") == 17
 
 
 # -- training ------------------------------------------------------------------------------
-
-
-def _plan(cfg, pp=1, **kw):
-    return HybridParallelConfig.uniform(cfg.num_layers, pp=pp, **kw)
 
 
 REFUSALS = [
@@ -581,43 +452,16 @@ REFUSALS = [
 ]
 
 
-@pytest.mark.parametrize("name,over,plan,message", REFUSALS, ids=[r[0] for r in REFUSALS])
-def test_build_runtime_refuses_by_name(name, over, plan, message):
-    cfg = small_cfg(**over)
-    mesh, axes = build_mesh(pp=plan.get("pp", 1), devices=jax.devices()[:2])
-    with pytest.raises(ValueError, match=message):
-        build_runtime(cfg, _plan(cfg, **plan), mesh=mesh, axes=axes, global_batch_size=4,
-                      seq_len=32)
+test_build_runtime_refuses_by_name = harness.refuses(REFUSALS, small_cfg)
 
 
 def test_the_runtime_trains_it_on_one_device():
-    cfg = small_cfg(num_layers=4, max_seq_len=32)
-    mesh, axes = build_mesh(pp=1, devices=jax.devices()[:1])
-    rt = build_runtime(cfg, _plan(cfg, mixed_precision="fp32"), mesh=mesh, axes=axes,
-                       adam=AdamConfig(lr=3e-3), global_batch_size=4, seq_len=32)
-    state = rt.init_state(jax.random.key(0))
-    batch = jax.random.randint(jax.random.key(1), (4, 33), 0, cfg.vocab_size, jnp.int32)
-    want = modeling.lm_loss(state["params"], batch, cfg)
-    assert float(rt.eval_loss(state, rt.shard_batch(batch))) == pytest.approx(float(want), rel=1e-5)
-    losses = []
-    for _ in range(8):
-        state, loss = rt.train_step(state, rt.shard_batch(batch))
-        losses.append(float(loss))
-    assert np.isfinite(losses).all() and losses[-1] < losses[0] - 0.1
+    harness.trains_on_one_device(small_cfg(num_layers=4, max_seq_len=32), steps=8, drop=0.1)
 
 
 def test_the_runtime_partitions_it_on_a_mesh():
-    """dp 8 under ZeRO-3 over the CPU mesh (GSPMD partitions the plain conv by itself):
-    the loss is the one-device loss."""
-    cfg = small_cfg(num_layers=4, max_seq_len=32)
-    batch = jax.random.randint(jax.random.key(1), (8, 33), 0, cfg.vocab_size, jnp.int32)
-    mesh, axes = build_mesh(pp=1)
-    rt = build_runtime(cfg, _plan(cfg, dp_type="zero3", mixed_precision="fp32"), mesh=mesh,
-                       axes=axes, global_batch_size=8, seq_len=32)
-    state = rt.init_state(jax.random.key(0))
-    params = jax.tree.map(np.asarray, state["params"])
-    want = modeling.lm_loss(params, batch, cfg)
-    assert float(rt.eval_loss(state, rt.shard_batch(batch))) == pytest.approx(float(want), rel=1e-4)
+    """(GSPMD partitions the plain conv by itself.)"""
+    harness.one_device_loss_on_a_mesh(small_cfg(num_layers=4, max_seq_len=32))
 
 
 def test_the_fingerprint_names_the_conv_body():

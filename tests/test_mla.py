@@ -6,6 +6,7 @@ small size on the CPU; the absorbed against the non-absorbed form; the shares of
 the experts adding up to the uncut layer; bf16 weights held once; the engine end
 to end; and each refusal by name."""
 
+import functools
 import math
 import os
 
@@ -16,12 +17,14 @@ import pytest
 
 from benchmark.lib import reference
 from galvatron_tpu.core.optim import AdamConfig
-from galvatron_tpu.core.strategy import HybridParallelConfig, LayerStrategy
 from galvatron_tpu.models import generation, mixers, mla, modeling, moe
 from galvatron_tpu.models.modeling import PRESETS
 from galvatron_tpu.ops import mla_decode, mla_prefill
 from galvatron_tpu.parallel.hybrid import build_runtime
 from galvatron_tpu.parallel.mesh import build_mesh
+from tests import _stack_harness as harness
+from tests._stack_harness import (  # noqa: F401  (`retraced`: a fixture)
+    close, forward, on_a_chip, retraced, seeded, small_tiles, through_the_cache)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ARCH = reference.load(ROOT, "sarvam_mla")
@@ -62,27 +65,8 @@ def ref_cfg(cfg, share=None):
             "vocab_size": cfg.vocab_size, "expert_share": {"rank": rank, "of": of}}
 
 
-def seeded(cfg, seed=0, batch=2, length=None):
-    """Parameters with every vector (norm scales, the router's bias) moved off its
-    initial value, and rows of tokens."""
-    params = modeling.init_model_params(jax.random.key(seed), cfg)
-    leaves, tree = jax.tree.flatten(params)
-    keys = jax.random.split(jax.random.key(seed + 1), len(leaves))
-    leaves = [a + 0.2 * jax.random.normal(k, a.shape, a.dtype) if a.ndim == 1 else a
-              for a, k in zip(leaves, keys)]
-    rows = jax.random.randint(jax.random.key(seed + 2), (batch, length or cfg.max_seq_len), 0,
-                              cfg.vocab_size, jnp.int32)
-    return jax.tree.unflatten(tree, leaves), rows
-
-
 def ref_logits(params, rows, cfg):
-    with jax.default_matmul_precision("highest"):
-        return ARCH.logits(ARCH.published_weights(params, ref_cfg(cfg)), rows, ref_cfg(cfg))
-
-
-def close(a, b, tol):
-    scale = max(1.0, float(jnp.abs(b).max()))
-    assert float(jnp.abs(a - b).max()) <= tol * scale, float(jnp.abs(a - b).max()) / scale
+    return harness.reference(ARCH, ref_cfg, cfg).logits(params, jnp.asarray(rows))
 
 
 # --- the preset and the parameters ----------------------------------------------------
@@ -146,7 +130,7 @@ def test_leading_layers_are_dense_and_the_rest_expert_layers():
     assert params["layers"][0]["mlp"]["w13"].shape == (32, 2 * 80)
     notes = modeling.model_annotations(cfg)["layers"]
     assert "w13" in notes[1]["mlp"] and "router" in notes[2]["mlp"]
-    logits, stats = modeling.forward_with_stats(params, rows, cfg)
+    logits, stats = harness.forward_with_stats(params, rows, cfg)
     assert len(stats) == 2  # the dense layers hand no router statistics up
     close(logits, ref_logits(params, rows, cfg), F32_TOL)
 
@@ -158,13 +142,13 @@ def test_leading_layers_are_dense_and_the_rest_expert_layers():
 def test_no_cache_forward_matches_the_reference(share):
     cfg = small_cfg(moe_share=share)
     params, rows = seeded(cfg)
-    close(modeling.forward(params, rows, cfg), ref_logits(params, rows, cfg), F32_TOL)
+    close(forward(params, rows, cfg), ref_logits(params, rows, cfg), F32_TOL)
 
 
 def test_the_loss_and_every_gradient_are_finite_and_the_bias_takes_none():
     cfg = small_cfg()
     params, rows = seeded(cfg, length=cfg.max_seq_len + 1)
-    loss, grads = jax.value_and_grad(lambda p: modeling.lm_loss(p, rows, cfg))(params)
+    loss, grads = harness.loss_and_gradients(lambda p: modeling.lm_loss(p, rows, cfg), params)
     assert np.isfinite(float(loss))
     assert all(bool(jnp.all(jnp.isfinite(g))) for g in jax.tree.leaves(grads))
     for lp in grads["layers"][1:]:
@@ -172,14 +156,9 @@ def test_the_loss_and_every_gradient_are_finite_and_the_bias_takes_none():
         assert float(jnp.abs(lp["mlp"]["router"]["w"]).max()) > 0.0
 
 
-def small_tiles(monkeypatch, key_block=16):
-    """The chunk kernel's key block at the tests' sizes: slots of 64 are whole blocks."""
-    monkeypatch.setattr(mla_prefill, "KEY_BLOCK", key_block)
-
-
 @pytest.mark.parametrize("key_block,path", [(64, "plain"), (16, "plain"), (16, "kernel")])
 def test_chunked_prefill_then_decoding_through_the_latent_cache_matches_the_reference(
-        monkeypatch, key_block, path):
+        monkeypatch, retraced, key_block, path):
     """One request in row 2 of a three-row latent slot cache: its prompt in chunks of
     16 (the chunk form, several blocks of keys where ``key_block`` is 16; the plain
     body, and the kernel `mla_chunk` interpreted), then token by token at per-row
@@ -187,24 +166,16 @@ def test_chunked_prefill_then_decoding_through_the_latent_cache_matches_the_refe
     monkeypatch.setattr(mla, "KEY_BLOCK", key_block)
     monkeypatch.setattr(mla, "key_block", lambda positions: min(positions, key_block))
     if path == "kernel":
-        small_tiles(monkeypatch, key_block)
+        small_tiles(monkeypatch, mla_prefill, key_block=key_block)
+    retraced()  # (the key blocks are bound when a forward is traced)
     cfg = small_cfg()
     assert mla._chunk_path(cfg, 16, 64) == path
     params, rows = seeded(cfg, batch=1)
     want = ref_logits(params, rows, cfg)
-    cache = generation.init_kv_cache(cfg, 3, cfg.max_seq_len)
+    # (rows 0 and 1 hold no request: they decode at (0, 0))
+    got, cache = through_the_cache(params, cfg, {2: (rows[0].tolist(), 48)}, {2: 64}, chunk=16)
     assert isinstance(cache, mla.LatentCache) and cache.latent.shape == (3, 3, 64, 24)
-    got = []
-    for start in range(0, 48, 16):
-        lg, cache = generation.forward_with_cache(
-            params, rows[:, start:start + 16], cfg, cache, jnp.int32(start), slot=jnp.int32(2))
-        got.append(lg)
-    for pos in range(48, 64):
-        tokens = jnp.zeros((3, 1), jnp.int32).at[2, 0].set(rows[0, pos])
-        offsets = jnp.asarray([0, 0, pos], jnp.int32)  # rows 0 and 1 hold no request
-        lg, cache = generation.forward_with_cache(params, tokens, cfg, cache, offsets)
-        got.append(lg[2:3])
-    close(jnp.concatenate(got, axis=1), want, F32_TOL)
+    close(got[2], want[0], F32_TOL)
     # rows 0 and 1 took only their own position 0
     assert float(jnp.abs(cache.latent[:, :2, 1:]).max()) == 0.0
 
@@ -232,7 +203,7 @@ def test_lockstep_generation_runs_over_the_latent_cache():
     out = generation.generate(params, rows[:, :8], jnp.asarray([8, 8]), cfg, jax.random.key(0),
                               max_new_tokens=6)
     assert out.shape == (2, 14)
-    logits = modeling.forward(params, out[:, :-1], cfg)
+    logits = forward(params, out[:, :-1], cfg)
     np.testing.assert_array_equal(np.asarray(jnp.argmax(logits[:, 7:], -1)), np.asarray(out[:, 8:]))
 
 
@@ -260,7 +231,7 @@ def test_the_decode_kernel_is_the_plain_absorbed_body(monkeypatch, dtype, window
     """`attend_window` through the kernel against `attend_absorbed` over the layer's
     slab: rows of length 1 (the window's own where it is longer), a key block less
     one, a block, a block plus one and the slot's capacity, in one batch."""
-    monkeypatch.setattr(mla_decode, "KEY_BLOCK", block)
+    small_tiles(monkeypatch, mla_decode, key_block=block)
     cfg = small_cfg(dtype=dtype)
     lengths = [1, block - 1, block, block + 1, 64]
     p, stacked, q_nope, q_rope, first = _window_case(cfg, window, lengths)
@@ -277,7 +248,7 @@ def test_what_lies_past_a_rows_length_is_never_read_or_never_counted(monkeypatch
     """Every position past each row's window filled with NaN: the kernel's outputs are
     bit for bit the clean cache's (blocks past the last live one are not fetched, and
     in the last live one such keys are masked and such values zeroed)."""
-    monkeypatch.setattr(mla_decode, "KEY_BLOCK", 16)
+    small_tiles(monkeypatch, mla_decode)
     cfg = small_cfg()
     lengths = [window, 15, 16, 17, 33, 64]
     p, stacked, q_nope, q_rope, first = _window_case(cfg, window, lengths)
@@ -296,7 +267,7 @@ def test_outside_the_kernels_envelope_the_plain_body_runs_and_the_counter_says_s
     a window of more query rows than the accumulator holds, compiled a width the chip
     keeps row-major: the plain body over the whole slab (the kernel is not called),
     `latent_read_positions` rows x capacity."""
-    monkeypatch.setattr(mla_decode, "KEY_BLOCK", 16)
+    small_tiles(monkeypatch, mla_decode)
     cfg, positions, window = small_cfg(), 64, 1
     if why == "capacity":
         positions = 40
@@ -306,10 +277,8 @@ def test_outside_the_kernels_envelope_the_plain_body_runs_and_the_counter_says_s
         window = 4
         monkeypatch.setattr(mla_decode, "MAX_QUERY_ROWS", 8)
     else:
-        from galvatron_tpu.ops import flash_attention
-
-        monkeypatch.setattr(flash_attention, "_use_interpret", lambda: False)
-        monkeypatch.setattr(mla_decode, "KEY_BLOCK", 1024)
+        on_a_chip(monkeypatch)
+        small_tiles(monkeypatch, mla_decode, key_block=1024)
         # (the cell's slots take the kernel compiled; 640 wide they would lie row-major)
         assert mla_decode.decode_path(16384, 576, 64, 512, jnp.bfloat16) == "kernel"
         assert mla_decode.decode_path(16384, 640, 64, 512, jnp.bfloat16) == "plain"
@@ -330,7 +299,7 @@ def test_outside_the_kernels_envelope_the_plain_body_runs_and_the_counter_says_s
 
 
 def test_read_positions_round_each_row_up_to_the_key_block(monkeypatch):
-    monkeypatch.setattr(mla_decode, "KEY_BLOCK", 16)
+    small_tiles(monkeypatch, mla_decode)
     cfg = small_cfg()
     # three rows in use (1, 2 and 3 blocks) and a free one, which attends its position 0
     assert mla.cache_read_positions(cfg, [1, 17, 48], 4, 64) == (1 + 2 + 3 + 1) * 16
@@ -375,7 +344,7 @@ def test_the_chunk_kernel_is_the_plain_chunk_body(monkeypatch, dtype, case):
     CLEAN cache: the real rows' outputs are the plain body's whatever lies past them
     (padding's latents; NaN past the chunk's end, where the output is bit for bit the
     clean cache's)."""
-    small_tiles(monkeypatch)
+    small_tiles(monkeypatch, mla_prefill)
     rows, offset, real, dirt = CHUNKS[case]
     cfg = small_cfg(dtype=dtype)
     p, stacked, q_nope, q_rope = _chunk_case(cfg, rows, offset)
@@ -419,16 +388,14 @@ ENVELOPE = {
 
 @pytest.mark.parametrize("edge", list(ENVELOPE))
 def test_chunk_path_answers_from_shapes_and_the_backend_each_side_of_every_edge(monkeypatch, edge):
-    from galvatron_tpu.ops import flash_attention
-
     for want, (positions, width, rows, dims, dtype, patched) in zip(("kernel", "plain"), ENVELOPE[edge]):
         with monkeypatch.context() as m:
             if "tiles" in patched:
-                small_tiles(m)
+                small_tiles(m, mla_prefill)
             if "gpu" in patched:
                 m.setattr(jax, "default_backend", lambda: "gpu")
             if "compiled" in patched:
-                m.setattr(flash_attention, "_use_interpret", lambda: False)
+                on_a_chip(m)
             assert mla_prefill.chunk_path(positions, width, rows, dims, dtype) == want, (edge, want)
 
 
@@ -447,7 +414,7 @@ def test_outside_the_chunk_kernels_envelope_the_plain_body_runs(monkeypatch):
     # all 64 keys are one block of the plain body
     assert mla.chunk_layout(cfg, 16, 64) == {"chunk_path": "plain", "chunk_key_block": 64}
     assert generation.chunk_layout(PRESETS["opt-125m"], 16, 64) == {}
-    small_tiles(monkeypatch)
+    small_tiles(monkeypatch, mla_prefill)
     assert generation.chunk_layout(cfg, 16, 64) == {"chunk_path": "kernel", "chunk_key_block": 16}
 
 
@@ -573,27 +540,12 @@ def test_the_shared_expert_is_ungated_and_a_gated_one_still_is():
 # --- what the kind does not implement, by the table -----------------------------------
 
 
-def _plan(cfg, strategies=None, pp=1, **kw):
-    strategies = strategies or [LayerStrategy() for _ in range(cfg.total_layers)]
-    return HybridParallelConfig(pp=pp, layer_strategies=strategies, mixed_precision="fp32", **kw)
-
-
 REFUSALS = [
-    ("tp", lambda c: _plan(c, [LayerStrategy(tp=2) for _ in range(3)]),
-     "tensor parallelism .* latent-attention layers"),
-    ("cp", lambda c: _plan(c, [LayerStrategy(cp=2) for _ in range(3)]),
-     "context parallelism .* latent-attention"),
-    ("pp", lambda c: _plan(c, pp=2), "pipeline parallelism .* dropless top-k MoE"),
+    ("tp", {}, dict(tp=2), "tensor parallelism .* latent-attention layers"),
+    ("cp", {}, dict(cp=2), "context parallelism .* latent-attention"),
+    ("pp", {}, dict(pp=2), "pipeline parallelism .* dropless top-k MoE"),
 ]
-
-
-@pytest.mark.parametrize("name,plan,message", REFUSALS, ids=[r[0] for r in REFUSALS])
-def test_build_runtime_refuses_by_name(name, plan, message):
-    cfg = small_cfg()
-    mesh, axes = build_mesh(pp=2 if name == "pp" else 1, devices=jax.devices()[:2])
-    with pytest.raises(ValueError, match=message):
-        build_runtime(cfg, plan(cfg), mesh=mesh, axes=axes, adam=AdamConfig(),
-                      global_batch_size=4, seq_len=64)
+test_build_runtime_refuses_by_name = harness.refuses(REFUSALS, small_cfg, seq_len=64)
 
 
 def test_limits_refuse_packing_and_accept_the_cache():
@@ -605,7 +557,8 @@ def test_limits_refuse_packing_and_accept_the_cache():
     assert mixers.cache_kind(cfg) == "mla" and mixers.cache_kind(PRESETS["opt-1.3b"]) is None
     mesh, axes = build_mesh(pp=1, devices=jax.devices()[:1])
     with pytest.raises(ValueError, match="pack_sequences .* latent-attention"):
-        build_runtime(cfg.replace(pack_sequences=True), _plan(cfg), mesh=mesh, axes=axes,
+        build_runtime(cfg.replace(pack_sequences=True),
+                      harness.plan(cfg, mixed_precision="fp32"), mesh=mesh, axes=axes,
                       adam=AdamConfig(), global_batch_size=4, seq_len=64)
     # a stack that interleaves cache layouts has no slot cache
     mixed = cfg.replace(layer_kinds=("mla", "attention", "mla"))
@@ -619,21 +572,11 @@ def test_limits_refuse_packing_and_accept_the_cache():
 
 
 def test_the_runtime_trains_it_on_one_device():
-    cfg = small_cfg()
-    mesh, axes = build_mesh(pp=1, devices=jax.devices()[:1])
-    rt = build_runtime(cfg, _plan(cfg), mesh=mesh, axes=axes, adam=AdamConfig(lr=3e-3),
-                       global_batch_size=4, seq_len=64)
-    state = rt.init_state(jax.random.key(0))
-    bias0 = np.asarray(state["params"]["layers"][1]["mlp"]["router"]["bias"])
-    batch = rt.shard_batch(np.asarray(seeded(cfg, batch=4, length=65)[1]))
-    losses = []
-    for _ in range(4):
-        state, loss = rt.train_step(state, batch)
-        losses.append(float(loss))
-    assert losses[-1] < losses[0]
+    before, state = harness.trains_on_one_device(small_cfg(), steps=4, drop=0.0)
     # no gradient reaches the selection bias (and no weight decay here): left constant
     np.testing.assert_array_equal(
-        np.asarray(state["params"]["layers"][1]["mlp"]["router"]["bias"]), bias0)
+        np.asarray(state["params"]["layers"][1]["mlp"]["router"]["bias"]),
+        before["layers"][1]["mlp"]["router"]["bias"])
 
 
 def test_the_search_prices_the_kind_and_leaves_out_what_it_lacks():
@@ -657,6 +600,9 @@ def test_the_search_prices_the_kind_and_leaves_out_what_it_lacks():
 
 # --- the engine -----------------------------------------------------------------------
 
+#: two slots, prompts in chunks of 8, no request expiring: this file's engine
+TWO_SLOTS = functools.partial(harness.engine, num_slots=2, prefill_chunk=8, request_ttl_s=None)
+
 
 def test_engine_serves_an_mla_stack_end_to_end():
     """More requests than slots through ``serving.Engine``: every slot is reused,
@@ -675,7 +621,7 @@ def test_engine_serves_an_mla_stack_end_to_end():
         audit = engine.drain(timeout_s=10.0)
     for prompt, out in zip(prompts, outs):
         assert out[:len(prompt)] == prompt and len(out) == len(prompt) + 6
-        logits = modeling.forward(params, jnp.asarray([out[:-1]]), cfg)[0]
+        logits = forward(params, jnp.asarray([out[:-1]]), cfg)[0]
         assert np.asarray(jnp.argmax(logits[len(prompt) - 1:], -1)).tolist() == out[len(prompt):]
     assert not audit["leaked"] and stats["completed"] == 5 and stats["engine_restarts"] == 0
     assert stats["kv_backend"] == "slot" and stats["cache_kind"] == "latent"
@@ -688,25 +634,12 @@ def test_engine_serves_an_mla_stack_end_to_end():
 
 
 def test_the_decode_span_carries_the_iterations_counters():
-    from galvatron_tpu.obs.tracing import tracer
-    from galvatron_tpu.serving import Engine
-
     cfg = small_cfg()
     params, rows = seeded(cfg, batch=2, length=12)
-    tracer.enable(capacity=4096)
-    tracer.clear()  # (the ring is the process's: another engine's spans may lie in it)
-    try:
-        engine = Engine(params, cfg, num_slots=2, prefill_chunk=8, request_ttl_s=None)
-        engine.generate([np.asarray(r).tolist() for r in rows], max_new_tokens=4)
-        stats = engine.stats()
-        engine.drain(timeout_s=10.0)
-        spans = [ev for ev in tracer.snapshot() if ev.get("name") == "decode"]
-        chunks = [ev for ev in tracer.snapshot() if ev.get("name") == "prefill"]
-    finally:
-        tracer.disable()
-        tracer.clear()
-    assert spans
-    args = spans[0]["args"]
+    _, stats, spans = harness.serve(TWO_SLOTS(cfg, params), [np.asarray(r).tolist() for r in rows],
+                                    4, traced=True)
+    chunks = spans["prefill"]
+    args = spans["decode"][0]
     assert args["latent_cache_bytes_per_position"] == 3 * 24 * 4
     assert args["latent_live_positions"] >= 2 * 12
     assert {"moe_held_pairs_per_token", "moe_load_imbalance"} <= set(args)
@@ -722,9 +655,9 @@ def test_the_decode_span_carries_the_iterations_counters():
     assert args["moe_live_rows_share"] == pytest.approx(held / (4 * 8), rel=1e-5)  # a tile each
     assert stats["moe_live_rows_share"] <= 4 / 32
     assert len(chunks) == 2  # (a prompt's 12 tokens: two chunks; the span carries the last's)
-    for ev in chunks:
-        assert ev["args"]["moe_row_tile"] == 8
-        assert 0.0 <= ev["args"]["moe_live_rows_share"] <= 16 / (4 * 8)
+    for chunk in chunks:
+        assert chunk["moe_row_tile"] == 8
+        assert 0.0 <= chunk["moe_live_rows_share"] <= 16 / (4 * 8)
 
 
 def test_the_engine_counts_the_positions_its_decode_kernel_fetches(monkeypatch):
@@ -732,25 +665,13 @@ def test_the_engine_counts_the_positions_its_decode_kernel_fetches(monkeypatch):
     greedy tokens are the model's, and every `decode` span (and `stats()`) carries
     `latent_read_positions`: the rows' lengths rounded up to the key block, one block
     for the free row."""
-    from galvatron_tpu.obs.tracing import tracer
-    from galvatron_tpu.serving import Engine
-
-    monkeypatch.setattr(mla_decode, "KEY_BLOCK", 16)
+    small_tiles(monkeypatch, mla_decode)
     cfg = small_cfg()
     params, rows = seeded(cfg, batch=1, length=12)
     prompt = np.asarray(rows[0]).tolist()
-    tracer.enable(capacity=4096)
-    tracer.clear()
-    try:
-        engine = Engine(params, cfg, num_slots=2, prefill_chunk=8, request_ttl_s=None)
-        out, = engine.generate([prompt], max_new_tokens=8)
-        stats = engine.stats()
-        engine.drain(timeout_s=10.0)
-        spans = [ev["args"] for ev in tracer.snapshot() if ev.get("name") == "decode"]
-    finally:
-        tracer.disable()
-        tracer.clear()
-    logits = modeling.forward(params, jnp.asarray([out[:-1]]), cfg)[0]
+    (out,), stats, spans = harness.serve(TWO_SLOTS(cfg, params), [prompt], 8, traced=True)
+    spans = spans["decode"]
+    logits = forward(params, jnp.asarray([out[:-1]]), cfg)[0]
     assert np.asarray(jnp.argmax(logits[11:], -1)).tolist() == out[12:]
     lives = [a["latent_live_positions"] for a in spans]
     assert min(lives) <= 16 < max(lives)  # the row grows past its first key block
@@ -766,26 +687,14 @@ def test_the_engine_counts_the_chunks_its_chunk_kernel_takes(monkeypatch, path):
     the greedy tokens are the model's; `latent_chunks_kernel / prefill_chunks` is 1.0 |
     0.0; the request's `prefill` span carries the count and the key blocks a layer's
     chunk attention fetched (1 + 1 + 2 of 16 keys | 3 times all 64)."""
-    from galvatron_tpu.obs.tracing import tracer
-    from galvatron_tpu.serving import Engine
-
     if path == "kernel":
-        small_tiles(monkeypatch)
+        small_tiles(monkeypatch, mla_prefill)
     cfg = small_cfg()
     params, rows = seeded(cfg, batch=1, length=20)
     prompt = np.asarray(rows[0]).tolist()
-    tracer.enable(capacity=4096)
-    tracer.clear()
-    try:
-        engine = Engine(params, cfg, num_slots=2, prefill_chunk=8, request_ttl_s=None)
-        out, = engine.generate([prompt], max_new_tokens=4)
-        stats = engine.stats()
-        engine.drain(timeout_s=10.0)
-        span, = [ev["args"] for ev in tracer.snapshot() if ev.get("name") == "prefill"]
-    finally:
-        tracer.disable()
-        tracer.clear()
-    logits = modeling.forward(params, jnp.asarray([out[:-1]]), cfg)[0]
+    (out,), stats, spans = harness.serve(TWO_SLOTS(cfg, params), [prompt], 4, traced=True)
+    span, = spans["prefill"]
+    logits = forward(params, jnp.asarray([out[:-1]]), cfg)[0]
     assert np.asarray(jnp.argmax(logits[19:], -1)).tolist() == out[20:]
     assert stats["prefill_chunks"] == 3 and stats["chunk_path"] == path
     share = stats["latent_chunks_kernel"] / stats["prefill_chunks"]
@@ -801,7 +710,7 @@ def test_a_prefill_that_ends_early_keeps_the_kernels_share_whole(monkeypatch):
     from galvatron_tpu.core import faults
     from galvatron_tpu.serving import Engine
 
-    small_tiles(monkeypatch)
+    small_tiles(monkeypatch, mla_prefill)
     cfg = small_cfg()
     params, rows = seeded(cfg, batch=1, length=20)
     engine = Engine(params, cfg, num_slots=2, prefill_chunk=8, start_loop=False)
@@ -820,40 +729,21 @@ def test_a_prefill_that_ends_early_keeps_the_kernels_share_whole(monkeypatch):
 
 
 def test_an_attention_engine_reports_no_latent_chunk():
-    from galvatron_tpu.obs.tracing import tracer
-    from galvatron_tpu.serving import Engine
-
     cfg = PRESETS["opt-125m"].replace(num_layers=2, hidden_size=64, num_heads=4, ffn_dim=128,
                                       vocab_size=128, max_seq_len=32, dtype=jnp.float32)
     params = modeling.init_model_params(jax.random.key(0), cfg)
-    tracer.enable(capacity=4096)
-    tracer.clear()
-    try:
-        engine = Engine(params, cfg, num_slots=2, prefill_chunk=8, request_ttl_s=None)
-        engine.generate([list(range(1, 13))], max_new_tokens=2)
-        stats = engine.stats()
-        engine.drain(timeout_s=10.0)
-        span, = [ev["args"] for ev in tracer.snapshot() if ev.get("name") == "prefill"]
-    finally:
-        tracer.disable()
-        tracer.clear()
+    _, stats, spans = harness.serve(TWO_SLOTS(cfg, params), [list(range(1, 13))], 2, traced=True)
+    span, = spans["prefill"]
     assert stats["prefill_chunks"] == 2
     assert not {"chunk_path", "latent_chunks_kernel"} & set(stats)
     assert not {"latent_chunks_kernel", "latent_chunk_key_blocks"} & set(span)
 
 
 def test_an_attention_engines_stats_name_its_kv_cache():
-    from galvatron_tpu.serving import Engine
-
     cfg = PRESETS["opt-125m"].replace(num_layers=2, hidden_size=64, num_heads=4, ffn_dim=128,
                                       vocab_size=128, max_seq_len=32, dtype=jnp.float32)
     params = modeling.init_model_params(jax.random.key(0), cfg)
-    engine = Engine(params, cfg, num_slots=2, prefill_chunk=8, request_ttl_s=None)
-    try:
-        engine.generate([[1, 2, 3]], max_new_tokens=2)
-        stats = engine.stats()
-    finally:
-        engine.drain(timeout_s=10.0)
+    _, stats, _ = harness.serve(TWO_SLOTS(cfg, params), [[1, 2, 3]], 2)
     assert stats["cache_kind"] == "kv" and stats["kv_cache_bytes_per_position"] == 2 * 2 * 64 * 4
     assert stats["cache_bytes"] == 2 * 2 * 64 * 4 * 2 * 32 and "moe_load_imbalance" not in stats
 
@@ -875,15 +765,12 @@ def test_the_slot_length_warning_names_the_flag():
 
 
 def test_cli_serve_parses_the_cells_flags():
-    from galvatron_tpu.core.arguments import initialize_galvatron, model_config_from_args
-
-    ns = initialize_galvatron("serve", [
+    harness.cli_serve_parses([
         "--model_size", "sarvam-105b", "--num_layers", "5", "--vocab_size", "65536",
         "--moe_share", "0/4", "--seq_length", "16384", "--param_dtype", "bf16",
-        "--num_slots", "32", "--prefill_chunk", "1024"])
-    cfg = model_config_from_args(ns)
-    assert (cfg.num_layers, cfg.vocab_size, cfg.max_seq_len, cfg.moe_held) == (5, 65536, 16384, 32)
-    assert cfg.kinds == ("mla",) * 5 and cfg.ffn == 16384 and cfg.param_dtype == jnp.bfloat16
+        "--num_slots", "32", "--prefill_chunk", "1024"],
+        dict(num_layers=5, vocab_size=65536, max_seq_len=16384, moe_held=32, kinds=("mla",) * 5,
+             ffn=16384, param_dtype=jnp.bfloat16))
 
 
 def test_weights_held_in_the_compute_type_take_the_bounded_held_path_unjoined(monkeypatch):

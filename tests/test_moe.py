@@ -85,7 +85,9 @@ def test_moe_model_forward():
     params = modeling.init_model_params(jax.random.key(0), cfg)
     assert "router" in params["layers"][0]["mlp"]
     tokens = jnp.zeros((2, 8), jnp.int32)
-    logits = modeling.forward(params, tokens, cfg)
+    from tests._stack_harness import forward
+
+    logits = forward(params, tokens, cfg)
     assert logits.shape == (2, 8, cfg.vocab_size)
     annots = modeling.model_annotations(cfg)
     assert annots["layers"][0]["mlp"]["w1"] == ("ep", "fsdp", "tp")

@@ -4,6 +4,7 @@ weights, at a small size on the CPU; and tests that fail on the likely mistakes
 (weights renormalised over the top-k, q/k normalised per head, a dropped pair,
 top-1 in place of top-k)."""
 
+import functools
 import os
 
 import jax
@@ -18,6 +19,8 @@ from galvatron_tpu.models import modeling, moe
 from galvatron_tpu.models.modeling import PRESETS
 from galvatron_tpu.parallel.hybrid import build_runtime
 from galvatron_tpu.parallel.mesh import build_mesh
+from tests import _stack_harness as harness
+from tests._stack_harness import forward, highest_precision  # noqa: F401  (a fixture)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ARCH = reference.load(ROOT, "olmoe")
@@ -41,7 +44,7 @@ def small_cfg(**kw):
     return PRESETS["olmoe-1b-7b"].replace(**base)
 
 
-def ref_cfg(cfg):
+def ref_cfg(cfg, share=None):
     return {"num_attention_heads": cfg.num_heads, "rms_norm_eps": cfg.norm_eps,
             "rope_theta": cfg.rope_theta, "num_experts_per_tok": cfg.moe_top_k,
             "num_experts": cfg.moe_experts, "hidden_size": cfg.hidden_size,
@@ -49,40 +52,21 @@ def ref_cfg(cfg):
             "vocab_size": cfg.vocab_size}
 
 
-def seeded(cfg, seed=0, batch=2):
-    """Parameters with every learned scale away from its initial 1 (a norm that
-    ignores its scale must show) and rows of tokens."""
-    params = modeling.init_model_params(jax.random.key(seed), cfg)
-    leaves, tree = jax.tree.flatten(params)
-    keys = jax.random.split(jax.random.key(seed + 1), len(leaves))
-    leaves = [a * (1 + 0.3 * jax.random.normal(k, a.shape, a.dtype)) if a.ndim == 1 else a
-              for a, k in zip(leaves, keys)]
-    rows = jax.random.randint(jax.random.key(seed + 2), (batch, cfg.max_seq_len + 1), 0,
-                              cfg.vocab_size, jnp.int32)
-    return jax.tree.unflatten(tree, leaves), rows
+#: every learned scale 0.3 away from its initial 1 (a norm that ignores its scale must
+#: show), and rows with the last position's target
+seeded = functools.partial(harness.seeded, spread=0.3, targets=True)
+#: differences as a share of the largest magnitude alone (gradients far under 1)
+close = functools.partial(harness.close, floor=0.0)
+pytestmark = pytest.mark.usefixtures("highest_precision")
+
+
+def ref_logits(params, rows, cfg):
+    return harness.reference(ARCH, ref_cfg, cfg).logits(params, rows)
 
 
 def reference_objective(params, rows, cfg):
     """(cross entropy, auxiliary loss) of the plain reference, float32 highest."""
-    rc = ref_cfg(cfg)
-    with jax.default_matmul_precision("highest"):
-        w = ARCH.published_weights(params, rc)
-        logp = jax.nn.log_softmax(ARCH.logits(w, rows[:, :-1], rc), axis=-1)
-        ce = -jnp.mean(jnp.take_along_axis(logp, rows[:, 1:, None], axis=-1))
-        return ce, ARCH.aux_loss(w, rows[:, :-1], rc)
-
-
-def close(got, want, tol):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    scale = max(np.abs(want).max(), 1e-30)
-    err = np.abs(got - want).max() / scale
-    assert err <= tol, f"largest difference {err:.3e} of the largest magnitude, bound {tol:.0e}"
-
-
-@pytest.fixture(autouse=True)
-def highest_precision():
-    with jax.default_matmul_precision("highest"):
-        yield
+    return harness.reference(ARCH, ref_cfg, cfg).objective(params, rows)
 
 
 def test_reference_imports_nothing_of_the_programs_models():
@@ -93,10 +77,10 @@ def test_reference_imports_nothing_of_the_programs_models():
 def test_logits_loss_and_aux_loss_match_the_reference_in_float32():
     cfg = small_cfg()
     params, rows = seeded(cfg)
-    logits, stats = modeling.forward_with_stats(params, rows[:, :-1], cfg)
+    logits, stats = harness.forward_with_stats(params, rows[:, :-1], cfg)
     rc = ref_cfg(cfg)
-    close(logits, ARCH.logits(ARCH.published_weights(params, rc), rows[:, :-1], rc), F32_TOL)
-    s, n, aux = modeling.moe_loss_sum(params, rows, cfg)
+    close(logits, ref_logits(params, rows[:, :-1], cfg), F32_TOL)
+    s, n, aux = harness.moe_loss_sum(params, rows, cfg)
     ce, aux_ref = reference_objective(params, rows, cfg)
     close(s / n, ce, F32_TOL)
     close(aux["moe_aux_loss"], aux_ref, F32_TOL)
@@ -104,30 +88,15 @@ def test_logits_loss_and_aux_loss_match_the_reference_in_float32():
     load = np.asarray(ARCH.expert_load(ARCH.published_weights(params, rc), rows[:, :-1], rc))
     pairs = rows[:, :-1].size * cfg.moe_top_k
     close(aux["moe_load_max_over_mean"], load.max() / (pairs / cfg.moe_experts), F32_TOL)
-    assert float(modeling.lm_loss(params, rows, cfg)) == pytest.approx(float(s / n), rel=1e-6)
+    assert float(harness.lm_loss(params, rows, cfg)) == pytest.approx(float(s / n), rel=1e-6)
 
 
 def test_every_gradient_matches_the_reference_in_float32():
     cfg = small_cfg()
     params, rows = seeded(cfg)
-
-    def program(p):
-        s, n, aux = modeling.moe_loss_sum(p, rows, cfg)
-        return s / n + cfg.moe_aux_coef * aux["moe_aux_loss"]
-
-    def plain(p):
-        ce, aux = reference_objective(p, rows, cfg)
-        return ce + cfg.moe_aux_coef * aux
-
-    got, want = jax.grad(program)(params), jax.grad(plain)(params)
-    flat_got, flat_want = jax.tree.leaves_with_path(got), jax.tree.leaves(want)
-    assert len(flat_got) == len(flat_want) > 20
-    for (path, g), w in zip(flat_got, flat_want):
-        assert float(jnp.abs(w).max()) > 0, f"{jax.tree_util.keystr(path)}: reference gradient is zero"
-        try:
-            close(g, w, F32_TOL)
-        except AssertionError as e:
-            raise AssertionError(f"{jax.tree_util.keystr(path)}: {e}") from None
+    got = harness.every_gradient_matches(params, rows, cfg, harness.reference(ARCH, ref_cfg, cfg),
+                                         F32_TOL)
+    assert len(jax.tree.leaves(got)) > 20
 
 
 @pytest.mark.parametrize("chunks", [1, 2])
@@ -160,25 +129,16 @@ def test_runtime_differentiates_ce_plus_aux_and_logs_ce_alone(chunks):
                    (reference_objective(p, jnp.asarray(mb), cfg) for mb in np.split(rows, chunks))
                    ) / chunks
 
-    want = jax.grad(plain)(params)
+    want = harness.loss_and_gradients(plain, params)[1]
     # Adam's first moment after one step from zero is (1 - b1) x the gradient
     got = jax.tree.map(lambda m: m / (1 - adam.b1), state["opt"]["mu"])
-    for (path, g), w in zip(jax.tree.leaves_with_path(got), jax.tree.leaves(want)):
-        try:
-            close(g, w, F32_TOL)
-        except AssertionError as e:
-            raise AssertionError(f"{jax.tree_util.keystr(path)}: {e}") from None
+    harness.close_by_leaf(got, want, F32_TOL, floor=0.0)
 
 
 def test_bf16_compute_stays_within_what_bf16_warrants():
     cfg = small_cfg(dtype=jnp.bfloat16)
     params, rows = seeded(cfg)
-    logits = modeling.forward(params, rows[:, :-1], cfg).astype(jnp.float32)
-    rc = ref_cfg(cfg)
-    want = ARCH.logits(ARCH.published_weights(params, rc), rows[:, :-1], rc)
-    close(logits, want, BF16_TOL)
-    err = np.abs(np.asarray(logits) - np.asarray(want)).max() / np.abs(np.asarray(want)).max()
-    assert err > F32_TOL, "a bf16 run inside the float32 tolerance: the tolerance has no power"
+    harness.bf16_stays_within(cfg, params, rows[:, :-1], ref_logits, BF16_TOL, F32_TOL)
 
 
 # -- the likely mistakes ------------------------------------------------------
@@ -257,17 +217,14 @@ def test_qk_norm_on_the_stacked_flash_path_equals_the_einsum_path():
     einsum branch norms (b, s, n, d): one model, two layouts."""
     cfg = small_cfg(num_layers=1, hidden_size=256, num_heads=2, max_seq_len=256, ffn_dim=128)
     params, rows = seeded(cfg, batch=1)
-    xla = modeling.forward(params, rows[:, :-1], cfg)
-    flash = modeling.forward(params, rows[:, :-1], cfg.replace(attn_impl="flash"))
+    xla = forward(params, rows[:, :-1], cfg)
+    flash = forward(params, rows[:, :-1], cfg.replace(attn_impl="flash"))
     close(flash, xla, 1e-4)  # the kernels' own blocked softmax, float32
 
 
-@pytest.mark.parametrize("field", ["ep", "pp", "cp"])
-def test_layouts_the_sorted_path_does_not_implement_are_refused_by_name(field):
-    cfg = small_cfg()
-    hp = HybridParallelConfig.uniform(cfg.num_layers, mixed_precision="fp32", **{field: 2})
-    with pytest.raises(ValueError, match=field + ">1"):
-        build_runtime(cfg, hp, global_batch_size=8, seq_len=cfg.max_seq_len)
+test_layouts_the_sorted_path_does_not_implement_are_refused_by_name = harness.refuses(
+    [(field, {}, {field: 2}, field + ">1") for field in ("ep", "pp", "cp")], small_cfg,
+    batch=8, devices=8)
 
 
 def test_sharded_layouts_train_like_one_device():

@@ -118,8 +118,8 @@ def test_flash_blocked_causal_path_matches_reference():
     for a, b in zip(g_blocked, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-4)
     # the gate scales with head_dim and unroll count, not bare seq length
-    # (the s*d envelope is 8192*128 under the raised vmem_limit_bytes —
-    # experiments/vmem_probe.py / ab_flash_bwd.py)
+    # (the s*d envelope is 8192*128 under the raised vmem_limit_bytes:
+    # BASELINE.md, "Round-4 VMEM discovery")
     assert not fa._use_blocked(16384, 128, True, 1024, 1024)
     assert not fa._use_blocked(8192, 256, True, 1024, 1024)
     assert not fa._use_blocked(4096, 128, True, 128, 128)
@@ -488,7 +488,8 @@ def test_cp_layer_in_hybrid_runtime():
     from galvatron_tpu.core.optim import AdamConfig
     from galvatron_tpu.core.strategy import HybridParallelConfig, LayerStrategy
     from galvatron_tpu.parallel.hybrid import build_runtime
-    from tests.test_hybrid_runtime import CFG, make_batches, reference_losses
+    from tests.test_hybrid_runtime import make_batches, reference_losses
+    from tests._train_common import CFG
 
     hp = HybridParallelConfig(
         pp=1,
@@ -518,7 +519,8 @@ def test_cp_layer_under_pipeline_parallelism():
     from galvatron_tpu.core.optim import AdamConfig
     from galvatron_tpu.core.strategy import HybridParallelConfig, LayerStrategy
     from galvatron_tpu.parallel.hybrid import build_runtime
-    from tests.test_hybrid_runtime import CFG, make_batches
+    from tests.test_hybrid_runtime import make_batches
+    from tests._train_common import CFG
 
     batches = make_batches()
 
@@ -635,7 +637,8 @@ def test_ulysses_layer_in_hybrid_runtime():
     from galvatron_tpu.core.optim import AdamConfig
     from galvatron_tpu.core.strategy import HybridParallelConfig, LayerStrategy
     from galvatron_tpu.parallel.hybrid import build_runtime
-    from tests.test_hybrid_runtime import CFG, make_batches, reference_losses
+    from tests.test_hybrid_runtime import make_batches, reference_losses
+    from tests._train_common import CFG
 
     hp = HybridParallelConfig(
         pp=1,
@@ -670,7 +673,8 @@ def test_cp_composes_with_pipeline_parallelism(impl):
     the attention-context sharding inside the pipelined stage fns)."""
     from galvatron_tpu.core.strategy import HybridParallelConfig, LayerStrategy
     from galvatron_tpu.parallel.hybrid import build_runtime
-    from tests.test_hybrid_runtime import ADAM, CFG, make_batches, reference_losses
+    from tests.test_hybrid_runtime import make_batches, reference_losses
+    from tests._train_common import ADAM, CFG
 
     batches = make_batches()
     flat = modeling.init_model_params(jax.random.key(0), CFG)

@@ -8,28 +8,18 @@ import os
 import re
 import warnings
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from galvatron_tpu.models import generation, modeling
+from galvatron_tpu.models import generation
 from galvatron_tpu.models.modeling import ModelConfig
 from galvatron_tpu.ops import flash_attention as fa
 from galvatron_tpu.serving import Engine, NoFreeBlocks, PagedKVCache
 from galvatron_tpu.serving.kv_slots import SlotKVCache, effective_max_seq_len
 from galvatron_tpu.serving.paged_kv import BLOCK_STATES, NULL_BLOCK, prefix_hashes
 
-CFG = ModelConfig(
-    vocab_size=97,
-    hidden_size=64,
-    num_layers=2,
-    num_heads=4,
-    num_kv_heads=2,
-    ffn_dim=128,
-    max_seq_len=64,
-    dtype=jnp.float32,
-)
+from tests._serving_common import CFG, params, prompts as _prompts  # noqa: F401  (`params`: a fixture)
 
 TINY = ModelConfig(
     vocab_size=64,
@@ -40,17 +30,6 @@ TINY = ModelConfig(
     max_seq_len=32,
     dtype=jnp.float32,
 )
-
-
-@pytest.fixture(scope="module")
-def params():
-    return modeling.init_model_params(jax.random.key(0), CFG)
-
-
-def _prompts(n, lo=3, hi=14, seed=0):
-    rng = np.random.RandomState(seed)
-    return [rng.randint(1, CFG.vocab_size, (rng.randint(lo, hi),)).tolist()
-            for _ in range(n)]
 
 
 # ---------------------------------------------------------------------------

@@ -10,46 +10,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from galvatron_tpu.core.optim import AdamConfig
 from galvatron_tpu.core.strategy import HybridParallelConfig, LayerStrategy
 from galvatron_tpu.models import modeling
-from galvatron_tpu.models.modeling import ModelConfig
 from galvatron_tpu.parallel.hybrid import build_runtime
 
-CFG = ModelConfig(
-    vocab_size=128,
-    hidden_size=64,
-    num_layers=4,
-    num_heads=4,
-    ffn_dim=128,
-    max_seq_len=32,
-    dtype=jnp.float32,
-)
-ADAM = AdamConfig(lr=1e-3, grad_clip=1.0)
-
-
-def unstack_params(pipe_params, cfg, pp):
-    """stage-stacked → flat pp=1 param tree (on host)."""
-    lps = cfg.num_layers // pp
-    layers = []
-    for s in range(pp):
-        for j in range(lps):
-            layers.append(jax.tree.map(lambda a: np.asarray(a)[s], pipe_params["stages"][j]))
-    flat = {k: jax.tree.map(np.asarray, v) for k, v in pipe_params.items() if k != "stages"}
-    flat["layers"] = layers
-    return flat
-
-
-def make_batch(seed=0, batch=8, seq=32, vocab=128):
-    rng = np.random.RandomState(seed)
-    return jnp.asarray(rng.randint(0, vocab, (batch, seq + 1)), jnp.int32)
-
-
-def reference_loss_and_step(flat_params, batch, cfg):
-    loss, grads = jax.jit(jax.value_and_grad(lambda p, b: modeling.lm_loss(p, b, cfg)))(
-        flat_params, batch
-    )
-    return float(loss), grads
+from tests._train_common import ADAM, CFG, make_batch, unstack_params
 
 
 @pytest.mark.parametrize(
@@ -70,7 +35,7 @@ def test_gpipe_loss_parity(pp, chunks, tp, dp_type, ckpt):
     state = rt.init_state(jax.random.key(0))
     batch = make_batch()
     flat = unstack_params(state["params"], CFG, pp)
-    ref_loss, _ = reference_loss_and_step(flat, batch, CFG)
+    ref_loss = float(jax.jit(lambda p, b: modeling.lm_loss(p, b, CFG))(flat, batch))
     loss = float(rt.eval_loss(state, batch))
     np.testing.assert_allclose(loss, ref_loss, rtol=2e-5, atol=2e-5)
 
@@ -78,7 +43,7 @@ def test_gpipe_loss_parity(pp, chunks, tp, dp_type, ckpt):
 def test_gpipe_training_matches_reference_trajectory():
     """Train 3 steps with pp=2 and compare each step's loss against a manual
     single-device AdamW loop starting from the identical (unstacked) params."""
-    from galvatron_tpu.core.optim import adamw_update, init_opt_state
+    from tests._stack_harness import tracks_the_flat_trajectory
 
     pp, chunks = 2, 2
     hp = HybridParallelConfig.uniform(
@@ -87,17 +52,7 @@ def test_gpipe_training_matches_reference_trajectory():
     rt = build_runtime(CFG, hp, adam=ADAM, global_batch_size=8, seq_len=32)
     state = rt.init_state(jax.random.key(0))
     flat = jax.tree.map(jnp.asarray, unstack_params(state["params"], CFG, pp))
-    opt = init_opt_state(flat)
-
-    batches = [make_batch(seed=i) for i in range(3)]
-    pipe_losses, ref_losses = [], []
-    for b in batches:
-        state, loss = rt.train_step(state, b)
-        pipe_losses.append(float(loss))
-        ref_loss, grads = reference_loss_and_step(flat, b, CFG)
-        flat, opt = adamw_update(flat, grads, opt, ADAM)
-        ref_losses.append(ref_loss)
-    np.testing.assert_allclose(pipe_losses, ref_losses, rtol=5e-5, atol=5e-5)
+    tracks_the_flat_trajectory(rt, state, flat, CFG, [make_batch(seed=i) for i in range(3)], ADAM)
 
 
 def test_pipeline_stage_param_placement():

@@ -9,14 +9,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from galvatron_tpu.core.optim import AdamConfig, adamw_update, init_opt_state
 from galvatron_tpu.core.strategy import HybridParallelConfig
 from galvatron_tpu.models import modeling
-from galvatron_tpu.models.modeling import ModelConfig
 from galvatron_tpu.parallel.hybrid import build_runtime
-from tests.test_pipeline import CFG, make_batch, unstack_params
-
-ADAM = AdamConfig(lr=1e-3, grad_clip=1.0)
+from tests._stack_harness import tracks_the_flat_trajectory
+from tests._train_common import ADAM, CFG, make_batch, unstack_params
 
 
 @pytest.mark.parametrize(
@@ -36,18 +33,7 @@ def test_1f1b_training_parity(pp, chunks, tp, dp_type, ckpt):
     rt = build_runtime(CFG, hp, adam=ADAM, global_batch_size=8, seq_len=32)
     state = rt.init_state(jax.random.key(0))
     flat = jax.tree.map(jnp.asarray, unstack_params(state["params"], CFG, pp))
-    opt = init_opt_state(flat)
-    pipe_losses, ref_losses = [], []
-    for i in range(2):
-        b = make_batch(seed=i)
-        state, loss = rt.train_step(state, b)
-        pipe_losses.append(float(loss))
-        ref_loss, grads = jax.jit(
-            jax.value_and_grad(lambda p, bb: modeling.lm_loss(p, bb, CFG))
-        )(flat, b)
-        flat, opt = adamw_update(flat, grads, opt, ADAM)
-        ref_losses.append(float(ref_loss))
-    np.testing.assert_allclose(pipe_losses, ref_losses, rtol=5e-5, atol=5e-5)
+    tracks_the_flat_trajectory(rt, state, flat, CFG, [make_batch(seed=i) for i in range(2)], ADAM)
 
 
 @pytest.mark.parametrize("pp,chunks", [(2, 4), (4, 4)])
@@ -77,15 +63,5 @@ def test_1f1b_tied_embeddings():
     rt = build_runtime(cfg, hp, adam=ADAM, global_batch_size=8, seq_len=32)
     state = rt.init_state(jax.random.key(0))
     flat = jax.tree.map(jnp.asarray, unstack_params(state["params"], cfg, 2))
-    opt = init_opt_state(flat)
-    pipe_losses, ref_losses = [], []
-    for i in range(2):
-        b = make_batch(seed=10 + i)
-        state, loss = rt.train_step(state, b)
-        pipe_losses.append(float(loss))
-        ref_loss, grads = jax.jit(
-            jax.value_and_grad(lambda p, bb: modeling.lm_loss(p, bb, cfg))
-        )(flat, b)
-        flat, opt = adamw_update(flat, grads, opt, ADAM)
-        ref_losses.append(float(ref_loss))
-    np.testing.assert_allclose(pipe_losses, ref_losses, rtol=5e-5, atol=5e-5)
+    tracks_the_flat_trajectory(rt, state, flat, cfg, [make_batch(seed=10 + i) for i in range(2)],
+                               ADAM)

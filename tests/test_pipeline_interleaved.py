@@ -11,17 +11,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from galvatron_tpu.core.optim import AdamConfig
 from galvatron_tpu.core.strategy import HybridParallelConfig, LayerStrategy
 from galvatron_tpu.models import modeling
-from galvatron_tpu.models.modeling import ModelConfig
 from galvatron_tpu.parallel.hybrid import build_runtime
 
-CFG = ModelConfig(
-    vocab_size=128, hidden_size=64, num_layers=4, num_heads=4,
-    ffn_dim=128, max_seq_len=32, dtype=jnp.float32,
-)
-ADAM = AdamConfig(lr=1e-3, grad_clip=1.0)
+from tests._train_common import ADAM, CFG, make_batch, unstack_params
 
 
 def unstack_vparams(pipe_params, cfg, pp, vpp):
@@ -36,11 +30,6 @@ def unstack_vparams(pipe_params, cfg, pp, vpp):
     flat = {k: jax.tree.map(np.asarray, v) for k, v in pipe_params.items() if k != "vstages"}
     flat["layers"] = layers
     return flat
-
-
-def make_batch(seed=0, batch=8, seq=32, vocab=128):
-    rng = np.random.RandomState(seed)
-    return jnp.asarray(rng.randint(0, vocab, (batch, seq + 1)), jnp.int32)
 
 
 @pytest.mark.parametrize(
@@ -63,15 +52,13 @@ def test_interleaved_loss_parity(pp, vpp, chunks, tp, dp_type):
     if vpp > 1:
         flat = unstack_vparams(jax.device_get(state["params"]), CFG, pp, vpp)
     else:
-        from tests.test_pipeline import unstack_params
-
         flat = unstack_params(jax.device_get(state["params"]), CFG, pp)
     ref_loss = float(jax.jit(lambda p, b: modeling.lm_loss(p, b, CFG))(flat, batch))
     np.testing.assert_allclose(pipe_loss, ref_loss, rtol=2e-5, atol=2e-5)
 
 
 def test_interleaved_training_matches_reference_trajectory():
-    from galvatron_tpu.core.optim import adamw_update, init_opt_state
+    from tests._stack_harness import tracks_the_flat_trajectory
 
     hp = HybridParallelConfig.uniform(
         4, pp=2, vpp=2, tp=1, chunks=2, mixed_precision="fp32", vocab_tp=1
@@ -79,18 +66,8 @@ def test_interleaved_training_matches_reference_trajectory():
     rt = build_runtime(CFG, hp, adam=ADAM, global_batch_size=8, seq_len=32)
     state = rt.init_state(jax.random.key(0))
     flat = unstack_vparams(jax.device_get(state["params"]), CFG, 2, 2)
-    opt = init_opt_state(flat)
-    losses, ref_losses = [], []
-    for i in range(3):
-        batch = make_batch(seed=i)
-        loss, grads = jax.jit(
-            jax.value_and_grad(lambda p, b: modeling.lm_loss(p, b, CFG))
-        )(flat, batch)
-        flat, opt = adamw_update(flat, grads, opt, ADAM)
-        ref_losses.append(float(loss))
-        state, ploss = rt.train_step(state, batch)
-        losses.append(float(ploss))
-    np.testing.assert_allclose(losses, ref_losses, rtol=2e-4, atol=2e-4)
+    tracks_the_flat_trajectory(rt, state, flat, CFG, [make_batch(seed=i) for i in range(3)], ADAM,
+                               tol=2e-4)
 
 
 def test_interleaved_constraint_errors():
@@ -177,7 +154,7 @@ def test_interleaved_1f1b_loss_parity(pp, vpp, chunks, tp, dp_type, ckpt):
 def test_interleaved_1f1b_training_matches_flat_trajectory():
     """Two interleaved-1F1B steps track a manual flat AdamW loop — the
     hand-written mirrored backward wave must produce exact gradients."""
-    from galvatron_tpu.core.optim import adamw_update, init_opt_state
+    from tests._stack_harness import tracks_the_flat_trajectory
 
     cfg = CFG.replace(num_layers=8)
     hp = HybridParallelConfig.uniform(
@@ -188,18 +165,9 @@ def test_interleaved_1f1b_training_matches_flat_trajectory():
     rt = build_runtime(cfg, hp, adam=ADAM, global_batch_size=8, seq_len=32)
     flat = modeling.init_model_params(jax.random.key(1), cfg)
     state = rt.init_state_from(flat)
-    opt = init_opt_state(flat)
-    pipe_losses, ref_losses = [], []
-    for i in range(2):
-        b = jnp.asarray(np.random.RandomState(i).randint(0, 128, (8, 33)), jnp.int32)
-        state, loss = rt.train_step(state, b)
-        pipe_losses.append(float(loss))
-        ref_loss, grads = jax.jit(
-            jax.value_and_grad(lambda p, bb: modeling.lm_loss(p, bb, cfg))
-        )(flat, b)
-        flat, opt = adamw_update(flat, grads, opt, ADAM)
-        ref_losses.append(float(ref_loss))
-    np.testing.assert_allclose(pipe_losses, ref_losses, rtol=5e-5, atol=5e-5)
+    batches = [jnp.asarray(np.random.RandomState(i).randint(0, 128, (8, 33)), jnp.int32)
+               for i in range(2)]
+    tracks_the_flat_trajectory(rt, state, flat, cfg, batches, ADAM)
 
 
 def test_interleaved_1f1b_bounded_stash_long_chunks():

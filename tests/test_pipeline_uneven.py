@@ -9,32 +9,18 @@ Parity methodology mirrors test_pipeline.py: pipeline losses must equal the
 flat single-path model on identical weights."""
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from galvatron_tpu.core.optim import AdamConfig, adamw_update, init_opt_state
-from galvatron_tpu.core.strategy import HybridParallelConfig, balanced_division
+from galvatron_tpu.core.strategy import HybridParallelConfig
 from galvatron_tpu.models import modeling
-from galvatron_tpu.models.modeling import ModelConfig
 from galvatron_tpu.parallel.hybrid import build_runtime
+from tests._stack_harness import tracks_the_flat_trajectory
 from galvatron_tpu.search.pp_division import pp_division_memory_balanced
 
-CFG5 = ModelConfig(
-    vocab_size=128,
-    hidden_size=64,
-    num_layers=5,
-    num_heads=4,
-    ffn_dim=128,
-    max_seq_len=32,
-    dtype=jnp.float32,
-)
-ADAM = AdamConfig(lr=1e-3, grad_clip=1.0)
+from tests._train_common import ADAM, CFG, make_batch
 
-
-def make_batch(seed=0, batch=8, seq=32, vocab=128):
-    rng = np.random.RandomState(seed)
-    return jnp.asarray(rng.randint(0, vocab, (batch, seq + 1)), jnp.int32)
+CFG5 = CFG.replace(num_layers=5)
 
 
 def flat_loss(flat_params, batch, cfg):
@@ -75,18 +61,7 @@ def test_uneven_1f1b_training_matches_flat_trajectory():
     rt = build_runtime(CFG5, hp, adam=ADAM, global_batch_size=8, seq_len=32)
     flat = modeling.init_model_params(jax.random.key(1), CFG5)
     state = rt.init_state_from(flat)
-    opt = init_opt_state(flat)
-    pipe_losses, ref_losses = [], []
-    for i in range(2):
-        b = make_batch(seed=i)
-        state, loss = rt.train_step(state, b)
-        pipe_losses.append(float(loss))
-        ref_loss, grads = jax.jit(
-            jax.value_and_grad(lambda p, bb: modeling.lm_loss(p, bb, CFG5))
-        )(flat, b)
-        flat, opt = adamw_update(flat, grads, opt, ADAM)
-        ref_losses.append(float(ref_loss))
-    np.testing.assert_allclose(pipe_losses, ref_losses, rtol=5e-5, atol=5e-5)
+    tracks_the_flat_trajectory(rt, state, flat, CFG5, [make_batch(seed=i) for i in range(2)], ADAM)
 
 
 def test_default_division_pp4_ragged():

@@ -14,8 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from galvatron_tpu.models import generation, modeling
-from galvatron_tpu.models.modeling import ModelConfig
+from galvatron_tpu.models import generation
 from galvatron_tpu.ops import quant
 from galvatron_tpu.ops.quant import (
     QuantParityError,
@@ -30,29 +29,9 @@ from galvatron_tpu.serving.engine import (
     _prefill_chunk,
 )
 
-CFG = ModelConfig(
-    vocab_size=97,
-    hidden_size=64,
-    num_layers=2,
-    num_heads=4,
-    num_kv_heads=2,
-    ffn_dim=128,
-    max_seq_len=64,
-    dtype=jnp.float32,
-)
+from tests._serving_common import CFG, params, prompts as _prompts  # noqa: F401  (`params`: a fixture)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-@pytest.fixture(scope="module")
-def params():
-    return modeling.init_model_params(jax.random.key(0), CFG)
-
-
-def _prompts(n, lo=3, hi=14, seed=0):
-    rng = np.random.RandomState(seed)
-    return [rng.randint(1, CFG.vocab_size, (rng.randint(lo, hi),)).tolist()
-            for _ in range(n)]
 
 
 def _repetitive_prompts(n, period=3, length=12):

@@ -6,6 +6,7 @@ weights, at a small size on the CPU; the chunked gated delta rule against the
 recurrent form; the shares of the experts adding up to the uncut layer; and each
 refusal by name."""
 
+import functools
 import os
 
 import jax
@@ -21,6 +22,8 @@ from galvatron_tpu.models.modeling import PRESETS
 from galvatron_tpu.ops.gated_delta import gated_delta_chunked
 from galvatron_tpu.parallel.hybrid import build_runtime
 from galvatron_tpu.parallel.mesh import build_mesh
+from tests import _stack_harness as harness
+from tests._stack_harness import forward, highest_precision  # noqa: F401  (a fixture)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ARCH = reference.load(ROOT, "qwen3_next")
@@ -68,39 +71,19 @@ def ref_cfg(cfg, share=None):
             "expert_share": {"rank": rank, "of": of}}
 
 
-def seeded(cfg, seed=0, batch=2):
-    """Parameters with every vector (norm weights at 0 or 1, A_log, dt_bias)
-    moved off its initial value, and rows of tokens."""
-    params = modeling.init_model_params(jax.random.key(seed), cfg)
-    leaves, tree = jax.tree.flatten(params)
-    keys = jax.random.split(jax.random.key(seed + 1), len(leaves))
-    leaves = [a + 0.2 * jax.random.normal(k, a.shape, a.dtype) if a.ndim == 1 else a
-              for a, k in zip(leaves, keys)]
-    rows = jax.random.randint(jax.random.key(seed + 2), (batch, cfg.max_seq_len + 1), 0,
-                              cfg.vocab_size, jnp.int32)
-    return jax.tree.unflatten(tree, leaves), rows
+#: rows with the last position's target
+seeded = functools.partial(harness.seeded, targets=True)
+#: differences as a share of the largest magnitude alone (gradients and blocks far under 1)
+close = functools.partial(harness.close, floor=0.0)
+pytestmark = pytest.mark.usefixtures("highest_precision")
+
+
+def ref_logits(params, rows, cfg):
+    return harness.reference(ARCH, ref_cfg, cfg).logits(params, rows)
 
 
 def reference_objective(params, rows, cfg):
-    rc = ref_cfg(cfg)
-    with jax.default_matmul_precision("highest"):
-        w = ARCH.published_weights(jax.tree.map(lambda a: a.astype(jnp.float32), params), rc)
-        logp = jax.nn.log_softmax(ARCH.logits(w, rows[:, :-1], rc), axis=-1)
-        ce = -jnp.mean(jnp.take_along_axis(logp, rows[:, 1:, None], axis=-1))
-        return ce, ARCH.aux_loss(w, rows[:, :-1], rc)
-
-
-def close(got, want, tol):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    scale = max(np.abs(want).max(), 1e-30)
-    err = np.abs(got - want).max() / scale
-    assert err <= tol, f"largest difference {err:.3e} of the largest magnitude, bound {tol:.0e}"
-
-
-@pytest.fixture(autouse=True)
-def highest_precision():
-    with jax.default_matmul_precision("highest"):
-        yield
+    return harness.reference(ARCH, ref_cfg, cfg).objective(params, rows)
 
 
 # -- the preset, the reference file ---------------------------------------------
@@ -180,10 +163,9 @@ def test_initialisation_is_the_published_codes():
 def test_logits_loss_and_aux_loss_match_the_reference_in_float32():
     cfg = small_cfg()
     params, rows = seeded(cfg)
-    logits, stats = modeling.forward_with_stats(params, rows[:, :-1], cfg)
-    rc = ref_cfg(cfg)
-    close(logits, ARCH.logits(ARCH.published_weights(params, rc), rows[:, :-1], rc), F32_TOL)
-    s, n, aux = modeling.moe_loss_sum(params, rows, cfg)
+    logits, stats = harness.forward_with_stats(params, rows[:, :-1], cfg)
+    close(logits, ref_logits(params, rows[:, :-1], cfg), F32_TOL)
+    s, n, aux = harness.moe_loss_sum(params, rows, cfg)
     ce, aux_ref = reference_objective(params, rows, cfg)
     close(s / n, ce, F32_TOL)
     close(aux["moe_aux_loss"], aux_ref, F32_TOL)  # over all 16 experts, held or not
@@ -201,38 +183,21 @@ def test_logits_loss_and_aux_loss_match_the_reference_in_float32():
 def test_every_gradient_matches_the_reference_in_float32():
     cfg = small_cfg()
     params, rows = seeded(cfg)
-
-    def program(p):
-        s, n, aux = modeling.moe_loss_sum(p, rows, cfg)
-        return s / n + cfg.moe_aux_coef * aux["moe_aux_loss"]
-
-    def plain(p):
-        ce, aux = reference_objective(p, rows, cfg)
-        return ce + cfg.moe_aux_coef * aux
-
-    got, want = jax.grad(program)(params), jax.grad(plain)(params)
-    flat_got, flat_want = jax.tree.leaves_with_path(got), jax.tree.leaves(want)
-    assert len(flat_got) == len(flat_want) > 50
-    for (path, g), w in zip(flat_got, flat_want):
-        name = jax.tree_util.keystr(path)
-        assert float(jnp.abs(w).max()) > 0, f"{name}: reference gradient is zero"
-        try:
-            close(g, w, 5 * F32_TOL)
-        except AssertionError as e:
-            raise AssertionError(f"{name}: {e}") from None
+    got = harness.every_gradient_matches(params, rows, cfg, harness.reference(ARCH, ref_cfg, cfg),
+                                         5 * F32_TOL)
+    assert len(jax.tree.leaves(got)) > 50
 
 
 @pytest.mark.parametrize("seed", [0, 3])
 def test_bf16_compute_stays_near_the_reference(seed):
     cfg = small_cfg(dtype=jnp.bfloat16)
     params, rows = seeded(cfg, seed=seed)
-    logits = modeling.forward(params, rows[:, :-1], cfg)
-    rc = ref_cfg(cfg)
-    want = np.asarray(ARCH.logits(ARCH.published_weights(params, rc), rows[:, :-1], rc))
+    logits = forward(params, rows[:, :-1], cfg)
+    want = np.asarray(ref_logits(params, rows[:, :-1], cfg))
     assert logits.dtype == jnp.bfloat16
     row_err = np.abs(np.asarray(logits, np.float32) - want).max(axis=-1) / np.abs(want).max()
     assert np.median(row_err) <= BF16_TOL / 2, np.median(row_err)
-    s, n, _ = modeling.moe_loss_sum(params, rows, cfg)
+    s, n, _ = harness.moe_loss_sum(params, rows, cfg)
     assert float(s / n) == pytest.approx(float(reference_objective(params, rows, cfg)[0]), rel=1e-2)
 
 
@@ -245,26 +210,29 @@ def test_bf16_blocks_stay_near_float32_on_the_same_input():
     tables = modeling.rope_tables(cfg, 100)
 
     def mixer(c, p):
-        return jnp.sum(gdn.block(x.astype(c.dtype), p, c).astype(jnp.float32) * weight)
+        return gdn.block(x.astype(c.dtype), p, c)
 
     def attn(c, p):
-        return jnp.sum(modeling.attn_block(x.astype(c.dtype), p, c, tables).astype(jnp.float32)
-                       * weight)
+        return modeling.attn_block(x.astype(c.dtype), p, c, tables)
+
+    def block_and_gradients(fn, c, p):  # one compiled program a block and a compute type
+        def weighted(p_):
+            y = fn(c, p_).astype(jnp.float32)
+            return jnp.sum(y * weight), y
+
+        (_, y), grads = jax.jit(jax.value_and_grad(weighted, has_aux=True))(p)
+        return y, grads
 
     for fn, p, tol in ((mixer, params["layers"][0]["gdn"], BF16_TOL),
                        (attn, params["layers"][3]["attn"], BF16_TOL / 4)):
-        got, want = jax.grad(lambda p_: fn(cfg, p_))(p), jax.grad(lambda p_: fn(f32, p_))(p)
+        (y, got), (y32, want) = block_and_gradients(fn, cfg, p), block_and_gradients(fn, f32, p)
         for name in want:
             if want[name].ndim == 2:  # the matrices: a vector's gradient is a sum of roundings
                 try:
                     close(got[name], want[name], tol)
                 except AssertionError as e:
                     raise AssertionError(f"{fn.__name__} d{name}: {e}") from None
-    close(gdn.block(x.astype(jnp.bfloat16), params["layers"][0]["gdn"], cfg).astype(jnp.float32),
-          gdn.block(x, params["layers"][0]["gdn"], f32), BF16_TOL)
-    close(modeling.attn_block(x.astype(jnp.bfloat16), params["layers"][3]["attn"], cfg,
-                              tables).astype(jnp.float32),
-          modeling.attn_block(x, params["layers"][3]["attn"], f32, tables), BF16_TOL / 4)
+        close(y, y32, tol)
 
 
 def test_the_likely_mistakes_show():
@@ -273,11 +241,10 @@ def test_the_likely_mistakes_show():
     whole head, the gate left off, the shared expert left out."""
     cfg = small_cfg()
     params, rows = seeded(cfg)
-    rc = ref_cfg(cfg)
-    want = np.asarray(ARCH.logits(ARCH.published_weights(params, rc), rows[:, :-1], rc))
+    want = np.asarray(ref_logits(params, rows[:, :-1], cfg))
     for wrong in (dict(moe_norm_topk=False), dict(norm_zero_centered=False),
                   dict(rotary_fraction=1.0), dict(moe_share=(0, 4))):
-        got = np.asarray(modeling.forward(params, rows[:, :-1], cfg.replace(**wrong)))
+        got = np.asarray(forward(params, rows[:, :-1], cfg.replace(**wrong)))
         assert np.abs(got - want).max() / np.abs(want).max() > 1e-3, wrong
 
 
@@ -662,38 +629,27 @@ def test_runtime_steps_and_hands_up_the_held_pairs(chunks):
 # -- (d) each refusal by name -------------------------------------------------------
 
 
-def _plan(cfg, layers=None, **kw):
-    layers = layers or [LayerStrategy() for _ in range(cfg.num_layers)]
+def by_layer(layers, **kw):
     return HybridParallelConfig(layer_strategies=layers, mixed_precision="fp32", **kw)
 
 
+SHORT = dict(max_seq_len=64)
 REFUSALS = [
-    ("tp", lambda c: _plan(c, [LayerStrategy(tp=2)] + [LayerStrategy()] * 3),
+    ("tp", SHORT, lambda c: by_layer([LayerStrategy(tp=2)] + [LayerStrategy()] * 3),
      "tensor parallelism .* Gated DeltaNet layers"),
-    ("cp", lambda c: _plan(c, [LayerStrategy(cp=2) for _ in range(4)]),
-     "context parallelism .* Gated\\s+DeltaNet"),
-    ("pp", lambda c: _plan(c, pp=2), "pipeline parallelism .* interleaved layer kinds"),
-    ("ep", lambda c: _plan(c, [LayerStrategy(ep=2) for _ in range(4)]),
-     "expert parallelism .* held share"),
+    ("cp", SHORT, dict(cp=2), "context parallelism .* Gated\\s+DeltaNet"),
+    ("pp", SHORT, dict(pp=2), "pipeline parallelism .* interleaved layer kinds"),
+    ("ep", SHORT, dict(ep=2), "expert parallelism .* held share"),
 ]
-
-
-@pytest.mark.parametrize("name,plan,message", REFUSALS, ids=[r[0] for r in REFUSALS])
-def test_build_runtime_refuses_by_name(name, plan, message):
-    cfg = small_cfg(max_seq_len=64)
-    world = {"tp": 2, "cp": 2, "pp": 2, "ep": 2}[name]
-    mesh, axes = build_mesh(pp=2 if name == "pp" else 1, devices=jax.devices()[:world])
-    with pytest.raises(ValueError, match=message):
-        build_runtime(cfg, plan(cfg), mesh=mesh, axes=axes, adam=AdamConfig(),
-                      global_batch_size=4, seq_len=64)
+test_build_runtime_refuses_by_name = harness.refuses(REFUSALS, small_cfg, seq_len=64)
 
 
 def test_packing_and_generation_are_refused_by_name():
     cfg = small_cfg(max_seq_len=64, pack_sequences=True, attn_impl="xla")
     mesh, axes = build_mesh(pp=1, devices=jax.devices()[:1])
     with pytest.raises(ValueError, match="pack_sequences .* Gated DeltaNet"):
-        build_runtime(cfg, _plan(cfg), mesh=mesh, axes=axes, adam=AdamConfig(),
-                      global_batch_size=4, seq_len=64)
+        build_runtime(cfg, harness.plan(cfg, mixed_precision="fp32"), mesh=mesh, axes=axes,
+                      adam=AdamConfig(), global_batch_size=4, seq_len=64)
     from galvatron_tpu.models.generation import init_kv_cache
 
     with pytest.raises(ValueError, match="generation .* Gated DeltaNet"):
@@ -705,7 +661,7 @@ def test_plan_check_names_the_same_refusals():
 
     cfg = small_cfg(max_seq_len=64)
     layers = [LayerStrategy(tp=2, cp=2)] + [LayerStrategy(ep=2)] * 3
-    found = plan_check.check_plan(_plan(cfg, layers, pp=2), cfg, 16)
+    found = plan_check.check_plan(by_layer(layers, pp=2), cfg, 16)
     text = "\n".join(f"{d.code} {d.message}" for d in found)
     assert "GTA019 layer 0: tp=2 on a Gated DeltaNet layer" in text
     assert "GTA019 layer 0: cp=2 on a Gated DeltaNet layer" in text

@@ -27,27 +27,7 @@ from galvatron_tpu.serving import (
 )
 from galvatron_tpu.serving.engine import _decode_step, _prefill_chunk
 
-CFG = ModelConfig(
-    vocab_size=97,
-    hidden_size=64,
-    num_layers=2,
-    num_heads=4,
-    num_kv_heads=2,
-    ffn_dim=128,
-    max_seq_len=64,
-    dtype=jnp.float32,
-)
-
-
-@pytest.fixture(scope="module")
-def params():
-    return modeling.init_model_params(jax.random.key(0), CFG)
-
-
-def _prompts(n, lo=3, hi=14, seed=0):
-    rng = np.random.RandomState(seed)
-    return [rng.randint(1, CFG.vocab_size, (rng.randint(lo, hi),)).tolist()
-            for _ in range(n)]
+from tests._serving_common import CFG, params, prompts as _prompts  # noqa: F401  (`params`: a fixture)
 
 
 # ---------------------------------------------------------------------------
@@ -250,10 +230,10 @@ def test_slotwise_forward_matches_scalar_offset(params):
     (the slot-wise entry point degrades to the lockstep one)."""
     cache = generation.init_kv_cache(CFG, 2, 32)
     toks = jnp.asarray(np.random.RandomState(8).randint(1, CFG.vocab_size, (2, 5)), jnp.int32)
-    l_ref, c_ref = generation.forward_with_cache(params, toks, CFG, cache, 0)
-    l_slot, c_slot = generation.forward_with_cache(
-        params, toks, CFG, cache, jnp.zeros((2,), jnp.int32)
-    )
+    from tests._stack_harness import step_forward
+
+    l_ref, c_ref = step_forward(params, CFG, cache, toks, 0)
+    l_slot, c_slot = step_forward(params, CFG, cache, toks, jnp.zeros((2,), jnp.int32))
     np.testing.assert_allclose(np.asarray(l_ref), np.asarray(l_slot), rtol=1e-5)
     np.testing.assert_allclose(np.asarray(c_ref.k), np.asarray(c_slot.k), rtol=1e-5)
 

@@ -39,16 +39,7 @@ from galvatron_tpu.serving import (
 from galvatron_tpu.serving import resilience as rz
 from galvatron_tpu.serving.engine import _decode_step, _prefill_chunk
 
-CFG = ModelConfig(
-    vocab_size=97,
-    hidden_size=64,
-    num_layers=2,
-    num_heads=4,
-    num_kv_heads=2,
-    ffn_dim=128,
-    max_seq_len=64,
-    dtype=jnp.float32,
-)
+from tests._serving_common import CFG, params, prompts as _prompts  # noqa: F401  (`params`: a fixture)
 
 TINY = ModelConfig(
     vocab_size=pad_vocab_size(259),
@@ -61,22 +52,11 @@ TINY = ModelConfig(
 )
 
 
-@pytest.fixture(scope="module")
-def params():
-    return modeling.init_model_params(jax.random.key(0), CFG)
-
-
 @pytest.fixture(autouse=True)
 def _clean_faults():
     faults.reset()
     yield
     faults.reset()
-
-
-def _prompts(n, lo=3, hi=14, seed=0):
-    rng = np.random.RandomState(seed)
-    return [rng.randint(1, CFG.vocab_size, (rng.randint(lo, hi),)).tolist()
-            for _ in range(n)]
 
 
 # ---------------------------------------------------------------------------

@@ -6,6 +6,7 @@ computes; and tests that fail on the likely mistakes (a decay in bf16, a conv
 that sees the future, a scan that forgets its state at a chunk boundary, a
 multiplier left out)."""
 
+import functools
 import json
 import os
 
@@ -22,6 +23,8 @@ from galvatron_tpu.models.modeling import PRESETS
 from galvatron_tpu.ops import ssd
 from galvatron_tpu.parallel.hybrid import build_runtime
 from galvatron_tpu.parallel.mesh import build_mesh
+from tests import _stack_harness as harness
+from tests._stack_harness import forward, highest_precision, on_a_chip  # noqa: F401  (a fixture)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ARCH = reference.load(ROOT, "granitemoehybrid")
@@ -55,7 +58,7 @@ def small_cfg(**kw):
     return PRESETS["granite-4.0-h-micro"].replace(**base)
 
 
-def ref_cfg(cfg):
+def ref_cfg(cfg, share=None):
     return {"hidden_size": cfg.hidden_size, "num_attention_heads": cfg.num_heads,
             "num_key_value_heads": cfg.kv_heads, "attention_multiplier": cfg.attention_multiplier,
             "embedding_multiplier": cfg.embedding_multiplier,
@@ -68,37 +71,20 @@ def ref_cfg(cfg):
             "layer_types": ["mamba" if k == "ssm" else "attention" for k in cfg.kinds]}
 
 
-def seeded(cfg, seed=0, batch=2):
-    """Parameters with every vector (norm scales, conv bias, A_log, D, dt_bias)
-    away from its initial value, so that one the program ignores shows."""
-    params = modeling.init_model_params(jax.random.key(seed), cfg)
-    leaves, tree = jax.tree.flatten(params)
-    keys = jax.random.split(jax.random.key(seed + 1), len(leaves))
-    leaves = [a + 0.3 * jax.random.normal(k, a.shape, a.dtype) if a.ndim == 1 else a
-              for a, k in zip(leaves, keys)]
-    rows = jax.random.randint(jax.random.key(seed + 2), (batch, cfg.max_seq_len + 1), 0,
-                              cfg.vocab_size, jnp.int32)
-    return jax.tree.unflatten(tree, leaves), rows
+#: every vector (norm scales, conv bias, A_log, D, dt_bias) 0.3 off its initial value, and
+#: rows with the last position's target
+seeded = functools.partial(harness.seeded, spread=0.3, targets=True)
+#: differences as a share of the largest magnitude alone (gradients and blocks far under 1)
+close = functools.partial(harness.close, floor=0.0)
+pytestmark = pytest.mark.usefixtures("highest_precision")
+
+
+def ref_logits(params, rows, cfg):
+    return harness.reference(ARCH, ref_cfg, cfg).logits(params, rows)
 
 
 def reference_loss(params, rows, cfg):
-    rc = ref_cfg(cfg)
-    logp = jax.nn.log_softmax(
-        ARCH.logits(ARCH.published_weights(params, rc), rows[:, :-1], rc), axis=-1)
-    return -jnp.mean(jnp.take_along_axis(logp, rows[:, 1:, None], axis=-1))
-
-
-def close(got, want, tol):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    scale = max(np.abs(want).max(), 1e-30)
-    err = np.abs(got - want).max() / scale
-    assert err <= tol, f"largest difference {err:.3e} of the largest magnitude, bound {tol:.0e}"
-
-
-@pytest.fixture(autouse=True)
-def highest_precision():
-    with jax.default_matmul_precision("highest"):
-        yield
+    return harness.reference(ARCH, ref_cfg, cfg).objective(params, rows)[0]
 
 
 # -- the model against the reference -----------------------------------------
@@ -150,23 +136,16 @@ def test_logits_and_loss_match_the_reference_in_float32():
     cfg = small_cfg()
     assert cfg.kinds == KINDS
     params, rows = seeded(cfg)
-    logits = modeling.forward(params, rows[:, :-1], cfg)
-    rc = ref_cfg(cfg)
-    close(logits, ARCH.logits(ARCH.published_weights(params, rc), rows[:, :-1], rc), F32_TOL)
-    assert float(modeling.lm_loss(params, rows, cfg)) == pytest.approx(
+    close(forward(params, rows[:, :-1], cfg), ref_logits(params, rows[:, :-1], cfg), F32_TOL)
+    assert float(harness.lm_loss(params, rows, cfg)) == pytest.approx(
         float(reference_loss(params, rows, cfg)), rel=F32_TOL)
 
 
 def test_every_gradient_matches_the_reference_in_float32():
     cfg = small_cfg()
     params, rows = seeded(cfg, seed=5)
-    got = jax.grad(lambda p: modeling.lm_loss(p, rows, cfg))(params)
-    want = jax.grad(lambda p: reference_loss(p, rows, cfg))(params)
-    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)):
-        try:
-            close(g, w, GRAD_TOL)
-        except AssertionError as e:
-            raise AssertionError(f"{jax.tree_util.keystr(path)}: {e}") from None
+    got = harness.every_gradient_matches(params, rows, cfg, harness.reference(ARCH, ref_cfg, cfg),
+                                         GRAD_TOL)
     # every parameter of both kinds of layer is reached
     assert all(float(jnp.abs(g).max()) > 0 for g in jax.tree.leaves(got))
 
@@ -174,12 +153,7 @@ def test_every_gradient_matches_the_reference_in_float32():
 def test_bf16_compute_stays_within_what_bf16_warrants():
     cfg = small_cfg(dtype=jnp.bfloat16)
     params, rows = seeded(cfg)
-    logits = modeling.forward(params, rows[:, :-1], cfg).astype(jnp.float32)
-    rc = ref_cfg(cfg)
-    want = ARCH.logits(ARCH.published_weights(params, rc), rows[:, :-1], rc)
-    close(logits, want, BF16_TOL)
-    err = np.abs(np.asarray(logits) - np.asarray(want)).max() / np.abs(np.asarray(want)).max()
-    assert err > F32_TOL, "a bf16 run inside the float32 tolerance: the tolerance has no power"
+    harness.bf16_stays_within(cfg, params, rows[:, :-1], ref_logits, BF16_TOL, F32_TOL)
 
 
 @pytest.mark.parametrize("field,value", [
@@ -191,8 +165,8 @@ def test_each_departure_from_a_plain_decoder_shows(field, value):
     logits far outside the tolerance: the parity above holds the program to each."""
     cfg = small_cfg()
     params, rows = seeded(cfg)
-    want = modeling.forward(params, rows[:, :-1], cfg)
-    got = modeling.forward(params, rows[:, :-1], cfg.replace(**{field: value}))
+    want = forward(params, rows[:, :-1], cfg)
+    got = forward(params, rows[:, :-1], cfg.replace(**{field: value}))
     err = float(jnp.abs(got - want).max() / jnp.abs(want).max())
     assert err > 20 * F32_TOL
 
@@ -315,18 +289,8 @@ def fused_case(name, seed=0):
     return scan_inputs(seed=seed, slow=0.05, **sizes), chunk
 
 
-@pytest.fixture
-def on_a_chip(monkeypatch):
-    """`ssd.scan_path` as a chip would answer (it asks `flash_attention`'s
-    switch, the one every kernel of the repo goes by). For tests that ask which
-    body a shape takes; the kernels themselves run here, interpreted, through
-    `ssd.ssd_scan_fused`, and through the dispatch in tests/test_topology_aot.py."""
-    from galvatron_tpu.ops import flash_attention
-
-    monkeypatch.setattr(flash_attention, "_use_interpret", lambda: False)
-
-
-def test_the_fused_cases_lie_inside_the_envelope(on_a_chip):
+def test_the_fused_cases_lie_inside_the_envelope(monkeypatch):
+    on_a_chip(monkeypatch)
     for sizes, chunk in FUSED_CASES.values():
         assert ssd.scan_path(sizes["h"], sizes["p"], sizes["g"], sizes["n"], chunk,
                              jnp.float32) == "fused", sizes
@@ -384,10 +348,11 @@ def test_fused_scan_in_bf16_stays_within_what_bf16_warrants():
     close(ssd.ssd_scan_fused(*lo, chunk).astype(jnp.float32), ssd_sequential(*ref), BF16_TOL)
 
 
-def test_outside_the_envelope_the_plain_scan_runs_bit_for_bit(on_a_chip):
+def test_outside_the_envelope_the_plain_scan_runs_bit_for_bit(monkeypatch):
     """Heads of 8 and states of 16 (the small configurations of this file), a
     chunk of 72, float16: `scan_path` says plain even where a chip is there, and
     `ssd_scan` is then `ssd_scan_plain` to the bit."""
+    on_a_chip(monkeypatch)
     assert ssd.scan_path(4, 8, 2, 16, 16, jnp.float32) == "plain"
     assert ssd.scan_path(64, 64, 1, 128, 72, jnp.bfloat16) == "plain"  # chunk
     assert ssd.scan_path(64, 64, 1, 64, 256, jnp.bfloat16) == "plain"  # state
@@ -407,10 +372,8 @@ def test_scan_path_counts_the_layers_of_a_configuration(monkeypatch):
     for a stack without such layers."""
     granite = PRESETS["granite-4.0-h-micro"].replace(num_layers=10, max_seq_len=8192,
                                                      dtype=jnp.bfloat16)
-    from galvatron_tpu.ops import flash_attention
-
     assert ssm.path_counts(granite)["scan"] == {"fused": 0, "plain": 9}  # no chip here
-    monkeypatch.setattr(flash_attention, "_use_interpret", lambda: False)
+    on_a_chip(monkeypatch)
     assert ssm.path_counts(granite)["scan"] == {"fused": 9, "plain": 0}
     assert ssm.path_counts(small_cfg())["scan"] == {"fused": 0, "plain": 6}
     assert ssm.path_counts(PRESETS["llama-7b"])["scan"] == {"fused": 0, "plain": 0}
@@ -432,12 +395,7 @@ def test_the_mixer_through_the_kernels_equals_the_mixer_through_the_plain_scan(m
     monkeypatch.setattr(ssm, "ssd_scan", ssd.ssd_scan_fused)
     fused = run(x, p)
     assert float(fused[0]) == pytest.approx(float(plain[0]), rel=F32_TOL)
-    for (path, g), v in zip(jax.tree_util.tree_leaves_with_path(fused[1]),
-                            jax.tree.leaves(plain[1])):
-        try:
-            close(g, v, GRAD_TOL)
-        except AssertionError as e:
-            raise AssertionError(f"{jax.tree_util.keystr(path)}: {e}") from None
+    harness.close_by_leaf(fused[1], plain[1], GRAD_TOL, floor=0.0)
     assert float(jnp.abs(fused[1][1]["D"]).max()) > 0
 
 
@@ -567,11 +525,12 @@ def test_neither_conv_leaks_from_the_future_across_a_block_boundary(conv):
     assert rows.tolist() == list(range(1021, 1026))
 
 
-def test_outside_the_envelope_the_plain_conv_runs_bit_for_bit(on_a_chip):
+def test_outside_the_envelope_the_plain_conv_runs_bit_for_bit(monkeypatch):
     """The small configurations of this file (64 + 16 + 16 channels), five taps,
     float16, a window that is no whole lane tile: `conv_path` says plain even
     where a chip is there, and `ssm.conv_split` is then `causal_conv1d` +
     ``jax.nn.silu`` on the sliced channels to the bit."""
+    on_a_chip(monkeypatch)
     granite = ssm.conv_windows(PRESETS["granite-4.0-h-micro"])
     assert granite == (4096, 128, 128)
     assert ssd.conv_path(granite, 4, jnp.bfloat16) == "fused"
@@ -598,10 +557,8 @@ def test_conv_path_counts_the_layers_of_a_configuration(monkeypatch):
     configurations, 0 / 0 for a stack without state-space layers."""
     granite = PRESETS["granite-4.0-h-micro"].replace(num_layers=10, max_seq_len=8192,
                                                      dtype=jnp.bfloat16)
-    from galvatron_tpu.ops import flash_attention
-
     assert ssm.path_counts(granite)["conv"] == {"fused": 0, "plain": 9}  # no chip here
-    monkeypatch.setattr(flash_attention, "_use_interpret", lambda: False)
+    on_a_chip(monkeypatch)
     assert ssm.path_counts(granite)["conv"] == {"fused": 9, "plain": 0}
     assert ssm.path_counts(small_cfg())["conv"] == {"fused": 0, "plain": 6}
     assert ssm.path_counts(PRESETS["llama-7b"])["conv"] == {"fused": 0, "plain": 0}
@@ -628,12 +585,7 @@ def test_the_mixer_through_the_fused_conv_equals_the_mixer_through_the_plain_one
     fused = run(x, p)
     assert calls == [256, 512, 640]
     assert float(fused[0]) == pytest.approx(float(plain[0]), rel=F32_TOL)
-    for (path, g), v in zip(jax.tree_util.tree_leaves_with_path(fused[1]),
-                            jax.tree.leaves(plain[1])):
-        try:
-            close(g, v, GRAD_TOL)
-        except AssertionError as e:
-            raise AssertionError(f"{jax.tree_util.keystr(path)}: {e}") from None
+    harness.close_by_leaf(fused[1], plain[1], GRAD_TOL, floor=0.0)
     assert float(jnp.abs(fused[1][1]["conv_b"]).max()) > 0
 
 
@@ -682,38 +634,32 @@ def test_tp_on_the_attention_layer_alone_trains_like_one_device():
     assert losses[1] == pytest.approx(losses[0], rel=1e-4)
 
 
-def _plan(cfg, **kw):
-    pp = kw.pop("pp", 1)
-    return HybridParallelConfig(
-        pp=pp, layer_strategies=[LayerStrategy(**kw)] * cfg.num_layers, mixed_precision="fp32",
-        chunks=2 if pp > 1 else 1)
-
-
-@pytest.mark.parametrize("kw,named", [
-    ({"tp": 2}, r"tp>1.*state-space layers \(layers \[0, 1, 2, 3, 4, 6, 7\]"),
-    ({"cp": 2}, r"cp>1.*state-space"),
-    ({"pp": 2}, r"pp>1.*interleaved layer kinds"),
-])
-def test_build_runtime_refuses_by_name_what_a_hybrid_stack_cannot_run(kw, named):
-    cfg = small_cfg(num_layers=8)  # 8 layers so that pp 2 divides them
-    with pytest.raises(ValueError, match=named):
-        build_runtime(cfg, _plan(cfg, **dict(kw)), global_batch_size=8, seq_len=cfg.max_seq_len)
+EIGHT = dict(num_layers=8)  # 8 layers so that pp 2 divides them
+REFUSALS = [
+    ("tp", EIGHT, dict(tp=2, vocab_tp=1),
+     r"tp>1.*state-space layers \(layers \[0, 1, 2, 3, 4, 6, 7\]"),
+    ("cp", EIGHT, dict(cp=2), r"cp>1.*state-space"),
+    ("pp", EIGHT, dict(pp=2, chunks=2), r"pp>1.*interleaved layer kinds"),
+]
+test_build_runtime_refuses_by_name = harness.refuses(REFUSALS, small_cfg, seq_len=72, batch=8,
+                                                     devices=8)
 
 
 @pytest.mark.parametrize("kw,code,named", [
-    ({"tp": 2}, "GTA019", "tp=2 on a state-space layer"),
+    ({"tp": 2, "vocab_tp": 1}, "GTA019", "tp=2 on a state-space layer"),
     ({"cp": 2}, "GTA019", "cp=2 on a state-space layer"),
-    ({"pp": 2}, "GTA020", "interleaved layer kinds"),
+    ({"pp": 2, "chunks": 2}, "GTA020", "interleaved layer kinds"),
 ])
 def test_check_plan_names_the_same_refusals(kw, code, named):
     cfg = small_cfg(num_layers=8)
-    diags = check_plan(_plan(cfg, **dict(kw)), model_config=cfg, world_size=8, global_bsz=8)
+    plan = functools.partial(harness.plan, cfg, mixed_precision="fp32")
+    diags = check_plan(plan(**kw), model_config=cfg, world_size=8, global_bsz=8)
     hits = [d for d in diags if d.code == code]
     assert hits and all(named in d.message for d in hits)
     if code == "GTA019":  # one a state-space layer, none for the attention layer
         assert len(hits) == 7 and not any("layer 5:" in d.message for d in hits)
     # and a plan the runtime takes has neither
-    ok = check_plan(_plan(cfg, dp_type="zero3"), model_config=cfg, world_size=8, global_bsz=8)
+    ok = check_plan(plan(dp_type="zero3"), model_config=cfg, world_size=8, global_bsz=8)
     assert not [d for d in ok if d.code in ("GTA019", "GTA020")]
 
 

@@ -47,18 +47,14 @@ def one_chip(topo):
 
 @pytest.fixture
 def real_mosaic(monkeypatch):
-    """Lower the real kernels (interpret off in every module that binds the
-    switch) with the persistent cache off: a described-device executable is
-    written to the cache but cannot be read back without a chip, and the
-    next run would warn on every such entry."""
+    """Lower the real kernels (interpret off: the ONE switch every kernel module
+    and every choice of a kernel over its plain body asks) with the persistent
+    cache off: a described-device executable is written to the cache but cannot
+    be read back without a chip, and the next run would warn on every such entry."""
     from galvatron_tpu.aot.cache import persistent_cache_off
-    from galvatron_tpu.ops import flash_attention, grouped_matmul
-    from galvatron_tpu.parallel import ring
+    from tests._stack_harness import on_a_chip
 
-    # (ops/ssd.py asks flash_attention's switch, for its kernels and for its
-    # choice between them and the plain scan: no switch of its own)
-    for mod in (flash_attention, grouped_matmul, ring):
-        monkeypatch.setattr(mod, "_use_interpret", lambda: False)
+    on_a_chip(monkeypatch)
     with persistent_cache_off():
         yield
 
@@ -378,14 +374,13 @@ def test_qwen3_next_mixer_compiles_at_published_widths(one_chip, real_mosaic):
     planned in all."""
     from galvatron_tpu.models import gdn
     from galvatron_tpu.models.modeling import PRESETS
-    from galvatron_tpu.ops import flash_attention as fa
-    from galvatron_tpu.ops import gated_delta
+    from galvatron_tpu.ops import gated_delta, pallas_common
 
     cfg = PRESETS["qwen3-next-80b-a3b"].replace(mlp_recompute="off")
     assert gdn.gdn_dims(cfg) == (2048, 4096, 8192, 12288) and cfg.gdn_chunk == 64
     assert gdn.path_counts(cfg.replace(num_layers=4))["conv"] == {"fused": 3, "plain": 0}
     assert gdn.path_counts(cfg.replace(num_layers=4))["scan"] == {"fused": 3, "plain": 0}
-    assert 1.1 * gated_delta._fused_vmem_mb(2, 128, 128, 32, 2) <= fa._VMEM_EFF_MB
+    assert 1.1 * gated_delta._fused_vmem_mb(2, 128, 128, 32, 2) <= pallas_common.VMEM_LIMIT_MB
     shapes = jax.eval_shape(lambda k: gdn.init_params(k, cfg), jax.random.key(0))
     p = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), shapes)
     x = jax.ShapeDtypeStruct((4, 4096, 2048), jnp.bfloat16, sharding=one_chip)

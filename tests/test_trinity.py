@@ -23,7 +23,9 @@ from galvatron_tpu.core.strategy import HybridParallelConfig
 from galvatron_tpu.models import generation, modeling, moe
 from galvatron_tpu.models.modeling import PRESETS
 from galvatron_tpu.parallel.hybrid import build_runtime
-from galvatron_tpu.parallel.mesh import build_mesh
+from tests import _stack_harness as harness
+from tests._stack_harness import (  # noqa: F401  (`retraced`: a fixture)
+    close, forward, retraced, seeded, through_the_cache, worst)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ARCH = reference.load(ROOT, "afmoe")
@@ -62,19 +64,6 @@ def ref_cfg(cfg, share=None):
             "program_flags": ["--seq_length", str(cfg.max_seq_len)]}
 
 
-def seeded(cfg, seed=0, batch=2, length=None):
-    """Parameters with every norm gain and the selection bias moved off their start,
-    and rows of tokens."""
-    params = modeling.init_model_params(jax.random.key(seed), cfg)
-    leaves, tree = jax.tree.flatten(params)
-    keys = jax.random.split(jax.random.key(seed + 1), len(leaves))
-    leaves = [a + 0.2 * jax.random.normal(k, a.shape, a.dtype) if a.ndim == 1 else a
-              for a, k in zip(leaves, keys)]
-    rows = jax.random.randint(jax.random.key(seed + 2), (batch, length or cfg.max_seq_len), 0,
-                              cfg.vocab_size, jnp.int32)
-    return jax.tree.unflatten(tree, leaves), rows
-
-
 def held_by(params, cfg, share):
     """``params`` as rank ``share[0]`` of ``share[1]`` holds them: its experts' stacks."""
     rank, of = share
@@ -86,18 +75,7 @@ def held_by(params, cfg, share):
 
 
 def ref_logits(params, rows, cfg, share=None):
-    with jax.default_matmul_precision("highest"):
-        rc = ref_cfg(cfg, share)
-        return ARCH.logits(ARCH.published_weights(params, rc), rows, rc)
-
-
-def worst(a, b):
-    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-    return np.max(np.abs(a - b)) / max(1.0, np.max(np.abs(b)))
-
-
-def close(a, b, tol=F32_TOL):
-    return worst(a, b) <= tol
+    return harness.reference(ARCH, ref_cfg, cfg, share).logits(params, jnp.asarray(rows))
 
 
 # -- the configuration ------------------------------------------------------------------
@@ -192,15 +170,11 @@ def test_no_cache_forward_matches_the_reference(share):
     cfg = small_cfg(moe_share=share)
     params, rows = seeded(small_cfg(), length=40)
     params = held_by(params, cfg, share)
-    assert close(modeling.forward(params, rows, cfg), ref_logits(params, rows, cfg))
+    close(forward(params, rows, cfg), ref_logits(params, rows, cfg), F32_TOL)
 
 
 def test_bf16_in_place_of_float32_fails_the_tolerance():
-    cfg = small_cfg()
-    params, rows = seeded(cfg, length=40)
-    low = jax.tree.map(lambda a: a.astype(jnp.bfloat16) if a.ndim > 1 else a, params)
-    got = modeling.forward(low, rows, cfg.replace(dtype=jnp.bfloat16))
-    assert not close(got.astype(jnp.float32), ref_logits(params, rows, cfg))
+    harness.bf16_fails_the_tolerance(small_cfg(), ref_logits, F32_TOL)
 
 
 def test_the_ranks_shares_add_up_to_the_uncut_layer():
@@ -217,50 +191,19 @@ def test_the_ranks_shares_add_up_to_the_uncut_layer():
         cut = whole.replace(moe_share=(rank, 2))
         mine = held_by(params, whole, (rank, 2))["layers"][2]["mlp"]
         total = total + moe.moe_topk_block(y, mine, cut)[0] - shared
-    assert close(total, want) and not close(total + shared, want)
+    close(total, want, F32_TOL)
+    assert worst(total + shared, want) > F32_TOL
     rc = ref_cfg(whole)
     fw = ARCH.published_weights(params, rc)["layers"][2]["mlp"]
     with jax.default_matmul_precision("highest"):
-        assert close(want[:1], ARCH.moe(y[:1], fw, rc))
+        close(want[:1], ARCH.moe(y[:1], fw, rc), F32_TOL)
         part = dict(fw, experts={k: v[4:] for k, v in fw["experts"].items()})
         mine = held_by(params, whole, (1, 2))["layers"][2]["mlp"]
-        assert close(moe.moe_topk_block(y, mine, whole.replace(moe_share=(1, 2)))[0][:1],
-                     ARCH.moe(y[:1], part, ref_cfg(whole, (1, 2))))
+        close(moe.moe_topk_block(y, mine, whole.replace(moe_share=(1, 2)))[0][:1],
+              ARCH.moe(y[:1], part, ref_cfg(whole, (1, 2))), F32_TOL)
 
 
 # -- the ring and the whole slots ---------------------------------------------------------
-
-
-def _through_the_cache(params, cfg, prompts, total, slots=3, chunk=CHUNK):
-    """Prefill ``prompts`` ({slot: {"prompt", "row"}}) in chunks, then decode every slot
-    to ``total`` positions in shared steps (rows at their own depths; a slot out of use
-    carries (0, 0)) -> {slot: logits of every position}."""
-    cache = generation.init_kv_cache(cfg, slots, SLOT, tokens=chunk)
-    out = {s: [] for s in prompts}
-    for slot, row in prompts.items():
-        for start in range(0, len(row["prompt"]), chunk):
-            n = min(chunk, len(row["prompt"]) - start)
-            buf = np.zeros((1, chunk), np.int32)
-            buf[0, :n] = row["prompt"][start:start + n]
-            lg, cache = _forward(params, cfg, cache, jnp.asarray(buf), jnp.int32(start),
-                                 jnp.int32(slot))
-            out[slot].append(np.asarray(lg[0, :n]))
-    at = {s: len(r["prompt"]) for s, r in prompts.items()}
-    while any(at[s] < total[s] for s in prompts):
-        toks, offs = np.zeros((slots, 1), np.int32), np.zeros((slots,), np.int32)
-        live = [s for s in prompts if at[s] < total[s]]
-        for s in live:
-            toks[s, 0], offs[s] = prompts[s]["row"][at[s]], at[s]
-        lg, cache = _forward(params, cfg, cache, jnp.asarray(toks), jnp.asarray(offs))
-        for s in live:
-            out[s].append(np.asarray(lg[s, :1]))
-            at[s] += 1
-    return {s: np.concatenate(v) for s, v in out.items()}
-
-
-@partial(jax.jit, static_argnames=("cfg",))
-def _forward(params, cfg, cache, tokens, offsets, slot=None):
-    return generation.forward_with_cache(params, tokens, cfg, cache, offsets, slot=slot)
 
 
 def _served(params, cfg, rows):
@@ -268,9 +211,8 @@ def _served(params, cfg, rows):
     the window of 16 and past it, the chunk at 20 begins the ring's second lap) decoded
     to 60, three laps of the ring of 20; row 1 through slot 0 (a prompt of 7) decoded to
     30: its ring laps DURING decode."""
-    prompts = {2: {"prompt": rows[0, :26].tolist(), "row": rows[0].tolist()},
-               0: {"prompt": rows[1, :7].tolist(), "row": rows[1].tolist()}}
-    return _through_the_cache(params, cfg, prompts, {2: 60, 0: 30})
+    prompts = {2: (rows[0].tolist(), 26), 0: (rows[1].tolist(), 7)}
+    return through_the_cache(params, cfg, prompts, {2: 60, 0: 30}, capacity=SLOT)[0]
 
 
 def test_chunked_prefill_then_decoding_through_both_stacks_matches_the_reference():
@@ -279,7 +221,8 @@ def test_chunked_prefill_then_decoding_through_both_stacks_matches_the_reference
     want = np.asarray(ref_logits(params, rows, cfg))
     assert generation.ring_positions(cfg, SLOT, CHUNK) == WINDOW + CHUNK and 60 >= 3 * 20
     got = _served(params, cfg, rows)
-    assert close(got[2], want[0]) and close(got[0], want[1, :30])
+    close(got[2], want[0], F32_TOL)
+    close(got[0], want[1, :30], F32_TOL)
 
 
 def _without(name):
@@ -315,7 +258,7 @@ FAULTS = {
 
 
 @pytest.mark.parametrize("fault", sorted(FAULTS))
-def test_a_planted_fault_fails_the_tolerance(monkeypatch, fault):
+def test_a_planted_fault_fails_the_tolerance(monkeypatch, retraced, fault):
     """Each part of the layer the reference states and a pre-norm GQA stack lacks, taken
     out of the CACHED forwards (and the training forward with them): the tolerance these
     tests compare by tells each from the sound program."""
@@ -323,10 +266,20 @@ def test_a_planted_fault_fails_the_tolerance(monkeypatch, fault):
     params, rows = seeded(cfg, batch=2, length=60)
     want = np.asarray(ref_logits(params, rows, cfg))
     cfg, params = FAULTS[fault](cfg, params, monkeypatch)
+    retraced()  # (the planted scores are bound when a forward is traced)
     got = _served(params, cfg, rows)
     assert worst(got[2], want[0]) > 4 * F32_TOL and worst(got[0], want[1, :30]) > 4 * F32_TOL
-    assert not close(modeling.forward(params, rows, cfg), want)
-    _forward.clear_cache()  # (traced with the planted scores)
+    assert worst(forward(params, rows, cfg), want) > F32_TOL
+
+
+def _lockstep(params, cfg, rows):
+    """Both rows of a plain K/V stack's position-major cache at once: 8 positions in one
+    forward at offset 0, then a token a step -> the logits of all 12 positions."""
+    cache = generation.init_kv_cache(cfg, 2, 32)
+    pre, cache = harness.step_forward(params, cfg, cache, rows[:, :8], jnp.int32(0))
+    rest, _ = harness.decode(params, cfg, cache, {b: (rows[b].tolist(), 8, 12) for b in (0, 1)},
+                             slots=2)
+    return np.concatenate([np.asarray(pre), np.stack([rest[0], rest[1]])], axis=1)
 
 
 def test_a_served_model_with_an_embedding_multiplier_scales_its_embedding():
@@ -338,17 +291,9 @@ def test_a_served_model_with_an_embedding_multiplier_scales_its_embedding():
         dtype=jnp.float32, embedding_multiplier=12.0)
     params = modeling.init_model_params(jax.random.key(0), cfg)
     rows = jax.random.randint(jax.random.key(1), (2, 12), 0, 64, jnp.int32)
-    want = modeling.forward(params, rows, cfg)
-    cache = generation.init_kv_cache(cfg, 2, 32)
-    pre, cache = generation.forward_with_cache(params, rows[:, :8], cfg, cache, jnp.int32(0))
-    got = [pre]
-    for t in range(8, 12):
-        lg, cache = generation.forward_with_cache(params, rows[:, t:t + 1], cfg, cache,
-                                                  jnp.full((2,), t, jnp.int32))
-        got.append(lg)
-    assert close(jnp.concatenate(got, axis=1), want, 1e-5)
-    plain = modeling.forward(params, rows, cfg.replace(embedding_multiplier=1.0))
-    assert not close(plain, want, 1e-3)
+    want = forward(params, rows, cfg)
+    close(_lockstep(params, cfg, rows), want, 1e-5)
+    assert worst(forward(params, rows, cfg.replace(embedding_multiplier=1.0)), want) > 1e-3
 
 
 def test_a_plain_stack_with_the_gate_and_the_post_norms_is_served_whole():
@@ -361,17 +306,10 @@ def test_a_plain_stack_with_the_gate_and_the_post_norms_is_served_whole():
         vocab_size=64, hidden_size=32, num_layers=2, num_heads=4, num_kv_heads=2, ffn_dim=48,
         max_seq_len=32, dtype=jnp.float32, attn_gate=True, post_norms=True)
     params, rows = seeded(cfg, batch=2, length=12)
-    want = modeling.forward(params, rows, cfg)
-    cache = generation.init_kv_cache(cfg, 2, 32)
-    assert isinstance(cache, generation.KVCache)
-    pre, cache = generation.forward_with_cache(params, rows[:, :8], cfg, cache, jnp.int32(0))
-    got = [pre]
-    for t in range(8, 12):
-        lg, cache = generation.forward_with_cache(params, rows[:, t:t + 1], cfg, cache,
-                                                  jnp.full((2,), t, jnp.int32))
-        got.append(lg)
-    assert close(jnp.concatenate(got, axis=1), want, 1e-5)
-    assert not close(modeling.forward(params, rows, cfg.replace(attn_gate=False)), want, 1e-3)
+    want = forward(params, rows, cfg)
+    assert isinstance(generation.init_kv_cache(cfg, 2, 32), generation.KVCache)
+    close(_lockstep(params, cfg, rows), want, 1e-5)
+    assert worst(forward(params, rows, cfg.replace(attn_gate=False)), want) > 1e-3
     prompts = [rows[0, :9].tolist(), rows[1, :5].tolist()]
     ref = generation.generate_np(params, cfg, prompts, max_new_tokens=6)
     for paged in (dict(), dict(kv_num_blocks=-1, kv_block_size=8)):
@@ -404,14 +342,6 @@ def test_the_decode_kernel_takes_the_cells_shapes():
 # -- the engine ---------------------------------------------------------------------------
 
 
-def _engine(cfg, params, **kw):
-    from galvatron_tpu.serving import Engine
-
-    args = dict(num_slots=3, prefill_chunk=CHUNK, max_queue=64, eos_id=-1, pad_id=0, seed=0)
-    args.update(kw)
-    return Engine(params, cfg, **args)
-
-
 def test_the_engines_tap_rows_are_the_references_and_its_spans_count_the_touched_experts():
     """Three requests through the engine (prompts past and inside the window, answers
     that lap the ring), every token's logits row kept by the tap: the rows equal the
@@ -424,7 +354,7 @@ def test_the_engines_tap_rows_are_the_references_and_its_spans_count_the_touched
     params = held_by(params, cfg, (1, 2))
     prompts = [rows[0, :26].tolist(), rows[1, :5].tolist(), rows[2, :13].tolist()]
     new = [30, 40, 12]
-    engine = _engine(cfg, params)
+    engine = harness.engine(cfg, params)
     tracer.enable(capacity=1 << 13)
     tracer.clear()
     try:
@@ -443,7 +373,7 @@ def test_the_engines_tap_rows_are_the_references_and_its_spans_count_the_touched
         assert req.logits_rows == len(got)
         seq = jnp.asarray([prompt + got[:-1]], jnp.int32)
         want = np.asarray(ref_logits(params, seq, cfg))[0, len(prompt) - 1:]
-        assert close(buf, want)
+        close(buf, want, F32_TOL)
         assert [int(np.argmax(r)) for r in buf] == got
     decode = [e["args"] for e in spans if e["name"] == "decode"]
     assert decode and all(a["moe_held_experts"] == 4 for a in decode)
@@ -502,7 +432,7 @@ def test_a_cached_forwards_held_share_gives_tiles_to_the_touched_experts_alone(m
         del named[:]
         lg, cache, counters = forward(cache, tokens, offsets, slot, slot_form)
         jax.effects_barrier()
-        assert close(mine(lg), ref)
+        close(mine(lg), ref, F32_TOL)
         assert len(named) == 4  # the expert layers (the first layer is dense)
         touched = float(counters["moe_held_experts_touched"])
         assert touched == pytest.approx(sum(named) / 4) and 0 < touched <= 4
@@ -532,12 +462,12 @@ def test_the_engine_serves_it_under_int8_weights():
     params, rows = seeded(cfg, batch=2, length=24)
     qparams = quant.quantize_params(params, cfg)
     assert isinstance(qparams["layers"][0]["attn"]["wqkv"], quant.QuantTensor)
-    want = modeling.forward(qparams, rows, cfg)
+    want = forward(qparams, rows, cfg)
     cache = generation.init_kv_cache(cfg, 2, SLOT, tokens=24)
-    got, _ = _forward(qparams, cfg, cache, rows, jnp.zeros((2,), jnp.int32))
-    assert close(got, want, 1e-4)
-    assert not close(want, modeling.forward(params, rows, cfg), 1e-4)  # int8 is not float32
-    with _engine(cfg, params, serve_quant="int8", quant_drift_max=1e9) as engine:
+    got, _ = harness.step_forward(qparams, cfg, cache, rows, jnp.zeros((2,), jnp.int32))
+    close(got, want, 1e-4)
+    assert worst(want, forward(params, rows, cfg)) > 1e-4  # int8 is not float32
+    with harness.engine(cfg, params, serve_quant="int8", quant_drift_max=1e9) as engine:
         assert engine.quant_parity["max_abs_logit_drift"] > 0
         out = engine.generate([rows[0, :9].tolist()], max_new_tokens=4)
     assert len(out[0]) == 9 + 4
@@ -547,20 +477,9 @@ def test_the_engine_serves_it_under_int8_weights():
 
 
 def test_the_runtime_trains_it_on_one_device():
-    cfg = small_cfg(max_seq_len=32)
-    mesh, axes = build_mesh(pp=1, devices=jax.devices()[:1])
-    hp = HybridParallelConfig.uniform(cfg.num_layers, mixed_precision="fp32")
-    rt = build_runtime(cfg, hp, mesh=mesh, axes=axes, adam=AdamConfig(lr=3e-3),
-                       global_batch_size=4, seq_len=32)
-    state = rt.init_state(jax.random.key(0))
+    _, state = harness.trains_on_one_device(small_cfg(max_seq_len=32), steps=6, drop=0.2)
     assert {"post_attn_norm", "post_mlp_norm"} <= set(state["params"]["layers"][0])
     assert "wgate" in state["params"]["layers"][0]["attn"]
-    batch = jax.random.randint(jax.random.key(1), (4, 33), 0, cfg.vocab_size, jnp.int32)
-    losses = []
-    for _ in range(6):
-        state, loss = rt.train_step(state, rt.shard_batch(np.asarray(batch)))
-        losses.append(float(loss))
-    assert all(np.isfinite(losses)) and losses[-1] < losses[0] - 0.2
 
 
 @pytest.mark.parametrize("field", ["attn_gate", "post_norms"])

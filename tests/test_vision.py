@@ -12,11 +12,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from galvatron_tpu.core.optim import AdamConfig, adamw_update, init_opt_state
+from galvatron_tpu.core.optim import AdamConfig
 from galvatron_tpu.core.strategy import HybridParallelConfig, LayerStrategy
 from galvatron_tpu.models import modeling
 from galvatron_tpu.models.modeling import ModelConfig
 from galvatron_tpu.parallel.hybrid import build_runtime
+from tests._stack_harness import flat_losses, tracks_the_flat_trajectory
 
 VIT_CFG = ModelConfig(
     vocab_size=1, hidden_size=64, num_layers=4, num_heads=4, max_seq_len=0,
@@ -30,15 +31,7 @@ ADAM = AdamConfig(lr=1e-3, grad_clip=1.0)
 
 
 def reference_losses(cfg, batches):
-    params = modeling.init_model_params(jax.random.key(0), cfg)
-    opt = init_opt_state(params)
-    losses = []
-    step = jax.jit(jax.value_and_grad(lambda p, b: modeling.lm_loss(p, b, cfg)))
-    for b in batches:
-        loss, grads = step(params, b)
-        params, opt = adamw_update(params, grads, opt, ADAM)
-        losses.append(float(loss))
-    return losses
+    return flat_losses(cfg, modeling.init_model_params(jax.random.key(0), cfg), batches, ADAM)
 
 
 def run_hybrid(cfg, hp, batches):
@@ -102,16 +95,7 @@ def test_vit_pipeline_parity(vit_ref, schedule):
     rt = build_runtime(VIT_CFG, hp, adam=ADAM, global_batch_size=8)
     state = rt.init_state(jax.random.key(0))
     flat = jax.tree.map(jnp.asarray, _unstack_pipe_params(state["params"], VIT_CFG, pp))
-    opt = init_opt_state(flat)
-    step = jax.jit(jax.value_and_grad(lambda p, b: modeling.lm_loss(p, b, VIT_CFG)))
-    pipe_losses, ref_losses = [], []
-    for b in batches:
-        state, loss = rt.train_step(state, b)
-        pipe_losses.append(float(loss))
-        ref_loss, grads = step(flat, b)
-        flat, opt = adamw_update(flat, grads, opt, ADAM)
-        ref_losses.append(float(ref_loss))
-    np.testing.assert_allclose(pipe_losses, ref_losses, rtol=5e-5, atol=5e-5)
+    tracks_the_flat_trajectory(rt, state, flat, VIT_CFG, batches, ADAM)
 
 
 def test_vit_interleaved_trains(vit_ref):
