@@ -265,6 +265,11 @@ def server_metrics_text(service) -> str:
                              "engine's path)")):
             out.add(f"serving_{name}_total", s.get(name), mtype="counter",
                     help_=f"tokens drawn {where}")
+        for name, what in (("steps_ahead", "decode steps dispatched before the "
+                            "previous step's ids were on the host"),
+                           ("row_steps_wasted", "row-steps dispatched for a row that "
+                            "the bookkeeping, one iteration late, then retired")):
+            out.add(f"serving_{name}_total", s.get(name), mtype="counter", help_=what)
         out.add("serving_accepted_tokens_per_step",
                 s.get("accepted_tokens_per_step"),
                 help_="tokens emitted per decode iteration (batched over "

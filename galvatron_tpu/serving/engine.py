@@ -36,20 +36,26 @@ and decode twins replace the slot pair one-for-one.
 
 Sampling runs on the DEVICE, behind the decode step: the step's ``(num_slots,
 V)`` logits rows stay there and ONE batched program (``_sample_rows`` over
-``generation.draw_rows``) draws every active slot's next token from them; 16
-ids come back where 1.6 MB of logits and 16 numpy draws went. Everything a
-request may set (temperature, top-k, top-p) and the integers its randomness
-comes from (engine seed, request id, token index) are per-slot ARRAYS, never
-static arguments, so a greedy, a top-k and a nucleus request are the same
-compiled program and a request's tokens do not depend on its neighbours or its
-slot. A prompt's last row reaches the rows inside the prefill program (traced
-slot and row index). Rows come to the host only for a request that taps them
-(``capture_logits``), by a whole-buffer copy that needs no program. Greedy
-draws match ``generate``'s on-device argmax bit-for-bit, which is what the
-parity tests pin. The speculative engine (``spec_decode_k > 0``) keeps the
-host path (``_sample_host``): its verifier scores drafts against whole rows
-with ``generation.host_probs`` and draws its residual from an edited row, so
-its rows have to be on the host anyway.
+``generation.draw_rows``) draws every active slot's next token from them.
+Everything a request may set (temperature, top-k, top-p) and the integers its
+randomness comes from (engine seed, request id, token index) are per-slot
+ARRAYS, never static arguments, so a greedy, a top-k and a nucleus request are
+the same compiled program and a request's tokens do not depend on its
+neighbours or its slot. A prompt's last row reaches the rows inside the prefill
+program (traced slot and row index). Greedy draws match ``generate``'s
+on-device argmax bit-for-bit, which is what the parity tests pin.
+
+The drawn ids STAY on the device too (``Engine._ids``) and are the next step's
+``tokens`` operand, so the loop runs one step ahead of the host's bookkeeping
+(``Engine._step_ahead``): the host dispatches step N+1 and its draw, and only
+then waits for step N's ids and books them while the device runs step N+1. An
+iteration costs max(device, host), not their sum. Rows come to the host only
+for a request that taps them (``capture_logits``), by a whole-buffer copy that
+needs no program, of the rows the booked tokens were drawn from. The
+speculative engine (``spec_decode_k > 0``) keeps the synchronous host path
+(``_step_host``, ``_sample_host``): its verifier scores drafts against whole
+rows with ``generation.host_probs`` and draws its residual from an edited row,
+so its rows have to be on the host before the next window can be built.
 """
 
 from __future__ import annotations
@@ -146,8 +152,9 @@ def _router_counters(stats, cfg: ModelConfig, tokens: int) -> Dict[str, jax.Arra
 @partial(jax.jit, static_argnames=("cfg",), donate_argnames=("cache",))
 def _decode_step(params, cfg: ModelConfig, cache, tokens, offsets):
     """One decode iteration over ALL slots: tokens (B,) at per-row positions
-    offsets (B,). Inactive rows carry (0, 0) — their write lands at position
-    0 of their own free slot and is overwritten by the next prefill before
+    offsets (B,). A row that takes no step carries offset 0 (and whatever token
+    the device's ids hold for it) — its write lands at position 0 of its own
+    slot, free or finished, and is overwritten by the next prefill before
     any query can attend it. Returns ((B, V) next-position logits, cache,
     `_router_counters` of the step: a few scalars left on the device, which
     the engine reads while the tracer is on and no caller otherwise)."""
@@ -219,11 +226,15 @@ def _paged_decode_verify(params, cfg: ModelConfig, pool: KVCache, tokens,
 
 
 #: the engine's counters; ``draws_device`` / ``draws_host`` count the tokens
-#: drawn by ``_sample_rows`` and by ``_sample_host`` (the speculative engine's)
+#: drawn by ``_sample_rows`` and by ``_sample_host`` (the speculative engine's);
+#: ``steps_ahead`` the decode steps dispatched while the previous step's ids were
+#: not yet on the host, ``row_steps_wasted`` the row-steps dispatched for a row
+#: that the bookkeeping, one iteration late, then retired (`Engine._step_ahead`)
 _COUNTERS = (
     "steps", "prefill_chunks", "latent_chunks_kernel", "prefill_tokens", "tokens_generated",
     "engine_restarts", "draft_proposed", "draft_accepted",
     "spec_steps", "spec_fallbacks", "draws_device", "draws_host",
+    "steps_ahead", "row_steps_wasted",
 )
 
 #: lines of ``_sample_rows``'s two operand tables, one column a slot
@@ -232,19 +243,21 @@ _ACTIVE, _TOP_K, _RID, _INDEX, _SEED_LO, _SEED_HI = range(6)
 
 
 @jax.jit
-def _sample_rows(rows, knobs, ints):
+def _sample_rows(rows, knobs, ints, ids):
     """The engine's draw: one token a slot from the device-resident ``rows``
     (num_slots, V), each slot under its own request's parameters. ``knobs``
     (2, num_slots) float32 holds temperature and top-p, ``ints`` (6, num_slots)
     uint32 the active mask, top-k and what the randomness is made from (request
     id, token index, the engine seed's two words): data, all of it, so every mix
-    of requests is this one program. Returns (num_slots,) int32, 0 for a slot
-    that is not active."""
-    ids = generation.draw_rows(
+    of requests is this one program. Returns (num_slots,) int32: the drawn token
+    of an active slot, and of a slot that is not active what ``ids`` (the last
+    draws, still on the device) holds for it, so that a draw for one admitted
+    slot keeps the others' tokens and the result is the next step's operand."""
+    drawn = generation.draw_rows(
         rows, knobs[_TEMPERATURE], ints[_TOP_K].astype(jnp.int32), knobs[_TOP_P],
         jnp.stack([ints[_SEED_LO, 0], ints[_SEED_HI, 0]]), ints[_RID], ints[_INDEX],
     )
-    return jnp.where(ints[_ACTIVE] > 0, ids, 0)
+    return jnp.where(ints[_ACTIVE] > 0, drawn, ids)
 
 
 def _sample_host(rng: np.random.Generator, logits: np.ndarray,
@@ -414,8 +427,15 @@ class Engine:
         self._host_rows = np.zeros(
             (self.slots.num_slots, cfg.vocab_size), np.float32
         )
-        # the token each slot's request takes next (device draws, read back)
-        self._drawn = np.zeros((self.slots.num_slots,), np.int32)
+        # the token each slot's request takes next, ON THE DEVICE (the last draws,
+        # merged: `_sample_rows`): the next step's operand, read one iteration late
+        self._ids = self._fresh_ids()
+        # whether a decode step's draw is among them, unread (then the next step is
+        # dispatched AHEAD of the host), and that step's `_router_counters`
+        self._step_unread = False
+        self._router_unread: Dict[str, jax.Array] = {}
+        # the rows the tokens now being booked were drawn from (`_last_logits`)
+        self._booking = None
         self._by_slot: Dict[int, Request] = {}
         self._rng: Dict[int, np.random.Generator] = {}
         # the expert counters of the last step the tracer saw (host numbers; none for a dense model)
@@ -661,6 +681,11 @@ class Engine:
             # speculative engine's ``_sample_host``
             "draws_device": ec["draws_device"],
             "draws_host": ec["draws_host"],
+            # how far the loop ran ahead of its bookkeeping (``_step_ahead``): decode
+            # steps dispatched before the previous step's ids were on the host, and
+            # row-steps spent on a row that was found retired one iteration late
+            "steps_ahead": ec["steps_ahead"],
+            "row_steps_wasted": ec["row_steps_wasted"],
             "submitted": sc["submitted"],
             "admitted": sc["admitted"],
             "completed": sc["completed"],
@@ -871,20 +896,30 @@ class Engine:
         shape = (self.slots.num_slots, self.cfg.vocab_size)
         return jax.device_put(np.zeros(shape, jnp.dtype(self.cfg.dtype)))
 
+    def _fresh_ids(self):
+        """Zeroed device ids (num_slots,), as `_fresh_rows`."""
+        return jax.device_put(np.zeros((self.slots.num_slots,), np.int32))
+
     @property
     def _last_logits(self) -> np.ndarray:
         """(num_slots, V) float32: row ``slot`` is the row that slot's next
-        token is drawn from. The speculative engine holds them on the host;
+        token is drawn from: the token the host books next, so, while it books an
+        iteration's tokens, the rows THEY were drawn from (the device is a step
+        further by then). The speculative engine holds them on the host;
         otherwise this is a copy of the device's rows made on every read (tests
         and an operator's probe read it; the loop itself does not)."""
         if not self._device_draw:
             return self._host_rows
-        return np.asarray(self._rows).astype(np.float32)
+        rows = self._rows if self._booking is None else self._booking
+        return np.asarray(rows).astype(np.float32)
 
-    def _dispatch_draw(self, slots: Sequence[int]):
+    def _draw(self, slots: Sequence[int], unbooked: int) -> None:
         """Start ``_sample_rows`` for ``slots`` on the device rows: each slot's
-        next token under its request's own parameters, index ``len(generated)``.
-        Returns the ids, still on the device."""
+        next token under its request's own parameters. Its index in the request's
+        stream is ``len(generated)`` + ``unbooked``, the tokens drawn for it that
+        the host has not booked yet (0 behind a prompt, 1 behind a step): a
+        count, which no token's value moves. The ids stay on the device, merged
+        into the other slots' (``_ids``); their copy to the host starts now."""
         n = self.slots.num_slots
         knobs = np.zeros((2, n), np.float32)
         ints = np.zeros((6, n), np.uint32)
@@ -893,22 +928,9 @@ class Engine:
             req = self._by_slot[slot]
             knobs[:, slot] = req.temperature, req.top_p
             ints[:_SEED_LO, slot] = (1, max(req.top_k, 0), req.rid & 0xFFFFFFFF,
-                                     len(req.generated))
-        return _sample_rows(self._rows, knobs, ints)
-
-    def _collect_draw(self, ids, slots: Sequence[int], tapped: Sequence[int]) -> None:
-        """Bring a draw to the host: the ids of ``slots`` (4 bytes a slot), and
-        for the ``tapped`` ones the rows they were drawn from, as float32."""
-        slots = list(slots)
-        # (only these: the others' tokens, drawn behind the last step, still wait)
-        self._drawn[slots] = np.asarray(ids)[slots]
-        if tapped:
-            rows = np.asarray(self._rows)  # the whole buffer: a copy, no program
-            for slot in tapped:
-                self._host_rows[slot] = rows[slot]
-
-    def _tapped(self, slots: Sequence[int]) -> List[int]:
-        return [s for s in slots if self._by_slot[s].capture_logits is not None]
+                                     len(req.generated) + unbooked)
+        self._ids = _sample_rows(self._rows, knobs, ints, self._ids)
+        self._ids.copy_to_host_async()
 
     # -- engine loop (single thread owns cache + slots + jit calls) ---------
 
@@ -953,9 +975,10 @@ class Engine:
         """One iteration of the loop thread: admissions, then one decode step
         over the slots in use. The span tree (tracer on only; every child on
         this thread, inside its parent): ``iteration`` > ``admit`` >
-        ``prefill``; ``sample`` > ``sample_slot``; ``decode`` (or
-        ``decode_verify``) > ``decode_dispatch``, ``decode_wait``,
-        ``logits_readback``. ``step`` is the ``steps`` counter at entry, so a
+        ``prefill``; ``decode`` (or ``decode_verify``) > ``decode_dispatch``,
+        ``decode_wait``, ``logits_readback``; ``sample`` > ``sample_slot``
+        (behind the forward it runs beside on the device-draw path, in front of
+        it on the speculative engine's: ``_step``). ``step`` is the ``steps`` counter at entry, so a
         compile or a collection inside an iteration names it
         (``Tracer.current_step``) and the profiler groups device work by it."""
         if self.scheduler.empty() and not self._by_slot:
@@ -1043,6 +1066,13 @@ class Engine:
         attrs = {"rid": req.rid, "tokens": len(req.tokens)}
         if req.trace_id is not None:
             attrs["trace_id"] = req.trace_id
+        if _obs_tracer.enabled:
+            # tracer on, the span times the prompt's chunks on the device, as it did when
+            # the loop was synchronous: it opens on a device that is through with the step
+            # in flight and closes on realized compute (`_prefill_impl`). The two waits
+            # cost the TRACED loop a dispatch of device idle each an admission; untraced,
+            # nobody waits for a prompt inside its admission
+            jax.block_until_ready(self._ids)
         with _obs_tracer.span("prefill", **attrs) as span:
             self._prefill_impl(req, span)
 
@@ -1148,31 +1178,69 @@ class Engine:
             self.slots.register_prefix(slot, req.tokens)
         self._by_slot[slot] = req
         if self._device_draw:
-            # the first token, drawn from the prompt's last row where it lies
-            self._collect_draw(self._dispatch_draw([slot]), [slot], self._tapped([slot]))
+            # the first token, drawn from the prompt's last row where it lies; it is
+            # booked with the others' (`_step_ahead`): nobody waits for the chunks here
+            self._draw([slot], unbooked=0)
         else:
             self._host_rows[slot] = np.asarray(self._rows)[slot]
             self._rng[slot] = np.random.default_rng((self.seed, req.rid))
         if _obs_tracer.enabled:
-            # (the first token's read above waited for the chunks: a few bytes more)
+            # (the span's other end: the chunks and the first draw are through)
+            span.sync(self._ids)
             span.set(**{k: float(v) for k, v in router.items()})
         rz.advance(req, rz.DECODING, slot=slot)
         self._busy_s += time.perf_counter() - t0
 
     def _step(self) -> None:
-        """One decode iteration: every active slot takes its next token (drawn
-        on the device behind the forward that made its row, or here on the
-        host by the speculative engine), eos/budget-exhausted/cancelled/
-        over-deadline rows retire, then ONE shared forward runs for the
-        survivors, with the draw of their next tokens behind it."""
+        """One decode iteration over the slots in use: ONE shared forward, every
+        slot's next token drawn, every drawn token booked (``_book``).
+
+        Drawing on the device (``_step_ahead``) the loop runs one step ahead of
+        its bookkeeping: the forward is dispatched on the ids the device still
+        holds, and the tokens booked are the ones drawn an iteration earlier.
+        What is ON TIME: a row's offset, its draw's index and its LENGTH
+        retirement, which are counts (booked + in flight) and need no token's
+        value; a row's last token is never fed to a step. What is ONE ITERATION
+        LATE: eos, cancel and deadline, seen when the token is booked. The row's
+        step is then already dispatched and wasted (``row_steps_wasted``), its
+        id discarded, and the slot freed by the bookkeeping that sees it; an
+        admission into it is dispatched after that step in device order. The
+        wasted write is harmless for every stack kind: K/V (whole slots or
+        paged blocks) take it at the row's own next position of a slot that is
+        retired and never attended again, a ring stack's lap starts over with
+        the next prompt, and a state stack's row is reset by the next prompt's
+        read at offset 0. ``finish_reason``, ``generated`` and ``token_times``
+        are what a synchronous loop gives, one iteration later on the clock.
+
+        The speculative engine draws on the host and builds its window from the
+        tokens' values: it keeps the synchronous step (``_step_host``)."""
         t0 = time.perf_counter()
         # the chaos seam: engine_crash_at_iter raises here (the supervisor
         # must recover), slow_decode_ms stretches the iteration
         faults.engine_iteration(self.counters.get("steps"))
-        tokens = np.zeros((self.slots.num_slots,), np.int32)
-        offsets = np.zeros((self.slots.num_slots,), np.int32)
-        sampled = 0
-        appended = 0
+        step = self._step_ahead if self._device_draw else self._step_host
+        sampled, appended, forward = step()
+        self.counters.inc("steps")
+        self.counters.inc("tokens_generated", appended)
+        if self._guard_armed:
+            self.assert_cache_bounded()
+        dt = time.perf_counter() - t0
+        self._busy_s += dt
+        if forward:
+            self.decode_step_hist.observe(dt)
+        if dt > 0:
+            self._last_step_tps = sampled / dt
+
+    def _book(self, token_of, rows=None):
+        """The host's bookkeeping of one drawn token a slot in use, under the
+        ``sample`` span (one ``sample_slot`` a slot that draws): cancelled and
+        over-deadline rows retire without it; the tap's row, the token
+        (``token_of(slot, req)``), the request's times; eos and an exhausted
+        budget retire the row. ``rows``: the host's copy of the rows the tokens
+        were drawn from, where a slot taps. Returns (tokens drawn, tokens
+        appended, [(slot, token)] of the rows that go on)."""
+        sampled = appended = 0
+        kept: List[tuple] = []
         retired: List[int] = []
         cancelled: List[int] = []
         expired: List[int] = []
@@ -1196,17 +1264,9 @@ class Engine:
                     if req.capture_logits is not None:
                         # the tap: the row token k was drawn from
                         k = len(req.generated)
-                        req.capture_logits[k] = self._host_rows[slot]
+                        req.capture_logits[k] = rows[slot]
                         req.logits_rows = k + 1
-                    if self._device_draw:
-                        tok = int(self._drawn[slot])
-                        self.counters.inc("draws_device")
-                    else:
-                        tok = _sample_host(
-                            self._rng[slot], self._host_rows[slot],
-                            req.temperature, req.top_k, req.top_p,
-                        )
-                        self.counters.inc("draws_host")
+                    tok = token_of(slot, req)
                     sampled += 1
                     if req.first_token_at is None:
                         req.first_token_at = now
@@ -1223,49 +1283,148 @@ class Engine:
                         req.finish_reason = "length"
                         retired.append(slot)
                         continue
-                    tokens[slot] = tok
-                    offsets[slot] = self.slots.lengths[slot]
-                    self.slots.lengths[slot] += 1
+                    kept.append((slot, tok))
         for slot in retired:
             self._retire(slot)
         for slot in cancelled:
             self._retire_cancelled(slot)
         for slot in expired:
             self._retire_deadline(slot)
-        still = self.slots.active_slots()
+        return sampled, appended, kept
+
+    def _step_ahead(self):
+        """The device-draw iteration (the lag and why it is safe: ``_step``).
+        Every slot in use has exactly ONE token drawn and not yet booked, in
+        ``_ids`` on the device (behind the last step, or behind its prompt). The
+        rows that have budget left after it are fed to the next step straight
+        from there and their next draw follows it; only then the host waits for
+        the tokens it owes the requests, and books them while the device runs
+        the step. The span ``decode`` (one a dispatched step) is covered by
+        ``decode_dispatch`` (host: operands, the two jitted calls' return),
+        ``decode_wait`` (``Span.sync``, tracer on only: what the host really
+        waits for, the PREVIOUS draws' ids; that step's counters are read with
+        them) and ``logits_readback`` (the ids, and the rows of an iteration in
+        which a slot taps); ``sample`` follows it."""
+        ids, rows = self._ids, self._rows  # what the tokens to book are, and were drawn from
+        behind_a_step, router = self._step_unread, self._router_unread
+        tapped = any(req.capture_logits is not None for req in self._by_slot.values())
+        if tapped:
+            # the whole buffer, no program; started now, it runs as soon as the device
+            # is through (these rows are past every donation: the admissions are sent)
+            rows.copy_to_host_async()
+        nbytes = ids.nbytes + (rows.nbytes if tapped else 0)
+
+        def read():
+            return np.asarray(ids), np.asarray(rows) if tapped else None
+
+        # the held token is not the row's last: a count, known without its value
+        by = self._by_slot
+        fed = [slot for slot in self.slots.active_slots()
+               if len(by[slot].generated) + 1 < by[slot].max_new_tokens]
+        self._step_unread, self._router_unread = bool(fed), {}
+        if fed:
+            with _obs_tracer.span("decode", active=len(fed)) as step_span:
+                with _obs_tracer.span("decode_dispatch"):
+                    self._dispatch_step(fed)
+                    self.counters.inc("steps_ahead", int(behind_a_step))
+                with _obs_tracer.span("decode_wait") as sp:
+                    sp.sync(ids)
+                    if _obs_tracer.enabled:
+                        # the iteration's counters ride its span; the expert counters are
+                        # the step's whose ids have just arrived (their copies were started
+                        # with it: a few bytes, kept as host numbers for `stats`)
+                        self._router_counters = {k: float(v) for k, v in router.items()}
+                        step_span.set(
+                            **self.step_counters(fed),
+                            steps_ahead=self.counters.get("steps_ahead"),
+                            row_steps_wasted=self.counters.get("row_steps_wasted"))
+                with _obs_tracer.span("logits_readback", bytes=nbytes):
+                    drawn, host_rows = read()
+        else:
+            # nothing to send (every row holds its last token): the read is the wait
+            drawn, host_rows = read()
+
+        drawn = drawn.tolist()
+        self._booking = rows
+        try:
+            sampled, appended, kept = self._book(lambda slot, req: drawn[slot], host_rows)
+        finally:
+            self._booking = None
+        self.counters.inc("draws_device", sampled)
+        # a row that was fed and did not go on: eos, cancel or deadline, seen late
+        self.counters.inc("row_steps_wasted", len(set(fed) - {slot for slot, _ in kept}))
+        return sampled, appended, bool(fed)
+
+    def _dispatch_step(self, fed: Sequence[int]) -> None:
+        """Send the decode step for the rows ``fed`` and the draw behind it: the
+        step's tokens are the device's ids as they are, a fed row's offset its
+        slot's length, every other row's 0 (``_decode_step``). Nothing is waited for."""
+        offsets = np.zeros((self.slots.num_slots,), np.int32)
+        for slot in fed:
+            offsets[slot] = self.slots.lengths[slot]
+            self.slots.lengths[slot] += 1
+        if self.paged:
+            # a copy, of the fed rows' tables alone: the bookkeeping frees slots (and
+            # edits ``slots.tables`` in place) while this step is still queued, and a
+            # row that takes no step writes to the null block, not to position 0 of a
+            # finished request's first block, which a cached prefix may share
+            tables = np.zeros_like(self.slots.tables)
+            for slot in fed:
+                # provably a no-op for a plain step today (decode writes past every
+                # registered/shared block), kept as a cheap COW invariant
+                off = int(offsets[slot])
+                self.slots.ensure_writable(slot, off, min(off + 1, self.slots.max_seq_len))
+                tables[slot] = self.slots.tables[slot]
+            logits, self.slots.pool = _paged_decode_step(
+                self.params, self.cfg, self.slots.pool, self._ids,
+                jnp.asarray(tables), jnp.asarray(offsets),
+            )
+        else:
+            logits, self.slots.cache, self._router_unread = _decode_step(
+                self.params, self.cfg, self.slots.cache, self._ids, jnp.asarray(offsets),
+            )
+            if _obs_tracer.enabled:
+                for value in self._router_unread.values():  # (read with the step's ids)
+                    value.copy_to_host_async()
+        self._rows = logits
+        self._draw(fed, unbooked=1)
+
+    def _step_host(self):
+        """The speculative engine's iteration, synchronous: every slot's token is
+        drawn here on the host from the rows the last forward brought back,
+        eos/budget-exhausted/cancelled/over-deadline rows retire, then ONE
+        shared forward runs for the survivors (a verify window where a row has
+        drafts) and its logits come to the host."""
+        tokens = np.zeros((self.slots.num_slots,), np.int32)
+        offsets = np.zeros((self.slots.num_slots,), np.int32)
+
+        sampled, appended, kept = self._book(lambda slot, req: _sample_host(
+            self._rng[slot], self._host_rows[slot], req.temperature, req.top_k, req.top_p))
+        self.counters.inc("draws_host", sampled)
+        for slot, tok in kept:
+            tokens[slot] = tok
+            offsets[slot] = self.slots.lengths[slot]
+            self.slots.lengths[slot] += 1
+        still = [slot for slot, _ in kept]
         drafts = self._build_drafts(still, offsets) if still else {}
         if still and drafts:
             appended += self._verify_step(still, tokens, offsets, drafts)
         elif still:
             logits = self._forward_step("decode", tokens, offsets, still)
-            if not self._device_draw:
-                for slot in still:
-                    self._host_rows[slot] = logits[slot]
-        self.counters.inc("steps")
-        self.counters.inc("tokens_generated", appended)
-        if self._guard_armed:
-            self.assert_cache_bounded()
-        dt = time.perf_counter() - t0
-        self._busy_s += dt
-        if still:
-            self.decode_step_hist.observe(dt)
-        if dt > 0:
-            self._last_step_tps = sampled / dt
+            for slot in still:
+                self._host_rows[slot] = logits[slot]
+        return sampled, appended, bool(still)
 
     def _forward_step(self, name: str, tokens: np.ndarray, offsets: np.ndarray,
-                      still: Sequence[int], **attrs) -> Optional[np.ndarray]:
-        """The iteration's one shared forward over ALL slots: ``tokens`` (B,)
-        is the plain decode step, (B, 1+k) the speculative verify window. Its
-        span ``name`` (``decode`` / ``decode_verify``) is covered by three
-        children: ``decode_dispatch`` (host: operands to the device and the
-        jitted calls' return), ``decode_wait`` (the device: ``Span.sync``
-        blocks with the tracer on only, where ``np.asarray`` blocked anyway)
-        and ``logits_readback`` (the copy to the host, ``bytes``).
-
-        Drawing on the device, the logits stay there as the rows, the draw of
-        ``still``'s next tokens is dispatched behind the step, and what comes
-        back is their ids and the rows of the slots that tap (nothing is
-        returned). The speculative engine gets the logits on the host."""
+                      still: Sequence[int], **attrs) -> np.ndarray:
+        """The speculative engine's shared forward over ALL slots: ``tokens``
+        (B,) is the plain decode step (an iteration without a draft), (B, 1+k)
+        the verify window. Its span ``name`` (``decode`` / ``decode_verify``) is
+        covered by three children: ``decode_dispatch`` (host: operands to the
+        device and the jitted call's return), ``decode_wait`` (the device:
+        ``Span.sync`` blocks with the tracer on only, where ``np.asarray``
+        blocked anyway) and ``logits_readback`` (the copy to the host,
+        ``bytes``). Returns the logits on the host."""
         verify = tokens.ndim == 2
         with _obs_tracer.span(name, active=len(still), **attrs) as step_span:
             with _obs_tracer.span("decode_dispatch"):
@@ -1293,20 +1452,11 @@ class Engine:
                     if _obs_tracer.enabled:
                         for value in router.values():  # (read inside decode_wait, below)
                             value.copy_to_host_async()
-                if self._device_draw:
-                    self._rows = logits
-                    ids = self._dispatch_draw(still)
-                    tapped = self._tapped(still)
-                    # start the copies now: they run as soon as the device is
-                    # through, not when the host gets round to asking
-                    ids.copy_to_host_async()
-                    if tapped:
-                        logits.copy_to_host_async()
             # np.asarray is the engine's own readback sync (the next iteration
-            # needs the ids, or the logits, on the host), so the span closes on
-            # realized compute with the tracer off too
+            # needs the logits on the host), so the span closes on realized
+            # compute with the tracer off too
             with _obs_tracer.span("decode_wait") as sp:
-                sp.sync(ids if self._device_draw else logits)
+                sp.sync(logits)
                 if _obs_tracer.enabled:
                     # the iteration's counters ride its span (the device is through
                     # and their copies were started with the step: a few bytes, kept as
@@ -1316,13 +1466,8 @@ class Engine:
                         self._router_counters = {k: float(v) for k, v in router.items()}
                     step_span.set(**self.step_counters(
                         still, tokens.shape[1] if verify else 1))
-            if not self._device_draw:
-                with _obs_tracer.span("logits_readback", bytes=logits.nbytes):
-                    return np.asarray(logits)
-            with _obs_tracer.span("logits_readback",
-                                  bytes=ids.nbytes + (logits.nbytes if tapped else 0)):
-                self._collect_draw(ids, still, tapped)
-            return None
+            with _obs_tracer.span("logits_readback", bytes=logits.nbytes):
+                return np.asarray(logits)
 
     def _build_drafts(self, still, offsets) -> Dict[int, List[int]]:
         """Propose up to ``spec_k`` draft tokens per surviving slot from the
@@ -1550,6 +1695,9 @@ class Engine:
         # mid-call fresh ones are the only safe state
         self._rows = self._fresh_rows()
         self._host_rows[:] = 0.0
+        # and with the rows goes what was drawn from them and not yet booked
+        self._ids = self._fresh_ids()
+        self._step_unread, self._router_unread = False, {}
         # queued requests were never admitted: they survive the restart —
         # minus the ones whose TTL budget the crash already consumed
         self.scheduler.expire()
@@ -1636,7 +1784,7 @@ def _serving_programs(ctx):
     draw = [] if spec_k > 0 else [ProgramSpec(
         "serving_sample", _sample_rows,
         (rows_abs, jax.ShapeDtypeStruct((2, num_slots), jnp.float32),
-         jax.ShapeDtypeStruct((6, num_slots), jnp.uint32)),
+         jax.ShapeDtypeStruct((6, num_slots), jnp.uint32), i32(num_slots)),
         # (no weight enters it, but an int8 engine warms a set of its own)
         meta={"num_slots": num_slots, **({"key_extra": key_extra} if key_extra else {})},
     )]

@@ -639,7 +639,10 @@ def test_the_decode_span_carries_the_iterations_counters():
     _, stats, spans = harness.serve(TWO_SLOTS(cfg, params), [np.asarray(r).tolist() for r in rows],
                                     4, traced=True)
     chunks = spans["prefill"]
-    args = spans["decode"][0]
+    # (a step's expert counters are read with its ids, one iteration late: they ride the
+    # NEXT step's span, so the first step behind an idle engine carries none)
+    assert "moe_held_pairs_per_token" not in spans["decode"][0]
+    args = spans["decode"][1]
     assert args["latent_cache_bytes_per_position"] == 3 * 24 * 4
     assert args["latent_live_positions"] >= 2 * 12
     assert {"moe_held_pairs_per_token", "moe_load_imbalance"} <= set(args)

@@ -298,6 +298,212 @@ def test_engine_programs_match_the_replaced_forwards_bitwise(params, program):
 
 
 # ---------------------------------------------------------------------------
+# the loop one step ahead of its bookkeeping (PR 64)
+# ---------------------------------------------------------------------------
+
+
+def _until_done(eng, reqs, limit=300):
+    """``step_once`` until every request is finished -> the iterations it took."""
+    for n in range(limit):
+        if all(r.future.done() for r in reqs):
+            return n
+        eng.step_once()
+    raise AssertionError("the engine did not finish its requests")
+
+
+def _tapped(eng, prompt, max_new_tokens, **ask):
+    buf = np.full((max_new_tokens, eng.cfg.vocab_size), np.nan, np.float32)
+    return eng.submit_request(prompt, max_new_tokens, capture_logits=buf, **ask)
+
+
+def test_every_token_is_draw_rows_of_the_row_it_was_tapped_from(params):
+    """A mix of greedy and sampled requests through three slots, served a step
+    ahead of the bookkeeping: token k of every request is what
+    ``generation.draw_rows`` draws from the row the tap kept for it under
+    (engine seed, rid, k), which is what a synchronous loop serves; and the
+    greedy ones are ``generate_np``'s."""
+    seed = 2**33 + 5
+    asks = [dict(), dict(temperature=0.8, top_p=0.9), dict(temperature=1.0, top_k=5),
+            dict(), dict(temperature=0.7, top_k=8, top_p=0.95)]
+    prompts = _prompts(5, lo=3, hi=12, seed=21)
+    lengths = [7, 9, 5, 1, 8]  # (one request's first token is its last)
+    eng = Engine(params, CFG, num_slots=3, prefill_chunk=4, start_loop=False, seed=seed)
+    try:
+        reqs = [_tapped(eng, p, n, **a) for p, n, a in zip(prompts, lengths, asks)]
+        _until_done(eng, reqs)
+        stats = eng.stats()
+    finally:
+        eng.close()
+    words = jnp.asarray([seed & 0xFFFFFFFF, seed >> 32], jnp.uint32)
+    for req, ask, n, prompt in zip(reqs, asks, lengths, prompts):
+        assert req.finish_reason == "length" and req.logits_rows == len(req.generated) == n
+        want = generation.draw_rows(
+            jnp.asarray(req.capture_logits[:n]), jnp.full((n,), ask.get("temperature", 0.0)),
+            jnp.full((n,), ask.get("top_k", 0), jnp.int32), jnp.full((n,), ask.get("top_p", 0.0)),
+            words, jnp.full((n,), req.rid & 0xFFFFFFFF, jnp.uint32), jnp.arange(n, dtype=jnp.uint32))
+        assert req.generated == list(np.asarray(want))
+        if not ask:
+            assert prompt + req.generated == generation.generate_np(
+                params, CFG, [prompt], max_new_tokens=n)[0]
+    # no eos, no cancel, no deadline: no row-step was spent on a row that had ended
+    assert stats["row_steps_wasted"] == 0 and stats["draws_device"] == sum(lengths)
+
+
+def _stack_cfg(kind):
+    """One small configuration a kind of slot cache: whole K/V slots, a ring beside
+    them (three window layers of four), a per-row state (three short-conv layers)."""
+    from galvatron_tpu.models.modeling import PRESETS
+
+    if kind == "kv":
+        return CFG
+    small = dict(vocab_size=96, hidden_size=32, num_layers=4, num_heads=4, num_kv_heads=2,
+                 max_seq_len=64, moe_experts=4, moe_top_k=2, moe_ffn_dim=24, dtype=jnp.float32)
+    if kind == "ring":
+        return PRESETS["smallthinker-21b-a3b"].replace(
+            **small, attn_head_dim=8, ffn_dim=24, sliding_window_size=8)
+    return PRESETS["lfm2-24b-a2b"].replace(**small, ffn_dim=48)
+
+
+@pytest.mark.parametrize("kind", ["kv", "ring", "state"])
+def test_a_row_that_draws_eos_wastes_one_step_and_leaves_its_slot_clean(kind):
+    """eos is seen when the token is booked, one iteration after its row's next
+    step was sent: the request ends as a synchronous loop ends it (``eos``, the
+    eos not among its tokens), ONE row-step is counted wasted, and the request
+    admitted into the slot next (its prompt sent behind the wasted step) has,
+    token for token and row for row, what it has served alone in a fresh engine:
+    the wasted write is invisible in a K/V slot, a ring and a state."""
+    cfg = _stack_cfg(kind)
+    weights = modeling.init_model_params(jax.random.key(3), cfg)
+    first, second = [5, 9, 2, 7, 1, 3], [8, 4, 6, 11, 2, 9, 7, 1, 3, 5]  # (three chunks)
+    build = lambda **kw: Engine(weights, cfg, num_slots=1, prefill_chunk=4,  # noqa: E731
+                                start_loop=False, **kw)
+    eng = build()
+    try:
+        probe, alone = _tapped(eng, first, 20), _tapped(eng, second, 16)
+        _until_done(eng, [probe, alone])
+        assert eng.stats()["row_steps_wasted"] == 0
+    finally:
+        eng.close()
+    # the token that first shows latest, not last: its row has budget left when it
+    # is drawn, so its next step is on the way when the host reads it
+    firsts = {}
+    for i, tok in enumerate(probe.generated[:-1]):
+        firsts.setdefault(tok, i)
+    eos, j = max(firsts.items(), key=lambda kv: kv[1])
+    assert j >= 1
+    eng = build(eos_id=eos)
+    try:
+        ended, after = _tapped(eng, first, 20), _tapped(eng, second, 16)
+        _until_done(eng, [ended])
+        assert eng.stats()["row_steps_wasted"] == 1
+        _until_done(eng, [after])
+        stats, audit = eng.stats(), eng.audit()
+    finally:
+        eng.close()
+    assert ended.finish_reason == "eos" and ended.generated == probe.generated[:j]
+    assert ended.logits_rows == j + 1 and int(ended.capture_logits[j].argmax()) == eos
+    assert after.slot == ended.slot == 0 and not audit["leaked"]  # the same slot, after it
+    # (the second request may end on the eos too; up to there it is the same request)
+    n = len(after.generated)
+    assert after.generated == alone.generated[:n] and n >= 1
+    rows = after.logits_rows
+    assert np.array_equal(after.capture_logits[:rows].view(np.uint32),
+                          alone.capture_logits[:rows].view(np.uint32))
+    assert stats["row_steps_wasted"] == 1 + (after.finish_reason == "eos" and n + 1 < 16)
+
+
+def test_cancel_and_deadline_seen_one_iteration_late_free_the_slot(params):
+    """A cancel and a deadline are found when the row's token is booked, after its
+    next step was sent: the slot is free at the end of that iteration, the step
+    is counted wasted, the neighbour is untouched and nothing leaks."""
+    prompts = _prompts(3, seed=31)
+    ref = generation.generate_np(params, CFG, [prompts[2]], max_new_tokens=12)[0]
+    eng = Engine(params, CFG, num_slots=3, prefill_chunk=8, start_loop=False)
+    try:
+        dropped, late, kept = (eng.submit_request(prompts[0], 12),
+                               eng.submit_request(prompts[1], 12, ttl_s=3600.0),
+                               eng.submit_request(prompts[2], 12))
+        for _ in range(3):
+            eng.step_once()
+        assert eng.slots.active_count == 3 and eng.stats()["row_steps_wasted"] == 0
+        dropped.cancel("test")
+        eng.step_once()
+        assert eng.slots.active_count == 2 and eng.stats()["row_steps_wasted"] == 1
+        assert dropped.state == "CANCELLED" and len(dropped.generated) == 3
+        late.deadline = time.time() - 1.0
+        eng.step_once()
+        assert eng.slots.active_count == 1 and eng.stats()["row_steps_wasted"] == 2
+        assert late.finish_reason == "deadline" and len(late.generated) == 4
+        assert late.future.result(timeout=1) == prompts[1] + late.generated
+        _until_done(eng, [kept])
+        audit = eng.audit()
+    finally:
+        eng.close()
+    assert kept.future.result(timeout=1) == ref and not audit["leaked"]
+    assert audit["free_slots"] == 3 and audit["tracked_requests"] == 0
+
+
+@pytest.mark.parametrize("backend", [{}, {"kv_num_blocks": -1, "kv_block_size": 8}],
+                         ids=["slot", "paged"])
+def test_a_tapped_row_outlives_the_prompt_chunks_sent_behind_it(params, backend):
+    """The prefill program DONATES the rows and the next step replaces them, and
+    both are sent before the host reads the rows a token was drawn from: a tapped
+    request served while three-chunk prompts are admitted beside it has, bit for
+    bit, the rows it has served alone, each the row its token was drawn from."""
+    prompt, joiners = [5, 9, 2, 7, 1, 3], _prompts(3, lo=9, hi=12, seed=41)
+
+    def serve(beside):
+        eng = Engine(params, CFG, num_slots=2, prefill_chunk=4, start_loop=False, **backend)
+        try:
+            req = _tapped(eng, prompt, 14)
+            others = []
+            for i in range(60):
+                if i in (2, 5, 9) and beside:
+                    # admitted at the head of the next iteration: its chunks go out
+                    # between the tapped row's draw and the read of that row
+                    others.append(_tapped(eng, beside[len(others)], 3, temperature=0.8))
+                if req.future.done() and all(o.future.done() for o in others):
+                    break
+                eng.step_once()
+            assert eng.stats()["prefill_chunks"] >= 2 + 3 * len(others)
+            return req, others
+        finally:
+            eng.close()
+
+    alone, _ = serve([])
+    crowded, others = serve(joiners)
+    assert len(others) == 3 and all(o.logits_rows == 3 for o in others)
+    assert crowded.generated == alone.generated and crowded.logits_rows == 14
+    assert np.array_equal(crowded.capture_logits.view(np.uint32),
+                          alone.capture_logits.view(np.uint32))
+    assert list(crowded.capture_logits.argmax(-1)) == crowded.generated
+    assert prompt + crowded.generated == generation.generate_np(
+        params, CFG, [prompt], max_new_tokens=14)[0]
+
+
+def test_steps_ahead_counts_the_steps_sent_before_the_last_ids_were_read(params):
+    """One request of six tokens: five forwards (its last token is fed to none),
+    the first behind the prompt, the other four sent while the step before was
+    still unread; the sixth iteration sends nothing and books the last token. An
+    engine that stood idle starts over: its next request's first step is not
+    ahead of anything."""
+    eng = Engine(params, CFG, num_slots=2, prefill_chunk=8, start_loop=False)
+    try:
+        req = eng.submit_request(_prompts(1, seed=51)[0], 6)
+        ahead = []
+        while not req.future.done():
+            eng.step_once()
+            ahead.append(eng.stats()["steps_ahead"])
+        assert ahead == [0, 1, 2, 3, 4, 4] and eng.stats()["steps"] == 6
+        assert len(req.generated) == 6 and eng.stats()["row_steps_wasted"] == 0
+        again = eng.submit_request(_prompts(1, seed=52)[0], 3)
+        assert _until_done(eng, [again]) == 3
+        assert eng.stats()["steps_ahead"] == 4 + 1 and eng.stats()["steps"] == 9
+    finally:
+        eng.close()
+
+
+# ---------------------------------------------------------------------------
 # HTTP end-to-end
 # ---------------------------------------------------------------------------
 

@@ -316,8 +316,88 @@ def test_an_iteration_is_the_profilers_step_while_a_window_is_open(params, trace
         traced.profiling = False
         eng.close()
     assert seen[0] == ("StepTraceAnnotation", "serve", {"step_num": 1})
+    # (the forward is sent first; the tokens booked beside it are the last draw's)
     assert [name for _, name, _ in seen[1:]] == [
-        "sample", "sample_slot", "decode", "decode_dispatch", "decode_wait", "logits_readback"]
+        "decode", "decode_dispatch", "decode_wait", "logits_readback", "sample", "sample_slot"]
+
+
+# --- the loop one step ahead of its bookkeeping (PR 64) ----------------------------------
+
+
+def _three_requests(eng):
+    return [eng.submit_request([1, 2, 3, 4, 5], 6), eng.submit_request([7, 8, 9], 4,
+                                                                      temperature=0.8),
+            eng.submit_request([9, 8, 7, 6], 3)]
+
+
+@pytest.mark.parametrize("backend", ["slot-plain", "paged-plain"])
+def test_an_iteration_sends_its_forward_and_then_books_the_last_draws(params, traced, backend):
+    """The tree of an iteration on the device-draw path: ``admit`` (where a
+    request waits), then ``decode`` (one a step SENT: dispatch, the wait for the
+    PREVIOUS draws' ids, their way to the host), then ``sample`` (their
+    bookkeeping, beside the step on the device).  An iteration whose rows all
+    hold their last token sends nothing: ``sample`` alone."""
+    eng = _engine(params, backend)
+    try:
+        reqs = _three_requests(eng)
+        _drive(eng, reqs)
+        stats = eng.stats()
+    finally:
+        eng.close()
+    spans = _spans(traced)
+    its = [s for s in spans if s["name"] == "iteration"]
+    trees = [[s["name"] for s in sorted(spans, key=lambda s: s["ts"])
+              if s["depth"] == 1 and _inside(s, it)] for it in its]
+    assert all(t in (["admit", "decode", "sample"], ["decode", "sample"], ["sample"],
+                     ["admit", "sample"]) for t in trees), trees
+    assert trees[0] == ["admit", "decode", "sample"] and trees[-1] == ["sample"]
+    # one ``decode`` a dispatched step, no more and no fewer: a request's last token is fed
+    # to none, so the request of 6 tokens takes 5 steps; the third request joins its fifth
+    # (the slot of the second, which ended at the fourth) and takes one more
+    decodes = [s for s in spans if s["name"] == "decode"]
+    assert len(decodes) == trees.count(["admit", "decode", "sample"]) + trees.count(
+        ["decode", "sample"]) == 6 and stats["steps"] == len(its) == 7
+    assert all(s["args"].get("synced") for s in spans if s["name"] == "decode_wait")
+    # the counters ride the ``decode`` span (cumulative, as of its dispatch); the step behind
+    # an idle engine's first admission is ahead of nothing
+    assert [s["args"]["steps_ahead"] for s in decodes] == [0, 1, 2, 3, 4, 5]
+    assert stats["steps_ahead"] == 5 and {s["args"]["row_steps_wasted"] for s in decodes} == {0}
+    # every token was booked under a ``sample_slot``, the first of each with its prompt's iteration
+    assert len([s for s in spans if s["name"] == "sample_slot"]) == 6 + 4 + 3
+    # tracer on, a ``prefill`` closes on realized compute as before (``synced``)
+    assert all(s["args"].get("synced") for s in spans if s["name"] == "prefill")
+
+
+@pytest.mark.parametrize("backend", ["slot-plain", "paged-plain"])
+def test_a_traced_and_an_untraced_engine_send_the_same_steps_in_the_same_order(params, backend):
+    """The tracer times the loop the users run: with it on, the same forwards are
+    sent ahead of the same bookkeeping (its ``sync`` blocks on what the host waits
+    for anyway, never on the step just sent)."""
+    def run():
+        eng = _engine(params, backend, seed=11)
+        try:
+            reqs = _three_requests(eng)
+            for r, rid in zip(reqs, (901, 902, 903)):
+                r.rid = rid  # (the sampled request's stream is its rid's)
+            order = []
+            while not all(r.future.done() for r in reqs):
+                eng.step_once()
+                st = eng.stats()
+                order.append((st["steps_ahead"], st["prefill_chunks"], st["tokens_generated"]))
+            return [r.generated for r in reqs], order, eng.stats()["row_steps_wasted"]
+        finally:
+            eng.close()
+
+    assert not tracer.enabled
+    plain = run()
+    tracer.enable(capacity=1 << 14)
+    try:
+        under_the_tracer = run()
+        assert len(_spans(tracer, "decode")) == 6
+    finally:
+        tracer.disable()
+        tracer.clear()
+    assert plain == under_the_tracer and plain[1][-1][0] == 5 and plain[2] == 0
 
 
 def test_an_idle_engine_opens_no_iteration(params, traced):

@@ -95,8 +95,9 @@ def test_kept_set_is_the_float64_references(vocab, temperature, top_p, top_k):
 
 
 def _tables(n, temperature=0.0, top_k=0, top_p=0.0, *, active=None, rid=None, index=None,
-            seed=0):
-    """``_sample_rows``'s two operand tables for ``n`` slots."""
+            seed=0, ids=None):
+    """``_sample_rows``'s two operand tables for ``n`` slots, and the ids a slot that
+    is not active keeps (0 unless given)."""
     knobs = np.zeros((2, n), np.float32)
     ints = np.zeros((6, n), np.uint32)
     knobs[engine_mod._TEMPERATURE], knobs[engine_mod._TOP_P] = temperature, top_p
@@ -105,7 +106,7 @@ def _tables(n, temperature=0.0, top_k=0, top_p=0.0, *, active=None, rid=None, in
     ints[engine_mod._RID] = np.arange(n) if rid is None else rid
     ints[engine_mod._INDEX] = 0 if index is None else index
     ints[engine_mod._SEED_LO], ints[engine_mod._SEED_HI] = seed & 0xFFFFFFFF, seed >> 32
-    return knobs, ints
+    return knobs, ints, np.zeros(n, np.int32) if ids is None else np.asarray(ids, np.int32)
 
 
 @pytest.mark.parametrize("temperature", [0.0, -1.0])
@@ -169,6 +170,13 @@ def test_each_row_of_a_mixed_batch_gets_its_own_result():
     later = np.asarray(engine_mod._sample_rows(rows, *_tables(
         8, temperature, top_k, top_p, active=active, rid=rid, index=index + 1, seed=5)))
     assert (later != together).any()
+    # a slot that is not active keeps the id it is handed (the last draws, which the
+    # engine leaves on the device): a draw for one admitted slot merges into them
+    held = np.arange(100, 108)
+    merged = np.asarray(engine_mod._sample_rows(rows, *_tables(
+        8, temperature, top_k, top_p, active=np.arange(8) == 2, rid=rid, index=index, seed=5,
+        ids=held)))
+    assert merged[2] == together[2] and list(np.delete(merged, 2)) == list(np.delete(held, 2))
 
 
 def test_the_sampler_is_one_program_whatever_the_mix():
@@ -360,10 +368,10 @@ def test_the_aot_registry_declares_the_sampler_with_the_engines_shapes(params):
 
     ctx = aot_registry.ProgramContext(cfg=CFG, num_slots=16, prefill_chunk=8)
     specs = {s.name: s for s in aot_registry.enumerate_programs(ctx, include=("serving",))}
-    rows, knobs, ints = specs["serving_sample"].args
+    rows, knobs, ints, ids = specs["serving_sample"].args
     assert specs["serving_sample"].fn is engine_mod._sample_rows
-    assert (rows.shape, knobs.shape, ints.shape) == ((16, 128), (2, 16), (6, 16))
-    assert rows.dtype == jnp.dtype(CFG.dtype) and ints.dtype == jnp.uint32
+    assert (rows.shape, knobs.shape, ints.shape, ids.shape) == ((16, 128), (2, 16), (6, 16), (16,))
+    assert rows.dtype == jnp.dtype(CFG.dtype) and ints.dtype == jnp.uint32 and ids.dtype == jnp.int32
     assert specs["serving_prefill"].args[6].shape == (16, 128)  # the rows the row lands in
     paged = {s.name for s in aot_registry.enumerate_programs(
         aot_registry.ProgramContext(cfg=CFG, num_slots=16, prefill_chunk=8, kv_num_blocks=-1),

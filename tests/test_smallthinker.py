@@ -457,7 +457,10 @@ def test_the_decode_span_carries_the_stacks_counters():
         assert a["kv_window_read_positions"] == 3 * (WINDOW + CHUNK)
         assert (a["kv_full_layers"], a["kv_window_layers"]) == (2, 6)
         assert a["kv_cache_bytes_per_position"] == 2 * 2 * 8 * 4
-        assert 0 < a["moe_held_pairs_per_token"] <= 3
+    # (a step's expert counters ride the NEXT step's span: read with its ids, a step late)
+    carried = [a for a in spans["decode"] if "moe_held_pairs_per_token" in a]
+    assert len(carried) == len(spans["decode"]) - 1
+    assert all(0 < a["moe_held_pairs_per_token"] <= 3 for a in carried)
     # prompts of 26 and 6 in chunks of 4 over a ring of 12: the long one's chunks at 12
     # and 24 begin a lap (none crosses the ring's end: 12 is 3 chunks)
     prefill = spans["prefill"]

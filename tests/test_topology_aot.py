@@ -1120,7 +1120,7 @@ def test_serving_sampler_compiles_for_the_chip_without_a_sort(one_chip, real_mos
     spec, = [sp for sp in registry.enumerate_programs(ctx, include=("serving",))
              if sp.name == "serving_sample"]
     args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip) for a in spec.args]
-    assert [a.shape for a in args] == [(16, 50272), (2, 16), (6, 16)]
+    assert [a.shape for a in args] == [(16, 50272), (2, 16), (6, 16), (16,)]  # (the last ids: PR 64)
     compiled = spec.fn.lower(*args).compile()
     text = compiled.as_text()
     assert " sort(" not in text and "while(" in text

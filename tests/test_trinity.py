@@ -377,12 +377,15 @@ def test_the_engines_tap_rows_are_the_references_and_its_spans_count_the_touched
         assert [int(np.argmax(r)) for r in buf] == got
     decode = [e["args"] for e in spans if e["name"] == "decode"]
     assert decode and all(a["moe_held_experts"] == 4 for a in decode)
+    stacks = decode[0]
+    # (a step's expert counters ride the NEXT step's span: read with its ids, a step late)
+    assert "moe_held_experts_touched" not in decode.pop(0)
     # 3 rows x top-2 = 6 pairs a step on 8 experts scored, 4 held: some held go without a row
     assert all(0 <= a["moe_held_experts_touched"] <= 4 for a in decode)
     assert any(0 < a["moe_held_experts_touched"] < 4 for a in decode)
     assert all(a["moe_held_experts_touched"] <= 3 * a["moe_held_pairs_per_token"] + 1e-6
                for a in decode)
-    assert (decode[0]["kv_full_layers"], decode[0]["kv_window_layers"]) == (1, 4)
+    assert (stacks["kv_full_layers"], stacks["kv_window_layers"]) == (1, 4)
     prefill = [e["args"] for e in spans if e["name"] == "prefill"]
     assert prefill and all(0 <= a["moe_held_experts_touched"] <= 4 for a in prefill)
     per = 2 * 2 * 8 * 4
