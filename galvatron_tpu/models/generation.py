@@ -18,7 +18,9 @@ What a cache holds goes by what a LAYER keeps, one rule for every stack:
 - a ring: keys and values of the last ``window`` positions (a sliding-window layer);
 - a state: a fixed-size array a row, no positions (a kind of ``mixers.MIXERS`` with
   ``state``: the gated short convolution's last inputs);
-- or the kind's own position-indexed cache, every layer alike (latent attention).
+- or the kind's own position-indexed cache (latent attention: every layer alike, or, of
+  a stack with an indexer or window layers, the full layers' latent and index keys in
+  whole slots and the window layers' latent in a ring: ``models/mla.LatentCache``).
 
 A stack whose layers all keep whole slots has a `KVCache`; one that mixes whole
 slots with rings or states has `SlotStacks`, a stack of arrays for each of the three,
@@ -151,7 +153,7 @@ def init_kv_cache(cfg: ModelConfig, batch_size: int, max_len: int, tokens: int =
             raise ValueError(limit.sentence())
     kind = mixers.cache_kind(cfg)
     if kind is not None:
-        return mixers.module(kind).init_cache(cfg, cfg.num_layers, batch_size, max_len)
+        return mixers.module(kind).init_cache(cfg, cfg.num_layers, batch_size, max_len, tokens)
     if stacked(cfg):
         layers = stack_layers(cfg)
         full = (layers["full"], batch_size, cfg.kv_heads, max_len, cfg.head_dim)
@@ -181,6 +183,9 @@ def cache_layout(cfg: ModelConfig, max_len: Optional[int] = None, tokens: int = 
     state layers' rows."""
     kind = mixers.cache_kind(cfg)
     if kind is not None:
+        own = mixers.module(kind).cache_layout(cfg, max_len, tokens)
+        if own is not None:  # (stacks of the kind's own, each with its bytes a position)
+            return own
         word, per_layer = mixers.MIXERS[kind].cache, mixers.module(kind).cache_bytes_per_position(cfg)
     else:
         word, per_layer = "kv", 2 * cfg.kv_heads * cfg.head_dim * jnp.dtype(cfg.dtype).itemsize
@@ -386,10 +391,11 @@ def chunk_key_blocks(positions: int, end):
     return block, positions // block, (end + block - 1) // block
 
 
-def write_ring(stacked, layer: int, new, starts, aligned: bool):
+def write_ring(stacked, layer: int, new, starts, aligned: bool, axis: int = 3):
     """`write_layer` into a head-major ring (L, Bc, kvh, R, hd): position p of
     ``new`` (B, kvh, s, hd) lands at ``p mod R``, by ``dynamic_update_slice`` on the
-    stacked array alone (`write_layer`'s rule). An update cannot wrap (XLA clamps
+    stacked array alone (`write_layer`'s rule); ``axis`` 2: a ring without heads (L, Bc,
+    R, width), ``new`` (B, s, width): a latent's. An update cannot wrap (XLA clamps
     its start, and the entries then lie where the mask reads other positions), so:
 
     - ``aligned`` (a SCALAR offset: a prompt chunk, ``generate``'s prefill) is one
@@ -403,14 +409,15 @@ def write_ring(stacked, layer: int, new, starts, aligned: bool):
     chip's compiler then re-laid the WHOLE ring stack to the new entries' order and
     back, 4 copies of 1.9 GiB a chunk at the cell's size, compiled for a described
     v5e; plain updates leave the stack where it is.)"""
-    ring, s = stacked.shape[3], new.shape[2]
+    ring, s = stacked.shape[axis], new.shape[axis - 1]
     if s > ring:
         raise ValueError(f"a forward of {s} positions does not fit a ring of {ring}")
     if s == 1 or aligned:
-        return write_layer(stacked, layer, new, [(row, pos % ring) for row, pos in starts], axis=3)
+        return write_layer(stacked, layer, new, [(row, pos % ring) for row, pos in starts],
+                           axis=axis)
     for i in range(s):
-        stacked = write_layer(stacked, layer, jax.lax.slice_in_dim(new, i, i + 1, axis=2),
-                              [(row, (pos + i) % ring) for row, pos in starts], axis=3)
+        stacked = write_layer(stacked, layer, jax.lax.slice_in_dim(new, i, i + 1, axis=axis - 1),
+                              [(row, (pos + i) % ring) for row, pos in starts], axis=axis)
     return stacked
 
 
@@ -583,10 +590,11 @@ def forward_with_cache(params: Params, tokens, cfg: ModelConfig, cache, offsets,
     starts = _window_starts(offsets, slot, tokens.shape[0])
     kind = mixers.cache_kind(cfg)
     by_stack = stacked(cfg)
-    if by_stack:
-        stacks = layer_stacks(cfg)
+    if by_stack or (kind is not None and cfg.windowed):
+        stacks = layer_stacks(cfg)  # (a kind's own stacks too: a layer's place in its own)
     elif kind is None:
         ks, vs = cache
+    tables = {cfg.rope_theta: cos_sin}  # (a window layer of another rotary base: its own)
     for i, p in enumerate(params["layers"]):
         with jax.named_scope(f"layer_{i}"):
             # (the router of such a layer reads what its attention block reads)
@@ -608,9 +616,15 @@ def forward_with_cache(params: Params, tokens, cfg: ModelConfig, cache, offsets,
                         stacks[i][1], starts, slot, offsets, cos_sin)
                     x = x + modeling.post_norm(y, p, "post_attn_norm", cfg)
                 elif kind is not None:
+                    # (under the layer's view; a window layer of other sizes: its own
+                    # rotary table and its place in the ring's stack)
+                    view = cfg.layer_view(i)
+                    if view.rope_theta not in tables:
+                        tables[view.rope_theta] = _rope_at(view, smax, offsets, s)
                     y, cache = mixers.module(kind).cached_block(
-                        modeling.norm(x, p["attn_norm"], cfg), p[kind], cfg, cache, i, starts,
-                        slot, offsets, cos_sin)
+                        modeling.norm(x, p["attn_norm"], cfg), p[kind], view, cache,
+                        stacks[i][1] if cfg.windowed else i, starts, slot, offsets,
+                        tables[view.rope_theta])
                     x = x + modeling.post_norm(y, p, "post_attn_norm", cfg)
                 else:
                     q, k, v = _project_qkv_at(x, p, cfg, cos_sin)

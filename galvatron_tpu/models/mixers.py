@@ -15,8 +15,11 @@ only where a configuration has such layers: `module`) exposes
   run's fingerprint, `path_counts`);
 - where the row says ``cache`` (the kind keeps a cache of its own in the cached
   forwards: no ``kv_cache`` under ``lacks``): ``init_cache(cfg, layers, rows,
-  positions)`` -> a NamedTuple of arrays stacked ``(layers, rows, positions,
-  ...)``, ``cache_bytes_per_position(cfg)`` (one layer's), ``cache_read_positions(
+  positions, tokens)`` -> a NamedTuple of arrays stacked ``(layers, rows, positions,
+  ...)``, ``cache_bytes_per_position(cfg)`` (one layer's), ``cache_layout(cfg, max_len,
+  tokens)`` (None: every layer alike; else the kind's own stacks, each with its bytes a
+  position, and ``step_counters(layout, lengths, rows, positions, window)``: what a decode
+  iteration of them fetches), ``cache_read_positions(
   cfg, lengths, rows, positions, window)`` (what a decode window's attention
   fetches of one layer, by construction), ``chunk_layout(cfg, rows,
   positions)`` (a prompt chunk's: which body its attention takes, the keys a block
@@ -63,6 +66,7 @@ class Mixer:
     lacks: Mapping[str, str]
     kernels: Tuple[str, ...] = ()  # the bodies `path_counts` reports, "<kind>_<kernel>_path"
     cache: str = ""  # "latent": what the kind's own cache holds a position ("": it has none)
+    ring: bool = False  # its cached forward keeps a window layer's entries in a ring of its own
     state: str = ""  # "conv": what the kind's state holds a row, no positions ("": it has none)
 
 
@@ -91,6 +95,7 @@ MIXERS: Dict[str, Mixer] = {entry.kind: entry for entry in (
     Mixer(
         kind="mla", module="galvatron_tpu.models.mla", layer="latent-attention layer",
         mixer="the latent-attention (MLA) mixer", tag="latent_attention_layers", cache="latent",
+        ring=True,
         lacks={
             "tp": ("the mixer's heads share one latent and one rotary key, and its projections "
                    "carry no tp sharding"),
@@ -196,6 +201,7 @@ def _window_limits(cfg, enc: int, every: Tuple[int, ...]) -> List[Limit]:
     """What a stack with sliding-window layers does not implement: the window is a
     mask of XLA's attention (``modeling.attention_xla``) and a ring of the slot
     cache (``generation.SlotStacks``), and of nothing else."""
+    kinds = tuple(cfg.kinds)
     at = tuple(enc + i for i, w in enumerate(cfg.window_layers) if w)
     layers = f"sliding-window layers (window {cfg.sliding_window_size}; layers {at})"
     out = [
@@ -226,7 +232,9 @@ def _window_limits(cfg, enc: int, every: Tuple[int, ...]) -> List[Limit]:
                        f"with {layers}: a block pool holds every layer's positions alike and "
                        "has no ring; serve it from the slot cache (kv_num_blocks 0)")),
     ]
-    if has_mixer_layers(cfg):
+    # (a stack of ONE kind whose cached forward keeps a ring of its own is served)
+    own_ring = len(set(kinds)) == 1 and kinds[0] in MIXERS and MIXERS[kinds[0]].ring
+    if has_mixer_layers(cfg) and not own_ring:
         out.append(Limit(
             "kv_cache", every,
             refusal=("generation is not implemented for a stack that has both sliding-window "
@@ -293,6 +301,14 @@ def limits(cfg) -> List[Limit]:
                      f"with {e.layer}s (layers {at}): a rejected draft has already advanced the "
                      f"{e.state} state, and a state cannot be wound back as a position is "
                      "overwritten; use spec_decode_k 0")))
+    if cache_kind(cfg) and (getattr(cfg, "windowed", False) or getattr(cfg, "mla_index_topk", 0)):
+        out.append(Limit(
+            "spec_decode", every,
+            refusal=("speculative decoding (spec_decode_k > 0) is not implemented for a "
+                     "latent-attention stack with an indexer or sliding-window layers: a verify "
+                     "window of several queries a row would attend under a mask over every "
+                     "slot's capacity, and no test holds the selection of a rejected draft's "
+                     "index keys; use spec_decode_k 0")))
     if len(set(kinds)) > 1:
         out.append(Limit(
             "pp", every, tag="interleaved_layer_kinds_no_pp", code="GTA020",

@@ -161,6 +161,36 @@ class ModelConfig:
     mla_nope_dim: int = 128
     mla_rope_dim: int = 64
     mla_v_dim: int = 128
+    # dots3_note-class latent attention, each off by default (sarvam's layer):
+    # low-rank queries (``mla_q_rank`` > 0: W_qa, an RMSNorm of that rank, W_qb);
+    # ``mla_rescale``: q after W_qb times (hidden / mla_q_rank)^1/2 and the normed
+    # latent times (hidden / mla_kv_rank)^1/2 (LongCat-Flash's mla_scale_q_lora /
+    # mla_scale_kv_lora); ``mla_head_gate``: sigmoid(h W_g), ONE scalar a head, on
+    # the attention output before W_o (``attn_gate`` is a head AND channel);
+    # the indexer of learned sparse attention (``mla_index_topk`` > 0: a query
+    # attends the ``mla_index_topk`` keys at or before it that score highest under
+    # ``mla_index_heads`` index heads of ``mla_index_dim``, all of them while there
+    # are no more; it needs ``mla_q_rank``: the index queries come from the query
+    # latent).
+    mla_q_rank: int = 0
+    mla_rescale: bool = False
+    mla_head_gate: bool = False
+    mla_index_heads: int = 0
+    mla_index_dim: int = 128
+    mla_index_topk: int = 0
+    # A latent stack's SLIDING-WINDOW layers (``sliding_window_layout``) with sizes
+    # of their own (``swa_kv_rank`` > 0): heads, the head's parts, the latent's and
+    # the queries' ranks, the rotary base. ``layer_view`` of such a layer carries
+    # them under the ``mla_*`` / ``num_heads`` / ``rope_theta`` names and no
+    # indexer, so ``models/mla.py`` reads ONE set of names; the cached forwards keep
+    # the window layers' latent in a ring beside the full layers' whole slots.
+    swa_num_heads: int = 0
+    swa_nope_dim: int = 0
+    swa_rope_dim: int = 0
+    swa_v_dim: int = 0
+    swa_kv_rank: int = 0
+    swa_q_rank: int = 0
+    swa_rope_theta: float = 0.0
     # YaRN rotary scaling (``deepseek_yarn``): (factor, original positions,
     # beta_fast, beta_slow, mscale, mscale_all_dim); empty: plain rotary.
     rope_yarn: Tuple[float, ...] = ()
@@ -311,9 +341,18 @@ class ModelConfig:
             raise ValueError(
                 f"rope_layout has {len(self.rope_layout)} entries for {self.num_layers} layers")
         rope = not self.rope_layout or bool(self.rope_layout[i])
-        return self.replace(
+        view = self.replace(
             attn_window=self.sliding_window_size if self.window_layers[i] else 0,
             pos_embed=self.pos_embed if rope else "nope")
+        if view.attn_window and self.swa_kv_rank:
+            # a latent stack's window layer: its own sizes, no indexer
+            view = view.replace(
+                num_heads=self.swa_num_heads, mla_nope_dim=self.swa_nope_dim,
+                mla_rope_dim=self.swa_rope_dim, mla_v_dim=self.swa_v_dim,
+                mla_kv_rank=self.swa_kv_rank, mla_q_rank=self.swa_q_rank,
+                rope_theta=self.swa_rope_theta, mla_index_topk=0,
+                attn_head_dim=self.swa_nope_dim + self.swa_rope_dim)
+        return view
 
     @property
     def kv_heads(self) -> int:
@@ -808,8 +847,8 @@ def init_model_params(key, cfg: ModelConfig) -> Params:
             * 0.02
         },
         "layers": [
-            init_layer_params(ks[cfg.enc_layers + i + 1], cfg, cross=cross, kind=kind,
-                              dense_mlp=i < cfg.moe_dense_layers)
+            init_layer_params(ks[cfg.enc_layers + i + 1], cfg.layer_view(i), cross=cross,
+                              kind=kind, dense_mlp=i < cfg.moe_dense_layers)
             for i, kind in enumerate(cfg.kinds)
         ],
         "final_norm": {"scale": _norm_scale_init(cfg, cfg.hidden_size)},
@@ -844,7 +883,7 @@ def model_annotations(cfg: ModelConfig) -> Params:
     cross = cfg.enc_layers > 0
     a: Params = {
         "embed": {"tok": ("tp", "fsdp")},
-        "layers": [layer_annotations(cfg, cross=cross, kind=kind,
+        "layers": [layer_annotations(cfg.layer_view(i), cross=cross, kind=kind,
                                      dense_mlp=i < cfg.moe_dense_layers)
                    for i, kind in enumerate(cfg.kinds)],
         "final_norm": {"scale": ("fsdp",)},
@@ -2303,6 +2342,28 @@ PRESETS: Dict[str, ModelConfig] = {
         mla_v_dim=128, rope_yarn=(40.0, 4096, 32.0, 1.0, 1.0, 1.0),
         moe_experts=128, moe_router="sigmoid_topk", moe_top_k=8, moe_route_scale=2.5,
         moe_ffn_dim=2048, moe_shared_ffn_dim=2048, moe_shared_gate=False, moe_dense_layers=1,
+    ),
+    # dots-studio/dots3-note-prev (model_type dots3_note; the language model, no towers, no
+    # MTP): 46 layers of latent attention of TWO widths. Full layers (0, 1, 5, 9, ... 45):
+    # 128 heads of 128 + 64 rotary / 128 over a 512 + 64 latent, queries through a rank of
+    # 1024, a DSA indexer (64 index heads of 128, ONE index key a position) that keeps the
+    # 2,048 best keys a query, rotary theta 8e7. Sliding layers (513 keys): 64 heads of
+    # 192 + 64 / 128 over a 1,024 + 64 latent, rank-1024 queries, theta 5e4, no indexer.
+    # Both: the low-rank rescale, a headwise sigmoid gate. Layer 0 a SwiGLU MLP of 13824,
+    # then 256 experts of 1536, sigmoid scores with a selection bias, top-8 renormalised
+    # x 1, one ungated shared expert; untied head. Served (models/mla.py: the full layers'
+    # latent and index keys in whole slots, the window layers' latent in a ring).
+    "dots3-note-prev": ModelConfig(
+        vocab_size=152064, hidden_size=5120, num_layers=46, num_heads=128, attn_head_dim=192,
+        ffn_dim=13824, max_seq_len=524288, rope_theta=8e7, norm_eps=1e-5,
+        layer_kinds=("mla",) * 46, mla_kv_rank=512, mla_nope_dim=128, mla_rope_dim=64,
+        mla_v_dim=128, mla_q_rank=1024, mla_rescale=True, mla_head_gate=True,
+        mla_index_heads=64, mla_index_dim=128, mla_index_topk=2048,
+        sliding_window_size=513, sliding_window_layout=(0, 0) + (1, 1, 1, 0) * 11,
+        swa_num_heads=64, swa_nope_dim=192, swa_rope_dim=64, swa_v_dim=128, swa_kv_rank=1024,
+        swa_q_rank=1024, swa_rope_theta=5e4,
+        moe_experts=256, moe_router="sigmoid_topk", moe_top_k=8, moe_route_scale=1.0,
+        moe_ffn_dim=1536, moe_shared_ffn_dim=1536, moe_shared_gate=False, moe_dense_layers=1,
     ),
     # PowerInfer/SmallThinker-21BA3B-Instruct (model_type smallthinker): 52 layers in
     # periods of four, one FULL layer without any position signal (NoPE) then three

@@ -33,6 +33,11 @@ with a float32 maximum, sum and accumulator, the exponentials cast to the comput
 type for the second product (the plain body's precision,
 ``models/mla._plain_context``).
 
+With a SELECTION (``latent_attention(selected=)``, PR 65: a learned sparse attention's
+keys, ``models/mla.select_mask``, one query a row) a fifth operand (rows, 1, positions)
+int32 rides the latent's block map: every live block takes the masked body with the
+selection in place of ``k <= q``, and a row is still read up to its own length only.
+
 The ``pl.pallas_call`` name ``mla_decode`` is what a device trace shows under
 ``attn_core`` (PERF.md section 3).
 """
@@ -78,9 +83,12 @@ def decode_path(positions: int, width: int, query_rows: int, rank: int, dtype) -
     return "kernel" if inside else "plain"
 
 
-def _kernel(layer_ref, first_ref, q_ref, kt_ref, o_ref, m_ref, l_ref, acc_ref, *,
-            scale: float, block_k: int, heads: int, window: int, rank: int):
+def _kernel(layer_ref, first_ref, q_ref, kt_ref, *rest, scale: float, block_k: int, heads: int,
+            window: int, rank: int, selected: bool):
+    """``selected``: a fifth operand, the keys a row's ONE query attends (1, Tk) int32 (a
+    learned selection: `models/mla.select_mask`), in place of every key at or before it."""
     del layer_ref  # (the index map's)
+    seen_ref, (o_ref, m_ref, l_ref, acc_ref) = (rest[0], rest[1:]) if selected else (None, rest)
     row, j = pl.program_id(0), pl.program_id(1)
     first = first_ref[row]  # the first query's position
     length = first + window  # the positions the row's window attends
@@ -99,7 +107,8 @@ def _kernel(layer_ref, first_ref, q_ref, kt_ref, o_ref, m_ref, l_ref, acc_ref, *
         if masked:
             k_pos = start + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
             q_pos = first + jax.lax.broadcasted_iota(jnp.int32, (q.shape[0], 1), 0) // heads
-            scores = jnp.where(k_pos <= q_pos, scores, pallas_common.NEG_INF)
+            seen = k_pos <= q_pos if seen_ref is None else seen_ref[...] != 0
+            scores = jnp.where(seen, scores, pallas_common.NEG_INF)
             values = jnp.where(k_pos < length, values, jnp.zeros_like(values))
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
@@ -112,8 +121,10 @@ def _kernel(layer_ref, first_ref, q_ref, kt_ref, o_ref, m_ref, l_ref, acc_ref, *
             preferred_element_type=F32)
 
     # (block 0 holds position 0, which every query sees: the maximum is real from
-    # the first block on)
-    whole = start + block_k <= first + 1
+    # the first block on; under a selection a block may hold no key the query attends:
+    # its keys count 1 each against NEG_INF until the first attended key shrinks that to
+    # an exact 0, as `modeling.running_softmax` has it, and no block is taken whole)
+    whole = start < 0 if selected else start + block_k <= first + 1
     pl.when(whole)(functools.partial(accumulate, False))
     pl.when(jnp.logical_and(jnp.logical_not(whole), start < length))(
         functools.partial(accumulate, True))
@@ -123,10 +134,11 @@ def _kernel(layer_ref, first_ref, q_ref, kt_ref, o_ref, m_ref, l_ref, acc_ref, *
         o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
-def _attend(layer, first, q, stacked_t, *, scale: float, block_k: int, heads: int, rank: int,
-            interpret: bool):
+def _attend(layer, first, q, stacked_t, *seen, scale: float, block_k: int, heads: int,
+            rank: int, interpret: bool):
     """``q`` (B, s x n, r + dr), rows of a window query-major, against ``stacked_t``
-    (layers, rows, r + dr, positions); -> (B, s x n, r)."""
+    (layers, rows, r + dr, positions); -> (B, s x n, r). ``seen`` (none, or one (B, 1,
+    positions) int32): the keys a row's one query attends (`_kernel`)."""
     b, rows, width = q.shape
     positions = stacked_t.shape[3]
     blocks = positions // block_k
@@ -142,32 +154,39 @@ def _attend(layer, first, q, stacked_t, *, scale: float, block_k: int, heads: in
         in_specs=[
             pl.BlockSpec((None, rows, width), lambda row, j, *_: (row, 0, 0)),
             pl.BlockSpec((None, None, width, block_k), live_block),
-        ],
+        ] + [pl.BlockSpec((None, 1, block_k),
+                          lambda row, j, *refs: (row, 0, live_block(row, j, *refs)[3]))
+             for _ in seen],
         out_specs=pl.BlockSpec((None, rows, rank), lambda row, j, *_: (row, 0, 0)),
         scratch_shapes=[pltpu.VMEM((rows, 1), F32), pltpu.VMEM((rows, 1), F32),
                         pltpu.VMEM((rows, rank), F32)],
     )
     return pl.pallas_call(
         functools.partial(_kernel, scale=scale, block_k=block_k, heads=heads, window=window,
-                          rank=rank),
+                          rank=rank, selected=bool(seen)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, rows, rank), q.dtype),
         compiler_params=pallas_common.compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="mla_decode",
-    )(layer, first, q, stacked_t)
+    )(layer, first, q, stacked_t, *seen)
 
 
 def latent_attention(q_cat, stacked, layer: int, first, *, rank: int, scale: float,
-                     block_k: Optional[int] = None):
+                     block_k: Optional[int] = None, selected=None):
     """The context over the latent of the windows whose first queries stand at
     ``first`` (B,): ``q_cat`` (B, s, n, r + dr) against rows [0, B) of layer
     ``layer`` of ``stacked`` (layers, rows >= B, positions, r + dr) -> (B, s, n, r)
-    in ``q_cat``'s type. ``block_k`` (None: ``KEY_BLOCK``) divides the positions."""
+    in ``q_cat``'s type. ``block_k`` (None: ``KEY_BLOCK``) divides the positions.
+    ``selected`` (B, 1, positions) bool, windows of ONE query: the keys each attends, in
+    place of every key at or before it; a row is still read up to its own length only."""
     b, s, n, width = q_cat.shape
+    seen = () if selected is None else (selected.astype(jnp.int32),)
+    if seen and s != 1:
+        raise ValueError(f"a selection is one query's; the window has {s}")
     out = pallas_common.traced_once(
         _attend, jnp.full((1,), layer, jnp.int32), first.astype(jnp.int32),
-        q_cat.reshape(b, s * n, width), jnp.swapaxes(stacked, 2, 3), scale=float(scale),
+        q_cat.reshape(b, s * n, width), jnp.swapaxes(stacked, 2, 3), *seen, scale=float(scale),
         block_k=block_k or KEY_BLOCK, heads=n, rank=rank, interpret=pallas_common.use_interpret())
     return out.reshape(b, s, n, rank)

@@ -156,9 +156,12 @@ def qmatmul(x, qw: QuantTensor):
 _ATTN_KEYS = ("wqkv", "wo")
 _CROSS_KEYS = ("wq", "wkv", "wo")
 _MLP_KEYS = ("w13", "w1", "w2")
-#: a latent-attention layer's plain GEMMs (W_kvb is absorbed into the queries and
-#: the context a head at a time: it stays fp)
-_MLA_KEYS = ("wq", "wkva", "wo")
+#: a latent-attention layer's plain GEMMs, the headwise gate's among them (W_kvb is
+#: absorbed into the queries and the context a head at a time: it stays fp)
+_MLA_KEYS = ("wq", "wqa", "wqb", "wkva", "wo", "wgate")
+#: the indexer's three projections of such a layer (``p["index"]``): GEMMs like the others,
+#: and the ones a selection hangs on
+_MLA_INDEX_KEYS = ("wq", "wk", "ww")
 
 
 def quantize_params(params: Dict[str, Any], cfg) -> Dict[str, Any]:
@@ -176,6 +179,10 @@ def quantize_params(params: Dict[str, Any], cfg) -> Dict[str, Any]:
                 for k in keys:
                     if k in gp and not isinstance(gp[k], QuantTensor):
                         gp[k] = quantize_int8(gp[k])
+                if group == "mla" and "index" in gp:
+                    gp["index"] = {k: quantize_int8(v) if k in _MLA_INDEX_KEYS
+                                   and not isinstance(v, QuantTensor) else v
+                                   for k, v in gp["index"].items()}
                 lp[group] = gp
         # (a dense MLP: every layer of a dense model, the leading layers of an expert
         # model; the experts' stacks go through the grouped GEMMs and stay fp)

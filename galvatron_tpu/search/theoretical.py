@@ -134,7 +134,9 @@ def total_param_count(cfg: ModelConfig) -> int:
             layer_param_count(vision_layer_cfg(cfg, i)) for i in range(cfg.num_layers)
         )
         return layers + other_param_count(cfg)
-    return sum(layer_param_count(cfg, kind=k) for k in cfg.kinds) + other_param_count(cfg)
+    # (a layer under its own view: a latent stack's window layers have sizes of their own)
+    return sum(layer_param_count(cfg.layer_view(i), kind=k)
+               for i, k in enumerate(cfg.kinds)) + other_param_count(cfg)
 
 
 def layer_states_mb(

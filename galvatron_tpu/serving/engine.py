@@ -716,13 +716,23 @@ class Engine:
         slots = self.slots.active_slots() if slots is None else slots
         # (on the loop's thread between two spans: no array is built here)
         live = [int(lengths[s]) for s in slots]
-        per_position = (layout["bytes_per_position_per_layer"] if "full_layers" in layout
-                        else layout["bytes_per_position"])
-        out = {f"{layout['kind']}_cache_bytes_per_position": per_position,
-               f"{layout['kind']}_live_positions": sum(live)}
-        read = generation.cache_read_positions(
-            self.cfg, [n + window - 1 for n in live], self.slots.num_slots,
-            self.slots.max_seq_len, window, ring=layout.get("ring_positions"))
+        if layout.get("latent_stacks"):
+            # a latent stack of more than one width (index keys, a ring): its own
+            # counters by stack, under names no reader of a dense latent or of K and V
+            # takes for its own (``latent_live_positions`` x a dense latent's bytes would
+            # price a sparse layer above what any program need fetch)
+            out = mixers.module(mixers.cache_kind(self.cfg)).step_counters(
+                layout, [n + window - 1 for n in live], self.slots.num_slots,
+                self.slots.max_seq_len, window)
+            read = None
+        else:
+            per_position = (layout["bytes_per_position_per_layer"] if "full_layers" in layout
+                            else layout["bytes_per_position"])
+            out = {f"{layout['kind']}_cache_bytes_per_position": per_position,
+                   f"{layout['kind']}_live_positions": sum(live)}
+            read = generation.cache_read_positions(
+                self.cfg, [n + window - 1 for n in live], self.slots.num_slots,
+                self.slots.max_seq_len, window, ring=layout.get("ring_positions"))
         if isinstance(read, dict):
             # stacks by what a layer keeps: what a layer of each holds live for the rows
             # (a window layer the last ``window`` positions) and fetches, and how many

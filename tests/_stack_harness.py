@@ -282,6 +282,18 @@ def engine(cfg, params, **kw):
     return Engine(params, cfg, **args)
 
 
+def first_request_ids(monkeypatch):
+    """Request ids from 0 until the test ends. An engine's draws are a function of (seed,
+    request id, token index) and the ids a counter of the process, so a test that holds a
+    nearly greedy draw to the arg-max hangs, without this, on how many requests the worker's
+    earlier tests submitted."""
+    import itertools
+
+    from galvatron_tpu.serving import scheduler
+
+    monkeypatch.setattr(scheduler, "_rid", itertools.count())
+
+
 def serve(eng, prompts, max_new_tokens, traced=False):
     """``eng.generate`` of ``prompts``, then the engine closed -> (the served rows, its
     ``stats()``, and, ``traced``, what the complete spans the tracer kept meanwhile carry,

@@ -59,8 +59,45 @@ _PINNED_TO_THE_TAIL_BY_PR_56 = frozenset(
     ["tests/benchmark/test_benchmark_search_terms.py::test_the_five_sit_at_the_tail_of_the_manifest"])
 
 
+#: Eleven accepted cases of tests/benchmark hold that NO reader that moves
+#: ``serve_tokens_per_s_per_chip`` carries a ``workloads`` list (PR 61's review took such
+#: lists out).  The driver's rule now asks for them: a reader that finds nothing to read in a
+#: decode-heavy cell's profile (the three prompt-chunk readers: a profile of 50 decode
+#: iterations often holds no prompt chunk) carries the list of the ACCEPTED cells that report
+#: the rate, and PR 65's cell is not asked for it.  Only a PR of kind ``benchmark`` may edit
+#: those files (ISSUE 65 asked for the edit; the driver's rule on files under ``paths``
+#: decides).  They are expected to fail, strictly, until such a PR admits exactly the three
+#: by name and deletes this list.  NOTHING else they assert is off meanwhile: each of the
+#: eleven bodies stands whole, with that one clause amended, in
+#: tests/benchmark/test_benchmark_chunk_lists.py, whose last case holds this list and its
+#: copies one for one (PERF.md section 7).
+_NO_SERVING_READER_HAD_A_LIST_BEFORE_PR_65 = frozenset(
+    ["tests/benchmark/test_benchmark_manifest.py::test_metrics",
+     "tests/benchmark/test_benchmark_lfm2.py::test_metric_is_declared_as_a_serving_reader"
+     "[shortconv_prefill_chunk_ms]",
+     "tests/benchmark/test_benchmark_lfm2.py::test_the_cell_joins_the_manifest_by_appends",
+     "tests/benchmark/test_benchmark_smallthinker.py::test_metric_is_declared_as_a_serving_reader"
+     "[kv_prefill_chunk_attn_ms]",
+     "tests/benchmark/test_benchmark_smallthinker.py::"
+     "test_the_latent_readers_are_still_declared_as_serving_readers[mla_prefill_chunk_attn_ms]",
+     "tests/benchmark/test_benchmark_smallthinker.py::"
+     "test_the_latent_cell_still_reads_the_rate_and_every_serving_reader",
+     "tests/benchmark/test_benchmark_trinity.py::test_the_cell_joins_the_manifest_by_appends",
+     "tests/benchmark/test_benchmark_trinity.py::"
+     "test_the_older_serving_cells_stand_in_the_manifest_where_they_were"]
+    + ["tests/benchmark/test_benchmark_trinity.py::"
+       "test_a_profile_without_a_prompt_chunk_leaves_the_chunk_readers_silent[%s]" % name
+       for name in ("mla_prefill_chunk_attn_ms", "kv_prefill_chunk_attn_ms",
+                    "shortconv_prefill_chunk_ms")])
+
+
 def pytest_collection_modifyitems(items):
     for item in items:
+        if item.nodeid in _NO_SERVING_READER_HAD_A_LIST_BEFORE_PR_65:
+            item.add_marker(pytest.mark.xfail(
+                strict=True, raises=AssertionError,
+                reason="pins 'no serving reader has a list'; the driver's rule now asks the "
+                       "three prompt-chunk readers for the accepted cells' (PR 65)"))
         if item.nodeid in _PINNED_TO_THE_TAIL_BY_PR_51:
             item.add_marker(pytest.mark.xfail(
                 strict=True, raises=AssertionError,

@@ -467,6 +467,50 @@ def test_a_gated_sandwich_stacks_serving_program_carries_the_scope(gated_texts, 
     assert any(re.search(rf"[/(]{scope}(?:[/)]|$)", n) for n in names), (program, scope)
 
 
+#: a latent stack with an indexer and a latent ring (PR 65): ``indexer`` and ``select``
+#: beside ``attn_core`` under ``full``, the headwise ``gate``, the ring's ``cache_write``
+#: under ``window`` (the names ``benchmark/metrics/_dsa.py`` and ``_swa.py`` key on)
+DSA_SCOPES = ("layer_0/attn/full/qkv_proj", "layer_1/attn/full/indexer",
+              "layer_1/attn/full/select", "layer_1/attn/full/attn_core",
+              "layer_1/attn/full/gate", "layer_1/attn/full/cache_write",
+              "layer_2/attn/window/cache_write", "layer_2/attn/window/attn_core",
+              "layer_4/attn/window/gate", "layer_4/attn/window/out_proj")
+
+
+def _dsa_cfg():
+    from galvatron_tpu.models.modeling import PRESETS
+
+    return PRESETS["dots3-note-prev"].replace(
+        vocab_size=128, hidden_size=32, num_layers=5, num_heads=4, attn_head_dim=12, ffn_dim=48,
+        max_seq_len=32, mla_kv_rank=16, mla_nope_dim=8, mla_rope_dim=4, mla_v_dim=8,
+        mla_q_rank=24, mla_index_heads=4, mla_index_dim=8, mla_index_topk=8,
+        sliding_window_size=8, swa_num_heads=2, swa_nope_dim=12, swa_rope_dim=4, swa_v_dim=8,
+        swa_kv_rank=24, swa_q_rank=20, moe_experts=8, moe_top_k=2, moe_ffn_dim=24,
+        moe_shared_ffn_dim=24)
+
+
+@pytest.fixture(scope="module")
+def dsa_texts():
+    from galvatron_tpu.aot import registry
+    from galvatron_tpu.serving import engine  # noqa: F401  (registers the serving family)
+
+    ctx = registry.ProgramContext(cfg=_dsa_cfg(), num_slots=2, prefill_chunk=8, max_seq_len=32)
+    return {spec.name: spec.fn.lower(*spec.args).compile().as_text()
+            for spec in registry.enumerate_programs(ctx, include=("serving",))}
+
+
+@pytest.mark.parametrize("scope", DSA_SCOPES)
+@pytest.mark.parametrize("program", SERVING_PROGRAMS[:2])
+def test_a_sparse_latent_stacks_serving_program_carries_the_scope(dsa_texts, program, scope):
+    import re
+
+    names = re.findall(r'op_name="([^"]*)"', dsa_texts[program])
+    # (the scope's parts in order: a prompt chunk's ``indexer`` scores and ``select`` lie in a
+    # branch of a ``cond``, whose own words stand between ``full`` and theirs)
+    pattern = "[/(]" + "/(?:[^/]+/)*?".join(map(re.escape, scope.split("/"))) + "(?:[/)]|$)"
+    assert any(re.search(pattern, n) for n in names), (program, scope)
+
+
 def test_the_training_forward_opens_the_gate_and_the_post_norms(serving_texts):
     import re
 

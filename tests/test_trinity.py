@@ -342,13 +342,17 @@ def test_the_decode_kernel_takes_the_cells_shapes():
 # -- the engine ---------------------------------------------------------------------------
 
 
-def test_the_engines_tap_rows_are_the_references_and_its_spans_count_the_touched_experts():
+def test_the_engines_tap_rows_are_the_references_and_its_spans_count_the_touched_experts(monkeypatch):
     """Three requests through the engine (prompts past and inside the window, answers
     that lap the ring), every token's logits row kept by the tap: the rows equal the
     reference's full forward over prompt + served tokens; the ``decode`` spans carry the
     held experts a step touched."""
     from galvatron_tpu.obs.tracing import tracer
 
+    # (a draw is a function of (seed, request id, token index), the ids a counter of the
+    # PROCESS: at 1e-4 the row whose two best lie 7e-4 apart draws the second at about one
+    # id in 800, so the ids start at 0 here, whatever the worker served before)
+    harness.first_request_ids(monkeypatch)
     cfg = small_cfg(moe_share=(1, 2))
     params, rows = seeded(small_cfg(), batch=3, length=30)
     params = held_by(params, cfg, (1, 2))
