@@ -19,6 +19,12 @@ request's rows and tokens printed.
 Run it in a ``git archive`` of the parent (this file laid over it) and in the change,
 in one call: equal digests = the same float32 rows, bit for bit.  One JSON line a
 request and one for the run; needs the chip the cell needs (no CPU fallback).
+
+Where a change means to move the rows by a rounding (another kernel for the same
+mathematics), ``--rows PREFIX`` says how far: the first run keeps each request's rows and
+tokens under ``PREFIX.<i>.npz``, a later run with the same ``PREFIX`` compares its own with
+them (``vs_kept``: the tokens up to the first that differs, and over those rows the largest
+difference of a logit and the mean KL of the two softmaxes).
 """
 
 from __future__ import annotations
@@ -35,6 +41,31 @@ sys.path.insert(0, ROOT)
 import numpy as np  # noqa: E402
 
 
+def _kept(path, rows, tokens) -> dict:
+    """Keep ``rows`` and ``tokens`` at ``path``, or, where an earlier run kept its own
+    there, how far these are from them: over the rows up to the first token that differs
+    (after it the two requests attend other positions)."""
+    if not os.path.exists(path):
+        np.savez(path, rows=rows, tokens=np.asarray(tokens, np.int64))
+        return {"kept": path}
+    with np.load(path) as other:
+        theirs, their_tokens = other["rows"], other["tokens"].tolist()
+    same = next((j for j, (a, b) in enumerate(zip(tokens, their_tokens)) if a != b),
+                min(len(tokens), len(their_tokens)))
+    n = min(same + 1, len(rows), len(theirs))  # (the row the differing token was drawn from too)
+
+    def log_softmax(x):
+        x = x.astype(np.float64)
+        x = x - x.max(-1, keepdims=True)
+        return x - np.log(np.exp(x).sum(-1, keepdims=True))
+
+    ours, kept = log_softmax(rows[:n]), log_softmax(theirs[:n])
+    kl = float((np.exp(kept) * (kept - ours)).sum(-1).mean())
+    return {"vs_kept": {"same_tokens": same, "of": len(their_tokens), "rows": n,
+                        "max_abs_logit_diff": float(np.abs(rows[:n] - theirs[:n]).max()),
+                        "mean_kl": kl}}
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", required=True)
@@ -42,6 +73,8 @@ def main(argv=None) -> int:
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--prompt", default="1400,2600", help="shortest,longest prompt")
     ap.add_argument("--new", type=int, default=64, help="tokens served a request")
+    ap.add_argument("--rows", default=None, help="keep the rows under this prefix, or compare "
+                    "with the rows an earlier run kept there")
     args = ap.parse_args(argv)
 
     import jax
@@ -68,10 +101,13 @@ def main(argv=None) -> int:
             rows = hashlib.sha256(np.ascontiguousarray(buf[:req.logits_rows]).tobytes())
             rows.update(np.asarray(tokens, np.int64).tobytes())
             whole.update(rows.digest())
-            print(json.dumps({"request": i, "prompt": len(prompts[i]), "tokens": len(tokens),
-                              "rows": int(req.logits_rows), "finite": bool(np.isfinite(buf).all()),
-                              "greedy": [int(np.argmax(r)) for r in buf[:len(tokens)]] == tokens,
-                              "sha256": rows.hexdigest()}), flush=True)
+            line = {"request": i, "prompt": len(prompts[i]), "tokens": len(tokens),
+                    "rows": int(req.logits_rows), "finite": bool(np.isfinite(buf).all()),
+                    "greedy": [int(np.argmax(r)) for r in buf[:len(tokens)]] == tokens,
+                    "sha256": rows.hexdigest()}
+            if args.rows:
+                line.update(_kept(f"{args.rows}.{i}.npz", buf[:req.logits_rows], tokens))
+            print(json.dumps(line), flush=True)
     finally:
         engine.close()
     print(json.dumps({"workload": args.workload, "seed": args.seed, "sha256": whole.hexdigest(),

@@ -47,7 +47,7 @@ import numpy as np
 
 from galvatron_tpu.models import mixers, modeling
 from galvatron_tpu.models.modeling import ModelConfig, Params
-from galvatron_tpu.ops import kv_decode
+from galvatron_tpu.ops import kv_decode, kv_prefill
 
 
 class KVCache(NamedTuple):
@@ -86,8 +86,9 @@ class SlotStacks(NamedTuple):
     slab with the POSITIONS on the lanes (``{3,4,2,1,0:T(8,128)(2,1)}``: K-transposed,
     (64, 16384)); a head of whole lane tiles (128) it keeps as written
     (``{4,3,2,1,0}``). The logical shape is the same either way, and so are the writers
-    and the prompt chunk; the decode kernel reads each where it lies (`ops/kv_decode`:
-    a head of 64 through ``swapaxes(stack, 3, 4)``, a bitcast on the chip)."""
+    and the plain bodies; the decode kernel and the chunk kernel read each where it lies
+    (`ops/kv_decode`, `ops/kv_prefill`: a head of 64 through ``swapaxes(stack, 3, 4)``, a
+    bitcast on the chip)."""
 
     k: jax.Array
     v: jax.Array
@@ -241,16 +242,27 @@ def cache_read_positions(cfg: ModelConfig, lengths, rows: int, positions: int, w
     return mixers.module(kind).cache_read_positions(cfg, lengths, rows, positions, window)
 
 
-def chunk_layout(cfg: ModelConfig, rows: int, positions: int) -> dict:
+def chunk_layout(cfg: ModelConfig, rows: int, positions: int, ring: Optional[int] = None) -> dict:
     """What joins `cache_layout` once an engine knows its prompt chunk (``rows``) and
-    its slots (``positions``): of a kind with a cache of its own, which body its chunk
-    attention takes (``chunk_path``) and the keys a block of it fetches
-    (``chunk_key_block``), the kind's own answer from the shapes; nothing for K and V
-    slots, whose chunk attention is `generation`'s own."""
+    its slots (``positions``): which body its chunk attention takes (``chunk_path``)
+    and the keys a block of it fetches (``chunk_key_block``), from the shapes. A kind
+    with a cache of its own gives its own answer. A `stacked` stack asks
+    `kv_prefill.chunk_path` of each stack it has, as `_windowed_attention` does
+    (``"kernel"`` | ``"plain"``, ``"mixed"`` where its slots and its ring of ``ring``
+    places, `ring_positions` of the chunk if None, disagree); nothing for a plain
+    `KVCache`, whose chunk attention is ``modeling.attention_xla``."""
     kind = mixers.cache_kind(cfg)
-    if kind is None:
+    if kind is not None:
+        return mixers.module(kind).chunk_layout(cfg, rows, positions)
+    if not stacked(cfg):
         return {}
-    return mixers.module(kind).chunk_layout(cfg, rows, positions)
+    layers = stack_layers(cfg)
+    places = ([positions] * bool(layers["full"])
+              + [ring or ring_positions(cfg, positions, rows)] * bool(layers["window"]))
+    paths = {kv_prefill.chunk_path(n, cfg.head_dim, rows, cfg.dtype) for n in places}
+    path = paths.pop() if len(paths) == 1 else "mixed"
+    block = kv_prefill.KEY_BLOCK if path == "kernel" else modeling.key_block(positions, KEY_BLOCK)
+    return {"chunk_path": path, "chunk_key_block": block}
 
 
 def _positions(offsets, s: int):
@@ -457,7 +469,8 @@ def _attend_chunk(qg, ks, vs, layer: int, slot, q_pos, key_positions, blocks, bl
     keys at a time with a running softmax, so that no more than (heads, s, block)
     float32 scores live at once (28 x 1,024 x 16,384 x 4 B = 1.9 GB a full layer
     otherwise). ``key_positions(places)``: the absolute positions the places hold;
-    ``blocks`` may be traced (a full layer stops at the chunk's end)."""
+    ``blocks`` may be traced (a full layer stops at the chunk's end). The plain body,
+    outside `kv_prefill.chunk_path`'s rule, and the kernel's reference."""
     b, s, kv, g, d = qg.shape
     shape = (1, 1, kv, block, d)
 
@@ -479,7 +492,9 @@ def _windowed_attention(x, p, cfg: ModelConfig, cache: SlotStacks, windowed: boo
     ``windowed`` says which stack the layer's keys and values live in (the ring, or
     whole rows), ``index`` where in it; ``cfg`` is the layer's view. Both forms fetch
     a key block only if the row holds a position in it: a prompt chunk (``slot``)
-    loops over the blocks up to its end (`chunk_key_blocks`); rows' windows go
+    takes the blocks up to its end (`chunk_key_blocks`), through the kernel `kv_chunk`
+    where `kv_prefill.chunk_path` says so of the layer's stack, else in a loop
+    (`_attend_chunk`); rows' windows go
     through the kernel `kv_decode`, bounded a row by the row's length, where
     `kv_decode.decode_path` says so of the layer's stack, else over every slot's
     capacity (`_attend_rows`). A layer with an output gate (``cfg.attn_gate``)
@@ -509,7 +524,11 @@ def _windowed_attention(x, p, cfg: ModelConfig, cache: SlotStacks, windowed: boo
             else:
                 def key_positions(places):
                     return places[None]
-            if slot is not None:
+            if slot is not None and kv_prefill.chunk_path(positions, d, s, ks.dtype) == "kernel":
+                # the same key blocks as the plain body's, their scores kept on the chip
+                o = kv_prefill.attend_chunk(qg, ks, vs, index, slot, offsets, scale=scale,
+                                            span=cfg.attn_window)
+            elif slot is not None:
                 block, whole, live = chunk_key_blocks(positions, offsets + s)
                 o = _attend_chunk(qg, ks, vs, index, slot, q_pos, key_positions,
                                   jnp.minimum(whole, live), block, cfg.attn_window, scale)

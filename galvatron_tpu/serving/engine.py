@@ -231,7 +231,8 @@ def _paged_decode_verify(params, cfg: ModelConfig, pool: KVCache, tokens,
 #: not yet on the host, ``row_steps_wasted`` the row-steps dispatched for a row
 #: that the bookkeeping, one iteration late, then retired (`Engine._step_ahead`)
 _COUNTERS = (
-    "steps", "prefill_chunks", "latent_chunks_kernel", "prefill_tokens", "tokens_generated",
+    "steps", "prefill_chunks", "latent_chunks_kernel", "kv_chunks_kernel", "prefill_tokens",
+    "tokens_generated",
     "engine_restarts", "draft_proposed", "draft_accepted",
     "spec_steps", "spec_fallbacks", "draws_device", "draws_host",
     "steps_ahead", "row_steps_wasted",
@@ -387,10 +388,11 @@ class Engine:
                 f"chunks: max_seq_len {self.slots.max_seq_len} is no multiple of prefill_chunk "
                 f"{self.prefill_chunk} (a chunk then starts at a multiple of the chunk, "
                 "never crosses the ring's end and never runs a position twice through a state)")
-        # of a cache of a kind of its own: which body a prompt chunk's attention takes
-        # and the keys a block of it fetches (fixed by the shapes: asked once)
+        # of a latent cache or a `stacked` stack's K and V: which body a prompt chunk's
+        # attention takes and the keys a block of it fetches (fixed by the shapes: asked once)
         self.cache_layout.update(
-            generation.chunk_layout(cfg, self.prefill_chunk, self.slots.max_seq_len))
+            generation.chunk_layout(cfg, self.prefill_chunk, self.slots.max_seq_len,
+                                    self.cache_layout.get("ring_positions")))
         # which body each kind's layers take, of the kinds the stack has (static: asked once)
         self._layer_paths = {k: v for k, v in mixers.path_counts(cfg).items() if any(v.values())}
         self.scheduler = Scheduler(max_queue=max_queue, default_ttl_s=request_ttl_s)
@@ -622,7 +624,8 @@ class Engine:
                 # the body every prompt chunk's attention takes, and how many took the
                 # kernel (over ``prefill_chunks``: 1.0 or 0.0)
                 extra["chunk_path"] = self.cache_layout["chunk_path"]
-                extra["latent_chunks_kernel"] = ec["latent_chunks_kernel"]
+                taken = self.cache_layout["kind"] + "_chunks_kernel"
+                extra[taken] = ec[taken]
         return {
             "kv_backend": "paged" if self.paged else "slot",
             # the replica's numerics contract rides /healthz: the fleet
@@ -1120,8 +1123,9 @@ class Engine:
         router: Dict[str, jax.Array] = {}  # the last chunk's `_router_counters`
         if self.cfg.moe_dropless:
             span.set(moe_row_tile=moe.layer_row_tile(self.cfg, c))
-        key_block = self.cache_layout.get("chunk_key_block")  # None for K and V slots
+        key_block = self.cache_layout.get("chunk_key_block")  # None for a plain `KVCache`
         kernel = self.cache_layout.get("chunk_path") == "kernel"
+        kind = self.cache_layout["kind"]  # "latent" | "kv": whose chunk kernel it is
         key_blocks = 0
         ring = self.cache_layout.get("ring_positions")  # a windowed stack's, else None
         ring_wraps = ring_blocks_read = ring_blocks = 0
@@ -1175,12 +1179,13 @@ class Engine:
                          kv_window_chunk_blocks=ring_blocks)
             if key_block:
                 # the chunks the chunk kernel took and the key blocks a layer's attention
-                # fetched for them so far (host arithmetic from the start: no array is
-                # built and nobody waits for the device)
+                # over whole slots fetched for them so far (host arithmetic from the start:
+                # no array is built and nobody waits for the device)
                 key_blocks += -(-(start + c) // key_block)
                 if kernel:
-                    self.counters.inc("latent_chunks_kernel")
-                span.set(latent_chunks_kernel=(i + 1) * kernel, latent_chunk_key_blocks=key_blocks)
+                    self.counters.inc(f"{kind}_chunks_kernel")
+                span.set(**{f"{kind}_chunks_kernel": (i + 1) * kernel,
+                            f"{kind}_chunk_key_blocks": key_blocks})
         self.slots.lengths[slot] = len(toks)
         if self.paged:
             # publish the prompt's full blocks while the request decodes, so

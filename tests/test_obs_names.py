@@ -52,6 +52,10 @@ KERNEL_NAMES = {
     # (read through `full` | `window` > `attn_core`: `full_attn_ms_per_step`,
     # `window_attn_ms_per_step`, `kv_decode_attn_roofline`)
     "kv_decode": "kv_decode.py",
+    # PR 66: a prompt chunk over the same stacks, its scores kept on the chip (read by
+    # `kv_prefill_chunk_attn_ms` under the PREFILL program's `full` | `window` >
+    # `attn_core`; the decode readers take the decode program's operations alone)
+    "kv_chunk": "kv_prefill.py",
 }
 
 

@@ -1331,6 +1331,110 @@ def test_mlp_recompute_buffer_accounting_tp2_zero3_sp(topo, real_mosaic):
     assert temps["policy"] <= temps["off"] * 0.95, temps
 
 
+def _kv_kernel_calls(text, kernel):
+    """The ENTRY's custom calls of the Pallas kernel ``kernel`` (`kv_decode` | `kv_chunk`)."""
+    import re
+
+    return [line for line in _entry_lines(text)
+            if "custom-call(" in line and re.search(rf"/{kernel}/pallas_call", line)]
+
+
+def _kv_program_kernels(text, name):
+    """The custom calls of the kernel a K/V stack's serving program ``name`` attends through
+    (`kv_decode` a decode step, `kv_chunk` a prompt chunk); the other's has none."""
+    kernel, other = (("kv_decode", "kv_chunk") if name == "serving_decode"
+                     else ("kv_chunk", "kv_decode"))
+    assert not _kv_kernel_calls(text, other)
+    return _kv_kernel_calls(text, kernel)
+
+
+def _handed_as_bitcasts(call):
+    """The last two operands of a kernel's custom call (its K and V stacks) are bitcasts."""
+    import re
+
+    handed = re.search(r"custom-call\(([^)]*)\)", call).group(1).split(", ")[-2:]
+    assert all(re.match(r"(/\*index=\d+\*/)?%bitcast", h) for h in handed), handed
+
+
+@pytest.mark.parametrize("dtype,rows,positions,span,heads", [
+    (jnp.float32, 1024, 16384, 0, (4, 7, 128)), (jnp.float32, 1024, 5120, 4096, (4, 7, 128)),
+    (jnp.float32, 1024, 16384, 0, (8, 4, 64)), (jnp.bfloat16, 512, 5120, 4096, (8, 6, 128)),
+    (jnp.bfloat16, 16, 16384, 0, (8, 4, 64)), (jnp.bfloat16, 1024, 5120, 4096, (8, 4, 64))],
+    ids=["f32_rows", "f32_ring", "d64_f32_rows", "bf16_half_a_chunk_ring", "d64_bf16_a_tile_of_rows",
+         "d64_bf16_ring"])
+def test_kv_chunk_takes_what_its_rule_lets_in(one_chip, real_mosaic, dtype, rows, positions, span,
+                                              heads):
+    """What the benchmark's cells do not run but `kv_prefill.chunk_path` lets in, at their
+    widths (32 slots; smallthinker's 4 key/value heads of 128 under 7 grouped query heads,
+    trinity's 8 under 6, lfm2's 8 of 64 under 4): float32, a chunk of 512 rows and of one
+    tile of rows, a ring at a head of 64: Mosaic takes each. A head of 64 is read
+    TRANSPOSED, which is how the chip keeps such a stack (``{3,4,2,1,0}``): the kernel's
+    operands are bitcasts of the stacks, nothing of a stack's size is copied."""
+    import re
+
+    from galvatron_tpu.ops import kv_prefill
+
+    kv, g, d = heads
+    assert kv_prefill.chunk_path(positions, d, rows, dtype) == "kernel"
+    stack = jax.ShapeDtypeStruct((4, 32, kv, positions, d), dtype, sharding=one_chip)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(lambda q, k, v, slot, offset: kv_prefill.attend_chunk(
+        q, k, v, 2, slot, offset, scale=d ** -0.5, span=span)).lower(
+        jax.ShapeDtypeStruct((1, rows, kv, g, d), dtype, sharding=one_chip), stack, stack,
+        scalar, scalar).compile()
+    text = compiled.as_text()
+    call, = _kv_kernel_calls(text, "kv_chunk")
+    assert "tpu_custom_call" in call
+    assert not _moved_slabs(text, 32 * kv * positions * d)
+    word = "bf16" if dtype == jnp.bfloat16 else "f32"
+    if d % 128:
+        kept = re.escape(f"{word}[4,32,{kv},{positions},{d}]") + r"\{3,4,2,1,0:"
+        assert len(re.findall(rf"= {kept}\S* parameter\(", text)) == 2
+        assert call.count(f"{word}[4,32,{kv},{d},{positions}]{{4,3,2,1,0}}") == 2, call
+        _handed_as_bitcasts(call)
+    else:
+        assert call.count(f"{word}[4,32,{kv},{positions},{d}]{{4,3,2,1,0}}") == 2, call
+
+
+def test_trinity_prefill_chunk_keeps_its_scores_on_the_chip(one_chip, real_mosaic):
+    """`_prefill_chunk` of `trinity-large-preview_serve_agent_above_knee` (5 layers, 32
+    slots: 1 full layer x 16,384 positions and 4 window layers x a ring of 5,120, K and V
+    of 8 heads of 128 under 6 grouped query heads, an output gate and q/k norms outside
+    ``attn_core``) as the chip's compiler sees it: one `kv_chunk` custom call under
+    ``window`` | ``full`` > ``attn_core`` of each layer, handed both stacks whole where
+    they lie; no float32 score block of 48 x 1,024 x 1,024, no loop over key blocks under
+    ``attn``, nothing as large as a ring layer's slab copied; the program fits the chip."""
+    import re
+
+    from galvatron_tpu.models.modeling import PRESETS
+
+    cfg = PRESETS["trinity-large-preview"].replace(
+        num_layers=5, moe_dense_layers=1, vocab_size=25024, moe_share=(0, 8), max_seq_len=16384,
+        param_dtype=jnp.bfloat16, dtype=jnp.bfloat16)
+    compiled = _lowered_serving_program(cfg, "serving_prefill", one_chip, num_slots=32,
+                                        prefill_chunk=1024, max_seq_len=16384).compile()
+    text = compiled.as_text()
+    kernels = _kv_kernel_calls(text, "kv_chunk")
+    under = sorted(re.search(r"/layer_(\d+)/attn/(\w+)/attn_core", line).groups() for line in kernels)
+    assert under == sorted((str(i), "window" if windowed else "full")
+                           for i, windowed in enumerate(cfg.window_layers))
+    for line in kernels:
+        stack = ("bf16[4,32,8,5120,128]{4,3,2,1,0}" if "/window/" in line
+                 else "bf16[1,32,8,16384,128]{4,3,2,1,0}")
+        assert line.count(stack) >= 2, line
+    assert not _kv_kernel_calls(text, "kv_decode")
+    assert not re.search(r"f32\[(1,)?8,6,1024,1024\]", text)
+    assert not any(" while(" in line and "/attn/" in line for line in text.splitlines())
+    moved = _moved_slabs(text, 32 * 5120 * 8 * 128)
+    assert not moved, moved[:4]
+    ma = compiled.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes - ma.alias_size_in_bytes
+             + ma.temp_size_in_bytes)
+    print(f"serving_prefill: arguments {ma.argument_size_in_bytes / 2**30:.3f} GiB, temporaries "
+          f"{ma.temp_size_in_bytes / 2**30:.3f} GiB, in all {total / 2**30:.3f} GiB")
+    assert total < HBM_V5E_GIB * 2**30, f"{total / 2**30:.2f} GiB"
+
+
 @pytest.mark.parametrize("dtype,s,positions,span,heads", [
     (jnp.bfloat16, 5, 16384, 0, (4, 7, 128)), (jnp.bfloat16, 5, 5120, 4096, (4, 7, 128)),
     (jnp.float32, 18, 16384, 0, (4, 7, 128)), (jnp.float32, 1, 5120, 4096, (4, 7, 128)),
@@ -1368,8 +1472,7 @@ def test_kv_decode_takes_what_its_rule_lets_in(one_chip, real_mosaic, dtype, s, 
         assert len(re.findall(rf"= {kept}\S* parameter\(", text)) == 2
         call, = [line for line in _entry_lines(text) if "custom-call(" in line and "kv_decode" in line]
         assert call.count(f"{word}[4,32,{kv},{d},{positions}]{{4,3,2,1,0}}") == 2, call
-        handed = re.search(r"custom-call\(([^)]*)\)", call).group(1).split(", ")[-2:]
-        assert all(name.startswith("%bitcast") for name in handed), handed
+        _handed_as_bitcasts(call)
 
 
 @pytest.mark.parametrize("name", ["serving_decode", "serving_prefill"])
@@ -1387,7 +1490,8 @@ def test_smallthinker_serving_programs_fit_one_chip_and_write_the_cache_in_place
     step attends through the kernel `kv_decode` (one custom call under ``full`` |
     ``window`` > ``attn_core`` of each of the 16, handed its K and V stacks whole
     where they lie: no float32 score of 32 x 28 x 16,384 or x 5,120 is left), every
-    layer of a prompt chunk through the plain body."""
+    layer of a prompt chunk through the kernel `kv_chunk` the same way (PR 66: no
+    float32 score block of 28 x 1,024 x 1,024, no loop over key blocks under ``attn``)."""
     import re
 
     from galvatron_tpu.models.modeling import PRESETS
@@ -1403,22 +1507,24 @@ def test_smallthinker_serving_programs_fit_one_chip_and_write_the_cache_in_place
         stack = "window" if windowed else "full"
         assert any(f"/layer_{i}/attn/{stack}/attn_core" in n for n in names), (i, stack)
         assert any(f"/layer_{i}/attn/{stack}/cache_write" in n for n in names), (i, stack)
-    kernels = [line for line in _entry_lines(text) if "custom-call(" in line and "kv_decode" in line]
+    kernels = _kv_program_kernels(text, name)
     under = sorted(re.search(r"/layer_(\d+)/attn/(\w+)/attn_core", line).groups() for line in kernels)
     stacks = [(str(i), "window" if windowed else "full") for i, windowed in enumerate(cfg.window_layers)]
-    assert under == (sorted(stacks) if name == "serving_decode" else [])
+    assert under == sorted(stacks)
     for line in kernels:  # the stacks as they lie, not a layer's slab cut out
         stack = ("bf16[12,32,4,5120,128]{4,3,2,1,0}" if "/window/" in line
                  else "bf16[4,32,4,16384,128]{4,3,2,1,0}")
         assert line.count(stack) >= 2, line
     if name == "serving_decode":
         assert not re.search(r"f32\[32,4,7,(1,)?(16384|5120)\]", text)
+    else:
+        assert not re.search(r"f32\[(1,)?4,7,1024,1024\]", text)
+        assert not any(" while(" in line and "/attn/" in line for line in text.splitlines())
     ma = compiled.memory_analysis()
     cache = 2 * 32 * 4 * 128 * 2 * (4 * 16384 + 12 * 5120)
     assert cache == 8_321_499_136 and ma.alias_size_in_bytes >= cache
-    # (a prompt chunk's loops over key blocks carry the stacks they read: no copy)
     ring_slab = 32 * 5120 * 4 * 128
-    moved = [(op, shape) for op, shape in _moved_slabs(text, ring_slab) if op != "while"]
+    moved = _moved_slabs(text, ring_slab)
     assert not moved, moved[:4]
     total = (ma.argument_size_in_bytes + ma.output_size_in_bytes - ma.alias_size_in_bytes
              + ma.temp_size_in_bytes)
@@ -1442,7 +1548,8 @@ def test_lfm2_serving_programs_fit_one_chip_and_copy_neither_stack(one_chip, rea
     through the kernel `kv_decode` reading that in place: one custom call under ``full`` >
     ``attn_core`` of each of the 5, handed both stacks whole as the bitcasts
     (5, 32, 8, 64, 16384), and no float32 score of 32 x 32 x 16,384 is left; a prompt
-    chunk takes the plain body."""
+    chunk attends through the kernel `kv_chunk`, handed the same bitcasts (PR 66: no
+    float32 score block of 32 x 1,024 x 1,024, no loop over key blocks under ``attn``)."""
     import re
 
     from galvatron_tpu.models import generation
@@ -1468,17 +1575,20 @@ def test_lfm2_serving_programs_fit_one_chip_and_copy_neither_stack(one_chip, rea
     assert 32 * layout["bytes_per_slot"] == kv + state
     # neither stack is copied: nothing as large as one layer's K or V slab moves, and no
     # result has the state stack's shape but its in-place updates
-    moved = [(op, shape) for op, shape in _moved_slabs(text, 32 * 8 * 16384 * 64)
-             if op != "while"]
+    moved = _moved_slabs(text, 32 * 8 * 16384 * 64)
     assert not moved, moved[:4]
-    kernels = [line for line in _entry_lines(text) if "custom-call(" in line and "kv_decode" in line]
+    kernels = _kv_program_kernels(text, name)
     under = sorted(int(re.search(r"/layer_(\d+)/attn/full/attn_core", line).group(1)) for line in kernels)
     full = [i for i, (stack, _) in enumerate(generation.layer_stacks(cfg)) if stack == "full"]
-    assert len(full) == 5 and under == (full if name == "serving_decode" else [])
+    assert len(full) == 5 and under == full
     for line in kernels:  # the stacks where they lie, transposed by a bitcast
         assert line.count("bf16[5,32,8,64,16384]{4,3,2,1,0}") == 2, line
+        _handed_as_bitcasts(line)
     if name == "serving_decode":
         assert not re.search(r"f32\[32,8,4,(1,)?16384\]", text)
+    else:
+        assert not re.search(r"f32\[(1,)?8,4,1024,1024\]", text)
+        assert not any(" while(" in line and "/attn/" in line for line in text.splitlines())
     # (a layer's write is a fusion whose root updates the stack in place: its name says so)
     state_results = [line.strip()[:120] for line in _entry_lines(text)
                      if re.match(r"\s*(?:ROOT )?%[\w.\-]+ = bf16\[17,32,4096\]\S* (?!parameter|bitcast)", line)
