@@ -11,7 +11,12 @@ For each share of the pairs that falls on the held experts (1/16 is the cell's w
 the load is even, 1/4 a rank of four, 0.98 a buffer that is nearly full: the end of
 the range where the bounded path has nothing to skip): forward and forward +
 backward (x, the combine weights and the three expert weights) of each body, ms a
-call, the layout alone (both bodies pay it), `moe_held_rows_share`, the largest
+call, the layout alone (both bodies pay it), counted as `moe.held_layout` runs it
+(PR 67) against the sort-based reference called directly, `moe._layout_by_sort`, at the
+cell's 33 groups and at 129, 256 and 512 (no cell's): host ms a call, the device's ms a
+call and its largest operations, the compiler's temporaries, and whether the six arrays
+are the same bits,
+`moe_held_rows_share`, the largest
 relative difference of the output and of each gradient between the bodies, whether
 everything is finite, and the largest device operations of the bounded backward.
 
@@ -32,7 +37,9 @@ the largest difference from the tile-256 output, the largest device operations; 
 beside each, the forward-only layout (PR 62: `held_layout(empty_tiles=False)` under
 `moe.held_forward`, what a cached forward runs): its ms, the held experts that got
 a row (whose weights alone it fetches), the rows its tiles cover, and whether its
-output is the default layout's to the bit. ``--skew`` sets the routers' loads (0
+output is the default layout's to the bit; and, once a shape at the tile the layers
+choose themselves (`ops/grouped_matmul.row_tile`), the layout alone, counted and sorted,
+as above. ``--skew`` sets the routers' loads (0
 even, 1 a few favourites); ``--tiny`` rehearses the mode at small widths on any
 backend.
 """
@@ -44,6 +51,7 @@ import functools
 import json
 import os
 import sys
+from unittest import mock
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -53,8 +61,10 @@ import numpy as np  # noqa: E402
 
 from experiments.ab_ssd import device_ops, rel, timed  # noqa: E402
 from galvatron_tpu.models import moe  # noqa: E402
+from galvatron_tpu.ops.grouped_matmul import row_tile  # noqa: E402
 
 TOKENS, TOP_K, EXPERTS, HELD, HIDDEN, WIDTH, TILE = 16384, 10, 512, 32, 2048, 512, 256
+WIDE_HELD = (128, 255, 511)  # groups - 1 of the wide layouts timed beside the cell's
 NAMES = ("y", "dx", "dweights", "dw1", "dw3", "dw2")
 
 
@@ -78,6 +88,29 @@ def inputs(share: float, dtype=jnp.bfloat16):
 
 def layout_of(idx):
     return moe.held_layout(idx, HELD, TILE, 0)
+
+
+def layout_alone(idx, held, tile, empty_tiles=True, tiny=False, top=6):
+    """The layout of ``idx`` alone, counted (`moe.held_layout` as the library runs it)
+    and sorted (the same over `moe._layout_by_sort`, the reference the library keeps for
+    this and for its tests, called directly). A row a body: host ms a call, the device's
+    ms a call and its largest operations, the compiler's temporaries."""
+    def by_sort(i):  # (trace-time: `held_layout` looks `sorted_layout` up by name)
+        with mock.patch.object(moe, "sorted_layout", moe._layout_by_sort):
+            return moe.held_layout(i, held, tile, 0, empty_tiles)
+
+    bodies = {"counted": jax.jit(lambda i: moe.held_layout(i, held, tile, 0, empty_tiles)),
+              "sorted": jax.jit(by_sort)}
+    want = bodies["sorted"](idx)
+    rows = []
+    for name, fn in bodies.items():
+        ops = [] if tiny else device_ops(fn, (idx,), top=1000)
+        rows.append({"layout_body": name, "layout_ms": timed(fn, idx, iters=3 if tiny else 30),
+                     "layout_device_ms": sum(ms for _, ms in ops), "layout_device_ops": len(ops),
+                     "temp_mb": fn.lower(idx).compile().memory_analysis().temp_size_in_bytes / 1e6,
+                     "same_bits": all(bool(jnp.array_equal(g, w)) for g, w in zip(fn(idx), want)),
+                     "layout_device_ops_ms": ops[:top]})
+    return rows
 
 
 def plain(x, weights, w1, w3, w2, idx):
@@ -131,17 +164,36 @@ def serve_forward(x, weights, w1, w3, w2, idx, *, held, tile, act, empty_tiles=T
                lay.tile_group, lay.num_tiles, tile, act)
 
 
+def print_layouts(layouts):
+    print("| model | tokens | pairs x groups | tile | empty tiles | body | host ms | device ms | "
+          "device operations | temporaries MB | same bits |")
+    print("| --- | --- | --- | --- | --- | --- | --- | --- | --- | --- | --- |")
+    for r in layouts:
+        print(f"| {r['model']} | {r['tokens']} | {r['pairs']} x {r['groups']} | {r['tile']} | "
+              f"{r['empty_tiles']} | {r['layout_body']} | {r['layout_ms']:.3f} | "
+              f"{r['layout_device_ms']:.4f} | {r['layout_device_ops']} | {r['temp_mb']:.1f} | "
+              f"{r['same_bits']} |")
+
+
 def serve(args) -> int:
     tiny = args.tiny
     shapes = TINY_SHAPES if tiny else SERVE_SHAPES
     tiles = [int(t) for t in args.tiles.split(",")]
-    rows = []
+    rows, layouts = [], []
     for model, (experts, held, top_k, hidden, width, act) in shapes.items():
         weight_bytes = 3 * held * hidden * width * 2
         for tokens, skew in ((t, float(k)) for t in ((8, 64) if tiny else (32, 1024))
                              for k in args.skew.split(",")):
             operands = serve_inputs(tokens, experts, held, top_k, hidden, width, skew)
             mean_rows = tokens * top_k / experts
+            own_tile = row_tile(tokens, top_k, experts, operands[0].dtype)
+            for empty_tiles in (True, False):  # (False: what a cached forward asks for)
+                for r in layout_alone(operands[-1], held, own_tile, empty_tiles, tiny):
+                    r = {"model": model, "tokens": tokens, "skew": skew, "tile": own_tile,
+                         "pairs": tokens * top_k, "groups": held + 1,
+                         "empty_tiles": empty_tiles, **r}
+                    print(json.dumps(r), flush=True)
+                    layouts.append(r)
             want = None
             for tile in sorted(tiles, reverse=True):  # 256 first: the one the others are held to
                 fwd, only = (jax.jit(functools.partial(serve_forward, held=held, tile=tile,
@@ -184,9 +236,10 @@ def serve(args) -> int:
               f"{r['fwd_ms']:.3f} | {r['weights_gb_per_s']:.0f} | "
               f"{r['experts_touched']} / {r['experts_held']} | {r['fwd_only_rows_in_use']} | "
               f"{r['fwd_only_ms']:.3f} | {r['fwd_only_is_the_default_to_the_bit']} |")
+    print_layouts(layouts)
     worst = max(r["rel_to_256"] for r in rows)
     ok = (all(r["finite"] and r["fwd_only_is_the_default_to_the_bit"] for r in rows)
-          and worst < 0.02)
+          and all(r["same_bits"] for r in layouts) and worst < 0.02)
     print(json.dumps({"ok": ok, "worst_rel_to_256": worst,
                       "device": str(np.asarray(jax.devices())[0])}))
     return 0 if ok else 1
@@ -205,9 +258,19 @@ def main(argv=None) -> int:
         raise SystemExit("ab_moe_held: needs a TPU")
     if args.serve:
         return serve(args)
-    rows = []
-    for share in (float(s) for s in args.shares.split(",")):
+    rows, layouts = [], []
+    shares = [float(s) for s in args.shares.split(",")]
+    for share in shares:
         operands, idx, cot = inputs(share)
+        # (the cell's 32 held + the dropped, then wider: a device that held 128, 256 or all
+        # 512 of the experts; no cell does, and the layout is counted there all the same)
+        for held in (HELD,) + (WIDE_HELD if share == shares[0] else ()):
+            for r in layout_alone(idx, held, TILE, top=args.ops):
+                r = {"model": "qwen3-next-80b-a3b", "tokens": TOKENS, "share": share,
+                     "tile": TILE, "pairs": TOKENS * TOP_K, "groups": held + 1,
+                     "empty_tiles": True, **r}
+                print(json.dumps(r), flush=True)
+                layouts.append(r)
         # (idx and the cotangent are operands: closed over they would be 140 MB of
         # constants in each executable)
         operands += (idx, cot)
@@ -237,8 +300,9 @@ def main(argv=None) -> int:
     for r in rows:
         print(f"| {r['share']} | {r['moe_held_rows_share']:.4f} | {r['body']} | "
               f"{r['layout_ms']:.2f} | {r['fwd_ms']:.2f} | {r['fwd_bwd_ms']:.2f} |")
+    print_layouts(layouts)
     worst = max(max(r["rel_to_plain"].values()) for r in rows if "rel_to_plain" in r)
-    ok = all(r["finite"] for r in rows) and worst < 0.05
+    ok = all(r["finite"] for r in rows) and all(r["same_bits"] for r in layouts) and worst < 0.05
     print(json.dumps({"ok": ok, "worst_rel_to_plain": worst,
                       "device": str(np.asarray(jax.devices())[0])}))
     return 0 if ok else 1

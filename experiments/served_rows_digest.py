@@ -24,7 +24,9 @@ Where a change means to move the rows by a rounding (another kernel for the same
 mathematics), ``--rows PREFIX`` says how far: the first run keeps each request's rows and
 tokens under ``PREFIX.<i>.npz``, a later run with the same ``PREFIX`` compares its own with
 them (``vs_kept``: the tokens up to the first that differs, and over those rows the largest
-difference of a logit and the mean KL of the two softmaxes).
+difference of a logit, the mean KL of the two softmaxes, how many rows are the same bits, and
+the largest difference in the first row, which the chunk program wrote, and in the later ones,
+which the decode program wrote).
 """
 
 from __future__ import annotations
@@ -61,9 +63,13 @@ def _kept(path, rows, tokens) -> dict:
 
     ours, kept = log_softmax(rows[:n]), log_softmax(theirs[:n])
     kl = float((np.exp(kept) * (kept - ours)).sum(-1).mean())
+    # row 0 is the prompt's last row (the chunk program's head), the others a decode step's
+    far = np.abs(rows[:n] - theirs[:n]).max(-1)
     return {"vs_kept": {"same_tokens": same, "of": len(their_tokens), "rows": n,
-                        "max_abs_logit_diff": float(np.abs(rows[:n] - theirs[:n]).max()),
-                        "mean_kl": kl}}
+                        "max_abs_logit_diff": float(far.max()), "mean_kl": kl,
+                        "rows_same_bits": int((far == 0).sum()),
+                        "first_row_max_abs_diff": float(far[0]),
+                        "later_rows_max_abs_diff": float(far[1:].max()) if n > 1 else None}}
 
 
 def main(argv=None) -> int:

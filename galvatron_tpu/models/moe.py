@@ -225,7 +225,8 @@ def moe_block(x: jax.Array, p: Params, cfg, train: bool = True,
 # and backward (a pair has one row and a row one pair), through two custom
 # VJPs: autodiff's transpose of a gather is a scatter-add, which the TPU
 # serializes.  Every shape is static: the buffer has T*k + E*tile rows, the
-# most that whole-tile groups can need.
+# most that whole-tile groups can need.  The sorted ORDER itself is counted, not
+# sorted (`sorted_layout`).
 
 class SortedLayout(NamedTuple):
     """Where each (token, choice) pair lives in the expert-sorted row buffer."""
@@ -257,6 +258,22 @@ def _group_tiles(sizes, tile: int, empty_tiles: bool):
     return jnp.maximum(-(-sizes // tile), 1 if empty_tiles else 0)
 
 
+#: pairs a block of the prefix count: a pair is ranked among its block's earlier pairs
+#: by comparison (block x block), and blocks by a running sum of their per-group totals
+COUNT_BLOCK = 256
+
+
+def _tile_tables(sizes, tile: int, empty_tiles: bool, rows: int):
+    """``(row_start, tile_end, tile_group)`` of groups of ``sizes`` pairs in a buffer of
+    ``rows`` rows: a group's first row, the tile its tiles end before, and each tile's
+    group = how many groups' ends it has passed (the last group's past them all)."""
+    tiles = _group_tiles(sizes, tile, empty_tiles)
+    tile_end = jnp.cumsum(tiles)
+    passed = jnp.arange(rows // tile, dtype=jnp.int32)[:, None] >= tile_end[None, :]
+    tile_group = jnp.minimum(jnp.sum(passed, axis=1, dtype=jnp.int32), sizes.shape[0] - 1)
+    return (tile_end - tiles) * tile, tile_end, tile_group
+
+
 def sorted_layout(expert_idx: jax.Array, num_experts: int, tile: int,
                   empty_tiles: bool = True) -> SortedLayout:
     """Sort the pairs of ``expert_idx`` (T, k) by expert, stably, into groups
@@ -264,7 +281,48 @@ def sorted_layout(expert_idx: jax.Array, num_experts: int, tile: int,
     (all-padding) tile, so that its weight gradient is written. ``empty_tiles``
     False (a forward that is never differentiated): it owns none, no tile names
     it and its weights are never fetched; ``num_tiles`` is then 0 where no expert
-    got a pair. The buffer's rows are the same either way."""
+    got a pair. The buffer's rows are the same either way.
+
+    Nothing is sorted: a stable sort by expert is a counting sort, a pair's row =
+    its group's first row + the pairs of its group before it. Dense comparisons and
+    sums, one scatter (the rows' pairs: the inverse of ``pair_row``), no sort, no
+    scatter-add, no search, at any number of experts (docs/DESIGN.md)."""
+    flat = expert_idx.reshape(-1).astype(jnp.int32)
+    pairs = flat.shape[0]
+    rows = buffer_rows(pairs, num_experts, tile)
+    block = min(COUNT_BLOCK, pairs)
+    blocks = -(-pairs // block)
+    # (a pair past the end names no group; it ranks behind the block's real pairs)
+    keys = jnp.pad(flat, (0, blocks * block - pairs), constant_values=num_experts)
+    keys = keys.reshape(blocks, block)
+    groups = jnp.arange(num_experts, dtype=jnp.int32)
+    member = keys[None, :, :] == groups[:, None, None]  # (groups, blocks, block)
+    totals = jnp.sum(member, axis=2, dtype=jnp.int32)  # a block's pairs of a group
+    through = jnp.cumsum(totals, axis=1)  # a group's pairs up to a block's end
+    before, sizes = through - totals, through[:, -1]
+    row_start, tile_end, tile_group = _tile_tables(sizes, tile, empty_tiles, rows)
+    # the pairs of its group before a pair, in its block: [j earlier][same key], summed
+    earlier = jnp.arange(block)[:, None] < jnp.arange(block)[None, :]  # (j, i)
+    rank = jnp.sum((keys[:, :, None] == keys[:, None, :]) & earlier[None], axis=1,
+                   dtype=jnp.int32)
+    base = jnp.sum(jnp.where(member, (row_start[:, None] + before)[:, :, None], 0), axis=0)
+    pair_row = (base + rank).reshape(-1)[:pairs]
+    row_pair = jnp.zeros((rows,), jnp.int32).at[pair_row].set(
+        jnp.arange(pairs, dtype=jnp.int32), unique_indices=True, mode="promise_in_bounds")
+    # a tile's rows that hold a pair: its group's, from the tile's place in the group on
+    at = tile_group[:, None] == groups[None, :]
+    first = jnp.arange(rows // tile, dtype=jnp.int32) * tile
+    live = jnp.sum(jnp.where(at, (sizes + row_start)[None, :], 0), axis=1) - first
+    valid = (jnp.arange(tile, dtype=jnp.int32)[None, :] < live[:, None]).reshape(-1)
+    return SortedLayout(pair_row, row_pair, valid, tile_group, tile_end[-1:].astype(jnp.int32),
+                        sizes)
+
+
+def _layout_by_sort(expert_idx, num_experts: int, tile: int,
+                    empty_tiles: bool = True) -> SortedLayout:
+    """`sorted_layout` by a stable argsort, as it was before PR 67: the REFERENCE that
+    tests/test_moe.py and experiments/ab_moe_held.py hold `sorted_layout` to, to the
+    bit. Nothing in the library calls it."""
     flat = expert_idx.reshape(-1).astype(jnp.int32)
     pairs = flat.shape[0]
     rows = buffer_rows(pairs, num_experts, tile)
@@ -604,15 +662,16 @@ def _topk_local(x, p, cfg, tile, over, router_x=None, forward_only=False):
             weights, idx = jax.lax.top_k(probs, k)  # weights: p's own values
             if cfg.moe_norm_topk:
                 weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
-        if held_share:
-            # an expert without a row owns a tile for its weight gradient's sake
-            # (`moe_tgmm`); a bounded forward that has none to write pays a fetch of the
-            # expert's weights for it, so it asks for none (the plain path runs over the
-            # whole buffer as it is, and keeps the layout its VJPs can take)
-            layout = held_layout(idx, cfg.moe_held, tile, cfg.moe_first_held,
-                                 empty_tiles=not (forward_only and bounded))
-        else:
-            layout = sorted_layout(idx, e, tile)
+        with jax.named_scope("layout"):
+            if held_share:
+                # an expert without a row owns a tile for its weight gradient's sake
+                # (`moe_tgmm`); a bounded forward that has none to write pays a fetch of
+                # the expert's weights for it, so it asks for none (the plain path runs
+                # over the whole buffer as it is, and keeps the layout its VJPs can take)
+                layout = held_layout(idx, cfg.moe_held, tile, cfg.moe_first_held,
+                                     empty_tiles=not (forward_only and bounded))
+            else:
+                layout = sorted_layout(idx, e, tile)
     if bounded:
         with jax.named_scope("experts"):
             # weights held in another type are converted every step, and that pass
@@ -640,9 +699,7 @@ def _topk_local(x, p, cfg, tile, over, router_x=None, forward_only=False):
         with jax.named_scope("shared_expert"):
             y = y + _shared_expert(xt, p["shared"], _glu_gate(cfg))
     # the statistics are over ALL the experts the router scores, held or not
-    sizes = jnp.bincount(idx.reshape(-1), length=e).astype(jnp.int32) if held_share \
-        else layout.sizes
-    stats = router_stats(probs, sizes)
+    stats = router_stats(probs, _pairs_an_expert(idx, e) if held_share else layout.sizes)
     if held_share:
         # the share of the worst-case buffer's rows whose tiles are in use: what is
         # left of the work where the path is bounded
@@ -650,6 +707,13 @@ def _topk_local(x, p, cfg, tile, over, router_x=None, forward_only=False):
     if over:
         stats = tuple(jax.lax.pmean(s_, over) for s_ in stats)
     return y.reshape(b, s, h), stats
+
+
+def _pairs_an_expert(idx, num_experts: int):
+    """The pairs each of ``num_experts`` experts got of the choices ``idx`` (T, k), by
+    comparison and sum (`jnp.bincount` is a scatter-add of the pairs: serial on the chip)."""
+    return jnp.sum(idx.reshape(-1)[None, :] == jnp.arange(num_experts, dtype=idx.dtype)[:, None],
+                   axis=1, dtype=jnp.int32)
 
 
 def _shared_expert(xt, p, act=jax.nn.silu):

@@ -91,8 +91,28 @@ _NO_SERVING_READER_HAD_A_LIST_BEFORE_PR_65 = frozenset(
                     "shortconv_prefill_chunk_ms")])
 
 
+#: Two accepted cases of tests/benchmark pin the manifest as their PR left it: the dots3
+#: cell's holds PR 65's five readers to the LAST five of ``per_layer`` (88 in all), the
+#: qwen3-next cell's holds the lists that name the cell to PR 47's four and the rate.  PR 67
+#: appended one reader, ``moe_layout_ms_per_step``, whose list names the qwen3-next cell (a new
+#: entry goes at the END of its list, by the driver's rule), and only a PR of kind ``benchmark``
+#: may edit those files.  They are expected to fail, strictly, until such a PR relaxes them in
+#: place and deletes this list; each body stands whole, with that one clause amended, in
+#: tests/benchmark/test_benchmark_moe_layout.py, whose last case holds this list and its
+#: copies one for one.
+_PINNED_TO_THE_MANIFEST_BEFORE_PR_67 = frozenset(
+    ["tests/benchmark/test_benchmark_dots3.py::test_the_cell_joins_the_manifest_by_appends",
+     "tests/benchmark/test_benchmark_qwen3_next.py::"
+     "test_the_cell_joins_no_list_but_its_own_metrics_and_the_rate"])
+
+
 def pytest_collection_modifyitems(items):
     for item in items:
+        if item.nodeid in _PINNED_TO_THE_MANIFEST_BEFORE_PR_67:
+            item.add_marker(pytest.mark.xfail(
+                strict=True, raises=AssertionError,
+                reason="pins the manifest's per-layer entries as PR 65 / PR 47 left them; PR 67 "
+                       "appended `moe_layout_ms_per_step`"))
         if item.nodeid in _NO_SERVING_READER_HAD_A_LIST_BEFORE_PR_65:
             item.add_marker(pytest.mark.xfail(
                 strict=True, raises=AssertionError,

@@ -632,3 +632,123 @@ def test_differentiating_a_forward_only_held_share_raises():
     assert float(jnp.abs(grads["w2"]).max()) > 0.0
     with pytest.raises(NotImplementedError):  # (Pallas has no JVP of a scalar-prefetch call)
         jax.grad(loss)(p, True)
+
+
+# -- the layout is counted, not sorted (PR 67) --------------------------------------------
+# `moe.sorted_layout`: a pair's row = its group's first row + the pairs of its group before
+# it, by comparison and sum, at any number of groups. The sort-based body it replaced stays as
+# `moe._layout_by_sort`, the reference it is held to here, all six arrays, to the bit.
+
+#: name: (tokens, top_k, groups, tile, load): each benchmark cell's group count and tile at a
+#: scaled-down pair count, and the loads that empty a group or fill one
+LAYOUT_CASES = {
+    "33x256_qwen3_next": (512, 10, 33, 256, "even"),
+    "33x256_skewed": (512, 10, 33, 256, "skewed"),
+    "33x64_chunk": (128, 8, 33, 64, "even"),
+    "33x16_step": (32, 8, 33, 16, "skewed"),
+    "17x16_step": (32, 6, 17, 16, "even"),
+    "17x64_chunk": (256, 6, 17, 64, "skewed"),
+    "64x256_olmoe": (512, 8, 64, 256, "even"),
+    "5x2": (4, 2, 5, 2, "even"),
+    "128_groups_a_lane_row": (64, 4, 128, 8, "even"),
+    "129_groups": (64, 4, 129, 8, "even"),
+    "512_groups_every_expert_held": (96, 10, 512, 8, "skewed"),
+    "first_group_empty": (96, 4, 9, 8, "first_empty"),
+    "last_group_empty": (96, 4, 9, 8, "last_empty"),
+    "inner_groups_empty": (96, 4, 9, 8, "inner_empty"),
+    "every_pair_dropped": (64, 4, 5, 8, "last_alone"),
+    "every_pair_in_one_group": (64, 4, 9, 8, "one"),
+    "pairs_no_multiple_of_the_block": (100, 3, 33, 16, "even"),
+    "pairs_one_past_a_block": (257, 1, 17, 16, "skewed"),
+    "one_pair": (1, 1, 3, 8, "even"),
+}
+
+
+def _layout_choices(tokens, top_k, groups, load):
+    rng = np.random.default_rng(tokens * 131 + groups)
+    if load == "even":
+        idx = rng.integers(0, groups, (tokens, top_k))
+    elif load == "skewed":
+        idx = np.minimum(rng.geometric(0.3, (tokens, top_k)) - 1, groups - 1)
+    elif load == "first_empty":
+        idx = rng.integers(2, groups, (tokens, top_k))
+    elif load == "last_empty":
+        idx = rng.integers(0, groups - 2, (tokens, top_k))
+    elif load == "inner_empty":
+        idx = rng.choice([0, 1, groups - 2, groups - 1], (tokens, top_k))
+    else:
+        idx = np.full((tokens, top_k), {"last_alone": groups - 1, "one": groups // 2}[load])
+    return jnp.asarray(idx, jnp.int32)
+
+
+@pytest.mark.parametrize("empty_tiles", [True, False], ids=["empty_tiles", "no_empty_tiles"])
+@pytest.mark.parametrize("case", list(LAYOUT_CASES))
+def test_counted_layout_is_the_sorted_layout_to_the_bit(case, empty_tiles):
+    tokens, top_k, groups, tile, load = LAYOUT_CASES[case]
+    idx = _layout_choices(tokens, top_k, groups, load)
+    got = jax.jit(lambda i: moe.sorted_layout(i, groups, tile, empty_tiles))(idx)
+    want = jax.jit(lambda i: moe._layout_by_sort(i, groups, tile, empty_tiles))(idx)
+    for name, g, w in zip(got._fields, got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype, name
+        assert np.array_equal(np.asarray(g), np.asarray(w)), name
+    # and the contract itself, on the counted body's own arrays
+    flat, pair_row = np.asarray(idx).reshape(-1), np.asarray(got.pair_row)
+    assert list(np.asarray(got.sizes)) == list(np.bincount(flat, minlength=groups))
+    assert int(got.row_valid.sum()) == flat.size == len(set(pair_row))
+    assert (np.asarray(got.row_pair)[pair_row] == np.arange(flat.size)).all()
+    assert (np.asarray(got.tile_group)[pair_row // tile] == flat).all()
+    if load == "last_alone":  # as `held_layout` sees it: no held expert got a pair
+        held = moe.held_layout(idx, groups - 1, tile, 0, empty_tiles=empty_tiles)
+        assert int(held.num_tiles[0]) == (groups - 1 if empty_tiles else 0)
+        assert not np.asarray(held.row_valid).any()
+        assert (np.asarray(held.pair_row) >= int(held.num_tiles[0]) * tile).all()
+
+
+def _primitives(jaxpr):
+    """Every primitive's name in a jaxpr, those of the jaxprs its equations hold too."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _primitives(sub)
+
+
+@pytest.mark.parametrize("held, tile", [(32, 256), (63, 256), (511, 256)],
+                         ids=["qwen3_next_33", "64_as_olmoe", "512_every_expert_held"])
+def test_a_training_layout_holds_no_sort_no_scatter_add_and_one_scatter(held, tile):
+    """`held_layout` at the qwen3-next cell's shape (16,384 tokens x top-10, 32 held + the
+    dropped, tiles of 256) and at wider ones, abstract values only: dense passes and the one
+    scatter of the rows' pairs, whatever the number of groups."""
+    idx = jax.ShapeDtypeStruct((16384, 10), jnp.int32)
+    names = list(_primitives(jax.make_jaxpr(lambda i: moe.held_layout(i, held, tile, 0))(idx).jaxpr))
+    assert not {"sort", "scatter-add", "scatter_add", "while", "gather"} & set(names), names
+    assert names.count("scatter") == 1
+    out = jax.eval_shape(lambda i: moe.held_layout(i, held, tile, 0), idx)
+    assert out.row_pair.shape == (moe.buffer_rows(163840, held + 1, tile),)
+    assert moe.buffer_rows(163840, 33, 256) == 172288
+
+
+def test_every_benchmark_configuration_with_a_routed_layer_counts_its_layout():
+    """Each at its own group count, top-k and a chunk's 1,024 tokens: no `sort` in the jaxpr."""
+    import glob
+    import json
+    import os
+
+    from galvatron_tpu.core.arguments import initialize_galvatron, model_config_from_args
+
+    routed = {}
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for path in sorted(glob.glob(os.path.join(here, "benchmark", "configs", "*.json"))):
+        flags = list(json.load(open(path))["program_flags"])
+        mode = "serve" if "--param_dtype" in flags else "train"
+        cfg = model_config_from_args(initialize_galvatron(mode, flags))
+        if cfg.moe_dropless:
+            groups = cfg.moe_held + 1 if cfg.moe_holds_share else cfg.moe_experts
+            idx = jax.ShapeDtypeStruct((1024, cfg.moe_top_k), jnp.int32)
+            tile = moe.layer_row_tile(cfg, 1024)
+            names = set(_primitives(jax.make_jaxpr(
+                lambda i: moe.sorted_layout(i, groups, tile))(idx).jaxpr))
+            routed[os.path.basename(path)[:-5]] = groups, bool({"sort", "scatter-add"} & names)
+    assert routed == {
+        "dots3-note-prev": (33, False), "lfm2-24b-a2b": (17, False), "olmoe-1b-7b": (64, False),
+        "qwen3-next-80b-a3b": (33, False), "sarvam-105b": (33, False),
+        "smallthinker-21b-a3b": (17, False), "trinity-large-preview": (33, False)}

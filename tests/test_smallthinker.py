@@ -600,15 +600,21 @@ def _expert_layer_text(name):
 
 @pytest.mark.parametrize("name", sorted(PARENT_TEXT))
 def test_the_other_expert_models_lower_to_the_parents_program(name, monkeypatch):
-    """But for ONE thing since PR 62: the kernels' shared index-map helper `used_tile`
+    """But for TWO things: since PR 62 the kernels' shared index-map helper `used_tile`
     clamps at tile 0 (a served share's ``num_tiles`` can be 0), a scalar ``max`` in every
-    block map. With the helper as the parent had it the text is the parent's, sha for sha:
-    a differentiated expert layer changed in nothing else."""
+    block map; since PR 67 the layout is counted where the parent sorted it and a held
+    share's statistics are counted by comparison where the parent's were a `bincount`
+    (the same integers: tests/test_moe.py). With the helper, the layout's body and the
+    count as the parent had them the text is the parent's, sha for sha: a
+    differentiated expert layer changed in nothing else."""
     from galvatron_tpu.ops import grouped_matmul, moe_held, pallas_common
 
     clamped = _expert_layer_text(name)
     for module in (grouped_matmul, moe_held):
         monkeypatch.setattr(module, "used_tile", lambda i, count: jnp.minimum(i, count[0] - 1))
+    monkeypatch.setattr(moe, "sorted_layout", moe._layout_by_sort)
+    monkeypatch.setattr(moe, "_pairs_an_expert", lambda idx, e: jnp.bincount(
+        idx.reshape(-1), length=e).astype(jnp.int32))
     pallas_common._traced.cache_clear()  # (kernels traced once a signature: `traced_once`)
     try:
         assert _expert_layer_text(name) == PARENT_TEXT[name] != clamped
