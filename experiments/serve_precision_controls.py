@@ -24,6 +24,12 @@ own ``run_serve_cell`` at the cell's own load, the fault planted underneath it:
 - ``state_stale``: a forward that starts a request at position 0 reads the state its
   slot holds (the previous request's, or what idle decode steps left) in place of zeros
   (``models/shortconv.fresh``): a state not reset at admission;
+- ``scan_bf16``: a Mamba-2 layer's scan state kept in bfloat16 where the configuration
+  states float32 (``models/ssm.state_shapes``: the stack's type, so every step's and every
+  chunk's state is rounded as it is written); PR 68's cell;
+- ``scan_stale``: a forward that starts a request at position 0 reads the conv tail and the
+  scan state its slot holds in place of zeros (``models/ssm.cached_block`` told that every
+  forward starts past position 0);
 - ``int8``: the engine's own per-channel int8 weights (``--serve_quant int8``), as
   ``benchmark/control.py`` reads them.
 
@@ -56,11 +62,12 @@ def planted(mode: str):
     import jax
     import jax.numpy as jnp
 
-    from galvatron_tpu.models import generation, mla, moe, shortconv
+    from galvatron_tpu.models import generation, mla, moe, shortconv, ssm
 
     real_scores, real_project = moe.router_scores, mla.project
     real_qkv = generation._project_qkv_at
     real_stored, real_fresh = shortconv.stored, shortconv.fresh
+    real_shapes, real_cached = ssm.state_shapes, ssm.cached_block
 
     def e4m3(t):
         bits = jax.lax.bitcast_convert_type(t.astype(jnp.bfloat16), jnp.uint16)
@@ -75,6 +82,10 @@ def planted(mode: str):
     def scores_bf16(xt, router, cfg):
         x, w = xt.astype(jnp.bfloat16), router["w"].astype(jnp.bfloat16)
         return jax.nn.sigmoid(x @ w).astype(jnp.float32)
+
+    def shapes_bf16(cfg):
+        shapes = real_shapes(cfg)
+        return dict(shapes, scan=(shapes["scan"][0], jnp.dtype(jnp.bfloat16)))
 
     def project_e4m3(x, p, cfg, cos_sin):
         q_nope, q_rope, new = real_project(x, p, cfg, cos_sin)
@@ -91,12 +102,18 @@ def planted(mode: str):
         shortconv.stored = lambda new, dtype: e4m3(new).astype(dtype)
     elif mode == "state_stale":
         shortconv.fresh = lambda prev, offsets: prev
+    elif mode == "scan_bf16":
+        ssm.state_shapes = shapes_bf16
+    elif mode == "scan_stale":
+        ssm.cached_block = lambda x, p, cfg, state, layer, slot, offsets, last: real_cached(
+            x, p, cfg, state, layer, slot, jnp.maximum(offsets, 1), last)
     try:
         yield
     finally:
         moe.router_scores, mla.project = real_scores, real_project
         generation._project_qkv_at = real_qkv
         shortconv.stored, shortconv.fresh = real_stored, real_fresh
+        ssm.state_shapes, ssm.cached_block = real_shapes, real_cached
         jax.clear_caches()
 
 

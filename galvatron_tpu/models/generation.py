@@ -16,8 +16,9 @@ What a cache holds goes by what a LAYER keeps, one rule for every stack:
 
 - whole slots: keys and values of every position of a row (attention);
 - a ring: keys and values of the last ``window`` positions (a sliding-window layer);
-- a state: a fixed-size array a row, no positions (a kind of ``mixers.MIXERS`` with
-  ``state``: the gated short convolution's last inputs);
+- a state: a fixed-size array a row, or one a part, no positions (a kind of
+  ``mixers.MIXERS`` with ``state``: the gated short convolution's last inputs; the
+  Mamba-2 mixer's conv tail and float32 scan state, ``models/ssm.SsmState``);
 - or the kind's own position-indexed cache (latent attention: every layer alike, or, of
   a stack with an indexer or window layers, the full layers' latent and index keys in
   whole slots and the window layers' latent in a ring: ``models/mla.LatentCache``).
@@ -71,7 +72,9 @@ class SlotStacks(NamedTuple):
     ``p mod R`` with ``R = window + the most positions one forward writes a row``
     (`ring_positions`); the state layers' STATE, ``state`` (L_state, B, ...), whose
     trailing shape is the kind's (``init_state`` of its module: the module docstring
-    has what a state asks that positions do not). HEAD-major: a key/value head's positions are a matrix
+    has what a state asks that positions do not; a kind whose row keeps parts of
+    different types gives a NamedTuple of such stacks, one a part, which this cache
+    carries as the pytree it is). HEAD-major: a key/value head's positions are a matrix
     (positions, head_dim), which the chip's matrix unit takes as it lies; from
     (positions, kv_heads, head_dim) the compiler copied every stack whole, every
     step, into that order (compiled for a described v5e at the cell's size: 7.76
@@ -94,7 +97,7 @@ class SlotStacks(NamedTuple):
     v: jax.Array
     wk: Optional[jax.Array] = None
     wv: Optional[jax.Array] = None
-    state: Optional[jax.Array] = None
+    state: Optional[object] = None  # an array, or the kind's NamedTuple of them
 
 
 def ring_positions(cfg: ModelConfig, max_len: int, tokens: int = 1) -> int:
@@ -198,8 +201,11 @@ def cache_layout(cfg: ModelConfig, max_len: Optional[int] = None, tokens: int = 
                "window": cfg.sliding_window_size if win else 0}
         state_bytes = 0
         if layers["state"]:
-            per_row = _state_module(cfg).state_bytes_per_row(cfg)
+            module = _state_module(cfg)
+            per_row = module.state_bytes_per_row(cfg)
             out.update(state_layers=layers["state"], state_bytes_per_row=per_row)
+            if hasattr(module, "state_part_bytes"):  # (a state of several parts)
+                out["state_part_bytes"] = module.state_part_bytes(cfg)
             state_bytes = layers["state"] * per_row
         if max_len is not None:
             positions = layers["full"] * max_len
@@ -659,8 +665,9 @@ def forward_with_cache(params: Params, tokens, cfg: ModelConfig, cache, offsets,
                     with jax.named_scope("out_proj"):
                         y = modeling.attn_output(o, p["attn"], cfg, x.dtype)
                     x = x + modeling.post_norm(y, p, "post_attn_norm", cfg)
-            x = x + modeling.post_norm(
-                _mlp_at(x, p, cfg, moe_stats, router_x), p, "post_mlp_norm", cfg)
+            if "mlp" in p:  # (a layer of its mixer alone has none: ``mlp_layout``)
+                x = x + modeling.post_norm(
+                    _mlp_at(x, p, cfg, moe_stats, router_x), p, "post_mlp_norm", cfg)
     return _head(x, params, cfg), (cache if kind is not None or by_stack else KVCache(ks, vs))
 
 

@@ -28,12 +28,18 @@ only where a configuration has such layers: `module`) exposes
   ``models/generation.forward_with_cache`` runs in place of attention over K and V;
 - where the row says ``state`` (the kind keeps a per-row STATE in the cached forwards,
   nothing indexed by position: no ``kv_cache`` under ``lacks`` either):
-  ``init_state(cfg, layers, rows)`` -> one array stacked ``(layers, rows, ...)``,
-  ``state_bytes_per_row(cfg)`` (one layer's) and ``cached_block(x, p, cfg, state,
+  ``init_state(cfg, layers, rows)`` -> one array stacked ``(layers, rows, ...)``, or,
+  where a row keeps parts of different types (the Mamba-2 mixer: a conv tail in the
+  compute type and a float32 scan state), a NamedTuple of such arrays, one a part (a
+  pytree: the cached forwards carry, donate and rebuild it whole and never look inside);
+  ``state_bytes_per_row(cfg)`` (one layer's, all parts; ``state_part_bytes(cfg)`` by
+  part where there are several) and ``cached_block(x, p, cfg, state,
   layer, slot, offsets, last)`` -> ``(y, state)``: the layer over the state stack of
   ``models/generation.SlotStacks``, beside the attention layers' keys and values. A
   state is read ZERO by a forward that starts at position 0 and written as of the
-  forward's last REAL row (``last``); `limits` says what a stack with one cannot do.
+  forward's last REAL row (``last``); `limits` says what a stack with one cannot do:
+  the paged backend and speculation (a state is no position and cannot be wound
+  back), beside the kind's own tp, cp and packing.
 
 The row holds the kind's words and, under ``lacks``, what it does not implement
 with the clause that says why. `limits` turns the rows of a configuration's
@@ -67,19 +73,20 @@ class Mixer:
     kernels: Tuple[str, ...] = ()  # the bodies `path_counts` reports, "<kind>_<kernel>_path"
     cache: str = ""  # "latent": what the kind's own cache holds a position ("": it has none)
     ring: bool = False  # its cached forward keeps a window layer's entries in a ring of its own
-    state: str = ""  # "conv": what the kind's state holds a row, no positions ("": it has none)
+    # "conv" | "conv + scan": what the kind's state holds a row, no positions ("": none)
+    state: str = ""
 
 
 MIXERS: Dict[str, Mixer] = {entry.kind: entry for entry in (
     Mixer(
         kind="ssm", module="galvatron_tpu.models.ssm", layer="state-space layer",
         mixer="the Mamba-2 mixer", tag="state_space_layers", kernels=("scan", "conv"),
+        state="conv + scan",
         lacks={
             "tp": "the Mamba-2 mixer's heads, conv channels and scan carry no tp sharding",
             "cp": "the scan's state is not passed between sequence shards",
             "pack_sequences": ("the conv and the scan do not reset their state at segment "
                                "boundaries"),
-            "kv_cache": "a key/value cache holds no recurrent (conv + scan) state",
         }),
     Mixer(
         kind="gdn", module="galvatron_tpu.models.gdn", layer="Gated DeltaNet layer",

@@ -52,7 +52,8 @@ class ModelConfig:
     max_seq_len: int = 2048
     pos_embed: str = "rope"  # 'rope' | 'learned' | 'alibi' | 'nope' (none at all)
     norm_type: str = "rms"  # 'rms' | 'layernorm'
-    act_fn: str = "swiglu"  # 'swiglu' | 'gelu' | 'relu' (OPT-style)
+    # 'swiglu' | 'gelu' | 'relu' (OPT-style) | 'relu2' (``relu(x)^2``, un-gated: nemotron_h)
+    act_fn: str = "swiglu"
     tie_word_embeddings: bool = False
     # GPT-2-style projection biases on qkv/out/mlp GEMMs (norm biases are
     # governed by norm_type). Requires the blocked qkv layout (no GQA).
@@ -153,6 +154,12 @@ class ModelConfig:
     # implement (``mixers.limits``) is refused by build_runtime and left out by
     # the search.
     layer_kinds: Tuple[str, ...] = ()
+    # Of each layer, as published for the WHOLE model like ``layer_kinds``: 1, the
+    # layer has an MLP (the program's pre-norm layer: a mixer and an MLP, two norms);
+    # 0, it is its mixer alone: one norm, one sublayer, no ``mlp`` / ``mlp_norm`` in its
+    # parameters (nemotron_h-class stacks, whose published blocks are ONE norm and ONE
+    # sublayer each: `blocks_to_layers`). Empty: every layer has one.
+    mlp_layout: Tuple[int, ...] = ()
     # Latent attention (layers of kind "mla", models/mla.py): the rank of the
     # compressed key/value latent, the non-rotary and rotary parts of a query /
     # key head and the value head's size. ``attn_head_dim`` is their query head
@@ -315,6 +322,16 @@ class ModelConfig:
             raise ValueError(
                 f"layer_kinds has {len(self.layer_kinds)} entries for {self.num_layers} layers")
         return self.layer_kinds[: self.num_layers]
+
+    @property
+    def mlp_layers(self) -> Tuple[bool, ...]:
+        """Of each of the ``num_layers`` decoder layers: it has an MLP."""
+        if not self.mlp_layout:
+            return (True,) * self.num_layers
+        if len(self.mlp_layout) < self.num_layers:
+            raise ValueError(
+                f"mlp_layout has {len(self.mlp_layout)} entries for {self.num_layers} layers")
+        return tuple(bool(m) for m in self.mlp_layout[: self.num_layers])
 
     @property
     def windowed(self) -> bool:
@@ -563,11 +580,21 @@ def _norm_scale_init(cfg: ModelConfig, n: int):
     return (jnp.zeros if cfg.norm_zero_centered else jnp.ones)((n,), cfg.param_dtype)
 
 
+def _without_mlp(p: Params) -> Params:
+    """A layer's tree without its MLP and the MLP's norms (``mlp_layout`` 0)."""
+    return {k: v for k, v in p.items() if k not in ("mlp", "mlp_norm", "post_mlp_norm")}
+
+
 def init_layer_params(key, cfg: ModelConfig, cross: bool = False,
-                      kind: str = "attention", dense_mlp: bool = False) -> Params:
+                      kind: str = "attention", dense_mlp: bool = False,
+                      mlp: bool = True) -> Params:
     """``dense_mlp``: the layer's MLP is the plain one of ``ffn`` though the
-    model's are expert layers (a leading layer: ``moe_dense_layers``)."""
+    model's are expert layers (a leading layer: ``moe_dense_layers``); ``mlp``
+    False: the layer is its mixer alone (``mlp_layout``)."""
     h, hd = cfg.hidden_size, cfg.head_dim
+    if not mlp:
+        return _without_mlp(init_layer_params(key, cfg.replace(moe_experts=0, ffn_dim=1),
+                                              cross=cross, kind=kind))
     if kind in mixers.MIXERS:
         # the mixer in place of attention; norms and MLP are an attention layer's
         k_mix, k_rest = jax.random.split(key)
@@ -650,10 +677,13 @@ def _layer_norms(cfg: ModelConfig) -> Tuple[str, ...]:
 
 
 def layer_annotations(cfg: ModelConfig, cross: bool = False,
-                      kind: str = "attention", dense_mlp: bool = False) -> Params:
+                      kind: str = "attention", dense_mlp: bool = False,
+                      mlp: bool = True) -> Params:
     """Logical axes per layer param: 'tp' = Megatron-sharded dim (column-out /
     row-in), 'fsdp' = the dim ZeRO shards (reference: FSDP flat-param sharding,
     galvatron/core/parallel.py:174-207)."""
+    if not mlp:
+        return _without_mlp(layer_annotations(cfg.replace(moe_experts=0), cross=cross, kind=kind))
     if kind in mixers.MIXERS:
         a = layer_annotations(cfg, cross=cross, dense_mlp=dense_mlp)
         del a["attn"]
@@ -848,7 +878,8 @@ def init_model_params(key, cfg: ModelConfig) -> Params:
         },
         "layers": [
             init_layer_params(ks[cfg.enc_layers + i + 1], cfg.layer_view(i), cross=cross,
-                              kind=kind, dense_mlp=i < cfg.moe_dense_layers)
+                              kind=kind, dense_mlp=i < cfg.moe_dense_layers,
+                              mlp=cfg.mlp_layers[i])
             for i, kind in enumerate(cfg.kinds)
         ],
         "final_norm": {"scale": _norm_scale_init(cfg, cfg.hidden_size)},
@@ -884,7 +915,7 @@ def model_annotations(cfg: ModelConfig) -> Params:
     a: Params = {
         "embed": {"tok": ("tp", "fsdp")},
         "layers": [layer_annotations(cfg.layer_view(i), cross=cross, kind=kind,
-                                     dense_mlp=i < cfg.moe_dense_layers)
+                                     dense_mlp=i < cfg.moe_dense_layers, mlp=cfg.mlp_layers[i])
                    for i, kind in enumerate(cfg.kinds)],
         "final_norm": {"scale": ("fsdp",)},
     }
@@ -1470,6 +1501,11 @@ def attn_block(x, p, cfg: ModelConfig, cos_sin=None, alibi=None, remat_attn: boo
 _gelu_tanh = partial(jax.nn.gelu, approximate=True)  # one object: it keys the seam's programs
 
 
+def relu2(x):
+    """``relu(x)^2``: the activation of an un-gated unit (``act_fn`` "relu2")."""
+    return jnp.square(jax.nn.relu(x))
+
+
 def glu_gate(cfg: ModelConfig):
     """The activation on the gate of a gated unit: silu (SwiGLU) or relu (ReGLU)."""
     if cfg.glu_act not in ("silu", "relu"):
@@ -1540,7 +1576,7 @@ def mlp_block(x, p, cfg: ModelConfig, train: bool = True, place: Placement = LOC
         if "w1_b" in p:
             g = g + p["w1_b"].astype(x.dtype)
         g = checkpoint_name(g, "mlp_gate")
-        act = jax.nn.relu if cfg.act_fn == "relu" else _gelu_tanh
+        act = {"relu": jax.nn.relu, "relu2": relu2}.get(cfg.act_fn, _gelu_tanh)
         if overlap:
             y = place.proj_down("bsf,fh->bsh", g, p["w2"], w_shard_dim=0, activation=act)
         else:
@@ -1582,6 +1618,8 @@ def mlp_residual(x, p, cfg: ModelConfig, train: bool = True, place: Placement = 
     the saved compute-dtype layer input. MoE layers fall back to the plain
     branch (dispatch buffers carry their own sharding pins; the router is
     deterministic but its recompute under a policy region is unvalidated)."""
+    if "mlp" not in p:  # the layer is its mixer alone (``mlp_layout``)
+        return (x, None) if cfg.moe_dropless else x
     routed = cfg.moe_experts > 0 and "router" in p["mlp"]
     if cfg.moe_dropless and routed:
         # a dropless top-k MoE layer hands the router's statistics up beside
@@ -2139,6 +2177,35 @@ def moe_loss_sum(params, batch, cfg: ModelConfig, layer_hook=None):
     return s, n, aux
 
 
+def blocks_to_layers(pattern: str, blocks: Optional[int] = None):
+    """A nemotron_h ``hybrid_override_pattern`` (one character a published block: ``M`` a
+    Mamba-2 mixer, ``*`` attention, ``E`` an expert MLP; each block ONE norm and ONE
+    sublayer, ``x + f(norm(x))``) as this program's layers -> ``(layer_kinds,
+    mlp_layout)``. Two such blocks in a row ARE the program's pre-norm layer, so a mixer
+    block and the ``E`` behind it make one layer with an MLP, and a mixer block followed
+    by another mixer is a layer of its mixer alone (``mlp_layout`` 0). ``blocks``: of the
+    first that many published blocks only (a model cut in depth: 26 of the 52 are the
+    first 15 layers). An ``E`` with no mixer in front of it is not this program's layer."""
+    kinds, mlps = [], []
+    for c in pattern[:blocks]:
+        if c == "E":
+            if not mlps or mlps[-1]:
+                raise ValueError(f"block pattern {pattern!r}: an expert block without a mixer "
+                                 "block in front of it is not a layer of this program")
+            mlps[-1] = 1
+        elif c in "M*":
+            kinds.append("ssm" if c == "M" else "attention")
+            mlps.append(0)
+        else:
+            raise ValueError(f"block pattern {pattern!r}: {c!r} is none of 'M', '*', 'E'")
+    return tuple(kinds), tuple(mlps)
+
+
+#: nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16's ``hybrid_override_pattern``: 52 blocks,
+#: 23 M / 23 E / 6 * = 29 program layers, 6 of them a mixer alone (the M before every *)
+NEMOTRON_3_NANO_PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+_NEMOTRON_KINDS, _NEMOTRON_MLPS = blocks_to_layers(NEMOTRON_3_NANO_PATTERN)
+
 # Preset configs mirroring the reference model zoo sizes
 # (galvatron/models/llama_hf/arguments.py:6, gpt_hf/arguments.py:6)
 PRESETS: Dict[str, ModelConfig] = {
@@ -2415,5 +2482,22 @@ PRESETS: Dict[str, ModelConfig] = {
         moe_experts=256, moe_router="sigmoid_topk", moe_top_k=4, moe_route_scale=2.448,
         moe_ffn_dim=3072, moe_norm_topk=True, moe_shared_ffn_dim=3072, moe_shared_gate=False,
         moe_dense_layers=6,
+    ),
+    # nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16 (model_type nemotron_h): 52 published
+    # blocks of ONE norm and ONE sublayer (`blocks_to_layers`: 29 program layers, 23
+    # Mamba-2 mixers of 64 heads x 64, state 128, 8 scan groups, conv 4, chunks of 128,
+    # the gate norm WITHIN each group; 6 GQA layers, 32 / 2 heads of 128, no rotary and no
+    # other position signal; 6 layers a mixer alone); 23 expert MLPs of 128 UN-GATED
+    # ``relu(x)^2`` experts of width 1856, sigmoid scores with a selection bias, top-6
+    # renormalised x 2.5, one ungated shared expert of 3712; untied head. Served
+    # (models/generation.py keeps a Mamba-2 layer's conv tail and scan state a row
+    # beside the attention layers' keys and values: models/ssm.py).
+    "nemotron-3-nano-30b-a3b": ModelConfig(
+        vocab_size=131072, hidden_size=2688, num_layers=29, num_heads=32, num_kv_heads=2,
+        attn_head_dim=128, ffn_dim=1856, max_seq_len=262144, pos_embed="nope", norm_eps=1e-5,
+        act_fn="relu2", layer_kinds=_NEMOTRON_KINDS, mlp_layout=_NEMOTRON_MLPS,
+        ssm_heads=64, ssm_head_dim=64, ssm_state=128, ssm_groups=8, ssm_conv=4, ssm_chunk=128,
+        moe_experts=128, moe_router="sigmoid_topk", moe_top_k=6, moe_route_scale=2.5,
+        moe_ffn_dim=1856, moe_norm_topk=True, moe_shared_ffn_dim=3712, moe_shared_gate=False,
     ),
 }

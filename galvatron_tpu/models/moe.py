@@ -112,6 +112,15 @@ def _glu_gate(cfg):
     return glu_gate(cfg)
 
 
+def ungated(cfg) -> bool:
+    """The experts (and the shared expert) are ``down(relu(up x)^2)``: one matrix in, no
+    gate (``act_fn`` "relu2", nemotron_h). Such a layer holds ``w1`` OUT-major, (E, f,
+    h), the published ``up_proj.weight``'s order (`grouped_matmul.grouped_matmul_t` says
+    why), ``w2`` (E, f, h) as ever and no ``w3``; its shared expert ``w1`` (h, fs) and
+    ``w2`` (fs, h)."""
+    return cfg.act_fn == "relu2"
+
+
 def init_moe_params(key, cfg) -> Params:
     """Router over all ``moe_experts`` + stacked expert FFN weights (leading
     dim: the experts this copy holds, ``cfg.moe_held``; all of them unless
@@ -135,11 +144,17 @@ def init_moe_params(key, cfg) -> Params:
         p["router"]["bias"] = jnp.zeros((cfg.moe_experts,), jnp.float32)
     if cfg.act_fn == "swiglu":
         p["w3"] = jax.random.uniform(ks[3], (e, h, f), cfg.param_dtype, -scale_in, scale_in)
+    if ungated(cfg):
+        p["w1"] = jnp.swapaxes(p["w1"], 1, 2)  # (E, f, h): `ungated`
     if cfg.moe_shared_ffn_dim:
         from galvatron_tpu.models.modeling import _dense_init
 
         fs = cfg.moe_shared_ffn_dim
         sk = jax.random.split(jax.random.fold_in(key, 1), 3)
+        if ungated(cfg):
+            p["shared"] = {"w1": _dense_init(sk[0], h, fs, cfg.param_dtype),
+                           "w2": _dense_init(sk[1], fs, h, cfg.param_dtype)}
+            return p
         p["shared"] = {  # [gate | up] fused, down
             "w13": _dense_init(sk[0], h, 2 * fs, cfg.param_dtype),
             "w2": _dense_init(sk[1], fs, h, cfg.param_dtype),
@@ -168,9 +183,11 @@ def moe_annotations(cfg) -> Params:
         a["w3"] = ("ep", "fsdp", "tp")
     if cfg.moe_router == "sigmoid_topk":
         a["router"]["bias"] = (None,)
+    if ungated(cfg):
+        a["w1"] = ("ep", "tp", "fsdp")  # (E, f, h)
     if cfg.moe_shared_ffn_dim:
         # whole on every device like the dropless experts (tp divides nothing there)
-        a["shared"] = {"w13": ("fsdp", None), "w2": (None, "fsdp")}
+        a["shared"] = {"w1" if ungated(cfg) else "w13": ("fsdp", None), "w2": (None, "fsdp")}
         if cfg.moe_shared_gate:
             a["shared"]["gate"] = (None, None)
     return a
@@ -439,7 +456,8 @@ def held_path_counts(cfg) -> dict:
     if cfg.moe_dropless and cfg.moe_holds_share:
         from galvatron_tpu.ops.moe_held import held_path  # (a dense run loads no kernels)
 
-        counts[held_path(cfg.hidden_size, cfg.expert_ffn, cfg.dtype)] = cfg.num_layers
+        counts[held_path(cfg.hidden_size, cfg.expert_ffn, cfg.dtype, not ungated(cfg))] = sum(
+            cfg.mlp_layers)
     return counts
 
 
@@ -644,7 +662,7 @@ def _topk_local(x, p, cfg, tile, over, router_x=None, forward_only=False):
     tile = tile or layer_row_tile(cfg, tokens, x.dtype)  # (trace-time, by shape)
     held_share = cfg.moe_holds_share  # trace-time: all held is the branch there always was
     # a share of the experts pays for the pairs it holds (trace-time, by shape)
-    bounded = held_share and held_path(h, cfg.expert_ffn, x.dtype) == "bounded"
+    bounded = held_share and held_path(h, cfg.expert_ffn, x.dtype, not ungated(cfg)) == "bounded"
     xt = x.reshape(tokens, h)
     sigmoid = cfg.moe_router == "sigmoid_topk"
     with jax.named_scope("router"):
@@ -689,15 +707,26 @@ def _topk_local(x, p, cfg, tile, over, router_x=None, forward_only=False):
         with jax.named_scope("dispatch"):
             rows = _dispatch(xt, layout.row_pair // k, layout.row_valid, layout.pair_row)
         with jax.named_scope("experts"):
-            w1, w3, w2 = (p[n].astype(x.dtype) for n in ("w1", "w3", "w2"))
-            gate = grouped_gemm(rows, w1, layout, tile)
-            up = grouped_gemm(rows, w3, layout, tile)
-            out = grouped_gemm(_glu_gate(cfg)(gate) * up, w2, layout, tile)
+            if ungated(cfg):
+                from galvatron_tpu.models.modeling import relu2
+                from galvatron_tpu.ops.grouped_matmul import grouped_matmul_t
+
+                up = grouped_matmul_t(rows, p["w1"].astype(x.dtype), layout.tile_group,
+                                      layout.num_tiles, tile)
+                with jax.named_scope("relu2"):
+                    mid = relu2(up)
+                out = grouped_gemm(mid, p["w2"].astype(x.dtype), layout, tile)
+            else:
+                w1, w3, w2 = (p[n].astype(x.dtype) for n in ("w1", "w3", "w2"))
+                gate = grouped_gemm(rows, w1, layout, tile)
+                up = grouped_gemm(rows, w3, layout, tile)
+                out = grouped_gemm(_glu_gate(cfg)(gate) * up, w2, layout, tile)
         with jax.named_scope("combine"):
             y = _combine(out, weights, layout.pair_row, layout.row_pair, layout.row_valid)
     if cfg.moe_shared_ffn_dim:
         with jax.named_scope("shared_expert"):
-            y = y + _shared_expert(xt, p["shared"], _glu_gate(cfg))
+            y = y + (_shared_ungated(xt, p["shared"]) if ungated(cfg)
+                     else _shared_expert(xt, p["shared"], _glu_gate(cfg)))
     # the statistics are over ALL the experts the router scores, held or not
     stats = router_stats(probs, _pairs_an_expert(idx, e) if held_share else layout.sizes)
     if held_share:
@@ -714,6 +743,14 @@ def _pairs_an_expert(idx, num_experts: int):
     comparison and sum (`jnp.bincount` is a scatter-add of the pairs: serial on the chip)."""
     return jnp.sum(idx.reshape(-1)[None, :] == jnp.arange(num_experts, dtype=idx.dtype)[:, None],
                    axis=1, dtype=jnp.int32)
+
+
+def _shared_ungated(xt, p):
+    """``down(relu(up x)^2)`` on (T, h): the un-gated shared expert, added as it is."""
+    from galvatron_tpu.models.modeling import relu2
+    from galvatron_tpu.ops.quant import project  # (a served int8 weight: its own GEMM)
+
+    return project(relu2(project(xt, p["w1"])), p["w2"])
 
 
 def _shared_expert(xt, p, act=jax.nn.silu):

@@ -6,22 +6,42 @@ layer of kind ``"ssm"`` runs in place of attention.
     [x | B | C] = xBC                             x as H heads of P
     dt = softplus(dt + dt_bias),  A = -exp(A_log)
     y = SSD(x, dt, A, B, C) + D x                 ops/ssd.py, chunked
-    y = RMSNorm(y * silu(z)) * scale              gate before the norm, over all d_inner
+    y = RMSNorm(y * silu(z)) * scale              gate before the norm, WITHIN each of the
+                                                  G groups of d_inner / G channels
     out = W_out y
 
-(HF ``modeling_granitemoehybrid.py`` GraniteMoeHybridMambaLayer; no projection
-biases, a conv bias.) Imported only where a configuration has such layers
+(HF ``modeling_granitemoehybrid.py`` GraniteMoeHybridMambaLayer, ``modeling_nemotron_h.py``
+NemotronHMamba2Mixer; no projection biases, a conv bias. Granite has one group, so its
+norm is over all of d_inner; nemotron_h's 8 groups of 512 each have their own
+statistics.) Imported only where a configuration has such layers
 (`models/mixers.py` names this module in the kind's row and says what it
 exposes), so every other model's imports stay what they were.
 
+The whole-sequence `block` is the training form. SERVED, the kind keeps a STATE of two
+parts a row and layer (``Mixer.state`` in `models/mixers.py`; `SsmState`, the ``state``
+of `models/generation.SlotStacks`): the conv's last ``K - 1`` inputs, ``(K - 1) x (d_inner
++ 2 G N)`` values in the compute type side by side (36,864 B at nemotron_h's sizes), and
+the scan's state, (N, H x P) FLOAT32 (2 MiB: a decay in bf16 loses the recurrence after a
+few hundred steps, and the state is what carries it from step to step). `cached_block` is
+the layer of the cached forwards: both parts read at the forward's start (ZERO where the
+forward starts at position 0, whatever the row held), written as of the forward's last
+REAL row. A forward of one position a row (a decode step) runs the SINGLE STEP,
+`ops/ssd.ssd_step`, over the state stack in place; a forward of more (a prompt chunk,
+``generate``'s prefill) the CHUNK form with the entering state, `ops/ssd.ssd_scan_plain`
+(the fused scan kernels carry no entering state yet and the fused conv no tail, so a
+served chunk takes the plain bodies: PERF.md section 7), padding after ``last`` kept out
+of the state by ``dt`` 0 there.
+
 Scopes under ``ssm``: ``in_proj``, ``conv``, ``scan``, ``gate_norm``,
-``out_proj`` (PERF.md §3; the ``ssm_*`` benchmark metrics read them).
+``out_proj`` (PERF.md §3; the ``ssm_*`` benchmark metrics read them); the cached
+forwards also ``state_read``, ``step`` (in place of ``scan`` in a decode step) and
+``state_write``.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict
+from typing import Any, Dict, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +49,8 @@ import numpy as np
 
 from galvatron_tpu.models.mixers import tally
 from galvatron_tpu.models.placement import LOCAL, Placement
+from galvatron_tpu.ops import ssd
+from galvatron_tpu.ops.quant import project as _project
 from galvatron_tpu.ops.ssd import causal_conv1d, conv_path, conv_silu_fused, scan_path, ssd_scan
 
 Params = Dict[str, Any]
@@ -152,7 +174,7 @@ def block(x, p: Params, cfg, place: Placement = LOCAL):
     heads, hd, groups, state = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state
     d_inner, conv_dim, _ = ssm_dims(cfg)
     with jax.named_scope("in_proj"):
-        zxbcdt = x @ p["in_proj"].astype(dtype)
+        zxbcdt = _project(x, p["in_proj"])
         z = zxbcdt[..., :d_inner]
         dt = zxbcdt[..., d_inner + conv_dim:]
     with jax.named_scope("conv"):
@@ -171,9 +193,133 @@ def block(x, p: Params, cfg, place: Placement = LOCAL):
         y = scan(xs, dt, -jnp.exp(p["A_log"].astype(F32)), b_mat, c_mat)
         y = (y.astype(F32) + p["D"].astype(F32)[:, None] * xs.astype(F32)).astype(dtype)
         y = y.reshape(*lead, d_inner)
+    return _gate_out(y, z, p, cfg)
+
+
+def _gate_out(y, z, p: Params, cfg):
+    """``W_out (RMSNorm(y * silu(z)) * scale)``, the norm within each scan group."""
+    dtype = y.dtype
     with jax.named_scope("gate_norm"):
         g = y.astype(F32) * jax.nn.silu(z.astype(F32))
-        g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + cfg.norm_eps)
-        y = (g * p["norm"].astype(F32)).astype(dtype)
+        grouped = g.reshape(*g.shape[:-1], cfg.ssm_groups, -1)
+        grouped = grouped * jax.lax.rsqrt(
+            jnp.mean(grouped * grouped, axis=-1, keepdims=True) + cfg.norm_eps)
+        y = (grouped.reshape(g.shape) * p["norm"].astype(F32)).astype(dtype)
     with jax.named_scope("out_proj"):
-        return y @ p["out_proj"].astype(dtype)
+        return _project(y, p["out_proj"])
+
+
+# -- the state the cached forwards keep ------------------------------------------------
+
+
+class SsmState(NamedTuple):
+    """The state stack of a stack's Mamba-2 layers: ``conv`` (layers, rows, (K - 1) x
+    conv channels) in the compute type, ``scan`` (layers, rows, N, H x P) float32."""
+
+    conv: jax.Array
+    scan: jax.Array
+
+
+def state_shapes(cfg) -> dict:
+    """What a row keeps of one layer, by part: its trailing shape and type. The conv's
+    tail lies side by side (`models/shortconv.state_shape`'s reason), the scan's state
+    as `ops/ssd.state_shape` has it."""
+    return {"conv": (((cfg.ssm_conv - 1) * ssm_dims(cfg)[1],), jnp.dtype(cfg.dtype)),
+            "scan": (ssd.state_shape(cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
+                     jnp.dtype(F32))}
+
+
+def init_state(cfg, layers: int, rows: int) -> SsmState:
+    """The state stack of ``layers`` such layers over ``rows`` rows, zero."""
+    return SsmState(**{part: jnp.zeros((layers, rows) + shape, dtype)
+                       for part, (shape, dtype) in state_shapes(cfg).items()})
+
+
+def state_part_bytes(cfg) -> dict:
+    """One layer's bytes a row, by part."""
+    return {part: int(np.prod(shape)) * dtype.itemsize
+            for part, (shape, dtype) in state_shapes(cfg).items()}
+
+
+def state_bytes_per_row(cfg) -> int:
+    """One layer's, both parts."""
+    return sum(state_part_bytes(cfg).values())
+
+
+def _row_of(stack, layer: int, slot):
+    """Layer ``layer``'s entries of a state stack: every row, or row ``slot`` alone."""
+    if slot is None:
+        return stack[layer]
+    return jax.lax.dynamic_slice(
+        stack, (layer, slot) + (0,) * (stack.ndim - 2), (1, 1) + stack.shape[2:])[0]
+
+
+def _write_rows(stack, layer: int, slot, new):
+    """`_row_of`'s inverse: one ``dynamic_update_slice`` on the stacked array at a static
+    layer (`generation.write_layer`'s rule)."""
+    return jax.lax.dynamic_update_slice(
+        stack, new.astype(stack.dtype)[None],
+        (layer, 0 if slot is None else slot) + (0,) * (stack.ndim - 2))
+
+
+@jax.named_scope("ssm")
+def cached_block(x, p: Params, cfg, state: SsmState, layer: int, slot, offsets, last):
+    """The layer over the state stack -> (y, state). ``x`` (B, s, hidden) is the normed
+    input of the forward's ``s`` new positions at ``offsets`` (`generation.
+    forward_with_cache`'s: a scalar, with ``slot`` one row of the stack; or a row each);
+    ``last`` (traced) is the forward's last REAL row of the s: rows after it are padding
+    and reach neither part of the state.
+
+    A forward that starts at position 0 reads ZERO for both parts whatever the row holds
+    (the slot's previous request, an idle row's decode steps): no admission has to clear
+    anything. ``s`` 1 is a decode step: the conv over [tail | the position], the single
+    step over the scan's stack in place. ``s`` > 1 is a chunk: the conv over [tail | the
+    chunk], the chunked scan from the entering state, ``dt`` 0 after ``last``."""
+    b, s, _ = x.shape
+    dtype = x.dtype
+    heads, hd, groups, n, k = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state,
+                               cfg.ssm_conv)
+    d_inner, conv_dim, _ = ssm_dims(cfg)
+    started = jnp.broadcast_to(jnp.reshape(jnp.asarray(offsets), (-1,)) > 0, (b,))
+    with jax.named_scope("in_proj"):
+        zxbcdt = _project(x, p["in_proj"])
+        z = zxbcdt[..., :d_inner]
+        xbc = zxbcdt[..., d_inner:d_inner + conv_dim]
+        dt = jax.nn.softplus(zxbcdt[..., d_inner + conv_dim:].astype(F32)
+                             + p["dt_bias"].astype(F32))
+    with jax.named_scope("state_read"):
+        tail = _row_of(state.conv, layer, slot).reshape(b, k - 1, conv_dim)
+        tail = jnp.where(started[:, None, None], tail, jnp.zeros_like(tail)).astype(dtype)
+    with jax.named_scope("conv"):
+        seen = jnp.concatenate([tail, xbc], axis=1)  # (B, K - 1 + s, conv channels)
+        conv = jax.nn.silu(causal_conv1d(seen, p["conv_w"], p["conv_b"])[:, k - 1:])
+        xs = conv[..., :d_inner].reshape(b, s, heads, hd)
+        b_mat = conv[..., d_inner:d_inner + groups * n].reshape(b, s, groups, n)
+        c_mat = conv[..., d_inner + groups * n:].reshape(b, s, groups, n)
+    a = -jnp.exp(p["A_log"].astype(F32))
+    if s == 1:
+        with jax.named_scope("step"):
+            y, scan = ssd.ssd_step(state.scan, layer, xs[:, 0], dt[:, 0], a, b_mat[:, 0],
+                                   c_mat[:, 0], started)
+            y = y[:, None]
+    else:
+        with jax.named_scope("state_read"):
+            entering = ssd.read_rows(state.scan, layer, slot, b)
+            entering = jnp.where(started[:, None, None], entering, jnp.zeros_like(entering))
+        with jax.named_scope("scan"):
+            # padding after the last real row: no decay, no input, so the state the
+            # scan leaves with is the state as of ``last``
+            real = (jnp.arange(s) <= last)[None, :, None]
+            y, leaving = ssd.ssd_scan_plain(xs, jnp.where(real, dt, 0.0), a, b_mat, c_mat,
+                                            cfg.ssm_chunk, state=entering)
+    with jax.named_scope("scan" if s > 1 else "step"):
+        y = (y.astype(F32) + p["D"].astype(F32)[:, None] * xs.astype(F32)).astype(dtype)
+        y = y.reshape(b, s, d_inner)
+    with jax.named_scope("state_write"):
+        # the conv's inputs of the K - 1 positions up to row ``last``: row t of the
+        # forward is row K - 1 + t of ``seen``
+        new_tail = jax.lax.dynamic_slice_in_dim(seen, last + 1, k - 1, axis=1)
+        conv_stack = _write_rows(state.conv, layer, slot, new_tail.reshape(b, -1))
+        if s > 1:
+            scan = ssd.write_rows(state.scan, layer, slot, leaving)
+    return _gate_out(y, z, p, cfg), SsmState(conv_stack, scan)

@@ -234,6 +234,37 @@ def _bwd(tile_m, tile_n, res, grad):
 grouped_matmul.defvjp(_fwd, _bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def grouped_matmul_t(lhs, rhs_t, tile_group, num_tiles, tile_m: int = TILE_M, tile_n: int = 1024):
+    """`grouped_matmul` against weights stored OUT-major: ``lhs`` (M, K) by ``rhs_t``
+    (G, N, K) -> (M, N), row tile ``i`` against ``rhs_t[tile_group[i]].T`` (the kernel
+    ``moe_gmm_dlhs``, which contracts the last dimension of both). For a weight whose
+    output width is no whole number of 128-lane tiles (nemotron_h's experts: 2688 ->
+    1856): stored (G, K, N) the chip lays such a stack K-minor, to spare the padding of
+    N, and re-lays ALL of it in front of every Mosaic call (compiled for a described
+    v5e: a 330 MB copy a layer and step); stored (G, N, K) the minor dimension is whole
+    tiles and the kernel reads the stack where it lies."""
+    return _gmm(lhs, rhs_t, tile_group, num_tiles, transpose_rhs=True,
+                tile_m=tile_m, tile_n=tile_n)
+
+
+def _fwd_t(lhs, rhs_t, tile_group, num_tiles, tile_m, tile_n):
+    out = grouped_matmul_t(lhs, rhs_t, tile_group, num_tiles, tile_m, tile_n)
+    return out, (lhs, rhs_t, tile_group, num_tiles)
+
+
+def _bwd_t(tile_m, tile_n, res, grad):
+    lhs, rhs_t, tile_group, num_tiles = res
+    dlhs = _gmm(grad, rhs_t, tile_group, num_tiles, transpose_rhs=False,
+                tile_m=tile_m, tile_n=tile_n)
+    drhs_t = _tgmm(grad, lhs, tile_group, num_tiles, rhs_t.shape[0], tile_m=tile_m,
+                   tile_n=tile_n, out_dtype=rhs_t.dtype)
+    return dlhs, drhs_t, None, None
+
+
+grouped_matmul_t.defvjp(_fwd_t, _bwd_t)
+
+
 def held_matmul(lhs, rhs, tile_group, num_tiles, *, tile_m: int = TILE_M,
                 transpose_rhs: bool = False, slab_out: bool = False):
     """`grouped_matmul`'s product (or, ``transpose_rhs``, its left gradient) for a

@@ -55,15 +55,21 @@ def per_word(dtype) -> int:
     return 2 if jnp.dtype(dtype) == jnp.bfloat16 else 1
 
 
-def held_path(hidden: int, width: int, dtype) -> str:
+def held_path(hidden: int, width: int, dtype, gated: bool = True) -> str:
     """``"bounded"`` or ``"worst_case"`` for a held share of these sizes, from the
     shapes alone: no flag, no environment variable.  `models/moe._topk_local` and
     `models/moe.held_path_counts` both ask here.  The bounded kernels take bf16 or
     float32 rows that are whole slab chunks (``hidden`` a multiple of 256, of 128
     in float32) and an expert width of whole lane tiles; everything else keeps the
-    plain path over the whole buffer."""
+    plain path over the whole buffer.  So do UN-GATED experts (``gated`` False,
+    ``act_fn`` "relu2"): the bounded body is built on the fused ``[gate | up]`` buffer
+    and `swiglu`; and nemotron_h's sizes are outside it twice over anyway (hidden
+    2688 = 21 x 128, an odd number of the lane tiles that `to_slab` packs in pairs;
+    width 1856 = 14.5 x 128).  What the plain path costs such a layer is the experts
+    no token chose: every held expert owns a tile and has its weights fetched, 32 of
+    32 where a decode step of 64 rows touches ~95% (PERF.md section 5)."""
     dtype = jnp.dtype(dtype)
-    if dtype not in (jnp.bfloat16, jnp.float32):
+    if dtype not in (jnp.bfloat16, jnp.float32) or not gated:
         return "worst_case"
     inside = hidden % (LANES * per_word(dtype)) == 0 and width % LANES == 0
     return "bounded" if inside else "worst_case"

@@ -149,6 +149,12 @@ def qmatmul(x, qw: QuantTensor):
     return (y * qw.scale).astype(x.dtype)
 
 
+def project(x, w):
+    """``x @ w`` in ``x``'s type for a weight that may be served int8: a `QuantTensor`
+    dequantises inside its GEMM, anything else is cast to ``x``'s type."""
+    return qmatmul(x, w) if isinstance(w, QuantTensor) else x @ w.astype(x.dtype)
+
+
 # weight keys eligible for quantization, per param sub-dict. Biases, norms,
 # and the embedding table (a gather, not a GEMM) stay in the param dtype;
 # MoE experts keep fp too (the dispatch einsums contract over the expert
@@ -162,6 +168,12 @@ _MLA_KEYS = ("wq", "wqa", "wqb", "wkva", "wo", "wgate")
 #: the indexer's three projections of such a layer (``p["index"]``): GEMMs like the others,
 #: and the ones a selection hangs on
 _MLA_INDEX_KEYS = ("wq", "wk", "ww")
+#: a Mamba-2 mixer's two projections (``p["ssm"]``), and an UN-GATED shared expert's two
+#: matrices (``p["mlp"]["shared"]`` with ``w1``: nemotron_h's; a gated one's ``w13`` stays
+#: fp, as the cells that have one were read): plain GEMMs, most of what a token is
+#: multiplied by outside the routed experts in such a stack
+_SSM_KEYS = ("in_proj", "out_proj")
+_SHARED_UNGATED_KEYS = ("w1", "w2")
 
 
 def quantize_params(params: Dict[str, Any], cfg) -> Dict[str, Any]:
@@ -173,7 +185,8 @@ def quantize_params(params: Dict[str, Any], cfg) -> Dict[str, Any]:
     layers = []
     for layer in params.get("layers", []):
         lp = dict(layer)
-        for group, keys in (("attn", _ATTN_KEYS), ("cross", _CROSS_KEYS), ("mla", _MLA_KEYS)):
+        for group, keys in (("attn", _ATTN_KEYS), ("cross", _CROSS_KEYS), ("mla", _MLA_KEYS),
+                            ("ssm", _SSM_KEYS)):
             if group in lp:
                 gp = dict(lp[group])
                 for k in keys:
@@ -192,6 +205,12 @@ def quantize_params(params: Dict[str, Any], cfg) -> Dict[str, Any]:
                 if k in mp and not isinstance(mp[k], QuantTensor):
                     mp[k] = quantize_int8(mp[k])
             lp["mlp"] = mp
+        elif "mlp" in lp and "w1" in lp["mlp"].get("shared", {}):
+            shared = dict(lp["mlp"]["shared"])
+            for k in _SHARED_UNGATED_KEYS:
+                if not isinstance(shared[k], QuantTensor):
+                    shared[k] = quantize_int8(shared[k])
+            lp["mlp"] = dict(lp["mlp"], shared=shared)
         layers.append(lp)
     if layers:
         out["layers"] = layers

@@ -61,12 +61,17 @@ def causal_conv1d(x, w, b):
     return y.astype(x.dtype)
 
 
-def ssd_scan_plain(x, dt, a, b_mat, c_mat, chunk: int):
+def ssd_scan_plain(x, dt, a, b_mat, c_mat, chunk: int, state=None):
     """Chunked SSD: ``x`` (B, S, H, P) in the compute dtype, ``dt`` (B, S, H)
     float32 and positive, ``a`` (H,) float32 and negative, ``b_mat`` / ``c_mat``
     (B, S, G, N) -> ``y`` (B, S, H, P) in ``x``'s dtype, without the ``D x``
     skip. A sequence that ``chunk`` does not divide is padded at its end
     (``dt`` 0 there: no decay, no input; causal, so nothing earlier moves).
+
+    ``state`` (B, N, H x P) float32 (`state_shape`'s order: the cached forwards'):
+    the state the sequence ENTERS with; the call then returns ``(y, state)``, the state
+    it leaves with. A position whose ``dt`` is 0 neither decays nor adds to it, which
+    is how a caller keeps padding after a chunk's last real row out of the state.
 
     Everything between the two transposes is head-major, (B, G, R, chunks, L,
     ...) with H = G x R: the score blocks differ by head, so the batched GEMMs
@@ -108,15 +113,198 @@ def ssd_scan_plain(x, dt, a, b_mat, c_mat, chunk: int):
         dec, add = inp
         return state * dec[..., None, None] + add, state
 
-    _, entering = jax.lax.scan(
-        carry, jnp.zeros((bsz, g, r, p, n), F32),
-        (jnp.moveaxis(jnp.exp(cum[..., -1]), -1, 0), added))
+    start = (jnp.zeros((bsz, g, r, p, n), F32) if state is None
+             else state.astype(F32).reshape(bsz, n, g, r, p).transpose(0, 2, 3, 4, 1))
+    leaving, entering = jax.lax.scan(
+        carry, start, (jnp.moveaxis(jnp.exp(cum[..., -1]), -1, 0), added))
 
     # 4. the entering state read out at every position of its chunk
     y_off = jnp.einsum("bgcln,cbgrpn->bgrclp", cc, entering.astype(dtype),
                        preferred_element_type=F32)
     y = (y + y_off * jnp.exp(cum)[..., None]).astype(dtype)
-    return y.reshape(bsz, h, nc * chunk, p).transpose(0, 2, 1, 3)[:, :s]
+    y = y.reshape(bsz, h, nc * chunk, p).transpose(0, 2, 1, 3)[:, :s]
+    if state is None:
+        return y
+    return y, leaving.transpose(0, 4, 1, 2, 3).reshape(bsz, n, h * p)
+
+
+# -- the single step over a stack of states -----------------------------------------
+#
+# One decode step of a served Mamba-2 layer: every row of the slot cache advances its
+# state by ONE position, ``H <- exp(dt A) H + dt B x^T``, ``y = C H``. The states of
+# all such layers are one stack (layers, rows, N, H x P) float32 (`state_shape`: the
+# state dimension on the sublanes, a head's P values side by side on the lanes, the
+# order the fused scan's scratch has), 2 MiB a row and layer at the published sizes,
+# 1.6 GB over 64 rows x 12 layers: the step reads and writes all of it, so it must do
+# so ONCE and in place. `ssm_step` is a Pallas kernel over the stack itself (block
+# index maps at a static layer, ``input_output_aliases``: no slab of the stack is
+# sliced out or copied back); the plain body is its reference and the CPU's path.
+
+
+def state_shape(heads: int, head_dim: int, state: int) -> tuple:
+    """What a row keeps of one layer's scan: (N, H x P) float32."""
+    return (state, heads * head_dim)
+
+
+_STEP_BLOCK_BYTES = 1 << 20  # of state a grid step: in and out, two buffers each
+
+
+def _step_groups(groups: int, width: int, state: int) -> int:
+    """Scan groups a grid step of `ssm_step` takes: the most that keep its block of
+    the state inside `_STEP_BLOCK_BYTES` (4 of 8 at the published sizes: 1 MiB)."""
+    gb = groups
+    while gb > 1 and (gb * width * state * 4 > _STEP_BLOCK_BYTES or groups % gb):
+        gb -= 1
+    return gb
+
+
+def step_path(heads: int, head_dim: int, groups: int, state: int) -> str:
+    """``"kernel"`` or ``"plain"`` for the single step of these sizes, from the shapes
+    and the backend alone (`scan_path`'s rule): the kernel on a chip where a group's
+    heads fill whole 128-lane tiles and the state dimension whole sublane tiles."""
+    if heads % max(groups, 1):
+        return "plain"
+    return rows_path(heads // groups * head_dim, state)
+
+
+def rows_path(width: int, state: int) -> str:
+    """``"kernel"`` or ``"plain"`` for a (N, width) float32 block of the state stack
+    (`read_rows` / `write_rows` move a row's whole (N, H x P), the step a group's
+    lanes of it): the kernel on a chip where ``width`` fills whole 128-lane tiles
+    and ``state`` whole sublane tiles."""
+    if pallas_common.use_interpret():
+        return "plain"
+    return "kernel" if width % _LANES == 0 and state % 8 == 0 else "plain"
+
+
+def ssd_step(stack, layer: int, x, dt, a, b_mat, c_mat, started):
+    """One position of every row: ``stack`` (layers, rows, N, H x P) float32, ``x``
+    (rows, H, P), ``dt`` (rows, H) float32 and positive, ``a`` (H,), ``b_mat`` /
+    ``c_mat`` (rows, G, N), ``started`` (rows,) bool: False reads the row's state ZERO
+    whatever it holds -> ``(y (rows, H, P) float32 without the D x skip, stack)`` with
+    layer ``layer``'s states advanced in place. Float32 throughout (no GEMM: the
+    vector unit's work, and the memory's)."""
+    rows, h, p = x.shape
+    g, n = b_mat.shape[1:]
+    decay = jnp.where(started[:, None], jnp.exp(dt.astype(F32) * a.astype(F32)[None]), 0.0)
+    decay = jnp.repeat(decay, p, axis=1)  # (rows, H x P): a head's decay on its lanes
+    dtx = (dt.astype(F32)[..., None] * x.astype(F32)).reshape(rows, h * p)
+    b32, c32 = b_mat.astype(F32), c_mat.astype(F32)
+    if step_path(h, p, g, n) == "kernel":
+        y, stack = _step_call(stack, layer, decay, dtx, b32, c32)
+    else:
+        y, stack = ssd_step_plain(stack, layer, decay, dtx, b32, c32)
+    return y.reshape(rows, h, p), stack
+
+
+def ssd_step_plain(stack, layer: int, decay, dtx, b32, c32):
+    """`ssd_step`'s body in plain ``jax.numpy``: the kernel's reference."""
+    rows, g, n = b32.shape
+    prev = stack[layer].reshape(rows, n, g, -1)
+    # (``where``, not a product with 0: what an unstarted row holds may be anything)
+    decay = decay.reshape(rows, 1, g, -1)
+    new = (jnp.where(decay > 0, prev * decay, 0.0)
+           + jnp.einsum("bgn,bgq->bngq", b32, dtx.reshape(rows, g, -1)))
+    y = jnp.einsum("bgn,bngq->bgq", c32, new).reshape(rows, -1)
+    stack = jax.lax.dynamic_update_slice(
+        stack, new.reshape((1, rows, n, -1)).astype(stack.dtype), (layer, 0, 0, 0))
+    return y, stack
+
+
+def _step_kernel(s_ref, decay_ref, dtx_ref, b_ref, c_ref, out_ref, y_ref, *, width):
+    for i in range(b_ref.shape[1]):  # the block's groups: B and C differ by group
+        lanes = slice(i * width, (i + 1) * width)
+        decay = decay_ref[0, :, lanes]  # (1, width)
+        new = (jnp.where(decay > 0, s_ref[0, 0, :, lanes] * decay, 0.0)
+               + b_ref[0, i] * dtx_ref[0, :, lanes])  # (N, 1) x (1, width)
+        out_ref[0, 0, :, lanes] = new.astype(out_ref.dtype)
+        y_ref[0, :, lanes] = jnp.sum(new * c_ref[0, i], axis=0, keepdims=True)
+
+
+def _step_call(stack, layer, decay, dtx, b32, c32):
+    rows, g, n = b32.shape
+    width = stack.shape[3] // g
+    gb = _step_groups(g, width, n)
+    states = pl.BlockSpec((1, 1, n, gb * width), lambda r, j: (layer, r, 0, j))
+    lanes = pl.BlockSpec((1, 1, gb * width), lambda r, j: (r, 0, j))
+    group = pl.BlockSpec((1, gb, n, 1), lambda r, j: (r, j, 0, 0))
+    out, y = pl.pallas_call(
+        functools.partial(_step_kernel, width=width),
+        grid=(rows, g // gb), in_specs=[states, lanes, lanes, group, group],
+        out_specs=[states, lanes],
+        out_shape=[jax.ShapeDtypeStruct(stack.shape, stack.dtype),
+                   jax.ShapeDtypeStruct((rows, 1, stack.shape[3]), F32)],
+        input_output_aliases={0: 0},
+        compiler_params=pallas_common.compiler_params(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=pallas_common.use_interpret(), name="ssm_step",
+    )(stack, decay[:, None], dtx[:, None], b32[..., None], c32[..., None])
+    return y[:, 0], out
+
+
+# A prompt chunk reads the state its row ENTERS with out of the stack and writes back the
+# state it LEAVES with. As ``dynamic_slice`` / ``dynamic_update_slice`` the chip's compiler
+# gave the WHOLE stack the layout its consumer liked (the chunked scan's batched GEMMs want
+# the state dimension minor) and copied all 1.6 GB of it into that layout and back around
+# every chunk (compiled for a described v5e; the chip's first traced run: two copies of
+# f32[12,64,128,4096], 53 ms a chunk each). A Mosaic call takes its operands as they lie,
+# so on a chip the rows go through two small kernels, `ssm_state_read` / `ssm_state_write`
+# (a row a grid step, the stack itself the operand, written in place), and the compiler is
+# left to re-lay the 2 MiB it was handed.
+
+
+def _first_row(slot):
+    """``slot`` (traced, or None: row 0) as the (1,) int32 a kernel prefetches."""
+    return jnp.zeros((1,), jnp.int32) if slot is None else jnp.reshape(slot, (1,)).astype(jnp.int32)
+
+
+def read_rows(stack, layer: int, slot, rows: int):
+    """Rows [slot, slot + rows) of layer ``layer`` of the state stack (layers, rows, N,
+    H x P) -> (rows, N, H x P); ``slot`` traced (None: 0)."""
+    slot = _first_row(slot)
+    n, width = stack.shape[2:]
+    if rows_path(width, n) != "kernel":
+        return jax.lax.dynamic_slice(stack, (layer, slot[0], 0, 0), (1, rows, n, width))[0]
+
+    def kernel(slot_ref, s_ref, out_ref):
+        del slot_ref
+        out_ref[...] = s_ref[0]
+
+    return pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct((rows, n, width), stack.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(rows,),
+            in_specs=[pl.BlockSpec((1, 1, n, width), lambda i, at: (layer, at[0] + i, 0, 0))],
+            out_specs=pl.BlockSpec((1, n, width), lambda i, at: (i, 0, 0))),
+        compiler_params=pallas_common.compiler_params(dimension_semantics=("arbitrary",)),
+        interpret=pallas_common.use_interpret(), name="ssm_state_read",
+    )(slot, stack)
+
+
+def write_rows(stack, layer: int, slot, new):
+    """`read_rows`' inverse: ``new`` (rows, N, H x P) into rows [slot, slot + rows) of
+    layer ``layer``, in place -> the stack."""
+    slot = _first_row(slot)
+    rows, n, width = new.shape
+    new = new.astype(stack.dtype)
+    if rows_path(width, n) != "kernel":
+        return jax.lax.dynamic_update_slice(stack, new[None], (layer, slot[0], 0, 0))
+
+    def kernel(slot_ref, new_ref, s_ref, out_ref):
+        del slot_ref, s_ref  # (the stack is the output's alias: nothing of it is read)
+        out_ref[0] = new_ref[...]
+
+    return pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct(stack.shape, stack.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(rows,),
+            in_specs=[pl.BlockSpec((1, n, width), lambda i, at: (i, 0, 0)),
+                      pl.BlockSpec(memory_space=pltpu.ANY)],
+            out_specs=pl.BlockSpec((1, 1, n, width), lambda i, at: (layer, at[0] + i, 0, 0))),
+        input_output_aliases={2: 0},
+        compiler_params=pallas_common.compiler_params(dimension_semantics=("arbitrary",)),
+        interpret=pallas_common.use_interpret(), name="ssm_state_write",
+    )(slot, new, stack)
 
 
 # -- the fused kernels ---------------------------------------------------------

@@ -65,11 +65,14 @@ def moe_untp_time_fraction(cfg: ModelConfig, seq_len: int) -> float:
     return routed / total
 
 
-def layer_param_count(cfg: ModelConfig, cross: bool = False, kind: str = "attention") -> int:
+def layer_param_count(cfg: ModelConfig, cross: bool = False, kind: str = "attention",
+                      mlp: bool = True) -> int:
     """Exact per-layer parameter count (matches init_layer_params).
     ``cross``: enc-dec decoder layers carry a cross-attention block
     (wq + wkv + wo + cross_norm). ``kind``: a kind of ``mixers.MIXERS`` (a
-    hybrid stack's layer) counts that kind's mixer in place of attention."""
+    hybrid stack's layer) counts that kind's mixer in place of attention. ``mlp``
+    False: the layer is its mixer alone (``ModelConfig.mlp_layout``): no MLP, one
+    norm. (Times and activations price such a layer as one WITH an MLP still.)"""
     h, hd = cfg.hidden_size, cfg.head_dim
     q_out, kv_out = cfg.num_heads * hd, cfg.kv_heads * hd
     attn = h * q_out + 2 * h * kv_out + q_out * h
@@ -80,6 +83,7 @@ def layer_param_count(cfg: ModelConfig, cross: bool = False, kind: str = "attent
     if cross:
         attn += h * q_out + 2 * h * kv_out + q_out * h
         attn += h if cfg.norm_type == "rms" else 2 * h  # cross_norm
+    has_mlp = mlp
     if cfg.moe_experts > 0:
         # router (+ its selection bias) + per-expert MLPs (+ the shared expert and its
         # gate); a leading dense layer (moe_dense_layers) is priced as an expert layer
@@ -87,12 +91,15 @@ def layer_param_count(cfg: ModelConfig, cross: bool = False, kind: str = "attent
         if cfg.moe_router == "sigmoid_topk":
             mlp += cfg.moe_experts
         if cfg.moe_shared_ffn_dim:
-            mlp += 3 * h * cfg.moe_shared_ffn_dim + (h if cfg.moe_shared_gate else 0)
+            mats = 3 if cfg.act_fn == "swiglu" else 2
+            mlp += mats * h * cfg.moe_shared_ffn_dim + (h if cfg.moe_shared_gate else 0)
     elif cfg.act_fn == "swiglu":
         mlp = 3 * h * cfg.ffn
     else:
         mlp = 2 * h * cfg.ffn
     norms = (4 if cfg.post_norms else 2) * (h if cfg.norm_type == "rms" else 2 * h)
+    if not has_mlp:
+        mlp, norms = 0, norms // 2
     if cfg.qk_norm and kind not in mixers.MIXERS:
         norms += 2 * hd if cfg.qk_norm_per_head else q_out + kv_out
     bias = 0
@@ -135,7 +142,7 @@ def total_param_count(cfg: ModelConfig) -> int:
         )
         return layers + other_param_count(cfg)
     # (a layer under its own view: a latent stack's window layers have sizes of their own)
-    return sum(layer_param_count(cfg.layer_view(i), kind=k)
+    return sum(layer_param_count(cfg.layer_view(i), kind=k, mlp=cfg.mlp_layers[i])
                for i, k in enumerate(cfg.kinds)) + other_param_count(cfg)
 
 

@@ -745,6 +745,14 @@ class Engine:
             if "state_layers" in layout:
                 out.update(state_layers=layout["state_layers"],
                            state_bytes_per_row=layout["state_bytes_per_row"])
+            if "state_part_bytes" in layout:
+                # a state of several parts (a Mamba-2 layer's conv tail and scan state):
+                # ONE layer's bytes a row by part, and what a decode step's state traffic
+                # moves by construction: every row's state read once and written once
+                out.update({f"state_{part}_bytes_per_row": size
+                            for part, size in layout["state_part_bytes"].items()})
+                out["state_step_bytes"] = (2 * self.slots.num_slots * layout["state_layers"]
+                                           * layout["state_bytes_per_row"])
             out.update(
                 kv_full_live_positions=sum(live),
                 kv_window_live_positions=sum(min(n, span) for n in live),

@@ -106,8 +106,51 @@ _PINNED_TO_THE_MANIFEST_BEFORE_PR_67 = frozenset(
      "test_the_cell_joins_no_list_but_its_own_metrics_and_the_rate"])
 
 
+#: Nine accepted cases of tests/benchmark cannot hold once a serving cell with Mamba-2 layers
+#: joins.  `test_benchmark_moe_layout.py`'s copy of the dots3 case pins that cell to the LAST of
+#: 13 workloads, 10 configurations and 89 readers (any appended cell breaks it).
+#: `test_benchmark_granite.py`'s tiny cell takes every per-layer metric whose name starts with
+#: ``ssm_`` for a reader with a ``workloads`` list: ISSUE 68 NAMES the decode step's readers
+#: ``ssm_decode_ms_per_step``, ``ssm_step_ms_per_step``, ``ssm_state_hbm_roofline`` (and a
+#: kernel's share by the kernel's name, ``ssm_step_roofline``) and asks that they answer in
+#: every serving cell, so they carry no list; giving them one would break the four cases
+#: below instead.  Four cases of `test_benchmark_chunk_lists.py` and the three of
+#: `test_benchmark_dots3.py`'s chunk-reader case hold that exactly three serving readers carry
+#: a list, each the five cells accepted before PR 65: PR 68's three prompt-chunk readers
+#: (``ssm_prefill_chunk_ms``, ``ssm_chunk_scan_ms``, ``ssm_chunk_scan_roofline``: None where
+#: a profile holds no chunk, so listed, by the driver's rule) and the cell appended to
+#: ``kv_prefill_chunk_attn_ms``'s list make that false.  Only a PR of kind ``benchmark`` may
+#: edit those files.  They are expected to fail, strictly, until such a PR relaxes them in
+#: place and deletes this list.  Nothing else they assert is off meanwhile: the first two
+#: stand whole, one clause amended, in tests/benchmark/test_benchmark_nemotron.py, which RUNS
+#: the other seven's own bodies whole under the amended lists; its last case holds this list
+#: and those one for one.
+_PINNED_BEFORE_PR_68 = {
+    "tests/benchmark/test_benchmark_moe_layout.py::"
+    "test_dots3_the_cell_joins_the_manifest_by_appends": AssertionError,
+    "tests/benchmark/test_benchmark_granite.py::test_whole_cell_tiny": KeyError,
+}
+_PINNED_BEFORE_PR_68.update({"tests/benchmark/test_benchmark_chunk_lists.py::" + case: AssertionError
+                             for case in (
+    "test_metrics", "test_smallthinker_metric_is_declared_as_a_serving_reader",
+    "test_the_latent_cell_still_reads_the_rate_and_every_serving_reader",
+    "test_a_profile_without_a_prompt_chunk_leaves_the_chunk_readers_silent"
+    "[kv_prefill_chunk_attn_ms]")})
+_PINNED_BEFORE_PR_68.update({
+    "tests/benchmark/test_benchmark_dots3.py::test_a_prompt_chunk_reader_lists_the_five_accepted_"
+    "serving_cells[%s]" % name: AssertionError
+    for name in ("mla_prefill_chunk_attn_ms", "kv_prefill_chunk_attn_ms",
+                 "shortconv_prefill_chunk_ms")})
+
+
 def pytest_collection_modifyitems(items):
     for item in items:
+        if item.nodeid in _PINNED_BEFORE_PR_68:
+            item.add_marker(pytest.mark.xfail(
+                strict=True, raises=_PINNED_BEFORE_PR_68[item.nodeid],
+                reason="pins the manifest as PR 67 left it, every `ssm_*` reader to a `workloads` "
+                       "list, or the listed serving readers to three; PR 68 appended a cell, "
+                       "four unlisted serving readers and three listed ones"))
         if item.nodeid in _PINNED_TO_THE_MANIFEST_BEFORE_PR_67:
             item.add_marker(pytest.mark.xfail(
                 strict=True, raises=AssertionError,

@@ -389,10 +389,14 @@ def test_lock_metrics_and_contention(armed):
     assert m["acquired_total"] == 1
     assert m["hold_ms"] > 0
     # contention: a second thread blocks while we hold the lock
+    # (the hold starts to count once the thread RUNS: under six test workers a thread has
+    # taken longer than a fixed sleep to be scheduled at all, and found the lock free)
     l.acquire()
-    t = threading.Thread(target=lambda: (l.acquire(), l.release()))
+    running = threading.Event()
+    t = threading.Thread(target=lambda: (running.set(), l.acquire(), l.release()))
     t.start()
-    time.sleep(0.05)
+    assert running.wait(timeout=5)
+    time.sleep(0.5)
     l.release()
     t.join(timeout=5)
     assert locks.lock_metrics()["m"]["contended_total"] >= 1

@@ -249,7 +249,7 @@ def _breaking(cfg, limit):
 
 
 def test_the_three_presets_yield_what_the_issue_counts():
-    assert [len(mixers.limits(cut(p))) for p in CUT] == [5, 9, 4, 9, 10] and len(CASES) == 37
+    assert [len(mixers.limits(cut(p))) for p in CUT] == [6, 9, 4, 9, 10] and len(CASES) == 38
     # a windowed stack's five (another attention path, cp, packing, pp, the paged backend)
     assert [t.what for t in mixers.limits(cut("smallthinker-21b-a3b"))[:5]] == [
         "attn_impl", "cp", "pack_sequences", "pp", "paged_kv"]
@@ -259,9 +259,11 @@ def test_the_three_presets_yield_what_the_issue_counts():
     assert [t.what for t in served] == ["tp", "cp", "pack_sequences", "paged_kv", "spec_decode",
                                         "pp", "ep", "pp", "cp", "fp16"]
     assert not [t for t in served if t.what == "kv_cache"]  # attention + shortconv is served
-    # the stacks that still cannot be served say why, each in its kind's own sentence
-    for preset, clause in (("granite-4.0-h-micro", "holds no recurrent (conv + scan) state"),
-                           ("qwen3-next-80b-a3b", "holds no recurrent (conv + delta rule) state")):
+    # a Mamba-2 stack is served likewise (PR 68: a state of two parts a row)
+    assert [t.what for t in mixers.limits(cut("granite-4.0-h-micro"))] == [
+        "tp", "cp", "pack_sequences", "paged_kv", "spec_decode", "pp"]
+    # the stack that still cannot be served says why, in its kind's own sentence
+    for preset, clause in (("qwen3-next-80b-a3b", "holds no recurrent (conv + delta rule) state"),):
         refusals = [t.sentence() for t in mixers.limits(cut(preset)) if t.what == "kv_cache"]
         assert len(refusals) == 1 and clause in refusals[0], preset
     latent = cut("lfm2-24b-a2b").replace(layer_kinds=("shortconv", "mla") * 2, mla_kv_rank=8)

@@ -56,6 +56,11 @@ KERNEL_NAMES = {
     # `kv_prefill_chunk_attn_ms` under the PREFILL program's `full` | `window` >
     # `attn_core`; the decode readers take the decode program's operations alone)
     "kv_chunk": "kv_prefill.py",
+    # PR 68: a served Mamba-2 layer's single step over the state stack in place (read by
+    # name, `ssm_step_roofline`, and under `ssm/step`: `ssm_step_ms_per_step`,
+    # `ssm_state_hbm_roofline`), and a prompt chunk's rows of that stack in and out (under
+    # the PREFILL program's `ssm/state_read` / `ssm/state_write`)
+    "ssm_step": "ssd.py", "ssm_state_read": "ssd.py", "ssm_state_write": "ssd.py",
 }
 
 
@@ -90,7 +95,8 @@ def test_every_pallas_call_has_a_name_from_the_table(name):
     # and nothing outside the table: a new kernel joins it, with its metric
     assert {n for names in found.values() for n in names} == set(KERNEL_NAMES)
     assert all(n.startswith(("flash_fwd", "flash_bwd", "flash_paged",
-                             "moe_gmm", "moe_tgmm", "moe_held_", "ssd_", "ssm_conv_", "gdn_",
+                             "moe_gmm", "moe_tgmm", "moe_held_", "ssd_", "ssm_conv_", "ssm_step",
+                             "ssm_state_", "gdn_",
                              "mla_", "kv_"))
                for n in KERNEL_NAMES)
 
@@ -402,6 +408,50 @@ def state_texts():
     ctx = registry.ProgramContext(cfg=cfg, num_slots=2, prefill_chunk=8, max_seq_len=32)
     return {spec.name: spec.fn.lower(*spec.args).compile().as_text()
             for spec in registry.enumerate_programs(ctx, include=("serving",))}
+
+
+#: a stack whose Mamba-2 layers keep a conv tail and a scan state a row (PR 68): ``ssm`` in
+#: place of the attention's scopes, ``step`` in the decode program where the prompt chunk
+#: has ``scan``; the un-gated activation under the experts' scope (what the ``ssm_*`` serving
+#: readers and `serve_expert_ms_per_step` key on)
+SSM_SERVING_SCOPES = ("layer_0/attn/ssm/in_proj", "layer_0/attn/ssm/state_read",
+                      "layer_0/attn/ssm/conv", "layer_0/attn/ssm/gate_norm",
+                      "layer_0/attn/ssm/out_proj", "layer_0/attn/ssm/state_write",
+                      "layer_0/mlp/experts/relu2", "layer_0/mlp/shared_expert",
+                      "layer_3/attn/full/attn_core")
+
+
+@pytest.fixture(scope="module")
+def ssm_serving_texts():
+    from galvatron_tpu.aot import registry
+    from galvatron_tpu.models.modeling import PRESETS
+    from galvatron_tpu.serving import engine  # noqa: F401  (registers the serving family)
+
+    cfg = PRESETS["nemotron-3-nano-30b-a3b"].replace(
+        vocab_size=128, hidden_size=32, num_layers=4, num_heads=4, num_kv_heads=2,
+        attn_head_dim=8, ffn_dim=24, max_seq_len=32, ssm_heads=8, ssm_head_dim=4, ssm_state=8,
+        ssm_groups=4, ssm_chunk=8, moe_experts=8, moe_top_k=2, moe_ffn_dim=24,
+        moe_shared_ffn_dim=40)
+    ctx = registry.ProgramContext(cfg=cfg, num_slots=2, prefill_chunk=8, max_seq_len=32)
+    return {spec.name: spec.fn.lower(*spec.args).compile().as_text()
+            for spec in registry.enumerate_programs(ctx, include=("serving",))}
+
+
+@pytest.mark.parametrize("scope", SSM_SERVING_SCOPES + ("layer_0/attn/ssm/step",
+                                                        "layer_0/attn/ssm/scan"))
+@pytest.mark.parametrize("program", SERVING_PROGRAMS[:2])
+def test_a_mamba_stacks_serving_program_carries_the_scope(ssm_serving_texts, program, scope):
+    import re
+
+    names = re.findall(r'op_name="([^"]*)"', ssm_serving_texts[program])
+    has = any(re.search(rf"[/(]{scope}(?:[/)]|$)", n) for n in names)
+    if scope.endswith(("/step", "/scan")):  # the decode step has `step`, the chunk `scan`
+        assert has == (scope.endswith("/step") == ("decode" in program)), (program, scope)
+    else:
+        assert has, (program, scope)
+    assert not [n for n in names if "/shortconv/" in n or "/window/" in n], program
+    # a layer of its mixer alone opens no `mlp`
+    assert not [n for n in names if "layer_2/mlp" in n], program
 
 
 @pytest.mark.parametrize("scope", STATE_SCOPES)

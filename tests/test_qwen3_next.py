@@ -108,8 +108,9 @@ def test_preset_runs_the_published_widths():
     # head_dim is its own field only here, where latent attention gives the query a
     # head of its own (tests/test_mla.py) and in smallthinker-21b-a3b (28 heads of 128
     # on a hidden size of 2560: tests/test_smallthinker.py) and trinity-large-preview (48
-    # heads of 128 on 3072: tests/test_trinity.py): every other preset keeps hidden / heads
-    own_head = ("smallthinker-21b-a3b", "trinity-large-preview")
+    # heads of 128 on 3072: tests/test_trinity.py) and nemotron-3-nano-30b-a3b (32 heads of
+    # 128 on 2688: tests/test_nemotron.py): every other preset keeps hidden / heads
+    own_head = ("smallthinker-21b-a3b", "trinity-large-preview", "nemotron-3-nano-30b-a3b")
     for name, other in PRESETS.items():
         if name != "qwen3-next-80b-a3b" and not other.mla_kv_rank:
             assert (other.attn_head_dim is None) == (name not in own_head)

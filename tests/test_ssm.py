@@ -663,11 +663,15 @@ def test_check_plan_names_the_same_refusals(kw, code, named):
     assert not [d for d in ok if d.code in ("GTA019", "GTA020")]
 
 
-def test_serving_cache_refuses_recurrent_state_by_name():
+def test_serving_cache_keeps_the_recurrent_state_a_row():
+    """(Until PR 68 `init_kv_cache` refused a state-space stack by name.)"""
     from galvatron_tpu.models.generation import init_kv_cache
 
-    with pytest.raises(ValueError, match="state-space"):
-        init_kv_cache(small_cfg(), 1, 16)
+    cfg = small_cfg()
+    cache = init_kv_cache(cfg, 1, 16)
+    layers = cfg.kinds.count("ssm")
+    assert cache.state.scan.shape == (layers, 1, cfg.ssm_state, cfg.ssm_heads * cfg.ssm_head_dim)
+    assert cache.state.scan.dtype == jnp.float32 and cache.state.conv.shape[:2] == (layers, 1)
 
 
 def test_analytic_costs_price_the_two_kinds():

@@ -1599,3 +1599,87 @@ def test_lfm2_serving_programs_fit_one_chip_and_copy_neither_stack(one_chip, rea
     print(f"{name}: arguments {ma.argument_size_in_bytes / 2**30:.3f} GiB, temporaries "
           f"{ma.temp_size_in_bytes / 2**30:.3f} GiB, in all {total / 2**30:.3f} GiB")
     assert total < HBM_V5E_GIB * 2**30, f"{total / 2**30:.2f} GiB"
+
+
+# -- nemotron_h at its published widths (PR 68): the Mamba-2 mixer over the state stack, the
+# un-gated experts of width 1856 on a hidden of 2688 ------------------------------------------
+
+
+def _nemotron_cut():
+    from galvatron_tpu.models.modeling import PRESETS
+
+    return PRESETS["nemotron-3-nano-30b-a3b"].replace(
+        num_layers=15, vocab_size=32768, moe_share=(0, 4), param_dtype=jnp.bfloat16)
+
+
+@pytest.mark.parametrize("form", ["decode_step", "prompt_chunk"])
+def test_nemotron_mixer_moves_the_state_stack_in_place(one_chip, real_mosaic, form):
+    """Two Mamba-2 layers of the cell's stack (12 layers x 64 rows: 1.6 GB of float32 scan
+    state) as the chip's compiler sees the cached forward: a decode step through the kernel
+    `ssm_step`, a prompt chunk through `ssm_state_read` / `ssm_state_write` around the plain
+    scan; the stack is donated and NOTHING of its size is copied (as `dynamic_slice` /
+    `dynamic_update_slice` the compiler re-laid all of it around every chunk: 1.57 GiB of
+    temporaries, the chip's first traced run two copies of f32[12,64,128,4096])."""
+    from galvatron_tpu.models import ssm
+
+    cfg = _nemotron_cut()
+    sd = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)  # noqa: E731
+    p = jax.tree.map(sd, jax.eval_shape(lambda k: ssm.init_params(k, cfg), jax.random.key(0)))
+    state = jax.tree.map(sd, jax.eval_shape(lambda: ssm.init_state(cfg, 12, 64)))
+    assert state.scan.shape == (12, 64, 128, 4096) and state.scan.dtype == jnp.float32
+    assert state.conv.shape == (12, 64, 3 * 6144) and state.conv.dtype == jnp.bfloat16
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    if form == "decode_step":
+        x = jax.ShapeDtypeStruct((64, 1, 2688), jnp.bfloat16, sharding=one_chip)
+        at = jax.ShapeDtypeStruct((64,), jnp.int32, sharding=one_chip)
+
+        def fn(x_, p_, st, offsets):
+            y, st = ssm.cached_block(x_, p_, cfg, st, 3, None, offsets, 0)
+            return ssm.cached_block(x_ + y, p_, cfg, st, 7, None, offsets, 0)
+
+        args, names = (x, p, state, at), ["ssm_step"] * 2
+    else:
+        x = jax.ShapeDtypeStruct((1, 1024, 2688), jnp.bfloat16, sharding=one_chip)
+
+        def fn(x_, p_, st, slot, start, last):
+            y, st = ssm.cached_block(x_, p_, cfg, st, 3, slot, start, last)
+            return ssm.cached_block(x_ + y, p_, cfg, st, 7, slot, start, last)
+
+        args, names = (x, p, state, i32, i32, i32), ["ssm_state_read", "ssm_state_write"] * 2
+    compiled = jax.jit(fn, donate_argnums=(2,)).lower(*args).compile()
+    text = compiled.as_text()
+    import re
+
+    found = [m.group(0) for n, _ in _entry_work(text)
+             for m in [re.search(r"ssm_(?:step|state_read|state_write)", n)] if m]
+    assert sorted(found) == sorted(names), found
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < 0.2 * 2**30, f"{ma.temp_size_in_bytes / 2**30:.2f} GiB"
+    assert ma.alias_size_in_bytes >= 12 * 64 * 2134016
+    assert not [line[:160] for line in text.splitlines()
+                if " copy(" in line and "12,64,128,4096" in line]
+
+
+@pytest.mark.parametrize("tokens", [64, 1024], ids=["decode_step", "prompt_chunk"])
+def test_nemotron_ungated_experts_compile_without_a_copy_of_the_stack(one_chip, real_mosaic,
+                                                                      tokens):
+    """An expert layer of the cell: 32 held experts of width 1856 on a hidden of 2688,
+    ``down(relu(up x)^2)``, through the plain held path (`moe_held.held_path`: worst_case).
+    The up projection is held OUT-major and multiplied by `moe_gmm_dlhs`; held (32, 2688,
+    1856) the compiler lays the stack K-minor and copies 330 MB of it in front of the call."""
+    from galvatron_tpu.models import moe
+
+    cfg = _nemotron_cut()
+    sd = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)  # noqa: E731
+    p = jax.tree.map(sd, jax.eval_shape(lambda k: moe.init_moe_params(k, cfg), jax.random.key(0)))
+    assert p["w1"].shape == (32, 1856, 2688) == p["w2"].shape
+    x = jax.ShapeDtypeStruct((tokens, 1, 2688), jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(lambda x_, p_: moe.moe_topk_block(x_, p_, cfg, forward_only=True)[0]
+                       ).lower(x, p).compile()
+    text = compiled.as_text()
+    kernels = [n for n, _ in _entry_work(text) if "moe_gmm" in n]
+    assert len(kernels) == 2 and any("moe_gmm_dlhs" in n for n in kernels), kernels
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 0.15 * 2**30, f"{temp / 2**30:.2f} GiB"
+    assert not [line[:160] for line in text.splitlines()
+                if " copy(" in line and "bf16[32," in line and "1856" in line]
