@@ -42,6 +42,17 @@ choose themselves (`ops/grouped_matmul.row_tile`), the layout alone, counted and
 as above. ``--skew`` sets the routers' loads (0
 even, 1 a few favourites); ``--tiny`` rehearses the mode at small widths on any
 backend.
+
+``--serve --plain`` (PR 69): the expert layer of
+`nemotron-3-nano-30b-a3b_serve_chat_above_knee` alone, on the PLAIN held path
+(un-gated ``down(relu(up x)^2)``, top-6 over 128 scored, 32 held, 2688 x 1856, ``w1``
+out-major; a decode step's 64 tokens and a prompt chunk's 1,024; skew 0.5 touches 23-27
+of 32 at 64 rows, as the cell's seeded routers do), by the two axes of the grid its
+grouped GEMMs walk: the layout with and without empty tiles (the parent's | (a)) and
+the down projection's column block, 128 (the parent's, 21 blocks) | 384 | 896 | 2688 (one
+block: (b)): ms a call of the layer, the device's ms of `moe_gmm` (down) and
+`moe_gmm_dlhs` (up) in it and of all its operations, those times 11 layers, the grid
+steps, the experts that own a tile, and whether the output is the parent's to the bit.
 """
 
 from __future__ import annotations
@@ -164,6 +175,90 @@ def serve_forward(x, weights, w1, w3, w2, idx, *, held, tile, act, empty_tiles=T
                lay.tile_group, lay.num_tiles, tile, act)
 
 
+#: the un-gated expert layer on the plain held path (scored, held, top-k, hidden, width)
+PLAIN_SHAPES = {"nemotron-3-nano-30b-a3b": (128, 32, 6, 2688, 1856)}
+TINY_PLAIN_SHAPES = {"tiny": (16, 4, 2, 384, 160)}
+PLAIN_LAYERS = 11  # the cell's expert layers
+
+
+def plain_forward(x, weights, w1_t, w2, idx, *, held, tile, empty_tiles, block):
+    """The un-gated plain held path as `moe._topk_local` runs it, with the column block of
+    a width it divides as a parameter (trace-time: `_gmm` looks `_tile` up by name): 128
+    is what `_tile` gave 2688 before PR 69."""
+    from galvatron_tpu.models.modeling import relu2
+    from galvatron_tpu.ops import grouped_matmul
+
+    lay = moe.held_layout(idx, held, tile, 0, empty_tiles=empty_tiles)
+    rows = moe._dispatch(x, lay.row_pair // weights.shape[1], lay.row_valid, lay.pair_row)
+    with mock.patch.object(grouped_matmul, "_tile", lambda n, want: n if n % block else block):
+        up = moe.grouped_gemm(rows, w1_t, lay, tile, out_major=True)
+        out = moe.grouped_gemm(relu2(up), w2, lay, tile)
+    return moe._combine(out, weights, lay.pair_row, lay.row_pair, lay.row_valid)
+
+
+def serve_plain(args) -> int:
+    tiny = args.tiny
+    rows = []
+    for model, (experts, held, top_k, hidden, width) in (
+            TINY_PLAIN_SHAPES if tiny else PLAIN_SHAPES).items():
+        blocks = (128, 384) if tiny else (128, 384, 896, hidden)
+        for tokens in ((8, 64) if tiny else (64, 1024)):
+            for seed in (int(s_) for s_ in args.seeds.split(",")):
+                x, weights, w1, _, w2, idx = serve_inputs(
+                    tokens, experts, held, top_k, hidden, width, float(args.skew.split(",")[0]),
+                    seed)
+                operands = (x, weights, jnp.swapaxes(w1, 1, 2), w2, idx)
+                tile = row_tile(tokens, top_k, experts, x.dtype)
+                want = None
+                for block in blocks:
+                    for empty_tiles in (True, False):
+                        fn = jax.jit(functools.partial(plain_forward, held=held, tile=tile,
+                                                       empty_tiles=empty_tiles, block=block))
+                        y = jax.block_until_ready(fn(*operands))
+                        want = y if want is None else want
+                        lay = jax.jit(functools.partial(
+                            moe.held_layout, held=held, tile=tile, first_held=0,
+                            empty_tiles=empty_tiles))(idx)
+                        ops = [] if tiny else device_ops(fn, operands, top=1000)
+                        kernel = lambda name: sum(  # noqa: E731  (keys: "category:name shape")
+                            ms for key, ms in ops if f":{name} " in key)
+                        row = {"model": model, "tokens": tokens, "seed": seed, "tile": tile,
+                               "empty_tiles": empty_tiles, "column_block": block,
+                               "grid_steps_down": hidden // block * (lay.row_valid.shape[0] // tile),
+                               "tiles_in_use": int(lay.num_tiles[0]),
+                               "tiles": int(lay.row_valid.shape[0]) // tile,
+                               "experts_with_a_tile": len(set(
+                                   np.asarray(lay.tile_group)[:int(lay.num_tiles[0])].tolist())),
+                               "experts_touched": int(jnp.sum(lay.sizes > 0)),
+                               "held_pairs": int(jnp.sum(lay.sizes)),
+                               "layer_ms": timed(fn, *operands, iters=3 if tiny else 50),
+                               "down_ms": kernel("moe_gmm"), "up_ms": kernel("moe_gmm_dlhs"),
+                               "device_ms": sum(ms for _, ms in ops),
+                               "same_bits": bool(jnp.array_equal(y, want)),
+                               "rel_to_parent": rel(y.astype(jnp.float32),
+                                                    want.astype(jnp.float32)),
+                               "finite": bool(jnp.isfinite(y.astype(jnp.float32)).all())}
+                        print(json.dumps(row), flush=True)
+                        rows.append(row)
+    print("| model | tokens | seed | empty tiles | column block | grid steps (down) | tiles in use "
+          "| experts with a tile / touched | layer ms | device ms | up ms | down ms | "
+          f"experts' kernels x {PLAIN_LAYERS} | device x {PLAIN_LAYERS} | same bits |")
+    print("| --- | --- | --- | --- | --- | --- | --- | --- | --- | --- | --- | --- | --- | --- | --- |")
+    for r in rows:
+        print(f"| {r['model']} | {r['tokens']} | {r['seed']} | {r['empty_tiles']} | "
+              f"{r['column_block']} | {r['grid_steps_down']} | {r['tiles_in_use']} / {r['tiles']} | "
+              f"{r['experts_with_a_tile']} / {r['experts_touched']} | {r['layer_ms']:.3f} | "
+              f"{r['device_ms']:.3f} | {r['up_ms']:.3f} | {r['down_ms']:.3f} | "
+              f"{PLAIN_LAYERS * (r['up_ms'] + r['down_ms']):.2f} | "
+              f"{PLAIN_LAYERS * r['device_ms']:.2f} | {r['same_bits']} |")
+    worst = max(r["rel_to_parent"] for r in rows)
+    ok = all(r["finite"] for r in rows) and worst < 0.02 and all(
+        r["same_bits"] for r in rows if r["column_block"] == 128)
+    print(json.dumps({"ok": ok, "worst_rel_to_parent": worst,
+                      "device": str(np.asarray(jax.devices())[0])}))
+    return 0 if ok else 1
+
+
 def print_layouts(layouts):
     print("| model | tokens | pairs x groups | tile | empty tiles | body | host ms | device ms | "
           "device operations | temporaries MB | same bits |")
@@ -253,11 +348,14 @@ def main(argv=None) -> int:
     ap.add_argument("--tiles", default="16,32,64,128,256")
     ap.add_argument("--skew", default="1", help="--serve: the routers' skews (0: an even load)")
     ap.add_argument("--tiny", action="store_true", help="--serve at small widths, any backend")
+    ap.add_argument("--plain", action="store_true",
+                    help="--serve: the un-gated plain held path by layout and column block")
+    ap.add_argument("--seeds", default="0,3", help="--plain: the seeds of the routers' choices")
     args = ap.parse_args(argv)
     if not (args.serve and args.tiny) and jax.devices()[0].platform != "tpu":
         raise SystemExit("ab_moe_held: needs a TPU")
     if args.serve:
-        return serve(args)
+        return serve_plain(args) if args.plain else serve(args)
     rows, layouts = [], []
     shares = [float(s) for s in args.shares.split(",")]
     for share in shares:

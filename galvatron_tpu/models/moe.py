@@ -373,9 +373,10 @@ def held_layout(expert_idx, held: int, tile: int, first_held: int,
     adds nothing forward and takes nothing backward. The buffer keeps its worst-case
     size (every pair held): shapes are static, and no pair that is held is ever
     dropped. What lies past ``num_tiles`` depends on who runs over the layout:
-    the plain path (`_dispatch`, `grouped_gemm`, `_combine`) writes zeros there
-    and reads them; `held_experts` never writes those rows, so they are
-    UNDEFINED and every reader masks by index."""
+    the plain path (`_dispatch`, `grouped_gemm` or `forward_gemm`, `_combine`)
+    writes zeros there and reads them, so it takes the layout without empty tiles
+    as it is (a pair that is not held combines a zero row); `held_experts` never
+    writes those rows, so they are UNDEFINED and every reader masks by index."""
     local = expert_idx.astype(jnp.int32) - first_held
     dropped = (local < 0) | (local >= held)
     full = sorted_layout(jnp.where(dropped, held, local), held + 1, tile, empty_tiles)
@@ -436,14 +437,27 @@ def _combine_bwd(res, g):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def grouped_gemm(lhs, rhs, layout: SortedLayout, tile: int):
-    """Rows of group g of ``lhs`` (M, K) by ``rhs[g]`` (E, K, N): the Pallas
+def grouped_gemm(lhs, rhs, layout: SortedLayout, tile: int, out_major: bool = False):
+    """Rows of group g of ``lhs`` (M, K) by ``rhs[g]`` (E, K, N), or (``out_major``)
+    by ``rhs[g].T`` of weights stored (E, N, K): the Pallas
     kernels of ops/grouped_matmul.py, which beat ``jax.lax.ragged_dot`` 1.40x
     at the OLMoE cell's shape (experiments/moe_gmm_bench.py swaps this name
     for its candidates; PERF.md §6, PR 28)."""
-    from galvatron_tpu.ops.grouped_matmul import grouped_matmul
+    from galvatron_tpu.ops.grouped_matmul import grouped_matmul, grouped_matmul_t
 
-    return grouped_matmul(lhs, rhs, layout.tile_group, layout.num_tiles, tile)
+    product = grouped_matmul_t if out_major else grouped_matmul
+    return product(lhs, rhs, layout.tile_group, layout.num_tiles, tile)
+
+
+def forward_gemm(lhs, rhs, layout: SortedLayout, tile: int, out_major: bool = False):
+    """`grouped_gemm` with no VJP: what the plain path of a forward that is never
+    differentiated runs over a layout without empty tiles (`sorted_layout`), as
+    `held_forward` is the bounded path's: the same kernels and the same zeros past
+    ``num_tiles``, and a gradient raises (`grouped_matmul.forward_matmul`)."""
+    from galvatron_tpu.ops.grouped_matmul import forward_matmul
+
+    return forward_matmul(lhs, rhs, layout.tile_group, layout.num_tiles, tile_m=tile,
+                          transpose_rhs=out_major)
 
 
 def held_path_counts(cfg) -> dict:
@@ -586,8 +600,8 @@ def held_experts_touched(stats, held: Tuple[int, int]) -> jax.Array:
     """Held experts that got at least one pair, mean over the layers: ``count`` when
     every held expert has a row, ``count * (1 - e^-r)`` at ``r`` rows an expert on
     average under even routing. The experts whose weights a forward-only held share
-    fetches (`sorted_layout`: the others own no tile there); a differentiated one
-    fetches all ``count``."""
+    fetches, on the bounded path and on the plain one (`sorted_layout`: the others
+    own no tile there); a differentiated one fetches all ``count``."""
     f = jnp.stack([s[0] for s in stats])
     return jnp.mean(jnp.sum((f[:, held[0]:held[0] + held[1]] > 0).astype(jnp.float32), axis=1))
 
@@ -617,9 +631,10 @@ def moe_topk_block(x: jax.Array, p: Params, cfg, tile: Optional[int] = None,
     H): what the router reads where that is not ``x`` (``cfg.moe_router_input``
     "attn": the attention block's normed input; split over the mesh like ``x``).
     ``forward_only``: the caller never differentiates this forward (the cached
-    forwards of `models/generation`), so a bounded held share gives no tile to an
-    expert without a row (`sorted_layout`) and a decode step fetches the weights of
-    the experts it touched alone; differentiating such a forward raises (`held_forward`).
+    forwards of `models/generation`), so a held share gives no tile to an expert
+    without a row (`sorted_layout`) and a decode step fetches the weights of the
+    experts it touched alone, on the bounded path and on the plain one;
+    differentiating such a forward raises (`held_forward`, `forward_gemm`).
 
     On a multi-device mesh ``place.route_tokens`` runs the block on each
     device's own tokens, every expert's weights whole on every device, and
@@ -683,11 +698,10 @@ def _topk_local(x, p, cfg, tile, over, router_x=None, forward_only=False):
         with jax.named_scope("layout"):
             if held_share:
                 # an expert without a row owns a tile for its weight gradient's sake
-                # (`moe_tgmm`); a bounded forward that has none to write pays a fetch of
-                # the expert's weights for it, so it asks for none (the plain path runs
-                # over the whole buffer as it is, and keeps the layout its VJPs can take)
+                # (`moe_tgmm`); a forward that has none to write pays a fetch of the
+                # expert's weights for it on either path, so it asks for none
                 layout = held_layout(idx, cfg.moe_held, tile, cfg.moe_first_held,
-                                     empty_tiles=not (forward_only and bounded))
+                                     empty_tiles=not forward_only)
             else:
                 layout = sorted_layout(idx, e, tile)
     if bounded:
@@ -707,20 +721,20 @@ def _topk_local(x, p, cfg, tile, over, router_x=None, forward_only=False):
         with jax.named_scope("dispatch"):
             rows = _dispatch(xt, layout.row_pair // k, layout.row_valid, layout.pair_row)
         with jax.named_scope("experts"):
+            # (a held share's forward-only layout leaves groups without a tile: no VJP)
+            gemm = forward_gemm if forward_only and held_share else grouped_gemm
             if ungated(cfg):
                 from galvatron_tpu.models.modeling import relu2
-                from galvatron_tpu.ops.grouped_matmul import grouped_matmul_t
 
-                up = grouped_matmul_t(rows, p["w1"].astype(x.dtype), layout.tile_group,
-                                      layout.num_tiles, tile)
+                up = gemm(rows, p["w1"].astype(x.dtype), layout, tile, out_major=True)
                 with jax.named_scope("relu2"):
                     mid = relu2(up)
-                out = grouped_gemm(mid, p["w2"].astype(x.dtype), layout, tile)
+                out = gemm(mid, p["w2"].astype(x.dtype), layout, tile)
             else:
                 w1, w3, w2 = (p[n].astype(x.dtype) for n in ("w1", "w3", "w2"))
-                gate = grouped_gemm(rows, w1, layout, tile)
-                up = grouped_gemm(rows, w3, layout, tile)
-                out = grouped_gemm(_glu_gate(cfg)(gate) * up, w2, layout, tile)
+                gate = gemm(rows, w1, layout, tile)
+                up = gemm(rows, w3, layout, tile)
+                out = gemm(_glu_gate(cfg)(gate) * up, w2, layout, tile)
         with jax.named_scope("combine"):
             y = _combine(out, weights, layout.pair_row, layout.row_pair, layout.row_valid)
     if cfg.moe_shared_ffn_dim:

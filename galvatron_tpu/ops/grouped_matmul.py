@@ -17,8 +17,9 @@ most ``tile_m`` rows a group, ``tile_m / 2`` on average.
 - ``moe_tgmm``: ``out[g] = lhs[rows of g].T @ grad[rows of g]``, the
   gradient of the weights; every group owns at least one tile (the layout's
   default, ``empty_tiles``: a forward that is never differentiated asks
-  `models/moe.sorted_layout` for none and pays no fetch of an empty group's
-  weights), so every output block is written.
+  `models/moe.sorted_layout` for none, pays no fetch of an empty group's
+  weights and runs `held_matmul` or `forward_matmul`, which carry no VJP), so
+  every output block is written.
 
 Row tiles past ``num_tiles`` (the static row count is an upper bound) are
 skipped: ``moe_gmm`` writes zeros there, ``moe_tgmm`` leaves them out.  For a
@@ -26,10 +27,13 @@ held share of the experts (`held_matmul`; `models/moe.held_experts`) the
 contract is weaker on purpose: a skipped tile writes NOTHING, the rows past
 ``num_tiles`` are undefined, and every reader masks them by index
 (`ops/moe_held.py`): zero-filling 150,000 rows that hold no pair was the cost.  The
-contraction is not tiled: at the widths this serves (hidden 2048, expert
-width 1024) a whole ``(tile_m, K)`` by ``(K, tile_n)`` product fits the
+contraction is not tiled: at the widths this serves (hidden 2048 to 5120, expert
+widths 512 to 3072) a whole ``(tile_m, K)`` by ``(K, tile_n)`` product fits the
 scoped VMEM, and a weight block is then fetched once a group and not once a
-row tile.
+row tile.  The output's columns are cut into blocks of `_tile`: the grid is
+(column blocks, row tiles), a row tile is read again for every column block and
+a grid step costs its ~0.3 us whatever it does, so no width ends at blocks of one
+lane tile.
 
 ``grouped_matmul`` ties the three together with a custom VJP.  The
 ``pl.pallas_call`` names are a contract (PERF.md §3): the benchmark's trace
@@ -75,10 +79,15 @@ def row_tile(tokens: int, top_k: int, experts_scored: int, dtype) -> int:
 
 
 def _tile(n: int, want: int) -> int:
-    """Largest power-of-two multiple of 128 that divides ``n``, at most
-    ``want``; a width that none divides is one whole block (always legal)."""
+    """The column block of a width ``n``: the largest power-of-two multiple of 128 above
+    one lane tile that divides ``n``, at most ``want``; a width that none divides is one
+    whole block (always legal).  So is a width of 128 x an odd number (2688 = 21 x 128),
+    which until PR 69 ended at ONE lane tile: 21 column blocks, each weight block K
+    strided rows of 256 B, the row tiles re-read once a column block and a grid step
+    paid 21 times a row tile.  The chip reads one block of 2688 ahead of 3 of 896 and 7 of
+    384 at both served shapes (PERF.md section 6, PR 69)."""
     t = want
-    while t >= 128:
+    while t > 128:
         if n % t == 0:
             return t
         t //= 2
@@ -273,6 +282,18 @@ def held_matmul(lhs, rhs, tile_group, num_tiles, *, tile_m: int = TILE_M,
     writes the backward out."""
     return traced_once(_gmm, lhs, rhs, tile_group, num_tiles, transpose_rhs=transpose_rhs,
                        tile_m=tile_m, tile_n=1024, bounded=True, slab_out=slab_out)
+
+
+def forward_matmul(lhs, rhs, tile_group, num_tiles, *, tile_m: int = TILE_M,
+                   transpose_rhs: bool = False):
+    """`grouped_matmul`'s product (``transpose_rhs``: `grouped_matmul_t`'s, against
+    out-major weights) for a forward that is never differentiated: the same kernel,
+    zeros past ``num_tiles``, and NO VJP.  Such a forward runs over a layout without
+    empty tiles (`models/moe.sorted_layout`), where `moe_tgmm` would leave the weight
+    gradient of a group that owns no tile unwritten: a gradient must raise (Pallas has
+    no JVP of a scalar-prefetch call) and never be read out of those blocks."""
+    return traced_once(_gmm, lhs, rhs, tile_group, num_tiles, transpose_rhs=transpose_rhs,
+                       tile_m=tile_m, tile_n=1024)
 
 
 def weight_grad(lhs, grad, tile_group, num_tiles, num_groups: int, *, tile_m: int = TILE_M,

@@ -65,9 +65,12 @@ def held_path(hidden: int, width: int, dtype, gated: bool = True) -> str:
     ``act_fn`` "relu2"): the bounded body is built on the fused ``[gate | up]`` buffer
     and `swiglu`; and nemotron_h's sizes are outside it twice over anyway (hidden
     2688 = 21 x 128, an odd number of the lane tiles that `to_slab` packs in pairs;
-    width 1856 = 14.5 x 128).  What the plain path costs such a layer is the experts
-    no token chose: every held expert owns a tile and has its weights fetched, 32 of
-    32 where a decode step of 64 rows touches ~95% (PERF.md section 5)."""
+    width 1856 = 14.5 x 128).  ``worst_case`` names the buffer XLA's passes walk
+    (dispatch, the activation, combine: every row of it), not the weights fetched: a
+    cached forward's layout gives an expert without a row no tile on this path either
+    (`models/moe.forward_gemm`), so its two grouped GEMMs fetch the touched experts
+    alone, 23 of 32 in a decode step of 64 rows (PERF.md section 5); a differentiated
+    forward keeps a tile for every held expert and fetches them all."""
     dtype = jnp.dtype(dtype)
     if dtype not in (jnp.bfloat16, jnp.float32) or not gated:
         return "worst_case"

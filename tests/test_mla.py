@@ -651,16 +651,19 @@ def test_the_decode_span_carries_the_iterations_counters():
     assert 0.0 <= stats["moe_held_pairs_per_token"] <= 2.0
     # the row tile each forward compiled with (float32's floor: 2 rows x top-2 over 8
     # scored experts, and a chunk's 8 x 2 / 8, are under 8 rows an expert) and the share
-    # of the rows its held experts multiplied that hold a pair: the step's 2 experts'
-    # tiles of 8 rows hold at most the 2 x 2 pairs, a chunk's at most its 8 x 2
+    # of the rows its held experts multiplied that hold a pair: a cached forward gives a
+    # tile of 8 rows to each held expert that got a row and to no other, on the plain path
+    # too (PR 69), and they hold the step's at most 2 x 2 pairs, a chunk's at most 8 x 2
     assert args["moe_row_tile"] == stats["moe_row_tile"] == stats["moe_row_tile_prefill"] == 8
     held = args["moe_held_pairs_per_token"] * 2  # pairs of the step's 2 rows on the 4 held
-    assert args["moe_live_rows_share"] == pytest.approx(held / (4 * 8), rel=1e-5)  # a tile each
-    assert stats["moe_live_rows_share"] <= 4 / 32
+    touched = args["moe_held_experts_touched"]
+    assert 0 < touched <= min(held, 4)
+    assert args["moe_live_rows_share"] == pytest.approx(held / (touched * 8), rel=1e-5)
+    assert stats["moe_live_rows_share"] <= 4 / 8
     assert len(chunks) == 2  # (a prompt's 12 tokens: two chunks; the span carries the last's)
     for chunk in chunks:
         assert chunk["moe_row_tile"] == 8
-        assert 0.0 <= chunk["moe_live_rows_share"] <= 16 / (4 * 8)
+        assert 0.0 <= chunk["moe_live_rows_share"] <= 1.0
 
 
 def test_the_engine_counts_the_positions_its_decode_kernel_fetches(monkeypatch):

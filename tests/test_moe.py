@@ -634,6 +634,200 @@ def test_differentiating_a_forward_only_held_share_raises():
         jax.grad(loss)(p, True)
 
 
+# -- the PLAIN held path of a forward that is never differentiated (PR 69) ----------------
+# `moe._topk_local` outside the bounded body (un-gated experts, a hidden size of an odd
+# number of lane tiles, an expert width of no whole number: nemotron_h's 2688 x 1856 at
+# 384 x 192): `forward_only` asks `held_layout(empty_tiles=False)` there too and runs the two
+# products through `moe.forward_gemm`, the same kernels with no VJP.
+
+PLAIN_T, PLAIN_E, PLAIN_HELD, PLAIN_FIRST = 24, 8, 4, 4
+
+
+def _plain_cfg(dtype, act_fn):
+    cfg = ModelConfig(vocab_size=64, hidden_size=384, num_layers=1, num_heads=4, ffn_dim=128,
+                      moe_ffn_dim=192, max_seq_len=32, dtype=dtype, act_fn=act_fn,
+                      moe_experts=PLAIN_E, moe_router="softmax_topk", moe_top_k=2,
+                      moe_share=(1, 2))
+    assert moe.held_path_counts(cfg) == {"bounded": 0, "worst_case": 1}
+    assert (cfg.moe_first_held, cfg.moe_held) == (PLAIN_FIRST, PLAIN_HELD)
+    return cfg
+
+
+def _plain_scores(load):
+    """(T, E) scores whose top-2 a token: ``empty`` leaves held experts 5 and 7 without a
+    row, ``full`` gives every held expert rows (the first more than a tile's), ``none``
+    names no held expert at all."""
+    t = np.arange(PLAIN_T)
+    first = {"empty": np.where(t % 3 == 0, 4, np.where(t % 3 == 1, 6, 0)),
+             "full": np.where(t < 18, 4, 5 + t % 3), "none": t % 4}[load]
+    second = {"empty": 1 + t % 3, "full": np.where(t % 2 == 0, 5 + t % 3, 3),
+              "none": (t + 1) % 4}[load]
+    scores = np.full((PLAIN_T, PLAIN_E), 0.01, np.float32)
+    scores[t, first], scores[t, second] = 0.5, 0.3
+    return jnp.asarray(scores)
+
+
+@pytest.mark.parametrize("act_fn", ["relu2", "swiglu"], ids=["ungated", "gated"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bf16"])
+@pytest.mark.parametrize("load", ["empty", "full", "none"])
+def test_forward_only_plain_path_is_the_differentiated_body_to_the_bit(monkeypatch, load, dtype,
+                                                                       act_fn):
+    """The same row tiles against the same weights, only their places in the buffer move;
+    the tiles below ``num_tiles`` name exactly the experts that got a row, and with no
+    held expert chosen (``num_tiles`` 0) exact zeros come out."""
+    cfg = _plain_cfg(dtype, act_fn)
+    p = moe.init_moe_params(jax.random.key(0), cfg)
+    x = jax.random.normal(jax.random.key(1), (2, PLAIN_T // 2, 384), dtype)
+    scores = _plain_scores(load)
+    monkeypatch.setattr(moe, "router_scores", lambda xt, router, cfg_: scores)
+    asked, real = [], moe.held_layout
+
+    def recording(*args, empty_tiles=True):
+        asked.append(empty_tiles)
+        return real(*args, empty_tiles=empty_tiles)
+
+    monkeypatch.setattr(moe, "held_layout", recording)
+    want, want_stats = moe.moe_topk_block(x, p, cfg)
+    got, got_stats = moe.moe_topk_block(x, p, cfg, forward_only=True)
+    assert asked == [True, False]
+    assert got.dtype == want.dtype == dtype
+    assert np.array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+    for g, w in zip(got_stats[:2], want_stats[:2]):
+        assert np.array_equal(np.asarray(g), np.asarray(w))
+
+    idx = jax.lax.top_k(scores, 2)[1]
+    tile = moe.layer_row_tile(cfg, PLAIN_T, dtype)
+    lay = real(idx, PLAIN_HELD, tile, PLAIN_FIRST, empty_tiles=False)
+    sizes, count = np.asarray(lay.sizes), int(lay.num_tiles[0])
+    assert {"empty": list(sizes > 0) == [True, False, True, False], "full": (sizes > 0).all(),
+            "none": not sizes.any()}[load]
+    named = np.asarray(lay.tile_group)[:count]
+    assert sorted(set(named.tolist())) == np.flatnonzero(sizes).tolist()
+    assert count == sum(-(-int(n) // tile) for n in sizes)
+    # `moe_held_rows_share`, the third statistic: the tiles in use over the buffer's
+    assert float(got_stats[2]) == pytest.approx(count * tile / lay.row_valid.shape[0])
+    assert float(want_stats[2]) == pytest.approx(
+        (count + int((sizes == 0).sum())) * tile / lay.row_valid.shape[0])
+    if load == "full":
+        assert sizes[0] > tile  # (consecutive tiles of one expert)
+    if load == "none":
+        assert count == 0 and not np.asarray(got, np.float32).any()
+    else:
+        assert np.abs(np.asarray(got, np.float32)).max() > 0.0
+
+
+@pytest.mark.parametrize("act_fn", ["relu2", "swiglu"], ids=["ungated", "gated"])
+def test_differentiating_a_forward_only_plain_path_raises(monkeypatch, act_fn):
+    """`moe.forward_gemm` carries no VJP: over a layout without empty tiles `moe_tgmm`
+    would write no weight-gradient block for an expert without a row, so a gradient is
+    refused; the default layout differentiates as ever."""
+    cfg = _plain_cfg(jnp.float32, act_fn)
+    p = moe.init_moe_params(jax.random.key(0), cfg)
+    x = jax.random.normal(jax.random.key(1), (2, PLAIN_T // 2, 384), jnp.float32)
+    scores = _plain_scores("empty")
+    monkeypatch.setattr(moe, "router_scores", lambda xt, router, cfg_: scores)
+
+    def loss(p_, forward_only):
+        return jnp.sum(moe.moe_topk_block(x, p_, cfg, forward_only=forward_only)[0] ** 2)
+
+    grads = jax.grad(loss)(p, False)
+    moved = np.abs(np.asarray(grads["w2"])).max(axis=(1, 2)) > 0
+    assert list(moved) == [True, False, True, False]  # (the held experts with a row)
+    with pytest.raises(NotImplementedError):  # (Pallas has no JVP of a scalar-prefetch call)
+        jax.grad(loss)(p, True)
+
+
+# -- the column block of a grouped GEMM follows the width's divisors (PR 69) --------------
+
+#: width -> the block `_tile(width, 1024)` gives it: every hidden size, expert width and
+#: joined [gate | up] width the dropless presets feed `_gmm` / `_tgmm`. Only 2688 moved in
+#: PR 69 (128 before: 21 column blocks; one whole block now, as 1856 always was); a width
+#: with a power-of-two block above one lane tile keeps it.
+COLUMN_BLOCKS = {512: 512, 768: 256, 1024: 1024, 1536: 512, 1856: 1856, 2048: 1024, 2560: 512,
+                 2688: 2688, 3072: 1024, 4096: 1024, 5120: 1024, 6144: 1024}
+
+
+@pytest.mark.parametrize("width,block", sorted(COLUMN_BLOCKS.items()))
+def test_column_block_of_a_width(width, block):
+    from galvatron_tpu.ops.grouped_matmul import _tile
+
+    assert _tile(width, 1024) == block and width % block == 0
+    assert block == width or block % 128 == 0
+    # a power-of-two block above one lane tile, where one divides, is kept; else one block
+    pow2 = [t for t in (1024, 512, 256) if width % t == 0]
+    assert block == (pow2[0] if pow2 else width)
+
+
+def test_column_block_table_covers_what_the_presets_feed_it():
+    from galvatron_tpu.models.modeling import PRESETS
+    from galvatron_tpu.ops.grouped_matmul import _tile
+
+    fed = set()
+    for cfg in PRESETS.values():
+        if cfg.moe_dropless:
+            fed |= {cfg.hidden_size, cfg.expert_ffn}
+            if not moe.ungated(cfg):
+                fed.add(2 * cfg.expert_ffn)
+    assert fed <= set(COLUMN_BLOCKS) and {2688, 1856} <= fed
+    # one lane tile times an odd number, or no whole lane tiles at all: one whole block
+    assert [_tile(n, 1024) for n in (128, 384, 640, 1152)] == [128, 384, 640, 1152]
+    assert [_tile(n, 1024) for n in (32, 96, 160)] == [32, 96, 160]
+    assert [_tile(2688, want) for want in (128, 512, 2688)] == [2688, 2688, 2688]
+
+
+@pytest.mark.parametrize("rows,tile_m,grid", [(912, 16, (1, 57)), (7200, 32, (1, 225))],
+                         ids=["decode_step", "prompt_chunk"])
+def test_the_grid_of_the_served_down_projection(rows, tile_m, grid):
+    """`moe_gmm` at the nemotron cell's down projection, a decode step's buffer (64 x 6
+    pairs + 33 groups x 16 rows) and a prompt chunk's: one column block where `_tile` gave
+    21 (1,197 and 4,725 grid steps a layer), the grid of the up projection."""
+    from galvatron_tpu.ops.grouped_matmul import _gmm
+
+    assert rows == moe.buffer_rows((64 if tile_m == 16 else 1024) * 6, 33, tile_m)
+    sd = jax.ShapeDtypeStruct
+    jaxpr = jax.make_jaxpr(functools.partial(
+        _gmm, transpose_rhs=False, tile_m=tile_m, tile_n=1024))(
+            sd((rows, 1856), jnp.bfloat16), sd((32, 1856, 2688), jnp.bfloat16),
+            sd((rows // tile_m,), jnp.int32), sd((1,), jnp.int32))
+    call, = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert tuple(call.params["grid_mapping"].grid) == grid
+    assert grid[1] == rows // tile_m
+
+
+@pytest.mark.parametrize("transpose_rhs", [False, True], ids=["moe_gmm", "moe_gmm_dlhs"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bf16"])
+def test_a_wider_column_block_is_the_lane_tile_blocks_product_to_the_bit(monkeypatch, dtype,
+                                                                        transpose_rhs):
+    """The contraction is whole in every block, so 2688 columns in one block
+    or in 21 of 128 are the same products summed in float32 and rounded once: to the bit
+    in bf16, the served type (in float32 the CPU's dot orders its sums by the block's
+    width: an ulp). A ragged layout: a group of several tiles, one of part of a tile, one
+    of none, tiles past ``num_tiles``."""
+    from galvatron_tpu.ops import grouped_matmul
+
+    tile_m, k, n, groups = 16, 64, 2688, 4
+    idx = jnp.asarray([0] * 37 + [2] * 5 + [3] * 16, jnp.int32)[:, None]
+    lay = moe.sorted_layout(idx, groups, tile_m, empty_tiles=False)
+    count = int(lay.num_tiles[0])
+    assert list(np.asarray(lay.tile_group)[:count]) == [0, 0, 0, 2, 3]
+    assert count < lay.row_valid.shape[0] // tile_m
+    ks = jax.random.split(jax.random.key(3), 2)
+    lhs = jnp.where(lay.row_valid[:, None],
+                    jax.random.normal(ks[0], (lay.row_valid.shape[0], k), dtype), 0)
+    rhs = jax.random.normal(ks[1], (groups, n, k) if transpose_rhs else (groups, k, n), dtype)
+    run = functools.partial(grouped_matmul._gmm, lhs, rhs, lay.tile_group, lay.num_tiles,
+                            transpose_rhs=transpose_rhs, tile_m=tile_m, tile_n=1024)
+    assert grouped_matmul._tile(n, 1024) == n
+    got = np.asarray(run(), np.float32)
+    monkeypatch.setattr(grouped_matmul, "_tile", lambda n_, want: 128)  # (as it was: 21 blocks)
+    want = np.asarray(run(), np.float32)
+    if dtype == jnp.bfloat16:
+        assert np.array_equal(got, want)
+    assert np.abs(got - want).max() <= 2e-6 * np.abs(want).max()
+    assert np.abs(got[:count * tile_m]).max() > 0.0
+    assert not got[count * tile_m:].any()  # (zeros past ``num_tiles``)
+
+
 # -- the layout is counted, not sorted (PR 67) --------------------------------------------
 # `moe.sorted_layout`: a pair's row = its group's first row + the pairs of its group before
 # it, by comparison and sum, at any number of groups. The sort-based body it replaced stays as

@@ -493,25 +493,38 @@ def test_held_experts_serve_unjoined_at_published_widths(one_chip, real_mosaic, 
         assert rhs.startswith("p_") and re.search(r"w[123]_", rhs), rhs
 
 
+@pytest.mark.parametrize("cell", ["trinity_bounded", "nemotron_plain"])
 def test_a_cached_forwards_expert_layer_takes_the_forward_only_layout(one_chip, real_mosaic,
-                                                                      monkeypatch):
+                                                                      monkeypatch, cell):
     """The expert layer of `trinity-large-preview_serve_agent_above_knee` as a CACHED forward
     runs it (`generation._mlp_at`: `moe_topk_block(forward_only=True)`, PR 62), a decode
     step's 32 tokens, rank 0 of 8 holding 32 of 256, weights held in bf16, as the chip's
     compiler sees it: the same six kernels over a layout that gives an expert without a row
-    no tile (`used_tile`'s clamp lowers for the chip), at the row tile of the shape."""
+    no tile (`used_tile`'s clamp lowers for the chip), at the row tile of the shape. And that
+    of `nemotron-3-nano-30b-a3b_serve_chat_above_knee` (64 tokens, 32 of 128 held), whose
+    un-gated experts take the PLAIN held path: the same layout there too (PR 69), its two
+    products still `moe_gmm_dlhs` (up) and `moe_gmm` (down)."""
     from galvatron_tpu.models import generation, moe
     from galvatron_tpu.models.modeling import PRESETS
 
-    cfg = PRESETS["trinity-large-preview"].replace(
-        moe_share=(0, 8), param_dtype=jnp.bfloat16, dtype=jnp.bfloat16)
-    assert (cfg.moe_experts, cfg.moe_held, cfg.moe_top_k, cfg.expert_ffn) == (256, 32, 4, 3072)
+    if cell == "trinity_bounded":
+        cfg = PRESETS["trinity-large-preview"].replace(
+            moe_share=(0, 8), param_dtype=jnp.bfloat16, dtype=jnp.bfloat16)
+        assert (cfg.moe_experts, cfg.moe_held, cfg.moe_top_k, cfg.expert_ffn) == (256, 32, 4, 3072)
+        tokens, path = 32, "bounded"
+        want = ["moe_held_rows", "moe_gmm", "moe_gmm", "moe_held_swiglu", "moe_gmm",
+                "moe_held_pairs"]
+    else:
+        cfg = _nemotron_cut()
+        assert (cfg.moe_experts, cfg.moe_held, cfg.moe_top_k, cfg.expert_ffn) == (128, 32, 6, 1856)
+        tokens, path, want = 64, "worst_case", ["moe_gmm_dlhs", "moe_gmm"]
+    assert moe.held_path_counts(cfg)[path] == sum(cfg.mlp_layers)
     shapes = jax.eval_shape(
         lambda k: {"mlp": moe.init_moe_params(k, cfg),
                    "mlp_norm": {"scale": jnp.zeros((cfg.hidden_size,), cfg.param_dtype)}},
         jax.random.key(0))
     p = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), shapes)
-    x = jax.ShapeDtypeStruct((32, 1, cfg.hidden_size), jnp.bfloat16, sharding=one_chip)
+    x = jax.ShapeDtypeStruct((tokens, 1, cfg.hidden_size), jnp.bfloat16, sharding=one_chip)
     asked = []
     real = moe.held_layout
 
@@ -524,8 +537,7 @@ def test_a_cached_forwards_expert_layer_takes_the_forward_only_layout(one_chip, 
     assert asked == [(16, {"empty_tiles": False})]
     kernels = sorted(n.split(".")[0] for n, _ in _entry_work(compiled.as_text())
                      if n.startswith("moe_"))
-    assert kernels == sorted(["moe_held_rows", "moe_gmm", "moe_gmm", "moe_held_swiglu", "moe_gmm",
-                              "moe_held_pairs"]), kernels
+    assert kernels == sorted(want), kernels
 
 
 def _lowered_serving_program(cfg, name, one_chip, **context):
@@ -1683,3 +1695,4 @@ def test_nemotron_ungated_experts_compile_without_a_copy_of_the_stack(one_chip, 
     assert temp < 0.15 * 2**30, f"{temp / 2**30:.2f} GiB"
     assert not [line[:160] for line in text.splitlines()
                 if " copy(" in line and "bf16[32," in line and "1856" in line]
+
