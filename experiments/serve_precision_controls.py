@@ -30,6 +30,15 @@ own ``run_serve_cell`` at the cell's own load, the fault planted underneath it:
 - ``scan_stale``: a forward that starts a request at position 0 reads the conv tail and the
   scan state its slot holds in place of zeros (``models/ssm.cached_block`` told that every
   forward starts past position 0);
+- ``weights_e4m3``: every matrix the ENGINE serves with rounded to float8 e4m3's 3 mantissa
+  bits (the nearest float type below the bfloat16 the configuration states), the reference
+  on the weights as they were drawn: ``lib/serve.make_weights`` hands the engine the rounded
+  tree, and the reference's ``published_weights`` drops it and draws the seed's tree again
+  (two trees of 9.5 GB do not fit a chip); PR 70's cell;
+- ``residual_one``: ``models/modeling.residual_add`` told that ``residual_multiplier`` is 1
+  (a branch joins the stream as it is: what every cached forward but a state layer's did
+  before PR 70); ``logits_one``: ``models/modeling.lm_head`` told that ``logits_scaling`` is
+  1; both need a stack with Granite's multipliers (PR 70's cell);
 - ``int8``: the engine's own per-channel int8 weights (``--serve_quant int8``), as
   ``benchmark/control.py`` reads them.
 
@@ -48,6 +57,7 @@ import shutil
 import sys
 import tempfile
 import time
+import types
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -62,12 +72,15 @@ def planted(mode: str):
     import jax
     import jax.numpy as jnp
 
-    from galvatron_tpu.models import generation, mla, moe, shortconv, ssm
+    from benchmark.lib import reference, serve
+    from galvatron_tpu.models import generation, mla, modeling, moe, shortconv, ssm
 
     real_scores, real_project = moe.router_scores, mla.project
     real_qkv = generation._project_qkv_at
     real_stored, real_fresh = shortconv.stored, shortconv.fresh
     real_shapes, real_cached = ssm.state_shapes, ssm.cached_block
+    real_add, real_head = modeling.residual_add, modeling.lm_head
+    real_make, real_load = serve.make_weights, reference.load
 
     def e4m3(t):
         bits = jax.lax.bitcast_convert_type(t.astype(jnp.bfloat16), jnp.uint16)
@@ -91,8 +104,31 @@ def planted(mode: str):
         q_nope, q_rope, new = real_project(x, p, cfg, cos_sin)
         return q_nope, q_rope, e4m3(new)
 
+    drawn = {}  # weights_e4m3: what the engine's tree was drawn from
+
+    def make_rounded(cfg, seed):
+        drawn["args"] = (cfg, seed)
+        return jax.jit(lambda tree: jax.tree.map(lambda a: e4m3(a) if a.ndim >= 2 else a, tree),
+                       donate_argnums=0)(real_make(cfg, seed))
+
+    def load_unrounded(root, model_type):
+        arch = real_load(root, model_type)
+
+        def published_weights(params, config):
+            for leaf in jax.tree.leaves(params):  # the rounded tree: the engine is gone
+                leaf.delete()
+            return arch.published_weights(real_make(*drawn["args"]), config)
+
+        return types.SimpleNamespace(**{**vars(arch), "published_weights": published_weights})
+
     jax.clear_caches()
-    if mode == "router_bf16":
+    if mode == "weights_e4m3":
+        serve.make_weights, reference.load = make_rounded, load_unrounded
+    elif mode == "residual_one":
+        modeling.residual_add = lambda x, y, cfg: x + y
+    elif mode == "logits_one":
+        modeling.lm_head = lambda x, params, cfg: real_head(x, params, cfg.replace(logits_scaling=1.0))
+    elif mode == "router_bf16":
         moe.router_scores = scores_bf16
     elif mode == "cache_e4m3":
         mla.project = project_e4m3
@@ -114,6 +150,8 @@ def planted(mode: str):
         generation._project_qkv_at = real_qkv
         shortconv.stored, shortconv.fresh = real_stored, real_fresh
         ssm.state_shapes, ssm.cached_block = real_shapes, real_cached
+        modeling.residual_add, modeling.lm_head = real_add, real_head
+        serve.make_weights, reference.load = real_make, real_load
         jax.clear_caches()
 
 

@@ -639,7 +639,8 @@ def forward_with_cache(params: Params, tokens, cfg: ModelConfig, cache, offsets,
                     y, cache = _windowed_attention(
                         x, p, cfg.layer_view(i), cache, stacks[i][0] == "window",
                         stacks[i][1], starts, slot, offsets, cos_sin)
-                    x = x + modeling.post_norm(y, p, "post_attn_norm", cfg)
+                    x = modeling.residual_add(
+                        x, modeling.post_norm(y, p, "post_attn_norm", cfg), cfg)
                 elif kind is not None:
                     # (under the layer's view; a window layer of other sizes: its own
                     # rotary table and its place in the ring's stack)
@@ -650,7 +651,8 @@ def forward_with_cache(params: Params, tokens, cfg: ModelConfig, cache, offsets,
                         modeling.norm(x, p["attn_norm"], cfg), p[kind], view, cache,
                         stacks[i][1] if cfg.windowed else i, starts, slot, offsets,
                         tables[view.rope_theta])
-                    x = x + modeling.post_norm(y, p, "post_attn_norm", cfg)
+                    x = modeling.residual_add(
+                        x, modeling.post_norm(y, p, "post_attn_norm", cfg), cfg)
                 else:
                     q, k, v = _project_qkv_at(x, p, cfg, cos_sin)
                     gate = _project_gate_at(x, p, cfg)
@@ -664,10 +666,11 @@ def forward_with_cache(params: Params, tokens, cfg: ModelConfig, cache, offsets,
                     o = modeling.gate_output(o, gate)
                     with jax.named_scope("out_proj"):
                         y = modeling.attn_output(o, p["attn"], cfg, x.dtype)
-                    x = x + modeling.post_norm(y, p, "post_attn_norm", cfg)
+                    x = modeling.residual_add(
+                        x, modeling.post_norm(y, p, "post_attn_norm", cfg), cfg)
             if "mlp" in p:  # (a layer of its mixer alone has none: ``mlp_layout``)
-                x = x + modeling.post_norm(
-                    _mlp_at(x, p, cfg, moe_stats, router_x), p, "post_mlp_norm", cfg)
+                x = modeling.residual_add(x, modeling.post_norm(
+                    _mlp_at(x, p, cfg, moe_stats, router_x), p, "post_mlp_norm", cfg), cfg)
     return _head(x, params, cfg), (cache if kind is not None or by_stack else KVCache(ks, vs))
 
 
@@ -713,9 +716,10 @@ def _layer_with_cache_paged(x, p, cfg: ModelConfig, pool_k, pool_v, tables,
         o = modeling.gate_output(o, gate)
         with jax.named_scope("out_proj"):
             y = modeling.attn_output(o, p["attn"], cfg, x.dtype)
-        x = x + modeling.post_norm(y, p, "post_attn_norm", cfg)
+        x = modeling.residual_add(x, modeling.post_norm(y, p, "post_attn_norm", cfg), cfg)
     y = modeling.mlp_block(modeling.norm(x, p["mlp_norm"], cfg), p["mlp"], cfg, train=False)
-    return x + modeling.post_norm(y, p, "post_mlp_norm", cfg), pool_k, pool_v
+    return (modeling.residual_add(x, modeling.post_norm(y, p, "post_mlp_norm", cfg), cfg),
+            pool_k, pool_v)
 
 
 def forward_with_cache_paged(params: Params, tokens, cfg: ModelConfig,

@@ -80,7 +80,7 @@ class Mixer:
 MIXERS: Dict[str, Mixer] = {entry.kind: entry for entry in (
     Mixer(
         kind="ssm", module="galvatron_tpu.models.ssm", layer="state-space layer",
-        mixer="the Mamba-2 mixer", tag="state_space_layers", kernels=("scan", "conv"),
+        mixer="the Mamba-2 mixer", tag="state_space_layers", kernels=("scan", "conv", "step"),
         state="conv + scan",
         lacks={
             "tp": "the Mamba-2 mixer's heads, conv channels and scan carry no tp sharding",

@@ -1629,7 +1629,7 @@ def mlp_residual(x, p, cfg: ModelConfig, train: bool = True, place: Placement = 
         normed = norm(x, p["mlp_norm"], cfg)
         with jax.named_scope("mlp"):
             y, stats = moe.moe_topk_block(normed, p["mlp"], cfg, place=place, router_x=router_x)
-        return x + post_norm(y, p, "post_mlp_norm", cfg), stats
+        return residual_add(x, post_norm(y, p, "post_mlp_norm", cfg), cfg), stats
     if cfg.moe_dropless:
         # a leading dense layer of such a model (``moe_dense_layers``): no router, no statistics
         return mlp_residual(x, p, cfg.replace(moe_experts=0), train=train, place=place), None
@@ -2374,6 +2374,25 @@ PRESETS: Dict[str, ModelConfig] = {
         ssm_heads=64, ssm_head_dim=64, ssm_state=128, ssm_groups=1, ssm_conv=4,
         ssm_chunk=256, attention_multiplier=0.015625, embedding_multiplier=12.0,
         residual_multiplier=0.22, logits_scaling=8.0,
+    ),
+    # ibm-granite/granite-4.0-h-small (model_type granitemoehybrid, "32B-A9B"): the micro's
+    # stack at hidden 4096 with a ROUTED MLP in every layer: 72 experts of width 768
+    # (``intermediate_size``), the 10 largest router logits a token, softmax over those 10,
+    # beside a shared SwiGLU MLP of 1536 added as it is; Mamba-2 mixers of 128 heads x 64,
+    # state 128, ONE scan group (a row's scan state is (128, 8192) float32 = 4 MiB a layer),
+    # conv 4, chunks of 256; GQA 32 / 8 heads of 128 without any position signal, softmax
+    # scale 1/128; the embedding x 12, every residual branch x 0.22, logits / 16; tied head.
+    # Served (``cli serve --param_dtype bf16``: models/generation.py's state stack) and trained
+    "granite-4.0-h-small": ModelConfig(
+        vocab_size=100352, hidden_size=4096, num_layers=40, num_heads=32, num_kv_heads=8,
+        ffn_dim=768, max_seq_len=131072, pos_embed="nope", tie_word_embeddings=True,
+        layer_kinds=tuple(
+            "attention" if i % 10 == 5 else "ssm" for i in range(40)),
+        ssm_heads=128, ssm_head_dim=64, ssm_state=128, ssm_groups=1, ssm_conv=4,
+        ssm_chunk=256, attention_multiplier=0.0078125, embedding_multiplier=12.0,
+        residual_multiplier=0.22, logits_scaling=16.0,
+        moe_experts=72, moe_router="softmax_topk", moe_top_k=10, moe_ffn_dim=768,
+        moe_norm_topk=True, moe_shared_ffn_dim=1536, moe_shared_gate=False,
     ),
     # Qwen/Qwen3-Next-80B-A3B-Instruct (model_type qwen3_next): 48 layers, gated
     # attention at (l + 1) % 4 == 0 and Gated DeltaNet mixers elsewhere; GQA 16 /

@@ -98,12 +98,15 @@ def fwd_flops_per_token(cfg) -> float:
 
 def path_counts(cfg) -> dict:
     """Which scan and which conv a configuration's state-space layers take
-    (`ops/ssd.scan_path`, `conv_path`: the functions `block` asks)."""
+    (`ops/ssd.scan_path`, `conv_path`: the functions `block` asks), and which body a
+    SERVED row's single step (`ops/ssd.step_path`, what `cached_block` asks: "fused" is
+    the kernel `ssm_step`)."""
     layers = cfg.kinds.count("ssm")
+    sizes = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state)
     return {
-        "scan": tally(scan_path(cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state,
-                                cfg.ssm_chunk, cfg.dtype), layers),
+        "scan": tally(scan_path(*sizes, cfg.ssm_chunk, cfg.dtype), layers),
         "conv": tally(conv_path(conv_windows(cfg), cfg.ssm_conv, cfg.dtype), layers),
+        "step": tally("fused" if ssd.step_path(*sizes) == "kernel" else "plain", layers),
     }
 
 

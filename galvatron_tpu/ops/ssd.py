@@ -151,7 +151,10 @@ _STEP_BLOCK_BYTES = 1 << 20  # of state a grid step: in and out, two buffers eac
 
 def _step_groups(groups: int, width: int, state: int) -> int:
     """Scan groups a grid step of `ssm_step` takes: the most that keep its block of
-    the state inside `_STEP_BLOCK_BYTES` (4 of 8 at the published sizes: 1 MiB)."""
+    the state inside `_STEP_BLOCK_BYTES` (4 of 8 at nemotron_h's sizes: 1 MiB). ONE group
+    is never cut: Granite 4.0-H Small's 128 x 64 = 8192 lanes go whole, a 4 MiB block (16
+    MiB resident, inside `pallas_common.VMEM_LIMIT_MB`; PERF.md 6, PR 70, has the chip's
+    reading and why no lane-block axis was added)."""
     gb = groups
     while gb > 1 and (gb * width * state * 4 > _STEP_BLOCK_BYTES or groups % gb):
         gb -= 1

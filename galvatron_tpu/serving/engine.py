@@ -1129,6 +1129,7 @@ class Engine:
             # so the rewrite is idempotent.
             starts[-1] = smax - c
         router: Dict[str, jax.Array] = {}  # the last chunk's `_router_counters`
+        full_chunks: list = []  # (tracer on) those of the chunks whose every row is real
         if self.cfg.moe_dropless:
             span.set(moe_row_tile=moe.layer_row_tile(self.cfg, c))
         key_block = self.cache_layout.get("chunk_key_block")  # None for a plain `KVCache`
@@ -1172,6 +1173,8 @@ class Engine:
                     self.params, self.cfg, self.slots.cache, jnp.asarray(buf),
                     np.int32(slot), np.int32(start), self._rows, np.int32(n - 1),
                 )
+                if _obs_tracer.enabled and n == c:
+                    full_chunks.append(router)
             self.counters.inc("prefill_chunks")
             self.counters.inc("prefill_tokens", n)
             if ring:
@@ -1211,6 +1214,18 @@ class Engine:
             # (the span's other end: the chunks and the first draw are through)
             span.sync(self._ids)
             span.set(**{k: float(v) for k, v in router.items()})
+            if "moe_held_pairs_per_token" in router:
+                # what ONE chunk of the prompt put on the held experts, a layer: the mean
+                # over its chunks of `c` real rows (a ragged last chunk routes its padding
+                # too, all of it to the same few experts, so it is left out; a prompt
+                # shorter than a chunk has no other, and `moe_chunks_counted` says 0)
+                of = full_chunks or [router]
+                span.set(
+                    moe_chunks_counted=len(full_chunks),
+                    moe_held_pairs=c * sum(
+                        float(r["moe_held_pairs_per_token"]) for r in of) / len(of),
+                    moe_held_experts_touched_a_chunk=sum(
+                        float(r["moe_held_experts_touched"]) for r in of) / len(of))
         rz.advance(req, rz.DECODING, slot=slot)
         self._busy_s += time.perf_counter() - t0
 

@@ -143,8 +143,45 @@ _PINNED_BEFORE_PR_68.update({
                  "shortconv_prefill_chunk_ms")})
 
 
+#: Twelve accepted cases of tests/benchmark/test_benchmark_nemotron.py pin the manifest as PR
+#: 68 left it: its cell the LAST of 14 workloads and 11 configurations, its seven readers the
+#: last of 96, its three prompt-chunk readers listing its cell ALONE, ``kv_prefill_chunk_attn_ms``
+#: ending on its cell, and six serving readers in all with a list.  ISSUE 70 asks for a cell
+#: appended to those four lists (a Mamba-2 stack's prompt chunk is what it measures) and for
+#: two listed readers of a chunk's routed experts; a new entry goes at the END of its list, by
+#: the driver's rule, and only a PR of kind ``benchmark`` may edit that file.  They are
+#: expected to fail, strictly, until such a PR relaxes them in place and deletes this list.
+#: Nothing else they assert is off meanwhile: each body stands whole, one clause amended (or
+#: is RUN whole under the amended lists), in tests/benchmark/test_benchmark_granite_small.py,
+#: whose last case holds this list and those one for one.
+_PINNED_BEFORE_PR_70 = frozenset(
+    ["tests/benchmark/test_benchmark_nemotron.py::" + case for case in (
+        "test_the_cell_joins_the_manifest_by_appends",
+        "test_dots3_the_cell_joins_the_manifest_by_appends",
+        "test_chunk_lists_case_runs_whole_under_the_amended_lists[test_metrics-args0]",
+        "test_chunk_lists_case_runs_whole_under_the_amended_lists"
+        "[test_smallthinker_metric_is_declared_as_a_serving_reader-args1]",
+        "test_chunk_lists_case_runs_whole_under_the_amended_lists"
+        "[test_the_latent_cell_still_reads_the_rate_and_every_serving_reader-args2]",
+        "test_chunk_lists_case_runs_whole_under_the_amended_lists"
+        "[test_a_profile_without_a_prompt_chunk_leaves_the_chunk_readers_silent-args3]")]
+    + ["tests/benchmark/test_benchmark_nemotron.py::test_metric_is_declared_as_a_serving_reader"
+       "[%s]" % name for name in ("ssm_prefill_chunk_ms", "ssm_chunk_scan_ms",
+                                  "ssm_chunk_scan_roofline")]
+    + ["tests/benchmark/test_benchmark_nemotron.py::test_dots3_a_prompt_chunk_reader_lists_the_"
+       "accepted_serving_cells[%s]" % name
+       for name in ("mla_prefill_chunk_attn_ms", "kv_prefill_chunk_attn_ms",
+                    "shortconv_prefill_chunk_ms")])
+
+
 def pytest_collection_modifyitems(items):
     for item in items:
+        if item.nodeid in _PINNED_BEFORE_PR_70:
+            item.add_marker(pytest.mark.xfail(
+                strict=True, raises=AssertionError,
+                reason="pins the manifest's tail, PR 68's three chunk readers' lists or the "
+                       "listed serving readers as PR 68 left them; PR 70 appended a cell to "
+                       "four lists and two listed readers"))
         if item.nodeid in _PINNED_BEFORE_PR_68:
             item.add_marker(pytest.mark.xfail(
                 strict=True, raises=_PINNED_BEFORE_PR_68[item.nodeid],

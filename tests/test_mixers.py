@@ -169,8 +169,8 @@ def test_a_third_kind_shows_in_the_run_s_fingerprint(glm, monkeypatch, tmp_path)
                if e["ph"] == "X" and e["name"] == "build_runtime"]
     assert span["args"]["layer_kinds"] == {"glm": 1, "attention": 1}
     assert [k for k in span["args"] if k.endswith("_path")] == [
-        "ssm_scan_path", "ssm_conv_path", "gdn_scan_path", "gdn_conv_path", "shortconv_conv_path",
-        "moe_held_path"]
+        "ssm_scan_path", "ssm_conv_path", "ssm_step_path", "gdn_scan_path", "gdn_conv_path",
+        "shortconv_conv_path", "moe_held_path"]
 
 
 def test_the_registry_loads_a_kind_s_module_only_for_a_stack_that_has_it():

@@ -943,7 +943,8 @@ def test_every_benchmark_configuration_with_a_routed_layer_counts_its_layout():
                 lambda i: moe.sorted_layout(i, groups, tile))(idx).jaxpr))
             routed[os.path.basename(path)[:-5]] = groups, bool({"sort", "scatter-add"} & names)
     assert routed == {
-        "dots3-note-prev": (33, False), "lfm2-24b-a2b": (17, False),
+        "dots3-note-prev": (33, False), "granite-4.0-h-small": (37, False),
+        "lfm2-24b-a2b": (17, False),
         "nemotron-3-nano-30b-a3b": (33, False), "olmoe-1b-7b": (64, False),
         "qwen3-next-80b-a3b": (33, False), "sarvam-105b": (33, False),
         "smallthinker-21b-a3b": (17, False), "trinity-large-preview": (33, False)}

@@ -454,6 +454,47 @@ def test_a_mamba_stacks_serving_program_carries_the_scope(ssm_serving_texts, pro
     assert not [n for n in names if "layer_2/mlp" in n], program
 
 
+#: a served Granite 4.0-H Small stack (PR 70): a ROUTED MLP behind every mixer, so both
+#: programs carry the ``ssm`` scopes AND, under ``mlp``, the five the expert readers key on
+#: (`serve_expert_ms_per_step`); the shared SwiGLU
+#: expert's scope is ``shared_expert``
+GRANITE_SMALL_SCOPES = ("layer_0/attn/ssm/in_proj", "layer_0/attn/ssm/state_read",
+                        "layer_0/attn/ssm/conv", "layer_0/attn/ssm/gate_norm",
+                        "layer_0/attn/ssm/out_proj", "layer_0/attn/ssm/state_write",
+                        "layer_0/mlp/router", "layer_0/mlp/dispatch", "layer_0/mlp/experts",
+                        "layer_0/mlp/combine", "layer_0/mlp/shared_expert",
+                        "layer_5/attn/full/attn_core", "layer_5/mlp/experts")
+
+
+@pytest.fixture(scope="module")
+def granite_small_texts():
+    from galvatron_tpu.aot import registry
+    from galvatron_tpu.models.modeling import PRESETS
+    from galvatron_tpu.serving import engine  # noqa: F401  (registers the serving family)
+
+    cfg = PRESETS["granite-4.0-h-small"].replace(
+        vocab_size=128, hidden_size=32, num_layers=6, num_heads=4, num_kv_heads=2, ffn_dim=24,
+        max_seq_len=32, ssm_heads=8, ssm_head_dim=4, ssm_state=8, ssm_chunk=8, moe_experts=8,
+        moe_top_k=3, moe_ffn_dim=24, moe_shared_ffn_dim=40, moe_share=(0, 2))
+    ctx = registry.ProgramContext(cfg=cfg, num_slots=2, prefill_chunk=8, max_seq_len=32)
+    return {spec.name: spec.fn.lower(*spec.args).compile().as_text()
+            for spec in registry.enumerate_programs(ctx, include=("serving",))}
+
+
+@pytest.mark.parametrize("scope", GRANITE_SMALL_SCOPES + ("layer_0/attn/ssm/step",
+                                                          "layer_0/attn/ssm/scan"))
+@pytest.mark.parametrize("program", SERVING_PROGRAMS[:2])
+def test_a_served_granite_stacks_program_carries_the_scope(granite_small_texts, program, scope):
+    import re
+
+    names = re.findall(r'op_name="([^"]*)"', granite_small_texts[program])
+    has = any(re.search(rf"[/(]{scope}(?:[/)]|$)", n) for n in names)
+    if scope.endswith(("/step", "/scan")):  # the decode step has `step`, the chunk `scan`
+        assert has == (scope.endswith("/step") == ("decode" in program)), (program, scope)
+    else:
+        assert has, (program, scope)
+
+
 @pytest.mark.parametrize("scope", STATE_SCOPES)
 @pytest.mark.parametrize("program", SERVING_PROGRAMS[:2])
 def test_a_state_stacks_serving_program_carries_the_scope(state_texts, program, scope):
@@ -866,7 +907,11 @@ def test_build_runtime_span_counts_the_conv_path_beside_the_scan_path(traced_run
     assert list(span["args"]).index("ssm_conv_path") == list(span["args"]).index("ssm_scan_path") + 1
     # and the same two for Gated DeltaNet layers (PR 47), behind them: none here
     assert span["args"]["gdn_scan_path"] == span["args"]["gdn_conv_path"] == {"fused": 0, "plain": 0}
-    assert list(span["args"]).index("gdn_scan_path") == list(span["args"]).index("ssm_conv_path") + 1
+    # (between them since PR 70: the body a SERVED row's single step takes,
+    # `ops/ssd.step_path`; none here)
+    assert span["args"]["ssm_step_path"] == {"fused": 0, "plain": 0}
+    assert list(span["args"]).index("ssm_step_path") == list(span["args"]).index("ssm_conv_path") + 1
+    assert list(span["args"]).index("gdn_scan_path") == list(span["args"]).index("ssm_step_path") + 1
     # and the path a held share of the experts takes (PR 49), behind those: no share here
     assert span["args"]["moe_held_path"] == {"bounded": 0, "worst_case": 0}
     # (between them since PR 58: the gated short convolution's one body, none here)

@@ -379,6 +379,72 @@ def test_scan_path_counts_the_layers_of_a_configuration(monkeypatch):
     assert ssm.path_counts(PRESETS["llama-7b"])["scan"] == {"fused": 0, "plain": 0}
 
 
+# -- the served state's kernels at one wide group (PR 70) -----------------------------------
+
+#: (heads, head size, groups, state): Granite 4.0-H Small's ONE group of 8192 lanes (4 MiB a
+#: row and layer) and nemotron_h's 8 groups of 512 (2 MiB)
+STATE_SHAPES = {"one_group_of_8192": (128, 64, 1, 128), "eight_groups_of_512": (64, 64, 8, 128)}
+
+
+def test_one_wide_group_is_one_block_of_the_step():
+    """A grid step of `ssm_step` takes whole groups: 4 of nemotron_h's 8 (1 MiB), and the
+    ONE group of 8192 lanes whole (4 MiB: read on the chip as fast as lane blocks inside
+    the group, PERF.md 6, PR 70, so no lane-block axis exists)."""
+    assert ssd._step_groups(8, 512, 128) == 4 and ssd._step_groups(1, 8192, 128) == 1
+    assert ssd._step_groups(1, 4096, 128) == 1  # granite-4.0-h-micro's, were it served
+    assert [ssd.step_path(*sizes) for sizes in STATE_SHAPES.values()] == ["plain"] * 2  # the CPU's
+
+
+@pytest.mark.parametrize("shape", list(STATE_SHAPES))
+def test_the_step_kernel_is_the_plain_step_at_the_published_shapes(shape):
+    """`ssm_step` (interpreted here, called as a chip calls it) against the plain body at
+    the two published shapes, over a stack of two layers in place: a row that has not
+    started reads zero, the other layer's states stay."""
+    h, p, g, n = STATE_SHAPES[shape]
+    ks = jax.random.split(jax.random.key(7), 5)
+    stack = jax.random.normal(ks[0], (2, 2) + ssd.state_shape(h, p, n))
+    decay = jnp.repeat(jnp.where(jnp.array([True, False])[:, None], jnp.exp(
+        -jax.nn.softplus(jax.random.normal(ks[1], (2, h)))), 0.0), p, axis=1)
+    dtx = jax.random.normal(ks[2], (2, h * p))
+    b, c = jax.random.normal(ks[3], (2, g, n)), jax.random.normal(ks[4], (2, g, n))
+    want_y, want = ssd.ssd_step_plain(stack, 1, decay, dtx, b, c)
+    got_y, got = ssd._step_call(stack, 1, decay, dtx, b, c)
+    harness.close(got_y, want_y, 1e-6)
+    harness.close(got, want, 1e-6)
+    assert np.array_equal(np.asarray(got[0]), np.asarray(stack[0]))
+
+
+@pytest.mark.parametrize("shape", list(STATE_SHAPES))
+def test_the_row_kernels_are_the_slices_at_the_published_shapes(monkeypatch, shape):
+    """`ssm_state_read` / `ssm_state_write` (interpreted) against ``dynamic_slice`` /
+    ``dynamic_update_slice`` at the two published shapes: a row out and in, bit for bit,
+    every other row and layer as it was."""
+    h, p, _, n = STATE_SHAPES[shape]
+    stack = jax.random.normal(jax.random.key(9), (2, 3, n, h * p))
+    monkeypatch.setattr(ssd, "rows_path", lambda *a: "kernel")
+    got = ssd.read_rows(stack, 1, jnp.int32(2), 1)
+    assert np.array_equal(np.asarray(got), np.asarray(stack[1, 2:3]))
+    new = jax.random.normal(jax.random.key(10), (1, n, h * p))
+    out = ssd.write_rows(stack, 1, jnp.int32(2), new)
+    assert np.array_equal(np.asarray(out), np.asarray(stack.at[1, 2:3].set(new)))
+
+
+def test_step_path_counts_the_layers_of_a_configuration(monkeypatch):
+    """``ssm_step_path`` (fingerprint, the ``build_runtime`` span, `Engine.stats`): which
+    body a served row's single step takes, by `ops/ssd.step_path`."""
+    small = PRESETS["granite-4.0-h-small"].replace(num_layers=10)
+    nemotron = PRESETS["nemotron-3-nano-30b-a3b"].replace(num_layers=15)
+    assert ssm.path_counts(small)["step"] == {"fused": 0, "plain": 9}  # no chip here
+    on_a_chip(monkeypatch)
+    assert ssm.path_counts(small)["step"] == {"fused": 9, "plain": 0}
+    assert ssm.path_counts(nemotron)["step"] == {"fused": 12, "plain": 0}
+    assert ssm.path_counts(small_cfg())["step"] == {"fused": 6, "plain": 0}  # 8 heads of 16
+    assert ssm.path_counts(small_cfg(ssm_head_dim=8))["step"] == {"fused": 0, "plain": 6}
+    from galvatron_tpu.models import mixers
+
+    assert mixers.path_counts(PRESETS["llama-7b"])["ssm_step_path"] == {"fused": 0, "plain": 0}
+
+
 def test_the_mixer_through_the_kernels_equals_the_mixer_through_the_plain_scan(monkeypatch):
     """`ssm.block` at sizes inside the envelope: output and the gradient of every
     parameter (D's skip and the conv in front included) agree between the two

@@ -1696,3 +1696,95 @@ def test_nemotron_ungated_experts_compile_without_a_copy_of_the_stack(one_chip, 
     assert not [line[:160] for line in text.splitlines()
                 if " copy(" in line and "bf16[32," in line and "1856" in line]
 
+
+
+# -- granite-4.0-h-small at its published widths (PR 70): ONE scan group of 8192 lanes through
+# the state kernels, 36 of 72 gated experts of width 768 on the bounded path ---
+
+
+def _granite_small_cut():
+    from galvatron_tpu.models.modeling import PRESETS
+
+    return PRESETS["granite-4.0-h-small"].replace(
+        num_layers=10, vocab_size=50176, moe_share=(0, 2), param_dtype=jnp.bfloat16)
+
+
+@pytest.mark.parametrize("form", ["decode_step", "prompt_chunk"])
+def test_granite_small_mixer_moves_the_wide_state_in_place(one_chip, real_mosaic, form):
+    """Two Mamba-2 layers of the cell's stack (9 layers x 32 rows x 4 MiB: 1.2 GB of float32
+    scan state) as the chip's compiler sees the cached forward: a decode step through
+    `ssm_step` (the one group of 8192 lanes a block of 4 MiB: 16 MiB resident, which Mosaic
+    takes under `pallas_common.VMEM_LIMIT_MB`), a prompt chunk through `ssm_state_read` /
+    `ssm_state_write` around the plain scan; the stack is donated and NOTHING of its size
+    is copied."""
+    import re
+
+    from galvatron_tpu.models import ssm
+
+    cfg = _granite_small_cut()
+    assert ssm.path_counts(cfg)["step"] == {"fused": 9, "plain": 0}
+    sd = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)  # noqa: E731
+    p = jax.tree.map(sd, jax.eval_shape(lambda k: ssm.init_params(k, cfg), jax.random.key(0)))
+    state = jax.tree.map(sd, jax.eval_shape(lambda: ssm.init_state(cfg, 9, 32)))
+    assert state.scan.shape == (9, 32, 128, 8192) and state.scan.dtype == jnp.float32
+    assert state.conv.shape == (9, 32, 3 * 8448) and state.conv.dtype == jnp.bfloat16
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    if form == "decode_step":
+        x = jax.ShapeDtypeStruct((32, 1, 4096), jnp.bfloat16, sharding=one_chip)
+        at = jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip)
+
+        def fn(x_, p_, st, offsets):
+            y, st = ssm.cached_block(x_, p_, cfg, st, 3, None, offsets, 0)
+            return ssm.cached_block(x_ + y, p_, cfg, st, 7, None, offsets, 0)
+
+        args, names = (x, p, state, at), ["ssm_step"] * 2
+    else:
+        x = jax.ShapeDtypeStruct((1, 1024, 4096), jnp.bfloat16, sharding=one_chip)
+
+        def fn(x_, p_, st, slot, start, last):
+            y, st = ssm.cached_block(x_, p_, cfg, st, 3, slot, start, last)
+            return ssm.cached_block(x_ + y, p_, cfg, st, 7, slot, start, last)
+
+        args, names = (x, p, state, i32, i32, i32), ["ssm_state_read", "ssm_state_write"] * 2
+    compiled = jax.jit(fn, donate_argnums=(2,)).lower(*args).compile()
+    text = compiled.as_text()
+    found = [m.group(0) for n, _ in _entry_work(text)
+             for m in [re.search(r"ssm_(?:step|state_read|state_write)", n)] if m]
+    assert sorted(found) == sorted(names), found
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < 0.4 * 2**30, f"{ma.temp_size_in_bytes / 2**30:.2f} GiB"
+    assert ma.alias_size_in_bytes >= 9 * 32 * 4244992
+    assert not [line[:160] for line in text.splitlines()
+                if " copy(" in line and "9,32,128,8192" in line]
+
+
+@pytest.mark.parametrize("tokens", [32, 1024], ids=["decode_step", "prompt_chunk"])
+def test_granite_small_held_experts_take_the_bounded_kernels(one_chip, real_mosaic, tokens):
+    """An expert layer of the cell as a CACHED forward runs it: 36 of 72 held (neither a
+    power of two), top-10, gated, width 768 = 6 lane tiles on a hidden of 4096, a decode
+    step's 32 tokens (4.4 rows an expert: tile 16) and a prompt chunk's 1,024 (142: tile
+    128): the bounded path's six kernels, no copy of a stack of the experts' weights."""
+    import re
+
+    from galvatron_tpu.models import generation, moe
+
+    cfg = _granite_small_cut()
+    assert (cfg.moe_experts, cfg.moe_held, cfg.moe_top_k, cfg.expert_ffn) == (72, 36, 10, 768)
+    assert moe.held_path_counts(cfg) == {"bounded": 10, "worst_case": 0}
+    assert moe.layer_row_tile(cfg, tokens) == (16 if tokens == 32 else 128)
+    shapes = jax.eval_shape(
+        lambda k: {"mlp": moe.init_moe_params(k, cfg),
+                   "mlp_norm": {"scale": jnp.zeros((cfg.hidden_size,), cfg.param_dtype)}},
+        jax.random.key(0))
+    assert shapes["mlp"]["w1"].shape == (36, 4096, 768)
+    p = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), shapes)
+    x = jax.ShapeDtypeStruct((tokens, 1, 4096) if tokens == 32 else (1, tokens, 4096),
+                             jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(lambda x_, p_: generation._mlp_at(x_, p_, cfg, None)).lower(x, p).compile()
+    text = compiled.as_text()
+    kernels = sorted(n.split(".")[0] for n, _ in _entry_work(text) if n.startswith("moe_"))
+    assert kernels == sorted(["moe_held_rows", "moe_gmm", "moe_gmm", "moe_held_swiglu", "moe_gmm",
+                              "moe_held_pairs"]), kernels
+    assert not re.search(r"= bf16\[36,4096,(768|1536)\]\S* (fusion|copy|concatenate)\(",
+                         "\n".join(_entry_lines(text)))
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * 2**30
