@@ -57,9 +57,12 @@ rescale, a headwise sigmoid gate before ``W_o``, and
 - a WINDOW layer (``attn_window``, the view's own sizes) whose latent lives in a RING
   (`LatentCache.ring`: position p at ``p mod R``, `generation.write_ring`), masked by
   the absolute position a place holds: the absorbed form over the ring for a decode
-  window, the chunk form over the ring's key blocks for a prompt chunk.
+  window (`attend_ring`: the kernel `mla_decode` with the window as its ``span`` where
+  `mla_decode.decode_path` says so, each row's key blocks along the ARC its window holds
+  and no other; XLA's over every place of the ring elsewhere), the chunk form over the
+  ring's key blocks for a prompt chunk.
 
-The rest are XLA's bodies (`mla_chunk` takes no mask, and neither kernel a ring). Such
+The rest are XLA's bodies (`mla_chunk` takes no mask and no ring). Such
 a stack's scopes lie one level deeper, under ``attn`` > ``full``
 | ``window`` (the windowed K/V stacks' two words), with ``indexer``, ``select`` and
 ``gate`` beside the four.
@@ -210,11 +213,19 @@ def cache_layout(cfg, max_len: Optional[int] = None, tokens: int = 1) -> Optiona
            "index_topk": cfg.mla_index_topk, "index_heads": cfg.mla_index_heads,
            # (what `mla_decode.decode_path` asks of a full layer's decode window)
            "full_window": (full.num_heads, full.mla_kv_rank + full.mla_rope_dim,
-                           full.mla_kv_rank, jnp.dtype(cfg.dtype).name) if full else None}
+                           full.mla_kv_rank, jnp.dtype(cfg.dtype).name) if full else None,
+           # (and of a window layer's over its ring)
+           "ring_window": (win.num_heads, win.mla_kv_rank + win.mla_rope_dim,
+                           win.mla_kv_rank, jnp.dtype(cfg.dtype).name) if win else None}
     if max_len is not None:
         ring = generation.ring_positions(cfg, max_len, tokens) if n_win else 0
         if n_win:
-            out["ring_positions"] = ring
+            # (which body a decode step's attention over the ring takes, `attend_ring`'s own
+            # question, and the key block the kernel reads it in: 0 on the plain body)
+            path = _ring_path(win, ring)
+            out.update(ring_positions=ring, ring_decode_path=path,
+                       ring_key_block=mla_decode.ring_block(ring, win.attn_window)
+                       if path == "kernel" else 0)
         out["bytes_per_slot"] = (
             out["full_layers"] * max_len * (out["latent_bytes_per_position"]
                                             + out["index_bytes_per_position"])
@@ -230,7 +241,10 @@ def step_counters(layout: dict, lengths, rows: int, positions: int, window: int 
     (`index_scores`), and the latents the same way (`attend_masked`'s loop) or, through
     the kernel `mla_decode`, each row up to its OWN length in whole key blocks (a row out
     of use one block), where its selection keeps ``min(n, topk)`` a row; a window layer
-    reads every row's whole ring."""
+    reads every row's whole ring on the plain body and, through the kernel, the key
+    blocks each row's arc touches (`mla_decode.ring_read_positions`);
+    ``ring_decode_path`` says which (`attend_ring`'s own question, asked of this
+    ``window``), so that an iteration's span carries it."""
     topk = layout["index_topk"] or positions
     span, ring = layout["window"], layout.get("ring_positions", 0)
     out = {"dsa_live_positions": sum(lengths),
@@ -257,7 +271,13 @@ def step_counters(layout: dict, lengths, rows: int, positions: int, window: int 
         read(_index_block(rows * window * layout["index_heads"], positions))
         if layout["index_topk"] else 0)
     out["latent_ring_live_positions"] = sum(min(n, span) for n in lengths) if span else 0
-    out["latent_ring_read_positions"] = rows * ring
+    if ring:
+        heads, width, rank, dtype = layout["ring_window"]
+        out["ring_decode_path"] = mla_decode.decode_path(ring, width, window * heads, rank, dtype,
+                                                         span=span)
+    out["latent_ring_read_positions"] = (
+        mla_decode.ring_read_positions(lengths, rows, ring, span, window)
+        if out.get("ring_decode_path") == "kernel" else rows * ring)
     return out
 
 
@@ -562,20 +582,38 @@ def _masked_context(q_cat, latent, visible, cfg):
     return jnp.einsum("bnqk,bkc->bqnc", probs, latent)[..., :cfg.mla_kv_rank]
 
 
+def _ring_path(cfg, places: int, window: int = 1, dtype=None) -> str:
+    """`mla_decode.decode_path` asked of a ring of ``places`` places (of ``dtype``; None:
+    ``cfg.dtype``, what `init_cache` makes) under ``cfg``, a window layer's view, for decode
+    windows of ``window`` queries a row."""
+    return mla_decode.decode_path(
+        places, cfg.mla_kv_rank + cfg.mla_rope_dim, window * cfg.num_heads, cfg.mla_kv_rank,
+        dtype or cfg.dtype, span=cfg.attn_window)
+
+
 def attend_ring(q_nope, q_rope, ring, layer: int, offsets, p: Params, cfg):
     """`attend_absorbed` for the windows at ``offsets`` (scalar | (B,)) of rows [0, B) of
-    the stacked RING (L_win, rows, R, r + dr): every place of a row's ring against the
-    absolute position it holds (`generation._ring_key_positions`), the last
-    ``cfg.attn_window`` of them seen."""
+    the stacked RING (L_win, rows, R, r + dr), the last ``cfg.attn_window`` positions
+    seen. Inside `mla_decode.decode_path`'s envelope the middle is the kernel
+    `mla_decode` over the ring where it lies, each row's key blocks along the ARC its
+    window holds (`mla_decode.ring_block` places a block); outside it the plain body:
+    every place of a row's ring against the absolute position it holds
+    (`generation._ring_key_positions`)."""
     from galvatron_tpu.models import generation
 
-    s, dn = q_nope.shape[1], cfg.mla_nope_dim
+    b, s = q_nope.shape[:2]
+    dn, r, places = cfg.mla_nope_dim, cfg.mla_kv_rank, ring.shape[2]
     wkvb = _kvb(p, cfg, ring.dtype)
     q_cat = _absorbed_queries(q_nope, q_rope, wkvb[..., :dn])
     first = jnp.reshape(jnp.asarray(offsets, jnp.int32), (-1,))
-    held = generation._ring_key_positions(first + s - 1, jnp.arange(ring.shape[2]), ring.shape[2])
-    visible = _in_window(first[:, None] + jnp.arange(s)[None], held, cfg.attn_window)
-    ctx = _masked_context(q_cat, generation.read_layer(ring, layer, None), visible, cfg)
+    if _ring_path(cfg, places, s, ring.dtype) == "kernel":
+        ctx = mla_decode.latent_attention(
+            q_cat, ring, layer, jnp.broadcast_to(first, (b,)), rank=r, scale=softmax_scale(cfg),
+            span=cfg.attn_window, block_k=mla_decode.ring_block(places, cfg.attn_window))
+    else:
+        held = generation._ring_key_positions(first + s - 1, jnp.arange(places), places)
+        visible = _in_window(first[:, None] + jnp.arange(s)[None], held, cfg.attn_window)
+        ctx = _masked_context(q_cat, generation.read_layer(ring, layer, None), visible, cfg)
     return _absorbed_values(ctx, wkvb[..., dn:])
 
 

@@ -13,6 +13,7 @@ import functools
 
 import jax
 import jax.extend
+import jax.numpy as jnp
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
@@ -50,3 +51,30 @@ def traced_once(fn, *args, **static):
     avals = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args)
     closed = _traced(fn, avals, tuple(sorted(static.items())), use_interpret())
     return jax.extend.core.jaxpr_as_fun(closed)(*args)[0]
+
+
+# -- a ring's arc (the decode kernels' block arithmetic) ---------------------------------
+
+
+def ring_steps(window: int, span: int, places: int, block: int) -> int:
+    """Grid steps a row of a ring of ``places`` places takes in key blocks of ``block``
+    (static): the most blocks the arc of `ring_arc` touches wherever it starts, an arc
+    of ``span + window - 1`` places, and no more than the ring has."""
+    return min(places // block, -(-(span + window - 2) // block) + 1)
+
+
+def ring_arc(first, window: int, span: int, places: int, block: int):
+    """Of a ring (position p at place ``p mod places``) in key blocks of ``block``, the
+    ARC a window of ``window`` queries at ``first ..`` sees under a span of ``span``:
+    positions ``(first - span, first + window - 1]``, none before 0, which lie in the ring
+    as one run of places that may wrap its end -> (the block the oldest of them lies in,
+    the blocks the run touches, no more than the ring has: a block is masked by the
+    positions it holds, so one visit serves both ends of a run that comes round to it).
+    Block ``(first block + j) mod (places // block)`` for ``j`` below the count is the walk.
+    ``first``: a traced scalar (an index map, a kernel body) or a host integer (the
+    engine's counters, once a row an iteration: plain integers, no array)."""
+    most, least = (jnp.maximum, jnp.minimum) if isinstance(first, jax.Array) else (max, min)
+    oldest = most(first - span + 1, 0)
+    at = oldest % places
+    touched = (at % block + first + window - oldest - 1) // block + 1
+    return at // block, least(touched, places // block)

@@ -289,6 +289,176 @@ def test_the_decode_kernel_under_the_selection_matches_the_reference(monkeypatch
     assert read["dsa_read_positions"] == 48 + 16 + 16 and read["dsa_index_read_positions"] == 3 * SLOT
 
 
+# -- the ring through the decode kernel (ops/mla_decode.py, span > 0; interpreted on the CPU) --
+
+RING = 2048  # (the cell's ring: a window of 513 under prompt chunks of 1,024)
+
+
+def _ring_rows(span, block, s):
+    """(name, the first query's position) of the rows one batch holds: short of the window,
+    past it, ending exactly at the ring's end, starting the second lap, lapped many times
+    with the arc's newest place early in a block (a span below the block: the arc inside
+    ONE block), with it just across a block's edge, with the arc WRAPPING the ring's end, a
+    row out of use, and a slot a shorter request took over."""
+    return [("short_of_the_window", span // 2), ("past_the_window", span + RING // 4),
+            ("ends_at_the_rings_end", RING - s), ("starts_the_second_lap", RING),
+            ("lapped_newest_late_in_a_block", 7 * RING + 4 * block - 2 - s),
+            ("lapped_across_an_edge", 9 * RING + 2 * block + 3),
+            ("lapped_and_wrapping", 5 * RING + 20), ("out_of_use", 0),
+            ("taken_over_by_a_shorter_request", 300)]
+
+
+@pytest.mark.parametrize("s", [1, 3], ids=["decode", "verify3"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("span,block", [(513, 128), (513, 256), (513, 512), (100, 128)])
+def test_the_ring_through_the_decode_kernel_is_the_plain_ring_body(monkeypatch, span, block, dtype, s):
+    """`attend_ring` through `mla_decode` (the arc's key blocks alone) against its plain
+    body (every place by the position it holds) on seeded latents, `_ring_rows` in one
+    batch: equal to a rounding; and with every place NO query of a row's window sees
+    (never written, a lap ago, the slot's previous request) filled with NaN the kernel's
+    output is bit for bit the clean ring's."""
+    from galvatron_tpu.ops import mla_decode, pallas_common
+
+    cfg = small_cfg(sliding_window_size=span, dtype=dtype)
+    view = next(v for v in map(cfg.layer_view, range(cfg.num_layers)) if v.attn_window)
+    assert view.attn_window == span
+    names, firsts = zip(*_ring_rows(span, block, s))
+    rows, (n, dn, dr, _, r) = len(firsts), mla.dims(view)
+    first = jnp.asarray(firsts, jnp.int32)
+    ks = jax.random.split(jax.random.key(span + block + s), 4)
+    p = mla.init_params(ks[0], view)
+    ring = jax.random.normal(ks[1], (3, rows, RING, r + dr), dtype)
+    q_nope = jax.random.normal(ks[2], (rows, s, n, dn), dtype)
+    q_rope = jax.random.normal(ks[3], (rows, s, n, dr), dtype)
+    # the arcs are what their names say (host arithmetic: what the index map walks)
+    arcs = {name: pallas_common.ring_arc(f, s, span, RING, block) for name, f in zip(names, firsts)}
+    steps = pallas_common.ring_steps(s, span, RING, block)
+    assert all(1 <= int(blocks) <= steps for _, blocks in arcs.values()) and steps < RING // block
+    assert tuple(map(int, arcs["out_of_use"])) == (0, 1)
+    b0, blocks = map(int, arcs["lapped_and_wrapping"])
+    assert b0 + blocks > RING // block  # (the walk goes round the ring's end)
+    if span < block:
+        assert int(arcs["lapped_newest_late_in_a_block"][1]) == 1
+        assert int(arcs["lapped_across_an_edge"][1]) == 2
+
+    monkeypatch.setattr(mla_decode, "RING_BLOCKS", ())
+    assert mla._ring_path(view, RING, s) == "plain"
+    want = mla.attend_ring(q_nope, q_rope, ring, 1, first, p, view)
+    monkeypatch.setattr(mla_decode, "RING_BLOCKS", (block,))
+    assert mla._ring_path(view, RING, s) == "kernel" and mla_decode.ring_block(RING, span) == block
+    attend = jax.jit(lambda c: mla.attend_ring(q_nope, q_rope, c, 1, first, p, view))
+    got = attend(ring)
+    assert got.shape == want.shape and got.dtype == dtype
+    close(got.astype(jnp.float32), want.astype(jnp.float32), F32_TOL if dtype == jnp.float32 else 2e-2)
+    held = generation._ring_key_positions(first + s - 1, jnp.arange(RING), RING)
+    unseen = (held <= (first - span)[:, None]) | (held < 0)
+    assert bool(unseen[names.index("taken_over_by_a_shorter_request"), 301 + s:].all())
+    dirty = attend(jnp.where(unseen[None, :, :, None], jnp.nan, ring))
+    assert bool(jnp.isfinite(dirty).all())
+    np.testing.assert_array_equal(np.asarray(dirty), np.asarray(got))
+
+
+@pytest.mark.parametrize("selected", [False, True], ids=["causal", "selected"])
+def test_without_a_span_the_decode_kernel_walks_the_prefix_as_before(selected):
+    """``span`` 0 (a full layer's slots, the sarvam cell's and this stack's two): the prefix
+    walk, its grid the slot's blocks. Its values are to the bit those of a ring that never
+    laps under a span as long as the slot (the same blocks in the same order, the masked
+    body where the prefix walk takes a block whole), with and without a selection's values
+    (`experiments/step_text_digest.py` holds the lowered TEXT to the parent's)."""
+    from galvatron_tpu.ops import mla_decode
+
+    ks = jax.random.split(jax.random.key(3), 3)
+    stacked = jax.random.normal(ks[0], (2, 4, SLOT, 20), jnp.float32)
+    q_cat = jax.random.normal(ks[1], (4, 1, 4, 20), jnp.float32)
+    first = jnp.asarray([0, 15, 33, SLOT - 1], jnp.int32)
+    seen = None
+    if selected:
+        seen = (jax.random.uniform(ks[2], (4, 1, SLOT)) < 0.5) & (
+            jnp.arange(SLOT)[None, None] <= first[:, None, None])
+        seen = seen.at[:, :, 0].set(True)
+    kw = dict(rank=16, scale=0.25, block_k=16)
+    got = mla_decode.latent_attention(q_cat, stacked, 1, first, selected=seen, **kw)
+    same = mla_decode.latent_attention(q_cat, stacked, 1, first, selected=seen, span=0, **kw)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(same))
+    if selected:
+        with pytest.raises(ValueError, match="a selection is a full layer's"):
+            mla_decode.latent_attention(q_cat, stacked, 1, first, selected=seen, span=SLOT, **kw)
+    else:
+        ring = mla_decode.latent_attention(q_cat, stacked, 1, first, span=SLOT, **kw)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(ring))
+
+
+def test_the_ring_through_the_decode_kernel_serves_the_references_rows(monkeypatch, retraced):
+    """A ring of whole key blocks (4 here, interpreted; `ring_block`'s on the chip): a decode
+    step's attention of the three sliding layers is the kernel `mla_decode` over the ring
+    with the layer's window as its span, the rows lapping the ring during decode; a prompt
+    chunk keeps XLA's body. The logits are the reference's as before, and the layout and the
+    counters say which path read the ring and what it fetched."""
+    from galvatron_tpu.ops import mla_decode
+
+    monkeypatch.setattr(mla_decode, "RING_BLOCKS", (4,))
+    retraced()
+    cfg = small_cfg()
+    called = []
+    real = mla_decode.latent_attention
+    monkeypatch.setattr(mla_decode, "latent_attention", lambda *a, **kw: called.append(
+        (a[1].shape, kw.get("span", 0), kw.get("block_k"))) or real(*a, **kw))
+    params, rows = seeded(cfg, batch=2, length=60)
+    want = np.asarray(ref_logits(params, rows, cfg))
+    got, _ = _served(params, cfg, rows)
+    close(got[2], want[0], F32_TOL)
+    close(got[0], want[1, :30], F32_TOL)
+    assert set(called) == {((3, 3, 16, 28), WINDOW, 4)}
+    layout = generation.cache_layout(cfg, SLOT, CHUNK)
+    assert (layout["ring_decode_path"], layout["ring_key_block"]) == ("kernel", 4)
+    # an arc of 9 places touches 3 blocks of 4 wherever it starts, a row out of use one
+    read = mla.step_counters(layout, [40, 9], 3, SLOT)
+    assert read["ring_decode_path"] == "kernel"
+    assert read["latent_ring_read_positions"] == 12 + 12 + 4 < 3 * 16
+    assert read["latent_ring_live_positions"] == 9 + 9
+
+
+@pytest.mark.parametrize("path", ["kernel", "plain"])
+def test_the_rings_read_positions_are_the_arithmetic_of_the_path_in_force(path):
+    """`step_counters` at the cell's sizes (32 slots x 20,480, a window of 513), host
+    arithmetic: under prompt chunks of 1,024 the ring is 2,048 places, whole key blocks, and
+    the kernel fetches of each row the blocks its arc touches (its length rounded up to the
+    block until it has passed the window, `ring_steps` blocks from then on, lapped or not;
+    a row out of use one block); under chunks of 1,000 the ring is 2,000 places, which no
+    block divides, and the plain body reads every row's whole ring. ``ring_decode_path`` in
+    the layout and in the counters says which."""
+    from galvatron_tpu.ops import mla_decode, pallas_common
+
+    cfg = PRESETS["dots3-note-prev"].replace(num_layers=5, max_seq_len=20480, dtype=jnp.bfloat16)
+    chunk = {"kernel": 1024, "plain": 1000}[path]
+    layout = generation.cache_layout(cfg, 20480, chunk)
+    ring = layout["ring_positions"]
+    assert ring == {"kernel": 2048, "plain": 2000}[path] and layout["ring_decode_path"] == path
+    lengths = [300, 513, 1500, 2048, 2049, 5000, 10870]
+    got = mla.step_counters(layout, lengths, 32, 20480)
+    assert got["ring_decode_path"] == path
+    assert got["latent_ring_live_positions"] == 300 + 6 * 513
+    if path == "plain":
+        assert layout["ring_key_block"] == 0
+        assert got["latent_ring_read_positions"] == 32 * ring
+        return
+    block = layout["ring_key_block"]
+    assert block == mla_decode.ring_block(ring, 513) and block in (128, 256, 512)
+    steps = pallas_common.ring_steps(1, 513, ring, block)
+    assert steps == 512 // block + 1
+    want = sum(min(steps, -(-n // block)) for n in lengths) + (32 - len(lengths))
+    assert got["latent_ring_read_positions"] == want * block
+    # every row lapped: the whole arc a row and nothing else, whatever the lengths
+    lapped = mla.step_counters(layout, [4096 + 97 * i for i in range(32)], 32, 20480)
+    assert lapped["latent_ring_read_positions"] == 32 * steps * block
+    assert lapped["latent_ring_read_positions"] / lapped["latent_ring_live_positions"] <= 2.0
+    # a verify window of 3 queries widens the arc by 2 places: the same path, asked again
+    wide = mla.step_counters(layout, [n + 2 for n in lengths], 32, 20480, window=3)
+    assert wide["ring_decode_path"] == "kernel"
+    assert got["latent_ring_read_positions"] <= wide["latent_ring_read_positions"] <= (
+        got["latent_ring_read_positions"] + len(lengths) * block)
+
+
 def test_a_slot_used_again_serves_the_new_request():
     """The slots are not zeroed: the second request reads nothing the first one left in
     the latent, in the index keys or on the ring."""
@@ -469,7 +639,7 @@ def test_the_engines_tap_rows_are_the_references_and_its_spans_count_the_three_s
             r.future.result(timeout=120)
         served = [list(r.generated) for r in reqs]
         spans = [e for e in tracer.snapshot() if e.get("ph") == "X"]
-        stats = engine.stats()
+        stats, layout = engine.stats(), engine.cache_layout
     finally:
         tracer.disable()
         engine.close()
@@ -491,7 +661,11 @@ def test_the_engines_tap_rows_are_the_references_and_its_spans_count_the_three_s
         # (every row's slot read up to the longest row's end, in whole key blocks)
         assert a["dsa_live_positions"] <= a["dsa_read_positions"] == 3 * SLOT
         assert a["dsa_index_read_positions"] == 3 * SLOT
-        assert 0 < a["latent_ring_live_positions"] <= a["latent_ring_read_positions"] == 3 * 16
+        # (a ring of 16 places is no whole key block of `mla_decode.RING_BLOCKS`: the plain
+        # body's arithmetic, every row's whole ring; the span says which path counted)
+        assert a["ring_decode_path"] == layout["ring_decode_path"] == "plain"
+        assert 0 < a["latent_ring_live_positions"] <= a["latent_ring_read_positions"] == (
+            engine.slots.num_slots * layout["ring_positions"]) == 3 * 16
     # (the rows grow: the selection stops at 16 a row, the ring's live part at 9)
     assert max(a["dsa_live_positions"] for a in decode) > 3 * TOPK
     assert max(a["dsa_selected_positions"] for a in decode) == 3 * TOPK

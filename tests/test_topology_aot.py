@@ -642,6 +642,54 @@ def test_sarvam_prefill_chunk_keeps_its_scores_on_the_chip(one_chip, real_mosaic
     assert total < HBM_V5E_GIB * 2**30, f"{total / 2**30:.2f} GiB"
 
 
+def test_dots3_decode_step_reads_the_latent_ring_in_place_along_its_arc(one_chip, real_mosaic):
+    """`_decode_step` of `dots3-note-prev_serve_reason_above_knee` (F F S S S, 32 slots x
+    20,480; the sliding layers' ring 2,048 places x 1,088 of bf16, 64 heads, a window of
+    513) as the chip's compiler sees it: EVERY layer's decode attention is the kernel
+    `mla_decode`, the two full layers' under ``full`` > ``attn_core`` (under the selection)
+    and, PR 71, the three sliding layers' under ``window`` > ``attn_core`` over the ring
+    where it lies: the chip keeps the 1,088-wide ring with its places on the lanes
+    (``{2,3,1,0}``, as it keeps the 576-wide slots), the kernel's operand is a bitcast of
+    the in-place write's result, its grid is (32 rows, `ring_steps` of the block
+    `ring_block` gives the shape), and no operation copies the ring, a layer's slab of it,
+    or a slot stack."""
+    import re
+
+    from galvatron_tpu.models.modeling import PRESETS
+    from galvatron_tpu.ops import mla_decode, pallas_common
+
+    cfg = PRESETS["dots3-note-prev"].replace(
+        num_layers=5, moe_dense_layers=1, vocab_size=19008, moe_share=(0, 8), max_seq_len=20480,
+        param_dtype=jnp.bfloat16, dtype=jnp.bfloat16)
+    assert mla_decode.decode_path(2048, 1088, 64, 1024, jnp.bfloat16, span=513) == "kernel"
+    compiled = _lowered_serving_program(cfg, "serving_decode", one_chip, num_slots=32,
+                                        prefill_chunk=1024, max_seq_len=20480).compile()
+    text = compiled.as_text()
+    kernels = sorted((line for line in _entry_lines(text)
+                      if "custom-call(" in line and "mla_decode" in line),
+                     key=lambda l: int(re.search(r"layer_(\d+)", l).group(1)))
+    assert len(kernels) == 5
+    for i, line in enumerate(kernels):
+        stack = "full" if i < 2 else "window"
+        assert f"/layer_{i}/attn/{stack}/attn_core/mla_decode" in line
+        assert ("bf16[2,32,576,20480]{3,2,1,0}" if i < 2 else "bf16[3,32,1088,2048]{3,2,1,0}") in line
+    # the ring's way through the step: parameter, in-place updates, bitcasts for the kernel
+    assert "bf16[3,32,2048,1088]{2,3,1,0" in text and "bf16[3,32,2048,1088]{3,2,1,0" not in text
+    # (the indexer's loop over key blocks carries the index-key stack through, by reference)
+    moved = [(op, shape) for op, shape in _moved_slabs(text, 32 * 2048 * 1088) if op != "while"]
+    assert not moved, moved[:4]
+    # (the grid a ring layer's kernel walks: rows x the arc's blocks, not the ring's)
+    block = mla_decode.ring_block(2048, 513)
+    steps = pallas_common.ring_steps(1, 513, 2048, block)
+    assert block in mla_decode.RING_BLOCKS and steps * block < 2048
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes == 2 * (2 * 32 * 20480 * (576 + 128) + 3 * 32 * 2048 * 1088)
+    assert ma.temp_size_in_bytes < 0.1 * 2**30, f"{ma.temp_size_in_bytes / 2**30:.3f} GiB"
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes - ma.alias_size_in_bytes
+             + ma.temp_size_in_bytes)
+    assert total < HBM_V5E_GIB * 2**30, f"{total / 2**30:.2f} GiB"
+
+
 def test_qwen3_next_held_experts_compile_at_published_widths(one_chip, real_mosaic):
     """The expert layer of `qwen3-next-80b-a3b_s4096` (16,384 tokens x top-10 over 512
     experts of width 512, rank 0 of 16 holding 32, a buffer of 172,288 rows), forward
