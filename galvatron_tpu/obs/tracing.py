@@ -32,6 +32,20 @@ first-class subsystem. This module is the host half of that layer:
 - ``record_span(..., track="serving queue")`` — a span reported after the
   fact on a named track that is no thread's (a request's wait in the queue
   began before the loop thread's open spans did, so it cannot nest there).
+- ``complete_span("prefill", after=ids0, done=ids1, track="device")`` — a
+  COMPLETION span: it opens when ``after`` is complete on the device and
+  closes when ``done`` is, stamped by the tracer's own worker thread; the
+  caller pays an enqueue. Handed in without ``done`` it returns a handle whose
+  ``close(done=...)`` says the other end later, so that the worker is already
+  waiting on ``after`` while the caller still dispatches what lies between
+  the two. ``snapshot()`` waits, bounded, for the worker.
+
+THE RULE a traced hot loop keeps, so that the loop the tracer times is the loop
+its users run: ``Span.sync`` on a loop's own thread only where the untraced
+loop waits at that very place (the serving engine's ``decode_wait``: untraced,
+``np.asarray(ids)`` blocks right there). Any other "when was the device through
+with this" goes through a completion span, which waits on a thread that is
+nobody's loop.
 
 The module-level ``tracer`` singleton is what the trainer, checkpoint layer,
 search engine, and serving engine all record into — enable it once
@@ -48,7 +62,7 @@ import threading
 import time
 import zlib
 from collections import deque
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 
@@ -75,11 +89,27 @@ class _NullSpan:
     def set(self, **attrs):
         return self
 
+    def close(self, **attrs):
+        """(what `Tracer.complete_span` hands out when tracing is off)"""
+        return None
+
 
 _NULL_SPAN = _NullSpan()
 
 #: the ring of the rare spans (`Tracer`): a cold start of the largest cell leaves ~600
 RARE_SPANS = 4096
+
+#: completion spans the worker has not got to yet; one more is dropped and counted
+#: (`Tracer.complete_span` never blocks its caller)
+COMPLETIONS_MAX = 1024
+
+#: how long ``snapshot()`` waits for the completion worker: a crash dump must not
+#: hang on a device that died
+SNAPSHOT_WAIT_S = 2.0
+
+#: how long the worker waits for a completion span's ``close``: a caller that lost its
+#: handle must not stop every span behind it
+CLOSE_WAIT_S = 60.0
 
 #: collections shorter than this leave no ``gc`` span: a trace-and-lower makes
 #: hundreds of young-generation passes of ~0.1 ms that explain no slow step
@@ -104,6 +134,34 @@ def _annotation(name: str, args: Dict[str, Any]):
         return jax.profiler.StepTraceAnnotation(
             _STEP_ANNOTATIONS[name], step_num=args["step"])
     return jax.profiler.TraceAnnotation(name)
+
+
+class _Completion:
+    """A completion span from `Tracer.complete_span` until the worker has recorded it;
+    the handle the caller closes where the span's other end is said later."""
+
+    __slots__ = ("name", "track", "after", "attrs", "handed_in", "done", "scalars", "finish",
+                 "closed_at", "_closed")
+
+    def __init__(self, name: str, track: str, after: Any, attrs: Dict[str, Any]):
+        self.name, self.track, self.after, self.attrs = name, track, after, attrs
+        self.handed_in = time.perf_counter()
+        self.done = self.scalars = self.finish = None
+        self.closed_at = 0.0
+        self._closed = threading.Event()
+
+    def close(self, done: Any = None, scalars: Any = None,
+              finish: Optional[Callable[[Any], Dict[str, Any]]] = None, **attrs) -> None:
+        """The span's other end: it closes when ``done`` is complete on the device
+        (absent: now, on the caller's clock); ``scalars`` / ``finish`` / further
+        arguments as `Tracer.complete_span` takes them. Costs a clock read and an
+        event; the first call counts."""
+        if self._closed.is_set():
+            return
+        self.done, self.scalars, self.finish = done, scalars, finish
+        self.attrs = {**self.attrs, **attrs}  # (rebound, not mutated: a snapshot may be reading it)
+        self.closed_at = time.perf_counter()
+        self._closed.set()
 
 
 class Span:
@@ -178,9 +236,15 @@ class Tracer:
     the crash" is exactly its contents (obs/flight.py dumps it). The
     ``jax.monitoring`` spans (a trace, a lowering, a backend compile: never in
     a warm step) live in a small ring of their own, merged into
-    ``snapshot()`` where they ended: the hot path's spans, 37 an iteration of a 32-slot engine,
+    ``snapshot()`` where they ended: the hot path's spans, 37 an iteration of a 32-slot engine
+    (``iteration``, ``decode`` and its three children, ``sample`` and 32 ``sample_slot``) and,
+    an admission, ``admit`` + ``queue_wait`` + ``prefill_dispatch`` + ``prefill`` + one
+    ``chunk_dispatch`` a chunk (4 + chunks; 2 + chunks of them new with the completion spans),
     would otherwise push a run's compiles out within the minute, and "did
-    anything compile, and when" is read at a run's END."""
+    anything compile, and when" is read at a run's END.
+
+    Completion spans (``complete_span``) are stamped by ONE daemon worker a
+    tracer, started at the first of them; ``snapshot()`` waits for it."""
 
     def __init__(self, capacity: int = 4096):
         self.enabled = False
@@ -191,6 +255,13 @@ class Tracer:
         self._local = threading.local()
         self._epoch_pc = time.perf_counter()
         self._epoch_wall = time.time()
+        # completion spans: enqueued (seq -> item) until the worker has recorded them
+        self._cv = threading.Condition()
+        self._pending: Dict[int, _Completion] = {}
+        self._enqueued = self._started = self._forgotten = 0
+        self._worker: Optional[threading.Thread] = None
+        #: completion spans refused because ``COMPLETIONS_MAX`` were waiting
+        self.completions_dropped = 0
 
     # -- lifecycle ----------------------------------------------------------
 
@@ -215,6 +286,9 @@ class Tracer:
     def clear(self) -> None:
         self._ring.clear()
         self._rare.clear()
+        with self._cv:
+            self._forgotten = self._enqueued
+            self.completions_dropped = 0
 
     # -- recording ----------------------------------------------------------
 
@@ -274,24 +348,124 @@ class Tracer:
         step = self.current_step()
         if step is not None:
             attrs["step"] = step
+        rec = self._track_record(name, track or "", time.perf_counter() - dur_s, dur_s, attrs)
         if track is None:
             t = threading.current_thread()
-            tid, tname, depth = t.ident or 0, t.name, len(self._stack_for_thread())
-        else:
-            tid, tname, depth = _track_tid(track), track, 0
-        self._record(
-            {
-                "name": name,
-                "ph": "X",
-                "ts": self.pc_to_us(time.perf_counter() - dur_s),
-                "dur": dur_s * 1e6,
-                "tid": tid,
-                "tname": tname,
-                "depth": depth,
-                "args": attrs,
-            },
-            rare,
-        )
+            rec.update(tid=t.ident or 0, tname=t.name, depth=len(self._stack_for_thread()))
+        self._record(rec, rare)
+
+    def complete_span(self, name: str, *, after: Any = None, done: Any = None,
+                      track: str = "device", scalars: Any = None,
+                      finish: Optional[Callable[[Any], Dict[str, Any]]] = None, **attrs):
+        """A span ``name`` on the named track ``track`` that opens when ``after``
+        (a jax array or tree; absent: now) is complete on the device and closes
+        when ``done`` is. The caller pays an enqueue: the tracer's worker blocks
+        on the two, in the order the spans were handed in, and stamps.
+
+        With ``done`` the span is whole and nothing is returned. WITHOUT it the
+        call returns a handle and the span stays open until ``handle.close(done=,
+        scalars=, finish=, **more)``: hand it in BEFORE dispatching what lies
+        between the two ends, so that the worker is waiting on ``after`` when it
+        completes; a span handed in whole after that work was sent finds ``after``
+        complete already wherever the dispatch outlasts it. ``close()`` without
+        ``done`` closes the span at the call (the caller saw the work through
+        itself); a handle never closed is given up after ``CLOSE_WAIT_S``.
+
+        ``scalars`` (a tree of device scalars) become floats on the worker, behind
+        ``done``, and join the span's arguments: as they are (a flat dict), or as
+        ``finish(floats)`` returns them. The record says ``synced: True``; an
+        array that was deleted or a device that died gives it ``error`` and
+        raises on no thread; ``opened_late: True`` where ``after`` was complete
+        before the worker looked (the span then opened no LATER than stamped).
+        Never blocks: with ``COMPLETIONS_MAX`` waiting, the span is dropped and
+        counted (``completions_dropped``). Hold no donated buffer in ``after`` /
+        ``done`` / ``scalars``: the worker reads them after the caller went on.
+        Tracer off: the no-op singleton, before anything is touched."""
+        if not self.enabled:
+            return _NULL_SPAN
+        item = _Completion(name, track, after, attrs)
+        if done is not None:
+            item.close(done, scalars, finish)
+        with self._cv:
+            if len(self._pending) >= COMPLETIONS_MAX:
+                self.completions_dropped += 1
+                return _NULL_SPAN
+            if self._worker is None:
+                self._worker = threading.Thread(
+                    target=self._complete_loop, name="tracer-completions", daemon=True)
+                self._worker.start()
+            self._enqueued += 1
+            self._pending[self._enqueued] = item
+            self._cv.notify_all()
+        return item
+
+    def _complete_loop(self) -> None:
+        chain: Tuple[Any, float] = (None, 0.0)  # the last span's ``done`` and its stamp
+        while True:
+            with self._cv:
+                while self._started == self._enqueued:
+                    self._cv.wait()
+                self._started += 1
+                seq = self._started
+                item = self._pending[seq]
+            rec, chain = self._complete(item, chain)
+            with self._cv:
+                if seq > self._forgotten:  # (a ring cleared meanwhile stays clear)
+                    self._record(rec)
+                del self._pending[seq]
+                self._cv.notify_all()
+
+    def _complete(self, item: _Completion, chain: Tuple[Any, float]):
+        """Wait for one completion span's two ends (the worker's thread) -> its
+        record and (its ``done``, the stamp it closed on) for the next one."""
+        after, args, t_open = item.after, {}, item.handed_in
+        try:
+            if after is not None and after is chain[0]:
+                t_open = chain[1]  # it opens where the span before it closed: that very stamp
+            elif after is not None:
+                ready = [leaf.is_ready() for leaf in jax.tree_util.tree_leaves(after)
+                         if hasattr(leaf, "is_ready")]
+                if ready and all(ready):
+                    args["opened_late"] = True
+                jax.block_until_ready(after)
+                t_open = time.perf_counter()
+            if not item._closed.wait(CLOSE_WAIT_S):
+                raise TimeoutError(f"completion span {item.name!r} was never closed")
+            if item.done is None:
+                t_close = item.closed_at
+            else:
+                jax.block_until_ready(item.done)
+                t_close = time.perf_counter()
+                chain = (item.done, t_close)
+            if item.scalars is not None:
+                # (one batch of copies, not a round trip a scalar: the next span's ``after``
+                # lands while this thread is busy here, and is then stamped late)
+                values = jax.tree_util.tree_map(float, jax.device_get(item.scalars))
+                args.update(values if item.finish is None else item.finish(values))
+            args["synced"] = True
+        except Exception as e:  # noqa: BLE001 — a deleted array, a dead device: said, not raised
+            t_close = time.perf_counter()
+            args["error"] = type(e).__name__
+        return self._track_record(item.name, item.track, t_open, max(0.0, t_close - t_open),
+                                  {**item.attrs, **args}), chain
+
+    def _track_record(self, name: str, track: str, t0: float, dur_s: float,
+                      args: Dict[str, Any]) -> Dict[str, Any]:
+        return {"name": name, "ph": "X", "ts": self.pc_to_us(t0), "dur": dur_s * 1e6,
+                "tid": _track_tid(track), "tname": track, "depth": 0, "args": args}
+
+    def _await_completions(self, wait_s: float) -> List[Dict[str, Any]]:
+        """Wait, ``wait_s`` at most, for the completion spans enqueued so far;
+        those still not through, as records that say ``pending``."""
+        if self._worker is None or threading.current_thread() is self._worker:
+            return []
+        with self._cv:
+            upto = self._enqueued
+            self._cv.wait_for(lambda: next(iter(self._pending), upto + 1) > upto, timeout=wait_s)
+            left = [item for seq, item in self._pending.items()
+                    if self._forgotten < seq <= upto]
+        return [self._track_record(it.name, it.track, it.handed_in, 0.0,
+                                   {**it.attrs, "pending": True}) for it in left]
 
     def _on_gc(self, phase: str, info: Dict[str, Any]) -> None:
         # the collector runs on whichever thread tripped it, start and stop
@@ -315,13 +489,23 @@ class Tracer:
 
     # -- readout ------------------------------------------------------------
 
-    def snapshot(self) -> List[Dict[str, Any]]:
+    def snapshot(self, wait_s: float = SNAPSHOT_WAIT_S) -> List[Dict[str, Any]]:
         """Both rings' records in the order they were made (a record is made when its
-        span ends)."""
+        span ends; a completion span's when the worker has stamped it). Waits first,
+        ``wait_s`` at most, for the completion spans handed in before the call, so no
+        reader need know of the worker; what is still not through comes last, as
+        records of no duration that say ``pending``, and behind them one instant
+        ``completions_dropped`` (``count``) where any was."""
+        tail = self._await_completions(wait_s)
+        if self.completions_dropped:
+            tail.append({"name": "completions_dropped", "ph": "i",
+                         "ts": self.pc_to_us(time.perf_counter()), "tid": 0,
+                         "tname": "tracer-completions",
+                         "args": {"count": self.completions_dropped}})
         rare, ring = list(self._rare), list(self._ring)
         if not rare:
-            return ring
-        return list(heapq.merge(rare, ring, key=lambda r: r["ts"] + r.get("dur", 0.0)))
+            return ring + tail
+        return list(heapq.merge(rare, ring, key=lambda r: r["ts"] + r.get("dur", 0.0))) + tail
 
     @property
     def epoch_wall(self) -> float:

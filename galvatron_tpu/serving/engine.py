@@ -65,7 +65,7 @@ import threading
 import time
 from concurrent.futures import Future
 from functools import partial
-from typing import Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -259,6 +259,26 @@ def _sample_rows(rows, knobs, ints, ids):
         jnp.stack([ints[_SEED_LO, 0], ints[_SEED_HI, 0]]), ints[_RID], ints[_INDEX],
     )
     return jnp.where(ints[_ACTIVE] > 0, drawn, ids)
+
+
+def _prompt_expert_counts(values: Dict[str, Any], chunk: int) -> Dict[str, float]:
+    """A ``prefill`` span's expert counters from its chunks' `_router_counters` as
+    host numbers (``last``: the last chunk's, ``full``: those of the chunks whose every
+    row is real): the last chunk's as they are and, with held experts, what ONE chunk
+    of the prompt put on them, a layer: the mean over its chunks of ``chunk`` real rows
+    (a ragged last chunk routes its padding too, all of it to the same few experts, so
+    it is left out; a prompt shorter than a chunk has no other, and
+    ``moe_chunks_counted`` says 0)."""
+    last, full = values["last"], values["full"]
+    out = dict(last)
+    if "moe_held_pairs_per_token" in last:
+        of = full or [last]
+        out.update(
+            moe_chunks_counted=len(full),
+            moe_held_pairs=chunk * sum(r["moe_held_pairs_per_token"] for r in of) / len(of),
+            moe_held_experts_touched_a_chunk=sum(
+                r["moe_held_experts_touched"] for r in of) / len(of))
+    return out
 
 
 def _sample_host(rng: np.random.Generator, logits: np.ndarray,
@@ -996,10 +1016,15 @@ class Engine:
         """One iteration of the loop thread: admissions, then one decode step
         over the slots in use. The span tree (tracer on only; every child on
         this thread, inside its parent): ``iteration`` > ``admit`` >
-        ``prefill``; ``decode`` (or ``decode_verify``) > ``decode_dispatch``,
+        ``prefill_dispatch`` (one a request) > ``chunk_dispatch`` (one a chunk);
+        ``decode`` (or ``decode_verify``) > ``decode_dispatch``,
         ``decode_wait``, ``logits_readback``; ``sample`` > ``sample_slot``
         (behind the forward it runs beside on the device-draw path, in front of
-        it on the speculative engine's: ``_step``). ``step`` is the ``steps`` counter at entry, so a
+        it on the speculative engine's: ``_step``). Off this thread, on tracks of
+        their own: ``queue_wait`` (``serving queue``) and ``prefill``, a prompt's
+        chunks on the device (``device``: ``_prefill``). Tracer on, this thread
+        waits for the device where it waits with the tracer off (``decode_wait``,
+        ``logits_readback``) and nowhere else. ``step`` is the ``steps`` counter at entry, so a
         compile or a collection inside an iteration names it
         (``Tracer.current_step``) and the profiler groups device work by it."""
         if self.scheduler.empty() and not self._by_slot:
@@ -1080,24 +1105,34 @@ class Engine:
         return admitted
 
     def _prefill(self, req: Request) -> None:
-        # the engine's spans land on the same process timeline as everything
-        # else; tracing off = no-op singleton. The prefill span is
-        # per-request, so the fleet trace_id rides it (batch-wide
-        # sample/decode spans cover many requests and don't).
+        """One admission, traced as it runs untraced: the loop thread sends the
+        prompt's chunks and its first draw behind whatever the device is at and
+        waits for nothing. Its spans (tracing off: no-op singletons):
+        ``prefill_dispatch``, on this thread inside ``admit``: the admission's whole
+        HOST cost (slot, buffers, the chunks' and the draw's dispatch), around one
+        ``chunk_dispatch`` a chunk; and ``prefill``, the prompt's chunks ON THE
+        DEVICE, a completion span on the track ``device`` (``Tracer.complete_span``:
+        the tracer's worker waits, not this thread): from the ids that were in force
+        when the admission began (the step in flight, or the admission before it;
+        handed in HERE, so that the worker is waiting on them when they land) to
+        the ids behind the prompt's draw (``_ids`` is donated to nothing, so the
+        worker may hold both). The speculative engine reads the prompt's row on
+        this thread in both loops: its span opens and closes on this thread's clock.
+        They are per-request, so the fleet trace_id rides them (batch-wide
+        sample/decode spans cover many requests and don't)."""
         attrs = {"rid": req.rid, "tokens": len(req.tokens)}
         if req.trace_id is not None:
             attrs["trace_id"] = req.trace_id
-        if _obs_tracer.enabled:
-            # tracer on, the span times the prompt's chunks on the device, as it did when
-            # the loop was synchronous: it opens on a device that is through with the step
-            # in flight and closes on realized compute (`_prefill_impl`). The two waits
-            # cost the TRACED loop a dispatch of device idle each an admission; untraced,
-            # nobody waits for a prompt inside its admission
-            jax.block_until_ready(self._ids)
-        with _obs_tracer.span("prefill", **attrs) as span:
-            self._prefill_impl(req, span)
+        with _obs_tracer.span("prefill_dispatch", **attrs) as span:
+            on_device = _obs_tracer.complete_span(
+                "prefill", after=self._ids if self._device_draw else None, **attrs)
+            try:
+                self._prefill_impl(req, span, on_device)
+            except BaseException as e:
+                on_device.close(error=type(e).__name__)
+                raise
 
-    def _prefill_impl(self, req: Request, span) -> None:
+    def _prefill_impl(self, req: Request, span, on_device) -> None:
         t0 = time.perf_counter()
         slot = self.slots.alloc()
         assert slot is not None
@@ -1128,17 +1163,12 @@ class Engine:
             # identical k/v (deterministic function of tokens + positions),
             # so the rewrite is idempotent.
             starts[-1] = smax - c
+        span.set(chunks=len(starts), first_start=matched)
         router: Dict[str, jax.Array] = {}  # the last chunk's `_router_counters`
         full_chunks: list = []  # (tracer on) those of the chunks whose every row is real
-        if self.cfg.moe_dropless:
-            span.set(moe_row_tile=moe.layer_row_tile(self.cfg, c))
-        key_block = self.cache_layout.get("chunk_key_block")  # None for a plain `KVCache`
         kernel = self.cache_layout.get("chunk_path") == "kernel"
         kind = self.cache_layout["kind"]  # "latent" | "kv": whose chunk kernel it is
-        key_blocks = 0
-        ring = self.cache_layout.get("ring_positions")  # a windowed stack's, else None
-        ring_wraps = ring_blocks_read = ring_blocks = 0
-        for i, start in enumerate(starts):
+        for start in starts:
             # the deadline is end-to-end: a long prompt must not burn chip
             # time prefilling past the moment its client stops waiting
             if req.deadline is not None and time.time() > req.deadline:
@@ -1146,57 +1176,41 @@ class Engine:
                     f"request {req.rid} deadline passed during prefill "
                     f"({start}/{len(toks)} tokens in)"
                 )
-            faults.prefill_chunk(self.counters.get("prefill_chunks"))
+            seq = self.counters.get("prefill_chunks")  # which execution of the prefill program
+            faults.prefill_chunk(seq)
             chunk = toks[start:start + c]
             n = len(chunk)
-            # fresh buffer per chunk: on CPU, jnp.asarray may alias the host
-            # memory and dispatch is async — mutating a shared buffer for the
-            # next chunk would corrupt the in-flight one's input
-            buf = np.full((1, c), self.pad_id, np.int32)
-            buf[0, :n] = chunk
-            # the chunk's last real row goes into the slot's row of the device
-            # rows inside the program; the final chunk's is the one that stays
-            if self.paged:
-                # the slid-left window may dip below the attached prefix —
-                # COW-copy any shared/registered block the write covers
-                # (recomputed k/v is identical; this protects the CACHE
-                # entry and other holders, not this request's numerics)
-                self.slots.ensure_writable(slot, start, min(start + c, smax))
-                self._rows, self.slots.pool = _paged_prefill_chunk(
-                    self.params, self.cfg, self.slots.pool, jnp.asarray(buf),
-                    jnp.asarray(self.slots.tables[slot:slot + 1]),
-                    jnp.asarray([start], np.int32),
-                    self._rows, np.int32(slot), np.int32(n - 1),
-                )
-            else:
-                self._rows, self.slots.cache, router = _prefill_chunk(
-                    self.params, self.cfg, self.slots.cache, jnp.asarray(buf),
-                    np.int32(slot), np.int32(start), self._rows, np.int32(n - 1),
-                )
-                if _obs_tracer.enabled and n == c:
-                    full_chunks.append(router)
+            with _obs_tracer.span("chunk_dispatch", start=start, rows=n, seq=seq):
+                # fresh buffer per chunk: on CPU, jnp.asarray may alias the host
+                # memory and dispatch is async — mutating a shared buffer for the
+                # next chunk would corrupt the in-flight one's input
+                buf = np.full((1, c), self.pad_id, np.int32)
+                buf[0, :n] = chunk
+                # the chunk's last real row goes into the slot's row of the device
+                # rows inside the program; the final chunk's is the one that stays
+                if self.paged:
+                    # the slid-left window may dip below the attached prefix —
+                    # COW-copy any shared/registered block the write covers
+                    # (recomputed k/v is identical; this protects the CACHE
+                    # entry and other holders, not this request's numerics)
+                    self.slots.ensure_writable(slot, start, min(start + c, smax))
+                    self._rows, self.slots.pool = _paged_prefill_chunk(
+                        self.params, self.cfg, self.slots.pool, jnp.asarray(buf),
+                        jnp.asarray(self.slots.tables[slot:slot + 1]),
+                        jnp.asarray([start], np.int32),
+                        self._rows, np.int32(slot), np.int32(n - 1),
+                    )
+                else:
+                    self._rows, self.slots.cache, router = _prefill_chunk(
+                        self.params, self.cfg, self.slots.cache, jnp.asarray(buf),
+                        np.int32(slot), np.int32(start), self._rows, np.int32(n - 1),
+                    )
+                    if _obs_tracer.enabled and n == c:
+                        full_chunks.append(router)
             self.counters.inc("prefill_chunks")
             self.counters.inc("prefill_tokens", n)
-            if ring:
-                # chunks that began a new lap of the ring: from there on a chunk
-                # overwrites the window layers' oldest positions (host arithmetic)
-                ring_wraps += start > 0 and start % ring == 0
-                # the key blocks a window layer's chunk attention fetched so far, of the
-                # ring's: up to the chunk's end until the ring has lapped
-                _, whole, live = generation.chunk_key_blocks(ring, start + c)
-                ring_blocks_read += min(whole, live)
-                ring_blocks += whole
-                span.set(ring_wraps=ring_wraps, kv_window_chunk_blocks_read=ring_blocks_read,
-                         kv_window_chunk_blocks=ring_blocks)
-            if key_block:
-                # the chunks the chunk kernel took and the key blocks a layer's attention
-                # over whole slots fetched for them so far (host arithmetic from the start:
-                # no array is built and nobody waits for the device)
-                key_blocks += -(-(start + c) // key_block)
-                if kernel:
-                    self.counters.inc(f"{kind}_chunks_kernel")
-                span.set(**{f"{kind}_chunks_kernel": (i + 1) * kernel,
-                            f"{kind}_chunk_key_blocks": key_blocks})
+            if kernel:
+                self.counters.inc(f"{kind}_chunks_kernel")
         self.slots.lengths[slot] = len(toks)
         if self.paged:
             # publish the prompt's full blocks while the request decodes, so
@@ -1208,26 +1222,46 @@ class Engine:
             # booked with the others' (`_step_ahead`): nobody waits for the chunks here
             self._draw([slot], unbooked=0)
         else:
+            # (the speculative engine draws on the host: this read is its loop's own wait)
             self._host_rows[slot] = np.asarray(self._rows)[slot]
             self._rng[slot] = np.random.default_rng((self.seed, req.rid))
         if _obs_tracer.enabled:
-            # (the span's other end: the chunks and the first draw are through)
-            span.sync(self._ids)
-            span.set(**{k: float(v) for k, v in router.items()})
-            if "moe_held_pairs_per_token" in router:
-                # what ONE chunk of the prompt put on the held experts, a layer: the mean
-                # over its chunks of `c` real rows (a ragged last chunk routes its padding
-                # too, all of it to the same few experts, so it is left out; a prompt
-                # shorter than a chunk has no other, and `moe_chunks_counted` says 0)
-                of = full_chunks or [router]
-                span.set(
-                    moe_chunks_counted=len(full_chunks),
-                    moe_held_pairs=c * sum(
-                        float(r["moe_held_pairs_per_token"]) for r in of) / len(of),
-                    moe_held_experts_touched_a_chunk=sum(
-                        float(r["moe_held_experts_touched"]) for r in of) / len(of))
+            # (drawing on the host, the read above saw the last chunk through: no ``done``)
+            on_device.close(
+                done=self._ids if self._device_draw else None,
+                scalars={"last": router, "full": full_chunks},
+                finish=partial(_prompt_expert_counts, chunk=c), **self._prompt_counts(starts))
         rz.advance(req, rz.DECODING, slot=slot)
         self._busy_s += time.perf_counter() - t0
+
+    def _prompt_counts(self, starts: Sequence[int]) -> Dict[str, Any]:
+        """What a ``prefill`` span says of its chunks, from where each began (host
+        arithmetic: no array is built and nobody waits for the device): how many and
+        how deep (``depth_sum``: the sum of their starts), the expert layers' row
+        tile, and the key blocks the chunk attention fetched."""
+        c = self.prefill_chunk
+        out: Dict[str, Any] = {"chunks": len(starts), "depth_sum": sum(starts)}
+        if self.cfg.moe_dropless:
+            out["moe_row_tile"] = moe.layer_row_tile(self.cfg, c)
+        ring = self.cache_layout.get("ring_positions")  # a windowed stack's, else None
+        if ring:
+            # chunks that began a new lap of the ring (from there on a chunk overwrites
+            # the window layers' oldest positions), and the key blocks a window layer's
+            # chunk attention fetched, of the ring's: up to the chunk's end until it lapped
+            blocks = [generation.chunk_key_blocks(ring, start + c)[1:] for start in starts]
+            out.update(
+                ring_wraps=sum(start > 0 and start % ring == 0 for start in starts),
+                kv_window_chunk_blocks_read=sum(min(whole, live) for whole, live in blocks),
+                kv_window_chunk_blocks=sum(whole for whole, _ in blocks))
+        key_block = self.cache_layout.get("chunk_key_block")  # None for a plain `KVCache`
+        if key_block:
+            # the chunks the chunk kernel took and the key blocks a layer's attention
+            # over whole slots fetched for them
+            kind = self.cache_layout["kind"]
+            kernel = self.cache_layout.get("chunk_path") == "kernel"
+            out[f"{kind}_chunks_kernel"] = len(starts) * kernel
+            out[f"{kind}_chunk_key_blocks"] = sum(-(-(start + c) // key_block) for start in starts)
+        return out
 
     def _step(self) -> None:
         """One decode iteration over the slots in use: ONE shared forward, every
@@ -1339,10 +1373,13 @@ class Engine:
         the tokens it owes the requests, and books them while the device runs
         the step. The span ``decode`` (one a dispatched step) is covered by
         ``decode_dispatch`` (host: operands, the two jitted calls' return),
-        ``decode_wait`` (``Span.sync``, tracer on only: what the host really
-        waits for, the PREVIOUS draws' ids; that step's counters are read with
-        them) and ``logits_readback`` (the ids, and the rows of an iteration in
-        which a slot taps); ``sample`` follows it."""
+        ``decode_wait`` (``Span.sync``, tracer on only, at the place where
+        ``read()`` blocks with the tracer off: what the host really waits for, the
+        PREVIOUS draws' ids; that step's counters are read with them) and
+        ``logits_readback`` (the ids, and the rows of an iteration in which a slot
+        taps); ``sample`` follows it. An admission's chunks and draw (``_prefill``)
+        lie in the device's queue between the last step and this one, traced or
+        not: the ids waited for here are behind them."""
         ids, rows = self._ids, self._rows  # what the tokens to book are, and were drawn from
         behind_a_step, router = self._step_unread, self._router_unread
         tapped = any(req.capture_logits is not None for req in self._by_slot.values())

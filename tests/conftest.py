@@ -174,8 +174,30 @@ _PINNED_BEFORE_PR_70 = frozenset(
                     "shortconv_prefill_chunk_ms")])
 
 
+#: Three accepted cases of tests/benchmark/test_benchmark_granite_small.py pin the manifest's
+#: per-layer entries as PR 70 left them: its two readers the LAST of 98 (its own case, and its
+#: copies of the nemotron and the dots3 cells' cases).  ISSUE 72 asks for three readers of the
+#: admission's new spans (`admission_host_ms_p50`, `serve_idle_ms_per_iteration`,
+#: `prefill_chunk_ms_at_depth0`); a new entry goes at the END of its list, by the driver's rule,
+#: and only a PR of kind ``benchmark`` may edit that file.  They are expected to fail, strictly,
+#: until such a PR relaxes them in place and deletes this list.  Nothing else they assert is off
+#: meanwhile: each body stands whole, that clause amended, in
+#: tests/benchmark/test_benchmark_admission.py, whose last case holds this list and those one
+#: for one.
+_PINNED_BEFORE_PR_72 = frozenset(
+    "tests/benchmark/test_benchmark_granite_small.py::" + case for case in (
+        "test_the_cell_joins_the_manifest_by_appends",
+        "test_nemotron_the_cell_joins_the_manifest_by_appends",
+        "test_dots3_the_cell_joins_the_manifest_by_appends"))
+
+
 def pytest_collection_modifyitems(items):
     for item in items:
+        if item.nodeid in _PINNED_BEFORE_PR_72:
+            item.add_marker(pytest.mark.xfail(
+                strict=True, raises=AssertionError,
+                reason="pins the manifest's per-layer entries as PR 70 left them (98, its two "
+                       "readers last); PR 72 appended three readers of the admission's spans"))
         if item.nodeid in _PINNED_BEFORE_PR_70:
             item.add_marker(pytest.mark.xfail(
                 strict=True, raises=AssertionError,

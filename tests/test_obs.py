@@ -13,6 +13,7 @@ import math
 import os
 import re
 import threading
+import time
 import urllib.request
 
 import jax
@@ -118,6 +119,283 @@ def test_thread_aware_tracks():
     assert by_name["worker_span"]["tname"] == "worker-thread"
     # concurrent threads have independent nesting stacks
     assert by_name["worker_span"]["depth"] == 0
+
+
+# ---------------------------------------------------------------------------
+# completion spans (`Tracer.complete_span`), on fake device values
+# ---------------------------------------------------------------------------
+
+
+class _Value:
+    """What the completion worker asks of a device value: ``is_ready`` and
+    ``block_until_ready``; ``land()`` is the device getting through with it."""
+
+    def __init__(self, ready=False, deleted=False):
+        self._event, self.deleted, self.blocked_on = threading.Event(), deleted, []
+        if ready:
+            self._event.set()
+
+    def land(self):
+        self._event.set()
+        return time.perf_counter()
+
+    def is_ready(self):
+        return self._event.is_set()
+
+    def block_until_ready(self):
+        if self.deleted:
+            raise RuntimeError("Array has been deleted.")
+        self.blocked_on.append(threading.current_thread().name)
+        assert self._event.wait(10.0)
+        return self
+
+
+class _Scalar:
+    """A device scalar: says which thread turned it into a float."""
+
+    read_on = []
+
+    def __init__(self, x):
+        self.x = x
+
+    def __float__(self):
+        _Scalar.read_on.append(threading.current_thread().name)
+        return float(self.x)
+
+
+@pytest.fixture()
+def completions():
+    """A tracer of its own, on; whatever the test left waiting is let through at
+    the end so that the daemon worker idles."""
+    t, values = Tracer(capacity=64), []
+    t.enable()
+
+    def value(**kw):
+        values.append(_Value(**kw))
+        return values[-1]
+
+    yield t, value
+    for v in values:
+        v.land()
+    t.snapshot(wait_s=5.0)
+
+
+def _us(t, pc):
+    return t.pc_to_us(pc)
+
+
+def test_completion_spans_open_on_after_and_close_on_done_in_the_order_handed_in(completions):
+    t, value = completions
+    a0, a1, b1 = value(), value(), value()
+    _Scalar.read_on.clear()
+    caller = threading.current_thread().name
+    t0 = time.perf_counter()
+    t.complete_span("prefill", after=a0, done=a1, rid=1,
+                    scalars={"last": {"x": _Scalar(3)}, "full": [{"x": _Scalar(5)}]},
+                    finish=lambda v: {"x": v["last"]["x"], "mean": v["full"][0]["x"] / 2})
+    t.complete_span("prefill", after=a1, done=b1, rid=2, scalars={"y": _Scalar(7)})
+    assert time.perf_counter() - t0 < 0.5 and t._worker.daemon
+    time.sleep(0.02)
+    opened = a0.land()
+    time.sleep(0.03)
+    closed = a1.land()
+    time.sleep(0.02)
+    closed_b = b1.land()
+    first, second = t.snapshot(wait_s=5.0)
+    assert [r["args"]["rid"] for r in (first, second)] == [1, 2]
+    for r in (first, second):
+        assert (r["name"], r["ph"], r["tname"], r["depth"]) == ("prefill", "X", "device", 0)
+        assert r["tid"] == tracing._track_tid("device") and r["args"]["synced"] is True
+    # the stamps are the worker's, taken as each value landed (a few ms of wake-up)
+    assert _us(t, opened) <= first["ts"] <= _us(t, opened) + 20e3
+    assert _us(t, closed) <= first["ts"] + first["dur"] <= _us(t, closed) + 20e3
+    assert first["dur"] >= 30e3 - 1
+    # a span that opens on what the one before it closed on opens on that very stamp
+    assert second["ts"] == pytest.approx(first["ts"] + first["dur"], abs=1e-3)
+    assert _us(t, closed_b) <= second["ts"] + second["dur"] <= _us(t, closed_b) + 20e3
+    assert "opened_late" not in first["args"] and "opened_late" not in second["args"]
+    # the arguments were finished on the worker: floats, through ``finish`` where given
+    assert first["args"] == {"rid": 1, "x": 3.0, "mean": 2.5, "synced": True}
+    assert second["args"] == {"rid": 2, "y": 7.0, "synced": True}
+    assert _Scalar.read_on == ["tracer-completions"] * 3
+    assert caller not in a0.blocked_on + a1.blocked_on + b1.blocked_on
+    assert a0.blocked_on == ["tracer-completions"]
+
+
+def test_a_completion_span_without_after_opens_when_it_is_handed_in(completions):
+    t, value = completions
+    done = value()
+    before = time.perf_counter()
+    t.complete_span("write", done=done, track="checkpoint")
+    after = time.perf_counter()
+    time.sleep(0.02)
+    done.land()
+    rec, = t.snapshot(wait_s=5.0)
+    assert _us(t, before) <= rec["ts"] <= _us(t, after) and rec["dur"] >= 20e3 - 1
+    assert rec["tname"] == "checkpoint" and rec["args"] == {"synced": True}
+
+
+def test_a_handle_lets_the_worker_wait_on_after_while_the_caller_still_dispatches(completions):
+    """The serving engine's use: the span is handed in when the admission begins and closed
+    when its last dispatch is out; ``after`` lands in between and is stamped as it lands."""
+    t, value = completions
+    after, done = value(), value()
+    handle = t.complete_span("prefill", after=after, rid=3, tokens=2000)
+    for _ in range(200):  # the worker is on it before the caller says anything more
+        if after.blocked_on:
+            break
+        time.sleep(0.005)
+    assert after.blocked_on == ["tracer-completions"]
+    opened = after.land()
+    time.sleep(0.03)  # (the caller is still dispatching: the worker waits for the close)
+    assert [r["args"] for r in t.snapshot(wait_s=0.01)] == [
+        {"rid": 3, "tokens": 2000, "pending": True}]
+    handle.close(done=done, scalars={"x": _Scalar(2)}, chunks=2, depth_sum=1024)
+    handle.close(done=value(), chunks=99)  # (the first close counts)
+    time.sleep(0.02)
+    closed = done.land()
+    rec, = t.snapshot(wait_s=5.0)
+    assert rec["args"] == {"rid": 3, "tokens": 2000, "chunks": 2, "depth_sum": 1024, "x": 2.0,
+                           "synced": True}
+    assert _us(t, opened) <= rec["ts"] <= _us(t, opened) + 20e3
+    assert _us(t, closed) <= rec["ts"] + rec["dur"] <= _us(t, closed) + 20e3
+
+
+def test_a_handle_closed_without_done_closes_at_the_call_and_one_never_closed_is_given_up(
+        completions, monkeypatch):
+    t, value = completions
+    # the speculative engine's use: no ``after`` (the hand-in opens it), no ``done`` (the
+    # caller saw the work through itself); the scalars still become floats on the worker
+    _Scalar.read_on.clear()
+    before = time.perf_counter()
+    handle = t.complete_span("prefill", rid=5)
+    time.sleep(0.02)
+    handle.close(scalars={"y": _Scalar(4)}, chunks=1)
+    at = time.perf_counter()
+    rec, = t.snapshot(wait_s=5.0)
+    assert rec["args"] == {"rid": 5, "chunks": 1, "y": 4.0, "synced": True}
+    assert _us(t, before) <= rec["ts"] and rec["ts"] + rec["dur"] <= _us(t, at)
+    assert rec["dur"] >= 20e3 - 1 and _Scalar.read_on == ["tracer-completions"]
+    # an admission that raised closes its span with the error's name
+    t.clear()
+    t.complete_span("prefill", rid=6).close(error="DeadlineExceeded")
+    rec, = t.snapshot(wait_s=5.0)
+    assert rec["args"] == {"rid": 6, "error": "DeadlineExceeded", "synced": True}
+    # a handle that was lost is given up, and the spans behind it go on
+    t.clear()
+    monkeypatch.setattr(tracing, "CLOSE_WAIT_S", 0.05)
+    t.complete_span("prefill", rid=7)
+    t.complete_span("prefill", done=value(ready=True), rid=8)
+    lost, behind = t.snapshot(wait_s=5.0)
+    assert lost["args"] == {"rid": 7, "error": "TimeoutError"}
+    assert behind["args"] == {"rid": 8, "synced": True}
+
+
+def test_an_after_that_was_through_before_the_worker_looked_says_so(completions):
+    t, value = completions
+    t.complete_span("prefill", after=value(ready=True), done=value(ready=True))
+    rec, = t.snapshot(wait_s=5.0)
+    assert rec["args"] == {"opened_late": True, "synced": True}
+
+
+def test_a_tracer_that_is_off_touches_nothing_for_a_completion_span(monkeypatch):
+    t = Tracer()
+    monkeypatch.setattr(tracing, "time", None)  # any clock read through the tracer raises
+    monkeypatch.setattr(tracing.threading, "Thread",
+                        lambda *a, **k: pytest.fail("a thread while off"))
+    v = _Value()
+    # whole or as a handle, the same no-op singleton every span is while tracing is off
+    assert t.complete_span("prefill", after=v, done=v, scalars={"x": _Scalar(1)}) is t.span("a")
+    handle = t.complete_span("prefill", after=v, rid=1)
+    assert handle is t.span("a") and handle.close(done=v, scalars={"x": _Scalar(1)}) is None
+    assert t._worker is None and t._pending == {} and t._enqueued == 0
+    assert v.blocked_on == [] and t.snapshot() == []
+
+
+def test_no_completion_worker_exists_until_a_span_is_handed_to_an_enabled_tracer(completions):
+    t, value = completions
+    assert t.enabled and t._worker is None
+    with t.span("iteration", step=0):
+        t.record_span("queue_wait", 0.1, track="serving queue")
+    assert t._worker is None and len(t.snapshot()) == 2
+    t.complete_span("prefill", done=value(ready=True))
+    assert t._worker is not None and t._worker.name == "tracer-completions"
+    assert [r["name"] for r in t.snapshot(wait_s=5.0)] == ["queue_wait", "iteration", "prefill"]
+
+
+def test_a_full_completion_queue_drops_and_counts_and_never_blocks(completions, monkeypatch):
+    t, value = completions
+    monkeypatch.setattr(tracing, "COMPLETIONS_MAX", 2)
+    stuck = [value() for _ in range(4)]
+    t0 = time.perf_counter()
+    for i, v in enumerate(stuck):
+        t.complete_span("prefill", done=v, rid=i)
+    assert time.perf_counter() - t0 < 0.5  # the device never answered; the caller went on
+    assert t.completions_dropped == 2
+    snap = t.snapshot(wait_s=0.05)
+    assert [(r["name"], r["args"]) for r in snap] == [
+        ("prefill", {"rid": 0, "pending": True}), ("prefill", {"rid": 1, "pending": True}),
+        ("completions_dropped", {"count": 2})]
+    for v in stuck:
+        v.land()
+    snap = t.snapshot(wait_s=5.0)
+    assert [r["args"].get("rid") for r in snap] == [0, 1, None] and snap[-1]["ph"] == "i"
+    t.clear()
+    assert t.completions_dropped == 0 and t.snapshot() == []
+
+
+def test_a_deleted_array_gives_the_record_an_error_and_raises_on_no_thread(completions,
+                                                                            monkeypatch):
+    t, value = completions
+    raised = []
+    monkeypatch.setattr(threading, "excepthook", lambda args: raised.append(args))
+    t.complete_span("prefill", after=value(ready=True), done=value(deleted=True), rid=1,
+                    scalars={"x": _Scalar(1)})
+    t.complete_span("prefill", done=value(ready=True), rid=2)
+    bad, good = t.snapshot(wait_s=5.0)
+    assert bad["args"]["error"] == "RuntimeError" and "synced" not in bad["args"]
+    assert bad["args"]["rid"] == 1 and "x" not in bad["args"] and bad["dur"] >= 0
+    assert good["args"] == {"rid": 2, "synced": True}
+    assert raised == [] and t._worker.is_alive()
+
+
+def test_snapshot_waits_bounded_and_marks_what_is_still_pending(completions):
+    t, value = completions
+    dead = value()
+    t.complete_span("prefill", done=dead, rid=9)
+    t0 = time.perf_counter()
+    snap = t.snapshot(wait_s=0.1)
+    assert 0.1 <= time.perf_counter() - t0 < 2.0
+    assert [(r["args"], r["dur"], r["tname"]) for r in snap] == [
+        ({"rid": 9, "pending": True}, 0.0, "device")]
+    # (the wait a reader that says nothing gets: a crash dump returns within it)
+    assert tracing.SNAPSHOT_WAIT_S <= 5.0
+    dead.land()
+    rec, = t.snapshot(wait_s=5.0)
+    assert rec["args"] == {"rid": 9, "synced": True} and rec["dur"] >= 100e3 - 1
+
+
+def test_a_cleared_ring_stays_clear_of_spans_that_were_in_flight(completions):
+    t, value = completions
+    late = value()
+    t.complete_span("prefill", done=late, rid=1)
+    t.clear()
+    late.land()
+    t.complete_span("prefill", done=value(ready=True), rid=2)
+    assert [r["args"]["rid"] for r in t.snapshot(wait_s=5.0)] == [2]
+
+
+def test_a_flight_dump_holds_a_prompts_span_without_knowing_of_the_worker(completions, tmp_path):
+    t, value = completions
+    done = value()
+    t.complete_span("prefill", done=done, rid=4, tokens=300)
+    threading.Timer(0.05, done.land).start()
+    doc = flight.read_flight(flight.dump_flight(str(tmp_path), t, reason="test"))
+    assert [(r["name"], r["args"]["rid"], r["args"].get("synced")) for r in doc["spans"]] == [
+        ("prefill", 4, True)]
+    trace = chrome_trace(doc["spans"])
+    named = {e["args"]["name"] for e in trace["traceEvents"] if e["name"] == "thread_name"}
+    assert named == {"device"}
 
 
 # ---------------------------------------------------------------------------

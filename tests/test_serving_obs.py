@@ -6,6 +6,7 @@ CPU, a toy model; no number here is a device number."""
 
 import os
 import sys
+import threading
 import time
 
 import jax
@@ -110,6 +111,7 @@ def test_request_times_are_filled_and_ordered_with_the_tracer_off(params, backen
             return getattr(time, name)
 
     monkeypatch.setattr(tracing, "time", CountingTime())
+    handed_in = tracer._enqueued
     eng = _engine(params, backend)
     try:
         reqs = [eng.submit_request([1, 2, 3, 4, 5], 6), eng.submit_request([7, 8, 9], 4,
@@ -119,6 +121,7 @@ def test_request_times_are_filled_and_ordered_with_the_tracer_off(params, backen
     finally:
         eng.close()
     assert tracer.snapshot() == [] and reads == []
+    assert tracer._enqueued == handed_in and tracer._pending == {}  # (no completion span)
     for r in reqs:
         assert len(r.token_times) == len(r.generated) == r.max_new_tokens
         stamps = [r.submitted_at, r.admitted_at, r.first_token_at, *r.token_times, r.finished_at]
@@ -166,8 +169,12 @@ def traced_run(params, traced, request):
 
 @pytest.mark.parametrize("traced_run", sorted(BACKENDS), indirect=True)
 def test_every_child_lies_inside_its_parent_on_the_loop_thread(traced_run):
+    """(PR 72: what ``admit`` holds on the loop thread is the admission's HOST side,
+    ``prefill_dispatch`` around one ``chunk_dispatch`` a chunk; ``prefill``, the
+    prompt's chunks on the device, is no longer this thread's: next case.)"""
     spans, fwd = traced_run["spans"], traced_run["forward"]
-    parents = {"admit": "iteration", "prefill": "admit", "sample": "iteration",
+    parents = {"admit": "iteration", "prefill_dispatch": "admit",
+               "chunk_dispatch": "prefill_dispatch", "sample": "iteration",
                "sample_slot": "sample", fwd: "iteration", "decode_dispatch": fwd,
                "decode_wait": fwd, "logits_readback": fwd}
     loop_tid = {s["tid"] for s in spans if s["name"] == "iteration"}
@@ -181,6 +188,45 @@ def test_every_child_lies_inside_its_parent_on_the_loop_thread(traced_run):
             assert len(hosts) == 1, (child, parent)
             assert s["depth"] == hosts[0]["depth"] + 1
     assert all(s["depth"] == 0 for s in spans if s["name"] == "iteration")
+    # nothing else is the loop thread's (but what the runtime did behind its back: a first
+    # run's compiles, a collection): the two tracks that are no thread's hold the rest
+    assert {s["name"] for s in spans if s["tid"] in loop_tid
+            and not s["name"].startswith("jax_") and s["name"] != "gc"} == (
+        set(parents) | {"iteration"})
+    assert {s["name"]: s["tname"] for s in spans if s["tid"] not in loop_tid} == {
+        "queue_wait": "serving queue", "prefill": "device"}
+
+
+@pytest.mark.parametrize("traced_run", sorted(BACKENDS), indirect=True)
+def test_a_prompts_device_span_lies_on_the_device_track_with_its_chunks(traced_run):
+    """One ``prefill`` a request on the track ``device``: the arguments its readers take
+    (``rid``, ``tokens``, ``synced``) plus ``chunks`` and ``depth_sum``, which are what its
+    ``prefill_dispatch`` and that span's ``chunk_dispatch`` children say: ``seq`` numbers
+    the prefill program's executions, consecutive over the run."""
+    spans, reqs = traced_run["spans"], traced_run["reqs"]
+    prompts = {r.rid: len(r.tokens) for r in reqs}
+    prefills = {s["args"]["rid"]: s for s in spans if s["name"] == "prefill"}
+    sends = {s["args"]["rid"]: s for s in spans if s["name"] == "prefill_dispatch"}
+    assert set(prefills) == set(sends) == set(prompts)
+    chunks = sorted((s for s in spans if s["name"] == "chunk_dispatch"), key=lambda s: s["ts"])
+    assert [c["args"]["seq"] for c in chunks] == list(range(len(chunks)))
+    for rid, tokens in prompts.items():
+        device, send = prefills[rid], sends[rid]
+        assert (device["tid"], device["tname"], device["depth"]) == (
+            tracing._track_tid("device"), "device", 0)
+        assert device["args"]["synced"] is True and "error" not in device["args"]
+        mine = [c["args"] for c in chunks if _inside(c, send)]
+        assert send["args"] == {"rid": rid, "tokens": tokens, "chunks": len(mine),
+                                "first_start": mine[0]["start"]}
+        assert sum(c["rows"] for c in mine) == tokens - send["args"]["first_start"]
+        assert {"rid": rid, "tokens": tokens, "chunks": len(mine),
+                "depth_sum": sum(c["start"] for c in mine)}.items() <= device["args"].items()
+        # the device is not through with a prompt before its first chunk was sent
+        assert device["ts"] + device["dur"] >= send["ts"]
+    if traced_run["forward"] == "decode":
+        # (drawing on the device, nobody on the loop thread saw the chunks through: the
+        # completion worker did)
+        assert all("step" not in s["args"] for s in prefills.values())
 
 
 @pytest.mark.parametrize("traced_run", sorted(BACKENDS), indirect=True)
@@ -364,8 +410,16 @@ def test_an_iteration_sends_its_forward_and_then_books_the_last_draws(params, tr
     assert stats["steps_ahead"] == 5 and {s["args"]["row_steps_wasted"] for s in decodes} == {0}
     # every token was booked under a ``sample_slot``, the first of each with its prompt's iteration
     assert len([s for s in spans if s["name"] == "sample_slot"]) == 6 + 4 + 3
-    # tracer on, a ``prefill`` closes on realized compute as before (``synced``)
-    assert all(s["args"].get("synced") for s in spans if s["name"] == "prefill")
+    # tracer on, a ``prefill`` closes on realized compute as before (``synced``); since PR 72
+    # it is the completion worker that saw it, on the track ``device``, and ``admit`` holds
+    # the admission's host side: one ``prefill_dispatch`` a request
+    prefills = [s for s in spans if s["name"] == "prefill"]
+    assert len(prefills) == 3 and all(s["args"].get("synced") for s in prefills)
+    assert {s["tname"] for s in prefills} == {"device"}
+    admits = [s for s in spans if s["name"] == "admit"]
+    assert sum(s["args"]["admitted"] for s in admits) == 3 == len(
+        [s for s in spans if s["name"] == "prefill_dispatch"
+         and any(_inside(s, a) for a in admits)])
 
 
 @pytest.mark.parametrize("backend", ["slot-plain", "paged-plain"])
@@ -400,6 +454,102 @@ def test_a_traced_and_an_untraced_engine_send_the_same_steps_in_the_same_order(p
     assert plain == under_the_tracer and plain[1][-1][0] == 5 and plain[2] == 0
 
 
+def _calls_and_waits(params, backend, monkeypatch):
+    """An admission of two chunks and two decode iterations, every jitted call of the
+    engine and every place a thread could wait for the device recorded by thread: a call
+    as ``("jit", name)``, a wait as ``("wait", the engine's function it was made in)``
+    (``jax.block_until_ready``; the engine's ``np.asarray`` of a device array; and
+    ``ArrayImpl._value``, which ``float`` and ``tolist`` of one go through).  Waits that
+    follow one another at one place are one."""
+    from jax._src import array as jax_array
+
+    from galvatron_tpu.serving import engine as engine_mod
+
+    events = {}
+
+    def note(kind, what):
+        mine = events.setdefault(threading.current_thread().name, [])
+        if kind == "jit" or not mine or mine[-1] != (kind, what):
+            mine.append((kind, what))
+
+    def place():
+        frame = sys._getframe(1)
+        while frame is not None:
+            if frame.f_code.co_filename == engine_mod.__file__:
+                # (a closure waits at its method's place: ``_step_ahead``'s ``read``)
+                return frame.f_code.co_qualname.split(".<locals>")[0].split(".")[-1]
+            frame = frame.f_back
+        return None  # (a wait outside the engine: the worker's, the test's own)
+
+    for name in ("_prefill_chunk", "_paged_prefill_chunk", "_decode_step", "_paged_decode_step",
+                 "_sample_rows"):
+        def jitted(*a, _f=getattr(engine_mod, name), _name=name, **k):
+            note("jit", _name)
+            return _f(*a, **k)
+        monkeypatch.setattr(engine_mod, name, jitted)
+    class Numpy:
+        """The engine's ``np``: numpy, with ``asarray`` of a device array noted."""
+
+        def __getattr__(self, name):
+            return getattr(np, name)
+
+        def asarray(self, x, *a, **k):
+            if isinstance(x, jax.Array):
+                note("wait", place())
+            return np.asarray(x, *a, **k)
+
+    monkeypatch.setattr(engine_mod, "np", Numpy())
+    block = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready", lambda x: (note("wait", place()), block(x))[1])
+    value = jax_array.ArrayImpl._value
+    monkeypatch.setattr(jax_array.ArrayImpl, "_value",
+                        property(lambda self: (note("wait", place()), value.fget(self))[1]))
+    eng = _engine(params, backend)
+    try:
+        req = eng.submit_request(list(range(1, 13)), 4)  # 12 tokens: two chunks of 8
+        for _ in range(3):
+            eng.step_once()
+        generated = list(req.generated)
+    finally:
+        eng.close()
+    return events, generated
+
+
+@pytest.mark.parametrize("backend", ["slot-plain", "paged-plain"])
+def test_the_traced_loop_thread_calls_and_waits_where_the_untraced_one_does(
+        params, backend, monkeypatch):
+    """THE RULE of ``obs/tracing.py``: tracer on, the loop thread makes the same jitted
+    calls in the same order and can wait for the device at the same places between them as
+    tracer off (its one ``Span.sync``, ``decode_wait``, sits where ``np.asarray(ids)``
+    blocks anyway); what waits for a prompt's chunks is the completion worker."""
+    me = threading.current_thread().name
+    assert not tracer.enabled
+    with monkeypatch.context() as patch:
+        plain, plain_tokens = _calls_and_waits(params, backend, patch)
+    tracer.enable(capacity=1 << 14)
+    try:
+        with monkeypatch.context() as patch:
+            under, tokens = _calls_and_waits(params, backend, patch)
+            spans = _spans(tracer)
+    finally:
+        tracer.disable()
+        tracer.clear()
+    prefill = "_paged_prefill_chunk" if "paged" in backend else "_prefill_chunk"
+    decode = "_paged_decode_step" if "paged" in backend else "_decode_step"
+    assert plain[me] == [
+        ("jit", prefill), ("jit", prefill), ("jit", "_sample_rows"),  # the admission: no wait
+        ("jit", decode), ("jit", "_sample_rows"), ("wait", "_step_ahead"),
+        ("jit", decode), ("jit", "_sample_rows"), ("wait", "_step_ahead"),
+        ("jit", decode), ("jit", "_sample_rows"), ("wait", "_step_ahead")]
+    assert under[me] == plain[me] and tokens == plain_tokens and len(tokens) == 3
+    assert set(plain) == {me}
+    # the prompt's two ends were waited for, by the worker, outside the engine
+    assert set(under) == {me, "tracer-completions"}
+    assert under["tracer-completions"] == [("wait", None)]
+    device, = [s for s in spans if s["name"] == "prefill"]
+    assert device["args"]["chunks"] == 2 and device["args"]["depth_sum"] == 8
+
+
 def test_an_idle_engine_opens_no_iteration(params, traced):
     eng = _engine(params)
     try:
@@ -407,7 +557,8 @@ def test_an_idle_engine_opens_no_iteration(params, traced):
             eng.step_once()
     finally:
         eng.close()
-    assert _spans(traced) == []
+    # (a collection of a millisecond that falls in here is the runtime's span, not the engine's)
+    assert [s for s in _spans(traced) if s["name"] != "gc"] == []
 
 
 # --- the tap ---------------------------------------------------------------------------
