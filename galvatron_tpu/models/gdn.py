@@ -14,12 +14,14 @@ program's in-projections are flat as written above; the published ones are
 interleaved by key head, ``benchmark/references/qwen3_next.published_weights``
 maps one to the other.) Imported only where a configuration has such layers.
 
-The rule has two bodies behind one contract (PR 48), chosen by
+The norms and the rule have two bodies (PR 48, PR 73), chosen by
 `ops/gated_delta.scan_path` from shapes and backend alone: on a chip at the
-published sizes the Pallas kernels ``gdn_fwd`` / ``gdn_bwd`` (a ``custom_vjp``
-that keeps its five inputs and the chunks' entering states; under
-``place.shard_kernel`` on a mesh), everywhere else the plain chunked body under
-this mixer's own ``jax.checkpoint``. `path_counts` reports which.
+published sizes the Pallas kernels ``gdn_fwd`` / ``gdn_bwd`` read q, k and v
+where the conv wrote them and normalise q and k themselves (a ``custom_vjp``
+that keeps the conv's output, the two scalars and the chunks' entering states;
+under ``place.shard_kernel`` on a mesh), everywhere else `_l2norm` and the plain
+chunked body under this mixer's own ``jax.checkpoint``. `path_counts` reports
+which.
 
 Scopes under ``gdn``: ``in_proj``, ``conv``, ``scan``, ``gate_norm``,
 ``out_proj`` (PERF.md §3; the ``gdn_*`` benchmark metrics read them).
@@ -35,12 +37,16 @@ import numpy as np
 
 from galvatron_tpu.models.mixers import tally
 from galvatron_tpu.models.placement import LOCAL, Placement
-from galvatron_tpu.ops.gated_delta import gated_delta_chunked, gated_delta_fused, scan_path
+from galvatron_tpu.ops.gated_delta import (
+    L2_EPS as _L2_EPS,
+    gated_delta_chunked,
+    gated_delta_fused,
+    scan_path,
+)
 from galvatron_tpu.ops.ssd import causal_conv1d, conv_path, conv_silu_fused
 
 Params = Dict[str, Any]
 F32 = jnp.float32
-_L2_EPS = 1e-6
 
 
 def gdn_dims(cfg):
@@ -159,24 +165,26 @@ def block(x, p: Params, cfg, place: Placement = LOCAL):
     with jax.named_scope("conv"):
         qkv = conv_silu(qkvz, p["conv_w"], cfg, place)
     with jax.named_scope("scan"):
-        q = (_l2norm(qkv[..., :key_dim].reshape(*lead, hk, dk)) * dk ** -0.5).astype(dtype)
-        k = _l2norm(qkv[..., key_dim:2 * key_dim].reshape(*lead, hk, dk)).astype(dtype)
-        v = qkv[..., 2 * key_dim:].reshape(*lead, hv, dv)
         beta = jax.nn.sigmoid(ba[..., :hv])
         g = -jnp.exp(p["A_log"].astype(F32)) * jax.nn.softplus(
             ba[..., hv:] + p["dt_bias"].astype(F32))
         if _rule_path(cfg) == "fused":
-            # the kernels keep their own residuals (the five inputs and the chunks'
-            # entering states); on a mesh each device runs them on its own batch rows
+            # the kernels read q, k and v out of the conv's output, normalise q and k,
+            # and keep their own residuals (their three inputs and the chunks' entering
+            # states); on a mesh each device runs them on its own batch rows
             rows = (0, None)
-            rule = place.shard_kernel(
-                lambda *t: gated_delta_fused(*t, cfg.gdn_chunk), [rows] * 5, rows)
+            o = place.shard_kernel(
+                lambda *t: gated_delta_fused(*t, hk, dk, cfg.gdn_chunk), [rows] * 3, rows)(
+                    qkv, g, beta)
         else:
+            q = (_l2norm(qkv[..., :key_dim].reshape(*lead, hk, dk)) * dk ** -0.5).astype(dtype)
+            k = _l2norm(qkv[..., key_dim:2 * key_dim].reshape(*lead, hk, dk)).astype(dtype)
+            v = qkv[..., 2 * key_dim:].reshape(*lead, hv, dv)
             # rematerialized in the backward from its five inputs: the chunks' float32
             # systems, solutions and carried states (~300 KB a token) are then live
             # only while the rule's own backward runs, not beside the expert layer's
             rule = jax.checkpoint(lambda *t: gated_delta_chunked(*t, cfg.gdn_chunk))
-        o = rule(q, k, v, g, beta)
+            o = rule(q, k, v, g, beta)
     with jax.named_scope("gate_norm"):
         o32 = o.astype(F32)
         o32 = o32 * jax.lax.rsqrt(jnp.mean(o32 * o32, axis=-1, keepdims=True) + cfg.norm_eps)

@@ -20,15 +20,18 @@ entering state ``S``::
     O  = (Q exp(G)) S + lower(Q K^T * D) V'
     S <- exp(G_last) S + (K exp(G_last - G))^T V'
 
-Two bodies, one contract (as `ops/ssd.py`'s scan): `gated_delta_chunked`, plain
-``jax.numpy`` (a ``lax.scan`` carries the states across the chunks, everything
-inside a chunk is batched over the chunks, the system goes through
-``jax.scipy.linalg.solve_triangular``, autodiff gives the backward), and
-`gated_delta_fused`, a ``jax.custom_vjp`` over the Pallas kernels ``gdn_fwd`` /
+Two bodies behind `scan_path`, which chooses from shapes and backend alone and
+which `models/gdn.block` asks. `gated_delta_chunked` is plain ``jax.numpy`` over
+q and k already normalised (a ``lax.scan`` carries the states across the chunks,
+everything inside a chunk is batched over the chunks, the system goes through
+``jax.scipy.linalg.solve_triangular``, autodiff gives the backward).
+`gated_delta_fused` is a ``jax.custom_vjp`` over the Pallas kernels ``gdn_fwd`` /
 ``gdn_bwd`` (further down: the state in VMEM across a sequential grid axis, the
-system inverted by matrix products, a backward of its own that keeps the five
-inputs and the chunks' entering states). `scan_path` chooses between them from
-shapes and backend alone; `models/gdn.block` asks it.
+system inverted by matrix products, a backward of its own that keeps its three
+inputs and the chunks' entering states); it takes the conv's output as it lies,
+``[q | k | v]`` a position, and the kernels normalise q and k themselves (since
+PR 73). The two share a contract at the mixer's level (`block`'s output and
+gradients), not an entry.
 
 Precision, both bodies: the log-decays, their running sums, ``beta``, the
 system, its inverse (or solve) and its application, and the carried state (and
@@ -122,17 +125,25 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64):
 # One grid step is `_SLABS` slabs of one key head, a slab `_SLAB` = 128 positions =
 # two chunks of 64: grid (batch, key head, step), the step axis sequential
 # ("arbitrary"), the states of the key head's ``Hv / Hk`` value heads, float32
-# (Dk, Dv) each, in VMEM scratch from one step to the next. q, k, o and their
-# gradients are head-major, (B, H, S, D): a block is (positions, D) of one head,
-# contiguous, and the transpositions from and to the mixer's (B, S, H, D) are XLA's,
-# under the caller's scope, fused into the norms on both sides (token-major
-# (B, S, H x D) blocks made XLA re-tile the float32 norms' operands in copies that
-# carry no name: 12 x 0.8 ms a step in the cell, PERF.md §6, PR 48). v and its
-# gradient stay token-major, (positions, R x Dv) blocks of (B, S, Hv x Dv): the
-# conv's output as it lies. The per-position scalars come both ways, made outside
-# from (B, S, Hv) float32 (2 MB at the cell's sizes): a position a sublane (the
-# running sums G and beta, a value head picked by a lane mask) and a position a
-# lane (G again, (B, Hk, R, S)).
+# (Dk, Dv) each, in VMEM scratch from one step to the next. q, k and v are three
+# blocks of ONE array, the conv's output (B, S, 2 Hk Dk + Hv Dv) where it lies: q a
+# (positions, Dk) block at lane block ``j``, k at ``Hk + j``, v a (positions, R Dv)
+# block at ``2 Hk Dk / (R Dv) + j``; dq, dk and dv leave token-major the same way,
+# and one concatenation makes them the conv's cotangent. The kernels normalise: a
+# block is (positions, Dk) of ONE head, so the L2 norm over a head is a reduction
+# along the lanes of what the kernel already holds (`_normalised`), and its VJP runs
+# on the float32 accumulators of dq and dk before their one cast. (The norms were
+# XLA's until PR 73, in float32 over head-major copies: on a token-major (S, Hk x
+# Dk) array XLA can only reduce over a head after re-tiling to (S, Hk, Dk), a
+# physical copy, and head-major blocks with XLA's norms need the transposition:
+# ~32 ms a step in the cell either way, PERF.md §6, PR 48 and PR 73. Only both
+# together leave XLA nothing to copy.) o and its gradient STAY head-major, (B, Hv,
+# S, Dv), their transpositions XLA's under the caller's scope: the gated norm that
+# follows reduces over Dv a head and lost 13 ms when o arrived in the tiling it
+# reduces in, and a token-major o is what made the unnamed copies of PR 48's first
+# try. The per-position scalars come both ways, made outside from (B, S, Hv) float32
+# (2 MB at the cell's sizes): a position a sublane (the running sums G and beta, a
+# value head picked by a lane mask) and a position a lane (G again, (B, Hk, R, S)).
 #
 # Every (C, C) block of a value head lives PACKED: the two chunks of the slab side
 # by side on the 128 lanes, (64, 128) = [chunk a | chunk b], so an elementwise op
@@ -158,13 +169,14 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64):
 # side by side; PERF.md §6, PR 48.)
 #
 # The backward walks the steps from the last to the first with the gradient of the
-# state in VMEM, rebuilds ``A``, ``T``, ``U``, ``W`` of its slabs from the inputs
-# and ``V'`` from the states the forward rule kept (one entering a chunk: (B, S/64,
-# Hv, Dk, Dv) float32, the only residual besides the inputs), and takes the
-# inverse's gradient as ``dA = -strict(dXu U^T + dXw W^T)`` with ``[dXu | dXw] =
-# T^T [dU | dW]``: products, no solve. ``dg`` leaves the kernel summed back over
-# each chunk (the running sum's transpose), a position a lane.
+# state in VMEM, rebuilds the normalised q and k, ``A``, ``T``, ``U``, ``W`` of its
+# slabs from the inputs and ``V'`` from the states the forward rule kept (one
+# entering a chunk: (B, S/64, Hv, Dk, Dv) float32, the only residual besides the
+# inputs), and takes the inverse's gradient as ``dA = -strict(dXu U^T + dXw W^T)``
+# with ``[dXu | dXw] = T^T [dU | dW]``: products, no solve. ``dg`` leaves the kernel
+# summed back over each chunk (the running sum's transpose), a position a lane.
 
+L2_EPS = 1e-6  # under the root of q's and k's norms (the published code's; `models/gdn` reads it here)
 _CHUNK = 64  # the chunk the kernels are written for (the published code's)
 _SLAB = 2 * _CHUNK
 _SLABS = 2  # slabs a grid step: their chains side by side (one: 9.8 ms a forward at the
@@ -273,8 +285,8 @@ def _padded(x, c):
 
 
 class _Slab(NamedTuple):
-    """q and k of a slab (128 positions of a key head), as they come and in
-    float32, and their two Gram products, packed."""
+    """q and k of a slab (128 positions of a key head), normalised, in the compute
+    dtype and widened again, and their two Gram products, packed."""
     q: Any
     k: Any
     q32: Any
@@ -297,10 +309,30 @@ class _Head(NamedTuple):
     uw: Any
 
 
+def _normalised(ref, sl):
+    """A slab's rows of q or k as the conv wrote them -> float32 of unit length
+    over the head's lanes (`models/gdn._l2norm`'s arithmetic), and the factor that
+    made them so, (128, 1)."""
+    x = ref[0, _slab_rows(sl)].astype(F32)
+    factor = jax.lax.rsqrt(jnp.sum(x * x, axis=1, keepdims=True) + L2_EPS)
+    return x * factor, factor
+
+
 def _slab_setup(sl, q_ref, k_ref, first):
-    q, k = q_ref[0, 0, _slab_rows(sl)], k_ref[0, 0, _slab_rows(sl)]
+    # rounded to the compute dtype where `models/gdn.block` rounds them for the plain
+    # body: the rule's products see the same operands
+    dtype = q_ref.dtype
+    q = (_normalised(q_ref, sl)[0] * q_ref.shape[2] ** -0.5).astype(dtype)
+    k = _normalised(k_ref, sl)[0].astype(dtype)
     return _Slab(q, k, q.astype(F32), k.astype(F32),
                  _halves(_dot(k, k, _NT), first), _halves(_dot(q, k, _NT), first))
+
+
+def _norm_vjp(ref, sl, d, scale):
+    """The float32 gradient ``d`` of a slab's normalised (and scaled) rows -> that
+    of the rows as they came: ``scale r (d - n <d, n>)``."""
+    n, factor = _normalised(ref, sl)
+    return scale * factor * (d - n * jnp.sum(d * n, axis=1, keepdims=True))
 
 
 def _heads_setup(r, slab, v_ref, gc_ref, bc_ref, gr_ref, geometry):
@@ -334,7 +366,7 @@ def _heads_setup(r, slab, v_ref, gc_ref, bc_ref, gr_ref, geometry):
 def _fwd_kernel(q_ref, k_ref, v_ref, gc_ref, bc_ref, gr_ref, o_ref, *rest, r, keep_states):
     state = rest[-1]  # (R, Dk, Dv) float32
     dtype = q_ref.dtype
-    dv, dk = v_ref.shape[2] // r, q_ref.shape[3]
+    dv, dk = v_ref.shape[2] // r, q_ref.shape[2]
 
     @pl.when(pl.program_id(2) == 0)
     def _zero():
@@ -392,7 +424,7 @@ def _suffix_sums(v):
 def _bwd_kernel(q_ref, k_ref, v_ref, gc_ref, bc_ref, gr_ref, do_ref, st_ref,
                 dq_ref, dk_ref, dv_ref, dg_ref, db_ref, dstate, *, r):
     dtype = q_ref.dtype
-    dv, dk = v_ref.shape[2] // r, q_ref.shape[3]
+    dv, dk = v_ref.shape[2] // r, q_ref.shape[2]
 
     @pl.when(pl.program_id(2) == 0)
     def _zero():
@@ -502,32 +534,36 @@ def _bwd_kernel(q_ref, k_ref, v_ref, gc_ref, bc_ref, gr_ref, do_ref, st_ref,
             db_ref[0, 0, i:i + 1, rows] = across[1:2]
     for i in value_heads:
         dstate[i] = leaving[i]
+    # through the norms in float32, then the one cast
     for sl in range(_SLABS):
-        dq_ref[0, 0, _slab_rows(sl)] = dq[sl].astype(dq_ref.dtype)
-        dk_ref[0, 0, _slab_rows(sl)] = dkey[sl].astype(dk_ref.dtype)
-
-
+        dq_ref[0, _slab_rows(sl)] = _norm_vjp(q_ref, sl, dq[sl], dk ** -0.5).astype(dq_ref.dtype)
+        dk_ref[0, _slab_rows(sl)] = _norm_vjp(k_ref, sl, dkey[sl], 1.0).astype(dk_ref.dtype)
 
 
 def _specs(bsz, sp, hk, r, dk, dv, rev):
     """Grid and block specs shared by the two kernels; ``rev`` walks the steps
-    from the last to the first."""
+    from the last to the first. ``joined``: q, k and v of a key head as blocks of
+    the conv's output (B, S, 2 Hk Dk + Hv Dv); ``own``: the same blocks of arrays as
+    wide as their own part of it (dq, dk: Hk Dk; dv: Hv Dv)."""
     block = _SLABS * _SLAB
     ns = sp // block
     sl = (lambda c: ns - 1 - c) if rev else (lambda c: c)
-    keys = pl.BlockSpec((1, 1, block, dk), lambda b, j, c: (b, j, sl(c), 0))  # q, k: (B, Hk, S, Dk)
-    values = pl.BlockSpec((1, block, r * dv), lambda b, j, c: (b, sl(c), j))
+    at = lambda width, first: pl.BlockSpec(  # noqa: E731
+        (1, block, width), lambda b, j, c: (b, sl(c), first + j))
+    v_first = 2 * hk * dk // (r * dv)
+    joined = at(dk, 0), at(dk, hk), at(r * dv, v_first)
+    own = at(dk, 0), at(dk, 0), at(r * dv, 0)
     heads = pl.BlockSpec((1, r, block, dv), lambda b, j, c: (b, j, sl(c), 0))  # o, do: (B, Hv, S, Dv)
     cols = pl.BlockSpec((1, block, hk * r), lambda b, j, c: (b, sl(c), 0))
     rows = pl.BlockSpec((1, 1, r, block), lambda b, j, c: (b, j, 0, sl(c)))
     states = pl.BlockSpec((1, 2 * _SLABS, r, dk, dv), lambda b, j, c: (b, sl(c), j, 0, 0))
-    return (bsz, hk, ns), keys, values, heads, cols, rows, states
+    return (bsz, hk, ns), joined, own, heads, cols, rows, states
 
 
-def _sizes(q, v, gr):
-    bsz, hk, sp, dk = q.shape
-    r = gr.shape[2]
-    return bsz, sp, hk, r, dk, v.shape[2] // (hk * r)
+def _sizes(qkv, gr, dk):
+    bsz, sp, width = qkv.shape
+    hk, r = gr.shape[1:3]
+    return bsz, sp, hk, r, dk, (width - 2 * hk * dk) // (hk * r)
 
 
 def _params():
@@ -536,36 +572,37 @@ def _params():
 
 # (jitted and inlined: a program traces each kernel once, however many layers call
 # it, and every call site still lowers under its own scope names)
-@functools.partial(jax.jit, static_argnames="keep_states", inline=True)
-def _fwd_call(q, k, v, gc, bc, gr, keep_states):
-    bsz, sp, hk, r, dk, dv = _sizes(q, v, gr)
-    grid, keys, values, heads, cols, rows, states = _specs(bsz, sp, hk, r, dk, dv, rev=False)
-    out_shape, out_specs = [jax.ShapeDtypeStruct((bsz, hk * r, sp, dv), v.dtype)], [heads]
+@functools.partial(jax.jit, static_argnames=("dk", "keep_states"), inline=True)
+def _fwd_call(qkv, gc, bc, gr, dk, keep_states):
+    bsz, sp, hk, r, dk, dv = _sizes(qkv, gr, dk)
+    grid, joined, _, heads, cols, rows, states = _specs(bsz, sp, hk, r, dk, dv, rev=False)
+    out_shape, out_specs = [jax.ShapeDtypeStruct((bsz, hk * r, sp, dv), qkv.dtype)], [heads]
     if keep_states:
         out_shape.append(jax.ShapeDtypeStruct((bsz, sp // _CHUNK, hk * r, dk, dv), F32))
         out_specs.append(states)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, r=r, keep_states=keep_states),
-        grid=grid, in_specs=[keys, keys, values, cols, cols, rows],
+        grid=grid, in_specs=[*joined, cols, cols, rows],
         out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((r, dk, dv), F32)], compiler_params=_params(),
         interpret=pallas_common.use_interpret(), name="gdn_fwd",
-    )(q, k, v, gc, bc, gr)
+    )(qkv, qkv, qkv, gc, bc, gr)
 
 
-@functools.partial(jax.jit, inline=True)
-def _bwd_call(q, k, v, gc, bc, gr, do, states):
-    bsz, sp, hk, r, dk, dv = _sizes(q, v, gr)
-    grid, keys, values, heads, cols, rows, st = _specs(bsz, sp, hk, r, dk, dv, rev=True)
-    like = lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype)  # noqa: E731
+@functools.partial(jax.jit, static_argnames="dk", inline=True)
+def _bwd_call(qkv, gc, bc, gr, do, states, dk):
+    bsz, sp, hk, r, dk, dv = _sizes(qkv, gr, dk)
+    grid, joined, own, heads, cols, rows, st = _specs(bsz, sp, hk, r, dk, dv, rev=True)
+    wide = lambda width: jax.ShapeDtypeStruct((bsz, sp, width), qkv.dtype)  # noqa: E731
+    like = jax.ShapeDtypeStruct(gr.shape, gr.dtype)
     return pl.pallas_call(
         functools.partial(_bwd_kernel, r=r),
-        grid=grid, in_specs=[keys, keys, values, cols, cols, rows, heads, st],
-        out_specs=[keys, keys, values, rows, rows],
-        out_shape=[like(q), like(k), like(v), like(gr), like(gr)],
+        grid=grid, in_specs=[*joined, cols, cols, rows, heads, st],
+        out_specs=[*own, rows, rows],
+        out_shape=[wide(hk * dk), wide(hk * dk), wide(hk * r * dv), like, like],
         scratch_shapes=[pltpu.VMEM((r, dk, dv), F32)], compiler_params=_params(),
         interpret=pallas_common.use_interpret(), name="gdn_bwd",
-    )(q, k, v, gc, bc, gr, do, states)
+    )(qkv, qkv, qkv, gc, bc, gr, do, states)
 
 
 def _prepared(g, beta, hk):
@@ -576,60 +613,60 @@ def _prepared(g, beta, hk):
     return gc, beta, gc.transpose(0, 2, 1).reshape(bsz, hk, hv // hk, sp)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _delta_core(q, k, v, g, beta, hk):
-    """q, k (B, Hk, S, Dk), v (B, S, Hv x Dv), g and beta (B, S, Hv) float32, S in
-    whole steps -> o (B, Hv, S, Dv)."""
-    return _fwd_call(q, k, v, *_prepared(g, beta, hk), keep_states=False)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _delta_core(qkv, g, beta, hk, dk):
+    """qkv (B, S, 2 Hk Dk + Hv Dv), g and beta (B, S, Hv) float32, S in whole
+    steps -> o (B, Hv, S, Dv)."""
+    return _fwd_call(qkv, *_prepared(g, beta, hk), dk=dk, keep_states=False)[0]
 
 
-def _core_fwd(q, k, v, g, beta, hk):
-    o, states = _fwd_call(q, k, v, *_prepared(g, beta, hk), keep_states=True)
-    return o, (q, k, v, g, beta, states)
+def _core_fwd(qkv, g, beta, hk, dk):
+    o, states = _fwd_call(qkv, *_prepared(g, beta, hk), dk=dk, keep_states=True)
+    return o, (qkv, g, beta, states)
 
 
-def _core_bwd(hk, res, do):
-    q, k, v, g, beta, states = res
-    dq, dk, dv, dg, db = _bwd_call(q, k, v, *_prepared(g, beta, hk), do, states)
+def _core_bwd(hk, dk, res, do):
+    qkv, g, beta, states = res
+    dq, dkey, dv, dg, db = _bwd_call(qkv, *_prepared(g, beta, hk), do, states, dk=dk)
     bsz, sp, hv = g.shape
-    return dq, dk, dv, *(t.reshape(bsz, hv, sp).transpose(0, 2, 1) for t in (dg, db))
+    return (jnp.concatenate([dq, dkey, dv], axis=-1),
+            *(t.reshape(bsz, hv, sp).transpose(0, 2, 1) for t in (dg, db)))
 
 
 _delta_core.defvjp(_core_fwd, _core_bwd)
 
 
-def gated_delta_fused(q, k, v, g, beta, chunk: int = _CHUNK):
-    """`gated_delta_chunked`'s contract through the kernels (`_delta_core`), for
-    sizes inside `scan_path`'s envelope. Outside the kernels only the padding to
-    whole steps, the transpositions of q, k and o (see above), and the running
-    sums and the transpose of the (B, S, Hv) scalars."""
+def gated_delta_fused(qkv, g, beta, hk: int, dk: int, chunk: int = _CHUNK):
+    """The rule through the kernels (`_delta_core`), for sizes inside `scan_path`'s
+    envelope: ``qkv`` (B, S, 2 Hk Dk + Hv Dv), `ops/ssd`'s conv + SiLU output
+    untouched (``[q | k | v]`` a position, q and k NOT normalised: the kernels do
+    it), ``g`` (log-decay, <= 0) and ``beta`` (B, S, Hv) -> ``o`` (B, S, Hv, Dv) in
+    ``qkv``'s dtype, which is the compute dtype. Outside the kernels only the
+    padding to whole steps, o's transposition (see above), the running sums and
+    the transpose of the scalars, and in the backward one concatenation."""
     if chunk != _CHUNK:
         raise ValueError(f"the fused delta rule is written for chunks of {_CHUNK}, not {chunk}")
-    b, s, hk, dk = q.shape
-    hv, dv = v.shape[2:]
+    s = qkv.shape[1]
     pad = -s % (_SLABS * _SLAB)
     if pad:
-        q, k, v, g, beta = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
-                            for t in (q, k, v, g, beta))
-    sp = s + pad
-    o = _delta_core(q.transpose(0, 2, 1, 3), k.astype(q.dtype).transpose(0, 2, 1, 3),
-                    v.astype(q.dtype).reshape(b, sp, hv * dv), g.astype(F32), beta.astype(F32), hk)
-    return o.transpose(0, 2, 1, 3)[:, :s].astype(v.dtype)
+        qkv, g, beta = (jnp.pad(t, ((0, 0), (0, pad), (0, 0))) for t in (qkv, g, beta))
+    o = _delta_core(qkv, g.astype(F32), beta.astype(F32), hk, dk)
+    return o.transpose(0, 2, 1, 3)[:, :s]
 
 
 def _fused_vmem_mb(r: int, dk: int, dv: int, hv: int, itemsize: int) -> float:
     """What a backward step holds in VMEM, reckoned from the shapes: the blocks of
     q, k, dq, dk, of v, do, dv, of the scalars and of the entering states of the
-    step's chunks (two buffers each), the carried gradient, and the float32
+    step's chunks (two buffers each), the carried gradient, the float32
     temporaries of a value head of a slab (a dozen packed blocks, a dozen
-    slab-sized products)."""
+    slab-sized products), and of a slab q and k normalised and their gradients."""
     step = _SLABS * _SLAB
     lanes = -(-hv // 128) * 128
     blocks = 2 * (4 * step * dk * itemsize + 3 * step * r * dv * itemsize
                   + 2 * step * lanes * 4 + 3 * 8 * step * 4 + 2 * _SLABS * r * dk * dv * 4)
     scratch = r * dk * dv * 4
     temps = (_SLABS * r * (12 * _CHUNK * _SLAB * 4 + 12 * _SLAB * (dk + dv) * 4)
-             + 2 * _SLAB * _SLAB * 4)
+             + _SLABS * 6 * _SLAB * dk * 4 + 2 * _SLAB * _SLAB * 4)
     return (blocks + scratch + temps) / 2**20
 
 
@@ -641,14 +678,16 @@ def scan_path(hk: int, hv: int, dk: int, dv: int, chunk: int, dtype) -> str:
 
     - a chip (`pallas_common.use_interpret`'s rule, the one switch of this
       repo's kernels: on the CPU they run interpreted, which only the tests that
-      call `gated_delta_fused` themselves want);
+      call `gated_delta_fused` themselves, or turn `block` to it, want);
     - chunks of 64, the size the kernels are written for (two side by side fill
       the 128 lanes);
-    - ``dk`` and ``dv`` multiples of 128: a head's v is whole lane tiles of the
-      token-major array, and every product's operands whole MXU tiles;
-    - ``hv`` a multiple of ``hk``: a grid step is a key head and its value heads;
+    - ``dk`` and ``dv`` multiples of 128: a head's q, k and v are whole lane tiles
+      of the token-major array, and every product's operands whole MXU tiles;
+    - ``hv`` a multiple of ``hk``: a grid step is a key head and its value heads,
+      and q and k together whole blocks of a key head's v (``2 Hk Dk`` a multiple
+      of ``R Dv``: v's blocks are counted from the array's first lane);
     - bf16 or float32 compute;
-    - a VMEM charge (`_fused_vmem_mb`, 10.5 MB at the published sizes) inside the
+    - a VMEM charge (`_fused_vmem_mb`, 11.3 MB at the published sizes) inside the
       budget `flash_attention._seq_envelope` reckons with.
     """
     dtype = jnp.dtype(dtype)
@@ -656,6 +695,7 @@ def scan_path(hk: int, hv: int, dk: int, dv: int, chunk: int, dtype) -> str:
             or dtype not in (jnp.bfloat16, jnp.float32)):
         return "plain"
     inside = (chunk == _CHUNK and dk % 128 == 0 and dv % 128 == 0
+              and 2 * hk * dk % (hv // hk * dv) == 0
               and 1.1 * _fused_vmem_mb(hv // hk, dk, dv, hv, dtype.itemsize)
               <= pallas_common.VMEM_LIMIT_MB)
     return "fused" if inside else "plain"

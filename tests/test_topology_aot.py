@@ -369,9 +369,10 @@ def test_qwen3_next_mixer_compiles_at_published_widths(one_chip, real_mosaic):
     ``gdn_fwd`` / ``gdn_bwd`` under ``gdn/scan``, the forward once plain and once replayed
     under autodiff's mark, each call site under its own layer's name): no triangular
     solve and no loop is left under ``scan``. The mixer's temporaries: 5.35 GiB while
-    autodiff kept the plain rule's float32 systems, solutions and carried states; the
-    kernels keep their five inputs and one entering state a chunk (537 MB), 2.33 GiB
-    planned in all."""
+    autodiff kept the plain rule's float32 systems, solutions and carried states; 2.33
+    GiB while the kernels kept q and k normalised head-major beside the conv's output;
+    2.14 GiB now that they keep that output alone, the scalars and one entering state a
+    chunk (537 MB)."""
     from galvatron_tpu.models import gdn
     from galvatron_tpu.models.modeling import PRESETS
     from galvatron_tpu.ops import gated_delta, pallas_common
@@ -393,7 +394,8 @@ def test_qwen3_next_mixer_compiles_at_published_widths(one_chip, real_mosaic):
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(x, p).compile()
     text = compiled.as_text()
     temp = compiled.memory_analysis().temp_size_in_bytes
-    assert temp < 2.6 * 2**30, f"{temp / 2**30:.2f} GiB"
+    assert temp < 2.3 * 2**30, f"{temp / 2**30:.2f} GiB"
+    import math
     import re
 
     ops = set(re.findall(r'op_name="([^"]*)"', text))
@@ -412,11 +414,24 @@ def test_qwen3_next_mixer_compiles_at_published_widths(one_chip, real_mosaic):
         ("gdn_bwd", True), ("gdn_fwd", False), ("gdn_fwd", True)], rule
     assert all("/gdn/scan/" in op and op.endswith("/pallas_call") for _, _, op in rule), rule
     assert not [n for n, _ in rows if n.startswith(("flash_", "ssd_"))]
-    # q, k and o pass between the mixer and the kernels head-major: XLA's transpositions
-    # carry the mixer's scope (token-major blocks cost four 256 MiB float32 copies with
-    # no name a layer, `scope_coverage` 96.2% in the cell: PERF.md §6, PR 48)
+    # the kernels read q, k and v where the conv wrote them and normalise q and k (PR 73):
+    # what XLA keeps under ``scan`` is the scalars (2 MiB arrays), do's transposition to
+    # head-major and the concatenation of dq, dk, dv, both bf16: no slice, nothing float32
+    # of a projection's size (eight 128 MiB copies a layer until then), and no float32 copy
+    # without a name (token-major blocks with XLA's norms made four of 256 MiB a layer,
+    # `scope_coverage` 96.2% in the cell: PERF.md §6, PR 48)
+    xla_scan = [ln for ln in _entry_lines(text)
+                if "/gdn/scan/" in ln and not re.search(r" (custom-call|get-tuple-element)\(", ln)]
+    assert not [ln for ln in xla_scan if re.search(r" = \S+ slice\(", ln)]
+    wide = [ln.split(" = ")[0].strip() for ln in xla_scan
+            if (m := re.search(r" = \(?f32\[([\d,]+)\]", ln))
+            and math.prod(int(d) for d in m.group(1).split(",")) >= 2**22]
+    assert not wide, wide
+    big = [ln for ln in xla_scan if re.search(r" = bf16\[4,\d+,\d+(,128)?\]", ln)]
+    assert len(big) == 2, big  # do head-major; [dq | dk | dv]
     nameless = [ln.split(" = ")[0].strip() for ln in _entry_lines(text)
-                if re.search(r" = f32\[2048,8,\d+,128\]\S* copy\(", ln) and "op_name=" not in ln]
+                if re.search(r" = f32\[(2048,8|4,4096),\d+(,128)?\]\S* copy\(", ln)
+                and "op_name=" not in ln]
     assert not nameless, nameless
 
 
